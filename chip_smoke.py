@@ -1,0 +1,604 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py
+
+One process holds the chip and drives the main path once through the entry
+points a user calls — CPU actor processes -> zmq -> ingest -> jitted update
+on the TPU -> publish -> hot-swap — plus the other device programs the repo
+has (the widest transformer through ``build_algorithm``, the fused anakin
+rollout, a served batch). It checks what comes out, fails on the first thing
+that is wrong (non-zero exit, one ``chip_smoke: FAIL`` line saying why; a
+phase's own exception is never caught), and ends with ONE JSON line:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+Without an accelerator it exits non-zero before running any phase and prints
+no result. It uses the chips it finds: on a multi-chip host the learner of
+phase A is sharded over all of them and the check says so.
+
+This measures nothing — the fence timings it prints answer "which fence is
+sound on this machine", they are not a benchmark.
+
+Actor children are started with ``spawn`` and re-import this module, so the
+module level imports nothing that touches jax.
+"""
+
+from __future__ import annotations
+
+import json
+import multiprocessing as mp
+import os
+import queue as queue_mod
+import shutil
+import socket
+import subprocess
+import sys
+import threading
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+OUT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
+RUN_DIR = os.path.join(OUT_DIR, "run")
+WALL_LIMIT_S = 1150.0  # the driver allows 1200 s, compilation included
+
+# Phase A: the 256x256 bf16 MLP of the old bench.py headline, under the
+# closed loop.
+A_ACTORS = 2
+A_UPDATES = 12   # past learner.checkpoint_every_epochs (10): one orbax
+#                  save happens while the persistent compile cache is on
+# Phase B: the widest model the repo has run (learner_tpu.json
+# transformer_flash_computebound), through the normal seam.
+B_ARCH = dict(model_kind="transformer_discrete", d_model=1024, n_layers=4,
+              n_heads=8, max_seq_len=1024, attention="flash")
+B_OBS, B_ACT, B_T, B_TRAJ = 64, 18, 1024, 4
+B_UPDATES = 3
+FENCE_CHAIN = 20
+
+
+def say(msg: str) -> None:
+    """This script's own lines: stdout, and a log beside the run directory
+    (the epoch tables the learner prints can push them out of a tail)."""
+    print(f"chip_smoke: {msg}", flush=True)
+    os.makedirs(OUT_DIR, exist_ok=True)
+    with open(os.path.join(OUT_DIR, "smoke.log"), "a") as f:
+        f.write(f"{time.strftime('%H:%M:%S')} {msg}\n")
+
+
+def fail(msg: str):
+    """First wrong thing ends the run. SystemExit unwinds through every
+    ``finally`` below, which is where started processes are stopped."""
+    say(f"FAIL {msg}")
+    sys.exit(1)
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        fail(msg)
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+# --------------------------------------------------------------------------
+# children: CPU hosts, and they prove it
+# --------------------------------------------------------------------------
+
+def _child_platform(tag: str, queue) -> None:
+    """First thing a child does: pin the CPU BEFORE importing jax, then
+    report the platform jax actually gave it."""
+    from relayrl_tpu.utils.hostpin import pin_cpu
+
+    pin_cpu()
+    import jax
+
+    queue.put((tag, "platform", jax.devices()[0].platform))
+
+
+def actor_child(idx: int, config_path: str, workdir: str, addrs: dict,
+                stop, queue) -> None:
+    tag = f"actor-{idx}"
+    _child_platform(tag, queue)
+    os.makedirs(workdir, exist_ok=True)
+    os.chdir(workdir)
+    from relayrl_tpu.envs import CartPoleEnv
+    from relayrl_tpu.runtime.agent import Agent, run_gym_loop
+
+    agent = Agent(config_path=config_path, server_type="zmq", seed=idx,
+                  **addrs)
+    env = CartPoleEnv()
+    episodes = 0
+    deadline = time.monotonic() + 600
+    # Play until the parent has its updates AND this actor has hot-swapped
+    # at least once (model_version >= 1 is the swap, seen from here).
+    while (not (stop.is_set() and agent.model_version >= 1)
+           and time.monotonic() < deadline):
+        episodes += len(run_gym_loop(agent, env, episodes=2, max_steps=500))
+    queue.put((tag, "done", {"model_version": agent.model_version,
+                             "episodes": episodes}))
+    agent.disable_agent()
+
+
+def remote_child(config_path: str, workdir: str, addrs: dict, episodes: int,
+                 queue) -> None:
+    tag = "remote-0"
+    _child_platform(tag, queue)
+    os.makedirs(workdir, exist_ok=True)
+    os.chdir(workdir)
+    from relayrl_tpu.envs import CartPoleEnv
+    from relayrl_tpu.runtime.agent import run_gym_loop
+    from relayrl_tpu.runtime.inference import RemoteActorClient
+
+    client = RemoteActorClient(config_path=config_path, server_type="zmq",
+                               seed=7, identity=tag, **addrs)
+    returns = run_gym_loop(client, CartPoleEnv(), episodes=episodes,
+                           max_steps=200)
+    queue.put((tag, "done", {"model_version": client.model_version,
+                             "episodes": len(returns),
+                             "steps": int(sum(returns))}))
+    client.disable_agent()
+
+
+class Children:
+    """Every process this script starts, so that each one is stopped."""
+
+    def __init__(self):
+        self.ctx = mp.get_context("spawn")  # never fork once jax is live
+        self.queue = self.ctx.Queue()
+        self.procs: list = []
+
+    def start(self, target, *args) -> None:
+        p = self.ctx.Process(target=target, args=(*args, self.queue),
+                             daemon=True)
+        p.start()
+        self.procs.append(p)
+
+    def check_alive(self) -> None:
+        dead = [(p.name, p.exitcode) for p in self.procs
+                if p.exitcode not in (None, 0)]
+        check(not dead, f"child process died: {dead}")
+
+    def expect(self, tags: set[str], kind: str, timeout: float) -> dict:
+        """One ``kind`` message from each of ``tags`` — a loud failure
+        with a timeout, never a wait: a child that initialises the TPU
+        backend while this process holds the chip hangs or dies."""
+        got: dict = {}
+        deadline = time.monotonic() + timeout
+        while set(got) != tags:
+            self.check_alive()
+            left = deadline - time.monotonic()
+            check(left > 0, f"no {kind!r} report from "
+                            f"{sorted(tags - set(got))} within {timeout:.0f}s")
+            try:
+                tag, k, payload = self.queue.get(timeout=min(left, 1.0))
+            except queue_mod.Empty:
+                continue
+            if k == kind and tag in tags:
+                got[tag] = payload
+        return got
+
+    def stop_all(self) -> None:
+        for p in self.procs:
+            p.join(timeout=20)
+        for p in self.procs:
+            if p.is_alive():
+                p.terminate()
+                p.join(timeout=5)
+            if p.is_alive():
+                p.kill()
+                p.join(timeout=5)
+        self.procs = []
+
+
+# --------------------------------------------------------------------------
+# set-up
+# --------------------------------------------------------------------------
+
+def rebuild_native() -> str:
+    """``native/librelayrl_native.so`` is git-ignored, and the server's
+    ingest decodes through whatever binary it finds — so a stale one in the
+    working tree would run on the chip. Rebuild it from ``native/*.cc`` in
+    this run, or make sure there is none."""
+    native_dir = os.path.join(REPO, "native")
+    lib = os.path.join(native_dir, "librelayrl_native.so")
+    if shutil.which("make") and shutil.which("g++"):
+        t0 = time.monotonic()
+        out = subprocess.run(["make", "-B", "-C", native_dir],
+                             capture_output=True, text=True, timeout=600)
+        if out.returncode != 0:
+            print(out.stderr[-2000:], flush=True)
+            fail(f"native library did not build (make rc={out.returncode})")
+        return f"built from native/*.cc in {time.monotonic() - t0:.0f}s"
+    if os.path.exists(lib):
+        os.remove(lib)
+        return "no toolchain: stale binary removed"
+    return "no toolchain, no binary"
+
+
+def write_config() -> str:
+    """The config this run uses, in the run directory: defaults plus these
+    overrides. (``ConfigLoader`` reads cwd when given no path, and the repo
+    root can hold an untracked ``relayrl_config.json``.)"""
+    from relayrl_tpu.config import default_config
+
+    cfg = default_config()
+    cfg["learner"]["precision"] = "bfloat16"
+    cfg["max_traj_length"] = B_T  # phase B's 1024 bucket (default cap 1000)
+    path = os.path.join(RUN_DIR, "relayrl_config.json")
+    with open(path, "w") as f:
+        json.dump(cfg, f, indent=1)
+    return path
+
+
+class CompileCounter:
+    """Compile requests vs persistent-cache hits, from jax's own events."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.requests = self.hits = 0
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+
+def on_tpu(tree) -> bool:
+    import jax
+
+    return all(d.platform == "tpu"
+               for leaf in jax.tree_util.tree_leaves(tree)
+               if isinstance(leaf, jax.Array) for d in leaf.devices())
+
+
+# --------------------------------------------------------------------------
+# phases
+# --------------------------------------------------------------------------
+
+def phase_a_and_d(config_path: str, n_devices: int):
+    """A: closed loop — TrainingServer on the chip(s), spawned CPU actors.
+    D: the same server answers RemoteActorClient requests from a CPU child.
+    Returns the learner's final bundle (phase C swaps to it)."""
+    import jax
+
+    from relayrl_tpu.runtime.server import TrainingServer
+
+    server_addrs = {
+        "agent_listener_addr": f"tcp://127.0.0.1:{free_port()}",
+        "trajectory_addr": f"tcp://127.0.0.1:{free_port()}",
+        "model_pub_addr": f"tcp://127.0.0.1:{free_port()}",
+        "serving_addr": f"tcp://127.0.0.1:{free_port()}",
+    }
+    agent_addrs = {
+        "agent_listener_addr": server_addrs["agent_listener_addr"],
+        "trajectory_addr": server_addrs["trajectory_addr"],
+        "model_sub_addr": server_addrs["model_pub_addr"],
+    }
+    remote_addrs = {**agent_addrs, "serving_addr": server_addrs["serving_addr"]}
+
+    t0 = time.monotonic()
+    server = TrainingServer(
+        "IMPALA", obs_dim=4, act_dim=2, server_type="zmq",
+        env_dir=os.path.join(RUN_DIR, "server"), config_path=config_path,
+        serving=True,
+        hyperparams={"hidden_sizes": [256, 256], "seed_salt": 0},
+        **server_addrs)
+    children = Children()
+    try:
+        server.wait_warmup(timeout=600)  # raises if the update won't compile
+        say(f"A: server up, warmup {server.timings['warmup_s']:.1f}s "
+            f"(bucket shapes {server.algorithm.buffer.buckets})")
+        check(server.inference is not None, "A: serving plane did not start")
+
+        stop = children.ctx.Event()
+        tags = {f"actor-{i}" for i in range(A_ACTORS)}
+        for i in range(A_ACTORS):
+            children.start(actor_child, i, config_path,
+                           os.path.join(RUN_DIR, f"actor-{i}"), agent_addrs,
+                           stop)
+        platforms = children.expect(tags, "platform", timeout=180)
+        check(set(platforms.values()) == {"cpu"},
+              f"A: actor children not on cpu: {platforms}")
+
+        deadline = time.monotonic() + 420
+        while server.stats["updates"] < A_UPDATES:
+            check(time.monotonic() < deadline,
+                  f"A: {server.stats['updates']} updates after 420s "
+                  f"(stats {server.stats})")
+            check(server._learner_thread.is_alive(), "A: learner thread died")
+            children.check_alive()
+            time.sleep(0.2)
+        stop.set()
+        done = children.expect(tags, "done", timeout=120)
+        check(all(d["model_version"] >= 1 for d in done.values()),
+              f"A: an actor never hot-swapped: {done}")
+        children.stop_all()
+
+        # D: one served batch or more, from a thin client on a CPU host.
+        children.start(remote_child, config_path,
+                       os.path.join(RUN_DIR, "remote-0"), remote_addrs, 3)
+        rp = children.expect({"remote-0"}, "platform", timeout=180)
+        check(rp["remote-0"] == "cpu", f"D: remote child not on cpu: {rp}")
+        served = children.expect({"remote-0"}, "done", timeout=180)["remote-0"]
+        children.stop_all()
+
+        check(server.drain(timeout=120), "A: server did not drain")
+        stats = dict(server.stats)
+        params = server.algorithm.state.params
+        check(on_tpu(params), "A: learner params are not on tpu devices")
+        spans = {len(x.sharding.device_set)
+                 for x in jax.tree_util.tree_leaves(params)}
+        check(spans == {n_devices},
+              f"A: param shardings span {spans} devices, expected "
+              f"{{{n_devices}}}")
+        check(stats["updates"] >= 3, f"A: updates {stats['updates']} < 3")
+        for key in ("dropped", "dropped_nonfinite", "learner_errors",
+                    "publish_errors", "warmup_failed"):
+            check(stats[key] == 0, f"A: stats[{key!r}] = {stats[key]}")
+        check(server._ckpt_consecutive_failures == 0,
+              "A: a periodic checkpoint failed")
+        server.algorithm._ckpt_mgr.wait()  # the async orbax save lands
+        ckpt_dir = os.path.join(RUN_DIR, "server", "checkpoints")
+        steps = sorted(d for d in os.listdir(ckpt_dir) if d.isdigit())
+        check(bool(steps), f"A: no orbax checkpoint step under {ckpt_dir}")
+
+        acct = server.inference.accounting()
+        check(served["episodes"] == 3 and served["steps"] > 0,
+              f"D: remote client did not finish its episodes: {served}")
+        check(served["model_version"] >= 1,
+              f"D: served actions came from version "
+              f"{served['model_version']} (install_params never ran)")
+        check(on_tpu(server.inference.params),
+              "D: serving params are not on tpu devices")
+        bundle = server.algorithm.bundle()
+        say(f"A: ok — {stats['updates']} updates from "
+            f"{stats['trajectories']} trajectories, actors {done}, params on "
+            f"tpu over {n_devices} device(s), dropped 0, "
+            f"warmup/learner/publish errors 0, orbax steps {steps} with the "
+            f"compile cache on, decode path {server.ingest_decoder}, "
+            f"{time.monotonic() - t0:.0f}s")
+        say(f"D: ok — remote client {served}, service at version "
+            f"{server.inference.version}, accounting {acct}")
+        return bundle
+    finally:
+        children.stop_all()
+        server.disable_server()
+
+
+def synthetic_episode(rng, length: int):
+    import numpy as np
+
+    from relayrl_tpu.types.action import ActionRecord
+
+    return [ActionRecord(
+        obs=rng.standard_normal(B_OBS).astype(np.float32),
+        act=np.int64(rng.integers(B_ACT)), rew=float(rng.random()),
+        data={"logp_a": np.float32(-2.89), "v": np.float32(0.0)},
+        done=(i == length - 1)) for i in range(length)]
+
+
+def phase_b(config_path: str) -> None:
+    """The d1024·L4·T1024·head_dim 128 transformer through
+    ``build_algorithm`` and the exact calls ``server._process_one`` makes:
+    accumulate -> stage_batch -> train_on_batch."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from relayrl_tpu.algorithms import build_algorithm
+    from relayrl_tpu.models import build_policy
+
+    t0 = time.monotonic()
+    algo = build_algorithm(
+        "IMPALA", obs_dim=B_OBS, act_dim=B_ACT, config_path=config_path,
+        env_dir=os.path.join(RUN_DIR, "phase_b"), traj_per_epoch=B_TRAJ,
+        bucket_lengths=[B_T], seed_salt=0, **B_ARCH)
+    check(algo.arch["precision"] == "bfloat16", "B: not the bf16 trunk")
+    before = jax.device_get(algo.state.params["params"]["block_0"]["qkv"]
+                            ["kernel"])
+    rng = np.random.default_rng(0)
+    losses = []
+    while len(losses) < B_UPDATES:
+        batch = algo.accumulate(synthetic_episode(rng, B_T))
+        if batch is None:
+            continue
+        staged = algo.stage_batch(batch)
+        metrics = algo.train_on_batch(staged)
+        losses.append(metrics["LossTotal"])  # reading it fences the update
+    algo.inflight.drain()
+    check(all(np.isfinite(losses)), f"B: non-finite loss {losses}")
+    after = jax.device_get(algo.state.params["params"]["block_0"]["qkv"]
+                           ["kernel"])
+    check(np.all(np.isfinite(after)) and not np.array_equal(before, after),
+          "B: parameters did not change (or went non-finite)")
+    check(on_tpu(algo.state.params), "B: params are not on tpu devices")
+    key = (B_T, B_ARCH["d_model"] // B_ARCH["n_heads"], "bfloat16")
+    resolved = dict(algo.policy.attention_backends)
+    check(resolved.get(key) == "flash_pallas",
+          f"B: attention at {key} resolved to {resolved.get(key)!r}, "
+          f"not the Pallas kernel ({resolved})")
+    text = algo._update.lower(algo.state, staged).compile().as_text()
+    n_mosaic = text.count("tpu_custom_call")
+    check(n_mosaic > 0, "B: no Mosaic custom call in the compiled update")
+
+    # Agreement with the reference on a small input: the same parameters
+    # through the same network with dense softmax attention.
+    dense = build_policy({**algo.arch, "attention": "dense"})
+    obs = jnp.asarray(rng.standard_normal((1, B_T, B_OBS)), jnp.float32)
+    act = jnp.asarray(rng.integers(0, B_ACT, (1, B_T)), jnp.int32)
+    logp_k, _, _ = jax.jit(algo.policy.evaluate)(algo.state.params, obs, act)
+    logp_d, _, _ = jax.jit(dense.evaluate)(algo.state.params, obs, act)
+    err = float(jnp.max(jnp.abs(logp_k - logp_d)))
+    # bf16 trunk: 8 mantissa bits through 4 layers; logp is O(3).
+    check(np.isfinite(err) and err < 0.1,
+          f"B: flash vs dense logp differ by {err}")
+    say(f"B: ok — {len(losses)} updates, LossTotal {losses}, attention "
+        f"{resolved}, {n_mosaic} Mosaic custom calls in the compiled update, "
+        f"flash-vs-dense max |dlogp| {err:.1e}, {time.monotonic() - t0:.0f}s")
+
+    # The fence question (bench.py, benches/common.py): the same chain of
+    # updates, fenced three ways. Straight through the jitted update — the
+    # in-flight window would fence for us.
+    state = algo.state  # settled: the in-flight window was drained above
+    walls = {}
+    for name in ("no_fence", "block_until_ready", "host_readback"):
+        t1 = time.perf_counter()
+        for _ in range(FENCE_CHAIN):
+            state, m = algo._update(state, staged)
+        if name == "block_until_ready":
+            jax.block_until_ready(state)
+        elif name == "host_readback":
+            float(m["LossTotal"])
+        walls[name] = time.perf_counter() - t1
+        jax.block_until_ready(state)  # settle before the next variant
+    algo.state = state
+    sound = walls["block_until_ready"] > 0.8 * walls["host_readback"]
+    say(f"fence: {FENCE_CHAIN} chained phase-B updates — dispatch only "
+        f"{walls['no_fence'] * 1e3:.0f} ms, jax.block_until_ready(state) "
+        f"{walls['block_until_ready'] * 1e3:.0f} ms, host readback "
+        f"{walls['host_readback'] * 1e3:.0f} ms => block_until_ready "
+        f"{'FENCES' if sound else 'does NOT fence'} on this machine")
+    check(walls["host_readback"] > 2 * walls["no_fence"],
+          "fence: host readback returned as fast as dispatch")
+
+
+def phase_b_default_buckets(config_path: str) -> None:
+    """``learner.bucket_lengths`` as shipped ([64, 256, 1000]) through
+    ``warmup()`` for the transformer policy — what a default server's
+    learner thread compiles first."""
+    from relayrl_tpu.algorithms import build_algorithm
+
+    t0 = time.monotonic()
+    algo = build_algorithm(
+        "IMPALA", obs_dim=B_OBS, act_dim=B_ACT, config_path=config_path,
+        env_dir=os.path.join(RUN_DIR, "phase_b_buckets"), seed_salt=0,
+        model_kind="transformer_discrete", d_model=256, n_layers=2,
+        n_heads=8, max_seq_len=1024, attention="flash")
+    buckets = algo.buffer.buckets
+    n = algo.warmup()
+    check(n == len(buckets), f"B': warmup compiled {n} of {buckets}")
+    resolved = dict(algo.policy.attention_backends)
+    for t in buckets:
+        check(resolved.get((t, 32, "bfloat16")) == "flash_pallas",
+              f"B': bucket {t} resolved to "
+              f"{resolved.get((t, 32, 'bfloat16'))!r} ({resolved})")
+    say(f"B': ok — default buckets {buckets} compiled through warmup() as "
+        f"{resolved}, {time.monotonic() - t0:.0f}s")
+
+
+def phase_c(bundle) -> None:
+    """One fused rollout: CartPole-JAX, 64 lanes x unroll 32, MLP, in this
+    process, with a parameter swap over the model wire between windows."""
+    import jax
+    import numpy as np
+
+    from relayrl_tpu.runtime.anakin import AnakinActorHost
+    from relayrl_tpu.transport.modelwire import ModelWireEncoder
+    from relayrl_tpu.types.model_bundle import ModelBundle
+
+    t0 = time.monotonic()
+    sent = []
+    zeros = jax.tree_util.tree_map(np.zeros_like, bundle.params)
+    host = AnakinActorHost(
+        ModelBundle(version=0, arch=bundle.arch, params=zeros),
+        "CartPole-v1", num_envs=64, unroll_length=32,
+        on_send=lambda lane, payload: sent.append(len(payload)))
+    try:
+        outs = [host.rollout() for _ in range(2)]
+        frame, _info = ModelWireEncoder().encode(
+            bundle.version, bundle.arch, bundle.params)
+        check(host.swap_from_wire(bundle.version, frame) is not None,
+              "C: the wire swap installed nothing")
+        outs += [host.rollout() for _ in range(2)]
+    finally:
+        host.close()
+    check(host.version == bundle.version, "C: swap did not take")
+    check(on_tpu(host.params), "C: swapped params are not on tpu devices")
+    check(on_tpu(host._carry), "C: the scan carry is not on tpu devices")
+    check(all(o["steps"] == 64 * 32 for o in outs), f"C: window sizes {outs}")
+    episodes = sum(len(r) for r in host.episode_returns)
+    check(episodes > 0 and sent, "C: no episode finished / nothing emitted")
+    rets = [r for lane in host.episode_returns for r in lane]
+    check(all(np.isfinite(rets)) and min(rets) >= 1.0,
+          "C: CartPole returns are not finite positive step counts")
+    say(f"C: ok — 4 windows of 64x32, swap v0->v{bundle.version} between "
+        f"them, {episodes} episodes, {len(sent)} frames emitted "
+        f"({outs[0]['wire']}), {time.monotonic() - t0:.0f}s")
+
+
+# --------------------------------------------------------------------------
+
+def main() -> None:
+    t_start = time.monotonic()
+    import jax
+    import jaxlib
+
+    devices = jax.devices()
+    dev = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+           "count": len(devices)}
+    from importlib import metadata
+
+    versions = {"jax": jax.__version__, "jaxlib": jaxlib.__version__}
+    for pkg in ("libtpu", "flax", "optax", "orbax-checkpoint"):
+        try:
+            versions[pkg] = metadata.version(pkg)
+        except metadata.PackageNotFoundError:
+            versions[pkg] = "not installed"
+    say(f"device platform={dev['platform']} device_kind={dev['kind']!r} "
+        f"count={dev['count']} versions={versions}")
+    if dev["platform"] != "tpu":
+        say(f"FAIL no accelerator: jax found platform {dev['platform']!r}; "
+            f"no phase was run")
+        sys.exit(2)
+
+    # A hang must not outlive the driver's limit with children attached.
+    def _overrun():
+        say(f"FAIL wall limit {WALL_LIMIT_S:.0f}s exceeded")
+        for p in mp.active_children():
+            p.kill()
+        os._exit(3)
+
+    watchdog = threading.Timer(WALL_LIMIT_S, _overrun)
+    watchdog.daemon = True
+    watchdog.start()
+
+    run(dev, t_start)
+    watchdog.cancel()
+    print(json.dumps({"ok": True, "device": dev}), flush=True)
+
+
+def run(dev: dict, t_start: float) -> None:
+    """Every phase, in a run directory of its own."""
+    shutil.rmtree(RUN_DIR, ignore_errors=True)
+    os.makedirs(RUN_DIR)
+    os.chdir(RUN_DIR)
+    say(f"native library: {rebuild_native()}")
+
+    from relayrl_tpu.utils.compile_cache import ENV_VAR, resolve_compile_cache
+
+    cache_dir = resolve_compile_cache()
+    compiles = CompileCounter()
+    say(f"compile cache: {cache_dir} "
+        f"({ENV_VAR} {'set' if os.environ.get(ENV_VAR) else 'not set'}, "
+        f"{len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0} "
+        f"entries at start)")
+    config_path = write_config()
+
+    bundle = phase_a_and_d(config_path, dev["count"])
+    phase_b(config_path)
+    phase_b_default_buckets(config_path)
+    phase_c(bundle)
+
+    say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
+        f"the persistent cache, {compiles.requests - compiles.hits} compiled "
+        f"new")
+    say(f"wall {time.monotonic() - t_start:.0f}s")
+
+
+if __name__ == "__main__":
+    main()

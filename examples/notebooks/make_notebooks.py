@@ -82,12 +82,8 @@ def build(env_key: str, baseline: bool, transport: str) -> nbformat.NotebookNode
     nb.cells.append(new_code_cell(
         "import os\n"
         "import socket\n\n"
-        "if os.environ.get(\"RELAYRL_TPU\") != \"1\":\n"
-        "    # Examples default to CPU JAX (actors are CPU hosts even in\n"
-        "    # production); set RELAYRL_TPU=1 to let the learner use the\n"
-        "    # real accelerator.\n"
-        "    from relayrl_tpu.utils.hostpin import pin_cpu\n"
-        "    pin_cpu()\n\n"
+        "# One kernel hosts the learner and the actor loop, so both run on\n"
+        "# the backend JAX finds (JAX_PLATFORMS=cpu keeps them on the CPU).\n"
         "from relayrl_tpu.envs import make\n"
         "from relayrl_tpu.runtime.agent import (\n"
         "    Agent, coerce_env_action, greedy_episodes)\n"

@@ -31,10 +31,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("RELAYRL_TPU") != "1":
-    from relayrl_tpu.utils.hostpin import pin_cpu
-
-    pin_cpu()
 
 
 def free_port() -> int:
@@ -46,7 +42,7 @@ def free_port() -> int:
 _CARTPOLE = ("CartPole-v1", 4, 2)
 _PENDULUM = ("Pendulum-v1", 3, 1)
 
-# Per-cell metadata (VERDICT r3 #7):
+# Per-cell metadata:
 #   expects: "learning" — the committed golden must show an improving
 #            greedy return at the golden budget; "wiring" — the cell is a
 #            plumbing/e2e smoke whose budget is too small for a trend
@@ -66,12 +62,12 @@ CELLS = [
     ("PPO", {}, "grpc", _CARTPOLE, {"expects": "learning"}),
     # The async staleness-corrected family over the default transport.
     ("IMPALA", {}, "zmq", _CARTPOLE, {"expects": "learning"}),
-    # Off-policy families (VERDICT r2 weak #2: the matrix had none):
+    # Off-policy families:
     # replay/warmup/target-net over zmq, and continuous squashed-Gaussian
     # actions over the native wire. The DQN cell is sized to learn: the
     # epsilon schedule completes inside the cell budget and the update-
     # to-data ratio is high enough for the greedy policy to clear random
-    # CartPole (VERDICT r3 weak #4: the old cell's curve declined).
+    # CartPole (a cell whose curve declines is not evidence).
     # Stability-tuned: at ratio 1.0 / lr 5e-4 / polyak 0.995 this cell
     # SOLVED CartPole then diverged (LossQ exploding to 1e5 on some runs,
     # timing-dependent). Slow targets (polyak .999), quarter update
@@ -108,7 +104,7 @@ CELLS = [
               "traj_per_epoch": 4, "hidden_sizes": [32, 32],
               "discrete": False, "act_limit": 2.0}, "native", _PENDULUM,
      {"expects": "wiring"}),
-    # Pixel cell (VERDICT r2 weak #2: no pixel cell): the CNN policy +
+    # Pixel cell: the CNN policy +
     # Atari preprocessing pipeline end-to-end over sockets — flat uint8
     # frames on the wire, Nature-trunk learner, hot-swap back.
     ("PPO", {"model_kind": "cnn_discrete", "obs_shape": [36, 36, 2],
@@ -185,7 +181,7 @@ def run_cell(algo: str, hp: dict, transport: str, env_spec: tuple,
         try:
             # Deterministic eval BEFORE training: the committed artifact
             # then shows the greedy trend, not the exploration-noised
-            # sampling returns (VERDICT r3 #7).
+            # sampling returns.
             greedy_first = greedy_episodes(agent.actor, _make_env(env_id),
                                            episodes=5, max_steps=200)
             while server.stats["updates"] < updates:
@@ -243,7 +239,11 @@ def main():
     args = ap.parse_args()
 
     from relayrl_tpu.transport.native_backend import native_available
+    from relayrl_tpu.utils.compile_cache import announce_learner_device
 
+    # Server and agent share this process, so both run on the backend JAX
+    # finds (JAX_PLATFORMS=cpu keeps the matrix off an accelerator).
+    announce_learner_device("matrix")
     cells = [c for c in CELLS
              if c[2] != "native" or native_available()]
     if len(cells) < len(CELLS):  # before --only: that filter also shrinks

@@ -1,43 +1,22 @@
 """TPU parallelism demo: one learner update over a dp x fsdp x tp mesh,
 plus the ring-attention sequence-parallel path.
 
-Runs on a virtual 8-device CPU mesh anywhere (the standard way to exercise
-shardings without a pod), and unchanged on real chips:
+Runs over the devices JAX finds; on a host with no accelerator, ask for a
+virtual CPU mesh (the standard way to exercise shardings without a pod):
 
-    python examples/tpu_sharded_learner.py            # 8 virtual devices
-    RELAYRL_TPU=1 python examples/tpu_sharded_learner.py   # real devices
+    python examples/tpu_sharded_learner.py                       # real devices
+    python examples/tpu_sharded_learner.py --virtual-devices 8   # CPU mesh
 """
 
 from __future__ import annotations
 
+import argparse
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("RELAYRL_TPU") != "1":
-    # Shared pin: sets XLA_FLAGS for the 8-device host platform BEFORE the
-    # jax import below can latch them, then forces the CPU backend.
-    from relayrl_tpu.utils.hostpin import pin_cpu
-
-    pin_cpu(virtual_devices=8)
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from relayrl_tpu.algorithms.reinforce import (
-    ReinforceState,
-    make_optimizers,
-    make_reinforce_update,
-)
-from relayrl_tpu.models import build_policy
-from relayrl_tpu.parallel import (
-    make_mesh,
-    make_sharded_update,
-    place_batch,
-    place_state,
-)
-from relayrl_tpu.utils import timed
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def make_batch(B, T, obs_dim, act_dim):
@@ -55,6 +34,23 @@ def make_batch(B, T, obs_dim, act_dim):
 
 
 def run(arch, mesh_spec, shard_time, label, B=16, T=64):
+    import jax
+    import jax.numpy as jnp
+
+    from relayrl_tpu.algorithms.reinforce import (
+        ReinforceState,
+        make_optimizers,
+        make_reinforce_update,
+    )
+    from relayrl_tpu.models import build_policy
+    from relayrl_tpu.parallel import (
+        make_mesh,
+        make_sharded_update,
+        place_batch,
+        place_state,
+    )
+    from relayrl_tpu.utils import timed
+
     policy = build_policy(arch)
     params = policy.init_params(jax.random.PRNGKey(0))
     tx_pi, tx_vf = make_optimizers(params, 3e-4, 1e-3)
@@ -77,8 +73,24 @@ def run(arch, mesh_spec, shard_time, label, B=16, T=64):
 
 
 def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--virtual-devices", type=int, default=0, metavar="N",
+                    help="run on N virtual CPU devices instead of the "
+                         "devices JAX finds")
+    args = ap.parse_args()
+    if args.virtual_devices:
+        # Sets XLA_FLAGS for the N-device host platform BEFORE jax can
+        # latch them, then forces the CPU backend.
+        from relayrl_tpu.utils.hostpin import pin_cpu
+
+        pin_cpu(virtual_devices=args.virtual_devices)
+
+    import jax
+
+    from relayrl_tpu.utils.compile_cache import announce_learner_device
+
+    announce_learner_device("sharded_learner")
     n = len(jax.devices())
-    print(f"{n} devices: {jax.devices()[:4]}...", flush=True)
 
     # Data + fully-sharded data + tensor parallel over an MLP learner.
     run({"kind": "mlp_discrete", "obs_dim": 32, "act_dim": 8,

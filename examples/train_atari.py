@@ -18,15 +18,9 @@ import argparse
 import os
 import sys
 
-# Importable as a script from anywhere; CPU by default (RELAYRL_TPU=1
-# targets the real chip) via the shared pin (see utils/hostpin.py for why
-# the env var alone is not enough).
+# Importable as a script from anywhere. The learner runs on the backend
+# JAX finds (JAX_PLATFORMS=cpu keeps it off an accelerator).
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("RELAYRL_TPU") != "1":
-    from relayrl_tpu.utils.hostpin import pin_cpu
-
-    pin_cpu()
-
 
 
 def main():
@@ -77,6 +71,9 @@ def main():
 
     from relayrl_tpu.envs import make_atari
     from relayrl_tpu.runtime.local_runner import LocalRunner
+    from relayrl_tpu.utils.compile_cache import announce_learner_device
+
+    announce_learner_device("train_atari")
 
     if args.shaped and args.env != "synthetic":
         ap.error("--shaped only applies to the synthetic env")

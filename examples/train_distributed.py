@@ -210,10 +210,12 @@ def main():
                          "JSON when possible, else stay strings")
     args = ap.parse_args()
 
-    if os.environ.get("RELAYRL_TPU") != "1":
-        from relayrl_tpu.utils.hostpin import pin_cpu
+    # This process is the learner: it runs on the backend JAX finds and
+    # owns it. The actor children below are spawned (never forked) and pin
+    # the CPU before they import jax — one process per chip.
+    from relayrl_tpu.utils.compile_cache import announce_learner_device
 
-        pin_cpu()
+    announce_learner_device("train_distributed")
 
     from relayrl_tpu.runtime.server import TrainingServer
 

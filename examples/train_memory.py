@@ -24,13 +24,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _pin_platform():
-    if os.environ.get("RELAYRL_TPU") != "1":
-        from relayrl_tpu.utils.hostpin import pin_cpu
-
-        pin_cpu()
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="transformer",
@@ -42,10 +35,12 @@ def main():
                     help="attention backend for the transformer policy")
     ap.add_argument("--env-dir", default="./env_memory")
     args = ap.parse_args()
-    _pin_platform()
 
     from relayrl_tpu.envs import RecallEnv
     from relayrl_tpu.runtime.local_runner import LocalRunner
+    from relayrl_tpu.utils.compile_cache import announce_learner_device
+
+    announce_learner_device("train_memory")
 
     bucket = max(16, 2 * args.horizon)
     hp = dict(with_vf_baseline=True, gamma=1.0, lam=0.95, traj_per_epoch=32,

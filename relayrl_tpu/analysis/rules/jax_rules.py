@@ -491,64 +491,6 @@ class UntimedJitDispatch(Rule):
                 and module.resolved_call(call.func) in JIT_WRAPPERS)
 
 
-class DirectShardMapBinding(Rule):
-    """``shard_map`` has lived at three addresses across JAX releases
-    (``jax.experimental.shard_map.shard_map`` with ``check_rep``,
-    ``jax.experimental.shard_map``, ``jax.shard_map`` with ``check_vma``)
-    — binding any of them directly scatters the next rename across every
-    mesh-program call site. :mod:`relayrl_tpu.parallel.compat` is the one
-    sanctioned resolver: it probes the installed surface, normalizes the
-    replication-check kwarg, and fails with the installed version in the
-    message when JAX moves the API again."""
-
-    code = "JAX07"
-    name = "direct-shard-map-binding"
-    description = ("jax.shard_map / jax.experimental.shard_map bound "
-                   "outside parallel/compat.py")
-
-    # The one module allowed to touch the raw surfaces.
-    _SANCTIONED_SUFFIX = "parallel/compat.py"
-
-    _TARGETS = frozenset({
-        "jax.shard_map",
-        "jax.experimental.shard_map",
-        "jax.experimental.shard_map.shard_map",
-    })
-
-    def check(self, module: ModuleInfo) -> Iterator[tuple[ast.AST, str]]:
-        if module.path.replace("\\", "/").endswith(self._SANCTIONED_SUFFIX):
-            return
-        reported: set[tuple[int, int]] = set()
-        for node in ast.walk(module.tree):
-            hit: str | None = None
-            if isinstance(node, ast.Import):
-                for a in node.names:
-                    if a.name in self._TARGETS:
-                        hit = a.name
-            elif isinstance(node, ast.ImportFrom) and node.module:
-                for a in node.names:
-                    dotted = f"{node.module}.{a.name}"
-                    if dotted in self._TARGETS or node.module in self._TARGETS:
-                        hit = dotted
-            elif isinstance(node, ast.Attribute):
-                resolved = module.resolve(qualname(node))
-                if resolved in self._TARGETS:
-                    hit = resolved
-            if hit is None:
-                continue
-            # An Attribute chain yields one node per segment, all sharing
-            # the expression's start position — report each site once.
-            pos = (node.lineno, node.col_offset)
-            if pos in reported:
-                continue
-            reported.add(pos)
-            yield node, (
-                f"`{hit}` bound directly — the shard_map surface moves "
-                f"between JAX releases (and renames check_rep/check_vma "
-                f"with it); import it from relayrl_tpu.parallel.compat, "
-                f"the one version-compat resolver")
-
-
 RULES = [
     PrngKeyReuse,
     HostSyncInJit,
@@ -556,5 +498,4 @@ RULES = [
     UntraceableArgNoStatic,
     MissingDonate,
     UntimedJitDispatch,
-    DirectShardMapBinding,
 ]

@@ -133,9 +133,11 @@ class GuardProbes:
                     if old_leaves else jnp.float32(0)
                 return nonfinite, norm, jnp.sqrt(delta_sq)
 
-            # old_copy is dead after this probe — donate it so the copy
-            # buffers free immediately on backends that support donation.
-            self._probe_delta_fn = jax.jit(probe, donate_argnums=0)
+            # No donation: the outputs are three scalars, so XLA has
+            # nothing to alias old_copy into (on the chip it only earned a
+            # "donated buffers were not usable" warning per compile). The
+            # copy frees when the caller drops it, right after this call.
+            self._probe_delta_fn = jax.jit(probe)
         nonfinite, norm, delta = self._probe_delta_fn(old_copy, new_params)
         return {PROBE_NONFINITE: nonfinite, PROBE_PARAM_NORM: norm,
                 PROBE_UPDATE_NORM: delta}

@@ -114,6 +114,11 @@ class Policy:
     # ``prefill_cache(params, cache, window) -> cache`` rebuilds the whole
     # cache from the padded window in one dispatch (used after hot-swaps).
     prefill_cache: Callable | None = None
+    # Sequence policies: ``{(T, head_dim, dtype): backend}`` for every
+    # attention shape traced so far (models/transformer._resolve_attention
+    # fills it at trace time) — which implementation a platform-dependent
+    # ``attention`` config actually compiled to. None for other families.
+    attention_backends: Mapping[tuple, str] | None = None
 
     @property
     def input_dim(self) -> int:

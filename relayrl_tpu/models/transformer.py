@@ -45,37 +45,68 @@ from relayrl_tpu.models.mlp import (
 from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
 
 
-def _resolve_attention(arch: Mapping[str, Any]) -> Callable:
-    """Arch config -> [B,T,H,D]x3 -> [B,T,H,D] attention callable."""
+def _resolve_attention(arch: Mapping[str, Any]) -> tuple[Callable, dict]:
+    """Arch config -> ``(attn_fn, resolved)``: the [B,T,H,D]x3 -> [B,T,H,D]
+    attention callable, and the record of what it ran as.
+
+    ``"flash"`` and ``"ring"`` pick their implementation at trace time
+    from the platform, the sequence length and the ambient mesh, so the
+    SAME arch config serves CPU actor hosts and the TPU learner. That
+    choice is never silent: ``resolved`` maps every traced
+    ``(T, head_dim, dtype)`` to the backend that was compiled for it
+    (``dense`` / ``blockwise`` / ``flash_pallas`` / ``ring_flash_pallas``
+    / ``ring_scan``), surfaced as ``Policy.attention_backends``, and each
+    new entry prints one line naming the platform it was resolved on.
+    """
     kind = arch.get("attention", "dense")
     block = int(arch.get("attention_block", 128))
+    resolved: dict[tuple[int, int, str], str] = {}
+
+    def ran(q, backend: str) -> None:
+        key = (int(q.shape[1]), int(q.shape[3]), q.dtype.name)
+        if resolved.get(key) != backend:
+            resolved[key] = backend
+            if kind in ("flash", "ring"):
+                print(f"[attention] {kind!r} T={key[0]} head_dim={key[1]} "
+                      f"{key[2]} -> {backend} "
+                      f"(platform {jax.default_backend()})", flush=True)
+
+    def dense(q, k, v):
+        ran(q, "dense")
+        return dense_attention(q, k, v, causal=True)
+
+    def blockwise(q, k, v):
+        ran(q, "blockwise")
+        return blockwise_attention(q, k, v, block, causal=True)
+
+    def local(q, k, v):
+        """The single-device XLA path "flash" and "ring" fall back to."""
+        return (blockwise if q.shape[1] % block == 0 else dense)(q, k, v)
+
     if kind == "dense":
-        return lambda q, k, v: dense_attention(q, k, v, causal=True)
+        return dense, resolved
     if kind == "blockwise":
-        return lambda q, k, v: blockwise_attention(q, k, v, block, causal=True)
+        return blockwise, resolved
     if kind == "flash":
         def flash_or_local(q, k, v):
             # Pallas kernel on TPU; off-TPU (CPU actor hosts, CI) the same
             # arch config resolves to the lax.scan blockwise path — the
             # heterogeneous-placement rule ring attention also follows.
             # The kernel has its OWN block knob (arch "flash_block"):
-            # grid-step count dominates kernel wall time so it wants large
-            # blocks, while the lax.scan fallback's "attention_block" is a
-            # memory/fusion knob that wants small ones — one shared key
-            # would silently deoptimize whichever path tuned second.
-            import jax as _jax
-
+            # it wants few large grid steps, while the lax.scan
+            # fallback's "attention_block" is a memory/fusion knob that
+            # wants small ones — one shared key would silently deoptimize
+            # whichever path tuned second.
             from relayrl_tpu.ops.flash import flash_attention
 
             T = q.shape[1]
             fblock = int(arch.get("flash_block", 1024))
-            if _jax.default_backend() == "tpu" and T % min(fblock, T) == 0:
+            if jax.default_backend() == "tpu" and T % min(fblock, T) == 0:
+                ran(q, "flash_pallas")
                 return flash_attention(q, k, v, causal=True,
                                        block_q=fblock, block_kv=fblock)
-            if T % block == 0:
-                return blockwise_attention(q, k, v, block, causal=True)
-            return dense_attention(q, k, v, causal=True)
-        return flash_or_local
+            return local(q, k, v)
+        return flash_or_local, resolved
     if kind == "ring":
         def ring_or_local(q, k, v):
             from relayrl_tpu.parallel.context import current_mesh
@@ -87,19 +118,18 @@ def _resolve_attention(arch: Mapping[str, Any]) -> Callable:
 
             mesh = current_mesh()
             if mesh is None or mesh.shape.get("sp", 1) <= 1:
-                if q.shape[1] % block == 0:
-                    return blockwise_attention(q, k, v, block, causal=True)
-                return dense_attention(q, k, v, causal=True)
+                return local(q, k, v)
             # On TPU the per-round combine runs as Pallas flash chunk
             # kernels when the local chunk tiles; the scan ring is the
-            # portable fallback (and the off-TPU path, where the kernel
-            # would run in the interpreter).
+            # portable fallback (and the off-TPU path).
             chunk = q.shape[1] // mesh.shape["sp"]
             if (jax.default_backend() == "tpu"
                     and pick_chunk_block(chunk) is not None):
+                ran(q, "ring_flash_pallas")
                 return make_ring_flash_attention(mesh)(q, k, v)
+            ran(q, "ring_scan")
             return make_ring_attention(mesh)(q, k, v)
-        return ring_or_local
+        return ring_or_local, resolved
     raise ValueError(f"unknown attention kind {kind!r}")
 
 
@@ -400,10 +430,13 @@ def _policy_from_apply(arch: Mapping[str, Any], init_params, apply_fn,
                   mode_window=mode_window)
 
 
-def _make_core(arch: Mapping[str, Any], moe_experts: int = 0) -> TransformerCore:
+def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
+               attn_fn: Callable | None = None) -> TransformerCore:
     """Arch -> TransformerCore module (shared by the policy builders and
     diagnostics like :func:`relayrl_tpu.models.moe.expert_utilization`,
     which re-applies the same module with captured intermediates)."""
+    if attn_fn is None:
+        attn_fn, _ = _resolve_attention(arch)
     return TransformerCore(
         act_dim=int(arch["act_dim"]),
         d_model=int(arch.get("d_model", 128)),
@@ -412,7 +445,7 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0) -> TransformerCore
         mlp_ratio=int(arch.get("mlp_ratio", 4)),
         max_seq_len=int(arch.get("max_seq_len", 1024)),
         has_critic=bool(arch.get("has_critic", True)),
-        attn_fn=_resolve_attention(arch),
+        attn_fn=attn_fn,
         compute_dtype=_compute_dtype(arch),
         moe_experts=moe_experts,
         moe_top_k=int(arch.get("moe_top_k", 2)),
@@ -421,7 +454,8 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0) -> TransformerCore
 
 def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     obs_dim = int(arch["obs_dim"])
-    core = _make_core(arch, moe_experts)
+    attn_fn, attention_backends = _resolve_attention(arch)
+    core = _make_core(arch, moe_experts, attn_fn)
 
     def init_params(rng):
         return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
@@ -482,7 +516,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
 
     return _dc.replace(policy, init_cache=init_cache,
                        step_cached=step_cached,
-                       prefill_cache=prefill_cache)
+                       prefill_cache=prefill_cache,
+                       attention_backends=attention_backends)
 
 
 @register_model("transformer_discrete")
@@ -544,9 +579,10 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
     d_model = int(arch.get("d_model", 128))
     n_layers = int(arch.get("n_layers", 2))
     n_micro = arch.get("pp_microbatches")
+    attn_fn, attention_backends = _resolve_attention(arch)
     block = TransformerBlock(
         d_model, int(arch.get("n_heads", 4)), int(arch.get("mlp_ratio", 4)),
-        _resolve_attention(arch), _compute_dtype(arch))
+        attn_fn, _compute_dtype(arch))
     embed = _PPEmbed(d_model, int(arch.get("max_seq_len", 1024)))
     readout = _PPReadout(int(arch["act_dim"]), d_model,
                          bool(arch.get("has_critic", True)))
@@ -584,4 +620,7 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
               if k not in _PP_IO_KEYS + ("blocks",)}
         return readout.apply({"params": ro}, x, mask)
 
-    return _policy_from_apply(arch, init_params, apply_fn)
+    import dataclasses as _dc
+
+    return _dc.replace(_policy_from_apply(arch, init_params, apply_fn),
+                       attention_backends=attention_backends)

@@ -7,6 +7,7 @@ buffers, TP/FSDP, and sequence-parallel hooks).
 
 from relayrl_tpu.parallel.mesh import (
     AXES,
+    accelerator_devices,
     data_axes,
     make_mesh,
     resolve_mesh_shape,
@@ -26,7 +27,6 @@ from relayrl_tpu.parallel.learner import (
     place_batch,
     place_state,
 )
-from relayrl_tpu.parallel.compat import shard_map, shard_map_impl_name
 from relayrl_tpu.parallel.context import current_mesh, use_mesh
 from relayrl_tpu.parallel.distributed import (
     broadcast_from_coordinator,
@@ -44,6 +44,7 @@ from relayrl_tpu.parallel.ring_flash import (
 
 __all__ = [
     "AXES",
+    "accelerator_devices",
     "data_axes",
     "make_mesh",
     "resolve_mesh_shape",
@@ -58,8 +59,6 @@ __all__ = [
     "make_sharded_update",
     "place_batch",
     "place_state",
-    "shard_map",
-    "shard_map_impl_name",
     "current_mesh",
     "use_mesh",
     "broadcast_from_coordinator",

@@ -67,6 +67,15 @@ def make_mesh(spec: Mapping[str, int] | None = None,
     return Mesh(arr, AXES)
 
 
+def accelerator_devices() -> list[jax.Device]:
+    """The devices a single-process learner spreads over without being
+    told to: every device of an accelerator backend, none of the CPU's.
+    ``--xla_force_host_platform_device_count`` devices are a technique for
+    testing sharded code, so code that wants them passes them to
+    :func:`make_mesh` itself."""
+    return [] if jax.default_backend() == "cpu" else list(jax.devices())
+
+
 def single_device_mesh() -> Mesh:
     return make_mesh({ax: 1 for ax in AXES}, jax.devices()[:1])
 

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from relayrl_tpu.parallel.compat import shard_map
 from relayrl_tpu.parallel.mesh import data_axes
 
 
@@ -112,7 +111,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array,
         ys = jax.lax.psum(ys, axis)
         return ys.reshape(x_local.shape)
 
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=x_spec,

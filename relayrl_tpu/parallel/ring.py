@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from relayrl_tpu.parallel.compat import shard_map
 from relayrl_tpu.ops.attention import attention_block_combine, finalize_attention
 
 _NEG_INF = -1e30
@@ -93,5 +92,5 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "sp",
     spec = P(b_axes if b_axes else None, axis_name, None, None)
     body = partial(ring_attention_sharded, axis_name=axis_name,
                    axis_size=axis_size, causal=causal)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)

@@ -46,7 +46,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-from relayrl_tpu.parallel.compat import shard_map
 from relayrl_tpu.ops.flash import (
     _LOG2E,
     _NEG_INF,
@@ -297,20 +296,17 @@ def pick_chunk_block(C: int, cap: int = 1024) -> int | None:
     return b
 
 
-def _resolve_chunk_config(C: int, block: int | None,
-                          interpret: bool | None) -> tuple[int, bool]:
-    """Shared block-resolution/tile-validation/interpret-default policy for
-    the sharded ring and the single-device cost model — one copy, so the
-    bench rows always measure the same kernels the ring runs."""
+def _resolve_chunk_block(C: int, block: int | None) -> int:
+    """Shared block-resolution/tile-validation policy for the sharded ring
+    and the single-device cost model — one copy, so the bench rows always
+    measure the same kernels the ring runs."""
     if block is None:
         block = pick_chunk_block(C)
     if block is None or C % block:
         raise ValueError(
             f"chunk length {C} does not tile (block={block}); use the scan "
             f"ring (relayrl_tpu.parallel.ring) for this shape")
-    if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
-    return int(block), bool(interpret)
+    return int(block)
 
 
 def _finalize_chunk_state(o, l, out_dtype):
@@ -456,7 +452,7 @@ def ring_flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                                  axis_name: str, axis_size: int,
                                  causal: bool = True,
                                  block: int | None = None,
-                                 interpret: bool | None = None) -> jax.Array:
+                                 interpret: bool = False) -> jax.Array:
     """Per-shard flash-chunk ring attention — call INSIDE ``shard_map``.
 
     Same contract as :func:`relayrl_tpu.parallel.ring.ring_attention_sharded`
@@ -465,15 +461,15 @@ def ring_flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     :func:`pick_chunk_block` and fall back to the scan ring when it
     returns None.
     """
-    block, interpret = _resolve_chunk_config(q.shape[1], block, interpret)
+    block = _resolve_chunk_block(q.shape[1], block)
     return _make_ring_flash(axis_name, axis_size, causal, block,
-                            interpret)(q, k, v)
+                            bool(interpret))(q, k, v)
 
 
 def chunked_flash_local(q: jax.Array, k: jax.Array, v: jax.Array,
                         n_chunks: int, causal: bool = True,
                         block: int | None = None,
-                        interpret: bool | None = None) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """Single-device emulation of the ring's per-chunk kernel schedule
     (forward only) — the ring cost model without a pod.
 
@@ -492,9 +488,9 @@ def chunked_flash_local(q: jax.Array, k: jax.Array, v: jax.Array,
     if T % n_chunks:
         raise ValueError(f"T={T} not divisible by n_chunks={n_chunks}")
     C = T // n_chunks
-    block, interpret = _resolve_chunk_config(C, block, interpret)
+    block = _resolve_chunk_block(C, block)
     fwd_call, _, _ = _build_chunk_calls(C, D, block, block,
-                                        q.dtype.name, interpret)
+                                        q.dtype.name, bool(interpret))
     qs = _prescale_q(_bthd_to_bht(q))
     kr, vr = _bthd_to_bht(k), _bthd_to_bht(v)
     bh = qs.shape[0]
@@ -520,7 +516,7 @@ def make_ring_flash_attention(mesh: Mesh, axis_name: str = "sp",
                               causal: bool = True,
                               batch_axes=("dp", "fsdp"),
                               block: int | None = None,
-                              interpret: bool | None = None):
+                              interpret: bool = False):
     """Global-view flash-chunk ring attention ``[B, T, H, D] -> same``.
 
     Drop-in for :func:`relayrl_tpu.parallel.ring.make_ring_attention` with
@@ -532,5 +528,5 @@ def make_ring_flash_attention(mesh: Mesh, axis_name: str = "sp",
     body = functools.partial(ring_flash_attention_sharded,
                              axis_name=axis_name, axis_size=axis_size,
                              causal=causal, block=block, interpret=interpret)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)

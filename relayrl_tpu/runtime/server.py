@@ -188,6 +188,17 @@ class TrainingServer:
             "checkpoint failures since the last successful save "
             "(alarm when this grows — resume would lose that window)")
         self._ckpt_consecutive_failures = 0
+        self._m_learner_errors = reg.counter(
+            "relayrl_server_learner_errors_total",
+            "accumulate/stage/update dispatches and window fences that "
+            "raised on the learner thread (the batch is lost, the loop "
+            "carries on)")
+        # Same family the publisher thread counts into
+        # (runtime/pipeline.ModelPublisher): the synchronous publish
+        # paths land here.
+        self._m_publish_errors = reg.counter(
+            "relayrl_learner_publish_errors_total",
+            "publish attempts that raised (transient socket/fs)")
         self._drop_events = _EventCoalescer()
         self._dup_events = _EventCoalescer()
 
@@ -351,22 +362,32 @@ class TrainingServer:
                 print("[TrainingServer] no checkpoint to resume; fresh start",
                       flush=True)
 
-        if self.distributed_info["multi_host"]:
-            # The learner step becomes SPMD over the global (all-host)
-            # mesh: coordinator-side socket ingest assembles batches, the
-            # broadcast loop ships them, every process steps in lockstep
-            # (SURVEY.md §7.4 item 5's asymmetric-ingest design).
+        # The learner step is SPMD over learner.mesh whenever that mesh
+        # has more than one device. Multi-host: the global (all-host)
+        # mesh — coordinator-side socket ingest assembles batches, the
+        # broadcast loop ships them, every process steps in lockstep
+        # (SURVEY.md §7.4 item 5's asymmetric-ingest design). One process
+        # that sees several chips: the same placement under the ordinary
+        # learner loop (a mesh of one process needs no broadcast).
+        import jax
+
+        from relayrl_tpu.parallel import accelerator_devices, make_mesh
+
+        mesh_devices = (jax.devices() if self.distributed_info["multi_host"]
+                        else accelerator_devices())
+        self.mesh = None
+        if len(mesh_devices) > 1:
             if not hasattr(self.algorithm, "enable_multihost"):
                 raise NotImplementedError(
-                    f"{algorithm_name} has no multi-host support "
-                    "(enable_multihost)")
-            from relayrl_tpu.parallel import make_mesh
-
-            self._mh_mesh = make_mesh(learner_cfg.get("mesh") or {"dp": -1})
-            self.algorithm.enable_multihost(self._mh_mesh)
-            print(f"[TrainingServer] multi-host mesh "
-                  f"{dict(self._mh_mesh.shape)} over "
-                  f"{len(self._mh_mesh.devices.flat)} devices", flush=True)
+                    f"{algorithm_name} cannot train over a device mesh "
+                    "(no enable_multihost)")
+            self.mesh = make_mesh(learner_cfg.get("mesh") or {"dp": -1},
+                                  mesh_devices)
+            self.algorithm.enable_multihost(self.mesh)
+            print(f"[TrainingServer] learner mesh "
+                  f"{dict(self.mesh.shape)} over "
+                  f"{len(mesh_devices)} {mesh_devices[0].platform} devices",
+                  flush=True)
 
         # Multi-actor registry (ref: MultiactorParams,
         # training_server_wrapper.rs:159-163). Always multi-capable; the
@@ -549,8 +570,17 @@ class TrainingServer:
         # finite-value guard's count is mirrored from the algorithm after
         # each trajectory so operators see poisoning without reaching
         # into algorithm internals.
+        # learner_errors / publish_errors / warmup_failed count what the
+        # loops below survive: a learner that ingests forever and never
+        # updates (a kernel the compiler refuses, a device OOM) shows here
+        # instead of only in scrolled-away log lines.
         self.stats = {"trajectories": 0, "updates": 0, "dropped": 0,
-                      "dropped_nonfinite": 0}
+                      "dropped_nonfinite": 0, "learner_errors": 0,
+                      "publish_errors": 0, "warmup_failed": 0}
+        # Which trajectory decoder the staging threads resolved:
+        # "native" (the C++ codec in native/) or "python".
+        self.ingest_decoder: str | None = None
+        self._warmup_error: Exception | None = None
         # Per-thread time ledger (seconds): where the ingest pipeline
         # actually spends its time — the profile evidence that the learner
         # thread waits on the device, not on msgpack (SURVEY §7.4-1).
@@ -1091,6 +1121,7 @@ class TrainingServer:
             decoder = NativeDecoder()
         except Exception:
             pass  # native codec unavailable: pure-Python decode
+        self.ingest_decoder = "python" if decoder is None else "native"
         guard = self.guardrails
         while not self._stop.is_set():
             try:
@@ -1214,7 +1245,7 @@ class TrainingServer:
             try:
                 got = self.algorithm.accumulate(one)
             except Exception as e:
-                print(f"[TrainingServer] accumulate error: {e!r}", flush=True)
+                self._learner_error("accumulate error", e)
                 continue
             finally:
                 self._sync_drop_stats()
@@ -1310,8 +1341,7 @@ class TrainingServer:
                 # program, so nothing here blocks the host).
                 algo.train_on_batch(batch)
             except Exception as e:
-                print(f"[TrainingServer] multi-host update error: {e!r}",
-                      flush=True)
+                self._learner_error("multi-host update error", e)
                 self._mh_busy = False
                 continue  # symmetric on all ranks: same data, same failure
             if (coord and self.guardrails is not None
@@ -1359,7 +1389,7 @@ class TrainingServer:
                                              jax.device_get(bundle.params))
                     ckpt_version = bundle.version
             except Exception as e:
-                print(f"[TrainingServer] publish error: {e!r}", flush=True)
+                self._publish_error("publish error", e)
                 ckpt_version = algo.dispatched_version
             # Full-state checkpoint is COLLECTIVE on a multi-host mesh
             # (orbax needs every process to contribute its shards to the
@@ -1394,9 +1424,15 @@ class TrainingServer:
                     print(f"[TrainingServer] warmup: {n} update shape(s) "
                           f"compiled in {time.monotonic() - t0:.1f}s",
                           flush=True)
-            except Exception as e:  # best-effort: first batch compiles then
-                print(f"[TrainingServer] warmup failed (non-fatal): {e!r}",
-                      flush=True)
+            except Exception as e:
+                # The update did not compile or did not run (a kernel the
+                # compiler refuses, device OOM): every real batch would
+                # fail the same way, so this learner is dead — say so in
+                # stats, hand the error to wait_warmup(), and let it end
+                # the thread instead of ingesting forever.
+                self.stats["warmup_failed"] += 1
+                self._warmup_error = e
+                raise
             finally:
                 self.timings["warmup_s"] += time.monotonic() - t0
                 self._warmup_done.set()
@@ -1410,7 +1446,13 @@ class TrainingServer:
                 # behind the in-flight updates, so resolving them (and
                 # flushing their deferred epoch logs) costs no overlap —
                 # and it is what lets drain() observe pending -> 0.
-                self._pipeline_quiesce()
+                try:
+                    self._pipeline_quiesce()
+                except Exception as e:
+                    # An update that failed ON the device surfaces at its
+                    # fence, which under async dispatch is usually here.
+                    self._learner_error("update failed at its fence", e)
+                    continue
                 # Everything dispatched is now fenced: resolve every
                 # pending health probe (free post-fence) and act on trips.
                 self._guard_poll()
@@ -1513,6 +1555,21 @@ class TrainingServer:
                        if ctx.born_version >= 0 else None)
                 tracer.observe_data_age(age_ns / 1e9, lag)
 
+    def _learner_error(self, what: str, e: Exception) -> None:
+        """One bad batch must not kill the learner loop — but it is
+        counted where an operator (and ``chip_smoke.py``) looks, not only
+        printed (learner thread)."""
+        self.stats["learner_errors"] += 1
+        self._m_learner_errors.inc()
+        print(f"[TrainingServer] {what}: {e!r}", flush=True)
+
+    def _publish_error(self, what: str, e: Exception) -> None:
+        """Same contract for a publish that raised on the learner thread
+        (the publisher thread counts its own in ``_publish_snapshot``)."""
+        self.stats["publish_errors"] += 1
+        self._m_publish_errors.inc()
+        print(f"[TrainingServer] {what}: {e!r}", flush=True)
+
     def _sync_drop_stats(self) -> None:
         """Mirror the algorithm's finite-guard counter into stats — the
         single owner, so every ingest path (single-host, multi-host, any
@@ -1563,7 +1620,7 @@ class TrainingServer:
                 else:
                     algo.train_on_batch(batches[0])
         except Exception as e:  # never kill the loop on one bad batch
-            print(f"[TrainingServer] learner error: {e!r}", flush=True)
+            self._learner_error("learner error", e)
             return
         finally:
             self._sync_drop_stats()
@@ -1603,7 +1660,7 @@ class TrainingServer:
                 else:
                     self._publish()  # sync escape hatch (async_publish off)
             except Exception as e:  # transient socket/fs errors must not
-                print(f"[TrainingServer] publish error: {e!r}", flush=True)
+                self._publish_error("publish error", e)
         self._flush_ready_logs()
         self._guard_poll()
 
@@ -1615,7 +1672,7 @@ class TrainingServer:
         try:
             updated = self.algorithm.receive_trajectory(item)
         except Exception as e:  # never kill the loop on one bad batch
-            print(f"[TrainingServer] learner error: {e!r}", flush=True)
+            self._learner_error("learner error", e)
             return
         finally:
             self._sync_drop_stats()
@@ -1625,7 +1682,7 @@ class TrainingServer:
             try:
                 self._publish()
             except Exception as e:  # transient socket/fs errors must not
-                print(f"[TrainingServer] publish error: {e!r}", flush=True)
+                self._publish_error("publish error", e)
             if self._tb is not None:
                 try:
                     self._tb.poll()
@@ -2020,8 +2077,7 @@ class TrainingServer:
                     self.inference.install_params(version, arch,
                                                   host_params)
                 except Exception as e:
-                    print(f"[TrainingServer] serving install error: "
-                          f"{e!r}", flush=True)
+                    self._publish_error("serving install error", e)
 
     def _traced_wire_publish(self, traced: bool, version: int,
                              frame: bytes, **kwargs) -> None:
@@ -2100,9 +2156,15 @@ class TrainingServer:
         publish, and artifact write all happen here — a slow subscriber
         or disk never stalls the learner thread, and back-to-back epochs
         coalesce latest-wins upstream (runtime/pipeline.ModelPublisher).
-        Exceptions are counted and logged by the publisher loop."""
-        self._publish_params(snapshot.version, snapshot.arch,
-                             snapshot.host_params())
+        Exceptions are logged and counted into the shared
+        ``publish_errors_total`` by the publisher loop; ``stats`` gets
+        its copy here."""
+        try:
+            self._publish_params(snapshot.version, snapshot.arch,
+                                 snapshot.host_params())
+        except Exception:
+            self.stats["publish_errors"] += 1
+            raise
 
     def _health_tag(self) -> dict:
         """The healthy-at-save tag every checkpoint carries (JSON
@@ -2260,10 +2322,17 @@ class TrainingServer:
         sleeps on the event, so the compile gets the core to itself.
         Returns False immediately when the server isn't running
         (``start=False`` and no enable yet): no learner thread exists to
-        ever set the event, so blocking would hang forever."""
+        ever set the event, so blocking would hang forever. Raises
+        ``RuntimeError`` (from the original error) when the warmup failed:
+        that learner thread has ended."""
         if not self.active and not self._warmup_done.is_set():
             return False
-        return self._warmup_done.wait(timeout)
+        done = self._warmup_done.wait(timeout)
+        if self._warmup_error is not None:
+            raise RuntimeError(
+                "learner warmup failed; the update does not compile or run "
+                "on this backend") from self._warmup_error
+        return done
 
     def disable_server(self, join_timeout: float | None = None) -> None:
         """``join_timeout`` overrides the per-thread join bounds — the

@@ -17,16 +17,6 @@ run() {
     "$@" || exit $?
 }
 
-# 0. shard_map compat probe: resolves the installed JAX's shard_map
-#    surface through the one sanctioned binding (parallel/compat.py).
-#    If a JAX upgrade removes/moves the API again, this fails in
-#    seconds with the pointed compat error naming the installed
-#    version — instead of 21 scattered tier-1 failures mid-suite
-#    (the pre-ISSUE-17 failure mode).
-run env JAX_PLATFORMS=cpu python -c \
-    "from relayrl_tpu.parallel.compat import shard_map_impl_name; \
-print('shard_map surface:', shard_map_impl_name())"
-
 # 1. Static analysis: jaxlint rules + cross-artifact contracts, gated
 #    on the committed baseline and contracts.json. Exit 1 here means a
 #    new finding or contract drift — fix it, suppress it with a

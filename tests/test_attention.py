@@ -348,6 +348,9 @@ class TestTransformerPolicy:
             out = jax.jit(ring.evaluate)(params, obs, act)
         for a, b in zip(ref, out):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+        # ...and each policy says what its attention actually ran as.
+        assert set(dense.attention_backends.values()) == {"dense"}
+        assert "ring_scan" in ring.attention_backends.values()
 
 
 class TestStepWindow:

@@ -1,18 +1,22 @@
 """Pallas flash-attention kernel vs the dense reference (interpret mode).
 
-The conftest pins tests to the CPU backend, so ``flash_attention`` runs the
-kernel through the Pallas interpreter — bit-accurate TPU semantics without
-hardware; the same kernel compiles on the chip (exercised by the attention
-bench, benches/bench_attention.py).
+The conftest pins tests to the CPU backend, so these tests ask for the
+Pallas interpreter explicitly (``interpret=True`` — never a default, see
+ops/flash.py) — bit-accurate TPU semantics without hardware; the compiled
+kernel runs on the chip through ``chip_smoke.py`` phase B.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from relayrl_tpu.ops import flash
 from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
-from relayrl_tpu.ops.flash import flash_attention
+
+flash_attention = functools.partial(flash.flash_attention, interpret=True)
 
 
 def _qkv(B=2, T=64, H=2, D=16, seed=0):
@@ -84,6 +88,17 @@ def test_transformer_flash_arch_runs_off_tpu():
     assert act.shape == (2,)
     logp, ent, v = policy.evaluate(params, obs, jnp.zeros((2, 32), jnp.int32))
     assert logp.shape == (2, 32)
+    # ...and says so: the resolved backend is on the policy.
+    assert policy.attention_backends[(32, 16, "float32")] == "blockwise"
+
+
+def test_flash_never_defaults_to_the_interpreter():
+    # Off-TPU without interpret=True the Mosaic lowering must refuse —
+    # a device process can never reach the interpreter by default.
+    q, k, v = _qkv(T=16)
+    with pytest.raises(Exception, match="(?i)interpret|tpu|mosaic"):
+        jax.block_until_ready(
+            flash.flash_attention(q, k, v, block_q=16, block_kv=16))
 
 
 @pytest.mark.parametrize("causal,bq,bk", [

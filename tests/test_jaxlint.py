@@ -341,58 +341,6 @@ class TestUntimedJitDispatch:
         """) == []
 
 
-class TestDirectShardMapBinding:
-    def test_positive_from_experimental(self):
-        assert "JAX07" in codes("""
-            from jax.experimental.shard_map import shard_map
-            def f(fn, mesh, spec):
-                return shard_map(fn, mesh=mesh, in_specs=spec,
-                                 out_specs=spec)
-        """)
-
-    def test_positive_jax_attribute(self):
-        # The pre-migration pipeline.py idiom: binding the moved surface.
-        assert "JAX07" in codes("""
-            import jax
-            shard_map = jax.shard_map
-        """)
-
-    def test_positive_experimental_module_attribute(self):
-        assert "JAX07" in codes("""
-            import jax.experimental.shard_map as shmap
-            def f(fn, mesh, spec):
-                return shmap.shard_map(fn, mesh=mesh, in_specs=spec,
-                                       out_specs=spec)
-        """)
-
-    def test_one_report_per_site(self):
-        # A full dotted chain is several Attribute nodes sharing one
-        # position — exactly one finding per call site.
-        found = [f for f in analyze_source(textwrap.dedent("""
-            import jax
-            f = jax.experimental.shard_map.shard_map
-        """), "x.py") if f.rule == "JAX07"]
-        assert len(found) == 1
-
-    def test_negative_compat_import(self):
-        assert codes("""
-            from relayrl_tpu.parallel.compat import shard_map
-            def f(fn, mesh, spec):
-                return shard_map(fn, mesh=mesh, in_specs=spec,
-                                 out_specs=spec, check_vma=False)
-        """) == []
-
-    def test_compat_module_itself_is_sanctioned(self):
-        src = textwrap.dedent("""
-            import jax
-            raw = getattr(jax, "shard_map", None) or jax.shard_map
-        """)
-        paths = {f.rule
-                 for f in analyze_source(src, "relayrl_tpu/parallel/compat.py")}
-        assert "JAX07" not in paths
-        assert "JAX07" in {f.rule for f in analyze_source(src, "other.py")}
-
-
 class TestBlockingUnderLock:
     def test_positive_sleep(self):
         assert "CONC01" in codes("""

@@ -404,3 +404,57 @@ class TestServerPipeline:
             assert stub.published and stub.published[-1][0] == 1
         finally:
             srv.disable_server()
+
+    def test_failed_update_is_counted_not_only_printed(
+            self, stub_server_factory):
+        """A learner that ingests forever and never updates (a kernel the
+        compiler refuses, a device OOM) must show in ``stats``."""
+        srv, _ = stub_server_factory(
+            "REINFORCE", start=False, hp={"with_vf_baseline": False})
+
+        def refuse(batch):
+            raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+        srv.algorithm.train_on_batch = refuse
+        for ep in _stream(3):
+            srv._process_one(ep)  # the learner-thread body, driven directly
+        assert srv.stats["trajectories"] == 3
+        assert srv.stats["learner_errors"] == 1
+        assert srv.stats["updates"] == 0 and srv.stats["publish_errors"] == 0
+
+    @pytest.mark.filterwarnings(
+        "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+    def test_failed_warmup_propagates(self, stub_server_factory):
+        """Warmup failing means every real batch would fail the same way:
+        not "non-fatal" — counted, handed to wait_warmup(), thread ends."""
+        srv, _ = stub_server_factory(
+            "REINFORCE", start=False, hp={"with_vf_baseline": False})
+
+        def refuse(should_continue=None):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+        srv.algorithm.warmup = refuse
+        srv.enable_server()
+        try:
+            with pytest.raises(RuntimeError, match="warmup failed") as err:
+                srv.wait_warmup(60)
+            assert "out of HBM" in str(err.value.__cause__)
+            assert srv.stats["warmup_failed"] == 1
+            srv._learner_thread.join(timeout=10)
+            assert not srv._learner_thread.is_alive()
+        finally:
+            srv.disable_server()
+
+    def test_failed_sync_publish_is_counted(self, stub_server_factory):
+        srv, stub = stub_server_factory(
+            "REINFORCE", start=False, hp={"with_vf_baseline": False})
+
+        def unplugged(version, raw, **kwargs):
+            raise OSError("socket closed")
+
+        stub.publish_model = unplugged
+        for ep in _stream(3):
+            srv._process_one(ep)  # no publisher thread: the sync path
+        srv._pipeline_quiesce()
+        assert srv.stats["updates"] == 1 and srv.stats["learner_errors"] == 0
+        assert srv.stats["publish_errors"] == 1

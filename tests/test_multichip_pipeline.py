@@ -251,3 +251,38 @@ class TestCheckpointQuiesce:
         assert len(flat_live) == len(flat_restored)
         for a, b in zip(flat_live, flat_restored):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_one_process_with_several_devices_trains_over_the_mesh(
+        tmp_cwd, monkeypatch):
+    """A single-process server that sees several accelerator devices
+    places state and batches on learner.mesh and runs the ORDINARY learner
+    loop — no second process needed to stop three chips idling. (Virtual
+    CPU devices never count as accelerators, so the test says which
+    devices to use.)"""
+    import jax
+
+    import relayrl_tpu.parallel as par_mod
+    import relayrl_tpu.runtime.server as srv_mod
+
+    monkeypatch.setattr(srv_mod, "make_server_transport",
+                        lambda *a, **k: StubTransport())
+    monkeypatch.setattr(par_mod, "accelerator_devices",
+                        lambda: jax.devices()[:4])
+    path = tmp_cwd / "cfg.json"
+    path.write_text(json.dumps({"learner": {"checkpoint_dir": ""}}))
+    server = srv_mod.TrainingServer(
+        "REINFORCE", obs_dim=OBS_DIM, act_dim=ACT_DIM, env_dir=str(tmp_cwd),
+        config_path=str(path), start=False,
+        hyperparams={"traj_per_epoch": 4, "hidden_sizes": [16],
+                     "with_vf_baseline": False, "seed_salt": 0})
+    assert not server.distributed_info["multi_host"]
+    assert dict(server.mesh.shape)["dp"] == 4
+    leaves = jax.tree_util.tree_leaves(server.algorithm.state.params)
+    assert {len(x.sharding.device_set) for x in leaves} == {4}
+    for ep in _stream(4):
+        server._process_one(ep)
+    server._pipeline_quiesce()
+    assert server.stats["updates"] == 1
+    assert server.stats["learner_errors"] == 0
+    assert server.algorithm.version == 1

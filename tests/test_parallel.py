@@ -145,52 +145,24 @@ def test_reinforce_state_places_on_mesh(tmp_cwd):
     assert all(len(l.devices()) == 8 for l in leaves if hasattr(l, "devices"))
 
 
-class TestShardMapCompat:
-    """The shard_map surface regression net: every parallel/ module must
-    import against the installed JAX (the compat resolver is the one
-    place allowed to touch the moving raw API), and a shard_mapped
-    program must build and run on a trivial mesh — the exact failure
-    mode the pre-migration tree had (21 tests dead on
-    ``jax.shard_map`` AttributeError) can never come back silently."""
+def test_virtual_cpu_devices_are_never_meshed_by_default():
+    # conftest gives this process 8 virtual CPU devices; a single-process
+    # server must not spread over them on its own (parallel/mesh.py).
+    from relayrl_tpu.parallel import accelerator_devices
 
-    def test_every_parallel_module_imports(self):
-        import importlib
-        import pkgutil
+    assert len(jax.devices()) == 8 and accelerator_devices() == []
 
-        import relayrl_tpu.parallel as pkg
 
-        names = [m.name for m in pkgutil.iter_modules(pkg.__path__)]
-        assert "compat" in names and "ring_flash" in names
-        for name in names:
-            importlib.import_module(f"relayrl_tpu.parallel.{name}")
+def test_dryrun_refuses_a_backend_with_too_few_devices():
+    # Never a quiet switch to some other mesh: too few devices is an error
+    # naming what was found.
+    import importlib.util
+    import os
 
-    def test_compat_reports_a_real_surface(self):
-        from relayrl_tpu.parallel.compat import shard_map_impl_name
-
-        assert shard_map_impl_name() in (
-            "jax.shard_map", "jax.experimental.shard_map.shard_map")
-
-    def test_shard_mapped_program_builds_on_single_device_mesh(self):
-        from relayrl_tpu.parallel.compat import shard_map
-        from relayrl_tpu.parallel.mesh import single_device_mesh
-
-        mesh = single_device_mesh()
-        prog = shard_map(lambda x: x * 2.0, mesh=mesh,
-                         in_specs=P(), out_specs=P(), check_vma=False)
-        out = jax.jit(prog)(jnp.arange(4, dtype=jnp.float32))
-        np.testing.assert_array_equal(np.asarray(out),
-                                      [0.0, 2.0, 4.0, 6.0])
-
-    def test_decorator_form(self):
-        from relayrl_tpu.parallel.compat import shard_map
-        from relayrl_tpu.parallel.mesh import single_device_mesh
-
-        mesh = single_device_mesh()
-
-        @shard_map(mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_vma=False)
-        def double(x):
-            return x + x
-
-        np.testing.assert_array_equal(
-            np.asarray(double(jnp.ones(3))), [2.0, 2.0, 2.0])
+    spec = importlib.util.spec_from_file_location(
+        "graft_entry", os.path.join(os.path.dirname(__file__), "..",
+                                    "__graft_entry__.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with pytest.raises(RuntimeError, match="need 64 devices, have 8 cpu"):
+        mod._ensure_devices(64)
