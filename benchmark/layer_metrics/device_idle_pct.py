@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device
+(reduced profiler trace; averaged over the chips used)."""
+
+
+def read(run):
+    t = run.trace_reduced
+    if not t or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
