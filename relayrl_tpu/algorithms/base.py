@@ -18,8 +18,12 @@ learner subprocess dynamically imports ``{ALGO}.{ALGO}``
 from __future__ import annotations
 
 import abc
+import contextlib
+import threading
+import time
 from typing import Any, Callable, Mapping, Sequence
 
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import ModelBundle
 
@@ -99,6 +103,29 @@ class AlgorithmBase(abc.ABC):
     # until the first dispatch (or after a checkpoint restore) — it
     # re-syncs from the (then resolved) device step before dispatching.
     _dispatched_updates = None
+    # The dispatching thread and its CPU clock at its previous dispatch
+    # (``_dispatch_span``).
+    _cpu_mark = None
+
+    @contextlib.contextmanager
+    def _dispatch_span(self, updates: int = 1):
+        """The once-per-dispatch ``host:dispatch`` span. Its arguments are
+        what only the program knows at this instant: the ``version`` the
+        dispatch produces, ``mono_ns`` (its own start stamp: the shift
+        from CLOCK_MONOTONIC to the profiler's clock) and ``cycle_cpu_ns``,
+        this thread's CPU time since its previous dispatch — against the
+        wall time between the two it says how long the thread was off the
+        CPU (blocked, or runnable and not running)."""
+        cpu_ns = time.thread_time_ns()
+        ident = threading.get_ident()
+        mark = self._cpu_mark
+        cycle = cpu_ns - mark[1] if mark and mark[0] == ident else 0
+        self._cpu_mark = (ident, cpu_ns)
+        with span("host:dispatch",
+                  version=self._dispatched_updates + updates,
+                  cycle_cpu_ns=cycle) as sp:
+            sp.note(mono_ns=sp.t0_ns)
+            yield
 
     def _drop_nonfinite(self) -> None:
         """Count + log one trajectory rejected by the finite-value guard —
@@ -355,10 +382,11 @@ class AlgorithmBase(abc.ABC):
         mesh placement (``_place``) already owns multihost batches."""
         import jax
 
-        place = getattr(self, "_place", None)
-        if place is not None:
-            return place(dict(host_batch))
-        return jax.device_put(dict(host_batch))
+        with span("host:stage_batch"):
+            place = getattr(self, "_place", None)
+            if place is not None:
+                return place(dict(host_batch))
+            return jax.device_put(dict(host_batch))
 
     def _to_device(self, host_batch) -> dict:
         """The single owner of host-batch → device-batch placement

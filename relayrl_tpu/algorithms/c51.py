@@ -68,7 +68,7 @@ def make_c51_update(module: DistributionalQNet, support: jax.Array,
                     gamma: float, lr: float, polyak: float):
     tx = optax.adam(lr)
 
-    def update(state: C51State, batch):
+    def c51_update(state: C51State, batch):
         obs, act, rew = batch["obs"], batch["act"], batch["rew"]
         obs2, mask2, done = batch["obs2"], batch["mask2"], batch["done"]
 
@@ -99,7 +99,7 @@ def make_c51_update(module: DistributionalQNet, support: jax.Array,
         return C51State(params=params, target_params=target_params,
                         opt_state=opt_state, step=state.step + 1), metrics
 
-    return update
+    return c51_update
 
 
 @register_algorithm("C51")

@@ -40,7 +40,7 @@ def make_ddpg_update(actor: DeterministicActor, critic: QValueNet,
     actor_tx = optax.adam(actor_lr)
     critic_tx = optax.adam(critic_lr)
 
-    def update(state: DDPGState, batch):
+    def ddpg_update(state: DDPGState, batch):
         obs, act, rew = batch["obs"], batch["act"], batch["rew"]
         obs2, done = batch["obs2"], batch["done"]
 
@@ -80,7 +80,7 @@ def make_ddpg_update(actor: DeterministicActor, critic: QValueNet,
             step=state.step + 1,
         ), metrics
 
-    return update
+    return ddpg_update
 
 
 @register_algorithm("DDPG")

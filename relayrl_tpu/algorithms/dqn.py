@@ -40,7 +40,7 @@ def make_dqn_update(module: DiscreteQNet, gamma: float, lr: float,
                     polyak: float, double_q: bool):
     tx = optax.adam(lr)
 
-    def update(state: DQNState, batch):
+    def dqn_update(state: DQNState, batch):
         obs, act, rew = batch["obs"], batch["act"], batch["rew"]
         obs2, mask2, done = batch["obs2"], batch["mask2"], batch["done"]
 
@@ -70,7 +70,7 @@ def make_dqn_update(module: DiscreteQNet, gamma: float, lr: float,
         return DQNState(params=params, target_params=target_params,
                         opt_state=opt_state, step=state.step + 1), metrics
 
-    return update
+    return dqn_update
 
 
 @register_algorithm("DQN")

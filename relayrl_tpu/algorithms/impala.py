@@ -65,7 +65,7 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
                        params_template=None):
     tx = make_impala_tx(lr, max_grad_norm, freeze, params_template)
 
-    def update(state: ImpalaState, batch: Mapping[str, jax.Array]):
+    def impala_update(state: ImpalaState, batch: Mapping[str, jax.Array]):
         obs, act, act_mask = batch["obs"], batch["act"], batch["act_mask"]
         rew, valid = batch["rew"], batch["valid"]
         behavior_logp = batch["logp"]
@@ -101,7 +101,7 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
         return ImpalaState(params=params, opt_state=opt_state, rng=state.rng,
                            step=state.step + 1), metrics
 
-    return update
+    return impala_update
 
 
 @register_algorithm("IMPALA")

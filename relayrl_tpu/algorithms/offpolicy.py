@@ -27,6 +27,7 @@ import optax
 from relayrl_tpu.algorithms.base import AlgorithmBase, anchor_path
 from relayrl_tpu.config import ConfigLoader
 from relayrl_tpu.data.step_buffer import StepReplayBuffer
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import ModelBundle
 from relayrl_tpu.utils import EpochLogger, setup_logger_kwargs
@@ -227,18 +228,21 @@ class OffPolicyAlgorithm(AlgorithmBase):
                       else np.stack([np.asarray(b[key]) for b in chunk]))
                 for key in chunk[0]}
             self._sync_version_mirror()
-            probe_base = self._guard_pre_update()
-            self.state, ms = self._fused_update()(
-                self.state, self._to_device(stacked))
-            self._dispatched_updates += k
-            # Per-row device slices dispatch lazily — no host readback on
-            # the dispatch path; resolution happens where the values are
-            # read (log_epoch / a test's _last_metrics access). Probes
-            # cover the whole fused dispatch (the k-th update's params).
-            self._last_metrics = LazyMetrics(self._guard_merge_probes(
-                {key: v[-1] for key, v in ms.items()}, probe_base))
-            self.inflight.push((ms, self._last_metrics.device),
-                               version=self.dispatched_version)
+            with self._dispatch_span(k):
+                probe_base = self._guard_pre_update()
+                with span("rl:dispatch.enqueue"):
+                    self.state, ms = self._fused_update()(
+                        self.state, self._to_device(stacked))
+                self._dispatched_updates += k
+                # Per-row device slices dispatch lazily — no host readback
+                # on the dispatch path; resolution happens where the values
+                # are read (log_epoch / a test's _last_metrics access).
+                # Probes cover the whole fused dispatch (the k-th update's
+                # params).
+                self._last_metrics = LazyMetrics(self._guard_merge_probes(
+                    {key: v[-1] for key, v in ms.items()}, probe_base))
+                self.inflight.push((ms, self._last_metrics.device),
+                                   version=self.dispatched_version)
             i += k
         for b in host_batches[i:]:
             self.train_on_batch(b)
@@ -254,13 +258,15 @@ class OffPolicyAlgorithm(AlgorithmBase):
         from relayrl_tpu.runtime.pipeline import LazyMetrics
 
         self._sync_version_mirror()
-        probe_base = self._guard_pre_update()
-        self.state, metrics = self._update(self.state,
-                                           self._to_device(host_batch))
-        self._dispatched_updates += 1
-        metrics = self._guard_merge_probes(metrics, probe_base)
-        self._last_metrics = LazyMetrics(metrics)
-        self.inflight.push(metrics, version=self.dispatched_version)
+        with self._dispatch_span():
+            probe_base = self._guard_pre_update()
+            with span("rl:dispatch.enqueue"):
+                self.state, metrics = self._update(
+                    self.state, self._to_device(host_batch))
+            self._dispatched_updates += 1
+            metrics = self._guard_merge_probes(metrics, probe_base)
+            self._last_metrics = LazyMetrics(metrics)
+            self.inflight.push(metrics, version=self.dispatched_version)
         # No logger.store here (the old per-update rows were never
         # consumed: log_epoch passes explicit values to log_tabular, so
         # the stored lists only grew for the life of the process — and as
@@ -296,31 +302,33 @@ class OffPolicyAlgorithm(AlgorithmBase):
             trajectory_is_finite,
         )
 
-        if isinstance(item, DecodedTrajectory):
-            if item.n_steps == 0:
+        with span("host:accumulate"):
+            if isinstance(item, DecodedTrajectory):
+                if item.n_steps == 0:
+                    return None
+                rew_total = item.total_reward
+            elif not item or all(a.act is None for a in item):
                 return None
-            rew_total = item.total_reward
-        elif not item or all(a.act is None for a in item):
-            return None
-        else:
-            rew_total = float(sum(a.rew for a in item))
-        if self.ingest_finite_guard and not trajectory_is_finite(item):
-            # Replay poisoning is worse than the on-policy case — a
-            # non-finite transition keeps resampling forever.
-            self._drop_nonfinite()
-            return None
-        stored = self.buffer.add_episode(item)
-        self._ep_returns.append(rew_total)
-        self._ep_lengths.append(stored)
-        self._traj_since_log += 1
-        if (self.updates_per_step <= 0
-                or self.buffer.total_steps < self.update_after
-                or stored == 0):
-            return None
-        self._update_debt += stored * self.updates_per_step
-        n = min(self.max_updates_per_ingest, max(1, int(self._update_debt)))
-        self._update_debt = max(0.0, self._update_debt - n)
-        return [self._sample_staged(n) for _ in range(n)]
+            else:
+                rew_total = float(sum(a.rew for a in item))
+            if self.ingest_finite_guard and not trajectory_is_finite(item):
+                # Replay poisoning is worse than the on-policy case — a
+                # non-finite transition keeps resampling forever.
+                self._drop_nonfinite()
+                return None
+            stored = self.buffer.add_episode(item)
+            self._ep_returns.append(rew_total)
+            self._ep_lengths.append(stored)
+            self._traj_since_log += 1
+            if (self.updates_per_step <= 0
+                    or self.buffer.total_steps < self.update_after
+                    or stored == 0):
+                return None
+            self._update_debt += stored * self.updates_per_step
+            n = min(self.max_updates_per_ingest,
+                    max(1, int(self._update_debt)))
+            self._update_debt = max(0.0, self._update_debt - n)
+            return [self._sample_staged(n) for _ in range(n)]
 
     def _sample_staged(self, round_size: int) -> dict:
         """One sampled batch written into a reusable staging slot (no

@@ -22,6 +22,7 @@ import numpy as np
 from relayrl_tpu.algorithms.base import AlgorithmBase, anchor_path
 from relayrl_tpu.config import ConfigLoader
 from relayrl_tpu.data import EpochBuffer
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import ModelBundle
 from relayrl_tpu.utils import EpochLogger, setup_logger_kwargs
@@ -169,19 +170,20 @@ class OnPolicyAlgorithm(AlgorithmBase):
             trajectory_is_finite,
         )
 
-        if isinstance(item, DecodedTrajectory):
-            if item.n_steps == 0:
+        with span("host:accumulate"):
+            if isinstance(item, DecodedTrajectory):
+                if item.n_steps == 0:
+                    return None
+            elif not item or all(a.act is None for a in item):
+                # Marker-only trajectories (stranded by a capacity flush)
+                # carry no steps; padding would raise on the empty fold.
                 return None
-        elif not item or all(a.act is None for a in item):
-            # Marker-only trajectories (stranded by a capacity flush)
-            # carry no steps; padding would raise on the empty fold.
+            if self.ingest_finite_guard and not trajectory_is_finite(item):
+                self._drop_nonfinite()
+                return None
+            if self.buffer.add_episode(item):
+                return self.buffer.drain().as_dict()
             return None
-        if self.ingest_finite_guard and not trajectory_is_finite(item):
-            self._drop_nonfinite()
-            return None
-        if self.buffer.add_episode(item):
-            return self.buffer.drain().as_dict()
-        return None
 
     def train_on_batch(self, host_batch: Mapping[str, Any]) -> Mapping[str, float]:
         """One jitted update on an assembled batch dict (host or device
@@ -194,15 +196,17 @@ class OnPolicyAlgorithm(AlgorithmBase):
         from relayrl_tpu.runtime.pipeline import LazyMetrics
 
         self._sync_version_mirror()
-        # Health-probe base copy BEFORE the donating update (guardrails
-        # plane; None without probes) — see base._guard_pre_update.
-        probe_base = self._guard_pre_update()
-        self.state, metrics = self._update(self.state,
-                                           self._to_device(host_batch))
-        self._dispatched_updates += 1
-        metrics = self._guard_merge_probes(metrics, probe_base)
-        self._last_metrics = LazyMetrics(metrics)
-        self.inflight.push(metrics, version=self.dispatched_version)
+        with self._dispatch_span():
+            # Health-probe base copy BEFORE the donating update (guardrails
+            # plane; None without probes) — see base._guard_pre_update.
+            probe_base = self._guard_pre_update()
+            with span("rl:dispatch.enqueue"):
+                self.state, metrics = self._update(
+                    self.state, self._to_device(host_batch))
+            self._dispatched_updates += 1
+            metrics = self._guard_merge_probes(metrics, probe_base)
+            self._last_metrics = LazyMetrics(metrics)
+            self.inflight.push(metrics, version=self.dispatched_version)
         return self._last_metrics
 
     def train_model(self) -> Mapping[str, float]:

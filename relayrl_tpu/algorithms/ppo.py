@@ -59,7 +59,7 @@ def make_ppo_update(
 ):
     """Build the pure ``(state, batch) -> (state, metrics)`` epoch update."""
 
-    def update(state: PPOState, batch: Mapping[str, jax.Array]):
+    def ppo_update(state: PPOState, batch: Mapping[str, jax.Array]):
         tx_pi, tx_vf = make_optimizers(state.params, pi_lr, vf_lr, freeze)
         obs, act, act_mask = batch["obs"], batch["act"], batch["act_mask"]
         rew, val, valid = batch["rew"], batch["val"], batch["valid"]
@@ -155,7 +155,7 @@ def make_ppo_update(
                              step=state.step + 1)
         return new_state, metrics
 
-    return update
+    return ppo_update
 
 
 @register_algorithm("PPO")

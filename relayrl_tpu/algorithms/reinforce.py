@@ -87,7 +87,7 @@ def make_reinforce_update(policy, pi_lr: float, vf_lr: float,
                           with_baseline: bool, freeze=()):
     """Build the pure (state, batch) -> (state, metrics) epoch update."""
 
-    def update(state: ReinforceState, batch: Mapping[str, jax.Array]):
+    def reinforce_update(state: ReinforceState, batch: Mapping[str, jax.Array]):
         tx_pi, tx_vf = make_optimizers(state.params, pi_lr, vf_lr, freeze)
         obs, act, act_mask = batch["obs"], batch["act"], batch["act_mask"]
         rew, val, valid = batch["rew"], batch["val"], batch["valid"]
@@ -167,7 +167,7 @@ def make_reinforce_update(policy, pi_lr: float, vf_lr: float,
         )
         return new_state, metrics
 
-    return update
+    return reinforce_update
 
 
 @register_algorithm("REINFORCE")

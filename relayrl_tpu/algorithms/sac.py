@@ -48,7 +48,7 @@ def make_sac_update(actor: SquashedGaussianActor, critic: TwinQNet,
     critic_tx = optax.adam(critic_lr)
     alpha_tx = optax.adam(alpha_lr)
 
-    def update(state: SACState, batch):
+    def sac_update(state: SACState, batch):
         obs, act, rew = batch["obs"], batch["act"], batch["rew"]
         obs2, done = batch["obs2"], batch["done"]
         rng, a2_rng, pi_rng = jax.random.split(state.rng, 3)
@@ -120,7 +120,7 @@ def make_sac_update(actor: SquashedGaussianActor, critic: TwinQNet,
             step=state.step + 1,
         ), metrics
 
-    return update
+    return sac_update
 
 
 @register_algorithm("SAC")

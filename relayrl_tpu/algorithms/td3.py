@@ -43,7 +43,7 @@ def make_td3_update(actor: DeterministicActor, critic: TwinQNet,
     actor_tx = optax.adam(actor_lr)
     critic_tx = optax.adam(critic_lr)
 
-    def update(state: TD3State, batch):
+    def td3_update(state: TD3State, batch):
         obs, act, rew = batch["obs"], batch["act"], batch["rew"]
         obs2, done = batch["obs2"], batch["done"]
         rng, noise_rng = jax.random.split(state.rng)
@@ -109,7 +109,7 @@ def make_td3_update(actor: DeterministicActor, critic: TwinQNet,
             step=state.step + 1,
         ), metrics
 
-    return update
+    return td3_update
 
 
 @register_algorithm("TD3")

@@ -218,6 +218,7 @@ def pad_decoded(
     obs_dim: int,
     act_dim: int,
     discrete: bool = True,
+    out: PaddedTrajectory | None = None,
 ) -> PaddedTrajectory:
     """Columnar fast path of :func:`pad_trajectory`.
 
@@ -226,6 +227,10 @@ def pad_decoded(
     vectorized slice assignment — no per-step Python loop. Semantics are
     kept identical to the ActionRecord path (tests/test_native_codec.py
     asserts byte equality of the padded outputs across both paths).
+
+    ``out`` is a padded trajectory of the same horizon and widths that
+    nothing reads any more: its arrays are written over and returned in a
+    new :class:`PaddedTrajectory`, equal to what a fresh call gives.
     """
     cols, aux = dt.columns, dt.aux
     total = dt.n_steps
@@ -233,36 +238,38 @@ def pad_decoded(
         raise ValueError("trajectory contained only terminal markers"
                          if dt.n_records else "empty trajectory")
     n = min(total, horizon)
-
-    obs = np.zeros((horizon, obs_dim), dtype=np.float32)
+    if out is None:
+        obs = np.zeros((horizon, obs_dim), dtype=np.float32)
+        act = (np.zeros((horizon,), dtype=np.int32) if discrete
+               else np.zeros((horizon, act_dim), dtype=np.float32))
+        act_mask = np.zeros((horizon, act_dim), dtype=np.float32)
+        rew, val, logp, valid = (np.zeros((horizon,), dtype=np.float32)
+                                 for _ in range(4))
+    else:
+        obs, act, act_mask = out.obs, out.act, out.act_mask
+        rew, val, logp, valid = out.rew, out.val, out.logp, out.valid
+        for arr in (obs, act, act_mask, rew, val, logp, valid):
+            arr[n:] = 0  # rows [:n] are each assigned below
     if "o" in cols:
         flat = cols["o"].reshape(total, -1)
         if flat.shape[1] < obs_dim:
             raise ValueError(
                 f"obs has {flat.shape[1]} features, expected >= {obs_dim}")
         obs[:n] = flat[:n, :obs_dim]
-    if discrete:
-        act = np.zeros((horizon,), dtype=np.int32)
-        if "a" in cols:
-            act[:n] = cols["a"].reshape(total, -1)[:n, 0]
     else:
-        act = np.zeros((horizon, act_dim), dtype=np.float32)
-        if "a" in cols:
-            act[:n] = cols["a"].reshape(total, -1)[:n, :act_dim]
-    act_mask = np.zeros((horizon, act_dim), dtype=np.float32)
-    if "m" in cols:
-        act_mask[:n] = cols["m"].reshape(total, -1)[:n, :act_dim]
+        obs[:n] = 0
+    if "a" not in cols:
+        act[:n] = 0
+    elif discrete:
+        act[:n] = cols["a"].reshape(total, -1)[:n, 0]
     else:
-        act_mask[:n] = 1.0
-    rew = np.zeros((horizon,), dtype=np.float32)
+        act[:n] = cols["a"].reshape(total, -1)[:n, :act_dim]
+    act_mask[:n] = (cols["m"].reshape(total, -1)[:n, :act_dim]
+                    if "m" in cols else 1.0)
     rew[:n] = cols["r"][:n]
-    val = np.zeros((horizon,), dtype=np.float32)
-    if "v" in aux:
-        val[:n] = aux["v"].reshape(total, -1)[:n, 0]
-    logp = np.zeros((horizon,), dtype=np.float32)
-    if "logp_a" in aux:
-        logp[:n] = aux["logp_a"].reshape(total, -1)[:n, 0]
-    valid = np.zeros((horizon,), dtype=np.float32)
+    val[:n] = aux["v"].reshape(total, -1)[:n, 0] if "v" in aux else 0
+    logp[:n] = (aux["logp_a"].reshape(total, -1)[:n, 0]
+                if "logp_a" in aux else 0)
     valid[:n] = 1.0
 
     done = cols["t"]

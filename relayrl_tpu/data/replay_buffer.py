@@ -23,6 +23,7 @@ from relayrl_tpu.data.batching import (
     repad_trajectory,
     stack_trajectories,
 )
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.columnar import DecodedTrajectory
 
@@ -72,6 +73,9 @@ class EpochBuffer:
                                       self.act_dim, self.discrete)
                          if staging_slots else None)
         self._pending: list[PaddedTrajectory] = []
+        # Drained episodes by horizon, their arrays free to be written over
+        # by the next episodes padded to that horizon (see add_episode).
+        self._spare: dict[int, list[PaddedTrajectory]] = {}
         self.episode_returns: list[float] = []
         self.episode_lengths: list[int] = []
 
@@ -97,13 +101,22 @@ class EpochBuffer:
         ``len()`` of both is the raw record count, so bucketing is
         identical across paths."""
         bucket = pick_bucket(len(actions), self.buckets)
-        if isinstance(actions, DecodedTrajectory):
-            padded = pad_decoded(
-                actions, bucket, self.obs_dim, self.act_dim, self.discrete)
-        else:
-            padded = pad_trajectory(
-                actions, bucket, self.obs_dim, self.act_dim, self.discrete
-            )
+        with span("rl:batch.pad"):
+            if isinstance(actions, DecodedTrajectory):
+                # Into the arrays of an episode already drained, when there
+                # is one: a fresh [T, obs_dim] float32 array per episode is
+                # fresh pages per episode whenever the allocator has handed
+                # the last batch's back to the OS, and first touch costs
+                # ten times the copy (2.26 ms against 0.24 ms an Atari
+                # unroll; which of the two a process got was chance:
+                # PERF.md, PR 24).
+                spare = self._spare.get(bucket)
+                padded = pad_decoded(actions, bucket, self.obs_dim,
+                                     self.act_dim, self.discrete,
+                                     out=spare.pop() if spare else None)
+            else:
+                padded = pad_trajectory(actions, bucket, self.obs_dim,
+                                        self.act_dim, self.discrete)
         self._pending.append(padded)
         self.episode_returns.append(float(padded.rew.sum()))
         self.episode_lengths.append(padded.length)
@@ -125,10 +138,24 @@ class EpochBuffer:
         take = self._pending[: self.traj_per_epoch]
         self._pending = self._pending[self.traj_per_epoch:]
         horizon = max(t.obs.shape[0] for t in take)
-        if self._staging is not None:
-            return stack_trajectories(
-                take, out=self._staging.acquire(len(take), horizon))
-        return stack_trajectories([repad_trajectory(t, horizon) for t in take])
+        # Host numbers only, from the padded episodes' own lengths and
+        # shapes: a counter never reads a device array.
+        with span("rl:batch.stack", valid=sum(t.length for t in take),
+                  padded=len(take) * horizon) as sp:
+            if self._staging is not None:
+                batch = stack_trajectories(
+                    take, out=self._staging.acquire(len(take), horizon))
+            else:
+                batch = stack_trajectories(
+                    [repad_trajectory(t, horizon) for t in take])
+            sp.note(bytes=sum(v.nbytes for v in batch.as_dict().values()))
+        # The batch is a copy (slab or np.stack): the episodes' own arrays
+        # are free again. One batch's worth a horizon is kept.
+        for t in take:
+            spare = self._spare.setdefault(t.obs.shape[0], [])
+            if len(spare) < self.traj_per_epoch:
+                spare.append(t)
+        return batch
 
     def pop_episode_stats(self) -> tuple[list[float], list[int]]:
         rets, lens = self.episode_returns, self.episode_lengths
@@ -140,5 +167,6 @@ class EpochBuffer:
         rollback path: episodes buffered on a rolled-back line of
         history must not leak into the restored line's first epoch."""
         self._pending.clear()
+        self._spare.clear()
         self.episode_returns.clear()
         self.episode_lengths.clear()

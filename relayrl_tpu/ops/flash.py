@@ -62,6 +62,13 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
 
+# The three kernels' names in a profiler trace and in the lowered program:
+# each ``pallas_call`` carries its name and sits in a ``jax.named_scope`` of
+# the same name, so a reduction finds it whatever flax scope called it.
+FWD_NAME = "relayrl_flash_fwd"
+DQ_NAME = "relayrl_flash_dq"
+DKV_NAME = "relayrl_flash_dkv"
+
 
 def _masked_scores2(q_ref, k_ref, q_start, k_start, masked: bool,
                     block_q: int, block_kv: int):
@@ -158,8 +165,9 @@ def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
 
     def call(qr, kr, vr):
         bh = qr.shape[0]
-        return pl.pallas_call(
+        fwd = pl.pallas_call(
             kernel,
+            name=FWD_NAME,
             grid=(bh,) + grid[1:],
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -183,7 +191,9 @@ def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
             interpret=interpret,
-        )(qr, kr, vr)
+        )
+        with jax.named_scope(FWD_NAME):
+            return fwd(qr, kr, vr)
 
     return call
 
@@ -306,8 +316,9 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
 
     def call(qr, kr, vr, dor, lse, delta):
         bh = qr.shape[0]
-        dq = pl.pallas_call(
+        dq_call = pl.pallas_call(
             dq_kernel,
+            name=DQ_NAME,
             grid=(bh, T // block_q, T // block_kv),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -321,9 +332,12 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
             out_shape=jax.ShapeDtypeStruct((bh, T, D), dtype),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
             interpret=interpret,
-        )(qr, kr, vr, dor, lse, delta)
-        dk, dv = pl.pallas_call(
+        )
+        with jax.named_scope(DQ_NAME):
+            dq = dq_call(qr, kr, vr, dor, lse, delta)
+        dkv_call = pl.pallas_call(
             dkv_kernel,
+            name=DKV_NAME,
             grid=(bh, T // block_kv, T // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
@@ -346,7 +360,9 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                 pltpu.VMEM((block_kv, D), jnp.float32),
             ],
             interpret=interpret,
-        )(qr, kr, vr, dor, lse, delta)
+        )
+        with jax.named_scope(DKV_NAME):
+            dk, dv = dkv_call(qr, kr, vr, dor, lse, delta)
         return dq, dk, dv
 
     return call

@@ -46,6 +46,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Iterator, Mapping
 
+from relayrl_tpu.telemetry.spans import span
+
 
 class LazyMetrics(Mapping):
     """Mapping view over a dict of device scalars that resolves to host
@@ -142,25 +144,18 @@ class InflightWindow:
         import jax
 
         fences, version = self._entries.popleft()
-        t0 = time.monotonic()
-        t0_ns = 0
-        if version is not None:
-            from relayrl_tpu.telemetry import trace as trace_mod
+        with span("rl:dispatch.fence", metric=self._m_device_wait,
+                  version=-1 if version is None else int(version)) as sp:
+            if version is not None:
+                from relayrl_tpu.telemetry import trace as trace_mod
 
-            tracer = trace_mod.get_tracer()
-            if tracer.enabled and tracer.sample_version(version):
-                t0_ns = time.monotonic_ns()
-        jax.block_until_ready(fences)
-        dt = time.monotonic() - t0
-        if t0_ns:
-            from relayrl_tpu.telemetry import trace as trace_mod
-
-            trace_mod.get_tracer().span(
-                "model", trace_mod.model_trace_id(version), "fence",
-                t0_ns, time.monotonic_ns(), version=int(version))
-        self.device_wait_s += dt
+                tracer = trace_mod.get_tracer()
+                if tracer.enabled and tracer.sample_version(version):
+                    sp.hop("model", trace_mod.model_trace_id(version),
+                           "fence", version=int(version))
+            jax.block_until_ready(fences)
+        self.device_wait_s += sp.seconds
         self.fenced_count += 1
-        self._m_device_wait.observe(dt)
         self._m_pending.set(len(self._entries))
 
 
@@ -287,9 +282,11 @@ class ModelPublisher:
                     return
                 snapshot, self._slot = self._slot, None
                 self._busy = True
-            t0 = time.monotonic()
+            sp = span("rl:publish", metric=self._m_publish,
+                      version=int(getattr(snapshot, "version", -1)))
             try:
-                self._publish_fn(snapshot)
+                with sp:
+                    self._publish_fn(snapshot)
                 self.published += 1
                 self._m_published.inc()
             except Exception as e:  # a transient socket/fs error must not
@@ -297,9 +294,7 @@ class ModelPublisher:
                 self._m_errors.inc()
                 print(f"[ModelPublisher] publish error: {e!r}", flush=True)
             finally:
-                dt = time.monotonic() - t0
-                self.publish_s += dt
-                self._m_publish.observe(dt)
+                self.publish_s += sp.seconds
                 with self._cond:
                     self._busy = False
                     self._cond.notify_all()

@@ -34,6 +34,7 @@ from typing import Any, Mapping
 from relayrl_tpu.algorithms import build_algorithm, registered_algorithms
 from relayrl_tpu.config import ConfigLoader
 from relayrl_tpu.telemetry.aggregate import is_snapshot_frame
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.transport import make_server_transport
 from relayrl_tpu.telemetry.trace import split_ctx as _split_trace_ctx
 from relayrl_tpu.transport.base import (
@@ -76,6 +77,22 @@ class _EventCoalescer:
         if due:
             self._last = time.monotonic()
         return due
+
+
+class _StampedQueue(queue.Queue):
+    """A ``Queue`` that stamps every item as it is put (CLOCK_MONOTONIC
+    ns). After a ``get`` its one consuming thread reads the stamp of the
+    item it got from ``got_put_ns``; against the instant the ``get``
+    returned it is how long the item waited in the queue."""
+
+    got_put_ns = 0
+
+    def _put(self, item):
+        self.queue.append((time.monotonic_ns(), item))
+
+    def _get(self):
+        self.got_put_ns, item = self.queue.popleft()
+        return item
 
 
 class _TracedRecords(list):
@@ -400,7 +417,7 @@ class TrainingServer:
         # them (native codec when built) into _decoded, which the learner
         # thread drains — decode overlaps the device step.
         self._ingest: queue.Queue[tuple[str, bytes]] = queue.Queue(maxsize=100_000)
-        self._decoded: queue.Queue = queue.Queue(maxsize=100_000)
+        self._decoded: queue.Queue = _StampedQueue(maxsize=100_000)
         # Pull-gauges: depth is read from the live queues only when an
         # export actually renders — zero hot-path cost. Sources hold a
         # WEAK reference to this server: the registry is process-global,
@@ -597,6 +614,8 @@ class TrainingServer:
         #                 that land there); superseded by the split above
         #   learner_idle_s learner thread blocked on an empty queue
         #   warmup_s      learner thread pre-compiling update shapes
+        # Every total is fed by the span round its site (telemetry/spans.py;
+        # docs/observability.md has the table).
         self.timings = {"decode_s": 0.0, "learn_s": 0.0, "dispatch_s": 0.0,
                         "device_wait_s": 0.0, "publish_s": 0.0,
                         "learner_idle_s": 0.0, "warmup_s": 0.0}
@@ -1132,81 +1151,79 @@ class TrainingServer:
                 guard.admission.note_dequeued(agent_id)
             item = None
             columnar = False
-            t0_ns = time.monotonic_ns() if ctx is not None else 0
-            t0 = time.monotonic()
-            try:
-                if is_columnar_frame(payload):
-                    # Columnar wire fast path (anakin actors): the frame
-                    # IS the folded column layout — a CRC check plus a
-                    # handful of np.frombuffer views, no msgpack, no
-                    # per-step objects, on every transport.
-                    columnar = True
-                    item = parse_frame(payload, agent_id=agent_id)
-                    self._m_columnar_frames.inc()
-                    self._m_columnar_bytes.inc(len(payload))
-                elif batch_kind(payload) == BATCH_KIND_FRAMES:
-                    # Coalesced columnar segments (actor.emit_coalesce_
-                    # frames / relay batch-forward): one spooled send —
-                    # one seq, one envelope — carrying N frames of ONE
-                    # logical lane; decode each and hand the learner the
-                    # list (the native drain's batch shape).
-                    columnar = True
-                    parts = split_batch(payload)
-                    item = [parse_frame(p, agent_id=agent_id)
-                            for p in parts]
-                    self._m_columnar_frames.inc(len(parts))
-                    self._m_columnar_bytes.inc(len(payload))
-                elif decoder is not None:
-                    # off-GIL msgpack -> columns; falls back to the Python
-                    # decoder only for payloads the columnar schema can't
-                    # represent
-                    item = decoder.decode(payload, agent_id=agent_id)
-                    if isinstance(item, RawTrajectory):
-                        raw = item.payload
-                        if item.is_envelope:
-                            from relayrl_tpu.transport.base import (
-                                unpack_trajectory_envelope,
-                            )
+            with span("rl:ingest.decode", metric=self._m_decode) as sp:
+                try:
+                    if is_columnar_frame(payload):
+                        # Columnar wire fast path (anakin actors): the
+                        # frame IS the folded column layout — a CRC check
+                        # plus a handful of np.frombuffer views, no
+                        # msgpack, no per-step objects, on every transport.
+                        columnar = True
+                        item = parse_frame(payload, agent_id=agent_id)
+                        self._m_columnar_frames.inc()
+                        self._m_columnar_bytes.inc(len(payload))
+                    elif batch_kind(payload) == BATCH_KIND_FRAMES:
+                        # Coalesced columnar segments (actor.emit_
+                        # coalesce_frames / relay batch-forward): one
+                        # spooled send — one seq, one envelope — carrying N
+                        # frames of ONE logical lane; decode each and hand
+                        # the learner the list (the native drain's batch
+                        # shape).
+                        columnar = True
+                        parts = split_batch(payload)
+                        item = [parse_frame(p, agent_id=agent_id)
+                                for p in parts]
+                        self._m_columnar_frames.inc(len(parts))
+                        self._m_columnar_bytes.inc(len(payload))
+                    elif decoder is not None:
+                        # off-GIL msgpack -> columns; falls back to the
+                        # Python decoder only for payloads the columnar
+                        # schema can't represent
+                        item = decoder.decode(payload, agent_id=agent_id)
+                        if isinstance(item, RawTrajectory):
+                            raw = item.payload
+                            if item.is_envelope:
+                                from relayrl_tpu.transport.base import (
+                                    unpack_trajectory_envelope,
+                                )
 
-                            _, raw = unpack_trajectory_envelope(raw)
-                        item = deserialize_actions(raw)
-                else:
-                    item = deserialize_actions(payload)
-            except Exception:
-                if columnar:
-                    self._m_columnar_rejects.inc()
-                # Un-see the seq: the payload never reached the learner
-                # (CRC/parse failure), so the actor's spool replay must be
-                # able to land its retained clean copy later.
-                if seq is not None and self._ingest_ledger is not None:
-                    self._ingest_ledger.retract(agent_id, seq)
-                self._count_dropped()
-            if item is not None and guard is not None:
-                # Ingest validation + per-agent strike accounting: the
-                # semantic trust boundary, BEFORE the decoded item can
-                # reach the staging slabs. None = rejected (counted,
-                # struck; the poison never reaches the learner plane).
-                # Coalesced batches validate per contained trajectory —
-                # one poisoned segment must not veto its clean siblings.
-                if (isinstance(item, list) and item
-                        and isinstance(item[0], DecodedTrajectory)):
-                    item = [one for one in item
-                            if guard.validate(agent_id, one) is not None]
-                    if not item:
-                        item = None
-                else:
-                    item = guard.validate(agent_id, item)
-            dt = time.monotonic() - t0
-            self._m_decode.observe(dt)  # per-thread shard: no lock needed
+                                _, raw = unpack_trajectory_envelope(raw)
+                            item = deserialize_actions(raw)
+                    else:
+                        item = deserialize_actions(payload)
+                except Exception:
+                    if columnar:
+                        self._m_columnar_rejects.inc()
+                    # Un-see the seq: the payload never reached the
+                    # learner (CRC/parse failure), so the actor's spool
+                    # replay must be able to land its retained clean copy
+                    # later.
+                    if seq is not None and self._ingest_ledger is not None:
+                        self._ingest_ledger.retract(agent_id, seq)
+                    self._count_dropped()
+                if item is not None and guard is not None:
+                    # Ingest validation + per-agent strike accounting:
+                    # the semantic trust boundary, BEFORE the decoded item
+                    # can reach the staging slabs. None = rejected (counted,
+                    # struck; the poison never reaches the learner plane).
+                    # Coalesced batches validate per contained trajectory —
+                    # one poisoned segment must not veto its clean siblings.
+                    if (isinstance(item, list) and item
+                            and isinstance(item[0], DecodedTrajectory)):
+                        item = [one for one in item if
+                                guard.validate(agent_id, one) is not None]
+                        if not item:
+                            item = None
+                    else:
+                        item = guard.validate(agent_id, item)
+                if ctx is not None and item is not None:
+                    # staging hop (decode + validate) + context handoff:
+                    # the learner attributes the consuming update at
+                    # dispatch.
+                    sp.hop("traj", ctx.trace_id, "staging", agent=agent_id)
+                    item = _attach_trace_ctx(item, ctx)
             with self._timings_lock:  # N decode workers share the ledger
-                self.timings["decode_s"] += dt
-            if ctx is not None and item is not None:
-                # staging hop (decode + validate) + context handoff: the
-                # learner attributes the consuming update at dispatch.
-                self._get_tracer().span(
-                    "traj", ctx.trace_id, "staging", t0_ns,
-                    time.monotonic_ns(), agent=agent_id)
-                item = _attach_trace_ctx(item, ctx)
+                self.timings["decode_s"] += sp.seconds
             if item is not None:
                 try:
                     self._decoded.put_nowait(item)
@@ -1328,46 +1345,45 @@ class TrainingServer:
             self._mh_busy = True
             batch = broadcast_from_coordinator(batch)
             algo = self.algorithm
-            t0 = time.monotonic()
-            try:
-                if self._prefetch:
-                    # Eager sharded H2D (device_put with NamedSharding
-                    # via the mesh-aware _place): the transfer enqueues
-                    # now and overlaps the in-flight updates instead of
-                    # running inside the dispatch below.
-                    batch = algo.stage_batch(batch)
-                # Dispatch-only: the sharded update enters the in-flight
-                # window unfenced (its collectives live inside the XLA
-                # program, so nothing here blocks the host).
-                algo.train_on_batch(batch)
-            except Exception as e:
-                self._learner_error("multi-host update error", e)
-                self._mh_busy = False
-                continue  # symmetric on all ranks: same data, same failure
-            if (coord and self.guardrails is not None
-                    and self.guardrails.watchdog is not None
-                    and self.distributed_info["num_processes"] == 1):
-                # Health probes ride LazyMetrics through the window on
-                # every rank (they are jitted over the same sharded
-                # state). The watchdog DETECTOR stays single-process:
-                # its rollback path restores a checkpoint, which is a
-                # collective a coordinator-solo trip would hang on.
-                self.guardrails.watchdog.observe_dispatch(
-                    algo.inflight.dispatch_count, algo._last_metrics)
-            if coord:
-                self.stats["updates"] += 1
-                self._m_updates.inc()
-                # Epoch log: captured now (on-policy: one per update;
-                # off-policy: the trajectory cadence), dumped once the
-                # update it describes is fenced.
-                payload = algo.capture_epoch_stats(True)
-                if payload is not None:
-                    self._pending_logs.append(
-                        (algo.inflight.dispatch_count, payload,
-                         algo._last_metrics))
-            dispatch_dt = time.monotonic() - t0
-            self.timings["dispatch_s"] += dispatch_dt
-            self._m_dispatch.observe(dispatch_dt)
+            with span("rl:learner.dispatch", self.timings, "dispatch_s",
+                      metric=self._m_dispatch):
+                try:
+                    if self._prefetch:
+                        # Eager sharded H2D (device_put with NamedSharding
+                        # via the mesh-aware _place): the transfer enqueues
+                        # now and overlaps the in-flight updates instead of
+                        # running inside the dispatch below.
+                        batch = algo.stage_batch(batch)
+                    # Dispatch-only: the sharded update enters the in-flight
+                    # window unfenced (its collectives live inside the XLA
+                    # program, so nothing here blocks the host).
+                    algo.train_on_batch(batch)
+                except Exception as e:
+                    self._learner_error("multi-host update error", e)
+                    self._mh_busy = False
+                    # symmetric on all ranks: same data, same failure
+                    continue
+                if (coord and self.guardrails is not None
+                        and self.guardrails.watchdog is not None
+                        and self.distributed_info["num_processes"] == 1):
+                    # Health probes ride LazyMetrics through the window on
+                    # every rank (they are jitted over the same sharded
+                    # state). The watchdog DETECTOR stays single-process:
+                    # its rollback path restores a checkpoint, which is a
+                    # collective a coordinator-solo trip would hang on.
+                    self.guardrails.watchdog.observe_dispatch(
+                        algo.inflight.dispatch_count, algo._last_metrics)
+                if coord:
+                    self.stats["updates"] += 1
+                    self._m_updates.inc()
+                    # Epoch log: captured now (on-policy: one per update;
+                    # off-policy: the trajectory cadence), dumped once the
+                    # update it describes is fenced.
+                    payload = algo.capture_epoch_stats(True)
+                    if payload is not None:
+                        self._pending_logs.append(
+                            (algo.inflight.dispatch_count, payload,
+                             algo._last_metrics))
             try:
                 if self._async_publish:
                     # The publish gather (jitted re-shard to replicated)
@@ -1414,16 +1430,13 @@ class TrainingServer:
             # hosting server + busy actor loop on a small host) a ~2 s
             # compile competing with the actor loop for CPU can stretch
             # past the whole example run, so no update ever happens live.
-            t0 = time.monotonic()
+            n = 0
             try:
-                n = self.algorithm.warmup(
-                    should_continue=lambda: (self._decoded.empty()
-                                             and self._ingest.empty()
-                                             and not self._stop.is_set()))
-                if n:
-                    print(f"[TrainingServer] warmup: {n} update shape(s) "
-                          f"compiled in {time.monotonic() - t0:.1f}s",
-                          flush=True)
+                with span("host:warmup", self.timings, "warmup_s") as sp:
+                    n = self.algorithm.warmup(
+                        should_continue=lambda: (
+                            self._decoded.empty() and self._ingest.empty()
+                            and not self._stop.is_set()))
             except Exception as e:
                 # The update did not compile or did not run (a kernel the
                 # compiler refuses, device OOM): every real batch would
@@ -1434,20 +1447,23 @@ class TrainingServer:
                 self._warmup_error = e
                 raise
             finally:
-                self.timings["warmup_s"] += time.monotonic() - t0
                 self._warmup_done.set()
+            if n:
+                print(f"[TrainingServer] warmup: {n} update shape(s) "
+                      f"compiled in {sp.seconds:.1f}s", flush=True)
         while not self._stop.is_set():
-            t_wait = time.monotonic()
             try:
-                item = self._decoded.get(timeout=0.1)
+                with span("host:wait_data", self.timings,
+                          "learner_idle_s") as wait:
+                    item = self._decoded.get(timeout=0.1)
             except queue.Empty:
-                self.timings["learner_idle_s"] += time.monotonic() - t_wait
                 # Idle is fence-for-free: the device has nothing queued
                 # behind the in-flight updates, so resolving them (and
                 # flushing their deferred epoch logs) costs no overlap —
                 # and it is what lets drain() observe pending -> 0.
                 try:
-                    self._pipeline_quiesce()
+                    with span("host:quiesce"):
+                        self._pipeline_quiesce()
                 except Exception as e:
                     # An update that failed ON the device surfaces at its
                     # fence, which under async dispatch is usually here.
@@ -1457,7 +1473,6 @@ class TrainingServer:
                 # pending health probe (free post-fence) and act on trips.
                 self._guard_poll()
                 continue
-            self.timings["learner_idle_s"] += time.monotonic() - t_wait
             if self._halted:
                 # Degraded halt-and-alarm: training is stopped (rollback
                 # budget spent / no healthy checkpoint); drain and drop
@@ -1467,20 +1482,19 @@ class TrainingServer:
                         len(item) if isinstance(item, list) else 1)
                 self._decoded.task_done()
                 continue
-            t0 = time.monotonic()
+            # A native drain batch is a list of DecodedTrajectory; a
+            # Python-decoded single trajectory is a list of ActionRecord
+            # (and a staged columnar one is a bare DecodedTrajectory) —
+            # disambiguate on the element type.
+            batch = (item if isinstance(item, list) and item
+                     and isinstance(item[0], DecodedTrajectory) else [item])
+            queued_ns = wait.t1_ns - self._decoded.got_put_ns
             try:
-                # A native drain batch is a list of DecodedTrajectory; a
-                # Python-decoded single trajectory is a list of
-                # ActionRecord (and a staged columnar one is a bare
-                # DecodedTrajectory) — disambiguate on the element type.
-                if (isinstance(item, list) and item
-                        and isinstance(item[0], DecodedTrajectory)):
-                    for one in item:
+                with span("rl:learner.item", self.timings, "learn_s",
+                          queued_us=queued_ns // 1000, n=len(batch)):
+                    for one in batch:
                         self._process_one(one)
-                else:
-                    self._process_one(item)
             finally:
-                self.timings["learn_s"] += time.monotonic() - t0
                 self._decoded.task_done()
         # Shutdown: fence what was dispatched and flush its logs so
         # disable_server leaves state/progress.txt consistent — then
@@ -1527,7 +1541,7 @@ class TrainingServer:
             # touch the ingest path's health.
             pass
 
-    def _trace_dispatch(self, tracer, algo, t0_ns: int,
+    def _trace_dispatch(self, tracer, algo, t0_ns: int, t1_ns: int,
                         consume_ver: int) -> None:
         """Close out the tracing bookkeeping of one update dispatch
         (learner thread): the downstream ``dispatch`` hop for sampled
@@ -1535,10 +1549,9 @@ class TrainingServer:
         since the previous dispatch, the upstream ``update`` hop plus
         the end-to-end data-age / version-lag observations (same-host
         skew-guarded — a cross-host born stamp is dropped, not
-        observed)."""
+        observed). The stamps are the ``rl:learner.dispatch`` span's."""
         from relayrl_tpu.telemetry.trace import SKEW_GUARD_NS, model_trace_id
 
-        t1_ns = time.monotonic_ns()
         ver = algo.dispatched_version
         if tracer.sample_version(ver):
             tracer.span("model", model_trace_id(ver), "dispatch",
@@ -1599,59 +1612,60 @@ class TrainingServer:
             self._trace_pending.append(ctx)
         self._observe_behavior_lag(item, algo, ctx)
         tracer = self._get_tracer()
-        t0_ns = time.monotonic_ns() if tracer.enabled else 0
         # The version this batch trains FROM (pre-dispatch) — the
         # convention _observe_behavior_lag's histogram uses, so the
         # trace-side version-lag distribution matches it exactly.
         consume_ver = algo.dispatched_version if tracer.enabled else 0
-        t0 = time.monotonic()
-        try:
-            got = algo.accumulate(item)
-            updated = got is not None
-            if updated:
-                batches = got if isinstance(got, list) else [got]
-                if self._prefetch:
-                    # Eager H2D: enqueued now, the transfer overlaps the
-                    # in-flight updates instead of running after the
-                    # window fence below.
-                    batches = [algo.stage_batch(b) for b in batches]
-                if isinstance(got, list):
-                    algo.train_on_batches(batches)
-                else:
-                    algo.train_on_batch(batches[0])
-        except Exception as e:  # never kill the loop on one bad batch
-            self._learner_error("learner error", e)
-            return
-        finally:
-            self._sync_drop_stats()
-        if (updated and self.guardrails is not None
-                and self.guardrails.watchdog is not None):
-            # Queue the dispatched update's (lazy) metrics — probe
-            # scalars included — for the watchdog; they resolve at the
-            # in-flight fence, never here (the LazyMetrics deferral).
-            self.guardrails.watchdog.observe_dispatch(
-                algo.inflight.dispatch_count, algo._last_metrics)
-        # Epoch log: captured now (episode counters must not leak across
-        # epochs), dumped once the update it describes is fenced.
-        payload = algo.capture_epoch_stats(updated)
-        if payload is not None:
-            self._pending_logs.append(
-                (algo.inflight.dispatch_count, payload, algo._last_metrics))
-        # dispatch_s ends here: the publish handoff below is a lock'd
+        # dispatch_s: accumulate + stage + enqueue + window fence + epoch
+        # capture. It ends before the publish handoff: that is a lock'd
         # slot swap, but a due checkpoint quiesces + saves — seconds of
         # fence/IO that must not masquerade as host-side enqueue (the
-        # window fence is already accounted in device_wait_s).
-        dispatch_dt = time.monotonic() - t0
-        self.timings["dispatch_s"] += dispatch_dt
-        self._m_dispatch.observe(dispatch_dt)
+        # window fence is also accounted in device_wait_s).
+        with span("rl:learner.dispatch", self.timings, "dispatch_s",
+                  metric=self._m_dispatch) as sp:
+            try:
+                got = algo.accumulate(item)
+                updated = got is not None
+                if updated:
+                    batches = got if isinstance(got, list) else [got]
+                    if self._prefetch:
+                        # Eager H2D: enqueued now, the transfer overlaps
+                        # the in-flight updates instead of running after
+                        # the window fence below.
+                        batches = [algo.stage_batch(b) for b in batches]
+                    if isinstance(got, list):
+                        algo.train_on_batches(batches)
+                    else:
+                        algo.train_on_batch(batches[0])
+            except Exception as e:  # never kill the loop on one bad batch
+                self._learner_error("learner error", e)
+                return
+            finally:
+                self._sync_drop_stats()
+            if (updated and self.guardrails is not None
+                    and self.guardrails.watchdog is not None):
+                # Queue the dispatched update's (lazy) metrics — probe
+                # scalars included — for the watchdog; they resolve at the
+                # in-flight fence, never here (the LazyMetrics deferral).
+                self.guardrails.watchdog.observe_dispatch(
+                    algo.inflight.dispatch_count, algo._last_metrics)
+            # Epoch log: captured now (episode counters must not leak
+            # across epochs), dumped once the update it describes is fenced.
+            payload = algo.capture_epoch_stats(updated)
+            if payload is not None:
+                self._pending_logs.append(
+                    (algo.inflight.dispatch_count, payload,
+                     algo._last_metrics))
         if tracer.enabled and updated:
-            self._trace_dispatch(tracer, algo, t0_ns, consume_ver)
+            self._trace_dispatch(tracer, algo, sp.t0_ns, sp.t1_ns,
+                                 consume_ver)
         if updated:
             self.stats["updates"] += 1
             self._m_updates.inc()
             try:
                 if self._publisher is not None:
-                    self._publisher.submit(algo.snapshot_for_publish())
+                    with span("host:publish_submit"):
+                        self._publisher.submit(algo.snapshot_for_publish())
                     # Full-state checkpointing stays on the learner
                     # thread (orbax save is not publisher-safe); gate on
                     # the host version mirror — int(state.step) would
@@ -1702,7 +1716,8 @@ class TrainingServer:
                 break
             self._pending_logs.popleft()
             try:
-                self.algorithm.log_epoch(stats=payload, metrics=metrics)
+                with span("host:epoch_log"):
+                    self.algorithm.log_epoch(stats=payload, metrics=metrics)
                 dumped = True
             except Exception as e:
                 print(f"[TrainingServer] log error: {e!r}", flush=True)
@@ -1736,11 +1751,12 @@ class TrainingServer:
             return False
         win = getattr(self.algorithm, "_inflight", None)
         fenced = win.fenced_count if win is not None else 0
-        trip = g.watchdog.poll(fenced)
-        if trip is None:
-            return False
-        self._execute_rollback(trip)
-        return True
+        with span("host:guard_poll"):
+            trip = g.watchdog.poll(fenced)
+            if trip is None:
+                return False
+            self._execute_rollback(trip)
+            return True
 
     def _execute_rollback(self, trip) -> None:
         """The watchdog tripped: halt dispatch, restore the newest
@@ -2028,39 +2044,42 @@ class TrainingServer:
             self._bundle_host = (int(version), dict(arch), host_params)
         tracer = self._get_tracer()
         traced = tracer.enabled and tracer.sample_version(version)
+        trace_id = None
+        if traced:
+            from relayrl_tpu.telemetry.trace import model_trace_id
+
+            trace_id = model_trace_id(version)
         try:
             if enc is not None:
-                t_enc0 = time.monotonic_ns() if traced else 0
-                frame, info = enc.encode(version, arch, host_params)
-                if traced:
-                    from relayrl_tpu.telemetry.trace import model_trace_id
-
-                    t_enc1 = time.monotonic_ns()
-                    tracer.span("model", model_trace_id(version), "encode",
-                                t_enc0, t_enc1, version=int(version),
-                                frame_kind=info["kind"],
-                                bytes=info["frame_bytes"])
+                with span("rl:publish.encode") as sp:
+                    frame, info = enc.encode(version, arch, host_params)
+                    sp.note(kind=info["kind"], bytes=info["frame_bytes"])
+                    if traced:
+                        sp.hop("model", trace_id, "encode",
+                               version=int(version), frame_kind=info["kind"],
+                               bytes=info["frame_bytes"])
                 if getattr(self.transport, "needs_handshake_bytes", False):
                     # The native core answers handshakes from pushed
                     # bytes; a v2 publish rides with the v1 bundle for
                     # set_model.
-                    self._traced_wire_publish(
-                        traced, version, frame,
-                        handshake_bytes=self._get_model()[1])
+                    self._wire_publish(trace_id, version, frame,
+                                       handshake_bytes=self._get_model()[1])
                 else:
-                    self._traced_wire_publish(traced, version, frame)
+                    self._wire_publish(trace_id, version, frame)
                 telemetry.emit("model_publish", version=version,
                                bytes=info["frame_bytes"], kind=info["kind"],
                                raw_bytes=info["raw_bytes"])
             else:
                 from relayrl_tpu.types.model_bundle import ModelBundle
 
-                raw = ModelBundle(version=int(version), arch=dict(arch),
-                                  params=host_params).to_bytes()
+                with span("rl:publish.encode", kind="bundle") as sp:
+                    raw = ModelBundle(version=int(version), arch=dict(arch),
+                                      params=host_params).to_bytes()
+                    sp.note(bytes=len(raw))
                 with self._bundle_lock:
                     self._bundle_bytes = raw
                     self._bundle_version = int(version)
-                self._traced_wire_publish(traced, version, raw)
+                self._wire_publish(trace_id, version, raw)
                 telemetry.emit("model_publish", version=version,
                                bytes=len(raw))
         finally:
@@ -2079,23 +2098,16 @@ class TrainingServer:
                 except Exception as e:
                     self._publish_error("serving install error", e)
 
-    def _traced_wire_publish(self, traced: bool, version: int,
-                             frame: bytes, **kwargs) -> None:
-        """The ``publish`` hop span (socket broadcast wall time on the
-        publisher thread) around the fault-site-wrapped broadcast."""
-        if not traced:
+    def _wire_publish(self, trace_id: str | None, version: int,
+                      frame: bytes, **kwargs) -> None:
+        """The fault-site-wrapped broadcast in its ``rl:publish.send`` span
+        (socket wall time on the publisher thread); ``trace_id`` makes it
+        the sampled version's ``publish`` hop as well."""
+        with span("rl:publish.send") as sp:
+            if trace_id is not None:
+                sp.hop("model", trace_id, "publish", version=int(version),
+                       backend=self.server_type)
             self._faulted_publish(version, frame, **kwargs)
-            return
-        from relayrl_tpu.telemetry.trace import model_trace_id
-
-        tracer = self._get_tracer()
-        t0 = time.monotonic_ns()
-        try:
-            self._faulted_publish(version, frame, **kwargs)
-        finally:
-            tracer.span("model", model_trace_id(version), "publish",
-                        t0, time.monotonic_ns(), version=int(version),
-                        backend=self.server_type)
 
     def _faulted_publish(self, version: int, frame: bytes,
                          **kwargs) -> None:
@@ -2118,9 +2130,10 @@ class TrainingServer:
         thread instead)."""
         import jax
 
-        bundle = self.algorithm.bundle()
-        self._publish_params(bundle.version, bundle.arch,
-                             jax.device_get(bundle.params))
+        with span("rl:publish.gather"):
+            bundle = self.algorithm.bundle()
+            host_params = jax.device_get(bundle.params)
+        self._publish_params(bundle.version, bundle.arch, host_params)
         self._maybe_periodic_checkpoint(bundle.version)
 
     def _maybe_periodic_checkpoint(self, version: int) -> None:
@@ -2137,14 +2150,15 @@ class TrainingServer:
         if (not self._checkpoint_dir
                 or version - self._ckpt_version < self._checkpoint_every):
             return
-        self._pipeline_quiesce()
-        # Post-quiesce the in-flight window is empty, so every pending
-        # health probe resolves for free here — a trip rolls back (the
-        # save is skipped: the state it would capture is the poisoned
-        # line) and a clean poll makes the healthy-at-save tag honest.
-        if self._guard_poll():
-            return
-        self._periodic_checkpoint()
+        with span("host:checkpoint", version=int(version)):
+            self._pipeline_quiesce()
+            # Post-quiesce the in-flight window is empty, so every pending
+            # health probe resolves for free here — a trip rolls back (the
+            # save is skipped: the state it would capture is the poisoned
+            # line) and a clean poll makes the healthy-at-save tag honest.
+            if self._guard_poll():
+                return
+            self._periodic_checkpoint()
         # Advance even on a (caught) failed save — retrying every epoch
         # would hammer a broken checkpoint dir, and multi-host ranks must
         # stay in lockstep on the due-check regardless of local errors.
@@ -2160,8 +2174,10 @@ class TrainingServer:
         ``publish_errors_total`` by the publisher loop; ``stats`` gets
         its copy here."""
         try:
+            with span("rl:publish.gather"):
+                host_params = snapshot.host_params()
             self._publish_params(snapshot.version, snapshot.arch,
-                                 snapshot.host_params())
+                                 host_params)
         except Exception:
             self.stats["publish_errors"] += 1
             raise

@@ -8,8 +8,6 @@ from relayrl_tpu.utils.logger import (
     statistics_scalar,
 )
 from relayrl_tpu.utils.profiling import (
-    annotate,
-    start_trace_server,
     timed,
     trace,
 )
@@ -20,8 +18,6 @@ __all__ = [
     "colorize",
     "setup_logger_kwargs",
     "statistics_scalar",
-    "annotate",
-    "start_trace_server",
     "timed",
     "trace",
 ]

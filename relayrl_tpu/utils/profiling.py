@@ -1,24 +1,16 @@
-"""Profiling hooks: jax.profiler surface (trace server, traces, scopes).
+"""Profiling hooks: scoped ``jax.profiler`` trace capture and a timing helper.
 
 TPU-native equivalent of the reference's tracing stack (SURVEY.md §5.1 —
 tokio-console behind a feature flag plus an optional flamegraph dep):
-a TensorBoard-profile trace server, scoped trace capture to disk, named
-annotations that show up on the TPU timeline, and a block-until-ready
-timing helper for quick latency checks without the full profiler.
+scoped trace capture to disk and a block-until-ready timing helper for
+quick latency checks without the full profiler. Named spans on the
+profiler's time line are ``relayrl_tpu.telemetry.spans.span``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-
-
-def start_trace_server(port: int = 9999):
-    """Start the profiler gRPC server (connect TensorBoard's profile plugin
-    or `jax.profiler.trace_remote` to it). Returns the server object."""
-    import jax
-
-    return jax.profiler.start_server(port)
 
 
 @contextlib.contextmanager
@@ -33,13 +25,6 @@ def trace(log_dir: str, create_perfetto_link: bool = False):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named scope that appears on the device timeline."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 def timed(fn, *args, **kwargs):

@@ -139,3 +139,48 @@ class TestEpochBuffer:
         buf = EpochBuffer(obs_dim=4, act_dim=2, traj_per_epoch=1,
                           buckets=[64, 256, 1000], max_traj_length=100)
         assert buf.buckets == (64,)
+
+
+def _decoded_episode(n, seed, obs_dim=6):
+    from relayrl_tpu.types.columnar import DecodedTrajectory
+
+    rng = np.random.default_rng(seed)
+    return DecodedTrajectory(
+        agent_id="a", n_steps=n, n_records=n, marker_truncated=False,
+        columns={"o": rng.integers(0, 255, (n, obs_dim)).astype(np.uint8),
+                 "a": rng.integers(0, 3, (n,)).astype(np.int32),
+                 "r": rng.random(n).astype(np.float32),
+                 "t": np.array([False] * (n - 1) + [seed % 2 == 0]),
+                 "u": np.zeros((n,), np.uint8),
+                 "x": np.zeros((n,), np.uint8)},
+        aux={"v": rng.standard_normal(n).astype(np.float32),
+             "logp_a": rng.standard_normal(n).astype(np.float32)})
+
+
+class TestEpochBufferRecyclesEpisodes:
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    def test_recycled_batches_equal_fresh_ones(self, staging_slots):
+        """From the second drain on, episodes are padded into the arrays of
+        episodes already drained: every batch must equal what a buffer that
+        has recycled nothing gives for the same episodes."""
+        lens = [5, 60, 64, 17, 100, 9, 33, 64, 2, 200, 64, 41]
+        kw = dict(obs_dim=6, act_dim=3, traj_per_epoch=4,
+                  buckets=(64, 256), staging_slots=staging_slots)
+        buf = EpochBuffer(**kw)
+        drained = 0
+        for i, n in enumerate(lens * 2):
+            if not buf.add_episode(_decoded_episode(n, seed=i)):
+                continue
+            got = {k: v.copy() for k, v in buf.drain().as_dict().items()}
+            fresh = EpochBuffer(**kw)
+            for j in range(i - 3, i + 1):
+                fresh.add_episode(_decoded_episode((lens * 2)[j], seed=j))
+            want = fresh.drain().as_dict()
+            assert got.keys() == want.keys()
+            for key in want:
+                np.testing.assert_array_equal(got[key], want[key], key)
+            drained += 1
+        assert drained == 6
+        assert 0 < sum(len(v) for v in buf._spare.values()) <= 2 * 4
+        buf.reset()
+        assert not buf._spare and len(buf) == 0

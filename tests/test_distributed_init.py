@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import pytest
 
 from relayrl_tpu.parallel import initialize_distributed, is_coordinator
-from relayrl_tpu.utils import annotate, timed, trace
+from relayrl_tpu.telemetry.spans import span
+from relayrl_tpu.utils import timed, trace
 
 
 @pytest.fixture(autouse=True)
@@ -69,9 +70,18 @@ class TestProfiling:
         produced = list(log_dir.rglob("*"))
         assert any(p.is_file() for p in produced), produced
 
-    def test_annotate_scope(self):
-        with annotate("test-scope"):
-            jax.block_until_ready(jnp.ones(8) * 2)
+    def test_span_scope_lands_in_the_trace(self, tmp_path):
+        ledger = {"scope_s": 0.0}
+        with trace(str(tmp_path)):
+            with span("rl:test.scope", ledger, "scope_s", n=8):
+                jax.block_until_ready(jnp.ones(8) * 2)
+        path, = tmp_path.rglob("*.xplane.pb")
+        found = [dict(ev.stats)
+                 for plane in jax.profiler.ProfileData.from_file(
+                     str(path)).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name == "rl:test.scope"]
+        assert found == [{"n": 8}] and ledger["scope_s"] > 0
 
     def test_timed(self):
         out, secs = timed(lambda: jnp.sum(jnp.ones((128, 128))))

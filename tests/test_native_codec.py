@@ -84,13 +84,23 @@ def _assert_pad_parity(actions, decoder, obs_dim=4, act_dim=2, discrete=True,
     h = horizon or pick_bucket(len(actions), (64, 256, 1000))
     want = pad_trajectory(deserialize_actions(payload), h, obs_dim, act_dim,
                           discrete)
-    got = pad_decoded(item, h, obs_dim, act_dim, discrete)
-    for field in ("obs", "act", "act_mask", "rew", "val", "logp", "valid"):
-        np.testing.assert_array_equal(
-            getattr(got, field), getattr(want, field), err_msg=field)
-    assert got.length == want.length
-    assert got.terminated == want.terminated
-    assert got.last_val == want.last_val
+    fields = ("obs", "act", "act_mask", "rew", "val", "logp", "valid")
+    # over the arrays of an episode already drained (EpochBuffer recycles
+    # them): nothing of what they held may stay
+    dirty = pad_decoded(item, h, obs_dim, act_dim, discrete)
+    for field in fields:
+        getattr(dirty, field)[...] = 7
+    dirty.length, dirty.terminated, dirty.last_val = -1, None, 7.0
+    for got in (pad_decoded(item, h, obs_dim, act_dim, discrete),
+                pad_decoded(item, h, obs_dim, act_dim, discrete, out=dirty)):
+        for field in fields:
+            np.testing.assert_array_equal(
+                getattr(got, field), getattr(want, field), err_msg=field)
+            assert getattr(got, field).dtype == getattr(want, field).dtype
+        assert got.length == want.length
+        assert got.terminated == want.terminated
+        assert got.last_val == want.last_val
+    assert got.obs is dirty.obs
     return item
 
 
