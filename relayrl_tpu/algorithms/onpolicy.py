@@ -213,12 +213,26 @@ class OnPolicyAlgorithm(AlgorithmBase):
         return self.train_on_batch(self.buffer.drain().as_dict())
 
     def mh_zero_batch(self, b: int, t: int) -> dict:
-        """Placeholder epoch batch (shape/dtype only) that non-coordinators
-        feed the batch broadcast — the descriptor carries (B, T)."""
+        """Placeholder epoch batch (shape/dtype only): what warm-up
+        compiles the update for, and what non-coordinators feed the batch
+        broadcast — the descriptor carries (B, T).
+
+        A batch's obs keeps the dtype its episodes were decoded in
+        (``data.batching.batch_obs_dtype``), so the placeholder takes the
+        last drained batch's; multi-host pins that to float32 on every
+        rank (:meth:`enable_multihost`). Before any data it goes by what
+        the learner can see: a policy that scales its observations by
+        1/255 (``scale_obs``, the pixel trunk) is fed byte frames, so
+        uint8; everything else float32. A ``scale_obs`` learner fed
+        float32 frames compiles once more, at its first batch."""
         from relayrl_tpu.data.batching import TrajectoryBatch
 
+        obs_dtype = self.buffer.obs_dtype
+        if obs_dtype is None:
+            obs_dtype = (np.uint8 if self.policy.arch.get("scale_obs")
+                         else np.float32)
         return TrajectoryBatch.zeros(b, t, self.obs_dim, self.act_dim,
-                                     self.discrete)
+                                     self.discrete, obs_dtype=obs_dtype)
 
     def warmup(self, should_continue=None) -> int:
         """Epoch batches are always ``[traj_per_epoch, bucket]`` — one
@@ -268,6 +282,10 @@ class OnPolicyAlgorithm(AlgorithmBase):
         # the broadcast loop overlaps ingest/broadcast/prefetch with the
         # in-flight updates under the same max_inflight_updates bound.
         self.buffer.disable_staging()
+        # ...and every rank must build the same placeholder from (B, T)
+        # alone, so the coordinator's batches keep one obs dtype whatever
+        # its actors send.
+        self.buffer.pin_float32_obs()
         self._inflight = None  # rebuilt over the (unchanged) window bound
         # One jitted params gather, reused by every bundle() call (a fresh
         # lambda per call would retrace + recompile the all-gather each

@@ -23,7 +23,7 @@ from relayrl_tpu.types.action import ActionRecord
 class PaddedTrajectory:
     """One episode padded to ``T`` with host (numpy) arrays."""
 
-    obs: np.ndarray        # [T, obs_dim] f32
+    obs: np.ndarray        # [T, obs_dim] u8 (byte frames) or f32
     act: np.ndarray        # [T] i32 (discrete) or [T, act_dim] f32
     act_mask: np.ndarray   # [T, act_dim] f32
     rew: np.ndarray        # [T] f32
@@ -39,7 +39,7 @@ class PaddedTrajectory:
 class TrajectoryBatch:
     """Stacked episodes ``[B, T, ...]`` — the learner-step input."""
 
-    obs: np.ndarray        # [B, T, obs_dim]
+    obs: np.ndarray        # [B, T, obs_dim] u8 or f32 (batch_obs_dtype)
     act: np.ndarray        # [B, T] or [B, T, act_dim]
     act_mask: np.ndarray   # [B, T, act_dim]
     rew: np.ndarray        # [B, T]
@@ -66,16 +66,19 @@ class TrajectoryBatch:
 
     @classmethod
     def zeros(cls, batch_size: int, horizon: int, obs_dim: int, act_dim: int,
-              discrete: bool = True) -> dict[str, np.ndarray]:
+              discrete: bool = True,
+              obs_dtype=np.float32) -> dict[str, np.ndarray]:
         """Zero batch dict with this schema's exact keys/dtypes/shapes —
-        the single owner used by the multi-host broadcast protocol, where
+        the single owner used by the staging slabs, the warm-up
+        placeholder and the multi-host broadcast protocol, where
         non-coordinator processes must hold a pytree-identical template
-        before ``broadcast_one_to_all`` fills it."""
+        before ``broadcast_one_to_all`` fills it. ``obs_dtype`` is the one
+        dtype that follows the stream (:func:`batch_obs_dtype`)."""
         b, t = int(batch_size), int(horizon)
         act = (np.zeros((b, t), np.int32) if discrete
                else np.zeros((b, t, act_dim), np.float32))
         return {
-            "obs": np.zeros((b, t, obs_dim), np.float32),
+            "obs": np.zeros((b, t, obs_dim), obs_dtype),
             "act": act,
             "act_mask": np.zeros((b, t, act_dim), np.float32),
             "rew": np.zeros((b, t), np.float32),
@@ -84,6 +87,34 @@ class TrajectoryBatch:
             "valid": np.zeros((b, t), np.float32),
             "last_val": np.zeros((b,), np.float32),
         }
+
+
+_U8 = np.dtype(np.uint8)
+_F32 = np.dtype(np.float32)
+
+
+def padded_obs_dtype(source_dtypes) -> np.dtype:
+    """The dtype observations keep from the wire to the jitted update:
+    byte frames (``uint8``) stay bytes — the model casts to its compute
+    dtype on entry, on the device, and 0..255 are exact in uint8, float32
+    and bfloat16 alike, so a float32 stop on the host would only make
+    every byte four for the pad, the stack and the H2D copy. Everything
+    else (float64, ints, mixed, no observations at all) is ``float32``
+    as it always was."""
+    seen = False
+    for dt in source_dtypes:
+        if dt != _U8:
+            return _F32
+        seen = True
+    return _U8 if seen else _F32
+
+
+def batch_obs_dtype(trajs) -> np.dtype:
+    """A batch's obs dtype from the padded episodes it takes: ``uint8``
+    iff every one of them is, else ``float32`` — the row assignment
+    widens a byte exactly, so two fleets with different env wrappers
+    never break a batch."""
+    return padded_obs_dtype(t.obs.dtype for t in trajs)
 
 
 def fold_trailing_markers(
@@ -153,7 +184,8 @@ def pad_trajectory(
     Aux ``logp_a``/``v`` come from the action's data dict (the reference's
     REINFORCE reads ``data['v']``/``data['logp_a']`` the same way). Episodes
     longer than ``horizon`` are truncated (bootstrapped from the stored value
-    of the last kept step).
+    of the last kept step). Observations keep ``uint8`` when every step's
+    are bytes, else they are ``float32`` (:func:`padded_obs_dtype`).
     """
     if not actions:
         raise ValueError("empty trajectory")
@@ -167,7 +199,9 @@ def pad_trajectory(
         raise ValueError("trajectory contained only terminal markers")
     n = min(len(actions), horizon)
 
-    obs = np.zeros((horizon, obs_dim), dtype=np.float32)
+    obs_dtype = padded_obs_dtype(
+        np.asarray(a.obs).dtype for a in actions[:n] if a.obs is not None)
+    obs = np.zeros((horizon, obs_dim), dtype=obs_dtype)
     act = np.zeros((horizon,), dtype=np.int32) if discrete else np.zeros(
         (horizon, act_dim), dtype=np.float32)
     act_mask = np.zeros((horizon, act_dim), dtype=np.float32)
@@ -180,7 +214,7 @@ def pad_trajectory(
     for t in range(n):
         a = actions[t]
         if a.obs is not None:
-            obs[t] = np.asarray(a.obs, dtype=np.float32).reshape(-1)[:obs_dim]
+            obs[t] = np.asarray(a.obs, dtype=obs_dtype).reshape(-1)[:obs_dim]
         if a.act is not None:
             if discrete:
                 act[t] = int(np.asarray(a.act).reshape(-1)[0])
@@ -212,6 +246,12 @@ def pad_trajectory(
     )
 
 
+def decoded_obs_dtype(dt) -> np.dtype:
+    """What :func:`pad_decoded` makes of ``dt``'s observation column."""
+    col = dt.columns.get("o")
+    return padded_obs_dtype(() if col is None else (col.dtype,))
+
+
 def pad_decoded(
     dt,
     horizon: int,
@@ -228,9 +268,10 @@ def pad_decoded(
     kept identical to the ActionRecord path (tests/test_native_codec.py
     asserts byte equality of the padded outputs across both paths).
 
-    ``out`` is a padded trajectory of the same horizon and widths that
-    nothing reads any more: its arrays are written over and returned in a
-    new :class:`PaddedTrajectory`, equal to what a fresh call gives.
+    ``out`` is a padded trajectory of the same horizon, widths and obs
+    dtype (:func:`decoded_obs_dtype`) that nothing reads any more: its
+    arrays are written over and returned in a new
+    :class:`PaddedTrajectory`, equal to what a fresh call gives.
     """
     cols, aux = dt.columns, dt.aux
     total = dt.n_steps
@@ -238,14 +279,18 @@ def pad_decoded(
         raise ValueError("trajectory contained only terminal markers"
                          if dt.n_records else "empty trajectory")
     n = min(total, horizon)
+    obs_dtype = decoded_obs_dtype(dt)
     if out is None:
-        obs = np.zeros((horizon, obs_dim), dtype=np.float32)
+        obs = np.zeros((horizon, obs_dim), dtype=obs_dtype)
         act = (np.zeros((horizon,), dtype=np.int32) if discrete
                else np.zeros((horizon, act_dim), dtype=np.float32))
         act_mask = np.zeros((horizon, act_dim), dtype=np.float32)
         rew, val, logp, valid = (np.zeros((horizon,), dtype=np.float32)
                                  for _ in range(4))
     else:
+        if out.obs.dtype != obs_dtype:
+            raise ValueError(f"cannot pad {obs_dtype} observations over a "
+                             f"{out.obs.dtype} episode")
         obs, act, act_mask = out.obs, out.act, out.act_mask
         rew, val, logp, valid = out.rew, out.val, out.logp, out.valid
         for arr in (obs, act, act_mask, rew, val, logp, valid):
@@ -289,12 +334,15 @@ _BATCH_FIELDS = ("obs", "act", "act_mask", "rew", "val", "logp", "valid")
 def stack_trajectories(
     trajs: Sequence[PaddedTrajectory],
     out: dict[str, np.ndarray] | None = None,
+    obs_dtype=None,
 ) -> TrajectoryBatch:
     """Padded episodes → one ``[B, T, ...]`` batch.
 
     Without ``out`` this is the original allocate-per-call path (eight
     fresh ``np.stack``/``asarray`` allocations; requires same-horizon
-    inputs). With ``out`` — a persistent staging dict from
+    inputs; obs stack to ``obs_dtype``, by default what
+    :func:`batch_obs_dtype` makes of the episodes). With ``out`` — a
+    persistent staging dict from
     :class:`BatchStaging` — every row writes in place (shorter episodes
     zero-fill their tail, subsuming :func:`repad_trajectory`), and the
     returned batch VIEWS the staging arrays: it is valid until the
@@ -304,8 +352,10 @@ def stack_trajectories(
         horizons = {t.obs.shape[0] for t in trajs}
         if len(horizons) != 1:
             raise ValueError(f"mixed horizons in batch: {sorted(horizons)}")
+        if obs_dtype is None:
+            obs_dtype = batch_obs_dtype(trajs)
         return TrajectoryBatch(
-            obs=np.stack([t.obs for t in trajs]),
+            obs=np.stack([t.obs for t in trajs], dtype=obs_dtype),
             act=np.stack([t.act for t in trajs]),
             act_mask=np.stack([t.act_mask for t in trajs]),
             rew=np.stack([t.rew for t in trajs]),
@@ -333,7 +383,7 @@ def stack_trajectories(
 
 class BatchStaging:
     """Ring of persistent ``[B, T, ...]`` host staging slabs, one ring
-    per distinct (batch, horizon) shape — the zero-alloc steady state
+    per distinct (batch, horizon, obs dtype) — the zero-alloc steady state
     for epoch assembly. A slab is handed out round-robin and REUSED
     after ``slots`` further acquires of the same shape; the owner must
     guarantee the slab's previous consumer is done by then (the
@@ -348,15 +398,17 @@ class BatchStaging:
         self.slots = int(slots)
         self.obs_dim, self.act_dim = int(obs_dim), int(act_dim)
         self.discrete = bool(discrete)
-        self._rings: dict[tuple[int, int], list[dict[str, np.ndarray]]] = {}
-        self._next: dict[tuple[int, int], int] = {}
+        self._rings: dict[tuple, list[dict[str, np.ndarray]]] = {}
+        self._next: dict[tuple, int] = {}
 
-    def acquire(self, batch_size: int, horizon: int) -> dict[str, np.ndarray]:
-        key = (int(batch_size), int(horizon))
+    def acquire(self, batch_size: int, horizon: int,
+                obs_dtype=np.float32) -> dict[str, np.ndarray]:
+        key = (int(batch_size), int(horizon), np.dtype(obs_dtype))
         ring = self._rings.setdefault(key, [])
         if len(ring) < self.slots:
             ring.append(TrajectoryBatch.zeros(
-                key[0], key[1], self.obs_dim, self.act_dim, self.discrete))
+                key[0], key[1], self.obs_dim, self.act_dim, self.discrete,
+                obs_dtype=key[2]))
             return ring[-1]
         i = self._next.get(key, 0)
         self._next[key] = (i + 1) % self.slots

@@ -13,10 +13,14 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import numpy as np
+
 from relayrl_tpu.data.batching import (
     BatchStaging,
     PaddedTrajectory,
     TrajectoryBatch,
+    batch_obs_dtype,
+    decoded_obs_dtype,
     pad_decoded,
     pad_trajectory,
     pick_bucket,
@@ -73,9 +77,16 @@ class EpochBuffer:
                                       self.act_dim, self.discrete)
                          if staging_slots else None)
         self._pending: list[PaddedTrajectory] = []
-        # Drained episodes by horizon, their arrays free to be written over
-        # by the next episodes padded to that horizon (see add_episode).
-        self._spare: dict[int, list[PaddedTrajectory]] = {}
+        # Drained episodes by (horizon, obs dtype), their arrays free to be
+        # written over by the next episodes padded to the same (see
+        # add_episode).
+        self._spare: dict[tuple, list[PaddedTrajectory]] = {}
+        # Obs dtype of the last batch drained (None before the first): a
+        # batch keeps the dtype its episodes were decoded in, so this is
+        # what the stream's next batch will most likely be — the warm-up
+        # placeholder reads it (OnPolicyAlgorithm.mh_zero_batch).
+        self.obs_dtype: np.dtype | None = None
+        self._wire_obs = True
         self.episode_returns: list[float] = []
         self.episode_lengths: list[int] = []
 
@@ -83,6 +94,14 @@ class EpochBuffer:
         """Switch drain() back to allocate-per-call (consumers that hold
         drained batches across drains — the multi-host ready queue)."""
         self._staging = None
+
+    def pin_float32_obs(self) -> None:
+        """Every batch's obs is float32 whatever the episodes' (bytes widen
+        exactly at the stack) — for a consumer whose peers must know the
+        batch's dtypes without seeing the data: the multi-host broadcast
+        describes a batch by (B, T) alone."""
+        self._wire_obs = False
+        self.obs_dtype = np.dtype(np.float32)
 
     def __len__(self) -> int:
         return len(self._pending)
@@ -110,7 +129,7 @@ class EpochBuffer:
                 # ten times the copy (2.26 ms against 0.24 ms an Atari
                 # unroll; which of the two a process got was chance:
                 # PERF.md, PR 24).
-                spare = self._spare.get(bucket)
+                spare = self._spare.get((bucket, decoded_obs_dtype(actions)))
                 padded = pad_decoded(actions, bucket, self.obs_dim,
                                      self.act_dim, self.discrete,
                                      out=spare.pop() if spare else None)
@@ -138,23 +157,28 @@ class EpochBuffer:
         take = self._pending[: self.traj_per_epoch]
         self._pending = self._pending[self.traj_per_epoch:]
         horizon = max(t.obs.shape[0] for t in take)
+        obs_dtype = (batch_obs_dtype(take) if self._wire_obs
+                     else np.dtype(np.float32))
         # Host numbers only, from the padded episodes' own lengths and
         # shapes: a counter never reads a device array.
         with span("rl:batch.stack", valid=sum(t.length for t in take),
                   padded=len(take) * horizon) as sp:
             if self._staging is not None:
                 batch = stack_trajectories(
-                    take, out=self._staging.acquire(len(take), horizon))
+                    take, out=self._staging.acquire(len(take), horizon,
+                                                    obs_dtype))
             else:
                 batch = stack_trajectories(
-                    [repad_trajectory(t, horizon) for t in take])
+                    [repad_trajectory(t, horizon) for t in take],
+                    obs_dtype=obs_dtype)
             sp.note(bytes=sum(v.nbytes for v in batch.as_dict().values()))
         # The batch is a copy (slab or np.stack): the episodes' own arrays
-        # are free again. One batch's worth a horizon is kept.
+        # are free again. One batch's worth a (horizon, obs dtype) is kept.
         for t in take:
-            spare = self._spare.setdefault(t.obs.shape[0], [])
+            spare = self._spare.setdefault((t.obs.shape[0], t.obs.dtype), [])
             if len(spare) < self.traj_per_epoch:
                 spare.append(t)
+        self.obs_dtype = obs_dtype
         return batch
 
     def pop_episode_stats(self) -> tuple[list[float], list[int]]:

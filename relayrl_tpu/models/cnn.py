@@ -154,6 +154,9 @@ def build_cnn_discrete(arch: Mapping[str, Any]) -> Policy:
     obs_dim = int(jnp.prod(jnp.array(obs_shape)))
     arch = dict(arch)
     arch.setdefault("obs_dim", obs_dim)
+    # The policy's own copy states the default as resolved: the on-policy
+    # learner reads it to tell a byte-frame stream before any data.
+    arch.setdefault("scale_obs", True)
     if int(arch["obs_dim"]) != obs_dim:
         raise ValueError(
             f"obs_dim {arch['obs_dim']} != prod(obs_shape) {obs_dim}")
@@ -163,7 +166,7 @@ def build_cnn_discrete(arch: Mapping[str, Any]) -> Policy:
         obs_shape=obs_shape,
         conv_spec=conv_spec,
         dense=int(arch.get("dense", 512)),
-        scale_obs=bool(arch.get("scale_obs", True)),
+        scale_obs=bool(arch["scale_obs"]),
         has_critic=bool(arch.get("has_critic", True)),
         compute_dtype=_compute_dtype(arch),
     )

@@ -141,13 +141,16 @@ class TestEpochBuffer:
         assert buf.buckets == (64,)
 
 
-def _decoded_episode(n, seed, obs_dim=6):
+def _decoded_episode(n, seed, obs_dim=6, obs_dtype=np.uint8, frac=False):
     from relayrl_tpu.types.columnar import DecodedTrajectory
 
     rng = np.random.default_rng(seed)
+    obs = rng.integers(0, 256, (n, obs_dim))
+    if frac:
+        obs = obs / 7.0  # float64 -> float32 has to round
     return DecodedTrajectory(
         agent_id="a", n_steps=n, n_records=n, marker_truncated=False,
-        columns={"o": rng.integers(0, 255, (n, obs_dim)).astype(np.uint8),
+        columns={"o": obs.astype(obs_dtype),
                  "a": rng.integers(0, 3, (n,)).astype(np.int32),
                  "r": rng.random(n).astype(np.float32),
                  "t": np.array([False] * (n - 1) + [seed % 2 == 0]),
@@ -158,8 +161,10 @@ def _decoded_episode(n, seed, obs_dim=6):
 
 
 class TestEpochBufferRecyclesEpisodes:
+    @pytest.mark.parametrize("obs_dtype", [np.uint8, np.float32])
     @pytest.mark.parametrize("staging_slots", [3, 0])
-    def test_recycled_batches_equal_fresh_ones(self, staging_slots):
+    def test_recycled_batches_equal_fresh_ones(self, staging_slots,
+                                               obs_dtype):
         """From the second drain on, episodes are padded into the arrays of
         episodes already drained: every batch must equal what a buffer that
         has recycled nothing gives for the same episodes."""
@@ -169,18 +174,219 @@ class TestEpochBufferRecyclesEpisodes:
         buf = EpochBuffer(**kw)
         drained = 0
         for i, n in enumerate(lens * 2):
-            if not buf.add_episode(_decoded_episode(n, seed=i)):
+            if not buf.add_episode(_decoded_episode(n, i,
+                                                    obs_dtype=obs_dtype)):
                 continue
             got = {k: v.copy() for k, v in buf.drain().as_dict().items()}
             fresh = EpochBuffer(**kw)
             for j in range(i - 3, i + 1):
-                fresh.add_episode(_decoded_episode((lens * 2)[j], seed=j))
+                fresh.add_episode(_decoded_episode((lens * 2)[j], j,
+                                                   obs_dtype=obs_dtype))
             want = fresh.drain().as_dict()
             assert got.keys() == want.keys()
             for key in want:
                 np.testing.assert_array_equal(got[key], want[key], key)
+                assert got[key].dtype == want[key].dtype, key
+            assert got["obs"].dtype == obs_dtype
             drained += 1
         assert drained == 6
         assert 0 < sum(len(v) for v in buf._spare.values()) <= 2 * 4
         buf.reset()
         assert not buf._spare and len(buf) == 0
+
+    @pytest.mark.parametrize("other", ["dtype", "horizon"])
+    def test_spare_of_another_dtype_or_horizon_is_never_written_over(
+            self, other):
+        """A drained episode's arrays are reused only by an episode of the
+        same horizon AND obs dtype: anything else pads into fresh arrays
+        and leaves every spare as it was."""
+        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=2,
+                          buckets=(64, 256))
+        for i in range(2):
+            buf.add_episode(_decoded_episode(30, i))
+        buf.drain()
+        spares = [t for v in buf._spare.values() for t in v]
+        assert len(spares) == 2 and all(t.obs.dtype == np.uint8
+                                        and t.obs.shape[0] == 64
+                                        for t in spares)
+        before = [t.obs.copy() for t in spares]
+        nxt = (_decoded_episode(30, 9, obs_dtype=np.float32)
+               if other == "dtype" else _decoded_episode(100, 9))
+        buf.add_episode(nxt)
+        new = buf._pending[-1]
+        assert all(new.obs is not t.obs for t in spares)
+        assert new.obs.dtype == (np.float32 if other == "dtype"
+                                 else np.uint8)
+        assert new.obs.shape[0] == (64 if other == "dtype" else 256)
+        for t, was in zip(spares, before):
+            np.testing.assert_array_equal(t.obs, was)
+        assert sum(len(v) for v in buf._spare.values()) == 2
+        # ...and the same kind of episode does take one
+        buf.add_episode(_decoded_episode(30, 10))
+        assert any(buf._pending[-1].obs is t.obs for t in spares)
+        assert sum(len(v) for v in buf._spare.values()) == 1
+
+    def test_pad_decoded_refuses_out_of_another_obs_dtype(self):
+        from relayrl_tpu.data.batching import pad_decoded
+
+        out = pad_decoded(_decoded_episode(5, 0, obs_dtype=np.float32),
+                          8, 6, 3)
+        with pytest.raises(ValueError, match="uint8"):
+            pad_decoded(_decoded_episode(5, 1), 8, 6, 3, out=out)
+
+
+# -- observations keep their wire dtype (uint8 frames stay bytes) ----------
+
+def _records_episode(n, seed, obs_dim=6, obs_dtype=np.uint8, frac=False):
+    """The ActionRecord twin of ``_decoded_episode`` (same values)."""
+    dt = _decoded_episode(n, seed, obs_dim, obs_dtype, frac)
+    c, aux = dt.columns, dt.aux
+    return [ActionRecord(
+        obs=c["o"][i], act=np.int64(c["a"][i]), rew=float(c["r"][i]),
+        data={"v": aux["v"][i], "logp_a": aux["logp_a"][i]},
+        done=bool(c["t"][i])) for i in range(n)]
+
+
+_MAKERS = {"DecodedTrajectory": _decoded_episode,
+           "ActionRecord": _records_episode}
+_LENS = [5, 60, 64, 17, 100, 9, 33, 64]
+
+
+def _drain_all(make, obs_dtypes, staging_slots, pin=False, frac=False):
+    """Feed ``_LENS`` episodes (obs dtype cycling through ``obs_dtypes``)
+    and return every drained batch as copied dicts."""
+    buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=4,
+                      buckets=(64, 256), staging_slots=staging_slots)
+    if pin:
+        buf.pin_float32_obs()
+    out = []
+    for i, n in enumerate(_LENS):
+        dtype = obs_dtypes[i % len(obs_dtypes)]
+        if buf.add_episode(make(n, i, obs_dtype=dtype, frac=frac)):
+            out.append({k: v.copy()
+                        for k, v in buf.drain().as_dict().items()})
+    assert len(out) == 2
+    return out, buf
+
+
+def _assert_same_batches(got, want, obs_dtype):
+    """``want`` is the float32 batch the parent tree built: obs equal in
+    value, of the dtype the rule gives; every other field byte-equal."""
+    for g, w in zip(got, want):
+        assert g.keys() == w.keys()
+        assert w["obs"].dtype == np.float32
+        assert g["obs"].dtype == obs_dtype
+        np.testing.assert_array_equal(g["obs"].astype(np.float32), w["obs"])
+        for key in w:
+            if key != "obs":
+                assert g[key].dtype == w[key].dtype, key
+                assert g[key].tobytes() == w[key].tobytes(), key
+
+
+class TestObsKeepsWireDtype:
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    @pytest.mark.parametrize("path", sorted(_MAKERS))
+    @pytest.mark.parametrize("src,batch", [
+        (np.uint8, np.uint8), (np.float32, np.float32),
+        (np.float64, np.float32)])
+    def test_batch_obs_dtype_follows_the_source(self, src, batch, path,
+                                                staging_slots):
+        make = _MAKERS[path]
+        frac = src is not np.uint8
+        got, buf = _drain_all(make, [src], staging_slots, frac=frac)
+        # the float32 batch of the same values: what every stream gave
+        # before observations kept their dtype
+        want, _ = _drain_all(make, [np.float32], staging_slots, frac=frac)
+        _assert_same_batches(got, want, batch)
+        assert buf.obs_dtype == batch
+
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    @pytest.mark.parametrize("path", sorted(_MAKERS))
+    def test_mixed_uint8_float32_batch_is_float32_and_exact(
+            self, path, staging_slots):
+        """Two fleets with different env wrappers feed one learner: a
+        batch that holds any float32 episode is float32, bytes widened
+        exactly at the row assignment."""
+        make = _MAKERS[path]
+        got, _ = _drain_all(make, [np.uint8, np.float32, np.uint8],
+                            staging_slots)
+        want, _ = _drain_all(make, [np.float32], staging_slots)
+        _assert_same_batches(got, want, np.float32)
+
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    def test_mixed_then_pure_uint8_batches(self, staging_slots):
+        """The dtype is chosen per batch, from the episodes it takes."""
+        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=2,
+                          buckets=(64,), staging_slots=staging_slots)
+        seen = []
+        for i, dtype in enumerate([np.uint8, np.float32, np.uint8, np.uint8,
+                                   np.float32, np.float32]):
+            if buf.add_episode(_decoded_episode(9, i, obs_dtype=dtype)):
+                seen.append(buf.drain().obs.dtype)
+                assert buf.obs_dtype == seen[-1]
+        assert seen == [np.float32, np.uint8, np.float32]
+
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    def test_pinned_float32_obs(self, staging_slots):
+        """Multi-host: the coordinator's batches stay float32 whatever its
+        actors send, because its peers build theirs from (B, T) alone."""
+        got, buf = _drain_all(_decoded_episode, [np.uint8], staging_slots,
+                              pin=True)
+        want, _ = _drain_all(_decoded_episode, [np.float32], staging_slots)
+        _assert_same_batches(got, want, np.float32)
+        assert buf.obs_dtype == np.float32
+
+    def test_obs_dtype_is_unknown_before_the_first_drain(self):
+        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=2)
+        assert buf.obs_dtype is None
+        buf.add_episode(_decoded_episode(5, 0))
+        assert buf.obs_dtype is None
+        buf.add_episode(_decoded_episode(5, 1))
+        buf.drain()
+        buf.reset()
+        assert buf.obs_dtype == np.uint8  # the stream's, not the epoch's
+
+    @pytest.mark.parametrize("obs,want", [
+        ("uint8", np.uint8), ("float32", np.float32), ("int64", np.float32),
+        ("none", np.float32), ("uint8+float32", np.float32)])
+    def test_pad_trajectory_obs_dtype(self, obs, want):
+        steps = _records_episode(4, 0)
+        if obs == "none":
+            steps = [ActionRecord(act=s.act, rew=s.rew, done=s.done)
+                     for s in steps]
+        elif obs == "uint8+float32":
+            steps[2] = ActionRecord(obs=steps[2].obs.astype(np.float32),
+                                    act=steps[2].act, rew=steps[2].rew)
+        elif obs != "uint8":
+            steps = [ActionRecord(obs=s.obs.astype(obs), act=s.act,
+                                  rew=s.rew, done=s.done) for s in steps]
+        padded = pad_trajectory(steps, 8, 6, 3)
+        assert padded.obs.dtype == want
+        if obs != "none":
+            np.testing.assert_array_equal(
+                padded.obs[:4], _decoded_episode(4, 0).columns["o"])
+        assert not padded.obs[4:].any()
+
+    def test_staging_rings_are_keyed_by_obs_dtype(self):
+        from relayrl_tpu.data import BatchStaging
+
+        st = BatchStaging(2, obs_dim=6, act_dim=3)
+        u8 = [st.acquire(4, 64, np.uint8) for _ in range(3)]
+        f32 = [st.acquire(4, 64) for _ in range(3)]
+        assert all(s["obs"].dtype == np.uint8 for s in u8)
+        assert all(s["obs"].dtype == np.float32 for s in f32)
+        assert u8[2] is u8[0] and u8[1] is not u8[0]
+        assert f32[2] is f32[0] and all(a is not b for a in u8 for b in f32)
+
+    @pytest.mark.parametrize("obs_dtype", [None, np.uint8])
+    def test_zeros_takes_the_obs_dtype(self, obs_dtype):
+        from relayrl_tpu.data import TrajectoryBatch
+
+        kw = {} if obs_dtype is None else {"obs_dtype": obs_dtype}
+        z = TrajectoryBatch.zeros(2, 8, 6, 3, **kw)
+        assert z["obs"].dtype == (obs_dtype or np.float32)
+        assert z["obs"].shape == (2, 8, 6)
+        assert {k: v.dtype for k, v in z.items() if k != "obs"} == {
+            "act": np.int32, "act_mask": np.float32, "rew": np.float32,
+            "val": np.float32, "logp": np.float32, "valid": np.float32,
+            "last_val": np.float32}

@@ -167,3 +167,197 @@ def test_impala_with_sequence_policy(tmp_cwd):
         ]
         updated = algo.receive_trajectory(records)
     assert updated and algo.version == 1
+
+
+# -- byte frames stay bytes from the wire to the jitted update --------------
+# (data/batching.padded_obs_dtype; the models cast on entry, on the device)
+
+_FRAME = (12, 12, 2)
+_PIX = 12 * 12 * 2
+_SMALL_CONV = [[8, 4, 2], [8, 3, 1]]
+
+
+def _frames_episode(n, seed, obs_dtype=np.uint8, obs_dim=_PIX, act_dim=3):
+    from relayrl_tpu.types.columnar import DecodedTrajectory
+
+    rng = np.random.default_rng(seed)
+    return DecodedTrajectory(
+        agent_id="a", n_steps=n, n_records=n, marker_truncated=False,
+        columns={"o": rng.integers(0, 256, (n, obs_dim)).astype(obs_dtype),
+                 "a": rng.integers(0, act_dim, (n,)).astype(np.int32),
+                 "r": rng.random(n).astype(np.float32),
+                 "t": np.array([False] * (n - 1) + [True]),
+                 "u": np.zeros((n,), np.uint8),
+                 "x": np.zeros((n,), np.uint8)},
+        aux={"v": rng.standard_normal(n).astype(np.float32),
+             "logp_a": (-1.0 - rng.random(n)).astype(np.float32)})
+
+
+def _build(tmp_path, algo, model, precision="float32", **hp):
+    import json
+
+    cfg = tmp_path / "cfg.json"
+    cfg.write_text(json.dumps({"learner": {"precision": precision}}))
+    if model == "cnn":
+        hp = {"obs_shape": list(_FRAME), "conv_spec": _SMALL_CONV,
+              "dense": 16, **hp}
+    else:
+        hp = {"hidden_sizes": [16], **hp}
+    if algo == "PPO":
+        hp = {"minibatch_count": 2, "train_iters": 2, **hp}
+    return build_algorithm(
+        algo, obs_dim=_PIX, act_dim=3, traj_per_epoch=2,
+        bucket_lengths=[8], seed_salt=0, env_dir=str(tmp_path),
+        config_path=str(cfg),
+        logger_kwargs={"output_dir": str(tmp_path / "logs")}, **hp)
+
+
+def _full_batch(algo, obs_dtype):
+    batch = None
+    for i, n in enumerate((8, 5)):
+        assert batch is None
+        batch = algo.accumulate(_frames_episode(n, i, obs_dtype))
+    return batch
+
+
+class _Compiles:
+    """Compile requests and backend compiles between enter and exit, from
+    jax's monitoring events (as chip_smoke.CompileCounter counts them)."""
+
+    def __enter__(self):
+        from jax import monitoring
+
+        self.requests = self.backend = 0
+        monitoring.register_event_listener(self._event)
+        monitoring.register_event_duration_secs_listener(self._duration)
+        return self
+
+    def __exit__(self, *exc):
+        from jax import monitoring
+
+        monitoring.unregister_event_listener(self._event)
+        monitoring.unregister_event_duration_listener(self._duration)
+
+    def _event(self, event, **_kw):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+
+    def _duration(self, event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.backend += 1
+
+
+class TestUint8Observations:
+    @pytest.mark.parametrize("precision", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("algo,model", [
+        ("IMPALA", "cnn"), ("IMPALA", "mlp"), ("PPO", "cnn"), ("PPO", "mlp"),
+        ("REINFORCE", "mlp")])
+    def test_update_is_bit_identical_to_the_float32_batch(
+            self, tmp_path, algo, model, precision):
+        """0..255 are exact in uint8, float32 and bfloat16, and no update
+        touches ``batch["obs"]`` before the model's cast: one update on
+        the uint8 batch gives the float32 batch's loss and parameters to
+        the bit."""
+        learner = _build(tmp_path, algo, model, precision)
+        u8 = _full_batch(learner, np.uint8)
+        f32 = dict(u8, obs=u8["obs"].astype(np.float32))
+        assert u8["obs"].dtype == np.uint8
+        outs = []
+        for batch in (u8, f32):
+            state = jax.tree_util.tree_map(
+                lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x,
+                learner.state)
+            outs.append(jax.device_get(
+                learner._update(state, learner._to_device(batch))))
+        (s_u8, m_u8), (s_f32, m_f32) = outs
+        assert m_u8.keys() == m_f32.keys()
+        for key in m_u8:
+            assert np.asarray(m_u8[key]).tobytes() == \
+                np.asarray(m_f32[key]).tobytes(), key
+        before = jax.tree_util.tree_leaves(jax.device_get(
+            learner.state.params))
+        got, want = (jax.tree_util.tree_leaves(s.params)
+                     for s in (s_u8, s_f32))
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+        assert any(a.tobytes() != b.tobytes()
+                   for a, b in zip(got, before)), "the update did nothing"
+
+    @pytest.mark.parametrize("algo,model,obs_dtype", [
+        ("IMPALA", "cnn", np.uint8), ("PPO", "cnn", np.uint8),
+        ("IMPALA", "mlp", np.float32), ("REINFORCE", "mlp", np.float32)])
+    def test_first_real_batch_after_warmup_compiles_nothing(
+            self, tmp_path, algo, model, obs_dtype):
+        """Warm-up compiles the signature the stream will use: a
+        ``scale_obs`` pixel learner is fed byte frames, everything else
+        float32."""
+        learner = _build(tmp_path, algo, model)
+        assert learner.mh_zero_batch(2, 8)["obs"].dtype == obs_dtype
+        assert learner.warmup() == 1
+        size = learner._update._cache_size()
+        batch = _full_batch(learner, obs_dtype)
+        assert batch["obs"].dtype == obs_dtype
+        with _Compiles() as seen:
+            learner.train_on_batch(learner.stage_batch(batch))
+            learner.inflight.drain()
+        assert (seen.requests, seen.backend) == (0, 0)
+        assert learner._update._cache_size() == size
+        assert learner.version == 1
+
+    def test_scale_obs_learner_fed_float32_compiles_once_more(
+            self, tmp_path):
+        """envs/atari.py's default obs_dtype is float32: such a stream
+        pays one more compile at its first batch, and from then on the
+        placeholder follows the stream."""
+        learner = _build(tmp_path, "IMPALA", "cnn")
+        learner.warmup()
+        size = learner._update._cache_size()
+        for rnd in range(2):
+            batch = None
+            for i, n in enumerate((8, 5)):
+                batch = learner.accumulate(
+                    _frames_episode(n, 10 * rnd + i, np.float32))
+            learner.train_on_batch(batch)
+        assert learner._update._cache_size() == size + 1
+        assert learner.mh_zero_batch(2, 8)["obs"].dtype == np.float32
+
+    @pytest.mark.parametrize("scale_obs,obs_dtype", [
+        (True, np.uint8), (False, np.float32), (None, np.uint8)])
+    def test_placeholder_goes_by_scale_obs_before_any_data(
+            self, tmp_path, scale_obs, obs_dtype):
+        hp = {} if scale_obs is None else {"scale_obs": scale_obs}
+        learner = _build(tmp_path, "IMPALA", "cnn", **hp)
+        zero = learner.mh_zero_batch(2, 8)
+        assert zero["obs"].dtype == obs_dtype
+        assert zero["obs"].shape == (2, 8, _PIX)
+        # the arch that goes out on the wire is as the user wrote it
+        assert learner.arch.get("scale_obs") == scale_obs
+
+    @pytest.mark.parametrize("model", ["cnn", "mlp"])
+    def test_multihost_placeholder_and_coordinator_batch_agree(
+            self, tmp_path, model):
+        """Non-coordinators build ``mh_zero_batch(B, T)`` from a
+        descriptor that carries only (B, T): under a mesh every batch the
+        coordinator assembles is float32, whatever its actors send, and
+        so is every rank's placeholder — before and after data."""
+        from relayrl_tpu.parallel import make_mesh
+
+        coordinator = _build(tmp_path, "IMPALA", model)
+        other = _build(tmp_path, "IMPALA", model)
+        for learner in (coordinator, other):
+            learner.enable_multihost(make_mesh({"dp": 2}, jax.devices()[:2]))
+        early = other.mh_zero_batch(2, 8)
+        batch = _full_batch(coordinator, np.uint8)
+        late = other.mh_zero_batch(*batch["obs"].shape[:2])
+        for zero in (early, late, coordinator.mh_zero_batch(2, 8)):
+            assert zero.keys() == batch.keys()
+            for key in batch:
+                assert zero[key].dtype == batch[key].dtype, key
+                assert zero[key].shape == batch[key].shape, key
+        assert batch["obs"].dtype == np.float32
+        np.testing.assert_array_equal(
+            batch["obs"][0], _frames_episode(8, 0).columns["o"])
+        coordinator.train_on_batch(batch)
+        coordinator.inflight.drain()
+        assert coordinator.version == 1

@@ -104,6 +104,18 @@ def _assert_pad_parity(actions, decoder, obs_dim=4, act_dim=2, discrete=True,
     return item
 
 
+_PIX = 6 * 6 * 2  # a small frame
+
+
+def _pixel_steps(n, dtype, seed=7):
+    rng = np.random.default_rng(seed)
+    return [ActionRecord(
+        obs=rng.integers(0, 256, _PIX).astype(dtype),
+        act=np.int64(rng.integers(3)), rew=float(rng.random()),
+        data={"logp_a": np.float32(-0.3), "v": np.float32(0.1)},
+        done=(i == n - 1)) for i in range(n)]
+
+
 class TestColumnarParity:
     def test_plain_discrete_episode(self, decoder):
         _assert_pad_parity(_mk_steps(17), decoder)
@@ -121,8 +133,9 @@ class TestColumnarParity:
     def test_uint8_pixel_obs(self, decoder):
         """The byte-sized pixel wire (envs obs_dtype="uint8"): the C++
         columnar decoder must carry uint8 obs columns and the padded
-        learner batch must match the Python path bit-for-bit (pixels
-        0..255 upcast once, at batch build)."""
+        episode must match the Python path bit-for-bit, dtype included:
+        both keep the bytes (pixels 0..255 are cast once, to the compute
+        dtype, on the device, where the model casts on entry)."""
         rng = np.random.default_rng(7)
         obs_dim = 12 * 12 * 2  # small pixel-ish frame, byte range
         steps = [ActionRecord(
@@ -135,6 +148,47 @@ class TestColumnarParity:
         # the decoded column itself must still be bytes, not floats
         assert item.columns["o"].dtype == np.uint8
         np.testing.assert_array_equal(item.columns["o"][0], steps[0].obs)
+        assert pad_decoded(item, 64, obs_dim, 3).obs.dtype == np.uint8
+
+    @pytest.mark.parametrize("src,padded", [
+        ("uint8", np.uint8), ("float32", np.float32),
+        ("float64", np.float32), ("int32", np.float32)])
+    def test_obs_dtype_parity(self, decoder, src, padded):
+        """The wire keeps the env's dtype; both pad paths make the same of
+        it: bytes stay bytes, everything else is float32."""
+        steps = _pixel_steps(9, src)
+        item = _assert_pad_parity(steps, decoder, obs_dim=_PIX, act_dim=3)
+        assert item.columns["o"].dtype == src
+        for got in (pad_decoded(item, 64, _PIX, 3),
+                    pad_trajectory(steps, 64, _PIX, 3)):
+            assert got.obs.dtype == padded
+            np.testing.assert_array_equal(
+                got.obs[:9],
+                np.stack([s.obs for s in steps]).astype(np.float32))
+
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    @pytest.mark.parametrize("src", ["uint8", "float32", "float64"])
+    def test_epoch_batches_equal_across_decode_paths(self, decoder, src,
+                                                     staging_slots):
+        """Natively decoded and Python-decoded episodes of one payload
+        drain to byte-equal batches, obs dtype included."""
+        from relayrl_tpu.data import EpochBuffer
+
+        bufs = [EpochBuffer(obs_dim=_PIX, act_dim=3, traj_per_epoch=3,
+                            buckets=(16, 64), staging_slots=staging_slots)
+                for _ in range(2)]
+        for rnd in range(2):
+            for i, n in enumerate((5, 40, 16)):
+                payload = serialize_actions(
+                    _pixel_steps(n, src, seed=10 * rnd + i))
+                bufs[0].add_episode(decoder.decode(payload, agent_id="a"))
+                bufs[1].add_episode(deserialize_actions(payload))
+            a, b = (buf.drain().as_dict() for buf in bufs)
+            assert a["obs"].dtype == (np.uint8 if src == "uint8"
+                                      else np.float32)
+            for key in a:
+                assert a[key].dtype == b[key].dtype, key
+                assert a[key].tobytes() == b[key].tobytes(), key
 
     def test_terminal_marker(self, decoder):
         steps = _mk_steps(10)
