@@ -6,7 +6,8 @@ One process holds the chip and drives the main path once through the entry
 points a user calls — CPU actor processes -> zmq -> ingest -> jitted update
 on the TPU -> publish -> hot-swap — plus the other device programs the repo
 has (the widest transformer through ``build_algorithm``, the fused anakin
-rollout, a served batch). It checks what comes out, fails on the first thing
+rollout, a served batch, the expert layer's grouped-matmul kernels against
+XLA's own). It checks what comes out, fails on the first thing
 that is wrong (non-zero exit, one ``chip_smoke: FAIL`` line saying why; a
 phase's own exception is never caught), and ends with ONE JSON line:
 
@@ -531,6 +532,91 @@ def phase_c(bundle) -> None:
         f"({outs[0]['wire']}), {time.monotonic() - t0:.0f}s")
 
 
+def phase_e() -> None:
+    """The expert layer's grouped-matmul kernels where they run: the
+    hand-written ``custom_vjp`` of ``ops/grouped_matmul.gmm`` (forward,
+    d_lhs through the transposed stack, d_rhs through ``tgmm``) at
+    ``olmoe-policy.update``'s shapes — 16,384 tokens x top-8 rows of 2048
+    through 64 experts of width 1024 — under a random router's load and
+    under one with empty groups, a group of one row and a group that takes
+    half the rows. Against the formulas written out with XLA's own
+    operations: the value and d_lhs = g W_e^T are ``lax.ragged_dot``
+    forwards, d_rhs[e] = x_e^T g_e a plain matmul over the group's rows,
+    for a handful of groups. Both
+    sides accumulate in float32 and round once to bfloat16, so they may
+    differ by the order of the sums: a few units in the last place of the
+    largest entry."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from relayrl_tpu.ops import grouped_matmul as kernels
+
+    t0 = time.monotonic()
+    m, k, n, n_exp = 16384 * 8, 2048, 1024, 64
+    check(kernels.fits(m, k, n), f"E: the kernels do not tile {(m, k, n)}")
+    rng = np.random.default_rng(0)
+    skewed = np.zeros(n_exp, np.int64)
+    skewed[3], skewed[7] = m // 2, 1
+    rest = m - int(skewed.sum())
+    skewed[32:] = rng.multinomial(rest, np.ones(32) / 32)  # 0..31: 29 empty
+    loads = {"random router": (rng.multinomial(m, np.ones(n_exp) / n_exp),
+                               (0, 1, 31, 32, 63)),
+             "empty groups, 1 row, half the rows": (skewed, (3, 7, 40, 63))}
+    key = jax.random.split(jax.random.PRNGKey(0), 3)
+    lhs = jax.random.normal(key[0], (m, k), jnp.bfloat16)
+    rhs = jax.random.normal(key[1], (n_exp, k, n), jnp.bfloat16) / 32
+    g = jax.random.normal(key[2], (m, n), jnp.bfloat16)
+
+    # the gigabyte operands are arguments: closed over, they would be
+    # constants of the compiled program (minutes of compile, PR 27)
+    @jax.jit
+    def kernel_side(lhs, rhs, g, sizes):
+        out, vjp = jax.vjp(lambda a, b: kernels.gmm(a, b, sizes), lhs, rhs)
+        return (out, *vjp(g))
+
+    @jax.jit
+    def xla_side(lhs, rhs, g, sizes):
+        def ragged(a, b):
+            return jax.lax.ragged_dot(a, b, sizes,
+                                      preferred_element_type=a.dtype)
+
+        return ragged(lhs, rhs), ragged(g, rhs.swapaxes(1, 2))
+
+    def differ(a, b) -> float:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.abs(a - b).max() / jnp.abs(b).max())
+
+    worst = {"value": 0.0, "d_lhs": 0.0, "d_rhs": 0.0}
+    for name, (load, groups) in loads.items():
+        check(int(load.sum()) == m, f"E: load {name!r} sums to {load.sum()}")
+        sizes = jnp.asarray(load, jnp.int32)
+        value, d_lhs, d_rhs = kernel_side(lhs, rhs, g, sizes)
+        errs = dict(zip(("value", "d_lhs"), map(
+            differ, (value, d_lhs), xla_side(lhs, rhs, g, sizes))))
+        start = np.concatenate([[0], np.cumsum(load)])
+        errs["d_rhs"] = max(
+            differ(d_rhs[e], jnp.einsum(
+                "mk,mn->kn", lhs[start[e]:start[e + 1]],
+                g[start[e]:start[e + 1]],
+                preferred_element_type=jnp.float32).astype(d_rhs.dtype))
+            for e in groups)
+        for what, err in errs.items():
+            worst[what] = max(worst[what], err)
+            check(err <= 2.0 ** -6,
+                  f"E: {what} under {name!r} differs from XLA's by "
+                  f"{err:.3g} of its largest entry (limit 2^-6)")
+        check(bool(jnp.isfinite(d_rhs.astype(jnp.float32)).all())
+              and not bool(d_rhs[np.flatnonzero(load == 0)].any()),
+              f"E: d_rhs under {name!r}: non-finite, or an empty group's "
+              f"is not zero")
+    say(f"E: ok — gmm / d_lhs / d_rhs kernels against lax.ragged_dot and "
+        f"per-group matmuls at [{m}, {k}] x [{n_exp}, {k}, {n}], two "
+        f"loads; largest relative difference "
+        f"{json.dumps({w: round(v, 6) for w, v in worst.items()})}, "
+        f"{time.monotonic() - t0:.0f}s")
+
+
 # --------------------------------------------------------------------------
 
 def main() -> None:
@@ -593,6 +679,7 @@ def run(dev: dict, t_start: float) -> None:
     phase_b(config_path)
     phase_b_default_buckets(config_path)
     phase_c(bundle)
+    phase_e()
 
     say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
         f"the persistent cache, {compiles.requests - compiles.hits} compiled "

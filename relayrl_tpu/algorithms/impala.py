@@ -64,6 +64,10 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
                        max_grad_norm: float, freeze=(),
                        params_template=None):
     tx = make_impala_tx(lr, max_grad_norm, freeze, params_template)
+    # a MoE trunk reports the expert load of the same forward
+    # (``moe_load_max`` / ``moe_load_min``); every other family reports {}
+    evaluate = policy.evaluate_stats or (
+        lambda *args: (*policy.evaluate(*args), {}))
 
     def impala_update(state: ImpalaState, batch: Mapping[str, jax.Array]):
         obs, act, act_mask = batch["obs"], batch["act"], batch["act_mask"]
@@ -73,7 +77,7 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
         n_valid = jnp.maximum(jnp.sum(valid), 1.0)
 
         def loss_fn(params):
-            logp, ent, v = policy.evaluate(params, obs, act, act_mask)
+            logp, ent, v, stats = evaluate(params, obs, act, act_mask)
             vt = vtrace(behavior_logp, jax.lax.stop_gradient(logp), rew,
                         jax.lax.stop_gradient(v), valid, gamma,
                         last_val=last_val, rho_bar=rho_bar, c_bar=c_bar)
@@ -81,9 +85,9 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
             vf_loss = jnp.sum(jnp.square(v - vt.vs) * valid) / n_valid
             ent_mean = jnp.sum(ent * valid) / n_valid
             total = pg_loss + vf_coef * vf_loss - ent_coef * ent_mean
-            return total, (pg_loss, vf_loss, ent_mean, vt.rho, logp)
+            return total, (pg_loss, vf_loss, ent_mean, vt.rho, logp, stats)
 
-        (total, (pg_loss, vf_loss, ent_mean, rho, logp_new)), grads = (
+        (total, (pg_loss, vf_loss, ent_mean, rho, logp_new, stats)), grads = (
             jax.value_and_grad(loss_fn, has_aux=True)(state.params))
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)
@@ -97,6 +101,7 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
             "LossTotal": total,
             "RhoMean": rho_mean,
             "KL": kl,
+            **stats,  # moe_load_max / moe_load_min, MoE trunks only
         }
         return ImpalaState(params=params, opt_state=opt_state, rng=state.rng,
                            step=state.step + 1), metrics
@@ -158,6 +163,22 @@ class IMPALA(OnPolicyAlgorithm):
             max_grad_norm=max_grad_norm, freeze=freeze,
             params_template=net_params)
         self._update = jax.jit(update, donate_argnums=0)
+        if self.policy.evaluate_stats is not None:
+            from relayrl_tpu import telemetry
+
+            reg = telemetry.get_registry()
+            self._metric_gauges = {
+                "moe_load_max": reg.gauge(
+                    "relayrl_moe_load_max",
+                    "fullest expert's share of the token-slots, newest "
+                    "update, max over MoE layers (1/E at even load)"),
+                "moe_load_min": reg.gauge(
+                    "relayrl_moe_load_min",
+                    "emptiest expert's share of the token-slots, newest "
+                    "update, min over MoE layers"),
+            }
+            self._fence_notes = ("moe_load_max",)
 
     def _log_keys(self):
-        return ("LossPi", "LossV", "Entropy", "RhoMean", "KL")
+        keys = ("LossPi", "LossV", "Entropy", "RhoMean", "KL")
+        return keys + tuple(self._metric_gauges)

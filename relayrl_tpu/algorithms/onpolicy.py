@@ -61,6 +61,13 @@ class OnPolicyAlgorithm(AlgorithmBase):
         salt = int(params.get("seed_salt", os.getpid()))
         rng = jax.random.fold_in(jax.random.PRNGKey(seed), salt)
 
+        # Update metrics mirrored into registry gauges at dispatch, as
+        # in-flight device scalars (``Gauge.set`` never fences), and those
+        # of them written on the update's fence span while a profiler
+        # records (each has a reader: docs/observability.md): filled by a
+        # subclass whose update reports more than losses.
+        self._metric_gauges: dict[str, Any] = {}
+        self._fence_notes: tuple[str, ...] = ()
         # Subclass: sets self.arch, self.policy, self.state, self._update.
         self._setup(params, learner, rng)
 
@@ -204,9 +211,12 @@ class OnPolicyAlgorithm(AlgorithmBase):
                 self.state, metrics = self._update(
                     self.state, self._to_device(host_batch))
             self._dispatched_updates += 1
+            for key, gauge in self._metric_gauges.items():
+                gauge.set(metrics[key])
             metrics = self._guard_merge_probes(metrics, probe_base)
             self._last_metrics = LazyMetrics(metrics)
-            self.inflight.push(metrics, version=self.dispatched_version)
+            self.inflight.push(metrics, version=self.dispatched_version,
+                               note=self._fence_notes)
         return self._last_metrics
 
     def train_model(self) -> Mapping[str, float]:

@@ -49,6 +49,9 @@ ARCH_PASSTHROUGH_KEYS = (
     "d_model", "n_layers", "n_heads", "mlp_ratio", "max_seq_len",
     "attention", "attention_block", "actor_context",
     "moe_experts", "moe_top_k", "pp_microbatches",
+    # the block the arch describes (models/transformer._BLOCK_ARCH_KEYS)
+    "norm", "norm_eps", "positions", "rope_theta", "qk_norm", "use_bias",
+    "ffn", "d_ff", "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch",
 )
 
 
@@ -119,6 +122,11 @@ class Policy:
     # fills it at trace time) — which implementation a platform-dependent
     # ``attention`` config actually compiled to. None for other families.
     attention_backends: Mapping[tuple, str] | None = None
+    # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
+    # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward
+    # (``moe_load_max`` / ``moe_load_min``: models/moe.load_extremes) for
+    # the update's metrics. None for every other family.
+    evaluate_stats: Callable | None = None
 
     @property
     def input_dim(self) -> int:

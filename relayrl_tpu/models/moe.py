@@ -1,4 +1,4 @@
-"""Mixture-of-experts MLP with per-token top-k routing (the ``ep`` family).
+"""Mixture-of-experts FFN with per-token top-k routing (the ``ep`` family).
 
 No counterpart exists in the reference (its only models are 2x128 MLPs —
 SURVEY.md §2.5); this is a TPU-first capacity-scaling component: the
@@ -6,28 +6,57 @@ transformer block's FFN becomes E experts whose stacked weights shard over
 the mesh ``ep`` axis (rule in parallel/sharding.py), so parameter capacity
 scales with devices.
 
-Routing is **per-token top-k** (default k=2): each token's gate picks its
-own experts from its own features alone, so routing is exactly causal and
-IDENTICAL between training batches and single-window actor serving — a
-hard requirement for RL policies, where logp at step t must condition only
-on history (capacity-competition schemes like expert-choice or
-token-dropping leak future timesteps / sibling sequences into the gate and
-bias the policy gradient).
+Routing is **per-token top-k**: each token's router picks its own experts
+from its own features alone, so routing is exactly causal and IDENTICAL
+between training batches and single-window actor serving — a hard
+requirement for RL policies, where logp at step t must condition only on
+history (capacity-competition schemes like expert-choice or token-dropping
+leak future timesteps / sibling sequences into the gate and bias the
+policy gradient). The router runs in float32 over all E experts; two
+weightings (arch ``moe_norm_topk_prob``):
 
-Dispatch is dense: every expert runs on every token and the top-k mask
-zeroes the rest in the combine einsum. That spends E× the FFN FLOPs of a
-capacity-based sparse dispatch — the honest tradeoff at RL model scale,
-where exactness beats the flop savings; under GSPMD each ``ep`` shard
-computes only its own experts and the combine contracts over E with a
-psum. A sparse gather/scatter dispatch is a later optimization for models
-where the FFN dominates.
+* ``True`` (default) — top-k of the logits, softmax over the k chosen
+  (equal to softmax over all E, top-k, renormalised);
+* ``False`` — softmax over all E, top-k, the k probabilities used as they
+  are (OLMoE's ``norm_topk_prob: false``).
+
+Experts are GELU (``moe_w_up`` / ``moe_w_down``, the default) or SwiGLU
+(arch ``ffn: "swiglu"``: ``moe_w_gate`` beside them,
+``down(silu(gate(x)) * up(x))``), of width ``moe_d_ff``.
+
+Dispatch is **sparse** on one device: the
+N·k token-slots are sorted by expert, each expert's rows go through one
+grouped matmul per weight stack (group sizes = a bincount of the chosen
+experts), and the down output is un-sorted and summed per token with the
+router weights. Every shape is static (N·k rows whatever the imbalance),
+there is no capacity and so **no token-slot is ever dropped**: an expert
+that every token picks gets all N rows, an expert nobody picks gets a group
+of size 0. The FFN costs k/E of running every expert on every token. Both
+permutations are gathers, forward and backward (a permutation's transpose
+is the gather by its inverse), because a TPU scatter-add of [N·k, d] rows
+is slower than the matmuls it serves.
+
+The **dense** path — every expert on every token, a dense ``[N, E]`` weight
+mask in the combine einsum, E/k times the FLOPs — is what GSPMD partitions:
+each ``ep`` shard computes its own experts and the combine contracts over E
+with a psum. The sparse path is a single-device program: its grouped
+matmuls are Pallas calls, which GSPMD does not partition, so under an
+``ep`` mesh every chip would have to hold every expert stack and ``ep``
+would scale nothing (not measured: no multi-chip cell yet). The layer
+therefore picks by what it can observe where it is traced: dense under an
+ambient mesh (``parallel/context.py``) whose ``ep`` axis is larger than 1,
+sparse everywhere else; an all-to-all dispatch under ``shard_map`` is the
+multi-chip follow-up (ROADMAP 2.7). Arch ``moe_dispatch`` (``"sparse"`` |
+``"dense"``) overrides the pick for ``tests/test_moe.py``, which compares
+the two paths (forward and every gradient); ``"sparse"`` under an ``ep``
+mesh is refused. No benchmark cell takes the dense path.
 
 No auxiliary load-balancing loss is applied (see
 :func:`expert_utilization` for the rationale and the monitoring hook for
 the gate-collapse failure mode that omission leaves open).
 
 Shapes: tokens flatten to ``[N = B*T, d]``; expert stacks are
-``moe_w_up [E, d, ff]`` / ``moe_w_down [E, ff, d]``.
+``moe_w_up`` / ``moe_w_gate [E, d, ff]`` and ``moe_w_down [E, ff, d]``.
 """
 
 from __future__ import annotations
@@ -39,81 +68,215 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 
+def route(logits, k: int, norm_topk_prob: bool):
+    """Router logits ``[N, E]`` (float32) -> (weights ``[N, k]``, expert
+    indices ``[N, k]``); a token's row depends on that token alone."""
+    if norm_topk_prob:
+        top_vals, top_idx = jax.lax.top_k(logits, k)
+        return jax.nn.softmax(top_vals, axis=-1), top_idx
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+
+@jax.custom_vjp
+def _dispatch_rows(tokens, token_of_row, slot_to_row):
+    """``tokens[token_of_row]``: each token's row once per chosen expert,
+    in expert order. Its transpose is a gather too: cotangent rows back in
+    slot order (``slot_to_row``), the k of each token summed."""
+    return _dispatch_fwd(tokens, token_of_row, slot_to_row)[0]
+
+
+def _dispatch_fwd(tokens, token_of_row, slot_to_row):
+    out = jnp.take(tokens, token_of_row, axis=0)
+    return out, (slot_to_row, tokens.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    slot_to_row, n = res
+    g = jnp.take(g, slot_to_row, axis=0)
+    return g.reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort_rows(rows, slot_to_row, row_to_slot):
+    """``rows[slot_to_row]``: expert-ordered rows back in slot order
+    (token-major, k to a token); transpose = the gather by the inverse."""
+    return _unsort_fwd(rows, slot_to_row, row_to_slot)[0]
+
+
+def _unsort_fwd(rows, slot_to_row, row_to_slot):
+    return jnp.take(rows, slot_to_row, axis=0), row_to_slot
+
+
+def _unsort_bwd(row_to_slot, g):
+    return jnp.take(g, row_to_slot, axis=0), None, None
+
+
+_unsort_rows.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``[m, k] x [E, k, n] -> [m, n]`` in ``lhs``'s dtype, float32
+    accumulation: row block i of ``lhs`` (``group_sizes[i]`` rows) times
+    ``rhs[i]``. On a TPU, where the shapes tile, the Pallas kernels of
+    ``ops/grouped_matmul.py``; everywhere else (CPU actor hosts, CI, a
+    handful of decode rows) XLA's ``ragged_dot`` — the same rule as
+    ``attention: "flash"``."""
+    if jax.default_backend() == "tpu":
+        from relayrl_tpu.ops import grouped_matmul as kernels
+
+        if kernels.fits(lhs.shape[0], rhs.shape[1], rhs.shape[2]):
+            return kernels.gmm(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+
+
+def _mesh_ep() -> int:
+    """Size of the ``ep`` axis of the mesh this trace runs under (1: none).
+    A mesh can be ambient only where ``parallel/context.py`` is loaded;
+    importing it here would load the whole ``parallel`` package (and
+    Pallas with it) into a CPU actor that holds this arch."""
+    import sys
+
+    context = sys.modules.get("relayrl_tpu.parallel.context")
+    mesh = context.current_mesh() if context else None
+    return 1 if mesh is None else int(mesh.shape.get("ep", 1))
+
+
 class MoEMLP(nn.Module):
-    """Per-token top-k MoE FFN over flattened tokens (dense dispatch)."""
+    """Per-token top-k MoE FFN over flattened tokens."""
 
     d_model: int
     d_ff: int
     n_experts: int
     top_k: int
     compute_dtype: Any
+    norm_topk_prob: bool = True
+    ffn: str = "gelu"
+    dispatch: str | None = None     # None: by the ambient mesh's ep axis
+    use_bias: bool = True      # the router's; the expert stacks have none
 
     @nn.compact
     def __call__(self, x):
         B, T, d = x.shape
         n = B * T
-        k = max(1, min(self.top_k, self.n_experts))
+        n_exp = self.n_experts
+        k = max(1, min(self.top_k, n_exp))
+        cd = self.compute_dtype
         tokens = x.reshape(n, d)
 
-        # Gate in f32; per-token top-k -> renormalized combine weights,
-        # scattered back to a dense [N, E] mask (static shapes, XLA-safe).
-        gate = nn.Dense(self.n_experts, dtype=jnp.float32, name="moe_gate")(
-            tokens.astype(jnp.float32))
-        top_vals, top_idx = jax.lax.top_k(gate, k)          # [N, k]
-        top_w = jax.nn.softmax(top_vals, axis=-1)           # [N, k]
-        weights = jnp.zeros((n, self.n_experts), jnp.float32)
-        weights = weights.at[
-            jnp.arange(n)[:, None], top_idx].set(top_w)     # [N, E]
+        logits = nn.Dense(n_exp, dtype=jnp.float32, use_bias=self.use_bias,
+                          name="moe_gate")(tokens.astype(jnp.float32))
+        top_w, top_idx = route(logits, k, self.norm_topk_prob)   # [N, k]
 
-        w_up = self.param(
-            "moe_w_up", nn.initializers.lecun_normal(batch_axis=(0,)),
-            (self.n_experts, d, self.d_ff), jnp.float32)
-        w_down = self.param(
-            "moe_w_down", nn.initializers.lecun_normal(batch_axis=(0,)),
-            (self.n_experts, self.d_ff, d), jnp.float32)
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        gated = self.ffn == "swiglu"
+        if gated:
+            w_gate = self.param("moe_w_gate", init, (n_exp, d, self.d_ff),
+                                jnp.float32).astype(cd)
+        w_up = self.param("moe_w_up", init, (n_exp, d, self.d_ff),
+                          jnp.float32).astype(cd)
+        w_down = self.param("moe_w_down", init, (n_exp, self.d_ff, d),
+                            jnp.float32).astype(cd)
 
-        # Monitoring hook: per-expert share of combine mass (weights sum to
-        # 1 per token, so load/ n == fraction of routing mass per expert).
-        # Inert unless applied with mutable=["intermediates"] — see
-        # expert_utilization() below.
-        self.sow("intermediates", "expert_load", weights.sum(axis=0))
+        def act(up, gate=None):
+            return nn.silu(gate) * up if gated else nn.gelu(up)
 
-        h = jnp.einsum("nd,edf->enf", tokens.astype(self.compute_dtype),
-                       w_up.astype(self.compute_dtype),
-                       preferred_element_type=jnp.float32)
-        h = nn.gelu(h)
-        out = jnp.einsum("enf,efd->end", h.astype(self.compute_dtype),
-                         w_down.astype(self.compute_dtype),
-                         preferred_element_type=jnp.float32)
-        y = jnp.einsum("ne,end->nd", weights, out)          # psum over ep
+        # token-slots per expert: the grouped matmuls' group sizes and the
+        # load monitor (a compare-and-reduce; a scatter-add serialises)
+        load = (top_idx[..., None] == jnp.arange(n_exp)).sum(
+            axis=(0, 1), dtype=jnp.int32)
+
+        dispatch = self.dispatch or ("dense" if _mesh_ep() > 1 else "sparse")
+        if dispatch == "dense":
+            weights = jnp.zeros((n, n_exp), jnp.float32).at[
+                jnp.arange(n)[:, None], top_idx].set(top_w)      # [N, E]
+            xs = tokens.astype(cd)
+
+            def up_proj(w):
+                return jnp.einsum("nd,edf->enf", xs, w,
+                                  preferred_element_type=jnp.float32)
+
+            h = act(up_proj(w_up), up_proj(w_gate) if gated else None)
+            out = jnp.einsum("enf,efd->end", h.astype(cd), w_down,
+                             preferred_element_type=jnp.float32)
+            y = jnp.einsum("ne,end->nd", weights, out)       # psum over ep
+        elif dispatch == "sparse":
+            if _mesh_ep() > 1:
+                raise ValueError(
+                    f"moe_dispatch 'sparse' under a mesh with ep="
+                    f"{_mesh_ep()}: the sparse dispatch is a single-device "
+                    f"program (GSPMD does not partition its Pallas calls "
+                    f"over ep); leave moe_dispatch unset and the layer "
+                    f"takes the dense path, which GSPMD does partition")
+            # slot s = token s // k, choice s % k; rows = slots by expert
+            expert_of_slot = top_idx.reshape(n * k)
+            row_to_slot = jnp.argsort(expert_of_slot, stable=True)
+            slot_to_row = jnp.zeros_like(row_to_slot).at[row_to_slot].set(
+                jnp.arange(n * k, dtype=row_to_slot.dtype),
+                unique_indices=True)
+            xs = _dispatch_rows(tokens.astype(cd), row_to_slot // k,
+                                slot_to_row)                      # [N*k, d]
+
+            def up_proj(w):
+                return grouped_matmul(xs, w, load).astype(jnp.float32)
+
+            h = act(up_proj(w_up), up_proj(w_gate) if gated else None)
+            out = grouped_matmul(h.astype(cd), w_down, load)      # [N*k, d]
+            out = _unsort_rows(out, slot_to_row, row_to_slot)
+            y = jnp.einsum("nk,nkd->nd", top_w,
+                           out.reshape(n, k, d).astype(jnp.float32))
+        else:
+            raise ValueError(f"unknown moe_dispatch {dispatch!r}")
+
+        # Monitoring hook: token-slots per expert (sums to N*k). Inert
+        # unless applied with mutable=["intermediates"] — the update's
+        # moe_load_max/min and expert_utilization() read it.
+        self.sow("intermediates", "expert_load", load)
         return y.reshape(B, T, d).astype(x.dtype)
 
 
+def _shares(intermediates) -> dict:
+    """``{layer: [E] shares of the token-slots}`` from one applied forward's
+    sown ``expert_load`` counts."""
+    out = {}
+    for layer, sub in intermediates.items():
+        if layer.startswith("block_") and "moe" in sub:
+            load = sub["moe"]["expert_load"][0].astype(jnp.float32)
+            out[layer] = load / jnp.maximum(load.sum(), 1.0)
+    return out
+
+
+def load_extremes(intermediates) -> dict:
+    """``{"moe_load_max", "moe_load_min"}``: the fullest and the emptiest
+    expert's share of the token-slots, over every MoE layer of one applied
+    forward (nothing is recomputed). 1/E each at even load; max -> 1/k is
+    the gate collapsing."""
+    shares = jnp.stack(list(_shares(intermediates).values()))
+    return {"moe_load_max": shares.max(), "moe_load_min": shares.min()}
+
+
 def expert_utilization(arch, params, obs, mask=None) -> dict:
-    """Per-layer routing-mass fraction per expert — the gate-collapse
+    """Per-layer share of the token-slots per expert — the gate-collapse
     monitor.
 
     No auxiliary load-balancing loss is applied during training (a
-    deliberate omission: at RL model scale the dense dispatch keeps
-    collapsed gates *correct*, just wasteful, and an aux loss would have to
-    be plumbed through every algorithm's update). The standard top-k
-    failure mode — the gate collapsing onto a few experts — is therefore
-    something to MONITOR: call this on a representative batch and alarm
-    when the max fraction nears 1.0.
+    deliberate omission: no token is ever dropped, so a collapsed gate is
+    *correct*, just slow — its experts' groups grow and the others' shrink
+    — and an aux loss would have to be plumbed through every algorithm's
+    update). The standard top-k failure mode — the gate collapsing onto a
+    few experts — is therefore something to MONITOR: the IMPALA update
+    reports the extremes of this every update (``moe_load_max`` /
+    ``moe_load_min``); call this on a representative batch for the whole
+    distribution, and alarm when the max fraction nears 1/k.
 
     Returns ``{layer_name: [E] fractions summing to 1}``.
     """
-    import jax.numpy as _jnp
-
     from relayrl_tpu.models.transformer import _make_core
 
     core = _make_core(arch, moe_experts=int(arch.get("moe_experts", 4)))
-    _, state = core.apply(params, _jnp.asarray(obs), mask,
+    _, state = core.apply(params, jnp.asarray(obs), mask,
                           mutable=["intermediates"])
-    out = {}
-    for layer, sub in state["intermediates"].items():
-        if not layer.startswith("block_"):
-            continue
-        load = sub["moe"]["expert_load"][0]
-        out[layer] = load / _jnp.maximum(load.sum(), 1e-9)
-    return out
+    return _shares(state["intermediates"])

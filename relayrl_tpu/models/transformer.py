@@ -19,6 +19,16 @@ four attention backends selected by arch config:
                     (the heterogeneous-placement requirement of SURVEY.md
                     §7.4 item 2).
 
+The block is described by the arch, not by knobs: ``norm`` (``"layer"`` |
+``"rms"``) with ``norm_eps``, ``positions`` (``"learned"`` table |
+``"rope"`` with ``rope_theta``, applied to q and k), ``qk_norm``,
+``use_bias``, ``ffn`` (``"gelu"`` | ``"swiglu"``) with ``d_ff``. With none
+of them given it is the GPT-2 shaped block (LayerNorm, learned positions,
+biases, GELU FFN of ``mlp_ratio * d_model``) — the same parameter tree and
+operations as before these keys existed. OLMoE-1B-7B's layer is
+``transformer_moe_discrete`` with rms / rope / qk_norm / no bias / swiglu
+(``benchmark/configs/olmoe-policy.json``).
+
 Sequence ABI: ``evaluate(params, obs[B,T,D], act[B,T], mask[B,T,A]) ->
 (logp[B,T], ent[B,T], v[B,T])`` — same shapes the per-step MLP family
 broadcasts to, so REINFORCE/PPO updates take this policy unchanged.
@@ -33,6 +43,7 @@ from typing import Any, Callable, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
+import flax
 from flax import linen as nn
 
 from relayrl_tpu.models.base import Policy, register_model
@@ -133,6 +144,67 @@ def _resolve_attention(arch: Mapping[str, Any]) -> tuple[Callable, dict]:
     raise ValueError(f"unknown attention kind {kind!r}")
 
 
+def _norm(arch_norm: str, eps, name: str):
+    """The arch's normalisation layer in float32: ``"layer"`` (LayerNorm,
+    scale + bias) or ``"rms"`` (RMSNorm, scale only). ``eps=None`` keeps
+    flax's default, 1e-6 — what every arch without ``norm_eps`` has always
+    run."""
+    if arch_norm not in ("layer", "rms"):
+        raise ValueError(f"unknown norm {arch_norm!r} (layer | rms)")
+    cls = nn.LayerNorm if arch_norm == "layer" else nn.RMSNorm
+    kw = {} if eps is None else {"epsilon": float(eps)}
+    return cls(dtype=jnp.float32, name=name, **kw)
+
+
+def apply_rope(x, start, theta: float):
+    """Rotary position embedding on ``x [B, T, H, hd]`` whose row j sits at
+    absolute position ``start + j`` (``start`` may be traced): pairs
+    (i, i + hd/2) rotate by ``pos * theta^(-2i/hd)`` — the half-split
+    convention of the published ``olmoe`` / GPT-NeoX code. Angles in
+    float32, result in ``x``'s dtype."""
+    hd = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    pos = jnp.asarray(start, jnp.float32) + jnp.arange(
+        x.shape[1], dtype=jnp.float32)
+    ang = pos[:, None] * inv_freq[None, :]                  # [T, hd/2]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _block_dense(block: "TransformerBlock", features: int, name: str):
+    return nn.Dense(features, dtype=block.compute_dtype, name=name,
+                    use_bias=block.use_bias)
+
+
+def _block_ffn(block: "TransformerBlock", x):
+    """``x + FFN(norm(x))`` in ``block``'s param scope: the arch's dense
+    FFN or the MoE layer. (A plain function, like :func:`_embed_obs`: a
+    module method would be wrapped by flax once per call.)"""
+    h = _norm(block.norm, block.norm_eps, "ln_mlp")(x)
+    width = block.d_ff or block.mlp_ratio * block.d_model
+    if block.ffn not in ("gelu", "swiglu"):
+        raise ValueError(f"unknown ffn {block.ffn!r} (gelu | swiglu)")
+    if block.moe_experts > 0:
+        from relayrl_tpu.models.moe import MoEMLP
+
+        h = MoEMLP(block.d_model, block.moe_d_ff or width,
+                   block.moe_experts, block.moe_top_k, block.compute_dtype,
+                   norm_topk_prob=block.moe_norm_topk_prob, ffn=block.ffn,
+                   dispatch=block.moe_dispatch, use_bias=block.use_bias,
+                   name="moe")(h)
+        return x + h.astype(x.dtype)
+    h = h.astype(block.compute_dtype)
+    up = _block_dense(block, width, "mlp_up")(h)
+    if block.ffn == "swiglu":
+        h = nn.silu(_block_dense(block, width, "mlp_gate")(h)) * up
+    else:
+        h = nn.gelu(up)
+    h = _block_dense(block, block.d_model, "mlp_down")(h)
+    return x + h.astype(x.dtype)
+
+
 class TransformerBlock(nn.Module):
     d_model: int
     n_heads: int
@@ -145,6 +217,21 @@ class TransformerBlock(nn.Module):
     # dense family are unchanged.
     moe_experts: int = 0
     moe_top_k: int = 2
+    # What the arch says of the model's block; every default is the GPT-2
+    # shaped block this family has always built (same parameter tree, same
+    # operations): LayerNorm at flax's epsilon, no rotary positions (the
+    # core adds a learned table), no QK-norm, biases, a GELU FFN of
+    # mlp_ratio * d_model.
+    norm: str = "layer"
+    norm_eps: float | None = None
+    rope_theta: float | None = None     # set = RoPE on q and k
+    qk_norm: bool = False
+    use_bias: bool = True
+    ffn: str = "gelu"                   # | "swiglu" (mlp_gate beside mlp_up)
+    d_ff: int | None = None             # FFN width; None = mlp_ratio * d
+    moe_d_ff: int | None = None         # one expert's width; None = d_ff
+    moe_norm_topk_prob: bool = True
+    moe_dispatch: str | None = None     # None: models/moe.py picks
 
     @nn.compact
     def __call__(self, x, cache=None, t=None, readout_idx=None):
@@ -166,32 +253,42 @@ class TransformerBlock(nn.Module):
         readout row — the dead (W-1)/W of the final block's compute that
         the full path pays per actor step. Returns ``[B, 1, d]``. The
         row's attention is computed densely (a 1-row query is trivially
-        dense; every backend computes the same causal function)."""
+        dense; every backend computes the same causal function).
+
+        With ``rope_theta`` set, q and k are rotated (after QK-norm) at
+        their absolute positions in all three modes: rows ``0..T-1`` of a
+        window, row ``readout_idx`` for the readout query, ``t + j`` in
+        decode mode — the cache holds rotated keys."""
         B, T, _ = x.shape
         head_dim = self.d_model // self.n_heads
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(x)
+        h = _norm(self.norm, self.norm_eps, "ln_attn")(x)
         h = h.astype(self.compute_dtype)
-        qkv = nn.Dense(3 * self.d_model, dtype=self.compute_dtype,
-                       name="qkv")(h)
+        qkv = _block_dense(self, 3 * self.d_model, "qkv")(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        if self.qk_norm:
+            # over the whole d_model-wide projection, before the heads
+            q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
+                self.compute_dtype)
+            k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
+                self.compute_dtype)
         shape = (B, T, self.n_heads, head_dim)
         q, k, v = (a.reshape(shape) for a in (q, k, v))
+        rope = self.rope_theta is not None
+        if rope:
+            k = apply_rope(k, 0 if t is None else t, self.rope_theta)
         if readout_idx is not None:
             q_row = jax.lax.dynamic_slice_in_dim(q, readout_idx, 1, axis=1)
+            if rope:
+                q_row = apply_rope(q_row, readout_idx, self.rope_theta)
             attn = dense_attention(q_row, k, v, causal=True,
                                    q_offset=readout_idx)
             attn = attn.reshape(B, 1, self.d_model)
             x = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1, axis=1)
-            x = x + nn.Dense(self.d_model, dtype=self.compute_dtype,
-                             name="attn_out")(attn).astype(x.dtype)
-            h = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x)
-            h = h.astype(self.compute_dtype)
-            h = nn.Dense(self.mlp_ratio * self.d_model,
-                         dtype=self.compute_dtype, name="mlp_up")(h)
-            h = nn.gelu(h)
-            h = nn.Dense(self.d_model, dtype=self.compute_dtype,
-                         name="mlp_down")(h)
-            return x + h.astype(x.dtype)
+            x = x + _block_dense(self, self.d_model, "attn_out")(
+                attn).astype(x.dtype)
+            return _block_ffn(self, x)
+        if rope:
+            q = apply_rope(q, 0 if t is None else t, self.rope_theta)
         if cache is None:
             attn = self.attn_fn(q, k, v)
             new_cache = None
@@ -209,45 +306,35 @@ class TransformerBlock(nn.Module):
                                    q_offset=t)
             new_cache = (k_cache, v_cache)
         attn = attn.reshape(B, T, self.d_model)
-        x = x + nn.Dense(self.d_model, dtype=self.compute_dtype,
-                         name="attn_out")(attn).astype(x.dtype)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x)
-        if self.moe_experts > 0:
-            from relayrl_tpu.models.moe import MoEMLP
-
-            h = MoEMLP(self.d_model, self.mlp_ratio * self.d_model,
-                       self.moe_experts, self.moe_top_k,
-                       self.compute_dtype, name="moe")(h)
-            out = x + h.astype(x.dtype)
-        else:
-            h = h.astype(self.compute_dtype)
-            h = nn.Dense(self.mlp_ratio * self.d_model,
-                         dtype=self.compute_dtype, name="mlp_up")(h)
-            h = nn.gelu(h)
-            h = nn.Dense(self.d_model, dtype=self.compute_dtype,
-                         name="mlp_down")(h)
-            out = x + h.astype(x.dtype)
+        x = x + _block_dense(self, self.d_model, "attn_out")(attn).astype(
+            x.dtype)
+        out = _block_ffn(self, x)
         return out if cache is None else (out, new_cache)
 
 
 def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
-               start=0):
+               start=0, learned_positions: bool = True):
     """Obs embedding + positional table, built in the CALLER's param scope
     (layer names land flat: obs_embed / pos_embed) — the single source of
     truth shared by TransformerCore (full AND cached-decode modes, which
     differ only in the ``start`` position) and the pipeline family's
-    _PPEmbed."""
+    _PPEmbed. With rotary positions (``learned_positions=False``) the
+    blocks place the tokens and there is no ``pos_embed`` leaf."""
     _, T, _ = obs.shape
     x = nn.Dense(d_model, dtype=jnp.float32, name="obs_embed")(obs)
+    if not learned_positions:
+        return x
     pos = parent.param("pos_embed", nn.initializers.normal(0.02),
                        (max_seq_len, d_model), jnp.float32)
     return x + jax.lax.dynamic_slice_in_dim(pos, start, T, axis=0)[None]
 
 
-def _readout_heads(x, mask, act_dim: int, d_model: int, has_critic: bool):
-    """Final LN + pi/vf heads in the caller's scope (shared with _PPReadout;
-    the vf optimizer partition keys off these exact `vf*` names)."""
-    x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
+def _readout_heads(x, mask, act_dim: int, d_model: int, has_critic: bool,
+                   norm: str = "layer", norm_eps=None):
+    """Final norm (the arch's kind and epsilon, as the blocks') + pi/vf
+    heads in the caller's scope (shared with _PPReadout; the vf optimizer
+    partition keys off these exact `vf*` names)."""
+    x = _norm(norm, norm_eps, "ln_final")(x)
     logits = nn.Dense(act_dim, dtype=jnp.float32, name="pi_head")(x)
     if mask is not None:
         logits = jnp.where(mask > 0, logits, _MASK_FILL)
@@ -279,6 +366,8 @@ class TransformerCore(nn.Module):
     compute_dtype: Any
     moe_experts: int = 0
     moe_top_k: int = 2
+    # TransformerBlock's arch fields, passed through as one dict
+    block_kw: Mapping[str, Any] = flax.core.FrozenDict()
 
     @nn.compact
     def __call__(self, obs, mask=None, cache=None, t=None, readout_t=None):
@@ -293,24 +382,32 @@ class TransformerCore(nn.Module):
         the heads see the one row; returns ``(logits[B, A], v[B])``. Init
         always traces the full path, so all modes share one param tree."""
         decode = cache is not None
-        x = _embed_obs(self, obs, self.d_model, self.max_seq_len,
-                       start=t if decode else 0)
+        kw = self.block_kw
+
+        def block_at(i: int) -> TransformerBlock:
+            return TransformerBlock(
+                self.d_model, self.n_heads, self.mlp_ratio, self.attn_fn,
+                self.compute_dtype, moe_experts=self.moe_experts,
+                moe_top_k=self.moe_top_k, name=f"block_{i}", **kw)
+
+        def heads(x, mask):
+            return _readout_heads(x, mask, self.act_dim, self.d_model,
+                                  self.has_critic, kw.get("norm", "layer"),
+                                  kw.get("norm_eps"))
+
+        x = _embed_obs(
+            self, obs, self.d_model, self.max_seq_len,
+            start=t if decode else 0,
+            learned_positions=kw.get("rope_theta") is None)
         if readout_t is not None:
             idx = jnp.asarray(readout_t, jnp.int32)
             for i in range(self.n_layers - 1):
-                x = TransformerBlock(
-                    self.d_model, self.n_heads, self.mlp_ratio,
-                    self.attn_fn, self.compute_dtype,
-                    moe_experts=self.moe_experts,
-                    moe_top_k=self.moe_top_k, name=f"block_{i}")(x)
-            final = TransformerBlock(
-                self.d_model, self.n_heads, self.mlp_ratio, self.attn_fn,
-                self.compute_dtype, moe_experts=self.moe_experts,
-                moe_top_k=self.moe_top_k,
-                name=f"block_{self.n_layers - 1}")
+                x = block_at(i)(x)
+            final = block_at(self.n_layers - 1)
             if self.moe_experts > 0:
-                # MoE routing is a cross-token decision — no per-row
-                # shortcut; run the block whole and slice the row.
+                # The MoE final block keeps its full-window pass (routing
+                # is per token, so the sliced row is what a row-only pass
+                # would give; the shortcut is simply not taken here).
                 x = jax.lax.dynamic_slice_in_dim(final(x), idx, 1, axis=1)
             else:
                 x = final(x, readout_idx=idx)
@@ -318,23 +415,18 @@ class TransformerCore(nn.Module):
             if mask is not None:
                 mask_row = jax.lax.dynamic_slice_in_dim(mask, idx, 1,
                                                         axis=1)
-            logits, v = _readout_heads(x, mask_row, self.act_dim,
-                                       self.d_model, self.has_critic)
+            logits, v = heads(x, mask_row)
             return logits[:, 0], v[:, 0]
         new_cache = []
         for i in range(self.n_layers):
-            block = TransformerBlock(
-                self.d_model, self.n_heads, self.mlp_ratio, self.attn_fn,
-                self.compute_dtype, moe_experts=self.moe_experts,
-                moe_top_k=self.moe_top_k, name=f"block_{i}")
+            block = block_at(i)
             if decode:
                 x, layer_cache = block(x, cache=cache[i], t=t)
                 new_cache.append(layer_cache)
             else:
                 x = block(x)
-        heads = _readout_heads(x, mask, self.act_dim, self.d_model,
-                               self.has_critic)
-        return (heads, tuple(new_cache)) if decode else heads
+        out = heads(x, mask)
+        return (out, tuple(new_cache)) if decode else out
 
 
 def _as_btd(obs, mask):
@@ -430,6 +522,23 @@ def _policy_from_apply(arch: Mapping[str, Any], init_params, apply_fn,
                   mode_window=mode_window)
 
 
+# Arch keys that describe the model's block (TransformerBlock's fields of
+# the same names; ``positions`` + ``rope_theta`` become its ``rope_theta``).
+# An arch with none of them is the GPT-2 shaped block.
+_BLOCK_ARCH_KEYS = ("norm", "norm_eps", "qk_norm", "use_bias", "ffn", "d_ff",
+                    "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch")
+
+
+def _block_kwargs(arch: Mapping[str, Any]) -> dict:
+    kw = {k: arch[k] for k in _BLOCK_ARCH_KEYS if k in arch}
+    positions = arch.get("positions", "learned")
+    if positions == "rope":
+        kw["rope_theta"] = float(arch.get("rope_theta", 10000.0))
+    elif positions != "learned":
+        raise ValueError(f"unknown positions {positions!r} (learned | rope)")
+    return kw
+
+
 def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
                attn_fn: Callable | None = None) -> TransformerCore:
     """Arch -> TransformerCore module (shared by the policy builders and
@@ -449,6 +558,7 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         compute_dtype=_compute_dtype(arch),
         moe_experts=moe_experts,
         moe_top_k=int(arch.get("moe_top_k", 2)),
+        block_kw=flax.core.FrozenDict(_block_kwargs(arch)),
     )
 
 
@@ -514,10 +624,30 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
             params, obs, mask, readout_t=idx))
     import dataclasses as _dc
 
+    evaluate_stats = None
+    if moe_experts > 0:
+        def evaluate_stats(params, obs, act, mask=None):
+            """``evaluate`` plus the expert load of the same forward
+            (``moe.load_extremes`` of the sown group sizes)."""
+            from relayrl_tpu.models.moe import load_extremes
+
+            stats = {}
+
+            def apply_fn(params, obs, mask):
+                out, state = core.apply(params, obs, mask,
+                                        mutable=["intermediates"])
+                stats.update(load_extremes(state["intermediates"]))
+                return out
+
+            out = _policy_from_apply(arch, init_params, apply_fn).evaluate(
+                params, obs, act, mask)
+            return (*out, stats)
+
     return _dc.replace(policy, init_cache=init_cache,
                        step_cached=step_cached,
                        prefill_cache=prefill_cache,
-                       attention_backends=attention_backends)
+                       attention_backends=attention_backends,
+                       evaluate_stats=evaluate_stats)
 
 
 @register_model("transformer_discrete")
@@ -575,6 +705,13 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
     plain ``lax.scan`` over layers — so the SAME arch config serves CPU
     actor hosts and the pipelined TPU learner (SURVEY.md §7.4 item 2).
     """
+    new = [k for k in _BLOCK_ARCH_KEYS + ("positions", "rope_theta")
+           if k in arch]
+    if new:
+        raise ValueError(
+            f"transformer_pp_discrete builds the GPT-2 shaped block only "
+            f"and does not take {new}; use transformer_discrete / "
+            f"transformer_moe_discrete for these")
     obs_dim = int(arch["obs_dim"])
     d_model = int(arch.get("d_model", 128))
     n_layers = int(arch.get("n_layers", 2))

@@ -122,13 +122,16 @@ class InflightWindow:
         """Dispatched-but-unfenced updates (the drain() contract)."""
         return len(self._entries)
 
-    def push(self, fences: Any, version: int | None = None) -> None:
+    def push(self, fences: Any, version: int | None = None,
+             note: tuple[str, ...] = ()) -> None:
         """Record one dispatched update; blocks only when the window is
         already full (fencing the oldest). ``version`` (the dispatching
         algorithm's host version mirror) labels the eventual fence span
         on the distributed-tracing plane — optional, never read
-        otherwise."""
-        self._entries.append((fences, version))
+        otherwise. ``note`` names metrics of ``fences`` to write on that
+        span as arguments: read back after the fence, and only while a
+        profiler records."""
+        self._entries.append((fences, version, note))
         self.dispatch_count += 1
         while len(self._entries) > self.max_in_flight:
             self._fence_oldest()
@@ -143,7 +146,7 @@ class InflightWindow:
     def _fence_oldest(self) -> None:
         import jax
 
-        fences, version = self._entries.popleft()
+        fences, version, note = self._entries.popleft()
         with span("rl:dispatch.fence", metric=self._m_device_wait,
                   version=-1 if version is None else int(version)) as sp:
             if version is not None:
@@ -154,6 +157,8 @@ class InflightWindow:
                     sp.hop("model", trace_mod.model_trace_id(version),
                            "fence", version=int(version))
             jax.block_until_ready(fences)
+            if note and sp.traced:
+                sp.note(**{k: float(fences[k]) for k in note})
         self.device_wait_s += sp.seconds
         self.fenced_count += 1
         self._m_pending.set(len(self._entries))

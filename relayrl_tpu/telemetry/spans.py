@@ -94,6 +94,12 @@ class span:
     def seconds(self) -> float:
         return (self.t1_ns - self.t0_ns) * 1e-9
 
+    @property
+    def traced(self) -> bool:
+        """Whether a profiler session is recording this span: arguments
+        that cost something to produce are worth producing only then."""
+        return self._ann is not None
+
     def note(self, **args) -> None:
         """Arguments known only inside the block (a frame's size, the
         span's own start stamp): added to the annotation, if there is one."""

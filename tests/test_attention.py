@@ -432,3 +432,131 @@ class TestStepWindow:
         for _ in range(3):
             actor.deterministic_action(rng.standard_normal(8))
         assert actor._window_len == 3  # greedy eval advances history too
+
+
+# -- the block the arch describes: RoPE, QK-norm, RMSNorm, SwiGLU, no bias ---
+# (models/transformer._BLOCK_ARCH_KEYS; every default is the GPT-2 shaped
+# block, pinned in tests/test_olmoe_reference.py)
+
+MODERN = {"norm": "rms", "norm_eps": 1e-5, "positions": "rope",
+          "rope_theta": 10000.0, "qk_norm": True, "use_bias": False,
+          "ffn": "swiglu", "d_ff": 48}
+BLOCK_VARIANTS = {
+    "rope": {"positions": "rope"},
+    "rope_qknorm": {"positions": "rope", "qk_norm": True},
+    "modern_dense": MODERN,
+    "modern_moe": {**MODERN, "kind": "transformer_moe_discrete",
+                   "moe_experts": 4, "moe_top_k": 2, "moe_d_ff": 16,
+                   "moe_norm_topk_prob": False},
+}
+
+
+class TestRope:
+    def _x(self, t=12, seed=0):
+        return jnp.asarray(np.random.default_rng(seed).standard_normal(
+            (2, t, 2, 16)), jnp.float32)
+
+    @pytest.mark.parametrize("start", [0, 3, 7])
+    def test_shifted_start_shifts_nothing_but_positions(self, start):
+        from relayrl_tpu.models.transformer import apply_rope
+
+        x = self._x()
+        whole = apply_rope(x, 0, 10000.0)
+        tail = apply_rope(x[:, start:], start, 10000.0)
+        np.testing.assert_allclose(tail, whole[:, start:], atol=1e-5)
+        # a traced start (cached decode) is the same rotation
+        traced = jax.jit(lambda a, s: apply_rope(a, s, 10000.0))(
+            x[:, start:], jnp.int32(start))
+        np.testing.assert_allclose(traced, tail, atol=1e-5)
+
+    def test_is_a_rotation_and_scores_are_relative(self):
+        from relayrl_tpu.models.transformer import apply_rope
+
+        q, k = self._x(seed=1), self._x(seed=2)
+        np.testing.assert_allclose(
+            jnp.linalg.norm(apply_rope(q, 5, 10000.0), axis=-1),
+            jnp.linalg.norm(q, axis=-1), rtol=1e-5)
+
+        def scores(shift):
+            return jnp.einsum("bqhd,bkhd->bhqk",
+                              apply_rope(q, shift, 10000.0),
+                              apply_rope(k, shift, 10000.0))
+
+        # q.k depends on the distance between the two positions only
+        np.testing.assert_allclose(scores(0), scores(9), atol=2e-4)
+        assert not np.allclose(
+            scores(0), jnp.einsum("bqhd,bkhd->bhqk", q, k), atol=1e-2)
+
+    def test_position_zero_is_the_identity(self):
+        from relayrl_tpu.models.transformer import apply_rope
+
+        x = self._x(t=1)
+        np.testing.assert_allclose(apply_rope(x, 0, 10000.0), x, atol=1e-7)
+
+
+class TestBlockArch:
+    @pytest.mark.parametrize("name", sorted(BLOCK_VARIANTS))
+    def test_parameter_tree_follows_the_arch(self, name):
+        arch = {**ARCH, **BLOCK_VARIANTS[name]}
+        params = build_policy(arch).init_params(
+            jax.random.PRNGKey(0))["params"]
+        rope = arch.get("positions") == "rope"
+        assert ("pos_embed" in params) == (not rope)
+        block = params["block_0"]
+        assert ("q_norm" in block) == bool(arch.get("qk_norm"))
+        if arch.get("norm") == "rms":
+            assert set(block["ln_attn"]) == {"scale"}
+            assert set(params["ln_final"]) == {"scale"}
+        else:
+            assert set(block["ln_attn"]) == {"scale", "bias"}
+        assert ("bias" in block["qkv"]) == arch.get("use_bias", True)
+        if "moe_experts" in arch:
+            assert block["moe"]["moe_w_gate"].shape == (4, 32, 16)
+            assert "bias" not in block["moe"]["moe_gate"]
+        elif arch.get("ffn") == "swiglu":
+            assert block["mlp_gate"]["kernel"].shape == (32, 48)
+            assert block["mlp_down"]["kernel"].shape == (48, 32)
+
+    @pytest.mark.parametrize("name", sorted(BLOCK_VARIANTS))
+    def test_full_window_equals_readout_row(self, name):
+        # the learner's full forward and the actor tiers' readout-row
+        # forward place q and k at the same absolute positions
+        policy = build_policy({**ARCH, **BLOCK_VARIANTS[name]})
+        params = policy.init_params(jax.random.PRNGKey(1))
+        rng = np.random.default_rng(4)
+        W = 10
+        window = rng.standard_normal((W, 8)).astype(np.float32)
+        act = jnp.zeros((1, W), jnp.int32)
+        logp, _, v = policy.evaluate(params, window[None], act)
+        for t in (1, 4, W):
+            padded = window.copy()
+            padded[t:] = 0.0  # the rows after the readout are never seen
+            _, aux = policy.step_window(
+                params, jax.random.PRNGKey(0), padded, t)
+            np.testing.assert_allclose(float(aux["v"]), float(v[0, t - 1]),
+                                       atol=2e-5)
+
+    @pytest.mark.parametrize("key,value", [
+        ("norm", "batch"), ("positions", "alibi"), ("ffn", "relu")])
+    def test_unknown_value_refused(self, key, value):
+        with pytest.raises(ValueError, match=value):
+            build_policy({**ARCH, key: value}).init_params(
+                jax.random.PRNGKey(0))
+
+    def test_pipeline_family_refuses_the_new_keys(self):
+        with pytest.raises(ValueError, match="GPT-2 shaped block"):
+            build_policy({**ARCH, "kind": "transformer_pp_discrete",
+                          "positions": "rope"})
+
+    def test_norm_eps_reaches_every_norm(self):
+        # a large epsilon changes the output; the default is flax's 1e-6
+        base = build_policy(ARCH)
+        params = base.init_params(jax.random.PRNGKey(0))
+        obs = 1e-3 * jnp.ones((1, 4, 8))
+        act = jnp.zeros((1, 4), jnp.int32)
+        same = build_policy({**ARCH, "norm_eps": 1e-6})
+        other = build_policy({**ARCH, "norm_eps": 1e-2})
+        np.testing.assert_array_equal(base.evaluate(params, obs, act)[2],
+                                      same.evaluate(params, obs, act)[2])
+        assert not np.allclose(base.evaluate(params, obs, act)[2],
+                               other.evaluate(params, obs, act)[2])

@@ -169,6 +169,80 @@ def test_impala_with_sequence_policy(tmp_cwd):
     assert updated and algo.version == 1
 
 
+_OLMOE_TINY = dict(
+    model_kind="transformer_moe_discrete", d_model=16, n_layers=2, n_heads=2,
+    max_seq_len=16, norm="rms", norm_eps=1e-5, positions="rope",
+    rope_theta=10000.0, qk_norm=True, use_bias=False, ffn="swiglu",
+    moe_experts=8, moe_top_k=2, moe_d_ff=8, moe_norm_topk_prob=False)
+
+
+@pytest.mark.parametrize("precision", ["float32", "bfloat16"])
+def test_impala_updates_the_olmoe_block(tmp_path, precision):
+    """The update on an OLMoE-shaped trunk (tiny widths): finite losses,
+    every expert stack moved, and the expert load among the metrics, in the
+    epoch log's keys and, with telemetry on, in the registry."""
+    import json
+
+    from relayrl_tpu import telemetry
+    from relayrl_tpu.types.columnar import DecodedTrajectory
+
+    cfg = tmp_path / "cfg.json"
+    cfg.write_text(json.dumps({"learner": {"precision": precision}}))
+    telemetry.reset_for_tests()
+    telemetry.set_registry(telemetry.Registry(run_id="olmoe-update"))
+    try:
+        algo = build_algorithm(
+            "IMPALA", obs_dim=6, act_dim=3, traj_per_epoch=2,
+            bucket_lengths=[16], seed_salt=0, env_dir=str(tmp_path),
+            config_path=str(cfg),
+            logger_kwargs={"output_dir": str(tmp_path / "logs")},
+            **_OLMOE_TINY)
+        assert algo.arch["positions"] == "rope"
+        assert "pos_embed" not in algo.state.params["params"]
+        before = jax.tree_util.tree_map(np.asarray, algo.state.params)
+        rng = np.random.default_rng(0)
+        metrics = None
+        for i in range(2):
+            n = 16
+            batch = algo.accumulate(DecodedTrajectory(
+                agent_id="a", n_steps=n, n_records=n, marker_truncated=False,
+                columns={"o": rng.standard_normal((n, 6)).astype(np.float32),
+                         "a": rng.integers(0, 3, (n,)).astype(np.int32),
+                         "r": rng.random(n).astype(np.float32),
+                         "t": np.array([False] * (n - 1) + [True]),
+                         "u": np.zeros((n,), np.uint8),
+                         "x": np.zeros((n,), np.uint8)},
+                aux={"v": rng.standard_normal(n).astype(np.float32),
+                     "logp_a": np.full((n,), -1.1, np.float32)}))
+            if batch is not None:
+                metrics = algo.train_on_batch(algo.stage_batch(batch))
+        assert metrics is not None
+        assert np.isfinite(metrics["LossTotal"])
+        # 8 experts: the fullest holds at least 1/8 of the slots, at most
+        # all of one choice (1/k); the emptiest at most 1/8
+        assert 1 / 8 <= metrics["moe_load_max"] <= 1 / 2
+        assert 0.0 <= metrics["moe_load_min"] <= 1 / 8
+        assert {"moe_load_max", "moe_load_min"} <= set(algo._log_keys())
+        snap = {m["name"]: m["value"]
+                for m in telemetry.get_registry().snapshot()["metrics"]
+                if m["kind"] == "gauge"}
+        assert snap["relayrl_moe_load_max"] == pytest.approx(
+            metrics["moe_load_max"])
+        after = algo.state.params["params"]
+        for stack in ("moe_w_gate", "moe_w_up", "moe_w_down"):
+            moved = np.abs(np.asarray(after["block_1"]["moe"][stack])
+                           - before["params"]["block_1"]["moe"][stack])
+            assert (moved.reshape(8, -1).max(axis=1) > 0).all(), stack
+    finally:
+        telemetry.reset_for_tests()
+
+
+def test_dense_trunks_report_no_expert_load(tmp_path):
+    algo = _build(tmp_path, "IMPALA", "mlp")
+    assert algo._metric_gauges == {}
+    assert "moe_load_max" not in algo._log_keys()
+
+
 # -- byte frames stay bytes from the wire to the jitted update --------------
 # (data/batching.padded_obs_dtype; the models cast on entry, on the device)
 

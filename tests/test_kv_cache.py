@@ -9,6 +9,7 @@ once the episode outgrows the context window.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from relayrl_tpu.models import build_policy
 from relayrl_tpu.runtime.policy_actor import PolicyActor
@@ -18,14 +19,30 @@ ARCH = {"kind": "transformer_discrete", "obs_dim": 6, "act_dim": 3,
         "d_model": 32, "n_layers": 2, "n_heads": 2, "max_seq_len": 12}
 
 
-def _policy_params(seed=0):
-    policy = build_policy(ARCH)
+# Blocks whose positions enter q and k (RoPE) instead of the embedding: the
+# cache then holds rotated keys, and the decode step rotates its query and
+# key at the write index.
+_MODERN = {"norm": "rms", "norm_eps": 1e-5, "positions": "rope",
+           "qk_norm": True, "use_bias": False, "ffn": "swiglu", "d_ff": 48}
+CACHED_ARCHS = {
+    "gpt2": {},
+    "rope": {"positions": "rope"},
+    "rope_qknorm_rms_swiglu": _MODERN,
+    "olmoe_block": {**_MODERN, "kind": "transformer_moe_discrete",
+                    "moe_experts": 4, "moe_top_k": 2, "moe_d_ff": 16,
+                    "moe_norm_topk_prob": False},
+}
+
+
+def _policy_params(seed=0, **arch):
+    policy = build_policy({**ARCH, **arch})
     return policy, policy.init_params(jax.random.PRNGKey(seed))
 
 
 class TestStepCachedNumerics:
-    def test_matches_step_window(self):
-        policy, params = _policy_params()
+    @pytest.mark.parametrize("name", sorted(CACHED_ARCHS))
+    def test_matches_step_window(self, name):
+        policy, params = _policy_params(**CACHED_ARCHS[name])
         rng = np.random.default_rng(0)
         W = 8
         cache = policy.init_cache(W)
@@ -43,6 +60,30 @@ class TestStepCachedNumerics:
                                        float(aux_c["v"]), atol=1e-4)
             np.testing.assert_allclose(float(aux_w["logp_a"]),
                                        float(aux_c["logp_a"]), atol=1e-4)
+
+    @pytest.mark.parametrize("name", ["rope", "olmoe_block"])
+    def test_prefilled_cache_continues_as_the_stepped_one(self, name):
+        # prefill rotates W keys at positions 0..W-1 in one dispatch; the
+        # steps after it must read them as if they had been written one by
+        # one
+        policy, params = _policy_params(**CACHED_ARCHS[name])
+        rng = np.random.default_rng(5)
+        W, t0 = 8, 5
+        window = np.zeros((W, 6), np.float32)
+        window[:t0] = rng.standard_normal((t0, 6))
+        stepped = policy.init_cache(W)
+        for t in range(t0):
+            _, _, stepped = policy.step_cached(
+                params, jax.random.PRNGKey(t), stepped, window[t], t)
+        filled = policy.prefill_cache(params, policy.init_cache(W),
+                                      jnp.asarray(window))
+        obs = rng.standard_normal(6).astype(np.float32)
+        key = jax.random.PRNGKey(9)
+        a1, aux1, _ = policy.step_cached(params, key, stepped, obs, t0)
+        a2, aux2, _ = policy.step_cached(params, key, filled, obs, t0)
+        assert int(a1) == int(a2)
+        np.testing.assert_allclose(float(aux1["v"]), float(aux2["v"]),
+                                   atol=1e-4)
 
     def test_moe_family_has_cache(self):
         moe = build_policy({**ARCH, "kind": "transformer_moe_discrete",

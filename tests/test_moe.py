@@ -14,8 +14,11 @@ ARCH = {"kind": "transformer_moe_discrete", "obs_dim": 6, "act_dim": 3,
         "moe_experts": 4}
 
 
-def _policy_params(seed=0):
-    policy = build_policy(ARCH)
+DISPATCHES = ("sparse", "dense")
+
+
+def _policy_params(seed=0, **arch):
+    policy = build_policy({**ARCH, **arch})
     return policy, policy.init_params(jax.random.PRNGKey(seed))
 
 
@@ -35,12 +38,13 @@ class TestMoELayer:
         assert logp.shape == (3, 8)
         assert bool(jnp.isfinite(logp).all() and jnp.isfinite(v).all())
 
-    def test_causal_routing(self):
+    @pytest.mark.parametrize("dispatch", DISPATCHES)
+    def test_causal_routing(self, dispatch):
         # Per-token routing must keep the policy causal: logp at step t may
         # not change when FUTURE observations change (capacity-competition
         # routing schemes violate this — the reason top-k per token was
         # chosen; see models/moe.py docstring).
-        policy, params = _policy_params()
+        policy, params = _policy_params(moe_dispatch=dispatch)
         rng = np.random.default_rng(3)
         obs = jnp.asarray(rng.standard_normal((1, 8, 6)), jnp.float32)
         act = jnp.zeros((1, 8), jnp.int32)
@@ -61,10 +65,11 @@ class TestMoELayer:
         logp, _, _ = policy.evaluate(params, obs, jnp.zeros((1, 8), jnp.int32))
         assert bool(jnp.isfinite(logp).all())
 
-    def test_grads_reach_every_expert(self):
+    @pytest.mark.parametrize("dispatch", DISPATCHES)
+    def test_grads_reach_every_expert(self, dispatch):
         # With top-2 of 4 experts over 16 tokens, every expert receives
         # assignments at init (uniform-ish gate) — all must get gradient.
-        policy, params = _policy_params()
+        policy, params = _policy_params(moe_dispatch=dispatch)
         obs = jnp.asarray(
             np.random.default_rng(1).standard_normal((2, 8, 6)), jnp.float32)
 
@@ -87,7 +92,138 @@ class TestMoELayer:
         assert "mlp_up" in p["params"]["block_0"]
 
 
+# -- sparse dispatch against the dense all-experts path ----------------------
+# The layer alone, float32, eager (no compile): N = 24 tokens, d = 16,
+# ff = 8. ``load`` shapes the router through its bias: "even" leaves the
+# random router, "one" sends every token's first choice to expert 0 (with
+# k = 1 every token-slot lands there: the whole batch in one group),
+# "empty" bars the upper half of the experts (groups of size 0).
+_N, _D, _FF = 24, 16, 8
+SPARSE_CASES = [
+    pytest.param(e, k, norm, load, id=f"E{e}-k{k}-"
+                 f"{'topk_softmax' if norm else 'softmax_topk_unnorm'}-{load}")
+    for e in (4, 64) for k in (1, 2, 8) for norm in (False, True)
+    for load in ("even", "one", "empty")
+    # barring half the experts needs k of them left to choose from
+    if not (load == "empty" and min(k, e) > e // 2)]
+
+
+def _layer(e, k, norm, dispatch, ffn="swiglu"):
+    from relayrl_tpu.models.moe import MoEMLP
+
+    return MoEMLP(_D, _FF, e, k, jnp.float32, norm_topk_prob=norm, ffn=ffn,
+                  dispatch=dispatch)
+
+
+def _layer_params(e, k, norm, load, ffn="swiglu"):
+    x = jnp.asarray(np.random.default_rng(e * 10 + k).standard_normal(
+        (2, _N // 2, _D)), jnp.float32)
+    params = _layer(e, k, norm, "sparse", ffn).init(jax.random.PRNGKey(e + k),
+                                                    x)
+    bias = np.zeros(e, np.float32)
+    if load == "one":
+        bias[0] = 50.0
+    elif load == "empty":
+        bias[e // 2:] = -50.0
+    params = jax.tree_util.tree_map(lambda a: a, params)
+    params["params"]["moe_gate"]["bias"] = jnp.asarray(bias)
+    return params, x
+
+
+class TestSparseDispatch:
+    @pytest.mark.parametrize("e,k,norm,load", SPARSE_CASES)
+    def test_matches_dense_forward_loss_and_every_gradient(self, e, k, norm,
+                                                           load):
+        """Tolerance 2e-5 absolute on values of order 1: both paths are
+        float32 and compute the same products; only the order of the sums
+        differs (k terms per token here, E masked terms there)."""
+        params, x = _layer_params(e, k, norm, load)
+
+        def loss(dispatch):
+            def f(params, x):
+                y, state = _layer(e, k, norm, dispatch).apply(
+                    params, x, mutable=["intermediates"])
+                return jnp.sum(jnp.sin(y) * x), (y, state)
+            return f
+
+        (ls, (ys, st)), gs = jax.value_and_grad(
+            loss("sparse"), argnums=(0, 1), has_aux=True)(params, x)
+        (ld, (yd, _)), gd = jax.value_and_grad(
+            loss("dense"), argnums=(0, 1), has_aux=True)(params, x)
+        np.testing.assert_allclose(ys, yd, atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(float(ls), float(ld), atol=2e-4,
+                                   rtol=1e-5)
+        flat_s = jax.tree_util.tree_flatten_with_path(gs)[0]
+        flat_d = jax.tree_util.tree_leaves(gd)
+        assert len(flat_s) == len(flat_d)
+        for (path, a), b in zip(flat_s, flat_d):
+            np.testing.assert_allclose(
+                a, b, atol=2e-5, rtol=1e-4,
+                err_msg=jax.tree_util.keystr(path))
+        # no token-slot dropped, whatever the imbalance
+        load_counts = np.asarray(st["intermediates"]["expert_load"][0])
+        kk = min(k, e)
+        assert load_counts.sum() == _N * kk
+        if load == "one":
+            assert load_counts[0] == _N
+        if load == "empty":
+            assert (load_counts[e // 2:] == 0).all()
+
+    @pytest.mark.parametrize("ffn", ["gelu", "swiglu"])
+    def test_expert_stacks_of_the_ffn_kind(self, ffn):
+        params, _ = _layer_params(4, 2, True, "even", ffn)
+        names = set(params["params"]) - {"moe_gate"}
+        want = {"moe_w_up", "moe_w_down"} | (
+            {"moe_w_gate"} if ffn == "swiglu" else set())
+        assert names == want
+
+    @pytest.mark.parametrize("norm", [False, True])
+    def test_router_weights(self, norm):
+        from relayrl_tpu.models.moe import route
+
+        logits = jnp.asarray(np.random.default_rng(0).standard_normal(
+            (5, 8)), jnp.float32)
+        w, idx = route(logits, 3, norm)
+        probs = jax.nn.softmax(logits, -1)
+        picked = jnp.take_along_axis(probs, idx, -1)
+        if norm:  # softmax over the chosen = the probabilities renormalised
+            np.testing.assert_allclose(w.sum(-1), 1.0, atol=1e-6)
+            np.testing.assert_allclose(
+                w, picked / picked.sum(-1, keepdims=True), atol=1e-6)
+        else:     # the probabilities over all experts, as they are
+            np.testing.assert_allclose(w, picked, atol=1e-7)
+            assert float(w.sum(-1).max()) < 1.0
+
+    @pytest.mark.parametrize("norm", [False, True])
+    def test_window_of_one_sequence_routes_as_inside_a_batch(self, norm):
+        # one actor's window and the same tokens inside a training batch:
+        # same experts, same outputs (the docstring's hard requirement)
+        policy, params = _policy_params(moe_norm_topk_prob=norm)
+        obs = jnp.asarray(np.random.default_rng(7).standard_normal(
+            (3, 8, 6)), jnp.float32)
+        act = jnp.zeros((3, 8), jnp.int32)
+        logp_b, _, v_b = policy.evaluate(params, obs, act)
+        for b in range(3):
+            logp_1, _, v_1 = policy.evaluate(params, obs[b:b + 1],
+                                             act[b:b + 1])
+            np.testing.assert_allclose(logp_1[0], logp_b[b], atol=1e-6)
+            np.testing.assert_allclose(v_1[0], v_b[b], atol=1e-6)
+
+    def test_unknown_dispatch_refused(self):
+        with pytest.raises(ValueError, match="moe_dispatch"):
+            _policy_params(moe_dispatch="capacity")
+
+
 class TestExpertParallel:
+    @pytest.mark.parametrize("stack,shape", [
+        ("moe_w_up", (4, 16, 64)), ("moe_w_gate", (4, 16, 64)),
+        ("moe_w_down", (4, 64, 16))])
+    def test_expert_stack_pspec(self, stack, shape):
+        mesh = make_mesh({"dp": -1, "ep": 4})
+        key = jax.tree_util.DictKey
+        path = (key("params"), key("block_0"), key("moe"), key(stack))
+        assert param_pspec(path, jnp.zeros(shape), mesh)[0] == "ep"
+
     def test_expert_pspec(self):
         mesh = make_mesh({"dp": -1, "ep": 4})
         key = jax.tree_util.DictKey
@@ -99,6 +235,32 @@ class TestExpertParallel:
                      key("moe_gate"), key("kernel"))
         assert param_pspec(gate_path, jnp.zeros((16, 4)), mesh) == \
             jax.sharding.PartitionSpec()
+
+    @pytest.mark.parametrize("asked,ep,runs", [
+        (None, 1, "sparse"), (None, 4, "dense"), ("dense", 1, "dense"),
+        ("sparse", 1, "sparse"), ("dense", 4, "dense"),
+        ("sparse", 4, "refused")])
+    def test_dispatch_follows_the_ambient_mesh(self, asked, ep, runs):
+        # unset, the layer takes the path GSPMD can partition where it is
+        # traced under an ep mesh and the sparse one elsewhere; the sparse
+        # path is told by its grouped matmul
+        from relayrl_tpu.parallel import use_mesh
+
+        over = {} if asked is None else {"moe_dispatch": asked}
+        policy, params = _policy_params(**over)
+        obs = jnp.zeros((2, 8, 6), jnp.float32)
+        act = jnp.zeros((2, 8), jnp.int32)
+
+        def trace():
+            return str(jax.make_jaxpr(policy.evaluate)(params, obs, act))
+
+        with use_mesh(make_mesh({"dp": -1, "ep": ep})):
+            if runs == "refused":
+                with pytest.raises(ValueError, match="single-device"):
+                    trace()
+                return
+            text = trace()
+        assert ("ragged_dot" in text) == (runs == "sparse")
 
     # ISSUE 17 wall re-fit: the heaviest compile in the fast wall (~30 s
     # on the 1-core CI host); ep-mesh stepping stays covered fast by the
@@ -151,6 +313,26 @@ class TestExpertParallel:
 
 
 class TestUtilizationMonitor:
+    def test_update_stats_are_the_extremes_of_the_utilization(self):
+        from relayrl_tpu.models.moe import expert_utilization
+
+        policy, params = _policy_params()
+        obs = np.random.default_rng(6).standard_normal((2, 8, 6)).astype(
+            np.float32)
+        act = jnp.zeros((2, 8), jnp.int32)
+        logp, ent, v, stats = policy.evaluate_stats(params, obs, act)
+        np.testing.assert_allclose(logp, policy.evaluate(params, obs, act)[0],
+                                   atol=1e-6)
+        util = expert_utilization(ARCH, params, obs)
+        np.testing.assert_allclose(
+            float(stats["moe_load_max"]),
+            max(float(f.max()) for f in util.values()), atol=1e-6)
+        np.testing.assert_allclose(
+            float(stats["moe_load_min"]),
+            min(float(f.min()) for f in util.values()), atol=1e-6)
+        dense = build_policy({**ARCH, "kind": "transformer_discrete"})
+        assert dense.evaluate_stats is None
+
     def test_fractions_sum_to_one_per_layer(self):
         from relayrl_tpu.models.moe import expert_utilization
 
