@@ -6,7 +6,6 @@ from relayrl_tpu.data.batching import (
     TrajectoryBatch,
     pad_trajectory,
     pick_bucket,
-    repad_trajectory,
     stack_trajectories,
 )
 from relayrl_tpu.data.replay_buffer import DEFAULT_BUCKETS, EpochBuffer
@@ -19,7 +18,6 @@ __all__ = [
     "TrajectoryBatch",
     "pad_trajectory",
     "pick_bucket",
-    "repad_trajectory",
     "stack_trajectories",
     "EpochBuffer",
     "DEFAULT_BUCKETS",

@@ -268,10 +268,12 @@ def pad_decoded(
     kept identical to the ActionRecord path (tests/test_native_codec.py
     asserts byte equality of the padded outputs across both paths).
 
-    ``out`` is a padded trajectory of the same horizon, widths and obs
-    dtype (:func:`decoded_obs_dtype`) that nothing reads any more: its
-    arrays are written over and returned in a new
-    :class:`PaddedTrajectory`, equal to what a fresh call gives.
+    ``out`` is a padded trajectory of the same horizon and widths that
+    nothing reads any more — a row of a batch slab (:func:`slab_row`):
+    its arrays are written over and returned in a new
+    :class:`PaddedTrajectory`, equal to what a fresh call gives. Its obs
+    may be float32 where the episode's are bytes (they widen exactly, as
+    they did at the stack), never the other way round.
     """
     cols, aux = dt.columns, dt.aux
     total = dt.n_steps
@@ -288,13 +290,14 @@ def pad_decoded(
         rew, val, logp, valid = (np.zeros((horizon,), dtype=np.float32)
                                  for _ in range(4))
     else:
-        if out.obs.dtype != obs_dtype:
+        if out.obs.dtype != obs_dtype and out.obs.dtype != _F32:
             raise ValueError(f"cannot pad {obs_dtype} observations over a "
                              f"{out.obs.dtype} episode")
         obs, act, act_mask = out.obs, out.act, out.act_mask
         rew, val, logp, valid = out.rew, out.val, out.logp, out.valid
-        for arr in (obs, act, act_mask, rew, val, logp, valid):
-            arr[n:] = 0  # rows [:n] are each assigned below
+        if n < horizon:  # rows [:n] are each assigned below
+            for arr in (obs, act, act_mask, rew, val, logp, valid):
+                arr[n:] = 0
     if "o" in cols:
         flat = cols["o"].reshape(total, -1)
         if flat.shape[1] < obs_dim:
@@ -333,52 +336,57 @@ _BATCH_FIELDS = ("obs", "act", "act_mask", "rew", "val", "logp", "valid")
 
 def stack_trajectories(
     trajs: Sequence[PaddedTrajectory],
-    out: dict[str, np.ndarray] | None = None,
     obs_dtype=None,
 ) -> TrajectoryBatch:
-    """Padded episodes → one ``[B, T, ...]`` batch.
+    """Padded episodes of one horizon → one freshly allocated
+    ``[B, T, ...]`` batch; obs stack to ``obs_dtype``, by default what
+    :func:`batch_obs_dtype` makes of the episodes. The plain form:
+    :class:`~relayrl_tpu.data.EpochBuffer` pads each episode straight
+    into its row of a slab and never stacks."""
+    horizons = {t.obs.shape[0] for t in trajs}
+    if len(horizons) != 1:
+        raise ValueError(f"mixed horizons in batch: {sorted(horizons)}")
+    if obs_dtype is None:
+        obs_dtype = batch_obs_dtype(trajs)
+    return TrajectoryBatch(
+        obs=np.stack([t.obs for t in trajs], dtype=obs_dtype),
+        act=np.stack([t.act for t in trajs]),
+        act_mask=np.stack([t.act_mask for t in trajs]),
+        rew=np.stack([t.rew for t in trajs]),
+        val=np.stack([t.val for t in trajs]),
+        logp=np.stack([t.logp for t in trajs]),
+        valid=np.stack([t.valid for t in trajs]),
+        last_val=np.asarray([t.last_val for t in trajs], dtype=np.float32),
+    )
 
-    Without ``out`` this is the original allocate-per-call path (eight
-    fresh ``np.stack``/``asarray`` allocations; requires same-horizon
-    inputs; obs stack to ``obs_dtype``, by default what
-    :func:`batch_obs_dtype` makes of the episodes). With ``out`` — a
-    persistent staging dict from
-    :class:`BatchStaging` — every row writes in place (shorter episodes
-    zero-fill their tail, subsuming :func:`repad_trajectory`), and the
-    returned batch VIEWS the staging arrays: it is valid until the
-    staging slot is reused (see :meth:`EpochBuffer.drain`'s contract).
-    """
-    if out is None:
-        horizons = {t.obs.shape[0] for t in trajs}
-        if len(horizons) != 1:
-            raise ValueError(f"mixed horizons in batch: {sorted(horizons)}")
-        if obs_dtype is None:
-            obs_dtype = batch_obs_dtype(trajs)
-        return TrajectoryBatch(
-            obs=np.stack([t.obs for t in trajs], dtype=obs_dtype),
-            act=np.stack([t.act for t in trajs]),
-            act_mask=np.stack([t.act_mask for t in trajs]),
-            rew=np.stack([t.rew for t in trajs]),
-            val=np.stack([t.val for t in trajs]),
-            logp=np.stack([t.logp for t in trajs]),
-            valid=np.stack([t.valid for t in trajs]),
-            last_val=np.asarray([t.last_val for t in trajs], dtype=np.float32),
-        )
-    b, horizon = out["obs"].shape[:2]
-    if len(trajs) != b:
-        raise ValueError(f"staging batch is {b} rows, got {len(trajs)} episodes")
-    for i, t in enumerate(trajs):
-        n = t.obs.shape[0]
-        if n > horizon:
-            raise ValueError(f"cannot shrink padded trajectory {n} -> {horizon}")
-        for name in _BATCH_FIELDS:
-            dst, src = out[name][i], getattr(t, name)
-            dst[:n] = src
-            if n < horizon:
-                dst[n:] = 0  # stale rows from the slab's previous epoch
-        out["last_val"][i] = t.last_val
-    return TrajectoryBatch(**{name: out[name] for name in _BATCH_FIELDS},
-                           last_val=out["last_val"])
+
+def slab_row(slab: dict[str, np.ndarray], i: int) -> PaddedTrajectory:
+    """Row ``i`` of a ``[B, T, ...]`` slab as the ``out`` of
+    :func:`pad_decoded`: contiguous views, one a field."""
+    return PaddedTrajectory(*(slab[name][i] for name in _BATCH_FIELDS),
+                            length=0, terminated=False, last_val=0.0)
+
+
+def copy_episode(row: PaddedTrajectory, traj: PaddedTrajectory) -> None:
+    """A padded episode into a slab row of a horizon no shorter (the tail
+    zero-filled) and an obs dtype no narrower."""
+    n = traj.obs.shape[0]
+    for name in _BATCH_FIELDS:
+        dst = getattr(row, name)
+        dst[:n] = getattr(traj, name)
+        dst[n:] = 0
+
+
+def copy_rows(dst: dict[str, np.ndarray], src: dict[str, np.ndarray],
+              rows: int) -> None:
+    """The leading ``rows`` rows of slab ``src`` into slab ``dst`` of a
+    horizon no shorter (the tail zero-filled) and an obs dtype no
+    narrower (bytes widen exactly)."""
+    horizon = src["obs"].shape[1]
+    for name in _BATCH_FIELDS:
+        dst[name][:rows, :horizon] = src[name][:rows]
+        dst[name][:rows, horizon:] = 0
+    dst["last_val"][:rows] = src["last_val"][:rows]
 
 
 class BatchStaging:
@@ -387,9 +395,11 @@ class BatchStaging:
     for epoch assembly. A slab is handed out round-robin and REUSED
     after ``slots`` further acquires of the same shape; the owner must
     guarantee the slab's previous consumer is done by then (the
-    algorithm in-flight window provides exactly that: with window W and
-    ``slots = W + 1``, the update that read slab k has been fenced
-    before drain k+W+1 overwrites it)."""
+    algorithm in-flight window provides exactly that, in the order
+    :meth:`EpochBuffer.add_episode` states: with window W and
+    ``slots = W + 1``, the update that read the slab of batch k has been
+    fenced by ``train_on_batch`` k+W, before the first episode of batch
+    k+W+1 writes its row)."""
 
     def __init__(self, slots: int, obs_dim: int, act_dim: int,
                  discrete: bool = True):
@@ -413,24 +423,3 @@ class BatchStaging:
         i = self._next.get(key, 0)
         self._next[key] = (i + 1) % self.slots
         return ring[i]
-
-
-def repad_trajectory(traj: PaddedTrajectory, horizon: int) -> PaddedTrajectory:
-    """Grow (or validate) a padded episode to a new horizon."""
-    cur = traj.obs.shape[0]
-    if cur == horizon:
-        return traj
-    if cur > horizon:
-        raise ValueError(f"cannot shrink padded trajectory {cur} -> {horizon}")
-    pad = horizon - cur
-
-    def _grow(arr):
-        width = [(0, pad)] + [(0, 0)] * (arr.ndim - 1)
-        return np.pad(arr, width)
-
-    return PaddedTrajectory(
-        obs=_grow(traj.obs), act=_grow(traj.act), act_mask=_grow(traj.act_mask),
-        rew=_grow(traj.rew), val=_grow(traj.val), logp=_grow(traj.logp),
-        valid=_grow(traj.valid), length=traj.length, terminated=traj.terminated,
-        last_val=traj.last_val,
-    )

@@ -11,21 +11,22 @@ step on device** so ingest overlaps compute and nothing round-trips
 
 from __future__ import annotations
 
+from collections import deque
 from typing import Sequence
 
 import numpy as np
 
 from relayrl_tpu.data.batching import (
     BatchStaging,
-    PaddedTrajectory,
     TrajectoryBatch,
-    batch_obs_dtype,
+    copy_episode,
+    copy_rows,
     decoded_obs_dtype,
     pad_decoded,
     pad_trajectory,
+    padded_obs_dtype,
     pick_bucket,
-    repad_trajectory,
-    stack_trajectories,
+    slab_row,
 )
 from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
@@ -34,12 +35,30 @@ from relayrl_tpu.types.columnar import DecodedTrajectory
 DEFAULT_BUCKETS = (64, 256, 1000)
 
 
+class _OpenBatch:
+    """A batch being filled: its slab, the rows written so far, their
+    valid steps, and the rows copied a second time (see
+    :meth:`EpochBuffer.add_episode`)."""
+
+    __slots__ = ("slab", "rows", "valid", "moved")
+
+    def __init__(self, slab: dict[str, np.ndarray]):
+        self.slab = slab
+        self.rows = self.valid = self.moved = 0
+
+
 class EpochBuffer:
     """Collects ``traj_per_epoch`` episodes, then drains one batch.
 
     Bucketing: each episode pads to the smallest configured bucket that fits;
     the drained batch uses the largest bucket present, so the learner step
     compiles once per (batch_size, bucket) pair.
+
+    One copy: a batch's ``[traj_per_epoch, T, ...]`` slab is opened by its
+    first episode, every episode is padded straight into its row, and
+    :meth:`drain` hands the slab out. With staging on (the default) the
+    slab comes from a ring of ``staging_slots`` persistent ones, and the
+    caller owes the ring an order, stated at :meth:`add_episode`.
     """
 
     def __init__(
@@ -68,19 +87,17 @@ class EpochBuffer:
         # consumer (guards future edits to the two rebuilds above).
         assert all(a < b for a, b in zip(self.buckets, self.buckets[1:])), \
             f"bucket lengths must be strictly ascending: {self.buckets}"
-        # Zero-alloc assembly: drained batches write into a ring of
-        # persistent staging slabs instead of eight np.stack allocations
-        # per epoch. staging_slots=0 disables (every drain allocates —
-        # required when drained batches outlive `slots` further drains,
+        # Zero-alloc assembly: batches are built in a ring of persistent
+        # staging slabs instead of eight fresh arrays per epoch.
+        # staging_slots=0 disables (every batch opens a fresh slab —
+        # required when drained batches outlive `slots` further batches,
         # e.g. the multi-host broadcast queue).
         self._staging = (BatchStaging(staging_slots, self.obs_dim,
                                       self.act_dim, self.discrete)
                          if staging_slots else None)
-        self._pending: list[PaddedTrajectory] = []
-        # Drained episodes by (horizon, obs dtype), their arrays free to be
-        # written over by the next episodes padded to the same (see
-        # add_episode).
-        self._spare: dict[tuple, list[PaddedTrajectory]] = {}
+        # Batches not yet drained, oldest first; only the last has rows
+        # to fill. In the learner's order there is at most one.
+        self._open: deque[_OpenBatch] = deque()
         # Obs dtype of the last batch drained (None before the first): a
         # batch keeps the dtype its episodes were decoded in, so this is
         # what the stream's next batch will most likely be — the warm-up
@@ -91,94 +108,142 @@ class EpochBuffer:
         self.episode_lengths: list[int] = []
 
     def disable_staging(self) -> None:
-        """Switch drain() back to allocate-per-call (consumers that hold
-        drained batches across drains — the multi-host ready queue)."""
+        """Every batch from now on opens a fresh slab (consumers that hold
+        drained batches across later ones — the multi-host ready queue)."""
         self._staging = None
 
     def pin_float32_obs(self) -> None:
         """Every batch's obs is float32 whatever the episodes' (bytes widen
-        exactly at the stack) — for a consumer whose peers must know the
-        batch's dtypes without seeing the data: the multi-host broadcast
-        describes a batch by (B, T) alone."""
+        exactly on their way into the row) — for a consumer whose peers
+        must know the batch's dtypes without seeing the data: the
+        multi-host broadcast describes a batch by (B, T) alone."""
         self._wire_obs = False
         self.obs_dtype = np.dtype(np.float32)
 
     def __len__(self) -> int:
-        return len(self._pending)
+        return sum(b.rows for b in self._open)
 
     @property
     def ready(self) -> bool:
-        return len(self._pending) >= self.traj_per_epoch
+        return bool(self._open) and self._open[0].rows >= self.traj_per_epoch
+
+    def _slab(self, horizon: int, obs_dtype, first: bool
+              ) -> dict[str, np.ndarray]:
+        """A slab for the oldest open batch comes from the ring; one opened
+        while an older batch waits to be drained is fresh, because the
+        ring's order (see :meth:`add_episode`) says nothing about it."""
+        if first and self._staging is not None:
+            return self._staging.acquire(self.traj_per_epoch, horizon,
+                                         obs_dtype)
+        return TrajectoryBatch.zeros(self.traj_per_epoch, horizon,
+                                     self.obs_dim, self.act_dim,
+                                     self.discrete, obs_dtype=obs_dtype)
+
+    def _batch_for(self, bucket: int, obs_dtype) -> _OpenBatch:
+        """The open batch with a free row, in a slab no shorter than
+        ``bucket`` and of an obs dtype no narrower than ``obs_dtype``."""
+        if not self._wire_obs:
+            obs_dtype = np.dtype(np.float32)
+        batch = self._open[-1] if self._open else None
+        if batch is None or batch.rows == self.traj_per_epoch:
+            batch = _OpenBatch(self._slab(bucket, obs_dtype,
+                                          first=not self._open))
+            self._open.append(batch)
+            return batch
+        horizon, dtype = batch.slab["obs"].shape[1], batch.slab["obs"].dtype
+        # The batch's key is its largest bucket and widest obs dtype (its
+        # own episodes': a slab kept over reset() holds none yet).
+        key = ((max(bucket, horizon), padded_obs_dtype((dtype, obs_dtype)))
+               if batch.rows else (bucket, obs_dtype))
+        if key != (horizon, dtype):
+            # This episode raises the key: the rows filled so far move to
+            # a slab of the new one — the copy every row once took.
+            slab = self._slab(*key, first=batch is self._open[0])
+            if batch.rows:
+                copy_rows(slab, batch.slab, batch.rows)
+            batch.slab = slab
+            batch.moved += batch.rows
+        return batch
 
     def add_episode(
         self, actions: Sequence[ActionRecord] | DecodedTrajectory
     ) -> bool:
-        """Pad + buffer one episode; True when a batch is ready to drain.
+        """Pad one episode into its row of the open batch; True when a
+        batch is ready to drain.
 
         Accepts either the ActionRecord list (Python decode path) or a
         :class:`DecodedTrajectory` from the native columnar decoder —
         ``len()`` of both is the raw record count, so bucketing is
-        identical across paths."""
+        identical across paths.
+
+        The slab is keyed by (rows, horizon, obs dtype) from the batch's
+        first episode. A later episode of a smaller bucket zero-fills its
+        row's tail; one of a larger bucket or a wider obs dtype moves the
+        rows filled so far into a slab of the new key (counted as
+        ``moved`` on the batch's ``rl:batch.stack`` span).
+
+        **Order the staging ring relies on.** The first episode after a
+        drain takes the next slab of the ring and writes into it at once,
+        so by then the update that last read that slab must be fenced.
+        With ``staging_slots = window + 1`` it is, provided the caller
+        hands each drained batch to ``train_on_batch`` (whose window push
+        fences update ``k - window``) *before* it adds the next episode:
+        ``drain k → stage_batch → train_on_batch k → add_episode``. Every
+        caller in this repo does (``accumulate`` drains the moment a batch
+        is full). Episodes added while an older batch still waits to be
+        drained go to a fresh slab, not to the ring; a caller that keeps
+        drained batches longer calls :meth:`disable_staging`."""
         bucket = pick_bucket(len(actions), self.buckets)
         with span("rl:batch.pad"):
             if isinstance(actions, DecodedTrajectory):
-                # Into the arrays of an episode already drained, when there
-                # is one: a fresh [T, obs_dim] float32 array per episode is
-                # fresh pages per episode whenever the allocator has handed
-                # the last batch's back to the OS, and first touch costs
-                # ten times the copy (2.26 ms against 0.24 ms an Atari
-                # unroll; which of the two a process got was chance:
-                # PERF.md, PR 24).
-                spare = self._spare.get((bucket, decoded_obs_dtype(actions)))
-                padded = pad_decoded(actions, bucket, self.obs_dim,
-                                     self.act_dim, self.discrete,
-                                     out=spare.pop() if spare else None)
+                batch = self._batch_for(bucket, decoded_obs_dtype(actions))
+                # shorter than the slab's horizon means shorter than its
+                # own bucket: padding to either keeps the same steps
+                padded = pad_decoded(
+                    actions, batch.slab["obs"].shape[1], self.obs_dim,
+                    self.act_dim, self.discrete,
+                    out=slab_row(batch.slab, batch.rows))
             else:
+                # not the hot path: padded apart, then copied into the row
                 padded = pad_trajectory(actions, bucket, self.obs_dim,
                                         self.act_dim, self.discrete)
-        self._pending.append(padded)
-        self.episode_returns.append(float(padded.rew.sum()))
+                batch = self._batch_for(bucket, padded.obs.dtype)
+                copy_episode(slab_row(batch.slab, batch.rows), padded)
+            batch.slab["last_val"][batch.rows] = padded.last_val
+            batch.rows += 1
+            batch.valid += padded.length
+        # over the episode's own bucket, as when it was padded apart: a
+        # float32 sum depends on the length summed over
+        self.episode_returns.append(float(padded.rew[:bucket].sum()))
         self.episode_lengths.append(padded.length)
         return self.ready
 
     def drain(self) -> TrajectoryBatch:
-        """Emit the epoch batch (and clear). All episodes pad to the
-        largest bucket present so the stack is rectangular.
+        """Hand out the oldest open batch: the slab its episodes were
+        padded into (its leading rows, a contiguous view, when the batch
+        is part-filled). Its horizon is the largest bucket among them.
 
-        With staging enabled (the default), the batch views a persistent
-        slab that is REUSED after ``staging_slots`` further drains of
-        the same shape — valid under the algorithm in-flight window
-        (``slots = window + 1``: the update that consumed this slab is
-        fenced before it can be overwritten), but callers that hold
-        batches longer (multi-host ready queues) must
+        With staging enabled (the default), the batch IS a persistent
+        slab that the ring hands out again ``staging_slots`` batches
+        later — valid under the algorithm in-flight window
+        (``slots = window + 1``) in the order :meth:`add_episode` states:
+        dispatch this batch before adding the next episode. Callers that
+        hold batches longer (multi-host ready queues) must
         :meth:`disable_staging` first."""
-        if not self._pending:
+        if not self._open or not self._open[0].rows:
             raise ValueError("drain() on empty buffer")
-        take = self._pending[: self.traj_per_epoch]
-        self._pending = self._pending[self.traj_per_epoch:]
-        horizon = max(t.obs.shape[0] for t in take)
-        obs_dtype = (batch_obs_dtype(take) if self._wire_obs
-                     else np.dtype(np.float32))
-        # Host numbers only, from the padded episodes' own lengths and
-        # shapes: a counter never reads a device array.
-        with span("rl:batch.stack", valid=sum(t.length for t in take),
-                  padded=len(take) * horizon) as sp:
-            if self._staging is not None:
-                batch = stack_trajectories(
-                    take, out=self._staging.acquire(len(take), horizon,
-                                                    obs_dtype))
-            else:
-                batch = stack_trajectories(
-                    [repad_trajectory(t, horizon) for t in take],
-                    obs_dtype=obs_dtype)
-            sp.note(bytes=sum(v.nbytes for v in batch.as_dict().values()))
-        # The batch is a copy (slab or np.stack): the episodes' own arrays
-        # are free again. One batch's worth a (horizon, obs dtype) is kept.
-        for t in take:
-            spare = self._spare.setdefault((t.obs.shape[0], t.obs.dtype), [])
-            if len(spare) < self.traj_per_epoch:
-                spare.append(t)
-        self.obs_dtype = obs_dtype
+        done = self._open.popleft()
+        slab, rows = done.slab, done.rows
+        # Host numbers only, counted as the rows were written: a counter
+        # never reads a device array.
+        with span("rl:batch.stack", valid=done.valid,
+                  padded=rows * slab["obs"].shape[1],
+                  moved=done.moved) as sp:
+            if rows < self.traj_per_epoch:
+                slab = {name: arr[:rows] for name, arr in slab.items()}
+            batch = TrajectoryBatch(**slab)
+            sp.note(bytes=sum(arr.nbytes for arr in slab.values()))
+        self.obs_dtype = batch.obs.dtype
         return batch
 
     def pop_episode_stats(self) -> tuple[list[float], list[int]]:
@@ -189,8 +254,13 @@ class EpochBuffer:
     def reset(self) -> None:
         """Drop the part-filled epoch (and its stats) — the guardrail
         rollback path: episodes buffered on a rolled-back line of
-        history must not leak into the restored line's first epoch."""
-        self._pending.clear()
-        self._spare.clear()
+        history must not leak into the restored line's first epoch.
+        The oldest open batch keeps its slab and starts again at row 0:
+        a ring slab taken and never dispatched would put the ring one
+        update ahead of the window."""
+        while len(self._open) > 1:
+            self._open.pop()
+        for batch in self._open:
+            batch.rows = batch.valid = batch.moved = 0
         self.episode_returns.clear()
         self.episode_lengths.clear()

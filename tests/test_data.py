@@ -160,83 +160,6 @@ def _decoded_episode(n, seed, obs_dim=6, obs_dtype=np.uint8, frac=False):
              "logp_a": rng.standard_normal(n).astype(np.float32)})
 
 
-class TestEpochBufferRecyclesEpisodes:
-    @pytest.mark.parametrize("obs_dtype", [np.uint8, np.float32])
-    @pytest.mark.parametrize("staging_slots", [3, 0])
-    def test_recycled_batches_equal_fresh_ones(self, staging_slots,
-                                               obs_dtype):
-        """From the second drain on, episodes are padded into the arrays of
-        episodes already drained: every batch must equal what a buffer that
-        has recycled nothing gives for the same episodes."""
-        lens = [5, 60, 64, 17, 100, 9, 33, 64, 2, 200, 64, 41]
-        kw = dict(obs_dim=6, act_dim=3, traj_per_epoch=4,
-                  buckets=(64, 256), staging_slots=staging_slots)
-        buf = EpochBuffer(**kw)
-        drained = 0
-        for i, n in enumerate(lens * 2):
-            if not buf.add_episode(_decoded_episode(n, i,
-                                                    obs_dtype=obs_dtype)):
-                continue
-            got = {k: v.copy() for k, v in buf.drain().as_dict().items()}
-            fresh = EpochBuffer(**kw)
-            for j in range(i - 3, i + 1):
-                fresh.add_episode(_decoded_episode((lens * 2)[j], j,
-                                                   obs_dtype=obs_dtype))
-            want = fresh.drain().as_dict()
-            assert got.keys() == want.keys()
-            for key in want:
-                np.testing.assert_array_equal(got[key], want[key], key)
-                assert got[key].dtype == want[key].dtype, key
-            assert got["obs"].dtype == obs_dtype
-            drained += 1
-        assert drained == 6
-        assert 0 < sum(len(v) for v in buf._spare.values()) <= 2 * 4
-        buf.reset()
-        assert not buf._spare and len(buf) == 0
-
-    @pytest.mark.parametrize("other", ["dtype", "horizon"])
-    def test_spare_of_another_dtype_or_horizon_is_never_written_over(
-            self, other):
-        """A drained episode's arrays are reused only by an episode of the
-        same horizon AND obs dtype: anything else pads into fresh arrays
-        and leaves every spare as it was."""
-        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=2,
-                          buckets=(64, 256))
-        for i in range(2):
-            buf.add_episode(_decoded_episode(30, i))
-        buf.drain()
-        spares = [t for v in buf._spare.values() for t in v]
-        assert len(spares) == 2 and all(t.obs.dtype == np.uint8
-                                        and t.obs.shape[0] == 64
-                                        for t in spares)
-        before = [t.obs.copy() for t in spares]
-        nxt = (_decoded_episode(30, 9, obs_dtype=np.float32)
-               if other == "dtype" else _decoded_episode(100, 9))
-        buf.add_episode(nxt)
-        new = buf._pending[-1]
-        assert all(new.obs is not t.obs for t in spares)
-        assert new.obs.dtype == (np.float32 if other == "dtype"
-                                 else np.uint8)
-        assert new.obs.shape[0] == (64 if other == "dtype" else 256)
-        for t, was in zip(spares, before):
-            np.testing.assert_array_equal(t.obs, was)
-        assert sum(len(v) for v in buf._spare.values()) == 2
-        # ...and the same kind of episode does take one
-        buf.add_episode(_decoded_episode(30, 10))
-        assert any(buf._pending[-1].obs is t.obs for t in spares)
-        assert sum(len(v) for v in buf._spare.values()) == 1
-
-    def test_pad_decoded_refuses_out_of_another_obs_dtype(self):
-        from relayrl_tpu.data.batching import pad_decoded
-
-        out = pad_decoded(_decoded_episode(5, 0, obs_dtype=np.float32),
-                          8, 6, 3)
-        with pytest.raises(ValueError, match="uint8"):
-            pad_decoded(_decoded_episode(5, 1), 8, 6, 3, out=out)
-
-
-# -- observations keep their wire dtype (uint8 frames stay bytes) ----------
-
 def _records_episode(n, seed, obs_dim=6, obs_dtype=np.uint8, frac=False):
     """The ActionRecord twin of ``_decoded_episode`` (same values)."""
     dt = _decoded_episode(n, seed, obs_dim, obs_dtype, frac)
@@ -249,6 +172,276 @@ def _records_episode(n, seed, obs_dim=6, obs_dtype=np.uint8, frac=False):
 
 _MAKERS = {"DecodedTrajectory": _decoded_episode,
            "ActionRecord": _records_episode}
+def _plain_batch(episodes, buckets=(64, 256, 1000), obs_dim=6, act_dim=3,
+                 pin=False):
+    """The batch as the tree before one-copy staging built it without a
+    slab: every episode padded apart to its own bucket, grown to the
+    largest bucket present, stacked into fresh arrays."""
+    from relayrl_tpu.data.batching import pad_decoded
+    from relayrl_tpu.types.columnar import DecodedTrajectory
+
+    padded = []
+    for ep in episodes:
+        pad = (pad_decoded if isinstance(ep, DecodedTrajectory)
+               else pad_trajectory)
+        padded.append(pad(ep, pick_bucket(len(ep), buckets), obs_dim,
+                          act_dim))
+    horizon = max(t.obs.shape[0] for t in padded)
+    for t in padded:
+        for name in ("obs", "act", "act_mask", "rew", "val", "logp",
+                     "valid"):
+            arr = getattr(t, name)
+            grow = [(0, horizon - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
+            setattr(t, name, np.pad(arr, grow))
+    return stack_trajectories(
+        padded, obs_dtype=np.float32 if pin else None).as_dict()
+
+
+def _assert_batch_bytes(got, want, what=""):
+    assert got.keys() == want.keys()
+    for key in want:
+        assert got[key].dtype == want[key].dtype, (what, key)
+        assert got[key].shape == want[key].shape, (what, key)
+        assert got[key].tobytes() == want[key].tobytes(), (what, key)
+
+
+@pytest.fixture
+def stack_spans(monkeypatch):
+    """Arguments of every ``rl:batch.stack`` span opened, in order."""
+    from relayrl_tpu.data import replay_buffer
+
+    seen = []
+
+    class recording(replay_buffer.span):
+        __slots__ = ()
+
+        def __init__(self, name, *a, **args):
+            super().__init__(name, *a, **args)
+            if name == "rl:batch.stack":
+                seen.append(self.args)
+
+    monkeypatch.setattr(replay_buffer, "span", recording)
+    return seen
+
+
+_U8, _F32 = np.uint8, np.float32
+# Each script: episodes (length, obs dtype) and the calls between them, for
+# a buffer of four rows and buckets (64, 256, 1000); then the rows moved a
+# second time, per drained batch.
+_SCRIPTS = {
+    "single_bucket": ([(5, _U8), (60, _U8), (64, _U8), (17, _U8), "drain",
+                       (9, _U8), (33, _U8), (64, _U8), (2, _U8), "drain"],
+                      [0, 0]),
+    "bucket_grows": ([(5, _U8), (9, _U8), (100, _U8), (7, _U8), "drain"],
+                     [2]),
+    "bucket_grows_twice": ([(5, _U8), (100, _U8), (300, _U8), (7, _U8),
+                            "drain"], [3]),
+    "bucket_grows_at_last_row": ([(5, _U8), (9, _U8), (7, _U8), (2000, _U8),
+                                  "drain"], [3]),
+    "bucket_shrinks": ([(100, _U8), (5, _U8), (9, _U8), (200, _U8),
+                        "drain"], [0]),
+    "uint8_then_float32": ([(5, _U8), (9, _U8), (7, _F32), (8, _U8),
+                            "drain"], [2]),
+    "float32_then_uint8": ([(5, _F32), (9, _U8), (7, _U8), (8, _U8),
+                            "drain"], [0]),
+    "grows_and_widens_at_once": ([(5, _U8), (100, _F32), (7, _U8),
+                                  (64, _U8), "drain"], [1]),
+    "part_filled_drain": ([(5, _U8), (100, _U8), (7, _U8), "drain",
+                           (9, _U8), (33, _U8), (64, _U8), (2, _U8),
+                           "drain"], [1, 0]),
+    "two_batches_pending": ([(5, _U8), (60, _U8), (64, _U8), (17, _U8),
+                             (100, _U8), (9, _F32), (33, _U8), (64, _U8),
+                             (2, _U8), "drain", "drain", "drain"],
+                            [0, 1, 0]),
+    "reset_mid_batch": ([(5, _U8), (100, _F32), "reset", (9, _U8),
+                         (33, _U8), (64, _U8), (2, _U8), "drain"], [0]),
+    "reset_then_another_key": ([(5, _U8), (9, _U8), "reset", (100, _F32),
+                                (33, _U8), (64, _U8), (2, _U8), "drain"],
+                               [0]),
+}
+
+
+def _play(script, make, staging_slots, pin=False):
+    """Run a script; returns the drained batches (copied), the episodes
+    each was made of, and the buffer."""
+    buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=4,
+                      buckets=(64, 256, 1000), staging_slots=staging_slots)
+    if pin:
+        buf.pin_float32_obs()
+    pending, batches, taken = [], [], []
+    for i, step in enumerate(script):
+        if step == "drain":
+            batches.append({k: v.copy()
+                            for k, v in buf.drain().as_dict().items()})
+            taken.append(pending[:4])
+            pending = pending[4:]
+        elif step == "reset":
+            buf.reset()
+            pending = []
+            assert len(buf) == 0 and not buf.ready
+            assert buf.pop_episode_stats() == ([], [])
+        else:
+            n, dtype = step
+            pending.append(make(n, i, obs_dtype=dtype))
+            ready = buf.add_episode(pending[-1])
+            assert len(buf) == len(pending)
+            assert ready == buf.ready == (len(pending) >= 4)
+    return batches, taken, buf
+
+
+class TestOneCopyStaging:
+    """Episodes are padded straight into their row of the batch slab: every
+    batch must equal, byte for byte and in dtype, what a buffer without
+    slabs gives and what padding apart and stacking gave."""
+
+    @pytest.mark.parametrize("path", sorted(_MAKERS))
+    @pytest.mark.parametrize("name", sorted(_SCRIPTS))
+    def test_slab_rows_equal_fresh_batches(self, name, path, stack_spans):
+        script, moved = _SCRIPTS[name]
+        make = _MAKERS[path]
+        got, taken, buf = _play(script, make, 3)
+        spans = list(stack_spans)
+        twin, _, _ = _play(script, make, 0)
+        assert len(got) == len(twin) == len(moved)
+        for k, (g, t, eps) in enumerate(zip(got, twin, taken)):
+            _assert_batch_bytes(g, t, f"{name}[{k}] vs staging_slots=0")
+            _assert_batch_bytes(g, _plain_batch(eps),
+                                f"{name}[{k}] vs padded apart")
+        assert [s["moved"] for s in spans] == moved
+        for s, g, eps in zip(spans, got, taken):
+            assert s["padded"] == g["valid"].size
+            assert s["valid"] == int(g["valid"].sum()) == sum(
+                min(len(e), 1000) for e in eps)
+        assert len(buf) == 0
+
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    def test_pinned_float32_slab_is_float32_from_the_first_row(
+            self, staging_slots, stack_spans):
+        script = _SCRIPTS["part_filled_drain"][0]
+        got, taken, buf = _play(script, _decoded_episode, staging_slots,
+                                pin=True)
+        for g, eps in zip(got, taken):
+            assert g["obs"].dtype == np.float32
+            _assert_batch_bytes(g, _plain_batch(eps, pin=True))
+        # widening is not a move: only the bucket growth counts
+        assert [s["moved"] for s in stack_spans] == [1, 0]
+
+    def test_drained_batch_is_the_slab_the_rows_were_written_to(
+            self, stack_spans):
+        """No copy at the drain: with a ring of one, the next batch's
+        first episode shows through the batch drained before."""
+        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=4,
+                          buckets=(64,), staging_slots=1)
+        for i in range(4):
+            buf.add_episode(_decoded_episode(64, i))
+        first = buf.drain()
+        assert first.obs.flags["C_CONTIGUOUS"] and first.obs.shape[0] == 4
+        nxt = _decoded_episode(64, 99)
+        assert first.obs[0].tobytes() != nxt.columns["o"].tobytes()
+        buf.add_episode(nxt)
+        assert first.obs[0].tobytes() == nxt.columns["o"].tobytes()
+        for i in range(3):
+            buf.add_episode(_decoded_episode(64, 100 + i))
+        second = buf.drain()
+        assert second.obs is first.obs
+        assert all(np.shares_memory(a, b) for a, b in zip(
+            first.as_dict().values(), second.as_dict().values()))
+        assert [s["moved"] for s in stack_spans] == [0, 0]
+
+    def test_part_filled_drain_views_the_leading_rows(self):
+        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=4,
+                          buckets=(64,))
+        for i in range(3):
+            buf.add_episode(_decoded_episode(9, i))
+        part = buf.drain()
+        assert part.batch_size == 3 and part.last_val.shape == (3,)
+        assert all(v.flags["C_CONTIGUOUS"] and v.base is not None
+                   for v in part.as_dict().values())
+
+    def test_batches_pending_behind_another_never_share_a_slab(self):
+        """More batches wait than the ring has slabs: each still holds its
+        own episodes when its turn to drain comes."""
+        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=2,
+                          buckets=(64,), staging_slots=1)
+        eps = [_decoded_episode(9, i) for i in range(8)]
+        for ep in eps:
+            buf.add_episode(ep)
+        assert len(buf) == 8
+        held = [buf.drain() for _ in range(4)]
+        for k, batch in enumerate(held):
+            _assert_batch_bytes(batch.as_dict(),
+                                _plain_batch(eps[2 * k: 2 * k + 2],
+                                             buckets=(64,)))
+
+    def test_episode_stats_are_those_of_the_episode_padded_apart(self):
+        """A float32 sum depends on the length summed over: the return is
+        taken over the episode's own bucket, not the slab's horizon."""
+        buf = EpochBuffer(obs_dim=6, act_dim=3, traj_per_epoch=4,
+                          buckets=(64, 256, 1000))
+        eps = [_decoded_episode(n, i) for i, n in
+               enumerate([900, 200, 63, 5])]
+        for ep in eps:
+            buf.add_episode(ep)
+        rets, lens = buf.pop_episode_stats()
+        from relayrl_tpu.data.batching import pad_decoded
+        apart = [pad_decoded(ep, pick_bucket(len(ep), buf.buckets), 6, 3)
+                 for ep in eps]
+        assert rets == [float(t.rew.sum()) for t in apart]
+        assert lens == [900, 200, 63, 5]
+
+
+class TestEpochBufferBuildsBatchesInSlabRows:
+    @pytest.mark.parametrize("obs_dtype", [np.uint8, np.float32])
+    @pytest.mark.parametrize("staging_slots", [3, 0])
+    def test_recycled_batches_equal_fresh_ones(self, staging_slots,
+                                               obs_dtype):
+        """From the ring's second round on, episodes are padded into rows
+        that held an earlier batch: every batch must equal what a buffer
+        that has built nothing yet gives for the same episodes."""
+        lens = [5, 60, 64, 17, 100, 9, 33, 64, 2, 200, 64, 41]
+        kw = dict(obs_dim=6, act_dim=3, traj_per_epoch=4,
+                  buckets=(64, 256), staging_slots=staging_slots)
+        buf = EpochBuffer(**kw)
+        drained = 0
+        for i, n in enumerate(lens * 4):
+            if not buf.add_episode(_decoded_episode(n, i,
+                                                    obs_dtype=obs_dtype)):
+                continue
+            got = {k: v.copy() for k, v in buf.drain().as_dict().items()}
+            fresh = EpochBuffer(**kw)
+            for j in range(i - 3, i + 1):
+                fresh.add_episode(_decoded_episode((lens * 4)[j], j,
+                                                   obs_dtype=obs_dtype))
+            want = fresh.drain().as_dict()
+            assert got.keys() == want.keys()
+            for key in want:
+                np.testing.assert_array_equal(got[key], want[key], key)
+                assert got[key].dtype == want[key].dtype, key
+            assert got["obs"].dtype == obs_dtype
+            drained += 1
+        assert drained == 12
+        buf.add_episode(_decoded_episode(5, 0, obs_dtype=obs_dtype))
+        buf.reset()
+        assert len(buf) == 0 and not buf.ready
+
+    def test_pad_decoded_refuses_out_of_a_narrower_obs_dtype(self):
+        from relayrl_tpu.data.batching import pad_decoded
+
+        out = pad_decoded(_decoded_episode(5, 0), 8, 6, 3)
+        with pytest.raises(ValueError, match="float32"):
+            pad_decoded(_decoded_episode(5, 1, obs_dtype=np.float32), 8, 6,
+                        3, out=out)
+        # the other way round widens exactly
+        wide = pad_decoded(_decoded_episode(5, 0, obs_dtype=np.float32),
+                           8, 6, 3)
+        got = pad_decoded(_decoded_episode(5, 1), 8, 6, 3, out=wide)
+        assert got.obs.dtype == np.float32
+        np.testing.assert_array_equal(
+            got.obs, pad_decoded(_decoded_episode(5, 1), 8, 6, 3).obs)
+
+
+# -- observations keep their wire dtype (uint8 frames stay bytes) ----------
+
 _LENS = [5, 60, 64, 17, 100, 9, 33, 64]
 
 

@@ -200,6 +200,57 @@ class TestStagingBuffers:
         assert len(set(ids)) == 2
         assert ids[0] == ids[2] and ids[1] == ids[3]
 
+    @pytest.mark.parametrize("algo_name,window", [("IMPALA", 2),
+                                                  ("REINFORCE", 1)])
+    def test_slab_opened_at_the_first_episode_is_free_by_then(
+            self, tmp_cwd, algo_name, window):
+        """A batch's slab is taken from the ring when its first episode
+        arrives, not when it is drained. In the learner's order (drain k
+        -> stage_batch -> train_on_batch k -> add_episode) the update that
+        last read that slab is fenced by then: ``window + 3`` updates,
+        never fenced from outside, take the inputs and give the parameters
+        of a twin whose every batch has a slab of its own. On the CPU
+        backend ``device_put`` may alias the slab, so a row written too
+        early would reach the update that still reads it."""
+        import jax
+        import jax.numpy as jnp
+
+        def run(staged):
+            algo = build_algorithm(
+                algo_name, obs_dim=OBS_DIM, act_dim=ACT_DIM,
+                env_dir=str(tmp_cwd / f"staged-{staged}"), traj_per_epoch=3,
+                hidden_sizes=[16], seed_salt=0, bucket_lengths=[64, 256],
+                max_inflight_updates=window)
+            assert algo.buffer._staging.slots == window + 1
+            if not staged:
+                algo.buffer.disable_staging()
+            inputs, params = [], []
+            for ep in _stream(3 * (window + 3)):
+                batch = algo.accumulate(ep)
+                if batch is None:
+                    continue
+                inputs.append({k: np.copy(v) for k, v in batch.items()})
+                algo.train_on_batch(algo.stage_batch(batch))
+                # a device-side copy: dispatched, not fenced
+                params.append(jax.tree_util.tree_map(jnp.copy,
+                                                     algo.state.params))
+            assert algo.inflight.dispatch_count == window + 3
+            assert algo.inflight.fenced_count == 3  # the window's own
+            return inputs, [jax.device_get(p) for p in params]
+
+        (in_a, par_a), (in_b, par_b) = run(True), run(False)
+        assert len(in_a) == len(in_b) == window + 3
+        for a, b in zip(in_a, in_b):
+            assert sorted(a) == sorted(b)
+            for k in a:
+                assert a[k].dtype == b[k].dtype, k
+                assert a[k].tobytes() == b[k].tobytes(), k
+        for k, (a, b) in enumerate(zip(par_a, par_b)):
+            la, lb = (jax.tree_util.tree_leaves(t) for t in (a, b))
+            assert len(la) == len(lb)
+            for x, y in zip(la, lb):
+                assert np.asarray(x).tobytes() == np.asarray(y).tobytes(), k
+
     def test_sample_out_gathers_identical_values(self):
         from relayrl_tpu.data import StepReplayBuffer
 

@@ -199,6 +199,34 @@ class TestPrimitive:
         assert stats == {**args, "mono_ns": sp.t0_ns}
 
 
+class TestBatchSpans:
+    @pytest.mark.parametrize("lens,moved", [((5, 9, 30), 0),
+                                            ((5, 9, 100), 2)])
+    def test_one_stack_span_a_drained_batch_with_its_counts(
+            self, tmp_path, lens, moved):
+        """``rl:batch.pad`` once an episode, ``rl:batch.stack`` once a
+        drained batch: ``moved`` counts the rows that an episode of a
+        larger bucket made the buffer copy a second time."""
+        from relayrl_tpu.data import EpochBuffer
+
+        buf = EpochBuffer(obs_dim=OBS_DIM, act_dim=ACT_DIM,
+                          traj_per_epoch=3, buckets=(64, 256))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for i, n in enumerate(lens):
+                buf.add_episode(_episode(n, seed=i))
+            batch = buf.drain()
+        finally:
+            jax.profiler.stop_trace()
+        events = _xplane_events(tmp_path)
+        assert len(events["rl:batch.pad"]) == 3
+        (_line, _start, _dur, stats), = events["rl:batch.stack"]
+        assert stats == {
+            "valid": sum(lens), "padded": 3 * batch.horizon,
+            "bytes": sum(v.nbytes for v in batch.as_dict().values()),
+            "moved": moved}
+
+
 class TestTracerSink:
     @pytest.fixture(autouse=True)
     def _tracer(self):
@@ -264,6 +292,9 @@ class TestLearnerSpans:
         for _line, _start, _dur, stats in stacks:
             assert stats["padded"] == 3 * 64 and 0 < stats["valid"] < 192
             assert stats["bytes"] > 3 * 64 * OBS_DIM * 4
+            # one bucket, one obs dtype: no row was copied a second time
+            assert stats["moved"] == 0
+            assert sorted(stats) == ["bytes", "moved", "padded", "valid"]
         assert len(events["rl:batch.pad"]) == 6
         items = events["rl:learner.item"]
         assert all(s["n"] == 1 and s["queued_us"] >= 0
