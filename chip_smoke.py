@@ -42,13 +42,13 @@ OUT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
 RUN_DIR = os.path.join(OUT_DIR, "run")
 WALL_LIMIT_S = 1150.0  # the driver allows 1200 s, compilation included
 
-# Phase A: the 256x256 bf16 MLP of the old bench.py headline, under the
+# Phase A: a 256x256 bf16 MLP (BASELINE.md's REINFORCE shape) under the
 # closed loop.
 A_ACTORS = 2
 A_UPDATES = 12   # past learner.checkpoint_every_epochs (10): one orbax
 #                  save happens while the persistent compile cache is on
-# Phase B: the widest model the repo has run (learner_tpu.json
-# transformer_flash_computebound), through the normal seam.
+# Phase B: docs/parallelism.md's transformer_flash_computebound shape
+# (d1024, head_dim 128), through the normal seam.
 B_ARCH = dict(model_kind="transformer_discrete", d_model=1024, n_layers=4,
               n_heads=8, max_seq_len=1024, attention="flash")
 B_OBS, B_ACT, B_T, B_TRAJ = 64, 18, 1024, 4
@@ -442,8 +442,8 @@ def phase_b(config_path: str) -> None:
         f"{resolved}, {n_mosaic} Mosaic custom calls in the compiled update, "
         f"flash-vs-dense max |dlogp| {err:.1e}, {time.monotonic() - t0:.0f}s")
 
-    # The fence question (bench.py, benches/common.py): the same chain of
-    # updates, fenced three ways. Straight through the jitted update — the
+    # The fence question (docs/operations.md, jaxlint JAX06): the same chain
+    # of updates, fenced three ways. Straight through the jitted update — the
     # in-flight window would fence for us.
     state = algo.state  # settled: the in-flight window was drained above
     walls = {}

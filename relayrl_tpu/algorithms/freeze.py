@@ -1,13 +1,13 @@
-"""Frozen-layer optimizer masks (``learner.freeze``), promoted to
-first-class config from the bench-only recipe of
-benches/bench_model_wire.py (the 7.7x RLHF-finetune headline row).
+"""Frozen-layer optimizer masks (``learner.freeze``): first-class
+config for fine-tune-style runs that train the heads and upper blocks
+over a frozen trunk.
 
 ``learner.freeze`` is a regex (or list of regexes) matched against
 "/"-joined parameter leaf paths (e.g. ``params/block_0/qkv/kernel``).
 Matching leaves are partitioned to ``optax.set_to_zero()`` via
 ``optax.multi_transform`` — NOT ``optax.masked``, which passes raw
 gradients through for unmasked leaves and silently moves the "frozen"
-params (caught in-bench, PR 5). Frozen leaves are therefore
+params (caught in PR 5). Frozen leaves are therefore
 bit-identical across any number of updates, which is also what makes
 them free on the wire: model-wire v2's delta encoder skips unchanged
 leaves outright, so every frozen leaf lands in

@@ -84,8 +84,8 @@ class OffPolicyAlgorithm(AlgorithmBase):
         # Dispatch fusion: run K sampled-batch updates inside ONE jitted
         # call (lax.scan over a [K, B, ...] stack). Small per-update
         # batches on a fast accelerator are dominated by per-dispatch
-        # host->device latency (benches/README.md DQN chip row: a 2x128
-        # MLP at B=256 spends more time on dispatch than math); fusing K
+        # host->device latency (ROADMAP 1.6; no benchmark cell is small
+        # enough to show it yet: a 2x128 MLP at B=256 would be); fusing K
         # of them amortizes that fixed cost without changing the math —
         # the scan threads state through the same K sequential updates
         # the unfused loop would run. Single-host only (the multi-host

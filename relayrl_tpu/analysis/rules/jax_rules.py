@@ -458,7 +458,7 @@ class UntimedJitDispatch(Rule):
         ``block_until_ready``, or a ``float(...)`` / ``np.asarray(...)``
         host readback of a non-constant value — the documented
         alternative on platforms where block_until_ready returns at
-        dispatch (see bench.py's host-fence note). Any such call anywhere
+        dispatch (docs/static_analysis.md JAX06). Any such call anywhere
         in the function counts: this rule deliberately trades recall for
         precision (an incidental float() on host data will mask a real
         unfenced measurement, but a fence-looking call must never be

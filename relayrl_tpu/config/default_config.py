@@ -174,16 +174,16 @@ DEFAULT_CONFIG: dict = {
         # actions come from the serving plane (serving.enabled on the
         # training server), the "millions of users" topology.
         # examples/train_distributed.py reads it to pick the actor
-        # topology (--num-envs overrides); benches/bench_soak.py's
-        # --vector/--anakin flags are the bench-plane equivalents.
+        # topology (--num-envs overrides); tests/drills/soak.py's
+        # vector= / anakin= arguments are the drills' equivalents.
         "host_mode": "process",
         # -- anakin tier (actor.host_mode: "anakin") --
         # Env steps per lane per fused dispatch: each dispatch returns a
         # [num_envs, unroll_length] trajectory window. Bigger amortizes
         # the dispatch further but widens the model-staleness window (a
         # hot-swap lands between windows, never inside one) and the
-        # host-side unstack burst. 32 is past the knee of the committed
-        # scaling curve (benches/results/anakin_rollout.json).
+        # host-side unstack burst. 32 was chosen on a CPU host; no
+        # benchmark cell measures it (ROADMAP 2.2, 3.11).
         "unroll_length": 32,
         # On-device env id for the anakin tier, resolved through the JAX
         # env registry (envs/jax/__init__.py; see envs.list_envs()).
@@ -203,12 +203,12 @@ DEFAULT_CONFIG: dict = {
         # overlaps the next window's device dispatch (bounded depth-2
         # hand-off — a slow wire backpressures the rollout loop).
         # Worth it when host_share_of_wall is high and a spare core
-        # exists; single-core hosts should leave it off. False is the
-        # MEASURED default: the committed A/B
-        # (benches/results/anakin_rollout.json,
-        # speedup_async_emit_vs_sync) shows 0.89-1.18x (median ~0.97)
-        # on the soak host — the hand-off overhead eats the overlap
-        # when rollout and emitter share a core.
+        # exists; single-core hosts should leave it off. False was
+        # chosen on a CPU host (there the hand-off overhead ate the
+        # overlap when rollout and emitter shared a core). No
+        # benchmark cell measures it: the rollout cell will, and it
+        # decides which of the two emit paths stays (ROADMAP 1.9, 3.8,
+        # 3.11).
         "async_emit": False,
         # Coalesce up to this many completed columnar segments (per
         # logical lane, per rollout window) into ONE transport send —
@@ -216,12 +216,12 @@ DEFAULT_CONFIG: dict = {
         # complete many segments per window, and each send pays the
         # envelope + spool + socket path. 1 keeps the one-frame-per-send
         # behavior; relays batch-forward the same container upstream
-        # (relay.batch_max), so the framing helper is shared. 1 is the
-        # MEASURED default: the committed A/B (anakin_rollout.json,
-        # speedup_emit_coalesce_vs_single) is neutral at 0.87-1.13x
-        # (median ~0.99) on CartPole-length episodes — raise it only
+        # (relay.batch_max), so the framing helper is shared. 1 was
+        # chosen on a CPU host with CartPole-length episodes; no
+        # benchmark cell measures it (ROADMAP 1.9, 3.11) — raise it only
         # when episodes are much shorter than unroll_length AND the
-        # per-send envelope cost shows up in host_share_of_wall.
+        # per-send envelope cost shows up in the host's share of a
+        # window (rollout()'s unstack_s against its dispatch_s).
         "emit_coalesce_frames": 1,
         # Trajectory wire form. "auto" (the default) picks per tier:
         # anakin hosts ship whole rollout segments as contiguous columnar
@@ -551,7 +551,7 @@ DEFAULT_CONFIG: dict = {
     "telemetry": {
         # false = the process-global registry stays a NullRegistry: every
         # instrumentation site holds a no-op metric and the hot-path cost
-        # is a single attribute call (benches/bench_telemetry.py).
+        # is a single attribute call on one shared no-op.
         "enabled": False,
         # Exporter port for /metrics (Prometheus text) + /snapshot
         # (JSON), served by the training-server process; 0 binds an
@@ -634,7 +634,7 @@ DEFAULT_CONFIG: dict = {
         "freeze": None,
         "mesh": {"dp": -1, "fsdp": 1, "ep": 1, "tp": 1, "sp": 1, "pp": 1},
         # compute dtype for policy trunks: float32 on CPU actors/tests;
-        # set "bfloat16" on TPU learners to feed the MXU (bench configs do).
+        # set "bfloat16" on TPU learners to feed the MXU (benchmark/configs do).
         "precision": "float32",
         "checkpoint_dir": "checkpoints",
         "checkpoint_every_epochs": 10,

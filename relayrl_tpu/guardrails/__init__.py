@@ -152,7 +152,7 @@ class Guardrails:
             algo.ingest_finite_guard = False
 
     def accounting(self) -> dict:
-        """The drill/bench evidence block (rides chaos rows)."""
+        """The drill evidence block (rides chaos results)."""
         out = {
             "validation_mode": self.validation_mode,
             "quarantine": self.quarantine.accounting(),

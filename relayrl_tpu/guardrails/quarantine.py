@@ -126,7 +126,7 @@ class QuarantineBook:
             until = self._quarantined.get(agent_id)
         return max(0.0, until - time.monotonic()) if until else 0.0
 
-    # -- accounting (bench rows / drills) --
+    # -- accounting (drills, status loops) --
     def accounting(self) -> dict:
         with self._lock:
             return {

@@ -298,7 +298,7 @@ def pick_chunk_block(C: int, cap: int = 1024) -> int | None:
 
 def _resolve_chunk_block(C: int, block: int | None) -> int:
     """Shared block-resolution/tile-validation policy for the sharded ring
-    and the single-device cost model — one copy, so the bench rows always
+    and the single-device cost model — one copy, so the cost model always
     measure the same kernels the ring runs."""
     if block is None:
         block = pick_chunk_block(C)
@@ -481,8 +481,8 @@ def chunked_flash_local(q: jax.Array, k: jax.Array, v: jax.Array,
     :func:`relayrl_tpu.ops.flash.flash_attention` at equal T measures
     what ring chunking costs per device (state-carry HBM traffic +
     per-call overhead) separately from ICI transfer time, which this
-    deliberately excludes. ``benches/bench_attention.py`` emits rows for
-    it on TPU.
+    deliberately excludes. No benchmark cell runs it (ROADMAP 3.6);
+    tests/test_attention.py holds it to dense.
     """
     B, T, H, D = q.shape
     if T % n_chunks:

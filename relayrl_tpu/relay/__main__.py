@@ -5,13 +5,13 @@ Two configuration surfaces:
 * human flags (``--upstream-type zmq --upstream-listener tcp://... ``
   etc.) layered over the ``relay.*`` config section, for operators;
 * ``--json '{...}'`` — a dict of :class:`RelayNode` ctor kwargs, for
-  drivers (benches, tests) that already hold the topology as data.
+  drivers (drills, tests) that already hold the topology as data.
 
 The process relays until ``--duration`` lapses, ``--stop-file``
 appears, or SIGTERM/SIGINT arrives; on the way out it flushes the
 spool, and with ``--result-path`` writes a JSON result (relay stats +
 the full telemetry snapshot in the production ``/snapshot`` schema) for
-the driver to embed — the bench's relay-counter evidence.
+the driver to embed — the drills' relay-counter evidence.
 """
 
 from __future__ import annotations
@@ -93,7 +93,7 @@ def main(argv: list[str] | None = None) -> int:
 
     if not args.no_telemetry:
         # A live registry regardless of config telemetry.enabled: the
-        # relay's result file must carry its counters (the bench/test
+        # relay's result file must carry its counters (the drill/test
         # workers' chaos_telemetry convention).
         telemetry.set_registry(telemetry.Registry(
             run_id=f"relay-{kwargs.get('name') or 'node'}"))

@@ -17,7 +17,7 @@ Pieces:
 The environment half lives in the env registries (``TokenGen-v0`` —
 ``envs/tokengen.py`` + the pure-JAX twin), the frozen-layer optimizer
 masks in ``algorithms/freeze.py`` (the ``learner.freeze`` knob), and
-the end-to-end scenario in ``benches/bench_rlhf.py``.
+the live dataflow test in ``tests/test_rlhf.py::TestLivePlane``.
 """
 
 from relayrl_tpu.rlhf.scorers import (  # noqa: F401

@@ -15,8 +15,8 @@ with per-lane PRNG keys split from one seed, in-scan autoreset
 (``envs.jax.base.step_autoreset`` — lanes never leave the device between
 episodes), and the whole carry (keys + env states + observations) donated
 back to the next window. Amortized per env step, the dispatch cost tends
-to zero as ``unroll_length`` grows; the scaling curve lives in
-``benches/bench_anakin.py`` and the committed results row.
+to zero as ``unroll_length`` grows. Its rate on the chip is not
+measured yet: the fused tier has no benchmark cell (ROADMAP 2.2).
 
 The host side of the engine is an **unstacker**: one ``device_get`` of
 the stacked window, then a replay of the window into the existing

@@ -560,7 +560,7 @@ class InferenceService:
     def _execute(self, batch: list[InferRequest], reason: str) -> None:
         t0 = time.monotonic()
         # Close accounting rides AHEAD of the dispatch: a reply observer
-        # (test, bench row) reading the counters right after its reply
+        # (test, drill) reading the counters right after its reply
         # arrives must already see this batch counted — the timing
         # histograms below stay post-dispatch because they measure it.
         self._m_batches[reason].inc()
@@ -855,7 +855,7 @@ class InferenceService:
                   flush=True)
 
     def accounting(self) -> dict:
-        """Bench/drill evidence block (mirrors the registry counters)."""
+        """Drill evidence block (mirrors the registry counters)."""
         return {
             "queue_depth": len(self._queue),
             "max_batch": self.max_batch,

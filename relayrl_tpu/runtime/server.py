@@ -185,8 +185,8 @@ class TrainingServer:
             "sequence-tagged trajectories dropped by idempotent ingest "
             "(replays, retry storms, duplicate-injection faults)")
         # Same bucket grid as the scheduler's emit-side lag histogram —
-        # bench_rlhf compares the two distributions side by side, so the
-        # grids must never drift apart.
+        # the two distributions are read side by side (telemetry.top), so
+        # the grids must never drift apart.
         from relayrl_tpu.rlhf.scheduler import LAG_BUCKETS
 
         self._m_rlhf_train_lag = reg.histogram(
@@ -530,7 +530,7 @@ class TrainingServer:
         self.inference = None
         serving_cfg = self.config.get_serving_params()
         if serving is not None:
-            # Ctor override for drivers/benches that decide the topology
+            # Ctor override for drivers/drills that decide the topology
             # programmatically (examples/train_distributed.py
             # --host-mode remote); config holds every other knob.
             serving_cfg["enabled"] = bool(serving)
@@ -1874,7 +1874,7 @@ class TrainingServer:
         return self._halted
 
     def guardrails_accounting(self) -> dict:
-        """Guardrail evidence block for drills/benches/status loops:
+        """Guardrail evidence block for drills and status loops:
         validation + quarantine + watchdog + admission accounting plus
         the server-side rollback/halt ledger. Empty when disabled."""
         g = self.guardrails
@@ -1981,7 +1981,7 @@ class TrainingServer:
                   f"{e!r}; dedup starts empty", flush=True)
 
     def ingest_accounting(self) -> dict:
-        """Sequence accounting for drills/benches: per-agent
+        """Sequence accounting for drills: per-agent
         ``{max_seq, accepted, contiguous}`` + duplicate count. Empty when
         dedup is disabled."""
         if self._ingest_ledger is None:

@@ -26,7 +26,7 @@ restored ledger and therefore re-accepted on replay — correct, since the
 updates they fed were rolled back with the params — while trajectories
 the restored params already learned from stay deduplicated. Zero loss,
 zero double-training, asserted end-to-end by tests/test_recovery.py and
-``bench_soak --chaos``.
+the chaos drill of tests/test_drills.py.
 
 The spool file format (``dir`` given) is a flat append log:
 ``SPL1`` magic, then per record ``u32 total_len | u32 seq | u16 id_len |
@@ -121,7 +121,7 @@ class TrajectorySpool:
             return self._next_seq.get(agent_id, 0) + 1
 
     def sent_counts(self) -> dict[str, int]:
-        """Per-agent highest assigned seq (the accounting the chaos bench
+        """Per-agent highest assigned seq (the accounting the chaos drill
         reconciles against the server ledger)."""
         with self._lock:
             return dict(self._next_seq)
@@ -199,7 +199,7 @@ class TrajectorySpool:
     def flush(self, deadline_s: float = 30.0) -> bool:
         """Replay until one FULL pass of the retained window succeeds
         (or the deadline lapses): end-of-run delivery guarantee for
-        drills/benches. Rides out an open breaker by waiting for its
+        drills. Rides out an open breaker by waiting for its
         half-open probe windows."""
         import time
 

@@ -1,9 +1,9 @@
 """Vectorized actor host: N logical agents, one batched jitted policy step.
 
-The round-5 soak shows every transport collapsing going 32 → 64 actor
-*processes* on this host (zmq 734 → 1.7 steps/s,
-benches/results/soak_scaling_zmq.json) — process oversubscription, not
-transport cost. The fix that transfers from large-scale RL practice is
+One actor process per agent stops scaling at the host's core count:
+past it the processes oversubscribe the cores (scheduler churn, one jit
+cache and one set of listener threads per process) — not a transport
+cost. The fix that transfers from large-scale RL practice is
 actor-side batching: Podracer's Anakin steps many environments against a
 single jitted policy call (arxiv 2104.06272), and TorchBeast/IMPALA batch
 actor inference so env count decouples from process count (arxiv

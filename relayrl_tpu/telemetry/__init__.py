@@ -15,7 +15,7 @@ module. Instrumentation sites (server, pipeline, transports, actors,
 epoch logger) call :func:`get_registry` / :func:`emit` at construction
 time and hold direct metric references — when telemetry is disabled
 those are null objects and the hot-path cost is a single attribute call
-(benches/bench_telemetry.py commits the numbers).
+on one shared no-op (tests/test_telemetry.py::TestCore holds that).
 
 Enablement: the first :class:`~relayrl_tpu.config.ConfigLoader`-bearing
 component in a process (TrainingServer, Agent, VectorAgent) calls
@@ -23,7 +23,7 @@ component in a process (TrainingServer, Agent, VectorAgent) calls
 (docs/observability.md has the knob table) and installs a real
 :class:`~relayrl_tpu.telemetry.core.Registry` + journal once; later
 calls are no-ops so a server and an in-process agent can't fight over
-it. Embedders and benches can instead install a registry directly with
+it. Embedders and drills can instead install a registry directly with
 :func:`set_registry` and serve it with :func:`serve`.
 
 Consume with Prometheus against ``/metrics``, any JSON poller against
@@ -74,7 +74,7 @@ def get_registry():
 
 
 def set_registry(registry) -> None:
-    """Install a registry explicitly (benches, tests, embedders). Marks
+    """Install a registry explicitly (drills, tests, embedders). Marks
     the process configured so a later config-driven component doesn't
     overwrite it."""
     global _registry, _configured

@@ -15,7 +15,7 @@ tree already has:
   untagged ``@fleet/<proc>`` marker, the payload is sniffed by magic at
   every ingest funnel exactly like columnar ``RLD1`` frames.
 * **Merge semantics** — :func:`merge_snapshots` is THE one merge
-  implementation (benches pool soak-row snapshots through it too):
+  implementation (tests/drills/soak.py pools snapshots through it too):
   counters sum, gauges keep min/max/sum/count across procs (the
   per-proc latest lives in the fleet table), histograms sum bucket-wise
   (the shared bucket presets make grids compatible; mismatches are
@@ -156,7 +156,7 @@ def merge_snapshots(snapshots: Iterable[Mapping]) -> dict:
 
     The output is itself snapshot-schema (``metrics`` sorted like
     ``Registry.snapshot``), so every existing consumer — the Prometheus
-    renderer, ``histogram_quantile``, the bench pooling — reads it
+    renderer, ``histogram_quantile``, the drills' pooling — reads it
     unchanged.
     """
     merged: dict[tuple, dict] = {}
@@ -248,7 +248,7 @@ def snapshot_metric(snap: Mapping, name: str,
                     labels: Mapping | None = None) -> float | None:
     """One scalar out of a snapshot document, labels matched as a SUBSET
     (instance-distinguishing labels the caller doesn't care about must
-    not break the lookup). The shared helper the benches used to
+    not break the lookup). The shared helper the drills used to
     re-implement privately."""
     want = {str(k): str(v) for k, v in (labels or {}).items()}
     for m in snap.get("metrics", []):
@@ -461,7 +461,7 @@ class FleetTable:
     def sweep(self, now: float | None = None) -> list[str]:
         """Evict procs silent past ``stale_s``; returns the evicted proc
         ids (the caller journals them — this module never imports the
-        journal so benches can use the table standalone)."""
+        journal so drills can use the table standalone)."""
         now = time.monotonic() if now is None else now
         evicted = []
         with self._lock:

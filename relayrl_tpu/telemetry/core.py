@@ -13,8 +13,8 @@ Design constraints (ISSUE 4 tentpole, part 1):
   false the process-global registry is a :class:`NullRegistry` whose
   metrics are one shared do-nothing object — instrumentation sites hold
   a direct metric reference, so the disabled cost is a single attribute
-  call (``self._m_steps.inc()``), measured by
-  ``benches/bench_telemetry.py``.
+  call (``self._m_steps.inc()``) —
+  ``tests/test_telemetry.py::TestCore::test_null_registry_is_total_noop``.
 * **JAX-aware: never fence a dispatch.** :meth:`Gauge.set` stores
   whatever it is given — a host float or an in-flight device scalar —
   and resolves to a host float only inside :meth:`Registry.snapshot`
@@ -269,7 +269,7 @@ class Histogram(_ShardedMetric):
 class Registry:
     """Process metrics registry: get-or-create by (name, labels), one
     structured :meth:`snapshot` consumed by the Prometheus exporter, the
-    JSON endpoint, ``telemetry.top`` and the soak bench rows (one
+    JSON endpoint, ``telemetry.top`` and the fleet drills (one
     schema everywhere — the acceptance bar)."""
 
     enabled = True
@@ -345,7 +345,7 @@ class Registry:
             if m.help:
                 entry["help"] = m.help
             # Non-finite values become JSON null, never bare NaN/Inf: the
-            # snapshot is served as strict JSON (/snapshot, bench rows)
+            # snapshot is served as strict JSON (/snapshot, drill results)
             # and a diverging run's NaN loss must not make the whole
             # document unparseable at exactly the moment an operator
             # needs it. The Prometheus renderer maps null back to NaN

@@ -6,7 +6,7 @@ Endpoints (GET):
 * ``/metrics``  — Prometheus text exposition format 0.0.4 (the scrape
   surface; conformance locked by tests/test_telemetry.py).
 * ``/snapshot`` — the registry's structured JSON snapshot verbatim (the
-  schema ``telemetry.top`` and the soak-bench rows consume — one schema
+  schema ``telemetry.top`` and the fleet drills consume — one schema
   for live scrapes and committed artifacts).
 * ``/healthz``  — liveness stub for probes.
 

@@ -44,16 +44,16 @@ numbers the metrics plane cannot produce: end-to-end **data age**
 (env-step → consumed-by-update) and **model age** (dispatch →
 applied-at-actor) distributions. The same ages are observed live into
 ``relayrl_trace_data_age_seconds`` / ``relayrl_trace_model_age_seconds``
-(surfaced by ``telemetry.top`` and embedded in bench_soak rows).
+(surfaced by ``telemetry.top`` and pooled by the fleet drills).
 
 Clock discipline: every stamp is CLOCK_MONOTONIC ``monotonic_ns()`` —
-comparable across processes on ONE host (the soak-bench fan-out
-methodology). Cross-host pairs inherit the PR 4 skew guard: an age
+comparable across processes on ONE host (how the transports stamp
+model receipts). Cross-host pairs inherit the PR 4 skew guard: an age
 outside ``[0, 300 s)`` is dropped as skew, never observed, and the
 analyzer applies the same bound when joining spans from different
 journals. Disabled mode is a shared :data:`NULL_TRACER` whose every
 surface is a no-op attribute call — the instrumented sites cost one
-``.enabled`` check (ceilings committed by ``benches/bench_telemetry.py``).
+``.enabled`` check (tests/test_trace.py holds the null tracer's no-ops).
 """
 
 from __future__ import annotations
@@ -282,7 +282,7 @@ class Tracer:
 class NullTracer:
     """Disabled mode: every surface is a no-op attribute call; sites
     gate their clock reads on ``.enabled`` so the hot paths stay
-    untouched (asserted by benches/bench_telemetry.py)."""
+    untouched (no test times it: a CPU run asserts no time)."""
 
     enabled = False
     sample_rate = 0.0

@@ -26,13 +26,13 @@ from relayrl_tpu.transport.probe import (
 def _resolve_auto() -> str:
     """``auto`` -> native framed-TCP when the C++ core loads, else zmq.
 
-    The 64-actor shootout (benches/results/transport_scale.json) shows
-    native ~1.5x faster than pyzmq on model fan-out; ``zmq`` stays the
-    DEFAULT for reference parity. On the *server* (bind) side this local
-    resolution defines the fleet's protocol; on the agent side ``auto``
-    additionally *negotiates* against the live server via
-    :func:`probe_endpoint`, so a mixed fleet converges on whatever the
-    server actually speaks instead of splitting protocols.
+    Native was preferred on a CPU host's fan-out comparison; no benchmark
+    cell measures the transports against each other (ROADMAP 3.9, 3.11).
+    ``zmq`` stays the DEFAULT for reference parity. On the *server*
+    (bind) side this local resolution defines the fleet's protocol; on
+    the agent side ``auto`` additionally *negotiates* against the live
+    server via :func:`probe_endpoint`, so a mixed fleet converges on
+    whatever the server actually speaks instead of splitting protocols.
     """
     from relayrl_tpu.transport.native_backend import native_available
 
@@ -40,7 +40,7 @@ def _resolve_auto() -> str:
 
 
 # Conclusive probe verdicts, cached per endpoint with a short TTL: a
-# process that builds many agents against one server (soaks, benches,
+# process that builds many agents against one server (soaks, drills,
 # vector envs) pays the probe round-trip once, while a server swapped to
 # a different backend on the same port ages out quickly. Inconclusive
 # verdicts are never cached — the server may simply not be up yet — and

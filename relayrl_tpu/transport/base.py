@@ -197,7 +197,7 @@ def split_agent_trace(agent_id: str) -> tuple[str, str | None]:
 def pack_model_frame(version: int, bundle_bytes: bytes,
                      pub_ns: int | None = None) -> bytes:
     """``pub_ns`` is the publisher's CLOCK_MONOTONIC stamp (same-host
-    comparable — the soak bench's fan-out methodology): when present, a
+    comparable — how model receipts are stamped): when present, a
     receiving SUB thread can compute its own publish→receipt latency
     without any cross-process glue. Omitted by default so handshake
     replies stay byte-stable; absent keys are simply not decoded."""
@@ -327,7 +327,7 @@ class ReceiptLedger:
     The Python mirror of the native C++ reader's ledger
     (``rl_sub_receipts``), shared by the zmq and grpc agent transports
     so the stamping semantics and bounds can never drift between
-    backends (the zmq 64-actor 0.433 lesson, benches/README.md)."""
+    backends (stamped post-decode, a busy SUB thread loses receipts)."""
 
     def __init__(self, maxlen: int = 65536):
         self._receipts: deque[tuple[int, int]] = deque(maxlen=maxlen)
@@ -416,7 +416,7 @@ def agent_wire_metrics(backend: str) -> dict:
     * ``model_deliver_seconds``        — SUB/poll thread time from the
       pre-decode receipt stamp to ``on_model`` returning (decode + swap
       + persist): the per-receipt cost that starves Python SUB threads
-      at fleet fan-out rates (benches/README.md, zmq 64-actor row)
+      at fleet fan-out rates
     * ``receipt_latency_seconds``      — publish→receipt when the frame
       carries the publisher's monotonic stamp (same-host pairs only)
     * ``reconnects``                   — transport heals/redials
@@ -563,7 +563,7 @@ class AgentTransport(abc.ABC):
     Backends that stamp model receipts pre-decode additionally expose
     ``drain_receipts() -> [(version, rx_mono_ns), ...]`` — the native
     C++ ledger's surface, mirrored in Python by the zmq/grpc listeners
-    so fan-out accounting (benches/bench_soak.py) is backend-uniform.
+    so fan-out accounting is backend-uniform.
     """
 
     def __init__(self):

@@ -109,9 +109,9 @@ def _zlib_compress_delta(data: bytes) -> bytes:
     # Z_RLE: run-length matches + Huffman literals. Delta payloads are
     # byte-plane transposed, so the high planes are long zero runs (RLE
     # folds them at memcpy speed) and the low planes are skewed literals
-    # (Huffman entropy-codes them) — measured both faster AND tighter
-    # than default deflate on real update deltas (benches/results/
-    # model_wire.json).
+    # (Huffman entropy-codes them). Chosen over default deflate on a
+    # CPU host; the benchmark's loop cell reads what it costs there as
+    # publish_encode_ms (PERF.md §5).
     co = zlib.compressobj(6, zlib.DEFLATED, zlib.MAX_WBITS, 9, zlib.Z_RLE)
     return co.compress(data) + co.flush()
 
@@ -411,9 +411,9 @@ class ModelWireEncoder:
     #: sniffing decode handles both formats): at ~100 KB the whole
     #: broadcast is two packets, dense-update deltas barely compress,
     #: and the zigzag/deflate work would COST publish→swap latency where
-    #: there are no meaningful bytes to win (benches/results/
-    #: model_wire.json latency rows). Deltas start paying around the
-    #: quarter-megabyte mark and dominate from transformer sizes up.
+    #: there are no meaningful bytes to win. The threshold was chosen on
+    #: a CPU host; no benchmark cell measures it (ROADMAP 3.10, 3.11):
+    #: the loop cell's 3.3 MB publish is already past it.
     SMALL_MODEL_BYTES = 256 * 1024
 
     def __init__(self, keyframe_interval: int = 10, compress: Any = "auto",

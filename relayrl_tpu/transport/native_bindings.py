@@ -553,8 +553,8 @@ class NativeAgentTransportImpl(AgentTransport):
                              else float(heartbeat_s))
         # Async mode: a C++ reader thread owns the socket — it parses and
         # CLOCK_MONOTONIC-timestamps every ModelPush the moment it arrives
-        # (GIL-free; the receipt ledger is the soak benches' fan-out
-        # evidence), owns the sub-channel keepalive, and reconnects. The
+        # (GIL-free; the receipt ledger behind drain_receipts()),
+        # owns the sub-channel keepalive, and reconnects. The
         # Python thread below only drains the decoded queue.
         self._lib.rl_sub_start_async(self._sub, int(self._heartbeat_s * 1000))
         self._stop.clear()

@@ -120,7 +120,7 @@ class RetryPolicy:
 class _OpCounters:
     """Per-op labeled counter front, lazily materialized per op label.
     Re-resolves against the CURRENT process registry on every call path
-    where it changed (benches install a fresh registry per row; a cached
+    where it changed (drills install a fresh registry per run; a cached
     metric bound to the old one would silently vanish from snapshots)."""
 
     def __init__(self, name: str, help_text: str):

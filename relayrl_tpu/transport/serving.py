@@ -186,8 +186,8 @@ def _infer_reply_fields(reply: dict) -> dict:
 #
 # A multiplexing client's per-step wire cost is dominated by per-request
 # overhead — one msgpack round + one socket hop each way per lane
-# (~190us/step measured on the bench host, ~40% of the total step
-# budget). Pipelining alone cannot reclaim it on a saturated core: there
+# (not measured on the chip machine: the serving plane has no cell,
+# ROADMAP 2.6). Pipelining alone cannot reclaim it on a saturated core: there
 # is no latency to hide, only work to amortize. Wave frames carry a
 # whole homogeneous wave in ONE frame with STACKED tensors (one obs
 # block, one key block), and the service coalesces replies the same way
@@ -529,7 +529,7 @@ class ZmqStreamingClient:
     inproc PUSH/PULL pipe (the ZmqServingPlane pattern, mirrored
     client-side), so a submit never waits on a reply and never touches
     the DEALER. ``inflight_high_water`` records the deepest concurrent
-    pipeline seen — the bench/test evidence that streaming actually
+    pipeline seen — the drill/test evidence that streaming actually
     streams (≥2 asserted by the serving smoke)."""
 
     def __init__(self, addr: str, identity: str | None = None):

@@ -164,8 +164,8 @@ class ZmqServerTransport(ServerTransport):
 
         # The publisher's monotonic stamp rides the frame so every SUB
         # thread on this host can compute publish→receipt latency
-        # locally (the telemetry answer to the soak bench's fan-out
-        # methodology; cross-host stamps don't pair and are ignored).
+        # locally (relayrl_transport_receipt_latency_seconds;
+        # cross-host stamps don't pair and are ignored).
         # A model blob over chunk_bytes ships as ordered chunk frames
         # under ONE lock hold, so no other publish can interleave; the
         # agent-side ChunkReassembler restores the original frame.
@@ -309,7 +309,7 @@ class ZmqAgentTransport(AgentTransport):
         # ledger's Python mirror): (version, rx_mono_ns) stamped the
         # moment recv returns, BEFORE the frame is decoded or the swap
         # runs — so fan-out accounting measures the wire, not the Python
-        # decode backlog behind it (benches/README.md zmq 64-actor note).
+        # decode backlog behind it (stamped after, a busy fleet loses them).
         self._ledger = ReceiptLedger()
         # Chunked model frames (server transport.chunk_bytes) reassemble
         # here before the ledger stamp / on_model, so one publish is one

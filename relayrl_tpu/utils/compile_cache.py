@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
-An entry point that owns a device (``chip_smoke.py``, ``bench.py``, the
-learner process of the examples) calls :func:`resolve_compile_cache` once,
+An entry point that owns a device (``chip_smoke.py``, ``benchmark/run.py``,
+the learner process of the examples) calls :func:`resolve_compile_cache` once,
 before its first compile. The library never calls it at import, and the
 tests keep the cache off (tests/conftest.py says why).
 

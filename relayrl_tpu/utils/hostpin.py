@@ -1,4 +1,4 @@
-"""Process-level CPU pinning for actor hosts, benches, and examples.
+"""Process-level CPU pinning for actor hosts, drills, and examples.
 
 Actors are CPU hosts: a process that only steps environments must never
 initialize the accelerator backend — the chip belongs to ONE process, the
@@ -6,7 +6,7 @@ learner, and a second process that touches it fails or hangs. The pin sets
 ``JAX_PLATFORMS`` for anything this process spawns and updates the live
 config for jax itself, which is valid until the backend initializes — so
 call it before any other jax use. This is the single shared
-implementation — examples, benches, and multi-process workers all call it
+implementation — examples, drills, and multi-process workers all call it
 instead of hand-rolling the block.
 """
 

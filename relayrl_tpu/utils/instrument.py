@@ -1,7 +1,7 @@
 """Lightweight agent instrumentation (wire bytes + env steps).
 
 One shared implementation for every harness that needs to know what an
-actor actually puts on the wire (benches/bench_pixel_wire.py, the e2e
+actor actually puts on the wire (tests/test_e2e_distributed.py's
 byte-plane guard test): wrapping ``transport.send_trajectory`` counts
 REAL serialized payload bytes identically on all three transports, and
 wrapping ``request_for_action`` counts one per env step — dividing one

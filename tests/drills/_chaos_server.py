@@ -1,7 +1,8 @@
 """Killable/restartable TrainingServer worker for crash drills.
 
-Shared by ``bench_soak --chaos`` and tests/test_recovery.py: the
-coordinator spawns this process, SIGKILLs it mid-run (the learner crash
+Shared by ``tests/drills/soak.py::run_chaos`` and the SIGKILL drills of
+tests/test_recovery.py, test_columnar_wire.py, test_anakin.py and
+test_rlhf.py: the coordinator spawns this process, SIGKILLs it mid-run (the learner crash
 drill), then respawns it with ``"resume": true`` — orbax restores the
 full train state and the ingest-ledger sidecar restores dedup state
 consistent with the restored params.
@@ -32,8 +33,9 @@ import sys
 import threading
 import time
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, os.path.dirname(_HERE))  # repo root, for relayrl_tpu
+# tests/drills/ -> repo root, for relayrl_tpu when PYTHONPATH is unset
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def _write_status(path: str, payload: dict) -> None:

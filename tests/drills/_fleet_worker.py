@@ -1,5 +1,5 @@
-"""Worker process for the fleet telemetry drill (bench_fleet.py and the
-tests/test_fleet.py live drill).
+"""Worker process for the fleet telemetry drill
+(``tests/test_fleet.py::TestLiveFleetDrill``).
 
 One :class:`relayrl_tpu.runtime.VectorAgent` hosting
 ``agents_per_proc`` logical lanes drives a synthetic env loop against
@@ -82,7 +82,7 @@ def main() -> None:
     snapshot = telemetry.get_registry().snapshot()
     # Ship the closing frame explicitly and give the PUSH pipe a beat:
     # disable_agent's own final emit races the linger-0 socket close
-    # (the chaos_finish flush-linger lesson, benches/_soak_worker.py),
+    # (the chaos_finish flush-linger lesson, tests/drills/_soak_worker.py),
     # and a dropped final frame would fail the exactness check for the
     # wrong reason.
     agent._fleet_emitter.emit_now()

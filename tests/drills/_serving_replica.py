@@ -1,15 +1,15 @@
-"""Serving-replica process for the horizontal-serving soak rows.
+"""Serving-replica process for the replica SIGKILL drill
+(``tests/test_drills.py::test_serving_replica_sigkill_drill``).
 
 Hosts ONE :class:`relayrl_tpu.runtime.inference.StandaloneInferenceHost`:
 handshakes the model off the root TrainingServer's agent plane exactly
 like an actor, binds its own zmq ROUTER serving endpoint, and follows
 model publishes live. Runs until the coordinator writes the stop file,
-then commits its accounting + telemetry snapshot to the result path —
-the replica-side half of the horizontal-serving SLO block (session
-table occupancy, eviction/resync counters, batch occupancy live HERE,
-not in the root server's snapshot).
+then writes its accounting + telemetry snapshot to the result path
+(session table occupancy, eviction/resync counters and batch occupancy
+live HERE, not in the root server's snapshot).
 
-Usage: _serving_replica.py <json-config>  (see bench_soak.py)
+Usage: _serving_replica.py <json-config>
 """
 
 from __future__ import annotations
@@ -19,17 +19,10 @@ import os
 import sys
 import time
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, _HERE)
-sys.path.insert(0, os.path.dirname(_HERE))
-from common import setup_platform  # noqa: E402
-
-setup_platform()
-
 
 def main():
     cfg = json.loads(sys.argv[1])
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
     from relayrl_tpu import telemetry
 
     telemetry.set_registry(telemetry.Registry(run_id=cfg["name"]))

@@ -20,8 +20,8 @@ from _util import free_port
 
 pytestmark = pytest.mark.anakin
 
-BENCHES = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benches")
+DRILLS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "drills")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _bundle(obs_dim=4, act_dim=2, seed=0, version=0):
@@ -239,7 +239,7 @@ def test_cross_process_determinism(tmp_path):
     acceptance; the numpy-parity half lives in tests/test_jax_envs.py."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.path.dirname(BENCHES)
+    env["PYTHONPATH"] = REPO
     digests = []
     for _ in range(2):
         out = subprocess.run([sys.executable, "-c", _DETERMINISM_SCRIPT],
@@ -626,9 +626,9 @@ def test_learner_sigkill_restart_with_anakin_actors_zero_loss(
         }
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = os.path.dirname(BENCHES)
+        env["PYTHONPATH"] = REPO
         return subprocess.Popen(
-            [sys.executable, os.path.join(BENCHES, "_chaos_server.py"),
+            [sys.executable, os.path.join(DRILLS, "_chaos_server.py"),
              json.dumps(cfg)],
             env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
             text=True)

@@ -173,9 +173,9 @@ class TestRingFlashAttention:
 
     @pytest.mark.parametrize("causal,n", [(True, 2), (True, 4), (False, 2)])
     def test_chunked_local_matches_dense(self, causal, n):
-        # The single-device ring cost model (benches emit rows for it on
-        # TPU) must agree with dense — it runs the exact chunk kernels
-        # and mode schedule the sharded ring uses.
+        # The single-device ring cost model must agree with dense — it
+        # runs the exact chunk kernels and mode schedule the sharded
+        # ring uses.
         from relayrl_tpu.parallel.ring_flash import chunked_flash_local
 
         q, k, v = _qkv(4, t=64)

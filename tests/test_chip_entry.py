@@ -1,7 +1,8 @@
 """The entry points that own a chip, checked from a host that has none.
 
-``chip_smoke.py`` and ``bench.py`` must refuse to run without a TPU (no
-fallback that lets a CPU run look like a chip run), and the compile cache
+``chip_smoke.py`` must refuse to run without a TPU (no fallback that lets
+a CPU run look like a chip run; ``benchmark/tests/test_refusals.py`` holds
+the benchmark to the same), and the compile cache
 must be placeable from outside and otherwise sit at one fixed path. All in
 subprocesses: the resolver mutates ``jax.config``, and the tests themselves
 keep the persistent cache off (conftest.py).

@@ -39,8 +39,8 @@ from tests._util import free_port
 
 pytestmark = pytest.mark.columnar
 
-BENCHES = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benches")
+DRILLS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "drills")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 OBS_DIM, ACT_DIM = 4, 2
 
@@ -561,9 +561,9 @@ def _spawn_chaos_server(scratch, transport, addrs, resume):
     }
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.path.dirname(BENCHES)
+    env["PYTHONPATH"] = REPO
     return subprocess.Popen(
-        [sys.executable, os.path.join(BENCHES, "_chaos_server.py"),
+        [sys.executable, os.path.join(DRILLS, "_chaos_server.py"),
          json.dumps(cfg)],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         text=True)

@@ -89,7 +89,7 @@ class TestGetters:
     def test_learner_params(self, tmp_cwd):
         params = ConfigLoader().get_learner_params()
         assert params["mesh"]["dp"] == -1
-        assert params["precision"] == "float32"  # CPU-safe default; TPU benches set bf16
+        assert params["precision"] == "float32"  # CPU-safe default; the benchmark configurations set bf16
 
 
 class TestEnvDirAnchoring:

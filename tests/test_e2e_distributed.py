@@ -351,8 +351,7 @@ def test_offpolicy_and_async_families_over_sockets(tmp_cwd, algo, hp):
 
 
 def test_uint8_pixel_frames_cross_the_wire_byte_sized(tmp_cwd):
-    """The byte-sized pixel plane end-to-end (guards what
-    benches/bench_pixel_wire.py measures at full scale): uint8 frames
+    """The byte-sized pixel plane end-to-end: uint8 frames
     from the Atari pipeline stay uint8 through actor -> codec -> socket
     -> decode -> CNN learner, with per-step payload ~= obs_dim bytes
     (a float32 regression would quadruple it — exactly the silent
@@ -377,7 +376,7 @@ def test_uint8_pixel_frames_cross_the_wire_byte_sized(tmp_cwd):
                       seed=0, **agent_addrs)
         from relayrl_tpu.utils.instrument import instrument_agent
 
-        wire = instrument_agent(agent)  # shared with bench_pixel_wire
+        wire = instrument_agent(agent)
         try:
             env = make_atari("synthetic", frame_size=frame,
                              frame_stack=stack, frame_skip=2,

@@ -756,9 +756,8 @@ class TestEndpointsAndPane:
 # ---------------------------------------------------------------------------
 
 class TestLiveFleetDrill:
-    # ISSUE 17 wall re-fit: live-zmq e2e rides the slow tier with the
-    # committed fleet_zmq.json bench drill; merge/relay semantics stay
-    # covered fast by the unit suite above.
+    # ISSUE 17 wall re-fit: live-zmq e2e rides the slow tier;
+    # merge/relay semantics stay covered fast by the unit suite above.
     @pytest.mark.slow
     def test_live_zmq_root_totals_bit_exact(self, tmp_path, tmp_cwd):
         from relayrl_tpu import telemetry
@@ -808,7 +807,7 @@ class TestLiveFleetDrill:
                 env["PYTHONPATH"] = REPO_ROOT
                 workers.append(subprocess.Popen(
                     [sys.executable,
-                     os.path.join(REPO_ROOT, "benches",
+                     os.path.join(REPO_ROOT, "tests", "drills",
                                   "_fleet_worker.py"),
                      json.dumps(wcfg)],
                     env=env, stdout=subprocess.PIPE,

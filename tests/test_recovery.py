@@ -5,7 +5,7 @@ Unit layer: TrajectorySpool retention/disk/breaker semantics and the
 SequenceLedger dedup window + sidecar persistence.
 
 Drill layer (all three transports): a real TrainingServer subprocess
-(benches/_chaos_server.py) is SIGKILLed mid-training while a live Agent
+(tests/drills/_chaos_server.py) is SIGKILLed mid-training while a live Agent
 keeps stepping; the respawned server resumes from orbax + the ingest-
 ledger sidecar, the agent heals (breaker probe / zmq socket monitor /
 native heartbeat), replays its spool, and the final sequence accounting
@@ -29,8 +29,8 @@ from relayrl_tpu import faults, telemetry
 from relayrl_tpu.runtime.spool import SequenceLedger, TrajectorySpool
 from tests._util import free_port
 
-BENCHES = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benches")
+DRILLS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "drills")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -247,9 +247,9 @@ def _spawn_server(scratch: str, transport: str, addrs: dict,
     }
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.path.dirname(BENCHES)
+    env["PYTHONPATH"] = REPO
     return subprocess.Popen(
-        [sys.executable, os.path.join(BENCHES, "_chaos_server.py"),
+        [sys.executable, os.path.join(DRILLS, "_chaos_server.py"),
          json.dumps(cfg)],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         text=True)
@@ -456,7 +456,7 @@ def test_actor_sigkill_reap_and_replacement_recovers(tmp_cwd):
         server_type="native", bind_addr=f"127.0.0.1:{port}")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.path.dirname(BENCHES)
+    env["PYTHONPATH"] = REPO
 
     def spawn_actor():
         return subprocess.Popen(

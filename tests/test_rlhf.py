@@ -27,12 +27,16 @@ import numpy as np
 import pytest
 
 from relayrl_tpu import telemetry
-from tests._util import free_port
+from tests._util import (
+    assert_episode_payloads_match,
+    free_port,
+    zmq_addr_pair,
+)
 
 pytestmark = pytest.mark.rlhf
 
-BENCHES = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benches")
+DRILLS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "drills")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -513,9 +517,12 @@ class TestGenerationBitIdentity:
     def test_scheduler_generation_equals_local_step_window_actor(self):
         """A batch-of-1 GenerationStage (the scheduler's generate stage
         over a VectorActorHost, rng_keys pinned to the actor's key)
-        produces byte-identical episode payloads to a local PolicyActor
-        driving the same env stream through step_window — same tokens,
-        same logp/v aux bits, same wire bytes."""
+        produces the episode payloads of a local PolicyActor driving the
+        same env stream through step_window: decoded, the same tokens,
+        observations, rewards, flags and ``bver`` exactly, and the same
+        logp/v to float32 rounding — the host's vmapped batch-of-1 and the
+        actor's plain call are two XLA program shapes
+        (``_util.MODEL_OUTPUT_RTOL``)."""
         import jax
 
         from relayrl_tpu.envs import SyncVectorEnv, TokenGenEnv
@@ -570,25 +577,13 @@ class TestGenerationBitIdentity:
                 episode += 1
                 # SyncVectorEnv autoreset seeding: base + lane + N*episode
                 obs, _ = env.reset(seed=123 + episode)
-        assert actor_payloads[:len(stage_payloads)] == stage_payloads, \
-            "scheduler generation diverged from the local actor"
+        for n, (got, want) in enumerate(zip(stage_payloads, actor_payloads)):
+            assert_episode_payloads_match(got, want, f"episode {n}")
 
 
 # ---------------------------------------------------------------------------
 # live plane (in-process server)
 # ---------------------------------------------------------------------------
-
-def _zmq_addr_pair():
-    addrs = {
-        "agent_listener_addr": f"tcp://127.0.0.1:{free_port()}",
-        "trajectory_addr": f"tcp://127.0.0.1:{free_port()}",
-        "model_pub_addr": f"tcp://127.0.0.1:{free_port()}",
-    }
-    agent = {"agent_listener_addr": addrs["agent_listener_addr"],
-             "trajectory_addr": addrs["trajectory_addr"],
-             "model_sub_addr": addrs["model_pub_addr"]}
-    return addrs, agent
-
 
 def _write_rlhf_config(path, vocab=6, prompt_len=2, max_new=6, lanes=4,
                        freeze=None, extra=None):
@@ -643,7 +638,7 @@ class TestLivePlane:
         from relayrl_tpu.runtime.server import TrainingServer
 
         config_path = _write_rlhf_config(tmp_cwd / "relayrl_config.json")
-        addrs, agent_addrs = _zmq_addr_pair()
+        addrs, agent_addrs = zmq_addr_pair()
         telemetry.set_registry(telemetry.Registry(run_id="rlhf-live"))
         server = TrainingServer(
             "IMPALA", obs_dim=8, act_dim=6, env_dir=str(tmp_cwd),
@@ -697,7 +692,7 @@ class TestLivePlane:
         config_path = _write_rlhf_config(
             tmp_cwd / "relayrl_config.json",
             extra={"rlhf": {"generation_tier": "anakin"}})
-        addrs, agent_addrs = _zmq_addr_pair()
+        addrs, agent_addrs = zmq_addr_pair()
         telemetry.set_registry(telemetry.Registry(run_id="rlhf-fused"))
         server = TrainingServer(
             "IMPALA", obs_dim=8, act_dim=6, env_dir=str(tmp_cwd),
@@ -758,7 +753,7 @@ class TestLivePlane:
                    "server": {"inference_server":
                               {"host": "127.0.0.1",
                                "port": str(free_port())}}})
-        addrs, agent_addrs = _zmq_addr_pair()
+        addrs, agent_addrs = zmq_addr_pair()
         server = TrainingServer(
             "IMPALA", obs_dim=8, act_dim=6, env_dir=str(tmp_cwd),
             hyperparams={"traj_per_epoch": 4, "hidden_sizes": [16],
@@ -828,9 +823,9 @@ def _spawn_rlhf_server(scratch: str, addrs: dict,
     }
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.path.dirname(BENCHES)
+    env["PYTHONPATH"] = REPO
     return subprocess.Popen(
-        [sys.executable, os.path.join(BENCHES, "_chaos_server.py"),
+        [sys.executable, os.path.join(DRILLS, "_chaos_server.py"),
          json.dumps(cfg)],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         text=True)
@@ -860,7 +855,7 @@ def test_chaos_learner_sigkill_rlhf_plane(tmp_path, tmp_cwd):
     from relayrl_tpu.rlhf.scheduler import RlhfScheduler
 
     scratch = str(tmp_path)
-    addrs, agent_addrs = _zmq_addr_pair()
+    addrs, agent_addrs = zmq_addr_pair()
     server_addrs = {k: addrs[k] for k in
                     ("agent_listener_addr", "trajectory_addr",
                      "model_pub_addr")}

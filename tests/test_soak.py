@@ -1,24 +1,17 @@
-"""Scaled-down multi-actor soak (the committed 64-actor numbers live in
-benches/results/soak64.json; this keeps the harness itself green)."""
-
-import os
-import sys
+"""Scaled-down multi-actor soak and ingest blast (tests/drills/soak.py):
+nothing dropped, nothing left in the ingest queue."""
 
 import pytest
 
 pytestmark = pytest.mark.slow
 
-_BENCHES = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benches")
-
 
 @pytest.fixture
 def soak(monkeypatch, tmp_path):
-    monkeypatch.syspath_prepend(_BENCHES)
     monkeypatch.chdir(tmp_path)
-    import bench_soak
+    from drills import soak
 
-    return bench_soak
+    return soak
 
 
 def test_multi_actor_soak_no_drops(soak):

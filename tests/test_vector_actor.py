@@ -468,24 +468,17 @@ class TestSyncVectorEnv:
 
 class TestVectorSoakSmoke:
     # ISSUE 17 wall re-fit: soak smokes live in the slow tier alongside
-    # the bench-scale soak (tests/test_soak.py keeps the fast quick shape).
+    # tests/test_soak.py and tests/test_drills.py.
     @pytest.mark.slow
     def test_quick_vector_soak_one_traj_per_logical_agent(
             self, monkeypatch, tmp_path):
-        """Tiny bench_soak --quick --vector shape: 4 logical agents in
+        """Tiny vector soak (tests/drills/soak.py): 4 logical agents in
         one process must each land >= 1 attributed trajectory (the CI
         gate for the vector actor plane)."""
-        import os
-        import sys
-
-        benches = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "benches")
-        monkeypatch.syspath_prepend(benches)
         monkeypatch.chdir(tmp_path)
-        import bench_soak
+        from drills import soak
 
-        result = bench_soak.run_soak(
+        result = soak.run_soak(
             n_actors=4, agents_per_proc=4, duration_s=3.0,
             traj_per_epoch=8, vector=True)
         assert result["agents_completed"] == 4
