@@ -5,7 +5,8 @@
 One process holds the chip and drives the main path once through the entry
 points a user calls — CPU actor processes -> zmq -> ingest -> jitted update
 on the TPU -> publish -> hot-swap — plus the other device programs the repo
-has (the widest transformer through ``build_algorithm``, the fused anakin
+has (the widest transformer through ``build_algorithm``, the flash kernels
+alone at the benchmark's shapes against XLA attention, the fused anakin
 rollout, a served batch, the expert layer's grouped-matmul kernels against
 XLA's own). It checks what comes out, fails on the first thing
 that is wrong (non-zero exit, one ``chip_smoke: FAIL`` line saying why; a
@@ -492,6 +493,65 @@ def phase_b_default_buckets(config_path: str) -> None:
         f"{resolved}, {time.monotonic() - t0:.0f}s")
 
 
+def differ(a, b) -> float:
+    """Largest difference between two device arrays, as a share of the
+    largest entry of the second."""
+    import jax.numpy as jnp
+
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.abs(a - b).max() / jnp.abs(b).max())
+
+
+def phase_b_kernels() -> None:
+    """The three flash kernels alone at the two shapes the benchmark's
+    transformer cells run — (B*H, T, D) = (128, 1024, 64), one block a head
+    walked in causal strips, and (64, 4096, 128), a 4 x 4 grid a head with
+    strips on its diagonal — bfloat16, forward and all three gradients
+    under a random cotangent, against the XLA paths on the same inputs in
+    float32 (``dense_attention`` at T 1024, ``blockwise_attention`` at
+    T 4096). The kernels round p and ds to bfloat16 for their second
+    matmuls and the results once: a few units in the last place of the
+    largest entry, phase E's limit."""
+    import jax
+    import jax.numpy as jnp
+
+    from relayrl_tpu.ops import flash
+    from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
+
+    t0 = time.monotonic()
+    said = []
+    for (B, T, H, D), reference in (
+            ((8, 1024, 16, 64), dense_attention),
+            ((4, 4096, 16, 128),
+             lambda q, k, v: blockwise_attention(q, k, v, 512, causal=True))):
+        q, k, v, do = (jax.random.normal(key, (B, T, H, D), jnp.bfloat16)
+                       for key in jax.random.split(jax.random.PRNGKey(T), 4))
+
+        @jax.jit
+        def kernel_side(q, k, v, do):
+            out, vjp = jax.vjp(flash.flash_attention, q, k, v)
+            return (out, *vjp(do))
+
+        @jax.jit
+        def xla_side(q, k, v, do):
+            out, vjp = jax.vjp(reference, *(
+                x.astype(jnp.float32) for x in (q, k, v)))
+            return (out, *vjp(do.astype(jnp.float32)))
+
+        area = flash.score_area_pct(T, *flash.tiling(T), True)
+        errs = dict(zip(("out", "dq", "dk", "dv"), map(
+            differ, kernel_side(q, k, v, do), xla_side(q, k, v, do))))
+        for what, err in errs.items():
+            check(err <= 2.0 ** -6,
+                  f"B\": flash {what} at {(B * H, T, D)} differs from "
+                  f"XLA's by {err:.3g} of its largest entry (limit 2^-6)")
+        said.append(f"{(B * H, T, D)} tiling {flash.tiling(T)} score area "
+                    f"{area:g}% "
+                    f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}")
+    say(f"B\": ok — flash fwd / dq / dkv kernels against XLA attention in "
+        f"float32: {'; '.join(said)}, {time.monotonic() - t0:.0f}s")
+
+
 def phase_c(bundle) -> None:
     """One fused rollout: CartPole-JAX, 64 lanes x unroll 32, MLP, in this
     process, with a parameter swap over the model wire between windows."""
@@ -582,10 +642,6 @@ def phase_e() -> None:
                                       preferred_element_type=a.dtype)
 
         return ragged(lhs, rhs), ragged(g, rhs.swapaxes(1, 2))
-
-    def differ(a, b) -> float:
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        return float(jnp.abs(a - b).max() / jnp.abs(b).max())
 
     worst = {"value": 0.0, "d_lhs": 0.0, "d_rhs": 0.0}
     for name, (load, groups) in loads.items():
@@ -678,6 +734,7 @@ def run(dev: dict, t_start: float) -> None:
     bundle = phase_a_and_d(config_path, dev["count"])
     phase_b(config_path)
     phase_b_default_buckets(config_path)
+    phase_b_kernels()
     phase_c(bundle)
     phase_e()
 

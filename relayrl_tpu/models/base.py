@@ -122,6 +122,11 @@ class Policy:
     # fills it at trace time) — which implementation a platform-dependent
     # ``attention`` config actually compiled to. None for other families.
     attention_backends: Mapping[tuple, str] | None = None
+    # Beside it, for the shapes that resolved to ``flash_pallas``: the share
+    # (%) of the T x T score matrix the flash kernels compute at that shape's
+    # tiling (``ops.flash.score_area_pct``; a causal kernel that skipped
+    # everything above the diagonal would read 50 + 50 / T).
+    attention_score_area_pct: Mapping[tuple, float] | None = None
     # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
     # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward
     # (``moe_load_max`` / ``moe_load_min``: models/moe.load_extremes) for

@@ -56,9 +56,10 @@ from relayrl_tpu.models.mlp import (
 from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
 
 
-def _resolve_attention(arch: Mapping[str, Any]) -> tuple[Callable, dict]:
-    """Arch config -> ``(attn_fn, resolved)``: the [B,T,H,D]x3 -> [B,T,H,D]
-    attention callable, and the record of what it ran as.
+def _resolve_attention(arch: Mapping[str, Any]
+                       ) -> tuple[Callable, dict, dict]:
+    """Arch config -> ``(attn_fn, resolved, score_area)``: the [B,T,H,D]x3
+    -> [B,T,H,D] attention callable, and the records of what it ran as.
 
     ``"flash"`` and ``"ring"`` pick their implementation at trace time
     from the platform, the sequence length and the ambient mesh, so the
@@ -68,18 +69,27 @@ def _resolve_attention(arch: Mapping[str, Any]) -> tuple[Callable, dict]:
     (``dense`` / ``blockwise`` / ``flash_pallas`` / ``ring_flash_pallas``
     / ``ring_scan``), surfaced as ``Policy.attention_backends``, and each
     new entry prints one line naming the platform it was resolved on.
+    ``score_area`` (``Policy.attention_score_area_pct``) maps the shapes
+    that run the Pallas flash kernels to the share of the T x T score
+    matrix those compute (``ops.flash.score_area_pct``: how far the causal
+    skip engages at that shape's tiling); the line says it too.
     """
     kind = arch.get("attention", "dense")
     block = int(arch.get("attention_block", 128))
     resolved: dict[tuple[int, int, str], str] = {}
+    score_area: dict[tuple[int, int, str], float] = {}
 
-    def ran(q, backend: str) -> None:
+    def ran(q, backend: str, area_pct: float | None = None) -> None:
         key = (int(q.shape[1]), int(q.shape[3]), q.dtype.name)
         if resolved.get(key) != backend:
             resolved[key] = backend
+            area = ""
+            if area_pct is not None:
+                score_area[key] = area_pct
+                area = f", score area {area_pct:g}%"
             if kind in ("flash", "ring"):
                 print(f"[attention] {kind!r} T={key[0]} head_dim={key[1]} "
-                      f"{key[2]} -> {backend} "
+                      f"{key[2]} -> {backend}{area} "
                       f"(platform {jax.default_backend()})", flush=True)
 
     def dense(q, k, v):
@@ -95,9 +105,9 @@ def _resolve_attention(arch: Mapping[str, Any]) -> tuple[Callable, dict]:
         return (blockwise if q.shape[1] % block == 0 else dense)(q, k, v)
 
     if kind == "dense":
-        return dense, resolved
+        return dense, resolved, score_area
     if kind == "blockwise":
-        return blockwise, resolved
+        return blockwise, resolved, score_area
     if kind == "flash":
         def flash_or_local(q, k, v):
             # Pallas kernel on TPU; off-TPU (CPU actor hosts, CI) the same
@@ -108,16 +118,17 @@ def _resolve_attention(arch: Mapping[str, Any]) -> tuple[Callable, dict]:
             # fallback's "attention_block" is a memory/fusion knob that
             # wants small ones — one shared key would silently deoptimize
             # whichever path tuned second.
-            from relayrl_tpu.ops.flash import flash_attention
+            from relayrl_tpu.ops import flash
 
             T = q.shape[1]
             fblock = int(arch.get("flash_block", 1024))
             if jax.default_backend() == "tpu" and T % min(fblock, T) == 0:
-                ran(q, "flash_pallas")
-                return flash_attention(q, k, v, causal=True,
-                                       block_q=fblock, block_kv=fblock)
+                ran(q, "flash_pallas", flash.score_area_pct(
+                    T, *flash.tiling(T, True, fblock, fblock), True))
+                return flash.flash_attention(q, k, v, causal=True,
+                                             block_q=fblock, block_kv=fblock)
             return local(q, k, v)
-        return flash_or_local, resolved
+        return flash_or_local, resolved, score_area
     if kind == "ring":
         def ring_or_local(q, k, v):
             from relayrl_tpu.parallel.context import current_mesh
@@ -140,7 +151,7 @@ def _resolve_attention(arch: Mapping[str, Any]) -> tuple[Callable, dict]:
                 return make_ring_flash_attention(mesh)(q, k, v)
             ran(q, "ring_scan")
             return make_ring_attention(mesh)(q, k, v)
-        return ring_or_local, resolved
+        return ring_or_local, resolved, score_area
     raise ValueError(f"unknown attention kind {kind!r}")
 
 
@@ -545,7 +556,7 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
     diagnostics like :func:`relayrl_tpu.models.moe.expert_utilization`,
     which re-applies the same module with captured intermediates)."""
     if attn_fn is None:
-        attn_fn, _ = _resolve_attention(arch)
+        attn_fn = _resolve_attention(arch)[0]
     return TransformerCore(
         act_dim=int(arch["act_dim"]),
         d_model=int(arch.get("d_model", 128)),
@@ -564,7 +575,7 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
 
 def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     obs_dim = int(arch["obs_dim"])
-    attn_fn, attention_backends = _resolve_attention(arch)
+    attn_fn, attention_backends, score_area = _resolve_attention(arch)
     core = _make_core(arch, moe_experts, attn_fn)
 
     def init_params(rng):
@@ -647,6 +658,7 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                        step_cached=step_cached,
                        prefill_cache=prefill_cache,
                        attention_backends=attention_backends,
+                       attention_score_area_pct=score_area,
                        evaluate_stats=evaluate_stats)
 
 
@@ -716,7 +728,7 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
     d_model = int(arch.get("d_model", 128))
     n_layers = int(arch.get("n_layers", 2))
     n_micro = arch.get("pp_microbatches")
-    attn_fn, attention_backends = _resolve_attention(arch)
+    attn_fn, attention_backends, score_area = _resolve_attention(arch)
     block = TransformerBlock(
         d_model, int(arch.get("n_heads", 4)), int(arch.get("mlp_ratio", 4)),
         attn_fn, _compute_dtype(arch))
@@ -760,4 +772,5 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
     import dataclasses as _dc
 
     return _dc.replace(_policy_from_apply(arch, init_params, apply_fn),
-                       attention_backends=attention_backends)
+                       attention_backends=attention_backends,
+                       attention_score_area_pct=score_area)

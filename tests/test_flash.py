@@ -121,3 +121,169 @@ def test_flash_grads_uneven_and_noncausal(causal, bq, bk):
     for g, w, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5,
                                    err_msg=f"d{name}")
+
+
+# -- causal strips inside a grid step (the shape class the benchmark's
+# transformer cells run: T == block, and T a few blocks) -------------------
+
+def _sub_tile(monkeypatch, rows):
+    """Scale the strip height down through the kernels' own derivation
+    (the production height, 256, takes a 512-step block to engage)."""
+    monkeypatch.setattr(flash, "_SUB_TILE", rows)
+
+
+def _clear_kernel_caches():
+    for cache in (flash._make_flash, flash._build_fwd, flash._build_bwd):
+        cache.cache_clear()
+
+
+def _grad_loss(fn):
+    return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("T,block,sub", [
+    (16, 16, 8),     # T == block == 2 strips: 3 of 4 sub-tiles
+    (32, 32, 8),     # T == block == 4 strips: 10 of 16
+    (64, 32, 8),     # T == 2 block: diagonal and interior grid blocks
+])
+def test_sub_tiled_matches_dense(monkeypatch, T, block, sub, dtype):
+    _sub_tile(monkeypatch, sub)
+    assert flash.tiling(T, True, block, block) == (block, block, sub)
+    q, k, v = (x.astype(dtype) for x in _qkv(B=1, T=T, H=2, D=16))
+    atol = 5e-5 if dtype == "float32" else 6e-2
+
+    def flash_fn(q, k, v):
+        return flash_attention(q, k, v, block_q=block, block_kv=block)
+
+    out = flash_fn(q, k, v)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(
+        out.astype(jnp.float32),
+        dense_attention(q, k, v, causal=True).astype(jnp.float32),
+        atol=atol, rtol=atol)
+    got = jax.grad(_grad_loss(flash_fn), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_grad_loss(dense_attention), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(
+            g.astype(jnp.float32), w.astype(jnp.float32), atol=atol,
+            rtol=atol, err_msg=f"d{name}")
+
+
+def test_sub_tiled_at_the_production_height():
+    # One head of gpt2m-policy.update's shape, in the strips the chip runs.
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(B=1, T=1024, H=1, D=64))
+    assert flash.tiling(1024) == (1024, 1024, flash._SUB_TILE)
+    out = flash_attention(q, k, v)
+    ref = dense_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               ref.astype(jnp.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("T,bq,bk,causal", [
+    (1, 16, 16, True),       # build's T = 1 kernel
+    (8, 16, 16, True),       # a block of one strip
+    (24, 24, 24, True),      # the side does not divide the block (T = 1000)
+    (32, 32, 16, True),      # unequal blocks
+    (32, 16, 32, True),
+    (32, 32, 32, False),     # non-causal
+])
+def test_fall_back_shapes_stay_one_tile(monkeypatch, T, bq, bk, causal):
+    """Where the strips do not apply the kernels run the single tile they
+    always ran: the same results to the bit as with no strip height at
+    all."""
+    q, k, v = _qkv(B=1, T=T, H=2, D=16)
+
+    def run():
+        _clear_kernel_caches()
+        fn = lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=bq, block_kv=bk)
+        return (fn(q, k, v),
+                *jax.grad(_grad_loss(fn), argnums=(0, 1, 2))(q, k, v))
+
+    _sub_tile(monkeypatch, 16)
+    assert flash.tiling(T, causal, bq, bk)[2] is None
+    with_strips = run()
+    _sub_tile(monkeypatch, 1 << 30)
+    for a, b in zip(with_strips, run()):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(
+        with_strips[0], dense_attention(q, k, v, causal=causal),
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("T,block,sub,pct", [
+    (1024, 1024, None, 100.0),   # gpt2m-policy.update before sub-tiling
+    (1024, 1024, 512, 75.0),
+    (1024, 1024, 256, 62.5),
+    (1024, 1024, 128, 56.25),
+    (4096, 1024, None, 62.5),    # olmoe-policy.update: 6 + 4 of 16 blocks
+    (4096, 1024, 256, 53.125),
+    (64, 16, None, 62.5),
+    (1, 1, None, 100.0),
+])
+def test_score_area_pct(T, block, sub, pct):
+    assert flash.score_area_pct(T, block, block, sub, True) == pct
+    assert flash.score_area_pct(T, block, block, None, False) == 100.0
+
+
+@pytest.mark.parametrize("T,block,sub", [(32, 32, 8), (32, 32, 16),
+                                         (64, 32, 8), (32, 32, None)])
+def test_score_area_is_what_the_kernels_visit(monkeypatch, T, block, sub):
+    """The area function against the kernel bodies: sum the score tiles
+    each of the three kernels emits at trace time."""
+    _sub_tile(monkeypatch, sub or 1 << 30)
+    _clear_kernel_caches()
+    visited = []
+    scores2 = flash._scores2
+
+    def counting(*args, **kw):
+        s = scores2(*args, **kw)
+        visited.append(s.shape)
+        return s
+
+    monkeypatch.setattr(flash, "_scores2", counting)
+    q, k, v = _qkv(B=1, T=T, H=1, D=16)
+    jax.grad(_grad_loss(lambda q, k, v: flash_attention(
+        q, k, v, block_q=block, block_kv=block)), argnums=(0, 1, 2))(q, k, v)
+    _clear_kernel_caches()  # they were built round the counting wrapper
+    n_blocks = T // block
+    if n_blocks > 1:
+        # One whole interior tile a kernel: emitted once, run by the
+        # n (n - 1) / 2 grid blocks below the diagonal.
+        for _ in range(3):
+            visited.remove((block, block))
+    strips = sum(a * b for a, b in visited)
+    assert strips % 3 == 0, visited  # fwd, dq, dk/dv: the same area
+    area = n_blocks * strips // 3 + (
+        n_blocks * (n_blocks - 1) // 2) * block * block
+    assert flash.score_area_pct(T, block, block, sub, True) == (
+        100.0 * area / (T * T))
+    if sub is not None:  # strips, and nothing taller or wider than needed
+        n = block // sub
+        assert sorted(visited) == sorted(
+            [(sub, (r + 1) * sub) for r in range(n)] * 2       # fwd, dq
+            + [(sub, (n - c) * sub) for c in range(n)])        # dk/dv^T
+
+
+def test_policy_records_the_score_area(monkeypatch, capsys):
+    # On a TPU "flash" resolves to the kernels; the policy then says how
+    # much of the score matrix they compute at each traced shape.
+    from relayrl_tpu.models import build_policy
+
+    _sub_tile(monkeypatch, 8)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "flash_attention", flash_attention)
+    arch = {"kind": "transformer_discrete", "obs_dim": 8, "act_dim": 3,
+            "d_model": 32, "n_layers": 1, "n_heads": 2, "max_seq_len": 32,
+            "attention": "flash"}
+    policy = build_policy(arch)
+    params = policy.init_params(jax.random.PRNGKey(0))
+    policy.evaluate(params, jnp.zeros((1, 32, 8), jnp.float32),
+                    jnp.zeros((1, 32), jnp.int32))
+    key = (32, 16, "float32")
+    assert policy.attention_backends[key] == "flash_pallas"
+    assert policy.attention_score_area_pct[key] == 62.5
+    assert policy.attention_score_area_pct[(1, 16, "float32")] == 100.0
+    assert ("T=32 head_dim=16 float32 -> flash_pallas, score area 62.5%"
+            in capsys.readouterr().out)

@@ -1,0 +1,48 @@
+"""The flash kernels at the benchmark's widths, compiled for a DESCRIBED
+v5e chip: no chip attached, nothing runs (on-chip-measurement guide,
+rehearsal 3). What the chip's compiler refuses — a slice off the tiling,
+more VMEM than a kernel may hold — fails here and costs no chip time. A
+compile that passes is not a chip run: ``chip_smoke.py`` phase B" runs them.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU's library, and every xdist worker imports every
+test file. Keep these tests in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from relayrl_tpu.ops import flash
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("shape,sub", [
+    ((8, 1024, 16, 64), 256),     # gpt2m-policy.update: one block a head
+    ((4, 4096, 16, 128), 256),    # olmoe-policy.update: 4 x 4 blocks a head
+    ((1, 1000, 2, 64), None),     # a default bucket: one tile a step
+])
+def test_flash_kernels_compile_for_v5e(one_chip, shape, sub):
+    assert flash.tiling(shape[1])[2] == sub
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def value_and_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(
+                flash.flash_attention(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(value_and_grads).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
