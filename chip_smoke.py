@@ -503,13 +503,16 @@ def differ(a, b) -> float:
 
 
 def phase_b_kernels() -> None:
-    """The three flash kernels alone at the two shapes the benchmark's
+    """The three flash kernels alone at the three shapes the benchmark's
     transformer cells run — (B*H, T, D) = (128, 1024, 64), one block a head
-    walked in causal strips, and (64, 4096, 128), a 4 x 4 grid a head with
-    strips on its diagonal — bfloat16, forward and all three gradients
+    walked in causal strips, (64, 4096, 128), a 4 x 4 grid a head with
+    strips on its diagonal, and (64, 8192, 64) grouped, 32 q heads over 8
+    k/v heads that reach the kernels unrepeated (lfm2-policy.update) —
+    bfloat16, forward and all three gradients
     under a random cotangent, against the XLA paths on the same inputs in
     float32 (``dense_attention`` at T 1024, ``blockwise_attention`` at
-    T 4096). The kernels round p and ds to bfloat16 for their second
+    T 4096; the grouped shape against ``dense_attention`` one k/v head and
+    its four q heads at a time). The kernels round p and ds to bfloat16 for their second
     matmuls and the results once: a few units in the last place of the
     largest entry, phase E's limit."""
     import jax
@@ -520,12 +523,17 @@ def phase_b_kernels() -> None:
 
     t0 = time.monotonic()
     said = []
-    for (B, T, H, D), reference in (
-            ((8, 1024, 16, 64), dense_attention),
-            ((4, 4096, 16, 128),
-             lambda q, k, v: blockwise_attention(q, k, v, 512, causal=True))):
-        q, k, v, do = (jax.random.normal(key, (B, T, H, D), jnp.bfloat16)
-                       for key in jax.random.split(jax.random.PRNGKey(T), 4))
+    def blockwise(q, k, v):
+        return blockwise_attention(q, k, v, 512, causal=True)
+
+    for (B, T, H, D), h_kv, reference in (
+            ((8, 1024, 16, 64), 16, dense_attention),
+            ((4, 4096, 16, 128), 16, blockwise),
+            ((2, 8192, 32, 64), 8, dense_attention)):
+        q, k, v, do = (
+            jax.random.normal(key, (B, T, heads, D), jnp.bfloat16)
+            for key, heads in zip(jax.random.split(jax.random.PRNGKey(T), 4),
+                                  (H, h_kv, h_kv, H)))
 
         @jax.jit
         def kernel_side(q, k, v, do):
@@ -538,14 +546,25 @@ def phase_b_kernels() -> None:
                 x.astype(jnp.float32) for x in (q, k, v)))
             return (out, *vjp(do.astype(jnp.float32)))
 
+        def xla_by_group(q, k, v, do):
+            """One k/v head and its q heads at a time (T x T float32
+            scores of every head at once do not fit at T 8192)."""
+            G = H // h_kv
+            parts = [xla_side(q[:, :, h * G:(h + 1) * G], k[:, :, h:h + 1],
+                              v[:, :, h:h + 1], do[:, :, h * G:(h + 1) * G])
+                     for h in range(h_kv)]
+            return [jnp.concatenate(x, axis=2) for x in zip(*parts)]
+
         area = flash.score_area_pct(T, *flash.tiling(T), True)
         errs = dict(zip(("out", "dq", "dk", "dv"), map(
-            differ, kernel_side(q, k, v, do), xla_side(q, k, v, do))))
+            differ, kernel_side(q, k, v, do),
+            (xla_side if h_kv == H else xla_by_group)(q, k, v, do))))
         for what, err in errs.items():
             check(err <= 2.0 ** -6,
                   f"B\": flash {what} at {(B * H, T, D)} differs from "
                   f"XLA's by {err:.3g} of its largest entry (limit 2^-6)")
-        said.append(f"{(B * H, T, D)} tiling {flash.tiling(T)} score area "
+        said.append(f"{(B * H, T, D)} k/v heads {h_kv} of {H} tiling "
+                    f"{flash.tiling(T)} score area "
                     f"{area:g}% "
                     f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}")
     say(f"B\": ok — flash fwd / dq / dkv kernels against XLA attention in "

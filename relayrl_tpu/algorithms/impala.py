@@ -65,7 +65,8 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
                        params_template=None):
     tx = make_impala_tx(lr, max_grad_norm, freeze, params_template)
     # a MoE trunk reports the expert load of the same forward
-    # (``moe_load_max`` / ``moe_load_min``); every other family reports {}
+    # (``moe_load_max`` / ``moe_load_min`` / ``moe_held_slots``); every
+    # other family reports {}
     evaluate = policy.evaluate_stats or (
         lambda *args: (*policy.evaluate(*args), {}))
 
@@ -101,7 +102,7 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
             "LossTotal": total,
             "RhoMean": rho_mean,
             "KL": kl,
-            **stats,  # moe_load_max / moe_load_min, MoE trunks only
+            **stats,  # moe_load_max / _min / moe_held_slots, MoE trunks only
         }
         return ImpalaState(params=params, opt_state=opt_state, rng=state.rng,
                            step=state.step + 1), metrics
@@ -176,8 +177,13 @@ class IMPALA(OnPolicyAlgorithm):
                     "relayrl_moe_load_min",
                     "emptiest expert's share of the token-slots, newest "
                     "update, min over MoE layers"),
+                "moe_held_slots": reg.gauge(
+                    "relayrl_moe_held_slots",
+                    "token-slots routed to experts this device holds, "
+                    "newest update, summed over MoE layers (all N*k a "
+                    "layer unless the arch sets moe_held)"),
             }
-            self._fence_notes = ("moe_load_max",)
+            self._fence_notes = ("moe_load_max", "moe_held_slots")
 
     def _log_keys(self):
         keys = ("LossPi", "LossV", "Entropy", "RhoMean", "KL")

@@ -52,6 +52,11 @@ ARCH_PASSTHROUGH_KEYS = (
     # the block the arch describes (models/transformer._BLOCK_ARCH_KEYS)
     "norm", "norm_eps", "positions", "rope_theta", "qk_norm", "use_bias",
     "ffn", "d_ff", "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch",
+    "n_kv_heads", "conv_taps",
+    # layers of several kinds (transformer._LAYER_ARCH_KEYS) and the expert
+    # layer's router and held range (transformer._MOE_ARCH_KEYS)
+    "layer_types", "moe_dense_layers",
+    "moe_router", "moe_expert_bias", "moe_held",
 )
 
 
@@ -114,8 +119,10 @@ class Policy:
     # replays the window to rebuild the cache after a model hot-swap).
     init_cache: Callable | None = None
     step_cached: Callable | None = None
-    # ``prefill_cache(params, cache, window) -> cache`` rebuilds the whole
-    # cache from the padded window in one dispatch (used after hot-swaps).
+    # ``prefill_cache(params, cache, window, n_valid=None) -> cache``
+    # rebuilds the whole cache from the padded window in one dispatch (used
+    # after hot-swaps); ``n_valid`` = the window's count of real rows, which
+    # a state without positions (a short convolution's) is taken before.
     prefill_cache: Callable | None = None
     # Sequence policies: ``{(T, head_dim, dtype): backend}`` for every
     # attention shape traced so far (models/transformer._resolve_attention

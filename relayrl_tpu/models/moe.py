@@ -12,13 +12,31 @@ between training batches and single-window actor serving — a hard
 requirement for RL policies, where logp at step t must condition only on
 history (capacity-competition schemes like expert-choice or token-dropping
 leak future timesteps / sibling sequences into the gate and bias the
-policy gradient). The router runs in float32 over all E experts; two
-weightings (arch ``moe_norm_topk_prob``):
+policy gradient). The router runs in float32 over all E experts; three
+weightings (arch ``moe_router`` and ``moe_norm_topk_prob``):
 
-* ``True`` (default) — top-k of the logits, softmax over the k chosen
-  (equal to softmax over all E, top-k, renormalised);
-* ``False`` — softmax over all E, top-k, the k probabilities used as they
-  are (OLMoE's ``norm_topk_prob: false``).
+* softmax, ``True`` (the default) — top-k of the logits, softmax over the
+  k chosen (equal to softmax over all E, top-k, renormalised);
+* softmax, ``False`` — softmax over all E, top-k, the k probabilities used
+  as they are (OLMoE's ``norm_topk_prob: false``);
+* ``moe_router: "sigmoid"`` — a sigmoid score per expert; the k are chosen
+  on ``score + moe_expert_bias`` (arch ``moe_expert_bias``: one float32 per
+  expert, seeded non-zero; it enters the choice only, so its gradient is
+  exactly zero and the optimizer never moves it) and weighted by their
+  UNBIASED scores, divided by their sum + 1e-6 under ``moe_norm_topk_prob``
+  (LFM2-MoE's router; its ``routed_scaling_factor`` is 1 and has no key).
+
+**Held experts.** A layer may be told which experts it holds: arch
+``moe_held = [first, count]`` says this device holds experts
+``first .. first + count - 1`` of the ``moe_experts`` the model has — the
+share of one chip among the chips that divide a layer's experts. The
+router still scores and chooses over all E (and normalises over the k
+chosen of all E, held or not, so that the shares of all the chips add up
+to the layer), the expert stacks are ``[count, ...]``, and the layer's
+output is the sum over the chosen experts that are held: a token-slot
+whose expert lives elsewhere adds nothing here — no stand-in for the
+absent chips' work or traffic. Unset, every expert is held and the layer
+is what it was.
 
 Experts are GELU (``moe_w_up`` / ``moe_w_down``, the default) or SwiGLU
 (arch ``ffn: "swiglu"``: ``moe_w_gate`` beside them,
@@ -31,7 +49,17 @@ experts), and the down output is un-sorted and summed per token with the
 router weights. Every shape is static (N·k rows whatever the imbalance),
 there is no capacity and so **no token-slot is ever dropped**: an expert
 that every token picks gets all N rows, an expert nobody picks gets a group
-of size 0. The FFN costs k/E of running every expert on every token. Both
+of size 0. With held experts the buffers keep their N·k rows and the slots
+of absent experts sort behind the held ones, as a tail no group covers:
+the grouped matmuls visit (and cost) the held rows only, the row gathers
+still move N·k rows, and what the kernels leave in the tail — rows they
+never wrote — is masked to zero where it would be read (the un-sorted
+output, the input cotangent). The layer's N·k-row buffers (the dispatched
+rows, up and gate in float32, the un-sorted output) are then recomputed in
+the backward from the ``[N, d]`` tokens (``jax.checkpoint`` round dispatch,
+experts and combine) instead of kept: sized for every slot they are eight
+times what the held slots need, 1.6 GB a layer at 65,536 slots of 2048.
+The FFN costs k/E of running every expert on every token. Both
 permutations are gathers, forward and backward (a permutation's transpose
 is the gather by its inverse), because a TPU scatter-add of [N·k, d] rows
 is slower than the matmuls it serves.
@@ -45,8 +73,11 @@ matmuls are Pallas calls, which GSPMD does not partition, so under an
 would scale nothing (not measured: no multi-chip cell yet). The layer
 therefore picks by what it can observe where it is traced: dense under an
 ambient mesh (``parallel/context.py``) whose ``ep`` axis is larger than 1,
-sparse everywhere else; an all-to-all dispatch under ``shard_map`` is the
-multi-chip follow-up (ROADMAP 2.7). Arch ``moe_dispatch`` (``"sparse"`` |
+sparse everywhere else. The multi-chip follow-up (ROADMAP 2.7) is the
+expert exchange under ``shard_map``: each chip running the held-experts
+layer on its ``moe_held`` range — the piece that exists now — between an
+all-to-all that brings it the token-slots of its experts and one that
+takes the results back. Arch ``moe_dispatch`` (``"sparse"`` |
 ``"dense"``) overrides the pick for ``tests/test_moe.py``, which compares
 the two paths (forward and every gradient); ``"sparse"`` under an ``ep``
 mesh is refused. No benchmark cell takes the dense path.
@@ -56,44 +87,78 @@ No auxiliary load-balancing loss is applied (see
 the gate-collapse failure mode that omission leaves open).
 
 Shapes: tokens flatten to ``[N = B*T, d]``; expert stacks are
-``moe_w_up`` / ``moe_w_gate [E, d, ff]`` and ``moe_w_down [E, ff, d]``.
+``moe_w_up`` / ``moe_w_gate [E, d, ff]`` and ``moe_w_down [E, ff, d]``
+(``E`` = the held count where ``moe_held`` is set).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+# Spread of the seeded ``moe_expert_bias`` (sigmoid router): against scores
+# whose 4th and 5th lie ~0.02 apart it moves the choice of about every
+# second token, and an expert's load by about a quarter.
+_EXPERT_BIAS_STD = 0.02
 
-def route(logits, k: int, norm_topk_prob: bool):
+
+def route(logits, k: int, norm_topk_prob: bool, router: str = "softmax",
+          expert_bias=None):
     """Router logits ``[N, E]`` (float32) -> (weights ``[N, k]``, expert
     indices ``[N, k]``); a token's row depends on that token alone."""
+    if router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if expert_bias is None else scores + expert_bias
+        top_idx = jax.lax.top_k(biased, k)[1]
+        top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if norm_topk_prob:
+            top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-6)
+        return top_w, top_idx
+    if router != "softmax":
+        raise ValueError(f"unknown moe_router {router!r} (softmax | sigmoid)")
     if norm_topk_prob:
         top_vals, top_idx = jax.lax.top_k(logits, k)
         return jax.nn.softmax(top_vals, axis=-1), top_idx
     return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
 
 
-@jax.custom_vjp
-def _dispatch_rows(tokens, token_of_row, slot_to_row):
+def _slots_3d(rows, n: int, choice_major: bool):
+    """Slot-ordered rows ``[N*k, d]`` as ``[N, k, d]`` (slot = token * k +
+    choice) or, ``choice_major``, ``[k, N, d]`` (slot = choice * N +
+    token): a split of the leading axis either way."""
+    lead = (-1, n) if choice_major else (n, -1)
+    return rows.reshape(lead + rows.shape[-1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch_rows(tokens, token_of_row, slot_to_row, live_slot=None,
+                   choice_major=False):
     """``tokens[token_of_row]``: each token's row once per chosen expert,
     in expert order. Its transpose is a gather too: cotangent rows back in
-    slot order (``slot_to_row``), the k of each token summed."""
-    return _dispatch_fwd(tokens, token_of_row, slot_to_row)[0]
+    slot order (``slot_to_row``), the k of each token summed.
+    ``live_slot`` (bool per slot, held-experts layers): the cotangent of a
+    slot whose expert is not held is zero, whatever its row holds."""
+    return _dispatch_fwd(tokens, token_of_row, slot_to_row, live_slot,
+                         choice_major)[0]
 
 
-def _dispatch_fwd(tokens, token_of_row, slot_to_row):
+def _dispatch_fwd(tokens, token_of_row, slot_to_row, live_slot=None,
+                  choice_major=False):
     out = jnp.take(tokens, token_of_row, axis=0)
-    return out, (slot_to_row, tokens.shape[0])
+    return out, (slot_to_row, live_slot, tokens.shape[0])
 
 
-def _dispatch_bwd(res, g):
-    slot_to_row, n = res
+def _dispatch_bwd(choice_major, res, g):
+    slot_to_row, live_slot, n = res
     g = jnp.take(g, slot_to_row, axis=0)
-    return g.reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
+    if live_slot is not None:
+        g = jnp.where(live_slot[:, None], g, 0)
+    g = _slots_3d(g, n, choice_major).sum(axis=0 if choice_major else 1)
+    return g, None, None, None
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -157,6 +222,10 @@ class MoEMLP(nn.Module):
     ffn: str = "gelu"
     dispatch: str | None = None     # None: by the ambient mesh's ep axis
     use_bias: bool = True      # the router's; the expert stacks have none
+    router: str = "softmax"         # | "sigmoid" (module docstring)
+    expert_bias: bool = False       # moe_expert_bias in the sigmoid choice
+    # (first, count): the experts of n_experts this device holds; None: all
+    held: tuple[int, int] | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -167,32 +236,48 @@ class MoEMLP(nn.Module):
         cd = self.compute_dtype
         tokens = x.reshape(n, d)
 
+        first, n_held = self.held or (0, n_exp)
+        if not (0 <= first and 0 < n_held and first + n_held <= n_exp):
+            raise ValueError(f"moe_held {self.held} outside 0..{n_exp}")
+        partial = n_held < n_exp
+
         logits = nn.Dense(n_exp, dtype=jnp.float32, use_bias=self.use_bias,
                           name="moe_gate")(tokens.astype(jnp.float32))
-        top_w, top_idx = route(logits, k, self.norm_topk_prob)   # [N, k]
+        bias = None
+        if self.expert_bias:
+            # enters the choice only: zero gradient, never moved; seeded
+            # non-zero so that the path is exercised
+            bias = self.param("moe_expert_bias",
+                              nn.initializers.normal(_EXPERT_BIAS_STD),
+                              (n_exp,), jnp.float32)
+        top_w, top_idx = route(logits, k, self.norm_topk_prob, self.router,
+                               bias)                               # [N, k]
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         gated = self.ffn == "swiglu"
         if gated:
-            w_gate = self.param("moe_w_gate", init, (n_exp, d, self.d_ff),
+            w_gate = self.param("moe_w_gate", init, (n_held, d, self.d_ff),
                                 jnp.float32).astype(cd)
-        w_up = self.param("moe_w_up", init, (n_exp, d, self.d_ff),
+        w_up = self.param("moe_w_up", init, (n_held, d, self.d_ff),
                           jnp.float32).astype(cd)
-        w_down = self.param("moe_w_down", init, (n_exp, self.d_ff, d),
+        w_down = self.param("moe_w_down", init, (n_held, self.d_ff, d),
                             jnp.float32).astype(cd)
 
         def act(up, gate=None):
             return nn.silu(gate) * up if gated else nn.gelu(up)
 
-        # token-slots per expert: the grouped matmuls' group sizes and the
-        # load monitor (a compare-and-reduce; a scatter-add serialises)
-        load = (top_idx[..., None] == jnp.arange(n_exp)).sum(
+        # token-slots per (held) expert: the grouped matmuls' group sizes
+        # and the load monitor (a compare-and-reduce; a scatter-add
+        # serialises)
+        load = (top_idx[..., None] == first + jnp.arange(n_held)).sum(
             axis=(0, 1), dtype=jnp.int32)
 
         dispatch = self.dispatch or ("dense" if _mesh_ep() > 1 else "sparse")
         if dispatch == "dense":
             weights = jnp.zeros((n, n_exp), jnp.float32).at[
                 jnp.arange(n)[:, None], top_idx].set(top_w)      # [N, E]
+            if partial:
+                weights = weights[:, first:first + n_held]
             xs = tokens.astype(cd)
 
             def up_proj(w):
@@ -211,51 +296,95 @@ class MoEMLP(nn.Module):
                     f"program (GSPMD does not partition its Pallas calls "
                     f"over ep); leave moe_dispatch unset and the layer "
                     f"takes the dense path, which GSPMD does partition")
-            # slot s = token s // k, choice s % k; rows = slots by expert
-            expert_of_slot = top_idx.reshape(n * k)
+            # slot s = token s // k, choice s % k; rows = slots by expert.
+            # [N*k, d] rows split as [N, k, d] for the combine, which a TPU
+            # tiles (8, 128) over (k, d): free at k = 8, a relayout into
+            # padded tiles at k = 4 (25 ms an update in lfm2-policy.update,
+            # PERF.md section 6). So where 8 does not divide k the slots run
+            # choice-major, slot s = choice s // N, token s % N, and the
+            # split is [k, N, d]. Both orders are measured: choice-major at
+            # k = 8 costs olmoe-policy.update 1.4% (1.7 ms an update, one
+            # broadcast-select fusion half as long again: PERF.md section 6).
+            choice_major = k % 8 != 0
+            expert_of_slot = (top_idx.T if choice_major else top_idx
+                              ).reshape(n * k)
+            live_slot = None
+            if partial:
+                # the slots of absent experts sort behind the held ones: a
+                # tail of rows no group covers and no matmul visits
+                local = expert_of_slot - first
+                live_slot = (local >= 0) & (local < n_held)
+                expert_of_slot = jnp.where(live_slot, local, n_held)
             row_to_slot = jnp.argsort(expert_of_slot, stable=True)
             slot_to_row = jnp.zeros_like(row_to_slot).at[row_to_slot].set(
                 jnp.arange(n * k, dtype=row_to_slot.dtype),
                 unique_indices=True)
-            xs = _dispatch_rows(tokens.astype(cd), row_to_slot // k,
-                                slot_to_row)                      # [N*k, d]
+            token_of_row = (row_to_slot % n if choice_major
+                            else row_to_slot // k)
 
-            def up_proj(w):
-                return grouped_matmul(xs, w, load).astype(jnp.float32)
+            def sparse(tokens, top_w, w_up, w_gate, w_down):
+                xs = _dispatch_rows(tokens, token_of_row, slot_to_row,
+                                    live_slot, choice_major)      # [N*k, d]
 
-            h = act(up_proj(w_up), up_proj(w_gate) if gated else None)
-            out = grouped_matmul(h.astype(cd), w_down, load)      # [N*k, d]
-            out = _unsort_rows(out, slot_to_row, row_to_slot)
-            y = jnp.einsum("nk,nkd->nd", top_w,
-                           out.reshape(n, k, d).astype(jnp.float32))
+                def up_proj(w):
+                    return grouped_matmul(xs, w, load).astype(jnp.float32)
+
+                h = act(up_proj(w_up), up_proj(w_gate) if gated else None)
+                out = grouped_matmul(h.astype(cd), w_down, load)  # [N*k, d]
+                out = _unsort_rows(out, slot_to_row, row_to_slot)
+                if partial:  # the tail's rows were never written
+                    out = jnp.where(live_slot[:, None], out, 0)
+                out = _slots_3d(out, n, choice_major).astype(jnp.float32)
+                if choice_major:
+                    return jnp.einsum("kn,knd->nd", top_w.T, out)
+                return jnp.einsum("nk,nkd->nd", top_w, out)
+
+            if partial:
+                # every N*k-row buffer (the dispatched rows, up and gate in
+                # float32, the un-sorted output) is sized for all the slots
+                # whatever the held rows: recomputed in the backward from
+                # the [N, d] tokens, not kept (module docstring)
+                sparse = jax.checkpoint(sparse)
+            y = sparse(tokens.astype(cd), top_w, w_up,
+                       w_gate if gated else None, w_down)
         else:
             raise ValueError(f"unknown moe_dispatch {dispatch!r}")
 
-        # Monitoring hook: token-slots per expert (sums to N*k). Inert
-        # unless applied with mutable=["intermediates"] — the update's
-        # moe_load_max/min and expert_utilization() read it.
+        # Monitoring hook: token-slots per held expert (sums to N*k where
+        # every expert is held). Inert unless applied with
+        # mutable=["intermediates"] — the update's moe_load_max/min,
+        # moe_held_slots and expert_utilization() read it.
         self.sow("intermediates", "expert_load", load)
+        self.sow("intermediates", "expert_slots", jnp.int32(n * k))
         return y.reshape(B, T, d).astype(x.dtype)
 
 
+def _loads(intermediates) -> dict:
+    """``{layer: ([held] token-slots per held expert, all N*k slots)}``
+    from one applied forward's sown counts."""
+    return {layer: (sub["moe"]["expert_load"][0].astype(jnp.float32),
+                    sub["moe"]["expert_slots"][0].astype(jnp.float32))
+            for layer, sub in intermediates.items()
+            if layer.startswith("block_") and "moe" in sub}
+
+
 def _shares(intermediates) -> dict:
-    """``{layer: [E] shares of the token-slots}`` from one applied forward's
-    sown ``expert_load`` counts."""
-    out = {}
-    for layer, sub in intermediates.items():
-        if layer.startswith("block_") and "moe" in sub:
-            load = sub["moe"]["expert_load"][0].astype(jnp.float32)
-            out[layer] = load / jnp.maximum(load.sum(), 1.0)
-    return out
+    """``{layer: [held] shares of ALL the layer's token-slots}``."""
+    return {layer: load / jnp.maximum(slots, 1.0)
+            for layer, (load, slots) in _loads(intermediates).items()}
 
 
 def load_extremes(intermediates) -> dict:
-    """``{"moe_load_max", "moe_load_min"}``: the fullest and the emptiest
-    expert's share of the token-slots, over every MoE layer of one applied
-    forward (nothing is recomputed). 1/E each at even load; max -> 1/k is
-    the gate collapsing."""
+    """``{"moe_load_max", "moe_load_min", "moe_held_slots"}``: the fullest
+    and the emptiest held expert's share of all the token-slots, over every
+    MoE layer of one applied forward (nothing is recomputed), and the
+    token-slots routed to held experts, summed over those layers. 1/E each
+    at even load; max -> 1/k is the gate collapsing; held slots = N*k a
+    layer where every expert is held."""
     shares = jnp.stack(list(_shares(intermediates).values()))
-    return {"moe_load_max": shares.max(), "moe_load_min": shares.min()}
+    held = sum(load.sum() for load, _ in _loads(intermediates).values())
+    return {"moe_load_max": shares.max(), "moe_load_min": shares.min(),
+            "moe_held_slots": held}
 
 
 def expert_utilization(arch, params, obs, mask=None) -> dict:
@@ -272,7 +401,9 @@ def expert_utilization(arch, params, obs, mask=None) -> dict:
     ``moe_load_min``); call this on a representative batch for the whole
     distribution, and alarm when the max fraction nears 1/k.
 
-    Returns ``{layer_name: [E] fractions summing to 1}``.
+    Returns ``{layer_name: [E] fractions summing to 1}`` (under
+    ``moe_held``: the held experts' fractions of all the token-slots,
+    summing to the held share).
     """
     from relayrl_tpu.models.transformer import _make_core
 

@@ -29,6 +29,19 @@ operations as before these keys existed. OLMoE-1B-7B's layer is
 ``transformer_moe_discrete`` with rms / rope / qk_norm / no bias / swiglu
 (``benchmark/configs/olmoe-policy.json``).
 
+A trunk may hold layers of several kinds (LFM2-24B-A2B's,
+``benchmark/configs/lfm2-policy.json``): ``layer_types`` names each
+layer's operator — ``"full_attention"`` or ``"conv"``, the gated short
+convolution (:func:`_short_conv`) — and ``moe_dense_layers`` how many
+leading layers keep the dense FFN of ``d_ff`` in a MoE trunk, the rest
+taking the expert layer. Attention may be grouped-query (``n_kv_heads``
+k/v heads under ``n_heads`` query heads, every head ``d_model //
+n_heads`` wide: separate ``q_proj`` / ``k_proj`` / ``v_proj`` where the
+default block splits one fused ``qkv``), with ``qk_norm: "head"``
+normalising each head's width (``True``: the whole projection, OLMoE's).
+RoPE turns q and k in attention layers only; a conv layer sees no
+positions.
+
 Sequence ABI: ``evaluate(params, obs[B,T,D], act[B,T], mask[B,T,A]) ->
 (logp[B,T], ent[B,T], v[B,T])`` — same shapes the per-step MLP family
 broadcasts to, so REINFORCE/PPO updates take this policy unchanged.
@@ -79,17 +92,21 @@ def _resolve_attention(arch: Mapping[str, Any]
     resolved: dict[tuple[int, int, str], str] = {}
     score_area: dict[tuple[int, int, str], float] = {}
 
-    def ran(q, backend: str, area_pct: float | None = None) -> None:
+    def ran(q, backend: str, area_pct: float | None = None,
+            k=None) -> None:
         key = (int(q.shape[1]), int(q.shape[3]), q.dtype.name)
         if resolved.get(key) != backend:
             resolved[key] = backend
             area = ""
+            heads = ""
+            if k is not None and k.shape[2] != q.shape[2]:  # grouped-query
+                heads = f" heads {q.shape[2]}/{k.shape[2]}"
             if area_pct is not None:
                 score_area[key] = area_pct
                 area = f", score area {area_pct:g}%"
             if kind in ("flash", "ring"):
                 print(f"[attention] {kind!r} T={key[0]} head_dim={key[1]} "
-                      f"{key[2]} -> {backend}{area} "
+                      f"{key[2]}{heads} -> {backend}{area} "
                       f"(platform {jax.default_backend()})", flush=True)
 
     def dense(q, k, v):
@@ -124,7 +141,7 @@ def _resolve_attention(arch: Mapping[str, Any]
             fblock = int(arch.get("flash_block", 1024))
             if jax.default_backend() == "tpu" and T % min(fblock, T) == 0:
                 ran(q, "flash_pallas", flash.score_area_pct(
-                    T, *flash.tiling(T, True, fblock, fblock), True))
+                    T, *flash.tiling(T, True, fblock, fblock), True), k)
                 return flash.flash_attention(q, k, v, causal=True,
                                              block_q=fblock, block_kv=fblock)
             return local(q, k, v)
@@ -141,6 +158,11 @@ def _resolve_attention(arch: Mapping[str, Any]
             mesh = current_mesh()
             if mesh is None or mesh.shape.get("sp", 1) <= 1:
                 return local(q, k, v)
+            if k.shape[2] != q.shape[2]:
+                raise ValueError(
+                    "ring attention takes one head count for q, k and v; "
+                    "grouped-query heads run under attention 'flash', "
+                    "'blockwise' or 'dense'")
             # On TPU the per-round combine runs as Pallas flash chunk
             # kernels when the local chunk tiles; the scan ring is the
             # portable fallback (and the off-TPU path).
@@ -184,6 +206,33 @@ def apply_rope(x, start, theta: float):
                            axis=-1).astype(x.dtype)
 
 
+SHORT_CONV_NAME = "relayrl_short_conv"
+
+
+def _short_conv(bcu, w, state=None):
+    """The gated short convolution between its two projections:
+    ``(B, C, u) = split3(bcu)``, ``z = B * u``, ``c_t = sum_j w[j] *
+    z_{t-(L-1)+j}`` (depthwise, causal, ``L = w.shape[0]`` taps, no bias),
+    returns ``(C * c, z_padded)``. ``state [batch, L-1, d]`` holds the
+    ``z`` rows before this call's first (zeros at a sequence's start, which
+    ``None`` means). Plain XLA under one named scope: L shifted
+    multiply-adds fused with the two gate products, accumulated in float32.
+    ``z_padded = concat(state, z)`` is what a cache takes its next state
+    from."""
+    with jax.named_scope(SHORT_CONV_NAME):
+        taps = w.shape[0]
+        T = bcu.shape[1]
+        b_gate, c_gate, u = jnp.split(bcu, 3, axis=-1)
+        z = b_gate * u
+        if state is None:
+            zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+        else:
+            zp = jnp.concatenate([state.astype(z.dtype), z], axis=1)
+        c = sum(w[j].astype(jnp.float32) * zp[:, j:j + T].astype(jnp.float32)
+                for j in range(taps))
+        return c_gate * c.astype(bcu.dtype), zp
+
+
 def _block_dense(block: "TransformerBlock", features: int, name: str):
     return nn.Dense(features, dtype=block.compute_dtype, name=name,
                     use_bias=block.use_bias)
@@ -204,7 +253,7 @@ def _block_ffn(block: "TransformerBlock", x):
                    block.moe_experts, block.moe_top_k, block.compute_dtype,
                    norm_topk_prob=block.moe_norm_topk_prob, ffn=block.ffn,
                    dispatch=block.moe_dispatch, use_bias=block.use_bias,
-                   name="moe")(h)
+                   **block.moe_kw, name="moe")(h)
         return x + h.astype(x.dtype)
     h = h.astype(block.compute_dtype)
     up = _block_dense(block, width, "mlp_up")(h)
@@ -243,14 +292,31 @@ class TransformerBlock(nn.Module):
     moe_d_ff: int | None = None         # one expert's width; None = d_ff
     moe_norm_topk_prob: bool = True
     moe_dispatch: str | None = None     # None: models/moe.py picks
+    # MoEMLP's further fields (router, expert_bias, held)
+    moe_kw: Mapping[str, Any] = flax.core.FrozenDict()
+    # The layer's operator: "attention" | "conv" (gated short convolution
+    # of conv_taps taps: conv_in d -> 3d, conv_w [taps, d], conv_out).
+    op: str = "attention"
+    conv_taps: int = 3
+    # Grouped-query heads: n_kv_heads k/v heads under n_heads query heads,
+    # all d_model // n_heads wide (separate q_proj / k_proj / v_proj).
+    # None: one fused qkv, as always. qk_norm "head": RMSNorm over each head.
+    n_kv_heads: int | None = None
 
     @nn.compact
-    def __call__(self, x, cache=None, t=None, readout_idx=None):
+    def __call__(self, x, cache=None, t=None, readout_idx=None,
+                 n_valid=None):
         """Full mode (``cache=None``): x ``[B, T, d]`` -> ``[B, T, d]``.
 
         Decode mode: x is ONE position ``[B, 1, d]``; ``cache`` is this
-        layer's ``(k, v)`` pair ``[B, W, H, hd]`` and ``t`` the write
-        index. Attention runs q against the cache prefix (positions <= t)
+        layer's state and ``t`` the write index. An attention layer's
+        state is its ``(k, v)`` pair ``[B, W, Hkv, hd]`` (``Hkv`` =
+        ``n_kv_heads``: grouped-query k/v are cached as they are, and the
+        q heads of a group read the same rows); a conv layer's is the last
+        ``conv_taps - 1`` rows of ``B * u``, ``[B, conv_taps - 1, d]``
+        (``n_valid``, prefill only: how many of x's rows are real — the
+        state is taken from the rows before that; None: all of them).
+        Attention runs q against the cache prefix (positions <= t)
         instead of recomputing the whole window — O(W) per step vs the
         window path's O(W^2). Returns ``(out, new_cache)``. Param
         names/creation order are identical in both modes (init always runs
@@ -271,19 +337,40 @@ class TransformerBlock(nn.Module):
         window, row ``readout_idx`` for the readout query, ``t + j`` in
         decode mode — the cache holds rotated keys."""
         B, T, _ = x.shape
+        if self.op == "conv":
+            return _conv_layer(self, x, cache, readout_idx, n_valid)
+        if self.op != "attention":
+            raise ValueError(f"unknown layer operator {self.op!r} "
+                             f"(attention | conv)")
         head_dim = self.d_model // self.n_heads
         h = _norm(self.norm, self.norm_eps, "ln_attn")(x)
         h = h.astype(self.compute_dtype)
-        qkv = _block_dense(self, 3 * self.d_model, "qkv")(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        if self.qk_norm:
+        if self.n_kv_heads is None:
+            n_kv = self.n_heads
+            qkv = _block_dense(self, 3 * self.d_model, "qkv")(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        else:
+            n_kv = self.n_kv_heads
+            q = _block_dense(self, self.d_model, "q_proj")(h)
+            k = _block_dense(self, n_kv * head_dim, "k_proj")(h)
+            v = _block_dense(self, n_kv * head_dim, "v_proj")(h)
+        if self.qk_norm is True:
             # over the whole d_model-wide projection, before the heads
             q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
                 self.compute_dtype)
             k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
                 self.compute_dtype)
-        shape = (B, T, self.n_heads, head_dim)
-        q, k, v = (a.reshape(shape) for a in (q, k, v))
+        q = q.reshape(B, T, self.n_heads, head_dim)
+        k, v = (a.reshape(B, T, n_kv, head_dim) for a in (k, v))
+        if self.qk_norm == "head":
+            # over each head's head_dim, one learned scale for all heads
+            q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
+                self.compute_dtype)
+            k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
+                self.compute_dtype)
+        elif self.qk_norm not in (True, False):
+            raise ValueError(f"unknown qk_norm {self.qk_norm!r} "
+                             f"(false | true | \"head\")")
         rope = self.rope_theta is not None
         if rope:
             k = apply_rope(k, 0 if t is None else t, self.rope_theta)
@@ -321,6 +408,45 @@ class TransformerBlock(nn.Module):
             x.dtype)
         out = _block_ffn(self, x)
         return out if cache is None else (out, new_cache)
+
+
+def _conv_layer(block: TransformerBlock, x, cache, readout_idx, n_valid):
+    """A conv layer in ``block``'s param scope, in the block's three modes:
+    ``x + conv_out(C * conv(B * u))``, then the FFN. No positions: the
+    operator is causal by construction and sees ``conv_taps - 1`` rows
+    back. (A plain function, like :func:`_block_ffn`.)"""
+    B, T, d = x.shape
+    back = block.conv_taps - 1
+    w = block.param("conv_w", nn.initializers.lecun_normal(),
+                    (block.conv_taps, d), jnp.float32)
+
+    def in_proj(rows):
+        h = _norm(block.norm, block.norm_eps, "ln_attn")(rows)
+        return _block_dense(block, 3 * d, "conv_in")(
+            h.astype(block.compute_dtype))
+
+    def out_proj(x, y):
+        return x + _block_dense(block, d, "conv_out")(y).astype(x.dtype)
+
+    if readout_idx is not None:
+        # the one row needs its own and the conv_taps - 1 rows before it;
+        # rows before the sequence's first have z = 0
+        xp = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))
+        rows = jax.lax.dynamic_slice_in_dim(xp, readout_idx, back + 1,
+                                            axis=1)
+        real = (readout_idx - back + jnp.arange(back + 1)) >= 0
+        bcu = jnp.where(real[None, :, None], in_proj(rows), 0)
+        y = _short_conv(bcu, w)[0][:, back:]
+        return _block_ffn(block, out_proj(rows[:, back:], y))
+    y, zp = _short_conv(in_proj(x), w, cache)
+    out = _block_ffn(block, out_proj(x, y))
+    if cache is None:
+        return out
+    # zp row j is z row j - back: the state after n real rows is z rows
+    # n - back .. n - 1
+    n = T if n_valid is None else n_valid
+    state = jax.lax.dynamic_slice_in_dim(zp, n, back, axis=1)
+    return out, state.astype(cache.dtype)
 
 
 def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
@@ -379,11 +505,29 @@ class TransformerCore(nn.Module):
     moe_top_k: int = 2
     # TransformerBlock's arch fields, passed through as one dict
     block_kw: Mapping[str, Any] = flax.core.FrozenDict()
+    # Per layer: its operator ("full_attention" | "conv"; empty: attention
+    # everywhere) and, in a MoE trunk, how many leading layers keep the
+    # dense FFN.
+    layer_types: tuple[str, ...] = ()
+    moe_dense_layers: int = 0
+
+    def layer_op(self, i: int) -> str:
+        kind = self.layer_types[i] if self.layer_types else "full_attention"
+        if kind not in ("full_attention", "conv"):
+            raise ValueError(f"unknown layer type {kind!r} "
+                             f"(full_attention | conv)")
+        return "conv" if kind == "conv" else "attention"
+
+    def layer_experts(self, i: int) -> int:
+        return 0 if i < self.moe_dense_layers else self.moe_experts
 
     @nn.compact
-    def __call__(self, obs, mask=None, cache=None, t=None, readout_t=None):
+    def __call__(self, obs, mask=None, cache=None, t=None, readout_t=None,
+                 n_valid=None):
         """Full mode: obs ``[B, T, D]`` -> (logits, v). Decode mode
-        (``cache`` = tuple of per-layer (k, v) pairs, ``t`` = position):
+        (``cache`` = tuple of per-layer states — a (k, v) pair for an
+        attention layer, the last rows of ``B * u`` for a conv layer —,
+        ``t`` = position; ``n_valid``: prefill's count of real rows):
         obs is ``[B, 1, D]``; returns ``((logits, v), new_cache)`` for the
         single position. Readout mode (``readout_t`` = dynamic row index):
         obs is a full window ``[B, W, D]`` but only position ``readout_t``
@@ -395,11 +539,16 @@ class TransformerCore(nn.Module):
         decode = cache is not None
         kw = self.block_kw
 
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} "
+                             f"layers, n_layers is {self.n_layers}")
+
         def block_at(i: int) -> TransformerBlock:
             return TransformerBlock(
                 self.d_model, self.n_heads, self.mlp_ratio, self.attn_fn,
-                self.compute_dtype, moe_experts=self.moe_experts,
-                moe_top_k=self.moe_top_k, name=f"block_{i}", **kw)
+                self.compute_dtype, moe_experts=self.layer_experts(i),
+                moe_top_k=self.moe_top_k, op=self.layer_op(i),
+                name=f"block_{i}", **kw)
 
         def heads(x, mask):
             return _readout_heads(x, mask, self.act_dim, self.d_model,
@@ -415,10 +564,11 @@ class TransformerCore(nn.Module):
             for i in range(self.n_layers - 1):
                 x = block_at(i)(x)
             final = block_at(self.n_layers - 1)
-            if self.moe_experts > 0:
+            if final.moe_experts > 0 and final.op == "attention":
                 # The MoE final block keeps its full-window pass (routing
                 # is per token, so the sliced row is what a row-only pass
-                # would give; the shortcut is simply not taken here).
+                # would give; the shortcut is simply not taken here). A
+                # conv layer takes the row path whatever its FFN.
                 x = jax.lax.dynamic_slice_in_dim(final(x), idx, 1, axis=1)
             else:
                 x = final(x, readout_idx=idx)
@@ -432,7 +582,8 @@ class TransformerCore(nn.Module):
         for i in range(self.n_layers):
             block = block_at(i)
             if decode:
-                x, layer_cache = block(x, cache=cache[i], t=t)
+                x, layer_cache = block(x, cache=cache[i], t=t,
+                                       n_valid=n_valid)
                 new_cache.append(layer_cache)
             else:
                 x = block(x)
@@ -537,11 +688,23 @@ def _policy_from_apply(arch: Mapping[str, Any], init_params, apply_fn,
 # the same names; ``positions`` + ``rope_theta`` become its ``rope_theta``).
 # An arch with none of them is the GPT-2 shaped block.
 _BLOCK_ARCH_KEYS = ("norm", "norm_eps", "qk_norm", "use_bias", "ffn", "d_ff",
-                    "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch")
+                    "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch",
+                    "n_kv_heads", "conv_taps")
+# MoEMLP's fields by the arch key that sets each (block field ``moe_kw``)
+_MOE_ARCH_KEYS = {"moe_router": "router", "moe_expert_bias": "expert_bias",
+                  "moe_held": "held"}
+# the core's own: what kind each layer is
+_LAYER_ARCH_KEYS = ("layer_types", "moe_dense_layers")
 
 
 def _block_kwargs(arch: Mapping[str, Any]) -> dict:
     kw = {k: arch[k] for k in _BLOCK_ARCH_KEYS if k in arch}
+    moe_kw = {field: arch[k] for k, field in _MOE_ARCH_KEYS.items()
+              if k in arch}
+    if "held" in moe_kw:
+        moe_kw["held"] = tuple(int(a) for a in moe_kw["held"])
+    if moe_kw:
+        kw["moe_kw"] = flax.core.FrozenDict(moe_kw)
     positions = arch.get("positions", "learned")
     if positions == "rope":
         kw["rope_theta"] = float(arch.get("rope_theta", 10000.0))
@@ -570,6 +733,8 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         moe_experts=moe_experts,
         moe_top_k=int(arch.get("moe_top_k", 2)),
         block_kw=flax.core.FrozenDict(_block_kwargs(arch)),
+        layer_types=tuple(arch.get("layer_types", ())),
+        moe_dense_layers=int(arch.get("moe_dense_layers", 0)),
     )
 
 
@@ -582,14 +747,21 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
 
     head_dim = core.d_model // core.n_heads
+    n_kv_heads = int(arch.get("n_kv_heads", core.n_heads))
+    conv_back = int(arch.get("conv_taps", 3)) - 1
     cache_dtype = core.compute_dtype
 
     def init_cache(length: int, batch_size: int = 1):
-        """Zeroed per-layer (k, v) caches for incremental decoding."""
-        shape = (batch_size, int(length), core.n_heads, head_dim)
+        """Zeroed per-layer states for incremental decoding, two kinds
+        side by side: a (k, v) pair ``[B, length, Hkv, hd]`` for an
+        attention layer, the last ``conv_taps - 1`` rows of ``B * u``
+        ``[B, conv_taps - 1, d]`` for a conv layer."""
+        kv = (batch_size, int(length), n_kv_heads, head_dim)
+        conv = (batch_size, conv_back, core.d_model)
         return tuple(
-            (jnp.zeros(shape, cache_dtype), jnp.zeros(shape, cache_dtype))
-            for _ in range(core.n_layers))
+            jnp.zeros(conv, cache_dtype) if core.layer_op(i) == "conv"
+            else (jnp.zeros(kv, cache_dtype), jnp.zeros(kv, cache_dtype))
+            for i in range(core.n_layers))
 
     def step_cached(params, rng, cache, obs, t, mask=None):
         """One O(W) decode step: writes position ``t`` into the cache and
@@ -617,16 +789,20 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
             aux = {k: a[0] for k, a in aux.items()}
         return act, aux, new_cache
 
-    def prefill_cache(params, cache, window):
+    def prefill_cache(params, cache, window, n_valid=None):
         """Rebuild the whole cache from a padded window in ONE dispatch
         (post-hot-swap path): runs decode mode with T = W queries at
         t=0. Padding rows write garbage K/V beyond the real prefix, which
         later per-step decodes never attend (their causal mask stops at
-        the current t) and overwrite in order."""
+        the current t) and overwrite in order. A conv layer's state has
+        no positions to overwrite: it is taken from the rows before
+        ``n_valid``, the count of real rows (None: the whole window is
+        real)."""
         window = jnp.asarray(window)
         if window.ndim == 2:
             window = window[None]
-        _, new_cache = core.apply(params, window, None, cache=cache, t=0)
+        _, new_cache = core.apply(params, window, None, cache=cache, t=0,
+                                  n_valid=n_valid)
         return new_cache
 
     policy = _policy_from_apply(
@@ -717,7 +893,8 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
     plain ``lax.scan`` over layers — so the SAME arch config serves CPU
     actor hosts and the pipelined TPU learner (SURVEY.md §7.4 item 2).
     """
-    new = [k for k in _BLOCK_ARCH_KEYS + ("positions", "rope_theta")
+    new = [k for k in _BLOCK_ARCH_KEYS + _LAYER_ARCH_KEYS
+           + tuple(_MOE_ARCH_KEYS) + ("positions", "rope_theta")
            if k in arch]
     if new:
         raise ValueError(

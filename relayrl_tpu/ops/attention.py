@@ -13,6 +13,13 @@ path in :mod:`relayrl_tpu.parallel.ring`.
 Layout convention: ``[batch, time, heads, head_dim]`` (BTHD) everywhere.
 Scores are computed in float32 regardless of input dtype (bf16 trunks feed
 the MXU; softmax stays f32 for stability).
+
+Grouped-query attention: ``k`` / ``v`` may carry fewer heads than ``q``
+(``H = G * Hkv``; q head ``j`` reads k/v head ``j // G``). The dense and
+blockwise forms then fold the ``G`` query heads of a group into the query
+time axis — ``[B, G * Tq, Hkv, D]``, each folded row keeping its own time
+position for the causal mask — so k and v are used as they are, never
+repeated. With ``H == Hkv`` nothing is folded and the code is what it was.
 """
 
 from __future__ import annotations
@@ -25,6 +32,25 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
+def _fold_groups(q: jax.Array, kv_heads: int) -> tuple[jax.Array, int]:
+    """``q [B, T, Hkv * G, D] -> ([B, G * T, Hkv, D], G)``: folded row
+    ``g * T + t`` is query head ``h * G + g`` at time ``t``."""
+    B, T, H, D = q.shape
+    if H % kv_heads:
+        raise ValueError(f"{H} query heads do not group over {kv_heads} "
+                         f"k/v heads")
+    G = H // kv_heads
+    q = q.reshape(B, T, kv_heads, G, D).transpose(0, 3, 1, 2, 4)
+    return q.reshape(B, G * T, kv_heads, D), G
+
+
+def _unfold_groups(out: jax.Array, G: int) -> jax.Array:
+    """Inverse of :func:`_fold_groups` on the attention output."""
+    B, GT, Hkv, D = out.shape
+    out = out.reshape(B, G, GT // G, Hkv, D).transpose(0, 2, 3, 1, 4)
+    return out.reshape(B, GT // G, Hkv * G, D)
+
+
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     q_offset: int | jax.Array = 0,
@@ -35,16 +61,22 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     query/key — used by the blockwise and ring variants to apply a causal
     mask across blocks that live on different devices.
     """
+    G, Tq = 1, q.shape[1]
+    if k.shape[2] != q.shape[2]:
+        q, G = _fold_groups(q, k.shape[2])
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
-        q_pos = q_offset + jnp.arange(q.shape[1])
+        q_pos = q_offset + jnp.arange(Tq)
+        if G > 1:
+            q_pos = jnp.tile(q_pos, G)
         kv_pos = kv_offset + jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= kv_pos[None, :]
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    return out if G == 1 else _unfold_groups(out, G)
 
 
 def attention_block_combine(carry, q, k_blk, v_blk, mask):
@@ -92,13 +124,17 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if T % block_size != 0:
         raise ValueError(f"seq len {T} not divisible by block {block_size}")
     n_blocks = T // block_size
+    G, q_pos = 1, jnp.arange(T)
+    if k.shape[2] != H:
+        H = k.shape[2]
+        q, G = _fold_groups(q, H)
+        q_pos = jnp.tile(q_pos, G)
     k_blocks = k.reshape(B, n_blocks, block_size, H, D)
     v_blocks = v.reshape(B, n_blocks, block_size, H, D)
-    q_pos = jnp.arange(T)
 
-    o = jnp.zeros((B, H, T, D), jnp.float32)
-    m = jnp.full((B, H, T), _NEG_INF, jnp.float32)
-    l = jnp.zeros((B, H, T), jnp.float32)
+    o = jnp.zeros((B, H, G * T, D), jnp.float32)
+    m = jnp.full((B, H, G * T), _NEG_INF, jnp.float32)
+    l = jnp.zeros((B, H, G * T), jnp.float32)
 
     def scan_step(carry, blk):
         k_blk, v_blk, blk_idx = blk
@@ -106,7 +142,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if causal:
             mask = q_pos[:, None] >= kv_pos[None, :]
         else:
-            mask = jnp.ones((T, block_size), bool)
+            mask = jnp.ones((G * T, block_size), bool)
         return attention_block_combine(carry, q, k_blk, v_blk, mask), None
 
     (o, m, l), _ = jax.lax.scan(
@@ -114,4 +150,5 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         (jnp.moveaxis(k_blocks, 1, 0), jnp.moveaxis(v_blocks, 1, 0),
          jnp.arange(n_blocks)),
     )
-    return finalize_attention(o, l, q.dtype)
+    out = finalize_attention(o, l, q.dtype)
+    return out if G == 1 else _unfold_groups(out, G)

@@ -75,6 +75,20 @@ of 256 against 128 and 512 at (B*H, T, D) = (128, 1024, 64) and
 (64, 4096, 128) — 256 is the rule (``_SUB_TILE``); the 1024 default block
 was not re-swept (a finer grid pays ~0.35 us a step, 16 x the steps).
 
+Grouped-query attention: ``k`` / ``v`` may carry fewer heads than ``q``
+(``H = G * Hkv``; q head ``j`` reads k/v head ``j // G``). k and v reach
+the kernels as they are, ``[B * Hkv, T, D]`` — never repeated in HBM,
+forward or backward — and only the index maps change: in the forward and
+dq grids (one step a q head) the k/v blocks are those of flat head
+``b // G`` (``_kv_head``); the dk/dv grid has one row a K/V head and its
+innermost axis walks the ``G`` q heads of the group, all their q blocks one
+after another (``_group_step``: step ``i`` is q head ``b * G + i // nq``,
+q block ``i % nq``), so that dk and dv are summed over the group in the
+kernel's own accumulators and written once, while the k/v block stays
+where it is. With ``G == 1`` both helpers return their arguments: the
+index maps, grids and kernel bodies are those of plain multi-head
+attention.
+
 Numerics: scores/softmax in float32 regardless of input dtype; p (and ds)
 are cast to the operands' dtype for the second matmul, which accumulates
 in float32 (bf16 x bf16 -> f32 on the MXU). Outputs cast once to the
@@ -165,6 +179,19 @@ def _causal_mask(q_start, k_start, nq: int, nk: int,
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     return q_pos >= k_pos
+
+
+def _kv_head(b, group: int):
+    """Flat k/v head ``[B * Hkv]`` that flat q head ``b`` of ``[B * H]``
+    reads (``H = group * Hkv``, heads of one batch row adjacent)."""
+    return b if group == 1 else b // group
+
+
+def _group_step(b, i, group: int, nq: int):
+    """dk/dv grid: K/V head ``b``, innermost step ``i`` -> (flat q head,
+    q block). The axis holds ``group * nq`` steps: the q blocks of the
+    group's first q head, then its second's..."""
+    return (b, i) if group == 1 else (b * group + i // nq, i % nq)
 
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b^T
@@ -310,13 +337,18 @@ def _row_spec(block_q: int, index_map):
 
 @functools.lru_cache(maxsize=None)
 def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
-               sub: int | None, in_dtype_name: str, interpret: bool):
-    """Compile-cached pallas_call for a [BH, T, D] layout forward."""
+               sub: int | None, in_dtype_name: str, interpret: bool,
+               group: int = 1):
+    """Compile-cached pallas_call for a [BH, T, D] layout forward (k and v
+    ``[BH / group, T, D]``)."""
     one_block = T == block_q == block_kv
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv,
         sub=sub, one_block=one_block)
     grid = (None, T // block_q, T // block_kv)  # BH filled per call
+
+    def kv_block(b, i, j):
+        return (_kv_head(b, group), j, 0)
 
     def call(qr, kr, vr):
         bh = qr.shape[0]
@@ -326,8 +358,8 @@ def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
             grid=(bh,) + grid[1:],
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, block_kv, D), kv_block),
+                pl.BlockSpec((1, block_kv, D), kv_block),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -374,7 +406,7 @@ def _prescale_q(qr):
 def _fwd(q, k, v, causal, block_q, block_kv, sub, interpret):
     B, T, H, D = q.shape
     call = _build_fwd(T, D, causal, block_q, block_kv, sub, q.dtype.name,
-                      interpret)
+                      interpret, H // k.shape[2])
     out, lse_row = call(_prescale_q(_bthd_to_bht(q)), _bthd_to_bht(k),
                         _bthd_to_bht(v))
     return _bht_to_bthd(out, B, H), lse_row
@@ -429,10 +461,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
                 block_q: int, block_kv: int, sub: int | None,
-                one_block: bool):
-    iq = pl.program_id(2)
+                one_block: bool, group: int = 1, nq: int = 1):
+    step = pl.program_id(2)  # the group's q heads, nq q blocks each
+    iq = _group_step(0, step, group, nq)[1]
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -458,7 +491,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
               one_block, kv_major=True)
 
-    @pl.when(iq == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         # dk contracted ds against the PRE-SCALED q (scale * log2e folded
         # in), while true dk = scale * (ds^T @ q_unscaled) — so divide the
@@ -469,32 +502,46 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 @functools.lru_cache(maxsize=None)
 def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
-               sub: int | None, in_dtype_name: str, interpret: bool):
+               sub: int | None, in_dtype_name: str, interpret: bool,
+               group: int = 1):
     """Compile-cached backward pallas_calls over the [BH, T, D] layout:
     a dq pass (grid q-major, KV innermost) and a dk/dv pass (grid kv-major,
     Q innermost) — the standard two-pass flash backward, so neither pass
     needs atomics or cross-block communication. ``lse`` and ``delta``
-    arrive as lane-dense rows (``_row_spec``)."""
+    arrive as lane-dense rows (``_row_spec``). ``group`` q heads share a
+    K/V head: dq is per q head, dk/dv per K/V head, summed over the group
+    along the dk/dv grid's innermost axis."""
     dtype = jnp.dtype(in_dtype_name)
     scale = 1.0 / (D ** 0.5)
     one_block = T == block_q == block_kv
     static = dict(causal=causal, block_q=block_q, block_kv=block_kv, sub=sub,
                   one_block=one_block)
+    nq = T // block_q
     dq_kernel = functools.partial(_dq_kernel, scale=scale, **static)
-    dkv_kernel = functools.partial(_dkv_kernel, **static)
+    dkv_kernel = functools.partial(_dkv_kernel, group=group, nq=nq, **static)
     row_spec_q = _row_spec(block_q, lambda b, i, j: (b, i, 0, 0))
-    row_spec_kv_inner = _row_spec(block_q, lambda b, j, i: (b, i, 0, 0))
+
+    def kv_block(b, i, j):          # dq grid: q head b, kv block j
+        return (_kv_head(b, group), j, 0)
+
+    def q_block(b, j, i):           # dk/dv grid: K/V head b, step i
+        return (*_group_step(b, i, group, nq), 0)
+
+    def q_row(b, j, i):
+        return (*_group_step(b, i, group, nq), 0, 0)
+
+    row_spec_kv_inner = _row_spec(block_q, q_row)
 
     def call(qr, kr, vr, dor, lse, delta):
-        bh = qr.shape[0]
+        bh, bh_kv = qr.shape[0], kr.shape[0]
         dq_call = pl.pallas_call(
             dq_kernel,
             name=DQ_NAME,
             grid=(bh, T // block_q, T // block_kv),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, block_kv, D), kv_block),
+                pl.BlockSpec((1, block_kv, D), kv_block),
                 pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
                 row_spec_q,
                 row_spec_q,
@@ -510,12 +557,12 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
         dkv_call = pl.pallas_call(
             dkv_kernel,
             name=DKV_NAME,
-            grid=(bh, T // block_kv, T // block_q),
+            grid=(bh_kv, T // block_kv, group * nq),
             in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, D), q_block),
                 pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
                 pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, D), q_block),
                 row_spec_kv_inner,
                 row_spec_kv_inner,
             ],
@@ -524,8 +571,8 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                 pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, T, D), dtype),
-                jax.ShapeDtypeStruct((bh, T, D), dtype),
+                jax.ShapeDtypeStruct((bh_kv, T, D), dtype),
+                jax.ShapeDtypeStruct((bh_kv, T, D), dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_kv, D), jnp.float32),
@@ -548,11 +595,12 @@ def _bwd_pallas(q, k, v, out, lse_row, do, causal, block_q, block_kv, sub,
     of = _bthd_to_bht(out)
     delta = jnp.sum(dor.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1).reshape(lse_row.shape)      # rows, like lse
+    h_kv = k.shape[2]
     call = _build_bwd(T, D, causal, block_q, block_kv, sub, q.dtype.name,
-                      interpret)
+                      interpret, H // h_kv)
     dq, dk, dv = call(qr, kr, vr, dor, lse_row, delta)
-    return (_bht_to_bthd(dq, B, H), _bht_to_bthd(dk, B, H),
-            _bht_to_bthd(dv, B, H))
+    return (_bht_to_bthd(dq, B, H), _bht_to_bthd(dk, B, h_kv),
+            _bht_to_bthd(dv, B, h_kv))
 
 
 @functools.lru_cache(maxsize=None)
@@ -579,7 +627,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 1024,
                     block_kv: int = 1024,
                     interpret: bool = False) -> jax.Array:
-    """Fused attention on ``[B, T, H, D]`` via a Pallas TPU kernel.
+    """Fused attention on ``q [B, T, H, D]``, ``k`` / ``v``
+    ``[B, T, Hkv, D]`` (``Hkv`` divides ``H``; q head ``j`` reads k/v head
+    ``j // (H / Hkv)``) via a Pallas TPU kernel.
 
     Compiled by Mosaic, so it runs on a TPU backend only; off-TPU callers
     use :func:`relayrl_tpu.ops.attention.blockwise_attention` (what the
@@ -596,6 +646,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     pressure forces it (an interior step's score tile is
     block_q x block_kv f32).
     """
+    if k.shape != v.shape or q.shape[2] % k.shape[2]:
+        raise ValueError(f"q heads {q.shape[2]} do not group over k/v "
+                         f"{k.shape} / {v.shape}")
     block_q, block_kv, sub = tiling(q.shape[1], causal, block_q, block_kv)
     return _make_flash(causal, block_q, block_kv, sub,
                        bool(interpret))(q, k, v)

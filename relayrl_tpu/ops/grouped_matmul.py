@@ -27,21 +27,29 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _jit_tgmm
 _gmm = _jit_gmm.__wrapped__
 _tgmm = _jit_tgmm.__wrapped__
 
-# (m, k, n) tile. m = token-slots, so a group boundary costs at most one
-# partly masked tile of 512 rows per expert.
+# (m, k, n) tile, at most. m = token-slots, so a group boundary costs at
+# most one partly masked tile of 512 rows per expert. k and n take the
+# largest multiple of 128 up to the cap that divides them: 1024 for widths
+# of 1024 and 2048 (OLMoE's), 768 for LFM2's experts of 1536.
 TILING = (512, 1024, 1024)
+
+
+def _tile(dim: int, cap: int) -> int:
+    """Largest multiple of 128 up to ``cap`` that divides ``dim`` (0:
+    none — ``dim`` is no multiple of 128)."""
+    return next((t for t in range(min(cap, dim) // 128 * 128, 0, -128)
+                 if dim % t == 0), 0)
 
 
 def fits(m: int, k: int, n: int) -> bool:
     """Whether the kernels tile these shapes (else: ``lax.ragged_dot``)."""
     tm, tk, tn = TILING
-    return m % tm == 0 and k % min(tk, k) == 0 and n % min(tn, n) == 0 \
-        and k % 128 == 0 and n % 128 == 0
+    return m % tm == 0 and _tile(k, tk) > 0 and _tile(n, tn) > 0
 
 
 def _tiling(k: int, n: int):
     tm, tk, tn = TILING
-    return (tm, min(tk, k), min(tn, n))
+    return (tm, _tile(k, tk), _tile(n, tn))
 
 
 @jax.custom_vjp

@@ -480,7 +480,7 @@ class PolicyActor:
         self._cache = self.policy.init_cache(self._window.shape[0])
         if t > 0:
             self._cache = self._prefill_fn(self.params, self._cache,
-                                           self._window)
+                                           self._window, t)
         self._cache_version = self.version
 
     def reset_episode(self) -> None:

@@ -560,3 +560,54 @@ class TestBlockArch:
                                       same.evaluate(params, obs, act)[2])
         assert not np.allclose(base.evaluate(params, obs, act)[2],
                                other.evaluate(params, obs, act)[2])
+
+
+class TestGroupedQueryAttention:
+    """k/v with fewer heads than q: q head j reads k/v head j // G. The
+    anchor is the same op on k/v repeated G times along the head axis."""
+
+    @staticmethod
+    def _grouped(h_kv, seed=3):
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+        k, v = (jnp.asarray(rng.standard_normal((B, T, h_kv, D)),
+                            jnp.float32) for _ in range(2))
+        rep = lambda a: jnp.repeat(a, H // h_kv, axis=2)
+        return q, k, v, rep(k), rep(v)
+
+    @pytest.mark.parametrize("h_kv", [1, 2, 4])
+    @pytest.mark.parametrize("fn", ["dense", "blockwise"])
+    def test_matches_repeated_kv(self, fn, h_kv):
+        q, k, v, k_rep, v_rep = self._grouped(h_kv)
+        attn = dense_attention if fn == "dense" else (
+            lambda q, k, v: blockwise_attention(q, k, v, 8))
+        np.testing.assert_allclose(attn(q, k, v),
+                                   dense_attention(q, k_rep, v_rep),
+                                   atol=2e-6, rtol=2e-6)
+
+    @pytest.mark.parametrize("fn", ["dense", "blockwise"])
+    def test_grads_sum_over_the_group(self, fn):
+        q, k, v, k_rep, v_rep = self._grouped(2)
+        attn = dense_attention if fn == "dense" else (
+            lambda q, k, v: blockwise_attention(q, k, v, 8))
+        loss = lambda f: lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+        dq, dk, dv = jax.grad(loss(attn), (0, 1, 2))(q, k, v)
+        wq, wk, wv = jax.grad(loss(dense_attention), (0, 1, 2))(
+            q, k_rep, v_rep)
+        group = lambda a: a.reshape(B, T, 2, H // 2, D).sum(3)
+        np.testing.assert_allclose(dq, wq, atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(dk, group(wk), atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(dv, group(wv), atol=1e-5, rtol=1e-5)
+
+    def test_offset_row_reads_a_grouped_cache(self):
+        # the cached / readout modes: one query row at position 5 against
+        # a grouped k/v prefix
+        q, k, v, k_rep, v_rep = self._grouped(2)
+        row = dense_attention(q[:, 5:6], k, v, q_offset=5)
+        np.testing.assert_allclose(
+            row, dense_attention(q, k_rep, v_rep)[:, 5:6], atol=2e-6)
+
+    def test_heads_that_do_not_group_are_refused(self):
+        q, k, v, _, _ = self._grouped(2)
+        with pytest.raises(ValueError, match="do not group"):
+            dense_attention(q, k[:, :, :1].repeat(3, 2), v)

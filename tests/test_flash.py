@@ -287,3 +287,73 @@ def test_policy_records_the_score_area(monkeypatch, capsys):
     assert policy.attention_score_area_pct[(1, 16, "float32")] == 100.0
     assert ("T=32 head_dim=16 float32 -> flash_pallas, score area 62.5%"
             in capsys.readouterr().out)
+
+
+# -- grouped-query k/v: index maps only, k/v never repeated -------------------
+
+def _grouped_qkv(B, T, H, h_kv, D=8, seed=5):
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((B, T, h_kv, D)), jnp.float32)
+            for _ in range(2))
+    return q, k, v
+
+
+@pytest.mark.parametrize("B,T,H,h_kv,block", [
+    (2, 32, 4, 4, 16),     # group 1: plain multi-head
+    (1, 32, 8, 2, 16),     # group 4, several q blocks a head
+    (2, 32, 4, 1, 16),     # one k/v head for all (multi-query)
+    (2, 16, 4, 2, 16),     # one block a head: the stateless kernels
+])
+def test_grouped_flash_matches_dense(B, T, H, h_kv, block):
+    """Forward, dq, dk and dv of the grouped kernels in the interpreter
+    against dense attention on the same grouped k/v (which folds the group
+    into the query axis: another formulation altogether)."""
+    q, k, v = _grouped_qkv(B, T, H, h_kv)
+    fl = lambda q, k, v: flash_attention(q, k, v, block_q=block,
+                                         block_kv=block)
+    np.testing.assert_allclose(fl(q, k, v), dense_attention(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    loss = lambda f: lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+    got = jax.grad(loss(fl), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense_attention), (0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("T,block", [(32, 16), (16, 16)])
+def test_grouped_flash_is_the_plain_kernels_on_each_q_head(T, block):
+    """A q head of a group computes what it computes with its k/v head
+    given to it alone: the grouped forward and dq are BIT-equal to the
+    group-1 kernels on k/v repeated over the group; dk/dv are those
+    kernels' summed over the group (another order of the same sums)."""
+    q, k, v = _grouped_qkv(2, T, 4, 2)
+    rep = lambda a: jnp.repeat(a, 2, axis=2)
+    fl = lambda q, k, v: flash_attention(q, k, v, block_q=block,
+                                         block_kv=block)
+    np.testing.assert_array_equal(fl(q, k, v), fl(q, rep(k), rep(v)))
+    loss = lambda q, k, v: jnp.sum(jnp.sin(fl(q, k, v)))
+    dq, dk, dv = jax.grad(loss, (0, 1, 2))(q, k, v)
+    wq, wk, wv = jax.grad(loss, (0, 1, 2))(q, rep(k), rep(v))
+    np.testing.assert_array_equal(dq, wq)
+    group = lambda a: a.reshape(2, T, 2, 2, 8).sum(3)
+    np.testing.assert_allclose(dk, group(wk), atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(dv, group(wv), atol=1e-5, rtol=1e-5)
+
+
+def test_group_one_keeps_the_index_maps_it_had():
+    # at group 1 the helpers hand back their arguments: the same index
+    # maps, grids and kernel bodies trace as before k/v could be grouped
+    b, i = object(), object()
+    assert flash._kv_head(b, 1) is b
+    assert flash._group_step(b, i, 1, 7) == (b, i)
+    assert flash._kv_head(13, 4) == 3
+    assert flash._group_step(3, 9, 4, 4) == (14, 1)
+
+
+def test_grouped_flash_refuses_heads_that_do_not_group():
+    q, k, v = _grouped_qkv(1, 16, 4, 3)
+    with pytest.raises(ValueError, match="do not group"):
+        flash_attention(q, k, v, block_q=16, block_kv=16)

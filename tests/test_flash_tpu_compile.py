@@ -29,14 +29,18 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("shape,sub", [
-    ((8, 1024, 16, 64), 256),     # gpt2m-policy.update: one block a head
-    ((4, 4096, 16, 128), 256),    # olmoe-policy.update: 4 x 4 blocks a head
-    ((1, 1000, 2, 64), None),     # a default bucket: one tile a step
+@pytest.mark.parametrize("shape,sub,kv_heads", [
+    ((8, 1024, 16, 64), 256, 16),   # gpt2m-policy.update: one block a head
+    ((4, 4096, 16, 128), 256, 16),  # olmoe-policy.update: 4 x 4 blocks
+    ((1, 1000, 2, 64), None, 2),    # a default bucket: one tile a step
+    ((2, 8192, 32, 64), 256, 8),    # lfm2-policy.update: 4 q heads a k/v
+    ((1, 1024, 4, 64), 256, 1),     # one block a head, one k/v head
 ])
-def test_flash_kernels_compile_for_v5e(one_chip, shape, sub):
+def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads):
     assert flash.tiling(shape[1])[2] == sub
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct(shape[:2] + (kv_heads, shape[3]),
+                              jnp.bfloat16, sharding=one_chip)
 
     def value_and_grads(q, k, v):
         return jax.value_and_grad(
@@ -44,5 +48,8 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, sub):
                 flash.flash_attention(q, k, v).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 
-    text = jax.jit(value_and_grads).lower(x, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
+    compiled = jax.jit(value_and_grads).lower(x, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    # grouped k/v are never repeated: dk and dv come back at their heads
+    assert [o.shape for o in compiled.out_info[1]] == [
+        x.shape, kv.shape, kv.shape]

@@ -31,6 +31,22 @@ CACHED_ARCHS = {
     "olmoe_block": {**_MODERN, "kind": "transformer_moe_discrete",
                     "moe_experts": 4, "moe_top_k": 2, "moe_d_ff": 16,
                     "moe_norm_topk_prob": False},
+    # grouped-query heads alone: the cache holds 1 k/v head for 2 q heads
+    "grouped_query": {**_MODERN, "n_kv_heads": 1, "qk_norm": "head"},
+    # layers of several kinds (the LFM2 trunk): two kinds of state side by
+    # side — a (k, v) pair of the k/v heads for the attention layer, the
+    # last two rows of B * u for each conv layer — dense and expert FFNs
+    "lfm2_trunk": {**_MODERN, "kind": "transformer_moe_discrete",
+                   "n_layers": 4, "n_heads": 4, "n_kv_heads": 2,
+                   "qk_norm": "head", "rope_theta": 1e6,
+                   "layer_types": ["conv", "full_attention", "conv", "conv"],
+                   "moe_dense_layers": 1, "moe_experts": 8, "moe_top_k": 2,
+                   "moe_d_ff": 16, "moe_router": "sigmoid",
+                   "moe_expert_bias": True, "moe_held": [2, 3]},
+    # a conv layer LAST: the readout-row mode runs the operator on three
+    # rows and the FFN on one
+    "conv_last_dense": {**_MODERN, "n_layers": 2,
+                        "layer_types": ["full_attention", "conv"]},
 }
 
 
@@ -61,7 +77,8 @@ class TestStepCachedNumerics:
             np.testing.assert_allclose(float(aux_w["logp_a"]),
                                        float(aux_c["logp_a"]), atol=1e-4)
 
-    @pytest.mark.parametrize("name", ["rope", "olmoe_block"])
+    @pytest.mark.parametrize("name", ["rope", "olmoe_block", "lfm2_trunk",
+                                      "conv_last_dense"])
     def test_prefilled_cache_continues_as_the_stepped_one(self, name):
         # prefill rotates W keys at positions 0..W-1 in one dispatch; the
         # steps after it must read them as if they had been written one by
@@ -75,8 +92,10 @@ class TestStepCachedNumerics:
         for t in range(t0):
             _, _, stepped = policy.step_cached(
                 params, jax.random.PRNGKey(t), stepped, window[t], t)
+        # n_valid = t0: a conv layer's state has no positions to
+        # overwrite, it is taken from the rows before the real prefix's end
         filled = policy.prefill_cache(params, policy.init_cache(W),
-                                      jnp.asarray(window))
+                                      jnp.asarray(window), t0)
         obs = rng.standard_normal(6).astype(np.float32)
         key = jax.random.PRNGKey(9)
         a1, aux1, _ = policy.step_cached(params, key, stepped, obs, t0)
@@ -84,6 +103,31 @@ class TestStepCachedNumerics:
         assert int(a1) == int(a2)
         np.testing.assert_allclose(float(aux1["v"]), float(aux2["v"]),
                                    atol=1e-4)
+
+    def test_two_kinds_of_state_side_by_side(self):
+        policy, _ = _policy_params(**CACHED_ARCHS["lfm2_trunk"])
+        cache = policy.init_cache(8, batch_size=3)
+        assert [getattr(c, "shape", None) for c in cache] == [
+            (3, 2, 32), None, (3, 2, 32), (3, 2, 32)]
+        k, v = cache[1]
+        assert k.shape == v.shape == (3, 8, 2, 8)   # 2 k/v heads, not 4
+
+    @pytest.mark.parametrize("name", ["lfm2_trunk", "conv_last_dense",
+                                      "grouped_query"])
+    def test_full_forward_equals_the_stepped_rows(self, name):
+        # evaluate() over the whole sequence against step_cached row by row
+        policy, params = _policy_params(**CACHED_ARCHS[name])
+        rng = np.random.default_rng(11)
+        W = 8
+        window = rng.standard_normal((W, 6)).astype(np.float32)
+        _logp, _ent, v_full = policy.evaluate(
+            params, jnp.asarray(window)[None], jnp.zeros((1, W), jnp.int32))
+        cache = policy.init_cache(W)
+        for t in range(W):
+            _, aux, cache = policy.step_cached(
+                params, jax.random.PRNGKey(t), cache, window[t], t)
+            np.testing.assert_allclose(float(aux["v"]), float(v_full[0, t]),
+                                       atol=1e-4, err_msg=f"t={t}")
 
     def test_moe_family_has_cache(self):
         moe = build_policy({**ARCH, "kind": "transformer_moe_discrete",

@@ -346,3 +346,160 @@ class TestUtilizationMonitor:
             np.testing.assert_allclose(float(frac.sum()), 1.0, atol=1e-5)
             # near-uniform at init: no expert should be collapsed-out
             assert float(frac.max()) < 0.9, (layer, frac)
+
+
+# -- the sigmoid router and the layer that is told which experts it holds ----
+
+def _held_layer(held, dispatch="sparse", e=8, k=2, bias=True):
+    from relayrl_tpu.models.moe import MoEMLP
+
+    return MoEMLP(_D, _FF, e, k, jnp.float32, norm_topk_prob=True,
+                  ffn="swiglu", dispatch=dispatch, use_bias=False,
+                  router="sigmoid", expert_bias=bias, held=held)
+
+
+def _held_params(e=8, k=2, seed=0):
+    """The whole layer's parameters (every expert held) and tokens."""
+    x = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (2, _N // 2, _D)), jnp.float32)
+    params = _held_layer(None, e=e, k=k).init(jax.random.PRNGKey(seed), x)
+    return jax.tree_util.tree_map(lambda a: a, params), x
+
+
+def _share_of(params, first, count):
+    """One chip's parameters of the whole layer's: its slice of the expert
+    stacks; the router and its bias whole."""
+    p = dict(params["params"])
+    for name in ("moe_w_gate", "moe_w_up", "moe_w_down"):
+        p[name] = p[name][first:first + count]
+    return {"params": p}
+
+
+class TestSigmoidRouter:
+    @pytest.mark.parametrize("norm", [False, True])
+    @pytest.mark.parametrize("k", [2, 3])
+    def test_weights_are_the_unbiased_scores_of_the_biased_choice(
+            self, norm, k):
+        from relayrl_tpu.models.moe import route
+
+        rng = np.random.default_rng(0)
+        logits = jnp.asarray(rng.standard_normal((6, 8)), jnp.float32)
+        bias = jnp.asarray(rng.standard_normal(8) * 0.5, jnp.float32)
+        w, idx = route(logits, k, norm, "sigmoid", bias)
+        s = np.asarray(jax.nn.sigmoid(logits))
+        want_idx = np.argsort(-(s + np.asarray(bias)), -1)[:, :k]
+        assert (np.sort(np.asarray(idx), -1) == np.sort(want_idx, -1)).all()
+        picked = np.take_along_axis(s, np.asarray(idx), -1)
+        if norm:
+            picked = picked / (picked.sum(-1, keepdims=True) + 1e-6)
+        np.testing.assert_allclose(w, picked, atol=1e-6)
+        # the bias moved a choice somewhere, and no weight
+        assert (np.sort(np.argsort(-s, -1)[:, :k], -1)
+                != np.sort(want_idx, -1)).any()
+
+    def test_bias_gets_no_gradient(self):
+        params, x = _held_params()
+        g = jax.grad(lambda p: jnp.sum(jnp.sin(
+            _held_layer(None).apply(p, x))))(params)
+        assert float(jnp.abs(params["params"]["moe_expert_bias"]).max()) > 0
+        assert float(jnp.abs(g["params"]["moe_expert_bias"]).max()) == 0.0
+        assert float(jnp.abs(g["params"]["moe_gate"]["kernel"]).max()) > 0
+
+    def test_unknown_router_refused(self):
+        with pytest.raises(ValueError, match="moe_router"):
+            _policy_params(moe_router="tanh")
+
+
+class TestHeldExperts:
+    @pytest.mark.parametrize("first,count", [(0, 2), (2, 4), (5, 3), (0, 8)])
+    def test_sparse_matches_dense_forward_and_every_gradient(self, first,
+                                                             count):
+        """The held layer's sparse dispatch (absent slots sorted behind,
+        the tail masked, the experts recomputed in the backward) against
+        its dense form (the held columns of the [N, E] weight mask)."""
+        params, x = _held_params()
+        share = _share_of(params, first, count)
+
+        def loss(dispatch):
+            def f(p, x):
+                y = _held_layer((first, count), dispatch).apply(p, x)
+                return jnp.sum(jnp.sin(y) * x), y
+            return f
+
+        (ls, ys), gs = jax.value_and_grad(
+            loss("sparse"), (0, 1), has_aux=True)(share, x)
+        (ld, yd), gd = jax.value_and_grad(
+            loss("dense"), (0, 1), has_aux=True)(share, x)
+        np.testing.assert_allclose(ys, yd, atol=2e-5, rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+
+    @pytest.mark.parametrize("chips", [1, 2, 4, 8])
+    def test_the_shares_add_up_to_the_layer(self, chips):
+        # the router normalises over the k chosen of ALL experts, held or
+        # not, so the chips' partial outputs sum to the whole layer's
+        params, x = _held_params()
+        whole = _held_layer(None).apply(params, x)
+        count = 8 // chips
+        parts = sum(_held_layer((c * count, count)).apply(
+            _share_of(params, c * count, count), x) for c in range(chips))
+        np.testing.assert_allclose(parts, whole, atol=2e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("dispatch", DISPATCHES)
+    def test_every_token_routed_to_held_experts_drops_nothing(self,
+                                                              dispatch):
+        # a bias that sends every token to experts 2 and 3: the layer that
+        # holds exactly those computes the whole layer, all N*k slots
+        params, x = _held_params()
+        bias = np.full(8, -50.0, np.float32)
+        bias[2:4] = 50.0
+        params["params"]["moe_expert_bias"] = jnp.asarray(bias)
+        whole = _held_layer(None).apply(params, x)
+        y, state = _held_layer((2, 2), dispatch).apply(
+            _share_of(params, 2, 2), x, mutable=["intermediates"])
+        np.testing.assert_allclose(y, whole, atol=2e-5, rtol=1e-5)
+        load = np.asarray(state["intermediates"]["expert_load"][0])
+        assert load.tolist() == [_N, _N]
+        # and the layer that holds none of the chosen adds exactly nothing
+        none = _held_layer((4, 4), dispatch).apply(
+            _share_of(params, 4, 4), x)
+        assert float(jnp.abs(none).max()) == 0.0
+
+    def test_update_stats_count_the_held_slots(self):
+        policy, params = _policy_params(
+            moe_experts=8, moe_top_k=2, moe_router="sigmoid",
+            moe_expert_bias=True, moe_held=[2, 3], moe_dense_layers=1,
+            n_layers=3)
+        assert "moe" not in params["params"]["block_0"]
+        assert params["params"]["block_1"]["moe"]["moe_w_up"].shape[0] == 3
+        obs = jnp.asarray(np.random.default_rng(2).standard_normal(
+            (2, 8, 6)), jnp.float32)
+        *_, stats = policy.evaluate_stats(params, obs,
+                                          jnp.zeros((2, 8), jnp.int32))
+        from relayrl_tpu.models.moe import expert_utilization
+
+        util = expert_utilization(policy.arch, params, obs)
+        assert sorted(util) == ["block_1", "block_2"]
+        held = sum(float(u.sum()) for u in util.values()) * 16 * 2
+        np.testing.assert_allclose(float(stats["moe_held_slots"]), held,
+                                   rtol=1e-6)
+        # shares of ALL the slots: the held experts' do not sum to 1
+        assert all(float(u.sum()) < 1.0 and u.shape == (3,)
+                   for u in util.values())
+        np.testing.assert_allclose(
+            float(stats["moe_load_max"]),
+            max(float(u.max()) for u in util.values()), rtol=1e-6)
+
+    def test_a_range_outside_the_experts_is_refused(self):
+        with pytest.raises(ValueError, match="moe_held"):
+            _policy_params(moe_experts=4, moe_held=[2, 3])
+
+    def test_the_pipeline_family_refuses_what_it_cannot_build(self):
+        for key, value in (("layer_types", ["conv", "conv"]),
+                           ("n_kv_heads", 1), ("moe_held", [0, 1])):
+            with pytest.raises(ValueError, match="transformer_pp_discrete"):
+                build_policy({**ARCH, "kind": "transformer_pp_discrete",
+                              key: value})
