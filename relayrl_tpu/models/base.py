@@ -134,6 +134,11 @@ class Policy:
     # tiling (``ops.flash.score_area_pct``; a causal kernel that skipped
     # everything above the diagonal would read 50 + 50 / T).
     attention_score_area_pct: Mapping[tuple, float] | None = None
+    # ...and the operand layout they ran in (``ops.flash.lane_layout``):
+    # ``"2 heads a step"`` of the projections' own ``[B, T, H * D]``
+    # (head_dim 64), or ``"head-major"`` (``[B * H, T, D]``, the head axis
+    # transposed out of the lanes round the kernels).
+    attention_layout: Mapping[tuple, str] | None = None
     # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
     # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward
     # (``moe_load_max`` / ``moe_load_min``: models/moe.load_extremes) for

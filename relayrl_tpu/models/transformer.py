@@ -70,9 +70,10 @@ from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
 
 
 def _resolve_attention(arch: Mapping[str, Any]
-                       ) -> tuple[Callable, dict, dict]:
-    """Arch config -> ``(attn_fn, resolved, score_area)``: the [B,T,H,D]x3
-    -> [B,T,H,D] attention callable, and the records of what it ran as.
+                       ) -> tuple[Callable, dict, dict, dict]:
+    """Arch config -> ``(attn_fn, resolved, score_area, layout)``: the
+    [B,T,H,D]x3 -> [B,T,H,D] attention callable, and the records of what
+    it ran as.
 
     ``"flash"`` and ``"ring"`` pick their implementation at trace time
     from the platform, the sequence length and the ambient mesh, so the
@@ -85,15 +86,20 @@ def _resolve_attention(arch: Mapping[str, Any]
     ``score_area`` (``Policy.attention_score_area_pct``) maps the shapes
     that run the Pallas flash kernels to the share of the T x T score
     matrix those compute (``ops.flash.score_area_pct``: how far the causal
-    skip engages at that shape's tiling); the line says it too.
+    skip engages at that shape's tiling), and ``layout``
+    (``Policy.attention_layout``) to the operand layout they ran in
+    (``ops.flash.lane_layout``: ``"2 heads a step"`` of the projections'
+    own ``[B, T, H * D]``, or ``"head-major"`` where the head axis is
+    transposed out of the lanes); the line says both.
     """
     kind = arch.get("attention", "dense")
     block = int(arch.get("attention_block", 128))
     resolved: dict[tuple[int, int, str], str] = {}
     score_area: dict[tuple[int, int, str], float] = {}
+    layout: dict[tuple[int, int, str], str] = {}
 
     def ran(q, backend: str, area_pct: float | None = None,
-            k=None) -> None:
+            k=None, heads_a_step: int | None = None) -> None:
         key = (int(q.shape[1]), int(q.shape[3]), q.dtype.name)
         if resolved.get(key) != backend:
             resolved[key] = backend
@@ -103,7 +109,10 @@ def _resolve_attention(arch: Mapping[str, Any]
                 heads = f" heads {q.shape[2]}/{k.shape[2]}"
             if area_pct is not None:
                 score_area[key] = area_pct
-                area = f", score area {area_pct:g}%"
+                layout[key] = ("head-major" if heads_a_step is None
+                               else f"{heads_a_step} heads a step")
+                area = (f", score area {area_pct:g}%, "
+                        f"layout {layout[key]}")
             if kind in ("flash", "ring"):
                 print(f"[attention] {kind!r} T={key[0]} head_dim={key[1]} "
                       f"{key[2]}{heads} -> {backend}{area} "
@@ -122,9 +131,9 @@ def _resolve_attention(arch: Mapping[str, Any]
         return (blockwise if q.shape[1] % block == 0 else dense)(q, k, v)
 
     if kind == "dense":
-        return dense, resolved, score_area
+        return dense, resolved, score_area, layout
     if kind == "blockwise":
-        return blockwise, resolved, score_area
+        return blockwise, resolved, score_area, layout
     if kind == "flash":
         def flash_or_local(q, k, v):
             # Pallas kernel on TPU; off-TPU (CPU actor hosts, CI) the same
@@ -141,11 +150,12 @@ def _resolve_attention(arch: Mapping[str, Any]
             fblock = int(arch.get("flash_block", 1024))
             if jax.default_backend() == "tpu" and T % min(fblock, T) == 0:
                 ran(q, "flash_pallas", flash.score_area_pct(
-                    T, *flash.tiling(T, True, fblock, fblock), True), k)
+                    T, *flash.tiling(T, True, fblock, fblock), True), k,
+                    flash.lane_layout(q.shape[2], k.shape[2], q.shape[3]))
                 return flash.flash_attention(q, k, v, causal=True,
                                              block_q=fblock, block_kv=fblock)
             return local(q, k, v)
-        return flash_or_local, resolved, score_area
+        return flash_or_local, resolved, score_area, layout
     if kind == "ring":
         def ring_or_local(q, k, v):
             from relayrl_tpu.parallel.context import current_mesh
@@ -173,7 +183,7 @@ def _resolve_attention(arch: Mapping[str, Any]
                 return make_ring_flash_attention(mesh)(q, k, v)
             ran(q, "ring_scan")
             return make_ring_attention(mesh)(q, k, v)
-        return ring_or_local, resolved, score_area
+        return ring_or_local, resolved, score_area, layout
     raise ValueError(f"unknown attention kind {kind!r}")
 
 
@@ -740,7 +750,8 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
 
 def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     obs_dim = int(arch["obs_dim"])
-    attn_fn, attention_backends, score_area = _resolve_attention(arch)
+    attn_fn, attention_backends, score_area, attn_layout = (
+        _resolve_attention(arch))
     core = _make_core(arch, moe_experts, attn_fn)
 
     def init_params(rng):
@@ -835,6 +846,7 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                        prefill_cache=prefill_cache,
                        attention_backends=attention_backends,
                        attention_score_area_pct=score_area,
+                       attention_layout=attn_layout,
                        evaluate_stats=evaluate_stats)
 
 
@@ -905,7 +917,8 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
     d_model = int(arch.get("d_model", 128))
     n_layers = int(arch.get("n_layers", 2))
     n_micro = arch.get("pp_microbatches")
-    attn_fn, attention_backends, score_area = _resolve_attention(arch)
+    attn_fn, attention_backends, score_area, attn_layout = (
+        _resolve_attention(arch))
     block = TransformerBlock(
         d_model, int(arch.get("n_heads", 4)), int(arch.get("mlp_ratio", 4)),
         attn_fn, _compute_dtype(arch))
@@ -950,4 +963,5 @@ def build_transformer_pp_discrete(arch: Mapping[str, Any]) -> Policy:
 
     return _dc.replace(_policy_from_apply(arch, init_params, apply_fn),
                        attention_backends=attention_backends,
-                       attention_score_area_pct=score_area)
+                       attention_score_area_pct=score_area,
+                       attention_layout=attn_layout)

@@ -9,7 +9,32 @@ the same op: one fused Pallas kernel that keeps the running-softmax state
 score matrix never materializes in HBM and the two matmuls per block hit
 the MXU back-to-back.
 
-Grid layout: ``(B*H, num_q_blocks, num_kv_blocks)`` with the KV axis
+Operand layout at head_dim 64: q, k, v, do, out come in and out, dq, dk,
+dv go out as the projections left them, ``[B, T, H * D]`` with the heads
+side by side in the lanes (a free reshape of the block's ``[B, T, H, D]``),
+in blocks ``(1, block, 128)``: TWO heads a grid step — heads ``2c`` and
+``2c + 1`` at lane block ``c``. No head transpose exists round the
+kernels, every block is lane-dense (a ``[BH, T, 64]`` operand is half
+padding in tiled memory, in HBM and in the DMA) and a call has half the
+grid steps. Two heads share a step without a lane shuffle: head ``h``'s
+scores are ``(q * lanes_h) @ k^T``, contracted over all 128 lanes of
+which the other head's 64 are exact zeros (the MXU passes a 64-deep
+contraction pays for anyway); ``p_h @ v`` gives 128 lanes of which head
+``h``'s 64 are its output, and the row strip is written as
+``where(lane < 64, acc_0 / l_0, acc_1 / l_1)``. The backward the same way:
+``ds_h @ k`` keeps one half, and ``p_h^T @ (do * lanes_h)``,
+``ds_h^T @ (q * lanes_h)`` land in head ``h``'s lanes of dv and dk with
+zeros beside them, so the heads' sums do not mix. Every added product is
+``x * 0``: forward and gradients are the numbers of one head a step.
+``lane_layout`` reads the layout off the operands' shape. Everywhere else
+(head_dim 128, where a head-major operand is lane-dense already and the
+lane blocks measured slower; ``Hkv * D`` no multiple of 128; an odd group)
+the operands are turned head-major, ``[B * H, T, D]`` — one row a head,
+one lane block a row — and the same kernels run one head a step.
+
+Grid layout: ``(steps, num_q_blocks, num_kv_blocks)``, ``steps = B * H /
+heads a step`` (batch row and lane block from the flat index,
+``_lane_block``), with the KV axis
 innermost — TPU grids execute sequentially, so scratch initialized at
 ``kv == 0`` and finalized at ``kv == last`` implements the flash
 recurrence without inter-kernel communication. Causal blocks strictly
@@ -41,20 +66,27 @@ O(T * block). The dk/dv pass works in transposed space — scores as
 nothing of score-tile size goes through a transpose.
 
 The per-query float32 residuals (LSE out of the forward, delta =
-rowsum(do * o)) live in HBM as lane-dense rows
-``[BH, num_q_blocks, 1, block_q]``: as ``[BH, T, 1]`` columns the same
-numbers take 128 x their bytes in tiled memory — in every kernel's DMA,
-in XLA's relayout of delta (3 ms an update in ``gpt2m-policy.update``) and
-in the residuals a training step keeps (1.6 GB there). The dk/dv pass
+rowsum(do * o) a head) live in HBM as lane-dense rows
+``[BH, num_q_blocks, 1, block_q]``, one row a head, the heads of a grid
+step adjacent: as ``[BH, T, 1]`` columns the same numbers take 128 x
+their bytes in tiled memory — in every kernel's DMA and in the residuals a
+training step keeps (1.6 GB in ``gpt2m-policy.update``). The dk/dv pass
 reads the rows as they are; the forward and dq kernels, which need them
-down the sublanes, turn a row in VMEM.
+down the sublanes, turn a row in VMEM. **delta is the dq kernel's own**:
+it holds the do block already, takes the out block beside it, sums each
+head's lanes of ``do * o`` into the column it subtracts, and writes the
+same numbers out as rows for the dk/dv pass — XLA never sees a
+``[B, T, H, D]``-shaped reduction (in the lane layout it turned the
+float32 product T-minor to make it: 0.78 ms of copy and 2.2 ms of
+convert-multiply an update in ``gpt2m-policy.update``, PERF.md §6 PR 32).
 
-VPU economy (at head_dim 64 the two block matmuls only half-fill the MXU
-contraction depth, so the score-tile softmax traffic sits on the critical
-path):
+VPU economy (at head_dim 64 the contraction is 128 deep with half of it
+zeros — the other head's lanes — so the two block matmuls do half the
+useful work of an MXU pass and the score-tile softmax traffic sits on the
+critical path):
 
 * **log2-space softmax**: ``1/sqrt(D) * log2(e)`` is folded into q OUTSIDE
-  the kernel (one fused elementwise on the [BH, T, D] operand, 16x fewer
+  the kernel (one fused elementwise on the operand, 16x fewer
   multiplies than scaling every [block_q, block_kv] score tile), so the
   in-kernel recurrence uses ``exp2`` — faster than ``exp`` on the VPU —
   and the saved residual is the log2-space LSE. The backward finalizers
@@ -77,17 +109,32 @@ was not re-swept (a finer grid pays ~0.35 us a step, 16 x the steps).
 
 Grouped-query attention: ``k`` / ``v`` may carry fewer heads than ``q``
 (``H = G * Hkv``; q head ``j`` reads k/v head ``j // G``). k and v reach
-the kernels as they are, ``[B * Hkv, T, D]`` — never repeated in HBM,
-forward or backward — and only the index maps change: in the forward and
-dq grids (one step a q head) the k/v blocks are those of flat head
-``b // G`` (``_kv_head``); the dk/dv grid has one row a K/V head and its
-innermost axis walks the ``G`` q heads of the group, all their q blocks one
-after another (``_group_step``: step ``i`` is q head ``b * G + i // nq``,
-q block ``i % nq``), so that dk and dv are summed over the group in the
-kernel's own accumulators and written once, while the k/v block stays
-where it is. With ``G == 1`` both helpers return their arguments: the
-index maps, grids and kernel bodies are those of plain multi-head
-attention.
+the kernels as they are — never repeated in HBM, forward or backward — and
+only the index maps change: in the forward and dq grids (one step a q
+head, or a pair) the k/v blocks are those of flat step ``g // G``
+(``_kv_head``); the dk/dv grid has one row a k/v step and its innermost
+axis walks the ``G`` q steps of the group, all their q blocks one after
+another (``_group_step``: step ``i`` is q step ``g * G + i // nq``, q block
+``i % nq``), so that dk and dv are summed over the group in the kernel's
+own accumulators and written once, while the k/v block stays where it is.
+With ``G == 1`` both helpers return their arguments: the index maps, grids
+and kernel bodies are those of plain multi-head attention. Two heads a
+step and grouped (head_dim 64, ``G`` even): the q pair shares ONE k/v head,
+which sits in one half of a 128-lane k/v block; ``_shared_kv`` copies that
+half into both (a lane roll by 64 in VMEM, once a grid step) so that each
+q head finds it in its own lanes, and the dk/dv kernel keeps one
+accumulator a k/v head of the block, whose two halves — the sums over the
+even and the odd q heads — are added at the end.
+
+The kernels' trace is shared by the repeats of a call (``_make_flash``). A
+``pallas_call`` traces its body to a jaxpr and lowers it to Mosaic every
+time it is called, two heads a step about doubles a body, and a trunk makes
+the same three calls a layer: the first attention call of a trace runs the
+builders' calls bare and every repeat in that trace runs them through one
+inner ``jit`` each, so that a 24-layer trunk traces and lowers two bodies a
+kernel, not 24, and a model with one attention layer never meets the inner
+``jit`` — it would share nothing there and only move the stack depth at
+which the lowering runs (PERF.md §6, PR 33, has what that cost, by phase).
 
 Numerics: scores/softmax in float32 regardless of input dtype; p (and ds)
 are cast to the operands' dtype for the second matmul, which accumulates
@@ -198,7 +245,62 @@ _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
-def _scores2(q_ref, k_ref, rows, cols, mask, transposed: bool = False):
+def _head_lanes(hps: int, D: int):
+    """One entry a head of a grid step: the head's ``D`` lanes of the
+    step's ``hps * D``-lane blocks as a bool ``[1, hps * D]`` mask. One
+    head a step owns the whole block: ``[None]``, and the kernel bodies
+    are those of the head-major layout."""
+    if hps == 1:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, hps * D), 1)
+    return [(lane >= h * D) & (lane < (h + 1) * D) for h in range(hps)]
+
+
+def _head_rows(ref, rows, lanes):
+    """Rows of a block with every lane but one head's zeroed: a matmul
+    that contracts them over all the block's lanes contracts over that
+    head's, and one that takes them as its right operand leaves the other
+    heads' output lanes exact zeros."""
+    x = ref[0, rows, :]
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _by_head(parts, heads):
+    """A block's lanes from its heads' results: head ``h``'s lanes of
+    ``parts[h]`` (each as wide as the block, or a column a row)."""
+    out = parts[-1]
+    for part, lanes in zip(parts[-2::-1], heads[-2::-1]):
+        out = jnp.where(lanes, part, out)
+    return out
+
+
+def _shared_kv(k_ref, v_ref, k2_ref, v2_ref, half, D: int):
+    """Two q heads a step over ONE k/v head (grouped-query heads at
+    head_dim 64): the k/v block holds two k/v heads and the step's q heads
+    both read its head ``half``. Copy that head into both halves of the
+    block (a lane roll by ``D`` in VMEM, once a grid step), so that each q
+    head finds its k/v head in its own lanes; returns the refs to read."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * D), 1)
+    own = (lane >= D) == (half == 1)
+    for src, dst in ((k_ref, k2_ref), (v_ref, v2_ref)):
+        x = src[0].astype(jnp.float32)   # 32-bit lanes: the roll's own width
+        dst[0] = jnp.where(own, x, pltpu.roll(x, D, 1)).astype(dst.dtype)
+    return k2_ref, v2_ref
+
+
+def _shares_kv(hps: int, group: int) -> bool:
+    """The q heads of a grid step read one k/v head (``_shared_kv``)."""
+    return hps > 1 and group > 1
+
+
+def _kv_half(g, group: int, hps: int):
+    """Which head of its k/v block q step ``g`` reads (``_shared_kv``):
+    ``group // hps`` steps share a k/v head."""
+    return (g // (group // hps)) % hps
+
+
+def _scores2(q_ref, k_ref, rows, cols, mask, transposed: bool = False,
+             lanes=None):
     """Log2-space scores of query rows ``rows`` against key rows ``cols``
     of the current block pair (``[keys, queries]`` when transposed) — the
     recompute shared by the forward and both backward kernels. q arrives
@@ -206,8 +308,10 @@ def _scores2(q_ref, k_ref, rows, cols, mask, transposed: bool = False):
     Inputs stay in their storage dtype (bf16 in production): the MXU runs
     bf16 x bf16 -> f32 at full rate, while casting to f32 first would
     quarter the matmul throughput; softmax math stays f32. ``mask`` is
-    None on the mask-free path."""
-    q, k = q_ref[0, rows, :], k_ref[0, cols, :]
+    None on the mask-free path. ``lanes``: the head of the step (see
+    ``_head_lanes``) — the other heads' q lanes enter the contraction as
+    zeros."""
+    q, k = _head_rows(q_ref, rows, lanes), k_ref[0, cols, :]
     s = jax.lax.dot_general(*((k, q) if transposed else (q, k)), _NT,
                             preferred_element_type=jnp.float32)
     return s if mask is None else jnp.where(mask, s, _NEG_INF)
@@ -266,24 +370,36 @@ def _dispatch(tile, q_start, k_start, causal: bool, block_q: int,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, causal: bool,
                 block_q: int, block_kv: int, sub: int | None,
-                one_block: bool):
+                one_block: bool, hps: int, D: int, group: int):
     q_start = pl.program_id(1) * block_q
     k_start = pl.program_id(2) * block_kv
+    heads = _head_lanes(hps, D)
+    if _shares_kv(hps, group):
+        k_ref, v_ref = _shared_kv(
+            k_ref, v_ref, *scratch[:2],
+            _kv_half(pl.program_id(0), group, hps), D)
+        scratch = scratch[2:]
+
+    def pv(p, cols):
+        # every lane of the block; head h's D lanes are p_h @ v_h
+        return jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, cols, :], _NN,
+            preferred_element_type=jnp.float32)
 
     if one_block:
         # One block a head: a tile is all its query rows ever see, so the
         # softmax needs no carried state — the same arithmetic as the
         # recurrence below from its initial state, written out directly.
         def tile(rows, cols, mask):
-            s = _scores2(q_ref, k_ref, rows, cols, mask)
-            m = jnp.max(s, axis=-1, keepdims=True)
-            p = jnp.exp2(s - m)
-            l = jnp.sum(p, axis=-1, keepdims=True)
-            acc = jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, cols, :], _NN,
-                preferred_element_type=jnp.float32)
-            o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
-            lse_ref[0, 0, :, rows] = (m + jnp.log2(l)).T
+            outs = []
+            for h, lanes in enumerate(heads):
+                s = _scores2(q_ref, k_ref, rows, cols, mask, lanes=lanes)
+                m = jnp.max(s, axis=-1, keepdims=True)
+                p = jnp.exp2(s - m)
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                outs.append(pv(p, cols) / l)
+                lse_ref[h, 0, :, rows] = (m + jnp.log2(l)).T
+            o_ref[0, rows, :] = _by_head(outs, heads).astype(o_ref.dtype)
 
         _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
                   one_block)
@@ -299,82 +415,109 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, causal: bool,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def tile(rows, cols, mask):
-        s = _scores2(q_ref, k_ref, rows, cols, mask)
-        m_prev = m_ref[rows]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # Masked entries carry s == _NEG_INF; with KV innermost, block
-        # ik == 0 is fully live, so m_new is finite for every valid row
-        # and exp2(_NEG_INF - m_new) flushes to exactly 0.
-        p = jnp.exp2(s - m_new)
-        corr = jnp.exp2(m_prev - m_new)
-        l_ref[rows] = l_ref[rows] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, cols, :], _NN,
-            preferred_element_type=jnp.float32)
-        m_ref[rows] = m_new
+        corrs, pvs = [], []
+        for h, lanes in enumerate(heads):
+            s = _scores2(q_ref, k_ref, rows, cols, mask, lanes=lanes)
+            m_prev = m_ref[h, rows]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # Masked entries carry s == _NEG_INF; with KV innermost, block
+            # ik == 0 is fully live, so m_new is finite for every valid row
+            # and exp2(_NEG_INF - m_new) flushes to exactly 0.
+            p = jnp.exp2(s - m_new)
+            corr = jnp.exp2(m_prev - m_new)
+            l_ref[h, rows] = (l_ref[h, rows] * corr
+                              + jnp.sum(p, axis=-1, keepdims=True))
+            m_ref[h, rows] = m_new
+            corrs.append(corr)
+            pvs.append(pv(p, cols))
+        acc_ref[rows] = (acc_ref[rows] * _by_head(corrs, heads)
+                         + _by_head(pvs, heads))
 
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
               one_block)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        ls = [jnp.maximum(l_ref[h], 1e-30) for h in range(hps)]
+        o_ref[0] = (acc_ref[:] / _by_head(ls, heads)).astype(o_ref.dtype)
         # log2-space LSE — the backward recomputes p = exp2(s2 - lse2).
-        # Stored as a lane-dense row (see _build_fwd).
-        lse_ref[0, 0] = (m_ref[:] + jnp.log2(l)).T
+        # Stored as lane-dense rows, one a head (see _build_fwd).
+        for h, l in enumerate(ls):
+            lse_ref[h, 0] = (m_ref[h] + jnp.log2(l)).T
 
 
-def _row_spec(block_q: int, index_map):
+def _row_spec(block_q: int, index_map, hps: int = 1):
     """Block of a per-query float32 residual (LSE, delta) stored as
     lane-dense rows ``[BH, num_q_blocks, 1, block_q]``: one ``[1, block_q]``
-    row a q block (the two trailing dims are whole, so any block_q tiles).
-    As a ``[BH, T, 1]`` column the same numbers take 128 x their bytes in
-    tiled HBM (module docstring). The forward and dq kernels, which need
-    them down the sublanes, turn a row in VMEM (``.T``)."""
-    return pl.BlockSpec((1, 1, 1, block_q), index_map)
+    row a head and q block (the two trailing dims are whole, so any block_q
+    tiles), the ``hps`` heads of a grid step adjacent. As a ``[BH, T, 1]``
+    column the same numbers take 128 x their bytes in tiled HBM (module
+    docstring). The forward and dq kernels, which need them down the
+    sublanes, turn a row in VMEM (``.T``)."""
+    return pl.BlockSpec((hps, 1, 1, block_q), index_map)
+
+
+def _lane_block(g, nlb: int):
+    """Grid step ``g`` -> (row, lane block) of a ``[rows, T, nlb * w]``
+    operand, the lane blocks of a row adjacent steps. Head-major operands
+    have one lane block a row: the step is the row."""
+    return (g, 0) if nlb == 1 else (g // nlb, g % nlb)
 
 
 @functools.lru_cache(maxsize=None)
 def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                sub: int | None, in_dtype_name: str, interpret: bool,
-               group: int = 1):
-    """Compile-cached pallas_call for a [BH, T, D] layout forward (k and v
-    ``[BH / group, T, D]``)."""
+               group: int = 1, hps: int = 1):
+    """Compile-cached pallas_call for a forward over ``[rows, T, lanes]``
+    operands in blocks ``hps * D`` lanes wide: head-major ``[BH, T, D]``
+    (k and v ``[BH / group, T, D]``), or the projections' own
+    ``[B, T, H * D]`` with ``hps`` heads a grid step (``lane_layout``)."""
     one_block = T == block_q == block_kv
+    w = hps * D
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv,
-        sub=sub, one_block=one_block)
-    grid = (None, T // block_q, T // block_kv)  # BH filled per call
-
-    def kv_block(b, i, j):
-        return (_kv_head(b, group), j, 0)
+        sub=sub, one_block=one_block, hps=hps, D=D, group=group)
+    nq, nkv = T // block_q, T // block_kv
+    dtype = jnp.dtype(in_dtype_name)
+    # the step's copy of its one k/v head (``_shared_kv``)
+    shared_kv = [pltpu.VMEM((1, block_kv, w), dtype)] * 2 * _shares_kv(
+        hps, group)
 
     def call(qr, kr, vr):
-        bh = qr.shape[0]
+        nlb, nlb_kv = qr.shape[2] // w, kr.shape[2] // w
+        steps = qr.shape[0] * nlb           # B * H / hps
+
+        def q_block(g, i, j):
+            row, c = _lane_block(g, nlb)
+            return (row, i, c)
+
+        def kv_block(g, i, j):
+            row, c = _lane_block(_kv_head(g, group), nlb_kv)
+            return (row, j, c)
+
         fwd = pl.pallas_call(
             kernel,
             name=FWD_NAME,
-            grid=(bh,) + grid[1:],
+            grid=(steps, nq, nkv),
             in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_kv, D), kv_block),
-                pl.BlockSpec((1, block_kv, D), kv_block),
+                pl.BlockSpec((1, block_q, w), q_block),
+                pl.BlockSpec((1, block_kv, w), kv_block),
+                pl.BlockSpec((1, block_kv, w), kv_block),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
+                pl.BlockSpec((1, block_q, w), q_block),
+                _row_spec(block_q, lambda g, i, j: (g, i, 0, 0), hps),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, T, D), jnp.dtype(in_dtype_name)),
-                jax.ShapeDtypeStruct((bh, T // block_q, 1, block_q),
+                jax.ShapeDtypeStruct(qr.shape, dtype),
+                jax.ShapeDtypeStruct((steps * hps, nq, 1, block_q),
                                      jnp.float32),
             ],
-            scratch_shapes=[] if one_block else [
-                pltpu.VMEM((block_q, D), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-            ],
+            scratch_shapes=shared_kv + ([] if one_block else [
+                pltpu.VMEM((block_q, w), jnp.float32),
+                pltpu.VMEM((hps, block_q, 1), jnp.float32),
+                pltpu.VMEM((hps, block_q, 1), jnp.float32),
+            ]),
             interpret=interpret,
         )
         with jax.named_scope(FWD_NAME):
@@ -394,59 +537,109 @@ def _bht_to_bthd(x, B, H):
     return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
-def _prescale_q(qr):
+def _prescale_q(qr, head_dim: int | None = None):
     """Fold softmax scale and the exp->exp2 base change into q: one fused
-    elementwise over [BH, T, D] instead of a multiply on every
-    [block_q, block_kv] score tile inside the kernels."""
-    D = qr.shape[-1]
+    elementwise over the operand instead of a multiply on every
+    [block_q, block_kv] score tile inside the kernels. ``head_dim``: a
+    head's width where the last axis holds several (None: it is one)."""
+    D = head_dim or qr.shape[-1]
     c = _LOG2E / (D ** 0.5)
     return (qr.astype(jnp.float32) * c).astype(qr.dtype)
 
 
-def _fwd(q, k, v, causal, block_q, block_kv, sub, interpret):
-    B, T, H, D = q.shape
-    call = _build_fwd(T, D, causal, block_q, block_kv, sub, q.dtype.name,
-                      interpret, H // k.shape[2])
-    out, lse_row = call(_prescale_q(_bthd_to_bht(q)), _bthd_to_bht(k),
-                        _bthd_to_bht(v))
-    return _bht_to_bthd(out, B, H), lse_row
+def lane_layout(n_heads: int, n_kv_heads: int, head_dim: int) -> int | None:
+    """Heads a grid step (2) where the kernels read q, k, v, do, out and
+    write out, dq, dk, dv in the projections' own ``[B, T, H * D]`` layout,
+    two heads of 64 side by side in a 128-lane block — or None where the
+    operands are turned head-major, ``[B * H, T, D]``, one head a step: an
+    odd k/v head count (``Hkv * D`` no multiple of 128), an odd group above
+    1 (the two q heads of a step would read different k/v heads), or
+    another head_dim. At 128 a head-major operand is lane-dense already,
+    and measured (PERF.md §6, PR 32) the kernels run 4-5 % slower on
+    strided lane blocks while XLA pays more to bring a rotary-embedded
+    ``[B, T, H, 128]`` back to ``[B, T, H * 128]`` than the transposes it
+    saves. Read off the operands' shape alone; the kernels' builders and
+    the ``[attention]`` line both ask here."""
+    group = n_heads // n_kv_heads
+    if head_dim == 64 and n_kv_heads % 2 == 0 and (
+            group == 1 or group % 2 == 0):
+        return 2
+    return None
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *scratch, causal: bool, block_q: int, block_kv: int,
-               sub: int | None, one_block: bool, scale: float):
+def _to_kernel(x, hps: int | None):
+    """``[B, T, H, D]`` -> the kernels' operand: ``[B, T, H * D]`` as it
+    is, or head-major ``[B * H, T, D]``."""
+    B, T, H, D = x.shape
+    return _bthd_to_bht(x) if hps is None else x.reshape(B, T, H * D)
+
+
+def _from_kernel(x, B: int, H: int, hps: int | None):
+    if hps is None:
+        return _bht_to_bthd(x, B, H)
+    return x.reshape(B, x.shape[1], H, x.shape[2] // H)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+               delta_ref, *scratch, causal: bool, block_q: int,
+               block_kv: int, sub: int | None, one_block: bool, scale: float,
+               hps: int, D: int, group: int):
     q_start = pl.program_id(1) * block_q
     k_start = pl.program_id(2) * block_kv
+    heads = _head_lanes(hps, D)
+    if _shares_kv(hps, group):
+        k_ref, v_ref = _shared_kv(
+            k_ref, v_ref, *scratch[:2],
+            _kv_half(pl.program_id(0), group, hps), D)
+        scratch = scratch[2:]
 
-    def dq_of(rows, cols, mask):
-        s = _scores2(q_ref, k_ref, rows, cols, mask)
-        p = jnp.exp2(s - lse_ref[0, 0, :, rows].T)        # [rows, cols]
-        dp = jax.lax.dot_general(
-            do_ref[0, rows, :], v_ref[0, cols, :], _NT,
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, :, rows].T)
-        return jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0, cols, :], _NN,
-            preferred_element_type=jnp.float32)
+    def deltas(rows):
+        """delta = rowsum(do * o) a head, as the columns this kernel
+        subtracts; written out as lane-dense rows for the dk/dv pass."""
+        prod = (do_ref[0, rows, :].astype(jnp.float32)
+                * o_ref[0, rows, :].astype(jnp.float32))
+        cols = [jnp.sum(prod if lanes is None else jnp.where(lanes, prod, 0.0),
+                        axis=-1, keepdims=True) for lanes in heads]
+        for h, col in enumerate(cols):
+            delta_ref[h, 0, :, rows] = col.T
+        return cols
+
+    def dq_of(rows, cols, mask, delta):
+        parts = []
+        for h, lanes in enumerate(heads):
+            s = _scores2(q_ref, k_ref, rows, cols, mask, lanes=lanes)
+            p = jnp.exp2(s - lse_ref[h, 0, :, rows].T)    # [rows, cols]
+            dp = jax.lax.dot_general(
+                _head_rows(do_ref, rows, lanes), v_ref[0, cols, :], _NT,
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta[h])
+            # every lane of the block; head h's D lanes are ds_h @ k_h
+            parts.append(jax.lax.dot_general(
+                ds.astype(k_ref.dtype), k_ref[0, cols, :], _NN,
+                preferred_element_type=jnp.float32))
+        return _by_head(parts, heads)
 
     if one_block:  # a tile is its query rows' whole dq: no accumulator
         def tile(rows, cols, mask):
-            dq_ref[0, rows, :] = (dq_of(rows, cols, mask)
+            dq_ref[0, rows, :] = (dq_of(rows, cols, mask, deltas(rows))
                                   * scale).astype(dq_ref.dtype)
 
         _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
                   one_block)
         return
 
-    acc_ref, = scratch
+    acc_ref, delta_col = scratch
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        for h, col in enumerate(deltas(slice(None))):
+            delta_col[h] = col
 
     def tile(rows, cols, mask):
-        acc_ref[rows] += dq_of(rows, cols, mask)
+        acc_ref[rows] += dq_of(rows, cols, mask,
+                               [delta_col[h, rows] for h in range(hps)])
 
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
               one_block)
@@ -459,11 +652,22 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
+                dk_ref, dv_ref, dk_acc, dv_acc, *scratch, causal: bool,
                 block_q: int, block_kv: int, sub: int | None,
-                one_block: bool, group: int = 1, nq: int = 1):
-    step = pl.program_id(2)  # the group's q heads, nq q blocks each
+                one_block: bool, hps: int, D: int, group: int = 1,
+                nq: int = 1):
+    step = pl.program_id(2)  # the group's q steps, nq q blocks each
     iq = _group_step(0, step, group, nq)[1]
+    heads = _head_lanes(hps, D)
+    shared = _shares_kv(hps, group)
+    at = lambda cols: cols      # where a tile adds to dk_acc / dv_acc
+    if shared:
+        # this step's q heads read k/v head ``half`` of the block, and what
+        # they add to dk / dv — each in its own lanes — is that head's: one
+        # accumulator a k/v head, its halves summed at the end
+        half = _kv_half(step // nq, group, hps)
+        k_ref, v_ref = _shared_kv(k_ref, v_ref, *scratch, half, D)
+        at = lambda cols: (half, cols)
 
     @pl.when(step == 0)
     def _init():
@@ -474,110 +678,143 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k_start = pl.program_id(1) * block_kv
 
     def tile(rows, cols, mask):
-        # Transposed space, [keys, queries]: lse and delta are rows.
-        s_t = _scores2(q_ref, k_ref, rows, cols, mask, transposed=True)
-        p_t = jnp.exp2(s_t - lse_ref[0, 0, :, rows])
-        dv_acc[cols] += jax.lax.dot_general(
-            p_t.astype(do_ref.dtype), do_ref[0, rows, :], _NN,
-            preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(
-            v_ref[0, cols, :], do_ref[0, rows, :], _NT,
-            preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta_ref[0, 0, :, rows])
-        dk_acc[cols] += jax.lax.dot_general(
-            ds_t.astype(q_ref.dtype), q_ref[0, rows, :], _NN,
-            preferred_element_type=jnp.float32)
+        # Transposed space, [keys, queries]: lse and delta are rows. A
+        # head's q and do rows are zero in the other heads' lanes, so its
+        # dv and dk land in its own lanes and the heads' sums do not mix.
+        for h, lanes in enumerate(heads):
+            s_t = _scores2(q_ref, k_ref, rows, cols, mask, transposed=True,
+                           lanes=lanes)
+            p_t = jnp.exp2(s_t - lse_ref[h, 0, :, rows])
+            do = _head_rows(do_ref, rows, lanes)
+            dv_acc[at(cols)] += jax.lax.dot_general(
+                p_t.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)
+            dp_t = jax.lax.dot_general(
+                v_ref[0, cols, :], do, _NT,
+                preferred_element_type=jnp.float32)
+            ds_t = p_t * (dp_t - delta_ref[h, 0, :, rows])
+            dk_acc[at(cols)] += jax.lax.dot_general(
+                ds_t.astype(q_ref.dtype), _head_rows(q_ref, rows, lanes),
+                _NN, preferred_element_type=jnp.float32)
 
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
               one_block, kv_major=True)
+
+    def total(acc):
+        if not shared:
+            return acc[:]
+        return _by_head([acc[h] + pltpu.roll(acc[h], D, 1)
+                         for h in range(hps)], heads)
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         # dk contracted ds against the PRE-SCALED q (scale * log2e folded
         # in), while true dk = scale * (ds^T @ q_unscaled) — so divide the
         # extra log2e back out. dv never touches scores: exact as-is.
-        dk_ref[0] = (dk_acc[:] * (1.0 / _LOG2E)).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (total(dk_acc) * (1.0 / _LOG2E)).astype(dk_ref.dtype)
+        dv_ref[0] = total(dv_acc).astype(dv_ref.dtype)
 
 
 @functools.lru_cache(maxsize=None)
 def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                sub: int | None, in_dtype_name: str, interpret: bool,
-               group: int = 1):
-    """Compile-cached backward pallas_calls over the [BH, T, D] layout:
-    a dq pass (grid q-major, KV innermost) and a dk/dv pass (grid kv-major,
-    Q innermost) — the standard two-pass flash backward, so neither pass
-    needs atomics or cross-block communication. ``lse`` and ``delta``
-    arrive as lane-dense rows (``_row_spec``). ``group`` q heads share a
-    K/V head: dq is per q head, dk/dv per K/V head, summed over the group
-    along the dk/dv grid's innermost axis."""
+               group: int = 1, hps: int = 1):
+    """Compile-cached backward pallas_calls over ``_build_fwd``'s operand
+    layouts: a dq pass (grid q-major, KV innermost) and a dk/dv pass (grid
+    kv-major, Q innermost) — the standard two-pass flash backward, so
+    neither pass needs atomics or cross-block communication. ``lse``
+    arrives as lane-dense rows (``_row_spec``); the dq pass makes delta =
+    rowsum(do * o) a head from the do and out blocks it holds and hands it
+    to the dk/dv pass as rows of the same kind. ``group`` q heads
+    share a K/V head: dq is per q head, dk/dv per K/V head, summed over the
+    group along the dk/dv grid's innermost axis."""
     dtype = jnp.dtype(in_dtype_name)
     scale = 1.0 / (D ** 0.5)
     one_block = T == block_q == block_kv
+    w = hps * D
     static = dict(causal=causal, block_q=block_q, block_kv=block_kv, sub=sub,
-                  one_block=one_block)
-    nq = T // block_q
+                  one_block=one_block, hps=hps, D=D, group=group)
+    nq, nkv = T // block_q, T // block_kv
     dq_kernel = functools.partial(_dq_kernel, scale=scale, **static)
-    dkv_kernel = functools.partial(_dkv_kernel, group=group, nq=nq, **static)
-    row_spec_q = _row_spec(block_q, lambda b, i, j: (b, i, 0, 0))
+    dkv_kernel = functools.partial(_dkv_kernel, nq=nq, **static)
+    shared = _shares_kv(hps, group)
+    shared_kv = [pltpu.VMEM((1, block_kv, w), dtype)] * 2 * shared
+    # dk/dv accumulators: one a k/v head of the block where q pairs share
+    acc_shape = (hps,) * shared + (block_kv, w)
+    row_spec_q = _row_spec(block_q, lambda g, i, j: (g, i, 0, 0), hps)
 
-    def kv_block(b, i, j):          # dq grid: q head b, kv block j
-        return (_kv_head(b, group), j, 0)
+    def q_row(g, j, i):              # dk/dv grid: K/V step g, step i
+        return (*_group_step(g, i, group, nq), 0, 0)
 
-    def q_block(b, j, i):           # dk/dv grid: K/V head b, step i
-        return (*_group_step(b, i, group, nq), 0)
+    row_spec_kv_inner = _row_spec(block_q, q_row, hps)
 
-    def q_row(b, j, i):
-        return (*_group_step(b, i, group, nq), 0, 0)
+    def call(qr, kr, vr, dor, out, lse):
+        nlb, nlb_kv = qr.shape[2] // w, kr.shape[2] // w
+        steps, steps_kv = qr.shape[0] * nlb, kr.shape[0] * nlb_kv
 
-    row_spec_kv_inner = _row_spec(block_q, q_row)
+        def q_block(g, i, j):        # dq grid: q step g
+            row, c = _lane_block(g, nlb)
+            return (row, i, c)
 
-    def call(qr, kr, vr, dor, lse, delta):
-        bh, bh_kv = qr.shape[0], kr.shape[0]
+        def kv_block(g, i, j):       # dq grid: q step g, kv block j
+            row, c = _lane_block(_kv_head(g, group), nlb_kv)
+            return (row, j, c)
+
+        def q_inner(g, j, i):        # dk/dv grid: K/V step g, step i
+            gq, iq = _group_step(g, i, group, nq)
+            row, c = _lane_block(gq, nlb)
+            return (row, iq, c)
+
+        def kv_outer(g, j, i):
+            row, c = _lane_block(g, nlb_kv)
+            return (row, j, c)
+
         dq_call = pl.pallas_call(
             dq_kernel,
             name=DQ_NAME,
-            grid=(bh, T // block_q, T // block_kv),
+            grid=(steps, nq, nkv),
             in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_kv, D), kv_block),
-                pl.BlockSpec((1, block_kv, D), kv_block),
-                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                row_spec_q,
+                pl.BlockSpec((1, block_q, w), q_block),
+                pl.BlockSpec((1, block_kv, w), kv_block),
+                pl.BlockSpec((1, block_kv, w), kv_block),
+                pl.BlockSpec((1, block_q, w), q_block),
+                pl.BlockSpec((1, block_q, w), q_block),
                 row_spec_q,
             ],
-            out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            out_shape=jax.ShapeDtypeStruct((bh, T, D), dtype),
-            scratch_shapes=([] if one_block
-                            else [pltpu.VMEM((block_q, D), jnp.float32)]),
+            out_specs=[pl.BlockSpec((1, block_q, w), q_block), row_spec_q],
+            out_shape=[jax.ShapeDtypeStruct(qr.shape, dtype),
+                       jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+            scratch_shapes=shared_kv + ([] if one_block else [
+                pltpu.VMEM((block_q, w), jnp.float32),
+                pltpu.VMEM((hps, block_q, 1), jnp.float32)]),
             interpret=interpret,
         )
         with jax.named_scope(DQ_NAME):
-            dq = dq_call(qr, kr, vr, dor, lse, delta)
+            dq, delta = dq_call(qr, kr, vr, dor, out, lse)
         dkv_call = pl.pallas_call(
             dkv_kernel,
             name=DKV_NAME,
-            grid=(bh_kv, T // block_kv, group * nq),
+            grid=(steps_kv, nkv, group * nq),
             in_specs=[
-                pl.BlockSpec((1, block_q, D), q_block),
-                pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_q, D), q_block),
+                pl.BlockSpec((1, block_q, w), q_inner),
+                pl.BlockSpec((1, block_kv, w), kv_outer),
+                pl.BlockSpec((1, block_kv, w), kv_outer),
+                pl.BlockSpec((1, block_q, w), q_inner),
                 row_spec_kv_inner,
                 row_spec_kv_inner,
             ],
             out_specs=[
-                pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_kv, D), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_kv, w), kv_outer),
+                pl.BlockSpec((1, block_kv, w), kv_outer),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((bh_kv, T, D), dtype),
-                jax.ShapeDtypeStruct((bh_kv, T, D), dtype),
+                jax.ShapeDtypeStruct(kr.shape, dtype),
+                jax.ShapeDtypeStruct(kr.shape, dtype),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_kv, D), jnp.float32),
-                pltpu.VMEM((block_kv, D), jnp.float32),
-            ],
+                pltpu.VMEM(acc_shape, jnp.float32),
+                pltpu.VMEM(acc_shape, jnp.float32),
+            ] + shared_kv,
             interpret=interpret,
         )
         with jax.named_scope(DKV_NAME):
@@ -587,39 +824,73 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
     return call
 
 
-def _bwd_pallas(q, k, v, out, lse_row, do, causal, block_q, block_kv, sub,
-                interpret):
-    B, T, H, D = q.shape
-    qr, kr, vr, dor = (_bthd_to_bht(x) for x in (q, k, v, do))
-    qr = _prescale_q(qr)  # the kernels recompute log2-space scores
-    of = _bthd_to_bht(out)
-    delta = jnp.sum(dor.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1).reshape(lse_row.shape)      # rows, like lse
-    h_kv = k.shape[2]
-    call = _build_bwd(T, D, causal, block_q, block_kv, sub, q.dtype.name,
-                      interpret, H // h_kv)
-    dq, dk, dv = call(qr, kr, vr, dor, lse_row, delta)
-    return (_bht_to_bthd(dq, B, H), _bht_to_bthd(dk, B, h_kv),
-            _bht_to_bthd(dv, B, h_kv))
+# One ``jit`` a builder's call: every repeat of the call is the same function
+# to ``jit``'s own caches, which hold its jaxpr and its lowering.
+_shared = functools.lru_cache(maxsize=None)(jax.jit)
 
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
-                interpret: bool):
-    @jax.custom_vjp
-    def flash(q, k, v):
-        return _fwd(q, k, v, causal, block_q, block_kv, sub, interpret)[0]
+                interpret: bool, D: int, group: int, hps: int | None):
+    """The differentiable call over the kernels' own operands (``hps``
+    None: head-major ``[BH, T, D]``; else ``[B, T, H * D]``) — what is
+    kept for the backward is kept as the kernels read it.
 
-    def fwd(q, k, v):
-        out, lse_row = _fwd(q, k, v, causal, block_q, block_kv, sub,
-                            interpret)
-        return out, (q, k, v, out, lse_row)
+    The first call a trace makes runs the builders' calls bare; every
+    repeat in that trace runs them through one inner ``jit`` each
+    (``_shared``). Every ``pallas_call`` traces its body and lowers it to
+    Mosaic anew, a trunk of L attention layers makes the same three calls
+    L times, and two heads a step about doubles a body
+    (``gpt2m-policy.update``: 72 calls, + 7 s of warm set-up traced a
+    layer); through the inner ``jit`` the repeats share one jaxpr and one
+    lowered function (eagerly — ``init_params`` runs every layer at T = 1
+    — one executable in place of one a layer). The first call stays bare
+    so that a model with ONE attention layer traces and lowers exactly
+    what it did without this: an inner ``jit`` shares nothing there, and
+    it moves the depth of the Python stack at which Mosaic's lowering
+    runs, which alone cost ``lfm2-policy.update`` 3 s a process (PERF.md
+    §6, PR 33: CPython's frame stack grows in 16 KB chunks, and a hot call
+    that straddles a chunk's end maps and unmaps one every time).
+    """
+    per_step = hps or 1
 
-    def bwd(res, do):
-        return _bwd_pallas(*res, do, causal, block_q, block_kv, sub,
-                           interpret)
+    def differentiable(built):
+        def run_fwd(qr, kr, vr):
+            T = qr.shape[1]
+            call = built(_build_fwd(T, D, causal, block_q, block_kv, sub,
+                                    qr.dtype.name, interpret, group,
+                                    per_step))
+            return call(_prescale_q(qr, D), kr, vr)
 
-    flash.defvjp(fwd, bwd)
+        @jax.custom_vjp
+        def flash(qr, kr, vr):
+            return run_fwd(qr, kr, vr)[0]
+
+        def fwd(qr, kr, vr):
+            out, lse_row = run_fwd(qr, kr, vr)
+            return out, (qr, kr, vr, out, lse_row)
+
+        def bwd(res, dor):
+            qr, kr, vr, out, lse_row = res
+            call = built(_build_bwd(qr.shape[1], D, causal, block_q,
+                                    block_kv, sub, qr.dtype.name, interpret,
+                                    group, per_step))
+            # the kernels recompute log2-space scores
+            return call(_prescale_q(qr, D), kr, vr, dor, out, lse_row)
+
+        flash.defvjp(fwd, bwd)
+        return flash
+
+    bare, shared = differentiable(lambda call: call), differentiable(_shared)
+    last_trace = [None]   # of the previous call; held weakly, eager is one
+
+    def flash(qr, kr, vr):
+        # asked here, in the layer's own trace: a custom_vjp that is not
+        # differentiated traces its forward in a trace of its own
+        trace = jax.core.get_opaque_trace_state()
+        repeat, last_trace[0] = trace == last_trace[0], trace
+        return (shared if repeat else bare)(qr, kr, vr)
+
     return flash
 
 
@@ -650,8 +921,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"q heads {q.shape[2]} do not group over k/v "
                          f"{k.shape} / {v.shape}")
     block_q, block_kv, sub = tiling(q.shape[1], causal, block_q, block_kv)
-    return _make_flash(causal, block_q, block_kv, sub,
-                       bool(interpret))(q, k, v)
+    B, _, H, D = q.shape
+    h_kv = k.shape[2]
+    hps = lane_layout(H, h_kv, D)
+    flash = _make_flash(causal, block_q, block_kv, sub, bool(interpret), D,
+                        H // h_kv, hps)
+    out = flash(*(_to_kernel(x, hps) for x in (q, k, v)))
+    return _from_kernel(out, B, H, hps)
 
 
 def tiling(T: int, causal: bool = True, block_q: int = 1024,

@@ -133,7 +133,8 @@ def _sub_tile(monkeypatch, rows):
 
 
 def _clear_kernel_caches():
-    for cache in (flash._make_flash, flash._build_fwd, flash._build_bwd):
+    for cache in (flash._make_flash, flash._build_fwd, flash._build_bwd,
+                  flash._shared):
         cache.cache_clear()
 
 
@@ -285,8 +286,29 @@ def test_policy_records_the_score_area(monkeypatch, capsys):
     assert policy.attention_backends[key] == "flash_pallas"
     assert policy.attention_score_area_pct[key] == 62.5
     assert policy.attention_score_area_pct[(1, 16, "float32")] == 100.0
-    assert ("T=32 head_dim=16 float32 -> flash_pallas, score area 62.5%"
-            in capsys.readouterr().out)
+    # ...and which operand layout the kernels ran: 2 heads of 16 fill no
+    # 128-lane block
+    assert policy.attention_layout[key] == "head-major"
+    assert ("T=32 head_dim=16 float32 -> flash_pallas, score area 62.5%, "
+            "layout head-major" in capsys.readouterr().out)
+
+
+def test_policy_records_the_lane_layout(monkeypatch, capsys):
+    # the same record at a head_dim the lanes admit: two heads a grid step
+    from relayrl_tpu.models import build_policy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "flash_attention", flash_attention)
+    arch = {"kind": "transformer_discrete", "obs_dim": 8, "act_dim": 3,
+            "d_model": 128, "n_layers": 1, "n_heads": 2, "max_seq_len": 16,
+            "attention": "flash"}
+    policy = build_policy(arch)
+    params = policy.init_params(jax.random.PRNGKey(0))
+    policy.evaluate(params, jnp.zeros((1, 16, 8), jnp.float32),
+                    jnp.zeros((1, 16), jnp.int32))
+    assert policy.attention_layout[(16, 64, "float32")] == "2 heads a step"
+    assert ("T=16 head_dim=64 float32 -> flash_pallas, score area 100%, "
+            "layout 2 heads a step" in capsys.readouterr().out)
 
 
 # -- grouped-query k/v: index maps only, k/v never repeated -------------------
@@ -357,3 +379,208 @@ def test_grouped_flash_refuses_heads_that_do_not_group():
     q, k, v = _grouped_qkv(1, 16, 4, 3)
     with pytest.raises(ValueError, match="do not group"):
         flash_attention(q, k, v, block_q=16, block_kv=16)
+
+
+# -- the projections' own [B, T, H * D] layout: 128 lanes a grid step ---------
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,want", [
+    (16, 16, 64, 2),      # gpt2m-policy.update
+    (16, 16, 128, None),  # olmoe-policy.update: lane-dense head-major
+    (32, 8, 64, 2),       # lfm2-policy.update: a q pair shares its k/v head
+    (2, 2, 64, 2),        # the (1, 1000, 2, 64) bucket
+    (8, 2, 128, None),
+    (4, 4, 256, None),    # a head_dim no cell has measured
+    (4, 1, 64, None),     # Hkv * D = 64: half a lane block
+    (3, 3, 64, None),     # H * D no multiple of 128
+    (6, 2, 64, None),     # the q heads of a pair would read two k/v heads
+    (8, 8, 32, None),     # a head_dim the lanes are not filled with
+    (2, 2, 16, None),
+    (16, 16, 96, None),   # 96 lanes a head: no two fill a 128-lane block
+    (4, 2, 96, None),
+    (12, 3, 64, None),    # an even group over an odd k/v head count
+    (64, 8, 64, 2),       # group 8: four q pairs a k/v head
+])
+def test_lane_layout(heads, kv_heads, head_dim, want):
+    assert flash.lane_layout(heads, kv_heads, head_dim) == want
+
+
+def _one_ulp_bf16(got, want, name):
+    """Equal to one bfloat16 unit in the last place (2^-8 of the value at
+    worst), with an absolute floor for entries that cancel to near zero."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    np.testing.assert_allclose(got, want, rtol=2.0 ** -7,
+                               atol=2.0 ** -8 * 1e-2 * np.abs(want).max(),
+                               err_msg=name)
+
+
+def _check_both_layouts(monkeypatch, q, k, v, causal, block, atol):
+    """Forward and the three gradients of the kernels in the layout the
+    shape selects: against dense attention (``atol``), and against the same
+    kernels on head-major ``[BH, T, D]`` operands — the added products are
+    ``x * 0``, so the two layouts give the same numbers."""
+    _sub_tile(monkeypatch, 8)
+
+    def fl(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=block,
+                               block_kv=block)
+
+    def run(fn):
+        return (fn(q, k, v),
+                *jax.grad(_grad_loss(fn), argnums=(0, 1, 2))(q, k, v))
+
+    names = ("out", "dq", "dk", "dv")
+    got = run(fl)
+    want = run(lambda q, k, v: dense_attention(q, k, v, causal=causal))
+    for g, w, name in zip(got, want, names):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(
+            g.astype(jnp.float32), w.astype(jnp.float32), atol=atol,
+            rtol=atol, err_msg=name)
+    _clear_kernel_caches()
+    monkeypatch.setattr(flash, "lane_layout", lambda *a: None)
+    for g, w, name in zip(got, run(fl), names):
+        _one_ulp_bf16(g, w, name)
+    _clear_kernel_caches()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("H,h_kv,D,T,block,layout", [
+    (4, 4, 64, 32, 32, 2),      # two heads a step, one block a head
+    (4, 4, 64, 32, 16, 2),      # ... a 2 x 2 grid with carried state
+    (2, 2, 128, 32, 16, None),  # head_dim 128 stays head-major
+    (8, 2, 64, 32, 16, 2),      # grouped: a q pair shares its k/v head
+    (8, 2, 64, 16, 16, 2),      # ... one block a head
+    (4, 1, 64, 32, 16, None),   # Hkv * D = 64: has to fall back
+    (6, 2, 64, 16, 16, None),   # an odd group: two k/v heads a q pair
+])
+def test_lane_layout_matches_dense_and_head_major(monkeypatch, H, h_kv, D, T,
+                                                  block, layout, dtype):
+    assert flash.lane_layout(H, h_kv, D) == layout
+    q, k, v = (x.astype(dtype) for x in _grouped_qkv(2, T, H, h_kv, D))
+    _check_both_layouts(monkeypatch, q, k, v, True, block,
+                        5e-5 if dtype == "float32" else 6e-2)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("H,h_kv,T,block", [
+    (4, 4, 64, 16),   # 4 x 4 blocks: interior, diagonal and skipped steps
+    (8, 2, 64, 16),   # ... grouped, the q pair's k/v head rolled into place
+    (4, 4, 32, 32),   # one block a head: no carried state
+    (8, 2, 32, 32),
+])
+def test_lane_layout_grid_steps_causal_and_not(monkeypatch, H, h_kv, T,
+                                               block, causal):
+    """Two heads a step over every kind of grid step — below, on and
+    (causal) above the diagonal — and with no mask at all."""
+    assert flash.lane_layout(H, h_kv, 64) == 2
+    q, k, v = _grouped_qkv(1, T, H, h_kv, 64, seed=11)
+    _check_both_layouts(monkeypatch, q, k, v, causal, block, 5e-5)
+
+
+# -- the kernels' trace: a call's repeats share one, its first stays bare -----
+
+def _flash_policy(monkeypatch, n_layers, T=16):
+    """A tiny ``transformer_discrete`` at head_dim 64 whose attention is the
+    kernels (as on a TPU), run through the interpreter."""
+    from relayrl_tpu.models import build_policy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "flash_attention", flash_attention)
+    return build_policy({
+        "kind": "transformer_discrete", "obs_dim": 8, "act_dim": 3,
+        "d_model": 128, "n_layers": n_layers, "n_heads": 2,
+        "max_seq_len": T, "attention": "flash"})
+
+
+def _count_kernel_bodies(monkeypatch):
+    """Calls of the three kernel body functions = traces of a body."""
+    calls = {}
+    _clear_kernel_caches()
+    for name in ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"):
+        body = getattr(flash, name)
+
+        def counted(*args, _body=body, _name=name, **kw):
+            calls[_name] = calls.get(_name, 0) + 1
+            return _body(*args, **kw)
+
+        monkeypatch.setattr(flash, name, counted)
+    return calls
+
+
+def _lowered_update(policy, T=16):
+    from relayrl_tpu.algorithms.impala import (
+        ImpalaState, make_impala_tx, make_impala_update)
+    from relayrl_tpu.data.batching import TrajectoryBatch
+
+    params = jax.eval_shape(policy.init_params, jax.random.PRNGKey(0))
+    tx = make_impala_tx(1e-4, 1.0)
+    state = ImpalaState(
+        params=params, opt_state=jax.eval_shape(tx.init, params),
+        rng=jax.ShapeDtypeStruct((2,), jnp.uint32),
+        step=jax.ShapeDtypeStruct((), jnp.int32))
+    update = make_impala_update(policy, lr=1e-4, gamma=0.99, vf_coef=0.5,
+                                ent_coef=0.01, rho_bar=1.0, c_bar=1.0,
+                                max_grad_norm=1.0)
+    batch = TrajectoryBatch.zeros(2, T, 8, 3, True)
+    return jax.jit(update, donate_argnums=0).lower(state, batch).as_text()
+
+
+@pytest.mark.parametrize("n_layers,traces,shared_funcs", [
+    (4, 2, 2),  # the first layer's call bare, one inner jit for the rest
+    (1, 1, 0),  # one attention layer never meets the inner jit
+])
+def test_a_trunk_traces_a_kernel_body_twice_at_most(monkeypatch, n_layers,
+                                                    traces, shared_funcs):
+    """Lowering an L-layer update traces each flash kernel body twice —
+    the first call bare, its repeats through the builders' one inner
+    ``jit`` (``flash._make_flash``) — where it was L times; a model with
+    one attention layer traces and lowers what it did without it."""
+    calls = _count_kernel_bodies(monkeypatch)
+    policy = _flash_policy(monkeypatch, n_layers)
+    text = _lowered_update(policy)
+    _clear_kernel_caches()
+    assert policy.attention_layout[(16, 64, "float32")] == "2 heads a step"
+    # init ran the model at T = 1 (forward only, the custom_vjp's forward
+    # in a trace of its own a layer), the update at T = 16
+    assert calls == {"_fwd_kernel": 2 * traces, "_dq_kernel": traces,
+                     "_dkv_kernel": traces}
+    # one lowered function a builder (forward; backward), called a layer
+    assert len([ln for ln in text.splitlines()
+                if "func.func private @call" in ln]) == shared_funcs
+
+
+def test_eager_repeats_share_one_program(monkeypatch):
+    """Eagerly — ``init_params`` runs every layer at T = 1 — the first
+    call compiles the bare primitive's program, as a lone call always did
+    (no ``jit(call)`` exists for it), and every repeat runs ONE
+    ``jit(call)`` executable where each layer compiled its own."""
+    from jax import monitoring
+
+    compiled = []
+
+    def on_compile(event, duration, fun_name="", **kw):
+        if event.endswith("backend_compile_duration"):
+            compiled.append(fun_name)
+
+    def kernel_programs():
+        found = [n for n in compiled if n in ("jit(call)", "jit(wrapped)")]
+        compiled.clear()
+        return found
+
+    monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        calls = _count_kernel_bodies(monkeypatch)
+        q, k, v = _grouped_qkv(1, 16, 2, 2, 64)
+        jax.grad(_grad_loss(lambda q, k, v: flash_attention(
+            q, k, v, block_q=16, block_kv=16)), argnums=(0, 1, 2))(q, k, v)
+        assert calls == {"_fwd_kernel": 1, "_dq_kernel": 1, "_dkv_kernel": 1}
+        assert "jit(call)" not in kernel_programs()
+        calls.clear()
+        policy = _flash_policy(monkeypatch, n_layers=4)
+        policy.init_params(jax.random.PRNGKey(0))
+        # four layers: the first bare, one trace of the shared jit
+        assert calls == {"_fwd_kernel": 2}
+        assert kernel_programs().count("jit(call)") == 1
+    finally:
+        monitoring.unregister_event_duration_listener(on_compile)
+        _clear_kernel_caches()

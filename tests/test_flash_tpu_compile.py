@@ -29,15 +29,17 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("shape,sub,kv_heads", [
-    ((8, 1024, 16, 64), 256, 16),   # gpt2m-policy.update: one block a head
-    ((4, 4096, 16, 128), 256, 16),  # olmoe-policy.update: 4 x 4 blocks
-    ((1, 1000, 2, 64), None, 2),    # a default bucket: one tile a step
-    ((2, 8192, 32, 64), 256, 8),    # lfm2-policy.update: 4 q heads a k/v
-    ((1, 1024, 4, 64), 256, 1),     # one block a head, one k/v head
+@pytest.mark.parametrize("shape,sub,kv_heads,layout", [
+    ((8, 1024, 16, 64), 256, 16, 2),    # gpt2m-policy.update: one block a head
+    ((4, 4096, 16, 128), 256, 16, None),  # olmoe-policy.update: 4 x 4 blocks
+    ((1, 1000, 2, 64), None, 2, 2),     # a default bucket: one tile a step
+    ((2, 8192, 32, 64), 256, 8, 2),     # lfm2-policy.update: 4 q heads a k/v
+    ((1, 1024, 4, 64), 256, 1, None),   # one k/v head: half a lane block
 ])
-def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads):
+def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
+                                       layout):
     assert flash.tiling(shape[1])[2] == sub
+    assert flash.lane_layout(shape[2], kv_heads, shape[3]) == layout
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct(shape[:2] + (kv_heads, shape[3]),
                               jnp.bfloat16, sharding=one_chip)
@@ -49,7 +51,17 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads):
             argnums=(0, 1, 2))(q, k, v)
 
     compiled = jax.jit(value_and_grads).lower(x, kv, kv).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    # In the lane layout the head axis never leaves the lanes: nothing in
+    # the compiled program (no transpose, no copy, no operand of a kernel)
+    # is [B, H, T, D]-shaped or its flat form. A shape that falls back is
+    # turned head-major, as every shape was before.
+    B, T, H, D = shape
+    head_major = [f"bf16[{B},{h},{T},{D}]" for h in {H, kv_heads}] + [
+        f"bf16[{B * h},{T},{D}]" for h in {H, kv_heads}]
+    found = [s for s in head_major if s in text]
+    assert (found == []) if layout else found, found
     # grouped k/v are never repeated: dk and dv come back at their heads
     assert [o.shape for o in compiled.out_info[1]] == [
         x.shape, kv.shape, kv.shape]
