@@ -27,6 +27,7 @@ module level imports nothing that touches jax.
 
 from __future__ import annotations
 
+import functools
 import json
 import multiprocessing as mp
 import os
@@ -503,16 +504,20 @@ def differ(a, b) -> float:
 
 
 def phase_b_kernels() -> None:
-    """The three flash kernels alone at the three shapes the benchmark's
+    """The three flash kernels alone at the shapes the benchmark's
     transformer cells run — (B*H, T, D) = (128, 1024, 64), one block a head
     walked in causal strips, (64, 4096, 128), a 4 x 4 grid a head with
-    strips on its diagonal, and (64, 8192, 64) grouped, 32 q heads over 8
-    k/v heads that reach the kernels unrepeated (lfm2-policy.update) —
+    strips on its diagonal, (64, 8192, 64) grouped, 32 q heads over 8
+    k/v heads that reach the kernels unrepeated (lfm2-policy.update), and
+    (28, 16384, 128) over 4 k/v heads under a window of 4096, the band
+    kernels (smallthinker-policy.update's windowed layers) —
     bfloat16, forward and all three gradients
     under a random cotangent, against the XLA paths on the same inputs in
     float32 (``dense_attention`` at T 1024, ``blockwise_attention`` at
     T 4096; the grouped shape against ``dense_attention`` one k/v head and
-    its four q heads at a time). The kernels round p and ds to bfloat16 for their second
+    its four q heads at a time; the band shape against ``dense_attention``
+    with the band mask one q head at a time, dk and dv summed over a
+    group). The kernels round p and ds to bfloat16 for their second
     matmuls and the results once: a few units in the last place of the
     largest entry, phase E's limit."""
     import jax
@@ -526,10 +531,12 @@ def phase_b_kernels() -> None:
     def blockwise(q, k, v):
         return blockwise_attention(q, k, v, 512, causal=True)
 
-    for (B, T, H, D), h_kv, reference in (
-            ((8, 1024, 16, 64), 16, dense_attention),
-            ((4, 4096, 16, 128), 16, blockwise),
-            ((2, 8192, 32, 64), 8, dense_attention)):
+    for (B, T, H, D), h_kv, reference, window in (
+            ((8, 1024, 16, 64), 16, dense_attention, None),
+            ((4, 4096, 16, 128), 16, blockwise, None),
+            ((2, 8192, 32, 64), 8, dense_attention, None),
+            ((1, 16384, 28, 128), 4,
+             functools.partial(dense_attention, window=4096), 4096)):
         q, k, v, do = (
             jax.random.normal(key, (B, T, heads, D), jnp.bfloat16)
             for key, heads in zip(jax.random.split(jax.random.PRNGKey(T), 4),
@@ -537,7 +544,8 @@ def phase_b_kernels() -> None:
 
         @jax.jit
         def kernel_side(q, k, v, do):
-            out, vjp = jax.vjp(flash.flash_attention, q, k, v)
+            out, vjp = jax.vjp(functools.partial(
+                flash.flash_attention, window=window), q, k, v)
             return (out, *vjp(do))
 
         @jax.jit
@@ -546,24 +554,36 @@ def phase_b_kernels() -> None:
                 x.astype(jnp.float32) for x in (q, k, v)))
             return (out, *vjp(do.astype(jnp.float32)))
 
-        def xla_by_group(q, k, v, do):
-            """One k/v head and its q heads at a time (T x T float32
-            scores of every head at once do not fit at T 8192)."""
+        def xla_in_parts(q, k, v, do, at_once):
+            """One k/v head and ``at_once`` of its q heads at a time (T x T
+            float32 scores of every head at once do not fit at T 8192, nor
+            a group's seven at T 16384); dk and dv summed over a group."""
             G = H // h_kv
-            parts = [xla_side(q[:, :, h * G:(h + 1) * G], k[:, :, h:h + 1],
-                              v[:, :, h:h + 1], do[:, :, h * G:(h + 1) * G])
-                     for h in range(h_kv)]
-            return [jnp.concatenate(x, axis=2) for x in zip(*parts)]
+            outs, dqs, dks, dvs = [], [], [], []
+            for h in range(h_kv):
+                dk = dv = 0.0
+                for j in range(h * G, (h + 1) * G, at_once):
+                    part = xla_side(q[:, :, j:j + at_once], k[:, :, h:h + 1],
+                                    v[:, :, h:h + 1], do[:, :, j:j + at_once])
+                    outs.append(part[0])
+                    dqs.append(part[1])
+                    dk, dv = dk + part[2], dv + part[3]
+                dks.append(dk)
+                dvs.append(dv)
+            return [jnp.concatenate(x, axis=2)
+                    for x in (outs, dqs, dks, dvs)]
 
-        area = flash.score_area_pct(T, *flash.tiling(T), True)
+        area = flash.score_area_pct(T, *flash.tiling(T), True, window)
         errs = dict(zip(("out", "dq", "dk", "dv"), map(
             differ, kernel_side(q, k, v, do),
-            (xla_side if h_kv == H else xla_by_group)(q, k, v, do))))
+            xla_side(q, k, v, do) if h_kv == H else xla_in_parts(
+                q, k, v, do, 1 if window else H // h_kv))))
         for what, err in errs.items():
             check(err <= 2.0 ** -6,
                   f"B\": flash {what} at {(B * H, T, D)} differs from "
                   f"XLA's by {err:.3g} of its largest entry (limit 2^-6)")
-        said.append(f"{(B * H, T, D)} k/v heads {h_kv} of {H} tiling "
+        said.append(f"{(B * H, T, D)} k/v heads {h_kv} of {H} "
+                    f"window {window} tiling "
                     f"{flash.tiling(T)} score area "
                     f"{area:g}% "
                     f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}")

@@ -57,6 +57,9 @@ ARCH_PASSTHROUGH_KEYS = (
     # layer's router and held range (transformer._MOE_ARCH_KEYS)
     "layer_types", "moe_dense_layers",
     "moe_router", "moe_expert_bias", "moe_held",
+    # a head width of its own, windowed layers, positions layer by layer,
+    # the router's input
+    "head_dim", "sliding_window", "rope_layers", "moe_router_input",
 )
 
 
@@ -128,11 +131,14 @@ class Policy:
     # attention shape traced so far (models/transformer._resolve_attention
     # fills it at trace time) — which implementation a platform-dependent
     # ``attention`` config actually compiled to. None for other families.
+    # (A windowed layer runs the backend of its shape: no key of its own.)
     attention_backends: Mapping[tuple, str] | None = None
     # Beside it, for the shapes that resolved to ``flash_pallas``: the share
     # (%) of the T x T score matrix the flash kernels compute at that shape's
     # tiling (``ops.flash.score_area_pct``; a causal kernel that skipped
-    # everything above the diagonal would read 50 + 50 / T).
+    # everything above the diagonal would read 50 + 50 / T). A windowed
+    # layer's entry is keyed ``(T, head_dim, dtype, window)``, beside the
+    # global layers' of the same shape, here and in ``attention_layout``.
     attention_score_area_pct: Mapping[tuple, float] | None = None
     # ...and the operand layout they ran in (``ops.flash.lane_layout``):
     # ``"2 heads a step"`` of the projections' own ``[B, T, H * D]``

@@ -129,6 +129,11 @@ def _gaussian_entropy(log_std, batch_shape):
     return jnp.broadcast_to(ent, batch_shape)
 
 
+# The gated FFN kinds (arch ``ffn``) by the activation on the gate: the
+# dense FFN of models/transformer.py and the experts of models/moe.py.
+GATED_FFN = {"swiglu": nn.silu, "reglu": nn.relu}
+
+
 def _compute_dtype(arch: Mapping[str, Any]):
     name = arch.get("precision", "float32")
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[name]

@@ -12,8 +12,14 @@ between training batches and single-window actor serving — a hard
 requirement for RL policies, where logp at step t must condition only on
 history (capacity-competition schemes like expert-choice or token-dropping
 leak future timesteps / sibling sequences into the gate and bias the
-policy gradient). The router runs in float32 over all E experts; three
-weightings (arch ``moe_router`` and ``moe_norm_topk_prob``):
+policy gradient). The router runs in float32 over all E experts. What it
+reads is the layer's to say: by default the rows the experts read (the
+block's normed FFN input); arch ``moe_router_input: "layer"`` hands it the
+layer's own input instead, as it arrives — before the attention and before
+any norm (SmallThinker's "router placed before attention": the choice is
+known before the attention runs) — while the experts still read the normed
+post-attention rows. Still one token's features alone. Three weightings
+(arch ``moe_router`` and ``moe_norm_topk_prob``):
 
 * softmax, ``True`` (the default) — top-k of the logits, softmax over the
   k chosen (equal to softmax over all E, top-k, renormalised);
@@ -38,9 +44,10 @@ whose expert lives elsewhere adds nothing here — no stand-in for the
 absent chips' work or traffic. Unset, every expert is held and the layer
 is what it was.
 
-Experts are GELU (``moe_w_up`` / ``moe_w_down``, the default) or SwiGLU
-(arch ``ffn: "swiglu"``: ``moe_w_gate`` beside them,
-``down(silu(gate(x)) * up(x))``), of width ``moe_d_ff``.
+Experts are GELU (``moe_w_up`` / ``moe_w_down``, the default) or gated,
+``moe_w_gate`` beside them: SwiGLU (arch ``ffn: "swiglu"``,
+``down(silu(gate(x)) * up(x))``) or ReGLU (``ffn: "reglu"``,
+``down(relu(gate(x)) * up(x))``), of width ``moe_d_ff``.
 
 Dispatch is **sparse** on one device: the
 N·k token-slots are sorted by expert, each expert's rows go through one
@@ -99,6 +106,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+from relayrl_tpu.models.mlp import GATED_FFN
 
 # Spread of the seeded ``moe_expert_bias`` (sigmoid router): against scores
 # whose 4th and 5th lie ~0.02 apart it moves the choice of about every
@@ -228,7 +237,10 @@ class MoEMLP(nn.Module):
     held: tuple[int, int] | None = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_x=None):
+        """``x [B, T, d]``: the rows the experts read. ``route_x``: the
+        rows the router reads where they are not the same (the layer's
+        un-normed input under ``moe_router_input: "layer"``); None: ``x``."""
         B, T, d = x.shape
         n = B * T
         n_exp = self.n_experts
@@ -241,8 +253,9 @@ class MoEMLP(nn.Module):
             raise ValueError(f"moe_held {self.held} outside 0..{n_exp}")
         partial = n_held < n_exp
 
+        routed = tokens if route_x is None else route_x.reshape(n, d)
         logits = nn.Dense(n_exp, dtype=jnp.float32, use_bias=self.use_bias,
-                          name="moe_gate")(tokens.astype(jnp.float32))
+                          name="moe_gate")(routed.astype(jnp.float32))
         bias = None
         if self.expert_bias:
             # enters the choice only: zero gradient, never moved; seeded
@@ -254,7 +267,7 @@ class MoEMLP(nn.Module):
                                bias)                               # [N, k]
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        gated = self.ffn == "swiglu"
+        gated = self.ffn in GATED_FFN
         if gated:
             w_gate = self.param("moe_w_gate", init, (n_held, d, self.d_ff),
                                 jnp.float32).astype(cd)
@@ -264,7 +277,7 @@ class MoEMLP(nn.Module):
                             jnp.float32).astype(cd)
 
         def act(up, gate=None):
-            return nn.silu(gate) * up if gated else nn.gelu(up)
+            return GATED_FFN[self.ffn](gate) * up if gated else nn.gelu(up)
 
         # token-slots per (held) expert: the grouped matmuls' group sizes
         # and the load monitor (a compare-and-reduce; a scatter-add

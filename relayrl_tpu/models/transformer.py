@@ -42,6 +42,21 @@ normalising each head's width (``True``: the whole projection, OLMoE's).
 RoPE turns q and k in attention layers only; a conv layer sees no
 positions.
 
+A third kind of layer is windowed attention (SmallThinker-21BA3B's,
+``benchmark/configs/smallthinker-policy.json``): ``layer_types`` entry
+``"sliding_attention"`` with ``sliding_window`` — query ``t`` sees the
+``sliding_window`` keys up to its own — in every backend (the flash
+kernels visit the band's blocks only; ``"ring"`` refuses a window) and
+every mode: a windowed layer's cache is a RING of ``min(sliding_window,
+length)`` rows, position ``p`` in row ``p % rows``, beside the full-length
+pairs of the global layers. ``head_dim`` gives the heads a width of their
+own (28 heads of 128 under a hidden size of 2560: q and the output
+projection are ``n_heads * head_dim`` wide); ``rope_layers`` says, layer
+by layer, which attention layers RoPE turns (the others see NO positional
+signal: under ``positions: "rope"`` there is no table either);
+``moe_router_input: "layer"`` hands the expert layer's router the layer's
+un-normed input (models/moe.py); ``ffn: "reglu"`` gates with ReLU.
+
 Sequence ABI: ``evaluate(params, obs[B,T,D], act[B,T], mask[B,T,A]) ->
 (logp[B,T], ent[B,T], v[B,T])`` — same shapes the per-step MLP family
 broadcasts to, so REINFORCE/PPO updates take this policy unchanged.
@@ -61,6 +76,7 @@ from flax import linen as nn
 
 from relayrl_tpu.models.base import Policy, register_model
 from relayrl_tpu.models.mlp import (
+    GATED_FFN,
     _MASK_FILL,
     _categorical_entropy,
     _categorical_logp,
@@ -90,52 +106,66 @@ def _resolve_attention(arch: Mapping[str, Any]
     (``Policy.attention_layout``) to the operand layout they ran in
     (``ops.flash.lane_layout``: ``"2 heads a step"`` of the projections'
     own ``[B, T, H * D]``, or ``"head-major"`` where the head axis is
-    transposed out of the lanes); the line says both.
+    transposed out of the lanes); the line says both. ``attn_fn`` takes a
+    fourth argument, a layer's ``window``; a windowed layer's score area
+    and layout are recorded under ``(T, head_dim, dtype, window)``, beside
+    the global layers' of the same shape, and its line says ``window W``
+    (``resolved`` keeps the three-part key: a shape runs one backend
+    whatever the mask).
     """
     kind = arch.get("attention", "dense")
     block = int(arch.get("attention_block", 128))
     resolved: dict[tuple[int, int, str], str] = {}
-    score_area: dict[tuple[int, int, str], float] = {}
-    layout: dict[tuple[int, int, str], str] = {}
+    score_area: dict[tuple, float] = {}
+    layout: dict[tuple, str] = {}
+    said: dict[tuple, str] = {}  # one line a shape and layer kind
 
     def ran(q, backend: str, area_pct: float | None = None,
-            k=None, heads_a_step: int | None = None) -> None:
+            k=None, heads_a_step: int | None = None,
+            window: int | None = None) -> None:
         key = (int(q.shape[1]), int(q.shape[3]), q.dtype.name)
-        if resolved.get(key) != backend:
-            resolved[key] = backend
+        # a windowed layer's records sit beside the global layers' of the
+        # same shape, under the shape's key with the window appended
+        kind_key = key if window is None else key + (int(window),)
+        if said.get(kind_key) != backend:
+            said[kind_key] = resolved[key] = backend
             area = ""
             heads = ""
             if k is not None and k.shape[2] != q.shape[2]:  # grouped-query
                 heads = f" heads {q.shape[2]}/{k.shape[2]}"
+            if window is not None:
+                heads += f" window {window}"
             if area_pct is not None:
-                score_area[key] = area_pct
-                layout[key] = ("head-major" if heads_a_step is None
-                               else f"{heads_a_step} heads a step")
+                score_area[kind_key] = area_pct
+                layout[kind_key] = ("head-major" if heads_a_step is None
+                                    else f"{heads_a_step} heads a step")
                 area = (f", score area {area_pct:g}%, "
-                        f"layout {layout[key]}")
+                        f"layout {layout[kind_key]}")
             if kind in ("flash", "ring"):
                 print(f"[attention] {kind!r} T={key[0]} head_dim={key[1]} "
                       f"{key[2]}{heads} -> {backend}{area} "
                       f"(platform {jax.default_backend()})", flush=True)
 
-    def dense(q, k, v):
-        ran(q, "dense")
-        return dense_attention(q, k, v, causal=True)
+    def dense(q, k, v, window=None):
+        ran(q, "dense", window=window)
+        return dense_attention(q, k, v, causal=True, window=window)
 
-    def blockwise(q, k, v):
-        ran(q, "blockwise")
-        return blockwise_attention(q, k, v, block, causal=True)
+    def blockwise(q, k, v, window=None):
+        ran(q, "blockwise", window=window)
+        return blockwise_attention(q, k, v, block, causal=True,
+                                   window=window)
 
-    def local(q, k, v):
+    def local(q, k, v, window=None):
         """The single-device XLA path "flash" and "ring" fall back to."""
-        return (blockwise if q.shape[1] % block == 0 else dense)(q, k, v)
+        return (blockwise if q.shape[1] % block == 0 else dense)(
+            q, k, v, window)
 
     if kind == "dense":
         return dense, resolved, score_area, layout
     if kind == "blockwise":
         return blockwise, resolved, score_area, layout
     if kind == "flash":
-        def flash_or_local(q, k, v):
+        def flash_or_local(q, k, v, window=None):
             # Pallas kernel on TPU; off-TPU (CPU actor hosts, CI) the same
             # arch config resolves to the lax.scan blockwise path — the
             # heterogeneous-placement rule ring attention also follows.
@@ -149,15 +179,19 @@ def _resolve_attention(arch: Mapping[str, Any]
             T = q.shape[1]
             fblock = int(arch.get("flash_block", 1024))
             if jax.default_backend() == "tpu" and T % min(fblock, T) == 0:
+                band = window if window is not None and window < T else None
                 ran(q, "flash_pallas", flash.score_area_pct(
-                    T, *flash.tiling(T, True, fblock, fblock), True), k,
-                    flash.lane_layout(q.shape[2], k.shape[2], q.shape[3]))
+                    T, *flash.tiling(T, True, fblock, fblock, band), True,
+                    band), k,
+                    flash.lane_layout(q.shape[2], k.shape[2], q.shape[3]),
+                    window)
                 return flash.flash_attention(q, k, v, causal=True,
-                                             block_q=fblock, block_kv=fblock)
-            return local(q, k, v)
+                                             block_q=fblock, block_kv=fblock,
+                                             window=window)
+            return local(q, k, v, window)
         return flash_or_local, resolved, score_area, layout
     if kind == "ring":
-        def ring_or_local(q, k, v):
+        def ring_or_local(q, k, v, window=None):
             from relayrl_tpu.parallel.context import current_mesh
             from relayrl_tpu.parallel.ring import make_ring_attention
             from relayrl_tpu.parallel.ring_flash import (
@@ -167,7 +201,12 @@ def _resolve_attention(arch: Mapping[str, Any]
 
             mesh = current_mesh()
             if mesh is None or mesh.shape.get("sp", 1) <= 1:
-                return local(q, k, v)
+                return local(q, k, v, window)
+            if window is not None:
+                raise ValueError(
+                    "ring attention takes no window; sliding_attention "
+                    "layers run under attention 'flash', 'blockwise' or "
+                    "'dense'")
             if k.shape[2] != q.shape[2]:
                 raise ValueError(
                     "ring attention takes one head count for q, k and v; "
@@ -248,27 +287,36 @@ def _block_dense(block: "TransformerBlock", features: int, name: str):
                     use_bias=block.use_bias)
 
 
-def _block_ffn(block: "TransformerBlock", x):
+def _block_ffn(block: "TransformerBlock", x, layer_in):
     """``x + FFN(norm(x))`` in ``block``'s param scope: the arch's dense
-    FFN or the MoE layer. (A plain function, like :func:`_embed_obs`: a
-    module method would be wrapped by flax once per call.)"""
+    FFN or the MoE layer. ``layer_in``: the rows of the layer's own input
+    that ``x``'s rows came from, which the MoE layer's router reads under
+    ``moe_router_input: "layer"``. (A plain function, like
+    :func:`_embed_obs`: a module method would be wrapped by flax once per
+    call.)"""
     h = _norm(block.norm, block.norm_eps, "ln_mlp")(x)
     width = block.d_ff or block.mlp_ratio * block.d_model
-    if block.ffn not in ("gelu", "swiglu"):
-        raise ValueError(f"unknown ffn {block.ffn!r} (gelu | swiglu)")
+    if block.ffn != "gelu" and block.ffn not in GATED_FFN:
+        raise ValueError(f"unknown ffn {block.ffn!r} (gelu | swiglu | reglu)")
     if block.moe_experts > 0:
         from relayrl_tpu.models.moe import MoEMLP
 
+        if block.moe_router_input not in ("ffn", "layer"):
+            raise ValueError(f"unknown moe_router_input "
+                             f"{block.moe_router_input!r} (ffn | layer)")
         h = MoEMLP(block.d_model, block.moe_d_ff or width,
                    block.moe_experts, block.moe_top_k, block.compute_dtype,
                    norm_topk_prob=block.moe_norm_topk_prob, ffn=block.ffn,
                    dispatch=block.moe_dispatch, use_bias=block.use_bias,
-                   **block.moe_kw, name="moe")(h)
+                   **block.moe_kw, name="moe")(
+                       h, layer_in if block.moe_router_input == "layer"
+                       else None)
         return x + h.astype(x.dtype)
     h = h.astype(block.compute_dtype)
     up = _block_dense(block, width, "mlp_up")(h)
-    if block.ffn == "swiglu":
-        h = nn.silu(_block_dense(block, width, "mlp_gate")(h)) * up
+    if block.ffn in GATED_FFN:
+        h = GATED_FFN[block.ffn](
+            _block_dense(block, width, "mlp_gate")(h)) * up
     else:
         h = nn.gelu(up)
     h = _block_dense(block, block.d_model, "mlp_down")(h)
@@ -312,6 +360,15 @@ class TransformerBlock(nn.Module):
     # all d_model // n_heads wide (separate q_proj / k_proj / v_proj).
     # None: one fused qkv, as always. qk_norm "head": RMSNorm over each head.
     n_kv_heads: int | None = None
+    # A head width of its own (None: d_model // n_heads): q and attn_out's
+    # input are n_heads * head_dim wide, projections separate.
+    head_dim: int | None = None
+    # Sliding-window attention: query t sees keys t - window < s <= t
+    # (None: every key up to its own). Set per layer by the core.
+    window: int | None = None
+    # "layer": the MoE router reads this layer's input as it arrives (before
+    # the operator, un-normed); "ffn": the rows the experts read.
+    moe_router_input: str = "ffn"
 
     @nn.compact
     def __call__(self, x, cache=None, t=None, readout_idx=None,
@@ -322,7 +379,9 @@ class TransformerBlock(nn.Module):
         layer's state and ``t`` the write index. An attention layer's
         state is its ``(k, v)`` pair ``[B, W, Hkv, hd]`` (``Hkv`` =
         ``n_kv_heads``: grouped-query k/v are cached as they are, and the
-        q heads of a group read the same rows); a conv layer's is the last
+        q heads of a group read the same rows) — of a windowed layer a
+        ring of ``min(window, W)`` rows (:func:`_ring_cached`); a conv
+        layer's is the last
         ``conv_taps - 1`` rows of ``B * u``, ``[B, conv_taps - 1, d]``
         (``n_valid``, prefill only: how many of x's rows are real — the
         state is taken from the rows before that; None: all of them).
@@ -352,16 +411,18 @@ class TransformerBlock(nn.Module):
         if self.op != "attention":
             raise ValueError(f"unknown layer operator {self.op!r} "
                              f"(attention | conv)")
-        head_dim = self.d_model // self.n_heads
+        head_dim = self.head_dim or self.d_model // self.n_heads
+        width = self.n_heads * head_dim     # of q and of attn_out's input
+        layer_in = x
         h = _norm(self.norm, self.norm_eps, "ln_attn")(x)
         h = h.astype(self.compute_dtype)
-        if self.n_kv_heads is None:
+        if self.n_kv_heads is None and self.head_dim is None:
             n_kv = self.n_heads
             qkv = _block_dense(self, 3 * self.d_model, "qkv")(h)
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
-            n_kv = self.n_kv_heads
-            q = _block_dense(self, self.d_model, "q_proj")(h)
+            n_kv = self.n_kv_heads or self.n_heads
+            q = _block_dense(self, width, "q_proj")(h)
             k = _block_dense(self, n_kv * head_dim, "k_proj")(h)
             v = _block_dense(self, n_kv * head_dim, "v_proj")(h)
         if self.qk_norm is True:
@@ -389,17 +450,20 @@ class TransformerBlock(nn.Module):
             if rope:
                 q_row = apply_rope(q_row, readout_idx, self.rope_theta)
             attn = dense_attention(q_row, k, v, causal=True,
-                                   q_offset=readout_idx)
-            attn = attn.reshape(B, 1, self.d_model)
-            x = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1, axis=1)
-            x = x + _block_dense(self, self.d_model, "attn_out")(
+                                   q_offset=readout_idx, window=self.window)
+            attn = attn.reshape(B, 1, width)
+            row_in = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1, axis=1)
+            x = row_in + _block_dense(self, self.d_model, "attn_out")(
                 attn).astype(x.dtype)
-            return _block_ffn(self, x)
+            return _block_ffn(self, x, row_in)
         if rope:
             q = apply_rope(q, 0 if t is None else t, self.rope_theta)
         if cache is None:
-            attn = self.attn_fn(q, k, v)
+            attn = self.attn_fn(q, k, v, self.window)
             new_cache = None
+        elif self.window is not None:
+            attn, new_cache = _ring_cached(q, k, v, cache, t, self.window,
+                                           n_valid)
         else:
             k_cache, v_cache = cache
             k_cache = jax.lax.dynamic_update_slice_in_dim(
@@ -413,11 +477,45 @@ class TransformerBlock(nn.Module):
             attn = dense_attention(q, k_cache, v_cache, causal=True,
                                    q_offset=t)
             new_cache = (k_cache, v_cache)
-        attn = attn.reshape(B, T, self.d_model)
+        attn = attn.reshape(B, T, width)
         x = x + _block_dense(self, self.d_model, "attn_out")(attn).astype(
             x.dtype)
-        out = _block_ffn(self, x)
+        out = _block_ffn(self, x, layer_in)
         return out if cache is None else (out, new_cache)
+
+
+def _ring_cached(q, k, v, cache, t, window: int, n_valid):
+    """A windowed layer's two cached modes -> ``(attn, new_cache)``. The
+    cache is a ring: ``(k, v)`` of ``rows = min(window, W)`` rows, position
+    ``p`` in row ``p % rows`` (keys rotated at their absolute positions,
+    where the layer has RoPE, before they go in). Softmax does not care
+    about the order of its keys, so a row's position is all a step needs.
+
+    One decode step (``T == 1``, position ``t``): write row ``t % rows``,
+    then attend every row under the positions the ring now holds — row
+    ``s`` the newest ``p <= t`` with ``p % rows == s``, negative while
+    nothing was written there. Prefill (``T > 1``): the rows are a
+    sequence's FIRST ``T`` positions (``t = 0``: what the cache held is
+    replaced, not read); windowed attention among them, then the ring takes
+    the last ``rows`` of the ``n_valid`` real ones (None: all ``T``) —
+    padding rows never enter, they would overwrite live ones."""
+    k_cache, v_cache = cache
+    rows, T = k_cache.shape[1], q.shape[1]
+    slot = jnp.arange(rows)
+    if T == 1:
+        k_cache = jax.lax.dynamic_update_slice_in_dim(
+            k_cache, k.astype(k_cache.dtype), t % rows, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(
+            v_cache, v.astype(v_cache.dtype), t % rows, axis=1)
+        attn = dense_attention(q, k_cache, v_cache, causal=True, q_offset=t,
+                               window=window,
+                               kv_positions=t - jnp.mod(t - slot, rows))
+        return attn, (k_cache, v_cache)
+    attn = dense_attention(q, k, v, causal=True, window=window)
+    n = T if n_valid is None else n_valid
+    newest = jnp.clip((n - 1) - jnp.mod(n - 1 - slot, rows), 0, T - 1)
+    return attn, (jnp.take(k, newest, axis=1).astype(k_cache.dtype),
+                  jnp.take(v, newest, axis=1).astype(v_cache.dtype))
 
 
 def _conv_layer(block: TransformerBlock, x, cache, readout_idx, n_valid):
@@ -447,9 +545,10 @@ def _conv_layer(block: TransformerBlock, x, cache, readout_idx, n_valid):
         real = (readout_idx - back + jnp.arange(back + 1)) >= 0
         bcu = jnp.where(real[None, :, None], in_proj(rows), 0)
         y = _short_conv(bcu, w)[0][:, back:]
-        return _block_ffn(block, out_proj(rows[:, back:], y))
+        return _block_ffn(block, out_proj(rows[:, back:], y),
+                          rows[:, back:])
     y, zp = _short_conv(in_proj(x), w, cache)
-    out = _block_ffn(block, out_proj(x, y))
+    out = _block_ffn(block, out_proj(x, y), x)
     if cache is None:
         return out
     # zp row j is z row j - back: the state after n real rows is z rows
@@ -515,18 +614,32 @@ class TransformerCore(nn.Module):
     moe_top_k: int = 2
     # TransformerBlock's arch fields, passed through as one dict
     block_kw: Mapping[str, Any] = flax.core.FrozenDict()
-    # Per layer: its operator ("full_attention" | "conv"; empty: attention
-    # everywhere) and, in a MoE trunk, how many leading layers keep the
-    # dense FFN.
+    # Per layer: its operator ("full_attention" | "sliding_attention" |
+    # "conv"; empty: full attention everywhere) and, in a MoE trunk, how
+    # many leading layers keep the dense FFN.
     layer_types: tuple[str, ...] = ()
     moe_dense_layers: int = 0
+    # the window of the "sliding_attention" layers
+    sliding_window: int | None = None
+    # Per layer under rotary positions: whether RoPE turns its q and k
+    # (empty: every attention layer's). A layer left out sees no positions.
+    rope_layers: tuple[bool, ...] = ()
 
     def layer_op(self, i: int) -> str:
         kind = self.layer_types[i] if self.layer_types else "full_attention"
-        if kind not in ("full_attention", "conv"):
-            raise ValueError(f"unknown layer type {kind!r} "
-                             f"(full_attention | conv)")
+        if kind not in ("full_attention", "sliding_attention", "conv"):
+            raise ValueError(f"unknown layer type {kind!r} (full_attention "
+                             f"| sliding_attention | conv)")
         return "conv" if kind == "conv" else "attention"
+
+    def layer_window(self, i: int) -> int | None:
+        """The window of layer ``i`` (None: a global layer, or a conv)."""
+        if not self.layer_types or self.layer_types[i] != "sliding_attention":
+            return None
+        if not self.sliding_window or self.sliding_window < 1:
+            raise ValueError(f"layer {i} is sliding_attention and the arch "
+                             f"gives no sliding_window")
+        return int(self.sliding_window)
 
     def layer_experts(self, i: int) -> int:
         return 0 if i < self.moe_dense_layers else self.moe_experts
@@ -536,7 +649,8 @@ class TransformerCore(nn.Module):
                  n_valid=None):
         """Full mode: obs ``[B, T, D]`` -> (logits, v). Decode mode
         (``cache`` = tuple of per-layer states — a (k, v) pair for an
-        attention layer, the last rows of ``B * u`` for a conv layer —,
+        attention layer (a ring of rows for a windowed one), the last rows
+        of ``B * u`` for a conv layer —,
         ``t`` = position; ``n_valid``: prefill's count of real rows):
         obs is ``[B, 1, D]``; returns ``((logits, v), new_cache)`` for the
         single position. Readout mode (``readout_t`` = dynamic row index):
@@ -549,16 +663,21 @@ class TransformerCore(nn.Module):
         decode = cache is not None
         kw = self.block_kw
 
-        if self.layer_types and len(self.layer_types) != self.n_layers:
-            raise ValueError(f"layer_types names {len(self.layer_types)} "
-                             f"layers, n_layers is {self.n_layers}")
+        for what, per_layer in (("layer_types", self.layer_types),
+                                ("rope_layers", self.rope_layers)):
+            if per_layer and len(per_layer) != self.n_layers:
+                raise ValueError(f"{what} names {len(per_layer)} layers, "
+                                 f"n_layers is {self.n_layers}")
 
         def block_at(i: int) -> TransformerBlock:
+            kw_i = kw
+            if self.rope_layers and not self.rope_layers[i]:
+                kw_i = {**kw, "rope_theta": None}   # no positions at all
             return TransformerBlock(
                 self.d_model, self.n_heads, self.mlp_ratio, self.attn_fn,
                 self.compute_dtype, moe_experts=self.layer_experts(i),
                 moe_top_k=self.moe_top_k, op=self.layer_op(i),
-                name=f"block_{i}", **kw)
+                window=self.layer_window(i), name=f"block_{i}", **kw_i)
 
         def heads(x, mask):
             return _readout_heads(x, mask, self.act_dim, self.d_model,
@@ -699,12 +818,14 @@ def _policy_from_apply(arch: Mapping[str, Any], init_params, apply_fn,
 # An arch with none of them is the GPT-2 shaped block.
 _BLOCK_ARCH_KEYS = ("norm", "norm_eps", "qk_norm", "use_bias", "ffn", "d_ff",
                     "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch",
-                    "n_kv_heads", "conv_taps")
+                    "n_kv_heads", "conv_taps", "head_dim",
+                    "moe_router_input")
 # MoEMLP's fields by the arch key that sets each (block field ``moe_kw``)
 _MOE_ARCH_KEYS = {"moe_router": "router", "moe_expert_bias": "expert_bias",
                   "moe_held": "held"}
 # the core's own: what kind each layer is
-_LAYER_ARCH_KEYS = ("layer_types", "moe_dense_layers")
+_LAYER_ARCH_KEYS = ("layer_types", "moe_dense_layers", "sliding_window",
+                    "rope_layers")
 
 
 def _block_kwargs(arch: Mapping[str, Any]) -> dict:
@@ -745,6 +866,8 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         block_kw=flax.core.FrozenDict(_block_kwargs(arch)),
         layer_types=tuple(arch.get("layer_types", ())),
         moe_dense_layers=int(arch.get("moe_dense_layers", 0)),
+        sliding_window=arch.get("sliding_window"),
+        rope_layers=tuple(bool(r) for r in arch.get("rope_layers", ())),
     )
 
 
@@ -757,22 +880,27 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     def init_params(rng):
         return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
 
-    head_dim = core.d_model // core.n_heads
+    head_dim = int(arch.get("head_dim", core.d_model // core.n_heads))
     n_kv_heads = int(arch.get("n_kv_heads", core.n_heads))
     conv_back = int(arch.get("conv_taps", 3)) - 1
     cache_dtype = core.compute_dtype
 
     def init_cache(length: int, batch_size: int = 1):
-        """Zeroed per-layer states for incremental decoding, two kinds
-        side by side: a (k, v) pair ``[B, length, Hkv, hd]`` for an
-        attention layer, the last ``conv_taps - 1`` rows of ``B * u``
-        ``[B, conv_taps - 1, d]`` for a conv layer."""
-        kv = (batch_size, int(length), n_kv_heads, head_dim)
+        """Zeroed per-layer states for incremental decoding, three kinds
+        side by side: a (k, v) pair ``[B, length, Hkv, hd]`` for a global
+        attention layer, a ring ``[B, min(window, length), Hkv, hd]`` x 2
+        for a windowed one (``_ring_cached``), the last ``conv_taps - 1``
+        rows of ``B * u`` ``[B, conv_taps - 1, d]`` for a conv layer."""
         conv = (batch_size, conv_back, core.d_model)
+
+        def kv_pair(i: int):
+            rows = min(core.layer_window(i) or int(length), int(length))
+            kv = (batch_size, rows, n_kv_heads, head_dim)
+            return jnp.zeros(kv, cache_dtype), jnp.zeros(kv, cache_dtype)
+
         return tuple(
             jnp.zeros(conv, cache_dtype) if core.layer_op(i) == "conv"
-            else (jnp.zeros(kv, cache_dtype), jnp.zeros(kv, cache_dtype))
-            for i in range(core.n_layers))
+            else kv_pair(i) for i in range(core.n_layers))
 
     def step_cached(params, rng, cache, obs, t, mask=None):
         """One O(W) decode step: writes position ``t`` into the cache and
@@ -806,7 +934,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         t=0. Padding rows write garbage K/V beyond the real prefix, which
         later per-step decodes never attend (their causal mask stops at
         the current t) and overwrite in order. A conv layer's state has
-        no positions to overwrite: it is taken from the rows before
+        no positions to overwrite, and a windowed layer's ring would lose
+        live rows to padding ones: both are taken from the rows before
         ``n_valid``, the count of real rows (None: the whole window is
         real)."""
         window = jnp.asarray(window)

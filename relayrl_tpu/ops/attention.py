@@ -20,6 +20,13 @@ blockwise forms then fold the ``G`` query heads of a group into the query
 time axis — ``[B, G * Tq, Hkv, D]``, each folded row keeping its own time
 position for the causal mask — so k and v are used as they are, never
 repeated. With ``H == Hkv`` nothing is folded and the code is what it was.
+
+Sliding-window attention: ``window`` (causal calls) lets query ``t`` see
+keys ``s`` with ``t - window < s <= t`` — ``window`` keys with its own.
+Both forms take it as one more term of the mask they already build
+(:func:`visible`); the blockwise scan still visits every K/V block (it is
+the CPU actors' and the tests' path — the kernels of
+:mod:`relayrl_tpu.ops.flash` are what skip the blocks outside the band).
 """
 
 from __future__ import annotations
@@ -51,16 +58,34 @@ def _unfold_groups(out: jax.Array, G: int) -> jax.Array:
     return out.reshape(B, GT // G, Hkv * G, D)
 
 
+def visible(q_pos: jax.Array, kv_pos: jax.Array,
+            window: int | None = None) -> jax.Array:
+    """Bool ``[Tq, Tk]``: the causal mask over global positions, narrowed
+    to the ``window`` keys up to the query's own where one is given."""
+    seen = q_pos[:, None] >= kv_pos[None, :]
+    if window is not None:
+        seen &= q_pos[:, None] - kv_pos[None, :] < window
+    return seen
+
+
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     q_offset: int | jax.Array = 0,
-                    kv_offset: int | jax.Array = 0) -> jax.Array:
+                    kv_offset: int | jax.Array = 0,
+                    window: int | None = None,
+                    kv_positions: jax.Array | None = None) -> jax.Array:
     """Plain softmax attention on ``[B, Tq, H, D] x [B, Tk, H, D]``.
 
     ``q_offset``/``kv_offset`` are the global time positions of the first
     query/key — used by the blockwise and ring variants to apply a causal
-    mask across blocks that live on different devices.
+    mask across blocks that live on different devices. ``kv_positions``
+    ``[Tk]`` gives each key row's position outright where the rows are not
+    in order (a ring cache; negative: an empty row). ``window``: see the
+    module docstring.
     """
+    if window is not None and not causal:
+        raise ValueError("a window narrows the causal mask: causal=False "
+                         "has none")
     G, Tq = 1, q.shape[1]
     if k.shape[2] != q.shape[2]:
         q, G = _fold_groups(q, k.shape[2])
@@ -71,9 +96,11 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         q_pos = q_offset + jnp.arange(Tq)
         if G > 1:
             q_pos = jnp.tile(q_pos, G)
-        kv_pos = kv_offset + jnp.arange(k.shape[1])
-        mask = q_pos[:, None] >= kv_pos[None, :]
-        s = jnp.where(mask[None, None], s, _NEG_INF)
+        if kv_positions is None:
+            seen = visible(q_pos, kv_offset + jnp.arange(k.shape[1]), window)
+        else:  # a row at a negative position does not exist yet
+            seen = visible(q_pos, kv_positions, window) & (kv_positions >= 0)
+        s = jnp.where(seen[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
     return out if G == 1 else _unfold_groups(out, G)
@@ -112,14 +139,19 @@ def finalize_attention(o: jax.Array, l: jax.Array, out_dtype) -> jax.Array:
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_size: int = 128,
-                        causal: bool = True) -> jax.Array:
+                        causal: bool = True,
+                        window: int | None = None) -> jax.Array:
     """Memory-efficient attention: ``lax.scan`` over KV blocks.
 
     Peak memory is O(Tq * block_size) instead of O(Tq * Tk); numerics match
     :func:`dense_attention` (same online-softmax math flash attention uses).
     Requires ``T % block_size == 0`` (pad to fixed shapes upstream — variable
-    shapes would recompile, SURVEY.md §7.4 item 3).
+    shapes would recompile, SURVEY.md §7.4 item 3). ``window``: see the
+    module docstring.
     """
+    if window is not None and not causal:
+        raise ValueError("a window narrows the causal mask: causal=False "
+                         "has none")
     B, T, H, D = q.shape
     if T % block_size != 0:
         raise ValueError(f"seq len {T} not divisible by block {block_size}")
@@ -140,7 +172,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         k_blk, v_blk, blk_idx = blk
         kv_pos = blk_idx * block_size + jnp.arange(block_size)
         if causal:
-            mask = q_pos[:, None] >= kv_pos[None, :]
+            mask = visible(q_pos, kv_pos, window)
         else:
             mask = jnp.ones((G * T, block_size), bool)
         return attention_block_combine(carry, q, k_blk, v_blk, mask), None

@@ -55,6 +55,36 @@ the same list of strips the kernels walk. A grid of one block a head
 carries no state between steps, so there each strip writes its output
 rows directly: no scratch, no init, no finalize pass.
 
+Sliding-window calls (``window``: query ``t`` sees keys ``s`` with ``t -
+window < s <= t``) do not walk the sequence at all: **the innermost grid
+axis is as long as the band**, ``window / block + 1`` steps for a window of
+whole blocks (5 of 16 at T 16384, block 1024, window 4096), and the index
+maps offset it by the outer block (``_band_step``) — forward and dq visit
+K/V blocks ``i - (nband - 1) .. i`` of q block ``i``, dk/dv the q blocks
+``j .. j + nband - 1`` of K/V block ``j`` (for every q head of the group).
+A block outside the band is therefore neither loaded nor computed, where
+the causal call's blocks above the diagonal are predicated off but still
+loaded. A step that falls before block 0 (past the last, in dk/dv)
+computes nothing and names the block its neighbour names, so Pallas skips
+its DMA too. Inside the band a block is the diagonal one (causal strips, as
+above), wholly inside the window (mask-free), or cut by the window's lower
+edge: where that edge runs corner to corner — a window of whole blocks —
+the block is walked in the mirror image of the diagonal strips (``sub``
+queries against the keys AFTER them, ``_strips(lower=True)``), so what
+lies below the edge beyond a strip's corner is skipped like what lies above
+the diagonal; any other window masks that block as one tile, and a window
+shorter than a block puts both edges into the diagonal block, one tile, one
+mask. At (16384, 1024, 4096) the band needs 21.9 % of T x T, the kernels
+compute 23.2 % (27.3 % without the strip walk of the cut block; the causal
+call 50.8 %). The windowed kernels carry names that extend the plain ones
+(``relayrl_flash_fwd_win`` ...), so a reader of all attention time finds
+them and one that wants the band alone can tell them apart; ``window=None``
+lowers the kernels that were there, a window of T or more is that call.
+Measured on the v5e (PR 34, PERF.md section 6): a windowed layer of
+``smallthinker-policy.update`` takes 0.43 of the global layer's kernel
+time for 0.44 of its scores; the strip height of the cut block is the
+diagonal's and was not swept on its own.
+
 The backward pass is two more Pallas kernels (the standard two-pass flash
 VJP — no atomics or cross-block communication): a dq pass (grid q-major,
 KV innermost, accumulator in VMEM) and a dk/dv pass (grid kv-major, Q
@@ -160,6 +190,10 @@ _LOG2E = 1.4426950408889634
 FWD_NAME = "relayrl_flash_fwd"
 DQ_NAME = "relayrl_flash_dq"
 DKV_NAME = "relayrl_flash_dkv"
+# A windowed call's kernels carry the same names with this suffix: a reader
+# that matches ``relayrl_flash_fwd`` finds them too, one that wants the band
+# calls alone asks for the suffix.
+WINDOW_SUFFIX = "_win"
 
 
 # Rows of a causal strip: a grid step on the diagonal is walked ``_SUB_TILE``
@@ -182,33 +216,65 @@ def _sub_tile(block_q: int, block_kv: int, causal: bool) -> int | None:
     return _SUB_TILE
 
 
-def _strips(block: int, sub: int,
-            kv_major: bool = False) -> list[tuple[int, int, int, int]]:
+def _strips(block: int, sub: int, kv_major: bool = False,
+            lower: bool = False) -> list[tuple[int, int, int, int]]:
     """``(q0, nq, k0, nk)``: the score tiles a ``block x block`` grid step
     on the diagonal computes — query rows ``[q0, q0 + nq)`` against key
     rows ``[k0, k0 + nk)``, local to the block. Query-major: ``sub``
     queries against every key at or before them. ``kv_major``: ``sub``
     keys against every query at or after them. Either way the same
-    ``sub x sub`` sub-tiles, those with ``c <= r``. The kernels walk this
+    ``sub x sub`` sub-tiles, those with ``c <= r``. ``lower``: the block a
+    window's lower edge cuts corner to corner (a window of whole blocks:
+    local key ``c`` is seen by local query ``r`` iff ``c > r``) — the
+    mirror image, the sub-tiles with ``c >= r``. The kernels walk this
     list and ``score_area_pct`` sums it."""
+    if lower and kv_major:
+        return [(0, k0 + sub, k0, sub) for k0 in range(0, block, sub)]
+    if lower:
+        return [(q0, sub, q0, block - q0) for q0 in range(0, block, sub)]
     if kv_major:
         return [(k0, block - k0, k0, sub) for k0 in range(0, block, sub)]
     return [(q0, sub, 0, q0 + sub) for q0 in range(0, block, sub)]
 
 
+def _band_blocks(window: int, block: int, n_blocks: int) -> int:
+    """K/V blocks a q block's band touches (q blocks a K/V block's): the
+    diagonal one and those the ``window`` keys before a block's first query
+    reach into — ``window / block + 1`` for a window of whole blocks — at
+    most all ``n_blocks``."""
+    return min((window + block - 2) // block + 1, n_blocks)
+
+
 @functools.lru_cache(maxsize=None)
 def score_area_pct(T: int, block_q: int, block_kv: int, sub: int | None,
-                   causal: bool) -> float:
+                   causal: bool, window: int | None = None) -> float:
     """Share (%) of the ``T x T`` score matrix the kernels compute: all of
     a non-causal call; under the causal mask the grid blocks that are live
     (``_dispatch``'s predicates), of which a block on the diagonal counts
     its strips only. A kernel that skipped everything above the diagonal
-    would read ``50 + 50 / T``."""
+    would read ``50 + 50 / T``. With a ``window`` (< T; equal blocks) only
+    the blocks of the band are visited at all: the diagonal one, those
+    wholly inside the window, and the ones its lower edge cuts, which count
+    their strips where they are walked in strips (a window of whole
+    blocks) and whole where they are masked as one tile."""
     if not causal:
         return 100.0
-    diagonal = (block_q * block_kv if sub is None else
-                sum(nq * nk for _, nq, _, nk in _strips(block_q, sub)))
+    if window is not None and window < block_q:   # both edges in one block
+        diagonal = block_q * block_kv
+    else:
+        diagonal = (block_q * block_kv if sub is None else
+                    sum(nq * nk for _, nq, _, nk in _strips(block_q, sub)))
     area = 0
+    if window is not None:
+        cut = (diagonal if sub is not None and window % block_q == 0
+               else block_q * block_kv)
+        n_blocks = T // block_q
+        for i in range(n_blocks):
+            for d in range(min(i + 1, _band_blocks(window, block_q,
+                                                   n_blocks))):
+                area += (diagonal if d == 0 else block_q * block_kv
+                         if d * block_q <= window - block_q else cut)
+        return 100.0 * area / (T * T)
     for q_start in range(0, T, block_q):
         for k_start in range(0, T, block_kv):
             if k_start + block_kv - 1 <= q_start:        # interior
@@ -219,13 +285,20 @@ def score_area_pct(T: int, block_q: int, block_kv: int, sub: int | None,
 
 
 def _causal_mask(q_start, k_start, nq: int, nk: int,
-                 transposed: bool = False):
+                 transposed: bool = False, window: int | None = None,
+                 above: bool = False):
     """Bool ``[nq, nk]`` (``[nk, nq]`` transposed), true where the query
-    may see the key."""
+    may see the key: the key at or before it and, with a ``window``, fewer
+    than ``window`` rows before it. ``above``: the complement of the causal
+    mask alone, ``k_pos > q_pos`` — what the block a whole-block window's
+    lower edge cuts looks like in positions local to it."""
     shape, q_axis = ((nk, nq), 1) if transposed else ((nq, nk), 0)
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return q_pos >= k_pos
+    if above:
+        return k_pos > q_pos
+    seen = q_pos >= k_pos
+    return seen if window is None else seen & (q_pos - k_pos < window)
 
 
 def _kv_head(b, group: int):
@@ -237,7 +310,9 @@ def _kv_head(b, group: int):
 def _group_step(b, i, group: int, nq: int):
     """dk/dv grid: K/V head ``b``, innermost step ``i`` -> (flat q head,
     q block). The axis holds ``group * nq`` steps: the q blocks of the
-    group's first q head, then its second's..."""
+    group's first q head, then its second's... (A windowed call's axis
+    holds the ``nq = nband`` blocks of the band a head, and the q block
+    returned counts from the band's first: ``_band_step`` places it.)"""
     return (b, i) if group == 1 else (b * group + i // nq, i % nq)
 
 
@@ -328,7 +403,8 @@ def _masked_scores2(q_ref, k_ref, q_start, k_start, masked: bool,
 
 def _dispatch(tile, q_start, k_start, causal: bool, block_q: int,
               block_kv: int, sub: int | None, one_block: bool,
-              kv_major: bool = False):
+              kv_major: bool = False, window: int | None = None,
+              in_grid=None):
     """Shared block-class dispatch for all three kernels: skip blocks
     strictly above the causal diagonal, run mask-free on ``interior``
     blocks (strictly at-or-below it), and pay the iota/compare/select
@@ -342,16 +418,28 @@ def _dispatch(tile, q_start, k_start, causal: bool, block_q: int,
     with a ``sub`` is walked strip by strip (``_strips``); ``kv_major``
     (the dk/dv pass) takes key strips and wants its masks transposed.
     ``one_block``: the grid has one block a head, which is the diagonal one
-    — no predicate, and no dead interior body for Mosaic to compile."""
+    — no predicate, and no dead interior body for Mosaic to compile.
+
+    With a ``window`` the grid holds the blocks of the band only
+    (``_band_step``; ``in_grid``: false on a step that fell off the grid's
+    end and repeats its neighbour's blocks), and a block is classed by how
+    many rows its queries lie after its keys: 0 — the diagonal one, as
+    above; up to ``window - block`` — wholly inside, mask-free; more — cut
+    by the window's lower edge, walked in the mirror image of the diagonal
+    strips where the edge runs corner to corner (a window of whole blocks)
+    and masked as one tile otherwise. A window shorter than a block puts
+    both edges into the diagonal block: one tile, one mask."""
     whole = slice(None)
     if not causal:
         tile(whole, whole, None)
         return
+    both_edges = window is not None and window < block_q
 
     def diagonal():
-        if sub is None:
-            tile(whole, whole, _causal_mask(q_start, k_start, block_q,
-                                            block_kv, kv_major))
+        if sub is None or both_edges:
+            tile(whole, whole, _causal_mask(
+                q_start, k_start, block_q, block_kv, kv_major,
+                window if both_edges else None))
             return
         # Equal blocks: on the diagonal q_start == k_start, so positions
         # local to the block decide the mask and it is static.
@@ -362,17 +450,59 @@ def _dispatch(tile, q_start, k_start, causal: bool, block_q: int,
     if one_block:
         diagonal()
         return
-    live = k_start <= q_start + block_q - 1
-    interior = k_start + block_kv - 1 <= q_start
-    pl.when(interior)(lambda: tile(whole, whole, None))
-    pl.when(live & jnp.logical_not(interior))(diagonal)
+    if window is None:
+        live = k_start <= q_start + block_q - 1
+        interior = k_start + block_kv - 1 <= q_start
+        pl.when(interior)(lambda: tile(whole, whole, None))
+        pl.when(live & jnp.logical_not(interior))(diagonal)
+        return
+
+    def cut():
+        if sub is None or window % block_q:
+            tile(whole, whole, _causal_mask(q_start, k_start, block_q,
+                                            block_kv, kv_major, window))
+            return
+        for q0, nq, k0, nk in _strips(block_q, sub, kv_major, lower=True):
+            tile(pl.ds(q0, nq), pl.ds(k0, nk),
+                 _causal_mask(q0, k0, nq, nk, kv_major, above=True))
+
+    rows_apart = q_start - k_start      # a multiple of the block, >= 0
+    pl.when(in_grid & (rows_apart == 0))(diagonal)
+    if window >= 2 * block_q:           # some block lies wholly inside
+        pl.when(in_grid & (rows_apart > 0)
+                & (rows_apart <= window - block_q))(
+                    lambda: tile(whole, whole, None))
+    pl.when(in_grid & (rows_apart > max(window - block_q, 0)))(cut)
+
+
+def _band_step(outer, inner, nband: int | None, n_blocks: int = 0,
+               kv_major: bool = False):
+    """A windowed call's innermost grid axis is as long as the band
+    (``_band_blocks``), not as the sequence: ``(block, in_grid)`` of step
+    ``inner`` beside outer block ``outer``. Forward and dq (q block
+    outside): K/V blocks ``outer - (nband - 1) .. outer``, in key order;
+    ``kv_major`` (dk/dv, K/V block outside): q blocks ``outer .. outer +
+    nband - 1``. A step that falls before block 0 or past the last block
+    computes nothing (``in_grid`` false) and names the nearest block of the
+    grid, the one its neighbour names, so that nothing new is loaded for
+    it. ``nband`` None — no window: the axis is the sequence, every step
+    in the grid."""
+    if nband is None:
+        return inner, None
+    if kv_major:
+        block = outer + inner
+        return jnp.minimum(block, n_blocks - 1), block < n_blocks
+    block = outer - (nband - 1) + inner
+    return jnp.maximum(block, 0), block >= 0
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, causal: bool,
                 block_q: int, block_kv: int, sub: int | None,
-                one_block: bool, hps: int, D: int, group: int):
+                one_block: bool, hps: int, D: int, group: int,
+                window: int | None = None, nband: int | None = None):
     q_start = pl.program_id(1) * block_q
-    k_start = pl.program_id(2) * block_kv
+    kv_block, in_grid = _band_step(pl.program_id(1), pl.program_id(2), nband)
+    k_start = kv_block * block_kv
     heads = _head_lanes(hps, D)
     if _shares_kv(hps, group):
         k_ref, v_ref = _shared_kv(
@@ -402,7 +532,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, causal: bool,
             o_ref[0, rows, :] = _by_head(outs, heads).astype(o_ref.dtype)
 
         _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
-                  one_block)
+                  one_block, window=window)
         return
 
     acc_ref, m_ref, l_ref = scratch
@@ -422,7 +552,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, causal: bool,
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             # Masked entries carry s == _NEG_INF; with KV innermost, block
             # ik == 0 is fully live, so m_new is finite for every valid row
-            # and exp2(_NEG_INF - m_new) flushes to exactly 0.
+            # and exp2(_NEG_INF - m_new) flushes to exactly 0. (A windowed
+            # call's first live block is the one the window's lower edge
+            # cuts, where a row may see no key yet: its m stays _NEG_INF
+            # and p is all ones there, and the next block's corr =
+            # exp2(_NEG_INF - m_new) = 0 wipes that from l and acc — the
+            # diagonal block, the last, holds a live key for every row.)
             p = jnp.exp2(s - m_new)
             corr = jnp.exp2(m_prev - m_new)
             l_ref[h, rows] = (l_ref[h, rows] * corr
@@ -434,7 +569,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, causal: bool,
                          + _by_head(pvs, heads))
 
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
-              one_block)
+              one_block, window=window, in_grid=in_grid)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _finalize():
@@ -467,17 +602,21 @@ def _lane_block(g, nlb: int):
 @functools.lru_cache(maxsize=None)
 def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                sub: int | None, in_dtype_name: str, interpret: bool,
-               group: int = 1, hps: int = 1):
+               group: int = 1, hps: int = 1, window: int | None = None):
     """Compile-cached pallas_call for a forward over ``[rows, T, lanes]``
     operands in blocks ``hps * D`` lanes wide: head-major ``[BH, T, D]``
     (k and v ``[BH / group, T, D]``), or the projections' own
-    ``[B, T, H * D]`` with ``hps`` heads a grid step (``lane_layout``)."""
+    ``[B, T, H * D]`` with ``hps`` heads a grid step (``lane_layout``).
+    ``window``: the K/V axis of the grid is the band (``_band_step``)."""
     one_block = T == block_q == block_kv
     w = hps * D
+    nq, nkv = T // block_q, T // block_kv
+    nband = None if window is None else _band_blocks(window, block_kv, nkv)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv,
-        sub=sub, one_block=one_block, hps=hps, D=D, group=group)
-    nq, nkv = T // block_q, T // block_kv
+        sub=sub, one_block=one_block, hps=hps, D=D, group=group,
+        window=window, nband=nband)
+    name = FWD_NAME + (WINDOW_SUFFIX if window is not None else "")
     dtype = jnp.dtype(in_dtype_name)
     # the step's copy of its one k/v head (``_shared_kv``)
     shared_kv = [pltpu.VMEM((1, block_kv, w), dtype)] * 2 * _shares_kv(
@@ -493,12 +632,12 @@ def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
 
         def kv_block(g, i, j):
             row, c = _lane_block(_kv_head(g, group), nlb_kv)
-            return (row, j, c)
+            return (row, _band_step(i, j, nband)[0], c)
 
         fwd = pl.pallas_call(
             kernel,
-            name=FWD_NAME,
-            grid=(steps, nq, nkv),
+            name=name,
+            grid=(steps, nq, nband or nkv),
             in_specs=[
                 pl.BlockSpec((1, block_q, w), q_block),
                 pl.BlockSpec((1, block_kv, w), kv_block),
@@ -520,7 +659,7 @@ def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
             ]),
             interpret=interpret,
         )
-        with jax.named_scope(FWD_NAME):
+        with jax.named_scope(name):
             return fwd(qr, kr, vr)
 
     return call
@@ -583,9 +722,11 @@ def _from_kernel(x, B: int, H: int, hps: int | None):
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                delta_ref, *scratch, causal: bool, block_q: int,
                block_kv: int, sub: int | None, one_block: bool, scale: float,
-               hps: int, D: int, group: int):
+               hps: int, D: int, group: int, window: int | None = None,
+               nband: int | None = None):
     q_start = pl.program_id(1) * block_q
-    k_start = pl.program_id(2) * block_kv
+    kv_block, in_grid = _band_step(pl.program_id(1), pl.program_id(2), nband)
+    k_start = kv_block * block_kv
     heads = _head_lanes(hps, D)
     if _shares_kv(hps, group):
         k_ref, v_ref = _shared_kv(
@@ -625,7 +766,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                                   * scale).astype(dq_ref.dtype)
 
         _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
-                  one_block)
+                  one_block, window=window)
         return
 
     acc_ref, delta_col = scratch
@@ -642,7 +783,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
                                [delta_col[h, rows] for h in range(hps)])
 
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
-              one_block)
+              one_block, window=window, in_grid=in_grid)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _finalize():
@@ -655,9 +796,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *scratch, causal: bool,
                 block_q: int, block_kv: int, sub: int | None,
                 one_block: bool, hps: int, D: int, group: int = 1,
-                nq: int = 1):
+                nq: int = 1, window: int | None = None,
+                nband: int | None = None):
     step = pl.program_id(2)  # the group's q steps, nq q blocks each
-    iq = _group_step(0, step, group, nq)[1]
+    per_head = nband or nq   # ... or the band's nband q blocks each
+    iq, in_grid = _band_step(
+        pl.program_id(1), _group_step(0, step, group, per_head)[1], nband,
+        nq, kv_major=True)
     heads = _head_lanes(hps, D)
     shared = _shares_kv(hps, group)
     at = lambda cols: cols      # where a tile adds to dk_acc / dv_acc
@@ -665,7 +810,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # this step's q heads read k/v head ``half`` of the block, and what
         # they add to dk / dv — each in its own lanes — is that head's: one
         # accumulator a k/v head, its halves summed at the end
-        half = _kv_half(step // nq, group, hps)
+        half = _kv_half(step // per_head, group, hps)
         k_ref, v_ref = _shared_kv(k_ref, v_ref, *scratch, half, D)
         at = lambda cols: (half, cols)
 
@@ -698,7 +843,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 _NN, preferred_element_type=jnp.float32)
 
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
-              one_block, kv_major=True)
+              one_block, kv_major=True, window=window, in_grid=in_grid)
 
     def total(acc):
         if not shared:
@@ -718,7 +863,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 @functools.lru_cache(maxsize=None)
 def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                sub: int | None, in_dtype_name: str, interpret: bool,
-               group: int = 1, hps: int = 1):
+               group: int = 1, hps: int = 1, window: int | None = None):
     """Compile-cached backward pallas_calls over ``_build_fwd``'s operand
     layouts: a dq pass (grid q-major, KV innermost) and a dk/dv pass (grid
     kv-major, Q innermost) — the standard two-pass flash backward, so
@@ -727,14 +872,18 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
     rowsum(do * o) a head from the do and out blocks it holds and hands it
     to the dk/dv pass as rows of the same kind. ``group`` q heads
     share a K/V head: dq is per q head, dk/dv per K/V head, summed over the
-    group along the dk/dv grid's innermost axis."""
+    group along the dk/dv grid's innermost axis. ``window``: the
+    innermost axis of either grid is the band (``_band_step``)."""
     dtype = jnp.dtype(in_dtype_name)
     scale = 1.0 / (D ** 0.5)
     one_block = T == block_q == block_kv
     w = hps * D
-    static = dict(causal=causal, block_q=block_q, block_kv=block_kv, sub=sub,
-                  one_block=one_block, hps=hps, D=D, group=group)
     nq, nkv = T // block_q, T // block_kv
+    nband = None if window is None else _band_blocks(window, block_kv, nkv)
+    static = dict(causal=causal, block_q=block_q, block_kv=block_kv, sub=sub,
+                  one_block=one_block, hps=hps, D=D, group=group,
+                  window=window, nband=nband)
+    suffix = WINDOW_SUFFIX if window is not None else ""
     dq_kernel = functools.partial(_dq_kernel, scale=scale, **static)
     dkv_kernel = functools.partial(_dkv_kernel, nq=nq, **static)
     shared = _shares_kv(hps, group)
@@ -743,8 +892,12 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
     acc_shape = (hps,) * shared + (block_kv, w)
     row_spec_q = _row_spec(block_q, lambda g, i, j: (g, i, 0, 0), hps)
 
-    def q_row(g, j, i):              # dk/dv grid: K/V step g, step i
-        return (*_group_step(g, i, group, nq), 0, 0)
+    def q_of(g, j, i):    # dk/dv grid: K/V step g, K/V block j, step i
+        gq, iq = _group_step(g, i, group, nband or nq)
+        return gq, _band_step(j, iq, nband, nq, kv_major=True)[0]
+
+    def q_row(g, j, i):
+        return (*q_of(g, j, i), 0, 0)
 
     row_spec_kv_inner = _row_spec(block_q, q_row, hps)
 
@@ -758,10 +911,10 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
 
         def kv_block(g, i, j):       # dq grid: q step g, kv block j
             row, c = _lane_block(_kv_head(g, group), nlb_kv)
-            return (row, j, c)
+            return (row, _band_step(i, j, nband)[0], c)
 
         def q_inner(g, j, i):        # dk/dv grid: K/V step g, step i
-            gq, iq = _group_step(g, i, group, nq)
+            gq, iq = q_of(g, j, i)
             row, c = _lane_block(gq, nlb)
             return (row, iq, c)
 
@@ -771,8 +924,8 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
 
         dq_call = pl.pallas_call(
             dq_kernel,
-            name=DQ_NAME,
-            grid=(steps, nq, nkv),
+            name=DQ_NAME + suffix,
+            grid=(steps, nq, nband or nkv),
             in_specs=[
                 pl.BlockSpec((1, block_q, w), q_block),
                 pl.BlockSpec((1, block_kv, w), kv_block),
@@ -789,12 +942,12 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                 pltpu.VMEM((hps, block_q, 1), jnp.float32)]),
             interpret=interpret,
         )
-        with jax.named_scope(DQ_NAME):
+        with jax.named_scope(DQ_NAME + suffix):
             dq, delta = dq_call(qr, kr, vr, dor, out, lse)
         dkv_call = pl.pallas_call(
             dkv_kernel,
-            name=DKV_NAME,
-            grid=(steps_kv, nkv, group * nq),
+            name=DKV_NAME + suffix,
+            grid=(steps_kv, nkv, group * (nband or nq)),
             in_specs=[
                 pl.BlockSpec((1, block_q, w), q_inner),
                 pl.BlockSpec((1, block_kv, w), kv_outer),
@@ -817,7 +970,7 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
             ] + shared_kv,
             interpret=interpret,
         )
-        with jax.named_scope(DKV_NAME):
+        with jax.named_scope(DKV_NAME + suffix):
             dk, dv = dkv_call(qr, kr, vr, dor, lse, delta)
         return dq, dk, dv
 
@@ -831,7 +984,8 @@ _shared = functools.lru_cache(maxsize=None)(jax.jit)
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
-                interpret: bool, D: int, group: int, hps: int | None):
+                interpret: bool, D: int, group: int, hps: int | None,
+                window: int | None = None):
     """The differentiable call over the kernels' own operands (``hps``
     None: head-major ``[BH, T, D]``; else ``[B, T, H * D]``) — what is
     kept for the backward is kept as the kernels read it.
@@ -859,7 +1013,7 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
             T = qr.shape[1]
             call = built(_build_fwd(T, D, causal, block_q, block_kv, sub,
                                     qr.dtype.name, interpret, group,
-                                    per_step))
+                                    per_step, window))
             return call(_prescale_q(qr, D), kr, vr)
 
         @jax.custom_vjp
@@ -874,7 +1028,7 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
             qr, kr, vr, out, lse_row = res
             call = built(_build_bwd(qr.shape[1], D, causal, block_q,
                                     block_kv, sub, qr.dtype.name, interpret,
-                                    group, per_step))
+                                    group, per_step, window))
             # the kernels recompute log2-space scores
             return call(_prescale_q(qr, D), kr, vr, dor, out, lse_row)
 
@@ -897,7 +1051,8 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 1024,
                     block_kv: int = 1024,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    window: int | None = None) -> jax.Array:
     """Fused attention on ``q [B, T, H, D]``, ``k`` / ``v``
     ``[B, T, Hkv, D]`` (``Hkv`` divides ``H``; q head ``j`` reads k/v head
     ``j // (H / Hkv)``) via a Pallas TPU kernel.
@@ -910,6 +1065,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     reach the interpreter without saying so.
     Requires ``T`` divisible by both block sizes; callers pad or fall back.
 
+    ``window`` (causal calls): query ``t`` sees keys ``s`` with ``t -
+    window < s <= t``, ``window`` keys with its own. The grids' innermost
+    axes are then as long as the band and no block outside it is loaded or
+    computed; a window of T or more is the causal call, the same kernels.
+
     Default blocks are 1024 (clamped to T): fewer, larger grid steps, and
     what a causal call does not need of a step on the diagonal is skipped
     inside it, strip by strip (``tiling``; the module docstring has the
@@ -920,26 +1080,36 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if k.shape != v.shape or q.shape[2] % k.shape[2]:
         raise ValueError(f"q heads {q.shape[2]} do not group over k/v "
                          f"{k.shape} / {v.shape}")
-    block_q, block_kv, sub = tiling(q.shape[1], causal, block_q, block_kv)
+    if window is not None and window >= q.shape[1]:
+        window = None
+    block_q, block_kv, sub = tiling(q.shape[1], causal, block_q, block_kv,
+                                    window)
     B, _, H, D = q.shape
     h_kv = k.shape[2]
     hps = lane_layout(H, h_kv, D)
     flash = _make_flash(causal, block_q, block_kv, sub, bool(interpret), D,
-                        H // h_kv, hps)
+                        H // h_kv, hps, window)
     out = flash(*(_to_kernel(x, hps) for x in (q, k, v)))
     return _from_kernel(out, B, H, hps)
 
 
 def tiling(T: int, causal: bool = True, block_q: int = 1024,
-           block_kv: int = 1024) -> tuple[int, int, int | None]:
+           block_kv: int = 1024, window: int | None = None
+           ) -> tuple[int, int, int | None]:
     """``(block_q, block_kv, sub)`` as ``flash_attention`` runs a length-T
     call: the blocks clamped to T, and the height of the causal strips a
     grid step on the diagonal is walked in (None: one tile a step).
-    ``score_area_pct(T, *tiling(T, ...), causal)`` is how much of the
-    score matrix the kernels then compute."""
+    ``score_area_pct(T, *tiling(T, ...), causal, window)`` is how much of
+    the score matrix the kernels then compute. A ``window`` (below T)
+    wants a causal call and equal blocks: the band is counted in blocks."""
     block_q = min(block_q, T)
     block_kv = min(block_kv, T)
     if T % block_q or T % block_kv:
         raise ValueError(
             f"seq len {T} not divisible by blocks ({block_q}, {block_kv})")
+    if window is not None and window < T and (
+            not causal or block_q != block_kv or window < 1):
+        raise ValueError(
+            f"window {window} needs a causal call and equal blocks, got "
+            f"causal={causal}, blocks ({block_q}, {block_kv})")
     return block_q, block_kv, _sub_tile(block_q, block_kv, causal)

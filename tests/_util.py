@@ -79,3 +79,19 @@ def assert_episode_payloads_match(got: bytes, want: bytes, what=""):
         assert set(g.data or {}) == set(w.data or {}), at
         for k in (w.data or {}):
             assert_aux_equal(g.data[k], w.data[k], (at, k))
+
+
+def band_attention_oracle(q, k, v, window):
+    """Softmax attention under the sliding-window mask (query t sees keys
+    ``t - window < s <= t``), written out from positions: the anchor of the
+    windowed ops and kernels, nothing of theirs (not even their mask).
+    ``q [B, T, H, D]``, ``k`` / ``v`` ``[B, T, Hkv, D]``, grouped k/v
+    repeated."""
+    import jax
+    import jax.numpy as jnp
+
+    k, v = (jnp.repeat(a, q.shape[2] // k.shape[2], axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (q.shape[-1] ** 0.5)
+    back = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :]
+    s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)

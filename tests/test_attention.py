@@ -4,11 +4,14 @@ Ring attention runs on the 8-virtual-CPU-device mesh from conftest; the
 correctness anchor is dense attention on the unsharded sequence.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _util import band_attention_oracle
 from relayrl_tpu.models import build_policy, validate_policy
 from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
 from relayrl_tpu.parallel import (
@@ -611,3 +614,180 @@ class TestGroupedQueryAttention:
         q, k, v, _, _ = self._grouped(2)
         with pytest.raises(ValueError, match="do not group"):
             dense_attention(q, k[:, :, :1].repeat(3, 2), v)
+
+
+class TestSlidingWindow:
+    """``window``: query t sees keys s with t - window < s <= t. The anchor
+    is softmax under a mask written out from positions
+    (``_util.band_attention_oracle``), nothing of the op's own."""
+
+    _oracle = staticmethod(band_attention_oracle)
+
+    @pytest.mark.parametrize("window", [1, 5, 8, 13, T, T + 9])
+    @pytest.mark.parametrize("h_kv", [H, 1])
+    @pytest.mark.parametrize("fn", ["dense", "blockwise"])
+    def test_matches_the_band_mask(self, fn, h_kv, window):
+        q, k, v, _, _ = TestGroupedQueryAttention._grouped(h_kv)
+        attn = (dense_attention if fn == "dense" else
+                lambda q, k, v, window: blockwise_attention(
+                    q, k, v, 8, window=window))
+        np.testing.assert_allclose(attn(q, k, v, window=window),
+                                   self._oracle(q, k, v, window),
+                                   atol=2e-6, rtol=2e-6)
+
+    @pytest.mark.parametrize("fn", ["dense", "blockwise"])
+    def test_grads_match_the_band_mask(self, fn):
+        q, k, v, _, _ = TestGroupedQueryAttention._grouped(2)
+        attn = (functools.partial(dense_attention, window=6)
+                if fn == "dense" else
+                lambda q, k, v: blockwise_attention(q, k, v, 8, window=6))
+        loss = lambda f: lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+        got = jax.grad(loss(attn), (0, 1, 2))(q, k, v)
+        want = jax.grad(loss(functools.partial(self._oracle, window=6)),
+                        (0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=1e-5, rtol=1e-5)
+
+    def test_a_window_of_the_sequence_is_the_causal_call(self):
+        q, k, v = _qkv()
+        np.testing.assert_array_equal(dense_attention(q, k, v, window=T),
+                                      dense_attention(q, k, v))
+
+    def test_keys_at_given_positions_in_any_order(self):
+        # a ring cache: the rows of k/v are not in order, and some are empty
+        q, k, v, _, _ = TestGroupedQueryAttention._grouped(2)
+        order = np.random.default_rng(0).permutation(T)
+        empty = jnp.concatenate([jnp.asarray(order), jnp.full((3,), -1)])
+        pad = lambda a: jnp.concatenate(
+            [a[:, order], 7.0 * jnp.ones_like(a[:, :3])], axis=1)
+        row = dense_attention(q[:, 20:21], pad(k), pad(v), q_offset=20,
+                              window=6, kv_positions=empty)
+        np.testing.assert_allclose(
+            row, self._oracle(q, k, v, 6)[:, 20:21], atol=2e-6)
+
+    def test_a_window_needs_the_causal_mask(self):
+        q, k, v = _qkv()
+        for attn in (dense_attention,
+                     lambda *a, **kw: blockwise_attention(*a, 8, **kw)):
+            with pytest.raises(ValueError, match="causal"):
+                attn(q, k, v, causal=False, window=4)
+
+
+# A SmallThinker-shaped trunk: a global NoPE layer, then windowed RoPE
+# layers; heads of a width of their own (3 x 16 = 48 under d_model 32) over
+# one k/v head.
+SLIDING = {"norm": "rms", "positions": "rope", "use_bias": False,
+           "ffn": "reglu", "d_ff": 48, "n_layers": 3, "n_heads": 3,
+           "n_kv_heads": 1, "head_dim": 16,
+           "layer_types": ["full_attention", "sliding_attention",
+                           "sliding_attention"],
+           "sliding_window": 4, "rope_layers": [0, 1, 1]}
+
+
+class TestWindowedLayers:
+    def test_parameter_tree_follows_the_arch(self):
+        params = build_policy({**ARCH, **SLIDING}).init_params(
+            jax.random.PRNGKey(0))["params"]
+        assert "pos_embed" not in params       # no table under rope
+        block = params["block_1"]
+        assert block["q_proj"]["kernel"].shape == (32, 48)
+        assert block["k_proj"]["kernel"].shape == (32, 16)
+        assert block["attn_out"]["kernel"].shape == (48, 32)
+        assert block["mlp_gate"]["kernel"].shape == (32, 48)
+
+    @pytest.mark.parametrize("attention", ["dense", "blockwise", "flash",
+                                           "ring"])
+    def test_every_backend_computes_the_band(self, attention):
+        # off-TPU and without a mesh every kind resolves to an XLA path
+        arch = {**ARCH, **SLIDING, "attention_block": 4}
+        dense = build_policy({**arch, "attention": "dense"})
+        other = build_policy({**arch, "attention": attention})
+        params = dense.init_params(jax.random.PRNGKey(0))
+        obs = jnp.asarray(np.random.default_rng(1).standard_normal(
+            (2, 16, 8)), jnp.float32)
+        act = jnp.zeros((2, 16), jnp.int32)
+        for a, b in zip(dense.evaluate(params, obs, act),
+                        other.evaluate(params, obs, act)):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+    def test_the_window_and_the_missing_positions_are_in_the_output(self):
+        # a wider window, or RoPE on the global layer, is another model
+        base = {**ARCH, **SLIDING}
+        policy = build_policy(base)
+        params = policy.init_params(jax.random.PRNGKey(0))
+        obs = jnp.asarray(np.random.default_rng(1).standard_normal(
+            (1, 12, 8)), jnp.float32)
+        act = jnp.zeros((1, 12), jnp.int32)
+        v = policy.evaluate(params, obs, act)[2]
+        for other in ({"sliding_window": 6}, {"rope_layers": [1, 1, 1]},
+                      {"rope_layers": [0, 0, 1]}):
+            v2 = build_policy({**base, **other}).evaluate(
+                params, obs, act)[2]
+            assert not np.allclose(v, v2, atol=1e-4), other
+        # rows inside the first window see every earlier key either way
+        v6 = build_policy({**base, "sliding_window": 6}).evaluate(
+            params, obs, act)[2]
+        np.testing.assert_allclose(v[:, :4], v6[:, :4], atol=1e-5)
+
+    def test_a_nope_global_layer_sees_no_order_among_earlier_rows(self):
+        # one global layer without positions: the last row's output is the
+        # same whatever order the rows before it came in
+        arch = {**ARCH, **SLIDING, "n_layers": 1,
+                "layer_types": ["full_attention"], "rope_layers": [0]}
+        policy = build_policy(arch)
+        params = policy.init_params(jax.random.PRNGKey(0))
+        obs = np.random.default_rng(2).standard_normal((1, 9, 8)).astype(
+            np.float32)
+        shuffled = obs.copy()
+        shuffled[0, :8] = obs[0, 7::-1]
+        act = jnp.zeros((1, 9), jnp.int32)
+        np.testing.assert_allclose(
+            policy.evaluate(params, obs, act)[2][0, -1],
+            policy.evaluate(params, shuffled, act)[2][0, -1], atol=1e-5)
+
+    @pytest.mark.parametrize("last", ["sliding_attention", "full_attention"])
+    def test_full_window_equals_readout_row(self, last):
+        arch = {**ARCH, **SLIDING}
+        arch["layer_types"] = arch["layer_types"][:2] + [last]
+        policy = build_policy(arch)
+        params = policy.init_params(jax.random.PRNGKey(1))
+        W = 10
+        window = np.random.default_rng(4).standard_normal((W, 8)).astype(
+            np.float32)
+        _, _, v = policy.evaluate(params, window[None],
+                                  jnp.zeros((1, W), jnp.int32))
+        for t in (1, 5, W):
+            _, aux = policy.step_window(params, jax.random.PRNGKey(0),
+                                        jnp.asarray(window), t)
+            np.testing.assert_allclose(float(aux["v"]), float(v[0, t - 1]),
+                                       atol=1e-4, err_msg=f"t={t}")
+
+    @pytest.mark.parametrize("over,match", [
+        ({"layer_types": ["full_attention", "local", "conv"]},
+         "unknown layer type"),
+        ({"sliding_window": None}, "no sliding_window"),
+        ({"rope_layers": [0, 1]}, "rope_layers names 2 layers"),
+        ({"ffn": "geglu"}, "unknown ffn"),
+    ])
+    def test_what_the_arch_cannot_mean_is_refused(self, over, match):
+        with pytest.raises(ValueError, match=match):
+            build_policy({**ARCH, **SLIDING, **over}).init_params(
+                jax.random.PRNGKey(0))
+
+    @pytest.mark.parametrize("key,value", [
+        ("head_dim", 16), ("sliding_window", 4), ("rope_layers", [1, 1]),
+        ("moe_router_input", "layer")])
+    def test_pipeline_family_refuses_the_keys(self, key, value):
+        with pytest.raises(ValueError, match="GPT-2 shaped block"):
+            build_policy({**ARCH, "kind": "transformer_pp_discrete",
+                          key: value})
+
+    def test_ring_attention_refuses_a_window_under_a_mesh(self):
+        mesh = make_mesh({"dp": 2, "fsdp": 1, "tp": 1, "sp": 4},
+                         jax.devices()[:8])
+        ring = build_policy({**ARCH, **SLIDING, "n_kv_heads": 3,
+                             "attention": "ring"})
+        params = ring.init_params(jax.random.PRNGKey(0))
+        with use_mesh(mesh), pytest.raises(ValueError, match="no window"):
+            jax.jit(ring.evaluate)(params, jnp.zeros((2, 16, 8)),
+                                   jnp.zeros((2, 16), jnp.int32))

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _util import band_attention_oracle
 from relayrl_tpu.ops import flash
 from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
 
@@ -584,3 +585,141 @@ def test_eager_repeats_share_one_program(monkeypatch):
     finally:
         monitoring.unregister_event_duration_listener(on_compile)
         _clear_kernel_caches()
+
+
+# -- sliding-window calls: the grids' innermost axes are the band ------------
+
+@pytest.mark.parametrize("T,block,window,H,h_kv,D,sub", [
+    (64, 16, 32, 7, 1, 16, 8),     # whole blocks, group 7: cut block in strips
+    (64, 16, 32, 14, 2, 16, None),  # ... masked as one tile
+    (64, 16, 24, 7, 1, 16, 8),     # the edge inside a block: two blocks cut
+    (64, 16, 17, 2, 1, 16, 8),     # one key past a block
+    (64, 16, 8, 4, 4, 16, 8),      # shorter than a block: both edges in one
+    (32, 32, 8, 2, 2, 16, 8),      # one block a head
+    (64, 16, 48, 4, 2, 64, 8),     # two heads a step over a shared k/v head
+    (64, 16, 16, 2, 2, 64, None),  # ... plain heads, a window of one block
+])
+def test_windowed_flash_matches_the_band_oracle(monkeypatch, T, block, window,
+                                                H, h_kv, D, sub):
+    """Forward and the three gradients of a windowed call against dense
+    attention under the band mask."""
+    _sub_tile(monkeypatch, sub or 1 << 30)
+    _clear_kernel_caches()
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.standard_normal((2, T, H, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, T, h_kv, D)), jnp.float32)
+            for _ in range(2))
+
+    def fl(q, k, v):
+        return flash_attention(q, k, v, block_q=block, block_kv=block,
+                               window=window)
+
+    oracle = functools.partial(band_attention_oracle, window=window)
+    np.testing.assert_allclose(fl(q, k, v), oracle(q, k, v), atol=2e-5,
+                               rtol=2e-5)
+    np.testing.assert_allclose(
+        fl(q, k, v), dense_attention(q, k, v, window=window), atol=2e-5,
+        rtol=2e-5)
+    got = jax.grad(_grad_loss(fl), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_grad_loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    _clear_kernel_caches()
+    for g, w, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("window", [64, 100])
+def test_a_window_of_the_whole_sequence_is_the_causal_call(window):
+    """Bit-equal, forward and gradients: the same kernels under the same
+    names."""
+    q, k, v = _grouped_qkv(1, 64, 7, 1)
+
+    def run(**kw):
+        fn = lambda q, k, v: flash_attention(q, k, v, block_q=16,
+                                             block_kv=16, **kw)
+        return (fn(q, k, v), *jax.grad(_grad_loss(fn),
+                                       argnums=(0, 1, 2))(q, k, v))
+
+    for got, want in zip(run(window=window), run()):
+        np.testing.assert_array_equal(got, want)
+    lowered = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, block_q=16, block_kv=16, window=window)).lower(q, k, v)
+    assert flash.WINDOW_SUFFIX not in lowered.as_text()
+
+
+def test_a_windowed_call_carries_names_that_extend_the_kernels_own():
+    q, k, v = _grouped_qkv(1, 64, 2, 1)
+    text = jax.jit(jax.grad(_grad_loss(lambda q, k, v: flash_attention(
+        q, k, v, block_q=16, block_kv=16, window=32)),
+        argnums=(0, 1, 2))).lower(q, k, v).as_text(debug_info=True)
+    for name in (flash.FWD_NAME, flash.DQ_NAME, flash.DKV_NAME):
+        assert name + flash.WINDOW_SUFFIX in text, name
+
+
+def test_a_window_wants_a_causal_call_and_equal_blocks():
+    q, k, v = _qkv(T=64)
+    with pytest.raises(ValueError, match="causal call and equal blocks"):
+        flash_attention(q, k, v, block_q=32, block_kv=16, window=16)
+    with pytest.raises(ValueError, match="causal call and equal blocks"):
+        flash_attention(q, k, v, causal=False, block_q=16, block_kv=16,
+                        window=16)
+
+
+@pytest.mark.parametrize("T,block,sub,window,pct", [
+    # smallthinker-policy.update's windowed layers: the band needs 21.9%
+    (16384, 1024, 256, 4096, 23.2421875),
+    (16384, 1024, None, 4096, 27.34375),   # no strip walk at all
+    (16384, 1024, 256, None, 50.78125),    # its global layer
+    (64, 16, 8, 32, 100.0 * (4 * 192 + 2 * 192 + 3 * 256) / 4096),
+    (64, 16, 8, 24, 100.0 * (4 * 192 + (3 + 2) * 256) / 4096),
+    (64, 16, 8, 8, 100.0 * (4 + 3) * 256 / 4096),
+])
+def test_score_area_pct_of_a_band(T, block, sub, window, pct):
+    assert flash.score_area_pct(T, block, block, sub, True, window) == pct
+
+
+@pytest.mark.parametrize("T,block,sub,window", [
+    (64, 16, 8, 32), (64, 16, None, 32), (64, 16, 8, 24), (64, 16, 8, 8)])
+def test_band_score_area_is_what_the_kernels_visit(monkeypatch, T, block,
+                                                   sub, window):
+    """The area function against the kernel bodies and the grids: every
+    kernel emits the diagonal's tiles, the cut block's and (where a block
+    lies wholly inside the window) one interior tile; the grid runs each
+    class as often as the band holds it."""
+    _sub_tile(monkeypatch, sub or 1 << 30)
+    _clear_kernel_caches()
+    visited = []
+    scores2 = flash._scores2
+
+    def counting(*args, **kw):
+        s = scores2(*args, **kw)
+        visited.append(s.shape)
+        return s
+
+    monkeypatch.setattr(flash, "_scores2", counting)
+    q, k, v = _qkv(B=1, T=T, H=1, D=16)
+    jax.grad(_grad_loss(lambda q, k, v: flash_attention(
+        q, k, v, block_q=block, block_kv=block, window=window)),
+        argnums=(0, 1, 2))(q, k, v)
+    _clear_kernel_caches()
+    assert len(visited) % 3 == 0
+    n = T // block
+    nband = flash._band_blocks(window, block, n)
+    both_edges = window < block
+    whole = window % block == 0 and sub is not None
+    strips = 0 if sub is None or both_edges else block // sub
+    diag = block * block if not strips else sum(
+        sub * (r + 1) * sub for r in range(strips))
+    cut = diag if whole else block * block
+    area = 0
+    for i in range(n):
+        for d in range(min(i, nband - 1) + 1):
+            area += (diag if d == 0 else block * block
+                     if d * block <= window - block else cut)
+    assert flash.score_area_pct(T, block, block, sub, True, window) == (
+        100.0 * area / (T * T))
+    # what a kernel's body holds: the diagonal's tiles, the cut block's,
+    # and an interior tile where the window spans two blocks or more
+    emitted = sum(a * b for a, b in visited) // 3
+    assert emitted == diag + cut * (nband > 1) + block * block * (
+        window >= 2 * block)

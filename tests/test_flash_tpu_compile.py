@@ -29,16 +29,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("shape,sub,kv_heads,layout", [
-    ((8, 1024, 16, 64), 256, 16, 2),    # gpt2m-policy.update: one block a head
-    ((4, 4096, 16, 128), 256, 16, None),  # olmoe-policy.update: 4 x 4 blocks
-    ((1, 1000, 2, 64), None, 2, 2),     # a default bucket: one tile a step
-    ((2, 8192, 32, 64), 256, 8, 2),     # lfm2-policy.update: 4 q heads a k/v
-    ((1, 1024, 4, 64), 256, 1, None),   # one k/v head: half a lane block
+@pytest.mark.parametrize("shape,sub,kv_heads,layout,window", [
+    # gpt2m-policy.update: one block a head
+    ((8, 1024, 16, 64), 256, 16, 2, None),
+    ((4, 4096, 16, 128), 256, 16, None, None),  # olmoe-policy.update: 4 x 4
+    ((1, 1000, 2, 64), None, 2, 2, None),   # a default bucket: one tile a step
+    ((2, 8192, 32, 64), 256, 8, 2, None),   # lfm2-policy.update: 4 q heads a k/v
+    ((1, 1024, 4, 64), 256, 1, None, None),  # one k/v head: half a lane block
+    # smallthinker-policy.update: 7 q heads a k/v head, its global layer...
+    ((1, 16384, 28, 128), 256, 4, None, None),
+    ((1, 16384, 28, 128), 256, 4, None, 4096),  # ...and the band: 5 of 16
+    ((1, 16384, 28, 128), 256, 4, None, 1000),  # an edge inside a block
+    ((2, 2048, 4, 64), 256, 2, 2, 512),     # both edges in one block, lanes
 ])
 def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
-                                       layout):
-    assert flash.tiling(shape[1])[2] == sub
+                                       layout, window):
+    assert flash.tiling(shape[1], window=window)[2] == sub
     assert flash.lane_layout(shape[2], kv_heads, shape[3]) == layout
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct(shape[:2] + (kv_heads, shape[3]),
@@ -47,12 +53,17 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
     def value_and_grads(q, k, v):
         return jax.value_and_grad(
             lambda q, k, v: jnp.sum(
-                flash.flash_attention(q, k, v).astype(jnp.float32)),
+                flash.flash_attention(q, k, v, window=window).astype(
+                    jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 
     compiled = jax.jit(value_and_grads).lower(x, kv, kv).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3
+    # a windowed call's kernels say so by name, the others' names are bare
+    for name in (flash.FWD_NAME, flash.DQ_NAME, flash.DKV_NAME):
+        assert name in text
+        assert (name + flash.WINDOW_SUFFIX in text) == bool(window)
     # In the lane layout the head axis never leaves the lanes: nothing in
     # the compiled program (no transpose, no copy, no operand of a kernel)
     # is [B, H, T, D]-shaped or its flat form. A shape that falls back is

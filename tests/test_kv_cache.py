@@ -47,6 +47,26 @@ CACHED_ARCHS = {
     # rows and the FFN on one
     "conv_last_dense": {**_MODERN, "n_layers": 2,
                         "layer_types": ["full_attention", "conv"]},
+    # the SmallThinker trunk: a global NoPE layer's full (k, v) pair beside
+    # the RINGS of the windowed RoPE layers (window 3 < the 8 rows the tests
+    # decode: the ring wraps twice), 3 q heads of a width of their own over
+    # 1 k/v head, the router on the layer's input, ReGLU experts
+    "smallthinker_trunk": {
+        "kind": "transformer_moe_discrete", "norm": "rms",
+        "positions": "rope", "rope_theta": 1.5e6, "use_bias": False,
+        "ffn": "reglu", "n_layers": 3, "n_heads": 3, "n_kv_heads": 1,
+        "head_dim": 16,
+        "layer_types": ["full_attention", "sliding_attention",
+                        "sliding_attention"],
+        "sliding_window": 3, "rope_layers": [0, 1, 1], "moe_experts": 8,
+        "moe_top_k": 3, "moe_d_ff": 16, "moe_router_input": "layer",
+        "moe_held": [2, 4]},
+    # a windowed layer LAST under a dense FFN: the readout-row mode masks
+    # the one row's keys by the window
+    "sliding_last_dense": {**_MODERN, "qk_norm": False, "n_layers": 2,
+                           "layer_types": ["full_attention",
+                                           "sliding_attention"],
+                           "sliding_window": 3},
 }
 
 
@@ -78,7 +98,9 @@ class TestStepCachedNumerics:
                                        float(aux_c["logp_a"]), atol=1e-4)
 
     @pytest.mark.parametrize("name", ["rope", "olmoe_block", "lfm2_trunk",
-                                      "conv_last_dense"])
+                                      "conv_last_dense",
+                                      "smallthinker_trunk",
+                                      "sliding_last_dense"])
     def test_prefilled_cache_continues_as_the_stepped_one(self, name):
         # prefill rotates W keys at positions 0..W-1 in one dispatch; the
         # steps after it must read them as if they had been written one by
@@ -112,8 +134,42 @@ class TestStepCachedNumerics:
         k, v = cache[1]
         assert k.shape == v.shape == (3, 8, 2, 8)   # 2 k/v heads, not 4
 
+    def test_a_windowed_layer_keeps_a_ring_of_window_rows(self):
+        policy, _ = _policy_params(**CACHED_ARCHS["smallthinker_trunk"])
+        shapes = [[a.shape for a in pair] for pair in policy.init_cache(8, 2)]
+        # the global layer: all 8 rows; the windowed ones: 3, of 1 k/v head
+        # of 16 (not d_model // n_heads)
+        assert shapes == [[(2, 8, 1, 16)] * 2, [(2, 3, 1, 16)] * 2,
+                          [(2, 3, 1, 16)] * 2]
+        # a cache shorter than the window is the plain cache
+        assert policy.init_cache(2)[1][0].shape == (1, 2, 1, 16)
+
+    @pytest.mark.parametrize("t0", [2, 3, 7])
+    def test_the_ring_takes_the_real_rows_of_a_prefill_only(self, t0):
+        # before, at and past the ring's first wrap; the padding rows after
+        # t0 must not displace the real ones
+        policy, params = _policy_params(**CACHED_ARCHS["smallthinker_trunk"])
+        W = 8
+        window = np.zeros((W, 6), np.float32)
+        window[:t0] = np.random.default_rng(5).standard_normal((t0, 6))
+        window[t0:] = 50.0    # padding that would be seen if it got in
+        stepped = policy.init_cache(W)
+        for t in range(t0):
+            _, _, stepped = policy.step_cached(
+                params, jax.random.PRNGKey(t), stepped, window[t], t)
+        filled = policy.prefill_cache(params, policy.init_cache(W),
+                                      jnp.asarray(window), t0)
+        obs = np.random.default_rng(6).standard_normal(6).astype(np.float32)
+        outs = [policy.step_cached(params, jax.random.PRNGKey(9), c, obs,
+                                   t0)[1] for c in (stepped, filled)]
+        np.testing.assert_allclose(float(outs[0]["v"]), float(outs[1]["v"]),
+                                   atol=1e-4)
+        np.testing.assert_allclose(float(outs[0]["logp_a"]),
+                                   float(outs[1]["logp_a"]), atol=1e-4)
+
     @pytest.mark.parametrize("name", ["lfm2_trunk", "conv_last_dense",
-                                      "grouped_query"])
+                                      "grouped_query", "smallthinker_trunk",
+                                      "sliding_last_dense"])
     def test_full_forward_equals_the_stepped_rows(self, name):
         # evaluate() over the whole sequence against step_cached row by row
         policy, params = _policy_params(**CACHED_ARCHS[name])

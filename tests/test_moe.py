@@ -503,3 +503,114 @@ class TestHeldExperts:
             with pytest.raises(ValueError, match="transformer_pp_discrete"):
                 build_policy({**ARCH, "kind": "transformer_pp_discrete",
                               key: value})
+
+
+# -- the router's input apart from the experts', and ReGLU (SmallThinker) ----
+
+def _early_layer(held, dispatch="sparse", e=64, k=6):
+    from relayrl_tpu.models.moe import MoEMLP
+
+    return MoEMLP(_D, _FF, e, k, jnp.float32, norm_topk_prob=True,
+                  ffn="reglu", dispatch=dispatch, use_bias=False, held=held)
+
+
+def _early_params(e=64, k=6, seed=0):
+    """The whole layer's parameters, the rows its experts read and the rows
+    its router reads."""
+    rng = np.random.default_rng(seed)
+    x, route_x = (jnp.asarray(rng.standard_normal((2, _N // 2, _D)),
+                              jnp.float32) for _ in range(2))
+    return _early_layer(None, e=e, k=k).init(jax.random.PRNGKey(seed), x,
+                                             route_x), x, route_x
+
+
+class TestRouterInputAndReGLU:
+    def test_the_layer_by_hand(self):
+        # top-6 of the logits of route_x, softmax over the six, every
+        # chosen expert's relu(gate) * up of x
+        params, x, route_x = _early_params()
+        p = params["params"]
+        tokens, routed = x.reshape(_N, _D), route_x.reshape(_N, _D)
+        logits = routed @ p["moe_gate"]["kernel"]
+        vals, idx = jax.lax.top_k(logits, 6)
+        w = jax.nn.softmax(vals, -1)
+        want = jnp.zeros((_N, _D))
+        for j in range(6):
+            e = idx[:, j]
+            mid = jax.nn.relu(jnp.einsum("nd,ndf->nf", tokens,
+                                         p["moe_w_gate"][e])) * jnp.einsum(
+                "nd,ndf->nf", tokens, p["moe_w_up"][e])
+            want += w[:, j:j + 1] * jnp.einsum("nf,nfd->nd", mid,
+                                               p["moe_w_down"][e])
+        got = _early_layer(None).apply(params, x, route_x)
+        np.testing.assert_allclose(got.reshape(_N, _D), want, atol=2e-5,
+                                   rtol=1e-5)
+
+    def test_the_router_reads_its_own_rows(self):
+        params, x, route_x = _early_params()
+        layer = _early_layer(None)
+        same = layer.apply(params, x, x)
+        np.testing.assert_array_equal(same, layer.apply(params, x))
+        assert not np.allclose(layer.apply(params, x, route_x), same,
+                               atol=1e-3)
+
+    @pytest.mark.parametrize("chips", [1, 4, 8])
+    def test_the_shares_add_up_to_the_uncut_layer(self, chips):
+        # 64 experts over 4 chips, 16 each (the configuration's share); the
+        # router reads the layer's input and normalises over the six chosen
+        # of ALL experts, so the chips' partial outputs sum to the layer's
+        params, x, route_x = _early_params()
+        whole = _early_layer(None).apply(params, x, route_x)
+        count = 64 // chips
+        parts = sum(_early_layer((c * count, count)).apply(
+            _share_of(params, c * count, count), x, route_x)
+            for c in range(chips))
+        np.testing.assert_allclose(parts, whole, atol=2e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("held", [None, (16, 16)])
+    def test_sparse_matches_dense_forward_and_every_gradient(self, held):
+        params, x, route_x = _early_params()
+        share = params if held is None else _share_of(params, *held)
+
+        def loss(dispatch):
+            def f(p, x, route_x):
+                y = _early_layer(held, dispatch).apply(p, x, route_x)
+                return jnp.sum(jnp.sin(y) * x), y
+            return f
+
+        (_, ys), gs = jax.value_and_grad(
+            loss("sparse"), (0, 1, 2), has_aux=True)(share, x, route_x)
+        (_, yd), gd = jax.value_and_grad(
+            loss("dense"), (0, 1, 2), has_aux=True)(share, x, route_x)
+        np.testing.assert_allclose(ys, yd, atol=2e-5, rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+        # the router's rows get a gradient of their own (through the
+        # weights), the experts' rows theirs
+        assert float(jnp.abs(gs[2]).max()) > 0
+
+    def test_the_block_hands_the_router_the_layers_input(self):
+        # the same parameters under "layer" and under "ffn" are two models,
+        # and a block whose attention adds nothing (attn_out zeroed) routes
+        # on the un-normed rows where "ffn" routes on the normed ones
+        from relayrl_tpu.models import build_policy
+
+        arch = {"kind": "transformer_moe_discrete", "obs_dim": 6,
+                "act_dim": 3, "d_model": 32, "n_layers": 1, "n_heads": 2,
+                "max_seq_len": 8, "norm": "rms", "positions": "rope",
+                "use_bias": False, "ffn": "reglu", "moe_experts": 8,
+                "moe_top_k": 2, "moe_d_ff": 16}
+        early = build_policy({**arch, "moe_router_input": "layer"})
+        late = build_policy(arch)
+        params = early.init_params(jax.random.PRNGKey(0))
+        obs = jnp.asarray(np.random.default_rng(0).standard_normal(
+            (1, 8, 6)), jnp.float32)
+        act = jnp.zeros((1, 8), jnp.int32)
+        assert not np.allclose(early.evaluate(params, obs, act)[2],
+                               late.evaluate(params, obs, act)[2], atol=1e-4)
+        with pytest.raises(ValueError, match="moe_router_input"):
+            build_policy({**arch, "moe_router_input": "embedding"}
+                         ).init_params(jax.random.PRNGKey(0))
