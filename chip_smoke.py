@@ -56,6 +56,12 @@ B_ARCH = dict(model_kind="transformer_discrete", d_model=1024, n_layers=4,
 B_OBS, B_ACT, B_T, B_TRAJ = 64, 18, 1024, 4
 B_UPDATES = 3
 FENCE_CHAIN = 20
+# Phase F: the held-experts layer alone, (cell, tokens, the layer's widths).
+_LFM2 = dict(d=2048, ff=1536, k=4, n_held=8, ffn="swiglu", router="sigmoid")
+F_SHAPES = [("lfm2-policy", 8192, _LFM2), ("lfm2-policy", 16384, _LFM2),
+            ("smallthinker-policy", 16384,
+             dict(d=2560, ff=768, k=6, n_held=16, ffn="reglu",
+                  router="softmax"))]
 
 
 def say(msg: str) -> None:
@@ -712,6 +718,178 @@ def phase_e() -> None:
         f"{time.monotonic() - t0:.0f}s")
 
 
+def forced_logits(rng, n: int, k: int, n_exp: int, held: tuple[int, int],
+                  live: int, empty_last: bool = False):
+    """Router logits ``[n, n_exp]`` (numpy float32) whose top-k sends
+    exactly ``live`` of the ``n * k`` slots to the held experts ``held =
+    (first, count)`` (none to the last of them under ``empty_last``), some
+    experts more popular than others. (``tests/test_moe.py`` walks the same
+    crossings with it on the CPU.)"""
+    import numpy as np
+
+    first, count = held
+    mine = np.arange(first, first + count - bool(empty_last))
+    absent = np.setdiff1d(np.arange(n_exp), np.arange(first, first + count))
+    per_token = np.full(n, live // n)
+    per_token[rng.permutation(n)[:live % n]] += 1
+    assert per_token.max() <= min(k, len(mine))
+    assert k - per_token.min() <= len(absent)
+
+    def pick(experts, how_many):  # weighted draws without replacement
+        keys = rng.random((n, len(experts))) ** (
+            1.0 / rng.uniform(0.3, 3.0, len(experts)))
+        chosen = np.zeros((n, n_exp), bool)
+        chosen[:, experts] = (-keys).argsort(1).argsort(1) < how_many[:, None]
+        return chosen
+
+    chosen = pick(mine, per_token) | pick(absent, k - per_token)
+    return np.where(chosen, rng.uniform(1.0, 3.0, (n, n_exp)),
+                    rng.uniform(-5.0, -4.0, (n, n_exp))).astype(np.float32)
+
+
+def phase_f() -> None:
+    """The held-experts layer's walk where it runs (``models/moe.py``,
+    "Held experts"): the layer alone, the Pallas kernels under it, at
+    ``lfm2-policy``'s widths (8 of 64 SwiGLU experts of 1536 held, top-4 by
+    a sigmoid router, 8,192 tokens — the reference comparison's sequence —
+    and 16,384, an update's) and at ``smallthinker-policy``'s (16 of 64
+    ReGLU experts of 768, top-6, 16,384 tokens), with a router made to
+    send the layer an exact number of live rows L: the crossing of its
+    R-row buffer (R - 513, R - 1, R, R + 1, R + 511, R + 513), of the
+    second (2R - 1, 2R, 2R + 1), three passes, every slot (``ceil(N k /
+    R)`` passes) and a last held expert of no rows. One compiled program a
+    shape walks them all — the trip count is the update's to compute.
+    Output and every gradient (tokens, the router's rows, the three
+    stacks) against the DENSE path of the same layer in float32 at the
+    highest matmul precision (a ReGLU layer's gradients: in bfloat16, see
+    below), NaN-free, the reported trip counts as ``ceil(L / R)``. Limit
+    2^-6 as phases B" and E: of the largest entry
+    for what belongs to one token, of the norm for the stacks' gradients."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from relayrl_tpu.models import moe
+
+    t0 = time.monotonic()
+    n_exp = 64
+    said = []
+    for cell, n, s in F_SHAPES:
+        d, k, n_held = s["d"], s["k"], s["n_held"]
+        rows = moe.row_buffer(n * k, n_held, n_exp)
+
+        def layer(dtype, dispatch):
+            return moe.MoEMLP(d, s["ff"], n_exp, k, dtype, ffn=s["ffn"],
+                              dispatch=dispatch, use_bias=False,
+                              router=s["router"],
+                              expert_bias=s["router"] == "sigmoid",
+                              held=(0, n_held))
+
+        def program(dtype, dispatch):
+            def loss(params, x, route_x, g):
+                y, state = layer(dtype, dispatch).apply(
+                    params, x.astype(dtype), route_x,
+                    mutable=["intermediates"])
+                sown = state["intermediates"]
+                return (jnp.sum(y.astype(jnp.float32) * g),
+                        (y, sown["row_passes"][0],
+                         sown["expert_load"][0]))
+            return jax.jit(jax.value_and_grad(loss, (0, 1, 2),
+                                              has_aux=True))
+
+        key = jax.random.split(jax.random.PRNGKey(n + d), 6)
+        x = jax.random.normal(key[0], (1, n, d), jnp.float32)
+        g = jax.random.normal(key[1], (1, n, d), jnp.float32)
+        # bfloat16 tokens on both sides: the layer's input as the trunk
+        # hands it over
+        x = x.astype(jnp.bfloat16).astype(jnp.float32)
+
+        def stack(key, fan_in, fan_out):
+            return jax.random.normal(key, (n_held, fan_in, fan_out),
+                                     jnp.float32) / fan_in ** 0.5
+
+        # the router reads its own rows: the logits, through an identity
+        params = {"moe_gate": {"kernel": jnp.eye(d, n_exp)},
+                  "moe_w_gate": stack(key[2], d, s["ff"]),
+                  "moe_w_up": stack(key[3], d, s["ff"]),
+                  "moe_w_down": stack(key[4], s["ff"], d)}
+        if s["router"] == "sigmoid":
+            params["moe_expert_bias"] = 0.02 * jax.random.normal(
+                key[5], (n_exp,), jnp.float32)
+        params = {"params": params}
+        compact = program(jnp.bfloat16, "sparse")
+        with jax.default_matmul_precision("highest"):
+            dense = program(jnp.float32, "dense")
+        # ReLU's derivative is a step: where bfloat16 rounding moves a gate
+        # pre-activation across zero a whole row's term comes or goes, and
+        # against float32 the gate stack's gradient read 0.033 of its norm
+        # apart for that alone (PR 36). A ReGLU layer's gradients are held
+        # to the dense path in the layer's own precision, its output to
+        # float32 like the others.
+        dense_own = (program(jnp.bfloat16, "dense") if s["ffn"] == "reglu"
+                     else None)
+        cases = [(rows + off, False) for off in (-513, -1, 0, 1, 511, 513)]
+        cases += [(2 * rows + off, False) for off in (-1, 0, 1)]
+        cases += [(3 * rows - 7, False), (n * k, False), (rows + 1, True)]
+        # a layer that holds a quarter of the experts has, at a margin of
+        # 2, buffers of half the slots: two passes are all there are
+        cases = list(dict.fromkeys(c for c in cases if c[0] <= n * k))
+        rng = np.random.default_rng(n + k)
+        worst, walked = {False: 0.0, True: 0.0}, []
+        for live, empty_last in cases:
+            route_x = np.zeros((1, n, d), np.float32)
+            route_x[0, :, :n_exp] = forced_logits(
+                rng, n, k, n_exp, (0, n_held), live, empty_last)
+            route_x = jnp.asarray(route_x)
+            (_, (y, passes, load)), grads = compact(params, x, route_x, g)
+            with jax.default_matmul_precision("highest"):
+                (_, (y_d, _, load_d)), grads_d = dense(params, x, route_x, g)
+            if dense_own is not None:
+                _, grads_d = dense_own(params, x, route_x, g)
+            what = f"F: {cell} N {n} R {rows} L {live}"
+            check(int(load.sum()) == live == int(load_d.sum()),
+                  f"{what}: the router sent {int(load.sum())} live rows")
+            check(not empty_last or int(load[-1]) == 0,
+                  f"{what}: the last held expert was to stay empty")
+            check(int(passes) == -(-live // rows),
+                  f"{what}: {int(passes)} passes reported, "
+                  f"{-(-live // rows)} expected")
+            pairs = [("y", y, y_d)] + [
+                (jax.tree_util.keystr(path), a, b) for (path, a), b in zip(
+                    jax.tree_util.tree_flatten_with_path(grads)[0],
+                    jax.tree_util.tree_leaves(grads_d))]
+            for name, a, b in pairs:
+                check(bool(jnp.isfinite(a.astype(jnp.float32)).all()),
+                      f"{what}: {name} is not finite")
+                if not float(jnp.abs(b).max()):  # the expert bias: no way
+                    check(not float(jnp.abs(a).max()),  # back to it
+                          f"{what}: {name} should be zero")
+                    continue
+                # what belongs to one token (the output, the tokens' and
+                # the router rows' gradients) by its largest difference: a
+                # dropped or doubled row shows there in full; a stack's
+                # gradient, a sum over thousands of rows, by the norm of
+                # the difference.
+                stack = "moe_w_" in name
+                err = (float(jnp.linalg.norm((a - b).astype(jnp.float32))
+                             / jnp.linalg.norm(b)) if stack
+                       else differ(a, b))
+                worst[stack] = max(worst[stack], err)
+                check(err <= 2.0 ** -6,
+                      f"{what}: {name} differs from the dense path's by "
+                      f"{err:.3g} of its "
+                      f"{'norm' if stack else 'largest entry'} "
+                      f"(limit 2^-6)")
+            walked.append(f"{live}:{int(passes)}")
+        said.append(f"{cell} N {n} R {rows} (L:passes {' '.join(walked)}; "
+                    f"largest difference {worst[False]:.4g} of the largest "
+                    f"entry, per token; {worst[True]:.4g} of the norm, the "
+                    f"stacks' gradients)")
+    say(f"F: ok — the held-experts layer's walk against its dense path in "
+        f"float32, output and every gradient, NaN-free: {'; '.join(said)}, "
+        f"{time.monotonic() - t0:.0f}s")
+
+
 # --------------------------------------------------------------------------
 
 def main() -> None:
@@ -776,6 +954,7 @@ def run(dev: dict, t_start: float) -> None:
     phase_b_kernels()
     phase_c(bundle)
     phase_e()
+    phase_f()
 
     say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
         f"the persistent cache, {compiles.requests - compiles.hits} compiled "

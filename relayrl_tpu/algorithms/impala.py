@@ -65,8 +65,8 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
                        params_template=None):
     tx = make_impala_tx(lr, max_grad_norm, freeze, params_template)
     # a MoE trunk reports the expert load of the same forward
-    # (``moe_load_max`` / ``moe_load_min`` / ``moe_held_slots``); every
-    # other family reports {}
+    # (``moe_load_max`` / ``moe_load_min`` / ``moe_held_slots`` /
+    # ``moe_row_passes``); every other family reports {}
     evaluate = policy.evaluate_stats or (
         lambda *args: (*policy.evaluate(*args), {}))
 
@@ -102,7 +102,9 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
             "LossTotal": total,
             "RhoMean": rho_mean,
             "KL": kl,
-            **stats,  # moe_load_max / _min / moe_held_slots, MoE trunks only
+            # moe_load_max / _min / moe_held_slots / moe_row_passes, MoE
+            # trunks only
+            **stats,
         }
         return ImpalaState(params=params, opt_state=opt_state, rng=state.rng,
                            step=state.step + 1), metrics
@@ -182,8 +184,15 @@ class IMPALA(OnPolicyAlgorithm):
                     "token-slots routed to experts this device holds, "
                     "newest update, summed over MoE layers (all N*k a "
                     "layer unless the arch sets moe_held)"),
+                "moe_row_passes": reg.gauge(
+                    "relayrl_moe_row_passes",
+                    "passes the sparse dispatch took over its row buffers, "
+                    "newest update, summed over MoE layers (1 a layer "
+                    "unless a held-experts layer got more rows than its "
+                    "buffer has)"),
             }
-            self._fence_notes = ("moe_load_max", "moe_held_slots")
+            self._fence_notes = ("moe_load_max", "moe_held_slots",
+                                 "moe_row_passes")
 
     def _log_keys(self):
         keys = ("LossPi", "LossV", "Entropy", "RhoMean", "KL")

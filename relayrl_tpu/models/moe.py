@@ -41,8 +41,9 @@ chosen of all E, held or not, so that the shares of all the chips add up
 to the layer), the expert stacks are ``[count, ...]``, and the layer's
 output is the sum over the chosen experts that are held: a token-slot
 whose expert lives elsewhere adds nothing here — no stand-in for the
-absent chips' work or traffic. Unset, every expert is held and the layer
-is what it was.
+absent chips' work or traffic. Unset (or naming all E), every expert is
+held and the layer is the plain sparse dispatch below: a full layer has no
+dead rows to leave out.
 
 Experts are GELU (``moe_w_up`` / ``moe_w_down``, the default) or gated,
 ``moe_w_gate`` beside them: SwiGLU (arch ``ffn: "swiglu"``,
@@ -56,20 +57,57 @@ experts), and the down output is un-sorted and summed per token with the
 router weights. Every shape is static (N·k rows whatever the imbalance),
 there is no capacity and so **no token-slot is ever dropped**: an expert
 that every token picks gets all N rows, an expert nobody picks gets a group
-of size 0. With held experts the buffers keep their N·k rows and the slots
-of absent experts sort behind the held ones, as a tail no group covers:
-the grouped matmuls visit (and cost) the held rows only, the row gathers
-still move N·k rows, and what the kernels leave in the tail — rows they
-never wrote — is masked to zero where it would be read (the un-sorted
-output, the input cotangent). The layer's N·k-row buffers (the dispatched
-rows, up and gate in float32, the un-sorted output) are then recomputed in
-the backward from the ``[N, d]`` tokens (``jax.checkpoint`` round dispatch,
-experts and combine) instead of kept: sized for every slot they are eight
-times what the held slots need, 1.6 GB a layer at 65,536 slots of 2048.
-The FFN costs k/E of running every expert on every token. Both
+of size 0. The FFN costs k/E of running every expert on every token. Both
 permutations are gathers, forward and backward (a permutation's transpose
 is the gather by its inverse), because a TPU scatter-add of [N·k, d] rows
 is slower than the matmuls it serves.
+
+With held experts the layer **works on the rows it holds**. The slots of
+absent experts sort behind the held ones, so the L live rows (the held
+experts' group sizes, summed) come first in the sorted order, and the
+layer's row buffers — the dispatched rows, up and gate in float32, the down
+output — have **R rows, not N·k**: ``R = min(N·k, round_up(margin · N·k ·
+held / E, 512))`` (:func:`row_buffer`), what even routing would send this
+device times a margin (:data:`_ROW_MARGIN`, with the readings it was set
+from), in whole row tiles of the grouped matmuls. The layer walks the
+sorted order in **``ceil(L / R)`` passes**, a loop whose trip count the
+update computes: pass p takes rows ``[p R, (p + 1) R)`` through ONE copy of
+the grouped matmuls with pass-local group sizes and adds its tokens' shares
+to ``y [N, d]`` in float32. One pass while the routing stays under the
+margin — a learner that trains only the experts it holds draws the routing
+towards them, update by update — and as many as it takes beyond: a router
+that sends this device everything takes ``ceil(N·k / R)``. Every live slot
+is computed exactly once whatever the routing, the guarantee above
+unchanged: no capacity, no dropped slot, the same sums. Token → row is an
+R-row gather (``tokens[token_of_row]``; in the backward the output's
+cotangent by the same index, times the row's router weight at R rows).
+Row → token is ONE N·k-row gather from the R-row buffer and a weighted sum
+over k: measured against an R-row scatter-add into ``[N, d]``, which costs
+206–320 ns a row where a gathered row costs 26–31 (PERF.md section 6,
+PR 35). **No arithmetic touches a row the kernels did not write**: what
+they leave in a pass's tail — rows past its live ones — is whatever the
+buffer held, and ``0 x`` that is NaN where that is NaN. A slot without a row
+in this pass (an absent expert's, another pass's) gathers row 0, a live
+row, and is then SELECTED away (``where``), in the forward's combine and in
+the tokens' gradient; the router weights' gradient selects its live rows at
+R rows; the stacks' gradients come from kernels that select their groups'
+rows themselves; and only selected values are accumulated across passes.
+Forward and backward are each a loop of their own under one
+``jax.custom_vjp`` (:func:`_held_experts`): the forward keeps the
+``[N, d]`` tokens, the router weights, the sort's index vectors and the
+stacks; the backward loop makes a pass's R-row buffers again from the
+tokens and runs that pass's transpose, accumulating the stacks' gradients,
+the tokens' and the router weights'. Differentiating THROUGH the loop (or
+through a ``lax.cond`` that picked a buffer size) would make every buffer
+of every pass a residual of the forward, written in full — zero-filled —
+for the passes not taken: the N·k-row buffers this form exists to avoid. A
+pass's experts, and their transpose, are each one inner ``jax.jit``
+(:data:`_shared_experts`, :func:`_shared_experts_vjp`): the held layers of
+a trunk share ONE trace and ONE lowering of the kernels — set-up time, not
+speed: the compiled update inlines the calls, twelve Mosaic calls a layer
+as before. The layer sows ``row_passes`` (the trips its forward loop
+counted as it ran) and ``row_buffer`` (R) beside ``expert_load``; the
+update reports ``moe_row_passes``.
 
 The **dense** path — every expert on every token, a dense ``[N, E]`` weight
 mask in the combine einsum, E/k times the FLOPs — is what GSPMD partitions:
@@ -101,6 +139,7 @@ Shapes: tokens flatten to ``[N = B*T, d]``; expert stacks are
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -143,31 +182,24 @@ def _slots_3d(rows, n: int, choice_major: bool):
     return rows.reshape(lead + rows.shape[-1:])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch_rows(tokens, token_of_row, slot_to_row, live_slot=None,
-                   choice_major=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(tokens, token_of_row, slot_to_row, choice_major=False):
     """``tokens[token_of_row]``: each token's row once per chosen expert,
     in expert order. Its transpose is a gather too: cotangent rows back in
-    slot order (``slot_to_row``), the k of each token summed.
-    ``live_slot`` (bool per slot, held-experts layers): the cotangent of a
-    slot whose expert is not held is zero, whatever its row holds."""
-    return _dispatch_fwd(tokens, token_of_row, slot_to_row, live_slot,
-                         choice_major)[0]
+    slot order (``slot_to_row``), the k of each token summed."""
+    return _dispatch_fwd(tokens, token_of_row, slot_to_row, choice_major)[0]
 
 
-def _dispatch_fwd(tokens, token_of_row, slot_to_row, live_slot=None,
-                  choice_major=False):
+def _dispatch_fwd(tokens, token_of_row, slot_to_row, choice_major=False):
     out = jnp.take(tokens, token_of_row, axis=0)
-    return out, (slot_to_row, live_slot, tokens.shape[0])
+    return out, (slot_to_row, tokens.shape[0])
 
 
 def _dispatch_bwd(choice_major, res, g):
-    slot_to_row, live_slot, n = res
+    slot_to_row, n = res
     g = jnp.take(g, slot_to_row, axis=0)
-    if live_slot is not None:
-        g = jnp.where(live_slot[:, None], g, 0)
     g = _slots_3d(g, n, choice_major).sum(axis=0 if choice_major else 1)
-    return g, None, None, None
+    return g, None, None
 
 
 _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -205,6 +237,198 @@ def grouped_matmul(lhs, rhs, group_sizes):
             return kernels.gmm(lhs, rhs, group_sizes)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                               preferred_element_type=lhs.dtype)
+
+
+def _activation(ffn: str, up, gate=None):
+    """The experts' inner activation: ``act(gate) * up`` where the FFN is
+    gated (``gate`` given), GELU of ``up`` otherwise."""
+    return nn.gelu(up) if gate is None else GATED_FFN[ffn](gate) * up
+
+
+def _experts(ffn, xs, stacks, group_sizes):
+    """Expert-ordered rows ``xs [m, d]`` through their experts' FFN:
+    ``stacks = (w_up, w_gate | None, w_down)``, float32 between the
+    grouped matmuls. Rows past ``group_sizes.sum()`` are never written."""
+    w_up, w_gate, w_down = stacks
+
+    def up_proj(w):
+        return grouped_matmul(xs, w, group_sizes).astype(jnp.float32)
+
+    h = _activation(ffn, up_proj(w_up),
+                    None if w_gate is None else up_proj(w_gate))
+    return grouped_matmul(h.astype(xs.dtype), w_down, group_sizes)
+
+
+# -- the held-experts layer: R-row buffers walked in ceil(live / R) passes ---
+
+# Rows of a held layer's buffers over the rows even routing would send it
+# (N*k * held / E). A learner that trains only the experts it holds draws the
+# routing towards them: read on the chip every update over 20 s windows of the
+# benchmark's two held cells (PERF.md section 6, PR 36), each layer's live
+# rows stay at 0.76-1.28 of the even share for 40 updates (8 of 64 held) or
+# 25 (16 of 64), pass 1.25 after 40-82 / 36-44 and stand at 1.4-2.3 / 1.5-1.9
+# when the window ends after 90 / 55. At 2 a layer takes one pass through
+# such a window on most seeds and a second for its last updates on the rest;
+# against 1.25 the larger buffers cost 4 ms an update at one pass where a
+# second walk cost 7 a layer (8 of 64 held; 13 where one cost 21, 16 of 64).
+_ROW_MARGIN = 2.0
+# ... rounded up to the grouped matmul kernels' row tile
+# (``ops.grouped_matmul.TILING[0]``; not imported: module docstring there)
+_ROW_TILE = 512
+
+
+def row_buffer(n_slots: int, n_held: int, n_exp: int) -> int:
+    """Rows of a held-experts layer's buffers: what the layer can observe
+    (its slots, the share of the experts it holds) says how many."""
+    rows = math.ceil(_ROW_MARGIN * n_slots * n_held / n_exp / _ROW_TILE)
+    return min(n_slots, rows * _ROW_TILE)
+
+
+def _passes(load, rows: int):
+    """Passes that cover the live rows: ``ceil(load.sum() / rows)``."""
+    return (load.sum() + (rows - 1)) // rows
+
+
+def _pass_of(p, rows, load, row_to_slot, slot_to_row):
+    """Pass ``p``'s share of the sorted order, rows ``[p R, (p + 1) R)``.
+    From the rows' side: the slot of each row, the rows that are live and
+    the pass-local group sizes. From the tokens' side: each slot's row in
+    this pass's buffers and whether it has one (a slot of an absent expert,
+    of another pass or of no pass points at row 0 and has none)."""
+    slots = jax.lax.dynamic_slice(row_to_slot, (p * rows,), (rows,))
+    ends = jnp.clip(jnp.cumsum(load) - p * rows, 0, rows)
+    live = jnp.arange(rows) < ends[-1]
+    row_of_slot = slot_to_row - p * rows
+    has_row = (row_of_slot >= 0) & (row_of_slot < ends[-1])
+    return (slots, live, jnp.diff(ends, prepend=0),
+            jnp.where(has_row, row_of_slot, 0), has_row)
+
+
+def _rows_of(tokens, slots, k, choice_major):
+    """``tokens [N, .]`` gathered to the rows of ``slots`` (R rows)."""
+    token_of_row = slots % tokens.shape[0] if choice_major else slots // k
+    return tokens.at[token_of_row].get(mode="promise_in_bounds")
+
+
+def _rows_to_tokens(rows, row_of_slot, has_row, n, choice_major,
+                    slot_weights=None):
+    """Each token's sum over its k slots of the slot's row (times
+    ``slot_weights`` where given), float32 ``[N, d]``: ONE N*k-row gather
+    and a sum over k. A slot without a row in this pass reads row 0 and is
+    SELECTED away — never multiplied by a zero weight: ``0 x`` whatever a
+    row holds is NaN where that is NaN. The sum is written out choice by
+    choice: as a reduction XLA first writes the gathered rows again in
+    float32."""
+    got = rows.at[row_of_slot].get(mode="promise_in_bounds")
+    axis = 0 if choice_major else 1
+
+    def choice(a, j):  # slot-ordered [N*k, .] -> choice j's [N, .]
+        return jnp.take(_slots_3d(a, n, choice_major), j, axis=axis)
+
+    def share(j):
+        row = choice(got, j).astype(jnp.float32)
+        if slot_weights is not None:
+            row = choice(slot_weights[:, None], j) * row
+        return jnp.where(choice(has_row[:, None], j), row, 0)
+
+    return sum(share(j) for j in range(has_row.shape[0] // n))
+
+
+# One trace and one lowering of the experts (and of their transpose) for
+# all the held layers of a trunk that share its shapes: a layer calls them.
+_shared_experts = jax.jit(_experts, static_argnums=0)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _shared_experts_vjp(ffn, xs, stacks, group_sizes, d_out):
+    """``(out, d xs, d stacks)`` of :func:`_experts` at ``d_out``."""
+    # a vjp's name transforms wrap the first scope entered under them: this
+    # one, so that the kernels keep the names a device trace finds them by
+    # (ops/grouped_matmul.py)
+    def experts(xs, stacks):
+        with jax.named_scope("held_experts"):
+            return _experts(ffn, xs, stacks, group_sizes)
+
+    out, transpose = jax.vjp(experts, xs, stacks)
+    return (out, *transpose(d_out))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(ffn, rows, choice_major, tokens, top_w, stacks, load,
+                  row_to_slot, slot_to_row):
+    """The held-experts layer on the rows it holds (module docstring):
+    ``(y [N, d] float32, the passes the loop ran)``. ``row_to_slot``: the
+    slots sorted by held expert, absent experts' behind, padded to a whole
+    number of ``rows``-row passes; ``slot_to_row``: its inverse."""
+    return _held_fwd(ffn, rows, choice_major, tokens, top_w, stacks, load,
+                     row_to_slot, slot_to_row)[0]
+
+
+def _flat(top_w, choice_major):
+    return (top_w.T if choice_major else top_w).reshape(-1)
+
+
+def _held_fwd(ffn, rows, choice_major, tokens, top_w, stacks, load,
+              row_to_slot, slot_to_row):
+    n, k = top_w.shape
+    top_w_flat = _flat(top_w, choice_major)
+
+    def one_pass(p, carry):
+        y, ran = carry
+        slots, _, sizes, row_of_slot, has_row = _pass_of(
+            p, rows, load, row_to_slot, slot_to_row)
+        out = _shared_experts(ffn, _rows_of(tokens, slots, k, choice_major),
+                              stacks, sizes)
+        return (y + _rows_to_tokens(out, row_of_slot, has_row, n,
+                                    choice_major, top_w_flat), ran + 1)
+
+    out = jax.lax.fori_loop(
+        0, _passes(load, rows), one_pass,
+        (jnp.zeros(tokens.shape, jnp.float32), jnp.int32(0)))
+    return out, (tokens, top_w, stacks, load, row_to_slot, slot_to_row)
+
+
+def _held_bwd(ffn, rows, choice_major, res, g):
+    """One loop of its own: pass p's R-row buffers again from the [N, d]
+    tokens, then that pass's transpose. Differentiating THROUGH a loop (or
+    a ``cond``) would make every buffer of every pass a residual of the
+    forward, zero-filled where a pass did not run."""
+    tokens, top_w, stacks, load, row_to_slot, slot_to_row = res
+    g_y, _ = g  # the trip count is an integer: nothing comes back for it
+    n, k = top_w.shape
+    top_w_flat = _flat(top_w, choice_major)
+
+    def one_pass(p, carry):
+        d_tokens, d_weights, d_stacks = carry
+        slots, live, sizes, row_of_slot, has_row = _pass_of(
+            p, rows, load, row_to_slot, slot_to_row)
+        g_rows = _rows_of(g_y, slots, k, choice_major)
+        weights = top_w_flat.at[slots].get(mode="promise_in_bounds")
+        out, d_xs, d_pass = _shared_experts_vjp(
+            ffn, _rows_of(tokens, slots, k, choice_major), stacks, sizes,
+            (g_rows * weights[:, None]).astype(tokens.dtype))
+        # the tail's rows were never written: selected away, not masked
+        d_w = jnp.where(live, (out.astype(jnp.float32) * g_rows).sum(-1), 0)
+        d_tokens = d_tokens + _rows_to_tokens(d_xs, row_of_slot, has_row, n,
+                                              choice_major)
+        return (d_tokens,
+                jax.lax.dynamic_update_slice(d_weights, d_w, (p * rows,)),
+                jax.tree_util.tree_map(jnp.add, d_stacks, d_pass))
+
+    d_tokens, d_weights, d_stacks = jax.lax.fori_loop(
+        0, _passes(load, rows), one_pass,
+        (jnp.zeros(tokens.shape, jnp.float32),
+         jnp.zeros(row_to_slot.shape, jnp.float32),
+         jax.tree_util.tree_map(jnp.zeros_like, stacks)))
+    # each slot's weight gradient is its row's (0 for a slot without one)
+    d_top_w = d_weights.at[slot_to_row].get(mode="promise_in_bounds")
+    d_top_w = (d_top_w.reshape(k, n).T if choice_major
+               else d_top_w.reshape(n, k))
+    return (d_tokens.astype(tokens.dtype), d_top_w.astype(top_w.dtype),
+            d_stacks, None, None, None)
+
+
+_held_experts.defvjp(_held_fwd, _held_bwd)
 
 
 def _mesh_ep() -> int:
@@ -276,9 +500,6 @@ class MoEMLP(nn.Module):
         w_down = self.param("moe_w_down", init, (n_held, self.d_ff, d),
                             jnp.float32).astype(cd)
 
-        def act(up, gate=None):
-            return GATED_FFN[self.ffn](gate) * up if gated else nn.gelu(up)
-
         # token-slots per (held) expert: the grouped matmuls' group sizes
         # and the load monitor (a compare-and-reduce; a scatter-add
         # serialises)
@@ -297,10 +518,12 @@ class MoEMLP(nn.Module):
                 return jnp.einsum("nd,edf->enf", xs, w,
                                   preferred_element_type=jnp.float32)
 
-            h = act(up_proj(w_up), up_proj(w_gate) if gated else None)
+            h = _activation(self.ffn, up_proj(w_up),
+                            up_proj(w_gate) if gated else None)
             out = jnp.einsum("enf,efd->end", h.astype(cd), w_down,
                              preferred_element_type=jnp.float32)
             y = jnp.einsum("ne,end->nd", weights, out)       # psum over ep
+            rows, row_passes = 0, jnp.int32(0)       # no row buffers here
         elif dispatch == "sparse":
             if _mesh_ep() > 1:
                 raise ValueError(
@@ -321,64 +544,62 @@ class MoEMLP(nn.Module):
             choice_major = k % 8 != 0
             expert_of_slot = (top_idx.T if choice_major else top_idx
                               ).reshape(n * k)
-            live_slot = None
+            stacks = (w_up, w_gate if gated else None, w_down)
             if partial:
                 # the slots of absent experts sort behind the held ones: a
-                # tail of rows no group covers and no matmul visits
+                # tail no pass reaches (module docstring, "Held experts")
                 local = expert_of_slot - first
-                live_slot = (local >= 0) & (local < n_held)
-                expert_of_slot = jnp.where(live_slot, local, n_held)
+                expert_of_slot = jnp.where((local >= 0) & (local < n_held),
+                                           local, n_held)
             row_to_slot = jnp.argsort(expert_of_slot, stable=True)
             slot_to_row = jnp.zeros_like(row_to_slot).at[row_to_slot].set(
                 jnp.arange(n * k, dtype=row_to_slot.dtype),
                 unique_indices=True)
-            token_of_row = (row_to_slot % n if choice_major
-                            else row_to_slot // k)
-
-            def sparse(tokens, top_w, w_up, w_gate, w_down):
-                xs = _dispatch_rows(tokens, token_of_row, slot_to_row,
-                                    live_slot, choice_major)      # [N*k, d]
-
-                def up_proj(w):
-                    return grouped_matmul(xs, w, load).astype(jnp.float32)
-
-                h = act(up_proj(w_up), up_proj(w_gate) if gated else None)
-                out = grouped_matmul(h.astype(cd), w_down, load)  # [N*k, d]
-                out = _unsort_rows(out, slot_to_row, row_to_slot)
-                if partial:  # the tail's rows were never written
-                    out = jnp.where(live_slot[:, None], out, 0)
-                out = _slots_3d(out, n, choice_major).astype(jnp.float32)
-                if choice_major:
-                    return jnp.einsum("kn,knd->nd", top_w.T, out)
-                return jnp.einsum("nk,nkd->nd", top_w, out)
-
             if partial:
-                # every N*k-row buffer (the dispatched rows, up and gate in
-                # float32, the un-sorted output) is sized for all the slots
-                # whatever the held rows: recomputed in the backward from
-                # the [N, d] tokens, not kept (module docstring)
-                sparse = jax.checkpoint(sparse)
-            y = sparse(tokens.astype(cd), top_w, w_up,
-                       w_gate if gated else None, w_down)
+                rows = row_buffer(n * k, n_held, n_exp)
+                y, row_passes = _held_experts(
+                    self.ffn, rows, choice_major, tokens.astype(cd), top_w,
+                    stacks, load,
+                    jnp.pad(row_to_slot, (0, -(n * k) % rows)), slot_to_row)
+            else:
+                rows, row_passes = n * k, jnp.int32(1)
+                token_of_row = (row_to_slot % n if choice_major
+                                else row_to_slot // k)
+                xs = _dispatch_rows(tokens.astype(cd), token_of_row,
+                                    slot_to_row, choice_major)    # [N*k, d]
+                out = _experts(self.ffn, xs, stacks, load)        # [N*k, d]
+                out = _unsort_rows(out, slot_to_row, row_to_slot)
+                out = _slots_3d(out, n, choice_major).astype(jnp.float32)
+                y = (jnp.einsum("kn,knd->nd", top_w.T, out) if choice_major
+                     else jnp.einsum("nk,nkd->nd", top_w, out))
         else:
             raise ValueError(f"unknown moe_dispatch {dispatch!r}")
 
         # Monitoring hook: token-slots per held expert (sums to N*k where
-        # every expert is held). Inert unless applied with
+        # every expert is held), and how the sparse dispatch walked them:
+        # the rows of its buffers and the passes it took over them (1 over
+        # N*k rows where every expert is held). Inert unless applied with
         # mutable=["intermediates"] — the update's moe_load_max/min,
-        # moe_held_slots and expert_utilization() read it.
+        # moe_held_slots, moe_row_passes and expert_utilization() read it.
         self.sow("intermediates", "expert_load", load)
         self.sow("intermediates", "expert_slots", jnp.int32(n * k))
+        self.sow("intermediates", "row_passes", row_passes)
+        self.sow("intermediates", "row_buffer", jnp.int32(rows))
         return y.reshape(B, T, d).astype(x.dtype)
+
+
+def _sown(intermediates) -> dict:
+    """``{layer: what its MoE layer sowed}`` of one applied forward."""
+    return {layer: sub["moe"] for layer, sub in intermediates.items()
+            if layer.startswith("block_") and "moe" in sub}
 
 
 def _loads(intermediates) -> dict:
     """``{layer: ([held] token-slots per held expert, all N*k slots)}``
     from one applied forward's sown counts."""
-    return {layer: (sub["moe"]["expert_load"][0].astype(jnp.float32),
-                    sub["moe"]["expert_slots"][0].astype(jnp.float32))
-            for layer, sub in intermediates.items()
-            if layer.startswith("block_") and "moe" in sub}
+    return {layer: (moe["expert_load"][0].astype(jnp.float32),
+                    moe["expert_slots"][0].astype(jnp.float32))
+            for layer, moe in _sown(intermediates).items()}
 
 
 def _shares(intermediates) -> dict:
@@ -388,16 +609,22 @@ def _shares(intermediates) -> dict:
 
 
 def load_extremes(intermediates) -> dict:
-    """``{"moe_load_max", "moe_load_min", "moe_held_slots"}``: the fullest
-    and the emptiest held expert's share of all the token-slots, over every
-    MoE layer of one applied forward (nothing is recomputed), and the
-    token-slots routed to held experts, summed over those layers. 1/E each
-    at even load; max -> 1/k is the gate collapsing; held slots = N*k a
-    layer where every expert is held."""
+    """``{"moe_load_max", "moe_load_min", "moe_held_slots",
+    "moe_row_passes"}``: the fullest and the emptiest held expert's share
+    of all the token-slots, over every MoE layer of one applied forward
+    (nothing is recomputed); the token-slots routed to held experts and the
+    passes the sparse dispatch took over its row buffers, each summed over
+    those layers. 1/E each at even load; max -> 1/k is the gate collapsing;
+    held slots = N*k a layer where every expert is held; passes = 1 a
+    layer unless a held-experts layer's router sent it more rows than its
+    buffer has."""
     shares = jnp.stack(list(_shares(intermediates).values()))
     held = sum(load.sum() for load, _ in _loads(intermediates).values())
+    passes = sum(moe["row_passes"][0]
+                 for moe in _sown(intermediates).values())
     return {"moe_load_max": shares.max(), "moe_load_min": shares.min(),
-            "moe_held_slots": held}
+            "moe_held_slots": held,
+            "moe_row_passes": jnp.asarray(passes, jnp.float32)}
 
 
 def expert_utilization(arch, params, obs, mask=None) -> dict:

@@ -76,3 +76,54 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
     # grouped k/v are never repeated: dk and dv come back at their heads
     assert [o.shape for o in compiled.out_info[1]] == [
         x.shape, kv.shape, kv.shape]
+
+
+def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
+    """The held-experts layer (``models/moe.py``) at ``lfm2-policy``'s
+    widths, 8 of 64 experts held, a quarter of its tokens: ONE copy of the
+    layer's grouped matmuls in each of its two pass loops — twelve Mosaic
+    calls, under the three names a device trace finds them by (the
+    backward's ``jax.vjp`` inside the loop must not wrap them: the
+    benchmark's ``moe_ffn_ms`` matches ``relayrl_moe_gmm`` at the start of
+    an instruction's name) — and no N*k-row buffer of model width."""
+    import collections
+    import re
+
+    from relayrl_tpu.models import moe
+
+    # the layer asks the backend whether the kernels may run; here the
+    # test (not the program) answers for the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, k = 4096, 2048, 4
+    layer = moe.MoEMLP(d, 1536, 64, k, jnp.bfloat16, ffn="swiglu",
+                       use_bias=False, router="sigmoid", expert_bias=True,
+                       held=(0, 8))
+    rows = moe.row_buffer(n * k, 8, 64)
+    assert rows == 4096
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    x = jnp.zeros((1, n, d), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    try:
+        compiled = jax.jit(jax.value_and_grad(
+            lambda p, x: jnp.sum(layer.apply(p, x).astype(jnp.float32)),
+            (0, 1))).lower(on_chip(params), on_chip(x)).compile()
+    finally:  # traces made under the answer "tpu" stay in this test
+        moe._shared_experts.clear_cache()
+        moe._shared_experts_vjp.clear_cache()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 12
+    names = collections.Counter(
+        re.sub(r"[._]\d+$", "", name)
+        for name in re.findall(r"%(\S*relayrl_moe_gmm\S*) = ", text))
+    assert names == {"relayrl_moe_gmm_fwd": 6, "relayrl_moe_gmm_dlhs": 3,
+                     "relayrl_moe_gmm_drhs": 3}
+    assert f"bf16[{rows},{d}]" in text
+    # row -> token is one N*k-row gather a pass and direction, read by the
+    # sum over k that follows it: nothing else is N*k rows of d
+    assert len(set(re.findall(rf"\w+\[{n * k},{d}\]", text))) <= 1
+    assert f"f32[{k},{n},{d}]" not in text and f"f32[{n * k},{d}]" not in text

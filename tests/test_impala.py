@@ -223,11 +223,18 @@ def test_impala_updates_the_olmoe_block(tmp_path, precision):
         assert 1 / 8 <= metrics["moe_load_max"] <= 1 / 2
         assert 0.0 <= metrics["moe_load_min"] <= 1 / 8
         assert {"moe_load_max", "moe_load_min"} <= set(algo._log_keys())
+        # every expert held: one pass over N k rows a MoE layer, written on
+        # the fence span beside the load
+        n_moe = sum("moe" in block
+                    for block in algo.state.params["params"].values())
+        assert metrics["moe_row_passes"] == n_moe > 0
+        assert "moe_row_passes" in algo._fence_notes
         snap = {m["name"]: m["value"]
                 for m in telemetry.get_registry().snapshot()["metrics"]
                 if m["kind"] == "gauge"}
         assert snap["relayrl_moe_load_max"] == pytest.approx(
             metrics["moe_load_max"])
+        assert snap["relayrl_moe_row_passes"] == n_moe
         after = algo.state.params["params"]
         for stack in ("moe_w_gate", "moe_w_up", "moe_w_down"):
             moved = np.abs(np.asarray(after["block_1"]["moe"][stack])

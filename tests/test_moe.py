@@ -375,6 +375,88 @@ def _share_of(params, first, count):
     return {"params": p}
 
 
+def _row_buffer_of(monkeypatch, rows, n_slots, n_held, n_exp):
+    """Make the held layer's buffers ``rows`` long at this shape — through
+    the module's margin and row tile, as a router's imbalance would at the
+    real ones (no arch key sets them)."""
+    from relayrl_tpu.models import moe
+
+    monkeypatch.setattr(moe, "_ROW_TILE", 1)
+    monkeypatch.setattr(moe, "_ROW_MARGIN",
+                        (rows - 0.5) * n_exp / (n_slots * n_held))
+    assert moe.row_buffer(n_slots, n_held, n_exp) == rows
+
+
+def _poison_unwritten_rows(monkeypatch):
+    """The TPU kernels never write the rows past the last group, in the
+    product and in its transpose to the rows (``d_lhs``), and never read
+    them (the transpose to the stacks selects its groups' rows);
+    ``ragged_dot`` zero-fills and multiplies by masks. This stand-in puts
+    NaN where the kernels leave whatever was there: every read of such a
+    row shows in the result."""
+    from relayrl_tpu.models import moe
+
+    plain = moe.grouped_matmul
+
+    def written(rows, group_sizes):
+        return (jnp.arange(rows.shape[0]) < group_sizes.sum())[:, None]
+
+    @jax.custom_vjp
+    def poisoned(lhs, rhs, group_sizes):
+        return jnp.where(written(lhs, group_sizes),
+                         plain(lhs, rhs, group_sizes), jnp.nan)
+
+    def fwd(lhs, rhs, group_sizes):
+        return poisoned(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(res, g):
+        lhs, rhs, group_sizes = res
+        live = written(lhs, group_sizes)
+        _, transpose = jax.vjp(
+            lambda a, b: plain(a, b, group_sizes),
+            jnp.where(live, lhs, 0), rhs)
+        d_lhs, d_rhs = transpose(jnp.where(live, g, 0).astype(lhs.dtype))
+        return jnp.where(live, d_lhs, jnp.nan), d_rhs, None
+
+    poisoned.defvjp(fwd, bwd)
+    monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+
+
+@pytest.fixture(autouse=True)
+def _fresh_expert_traces():
+    """The held layers of a process share one trace of their experts a
+    shape (``moe._shared_experts``): a test that stands something in for
+    what that trace calls must start, and leave, with none."""
+    from relayrl_tpu.models import moe
+
+    def clear():
+        moe._shared_experts.clear_cache()
+        moe._shared_experts_vjp.clear_cache()
+
+    clear()
+    yield
+    clear()
+
+
+def _impala_update_of(policy):
+    """``(update, state of shapes)``: IMPALA's update for ``policy`` as the
+    learner builds it, and a state to lower or (given real parameters) run
+    it with."""
+    from relayrl_tpu.algorithms.impala import (
+        ImpalaState, make_impala_tx, make_impala_update)
+
+    tx = make_impala_tx(1e-4, 1.0)
+
+    def state_of(params):
+        return ImpalaState(params=params, opt_state=tx.init(params),
+                           rng=jax.random.PRNGKey(0), step=jnp.int32(0))
+
+    update = make_impala_update(
+        policy, lr=1e-4, gamma=0.99, vf_coef=0.5, ent_coef=0.01,
+        rho_bar=1.0, c_bar=1.0, max_grad_norm=1.0)
+    return update, state_of
+
+
 class TestSigmoidRouter:
     @pytest.mark.parametrize("norm", [False, True])
     @pytest.mark.parametrize("k", [2, 3])
@@ -437,6 +519,206 @@ class TestHeldExperts:
             np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4,
                                        err_msg=jax.tree_util.keystr(path))
 
+    @pytest.mark.parametrize("poisoned", [False, True],
+                             ids=["zero_filled", "poisoned"])
+    @pytest.mark.parametrize("passes", [1, 2, "max"])
+    @pytest.mark.parametrize("k", [2, 4, 6, 8])
+    @pytest.mark.parametrize("first,count", [(0, 8), (5, 9)])
+    def test_row_buffers_walked_in_passes_match_dense(self, monkeypatch,
+                                                      first, count, k,
+                                                      passes, poisoned):
+        """The compact layer — R-row buffers, ceil(live / R) passes, its
+        own backward loop — against the dense form, whatever R: one pass
+        with a tail of unwritten rows, two, and (every token routed to
+        held experts) ceil(N k / R). k = 2, 4, 6 run choice-major, k = 8
+        token-major. Forward, loss and EVERY gradient: tokens, the router
+        (``top_w``'s only way back), the three stacks. Once on
+        ``ragged_dot`` as it is, which zero-fills the rows past the groups,
+        and once with NaN there, as on the chip they hold whatever they
+        held: a read of one fails the case."""
+        e, slots = 16, _N * k
+        params, x = _held_params(e=e, k=k)
+        if passes == "max":  # every choice among the held experts
+            bias = np.full(e, -50.0, np.float32)
+            bias[first:first + count] = 0.0
+            params["params"]["moe_expert_bias"] = jnp.asarray(bias)
+        share = _share_of(params, first, count)
+        _, state = _held_layer((first, count), "dense", e, k).apply(
+            share, x, mutable=["intermediates"])
+        live = int(state["intermediates"]["expert_load"][0].sum())
+        assert live == slots if passes == "max" else 2 <= live < slots
+        rows = {1: live + 3, 2: -(-live // 2), "max": slots // 3 - 1}[passes]
+        want = {1: 1, 2: 2, "max": 4}[passes]
+        _row_buffer_of(monkeypatch, rows, slots, count, e)
+        if poisoned:
+            _poison_unwritten_rows(monkeypatch)
+
+        def loss(dispatch):
+            def f(p, x):
+                y, state = _held_layer((first, count), dispatch, e, k).apply(
+                    p, x, mutable=["intermediates"])
+                return jnp.sum(jnp.sin(y) * x), (y, state["intermediates"])
+            return f
+
+        (ls, (ys, sown)), gs = jax.value_and_grad(
+            loss("sparse"), (0, 1), has_aux=True)(share, x)
+        (ld, (yd, _)), gd = jax.value_and_grad(
+            loss("dense"), (0, 1), has_aux=True)(share, x)
+        assert int(sown["row_passes"][0]) == want
+        assert int(sown["row_buffer"][0]) == rows
+        np.testing.assert_allclose(ys, yd, atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(float(ls), float(ld), atol=2e-4,
+                                   rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+        router = gs[0]["params"]["moe_gate"]["kernel"]
+        assert float(jnp.abs(router).max()) > 0
+
+    @pytest.mark.parametrize("k", [4, 8])
+    @pytest.mark.parametrize("live,rows,want", [
+        (31, 32, 1), (32, 32, 1), (33, 32, 2), (63, 32, 2), (64, 32, 2),
+        (65, 32, 3), (0, 32, 0), ("empty_last", 32, 2)])
+    def test_live_rows_at_the_buffer_s_edge(self, monkeypatch, live, rows,
+                                            want, k):
+        """The crossing: a router made to send the layer exactly R - 1, R,
+        R + 1, 2R - 1, 2R, 2R + 1 live rows (and none; and none to the last
+        held expert), the unwritten rows poisoned: forward, trip count and
+        every gradient — tokens, the router's input, the stacks — against
+        the dense path."""
+        from chip_smoke import forced_logits  # phase F walks them on chip
+        from relayrl_tpu.models.moe import MoEMLP
+
+        e, (first, count) = 16, (3, 8)
+        empty_last = live == "empty_last"
+        rng = np.random.default_rng(7)
+        logits = forced_logits(rng, _N, k, e, (first, count),
+                               40 if empty_last else live, empty_last)
+        x = jnp.asarray(rng.standard_normal((1, _N, _D)), jnp.float32)
+        # the router reads its own rows: the logits, through an identity
+        route_x = jnp.asarray(logits).reshape(1, _N, e)
+        assert e == _D
+
+        def layer(dispatch):
+            return MoEMLP(_D, _FF, e, k, jnp.float32, ffn="reglu",
+                          dispatch=dispatch, use_bias=False,
+                          held=(first, count))
+
+        params = layer("dense").init(jax.random.PRNGKey(0), x, route_x)
+        params["params"]["moe_gate"]["kernel"] = jnp.eye(e)
+        _row_buffer_of(monkeypatch, rows, _N * k, count, e)
+        _poison_unwritten_rows(monkeypatch)
+
+        def loss(dispatch):
+            def f(p, x, route_x):
+                y, state = layer(dispatch).apply(
+                    p, x, route_x, mutable=["intermediates"])
+                return jnp.sum(jnp.sin(y) * x), (y, state["intermediates"])
+            return jax.value_and_grad(f, (0, 1, 2), has_aux=True)
+
+        (_, (ys, sown)), gs = loss("sparse")(params, x, route_x)
+        (_, (yd, _)), gd = loss("dense")(params, x, route_x)
+        load = np.asarray(sown["expert_load"][0])
+        assert load.sum() == (40 if empty_last else live)
+        assert not empty_last or load[-1] == 0
+        assert int(sown["row_passes"][0]) == want
+        np.testing.assert_allclose(ys, yd, atol=2e-5, rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+
+    @pytest.mark.parametrize("rows", [7, 16, 48])
+    def test_sown_row_passes_is_the_count_the_loop_ran(self, monkeypatch,
+                                                       rows):
+        """``row_passes`` is the loop's own count: as many as the calls a
+        host callback sees the loop body make, forward and backward."""
+        from relayrl_tpu.models import moe
+
+        calls = {"fwd": 0, "bwd": 0}
+
+        def counted(name, inner):
+            def call(*args):
+                jax.debug.callback(
+                    lambda: calls.__setitem__(name, calls[name] + 1))
+                return inner(*args)
+            return call
+
+        monkeypatch.setattr(moe, "_shared_experts",
+                            counted("fwd", moe._shared_experts))
+        monkeypatch.setattr(moe, "_shared_experts_vjp",
+                            counted("bwd", moe._shared_experts_vjp))
+        params, x = _held_params(e=16, k=4)
+        share = _share_of(params, 5, 9)
+        _row_buffer_of(monkeypatch, rows, _N * 4, 9, 16)
+
+        def f(p, x):
+            y, state = _held_layer((5, 9), "sparse", 16, 4).apply(
+                p, x, mutable=["intermediates"])
+            return jnp.sum(y), state["intermediates"]
+
+        (_, sown), _ = jax.jit(jax.value_and_grad(f, has_aux=True))(share, x)
+        jax.effects_barrier()
+        live = int(sown["expert_load"][0].sum())
+        assert calls == {"fwd": -(-live // rows), "bwd": -(-live // rows)}
+        assert int(sown["row_passes"][0]) == calls["fwd"] >= 1
+
+    def test_row_buffer_follows_the_held_share(self):
+        from relayrl_tpu.models.moe import row_buffer
+
+        # the two held cells of the benchmark: 8 and 16 of 64 experts held
+        assert row_buffer(16384 * 4, 8, 64) == 16384
+        assert row_buffer(16384 * 6, 16, 64) == 49152
+        # whole row tiles, and never more rows than there are slots (a
+        # decode step's handful: one pass over all of them)
+        assert row_buffer(8192, 3, 64) == 1024
+        assert row_buffer(2, 3, 8) == 2
+        assert row_buffer(16384 * 8, 64, 64) == 16384 * 8
+
+    def test_no_token_routed_to_held_experts_takes_no_pass(self,
+                                                           monkeypatch):
+        # every token to experts 2 and 3, the layer holds 4..7: no live
+        # row, no pass, nothing added and nothing but zeros sent back
+        _poison_unwritten_rows(monkeypatch)
+        params, x = _held_params()
+        bias = np.full(8, -50.0, np.float32)
+        bias[2:4] = 50.0
+        params["params"]["moe_expert_bias"] = jnp.asarray(bias)
+
+        def f(p, x):
+            y, state = _held_layer((4, 4)).apply(p, x,
+                                                 mutable=["intermediates"])
+            return jnp.sum(jnp.sin(y) * x), (y, state["intermediates"])
+
+        (_, (y, sown)), grads = jax.value_and_grad(f, (0, 1), has_aux=True)(
+            _share_of(params, 4, 4), x)
+        assert int(sown["row_passes"][0]) == 0
+        assert int(sown["expert_load"][0].sum()) == 0
+        assert float(jnp.abs(y).max()) == 0.0
+        for path, g in jax.tree_util.tree_flatten_with_path(grads)[0]:
+            assert float(jnp.abs(g).max()) == 0.0, jax.tree_util.keystr(path)
+
+    def test_a_layer_that_holds_every_expert_lowers_the_plain_program(self):
+        """``held`` naming all the experts is no held layer: the same
+        StableHLO as ``held=None`` (the plain sparse dispatch: N k-row
+        gathers, no loop), forward and backward; only a layer that holds a
+        part of them walks row buffers in a loop."""
+        params, x = _held_params()
+
+        def text(held, p):
+            def f(p, x):
+                return jnp.sum(jnp.sin(_held_layer(held).apply(p, x)))
+            return jax.jit(jax.value_and_grad(f, (0, 1))).lower(
+                p, x).as_text()
+
+        plain = text(None, params)
+        assert text((0, 8), params) == plain
+        assert "stablehlo.while" not in plain
+        assert "stablehlo.while" in text((2, 4), _share_of(params, 2, 4))
+
     @pytest.mark.parametrize("chips", [1, 2, 4, 8])
     def test_the_shares_add_up_to_the_layer(self, chips):
         # the router normalises over the k chosen of ALL experts, held or
@@ -450,19 +732,29 @@ class TestHeldExperts:
 
     @pytest.mark.parametrize("dispatch", DISPATCHES)
     def test_every_token_routed_to_held_experts_drops_nothing(self,
+                                                              monkeypatch,
                                                               dispatch):
         # a bias that sends every token to experts 2 and 3: the layer that
         # holds exactly those computes the whole layer, all N*k slots
+        from relayrl_tpu.models import moe
+
         params, x = _held_params()
         bias = np.full(8, -50.0, np.float32)
         bias[2:4] = 50.0
         params["params"]["moe_expert_bias"] = jnp.asarray(bias)
         whole = _held_layer(None).apply(params, x)
+        # buffers sized for a quarter of the slots and a margin: all of
+        # them arrive, and the layer walks its buffer as often as it takes
+        monkeypatch.setattr(moe, "_ROW_TILE", 1)
+        rows = moe.row_buffer(2 * _N, 2, 8)
+        assert rows == 24
         y, state = _held_layer((2, 2), dispatch).apply(
             _share_of(params, 2, 2), x, mutable=["intermediates"])
         np.testing.assert_allclose(y, whole, atol=2e-5, rtol=1e-5)
         load = np.asarray(state["intermediates"]["expert_load"][0])
         assert load.tolist() == [_N, _N]
+        assert int(state["intermediates"]["row_passes"][0]) == (
+            -(-2 * _N // rows) if dispatch == "sparse" else 0)
         # and the layer that holds none of the chosen adds exactly nothing
         none = _held_layer((4, 4), dispatch).apply(
             _share_of(params, 4, 4), x)
@@ -492,6 +784,58 @@ class TestHeldExperts:
         np.testing.assert_allclose(
             float(stats["moe_load_max"]),
             max(float(u.max()) for u in util.values()), rtol=1e-6)
+
+    def test_update_stats_count_the_row_passes(self, monkeypatch):
+        from relayrl_tpu.data.batching import TrajectoryBatch
+        from relayrl_tpu.models import moe
+
+        def passes(**arch):
+            policy, params = _policy_params(moe_experts=8, moe_top_k=2,
+                                            n_layers=3, **arch)
+            obs = jnp.asarray(np.random.default_rng(2).standard_normal(
+                (2, 8, 6)), jnp.float32)
+            *_, stats = policy.evaluate_stats(params, obs,
+                                              jnp.zeros((2, 8), jnp.int32))
+            update, state_of = _impala_update_of(policy)
+            batch = {name: jnp.asarray(a) for name, a in
+                     TrajectoryBatch.zeros(2, 8, 6, 3, True).items()}
+            _, metrics = update(state_of(params), {
+                **batch, "obs": obs, "valid": jnp.ones((2, 8)),
+                "act_mask": jnp.ones((2, 8, 3))})
+            assert np.isfinite(float(metrics["LossTotal"]))
+            assert float(metrics["moe_row_passes"]) == float(
+                stats["moe_row_passes"])
+            return float(stats["moe_row_passes"])
+
+        # one pass a MoE layer: every expert held, or a share of them with
+        # buffers that take what the router sends; 3 layers, then 2
+        assert passes() == 3.0
+        assert passes(moe_held=[2, 3], moe_dense_layers=1) == 2.0
+        # buffers of 2 rows for 32 slots of which some 12 are live
+        monkeypatch.setattr(moe, "_ROW_TILE", 1)
+        monkeypatch.setattr(moe, "_ROW_MARGIN", 0.1)
+        assert moe.row_buffer(32, 3, 8) == 2
+        assert 4.0 < passes(moe_held=[2, 3], moe_dense_layers=1) <= 32.0
+
+    def test_held_layers_of_a_trunk_share_one_trace_of_their_experts(self):
+        """Three held layers of one shape: the lowered update holds ONE
+        function for a pass's experts and ONE for their transpose, called
+        from each layer's two pass loops (set-up time: the kernels are
+        traced and lowered once, not once a layer and direction)."""
+        from relayrl_tpu.data.batching import TrajectoryBatch
+
+        policy = build_policy({**ARCH, "moe_experts": 8, "moe_top_k": 2,
+                               "moe_held": [2, 3], "n_layers": 3})
+        update, state_of = _impala_update_of(policy)
+        state = jax.eval_shape(
+            lambda: state_of(policy.init_params(jax.random.PRNGKey(0))))
+        text = jax.jit(update, donate_argnums=0).lower(
+            state, TrajectoryBatch.zeros(2, 8, 6, 3, True)).as_text()
+        funcs = [ln.split("@")[1].split("(")[0] for ln in text.splitlines()
+                 if "func.func private @" in ln and "experts" in ln]
+        assert sorted(funcs) == ["_experts", "_shared_experts_vjp"], funcs
+        assert text.count("call @_experts(") == 3
+        assert text.count("call @_shared_experts_vjp(") == 3
 
     def test_a_range_outside_the_experts_is_refused(self):
         with pytest.raises(ValueError, match="moe_held"):
