@@ -29,6 +29,7 @@ from relayrl_tpu.algorithms.onpolicy import OnPolicyAlgorithm
 from relayrl_tpu.models import build_policy
 from relayrl_tpu.models.base import apply_arch_overrides
 from relayrl_tpu.ops.gae import masked_mean_std
+from relayrl_tpu.ops.scopes import LOSS, OPTIMIZER
 from relayrl_tpu.ops.vtrace import vtrace
 
 
@@ -75,26 +76,34 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
         rew, valid = batch["rew"], batch["valid"]
         behavior_logp = batch["logp"]
         last_val = batch["last_val"]
-        n_valid = jnp.maximum(jnp.sum(valid), 1.0)
+        # the update's parts carry their names onto the device
+        # (ops/scopes.py): the model's are opened where its work is written,
+        # V-trace's in ops/vtrace.py
+        with jax.named_scope(LOSS):
+            n_valid = jnp.maximum(jnp.sum(valid), 1.0)
 
         def loss_fn(params):
             logp, ent, v, stats = evaluate(params, obs, act, act_mask)
             vt = vtrace(behavior_logp, jax.lax.stop_gradient(logp), rew,
                         jax.lax.stop_gradient(v), valid, gamma,
                         last_val=last_val, rho_bar=rho_bar, c_bar=c_bar)
-            pg_loss = -jnp.sum(logp * vt.pg_adv * valid) / n_valid
-            vf_loss = jnp.sum(jnp.square(v - vt.vs) * valid) / n_valid
-            ent_mean = jnp.sum(ent * valid) / n_valid
-            total = pg_loss + vf_coef * vf_loss - ent_coef * ent_mean
+            with jax.named_scope(LOSS):
+                pg_loss = -jnp.sum(logp * vt.pg_adv * valid) / n_valid
+                vf_loss = jnp.sum(jnp.square(v - vt.vs) * valid) / n_valid
+                ent_mean = jnp.sum(ent * valid) / n_valid
+                total = pg_loss + vf_coef * vf_loss - ent_coef * ent_mean
             return total, (pg_loss, vf_loss, ent_mean, vt.rho, logp, stats)
 
         (total, (pg_loss, vf_loss, ent_mean, rho, logp_new, stats)), grads = (
             jax.value_and_grad(loss_fn, has_aux=True)(state.params))
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(OPTIMIZER):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
 
-        rho_mean, _ = masked_mean_std(rho, valid)
-        kl = jnp.sum((behavior_logp - logp_new) * valid) / n_valid
+        with jax.named_scope(LOSS):
+            rho_mean, _ = masked_mean_std(rho, valid)
+            kl = jnp.sum((behavior_logp - logp_new) * valid) / n_valid
         metrics = {
             "LossPi": pg_loss,
             "LossV": vf_loss,

@@ -30,6 +30,7 @@ from relayrl_tpu.models.mlp import (
     _categorical_logp,
     _compute_dtype,
 )
+from relayrl_tpu.ops.scopes import CONV, HEADS, OBS_PREP
 
 # (features, kernel, stride) — the Nature-DQN trunk.
 NATURE_CONV = ((32, 8, 4), (64, 4, 2), (64, 3, 1))
@@ -100,21 +101,26 @@ class ConvTrunk(nn.Module):
             raise ValueError(
                 f"obs trailing shape {x.shape} matches neither ({flat_dim},) "
                 f"nor {shape}")
-        x = x.reshape((-1,) + shape) if batch_shape else x.reshape((1,) + shape)
-        x = x.astype(self.compute_dtype)
-        if self.scale_obs:
-            x = x / jnp.asarray(255.0, self.compute_dtype)
-        for i, (feat, kern, stride) in enumerate(self.conv_spec):
-            x = nn.Conv(feat, (kern, kern), strides=(stride, stride),
-                        padding="VALID", dtype=self.compute_dtype,
-                        name=f"conv_{i}")(x)
-            x = nn.relu(x)
-        x = x.reshape(x.shape[0], -1)
-        x = nn.relu(nn.Dense(self.dense, dtype=self.compute_dtype,
-                             name="trunk_dense")(x))
-        if not batch_shape:
-            return x[0]
-        return x.reshape(*batch_shape, -1)
+        # the trunk's two parts on the device (ops/scopes.py): the frames'
+        # way in, and the convolutions with their dense layer
+        with jax.named_scope(OBS_PREP):
+            x = (x.reshape((-1,) + shape) if batch_shape
+                 else x.reshape((1,) + shape))
+            x = x.astype(self.compute_dtype)
+            if self.scale_obs:
+                x = x / jnp.asarray(255.0, self.compute_dtype)
+        with jax.named_scope(CONV):
+            for i, (feat, kern, stride) in enumerate(self.conv_spec):
+                x = nn.Conv(feat, (kern, kern), strides=(stride, stride),
+                            padding="VALID", dtype=self.compute_dtype,
+                            name=f"conv_{i}")(x)
+                x = nn.relu(x)
+            x = x.reshape(x.shape[0], -1)
+            x = nn.relu(nn.Dense(self.dense, dtype=self.compute_dtype,
+                                 name="trunk_dense")(x))
+            if not batch_shape:
+                return x[0]
+            return x.reshape(*batch_shape, -1)
 
 
 class ConvActorCritic(nn.Module):
@@ -131,16 +137,18 @@ class ConvActorCritic(nn.Module):
         feats = ConvTrunk(self.obs_shape, self.conv_spec, self.dense,
                           self.scale_obs, self.compute_dtype,
                           name="trunk")(obs)
-        logits = nn.Dense(self.act_dim, dtype=self.compute_dtype,
-                          name="pi_head")(feats)
-        logits = logits.astype(jnp.float32)
-        if mask is not None:
-            logits = jnp.where(mask > 0, logits, _MASK_FILL)
-        if self.has_critic:
-            v = nn.Dense(1, dtype=self.compute_dtype, name="vf_head")(feats)
-            v = jnp.squeeze(v.astype(jnp.float32), axis=-1)
-        else:
-            v = jnp.zeros(logits.shape[:-1], dtype=jnp.float32)
+        with jax.named_scope(HEADS):
+            logits = nn.Dense(self.act_dim, dtype=self.compute_dtype,
+                              name="pi_head")(feats)
+            logits = logits.astype(jnp.float32)
+            if mask is not None:
+                logits = jnp.where(mask > 0, logits, _MASK_FILL)
+            if self.has_critic:
+                v = nn.Dense(1, dtype=self.compute_dtype,
+                             name="vf_head")(feats)
+                v = jnp.squeeze(v.astype(jnp.float32), axis=-1)
+            else:
+                v = jnp.zeros(logits.shape[:-1], dtype=jnp.float32)
         return logits, v
 
 
@@ -182,7 +190,9 @@ def build_cnn_discrete(arch: Mapping[str, Any]) -> Policy:
 
     def evaluate(params, obs, act, mask=None):
         logits, v = module.apply(params, obs, mask)
-        return _categorical_logp(logits, act), _categorical_entropy(logits), v
+        with jax.named_scope(HEADS):
+            return (_categorical_logp(logits, act),
+                    _categorical_entropy(logits), v)
 
     def mode(params, obs, mask=None):
         logits, _ = module.apply(params, obs, mask)

@@ -147,6 +147,12 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from relayrl_tpu.models.mlp import GATED_FFN
+from relayrl_tpu.ops.scopes import (
+    HELD_EXPERTS_NAME,
+    MOE_ELEMENTWISE,
+    MOE_ROUTE,
+    MOE_ROWS,
+)
 
 # Spread of the seeded ``moe_expert_bias`` (sigmoid router): against scores
 # whose 4th and 5th lie ~0.02 apart it moves the choice of about every
@@ -346,7 +352,7 @@ def _shared_experts_vjp(ffn, xs, stacks, group_sizes, d_out):
     # one, so that the kernels keep the names a device trace finds them by
     # (ops/grouped_matmul.py)
     def experts(xs, stacks):
-        with jax.named_scope("held_experts"):
+        with jax.named_scope(HELD_EXPERTS_NAME):
             return _experts(ffn, xs, stacks, group_sizes)
 
     out, transpose = jax.vjp(experts, xs, stacks)
@@ -375,12 +381,17 @@ def _held_fwd(ffn, rows, choice_major, tokens, top_w, stacks, load,
 
     def one_pass(p, carry):
         y, ran = carry
-        slots, _, sizes, row_of_slot, has_row = _pass_of(
-            p, rows, load, row_to_slot, slot_to_row)
-        out = _shared_experts(ffn, _rows_of(tokens, slots, k, choice_major),
-                              stacks, sizes)
-        return (y + _rows_to_tokens(out, row_of_slot, has_row, n,
-                                    choice_major, top_w_flat), ran + 1)
+        with jax.named_scope(MOE_ROUTE):
+            slots, _, sizes, row_of_slot, has_row = _pass_of(
+                p, rows, load, row_to_slot, slot_to_row)
+        with jax.named_scope(MOE_ROWS):
+            xs = _rows_of(tokens, slots, k, choice_major)
+        with jax.named_scope(MOE_ELEMENTWISE):  # round the kernels' names
+            out = _shared_experts(ffn, xs, stacks, sizes)
+        with jax.named_scope(MOE_ROWS):
+            y = y + _rows_to_tokens(out, row_of_slot, has_row, n,
+                                    choice_major, top_w_flat)
+        return y, ran + 1
 
     out = jax.lax.fori_loop(
         0, _passes(load, rows), one_pass,
@@ -400,32 +411,42 @@ def _held_bwd(ffn, rows, choice_major, res, g):
 
     def one_pass(p, carry):
         d_tokens, d_weights, d_stacks = carry
-        slots, live, sizes, row_of_slot, has_row = _pass_of(
-            p, rows, load, row_to_slot, slot_to_row)
-        g_rows = _rows_of(g_y, slots, k, choice_major)
-        weights = top_w_flat.at[slots].get(mode="promise_in_bounds")
-        out, d_xs, d_pass = _shared_experts_vjp(
-            ffn, _rows_of(tokens, slots, k, choice_major), stacks, sizes,
-            (g_rows * weights[:, None]).astype(tokens.dtype))
-        # the tail's rows were never written: selected away, not masked
-        d_w = jnp.where(live, (out.astype(jnp.float32) * g_rows).sum(-1), 0)
-        d_tokens = d_tokens + _rows_to_tokens(d_xs, row_of_slot, has_row, n,
-                                              choice_major)
-        return (d_tokens,
-                jax.lax.dynamic_update_slice(d_weights, d_w, (p * rows,)),
-                jax.tree_util.tree_map(jnp.add, d_stacks, d_pass))
+        with jax.named_scope(MOE_ROUTE):
+            slots, live, sizes, row_of_slot, has_row = _pass_of(
+                p, rows, load, row_to_slot, slot_to_row)
+        with jax.named_scope(MOE_ROWS):
+            g_rows = _rows_of(g_y, slots, k, choice_major)
+            weights = top_w_flat.at[slots].get(mode="promise_in_bounds")
+            xs = _rows_of(tokens, slots, k, choice_major)
+        with jax.named_scope(MOE_ELEMENTWISE):  # round the kernels' names
+            d_out = (g_rows * weights[:, None]).astype(tokens.dtype)
+            out, d_xs, d_pass = _shared_experts_vjp(ffn, xs, stacks, sizes,
+                                                    d_out)
+            # the tail's rows were never written: selected away, not masked
+            d_w = jnp.where(live,
+                            (out.astype(jnp.float32) * g_rows).sum(-1), 0)
+        with jax.named_scope(MOE_ROWS):
+            d_tokens = d_tokens + _rows_to_tokens(d_xs, row_of_slot, has_row,
+                                                  n, choice_major)
+        with jax.named_scope(MOE_ELEMENTWISE):
+            return (d_tokens,
+                    jax.lax.dynamic_update_slice(d_weights, d_w,
+                                                 (p * rows,)),
+                    jax.tree_util.tree_map(jnp.add, d_stacks, d_pass))
 
     d_tokens, d_weights, d_stacks = jax.lax.fori_loop(
         0, _passes(load, rows), one_pass,
         (jnp.zeros(tokens.shape, jnp.float32),
          jnp.zeros(row_to_slot.shape, jnp.float32),
          jax.tree_util.tree_map(jnp.zeros_like, stacks)))
-    # each slot's weight gradient is its row's (0 for a slot without one)
-    d_top_w = d_weights.at[slot_to_row].get(mode="promise_in_bounds")
-    d_top_w = (d_top_w.reshape(k, n).T if choice_major
-               else d_top_w.reshape(n, k))
-    return (d_tokens.astype(tokens.dtype), d_top_w.astype(top_w.dtype),
-            d_stacks, None, None, None)
+    with jax.named_scope(MOE_ROWS):
+        # each slot's weight gradient is its row's (0 for a slot without one)
+        d_top_w = d_weights.at[slot_to_row].get(mode="promise_in_bounds")
+        d_top_w = (d_top_w.reshape(k, n).T if choice_major
+                   else d_top_w.reshape(n, k))
+    with jax.named_scope(MOE_ELEMENTWISE):
+        return (d_tokens.astype(tokens.dtype), d_top_w.astype(top_w.dtype),
+                d_stacks, None, None, None)
 
 
 _held_experts.defvjp(_held_fwd, _held_bwd)
@@ -477,34 +498,42 @@ class MoEMLP(nn.Module):
             raise ValueError(f"moe_held {self.held} outside 0..{n_exp}")
         partial = n_held < n_exp
 
-        routed = tokens if route_x is None else route_x.reshape(n, d)
-        logits = nn.Dense(n_exp, dtype=jnp.float32, use_bias=self.use_bias,
-                          name="moe_gate")(routed.astype(jnp.float32))
-        bias = None
-        if self.expert_bias:
-            # enters the choice only: zero gradient, never moved; seeded
-            # non-zero so that the path is exercised
-            bias = self.param("moe_expert_bias",
-                              nn.initializers.normal(_EXPERT_BIAS_STD),
-                              (n_exp,), jnp.float32)
-        top_w, top_idx = route(logits, k, self.norm_topk_prob, self.router,
-                               bias)                               # [N, k]
+        # the layer's parts carry their names onto the device
+        # (ops/scopes.py): the router and the sort, the row gathers, and the
+        # element-wise passes round the grouped matmuls, which keep their own
+        with jax.named_scope(MOE_ROUTE):
+            routed = tokens if route_x is None else route_x.reshape(n, d)
+            logits = nn.Dense(n_exp, dtype=jnp.float32,
+                              use_bias=self.use_bias,
+                              name="moe_gate")(routed.astype(jnp.float32))
+            bias = None
+            if self.expert_bias:
+                # enters the choice only: zero gradient, never moved; seeded
+                # non-zero so that the path is exercised
+                bias = self.param("moe_expert_bias",
+                                  nn.initializers.normal(_EXPERT_BIAS_STD),
+                                  (n_exp,), jnp.float32)
+            top_w, top_idx = route(logits, k, self.norm_topk_prob,
+                                   self.router, bias)              # [N, k]
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         gated = self.ffn in GATED_FFN
-        if gated:
-            w_gate = self.param("moe_w_gate", init, (n_held, d, self.d_ff),
+        with jax.named_scope(MOE_ELEMENTWISE):  # the stacks' casts
+            if gated:
+                w_gate = self.param("moe_w_gate", init,
+                                    (n_held, d, self.d_ff),
+                                    jnp.float32).astype(cd)
+            w_up = self.param("moe_w_up", init, (n_held, d, self.d_ff),
+                              jnp.float32).astype(cd)
+            w_down = self.param("moe_w_down", init, (n_held, self.d_ff, d),
                                 jnp.float32).astype(cd)
-        w_up = self.param("moe_w_up", init, (n_held, d, self.d_ff),
-                          jnp.float32).astype(cd)
-        w_down = self.param("moe_w_down", init, (n_held, self.d_ff, d),
-                            jnp.float32).astype(cd)
 
         # token-slots per (held) expert: the grouped matmuls' group sizes
         # and the load monitor (a compare-and-reduce; a scatter-add
         # serialises)
-        load = (top_idx[..., None] == first + jnp.arange(n_held)).sum(
-            axis=(0, 1), dtype=jnp.int32)
+        with jax.named_scope(MOE_ROUTE):
+            load = (top_idx[..., None] == first + jnp.arange(n_held)).sum(
+                axis=(0, 1), dtype=jnp.int32)
 
         dispatch = self.dispatch or ("dense" if _mesh_ep() > 1 else "sparse")
         if dispatch == "dense":
@@ -542,36 +571,57 @@ class MoEMLP(nn.Module):
             # k = 8 costs olmoe-policy.update 1.4% (1.7 ms an update, one
             # broadcast-select fusion half as long again: PERF.md section 6).
             choice_major = k % 8 != 0
-            expert_of_slot = (top_idx.T if choice_major else top_idx
-                              ).reshape(n * k)
             stacks = (w_up, w_gate if gated else None, w_down)
-            if partial:
-                # the slots of absent experts sort behind the held ones: a
-                # tail no pass reaches (module docstring, "Held experts")
-                local = expert_of_slot - first
-                expert_of_slot = jnp.where((local >= 0) & (local < n_held),
-                                           local, n_held)
-            row_to_slot = jnp.argsort(expert_of_slot, stable=True)
-            slot_to_row = jnp.zeros_like(row_to_slot).at[row_to_slot].set(
-                jnp.arange(n * k, dtype=row_to_slot.dtype),
-                unique_indices=True)
+            with jax.named_scope(MOE_ROUTE):
+                expert_of_slot = (top_idx.T if choice_major else top_idx
+                                  ).reshape(n * k)
+                if partial:
+                    # the slots of absent experts sort behind the held
+                    # ones: a tail no pass reaches (module docstring, "Held
+                    # experts")
+                    local = expert_of_slot - first
+                    expert_of_slot = jnp.where(
+                        (local >= 0) & (local < n_held), local, n_held)
+                row_to_slot = jnp.argsort(expert_of_slot, stable=True)
+                slot_to_row = jnp.zeros_like(row_to_slot).at[
+                    row_to_slot].set(
+                        jnp.arange(n * k, dtype=row_to_slot.dtype),
+                        unique_indices=True)
             if partial:
                 rows = row_buffer(n * k, n_held, n_exp)
+                # (the cast in each branch: where it stands in the program)
+                with jax.named_scope(MOE_ELEMENTWISE):
+                    xs = tokens.astype(cd)
+                with jax.named_scope(MOE_ROUTE):
+                    padded = jnp.pad(row_to_slot, (0, -(n * k) % rows))
+                # its parts are named inside, in both of its loops
                 y, row_passes = _held_experts(
-                    self.ffn, rows, choice_major, tokens.astype(cd), top_w,
-                    stacks, load,
-                    jnp.pad(row_to_slot, (0, -(n * k) % rows)), slot_to_row)
+                    self.ffn, rows, choice_major, xs, top_w, stacks, load,
+                    padded, slot_to_row)
             else:
                 rows, row_passes = n * k, jnp.int32(1)
-                token_of_row = (row_to_slot % n if choice_major
-                                else row_to_slot // k)
-                xs = _dispatch_rows(tokens.astype(cd), token_of_row,
-                                    slot_to_row, choice_major)    # [N*k, d]
-                out = _experts(self.ffn, xs, stacks, load)        # [N*k, d]
-                out = _unsort_rows(out, slot_to_row, row_to_slot)
-                out = _slots_3d(out, n, choice_major).astype(jnp.float32)
-                y = (jnp.einsum("kn,knd->nd", top_w.T, out) if choice_major
-                     else jnp.einsum("nk,nkd->nd", top_w, out))
+                with jax.named_scope(MOE_ROUTE):
+                    token_of_row = (row_to_slot % n if choice_major
+                                    else row_to_slot // k)
+                with jax.named_scope(MOE_ELEMENTWISE):
+                    xs = tokens.astype(cd)
+                # round the CALLS: a custom_vjp's backward carries the
+                # scopes of its call, not those opened in its forward
+                with jax.named_scope(MOE_ROWS):
+                    xs = _dispatch_rows(xs, token_of_row, slot_to_row,
+                                        choice_major)             # [N*k, d]
+                # what is not a kernel between the dispatch and its way
+                # back (the kernels keep their own innermost names)
+                with jax.named_scope(MOE_ELEMENTWISE):
+                    out = _experts(self.ffn, xs, stacks, load)    # [N*k, d]
+                with jax.named_scope(MOE_ROWS):
+                    out = _unsort_rows(out, slot_to_row, row_to_slot)
+                with jax.named_scope(MOE_ELEMENTWISE):
+                    out = _slots_3d(out, n, choice_major).astype(
+                        jnp.float32)
+                    y = (jnp.einsum("kn,knd->nd", top_w.T, out)
+                         if choice_major
+                         else jnp.einsum("nk,nkd->nd", top_w, out))
         else:
             raise ValueError(f"unknown moe_dispatch {dispatch!r}")
 

@@ -83,6 +83,14 @@ from relayrl_tpu.models.mlp import (
     _compute_dtype,
 )
 from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
+from relayrl_tpu.ops.scopes import (  # noqa: F401  (SHORT_CONV_NAME's home)
+    EMBED,
+    FFN,
+    HEADS,
+    MOE_ELEMENTWISE,
+    OP_PROJ,
+    SHORT_CONV_NAME,
+)
 
 
 def _resolve_attention(arch: Mapping[str, Any]
@@ -255,9 +263,6 @@ def apply_rope(x, start, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-SHORT_CONV_NAME = "relayrl_short_conv"
-
-
 def _short_conv(bcu, w, state=None):
     """The gated short convolution between its two projections:
     ``(B, C, u) = split3(bcu)``, ``z = B * u``, ``c_t = sum_j w[j] *
@@ -293,8 +298,11 @@ def _block_ffn(block: "TransformerBlock", x, layer_in):
     that ``x``'s rows came from, which the MoE layer's router reads under
     ``moe_router_input: "layer"``. (A plain function, like
     :func:`_embed_obs`: a module method would be wrapped by flax once per
-    call.)"""
-    h = _norm(block.norm, block.norm_eps, "ln_mlp")(x)
+    call.) The norm and the residual are the FFN's element-wise passes,
+    the dense one's or the expert layer's (``ops/scopes.py``)."""
+    part = MOE_ELEMENTWISE if block.moe_experts > 0 else FFN
+    with jax.named_scope(part):
+        h = _norm(block.norm, block.norm_eps, "ln_mlp")(x)
     width = block.d_ff or block.mlp_ratio * block.d_model
     if block.ffn != "gelu" and block.ffn not in GATED_FFN:
         raise ValueError(f"unknown ffn {block.ffn!r} (gelu | swiglu | reglu)")
@@ -311,16 +319,18 @@ def _block_ffn(block: "TransformerBlock", x, layer_in):
                    **block.moe_kw, name="moe")(
                        h, layer_in if block.moe_router_input == "layer"
                        else None)
+        with jax.named_scope(part):
+            return x + h.astype(x.dtype)
+    with jax.named_scope(part):
+        h = h.astype(block.compute_dtype)
+        up = _block_dense(block, width, "mlp_up")(h)
+        if block.ffn in GATED_FFN:
+            h = GATED_FFN[block.ffn](
+                _block_dense(block, width, "mlp_gate")(h)) * up
+        else:
+            h = nn.gelu(up)
+        h = _block_dense(block, block.d_model, "mlp_down")(h)
         return x + h.astype(x.dtype)
-    h = h.astype(block.compute_dtype)
-    up = _block_dense(block, width, "mlp_up")(h)
-    if block.ffn in GATED_FFN:
-        h = GATED_FFN[block.ffn](
-            _block_dense(block, width, "mlp_gate")(h)) * up
-    else:
-        h = nn.gelu(up)
-    h = _block_dense(block, block.d_model, "mlp_down")(h)
-    return x + h.astype(x.dtype)
 
 
 class TransformerBlock(nn.Module):
@@ -413,51 +423,58 @@ class TransformerBlock(nn.Module):
                              f"(attention | conv)")
         head_dim = self.head_dim or self.d_model // self.n_heads
         width = self.n_heads * head_dim     # of q and of attn_out's input
-        layer_in = x
-        h = _norm(self.norm, self.norm_eps, "ln_attn")(x)
-        h = h.astype(self.compute_dtype)
-        if self.n_kv_heads is None and self.head_dim is None:
-            n_kv = self.n_heads
-            qkv = _block_dense(self, 3 * self.d_model, "qkv")(h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-        else:
-            n_kv = self.n_kv_heads or self.n_heads
-            q = _block_dense(self, width, "q_proj")(h)
-            k = _block_dense(self, n_kv * head_dim, "k_proj")(h)
-            v = _block_dense(self, n_kv * head_dim, "v_proj")(h)
-        if self.qk_norm is True:
-            # over the whole d_model-wide projection, before the heads
-            q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
-                self.compute_dtype)
-            k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
-                self.compute_dtype)
-        q = q.reshape(B, T, self.n_heads, head_dim)
-        k, v = (a.reshape(B, T, n_kv, head_dim) for a in (k, v))
-        if self.qk_norm == "head":
-            # over each head's head_dim, one learned scale for all heads
-            q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
-                self.compute_dtype)
-            k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
-                self.compute_dtype)
-        elif self.qk_norm not in (True, False):
-            raise ValueError(f"unknown qk_norm {self.qk_norm!r} "
-                             f"(false | true | \"head\")")
-        rope = self.rope_theta is not None
-        if rope:
-            k = apply_rope(k, 0 if t is None else t, self.rope_theta)
-        if readout_idx is not None:
-            q_row = jax.lax.dynamic_slice_in_dim(q, readout_idx, 1, axis=1)
+        # everything of the operator but its kernel: one part on the device
+        with jax.named_scope(OP_PROJ):
+            layer_in = x
+            h = _norm(self.norm, self.norm_eps, "ln_attn")(x)
+            h = h.astype(self.compute_dtype)
+            if self.n_kv_heads is None and self.head_dim is None:
+                n_kv = self.n_heads
+                qkv = _block_dense(self, 3 * self.d_model, "qkv")(h)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+            else:
+                n_kv = self.n_kv_heads or self.n_heads
+                q = _block_dense(self, width, "q_proj")(h)
+                k = _block_dense(self, n_kv * head_dim, "k_proj")(h)
+                v = _block_dense(self, n_kv * head_dim, "v_proj")(h)
+            if self.qk_norm is True:
+                # over the whole d_model-wide projection, before the heads
+                q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
+                    self.compute_dtype)
+                k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
+                    self.compute_dtype)
+            q = q.reshape(B, T, self.n_heads, head_dim)
+            k, v = (a.reshape(B, T, n_kv, head_dim) for a in (k, v))
+            if self.qk_norm == "head":
+                # over each head's head_dim, one learned scale for all heads
+                q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
+                    self.compute_dtype)
+                k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
+                    self.compute_dtype)
+            elif self.qk_norm not in (True, False):
+                raise ValueError(f"unknown qk_norm {self.qk_norm!r} "
+                                 f"(false | true | \"head\")")
+            rope = self.rope_theta is not None
             if rope:
-                q_row = apply_rope(q_row, readout_idx, self.rope_theta)
+                k = apply_rope(k, 0 if t is None else t, self.rope_theta)
+        if readout_idx is not None:
+            with jax.named_scope(OP_PROJ):
+                q_row = jax.lax.dynamic_slice_in_dim(q, readout_idx, 1,
+                                                     axis=1)
+                if rope:
+                    q_row = apply_rope(q_row, readout_idx, self.rope_theta)
             attn = dense_attention(q_row, k, v, causal=True,
                                    q_offset=readout_idx, window=self.window)
-            attn = attn.reshape(B, 1, width)
-            row_in = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1, axis=1)
-            x = row_in + _block_dense(self, self.d_model, "attn_out")(
-                attn).astype(x.dtype)
+            with jax.named_scope(OP_PROJ):
+                attn = attn.reshape(B, 1, width)
+                row_in = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1,
+                                                      axis=1)
+                x = row_in + _block_dense(self, self.d_model, "attn_out")(
+                    attn).astype(x.dtype)
             return _block_ffn(self, x, row_in)
         if rope:
-            q = apply_rope(q, 0 if t is None else t, self.rope_theta)
+            with jax.named_scope(OP_PROJ):
+                q = apply_rope(q, 0 if t is None else t, self.rope_theta)
         if cache is None:
             attn = self.attn_fn(q, k, v, self.window)
             new_cache = None
@@ -477,9 +494,10 @@ class TransformerBlock(nn.Module):
             attn = dense_attention(q, k_cache, v_cache, causal=True,
                                    q_offset=t)
             new_cache = (k_cache, v_cache)
-        attn = attn.reshape(B, T, width)
-        x = x + _block_dense(self, self.d_model, "attn_out")(attn).astype(
-            x.dtype)
+        with jax.named_scope(OP_PROJ):
+            attn = attn.reshape(B, T, width)
+            x = x + _block_dense(self, self.d_model, "attn_out")(
+                attn).astype(x.dtype)
         out = _block_ffn(self, x, layer_in)
         return out if cache is None else (out, new_cache)
 
@@ -529,12 +547,14 @@ def _conv_layer(block: TransformerBlock, x, cache, readout_idx, n_valid):
                     (block.conv_taps, d), jnp.float32)
 
     def in_proj(rows):
-        h = _norm(block.norm, block.norm_eps, "ln_attn")(rows)
-        return _block_dense(block, 3 * d, "conv_in")(
-            h.astype(block.compute_dtype))
+        with jax.named_scope(OP_PROJ):
+            h = _norm(block.norm, block.norm_eps, "ln_attn")(rows)
+            return _block_dense(block, 3 * d, "conv_in")(
+                h.astype(block.compute_dtype))
 
     def out_proj(x, y):
-        return x + _block_dense(block, d, "conv_out")(y).astype(x.dtype)
+        with jax.named_scope(OP_PROJ):
+            return x + _block_dense(block, d, "conv_out")(y).astype(x.dtype)
 
     if readout_idx is not None:
         # the one row needs its own and the conv_taps - 1 rows before it;
@@ -567,12 +587,13 @@ def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
     _PPEmbed. With rotary positions (``learned_positions=False``) the
     blocks place the tokens and there is no ``pos_embed`` leaf."""
     _, T, _ = obs.shape
-    x = nn.Dense(d_model, dtype=jnp.float32, name="obs_embed")(obs)
-    if not learned_positions:
-        return x
-    pos = parent.param("pos_embed", nn.initializers.normal(0.02),
-                       (max_seq_len, d_model), jnp.float32)
-    return x + jax.lax.dynamic_slice_in_dim(pos, start, T, axis=0)[None]
+    with jax.named_scope(EMBED):
+        x = nn.Dense(d_model, dtype=jnp.float32, name="obs_embed")(obs)
+        if not learned_positions:
+            return x
+        pos = parent.param("pos_embed", nn.initializers.normal(0.02),
+                           (max_seq_len, d_model), jnp.float32)
+        return x + jax.lax.dynamic_slice_in_dim(pos, start, T, axis=0)[None]
 
 
 def _readout_heads(x, mask, act_dim: int, d_model: int, has_critic: bool,
@@ -580,21 +601,22 @@ def _readout_heads(x, mask, act_dim: int, d_model: int, has_critic: bool,
     """Final norm (the arch's kind and epsilon, as the blocks') + pi/vf
     heads in the caller's scope (shared with _PPReadout; the vf optimizer
     partition keys off these exact `vf*` names)."""
-    x = _norm(norm, norm_eps, "ln_final")(x)
-    logits = nn.Dense(act_dim, dtype=jnp.float32, name="pi_head")(x)
-    if mask is not None:
-        logits = jnp.where(mask > 0, logits, _MASK_FILL)
-    if has_critic:
-        # Shared-trunk actor-critic: unlike the MLP family's separate
-        # vf_trunk, the critic reads the policy-shaped features, so the
-        # vf optimizer partition (labels by `vf*` prefix) trains only
-        # this head — a 2-layer MLP rather than a single linear probe to
-        # give the vf steps real capacity.
-        h = nn.Dense(d_model, dtype=jnp.float32, name="vf_head_up")(x)
-        v = nn.Dense(1, dtype=jnp.float32, name="vf_head")(nn.tanh(h))
-        v = jnp.squeeze(v, axis=-1)
-    else:
-        v = jnp.zeros(logits.shape[:-1], jnp.float32)
+    with jax.named_scope(HEADS):
+        x = _norm(norm, norm_eps, "ln_final")(x)
+        logits = nn.Dense(act_dim, dtype=jnp.float32, name="pi_head")(x)
+        if mask is not None:
+            logits = jnp.where(mask > 0, logits, _MASK_FILL)
+        if has_critic:
+            # Shared-trunk actor-critic: unlike the MLP family's separate
+            # vf_trunk, the critic reads the policy-shaped features, so the
+            # vf optimizer partition (labels by `vf*` prefix) trains only
+            # this head — a 2-layer MLP rather than a single linear probe to
+            # give the vf steps real capacity.
+            h = nn.Dense(d_model, dtype=jnp.float32, name="vf_head_up")(x)
+            v = nn.Dense(1, dtype=jnp.float32, name="vf_head")(nn.tanh(h))
+            v = jnp.squeeze(v, axis=-1)
+        else:
+            v = jnp.zeros(logits.shape[:-1], jnp.float32)
     return logits, v
 
 
@@ -769,8 +791,9 @@ def _policy_from_apply(arch: Mapping[str, Any], init_params, apply_fn,
         while act_b.ndim < 2:  # scalar -> [1,1], [T] -> [1,T]
             act_b = act_b[None]
         logits, v = apply_fn(params, obs, mask)
-        logp = _categorical_logp(logits, act_b)
-        ent = _categorical_entropy(logits)
+        with jax.named_scope(HEADS):
+            logp = _categorical_logp(logits, act_b)
+            ent = _categorical_entropy(logits)
         if lead != "batch":
             logp, ent, v = logp[0], ent[0], v[0]
         if lead == "scalar":

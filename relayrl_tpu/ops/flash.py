@@ -181,19 +181,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from relayrl_tpu.ops.scopes import (  # noqa: F401  (re-exported)
+    DKV_NAME,
+    DQ_NAME,
+    FWD_NAME,
+    OP_PROJ,
+    WINDOW_SUFFIX,
+)
+
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
 
-# The three kernels' names in a profiler trace and in the lowered program:
-# each ``pallas_call`` carries its name and sits in a ``jax.named_scope`` of
-# the same name, so a reduction finds it whatever flax scope called it.
-FWD_NAME = "relayrl_flash_fwd"
-DQ_NAME = "relayrl_flash_dq"
-DKV_NAME = "relayrl_flash_dkv"
-# A windowed call's kernels carry the same names with this suffix: a reader
-# that matches ``relayrl_flash_fwd`` finds them too, one that wants the band
-# calls alone asks for the suffix.
-WINDOW_SUFFIX = "_win"
+# The three kernels' names in a profiler trace and in the lowered program
+# (``ops/scopes.py`` holds them): each ``pallas_call`` carries its name and
+# sits in a ``jax.named_scope`` of the same name, so a reduction finds it
+# whatever flax scope called it. What this module does round the kernels —
+# the head transposes, q's pre-scaling — is the operator's glue and sits
+# under ``OP_PROJ``, in the forward and in the backward rule.
 
 
 # Rows of a causal strip: a grid step on the diagonal is walked ``_SUB_TILE``
@@ -1014,7 +1018,9 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
             call = built(_build_fwd(T, D, causal, block_q, block_kv, sub,
                                     qr.dtype.name, interpret, group,
                                     per_step, window))
-            return call(_prescale_q(qr, D), kr, vr)
+            with jax.named_scope(OP_PROJ):
+                qs = _prescale_q(qr, D)
+            return call(qs, kr, vr)
 
         @jax.custom_vjp
         def flash(qr, kr, vr):
@@ -1030,7 +1036,9 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
                                     block_kv, sub, qr.dtype.name, interpret,
                                     group, per_step, window))
             # the kernels recompute log2-space scores
-            return call(_prescale_q(qr, D), kr, vr, dor, out, lse_row)
+            with jax.named_scope(OP_PROJ):
+                qs = _prescale_q(qr, D)
+            return call(qs, kr, vr, dor, out, lse_row)
 
         flash.defvjp(fwd, bwd)
         return flash
@@ -1089,8 +1097,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     hps = lane_layout(H, h_kv, D)
     flash = _make_flash(causal, block_q, block_kv, sub, bool(interpret), D,
                         H // h_kv, hps, window)
-    out = flash(*(_to_kernel(x, hps) for x in (q, k, v)))
-    return _from_kernel(out, B, H, hps)
+    with jax.named_scope(OP_PROJ):
+        operands = [_to_kernel(x, hps) for x in (q, k, v)]
+    out = flash(*operands)
+    with jax.named_scope(OP_PROJ):
+        return _from_kernel(out, B, H, hps)
 
 
 def tiling(T: int, causal: bool = True, block_q: int = 1024,

@@ -21,6 +21,8 @@ import jax
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _jit_gmm
 from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _jit_tgmm
 
+from relayrl_tpu.ops.scopes import GMM_DLHS_NAME, GMM_DRHS_NAME, GMM_FWD_NAME
+
 # The kernels' own ``jax.jit`` wrappers would name the compiled calls
 # ``gmm.N`` / ``tgmm.N``; traced inline, each call takes the name of the
 # ``named_scope`` it is made under.
@@ -58,7 +60,7 @@ def gmm(lhs, rhs, group_sizes):
 
 
 def _fwd(lhs, rhs, group_sizes):
-    with jax.named_scope("relayrl_moe_gmm_fwd"):
+    with jax.named_scope(GMM_FWD_NAME):
         out = _gmm(lhs, rhs, group_sizes, lhs.dtype,
                    _tiling(rhs.shape[1], rhs.shape[2]))
     return out, (lhs, rhs, group_sizes)
@@ -67,10 +69,10 @@ def _fwd(lhs, rhs, group_sizes):
 def _bwd(res, g):
     lhs, rhs, group_sizes = res
     g = g.astype(lhs.dtype)
-    with jax.named_scope("relayrl_moe_gmm_dlhs"):
+    with jax.named_scope(GMM_DLHS_NAME):
         d_lhs = _gmm(g, rhs, group_sizes, lhs.dtype,
                      _tiling(rhs.shape[2], rhs.shape[1]), transpose_rhs=True)
-    with jax.named_scope("relayrl_moe_gmm_drhs"):
+    with jax.named_scope(GMM_DRHS_NAME):
         d_rhs = _tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
                       _tiling(rhs.shape[1], rhs.shape[2]),
                       num_actual_groups=rhs.shape[0])

@@ -1,0 +1,60 @@
+"""The names the program gives its work on the device: the ONE list that the
+program's ``jax.named_scope`` lines, the tests, ``docs/observability.md`` and
+the benchmark's readers are held to.
+
+A ``jax.named_scope`` round plain XLA operations reaches every instruction's
+``op_name`` in the compiled module's metadata, which the profiler saves
+beside a trace's events (``benchmark/scope_trace.py`` maps the events back).
+A scope is metadata: it is always on, costs nothing at run time and leaves
+the device program as it was (``tests/test_device_scopes.py`` compares the
+lowered text with ``jax.named_scope`` patched away).
+
+**Parts** (:data:`DEVICE_SCOPES`) are siblings — none is opened inside
+another — and each is one ``with`` line where the work is written. A
+transform wraps the outermost scope entered under it
+(``transpose(jvp(relayrl_ffn))/...``), so a part's backward is found by the
+substring; a ``custom_vjp``'s backward carries the scopes round the CALL, not
+those opened inside its forward, which is why the held-experts layer and the
+flash kernels' glue open theirs in both rules.
+
+**Kernels** (:data:`KERNEL_SCOPES`) keep their own innermost names inside
+whatever part calls them: a Mosaic call is named after the innermost scope,
+and the device trace's readers match those names.
+"""
+
+from __future__ import annotations
+
+# -- parts of the jitted update (who opens each: docs/observability.md) ------
+OPTIMIZER = "relayrl_optimizer"      # global-norm clip, Adam, the apply
+VTRACE = "relayrl_vtrace"            # ratios, delta, the reverse scan, pg_adv
+LOSS = "relayrl_loss"                # the three loss sums, RhoMean, KL
+EMBED = "relayrl_embed"              # obs embedding + learned positions
+OP_PROJ = "relayrl_op_proj"          # a layer's operator less its kernel
+FFN = "relayrl_ffn"                  # a layer's dense FFN, norm and residual
+MOE_ROUTE = "relayrl_moe_route"      # router, top-k, the sort, load counts
+MOE_ROWS = "relayrl_moe_rows"        # tokens -> rows, rows -> tokens
+MOE_ELEMENTWISE = "relayrl_moe_elementwise"  # between and after the matmuls
+HEADS = "relayrl_heads"              # final norm, pi / vf heads, logp, entropy
+OBS_PREP = "relayrl_obs_prep"        # cnn: cast, scale, relayout on entry
+CONV = "relayrl_conv"                # cnn: the conv stack and its dense layer
+
+DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, FFN, MOE_ROUTE,
+                 MOE_ROWS, MOE_ELEMENTWISE, HEADS, OBS_PREP, CONV)
+
+# -- kernels and the operators that keep a name of their own -----------------
+SHORT_CONV_NAME = "relayrl_short_conv"   # models/transformer._short_conv
+FWD_NAME = "relayrl_flash_fwd"           # ops/flash.py, also the calls' name
+DQ_NAME = "relayrl_flash_dq"
+DKV_NAME = "relayrl_flash_dkv"
+# A windowed call's kernels carry the same names with this suffix: a reader
+# that matches ``relayrl_flash_fwd`` finds them too, one that wants the band
+# calls alone asks for the suffix.
+WINDOW_SUFFIX = "_win"
+GMM_FWD_NAME = "relayrl_moe_gmm_fwd"     # ops/grouped_matmul.py
+GMM_DLHS_NAME = "relayrl_moe_gmm_dlhs"
+GMM_DRHS_NAME = "relayrl_moe_gmm_drhs"
+# absorbs the vjp's name transform round a held pass's experts (models/moe.py)
+HELD_EXPERTS_NAME = "held_experts"
+
+KERNEL_SCOPES = (SHORT_CONV_NAME, FWD_NAME, DQ_NAME, DKV_NAME, GMM_FWD_NAME,
+                 GMM_DLHS_NAME, GMM_DRHS_NAME)

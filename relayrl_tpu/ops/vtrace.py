@@ -19,6 +19,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from relayrl_tpu.ops.scopes import VTRACE
+
 
 class VTraceReturns(NamedTuple):
     vs: jax.Array       # [B, T] value targets
@@ -45,44 +47,46 @@ def vtrace(
     critic's values v(x_t). With behavior == target and ``rho_bar, c_bar >=
     1`` the recursion telescopes to the on-policy n-step return.
     """
-    rew = rew * valid
-    val = val * valid
-    if last_val is None:
-        last_val = jnp.zeros(rew.shape[:-1], rew.dtype)
+    with jax.named_scope(VTRACE):
+        rew = rew * valid
+        val = val * valid
+        if last_val is None:
+            last_val = jnp.zeros(rew.shape[:-1], rew.dtype)
 
-    log_rho = jnp.where(valid > 0, target_logp - behavior_logp, 0.0)
-    ratio = jnp.exp(log_rho)
-    rho = jnp.minimum(rho_bar, ratio) * valid
-    c = jnp.minimum(c_bar, ratio) * valid
+        log_rho = jnp.where(valid > 0, target_logp - behavior_logp, 0.0)
+        ratio = jnp.exp(log_rho)
+        rho = jnp.minimum(rho_bar, ratio) * valid
+        c = jnp.minimum(c_bar, ratio) * valid
 
-    # v_{t+1} with the bootstrap injected at the last valid step (same
-    # construction as ops/gae.gae_advantages).
-    lengths = jnp.sum(valid, axis=-1).astype(jnp.int32)
-    t_idx = jnp.arange(rew.shape[-1])
-    is_last = (t_idx == (lengths[..., None] - 1)) & (valid > 0)
-    val_next = jnp.concatenate([val[..., 1:], last_val[..., None]], axis=-1)
-    val_next = jnp.where(is_last, last_val[..., None], val_next)
+        # v_{t+1} with the bootstrap injected at the last valid step (same
+        # construction as ops/gae.gae_advantages).
+        lengths = jnp.sum(valid, axis=-1).astype(jnp.int32)
+        t_idx = jnp.arange(rew.shape[-1])
+        is_last = (t_idx == (lengths[..., None] - 1)) & (valid > 0)
+        val_next = jnp.concatenate([val[..., 1:], last_val[..., None]],
+                                   axis=-1)
+        val_next = jnp.where(is_last, last_val[..., None], val_next)
 
-    delta = rho * (rew + gamma * val_next - val) * valid
+        delta = rho * (rew + gamma * val_next - val) * valid
 
-    # Reverse recursion: a_t = delta_t + gamma c_t a_{t+1}, vs = v + a.
-    def backward(carry, inp):
-        delta_t, c_t, valid_t = inp
-        a_t = (delta_t + gamma * c_t * carry) * valid_t
-        return a_t, a_t
+        # Reverse recursion: a_t = delta_t + gamma c_t a_{t+1}, vs = v + a.
+        def backward(carry, inp):
+            delta_t, c_t, valid_t = inp
+            a_t = (delta_t + gamma * c_t * carry) * valid_t
+            return a_t, a_t
 
-    _, a_rev = jax.lax.scan(
-        backward,
-        jnp.zeros(rew.shape[:-1], rew.dtype),
-        (jnp.flip(delta, -1).swapaxes(0, -1),
-         jnp.flip(c, -1).swapaxes(0, -1),
-         jnp.flip(valid, -1).swapaxes(0, -1)),
-    )
-    a = jnp.flip(a_rev.swapaxes(0, -1), -1)
-    vs = (val + a) * valid
+        _, a_rev = jax.lax.scan(
+            backward,
+            jnp.zeros(rew.shape[:-1], rew.dtype),
+            (jnp.flip(delta, -1).swapaxes(0, -1),
+             jnp.flip(c, -1).swapaxes(0, -1),
+             jnp.flip(valid, -1).swapaxes(0, -1)),
+        )
+        a = jnp.flip(a_rev.swapaxes(0, -1), -1)
+        vs = (val + a) * valid
 
-    # vs_{t+1} for the pg advantage, bootstrapping the last valid step.
-    vs_next = jnp.concatenate([vs[..., 1:], last_val[..., None]], axis=-1)
-    vs_next = jnp.where(is_last, last_val[..., None], vs_next)
-    pg_adv = rho * (rew + gamma * vs_next - val) * valid
+        # vs_{t+1} for the pg advantage, bootstrapping the last valid step.
+        vs_next = jnp.concatenate([vs[..., 1:], last_val[..., None]], axis=-1)
+        vs_next = jnp.where(is_last, last_val[..., None], vs_next)
+        pg_adv = rho * (rew + gamma * vs_next - val) * valid
     return VTraceReturns(vs=vs, pg_adv=pg_adv, rho=rho)

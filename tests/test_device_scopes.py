@@ -1,0 +1,250 @@
+"""The update names its parts on the device: every ``jax.named_scope`` of
+``relayrl_tpu/ops/scopes.py`` reaches the instructions it is written round,
+forward and backward, and changes nothing else.
+
+IMPALA's update is lowered and compiled (XLA:CPU) at tiny sizes for each
+model family the benchmark runs. An instruction's ``op_name`` is the scope
+path it was traced under; a transform wraps the outermost scope entered
+under it (``transpose(jvp(relayrl_ffn))/...``), so a backward instruction is
+one whose path holds ``transpose(``. The flash and grouped-matmul kernels
+lower on a TPU only: their names and the glue round them are checked where
+they compile, in ``tests/test_flash_tpu_compile.py``.
+"""
+
+import contextlib
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from relayrl_tpu.algorithms.impala import (
+    ImpalaState,
+    make_impala_tx,
+    make_impala_update,
+)
+from relayrl_tpu.data.batching import TrajectoryBatch
+from relayrl_tpu.models import build_policy
+from relayrl_tpu.ops import scopes
+from relayrl_tpu.ops.scopes import (
+    CONV,
+    DEVICE_SCOPES,
+    EMBED,
+    FFN,
+    HEADS,
+    LOSS,
+    MOE_ELEMENTWISE,
+    MOE_ROUTE,
+    MOE_ROWS,
+    OBS_PREP,
+    OP_PROJ,
+    OPTIMIZER,
+    SHORT_CONV_NAME,
+    VTRACE,
+)
+
+REPO = Path(__file__).resolve().parent.parent
+SEQ = {"obs_dim": 6, "act_dim": 3, "d_model": 16, "n_heads": 2,
+       "max_seq_len": 8}
+EVERY_UPDATE = (OPTIMIZER, VTRACE, LOSS, HEADS)
+TRUNK = EVERY_UPDATE + (EMBED, OP_PROJ)
+MOE = (MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE)
+# family -> (arch, the scopes its update uses, observation width)
+FAMILIES = {
+    # the GPT-2 shaped block (gpt2m-policy)
+    "gpt2": ({**SEQ, "kind": "transformer_discrete", "n_layers": 2},
+             TRUNK + (FFN,)),
+    # every expert held: the plain sparse dispatch (olmoe-policy)
+    "moe": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 1,
+             "moe_experts": 4, "moe_top_k": 2, "norm": "rms",
+             "positions": "rope", "qk_norm": True, "use_bias": False,
+             "ffn": "swiglu", "moe_norm_topk_prob": False},
+            TRUNK + MOE),
+    # a share of the experts held, a conv layer with a dense FFN, grouped
+    # heads, a windowed layer (lfm2-policy, smallthinker-policy)
+    "moe_held": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 3,
+                  "n_heads": 4, "n_kv_heads": 2, "moe_experts": 8,
+                  "moe_top_k": 2, "moe_held": [2, 4], "moe_dense_layers": 1,
+                  "moe_router": "sigmoid", "moe_expert_bias": True,
+                  "layer_types": ["conv", "full_attention",
+                                  "sliding_attention"],
+                  "sliding_window": 4, "norm": "rms", "positions": "rope",
+                  "qk_norm": "head", "use_bias": False, "ffn": "swiglu",
+                  "moe_router_input": "layer"},
+                 TRUNK + MOE + (FFN, SHORT_CONV_NAME)),
+    # the pixel learner (nature-cnn)
+    "cnn": ({"kind": "cnn_discrete", "obs_shape": [36, 36, 2],
+             "obs_dim": 36 * 36 * 2, "act_dim": 3},
+            EVERY_UPDATE + (OBS_PREP, CONV)),
+}
+# parts nothing is differentiated through: no parameter lies before the
+# frames' way in, V-trace reads stopped gradients, the optimizer comes after
+NO_BACKWARD = (OPTIMIZER, VTRACE, OBS_PREP)
+# Share of a compiled update's instructions that carry an ``op_name`` (XLA's
+# own expansions carry none) under a relayrl_ name, at least. Read 0.93-0.99
+# over the four families: what is left is the attention itself (XLA
+# operations here, a kernel of its own name on the chip), the MoE load
+# statistics and the step counter.
+SCOPED_FLOOR = 0.9
+TRIVIAL = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+           "copy", "while", "call", "conditional")
+
+
+def _lower(family: str):
+    arch, _ = FAMILIES[family]
+    policy = build_policy({**arch, "has_critic": True})
+    params = jax.eval_shape(policy.init_params, jax.random.PRNGKey(0))
+    tx = make_impala_tx(1e-4, 1.0)
+    state = ImpalaState(params=params,
+                        opt_state=jax.eval_shape(tx.init, params),
+                        rng=jax.ShapeDtypeStruct((2,), jnp.uint32),
+                        step=jax.ShapeDtypeStruct((), jnp.int32))
+    update = make_impala_update(policy, lr=1e-4, gamma=0.99, vf_coef=0.5,
+                                ent_coef=0.01, rho_bar=1.0, c_bar=1.0,
+                                max_grad_norm=1.0)
+    batch = TrajectoryBatch.zeros(2, 8, arch["obs_dim"], arch["act_dim"],
+                                  True)
+    return jax.jit(update, donate_argnums=0).lower(state, batch)
+
+
+def _once(make):
+    """``make(family)``, kept: a family's update is lowered once a module."""
+    cache: dict = {}
+
+    def of(family: str):
+        if family not in cache:
+            cache[family] = make(family)
+        return cache[family]
+
+    return of
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    return _once(_lower)
+
+
+@pytest.fixture(scope="module")
+def paths(lowered):
+    """family -> the ``op_name`` paths of the lowered update's operations
+    (the named locations of its text with debug info)."""
+    return _once(lambda family: sorted(set(re.findall(
+        r'loc\("(jit\([^"]*)"',
+        lowered(family).as_text(debug_info=True)))))
+
+
+@pytest.fixture(scope="module")
+def compiled(lowered):
+    """family -> the text of the update compiled for XLA:CPU, where the
+    inner ``jit`` calls are inlined and their paths composed."""
+    return _once(lambda family: lowered(family).compile().as_text())
+
+
+def _part_names(path: str) -> set:
+    return {name for name in DEVICE_SCOPES
+            if re.search(rf"{name}(?!\w)", path)}
+
+
+USES = [(family, scope) for family, (_arch, used) in FAMILIES.items()
+        for scope in used]
+
+
+def test_one_list_of_names():
+    """Every name a ``with`` line can open is a constant of the one module,
+    and the lists hold each once."""
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 12
+    assert not set(DEVICE_SCOPES) & set(scopes.KERNEL_SCOPES)
+    used = {scope for _family, scope in USES}
+    assert used == set(DEVICE_SCOPES) | {SHORT_CONV_NAME}
+    program = "".join(p.read_text() for p in (REPO / "relayrl_tpu").rglob(
+        "*.py") if p.name != "scopes.py")
+    # no scope is spelled out where it is opened
+    assert re.findall(r'named_scope\(\s*["\']', program) == []
+
+
+@pytest.mark.parametrize("family,scope", USES)
+def test_scope_reaches_forward_and_backward(paths, family, scope):
+    mine = [p for p in paths(family) if scope in _part_names(p)
+            or (scope == SHORT_CONV_NAME and scope in p)]
+    forward = [p for p in mine if "transpose(" not in p]
+    backward = [p for p in mine if "transpose(" in p]
+    assert forward, f"{scope} is on no forward operation of {family}"
+    if scope in NO_BACKWARD:
+        # jvp( stays: V-trace's scope is entered under value_and_grad
+        assert backward == [], backward[:3]
+        if scope == OPTIMIZER:
+            assert not [p for p in mine if "jvp(" in p]
+    else:
+        assert backward, f"{scope} is on no backward operation of {family}"
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_other_part_in_this_family(paths, family):
+    found = set().union(*(_part_names(p) for p in paths(family)))
+    assert found == set(FAMILIES[family][1]) - {SHORT_CONV_NAME}
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_parts_are_siblings(paths, family):
+    """No operation's path holds two parts, nor one part twice."""
+    for path in paths(family):
+        assert len(_part_names(path)) <= 1, path
+        for name in _part_names(path):
+            assert len(re.findall(rf"{name}(?!\w)", path)) == 1, path
+
+
+def test_custom_vjp_backwards_carry_their_parts(paths, compiled):
+    """The rules that are traced on their own: the plain dispatch's two
+    gathers take the scope round their CALLS, the held layer's loops open
+    theirs in both rules."""
+    def backward(family, scope, what):
+        return [p for p in paths(family) if "transpose(" in p
+                and scope in _part_names(p) and what in p]
+
+    assert backward("moe", MOE_ROWS, "_take")    # the gather, a call here
+    for scope, what in ((MOE_ROUTE, "cumsum"), (MOE_ROWS, "gather"),
+                        (MOE_ELEMENTWISE, "dynamic_update_slice")):
+        assert backward("moe_held", scope, what), (scope, what)
+    # ... and a held pass's experts sit inside the element-wise part, the
+    # vjp's own wrapper absorbed by the name made for it
+    assert re.search(rf'op_name="[^"]*{MOE_ELEMENTWISE}/[^"]*'
+                     rf'jvp\({scopes.HELD_EXPERTS_NAME}\)/', compiled("moe_held"))
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_compiled_update_is_scoped(compiled, family):
+    scoped = total = 0
+    for line in compiled(family).splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = .*? ([\w\-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name and m.group(1) not in TRIVIAL:
+            total += 1
+            scoped += "relayrl_" in name.group(1)
+    assert total > 50
+    assert scoped / total >= SCOPED_FLOOR, (scoped, total)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_device_program_is_the_same_without_the_scopes(lowered, family,
+                                                           monkeypatch):
+    """A scope is metadata: the lowered text without locations is equal,
+    byte for byte, with ``jax.named_scope`` patched away."""
+    named = lowered(family).as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _lower(family)
+    with_names = bare.as_text(debug_info=True)
+    assert not [name for name in DEVICE_SCOPES if name in with_names]
+    assert bare.as_text() == named
+    assert "relayrl_" not in named      # no name leaks into the program
+
+
+def test_the_doc_names_every_scope():
+    """``docs/observability.md``, "Device names": every part and every
+    kernel name of the one list, each beside what reads it."""
+    doc = (REPO / "docs" / "observability.md").read_text()
+    section = doc[doc.index("## Device names"):]
+    missing = [name for name in DEVICE_SCOPES + scopes.KERNEL_SCOPES
+               if f"`{name}`" not in section]
+    assert missing == []

@@ -9,11 +9,13 @@ process may load the TPU's library, and every xdist worker imports every
 test file. Keep these tests in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
-from relayrl_tpu.ops import flash
+from relayrl_tpu.ops import flash, scopes
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +66,12 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
     for name in (flash.FWD_NAME, flash.DQ_NAME, flash.DKV_NAME):
         assert name in text
         assert (name + flash.WINDOW_SUFFIX in text) == bool(window)
+    # What the module does round the kernels is the operator's glue, named
+    # in the forward and in the backward rule; no kernel sits in a part.
+    glue = re.findall(rf'op_name="([^"]*{scopes.OP_PROJ}[^"]*)"', text)
+    assert [p for p in glue if "transpose(" in p]
+    assert [p for p in glue if "transpose(" not in p]
+    assert not [p for p in glue if "relayrl_flash" in p]
     # In the lane layout the head axis never leaves the lanes: nothing in
     # the compiled program (no transpose, no copy, no operand of a kernel)
     # is [B, H, T, D]-shaped or its flat form. A shape that falls back is
@@ -122,6 +130,22 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
         for name in re.findall(r"%(\S*relayrl_moe_gmm\S*) = ", text))
     assert names == {"relayrl_moe_gmm_fwd": 6, "relayrl_moe_gmm_dlhs": 3,
                      "relayrl_moe_gmm_drhs": 3}
+    # each kernel's own name is the innermost of its path, inside the
+    # element-wise part that holds what lies between them; the layer's
+    # three parts are on both loops' operations
+    # (an inner jit's shared computations keep paths of their own)
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    inside = [p for p in paths
+              if "relayrl_moe_gmm" in p and scopes.MOE_ELEMENTWISE in p]
+    assert inside
+    for path in inside:
+        assert path.rindex(scopes.MOE_ELEMENTWISE) < path.index(
+            "relayrl_moe_gmm"), path
+    for part in (scopes.MOE_ROUTE, scopes.MOE_ROWS, scopes.MOE_ELEMENTWISE):
+        mine = [p for p in paths if f"/{part}/" in p
+                and "relayrl_moe_gmm" not in p]
+        assert [p for p in mine if "transpose(" in p], part
+        assert [p for p in mine if "transpose(" not in p], part
     assert f"bf16[{rows},{d}]" in text
     # row -> token is one N*k-row gather a pass and direction, read by the
     # sum over k that follows it: nothing else is N*k rows of d
