@@ -5,8 +5,10 @@ Capability parity with the reference's replay-buffer math
 BaseReplayBuffer.py:6-83 ``discount_cumsum`` via scipy lfilter, and
 algorithms/REINFORCE/replay_buffer.py:48-79 GAE-λ + rewards-to-go on
 ``finish_path``), re-designed for XLA: the reference runs scipy on Python
-lists per episode; here everything is a reverse ``lax.scan`` / associative
-scan over padded ``[B, T]`` device arrays with a validity mask, so the whole
+lists per episode; here everything is element-wise over padded ``[B, T]``
+device arrays with a validity mask, the discounted sums a log-depth reverse
+recurrence (:mod:`relayrl_tpu.ops.recurrence`, the one V-trace runs with a
+per-step coefficient; no loop in the compiled program), so the whole
 epoch's advantage computation compiles into the learner step (no host round
 trip, no per-length recompilation — see SURVEY.md §7.4 item 3).
 """
@@ -16,27 +18,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from relayrl_tpu.ops.recurrence import reverse_linear_recurrence
+
 
 def discount_cumsum(x: jax.Array, discount: float, axis: int = -1) -> jax.Array:
     """Reverse discounted cumulative sum along ``axis``.
 
     ``out[t] = sum_k discount^k * x[t+k]`` — the scipy ``lfilter`` identity
-    the reference uses, as an associative scan (log-depth on device).
+    the reference uses, as the log-depth recurrence of
+    :mod:`relayrl_tpu.ops.recurrence` with a constant coefficient.
     """
     x = jnp.moveaxis(x, axis, -1)
-
-    # Associative: combine (a, va) ⊕ (b, vb) = (a*b, vb + b*va) over reversed
-    # time gives the discounted suffix sum in O(log T) depth.
-    rev = jnp.flip(x, axis=-1)
-    coeff = jnp.full_like(rev, discount)
-
-    def combine(left, right):
-        a_l, v_l = left
-        a_r, v_r = right
-        return a_l * a_r, v_r + a_r * v_l
-
-    _, out = jax.lax.associative_scan(combine, (coeff, rev), axis=-1)
-    return jnp.moveaxis(jnp.flip(out, axis=-1), -1, axis)
+    out = reverse_linear_recurrence(jnp.full_like(x, discount), x)
+    return jnp.moveaxis(out, -1, axis)
 
 
 def rewards_to_go(rew: jax.Array, valid: jax.Array, gamma: float) -> jax.Array:

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 # -- parts of the jitted update (who opens each: docs/observability.md) ------
 OPTIMIZER = "relayrl_optimizer"      # global-norm clip, Adam, the apply
-VTRACE = "relayrl_vtrace"            # ratios, delta, the reverse scan, pg_adv
+VTRACE = "relayrl_vtrace"            # ratios, delta, the reverse recursion, pg_adv
 LOSS = "relayrl_loss"                # the three loss sums, RhoMean, KL
 EMBED = "relayrl_embed"              # obs embedding + learned positions
 OP_PROJ = "relayrl_op_proj"          # a layer's operator less its kernel

@@ -1,6 +1,7 @@
 """GAE/discount ops vs. straightforward numpy references (the reference's
 scipy lfilter math, BaseReplayBuffer.py:6-83 / replay_buffer.py:48-79)."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -11,6 +12,7 @@ from relayrl_tpu.ops import (
     normalize_advantages,
     rewards_to_go,
 )
+from relayrl_tpu.ops.recurrence import reverse_linear_recurrence
 
 
 def np_discount_cumsum(x, discount):
@@ -36,6 +38,21 @@ class TestDiscountCumsum:
         out = np.asarray(discount_cumsum(x, 0.9))
         for b in range(4):
             np.testing.assert_allclose(out[b], np_discount_cumsum(x[b], 0.9), rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("axis", [-1, 0])
+    def test_is_the_shared_recurrence_with_a_constant_coefficient(self, axis):
+        """PR 38: the repo has one reverse linear recurrence
+        (ops/recurrence.py, the one V-trace runs with a per-step
+        coefficient); ``discount_cumsum`` is it, bit for bit."""
+        rng = np.random.default_rng(2)
+        x = jnp.asarray(rng.standard_normal((5, 41)).astype(np.float32))
+        rows = jnp.moveaxis(x, axis, -1)
+        expected = jnp.moveaxis(
+            reverse_linear_recurrence(jnp.full_like(rows, 0.97), rows),
+            -1, axis)
+        np.testing.assert_array_equal(
+            np.asarray(discount_cumsum(x, 0.97, axis=axis)),
+            np.asarray(expected))
 
 
 class TestRewardsToGo:

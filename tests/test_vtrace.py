@@ -19,10 +19,12 @@ the IMPALA e2e tests. This suite pins it directly:
   ``[B, T]`` batches every learner feeds it.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from relayrl_tpu.ops.recurrence import reverse_linear_recurrence
 from relayrl_tpu.ops.vtrace import vtrace
 
 pytestmark = pytest.mark.rlhf
@@ -201,3 +203,207 @@ class TestPaddedBatches:
                                        single[b][0], rtol=1e-5)
             np.testing.assert_allclose(np.asarray(stacked.pg_adv)[b],
                                        single[b][1], rtol=1e-5)
+
+
+# --- PR 38: the recursion in log depth (ops/recurrence.py) -----------------
+#
+# Everything below compares with a float64 numpy recursion, step by step
+# over T — the form the op no longer has — at the two long shapes the
+# benchmark's sequence cells run ([1, 16384] and [2, 8192]).
+
+
+def reference_vtrace_f64(behavior_logp, target_logp, rew, val, valid, gamma,
+                         last_val, rho_bar, c_bar):
+    """[B, T] float64 V-trace on right-padded rows, the recursion a Python
+    loop over T (vectorised over B only)."""
+    b, t, rew, val, valid, last_val = (
+        np.asarray(x, np.float64)
+        for x in (behavior_logp, target_logp, rew, val, valid, last_val))
+    B, T = rew.shape
+    rew, val = rew * valid, val * valid
+    ratio = np.exp(np.where(valid > 0, t - b, 0.0))
+    rho = np.minimum(rho_bar, ratio) * valid
+    c = np.minimum(c_bar, ratio) * valid
+    lengths = valid.sum(-1).astype(int)
+    rows = np.arange(B)
+    has = lengths > 0
+
+    def shifted(v):
+        nxt = np.concatenate([v[:, 1:], last_val[:, None]], -1)
+        nxt[rows[has], lengths[has] - 1] = last_val[has]
+        return nxt
+
+    delta = rho * (rew + gamma * shifted(val) - val) * valid
+    a = np.zeros((B, T + 1))
+    with np.errstate(over="ignore", invalid="ignore"):
+        for i in reversed(range(T)):
+            a[:, i] = delta[:, i] + gamma * c[:, i] * a[:, i + 1]
+    vs = (val + a[:, :T]) * valid
+    pg_adv = rho * (rew + gamma * shifted(vs) - val) * valid
+    return vs, pg_adv, rho
+
+
+def random_batch(shape, lengths, seed, logp_scale=0.3):
+    rng = np.random.default_rng(seed)
+    b = rng.normal(-1.0, logp_scale, shape).astype(np.float32)
+    t = rng.normal(-1.0, logp_scale, shape).astype(np.float32)
+    rew = rng.standard_normal(shape).astype(np.float32)
+    val = rng.standard_normal(shape).astype(np.float32)
+    valid = (np.arange(shape[1])[None, :]
+             < np.asarray(lengths)[:, None]).astype(np.float32)
+    last_val = rng.standard_normal(shape[0]).astype(np.float32)
+    return b, t, rew, val, valid, last_val
+
+
+def assert_matches_f64(args, gamma, rho_bar, c_bar):
+    b, t, rew, val, valid, last_val = args
+    out = vtrace(*(jnp.asarray(x) for x in (b, t, rew, val, valid)), gamma,
+                 last_val=jnp.asarray(last_val), rho_bar=rho_bar,
+                 c_bar=c_bar)
+    ref_vs, ref_pg, ref_rho = reference_vtrace_f64(
+        b, t, rew, val, valid, gamma, last_val, rho_bar, c_bar)
+    for got, ref in ((out.vs, ref_vs), (out.pg_adv, ref_pg),
+                     (out.rho, ref_rho)):
+        got = np.asarray(got)
+        assert got.dtype == np.float32
+        assert np.all(got[valid == 0] == 0)
+        assert np.abs(got - ref).max() <= 1e-5 * np.abs(ref).max()
+    return out
+
+
+LONG_SHAPES = [(1, 16384), (2, 8192)]
+
+
+class TestLogDepthRecursion:
+    @pytest.mark.parametrize("bar", [1.0, 0.5])
+    @pytest.mark.parametrize("tail", ["0", "1", "100", "T-1"])
+    @pytest.mark.parametrize("shape", LONG_SHAPES, ids=["1x16384", "2x8192"])
+    def test_long_rows_against_float64(self, shape, tail, bar):
+        """Random log-ratios at the cells' shapes, a padded tail of 0, 1,
+        100 and T − 1 steps on the first row (the second row, where there
+        is one, full)."""
+        T = shape[1]
+        pad = T - 1 if tail == "T-1" else int(tail)
+        lengths = [T - pad] + [T] * (shape[0] - 1)
+        assert_matches_f64(random_batch(shape, lengths, seed=pad + shape[0]),
+                           0.99, bar, bar)
+
+    @pytest.mark.parametrize("shape", LONG_SHAPES, ids=["1x16384", "2x8192"])
+    def test_all_padding_row(self, shape):
+        """A row with no valid step is all zeros, and leaves its
+        neighbour alone."""
+        lengths = [0] + [shape[1] - 7] * (shape[0] - 1)
+        out = assert_matches_f64(random_batch(shape, lengths, seed=11),
+                                 0.99, 1.0, 1.0)
+        assert np.all(np.asarray(out.vs)[0] == 0)
+        assert np.all(np.asarray(out.pg_adv)[0] == 0)
+
+    @pytest.mark.parametrize("shape", LONG_SHAPES, ids=["1x16384", "2x8192"])
+    def test_recursion_restarts_where_c_is_zero(self, shape):
+        """A step whose ratio underflows to 0 (c_t = rho_t = 0) cuts the
+        trace: nothing from later steps reaches that step or any before it
+        through it, so vs there is exactly v."""
+        T = shape[1]
+        args = random_batch(shape, [T] * shape[0], seed=5)
+        cut = T // 2 + 3
+        args[1][0, cut] = args[0][0, cut] - 200.0   # exp(-200) == 0 in f32
+        out = assert_matches_f64(args, 0.99, 1.0, 1.0)
+        assert np.asarray(out.rho)[0, cut] == 0
+        assert np.asarray(out.vs)[0, cut] == args[3][0, cut]
+        # the steps before the cut see the same targets whatever follows it
+        later = [x.copy() for x in args]
+        later[2][0, cut + 1:] = 0.0
+        out_b = vtrace(*(jnp.asarray(x) for x in later[:5]), 0.99,
+                       last_val=jnp.asarray(later[5]))
+        np.testing.assert_array_equal(np.asarray(out.vs)[0, :cut],
+                                      np.asarray(out_b.vs)[0, :cut])
+
+    def test_products_above_one_short(self):
+        """gamma * c_bar > 1 at T 64: the coefficients' products grow
+        along the row; equal to the reference."""
+        args = random_batch((2, 64), [64, 40], seed=2, logp_scale=1.0)
+        b, t, rew, val, valid, last_val = args
+        out = vtrace(*(jnp.asarray(x) for x in args[:5]), 0.99,
+                     last_val=jnp.asarray(last_val), rho_bar=2.0, c_bar=2.0)
+        ref_vs, ref_pg, _ = reference_vtrace_f64(*args[:5], 0.99, last_val,
+                                                 2.0, 2.0)
+        np.testing.assert_allclose(np.asarray(out.vs), ref_vs, rtol=2e-5,
+                                   atol=1e-5 * np.abs(ref_vs).max())
+        np.testing.assert_allclose(np.asarray(out.pg_adv), ref_pg, rtol=2e-5,
+                                   atol=1e-5 * np.abs(ref_pg).max())
+
+    def test_products_above_one_long_stay_finite(self):
+        """gamma * c_bar > 1 at T 16,384: 2,000 steps of ratio e^2 with no
+        reward and no value (delta 0, coefficient 1.98) after a stretch of
+        ordinary steps. The product of those coefficients overflows
+        float32 (1.98^2000), nothing follows them, and the targets before
+        them are ordinary numbers: finite wherever the reference is, and
+        equal to it."""
+        T = 16384
+        args = random_batch((1, T), [T - 100], seed=9)
+        b, t, rew, val, valid, last_val = args
+        t[0, 1000:3000] = b[0, 1000:3000] + 2.0
+        rew[0, 1000:] = 0.0
+        val[0, 1000:] = 0.0
+        last_val[:] = 0.0
+        out = vtrace(*(jnp.asarray(x) for x in args[:5]), 0.99,
+                     last_val=jnp.asarray(last_val), rho_bar=2.0, c_bar=2.0)
+        ref_vs, ref_pg, _ = reference_vtrace_f64(*args[:5], 0.99, last_val,
+                                                 2.0, 2.0)
+        assert np.all(np.isfinite(ref_vs)) and np.all(np.isfinite(ref_pg))
+        for got, ref in ((np.asarray(out.vs), ref_vs),
+                         (np.asarray(out.pg_adv), ref_pg)):
+            assert np.all(np.isfinite(got))
+            assert np.abs(got - ref).max() <= 1e-5 * np.abs(ref).max()
+
+
+def primitives_of(jaxpr) -> set:
+    """Names of every primitive in a jaxpr, sub-jaxprs included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= primitives_of(sub)
+    return names
+
+
+class TestNoLoopInTheProgram:
+    """The mechanism's guard on the CPU: what V-trace traces to."""
+
+    @staticmethod
+    def traced(c_bar, shape=(2, 64)):
+        x = jnp.zeros(shape, jnp.float32)
+        return primitives_of(jax.make_jaxpr(
+            lambda b, t, r, v, m, lv: vtrace(b, t, r, v, m, 0.99,
+                                             last_val=lv, c_bar=c_bar))(
+            x, x, x, x, x, jnp.zeros(shape[:1], jnp.float32)).jaxpr)
+
+    def test_no_scan_or_while_at_the_shipped_bars(self):
+        assert not {"scan", "while"} & self.traced(c_bar=1.0)
+
+    def test_no_scan_or_while_at_gamma_c_bar_of_one(self):
+        x = jnp.zeros((1, 16), jnp.float32)
+        prims = primitives_of(jax.make_jaxpr(
+            lambda d: vtrace(d, d, d, d, d, 1.0, c_bar=1.0))(x).jaxpr)
+        assert not {"scan", "while"} & prims
+
+    def test_sequential_scan_where_products_can_grow(self):
+        assert "scan" in self.traced(c_bar=2.0)
+
+    @pytest.mark.parametrize("steps", [1, 2, 3, 20, 1000])
+    def test_doubling_equals_sequential(self, steps):
+        """The helper's two forms on one input, any T (no power of two
+        needed), against each other and a float64 loop."""
+        rng = np.random.default_rng(steps)
+        k = rng.uniform(0.0, 1.0, (3, steps)).astype(np.float32)
+        x = rng.standard_normal((3, steps)).astype(np.float32)
+        ref = np.zeros((3, steps + 1))
+        for i in reversed(range(steps)):
+            ref[:, i] = x[:, i] + k[:, i].astype(np.float64) * ref[:, i + 1]
+        fast = np.asarray(reverse_linear_recurrence(jnp.asarray(k),
+                                                    jnp.asarray(x)))
+        slow = np.asarray(reverse_linear_recurrence(
+            jnp.asarray(k), jnp.asarray(x), sequential=True))
+        scale = np.abs(ref).max()
+        assert np.abs(fast - ref[:, :steps]).max() <= 1e-6 * scale
+        assert np.abs(slow - ref[:, :steps]).max() <= 1e-6 * scale
