@@ -650,7 +650,18 @@ def phase_e() -> None:
     for a handful of groups. Both
     sides accumulate in float32 and round once to bfloat16, so they may
     differ by the order of the sums: a few units in the last place of the
-    largest entry."""
+    largest entry.
+
+    And at ``nemotron-twotower-policy.update``'s (PR 39): a 12,288-row
+    buffer of 2688 through 8 held experts of width 1856 = 14.5 x 128, which
+    no multiple of 128 divides, up (the irregular last tile on the result's
+    axis, on the contracted axis in d_lhs) and down (1856 contracted)."""
+    phase_e_at(16384 * 8, 2048, 1024, 64)
+    phase_e_at(12288, 2688, 1856, 8)
+    phase_e_at(12288, 1856, 2688, 8)
+
+
+def phase_e_at(m: int, k: int, n: int, n_exp: int) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -658,16 +669,19 @@ def phase_e() -> None:
     from relayrl_tpu.ops import grouped_matmul as kernels
 
     t0 = time.monotonic()
-    m, k, n, n_exp = 16384 * 8, 2048, 1024, 64
     check(kernels.fits(m, k, n), f"E: the kernels do not tile {(m, k, n)}")
     rng = np.random.default_rng(0)
+    half = n_exp // 2
     skewed = np.zeros(n_exp, np.int64)
-    skewed[3], skewed[7] = m // 2, 1
+    skewed[half // 8], skewed[half // 4] = m // 2, 1
     rest = m - int(skewed.sum())
-    skewed[32:] = rng.multinomial(rest, np.ones(32) / 32)  # 0..31: 29 empty
+    # the first half: all empty but the two above
+    skewed[half:] = rng.multinomial(rest, np.ones(half) / half)
     loads = {"random router": (rng.multinomial(m, np.ones(n_exp) / n_exp),
-                               (0, 1, 31, 32, 63)),
-             "empty groups, 1 row, half the rows": (skewed, (3, 7, 40, 63))}
+                               sorted({0, 1, half - 1, half, n_exp - 1})),
+             "empty groups, 1 row, half the rows": (
+                 skewed, sorted({half // 8, half // 4, half + half // 4,
+                                 n_exp - 1}))}
     key = jax.random.split(jax.random.PRNGKey(0), 3)
     lhs = jax.random.normal(key[0], (m, k), jnp.bfloat16)
     rhs = jax.random.normal(key[1], (n_exp, k, n), jnp.bfloat16) / 32

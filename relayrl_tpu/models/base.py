@@ -60,6 +60,10 @@ ARCH_PASSTHROUGH_KEYS = (
     # a head width of its own, windowed layers, positions layer by layer,
     # the router's input
     "head_dim", "sliding_window", "rope_layers", "moe_router_input",
+    # the Mamba-2 mixer, the routed weights' factor, the shared expert
+    "mamba_heads", "mamba_head_dim", "mamba_state", "mamba_groups",
+    "mamba_conv_taps", "mamba_chunk", "moe_routed_scaling",
+    "moe_shared_d_ff",
 )
 
 

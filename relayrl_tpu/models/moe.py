@@ -30,7 +30,11 @@ post-attention rows. Still one token's features alone. Three weightings
   expert, seeded non-zero; it enters the choice only, so its gradient is
   exactly zero and the optimizer never moves it) and weighted by their
   UNBIASED scores, divided by their sum + 1e-6 under ``moe_norm_topk_prob``
-  (LFM2-MoE's router; its ``routed_scaling_factor`` is 1 and has no key).
+  (LFM2-MoE's router, whose ``routed_scaling_factor`` is 1).
+
+Whatever the weighting, arch ``moe_routed_scaling`` multiplies the k weights
+(Nemotron-H's ``routed_scaling_factor`` 2.5, after the normalisation; 1, the
+default, multiplies nothing).
 
 **Held experts.** A layer may be told which experts it holds: arch
 ``moe_held = [first, count]`` says this device holds experts
@@ -45,10 +49,19 @@ absent chips' work or traffic. Unset (or naming all E), every expert is
 held and the layer is the plain sparse dispatch below: a full layer has no
 dead rows to leave out.
 
-Experts are GELU (``moe_w_up`` / ``moe_w_down``, the default) or gated,
-``moe_w_gate`` beside them: SwiGLU (arch ``ffn: "swiglu"``,
-``down(silu(gate(x)) * up(x))``) or ReGLU (``ffn: "reglu"``,
+Experts are two stacks, ``moe_w_up`` / ``moe_w_down`` — GELU (the default)
+or squared ReLU (arch ``ffn: "relu2"``, ``down(relu(up(x))^2)``,
+Nemotron-H's) — or gated, ``moe_w_gate`` beside them: SwiGLU (``ffn:
+"swiglu"``, ``down(silu(gate(x)) * up(x))``) or ReGLU (``ffn: "reglu"``,
 ``down(relu(gate(x)) * up(x))``), of width ``moe_d_ff``.
+
+**A shared expert** (arch ``moe_shared_d_ff``: its width; unset, none) is one
+more FFN of the experts' kind that EVERY token takes, added once beside the
+routed sum with weight 1 (``moe_shared_up`` / ``moe_shared_gate`` /
+``moe_shared_down``, dense matmuls under the part ``relayrl_ffn``). It lies
+outside the held share: every chip of a layer computes it alike, so the
+shares of the chips that divide a layer add up to the layer with the shared
+expert counted ONCE (``tests/test_nemotron_reference.py``).
 
 Dispatch is **sparse** on one device: the
 N·k token-slots are sorted by expert, each expert's rows go through one
@@ -146,8 +159,9 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from relayrl_tpu.models.mlp import GATED_FFN
+from relayrl_tpu.models.mlp import GATED_FFN, UNGATED_FFN
 from relayrl_tpu.ops.scopes import (
+    FFN,
     HELD_EXPERTS_NAME,
     MOE_ELEMENTWISE,
     MOE_ROUTE,
@@ -247,8 +261,9 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
 def _activation(ffn: str, up, gate=None):
     """The experts' inner activation: ``act(gate) * up`` where the FFN is
-    gated (``gate`` given), GELU of ``up`` otherwise."""
-    return nn.gelu(up) if gate is None else GATED_FFN[ffn](gate) * up
+    gated (``gate`` given), ``act(up)`` otherwise."""
+    return (UNGATED_FFN[ffn](up) if gate is None
+            else GATED_FFN[ffn](gate) * up)
 
 
 def _experts(ffn, xs, stacks, group_sizes):
@@ -464,6 +479,24 @@ def _mesh_ep() -> int:
     return 1 if mesh is None else int(mesh.shape.get("ep", 1))
 
 
+def _shared_ffn(layer: "MoEMLP", xs, gated: bool):
+    """The expert every token takes, ``[N, d]`` float32, in ``layer``'s
+    param scope: dense matmuls, outside the dispatch and outside the held
+    share. (A plain function: a module method would be wrapped by flax.)"""
+    def dense(features, name):
+        return nn.Dense(features, dtype=layer.compute_dtype, use_bias=False,
+                        name=name)
+
+    with jax.named_scope(FFN):
+        up = dense(layer.shared_d_ff, "moe_shared_up")(xs)
+        gate = (dense(layer.shared_d_ff, "moe_shared_gate")(xs)
+                if gated else None)
+        h = _activation(layer.ffn, up.astype(jnp.float32),
+                        None if gate is None else gate.astype(jnp.float32))
+        return dense(layer.d_model, "moe_shared_down")(
+            h.astype(layer.compute_dtype)).astype(jnp.float32)
+
+
 class MoEMLP(nn.Module):
     """Per-token top-k MoE FFN over flattened tokens."""
 
@@ -480,6 +513,9 @@ class MoEMLP(nn.Module):
     expert_bias: bool = False       # moe_expert_bias in the sigmoid choice
     # (first, count): the experts of n_experts this device holds; None: all
     held: tuple[int, int] | None = None
+    routed_scaling: float = 1.0     # times the k weights, after normalising
+    # width of the one shared expert every token takes (None: none)
+    shared_d_ff: int | None = None
 
     @nn.compact
     def __call__(self, x, route_x=None):
@@ -515,6 +551,8 @@ class MoEMLP(nn.Module):
                                   (n_exp,), jnp.float32)
             top_w, top_idx = route(logits, k, self.norm_topk_prob,
                                    self.router, bias)              # [N, k]
+            if self.routed_scaling != 1.0:
+                top_w = top_w * float(self.routed_scaling)
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         gated = self.ffn in GATED_FFN
@@ -635,6 +673,8 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "expert_slots", jnp.int32(n * k))
         self.sow("intermediates", "row_passes", row_passes)
         self.sow("intermediates", "row_buffer", jnp.int32(rows))
+        if self.shared_d_ff:
+            y = y + _shared_ffn(self, tokens.astype(cd), gated)
         return y.reshape(B, T, d).astype(x.dtype)
 
 

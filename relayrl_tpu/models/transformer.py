@@ -57,6 +57,24 @@ signal: under ``positions: "rope"`` there is no table either);
 ``moe_router_input: "layer"`` hands the expert layer's router the layer's
 un-normed input (models/moe.py); ``ffn: "reglu"`` gates with ReLU.
 
+A layer may also be ONE part behind one norm, ``x + part(norm(x))``
+(Nemotron-H's, ``benchmark/configs/nemotron-twotower-policy.json``), by
+three more ``layer_types`` entries: ``"mamba2"`` — the Mamba-2 mixer
+(:func:`_mamba_layer`: one input projection to ``[z | xBC | dt]``, a
+depthwise causal convolution of ``mamba_conv_taps`` taps with bias and SiLU
+over ``xBC`` (:func:`_mamba_conv`), the state-space scan of
+:mod:`relayrl_tpu.ops.ssd` over ``mamba_heads`` heads of ``mamba_head_dim``
+with a state of ``mamba_state`` columns and ``mamba_groups`` groups of B
+and C in chunks of ``mamba_chunk``, an RMSNorm by groups of the output
+gated by ``silu(z)``, the output projection) —, ``"attention"`` — global
+attention and no FFN — and ``"ffn"`` — the FFN (dense, or the expert layer
+past ``moe_dense_layers``) and no operator. A Mamba-2 layer's cache is the
+FOURTH kind: the convolution's last ``mamba_conv_taps - 1`` rows of ``xBC``
+and the ``[H, P, N]`` state in float32 — a decode step is O(1) in the
+position; an ``"ffn"`` layer's is empty. ``positions: "none"`` gives a trunk
+no positional signal at all (no table, no rotation: the state-space layers
+order the tokens).
+
 Sequence ABI: ``evaluate(params, obs[B,T,D], act[B,T], mask[B,T,A]) ->
 (logp[B,T], ent[B,T], v[B,T])`` — same shapes the per-step MLP family
 broadcasts to, so REINFORCE/PPO updates take this policy unchanged.
@@ -73,10 +91,12 @@ import jax
 import jax.numpy as jnp
 import flax
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from relayrl_tpu.models.base import Policy, register_model
 from relayrl_tpu.models.mlp import (
     GATED_FFN,
+    UNGATED_FFN,
     _MASK_FILL,
     _categorical_entropy,
     _categorical_logp,
@@ -87,10 +107,15 @@ from relayrl_tpu.ops.scopes import (  # noqa: F401  (SHORT_CONV_NAME's home)
     EMBED,
     FFN,
     HEADS,
+    MAMBA_CONV_NAME,
     MOE_ELEMENTWISE,
     OP_PROJ,
     SHORT_CONV_NAME,
 )
+from relayrl_tpu.ops.ssd import ssd, ssd_step
+
+# the one activation a Mamba-2 layer's checkpoint keeps (_mamba_layer)
+_SSD_OUT = "relayrl_ssd_out"
 
 
 def _resolve_attention(arch: Mapping[str, Any]
@@ -287,6 +312,26 @@ def _short_conv(bcu, w, state=None):
         return c_gate * c.astype(bcu.dtype), zp
 
 
+def _mamba_conv(xbc, w, bias, state=None):
+    """The Mamba-2 mixer's convolution: ``silu(conv(xbc) + bias)``,
+    depthwise and causal, ``L = w.shape[0]`` taps, returns ``(out,
+    xbc_padded)``. ``state [batch, L-1, c]`` holds the ``xbc`` rows before
+    this call's first (zeros at a sequence's start, which ``None`` means);
+    ``xbc_padded = concat(state, xbc)`` is what a cache takes its next rows
+    from. Plain XLA under one named scope, the tap sums in float32, as
+    :func:`_short_conv`."""
+    with jax.named_scope(MAMBA_CONV_NAME):
+        taps = w.shape[0]
+        T = xbc.shape[1]
+        if state is None:
+            xp = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+        else:
+            xp = jnp.concatenate([state.astype(xbc.dtype), xbc], axis=1)
+        c = sum(w[j].astype(jnp.float32) * xp[:, j:j + T].astype(jnp.float32)
+                for j in range(taps))
+        return nn.silu(c + bias.astype(jnp.float32)).astype(xbc.dtype), xp
+
+
 def _block_dense(block: "TransformerBlock", features: int, name: str):
     return nn.Dense(features, dtype=block.compute_dtype, name=name,
                     use_bias=block.use_bias)
@@ -299,13 +344,17 @@ def _block_ffn(block: "TransformerBlock", x, layer_in):
     ``moe_router_input: "layer"``. (A plain function, like
     :func:`_embed_obs`: a module method would be wrapped by flax once per
     call.) The norm and the residual are the FFN's element-wise passes,
-    the dense one's or the expert layer's (``ops/scopes.py``)."""
+    the dense one's or the expert layer's (``ops/scopes.py``). A layer
+    that is an operator alone (``has_ffn`` false) has none: ``x``."""
+    if not block.has_ffn:
+        return x
     part = MOE_ELEMENTWISE if block.moe_experts > 0 else FFN
     with jax.named_scope(part):
         h = _norm(block.norm, block.norm_eps, "ln_mlp")(x)
     width = block.d_ff or block.mlp_ratio * block.d_model
-    if block.ffn != "gelu" and block.ffn not in GATED_FFN:
-        raise ValueError(f"unknown ffn {block.ffn!r} (gelu | swiglu | reglu)")
+    if block.ffn not in UNGATED_FFN and block.ffn not in GATED_FFN:
+        raise ValueError(f"unknown ffn {block.ffn!r} "
+                         f"(gelu | relu2 | swiglu | reglu)")
     if block.moe_experts > 0:
         from relayrl_tpu.models.moe import MoEMLP
 
@@ -328,7 +377,7 @@ def _block_ffn(block: "TransformerBlock", x, layer_in):
             h = GATED_FFN[block.ffn](
                 _block_dense(block, width, "mlp_gate")(h)) * up
         else:
-            h = nn.gelu(up)
+            h = UNGATED_FFN[block.ffn](up)
         h = _block_dense(block, block.d_model, "mlp_down")(h)
         return x + h.astype(x.dtype)
 
@@ -363,9 +412,22 @@ class TransformerBlock(nn.Module):
     # MoEMLP's further fields (router, expert_bias, held)
     moe_kw: Mapping[str, Any] = flax.core.FrozenDict()
     # The layer's operator: "attention" | "conv" (gated short convolution
-    # of conv_taps taps: conv_in d -> 3d, conv_w [taps, d], conv_out).
+    # of conv_taps taps: conv_in d -> 3d, conv_w [taps, d], conv_out) |
+    # "mamba2" (the Mamba-2 mixer, _mamba_layer) | "none"; and whether an
+    # FFN follows it. A layer of one part is an operator with has_ffn false,
+    # or "none" with its FFN: one norm, one residual.
     op: str = "attention"
+    has_ffn: bool = True
     conv_taps: int = 3
+    # The Mamba-2 mixer: heads x head_dim wide inside (not a multiple of
+    # d_model), a state of mamba_state columns a head, B and C in
+    # mamba_groups groups, the scan in chunks of mamba_chunk.
+    mamba_heads: int = 8
+    mamba_head_dim: int = 64
+    mamba_state: int = 128
+    mamba_groups: int = 1
+    mamba_conv_taps: int = 4
+    mamba_chunk: int = 128
     # Grouped-query heads: n_kv_heads k/v heads under n_heads query heads,
     # all d_model // n_heads wide (separate q_proj / k_proj / v_proj).
     # None: one fused qkv, as always. qk_norm "head": RMSNorm over each head.
@@ -418,9 +480,16 @@ class TransformerBlock(nn.Module):
         B, T, _ = x.shape
         if self.op == "conv":
             return _conv_layer(self, x, cache, readout_idx, n_valid)
+        if self.op == "mamba2":
+            return _mamba_layer(self, x, cache, n_valid)
+        if self.op == "none":   # the FFN alone: nothing to cache
+            if readout_idx is not None:
+                x = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1, axis=1)
+            out = _block_ffn(self, x, x)
+            return out if cache is None else (out, ())
         if self.op != "attention":
             raise ValueError(f"unknown layer operator {self.op!r} "
-                             f"(attention | conv)")
+                             f"(attention | conv | mamba2 | none)")
         head_dim = self.head_dim or self.d_model // self.n_heads
         width = self.n_heads * head_dim     # of q and of attn_out's input
         # everything of the operator but its kernel: one part on the device
@@ -578,6 +647,122 @@ def _conv_layer(block: TransformerBlock, x, cache, readout_idx, n_valid):
     return out, state.astype(cache.dtype)
 
 
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``softplus^-1`` of step sizes log-uniform over Mamba-2's published
+    range (``time_step_min`` 0.001 .. ``time_step_max`` 0.1, floor 1e-4)."""
+    lo, hi = jnp.log(0.001), jnp.log(0.1)
+    dt = jnp.maximum(jnp.exp(lo + (hi - lo) * jax.random.uniform(key, shape)),
+                     1e-4)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log`` of decay rates uniform over Mamba-2's ``A_init_range``
+    (1, 16): ``A = -exp(A_log)``."""
+    return jnp.log(jax.random.uniform(key, shape, minval=1.0,
+                                      maxval=16.0)).astype(dtype)
+
+
+def _mamba_layer(block: TransformerBlock, x, cache, n_valid):
+    """A Mamba-2 layer in ``block``'s param scope, ``x + out(norm_g(y *
+    silu(z)))`` behind the layer's one norm, then the FFN if the layer has
+    one. ``[z | xBC | dt] = in(norm(x))``; ``xBC = silu(conv(xBC) + b)``;
+    ``(x, B, C) = split(xBC)``; ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)`` in float32; ``y`` the scan of ``ops/ssd.py``; ``norm_g``
+    an RMSNorm over each of ``mamba_groups`` groups of the gated output.
+    No positions: the scan orders the tokens.
+
+    Full mode (``cache=None``). Cached modes: ``cache`` is ``(the
+    convolution's last mamba_conv_taps - 1 rows of xBC, the [B, H, P, N]
+    state in float32)``; one row continues from it in one step of the
+    recurrence (O(1) in the position), several rows (prefill) run the
+    chunked scan from it and leave the state after the ``n_valid`` real
+    ones (rows past them get ``dt = 0``: they leave the state as it is).
+    The readout row of a window needs the whole scan before it: the core
+    runs a final Mamba-2 layer in full and slices."""
+    Bsz, T, d = x.shape
+    H, P = block.mamba_heads, block.mamba_head_dim
+    G, N = block.mamba_groups, block.mamba_state
+    inner, bc, back = H * P, G * N, block.mamba_conv_taps - 1
+    if H % G:
+        raise ValueError(f"mamba_groups {G} does not divide mamba_heads {H}")
+    f32 = jnp.float32
+    cd = block.compute_dtype
+    lecun = nn.initializers.lecun_normal()
+    weights = (
+        block.param("mamba_in", lecun, (d, 2 * inner + 2 * bc + H), f32),
+        block.param("mamba_conv_w", lecun,
+                    (block.mamba_conv_taps, inner + 2 * bc), f32),
+        block.param("mamba_conv_b", nn.initializers.normal(0.02),
+                    (inner + 2 * bc,), f32),
+        block.param("mamba_dt_bias", _dt_bias_init, (H,), f32),
+        block.param("mamba_A_log", _a_log_init, (H,), f32),
+        block.param("mamba_D", nn.initializers.ones, (H,), f32),
+        block.param("mamba_norm", nn.initializers.ones, (inner,), f32),
+        block.param("mamba_out", lecun, (inner, d), f32))
+    eps = 1e-6 if block.norm_eps is None else float(block.norm_eps)
+
+    def mix(h, weights, conv_rows, state, n_valid):
+        """normed rows -> (the mixer's output, xBC with the rows before it,
+        the state after the last real row)"""
+        w_in, conv_w, conv_b, dt_bias, a_log, skip, scale, w_out = weights
+        with jax.named_scope(OP_PROJ):
+            z, xbc, dt = jnp.split(
+                jnp.dot(h, w_in.astype(cd)),
+                [inner, 2 * inner + 2 * bc], axis=-1)
+            dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+            if n_valid is not None:
+                dt = jnp.where(jnp.arange(T)[None, :, None] < n_valid, dt,
+                               0.0)
+            a = -jnp.exp(a_log)
+        xbc, padded = _mamba_conv(xbc, conv_w, conv_b, conv_rows)
+        with jax.named_scope(OP_PROJ):
+            xs, b_in, c_in = jnp.split(xbc, [inner, inner + bc], axis=-1)
+            xs = xs.reshape(Bsz, T, H, P)
+            b_in, c_in = (r.reshape(Bsz, T, G, N) for r in (b_in, c_in))
+        if state is not None and T == 1:
+            y, state = ssd_step(xs[:, 0], dt[:, 0], a, b_in[:, 0],
+                                c_in[:, 0], skip, state)
+            y = y[:, None]
+        else:
+            y, state = ssd(xs, dt, a, b_in, c_in, skip, block.mamba_chunk,
+                           state)
+        y = checkpoint_name(y, _SSD_OUT)
+        with jax.named_scope(OP_PROJ):
+            # the gate BEFORE the norm, the norm over each group's columns
+            g = (y.reshape(Bsz, T, inner).astype(f32)
+                 * nn.silu(z.astype(f32))).reshape(Bsz, T, G, inner // G)
+            g = g * jax.lax.rsqrt(
+                jnp.mean(jnp.square(g), -1, keepdims=True) + eps)
+            y = (g.reshape(Bsz, T, inner) * scale).astype(cd)
+            return jnp.dot(y, w_out.astype(cd)), padded, state
+
+    with jax.named_scope(OP_PROJ):
+        h = _norm(block.norm, block.norm_eps, "ln_attn")(x).astype(cd)
+    if cache is None:
+        # Full mode, the learner's: the mixer's inner activations (the
+        # 10,304-wide projection, the convolution's rows, the gate and the
+        # norm in float32: 1.7 GB a layer at 16,384 tokens) are made again
+        # in the backward from the normed rows; of them only the scan's
+        # output is kept, so that the scan runs twice (its own checkpoint,
+        # ops/ssd.py) and not three times.
+        y, _, _ = jax.checkpoint(
+            mix, policy=jax.checkpoint_policies.save_only_these_names(
+                _SSD_OUT))(h, weights, None, None, None)
+    else:
+        y, padded, state = mix(h, weights, *cache, n_valid)
+    with jax.named_scope(OP_PROJ):
+        x_out = x + y.astype(x.dtype)
+    out = _block_ffn(block, x_out, x)
+    if cache is None:
+        return out
+    # padded row j is xBC row j - back: after n real rows the convolution
+    # wants rows n - back .. n - 1
+    n = T if n_valid is None else n_valid
+    rows = jax.lax.dynamic_slice_in_dim(padded, n, back, axis=1)
+    return out, (rows.astype(cache[0].dtype), state)
+
+
 def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
                start=0, learned_positions: bool = True):
     """Obs embedding + positional table, built in the CALLER's param scope
@@ -636,8 +821,9 @@ class TransformerCore(nn.Module):
     moe_top_k: int = 2
     # TransformerBlock's arch fields, passed through as one dict
     block_kw: Mapping[str, Any] = flax.core.FrozenDict()
-    # Per layer: its operator ("full_attention" | "sliding_attention" |
-    # "conv"; empty: full attention everywhere) and, in a MoE trunk, how
+    # Per layer: its kind — an operator and an FFN ("full_attention" |
+    # "sliding_attention" | "conv"; empty: full attention everywhere) or
+    # ONE part ("mamba2" | "attention" | "ffn") — and, in a MoE trunk, how
     # many leading layers keep the dense FFN.
     layer_types: tuple[str, ...] = ()
     moe_dense_layers: int = 0
@@ -646,13 +832,20 @@ class TransformerCore(nn.Module):
     # Per layer under rotary positions: whether RoPE turns its q and k
     # (empty: every attention layer's). A layer left out sees no positions.
     rope_layers: tuple[bool, ...] = ()
+    # "learned": a table added to the embedding (unless the blocks rotate:
+    # block_kw's rope_theta); "none": no positional signal at all.
+    positions: str = "learned"
+
+    def layer_parts(self, i: int) -> tuple[str, bool]:
+        """Layer ``i``'s (operator, whether an FFN follows it)."""
+        kind = self.layer_types[i] if self.layer_types else "full_attention"
+        if kind not in _LAYER_KINDS:
+            raise ValueError(f"unknown layer type {kind!r} "
+                             f"({' | '.join(_LAYER_KINDS)})")
+        return _LAYER_KINDS[kind]
 
     def layer_op(self, i: int) -> str:
-        kind = self.layer_types[i] if self.layer_types else "full_attention"
-        if kind not in ("full_attention", "sliding_attention", "conv"):
-            raise ValueError(f"unknown layer type {kind!r} (full_attention "
-                             f"| sliding_attention | conv)")
-        return "conv" if kind == "conv" else "attention"
+        return self.layer_parts(i)[0]
 
     def layer_window(self, i: int) -> int | None:
         """The window of layer ``i`` (None: a global layer, or a conv)."""
@@ -664,7 +857,9 @@ class TransformerCore(nn.Module):
         return int(self.sliding_window)
 
     def layer_experts(self, i: int) -> int:
-        return 0 if i < self.moe_dense_layers else self.moe_experts
+        if i < self.moe_dense_layers or not self.layer_parts(i)[1]:
+            return 0
+        return self.moe_experts
 
     @nn.compact
     def __call__(self, obs, mask=None, cache=None, t=None, readout_t=None,
@@ -672,7 +867,8 @@ class TransformerCore(nn.Module):
         """Full mode: obs ``[B, T, D]`` -> (logits, v). Decode mode
         (``cache`` = tuple of per-layer states — a (k, v) pair for an
         attention layer (a ring of rows for a windowed one), the last rows
-        of ``B * u`` for a conv layer —,
+        of ``B * u`` for a conv layer, the convolution's rows and the
+        state for a Mamba-2 layer, ``()`` for an FFN alone —,
         ``t`` = position; ``n_valid``: prefill's count of real rows):
         obs is ``[B, 1, D]``; returns ``((logits, v), new_cache)`` for the
         single position. Readout mode (``readout_t`` = dynamic row index):
@@ -699,6 +895,7 @@ class TransformerCore(nn.Module):
                 self.d_model, self.n_heads, self.mlp_ratio, self.attn_fn,
                 self.compute_dtype, moe_experts=self.layer_experts(i),
                 moe_top_k=self.moe_top_k, op=self.layer_op(i),
+                has_ffn=self.layer_parts(i)[1],
                 window=self.layer_window(i), name=f"block_{i}", **kw_i)
 
         def heads(x, mask):
@@ -709,17 +906,21 @@ class TransformerCore(nn.Module):
         x = _embed_obs(
             self, obs, self.d_model, self.max_seq_len,
             start=t if decode else 0,
-            learned_positions=kw.get("rope_theta") is None)
+            learned_positions=(self.positions == "learned"
+                               and kw.get("rope_theta") is None))
         if readout_t is not None:
             idx = jnp.asarray(readout_t, jnp.int32)
             for i in range(self.n_layers - 1):
                 x = block_at(i)(x)
             final = block_at(self.n_layers - 1)
-            if final.moe_experts > 0 and final.op == "attention":
+            if (final.moe_experts > 0 and final.op == "attention"
+                    or final.op == "mamba2"):
                 # The MoE final block keeps its full-window pass (routing
                 # is per token, so the sliced row is what a row-only pass
                 # would give; the shortcut is simply not taken here). A
-                # conv layer takes the row path whatever its FFN.
+                # conv layer takes the row path whatever its FFN, as an FFN
+                # alone does; a Mamba-2 layer's row needs the scan over
+                # every row before it.
                 x = jax.lax.dynamic_slice_in_dim(final(x), idx, 1, axis=1)
             else:
                 x = final(x, readout_idx=idx)
@@ -842,13 +1043,23 @@ def _policy_from_apply(arch: Mapping[str, Any], init_params, apply_fn,
 _BLOCK_ARCH_KEYS = ("norm", "norm_eps", "qk_norm", "use_bias", "ffn", "d_ff",
                     "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch",
                     "n_kv_heads", "conv_taps", "head_dim",
-                    "moe_router_input")
+                    "moe_router_input", "mamba_heads", "mamba_head_dim",
+                    "mamba_state", "mamba_groups", "mamba_conv_taps",
+                    "mamba_chunk")
 # MoEMLP's fields by the arch key that sets each (block field ``moe_kw``)
 _MOE_ARCH_KEYS = {"moe_router": "router", "moe_expert_bias": "expert_bias",
-                  "moe_held": "held"}
+                  "moe_held": "held", "moe_routed_scaling": "routed_scaling",
+                  "moe_shared_d_ff": "shared_d_ff"}
 # the core's own: what kind each layer is
 _LAYER_ARCH_KEYS = ("layer_types", "moe_dense_layers", "sliding_window",
                     "rope_layers")
+# ``layer_types`` entry -> (the layer's operator, whether an FFN follows)
+_LAYER_KINDS = {"full_attention": ("attention", True),
+                "sliding_attention": ("attention", True),
+                "conv": ("conv", True),
+                "mamba2": ("mamba2", False),
+                "attention": ("attention", False),
+                "ffn": ("none", True)}
 
 
 def _block_kwargs(arch: Mapping[str, Any]) -> dict:
@@ -862,8 +1073,9 @@ def _block_kwargs(arch: Mapping[str, Any]) -> dict:
     positions = arch.get("positions", "learned")
     if positions == "rope":
         kw["rope_theta"] = float(arch.get("rope_theta", 10000.0))
-    elif positions != "learned":
-        raise ValueError(f"unknown positions {positions!r} (learned | rope)")
+    elif positions not in ("learned", "none"):
+        raise ValueError(f"unknown positions {positions!r} "
+                         f"(learned | rope | none)")
     return kw
 
 
@@ -891,6 +1103,7 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         moe_dense_layers=int(arch.get("moe_dense_layers", 0)),
         sliding_window=arch.get("sliding_window"),
         rope_layers=tuple(bool(r) for r in arch.get("rope_layers", ())),
+        positions=("none" if arch.get("positions") == "none" else "learned"),
     )
 
 
@@ -908,12 +1121,18 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     conv_back = int(arch.get("conv_taps", 3)) - 1
     cache_dtype = core.compute_dtype
 
+    def mamba(key: str) -> int:     # the arch's, else the block's default
+        return int(arch.get(key, getattr(TransformerBlock, key)))
+
     def init_cache(length: int, batch_size: int = 1):
-        """Zeroed per-layer states for incremental decoding, three kinds
+        """Zeroed per-layer states for incremental decoding, four kinds
         side by side: a (k, v) pair ``[B, length, Hkv, hd]`` for a global
         attention layer, a ring ``[B, min(window, length), Hkv, hd]`` x 2
         for a windowed one (``_ring_cached``), the last ``conv_taps - 1``
-        rows of ``B * u`` ``[B, conv_taps - 1, d]`` for a conv layer."""
+        rows of ``B * u`` ``[B, conv_taps - 1, d]`` for a conv layer, and
+        for a Mamba-2 layer the convolution's last ``mamba_conv_taps - 1``
+        rows of ``xBC`` with the ``[B, H, P, N]`` state in float32 — whose
+        size does not grow with ``length``. An FFN alone keeps nothing."""
         conv = (batch_size, conv_back, core.d_model)
 
         def kv_pair(i: int):
@@ -921,9 +1140,24 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
             kv = (batch_size, rows, n_kv_heads, head_dim)
             return jnp.zeros(kv, cache_dtype), jnp.zeros(kv, cache_dtype)
 
-        return tuple(
-            jnp.zeros(conv, cache_dtype) if core.layer_op(i) == "conv"
-            else kv_pair(i) for i in range(core.n_layers))
+        def mamba_state():
+            heads, width = mamba("mamba_heads"), mamba("mamba_head_dim")
+            state = mamba("mamba_state")
+            xbc = heads * width + 2 * mamba("mamba_groups") * state
+            return (jnp.zeros((batch_size, mamba("mamba_conv_taps") - 1,
+                               xbc), cache_dtype),
+                    jnp.zeros((batch_size, heads, width, state),
+                              jnp.float32))
+
+        def layer_cache(i: int):
+            op = core.layer_op(i)
+            if op == "conv":
+                return jnp.zeros(conv, cache_dtype)
+            if op == "mamba2":
+                return mamba_state()
+            return () if op == "none" else kv_pair(i)
+
+        return tuple(layer_cache(i) for i in range(core.n_layers))
 
     def step_cached(params, rng, cache, obs, t, mask=None):
         """One O(W) decode step: writes position ``t`` into the cache and
@@ -956,11 +1190,11 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         (post-hot-swap path): runs decode mode with T = W queries at
         t=0. Padding rows write garbage K/V beyond the real prefix, which
         later per-step decodes never attend (their causal mask stops at
-        the current t) and overwrite in order. A conv layer's state has
-        no positions to overwrite, and a windowed layer's ring would lose
-        live rows to padding ones: both are taken from the rows before
-        ``n_valid``, the count of real rows (None: the whole window is
-        real)."""
+        the current t) and overwrite in order. A conv layer's state and a
+        Mamba-2 layer's have no positions to overwrite, and a windowed
+        layer's ring would lose live rows to padding ones: all three are
+        taken from the rows before ``n_valid``, the count of real rows
+        (None: the whole window is real)."""
         window = jnp.asarray(window)
         if window.ndim == 2:
             window = window[None]

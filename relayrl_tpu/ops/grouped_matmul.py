@@ -9,6 +9,12 @@ its own — ``relayrl_moe_gmm_fwd`` / ``_dlhs`` / ``_drhs`` — which is the
 name the compiled instruction carries into a device trace (as
 ``relayrl_flash_*``, ops/flash.py).
 
+k and n need not be multiples of 128: the stacks, the rows and the results
+keep the model's published widths and the last tile of such an axis is
+irregular (:func:`_tile`; ``chip_smoke.py`` phase E compares the three
+kernels with ``lax.ragged_dot`` at 2688 x 1856 on the chip,
+``tests/test_flash_tpu_compile.py`` compiles them for a described v5e).
+
 Imported only where an arch asks for the sparse MoE dispatch on a TPU
 (:func:`relayrl_tpu.models.moe.grouped_matmul`): importing
 ``jax.experimental.pallas`` costs about a second that no other model should
@@ -32,19 +38,34 @@ _tgmm = _jit_tgmm.__wrapped__
 # (m, k, n) tile, at most. m = token-slots, so a group boundary costs at
 # most one partly masked tile of 512 rows per expert. k and n take the
 # largest multiple of 128 up to the cap that divides them: 1024 for widths
-# of 1024 and 2048 (OLMoE's), 768 for LFM2's experts of 1536.
+# of 1024 and 2048 (OLMoE's), 768 for LFM2's experts of 1536, 896 for
+# Nemotron-H's hidden size of 2688. A width that NO multiple of 128 divides
+# (Nemotron-H's experts of 1856 = 14.5 x 128) keeps its published size —
+# no padded weight, no padded row — and its last tile hangs over: see _tile.
 TILING = (512, 1024, 1024)
 
 
 def _tile(dim: int, cap: int) -> int:
-    """Largest multiple of 128 up to ``cap`` that divides ``dim`` (0:
-    none — ``dim`` is no multiple of 128)."""
-    return next((t for t in range(min(cap, dim) // 128 * 128, 0, -128)
-                 if dim % t == 0), 0)
+    """The tile of a k or n axis ``dim`` long: the largest multiple of 128
+    up to ``cap`` that divides ``dim``. Where ``dim`` is no multiple of 128
+    none does, and the tile is the multiple of 128 up to ``cap`` whose
+    whole tiles overshoot ``dim`` by least, the largest of those (640 for
+    1856: three tiles cover 1920): megablox takes an irregular last tile,
+    zeroing its tail where the axis is contracted (k) and dropping the
+    columns past the array's edge where it is the result's (n), so the
+    result is the exact product at the published width. 0 under 128: no
+    tile."""
+    tiles = range(min(cap, dim) // 128 * 128, 0, -128)
+    exact = next((t for t in tiles if dim % t == 0), 0)
+    if exact or not tiles:
+        return exact
+    return min(tiles, key=lambda t: (-(-dim // t) * t, -t))
 
 
 def fits(m: int, k: int, n: int) -> bool:
-    """Whether the kernels tile these shapes (else: ``lax.ragged_dot``)."""
+    """Whether the kernels take these shapes (else: ``lax.ragged_dot``):
+    whole row tiles, and k and n of at least one 128-wide tile each — a
+    multiple of 128 or not (:func:`_tile`)."""
     tm, tk, tn = TILING
     return m % tm == 0 and _tile(k, tk) > 0 and _tile(n, tn) > 0
 

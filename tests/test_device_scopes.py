@@ -34,6 +34,7 @@ from relayrl_tpu.ops.scopes import (
     FFN,
     HEADS,
     LOSS,
+    MAMBA_CONV_NAME,
     MOE_ELEMENTWISE,
     MOE_ROUTE,
     MOE_ROWS,
@@ -41,6 +42,7 @@ from relayrl_tpu.ops.scopes import (
     OP_PROJ,
     OPTIMIZER,
     SHORT_CONV_NAME,
+    SSD_NAME,
     VTRACE,
 )
 
@@ -50,6 +52,8 @@ SEQ = {"obs_dim": 6, "act_dim": 3, "d_model": 16, "n_heads": 2,
 EVERY_UPDATE = (OPTIMIZER, VTRACE, LOSS, HEADS)
 TRUNK = EVERY_UPDATE + (EMBED, OP_PROJ)
 MOE = (MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE)
+# plain XLA operators that keep a name of their own, as the kernels do
+OWN_NAMES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME)
 # family -> (arch, the scopes its update uses, observation width)
 FAMILIES = {
     # the GPT-2 shaped block (gpt2m-policy)
@@ -73,6 +77,19 @@ FAMILIES = {
                   "qk_norm": "head", "use_bias": False, "ffn": "swiglu",
                   "moe_router_input": "layer"},
                  TRUNK + MOE + (FFN, SHORT_CONV_NAME)),
+    # layers of one part each: Mamba-2 (a scan over two chunks), attention
+    # alone, relu2 experts beside a shared expert (nemotron-twotower-policy)
+    "one_part": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 4,
+                  "n_heads": 4, "n_kv_heads": 1, "head_dim": 8,
+                  "layer_types": ["mamba2", "ffn", "attention", "ffn"],
+                  "mamba_heads": 4, "mamba_head_dim": 8, "mamba_state": 8,
+                  "mamba_groups": 2, "mamba_chunk": 4, "moe_experts": 8,
+                  "moe_top_k": 2, "moe_held": [2, 4], "moe_d_ff": 12,
+                  "moe_router": "sigmoid", "moe_expert_bias": True,
+                  "moe_routed_scaling": 2.5, "moe_shared_d_ff": 24,
+                  "norm": "rms", "positions": "none", "use_bias": False,
+                  "ffn": "relu2"},
+                 TRUNK + MOE + (FFN, SSD_NAME, MAMBA_CONV_NAME)),
     # the pixel learner (nature-cnn)
     "cnn": ({"kind": "cnn_discrete", "obs_shape": [36, 36, 2],
              "obs_dim": 36 * 36 * 2, "act_dim": 3},
@@ -83,7 +100,7 @@ FAMILIES = {
 NO_BACKWARD = (OPTIMIZER, VTRACE, OBS_PREP)
 # Share of a compiled update's instructions that carry an ``op_name`` (XLA's
 # own expansions carry none) under a relayrl_ name, at least. Read 0.93-0.99
-# over the four families: what is left is the attention itself (XLA
+# over the five families: what is left is the attention itself (XLA
 # operations here, a kernel of its own name on the chip), the MoE load
 # statistics and the step counter.
 SCOPED_FLOOR = 0.9
@@ -156,7 +173,7 @@ def test_one_list_of_names():
     assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 12
     assert not set(DEVICE_SCOPES) & set(scopes.KERNEL_SCOPES)
     used = {scope for _family, scope in USES}
-    assert used == set(DEVICE_SCOPES) | {SHORT_CONV_NAME}
+    assert used == set(DEVICE_SCOPES) | set(OWN_NAMES)
     program = "".join(p.read_text() for p in (REPO / "relayrl_tpu").rglob(
         "*.py") if p.name != "scopes.py")
     # no scope is spelled out where it is opened
@@ -166,7 +183,7 @@ def test_one_list_of_names():
 @pytest.mark.parametrize("family,scope", USES)
 def test_scope_reaches_forward_and_backward(paths, family, scope):
     mine = [p for p in paths(family) if scope in _part_names(p)
-            or (scope == SHORT_CONV_NAME and scope in p)]
+            or (scope in OWN_NAMES and scope in p)]
     forward = [p for p in mine if "transpose(" not in p]
     backward = [p for p in mine if "transpose(" in p]
     assert forward, f"{scope} is on no forward operation of {family}"
@@ -182,7 +199,7 @@ def test_scope_reaches_forward_and_backward(paths, family, scope):
 @pytest.mark.parametrize("family", FAMILIES)
 def test_no_other_part_in_this_family(paths, family):
     found = set().union(*(_part_names(p) for p in paths(family)))
-    assert found == set(FAMILIES[family][1]) - {SHORT_CONV_NAME}
+    assert found == set(FAMILIES[family][1]) - set(OWN_NAMES)
 
 
 @pytest.mark.parametrize("family", FAMILIES)

@@ -151,3 +151,86 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
     # sum over k that follows it: nothing else is N*k rows of d
     assert len(set(re.findall(rf"\w+\[{n * k},{d}\]", text))) <= 1
     assert f"f32[{k},{n},{d}]" not in text and f"f32[{n * k},{d}]" not in text
+
+
+def test_held_experts_of_a_width_no_128_divides_compile_for_v5e(
+        one_chip, monkeypatch):
+    """The held-experts layer at ``nemotron-twotower-policy``'s widths:
+    hidden 2688, ``relu^2`` experts of 1856 = 14.5 x 128 (two stacks), 8 of
+    128 held, top-6, a quarter of its tokens. The grouped matmuls are the
+    Mosaic kernels — eight a layer, the forward's two again in the backward
+    —, NOT ``lax.ragged_dot``: the last of three 640-wide tiles hangs over
+    the stacks' edge, and the stacks, the rows and the results keep the
+    published width (no array 1920 or 2048 wide)."""
+    import collections
+    import re
+
+    from relayrl_tpu.models import moe
+    from relayrl_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, ff, k = 4096, 2688, 1856, 6
+    assert grouped_matmul._tiling(d, ff) == (512, 896, 640)
+    assert grouped_matmul._tiling(ff, d) == (512, 640, 896)
+    rows = moe.row_buffer(n * k, 8, 128)
+    assert rows == 3072 and grouped_matmul.fits(rows, d, ff)
+    # a width under one tile, or a handful of decode rows, stays with XLA
+    assert not grouped_matmul.fits(rows, 64, ff)
+    assert not grouped_matmul.fits(k, d, ff)
+    layer = moe.MoEMLP(d, ff, 128, k, jnp.bfloat16, ffn="relu2",
+                       use_bias=False, router="sigmoid", expert_bias=True,
+                       held=(0, 8), routed_scaling=2.5, shared_d_ff=3712)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    x = jnp.zeros((1, n, d), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    assert params["params"]["moe_w_up"].shape == (8, d, ff)
+    try:
+        compiled = jax.jit(jax.value_and_grad(
+            lambda p, x: jnp.sum(layer.apply(p, x).astype(jnp.float32)),
+            (0, 1))).lower(on_chip(params), on_chip(x)).compile()
+    finally:  # traces made under the answer "tpu" stay in this test
+        moe._shared_experts.clear_cache()
+        moe._shared_experts_vjp.clear_cache()
+    text = compiled.as_text()
+    assert "ragged" not in text
+    assert text.count("tpu_custom_call") == 8
+    names = collections.Counter(
+        re.sub(r"[._]\d+$", "", name)
+        for name in re.findall(r"%(\S*relayrl_moe_gmm\S*) = ", text))
+    assert names == {"relayrl_moe_gmm_fwd": 4, "relayrl_moe_gmm_dlhs": 2,
+                     "relayrl_moe_gmm_drhs": 2}
+    assert f"bf16[{rows},{ff}]" in text
+    assert not re.findall(r"\[(?:\d+,)*(?:1920|2048)\]", text)
+    # the shared expert is dense matmuls under the dense FFN's part
+    assert re.search(rf'op_name="[^"]*/{scopes.FFN}/[^"]*dot_general',
+                     text)
+
+
+def test_the_state_space_scan_compiles_for_v5e(one_chip):
+    """``ops/ssd.py`` at ``nemotron-twotower-policy.update``'s shape — two
+    8192-token episodes, 64 heads of 64, a state of 128, 8 groups, chunks of
+    128 —, forward and every gradient: plain XLA under its own name in both
+    directions, one group's score tiles alive at a time (a whole layer's
+    would be 0.27 GB an array and a dozen arrays)."""
+    from relayrl_tpu.ops.ssd import ssd
+
+    b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    args = (S((b, t, h, p), jnp.bfloat16), S((b, t, h), jnp.float32),
+            S((h,), jnp.float32), S((b, t, g, n), jnp.bfloat16),
+            S((b, t, g, n), jnp.bfloat16), S((h,), jnp.float32))
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(ssd(*a)[0].astype(jnp.float32)),
+        argnums=tuple(range(6)))).lower(*args).compile()
+    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    mine = [path for path in paths if scopes.SSD_NAME in path]
+    assert [path for path in mine if "transpose(" in path]
+    assert [path for path in mine if "transpose(" not in path]
+    # arguments, cotangents and one group's intermediates: under 1.5 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -958,3 +958,189 @@ class TestRouterInputAndReGLU:
         with pytest.raises(ValueError, match="moe_router_input"):
             build_policy({**arch, "moe_router_input": "embedding"}
                          ).init_params(jax.random.PRNGKey(0))
+
+
+# -- experts without a gate, the routed weights' factor, the shared expert ---
+
+def _relu2_layer(held, dispatch="sparse", e=16, k=6, scaling=2.5,
+                 shared=24, ffn="relu2"):
+    from relayrl_tpu.models.moe import MoEMLP
+
+    return MoEMLP(_D, _FF, e, k, jnp.float32, norm_topk_prob=True, ffn=ffn,
+                  dispatch=dispatch, use_bias=False, router="sigmoid",
+                  expert_bias=True, held=held, routed_scaling=scaling,
+                  shared_d_ff=shared)
+
+
+def _relu2_params(seed=0, **kw):
+    x = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (2, _N // 2, _D)), jnp.float32)
+    return _relu2_layer(None, **kw).init(jax.random.PRNGKey(seed), x), x
+
+
+def _relu2_share(params, first, count):
+    p = dict(params["params"])
+    for name in ("moe_w_up", "moe_w_down"):
+        p[name] = p[name][first:first + count]
+    return {"params": p}
+
+
+class TestReLU2ScalingAndTheSharedExpert:
+    def test_the_layer_by_hand(self):
+        """``y = 2.5 sum_chosen w_e W_down,e relu(W_up,e x)^2 + W_down,s
+        relu(W_up,s x)^2`` with ``w`` the chosen experts' unbiased sigmoid
+        scores over their sum."""
+        params, x = _relu2_params()
+        p = params["params"]
+        assert set(p) == {"moe_gate", "moe_expert_bias", "moe_w_up",
+                          "moe_w_down", "moe_shared_up", "moe_shared_down"}
+        tokens = x.reshape(-1, _D)
+        s = jax.nn.sigmoid(tokens @ p["moe_gate"]["kernel"])
+        idx = np.argsort(-np.asarray(s + p["moe_expert_bias"]), -1)[:, :6]
+        picked = np.take_along_axis(np.asarray(s), idx, -1)
+        w = 2.5 * picked / (picked.sum(-1, keepdims=True) + 1e-6)
+        relu2 = lambda a: jnp.square(jax.nn.relu(a))
+        want = relu2(tokens @ p["moe_shared_up"]["kernel"]) @ p[
+            "moe_shared_down"]["kernel"]
+        for j in range(6):
+            up = jnp.einsum("nd,ndf->nf", tokens, p["moe_w_up"][idx[:, j]])
+            want = want + w[:, j:j + 1] * jnp.einsum(
+                "nf,nfd->nd", relu2(up), p["moe_w_down"][idx[:, j]])
+        for dispatch in DISPATCHES:
+            got = _relu2_layer(None, dispatch).apply(params, x)
+            np.testing.assert_allclose(got.reshape(-1, _D), want, atol=2e-5,
+                                       rtol=1e-5, err_msg=dispatch)
+
+    @pytest.mark.parametrize("ffn,stacks", [
+        ("relu2", {"moe_w_up", "moe_w_down"}),
+        ("gelu", {"moe_w_up", "moe_w_down"}),
+        ("reglu", {"moe_w_gate", "moe_w_up", "moe_w_down"})])
+    def test_the_shared_expert_is_of_the_experts_kind(self, ffn, stacks):
+        params, _ = _relu2_params(ffn=ffn)
+        p = params["params"]
+        assert {n for n in p if n.startswith("moe_w_")} == stacks
+        assert ("moe_shared_gate" in p) == ("moe_w_gate" in p)
+        assert p["moe_shared_up"]["kernel"].shape == (_D, 24)
+        assert "bias" not in p["moe_shared_up"]
+
+    def test_scaling_multiplies_the_routed_sum_alone(self):
+        params, x = _relu2_params()
+        no_shared = {"params": {k: v for k, v in params["params"].items()
+                                if "shared" not in k}}
+        routed = _relu2_layer(None, shared=None, scaling=1.0).apply(
+            no_shared, x)
+        shared = _relu2_layer(None, scaling=1.0).apply(params, x) - routed
+        assert float(jnp.abs(shared).max()) > 1e-3
+        np.testing.assert_allclose(
+            _relu2_layer(None, scaling=2.5).apply(params, x),
+            2.5 * routed + shared, atol=2e-5, rtol=1e-5)
+        # the factor touches the weights alone, never the choice
+        def sown(scaling):
+            _, state = _relu2_layer(None, scaling=scaling).apply(
+                params, x, mutable=["intermediates"])
+            return np.asarray(state["intermediates"]["expert_load"][0])
+
+        assert (sown(2.5) == sown(1.0)).all()
+
+    @pytest.mark.parametrize("held", [None, (0, 8), (5, 3)])
+    def test_sparse_matches_dense_forward_and_every_gradient(self, held):
+        """relu^2 experts, the 2.5 and the shared expert under the held
+        layer's own backward (its pass loop, its recomputed buffers)
+        against the dense form: forward, loss and EVERY gradient — tokens,
+        router, both stacks, the shared expert's two matrices."""
+        params, x = _relu2_params()
+        share = params if held is None else _relu2_share(params, *held)
+
+        def loss(dispatch):
+            def f(p, x):
+                y = _relu2_layer(held, dispatch).apply(p, x)
+                return jnp.sum(jnp.sin(y) * x), y
+            return f
+
+        (ls, ys), gs = jax.value_and_grad(
+            loss("sparse"), (0, 1), has_aux=True)(share, x)
+        (ld, yd), gd = jax.value_and_grad(
+            loss("dense"), (0, 1), has_aux=True)(share, x)
+        np.testing.assert_allclose(ys, yd, atol=2e-5, rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            name = jax.tree_util.keystr(path)
+            np.testing.assert_allclose(a, b, atol=3e-5, rtol=1e-4,
+                                       err_msg=name)
+            assert (float(jnp.abs(a).max()) > 0) != (
+                "moe_expert_bias" in name), name
+
+    @pytest.mark.parametrize("passes", [1, 2])
+    def test_row_buffers_walked_in_passes_with_rows_never_written(
+            self, monkeypatch, passes):
+        """... and with the buffers a pass or two long, the rows the
+        kernels leave unwritten holding NaN: ``relu(NaN)^2`` is NaN, and
+        none reaches the result or a gradient."""
+        held, e, k = (5, 3), 16, 6
+        params, x = _relu2_params()
+        share = _relu2_share(params, *held)
+        _, state = _relu2_layer(held, "dense").apply(
+            share, x, mutable=["intermediates"])
+        live = int(state["intermediates"]["expert_load"][0].sum())
+        rows = {1: live + 3, 2: -(-live // 2)}[passes]
+        _row_buffer_of(monkeypatch, rows, _N * k, held[1], e)
+        _poison_unwritten_rows(monkeypatch)
+
+        def loss(dispatch):
+            def f(p, x):
+                y, state = _relu2_layer(held, dispatch).apply(
+                    p, x, mutable=["intermediates"])
+                return jnp.sum(jnp.sin(y) * x), state["intermediates"]
+            return f
+
+        (ls, sown), gs = jax.value_and_grad(
+            loss("sparse"), (0, 1), has_aux=True)(share, x)
+        (ld, _), gd = jax.value_and_grad(
+            loss("dense"), (0, 1), has_aux=True)(share, x)
+        assert int(sown["row_passes"][0]) == passes
+        np.testing.assert_allclose(float(ls), float(ld), rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(a, b, atol=3e-5, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+
+    @pytest.mark.parametrize("chips", [2, 16])
+    def test_the_shares_and_the_shared_expert_once_add_up(self, chips):
+        """Every chip of a layer computes the shared expert alike: the
+        chips' ROUTED shares and the shared expert counted once are the
+        layer."""
+        params, x = _relu2_params()
+        whole = _relu2_layer(None).apply(params, x)
+        per = 16 // chips
+        no_shared = lambda p: {"params": {
+            k: v for k, v in p["params"].items() if "shared" not in k}}
+        routed = sum(_relu2_layer((c * per, per), shared=None).apply(
+            no_shared(_relu2_share(params, c * per, per)), x)
+            for c in range(chips))
+        with_shared = _relu2_layer((0, per)).apply(
+            _relu2_share(params, 0, per), x)
+        once = with_shared - _relu2_layer((0, per), shared=None).apply(
+            no_shared(_relu2_share(params, 0, per)), x)
+        np.testing.assert_allclose(routed + once, whole, atol=3e-5,
+                                   rtol=1e-5)
+
+    def test_an_unknown_ffn_is_refused_and_the_arch_sets_the_fields(self):
+        with pytest.raises(ValueError, match="unknown ffn"):
+            _policy_params(ffn="relu3")
+        policy, params = _policy_params(
+            ffn="relu2", moe_routed_scaling=2.5, moe_shared_d_ff=24,
+            moe_d_ff=12, moe_router="sigmoid", use_bias=False)
+        moe = params["params"]["block_0"]["moe"]
+        assert moe["moe_shared_up"]["kernel"].shape == (16, 24)
+        assert "moe_w_gate" not in moe
+        other, _ = _policy_params(
+            ffn="relu2", moe_routed_scaling=1.0, moe_shared_d_ff=24,
+            moe_d_ff=12, moe_router="sigmoid", use_bias=False)
+        obs = jnp.asarray(np.random.default_rng(0).standard_normal(
+            (1, 8, 6)), jnp.float32)
+        act = jnp.zeros((1, 8), jnp.int32)
+        assert not np.allclose(policy.evaluate(params, obs, act)[2],
+                               other.evaluate(params, obs, act)[2],
+                               atol=1e-4)
