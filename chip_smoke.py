@@ -904,6 +904,60 @@ def phase_f() -> None:
         f"{time.monotonic() - t0:.0f}s")
 
 
+def phase_g() -> None:
+    """The Mamba-2 scan at ``nemotron-twotower-policy.update``'s shape —
+    two 8192-token episodes, 64 heads of 64, a state of 128, 8 groups,
+    chunks of 128, bfloat16, from a carried state — through the Pallas
+    kernels (``ops/ssd_pallas.py``: ``ssd_fwd``, and under a random
+    cotangent of both results ``ssd_states`` + ``ssd_bwd``) and through the
+    plain form (``ops/ssd.ssd_xla``) on the same operands: ``y``, the last
+    state and the gradients of all six arguments and of the carried state,
+    each within 2^-6 of the plain form's largest entry (both round their
+    matmuls' operands to bfloat16; phases B" / E's limit). ``ssd()`` itself
+    has to pick the kernels here, and says so."""
+    import jax
+    import jax.numpy as jnp
+
+    from relayrl_tpu.ops import ssd as scan
+
+    t0 = time.monotonic()
+    b, T, H, P, G, N, chunk = 2, 8192, 64, 64, 8, 128, 128
+    check(scan.backend(T, H, P, G, N, chunk) == scan.PALLAS,
+          f"G: ssd() would run {scan.backend(T, H, P, G, N, chunk)} at heads "
+          f"{H} x {P}, groups {G}, state {N}, chunk {chunk} on a TPU")
+    keys = jax.random.split(jax.random.PRNGKey(40), 9)
+    lo = jnp.bfloat16
+    args = (jax.random.normal(keys[0], (b, T, H, P), lo),
+            jax.random.uniform(keys[1], (b, T, H), jnp.float32, 0.001, 0.1),
+            -jax.random.uniform(keys[2], (H,), jnp.float32, 1.0, 16.0),
+            jax.random.normal(keys[3], (b, T, G, N), lo),
+            jax.random.normal(keys[4], (b, T, G, N), lo),
+            jax.random.normal(keys[5], (H,), jnp.float32),
+            jax.random.normal(keys[6], (b, H, P, N), jnp.float32))
+    cotangents = (jax.random.normal(keys[7], (b, T, H, P), lo),
+                  jax.random.normal(keys[8], (b, H, P, N), jnp.float32))
+
+    def both_ways(fn):
+        @jax.jit
+        def run(args, cotangents):
+            out, vjp = jax.vjp(
+                lambda *a: fn(*a[:6], chunk=chunk, state=a[6]), *args)
+            return (*out, *vjp(cotangents))
+        return run(args, cotangents)
+
+    names = ("y", "last", "dx", "ddt", "dA", "dB", "dC", "dD", "dstate")
+    errs = dict(zip(names, map(differ, both_ways(scan.ssd),
+                               both_ways(scan.ssd_xla))))
+    for what, err in errs.items():
+        check(err <= 2.0 ** -6,
+              f"G: the scan kernels' {what} differs from the plain form's "
+              f"by {err:.3g} of its largest entry (limit 2^-6)")
+    say(f"G: ok — ssd_fwd / ssd_states / ssd_bwd against the plain form at "
+        f"{(b, T, H, P)} state {N} groups {G} chunk {chunk} bfloat16: "
+        f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}, "
+        f"{time.monotonic() - t0:.0f}s")
+
+
 # --------------------------------------------------------------------------
 
 def main() -> None:
@@ -969,6 +1023,7 @@ def run(dev: dict, t_start: float) -> None:
     phase_c(bundle)
     phase_e()
     phase_f()
+    phase_g()
 
     say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
         f"the persistent cache, {compiles.requests - compiles.hits} compiled "

@@ -149,6 +149,12 @@ class Policy:
     # (head_dim 64), or ``"head-major"`` (``[B * H, T, D]``, the head axis
     # transposed out of the lanes round the kernels).
     attention_layout: Mapping[tuple, str] | None = None
+    # Sequence policies with Mamba-2 layers: ``{(T, heads, head_dim, state,
+    # dtype): "ssd_pallas" | "ssd_xla"}`` for every scan shape traced so far
+    # (models/transformer._resolve_scan) — whether ``ops/ssd.py`` ran the
+    # Pallas kernels (a TPU, shapes that tile) or plain XLA. Empty for a
+    # trunk without such layers, None for other families.
+    scan_backends: Mapping[tuple, str] | None = None
     # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
     # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward
     # (``moe_load_max`` / ``moe_load_min``: models/moe.load_extremes) for

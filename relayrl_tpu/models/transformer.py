@@ -112,6 +112,7 @@ from relayrl_tpu.ops.scopes import (  # noqa: F401  (SHORT_CONV_NAME's home)
     OP_PROJ,
     SHORT_CONV_NAME,
 )
+from relayrl_tpu.ops.ssd import backend as ssd_backend
 from relayrl_tpu.ops.ssd import ssd, ssd_step
 
 # the one activation a Mamba-2 layer's checkpoint keeps (_mamba_layer)
@@ -257,6 +258,33 @@ def _resolve_attention(arch: Mapping[str, Any]
             return make_ring_attention(mesh)(q, k, v)
         return ring_or_local, resolved, score_area, layout
     raise ValueError(f"unknown attention kind {kind!r}")
+
+
+def _resolve_scan() -> tuple[Callable, dict]:
+    """``(scan_fn, resolved)``: the Mamba-2 layers' scan, ``ops.ssd.ssd``
+    behind a record of what it ran as. Platform and shape pick the
+    implementation at trace time (``ops.ssd.backend``: the Pallas kernels
+    on a TPU where the shapes tile, plain XLA on CPU actor hosts, in CI and
+    for a shape that does not tile), and as with ``attention: "flash"`` the
+    choice is never silent: ``resolved`` (``Policy.scan_backends``) maps
+    every traced ``(T, heads, head_dim, state, dtype)`` to ``ssd_pallas`` |
+    ``ssd_xla``, and each new entry prints one ``[scan]`` line naming the
+    platform it was resolved on."""
+    resolved: dict[tuple, str] = {}
+
+    def scan_fn(x, dt, a, b, c, skip, chunk, state):
+        key = (int(x.shape[1]), int(x.shape[2]), int(x.shape[3]),
+               int(b.shape[3]), x.dtype.name)
+        ran = ssd_backend(*x.shape[1:], *b.shape[2:], chunk)
+        if resolved.get(key) != ran:
+            resolved[key] = ran
+            print(f"[scan] T={key[0]} heads={key[1]} head_dim={key[2]} "
+                  f"state={key[3]} groups={b.shape[2]} chunk={chunk} "
+                  f"{key[4]} -> {ran} (platform {jax.default_backend()})",
+                  flush=True)
+        return ssd(x, dt, a, b, c, skip, chunk, state)
+
+    return scan_fn, resolved
 
 
 def _norm(arch_norm: str, eps, name: str):
@@ -428,6 +456,9 @@ class TransformerBlock(nn.Module):
     mamba_groups: int = 1
     mamba_conv_taps: int = 4
     mamba_chunk: int = 128
+    # The scan a Mamba-2 layer runs: ops.ssd.ssd, or the policy's recording
+    # wrapper of it (_resolve_scan).
+    scan_fn: Callable = ssd
     # Grouped-query heads: n_kv_heads k/v heads under n_heads query heads,
     # all d_model // n_heads wide (separate q_proj / k_proj / v_proj).
     # None: one fused qkv, as always. qk_norm "head": RMSNorm over each head.
@@ -725,16 +756,24 @@ def _mamba_layer(block: TransformerBlock, x, cache, n_valid):
                                 c_in[:, 0], skip, state)
             y = y[:, None]
         else:
-            y, state = ssd(xs, dt, a, b_in, c_in, skip, block.mamba_chunk,
-                           state)
-        y = checkpoint_name(y, _SSD_OUT)
+            y, state = block.scan_fn(xs, dt, a, b_in, c_in, skip,
+                                     block.mamba_chunk, state)
+        # named (and kept) with the heads side by side in the lanes, as the
+        # scan's kernels write it: a [..., H, 64] view between them and the
+        # norm is turned T-minor and back, 0.4 GB a layer (PERF.md section
+        # 6, PR 40)
+        y = checkpoint_name(y.reshape(Bsz, T, inner), _SSD_OUT)
         with jax.named_scope(OP_PROJ):
-            # the gate BEFORE the norm, the norm over each group's columns
-            g = (y.reshape(Bsz, T, inner).astype(f32)
-                 * nn.silu(z.astype(f32))).reshape(Bsz, T, G, inner // G)
-            g = g * jax.lax.rsqrt(
-                jnp.mean(jnp.square(g), -1, keepdims=True) + eps)
-            y = (g.reshape(Bsz, T, inner) * scale).astype(cd)
+            # the gate BEFORE the norm, the norm over each group's columns:
+            # a group's lane-aligned slice at a time, no [..., G, inner / G]
+            # view of the rows (that view splits the lanes, and XLA copies
+            # 0.27 GB in float32 to make it, three times a layer)
+            g = y.astype(f32) * nn.silu(z.astype(f32))
+            g = jnp.concatenate(
+                [cols * jax.lax.rsqrt(
+                    jnp.mean(jnp.square(cols), -1, keepdims=True) + eps)
+                 for cols in jnp.split(g, G, axis=-1)], axis=-1)
+            y = (g * scale).astype(cd)
             return jnp.dot(y, w_out.astype(cd)), padded, state
 
     with jax.named_scope(OP_PROJ):
@@ -744,8 +783,8 @@ def _mamba_layer(block: TransformerBlock, x, cache, n_valid):
         # 10,304-wide projection, the convolution's rows, the gate and the
         # norm in float32: 1.7 GB a layer at 16,384 tokens) are made again
         # in the backward from the normed rows; of them only the scan's
-        # output is kept, so that the scan runs twice (its own checkpoint,
-        # ops/ssd.py) and not three times.
+        # output is kept, so that the backward runs the scan's backward
+        # alone and never its forward a second time (ops/ssd.py).
         y, _, _ = jax.checkpoint(
             mix, policy=jax.checkpoint_policies.save_only_these_names(
                 _SSD_OUT))(h, weights, None, None, None)
@@ -1080,7 +1119,8 @@ def _block_kwargs(arch: Mapping[str, Any]) -> dict:
 
 
 def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
-               attn_fn: Callable | None = None) -> TransformerCore:
+               attn_fn: Callable | None = None,
+               scan_fn: Callable = ssd) -> TransformerCore:
     """Arch -> TransformerCore module (shared by the policy builders and
     diagnostics like :func:`relayrl_tpu.models.moe.expert_utilization`,
     which re-applies the same module with captured intermediates)."""
@@ -1098,7 +1138,8 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         compute_dtype=_compute_dtype(arch),
         moe_experts=moe_experts,
         moe_top_k=int(arch.get("moe_top_k", 2)),
-        block_kw=flax.core.FrozenDict(_block_kwargs(arch)),
+        block_kw=flax.core.FrozenDict(
+            {**_block_kwargs(arch), "scan_fn": scan_fn}),
         layer_types=tuple(arch.get("layer_types", ())),
         moe_dense_layers=int(arch.get("moe_dense_layers", 0)),
         sliding_window=arch.get("sliding_window"),
@@ -1111,7 +1152,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     obs_dim = int(arch["obs_dim"])
     attn_fn, attention_backends, score_area, attn_layout = (
         _resolve_attention(arch))
-    core = _make_core(arch, moe_experts, attn_fn)
+    scan_fn, scan_backends = _resolve_scan()
+    core = _make_core(arch, moe_experts, attn_fn, scan_fn)
 
     def init_params(rng):
         return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
@@ -1233,6 +1275,7 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                        attention_backends=attention_backends,
                        attention_score_area_pct=score_area,
                        attention_layout=attn_layout,
+                       scan_backends=scan_backends,
                        evaluate_stats=evaluate_stats)
 
 

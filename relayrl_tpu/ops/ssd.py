@@ -12,20 +12,17 @@ scalar a head. Nothing else under ``ops/`` recurs over T
 (:mod:`relayrl_tpu.ops.recurrence` is V-trace's scalar recursion).
 
 :func:`ssd` evaluates it a chunk of ``chunk`` tokens at a time (Dao & Gu
-2024, "Transformers are SSMs", the block decomposition of section 6), every
-chunk at once, one group's heads after another:
+2024, "Transformers are SSMs", the block decomposition of section 6):
 
 * **inside a chunk**, the quadratic form: ``y_i += sum_{j <= i} (C_i . B_j)
   exp(cs_i - cs_j) dt_j x_j`` with ``cs`` the running sum of ``dt A``
-  inside the chunk — two batched matmuls over ``[chunk, chunk]`` score
-  tiles (``C B^T`` a group, scores times ``x`` a head), the work the MXU
-  takes;
+  inside the chunk — ``[chunk, chunk]`` score tiles (``C B^T`` a group,
+  scores times ``x`` a head), the work the MXU takes;
 * **a chunk's own state**, ``sum_j exp(cs_last - cs_j) dt_j x_j (x) B_j``,
   one matmul a head over the chunk;
-* **across chunks**, ``S_{c+1} = exp(cs_last,c) S_c + state_c``: ONE
-  ``lax.scan`` over the ``T / chunk`` chunks whose step is a multiply-add
-  on the heads' ``[P, N]`` states (64 steps at T 8192) — the only part
-  sequential in T, no Python loop over the chunks in the trace;
+* **across chunks**, ``S_{c+1} = exp(cs_last,c) S_c + state_c``: a
+  multiply-add on the heads' ``[P, N]`` states, the only part sequential in
+  T (64 steps at T 8192), no Python loop over the chunks in the trace;
 * **the carried state's part**, ``y_i += exp(cs_i) (S_c C_i)``, one matmul
   a head.
 
@@ -44,21 +41,37 @@ need nothing: the recurrence is causal, a real row never sees a later one
 ``n`` rows zeroes ``dt`` from row ``n`` on (``models/transformer.py``'s
 prefill).
 
-**One group a step** (``lax.map`` over the ``G`` groups, a group's ``H /
-G`` heads together with the group's own ``B`` and ``C``): the score tiles
-of every head and chunk and the per-chunk states are 0.27 GB each in
-bfloat16 and 0.27 to 0.54 GB in float32 a layer at 16,384 tokens, and a
-dozen such arrays live at once in the backward; a group's are an eighth
-(the whole update of ``nemotron-twotower-policy.update`` compiled for a
-v5e: 14.36 GB with a layer's heads at once, 13.51 a group at a time).
+**Two forms of the same five lines, picked by what the code can observe**
+(:func:`backend`; no arch key, no environment variable, no switch):
 
-**Backward: autodiff under ``jax.checkpoint``**, not a hand-written
-``custom_vjp``: a group's intermediates are made again in the backward from
-its arguments, which is all the forward keeps (the six arguments, 0.2 GB a
-layer); differentiating the five lines of algebra by hand would restate
-them once more for no byte saved. Plain XLA under one named scope,
-``relayrl_ssd`` (``ops/scopes.py``): a Pallas kernel is a later issue that
-starts from the benchmark's ``ssd_roofline``.
+* ``ssd_pallas`` — on a TPU, for shapes that tile (eight heads of a group
+  a grid step, whole heads a 128-lane block, a chunk of 128 or 256, a state
+  of whole lane tiles: :func:`relayrl_tpu.ops.ssd_pallas.fits`) and at
+  least one whole chunk of rows: the Pallas kernels of
+  :mod:`relayrl_tpu.ops.ssd_pallas`, a grid over (sequence, eight heads,
+  chunk) with the chunk axis sequential, the score tiles and the carried
+  state in VMEM, a hand-written backward
+  (``jax.custom_vjp``: the chunk-start states made again by one kernel, the
+  reverse sweep by another). ``nemotron-twotower-policy.update`` runs them
+  (PERF.md section 6, PR 40: 91.9 ms an update of plain XLA at 5.4 % of its
+  roofline before them).
+* ``ssd_xla`` (:func:`ssd_xla`) — everywhere else (CPU actor hosts, CI, a
+  shape that does not tile, the single row ``init`` traces) and the
+  reference the kernels' tests hold them to: plain XLA, every chunk at once, **one group a step** of a ``lax.map``
+  (a group's ``H / G`` heads together with the group's own ``B`` and
+  ``C``: the score tiles of every head and chunk and the per-chunk states
+  are 0.27 GB each in bfloat16 and 0.27 to 0.54 GB in float32 a layer at
+  16,384 tokens, and a dozen such arrays live at once in the backward; a
+  group's are an eighth), the chunks' states carried by ONE ``lax.scan``,
+  **backward by autodiff under ``jax.checkpoint``** (a group's
+  intermediates are made again from its arguments, which is all the
+  forward keeps).
+
+Both sit under one named scope, ``relayrl_ssd`` (``ops/scopes.py``), and no
+deeper ``relayrl_`` name: the benchmark's ``ssd_ms`` / ``ssd_roofline`` read
+the exact scope. ``models/transformer._resolve_scan`` records which form a
+policy's scans ran as (``Policy.scan_backends``) and prints one ``[scan]``
+line a shape.
 
 :func:`ssd_step` is the recurrence's one step, what a cached decode runs.
 """
@@ -71,6 +84,9 @@ import jax
 import jax.numpy as jnp
 
 from relayrl_tpu.ops.scopes import SSD_NAME
+
+# what a scan ran as (``backend``; ``Policy.scan_backends``)
+PALLAS, XLA = "ssd_pallas", "ssd_xla"
 
 
 @functools.partial(jax.checkpoint, static_argnums=(1,))
@@ -122,12 +138,9 @@ def _group(args, chunk: int):
     return y.astype(cd), last
 
 
-def ssd(x, dt, A, B, C, D, chunk: int = 128, state=None):
-    """``x [b, T, H, P]``, step sizes ``dt [b, T, H]`` (positive, as they
-    enter the recurrence), ``A [H]`` (negative), ``B, C [b, T, G, N]``,
-    ``D [H]``, ``state [b, H, P, N]`` float32 (None: zeros, a sequence's
-    start) -> ``(y [b, T, H, P]`` in ``x``'s dtype, ``last_state [b, H, P,
-    N]`` float32``)``."""
+def ssd_xla(x, dt, A, B, C, D, chunk: int = 128, state=None):
+    """:func:`ssd` as plain XLA, one group a step of a ``lax.map``: every
+    backend takes it, and the kernels' tests hold them to it."""
     with jax.named_scope(SSD_NAME):
         b, T, H, P = x.shape
         G, N = B.shape[2:]
@@ -150,6 +163,35 @@ def ssd(x, dt, A, B, C, D, chunk: int = 128, state=None):
         y, last = jax.lax.map(lambda group: _group(group, chunk), by_group)
         y = jnp.moveaxis(y, 0, 2).reshape(b, T + pad, H, P)
         return y[:, :T], jnp.moveaxis(last, 0, 1).reshape(b, H, P, N)
+
+
+def backend(T: int, H: int, P: int, G: int, N: int, chunk: int) -> str:
+    """``"ssd_pallas"`` or ``"ssd_xla"``: what :func:`ssd` runs a scan of
+    these shapes as on this process's platform. The kernels on a TPU where
+    the shapes tile (``ssd_pallas.fits``) and there is a whole chunk of
+    rows, plain XLA everywhere else — CPU actor hosts, CI, a shape that does
+    not tile, a scan shorter than a chunk (the one row ``init`` traces: the
+    kernels' grid would be one step of padding, and their lowering a second
+    of every process's start). Platform and shape decide, nothing else: no
+    arch key, no environment variable."""
+    if jax.default_backend() != "tpu" or T < chunk:
+        return XLA
+    from relayrl_tpu.ops import ssd_pallas
+
+    return PALLAS if ssd_pallas.fits(H, P, G, N, chunk) else XLA
+
+
+def ssd(x, dt, A, B, C, D, chunk: int = 128, state=None):
+    """``x [b, T, H, P]``, step sizes ``dt [b, T, H]`` (positive, as they
+    enter the recurrence), ``A [H]`` (negative), ``B, C [b, T, G, N]``,
+    ``D [H]``, ``state [b, H, P, N]`` float32 (None: zeros, a sequence's
+    start) -> ``(y [b, T, H, P]`` in ``x``'s dtype, ``last_state [b, H, P,
+    N]`` float32``)``, as :func:`backend` says."""
+    if backend(*x.shape[1:], *B.shape[2:], chunk) == PALLAS:
+        from relayrl_tpu.ops.ssd_pallas import ssd_pallas
+
+        return ssd_pallas(x, dt, A, B, C, D, chunk, state)
+    return ssd_xla(x, dt, A, B, C, D, chunk, state)
 
 
 def ssd_step(x, dt, A, B, C, D, state):

@@ -257,6 +257,54 @@ def test_the_device_program_is_the_same_without_the_scopes(lowered, family,
     assert "relayrl_" not in named      # no name leaks into the program
 
 
+def _lower_scan_kernels():
+    """The scan's Pallas kernels (what ``one_part``'s Mamba-2 layers run at
+    shapes that tile, on a TPU), lowered through the interpreter: forward
+    and the ``custom_vjp``'s backward, every gradient."""
+    from relayrl_tpu.ops import ssd_pallas
+
+    for cached in (ssd_pallas._build, ssd_pallas._make_scan):
+        cached.cache_clear()        # a call is named where it is built
+    b, T, H, P, G, N = 1, 256, 8, 64, 1, 128
+    S = jax.ShapeDtypeStruct
+    args = [S(shape, jnp.float32) for shape in (
+        (b, T, H, P), (b, T, H), (H,), (b, T, G, N), (b, T, G, N), (H,),
+        (b, H, P, N))]
+
+    def loss(*a):
+        y, last = ssd_pallas.ssd_pallas(*a[:6], state=a[6], interpret=True)
+        return jnp.sum(y) + jnp.sum(last)
+
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(7)))).lower(
+        *args)
+
+
+def test_the_scan_kernels_count_for_the_scans_name(monkeypatch):
+    """Every kernel call's innermost ``relayrl_`` name is ``relayrl_ssd``
+    — ``ssd_ms`` reads the exact scope, so a kernel named ``relayrl_ssd_fwd``
+    would leave it the glue alone — in the forward and in the backward
+    rule, which opens the scope itself; and the names are metadata."""
+    from relayrl_tpu.ops import ssd_pallas
+
+    named = _lower_scan_kernels()
+    paths = set(re.findall(r'loc\("(jit\([^"]*)"',
+                           named.as_text(debug_info=True)))
+    for kernel, backward in ((ssd_pallas.FWD_NAME, False),
+                             (ssd_pallas.STATES_NAME, True),
+                             (ssd_pallas.BWD_NAME, True)):
+        mine = [p for p in paths if re.search(rf"/{kernel}(/|$)", p)]
+        assert mine, kernel
+        for path in mine:
+            assert re.findall(r"relayrl_\w+", path)[-1] == SSD_NAME, path
+            assert ("transpose(" in path) == backward, path
+    assert not [p for p in paths if "relayrl_flash_" in p]
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _lower_scan_kernels()
+    assert SSD_NAME not in bare.as_text(debug_info=True)
+    assert bare.as_text() == named.as_text()
+
+
 def test_the_doc_names_every_scope():
     """``docs/observability.md``, "Device names": every part and every
     kernel name of the one list, each beside what reads it."""

@@ -211,26 +211,85 @@ def test_held_experts_of_a_width_no_128_divides_compile_for_v5e(
                      text)
 
 
-def test_the_state_space_scan_compiles_for_v5e(one_chip):
-    """``ops/ssd.py`` at ``nemotron-twotower-policy.update``'s shape — two
-    8192-token episodes, 64 heads of 64, a state of 128, 8 groups, chunks of
-    128 —, forward and every gradient: plain XLA under its own name in both
-    directions, one group's score tiles alive at a time (a whole layer's
-    would be 0.27 GB an array and a dozen arrays)."""
-    from relayrl_tpu.ops.ssd import ssd
-
+def _scan_at_the_cells_shape(one_chip, fn, wrt):
+    """``fn`` (a form of ``ops/ssd.py``'s scan) at
+    ``nemotron-twotower-policy.update``'s shape — two 8192-token episodes,
+    64 heads of 64, a state of 128, 8 groups, chunks of 128, bfloat16 from
+    a carried state —, a loss that reads both results differentiated with
+    respect to ``wrt`` (none: the two results themselves), compiled for
+    the described chip."""
     b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
     S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                   sharding=one_chip)
     args = (S((b, t, h, p), jnp.bfloat16), S((b, t, h), jnp.float32),
             S((h,), jnp.float32), S((b, t, g, n), jnp.bfloat16),
-            S((b, t, g, n), jnp.bfloat16), S((h,), jnp.float32))
-    compiled = jax.jit(jax.value_and_grad(
-        lambda *a: jnp.sum(ssd(*a)[0].astype(jnp.float32)),
-        argnums=tuple(range(6)))).lower(*args).compile()
+            S((b, t, g, n), jnp.bfloat16), S((h,), jnp.float32),
+            S((b, h, p, n), jnp.float32))
+
+    def loss(*a):
+        y, last = fn(*a[:6], state=a[6])
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(last)
+
+    step = (jax.value_and_grad(loss, argnums=wrt) if wrt
+            else lambda *a: fn(*a[:6], state=a[6]))
+    return jax.jit(step).lower(*args).compile()
+
+
+def _scan_paths(compiled):
     paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
-    mine = [path for path in paths if scopes.SSD_NAME in path]
+    return [path for path in paths if scopes.SSD_NAME in path]
+
+
+def test_the_state_space_scan_compiles_for_v5e(one_chip):
+    """The scan's Pallas kernels (``ops/ssd_pallas.py``), forward and every
+    gradient: three Mosaic calls — ``ssd_fwd``, and in the backward
+    ``ssd_states`` + ``ssd_bwd`` — each under ``relayrl_ssd`` and under no
+    deeper ``relayrl_`` name (the benchmark's ``ssd_ms`` reads the exact
+    scope), no loop left under the scope, and of chunk-shaped arrays only
+    the chunk-start states (0.27 GB) in HBM."""
+    from relayrl_tpu.ops import ssd_pallas
+
+    compiled = _scan_at_the_cells_shape(one_chip, ssd_pallas.ssd_pallas,
+                                        tuple(range(7)))
+    text = compiled.as_text()
+    calls = re.findall(r'(%[\w.\-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) == [
+        "%" + ssd_pallas.BWD_NAME, "%" + ssd_pallas.FWD_NAME,
+        "%" + ssd_pallas.STATES_NAME]
+    for name, path in calls:
+        assert re.findall(r"relayrl_\w+", path)[-1] == scopes.SSD_NAME, path
+        assert ("transpose(" in path) == (ssd_pallas.FWD_NAME not in name)
+        assert "relayrl_flash_" not in name + path
+    mine = _scan_paths(compiled)
     assert [path for path in mine if "transpose(" in path]
     assert [path for path in mine if "transpose(" not in path]
+    assert not re.findall(r"\bwhile\(", text)
+    # arguments, cotangents and the chunk-start states
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+def test_a_scan_nobody_differentiates_writes_no_state(one_chip):
+    """The prefill's call: ``ssd_fwd`` alone, no chunk-shaped temporary."""
+    from relayrl_tpu.ops import ssd_pallas
+
+    compiled = _scan_at_the_cells_shape(one_chip, ssd_pallas.ssd_pallas, ())
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and ssd_pallas.FWD_NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_the_plain_scan_compiles_for_v5e(one_chip):
+    """``ops/ssd.ssd_xla`` at the same shape — what a shape that does not
+    tile runs on a TPU: plain XLA under its own name in both directions,
+    one group's score tiles alive at a time (a whole layer's would be 0.27
+    GB an array and a dozen arrays)."""
+    from relayrl_tpu.ops.ssd import ssd_xla
+
+    compiled = _scan_at_the_cells_shape(one_chip, ssd_xla, tuple(range(6)))
+    mine = _scan_paths(compiled)
+    assert [path for path in mine if "transpose(" in path]
+    assert [path for path in mine if "transpose(" not in path]
+    assert "tpu_custom_call" not in compiled.as_text()
     # arguments, cotangents and one group's intermediates: under 1.5 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
