@@ -64,6 +64,11 @@ ARCH_PASSTHROUGH_KEYS = (
     "mamba_heads", "mamba_head_dim", "mamba_state", "mamba_groups",
     "mamba_conv_taps", "mamba_chunk", "moe_routed_scaling",
     "moe_shared_d_ff",
+    # zero-centred norm weights, a partial rotary, the attention's output
+    # gate, the linear-attention mixer, the shared expert's gate
+    "norm_zero_centred", "rope_share", "attn_gate", "gdn_key_heads",
+    "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "gdn_conv_taps",
+    "gdn_chunk", "moe_shared_expert_gate",
 )
 
 
@@ -155,6 +160,11 @@ class Policy:
     # Pallas kernels (a TPU, shapes that tile) or plain XLA. Empty for a
     # trunk without such layers, None for other families.
     scan_backends: Mapping[tuple, str] | None = None
+    # Sequence policies with linear-attention layers: ``{(T, value heads,
+    # key width, value width, dtype): "gdn_xla"}`` for every delta-rule
+    # shape traced so far (models/transformer._resolve_delta_rule). Empty
+    # for a trunk without such layers, None for other families.
+    gdn_backends: Mapping[tuple, str] | None = None
     # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
     # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward
     # (``moe_load_max`` / ``moe_load_min``: models/moe.load_extremes) for

@@ -61,7 +61,11 @@ routed sum with weight 1 (``moe_shared_up`` / ``moe_shared_gate`` /
 ``moe_shared_down``, dense matmuls under the part ``relayrl_ffn``). It lies
 outside the held share: every chip of a layer computes it alike, so the
 shares of the chips that divide a layer add up to the layer with the shared
-expert counted ONCE (``tests/test_nemotron_reference.py``).
+expert counted ONCE (``tests/test_nemotron_reference.py``). Arch
+``moe_shared_expert_gate`` gives it a gate of its own, ``sigmoid(u w_s)``
+with ``w_s [d]`` (``moe_shared_expert_gate``, no bias): one scalar a token
+times the shared expert's output (Qwen3-Next's, Qwen2-MoE's), in float32
+under the same part, outside the held share like the expert it gates.
 
 Dispatch is **sparse** on one device: the
 N·k token-slots are sorted by expert, each expert's rows go through one
@@ -479,10 +483,12 @@ def _mesh_ep() -> int:
     return 1 if mesh is None else int(mesh.shape.get("ep", 1))
 
 
-def _shared_ffn(layer: "MoEMLP", xs, gated: bool):
+def _shared_ffn(layer: "MoEMLP", xs, gated: bool, tokens):
     """The expert every token takes, ``[N, d]`` float32, in ``layer``'s
     param scope: dense matmuls, outside the dispatch and outside the held
-    share. (A plain function: a module method would be wrapped by flax.)"""
+    share; under ``shared_gate`` times ``sigmoid(tokens w_s)``, read from
+    the float32 rows as the router reads them. (A plain function: a module
+    method would be wrapped by flax.)"""
     def dense(features, name):
         return nn.Dense(features, dtype=layer.compute_dtype, use_bias=False,
                         name=name)
@@ -493,8 +499,13 @@ def _shared_ffn(layer: "MoEMLP", xs, gated: bool):
                 if gated else None)
         h = _activation(layer.ffn, up.astype(jnp.float32),
                         None if gate is None else gate.astype(jnp.float32))
-        return dense(layer.d_model, "moe_shared_down")(
+        out = dense(layer.d_model, "moe_shared_down")(
             h.astype(layer.compute_dtype)).astype(jnp.float32)
+        if layer.shared_gate:
+            out = out * jax.nn.sigmoid(nn.Dense(
+                1, dtype=jnp.float32, use_bias=False,
+                name="moe_shared_expert_gate")(tokens.astype(jnp.float32)))
+        return out
 
 
 class MoEMLP(nn.Module):
@@ -516,6 +527,8 @@ class MoEMLP(nn.Module):
     routed_scaling: float = 1.0     # times the k weights, after normalising
     # width of the one shared expert every token takes (None: none)
     shared_d_ff: int | None = None
+    # sigmoid(u w_s) times the shared expert's output (module docstring)
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x, route_x=None):
@@ -674,7 +687,7 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "row_passes", row_passes)
         self.sow("intermediates", "row_buffer", jnp.int32(rows))
         if self.shared_d_ff:
-            y = y + _shared_ffn(self, tokens.astype(cd), gated)
+            y = y + _shared_ffn(self, tokens.astype(cd), gated, tokens)
         return y.reshape(B, T, d).astype(x.dtype)
 
 

@@ -75,6 +75,20 @@ position; an ``"ffn"`` layer's is empty. ``positions: "none"`` gives a trunk
 no positional signal at all (no table, no rotation: the state-space layers
 order the tokens).
 
+A fifth kind of layer is linear attention (Qwen3-Next's Gated DeltaNet,
+``benchmark/configs/qwen3next-policy.json``): ``layer_types`` entry
+``"linear_attention"`` — :func:`_gdn_layer`: fused projections to ``[q | k |
+v | z]`` and ``[b | a]``, a ``gdn_conv_taps``-tap convolution without bias
+over q, k and v, L2-normed q and k, the gated delta rule of
+:mod:`relayrl_tpu.ops.gdn` on a ``[gdn_key_dim, gdn_value_dim]`` matrix state
+a value head (``gdn_key_heads`` q/k heads under ``gdn_value_heads``) in
+chunks of ``gdn_chunk``, an RMSNorm a head before the ``silu(z)`` gate — and
+an FFN; its cache is the FIFTH kind, the convolution's last rows and the
+float32 state. The attention block beside it may carry ``attn_gate`` (a q
+projection twice as wide whose second half gates the attention's output),
+``rope_share`` (RoPE on the first share of a head's lanes) and
+``norm_zero_centred`` (RMSNorm weights as offsets from one).
+
 Sequence ABI: ``evaluate(params, obs[B,T,D], act[B,T], mask[B,T,A]) ->
 (logp[B,T], ent[B,T], v[B,T])`` — same shapes the per-step MLP family
 broadcasts to, so REINFORCE/PPO updates take this policy unchanged.
@@ -106,17 +120,22 @@ from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
 from relayrl_tpu.ops.scopes import (  # noqa: F401  (SHORT_CONV_NAME's home)
     EMBED,
     FFN,
+    GDN_CONV_NAME,
     HEADS,
     MAMBA_CONV_NAME,
     MOE_ELEMENTWISE,
     OP_PROJ,
     SHORT_CONV_NAME,
 )
+from relayrl_tpu.ops.gdn import backend as gdn_backend
+from relayrl_tpu.ops.gdn import gdn, gdn_step
 from relayrl_tpu.ops.ssd import backend as ssd_backend
 from relayrl_tpu.ops.ssd import ssd, ssd_step
 
 # the one activation a Mamba-2 layer's checkpoint keeps (_mamba_layer)
 _SSD_OUT = "relayrl_ssd_out"
+# ... and a linear-attention layer's (_gdn_layer)
+_GDN_OUT = "relayrl_gdn_out"
 
 
 def _resolve_attention(arch: Mapping[str, Any]
@@ -287,24 +306,85 @@ def _resolve_scan() -> tuple[Callable, dict]:
     return scan_fn, resolved
 
 
-def _norm(arch_norm: str, eps, name: str):
+def _resolve_delta_rule() -> tuple[Callable, dict]:
+    """``(rule_fn, resolved)``: the linear-attention layers' delta rule,
+    ``ops.gdn.gdn`` behind a record of what it ran as, as
+    :func:`_resolve_scan`: ``resolved`` (``Policy.gdn_backends``) maps every
+    traced ``(T, value heads, key width, value width, dtype)`` to
+    ``ops.gdn.backend``'s answer (``gdn_xla``: the one form today), and each
+    new entry prints one ``[gdn]`` line naming the platform."""
+    resolved: dict[tuple, str] = {}
+
+    def rule_fn(q, k, v, g, beta, chunk, state):
+        key = (int(v.shape[1]), int(v.shape[2]), int(k.shape[3]),
+               int(v.shape[3]), v.dtype.name)
+        ran = gdn_backend(*key[:4], chunk)
+        if resolved.get(key) != ran:
+            resolved[key] = ran
+            print(f"[gdn] T={key[0]} heads={key[1]}/{k.shape[2]} "
+                  f"key_dim={key[2]} value_dim={key[3]} chunk={chunk} "
+                  f"{key[4]} -> {ran} (platform {jax.default_backend()})",
+                  flush=True)
+        return gdn(q, k, v, g, beta, chunk, state)
+
+    return rule_fn, resolved
+
+
+class _ZeroCentredRMSNorm(nn.Module):
+    """RMSNorm whose learned weight is an offset from one, ``x^ (1 + w)``
+    (Qwen3-Next's, Gemma's), float32. ``w`` is seeded at std 0.02 round 0
+    (the sources start it at 0) so that ``1 + w`` and ``w`` differ."""
+
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.normal(0.02),
+                       (x.shape[-1],), jnp.float32)
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), -1, keepdims=True)
+            + self.epsilon) * (1.0 + w)
+
+
+def _norm(arch_norm: str, eps, name: str, zero_centred: bool = False):
     """The arch's normalisation layer in float32: ``"layer"`` (LayerNorm,
-    scale + bias) or ``"rms"`` (RMSNorm, scale only). ``eps=None`` keeps
-    flax's default, 1e-6 — what every arch without ``norm_eps`` has always
-    run."""
+    scale + bias) or ``"rms"`` (RMSNorm, scale only; ``zero_centred``: the
+    weight is ``1 + scale``). ``eps=None`` keeps flax's default, 1e-6 — what
+    every arch without ``norm_eps`` has always run."""
     if arch_norm not in ("layer", "rms"):
         raise ValueError(f"unknown norm {arch_norm!r} (layer | rms)")
-    cls = nn.LayerNorm if arch_norm == "layer" else nn.RMSNorm
     kw = {} if eps is None else {"epsilon": float(eps)}
+    if zero_centred:
+        if arch_norm != "rms":
+            raise ValueError("norm_zero_centred needs norm 'rms'")
+        return _ZeroCentredRMSNorm(name=name, **kw)
+    cls = nn.LayerNorm if arch_norm == "layer" else nn.RMSNorm
     return cls(dtype=jnp.float32, name=name, **kw)
 
 
-def apply_rope(x, start, theta: float):
+def _block_norm(block: "TransformerBlock", name: str, kind: str | None = None):
+    """``block``'s norm under ``name`` (``kind``: "rms" for the q/k norms)."""
+    return _norm(kind or block.norm, block.norm_eps, name,
+                 block.norm_zero_centred)
+
+
+def apply_rope(x, start, theta: float, share: float = 1.0):
     """Rotary position embedding on ``x [B, T, H, hd]`` whose row j sits at
     absolute position ``start + j`` (``start`` may be traced): pairs
     (i, i + hd/2) rotate by ``pos * theta^(-2i/hd)`` — the half-split
     convention of the published ``olmoe`` / GPT-NeoX code. Angles in
-    float32, result in ``x``'s dtype."""
+    float32, result in ``x``'s dtype. ``share`` below 1 (a
+    ``partial_rotary_factor``): only the FIRST ``share * hd`` lanes turn, as
+    a head of that width would, the rest pass untouched."""
+    if share != 1.0:
+        turned = int(x.shape[-1] * share)
+        if not 0 < turned <= x.shape[-1] or turned % 2:
+            raise ValueError(f"rope_share {share} of a head of "
+                             f"{x.shape[-1]} turns {turned} lanes")
+        return jnp.concatenate(
+            [apply_rope(x[..., :turned], start, theta), x[..., turned:]],
+            axis=-1)
     hd = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     pos = jnp.asarray(start, jnp.float32) + jnp.arange(
@@ -340,15 +420,16 @@ def _short_conv(bcu, w, state=None):
         return c_gate * c.astype(bcu.dtype), zp
 
 
-def _mamba_conv(xbc, w, bias, state=None):
+def _mamba_conv(xbc, w, bias, state=None, scope: str = MAMBA_CONV_NAME):
     """The Mamba-2 mixer's convolution: ``silu(conv(xbc) + bias)``,
     depthwise and causal, ``L = w.shape[0]`` taps, returns ``(out,
     xbc_padded)``. ``state [batch, L-1, c]`` holds the ``xbc`` rows before
     this call's first (zeros at a sequence's start, which ``None`` means);
     ``xbc_padded = concat(state, xbc)`` is what a cache takes its next rows
     from. Plain XLA under one named scope, the tap sums in float32, as
-    :func:`_short_conv`."""
-    with jax.named_scope(MAMBA_CONV_NAME):
+    :func:`_short_conv`. ``bias`` None: none is added (a linear-attention
+    mixer's, :func:`_gdn_conv`, under its own ``scope``)."""
+    with jax.named_scope(scope):
         taps = w.shape[0]
         T = xbc.shape[1]
         if state is None:
@@ -357,7 +438,9 @@ def _mamba_conv(xbc, w, bias, state=None):
             xp = jnp.concatenate([state.astype(xbc.dtype), xbc], axis=1)
         c = sum(w[j].astype(jnp.float32) * xp[:, j:j + T].astype(jnp.float32)
                 for j in range(taps))
-        return nn.silu(c + bias.astype(jnp.float32)).astype(xbc.dtype), xp
+        if bias is not None:
+            c = c + bias.astype(jnp.float32)
+        return nn.silu(c).astype(xbc.dtype), xp
 
 
 def _block_dense(block: "TransformerBlock", features: int, name: str):
@@ -378,7 +461,7 @@ def _block_ffn(block: "TransformerBlock", x, layer_in):
         return x
     part = MOE_ELEMENTWISE if block.moe_experts > 0 else FFN
     with jax.named_scope(part):
-        h = _norm(block.norm, block.norm_eps, "ln_mlp")(x)
+        h = _block_norm(block, "ln_mlp")(x)
     width = block.d_ff or block.mlp_ratio * block.d_model
     if block.ffn not in UNGATED_FFN and block.ffn not in GATED_FFN:
         raise ValueError(f"unknown ffn {block.ffn!r} "
@@ -472,6 +555,28 @@ class TransformerBlock(nn.Module):
     # "layer": the MoE router reads this layer's input as it arrives (before
     # the operator, un-normed); "ffn": the rows the experts read.
     moe_router_input: str = "ffn"
+    # RMSNorm weights as offsets from one, x^ (1 + w): every norm of the
+    # block but a linear-attention layer's gated one.
+    norm_zero_centred: bool = False
+    # The share of a head's lanes RoPE turns (a partial_rotary_factor).
+    rope_share: float = 1.0
+    # Gated attention: q_proj is twice as wide, a head's head_dim query
+    # lanes then its head_dim gate lanes, and the attention's output is
+    # multiplied by sigmoid(gate) before the output projection.
+    attn_gate: bool = False
+    # The linear-attention (Gated DeltaNet) mixer, _gdn_layer: gdn_key_heads
+    # q/k heads of gdn_key_dim under gdn_value_heads value heads of
+    # gdn_value_dim, a gdn_conv_taps-tap convolution over q, k and v, the
+    # delta rule in chunks of gdn_chunk.
+    gdn_key_heads: int = 4
+    gdn_value_heads: int = 8
+    gdn_key_dim: int = 64
+    gdn_value_dim: int = 64
+    gdn_conv_taps: int = 4
+    gdn_chunk: int = 64
+    # The rule a linear-attention layer runs: ops.gdn.gdn, or the policy's
+    # recording wrapper of it (_resolve_delta_rule).
+    rule_fn: Callable = gdn
 
     @nn.compact
     def __call__(self, x, cache=None, t=None, readout_idx=None,
@@ -513,6 +618,8 @@ class TransformerBlock(nn.Module):
             return _conv_layer(self, x, cache, readout_idx, n_valid)
         if self.op == "mamba2":
             return _mamba_layer(self, x, cache, n_valid)
+        if self.op == "gdn":
+            return _gdn_layer(self, x, cache, n_valid)
         if self.op == "none":   # the FFN alone: nothing to cache
             if readout_idx is not None:
                 x = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1, axis=1)
@@ -520,13 +627,13 @@ class TransformerBlock(nn.Module):
             return out if cache is None else (out, ())
         if self.op != "attention":
             raise ValueError(f"unknown layer operator {self.op!r} "
-                             f"(attention | conv | mamba2 | none)")
+                             f"(attention | conv | mamba2 | gdn | none)")
         head_dim = self.head_dim or self.d_model // self.n_heads
         width = self.n_heads * head_dim     # of q and of attn_out's input
         # everything of the operator but its kernel: one part on the device
         with jax.named_scope(OP_PROJ):
             layer_in = x
-            h = _norm(self.norm, self.norm_eps, "ln_attn")(x)
+            h = _block_norm(self, "ln_attn")(x)
             h = h.astype(self.compute_dtype)
             if self.n_kv_heads is None and self.head_dim is None:
                 n_kv = self.n_heads
@@ -534,39 +641,57 @@ class TransformerBlock(nn.Module):
                 q, k, v = jnp.split(qkv, 3, axis=-1)
             else:
                 n_kv = self.n_kv_heads or self.n_heads
-                q = _block_dense(self, width, "q_proj")(h)
+                q = _block_dense(self, width * (1 + self.attn_gate),
+                                 "q_proj")(h)
                 k = _block_dense(self, n_kv * head_dim, "k_proj")(h)
                 v = _block_dense(self, n_kv * head_dim, "v_proj")(h)
             if self.qk_norm is True:
                 # over the whole d_model-wide projection, before the heads
-                q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
+                q = _block_norm(self, "q_norm", "rms")(q).astype(
                     self.compute_dtype)
-                k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
+                k = _block_norm(self, "k_norm", "rms")(k).astype(
                     self.compute_dtype)
+            gate = None
+            if self.attn_gate:
+                if self.n_kv_heads is None and self.head_dim is None:
+                    raise ValueError("attn_gate needs separate projections "
+                                     "(n_kv_heads or head_dim)")
+                if self.qk_norm is True:
+                    raise ValueError("attn_gate takes qk_norm false | "
+                                     "\"head\"")
+                # a head's query lanes, then its gate lanes
+                q, gate = jnp.split(
+                    q.reshape(B, T, self.n_heads, 2 * head_dim), 2, axis=-1)
+                gate = gate.reshape(B, T, width)
             q = q.reshape(B, T, self.n_heads, head_dim)
             k, v = (a.reshape(B, T, n_kv, head_dim) for a in (k, v))
             if self.qk_norm == "head":
                 # over each head's head_dim, one learned scale for all heads
-                q = _norm("rms", self.norm_eps, "q_norm")(q).astype(
+                q = _block_norm(self, "q_norm", "rms")(q).astype(
                     self.compute_dtype)
-                k = _norm("rms", self.norm_eps, "k_norm")(k).astype(
+                k = _block_norm(self, "k_norm", "rms")(k).astype(
                     self.compute_dtype)
             elif self.qk_norm not in (True, False):
                 raise ValueError(f"unknown qk_norm {self.qk_norm!r} "
                                  f"(false | true | \"head\")")
             rope = self.rope_theta is not None
             if rope:
-                k = apply_rope(k, 0 if t is None else t, self.rope_theta)
+                k = apply_rope(k, 0 if t is None else t, self.rope_theta,
+                               self.rope_share)
         if readout_idx is not None:
             with jax.named_scope(OP_PROJ):
                 q_row = jax.lax.dynamic_slice_in_dim(q, readout_idx, 1,
                                                      axis=1)
                 if rope:
-                    q_row = apply_rope(q_row, readout_idx, self.rope_theta)
+                    q_row = apply_rope(q_row, readout_idx, self.rope_theta,
+                                       self.rope_share)
             attn = dense_attention(q_row, k, v, causal=True,
                                    q_offset=readout_idx, window=self.window)
             with jax.named_scope(OP_PROJ):
                 attn = attn.reshape(B, 1, width)
+                if gate is not None:
+                    attn = _gated(attn, jax.lax.dynamic_slice_in_dim(
+                        gate, readout_idx, 1, axis=1))
                 row_in = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1,
                                                       axis=1)
                 x = row_in + _block_dense(self, self.d_model, "attn_out")(
@@ -574,7 +699,8 @@ class TransformerBlock(nn.Module):
             return _block_ffn(self, x, row_in)
         if rope:
             with jax.named_scope(OP_PROJ):
-                q = apply_rope(q, 0 if t is None else t, self.rope_theta)
+                q = apply_rope(q, 0 if t is None else t, self.rope_theta,
+                               self.rope_share)
         if cache is None:
             attn = self.attn_fn(q, k, v, self.window)
             new_cache = None
@@ -596,10 +722,18 @@ class TransformerBlock(nn.Module):
             new_cache = (k_cache, v_cache)
         with jax.named_scope(OP_PROJ):
             attn = attn.reshape(B, T, width)
+            if gate is not None:
+                attn = _gated(attn, gate)
             x = x + _block_dense(self, self.d_model, "attn_out")(
                 attn).astype(x.dtype)
         out = _block_ffn(self, x, layer_in)
         return out if cache is None else (out, new_cache)
+
+
+def _gated(attn, gate):
+    """``attn * sigmoid(gate)``, the product in float32."""
+    return (attn.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))).astype(attn.dtype)
 
 
 def _ring_cached(q, k, v, cache, t, window: int, n_valid):
@@ -648,7 +782,7 @@ def _conv_layer(block: TransformerBlock, x, cache, readout_idx, n_valid):
 
     def in_proj(rows):
         with jax.named_scope(OP_PROJ):
-            h = _norm(block.norm, block.norm_eps, "ln_attn")(rows)
+            h = _block_norm(block, "ln_attn")(rows)
             return _block_dense(block, 3 * d, "conv_in")(
                 h.astype(block.compute_dtype))
 
@@ -777,7 +911,7 @@ def _mamba_layer(block: TransformerBlock, x, cache, n_valid):
             return jnp.dot(y, w_out.astype(cd)), padded, state
 
     with jax.named_scope(OP_PROJ):
-        h = _norm(block.norm, block.norm_eps, "ln_attn")(x).astype(cd)
+        h = _block_norm(block, "ln_attn")(x).astype(cd)
     if cache is None:
         # Full mode, the learner's: the mixer's inner activations (the
         # 10,304-wide projection, the convolution's rows, the gate and the
@@ -802,6 +936,137 @@ def _mamba_layer(block: TransformerBlock, x, cache, n_valid):
     return out, (rows.astype(cache[0].dtype), state)
 
 
+def _gdn_conv(qkv, w, state=None):
+    """A linear-attention layer's convolution: ``silu(conv(qkv))`` over q,
+    k and v together, :func:`_mamba_conv` WITHOUT a bias under the scope
+    ``relayrl_gdn_conv``; returns ``(out, qkv_padded)`` likewise."""
+    return _mamba_conv(qkv, w, None, state, GDN_CONV_NAME)
+
+
+def _gdn_a_log_init(key, shape, dtype=jnp.float32):
+    """``log`` of decay rates uniform over (0, 16), Qwen3-Next's own: ``g =
+    -exp(A_log) softplus(a + dt_bias)``."""
+    return jnp.log(jax.random.uniform(key, shape, minval=1e-4,
+                                      maxval=16.0)).astype(dtype)
+
+
+def _gdn_layer(block: TransformerBlock, x, cache, n_valid):
+    """A linear-attention (Gated DeltaNet) layer in ``block``'s param scope,
+    ``x + out(norm_h(o) * silu(z))`` behind the layer's norm, then the FFN.
+    ``[q | k | v | z] = in_qkvz(norm(x))``, ``[b | a] = in_ba(norm(x))``;
+    ``[q | k | v] = silu(conv([q | k | v]))`` (no bias); ``beta =
+    sigmoid(b)`` and ``g = -exp(A_log) softplus(a + dt_bias)`` in float32,
+    one scalar a value head; ``q = q / |q| / sqrt(K)``, ``k = k / |k|`` a
+    head; ``o`` the delta rule of ``ops/gdn.py``; ``norm_h`` an RMSNorm over
+    each value head's width with a plain weight, BEFORE the gate. No
+    positions: the rule orders the tokens.
+
+    Full mode (``cache=None``). Cached modes: ``cache`` is the FIFTH kind,
+    ``(the convolution's last gdn_conv_taps - 1 rows of [q | k | v], the
+    [B, H, K, V] state in float32)``; one row continues from it in one step
+    of the rule (O(1) in the position), several rows (prefill) run the
+    chunked rule from it and leave the state after the ``n_valid`` real ones
+    (rows past them get ``g = 0`` and ``beta = 0``: they leave the state as
+    it is). The readout row of a window needs the whole rule before it: the
+    core runs a final linear-attention layer in full and slices."""
+    Bsz, T, d = x.shape
+    Hk, H = block.gdn_key_heads, block.gdn_value_heads
+    K, V = block.gdn_key_dim, block.gdn_value_dim
+    kw, vw, back = Hk * K, H * V, block.gdn_conv_taps - 1
+    if H % Hk:
+        raise ValueError(f"gdn_key_heads {Hk} does not divide "
+                         f"gdn_value_heads {H}")
+    f32 = jnp.float32
+    cd = block.compute_dtype
+    lecun = nn.initializers.lecun_normal()
+    weights = (
+        block.param("gdn_in_qkvz", lecun, (d, 2 * kw + 2 * vw), f32),
+        block.param("gdn_in_ba", lecun, (d, 2 * H), f32),
+        block.param("gdn_conv_w", lecun,
+                    (block.gdn_conv_taps, 2 * kw + vw), f32),
+        block.param("gdn_dt_bias", nn.initializers.ones, (H,), f32),
+        block.param("gdn_A_log", _gdn_a_log_init, (H,), f32),
+        block.param("gdn_norm", nn.initializers.ones, (V,), f32),
+        block.param("gdn_out", lecun, (vw, d), f32))
+    eps = 1e-6 if block.norm_eps is None else float(block.norm_eps)
+
+    def l2_normed(a):   # over a head's width, float32 (eps as the source's)
+        a = a.astype(f32)
+        return a * jax.lax.rsqrt(
+            jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+
+    def mix(h, weights, conv_rows, state, n_valid):
+        """normed rows -> (the mixer's output, [q | k | v] with the rows
+        before it, the state after the last real row)"""
+        w_qkvz, w_ba, conv_w, dt_bias, a_log, scale, w_out = weights
+        with jax.named_scope(OP_PROJ):
+            qkv, z = jnp.split(jnp.dot(h, w_qkvz.astype(cd)),
+                               [2 * kw + vw], axis=-1)
+            b_in, a_in = jnp.split(
+                jnp.dot(h, w_ba.astype(cd), preferred_element_type=f32),
+                2, axis=-1)
+            beta = jax.nn.sigmoid(b_in)
+            g = -jnp.exp(a_log) * jax.nn.softplus(a_in + dt_bias)
+            if n_valid is not None:
+                real = jnp.arange(T)[None, :, None] < n_valid
+                beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
+        qkv, padded = _gdn_conv(qkv, conv_w, conv_rows)
+        with jax.named_scope(OP_PROJ):
+            q, k, v = jnp.split(qkv, [kw, 2 * kw], axis=-1)
+            q = (l2_normed(q.reshape(Bsz, T, Hk, K)) * K ** -0.5).astype(cd)
+            k = l2_normed(k.reshape(Bsz, T, Hk, K)).astype(cd)
+            v = v.reshape(Bsz, T, H, V)
+        if T == 1:
+            # one row is one step of the rule, from the cache's state or
+            # (the row ``init`` traces) from nothing: no chunk to pad to
+            if state is None:
+                state = jnp.zeros((Bsz, H, K, V), f32)
+            o, state = gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], state)
+            o = o[:, None]
+        else:
+            o, state = block.rule_fn(q, k, v, g, beta, block.gdn_chunk,
+                                     state)
+        o = checkpoint_name(o, _GDN_OUT)
+        with jax.named_scope(OP_PROJ):
+            # the norm over each head's width BEFORE the gate, plain weight
+            # (per-head norms a head's lane-aligned columns at a time, as
+            # _mamba_layer's, were measured: 9 ms an update and 1.3 GB more
+            # than this view costs in XLA's copies; PERF.md section 6, PR 42)
+            o = o.astype(f32)
+            y = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), -1, keepdims=True) + eps) * scale
+            y = y * nn.silu(z.astype(f32).reshape(Bsz, T, H, V))
+            return (jnp.dot(y.reshape(Bsz, T, vw).astype(cd),
+                            w_out.astype(cd)), padded, state)
+
+    with jax.named_scope(OP_PROJ):
+        layer_in = x
+        h = _block_norm(block, "ln_attn")(x).astype(cd)
+    if cache is None:
+        # Full mode, the learner's: the mixer's inner activations (the
+        # 12,288-wide projection, the convolution's rows, the normed q and
+        # k, the gated norm in float32) are made again in the backward from
+        # the normed rows; of them only the rule's output is kept, so that
+        # the backward runs the rule's backward alone and never its forward
+        # a second time (ops/gdn.py), as _mamba_layer.
+        y, _, _ = jax.checkpoint(
+            mix, policy=jax.checkpoint_policies.save_only_these_names(
+                _GDN_OUT))(h, weights, None, None, None)
+    else:
+        y, padded, state = mix(h, weights, *cache, n_valid)
+    with jax.named_scope(OP_PROJ):
+        x_out = x + y.astype(x.dtype)
+    out = _block_ffn(block, x_out, layer_in)
+    if cache is None:
+        return out
+    # padded row j is [q | k | v] row j - back: after n real rows the
+    # convolution wants rows n - back .. n - 1
+    n = T if n_valid is None else n_valid
+    rows = jax.lax.dynamic_slice_in_dim(padded, n, back, axis=1)
+    return out, (rows.astype(cache[0].dtype), state)
+
+
 def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
                start=0, learned_positions: bool = True):
     """Obs embedding + positional table, built in the CALLER's param scope
@@ -821,12 +1086,13 @@ def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
 
 
 def _readout_heads(x, mask, act_dim: int, d_model: int, has_critic: bool,
-                   norm: str = "layer", norm_eps=None):
+                   norm: str = "layer", norm_eps=None,
+                   norm_zero_centred: bool = False):
     """Final norm (the arch's kind and epsilon, as the blocks') + pi/vf
     heads in the caller's scope (shared with _PPReadout; the vf optimizer
     partition keys off these exact `vf*` names)."""
     with jax.named_scope(HEADS):
-        x = _norm(norm, norm_eps, "ln_final")(x)
+        x = _norm(norm, norm_eps, "ln_final", norm_zero_centred)(x)
         logits = nn.Dense(act_dim, dtype=jnp.float32, name="pi_head")(x)
         if mask is not None:
             logits = jnp.where(mask > 0, logits, _MASK_FILL)
@@ -861,8 +1127,9 @@ class TransformerCore(nn.Module):
     # TransformerBlock's arch fields, passed through as one dict
     block_kw: Mapping[str, Any] = flax.core.FrozenDict()
     # Per layer: its kind — an operator and an FFN ("full_attention" |
-    # "sliding_attention" | "conv"; empty: full attention everywhere) or
-    # ONE part ("mamba2" | "attention" | "ffn") — and, in a MoE trunk, how
+    # "sliding_attention" | "conv" | "linear_attention"; empty: full
+    # attention everywhere) or ONE part ("mamba2" | "attention" | "ffn") —
+    # and, in a MoE trunk, how
     # many leading layers keep the dense FFN.
     layer_types: tuple[str, ...] = ()
     moe_dense_layers: int = 0
@@ -940,7 +1207,8 @@ class TransformerCore(nn.Module):
         def heads(x, mask):
             return _readout_heads(x, mask, self.act_dim, self.d_model,
                                   self.has_critic, kw.get("norm", "layer"),
-                                  kw.get("norm_eps"))
+                                  kw.get("norm_eps"),
+                                  kw.get("norm_zero_centred", False))
 
         x = _embed_obs(
             self, obs, self.d_model, self.max_seq_len,
@@ -953,13 +1221,13 @@ class TransformerCore(nn.Module):
                 x = block_at(i)(x)
             final = block_at(self.n_layers - 1)
             if (final.moe_experts > 0 and final.op == "attention"
-                    or final.op == "mamba2"):
+                    or final.op in ("mamba2", "gdn")):
                 # The MoE final block keeps its full-window pass (routing
                 # is per token, so the sliced row is what a row-only pass
                 # would give; the shortcut is simply not taken here). A
                 # conv layer takes the row path whatever its FFN, as an FFN
                 # alone does; a Mamba-2 layer's row needs the scan over
-                # every row before it.
+                # every row before it, a linear-attention layer's the rule.
                 x = jax.lax.dynamic_slice_in_dim(final(x), idx, 1, axis=1)
             else:
                 x = final(x, readout_idx=idx)
@@ -1084,11 +1352,15 @@ _BLOCK_ARCH_KEYS = ("norm", "norm_eps", "qk_norm", "use_bias", "ffn", "d_ff",
                     "n_kv_heads", "conv_taps", "head_dim",
                     "moe_router_input", "mamba_heads", "mamba_head_dim",
                     "mamba_state", "mamba_groups", "mamba_conv_taps",
-                    "mamba_chunk")
+                    "mamba_chunk", "norm_zero_centred", "rope_share",
+                    "attn_gate", "gdn_key_heads", "gdn_value_heads",
+                    "gdn_key_dim", "gdn_value_dim", "gdn_conv_taps",
+                    "gdn_chunk")
 # MoEMLP's fields by the arch key that sets each (block field ``moe_kw``)
 _MOE_ARCH_KEYS = {"moe_router": "router", "moe_expert_bias": "expert_bias",
                   "moe_held": "held", "moe_routed_scaling": "routed_scaling",
-                  "moe_shared_d_ff": "shared_d_ff"}
+                  "moe_shared_d_ff": "shared_d_ff",
+                  "moe_shared_expert_gate": "shared_gate"}
 # the core's own: what kind each layer is
 _LAYER_ARCH_KEYS = ("layer_types", "moe_dense_layers", "sliding_window",
                     "rope_layers")
@@ -1097,6 +1369,7 @@ _LAYER_KINDS = {"full_attention": ("attention", True),
                 "sliding_attention": ("attention", True),
                 "conv": ("conv", True),
                 "mamba2": ("mamba2", False),
+                "linear_attention": ("gdn", True),
                 "attention": ("attention", False),
                 "ffn": ("none", True)}
 
@@ -1120,7 +1393,8 @@ def _block_kwargs(arch: Mapping[str, Any]) -> dict:
 
 def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
                attn_fn: Callable | None = None,
-               scan_fn: Callable = ssd) -> TransformerCore:
+               scan_fn: Callable = ssd,
+               rule_fn: Callable = gdn) -> TransformerCore:
     """Arch -> TransformerCore module (shared by the policy builders and
     diagnostics like :func:`relayrl_tpu.models.moe.expert_utilization`,
     which re-applies the same module with captured intermediates)."""
@@ -1139,7 +1413,8 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         moe_experts=moe_experts,
         moe_top_k=int(arch.get("moe_top_k", 2)),
         block_kw=flax.core.FrozenDict(
-            {**_block_kwargs(arch), "scan_fn": scan_fn}),
+            {**_block_kwargs(arch), "scan_fn": scan_fn,
+             "rule_fn": rule_fn}),
         layer_types=tuple(arch.get("layer_types", ())),
         moe_dense_layers=int(arch.get("moe_dense_layers", 0)),
         sliding_window=arch.get("sliding_window"),
@@ -1153,7 +1428,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     attn_fn, attention_backends, score_area, attn_layout = (
         _resolve_attention(arch))
     scan_fn, scan_backends = _resolve_scan()
-    core = _make_core(arch, moe_experts, attn_fn, scan_fn)
+    rule_fn, gdn_backends = _resolve_delta_rule()
+    core = _make_core(arch, moe_experts, attn_fn, scan_fn, rule_fn)
 
     def init_params(rng):
         return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
@@ -1163,18 +1439,21 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     conv_back = int(arch.get("conv_taps", 3)) - 1
     cache_dtype = core.compute_dtype
 
-    def mamba(key: str) -> int:     # the arch's, else the block's default
+    def sized(key: str) -> int:     # the arch's, else the block's default
         return int(arch.get(key, getattr(TransformerBlock, key)))
 
     def init_cache(length: int, batch_size: int = 1):
-        """Zeroed per-layer states for incremental decoding, four kinds
+        """Zeroed per-layer states for incremental decoding, five kinds
         side by side: a (k, v) pair ``[B, length, Hkv, hd]`` for a global
         attention layer, a ring ``[B, min(window, length), Hkv, hd]`` x 2
         for a windowed one (``_ring_cached``), the last ``conv_taps - 1``
         rows of ``B * u`` ``[B, conv_taps - 1, d]`` for a conv layer, and
         for a Mamba-2 layer the convolution's last ``mamba_conv_taps - 1``
         rows of ``xBC`` with the ``[B, H, P, N]`` state in float32 — whose
-        size does not grow with ``length``. An FFN alone keeps nothing."""
+        size does not grow with ``length`` —, for a linear-attention layer
+        the convolution's last ``gdn_conv_taps - 1`` rows of ``[q | k | v]``
+        with the ``[B, H, K, V]`` state in float32, likewise. An FFN alone
+        keeps nothing."""
         conv = (batch_size, conv_back, core.d_model)
 
         def kv_pair(i: int):
@@ -1183,12 +1462,21 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
             return jnp.zeros(kv, cache_dtype), jnp.zeros(kv, cache_dtype)
 
         def mamba_state():
-            heads, width = mamba("mamba_heads"), mamba("mamba_head_dim")
-            state = mamba("mamba_state")
-            xbc = heads * width + 2 * mamba("mamba_groups") * state
-            return (jnp.zeros((batch_size, mamba("mamba_conv_taps") - 1,
+            heads, width = sized("mamba_heads"), sized("mamba_head_dim")
+            state = sized("mamba_state")
+            xbc = heads * width + 2 * sized("mamba_groups") * state
+            return (jnp.zeros((batch_size, sized("mamba_conv_taps") - 1,
                                xbc), cache_dtype),
                     jnp.zeros((batch_size, heads, width, state),
+                              jnp.float32))
+
+        def gdn_state():
+            heads, k_dim = sized("gdn_value_heads"), sized("gdn_key_dim")
+            v_dim = sized("gdn_value_dim")
+            qkv = 2 * sized("gdn_key_heads") * k_dim + heads * v_dim
+            return (jnp.zeros((batch_size, sized("gdn_conv_taps") - 1, qkv),
+                              cache_dtype),
+                    jnp.zeros((batch_size, heads, k_dim, v_dim),
                               jnp.float32))
 
         def layer_cache(i: int):
@@ -1197,6 +1485,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                 return jnp.zeros(conv, cache_dtype)
             if op == "mamba2":
                 return mamba_state()
+            if op == "gdn":
+                return gdn_state()
             return () if op == "none" else kv_pair(i)
 
         return tuple(layer_cache(i) for i in range(core.n_layers))
@@ -1276,6 +1566,7 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                        attention_score_area_pct=score_area,
                        attention_layout=attn_layout,
                        scan_backends=scan_backends,
+                       gdn_backends=gdn_backends,
                        evaluate_stats=evaluate_stats)
 
 

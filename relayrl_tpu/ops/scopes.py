@@ -45,6 +45,8 @@ DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, FFN, MOE_ROUTE,
 SHORT_CONV_NAME = "relayrl_short_conv"   # models/transformer._short_conv
 SSD_NAME = "relayrl_ssd"                 # ops/ssd.py: the Mamba-2 scan
 MAMBA_CONV_NAME = "relayrl_mamba_conv"   # models/transformer._mamba_conv
+GDN_NAME = "relayrl_gdn"                 # ops/gdn.py: the gated delta rule
+GDN_CONV_NAME = "relayrl_gdn_conv"       # models/transformer._gdn_conv
 FWD_NAME = "relayrl_flash_fwd"           # ops/flash.py, also the calls' name
 DQ_NAME = "relayrl_flash_dq"
 DKV_NAME = "relayrl_flash_dkv"
@@ -58,5 +60,6 @@ GMM_DRHS_NAME = "relayrl_moe_gmm_drhs"
 # absorbs the vjp's name transform round a held pass's experts (models/moe.py)
 HELD_EXPERTS_NAME = "held_experts"
 
-KERNEL_SCOPES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME, FWD_NAME, DQ_NAME,
-                 DKV_NAME, GMM_FWD_NAME, GMM_DLHS_NAME, GMM_DRHS_NAME)
+KERNEL_SCOPES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME, GDN_NAME,
+                 GDN_CONV_NAME, FWD_NAME, DQ_NAME, DKV_NAME, GMM_FWD_NAME,
+                 GMM_DLHS_NAME, GMM_DRHS_NAME)

@@ -32,6 +32,8 @@ from relayrl_tpu.ops.scopes import (
     DEVICE_SCOPES,
     EMBED,
     FFN,
+    GDN_CONV_NAME,
+    GDN_NAME,
     HEADS,
     LOSS,
     MAMBA_CONV_NAME,
@@ -53,7 +55,8 @@ EVERY_UPDATE = (OPTIMIZER, VTRACE, LOSS, HEADS)
 TRUNK = EVERY_UPDATE + (EMBED, OP_PROJ)
 MOE = (MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE)
 # plain XLA operators that keep a name of their own, as the kernels do
-OWN_NAMES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME)
+OWN_NAMES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME, GDN_NAME,
+             GDN_CONV_NAME)
 # family -> (arch, the scopes its update uses, observation width)
 FAMILIES = {
     # the GPT-2 shaped block (gpt2m-policy)
@@ -90,6 +93,20 @@ FAMILIES = {
                   "norm": "rms", "positions": "none", "use_bias": False,
                   "ffn": "relu2"},
                  TRUNK + MOE + (FFN, SSD_NAME, MAMBA_CONV_NAME)),
+    # linear-attention layers (a delta rule over two chunks) beside a gated
+    # attention layer with a partial rotary and zero-centred norms, a gated
+    # shared expert (qwen3next-policy)
+    "linear": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 2,
+                "n_heads": 4, "n_kv_heads": 1, "head_dim": 8,
+                "layer_types": ["linear_attention", "full_attention"],
+                "gdn_key_heads": 2, "gdn_value_heads": 4, "gdn_key_dim": 8,
+                "gdn_value_dim": 8, "gdn_chunk": 4, "moe_experts": 8,
+                "moe_top_k": 3, "moe_held": [2, 4], "moe_d_ff": 12,
+                "moe_shared_d_ff": 12, "moe_shared_expert_gate": True,
+                "norm": "rms", "norm_zero_centred": True,
+                "positions": "rope", "rope_share": 0.5, "qk_norm": "head",
+                "attn_gate": True, "use_bias": False, "ffn": "swiglu"},
+               TRUNK + MOE + (FFN, GDN_NAME, GDN_CONV_NAME)),
     # the pixel learner (nature-cnn)
     "cnn": ({"kind": "cnn_discrete", "obs_shape": [36, 36, 2],
              "obs_dim": 36 * 36 * 2, "act_dim": 3},
@@ -100,7 +117,7 @@ FAMILIES = {
 NO_BACKWARD = (OPTIMIZER, VTRACE, OBS_PREP)
 # Share of a compiled update's instructions that carry an ``op_name`` (XLA's
 # own expansions carry none) under a relayrl_ name, at least. Read 0.93-0.99
-# over the five families: what is left is the attention itself (XLA
+# over the six families: what is left is the attention itself (XLA
 # operations here, a kernel of its own name on the chip), the MoE load
 # statistics and the step counter.
 SCOPED_FLOOR = 0.9

@@ -43,6 +43,10 @@ def one_chip():
     ((1, 16384, 28, 128), 256, 4, None, 4096),  # ...and the band: 5 of 16
     ((1, 16384, 28, 128), 256, 4, None, 1000),  # an edge inside a block
     ((2, 2048, 4, 64), 256, 2, 2, 512),     # both edges in one block, lanes
+    # qwen3next-policy.update: head_dim 256, 8 q heads a k/v head, the
+    # default 1024 blocks in 256-row strips, head-major
+    ((2, 8192, 16, 256), 256, 2, None, None),
+    ((1, 1024, 8, 256), 256, 1, None, None),    # one block a head at 256
 ])
 def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
                                        layout, window):
@@ -293,3 +297,42 @@ def test_the_plain_scan_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()
     # arguments, cotangents and one group's intermediates: under 1.5 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_the_whole_rotary_is_todays_function_bit_for_bit():
+    """``apply_rope`` at a share of 1.0 (the default) is the function every
+    accepted configuration has run: the same bits as its lines written out
+    here, at a traced start too; a quarter turns the first quarter's lanes
+    as a head of that width and passes the rest through untouched. (CPU;
+    in this file beside the D-256 kernels it serves.)"""
+    from relayrl_tpu.models.transformer import apply_rope
+
+    def as_it_was(x, start, theta):
+        hd = x.shape[-1]
+        inv_freq = 1.0 / (theta ** (
+            jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+        pos = jnp.asarray(start, jnp.float32) + jnp.arange(
+            x.shape[1], dtype=jnp.float32)
+        ang = pos[:, None] * inv_freq[None, :]
+        cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 3, 16), dtype)
+        for start in (0, 5):
+            want = as_it_was(x, start, 1e4)
+            assert (apply_rope(x, start, 1e4) == want).all()
+            assert (apply_rope(x, start, 1e4, 1.0) == want).all()
+            assert (jax.jit(apply_rope, static_argnums=2)(
+                x, jnp.int32(start), 1e4) == jax.jit(
+                    as_it_was, static_argnums=2)(
+                        x, jnp.int32(start), 1e4)).all()
+        quarter = apply_rope(x, 3, 1e7, 0.25)
+        assert (quarter[..., 4:] == x[..., 4:]).all()
+        assert (quarter[..., :4] == as_it_was(x[..., :4], 3, 1e7)).all()
+        assert float(jnp.abs(quarter[..., :4].astype(jnp.float32)
+                             - x[..., :4].astype(jnp.float32)).max()) > 0.1
+    with pytest.raises(ValueError, match="rope_share"):
+        apply_rope(x, 0, 1e4, 0.2)          # 3.2 lanes of 16

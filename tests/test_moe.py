@@ -1144,3 +1144,155 @@ class TestReLU2ScalingAndTheSharedExpert:
         assert not np.allclose(policy.evaluate(params, obs, act)[2],
                                other.evaluate(params, obs, act)[2],
                                atol=1e-4)
+
+
+# -- the shared expert's gate; 32 held of 512 at top-10 (qwen3next-policy) ---
+
+def _gated_shared_layer(held, dispatch="sparse", e=512, k=10, gate=True,
+                        shared=24):
+    from relayrl_tpu.models.moe import MoEMLP
+
+    return MoEMLP(_D, _FF, e, k, jnp.float32, norm_topk_prob=True,
+                  ffn="swiglu", dispatch=dispatch, use_bias=False, held=held,
+                  shared_d_ff=shared, shared_gate=gate)
+
+
+def _gated_shared_params(seed=0, **kw):
+    x = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (2, _N // 2, _D)), jnp.float32)
+    return _gated_shared_layer(None, **kw).init(jax.random.PRNGKey(seed),
+                                                x), x
+
+
+def _swiglu_share(params, first, count):
+    p = dict(params["params"])
+    for name in ("moe_w_gate", "moe_w_up", "moe_w_down"):
+        p[name] = p[name][first:first + count]
+    return {"params": p}
+
+
+class TestTheSharedExpertsGate:
+    def test_the_layer_by_hand(self):
+        """``y = sum_chosen w_e expert_e(x) + sigmoid(x w_s) shared(x)``
+        with ``w`` the ten largest of the softmax over 512, over their
+        sum."""
+        params, x = _gated_shared_params()
+        p = params["params"]
+        assert set(p) == {"moe_gate", "moe_w_gate", "moe_w_up", "moe_w_down",
+                          "moe_shared_gate", "moe_shared_up",
+                          "moe_shared_down", "moe_shared_expert_gate"}
+        assert p["moe_shared_expert_gate"]["kernel"].shape == (_D, 1)
+        assert "bias" not in p["moe_shared_expert_gate"]
+        tokens = x.reshape(-1, _D)
+        probs = jax.nn.softmax(tokens @ p["moe_gate"]["kernel"], -1)
+        idx = np.argsort(-np.asarray(probs), -1)[:, :10]
+        picked = np.take_along_axis(np.asarray(probs), idx, -1)
+        w = picked / picked.sum(-1, keepdims=True)
+
+        def swiglu(t, gate, up, down):
+            return (jax.nn.silu(t @ gate) * (t @ up)) @ down
+
+        want = jax.nn.sigmoid(
+            tokens @ p["moe_shared_expert_gate"]["kernel"]) * swiglu(
+                tokens, p["moe_shared_gate"]["kernel"],
+                p["moe_shared_up"]["kernel"], p["moe_shared_down"]["kernel"])
+        for j in range(10):
+            e = idx[:, j]
+            inner = jax.nn.silu(jnp.einsum(
+                "nd,ndf->nf", tokens, p["moe_w_gate"][e])) * jnp.einsum(
+                    "nd,ndf->nf", tokens, p["moe_w_up"][e])
+            want = want + w[:, j:j + 1] * jnp.einsum(
+                "nf,nfd->nd", inner, p["moe_w_down"][e])
+        for dispatch in DISPATCHES:
+            got = _gated_shared_layer(None, dispatch).apply(params, x)
+            np.testing.assert_allclose(got.reshape(-1, _D), want, atol=2e-5,
+                                       rtol=1e-5, err_msg=dispatch)
+
+    def test_without_the_key_the_tree_and_the_layer_are_todays(self):
+        params, x = _gated_shared_params(gate=False)
+        assert "moe_shared_expert_gate" not in params["params"]
+        gated, _ = _gated_shared_params()
+        ungated = {"params": {k: v for k, v in gated["params"].items()
+                              if k != "moe_shared_expert_gate"}}
+        y = _gated_shared_layer(None, gate=False).apply(ungated, x)
+        assert float(jnp.abs(
+            y - _gated_shared_layer(None).apply(gated, x)).max()) > 1e-3
+
+    @pytest.mark.parametrize("held", [None, (0, 32), (37, 32)])
+    def test_sparse_matches_dense_forward_and_every_gradient(self, held):
+        """32 held of 512 at top-10 (choice-major slots: 8 does not divide
+        10) with the gated shared expert under the held layer's own
+        backward against the dense form: forward, loss and EVERY gradient
+        — tokens, router, the three stacks, the shared expert's three
+        matrices and its gate."""
+        params, x = _gated_shared_params()
+        share = params if held is None else _swiglu_share(params, *held)
+
+        def loss(dispatch):
+            def f(p, x):
+                y = _gated_shared_layer(held, dispatch).apply(p, x)
+                return jnp.sum(jnp.sin(y) * x), y
+            return f
+
+        (ls, ys), gs = jax.value_and_grad(
+            loss("sparse"), (0, 1), has_aux=True)(share, x)
+        (ld, yd), gd = jax.value_and_grad(
+            loss("dense"), (0, 1), has_aux=True)(share, x)
+        np.testing.assert_allclose(ys, yd, atol=2e-5, rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            name = jax.tree_util.keystr(path)
+            np.testing.assert_allclose(a, b, atol=3e-5, rtol=1e-4,
+                                       err_msg=name)
+            assert float(jnp.abs(a).max()) > 0, name
+
+    @pytest.mark.parametrize("passes", [1, 2])
+    def test_row_buffers_walked_in_passes_with_rows_never_written(
+            self, monkeypatch, passes):
+        held, e, k = (37, 32), 512, 10
+        params, x = _gated_shared_params()
+        share = _swiglu_share(params, *held)
+        _, state = _gated_shared_layer(held, "dense").apply(
+            share, x, mutable=["intermediates"])
+        live = int(state["intermediates"]["expert_load"][0].sum())
+        assert live > 4
+        rows = {1: live + 3, 2: -(-live // 2)}[passes]
+        _row_buffer_of(monkeypatch, rows, _N * k, held[1], e)
+        _poison_unwritten_rows(monkeypatch)
+
+        def loss(dispatch):
+            def f(p, x):
+                y, state = _gated_shared_layer(held, dispatch).apply(
+                    p, x, mutable=["intermediates"])
+                return jnp.sum(jnp.sin(y) * x), state["intermediates"]
+            return f
+
+        (ls, sown), gs = jax.value_and_grad(
+            loss("sparse"), (0, 1), has_aux=True)(share, x)
+        (ld, _), gd = jax.value_and_grad(
+            loss("dense"), (0, 1), has_aux=True)(share, x)
+        assert int(sown["row_passes"][0]) == passes
+        np.testing.assert_allclose(float(ls), float(ld), rtol=1e-5)
+        for (path, a), b in zip(
+                jax.tree_util.tree_flatten_with_path(gs)[0],
+                jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(a, b, atol=3e-5, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+
+    def test_the_row_buffer_at_a_sixteenth(self):
+        from relayrl_tpu.models.moe import row_buffer
+
+        # qwen3next-policy.update: 16,384 tokens x 10 slots, 32 of 512 held
+        assert row_buffer(163_840, 32, 512) == 20_480       # 2 x 10,240
+
+    def test_the_arch_sets_the_field(self):
+        policy, params = _policy_params(
+            ffn="swiglu", moe_shared_d_ff=24, moe_shared_expert_gate=True,
+            moe_d_ff=12, use_bias=False)
+        moe = params["params"]["block_0"]["moe"]
+        assert moe["moe_shared_expert_gate"]["kernel"].shape == (16, 1)
+        _, plain = _policy_params(ffn="swiglu", moe_shared_d_ff=24,
+                                  moe_d_ff=12, use_bias=False)
+        assert "moe_shared_expert_gate" not in plain["params"]["block_0"][
+            "moe"]
