@@ -8,7 +8,8 @@ on the TPU -> publish -> hot-swap — plus the other device programs the repo
 has (the widest transformer through ``build_algorithm``, the flash kernels
 alone at the benchmark's shapes against XLA attention, the fused anakin
 rollout, a served batch, the expert layer's grouped-matmul kernels against
-XLA's own). It checks what comes out, fails on the first thing
+XLA's own, the Mamba-2 scan's and the gated delta rule's kernels against
+their plain forms). It checks what comes out, fails on the first thing
 that is wrong (non-zero exit, one ``chip_smoke: FAIL`` line saying why; a
 phase's own exception is never caught), and ends with ONE JSON line:
 
@@ -904,6 +905,20 @@ def phase_f() -> None:
         f"{time.monotonic() - t0:.0f}s")
 
 
+def both_ways(fn, args, cotangents, chunk: int):
+    """``fn(*args[:-1], chunk=chunk, state=args[-1])``'s two results and,
+    under ``cotangents`` of both, the gradient of every argument: one jitted
+    program (phases G and H, a kernel form and its plain form each)."""
+    import jax
+
+    @jax.jit
+    def run(args, cotangents):
+        out, vjp = jax.vjp(
+            lambda *a: fn(*a[:-1], chunk=chunk, state=a[-1]), *args)
+        return (*out, *vjp(cotangents))
+    return run(args, cotangents)
+
+
 def phase_g() -> None:
     """The Mamba-2 scan at ``nemotron-twotower-policy.update``'s shape —
     two 8192-token episodes, 64 heads of 64, a state of 128, 8 groups,
@@ -937,23 +952,69 @@ def phase_g() -> None:
     cotangents = (jax.random.normal(keys[7], (b, T, H, P), lo),
                   jax.random.normal(keys[8], (b, H, P, N), jnp.float32))
 
-    def both_ways(fn):
-        @jax.jit
-        def run(args, cotangents):
-            out, vjp = jax.vjp(
-                lambda *a: fn(*a[:6], chunk=chunk, state=a[6]), *args)
-            return (*out, *vjp(cotangents))
-        return run(args, cotangents)
-
     names = ("y", "last", "dx", "ddt", "dA", "dB", "dC", "dD", "dstate")
-    errs = dict(zip(names, map(differ, both_ways(scan.ssd),
-                               both_ways(scan.ssd_xla))))
+    errs = dict(zip(names, map(
+        differ, both_ways(scan.ssd, args, cotangents, chunk),
+        both_ways(scan.ssd_xla, args, cotangents, chunk))))
     for what, err in errs.items():
         check(err <= 2.0 ** -6,
               f"G: the scan kernels' {what} differs from the plain form's "
               f"by {err:.3g} of its largest entry (limit 2^-6)")
     say(f"G: ok — ssd_fwd / ssd_states / ssd_bwd against the plain form at "
         f"{(b, T, H, P)} state {N} groups {G} chunk {chunk} bfloat16: "
+        f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}, "
+        f"{time.monotonic() - t0:.0f}s")
+
+
+def phase_h() -> None:
+    """The gated delta rule at ``qwen3next-policy.update``'s shape — two
+    8192-token episodes, 32 value heads over 16 key heads of 128, chunks of
+    64, bfloat16, from a carried state — through the Pallas kernels
+    (``ops/gdn_pallas.py``: ``gdn_fwd``, and under a random cotangent of
+    both results ``gdn_states`` + ``gdn_bwd``) and through the plain form
+    (``ops/gdn.gdn_xla``) on the same operands: ``o``, the last state and
+    the gradients of all five arguments and of the carried state, each
+    within 2^-6 of the plain form's largest entry (both round their
+    matmuls' operands to bfloat16; phases E-G's limit). ``gdn()`` itself
+    has to pick the kernels here, and says so."""
+    import jax
+    import jax.numpy as jnp
+
+    from relayrl_tpu.ops import gdn as rule
+
+    t0 = time.monotonic()
+    b, T, Hk, H, K, V, chunk = 2, 8192, 16, 32, 128, 128, 64
+    check(rule.backend(T, H, Hk, K, V, chunk) == rule.PALLAS,
+          f"H: gdn() would run {rule.backend(T, H, Hk, K, V, chunk)} at heads "
+          f"{Hk} x {K} under {H} x {V}, chunk {chunk} on a TPU")
+    keys = jax.random.split(jax.random.PRNGKey(43), 8)
+    lo = jnp.bfloat16
+
+    def unit(key, scale):    # as the mixer's L2 norm leaves q and k
+        a = jax.random.normal(key, (b, T, Hk, K), jnp.float32)
+        return (a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+                * scale).astype(lo)
+
+    args = (unit(keys[0], K ** -0.5), unit(keys[1], 1.0),
+            jax.random.normal(keys[2], (b, T, H, V), lo),
+            # log decays from a state that spans chunks to one that forgets
+            # within a few tokens
+            -jax.random.uniform(keys[3], (b, T, H), jnp.float32, 1e-3, 2.0),
+            jax.random.uniform(keys[4], (b, T, H), jnp.float32),
+            jax.random.normal(keys[5], (b, H, K, V), jnp.float32))
+    cotangents = (jax.random.normal(keys[6], (b, T, H, V), lo),
+                  jax.random.normal(keys[7], (b, H, K, V), jnp.float32))
+
+    names = ("o", "last", "dq", "dk", "dv", "dg", "dbeta", "dstate")
+    errs = dict(zip(names, map(
+        differ, both_ways(rule.gdn, args, cotangents, chunk),
+        both_ways(rule.gdn_xla, args, cotangents, chunk))))
+    for what, err in errs.items():
+        check(err <= 2.0 ** -6,
+              f"H: the delta rule's kernels' {what} differs from the plain "
+              f"form's by {err:.3g} of its largest entry (limit 2^-6)")
+    say(f"H: ok — gdn_fwd / gdn_states / gdn_bwd against the plain form at "
+        f"{(b, T, Hk, K)} | {(H, V)} chunk {chunk} bfloat16: "
         f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}, "
         f"{time.monotonic() - t0:.0f}s")
 
@@ -1024,6 +1085,7 @@ def run(dev: dict, t_start: float) -> None:
     phase_e()
     phase_f()
     phase_g()
+    phase_h()
 
     say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
         f"the persistent cache, {compiles.requests - compiles.hits} compiled "

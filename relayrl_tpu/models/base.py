@@ -161,9 +161,11 @@ class Policy:
     # trunk without such layers, None for other families.
     scan_backends: Mapping[tuple, str] | None = None
     # Sequence policies with linear-attention layers: ``{(T, value heads,
-    # key width, value width, dtype): "gdn_xla"}`` for every delta-rule
-    # shape traced so far (models/transformer._resolve_delta_rule). Empty
-    # for a trunk without such layers, None for other families.
+    # key width, value width, dtype): "gdn_pallas" | "gdn_xla"}`` for every
+    # delta-rule shape traced so far (models/transformer._resolve_delta_rule)
+    # — whether ``ops/gdn.py`` ran the Pallas kernels (a TPU, shapes that
+    # tile) or plain XLA. Empty for a trunk without such layers, None for
+    # other families.
     gdn_backends: Mapping[tuple, str] | None = None
     # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
     # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward

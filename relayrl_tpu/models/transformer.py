@@ -99,6 +99,7 @@ context of one.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Mapping, Sequence
 
 import jax
@@ -127,6 +128,7 @@ from relayrl_tpu.ops.scopes import (  # noqa: F401  (SHORT_CONV_NAME's home)
     OP_PROJ,
     SHORT_CONV_NAME,
 )
+from relayrl_tpu.ops.gdn import SOLVE_NAME as _GDN_SOLVE
 from relayrl_tpu.ops.gdn import backend as gdn_backend
 from relayrl_tpu.ops.gdn import gdn, gdn_step
 from relayrl_tpu.ops.ssd import backend as ssd_backend
@@ -311,14 +313,15 @@ def _resolve_delta_rule() -> tuple[Callable, dict]:
     ``ops.gdn.gdn`` behind a record of what it ran as, as
     :func:`_resolve_scan`: ``resolved`` (``Policy.gdn_backends``) maps every
     traced ``(T, value heads, key width, value width, dtype)`` to
-    ``ops.gdn.backend``'s answer (``gdn_xla``: the one form today), and each
-    new entry prints one ``[gdn]`` line naming the platform."""
+    ``ops.gdn.backend``'s answer (``gdn_pallas`` | ``gdn_xla``), and each new
+    entry prints one ``[gdn]`` line naming the platform."""
     resolved: dict[tuple, str] = {}
 
     def rule_fn(q, k, v, g, beta, chunk, state):
         key = (int(v.shape[1]), int(v.shape[2]), int(k.shape[3]),
                int(v.shape[3]), v.dtype.name)
-        ran = gdn_backend(*key[:4], chunk)
+        ran = gdn_backend(key[0], key[1], int(k.shape[2]), key[2], key[3],
+                          chunk)
         if resolved.get(key) != ran:
             resolved[key] = ran
             print(f"[gdn] T={key[0]} heads={key[1]}/{k.shape[2]} "
@@ -936,6 +939,30 @@ def _mamba_layer(block: TransformerBlock, x, cache, n_valid):
     return out, (rows.astype(cache[0].dtype), state)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normed_heads(a, heads: int, eps: float, mean: bool):
+    """``a [..., heads * width]`` (float32) with each head's columns scaled
+    by ``rsqrt(sum of their squares + eps)`` (``mean``: of their mean
+    square): a head's lane-aligned slice at a time where a head is whole
+    lane tiles, as the delta rule's kernels read and write them — no ``[...,
+    heads, width]`` view of the rows (that view splits the lanes, and XLA
+    copies 0.13 to 0.27 GB to make it, some thirty times a layer: PERF.md
+    section 6, PR 43) —, through the view everywhere else. Jitted: a trunk's
+    layers share ONE trace and one lowering of the slices (2.4 s of every
+    process's start otherwise)."""
+    def normed(cols):
+        squares = jnp.square(cols)
+        size = (jnp.mean if mean else jnp.sum)(squares, -1, keepdims=True)
+        return cols * jax.lax.rsqrt(size + eps)
+
+    width = a.shape[-1] // heads
+    if width % 128:
+        return normed(a.reshape(a.shape[:-1] + (heads, width))).reshape(
+            a.shape)
+    return jnp.concatenate(
+        [normed(cols) for cols in jnp.split(a, heads, axis=-1)], axis=-1)
+
+
 def _gdn_conv(qkv, w, state=None):
     """A linear-attention layer's convolution: ``silu(conv(qkv))`` over q,
     k and v together, :func:`_mamba_conv` WITHOUT a bias under the scope
@@ -990,10 +1017,9 @@ def _gdn_layer(block: TransformerBlock, x, cache, n_valid):
         block.param("gdn_out", lecun, (vw, d), f32))
     eps = 1e-6 if block.norm_eps is None else float(block.norm_eps)
 
-    def l2_normed(a):   # over a head's width, float32 (eps as the source's)
-        a = a.astype(f32)
-        return a * jax.lax.rsqrt(
-            jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+    def l2_normed(a, heads):
+        # over a head's width, float32 (eps as the source's)
+        return _normed_heads(a.astype(f32), heads, 1e-6, False)
 
     def mix(h, weights, conv_rows, state, n_valid):
         """normed rows -> (the mixer's output, [q | k | v] with the rows
@@ -1013,8 +1039,9 @@ def _gdn_layer(block: TransformerBlock, x, cache, n_valid):
         qkv, padded = _gdn_conv(qkv, conv_w, conv_rows)
         with jax.named_scope(OP_PROJ):
             q, k, v = jnp.split(qkv, [kw, 2 * kw], axis=-1)
-            q = (l2_normed(q.reshape(Bsz, T, Hk, K)) * K ** -0.5).astype(cd)
-            k = l2_normed(k.reshape(Bsz, T, Hk, K)).astype(cd)
+            q = (l2_normed(q, Hk) * K ** -0.5).astype(cd).reshape(
+                Bsz, T, Hk, K)
+            k = l2_normed(k, Hk).astype(cd).reshape(Bsz, T, Hk, K)
             v = v.reshape(Bsz, T, H, V)
         if T == 1:
             # one row is one step of the rule, from the cache's state or
@@ -1027,18 +1054,15 @@ def _gdn_layer(block: TransformerBlock, x, cache, n_valid):
         else:
             o, state = block.rule_fn(q, k, v, g, beta, block.gdn_chunk,
                                      state)
-        o = checkpoint_name(o, _GDN_OUT)
+        # named (and kept) with the heads side by side in the lanes, as the
+        # rule's kernels write it
+        o = checkpoint_name(o.reshape(Bsz, T, vw), _GDN_OUT)
         with jax.named_scope(OP_PROJ):
             # the norm over each head's width BEFORE the gate, plain weight
-            # (per-head norms a head's lane-aligned columns at a time, as
-            # _mamba_layer's, were measured: 9 ms an update and 1.3 GB more
-            # than this view costs in XLA's copies; PERF.md section 6, PR 42)
-            o = o.astype(f32)
-            y = o * jax.lax.rsqrt(
-                jnp.mean(jnp.square(o), -1, keepdims=True) + eps) * scale
-            y = y * nn.silu(z.astype(f32).reshape(Bsz, T, H, V))
-            return (jnp.dot(y.reshape(Bsz, T, vw).astype(cd),
-                            w_out.astype(cd)), padded, state)
+            y = _normed_heads(o.astype(f32), H, eps, True) * jnp.tile(
+                scale, H)
+            y = y * nn.silu(z.astype(f32))
+            return jnp.dot(y.astype(cd), w_out.astype(cd)), padded, state
 
     with jax.named_scope(OP_PROJ):
         layer_in = x
@@ -1047,12 +1071,14 @@ def _gdn_layer(block: TransformerBlock, x, cache, n_valid):
         # Full mode, the learner's: the mixer's inner activations (the
         # 12,288-wide projection, the convolution's rows, the normed q and
         # k, the gated norm in float32) are made again in the backward from
-        # the normed rows; of them only the rule's output is kept, so that
-        # the backward runs the rule's backward alone and never its forward
-        # a second time (ops/gdn.py), as _mamba_layer.
+        # the normed rows; of them only the rule's output is kept — and,
+        # where the rule runs as kernels, the solve's tiles their forward
+        # wrote (67 MB a layer) —, so that the backward runs the rule's
+        # backward alone and never its forward a second time (ops/gdn.py),
+        # as _mamba_layer.
         y, _, _ = jax.checkpoint(
             mix, policy=jax.checkpoint_policies.save_only_these_names(
-                _GDN_OUT))(h, weights, None, None, None)
+                _GDN_OUT, _GDN_SOLVE))(h, weights, None, None, None)
     else:
         y, padded, state = mix(h, weights, *cache, n_valid)
     with jax.named_scope(OP_PROJ):

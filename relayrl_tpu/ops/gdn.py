@@ -28,9 +28,9 @@ gamma_j)`` on and under the diagonal:
   precision "highest", no loop over the chunk's rows in the trace (forward
   substitution row by row is the same matrix in ``chunk`` dependent steps);
   then ``W = T (K_beta (.) e^gamma)`` and ``U = T (beta v)``;
-* **across chunks**, ONE ``lax.scan`` over the ``T / chunk`` chunks carrying
-  the heads' ``[K, V]`` states in float32 (128 steps at T 8192, the only
-  part sequential in T; no Python loop over the chunks in the trace):
+* **across chunks**, the ``T / chunk`` chunks in order, carrying the heads'
+  ``[K, V]`` states in float32 (128 steps at T 8192, the only part
+  sequential in T; no Python loop over the chunks in the trace):
   ``v' = U - W S``, ``o = (Q (.) e^gamma) S + (Q K^T (.) decay (.) causal)
   v'``, ``S <- e^{gamma_C} S + (K (.) e^{gamma_C - gamma})^T v'``.
 
@@ -48,22 +48,36 @@ sees a later one (``tests/test_gdn.py``). A caller that wants the state
 after its first ``n`` rows zeroes ``g`` and ``beta`` from row ``n`` on
 (``models/transformer.py``'s prefill).
 
-**One form, plain XLA** (``gdn_xla``, what :func:`backend` names on every
-platform; a Pallas kernel is a later ``perf_opt`` issue that starts from the
-benchmark's ``gdn_roofline`` as ``ops/ssd_pallas.py`` started from
-``ssd_roofline``): ``_HEADS_A_STEP`` value heads a step of a ``lax.map``,
-**backward by autodiff under ``jax.checkpoint``** — a step's intermediates
-(the ``[chunk, chunk]`` tiles, ``T``, ``W``, ``U`` and the chunk-start
-states) are made again from its arguments, which is all the forward keeps.
-A hand-written ``custom_vjp`` would have to carry the reverse recurrence of
-the state AND the transpose of the solve; autodiff of the product form is
-six more matmuls and needs no second proof, and the checkpoint bounds what
-it keeps (PERF.md section 6, PR 42, has what ``rehearse_compile`` and the
-chip said). All of it sits under one named scope, ``relayrl_gdn``
-(``ops/scopes.py``), and no deeper ``relayrl_`` name: the benchmark's
-``gdn_ms`` / ``gdn_roofline`` read the exact scope.
-``models/transformer._resolve_delta_rule`` records what a policy's rules ran
-as (``Policy.gdn_backends``) and prints one ``[gdn]`` line a shape.
+**Two forms of the same algebra, picked by what the code can observe**
+(:func:`backend`; no arch key, no environment variable, no switch):
+
+* ``gdn_pallas`` — on a TPU, for shapes that tile (``K`` and ``V`` whole lane
+  tiles, eight value heads a grid step with whole key heads, a chunk of 64 or
+  128: :func:`relayrl_tpu.ops.gdn_pallas.fits`) and at least one whole chunk
+  of rows: the Pallas kernels of :mod:`relayrl_tpu.ops.gdn_pallas`, a grid
+  over (sequence, eight value heads, chunk) with the chunk axis sequential, a
+  chunk's tiles, its solve and the carried state in VMEM, ``q`` / ``k`` /
+  ``v`` / ``o`` as the projections leave them (no head transpose), a
+  hand-written backward (``jax.custom_vjp``: the chunk-start states made
+  again by one kernel, the reverse sweep by another, the solve's transpose
+  two matmuls and no second inversion). ``qwen3next-policy.update`` runs
+  them (PERF.md section 6, PR 43: 280 ms an update of plain XLA at 3.0 % of
+  its roofline before them).
+* ``gdn_xla`` (:func:`gdn_xla`) — everywhere else (CPU actor hosts, CI, a
+  shape that does not tile, a prompt shorter than a chunk) and the reference
+  the kernels' tests hold them to: plain XLA, ``_HEADS_A_STEP`` value heads a
+  step of a ``lax.map``, heads before rows inside, the chunks' states carried
+  by ONE ``lax.scan``, **backward by autodiff under ``jax.checkpoint``** — a
+  step's intermediates (the ``[chunk, chunk]`` tiles, ``T``, ``W``, ``U`` and
+  the chunk-start states) are made again from its arguments, which is all
+  the forward keeps (PERF.md section 6, PR 42, has what ``rehearse_compile``
+  and the chip said).
+
+Both sit under one named scope, ``relayrl_gdn`` (``ops/scopes.py``), and no
+deeper ``relayrl_`` name: the benchmark's ``gdn_ms`` / ``gdn_roofline`` read
+the exact scope. ``models/transformer._resolve_delta_rule`` records which
+form a policy's rules ran as (``Policy.gdn_backends``) and prints one
+``[gdn]`` line a shape.
 
 :func:`gdn_step` is the rule's one step, what a cached decode runs.
 """
@@ -78,7 +92,10 @@ import jax.numpy as jnp
 from relayrl_tpu.ops.scopes import GDN_NAME
 
 # what a rule ran as (``backend``; ``Policy.gdn_backends``)
-XLA = "gdn_xla"
+PALLAS, XLA = "gdn_pallas", "gdn_xla"
+# what a differentiated kernel forward keeps beside its arguments, by the name
+# a caller's checkpoint policy saves it under (``ops/gdn_pallas.py``)
+SOLVE_NAME = "relayrl_gdn_solve"
 
 # Value heads a step of the map over heads: the chunk scan's 128 steps are
 # the sequential part, so the fewer map steps the better, while a step's
@@ -169,25 +186,13 @@ def _heads(args, chunk: int):
     return o.astype(cd), last
 
 
-def backend(T: int, H: int, K: int, V: int, chunk: int) -> str:
-    """What :func:`gdn` runs a rule of these shapes as on this process's
-    platform: ``"gdn_xla"`` everywhere today. Platform and shape would
-    decide, nothing else (no arch key, no environment variable): the seam a
-    kernel will be picked at, as ``ops.ssd.backend``."""
-    return XLA
-
-
-def gdn(q, k, v, g, beta, chunk: int = 64, state=None):
-    """``q, k [b, T, Hk, K]`` (as they enter the rule: normalised and scaled
-    by the caller), ``v [b, T, H, V]`` with ``Hk`` dividing ``H``, ``g [b,
-    T, H]`` (log decay, <= 0) and ``beta [b, T, H]`` float32, ``state [b,
-    H, K, V]`` float32 (None: zeros, a sequence's start) -> ``(o [b, T, H,
-    V]`` in ``v``'s dtype, ``last_state [b, H, K, V]`` float32``)``."""
+def gdn_xla(q, k, v, g, beta, chunk: int = 64, state=None):
+    """:func:`gdn` as plain XLA, ``_HEADS_A_STEP`` value heads a step of a
+    ``lax.map``: every backend takes it, and the kernels' tests hold them to
+    it."""
     with jax.named_scope(GDN_NAME):
         b, T, H, V = v.shape
         Hk, K = k.shape[2:]
-        if H % Hk:
-            raise ValueError(f"{Hk} key heads do not divide {H} value heads")
         f32 = jnp.float32
         if state is None:
             state = jnp.zeros((b, H, K, V), f32)
@@ -219,6 +224,40 @@ def gdn(q, k, v, g, beta, chunk: int = 64, state=None):
         o = jnp.swapaxes(jnp.moveaxis(o, 0, 1).reshape(b, H, T + pad, V),
                          1, 2)
         return o[:, :T], jnp.moveaxis(last, 0, 1).reshape(b, H, K, V)
+
+
+def backend(T: int, H: int, Hk: int, K: int, V: int, chunk: int) -> str:
+    """``"gdn_pallas"`` or ``"gdn_xla"``: what :func:`gdn` runs a rule of
+    these shapes as on this process's platform. The kernels on a TPU where
+    the shapes tile (``gdn_pallas.fits``) and there is a whole chunk of
+    rows, plain XLA everywhere else — CPU actor hosts, CI, a shape that does
+    not tile, a rule shorter than a chunk (a short prompt: the kernels' grid
+    would be one step of padding, and their lowering a second of every
+    process's start). Platform and shape decide, nothing else: no arch key,
+    no environment variable."""
+    if jax.default_backend() != "tpu" or T < chunk:
+        return XLA
+    from relayrl_tpu.ops import gdn_pallas
+
+    return PALLAS if gdn_pallas.fits(H, Hk, K, V, chunk) else XLA
+
+
+def gdn(q, k, v, g, beta, chunk: int = 64, state=None):
+    """``q, k [b, T, Hk, K]`` (as they enter the rule: normalised and scaled
+    by the caller), ``v [b, T, H, V]`` with ``Hk`` dividing ``H``, ``g [b,
+    T, H]`` (log decay, <= 0) and ``beta [b, T, H]`` float32, ``state [b,
+    H, K, V]`` float32 (None: zeros, a sequence's start) -> ``(o [b, T, H,
+    V]`` in ``v``'s dtype, ``last_state [b, H, K, V]`` float32``)``, as
+    :func:`backend` says."""
+    T, H, V = v.shape[1:]
+    Hk, K = k.shape[2:]
+    if H % Hk:
+        raise ValueError(f"{Hk} key heads do not divide {H} value heads")
+    if backend(T, H, Hk, K, V, chunk) == PALLAS:
+        from relayrl_tpu.ops.gdn_pallas import gdn_pallas
+
+        return gdn_pallas(q, k, v, g, beta, chunk, state)
+    return gdn_xla(q, k, v, g, beta, chunk, state)
 
 
 def gdn_step(q, k, v, g, beta, state):

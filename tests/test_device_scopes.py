@@ -322,6 +322,54 @@ def test_the_scan_kernels_count_for_the_scans_name(monkeypatch):
     assert bare.as_text() == named.as_text()
 
 
+def _lower_rule_kernels():
+    """The delta rule's Pallas kernels (what ``one_part``'s linear-attention
+    layers run at shapes that tile, on a TPU), lowered through the
+    interpreter: forward and the ``custom_vjp``'s backward, every
+    gradient."""
+    from relayrl_tpu.ops import gdn_pallas
+
+    for cached in (gdn_pallas._build, gdn_pallas._make_rule):
+        cached.cache_clear()        # a call is named where it is built
+    b, T, Hk, H, K = 1, 128, 4, 8, 128
+    S = jax.ShapeDtypeStruct
+    args = [S(shape, jnp.float32) for shape in (
+        (b, T, Hk, K), (b, T, Hk, K), (b, T, H, K), (b, T, H), (b, T, H),
+        (b, H, K, K))]
+
+    def loss(*a):
+        o, last = gdn_pallas.gdn_pallas(*a[:5], state=a[5], interpret=True)
+        return jnp.sum(o) + jnp.sum(last)
+
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(
+        *args)
+
+
+def test_the_rule_kernels_count_for_the_rules_name(monkeypatch):
+    """Every kernel call's innermost ``relayrl_`` name is ``relayrl_gdn``
+    — ``gdn_ms`` reads the exact scope — in the forward and in the backward
+    rule, which opens the scope itself; and the names are metadata."""
+    from relayrl_tpu.ops import gdn_pallas
+
+    named = _lower_rule_kernels()
+    paths = set(re.findall(r'loc\("(jit\([^"]*)"',
+                           named.as_text(debug_info=True)))
+    for kernel, backward in ((gdn_pallas.FWD_NAME, False),
+                             (gdn_pallas.STATES_NAME, True),
+                             (gdn_pallas.BWD_NAME, True)):
+        mine = [p for p in paths if re.search(rf"/{kernel}(/|$)", p)]
+        assert mine, kernel
+        for path in mine:
+            assert re.findall(r"relayrl_\w+", path)[-1] == scopes.GDN_NAME, (
+                path)
+            assert ("transpose(" in path) == backward, path
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _lower_rule_kernels()
+    assert scopes.GDN_NAME not in bare.as_text(debug_info=True)
+    assert bare.as_text() == named.as_text()
+
+
 def test_the_doc_names_every_scope():
     """``docs/observability.md``, "Device names": every part and every
     kernel name of the one list, each beside what reads it."""

@@ -299,6 +299,89 @@ def test_the_plain_scan_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def _rule_at_the_cells_shape(one_chip, fn, wrt):
+    """``fn`` (a form of ``ops/gdn.py``'s rule) at
+    ``qwen3next-policy.update``'s shape — two 8192-token episodes, 32 value
+    heads over 16 key heads of 128, chunks of 64, bfloat16 from a carried
+    state —, a loss that reads both results differentiated with respect to
+    ``wrt`` (none: the two results themselves), compiled for the described
+    chip."""
+    b, t, hk, h, w = 2, 8192, 16, 32, 128
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    args = (S((b, t, hk, w), jnp.bfloat16), S((b, t, hk, w), jnp.bfloat16),
+            S((b, t, h, w), jnp.bfloat16), S((b, t, h), jnp.float32),
+            S((b, t, h), jnp.float32), S((b, h, w, w), jnp.float32))
+
+    def loss(*a):
+        o, last = fn(*a[:5], chunk=64, state=a[5])
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(last)
+
+    step = (jax.value_and_grad(loss, argnums=wrt) if wrt
+            else lambda *a: fn(*a[:5], chunk=64, state=a[5]))
+    return jax.jit(step).lower(*args).compile()
+
+
+def _rule_paths(compiled):
+    paths = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    return [path for path in paths if scopes.GDN_NAME in path]
+
+
+def test_the_delta_rule_compiles_for_v5e(one_chip):
+    """The rule's Pallas kernels (``ops/gdn_pallas.py``), forward and every
+    gradient: three Mosaic calls — ``gdn_fwd``, and in the backward
+    ``gdn_states`` + ``gdn_bwd`` — each under ``relayrl_gdn`` and under no
+    deeper ``relayrl_`` name (the benchmark's ``gdn_ms`` reads the exact
+    scope), no loop left under the scope, and of chunk-shaped arrays only
+    the chunk-start states (0.54 GB), the solve's tiles (0.13 GB as the
+    chip pads their 64 lanes) and the 4 MB columns in HBM: temporaries
+    under 1.2 GB."""
+    from relayrl_tpu.ops import gdn_pallas
+
+    compiled = _rule_at_the_cells_shape(one_chip, gdn_pallas.gdn_pallas,
+                                        tuple(range(6)))
+    text = compiled.as_text()
+    calls = re.findall(r'(%[\w.\-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) == [
+        "%" + gdn_pallas.BWD_NAME, "%" + gdn_pallas.FWD_NAME,
+        "%" + gdn_pallas.STATES_NAME]
+    for name, path in calls:
+        assert re.findall(r"relayrl_\w+", path)[-1] == scopes.GDN_NAME, path
+        assert ("transpose(" in path) == (gdn_pallas.FWD_NAME not in name)
+    mine = _rule_paths(compiled)
+    assert [path for path in mine if "transpose(" in path]
+    assert [path for path in mine if "transpose(" not in path]
+    assert not re.findall(r"\bwhile\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+def test_a_rule_nobody_differentiates_writes_no_state(one_chip):
+    """The prefill's call: ``gdn_fwd`` alone, no chunk-shaped temporary but
+    the columns."""
+    from relayrl_tpu.ops import gdn_pallas
+
+    compiled = _rule_at_the_cells_shape(one_chip, gdn_pallas.gdn_pallas, ())
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and gdn_pallas.FWD_NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
+
+
+def test_the_plain_rule_compiles_for_v5e(one_chip):
+    """``ops/gdn.gdn_xla`` at the same shape — what a shape that does not
+    tile runs on a TPU: plain XLA under its own name in both directions,
+    eight heads' intermediates alive at a time."""
+    from relayrl_tpu.ops.gdn import gdn_xla
+
+    compiled = _rule_at_the_cells_shape(one_chip, gdn_xla, tuple(range(5)))
+    mine = _rule_paths(compiled)
+    assert [path for path in mine if "transpose(" in path]
+    assert [path for path in mine if "transpose(" not in path]
+    assert "tpu_custom_call" not in compiled.as_text()
+    # arguments, cotangents and a step's intermediates: 2.3 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 def test_the_whole_rotary_is_todays_function_bit_for_bit():
     """``apply_rope`` at a share of 1.0 (the default) is the function every
     accepted configuration has run: the same bits as its lines written out

@@ -4,7 +4,10 @@ k_t (v_t - S~^T k_t)^T``, ``o_t = S^T q_t``) — outputs, the last state and
 the gradients of all five arguments, at T a multiple of the chunk and not,
 from a carried state, and under right padding; ``beta = 0`` leaves the state
 decayed only; one decode step is one step of the rule; and the record a
-policy keeps of what its rules ran as."""
+policy keeps of what its rules ran as. The plain form first (what every
+backend but a TPU runs), then the Pallas kernels of ``ops/gdn_pallas.py`` in
+the interpreter at small shapes that tile, against the rule and against the
+plain form, and the rule that picks between the two."""
 
 import jax
 import jax.numpy as jnp
@@ -235,8 +238,290 @@ def test_the_policy_records_what_its_rules_ran_as(capsys):
     jax.eval_shape(policy.evaluate, params, jnp.zeros((2, 8, 6)),
                    jnp.zeros((2, 8), jnp.int32), jnp.ones((2, 8, 3), bool))
     assert policy.gdn_backends[(8, 4, 8, 8, "float32")] == rule.XLA
-    assert rule.backend(8, 4, 8, 8, 4) == rule.XLA
     said = [line for line in capsys.readouterr().out.splitlines()
             if line.startswith("[gdn]")]
     assert len(said) == 1 and "T=8 " in said[0]       # one line a shape
     assert said[0].endswith("-> gdn_xla (platform cpu)")
+
+
+# -- the Pallas kernels, in the interpreter ---------------------------------
+
+# (key heads, value heads): eight value heads a grid step over 4, 8 and 2
+# key heads (2, 1 and 4 value heads a key head), and two steps of heads
+GROUPINGS = [(4, 8), (8, 8), (2, 8), (8, 16)]
+WIDTH, CHUNK = 128, 64
+WRT = ARGS + ("state",)
+
+
+# jitted: an eager call traces and compiles the interpreted kernels op by op
+@jax.jit
+def _kernels(**kw):
+    from relayrl_tpu.ops.gdn_pallas import gdn_pallas
+
+    return gdn_pallas(**kw, chunk=CHUNK, interpret=True)
+
+
+@jax.jit
+def _plain(**kw):
+    return rule.gdn_xla(**kw, chunk=CHUNK)
+
+
+def _tiled(T, grouping=GROUPINGS[0], seed=0, batch=1):
+    hk, h = grouping
+    a = _inputs(T, seed, batch, HK=hk, H=h, K=WIDTH, V=WIDTH)
+    a["state"] = jnp.asarray(np.random.default_rng(seed + 7).standard_normal(
+        (batch, h, WIDTH, WIDTH)), jnp.float32)
+    return a
+
+
+@pytest.mark.parametrize("T", [128, 150])
+def test_kernels_are_the_rule(T):
+    """From a carried state, at whole chunks and padded on the right; in
+    float32 the forward is the plain form's to the last bits."""
+    a = _tiled(T, batch=2)
+    o, last = _kernels(**a)
+    o_ref, last_ref = step_by_step(**a)
+    np.testing.assert_allclose(o, o_ref, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(last, last_ref, atol=1e-4, rtol=1e-4)
+    o_plain, last_plain = _plain(**a)
+    np.testing.assert_allclose(o, o_plain, atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(last, last_plain, atol=1e-6, rtol=1e-6)
+
+
+@pytest.fixture(scope="module")
+def gradients():
+    """grouping -> form -> ``((o, last state), the gradients of a loss that
+    reads both with respect to all six arguments)``; T 150 (a padded third
+    chunk), made once a grouping and form."""
+    made: dict = {}
+
+    def of(grouping, form):
+        if (grouping, form) not in made:
+            a = _tiled(150, grouping, seed=1)
+            rng = np.random.default_rng(2)
+            wo = jnp.asarray(rng.standard_normal(a["v"].shape), jnp.float32)
+            ws = jnp.asarray(rng.standard_normal(a["state"].shape),
+                             jnp.float32)
+            fn = {"kernels": _kernels, "plain": _plain,
+                  "rule": step_by_step}[form]
+
+            def loss(a):
+                o, last = fn(**a)
+                return jnp.sum(wo * o) + jnp.sum(ws * last), (o, last)
+
+            grads, out = jax.jit(jax.grad(loss, has_aux=True))(a)
+            made[grouping, form] = out, grads
+        return made[grouping, form]
+
+    return of
+
+
+@pytest.mark.parametrize("against", ["rule", "plain"])
+@pytest.mark.parametrize("wrt", WRT)
+def test_kernel_gradients(gradients, wrt, against):
+    """``gdn_states`` + ``gdn_bwd``: no term of any gradient left out."""
+    got = gradients(GROUPINGS[0], "kernels")[1][wrt]
+    want = gradients(GROUPINGS[0], against)[1][wrt]
+    scale = float(jnp.abs(want).max())
+    assert scale > 0
+    np.testing.assert_allclose(got, want, atol=1e-4 * max(1.0, scale),
+                               rtol=1e-3)
+
+
+@pytest.mark.parametrize("grouping", GROUPINGS[1:])
+def test_kernels_at_other_groupings(gradients, grouping):
+    """One, four and (over two grid steps) two value heads a key head:
+    outputs and every gradient are the plain form's."""
+    (out, got), (out_plain, want) = (gradients(grouping, form)
+                                     for form in ("kernels", "plain"))
+    for mine, plain in zip(out, out_plain):
+        np.testing.assert_allclose(mine, plain, atol=1e-4, rtol=1e-4)
+    for wrt in WRT:
+        scale = max(1.0, float(jnp.abs(want[wrt]).max()))
+        np.testing.assert_allclose(got[wrt], want[wrt], atol=1e-4 * scale,
+                                   rtol=1e-3, err_msg=wrt)
+
+
+def test_kernels_at_a_chunk_of_two_lane_halves():
+    """Chunks of 128, the other size ``fits`` takes: outputs and every
+    gradient are the plain form's at that chunk."""
+    from relayrl_tpu.ops.gdn_pallas import gdn_pallas
+
+    a = _tiled(200, seed=4)
+
+    def both(fn):
+        def loss(a):
+            o, last = fn(**a, chunk=128)
+            return jnp.sum(o) + jnp.sum(last ** 2), (o, last)
+        return jax.jit(jax.grad(loss, has_aux=True))(a)
+
+    got, out = both(lambda **kw: gdn_pallas(**kw, interpret=True))
+    want, out_plain = both(rule.gdn_xla)
+    for mine, plain in zip(out, out_plain):
+        np.testing.assert_allclose(mine, plain, atol=1e-4, rtol=1e-4)
+    for wrt in WRT:
+        scale = max(1.0, float(jnp.abs(want[wrt]).max()))
+        np.testing.assert_allclose(got[wrt], want[wrt], atol=1e-4 * scale,
+                                   rtol=1e-3, err_msg=wrt)
+
+
+def test_kernels_carry_a_state_in_and_out():
+    """Two calls, the second from the first's last state, are one call."""
+    a = _tiled(192)
+    a.pop("state")
+    whole, last = _kernels(**a)
+    cut = 83                              # inside a chunk
+    head, state = _kernels(**{n: x[:, :cut] for n, x in a.items()})
+    tail, last2 = _kernels(**{n: x[:, cut:] for n, x in a.items()},
+                           state=state)
+    np.testing.assert_allclose(jnp.concatenate([head, tail], 1), whole,
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(last2, last, atol=1e-4, rtol=1e-4)
+
+
+def test_kernels_right_padding_and_the_calls_own_are_inert():
+    a = _tiled(128)
+    n = 83
+    real = {name: x[:, :n] if name != "state" else x
+            for name, x in a.items()}
+    o_real, last_real = _kernels(**real)     # padded to 128 inside the call
+    np.testing.assert_allclose(_kernels(**a)[0][:, :n], o_real, atol=1e-5,
+                               rtol=1e-5)
+    np.testing.assert_allclose(last_real, step_by_step(**real)[1],
+                               atol=1e-4, rtol=1e-4)
+    padded = dict(a, g=a["g"].at[:, n:].set(0.0),
+                  beta=a["beta"].at[:, n:].set(0.0))
+    np.testing.assert_allclose(_kernels(**padded)[1], last_real, atol=1e-5,
+                               rtol=1e-5)
+
+
+def test_kernels_decays_that_underflow_stay_finite():
+    a = _tiled(128)
+    a["g"] = jnp.full_like(a["g"], -60.0)     # exp(-60 * 64) is 0 in float32
+    o, last = _kernels(**a)
+    grads = jax.jit(jax.grad(lambda a: jnp.sum(_kernels(**a)[0])))(a)
+    assert all(bool(jnp.isfinite(x).all())
+               for x in (o, last, *grads.values()))
+    np.testing.assert_allclose(o, step_by_step(**a)[0], atol=1e-4, rtol=1e-4)
+
+
+def test_kernels_bfloat16_operands_accumulate_in_float32():
+    """The kernels round where the plain form rounds: in bfloat16 the two
+    agree to the last place of the largest entry, forward and backward."""
+    a = _tiled(128)
+    lo = {n: x.astype(jnp.bfloat16) if n in ("q", "k", "v") else x
+          for n, x in a.items()}
+    o, last = _kernels(**lo)
+    assert o.dtype == jnp.bfloat16 and last.dtype == jnp.float32
+    o_ref, last_ref = _plain(**lo)
+    f32 = lambda x: x.astype(jnp.float32)
+    scale = float(jnp.abs(f32(o_ref)).max())
+    assert float(jnp.abs(f32(o) - f32(o_ref)).max()) <= scale * 2.0 ** -7
+    np.testing.assert_allclose(last, last_ref, atol=1e-3, rtol=1e-3)
+    got, want = (jax.jit(jax.grad(lambda a: jnp.sum(f32(fn(**a)[0]))))(lo)
+                 for fn in (_kernels, _plain))
+    for wrt in WRT:
+        assert got[wrt].dtype == want[wrt].dtype
+        scale = float(jnp.abs(f32(want[wrt])).max())
+        assert float(jnp.abs(f32(got[wrt]) - f32(want[wrt])).max()) <= (
+            scale * 2.0 ** -6), wrt
+
+
+def _kernel_calls(jaxpr):
+    """``[(kernel name, number of results)]`` of every ``pallas_call`` in a
+    jaxpr, inner jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], len(eqn.outvars)))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_calls(inner)
+    return found
+
+
+def test_a_rule_nobody_differentiates_writes_no_states():
+    """The prefill's call is ``gdn_fwd`` alone with its two results; a
+    differentiated one also writes the solve's tiles, and the chunk-start
+    states are made in the backward only."""
+    a = _tiled(128)
+    assert _kernel_calls(jax.make_jaxpr(_kernels)(**a).jaxpr) == [
+        ("gdn_fwd", 2)]
+    grad = jax.make_jaxpr(jax.grad(lambda a: jnp.sum(_kernels(**a)[0])))(a)
+    assert sorted(_kernel_calls(grad.jaxpr)) == [
+        ("gdn_bwd", 6), ("gdn_fwd", 3), ("gdn_states", 1)]
+
+
+def test_under_the_mixers_checkpoint_the_forward_runs_once():
+    """``_gdn_layer``'s policy keeps the rule's output and the solve's
+    tiles by name: the backward is ``gdn_states`` + ``gdn_bwd`` and never
+    ``gdn_fwd`` a second time; without the solve's name it would be."""
+    from relayrl_tpu.models.transformer import _GDN_OUT, _GDN_SOLVE
+    from jax.ad_checkpoint import checkpoint_name
+
+    a = _tiled(128)
+
+    def calls(*names):
+        def mixer(a):
+            o, _ = _kernels(**a)
+            return jnp.sum(checkpoint_name(o, _GDN_OUT) ** 2)
+
+        kept = jax.checkpoint(
+            mixer, policy=jax.checkpoint_policies.save_only_these_names(
+                *names))
+        found = _kernel_calls(jax.make_jaxpr(jax.grad(kept))(a).jaxpr)
+        return sorted(name for name, _ in found)
+
+    assert calls(_GDN_OUT, _GDN_SOLVE) == ["gdn_bwd", "gdn_fwd",
+                                           "gdn_states"]
+    assert calls(_GDN_OUT).count("gdn_fwd") == 2
+
+
+@pytest.mark.parametrize("shape,fits", [
+    ((32, 16, 128, 128, 64), True),     # qwen3next-policy
+    ((8, 8, 128, 256, 128), True),
+    ((8, 2, 128, 128, 64), True),
+    ((4, 2, 16, 8, 8), False),          # this file's small shapes
+    ((32, 16, 64, 128, 64), False),     # keys of half a lane tile
+    ((32, 16, 128, 64, 64), False),     # values of half a lane tile
+    ((12, 6, 128, 128, 64), False),     # no eight value heads a step
+    ((16, 1, 128, 128, 64), False),     # a key head wider than a step
+    ((32, 16, 128, 128, 32), False),    # a chunk that does not tile
+])
+def test_the_rule_that_picks_the_kernels(monkeypatch, shape, fits):
+    """Platform and shape: off a TPU every shape takes the plain form; on
+    one (this process made to say so) the shapes that tile take the kernels
+    from one whole chunk of rows on (``init``'s single row and a prompt
+    shorter than a chunk stay plain)."""
+    from relayrl_tpu.ops import gdn_pallas
+
+    chunk = shape[-1]
+    assert gdn_pallas.fits(*shape) == fits
+    assert rule.backend(8192, *shape) == rule.XLA
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for T in (8192, chunk + 1, chunk):
+        assert rule.backend(T, *shape) == (rule.PALLAS if fits else rule.XLA)
+    for T in (chunk - 1, 1):
+        assert rule.backend(T, *shape) == rule.XLA
+
+
+def test_the_policy_records_the_kernels_where_they_run(monkeypatch, capsys):
+    """On a TPU (this process made to say so while the policy is traced,
+    nothing lowered) a shape that tiles is recorded as ``gdn_pallas``."""
+    from relayrl_tpu.models import build_policy
+
+    policy = build_policy({
+        "kind": "transformer_discrete", "obs_dim": 6, "act_dim": 3,
+        "d_model": 16, "n_heads": 2, "max_seq_len": 64, "n_layers": 1,
+        "layer_types": ["linear_attention"], "gdn_key_heads": 4,
+        "gdn_value_heads": 8, "gdn_key_dim": 128, "gdn_value_dim": 128,
+        "gdn_chunk": 64, "norm": "rms", "positions": "none"})
+    params = jax.eval_shape(policy.init_params, jax.random.PRNGKey(0))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    capsys.readouterr()
+    jax.eval_shape(policy.evaluate, params, jnp.zeros((2, 64, 6)),
+                   jnp.zeros((2, 64), jnp.int32), jnp.ones((2, 64, 3), bool))
+    assert policy.gdn_backends == {(64, 8, 128, 128, "float32"): rule.PALLAS}
+    said = [line for line in capsys.readouterr().out.splitlines()
+            if line.startswith("[gdn]")]
+    assert len(said) == 1 and said[0].endswith(
+        "-> gdn_pallas (platform tpu)")
