@@ -8,8 +8,8 @@ on the TPU -> publish -> hot-swap — plus the other device programs the repo
 has (the widest transformer through ``build_algorithm``, the flash kernels
 alone at the benchmark's shapes against XLA attention, the fused anakin
 rollout, a served batch, the expert layer's grouped-matmul kernels against
-XLA's own, the Mamba-2 scan's and the gated delta rule's kernels against
-their plain forms). It checks what comes out, fails on the first thing
+XLA's own, the Mamba-2 scan's, the gated delta rule's and the mixers'
+convolution's kernels against their plain forms). It checks what comes out, fails on the first thing
 that is wrong (non-zero exit, one ``chip_smoke: FAIL`` line saying why; a
 phase's own exception is never caught), and ends with ONE JSON line:
 
@@ -1019,6 +1019,68 @@ def phase_h() -> None:
         f"{time.monotonic() - t0:.0f}s")
 
 
+def phase_i() -> None:
+    """The mixers' convolution at the two cells' shapes — two 8192-token
+    episodes of 6,144 columns with a bias (``nemotron-twotower-policy``) and
+    of 8,192 without (``qwen3next-policy``), 4 taps, bfloat16, from a
+    sequence's start — through the Pallas kernels (``ops/conv_pallas.py``:
+    ``conv_fwd``, and under a random cotangent ``conv_bwd``) and through the
+    plain form (``ops/conv.conv_xla``) on the same operands: the output
+    within one bfloat16 step (2^-8 of the largest entry) on no more than one
+    entry in a thousand and equal everywhere else, the gradients of the
+    rows, the taps and the bias within 2^-6 of the plain form's largest
+    entry (autodiff of the plain form rounds every tap's term of ``dx`` to
+    bfloat16; the kernel rounds their float32 sum once). ``conv()`` itself
+    has to pick the kernels here, and says so."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from relayrl_tpu.ops import conv as cv
+    from relayrl_tpu.ops.scopes import MAMBA_CONV_NAME
+
+    t0 = time.monotonic()
+    said = []
+    for C, has_bias in ((6144, True), (8192, False)):
+        check(cv.backend(8192, C, 4) == cv.PALLAS,
+              f"I: conv() would run {cv.backend(8192, C, 4)} at 8192 rows of "
+              f"{C} columns on a TPU")
+        keys = jax.random.split(jax.random.PRNGKey(44 + C), 4)
+        x, dy = (jax.random.normal(k, (2, 8192, C), jnp.bfloat16)
+                 for k in keys[:2])
+        args = (x, 0.5 * jax.random.normal(keys[2], (4, C), jnp.float32)) + (
+            (0.1 * jax.random.normal(keys[3], (C,), jnp.float32),)
+            if has_bias else ())
+
+        def ways(fn):
+            @jax.jit
+            def run(args, dy):
+                out, vjp = jax.vjp(
+                    lambda x, w, bias=None: fn(x, w, bias), *args)
+                return (out, *vjp(dy))
+            return run(args, dy)
+
+        got = ways(lambda *a: cv.conv(*a, None, MAMBA_CONV_NAME))
+        want = ways(cv.conv_xla)
+        out, ref = (np.asarray(a[0], np.float32) for a in (got, want))
+        unequal = float((out != ref).mean())
+        errs = dict(zip(("out", "dx", "dw", "dbias"), map(differ, got, want)))
+        check(unequal <= 1e-3 and errs["out"] <= 2.0 ** -8,
+              f"I: the convolution kernel's output differs from the plain "
+              f"form's on {unequal:.3g} of the entries, by {errs['out']:.3g} "
+              f"of the largest at most (limits 1e-3, 2^-8)")
+        for what, err in errs.items():
+            check(err <= 2.0 ** -6,
+                  f"I: the convolution kernels' {what} differs from the "
+                  f"plain form's by {err:.3g} of its largest entry (limit "
+                  f"2^-6)")
+        said.append(f"{C} columns, bias {has_bias}: unequal outputs "
+                    f"{unequal:.3g}, "
+                    f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}")
+    say(f"I: ok — conv_fwd / conv_bwd against the plain form at (2, 8192, C) "
+        f"4 taps bfloat16: {'; '.join(said)}, {time.monotonic() - t0:.0f}s")
+
+
 # --------------------------------------------------------------------------
 
 def main() -> None:
@@ -1086,6 +1148,7 @@ def run(dev: dict, t_start: float) -> None:
     phase_f()
     phase_g()
     phase_h()
+    phase_i()
 
     say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
         f"the persistent cache, {compiles.requests - compiles.hits} compiled "

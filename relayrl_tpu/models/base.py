@@ -167,6 +167,14 @@ class Policy:
     # tile) or plain XLA. Empty for a trunk without such layers, None for
     # other families.
     gdn_backends: Mapping[tuple, str] | None = None
+    # Sequence policies with Mamba-2 or linear-attention layers: ``{(T,
+    # columns, taps, continues from a cache's rows, dtype): "conv_pallas" |
+    # "conv_xla"}`` for every shape of the mixers' depthwise convolution
+    # traced so far (models/transformer._resolve_conv) — whether
+    # ``ops/conv.py`` ran the Pallas kernels (a TPU, a sequence's start,
+    # shapes that tile) or plain XLA. Empty for a trunk without such
+    # layers, None for other families.
+    conv_backends: Mapping[tuple, str] | None = None
     # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
     # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward
     # (``moe_load_max`` / ``moe_load_min``: models/moe.load_extremes) for

@@ -128,6 +128,9 @@ from relayrl_tpu.ops.scopes import (  # noqa: F401  (SHORT_CONV_NAME's home)
     OP_PROJ,
     SHORT_CONV_NAME,
 )
+from relayrl_tpu.ops.conv import backend as conv_backend
+from relayrl_tpu.ops.conv import conv
+from relayrl_tpu.ops.conv import padded as conv_padded
 from relayrl_tpu.ops.gdn import SOLVE_NAME as _GDN_SOLVE
 from relayrl_tpu.ops.gdn import backend as gdn_backend
 from relayrl_tpu.ops.gdn import gdn, gdn_step
@@ -333,6 +336,30 @@ def _resolve_delta_rule() -> tuple[Callable, dict]:
     return rule_fn, resolved
 
 
+def _resolve_conv() -> tuple[Callable, dict]:
+    """``(conv_fn, resolved)``: the mixers' depthwise convolution,
+    ``ops.conv.conv`` behind a record of what it ran as, as
+    :func:`_resolve_scan`: ``resolved`` (``Policy.conv_backends``) maps every
+    traced ``(T, columns, taps, continues from a cache's rows, dtype)`` to
+    ``ops.conv.backend``'s answer (``conv_pallas`` | ``conv_xla``), and each
+    new entry prints one ``[conv]`` line naming the platform."""
+    resolved: dict[tuple, str] = {}
+
+    def conv_fn(x, w, bias, state, scope):
+        key = (int(x.shape[1]), int(x.shape[2]), int(w.shape[0]),
+               state is not None, x.dtype.name)
+        ran = conv_backend(*key[:4])
+        if resolved.get(key) != ran:
+            resolved[key] = ran
+            print(f"[conv] T={key[0]} columns={key[1]} taps={key[2]} "
+                  f"bias={'no' if bias is None else 'yes'} "
+                  f"from={'cache' if key[3] else 'start'} {key[4]} -> {ran} "
+                  f"(platform {jax.default_backend()})", flush=True)
+        return conv(x, w, bias, state, scope)
+
+    return conv_fn, resolved
+
+
 class _ZeroCentredRMSNorm(nn.Module):
     """RMSNorm whose learned weight is an offset from one, ``x^ (1 + w)``
     (Qwen3-Next's, Gemma's), float32. ``w`` is seeded at std 0.02 round 0
@@ -423,27 +450,23 @@ def _short_conv(bcu, w, state=None):
         return c_gate * c.astype(bcu.dtype), zp
 
 
-def _mamba_conv(xbc, w, bias, state=None, scope: str = MAMBA_CONV_NAME):
+def _mamba_conv(xbc, w, bias, state=None, scope: str = MAMBA_CONV_NAME,
+                conv_fn: Callable = conv):
     """The Mamba-2 mixer's convolution: ``silu(conv(xbc) + bias)``,
     depthwise and causal, ``L = w.shape[0]`` taps, returns ``(out,
     xbc_padded)``. ``state [batch, L-1, c]`` holds the ``xbc`` rows before
     this call's first (zeros at a sequence's start, which ``None`` means);
     ``xbc_padded = concat(state, xbc)`` is what a cache takes its next rows
-    from. Plain XLA under one named scope, the tap sums in float32, as
-    :func:`_short_conv`. ``bias`` None: none is added (a linear-attention
-    mixer's, :func:`_gdn_conv`, under its own ``scope``)."""
+    from (full mode drops it unmade). Under one named scope, the tap sums in
+    float32, as :func:`_short_conv`: :mod:`relayrl_tpu.ops.conv`, as two
+    Pallas kernels on a TPU at a sequence's start and as plain XLA
+    everywhere else (``conv_fn``: ``ops.conv.conv``, or the policy's
+    recording wrapper of it, :func:`_resolve_conv`). ``bias`` None: none is
+    added (a linear-attention mixer's, :func:`_gdn_conv`, under its own
+    ``scope``)."""
+    out = conv_fn(xbc, w, bias, state, scope)
     with jax.named_scope(scope):
-        taps = w.shape[0]
-        T = xbc.shape[1]
-        if state is None:
-            xp = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
-        else:
-            xp = jnp.concatenate([state.astype(xbc.dtype), xbc], axis=1)
-        c = sum(w[j].astype(jnp.float32) * xp[:, j:j + T].astype(jnp.float32)
-                for j in range(taps))
-        if bias is not None:
-            c = c + bias.astype(jnp.float32)
-        return nn.silu(c).astype(xbc.dtype), xp
+        return out, conv_padded(xbc, w.shape[0], state)
 
 
 def _block_dense(block: "TransformerBlock", features: int, name: str):
@@ -545,6 +568,9 @@ class TransformerBlock(nn.Module):
     # The scan a Mamba-2 layer runs: ops.ssd.ssd, or the policy's recording
     # wrapper of it (_resolve_scan).
     scan_fn: Callable = ssd
+    # The convolution a Mamba-2 or a linear-attention layer runs:
+    # ops.conv.conv, or the policy's recording wrapper of it (_resolve_conv).
+    conv_fn: Callable = conv
     # Grouped-query heads: n_kv_heads k/v heads under n_heads query heads,
     # all d_model // n_heads wide (separate q_proj / k_proj / v_proj).
     # None: one fused qkv, as always. qk_norm "head": RMSNorm over each head.
@@ -883,7 +909,8 @@ def _mamba_layer(block: TransformerBlock, x, cache, n_valid):
                 dt = jnp.where(jnp.arange(T)[None, :, None] < n_valid, dt,
                                0.0)
             a = -jnp.exp(a_log)
-        xbc, padded = _mamba_conv(xbc, conv_w, conv_b, conv_rows)
+        xbc, padded = _mamba_conv(xbc, conv_w, conv_b, conv_rows,
+                                  conv_fn=block.conv_fn)
         with jax.named_scope(OP_PROJ):
             xs, b_in, c_in = jnp.split(xbc, [inner, inner + bc], axis=-1)
             xs = xs.reshape(Bsz, T, H, P)
@@ -963,11 +990,11 @@ def _normed_heads(a, heads: int, eps: float, mean: bool):
         [normed(cols) for cols in jnp.split(a, heads, axis=-1)], axis=-1)
 
 
-def _gdn_conv(qkv, w, state=None):
+def _gdn_conv(qkv, w, state=None, conv_fn: Callable = conv):
     """A linear-attention layer's convolution: ``silu(conv(qkv))`` over q,
     k and v together, :func:`_mamba_conv` WITHOUT a bias under the scope
     ``relayrl_gdn_conv``; returns ``(out, qkv_padded)`` likewise."""
-    return _mamba_conv(qkv, w, None, state, GDN_CONV_NAME)
+    return _mamba_conv(qkv, w, None, state, GDN_CONV_NAME, conv_fn)
 
 
 def _gdn_a_log_init(key, shape, dtype=jnp.float32):
@@ -1036,7 +1063,7 @@ def _gdn_layer(block: TransformerBlock, x, cache, n_valid):
             if n_valid is not None:
                 real = jnp.arange(T)[None, :, None] < n_valid
                 beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
-        qkv, padded = _gdn_conv(qkv, conv_w, conv_rows)
+        qkv, padded = _gdn_conv(qkv, conv_w, conv_rows, block.conv_fn)
         with jax.named_scope(OP_PROJ):
             q, k, v = jnp.split(qkv, [kw, 2 * kw], axis=-1)
             q = (l2_normed(q, Hk) * K ** -0.5).astype(cd).reshape(
@@ -1420,7 +1447,8 @@ def _block_kwargs(arch: Mapping[str, Any]) -> dict:
 def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
                attn_fn: Callable | None = None,
                scan_fn: Callable = ssd,
-               rule_fn: Callable = gdn) -> TransformerCore:
+               rule_fn: Callable = gdn,
+               conv_fn: Callable = conv) -> TransformerCore:
     """Arch -> TransformerCore module (shared by the policy builders and
     diagnostics like :func:`relayrl_tpu.models.moe.expert_utilization`,
     which re-applies the same module with captured intermediates)."""
@@ -1440,7 +1468,7 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         moe_top_k=int(arch.get("moe_top_k", 2)),
         block_kw=flax.core.FrozenDict(
             {**_block_kwargs(arch), "scan_fn": scan_fn,
-             "rule_fn": rule_fn}),
+             "rule_fn": rule_fn, "conv_fn": conv_fn}),
         layer_types=tuple(arch.get("layer_types", ())),
         moe_dense_layers=int(arch.get("moe_dense_layers", 0)),
         sliding_window=arch.get("sliding_window"),
@@ -1455,7 +1483,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         _resolve_attention(arch))
     scan_fn, scan_backends = _resolve_scan()
     rule_fn, gdn_backends = _resolve_delta_rule()
-    core = _make_core(arch, moe_experts, attn_fn, scan_fn, rule_fn)
+    conv_fn, conv_backends = _resolve_conv()
+    core = _make_core(arch, moe_experts, attn_fn, scan_fn, rule_fn, conv_fn)
 
     def init_params(rng):
         return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
@@ -1593,6 +1622,7 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                        attention_layout=attn_layout,
                        scan_backends=scan_backends,
                        gdn_backends=gdn_backends,
+                       conv_backends=conv_backends,
                        evaluate_stats=evaluate_stats)
 
 

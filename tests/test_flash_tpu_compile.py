@@ -382,6 +382,47 @@ def test_the_plain_rule_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
 
 
+@pytest.mark.parametrize("columns,bias,scope", [
+    (6144, True, scopes.MAMBA_CONV_NAME),    # nemotron-twotower-policy.update
+    (8192, False, scopes.GDN_CONV_NAME),     # qwen3next-policy.update
+])
+def test_the_mixers_convolution_compiles_for_v5e(one_chip, columns, bias,
+                                                 scope):
+    """The convolution's Pallas kernels (``ops/conv_pallas.py``), forward
+    and every gradient at the cells' ``[2, 8192, C]`` rows: two Mosaic calls
+    — ``conv_fwd``, and in the backward ``conv_bwd`` — each under the
+    caller's scope (the benchmark's ``mamba_conv_ms`` / ``gdn_conv_ms`` read
+    it), and no copy of the rows in HBM in any dtype: the temporaries hold a
+    direction's cotangents and the taps' partial sums, nothing of ``[T, C]``
+    in float32."""
+    from relayrl_tpu.ops import conv_pallas
+
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    x = S((2, 8192, columns), jnp.bfloat16)
+    w = S((4, columns), jnp.float32)
+    b = S((columns,), jnp.float32) if bias else None
+
+    def loss(x, w, b):
+        return jnp.sum(conv_pallas.conv_pallas(x, w, b, scope).astype(
+            jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2) if bias else (0, 1))).lower(x, w, b).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'(%[\w.\-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) == [
+        "%" + conv_pallas.BWD_NAME, "%" + conv_pallas.FWD_NAME]
+    for name, path in calls:
+        assert re.findall(r"relayrl_\w+", path)[-1] == scope, path
+        assert ("transpose(" in path) == (conv_pallas.BWD_NAME in name)
+    # the cotangent of the sum (one bfloat16 array of the rows' size) and
+    # the sums' float32 partials
+    rows = 2 * 8192 * columns * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * rows
+
+
 def test_the_whole_rotary_is_todays_function_bit_for_bit():
     """``apply_rope`` at a share of 1.0 (the default) is the function every
     accepted configuration has run: the same bits as its lines written out
