@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from relayrl_tpu.models.arch_keys import DECLARED, TRUNK_KEYS
+
 _REGISTRY: dict[str, Callable[[Mapping[str, Any]], "Policy"]] = {}
 
 
@@ -44,32 +46,10 @@ def build_policy(arch: Mapping[str, Any]) -> "Policy":
 # Model-shape hyperparams that algorithms forward verbatim from their
 # hyperparam dict into the arch config when present, so any policy family
 # (e.g. model_kind="transformer_discrete" with d_model/n_layers/attention)
-# is reachable through the algorithm ctor without per-algorithm plumbing.
-ARCH_PASSTHROUGH_KEYS = (
-    "d_model", "n_layers", "n_heads", "mlp_ratio", "max_seq_len",
-    "attention", "attention_block", "actor_context",
-    "moe_experts", "moe_top_k", "pp_microbatches",
-    # the block the arch describes (models/transformer._BLOCK_ARCH_KEYS)
-    "norm", "norm_eps", "positions", "rope_theta", "qk_norm", "use_bias",
-    "ffn", "d_ff", "moe_d_ff", "moe_norm_topk_prob", "moe_dispatch",
-    "n_kv_heads", "conv_taps",
-    # layers of several kinds (transformer._LAYER_ARCH_KEYS) and the expert
-    # layer's router and held range (transformer._MOE_ARCH_KEYS)
-    "layer_types", "moe_dense_layers",
-    "moe_router", "moe_expert_bias", "moe_held",
-    # a head width of its own, windowed layers, positions layer by layer,
-    # the router's input
-    "head_dim", "sliding_window", "rope_layers", "moe_router_input",
-    # the Mamba-2 mixer, the routed weights' factor, the shared expert
-    "mamba_heads", "mamba_head_dim", "mamba_state", "mamba_groups",
-    "mamba_conv_taps", "mamba_chunk", "moe_routed_scaling",
-    "moe_shared_d_ff",
-    # zero-centred norm weights, a partial rotary, the attention's output
-    # gate, the linear-attention mixer, the shared expert's gate
-    "norm_zero_centred", "rope_share", "attn_gate", "gdn_key_heads",
-    "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "gdn_conv_taps",
-    "gdn_chunk", "moe_shared_expert_gate",
-)
+# is reachable through the algorithm ctor without per-algorithm plumbing:
+# the sequence trunk's own keys and every key its layers declare
+# (models/arch_keys.py, where each is written once).
+ARCH_PASSTHROUGH_KEYS = TRUNK_KEYS + DECLARED
 
 
 def apply_arch_overrides(arch: dict, params: Mapping[str, Any]) -> dict:
@@ -137,8 +117,8 @@ class Policy:
     # a state without positions (a short convolution's) is taken before.
     prefill_cache: Callable | None = None
     # Sequence policies: ``{(T, head_dim, dtype): backend}`` for every
-    # attention shape traced so far (models/transformer._resolve_attention
-    # fills it at trace time) — which implementation a platform-dependent
+    # attention shape traced so far (models/layers/attention.resolve fills
+    # it at trace time) — which implementation a platform-dependent
     # ``attention`` config actually compiled to. None for other families.
     # (A windowed layer runs the backend of its shape: no key of its own.)
     attention_backends: Mapping[tuple, str] | None = None
@@ -156,21 +136,21 @@ class Policy:
     attention_layout: Mapping[tuple, str] | None = None
     # Sequence policies with Mamba-2 layers: ``{(T, heads, head_dim, state,
     # dtype): "ssd_pallas" | "ssd_xla"}`` for every scan shape traced so far
-    # (models/transformer._resolve_scan) — whether ``ops/ssd.py`` ran the
+    # (models/layers/recurrent.kernel) — whether ``ops/ssd.py`` ran the
     # Pallas kernels (a TPU, shapes that tile) or plain XLA. Empty for a
     # trunk without such layers, None for other families.
     scan_backends: Mapping[tuple, str] | None = None
     # Sequence policies with linear-attention layers: ``{(T, value heads,
     # key width, value width, dtype): "gdn_pallas" | "gdn_xla"}`` for every
-    # delta-rule shape traced so far (models/transformer._resolve_delta_rule)
-    # — whether ``ops/gdn.py`` ran the Pallas kernels (a TPU, shapes that
+    # delta-rule shape traced so far (models/layers/recurrent.kernel) —
+    # whether ``ops/gdn.py`` ran the Pallas kernels (a TPU, shapes that
     # tile) or plain XLA. Empty for a trunk without such layers, None for
     # other families.
     gdn_backends: Mapping[tuple, str] | None = None
     # Sequence policies with Mamba-2 or linear-attention layers: ``{(T,
     # columns, taps, continues from a cache's rows, dtype): "conv_pallas" |
     # "conv_xla"}`` for every shape of the mixers' depthwise convolution
-    # traced so far (models/transformer._resolve_conv) — whether
+    # traced so far (models/layers/recurrent.kernel) — whether
     # ``ops/conv.py`` ran the Pallas kernels (a TPU, a sequence's start,
     # shapes that tile) or plain XLA. Empty for a trunk without such
     # layers, None for other families.

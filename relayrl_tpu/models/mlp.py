@@ -130,7 +130,7 @@ def _gaussian_entropy(log_std, batch_shape):
 
 
 # The gated FFN kinds (arch ``ffn``) by the activation on the gate: the
-# dense FFN of models/transformer.py and the experts of models/moe.py.
+# dense FFN of models/layers/block.py and the experts of models/moe.py.
 GATED_FFN = {"swiglu": nn.silu, "reglu": nn.relu}
 # ... and the kinds without a gate, ``down(act(up(x)))``, by their activation
 UNGATED_FFN = {"gelu": nn.gelu, "relu2": lambda x: jnp.square(nn.relu(x))}

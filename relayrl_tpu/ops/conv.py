@@ -1,6 +1,6 @@
 """The mixers' depthwise causal convolution: ``silu(conv(x) + bias)`` over
 the rows a Mamba-2 layer calls ``xBC`` and a linear-attention layer ``[q | k
-| v]`` (``models/transformer._mamba_conv`` / ``_gdn_conv``)::
+| v]`` (``models/layers/recurrent.mixer_conv``, both mixers')::
 
     c_t = sum_j w[j] * x_{t - (L - 1) + j} (+ bias)        L = w.shape[0] taps
     out_t = silu(c_t)
@@ -30,7 +30,7 @@ compute dtype), rounded once.
 Both sit under the caller's named scope (``relayrl_mamba_conv`` |
 ``relayrl_gdn_conv``, ``ops/scopes.py``): the benchmark's ``mamba_conv_ms``
 / ``gdn_conv_ms`` read the scope, whichever form runs.
-``models/transformer._resolve_conv`` records which form a policy's
+``models/layers/recurrent.CONV_KERNEL`` records which form a policy's
 convolutions ran as (``Policy.conv_backends``) and prints one ``[conv]``
 line a shape.
 """

@@ -46,7 +46,7 @@ state as it is, so ``last_state`` is the state after the real rows.
 Right-padded episodes need nothing: the rule is causal, a real row never
 sees a later one (``tests/test_gdn.py``). A caller that wants the state
 after its first ``n`` rows zeroes ``g`` and ``beta`` from row ``n`` on
-(``models/transformer.py``'s prefill).
+(``models/layers/gdn.py``'s prefill).
 
 **Two forms of the same algebra, picked by what the code can observe**
 (:func:`backend`; no arch key, no environment variable, no switch):
@@ -75,7 +75,7 @@ after its first ``n`` rows zeroes ``g`` and ``beta`` from row ``n`` on
 
 Both sit under one named scope, ``relayrl_gdn`` (``ops/scopes.py``), and no
 deeper ``relayrl_`` name: the benchmark's ``gdn_ms`` / ``gdn_roofline`` read
-the exact scope. ``models/transformer._resolve_delta_rule`` records which
+the exact scope. ``models/layers/gdn.KERNELS`` records which
 form a policy's rules ran as (``Policy.gdn_backends``) and prints one
 ``[gdn]`` line a shape.
 

@@ -42,11 +42,11 @@ DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, FFN, MOE_ROUTE,
                  MOE_ROWS, MOE_ELEMENTWISE, HEADS, OBS_PREP, CONV)
 
 # -- kernels and the operators that keep a name of their own -----------------
-SHORT_CONV_NAME = "relayrl_short_conv"   # models/transformer._short_conv
+SHORT_CONV_NAME = "relayrl_short_conv"   # models/layers/short_conv.py
 SSD_NAME = "relayrl_ssd"                 # ops/ssd.py: the Mamba-2 scan
-MAMBA_CONV_NAME = "relayrl_mamba_conv"   # models/transformer._mamba_conv
+MAMBA_CONV_NAME = "relayrl_mamba_conv"   # models/layers/mamba2.py's
 GDN_NAME = "relayrl_gdn"                 # ops/gdn.py: the gated delta rule
-GDN_CONV_NAME = "relayrl_gdn_conv"       # models/transformer._gdn_conv
+GDN_CONV_NAME = "relayrl_gdn_conv"       # models/layers/gdn.py's
 FWD_NAME = "relayrl_flash_fwd"           # ops/flash.py, also the calls' name
 DQ_NAME = "relayrl_flash_dq"
 DKV_NAME = "relayrl_flash_dkv"

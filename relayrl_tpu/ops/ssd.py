@@ -38,7 +38,7 @@ float32 accumulation, as the flash kernels round their probabilities.
 so ``last_state`` is the state after the real rows. Right-padded episodes
 need nothing: the recurrence is causal, a real row never sees a later one
 (``tests/test_ssd.py``). A caller that wants the state after its first
-``n`` rows zeroes ``dt`` from row ``n`` on (``models/transformer.py``'s
+``n`` rows zeroes ``dt`` from row ``n`` on (``models/layers/mamba2.py``'s
 prefill).
 
 **Two forms of the same five lines, picked by what the code can observe**
@@ -69,7 +69,7 @@ prefill).
 
 Both sit under one named scope, ``relayrl_ssd`` (``ops/scopes.py``), and no
 deeper ``relayrl_`` name: the benchmark's ``ssd_ms`` / ``ssd_roofline`` read
-the exact scope. ``models/transformer._resolve_scan`` records which form a
+the exact scope. ``models/layers/mamba2.KERNELS`` records which form a
 policy's scans ran as (``Policy.scan_backends``) and prints one ``[scan]``
 line a shape.
 

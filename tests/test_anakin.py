@@ -37,9 +37,18 @@ def _bundle(obs_dim=4, act_dim=2, seed=0, version=0):
     return ModelBundle(version=version, arch=arch, params=params)
 
 
-def _seq_bundle(obs_dim=4, act_dim=2, max_seq_len=8, seed=0, version=0):
+def _seq_bundle(obs_dim=4, act_dim=2, max_seq_len=8, seed=0, version=0,
+                uniform=False):
     """Deterministic windowed-transformer bundle (a ``step_window``
-    sequence policy — the fused scan's rolling-window carry path)."""
+    sequence policy — the fused scan's rolling-window carry path).
+
+    A seeded initialisation is NOT a random policy: this one answers the
+    pole's lean and holds it up for 52–85 steps (measured, PR 45), and how
+    long depends on the initialisers' bytes, which differ by jax version.
+    ``uniform`` zeroes the action head, so every logit is 0 and sampling is
+    uniform whatever those bytes are: a random policy's CartPole episodes
+    (11–34 steps at the tests' seeds). The value head still reads the
+    window's features, so ``v`` differs row by row as before."""
     from relayrl_tpu.models import build_policy
     from relayrl_tpu.types.model_bundle import ModelBundle
 
@@ -48,6 +57,11 @@ def _seq_bundle(obs_dim=4, act_dim=2, max_seq_len=8, seed=0, version=0):
             "max_seq_len": max_seq_len}
     policy = build_policy(arch)
     params = policy.init_params(jax.random.PRNGKey(seed))
+    if uniform:
+        inner = dict(params["params"])
+        inner["pi_head"] = jax.tree_util.tree_map(np.zeros_like,
+                                                  inner["pi_head"])
+        params = {**params, "params": inner}
     return ModelBundle(version=version, arch=arch, params=params)
 
 
@@ -287,7 +301,8 @@ class TestFusedSequenceRollout:
         telemetry.set_registry(telemetry.Registry(run_id="fused-seq"))
         sent: list[bytes] = []
         host = AnakinActorHost(
-            _seq_bundle(max_seq_len=8, version=3), "CartPole-v1",
+            _seq_bundle(max_seq_len=8, version=3, uniform=True),
+            "CartPole-v1",
             num_envs=4, unroll_length=64, columnar_wire=False,
             record_bver=True,
             on_send=lambda lane, p: sent.append(p), seed=2)
@@ -357,9 +372,10 @@ class TestFusedSequenceCrossTierParity:
     terminations AND time-limit truncations (the bootstrap ``final_obs``
     marker), in both wire forms."""
 
-    # max_steps=18 against random-policy CartPole episode lengths gives
-    # every run BOTH ending kinds (pole falls < 18 / time limit at 18)
-    # while the W=8 ring still rolls well past capacity.
+    # max_steps=18 against random-policy CartPole episode lengths
+    # (``_seq_bundle(uniform=True)``: uniform sampling by construction)
+    # gives every run BOTH ending kinds (pole falls < 18 / time limit at
+    # 18) while the W=8 ring still rolls well past capacity.
     N, UNROLL, SEED, MAX_STEPS = 2, 150, 3, 18
 
     def _run_fused(self, columnar: bool):
@@ -368,7 +384,7 @@ class TestFusedSequenceCrossTierParity:
 
         per_lane: dict[int, list[bytes]] = {k: [] for k in range(self.N)}
         host = AnakinActorHost(
-            _seq_bundle(max_seq_len=8),
+            _seq_bundle(max_seq_len=8, uniform=True),
             JaxCartPole(max_steps=self.MAX_STEPS),
             num_envs=self.N, unroll_length=self.UNROLL,
             columnar_wire=columnar,
@@ -384,7 +400,7 @@ class TestFusedSequenceCrossTierParity:
 
         per_lane: dict[int, list[bytes]] = {k: [] for k in range(self.N)}
         host = VectorActorHost(
-            _seq_bundle(max_seq_len=8), num_envs=self.N,
+            _seq_bundle(max_seq_len=8, uniform=True), num_envs=self.N,
             on_send=lambda lane, p: per_lane[lane].append(p),
             seed=self.SEED)
         twin = _JaxVectorTwin(JaxCartPole(max_steps=self.MAX_STEPS),

@@ -438,7 +438,7 @@ class TestStepWindow:
 
 
 # -- the block the arch describes: RoPE, QK-norm, RMSNorm, SwiGLU, no bias ---
-# (models/transformer._BLOCK_ARCH_KEYS; every default is the GPT-2 shaped
+# (models/arch_keys.py: BLOCK_KEYS, the operators'; every default is the GPT-2 shaped
 # block, pinned in tests/test_olmoe_reference.py)
 
 MODERN = {"norm": "rms", "norm_eps": 1e-5, "positions": "rope",
@@ -461,7 +461,7 @@ class TestRope:
 
     @pytest.mark.parametrize("start", [0, 3, 7])
     def test_shifted_start_shifts_nothing_but_positions(self, start):
-        from relayrl_tpu.models.transformer import apply_rope
+        from relayrl_tpu.models.layers.attention import apply_rope
 
         x = self._x()
         whole = apply_rope(x, 0, 10000.0)
@@ -473,7 +473,7 @@ class TestRope:
         np.testing.assert_allclose(traced, tail, atol=1e-5)
 
     def test_is_a_rotation_and_scores_are_relative(self):
-        from relayrl_tpu.models.transformer import apply_rope
+        from relayrl_tpu.models.layers.attention import apply_rope
 
         q, k = self._x(seed=1), self._x(seed=2)
         np.testing.assert_allclose(
@@ -491,7 +491,7 @@ class TestRope:
             scores(0), jnp.einsum("bqhd,bkhd->bhqk", q, k), atol=1e-2)
 
     def test_position_zero_is_the_identity(self):
-        from relayrl_tpu.models.transformer import apply_rope
+        from relayrl_tpu.models.layers.attention import apply_rope
 
         x = self._x(t=1)
         np.testing.assert_allclose(apply_rope(x, 0, 10000.0), x, atol=1e-7)

@@ -76,7 +76,7 @@ def test_flash_rejects_indivisible_seq():
 
 def test_transformer_flash_arch_runs_off_tpu():
     # attention="flash" must be usable in the same arch config everywhere:
-    # off-TPU it falls back to blockwise (models/transformer.py resolver).
+    # off-TPU it falls back to blockwise (models/layers/attention.resolve).
     from relayrl_tpu.models import build_policy
 
     arch = {"kind": "transformer_discrete", "obs_dim": 8, "act_dim": 3,

@@ -429,7 +429,7 @@ def test_the_whole_rotary_is_todays_function_bit_for_bit():
     here, at a traced start too; a quarter turns the first quarter's lanes
     as a head of that width and passes the rest through untouched. (CPU;
     in this file beside the D-256 kernels it serves.)"""
-    from relayrl_tpu.models.transformer import apply_rope
+    from relayrl_tpu.models.layers.attention import apply_rope
 
     def as_it_was(x, start, theta):
         hd = x.shape[-1]

@@ -452,10 +452,10 @@ def test_a_rule_nobody_differentiates_writes_no_states():
 
 
 def test_under_the_mixers_checkpoint_the_forward_runs_once():
-    """``_gdn_layer``'s policy keeps the rule's output and the solve's
+    """The layer's policy (``models/layers/gdn.py``) keeps the rule's output and the solve's
     tiles by name: the backward is ``gdn_states`` + ``gdn_bwd`` and never
     ``gdn_fwd`` a second time; without the solve's name it would be."""
-    from relayrl_tpu.models.transformer import _GDN_OUT, _GDN_SOLVE
+    from relayrl_tpu.models.layers.gdn import _GDN_OUT, _GDN_SOLVE
     from jax.ad_checkpoint import checkpoint_name
 
     a = _tiled(128)
