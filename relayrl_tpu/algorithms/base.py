@@ -44,7 +44,15 @@ def build_algorithm(name: str, **kwargs) -> "AlgorithmBase":
         raise ValueError(
             f"unknown algorithm {name!r}; registered: {sorted(_ALGO_REGISTRY)}"
         ) from None
-    return cls(**kwargs)
+    algo = cls(**kwargs)
+    own_loss = getattr(getattr(algo, "policy", None), "own_loss", None)
+    if own_loss and not cls.ADDS_OWN_LOSS:
+        raise ValueError(
+            f"{name}: the policy {algo.arch.get('kind')!r} brings a loss of "
+            f"its own ({own_loss}: models/base.Policy.own_loss) that this "
+            f"algorithm's update would drop — its indexers would never "
+            f"train; IMPALA's update adds it")
+    return algo
 
 
 def registered_algorithms() -> list[str]:
@@ -73,6 +81,11 @@ class AlgorithmBase(abc.ABC):
     # the largest default bucket 1000 = 8000; override per-instance when a
     # deployment with bigger epochs wants full pre-compilation anyway).
     warmup_max_elements = 32768
+
+    # Whether the update adds the loss a model brings itself
+    # (``models/base.Policy.own_loss``); ``build_algorithm`` refuses such a
+    # policy for an algorithm that would drop it.
+    ADDS_OWN_LOSS = False
 
     # Trajectories rejected by the ingest finite-value guard
     # (types/columnar.py trajectory_is_finite); class default so the

@@ -67,9 +67,14 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
     tx = make_impala_tx(lr, max_grad_norm, freeze, params_template)
     # a MoE trunk reports the expert load of the same forward
     # (``moe_load_max`` / ``moe_load_min`` / ``moe_held_slots`` /
-    # ``moe_row_passes``); every other family reports {}
+    # ``moe_row_passes``), a sparse-attention trunk the share of the causal
+    # pairs its selections kept (``index_kept_pct``); every other family
+    # reports {}
     evaluate = policy.evaluate_stats or (
         lambda *args: (*policy.evaluate(*args), {}))
+    # a loss the model itself brings (``Policy.own_loss``): the name it is
+    # reported under; its rows come with the stats
+    own_loss = policy.own_loss
 
     def impala_update(state: ImpalaState, batch: Mapping[str, jax.Array]):
         obs, act, act_mask = batch["obs"], batch["act"], batch["act_mask"]
@@ -92,6 +97,11 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
                 vf_loss = jnp.sum(jnp.square(v - vt.vs) * valid) / n_valid
                 ent_mean = jnp.sum(ent * valid) / n_valid
                 total = pg_loss + vf_coef * vf_loss - ent_coef * ent_mean
+                if own_loss:
+                    stats = dict(stats)
+                    stats[own_loss] = jnp.sum(
+                        stats.pop("own_loss_rows") * valid) / n_valid
+                    total = total + stats[own_loss]
             return total, (pg_loss, vf_loss, ent_mean, vt.rho, logp, stats)
 
         (total, (pg_loss, vf_loss, ent_mean, rho, logp_new, stats)), grads = (
@@ -111,8 +121,9 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
             "LossTotal": total,
             "RhoMean": rho_mean,
             "KL": kl,
-            # moe_load_max / _min / moe_held_slots / moe_row_passes, MoE
-            # trunks only
+            # moe_load_max / _min / moe_held_slots / moe_row_passes (MoE
+            # trunks), IndexLoss / index_kept_pct (sparse attention): only
+            # where the trunk has such layers
             **stats,
         }
         return ImpalaState(params=params, opt_state=opt_state, rng=state.rng,
@@ -127,6 +138,7 @@ class IMPALA(OnPolicyAlgorithm):
     the update is staleness-corrected so it works with many async actors."""
 
     ALGO_NAME = "IMPALA"
+    ADDS_OWN_LOSS = True  # make_impala_update adds ``Policy.own_loss``
 
     def _setup(self, params: dict, learner: dict, rng: jax.Array) -> None:
         # obs_shape implies the pixel trunk, as in PPO/DQN/C51; an explicit
@@ -202,7 +214,12 @@ class IMPALA(OnPolicyAlgorithm):
             }
             self._fence_notes = ("moe_load_max", "moe_held_slots",
                                  "moe_row_passes")
+        # a trunk with a loss of its own: the loss as the update reports it
+        # and the share of the causal pairs its selections kept
+        self._own_loss_keys = ((self.policy.own_loss, "index_kept_pct")
+                               if self.policy.own_loss else ())
+        self._fence_notes += self._own_loss_keys
 
     def _log_keys(self):
         keys = ("LossPi", "LossV", "Entropy", "RhoMean", "KL")
-        return keys + tuple(self._metric_gauges)
+        return keys + tuple(self._metric_gauges) + self._own_loss_keys

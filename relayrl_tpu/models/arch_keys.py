@@ -70,8 +70,17 @@ OPERATOR_KEYS: Mapping[str, Mapping[str, Any]] = {
         "gdn_key_heads": 4, "gdn_value_heads": 8, "gdn_key_dim": 64,
         "gdn_value_dim": 64, "gdn_conv_taps": 4, "gdn_chunk": 64,
     },
+    # "attention" (OPERATOR_BASE) with a learned indexer in front of it
+    "sparse_attention": {
+        "index_heads": 4, "index_head_dim": 32,  # over ONE key head
+        "index_topk": 2048,         # the keys a query attends
+        "index_chunk": 512,         # the query tile; no part of the model
+    },
     "none": {},
 }
+
+# An operator that is another with more: its settings hold the other's too.
+OPERATOR_BASE: Mapping[str, str] = {"sparse_attention": "attention"}
 
 # Everything but TRUNK_KEYS: what only ``TransformerCore``'s trunks take.
 DECLARED = (CORE_KEYS + tuple(BLOCK_KEYS) + tuple(MOE_KEYS)

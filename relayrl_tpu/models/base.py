@@ -155,11 +155,26 @@ class Policy:
     # shapes that tile) or plain XLA. Empty for a trunk without such
     # layers, None for other families.
     conv_backends: Mapping[tuple, str] | None = None
-    # MoE families: ``evaluate_stats(params, obs, act, mask) -> (logp,
-    # entropy, v, stats)`` — ``evaluate`` plus scalars of the same forward
-    # (``moe_load_max`` / ``moe_load_min``: models/moe.load_extremes) for
-    # the update's metrics. None for every other family.
+    # Sequence policies with sparse-attention layers: ``{(T, head_dim, index
+    # heads, index head_dim, topk, dtype): selection + attention backend}``
+    # for every full-mode shape traced so far (models/layers/
+    # sparse_attention.py). Empty for a trunk without such layers, None for
+    # other families.
+    index_backends: Mapping[tuple, str] | None = None
+    # MoE and sparse-attention trunks: ``evaluate_stats(params, obs, act,
+    # mask) -> (logp, entropy, v, stats)`` — ``evaluate`` plus what the same
+    # forward counted (``moe_load_max`` / ``moe_load_min``:
+    # models/moe.load_extremes; ``index_kept_pct``) for the
+    # update's metrics. None for every other family.
     evaluate_stats: Callable | None = None
+    # THE seam for a loss the model itself brings: the name an update
+    # reports it under (``"IndexLoss"``: a sparse-attention trunk's indexers
+    # learn from nothing else), None for a model without one. With it set,
+    # ``evaluate_stats``' stats hold ``own_loss_rows`` ``[B, T]``, the term
+    # row by row summed over the layers; an update adds its mean over the
+    # valid rows to its loss (algorithms/impala.py), and an algorithm that
+    # would drop it refuses the policy (``build_algorithm``).
+    own_loss: str | None = None
 
     @property
     def input_dim(self) -> int:

@@ -35,10 +35,17 @@ from __future__ import annotations
 
 from typing import Any, Mapping
 
-from relayrl_tpu.models.layers import attention, block, gdn, mamba2, short_conv
+from relayrl_tpu.models.layers import (
+    attention,
+    block,
+    gdn,
+    mamba2,
+    short_conv,
+    sparse_attention,
+)
 
 OPERATORS = {"attention": attention, "conv": short_conv, "mamba2": mamba2,
-             "gdn": gdn, "none": block}
+             "gdn": gdn, "sparse_attention": sparse_attention, "none": block}
 
 # ``layer_types`` entry -> (the layer's operator, whether an FFN follows)
 LAYER_KINDS = {"full_attention": ("attention", True),
@@ -46,6 +53,7 @@ LAYER_KINDS = {"full_attention": ("attention", True),
                "conv": ("conv", True),
                "mamba2": ("mamba2", False),
                "linear_attention": ("gdn", True),
+               "sparse_attention": ("sparse_attention", True),
                "attention": ("attention", False),
                "ffn": ("none", True)}
 

@@ -734,11 +734,16 @@ def expert_utilization(arch, params, obs, mask=None) -> dict:
     """Per-layer share of the token-slots per expert — the gate-collapse
     monitor.
 
-    No auxiliary load-balancing loss is applied during training (a
-    deliberate omission: no token is ever dropped, so a collapsed gate is
-    *correct*, just slow — its experts' groups grow and the others' shrink
-    — and an aux loss would have to be plumbed through every algorithm's
-    update). The standard top-k failure mode — the gate collapsing onto a
+    No auxiliary load-balancing loss is applied during training — by
+    choice, not for want of a path: no token is ever dropped, so a collapsed
+    gate is *correct*, just slow (its experts' groups grow and the others'
+    shrink), and a balancing term would pull an RL policy's routing towards
+    evenness at the cost of its return. (The path exists since PR 47: a
+    trunk brings a loss of its own through ``Policy.own_loss`` — the rows
+    come with ``evaluate_stats``, IMPALA's update adds their mean, any
+    other algorithm refuses the policy — which is how a sparse-attention
+    layer's indexer trains; a balancing loss would be sown the same way.)
+    The standard top-k failure mode — the gate collapsing onto a
     few experts — is therefore something to MONITOR: the IMPALA update
     reports the extremes of this every update (``moe_load_max`` /
     ``moe_load_min``); call this on a representative batch for the whole

@@ -51,6 +51,7 @@ from relayrl_tpu.models.arch_keys import (
     BLOCK_KEYS,
     DECLARED,
     MOE_KEYS,
+    OPERATOR_BASE,
     OPERATOR_KEYS,
     settings,
 )
@@ -394,10 +395,11 @@ def _block_settings(arch: Mapping[str, Any]) -> dict:
 
 def _operator_settings(arch: Mapping[str, Any]) -> dict:
     """Operator -> a block's ``cfg``: the arch's values for the operator's
-    declared keys, beside what the trunk tells every layer — its head count
-    and, under ``positions: "rope"``, the rotation's base (None: no
-    rotation; the core clears it for the layers ``rope_layers`` leaves
-    out)."""
+    declared keys (and those of the operator it extends,
+    ``arch_keys.OPERATOR_BASE``), beside what the trunk tells every layer —
+    its head count and, under ``positions: "rope"``, the rotation's base
+    (None: no rotation; the core clears it for the layers ``rope_layers``
+    leaves out)."""
     positions = arch.get("positions", "learned")
     if positions not in ("learned", "rope", "none"):
         raise ValueError(f"unknown positions {positions!r} "
@@ -405,7 +407,8 @@ def _operator_settings(arch: Mapping[str, Any]) -> dict:
     trunk = {"n_heads": int(arch.get("n_heads", 4)),
              "rope_theta": (float(arch.get("rope_theta", 10000.0))
                             if positions == "rope" else None)}
-    return {op: {**settings(keys, arch), **trunk}
+    return {op: {**settings(OPERATOR_KEYS.get(OPERATOR_BASE.get(op), {}),
+                            arch), **settings(keys, arch), **trunk}
             for op, keys in OPERATOR_KEYS.items()}
 
 
@@ -505,19 +508,29 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         arch, init_params, core.apply,
         apply_row_fn=lambda params, obs, mask, idx: core.apply(
             params, obs, mask, readout_t=idx))
+    # the operator that brings a loss of its own, if a layer has one
+    own = next((op for op in (layers.OPERATORS[core.layer_parts(i)[0]]
+                              for i in range(core.n_layers))
+                if hasattr(op, "OWN_LOSS")), None)
+    own_loss = own and own.OWN_LOSS
     evaluate_stats = None
-    if moe_experts > 0:
+    if moe_experts > 0 or own_loss:
         def evaluate_stats(params, obs, act, mask=None):
-            """``evaluate`` plus the expert load of the same forward
-            (``moe.load_extremes`` of the sown group sizes)."""
-            from relayrl_tpu.models.moe import load_extremes
-
+            """``evaluate`` plus what the same forward counted: the expert
+            load (``moe.load_extremes`` of the sown group sizes) and, of a
+            trunk that brings a loss (``Policy.own_loss``), its rows."""
             stats = {}
 
             def apply_fn(params, obs, mask):
                 out, state = core.apply(params, obs, mask,
                                         mutable=["intermediates"])
-                stats.update(load_extremes(state["intermediates"]))
+                if moe_experts > 0:
+                    from relayrl_tpu.models.moe import load_extremes
+
+                    stats.update(load_extremes(state["intermediates"]))
+                if own_loss:
+                    stats.update(own.own_loss_stats(state["intermediates"],
+                                                    obs.shape[1]))
                 return out
 
             out = _policy_from_apply(arch, init_params, apply_fn).evaluate(
@@ -527,7 +540,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     return dataclasses.replace(policy, init_cache=init_cache,
                                step_cached=step_cached,
                                prefill_cache=prefill_cache,
-                               evaluate_stats=evaluate_stats, **records)
+                               evaluate_stats=evaluate_stats,
+                               own_loss=own_loss, **records)
 
 
 @register_model("transformer_discrete")

@@ -27,9 +27,11 @@ from __future__ import annotations
 # -- parts of the jitted update (who opens each: docs/observability.md) ------
 OPTIMIZER = "relayrl_optimizer"      # global-norm clip, Adam, the apply
 VTRACE = "relayrl_vtrace"            # ratios, delta, the reverse recursion, pg_adv
-LOSS = "relayrl_loss"                # the three loss sums, RhoMean, KL
+LOSS = "relayrl_loss"                # the loss sums, a trunk's own, RhoMean, KL
 EMBED = "relayrl_embed"              # obs embedding + learned positions
 OP_PROJ = "relayrl_op_proj"          # a layer's operator less its kernel
+INDEX = "relayrl_index"              # an indexer: projections, scores, selection
+SPARSE_ATTN = "relayrl_sparse_attn"  # attention over the selected keys, and p^
 FFN = "relayrl_ffn"                  # a layer's dense FFN, norm and residual
 MOE_ROUTE = "relayrl_moe_route"      # router, top-k, the sort, load counts
 MOE_ROWS = "relayrl_moe_rows"        # tokens -> rows, rows -> tokens
@@ -38,8 +40,9 @@ HEADS = "relayrl_heads"              # final norm, pi / vf heads, logp, entropy
 OBS_PREP = "relayrl_obs_prep"        # cnn: cast, scale, relayout on entry
 CONV = "relayrl_conv"                # cnn: the conv stack and its dense layer
 
-DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, FFN, MOE_ROUTE,
-                 MOE_ROWS, MOE_ELEMENTWISE, HEADS, OBS_PREP, CONV)
+DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, INDEX, SPARSE_ATTN,
+                 FFN, MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE, HEADS, OBS_PREP,
+                 CONV)
 
 # -- kernels and the operators that keep a name of their own -----------------
 SHORT_CONV_NAME = "relayrl_short_conv"   # models/layers/short_conv.py

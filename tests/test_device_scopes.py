@@ -35,6 +35,7 @@ from relayrl_tpu.ops.scopes import (
     GDN_CONV_NAME,
     GDN_NAME,
     HEADS,
+    INDEX,
     LOSS,
     MAMBA_CONV_NAME,
     MOE_ELEMENTWISE,
@@ -44,6 +45,7 @@ from relayrl_tpu.ops.scopes import (
     OP_PROJ,
     OPTIMIZER,
     SHORT_CONV_NAME,
+    SPARSE_ATTN,
     SSD_NAME,
     VTRACE,
 )
@@ -107,6 +109,16 @@ FAMILIES = {
                 "positions": "rope", "rope_share": 0.5, "qk_norm": "head",
                 "attn_gate": True, "use_bias": False, "ffn": "swiglu"},
                TRUNK + MOE + (FFN, GDN_NAME, GDN_CONV_NAME)),
+    # attention over the keys an indexer picks, 2 of up to 8, in two tiles;
+    # the indexers' own loss under the loss's name (keye-vl2-policy)
+    "sparse": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 2,
+                "n_heads": 4, "n_kv_heads": 2, "head_dim": 8,
+                "layer_types": ["sparse_attention"] * 2, "index_heads": 2,
+                "index_head_dim": 8, "index_topk": 2, "index_chunk": 4,
+                "moe_experts": 8, "moe_top_k": 3, "moe_held": [2, 4],
+                "moe_d_ff": 12, "norm": "rms", "positions": "rope",
+                "qk_norm": "head", "use_bias": False, "ffn": "swiglu"},
+               TRUNK + MOE + (INDEX, SPARSE_ATTN)),
     # the pixel learner (nature-cnn)
     "cnn": ({"kind": "cnn_discrete", "obs_shape": [36, 36, 2],
              "obs_dim": 36 * 36 * 2, "act_dim": 3},
@@ -160,19 +172,26 @@ def lowered():
 
 
 @pytest.fixture(scope="module")
-def paths(lowered):
-    """family -> the ``op_name`` paths of the lowered update's operations
-    (the named locations of its text with debug info)."""
-    return _once(lambda family: sorted(set(re.findall(
-        r'loc\("(jit\([^"]*)"',
-        lowered(family).as_text(debug_info=True)))))
-
-
-@pytest.fixture(scope="module")
 def compiled(lowered):
     """family -> the text of the update compiled for XLA:CPU, where the
     inner ``jit`` calls are inlined and their paths composed."""
     return _once(lambda family: lowered(family).compile().as_text())
+
+
+@pytest.fixture(scope="module")
+def paths(lowered, compiled):
+    """family -> the ``op_name`` paths of the lowered update's operations
+    (the named locations of its text with debug info) and, for the family
+    whose tiles run inside a loop's body (the text names a body's
+    operations from the body's own start), of the compiled update's."""
+    def of(family):
+        found = set(re.findall(r'loc\("(jit\([^"]*)"',
+                               lowered(family).as_text(debug_info=True)))
+        if family == "sparse":
+            found |= set(re.findall(r'op_name="([^"]*)"', compiled(family)))
+        return sorted(found)
+
+    return _once(of)
 
 
 def _part_names(path: str) -> set:
@@ -187,7 +206,7 @@ USES = [(family, scope) for family, (_arch, used) in FAMILIES.items()
 def test_one_list_of_names():
     """Every name a ``with`` line can open is a constant of the one module,
     and the lists hold each once."""
-    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 12
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 14
     assert not set(DEVICE_SCOPES) & set(scopes.KERNEL_SCOPES)
     used = {scope for _family, scope in USES}
     assert used == set(DEVICE_SCOPES) | set(OWN_NAMES)

@@ -28,8 +28,11 @@ def test_every_layer_type_resolves_to_a_whole_operator(kind):
     assert isinstance(has_ffn, bool)
     assert [n for n in INTERFACE if not hasattr(module, n)] == []
     # its settings are its declared keys at their declared defaults (or as
-    # the arch gives them), beside the trunk's head count and rotation
-    declared = arch_keys.OPERATOR_KEYS[op]
+    # the arch gives them) — after those of the operator it extends, where
+    # it extends one —, beside the trunk's head count and rotation
+    base_op = arch_keys.OPERATOR_BASE.get(op)
+    declared = {**arch_keys.OPERATOR_KEYS.get(base_op, {}),
+                **arch_keys.OPERATOR_KEYS[op]}
     cfg = transformer._operator_settings({})[op]
     assert cfg == {**declared, "n_heads": 4, "rope_theta": None}
     given = {k: object() for k in declared}
@@ -40,6 +43,11 @@ def test_every_layer_type_resolves_to_a_whole_operator(kind):
 def test_the_tables_name_the_same_operators():
     assert set(layers.OPERATORS) == set(arch_keys.OPERATOR_KEYS) == {
         op for op, _ in layers.LAYER_KINDS.values()}
+    # an operator extends one of the table, and declares no key of its twice
+    for op, base_op in arch_keys.OPERATOR_BASE.items():
+        assert op in layers.OPERATORS and base_op in layers.OPERATORS
+        assert not set(arch_keys.OPERATOR_KEYS[op]) & set(
+            arch_keys.OPERATOR_KEYS[base_op])
 
 
 def test_the_passthrough_keys_are_the_declarations():
@@ -49,10 +57,10 @@ def test_the_passthrough_keys_are_the_declarations():
                    for k in keys])
     assert list(arch_keys.DECLARED) == declared
     assert base.ARCH_PASSTHROUGH_KEYS == arch_keys.TRUNK_KEYS + tuple(declared)
-    # no key declared twice, and the 51 the configurations' references ask
+    # no key declared twice, and the 55 the configurations' references ask
     # base for (benchmark/reference/*.py) are all there
     assert len(set(base.ARCH_PASSTHROUGH_KEYS)) == len(
-        base.ARCH_PASSTHROUGH_KEYS) == 51
+        base.ARCH_PASSTHROUGH_KEYS) == 55
 
 
 @pytest.mark.parametrize("key", arch_keys.DECLARED)
@@ -112,6 +120,20 @@ TRUNKS = {
         {"ln_attn/scale": (16,), "gdn_in_qkvz": (16, 96),
          "gdn_in_ba": (16, 8), "gdn_conv_w": (4, 64), "gdn_dt_bias": (4,),
          "gdn_A_log": (4,), "gdn_norm": (8,), "gdn_out": (32, 16), **FFN}),
+    "sparse_attention": (
+        {"layer_types": ["sparse_attention"], "positions": "rope",
+         "n_kv_heads": 1, "head_dim": 4, "qk_norm": "head",
+         "index_heads": 3, "index_head_dim": 2, "index_topk": 2,
+         "index_chunk": 4},
+        {"ln_attn/scale": (16,), "q_proj/kernel": (16, 8),
+         "q_proj/bias": (8,), "k_proj/kernel": (16, 4), "k_proj/bias": (4,),
+         "v_proj/kernel": (16, 4), "v_proj/bias": (4,), "q_norm/scale": (4,),
+         "k_norm/scale": (4,), "index_q/kernel": (16, 6),
+         "index_q/bias": (6,), "index_k/kernel": (16, 2),
+         "index_k/bias": (2,), "index_k_norm/scale": (2,),
+         "index_k_norm/bias": (2,), "index_w/kernel": (16, 3),
+         "index_w/bias": (3,), "attn_out/kernel": (8, 16),
+         "attn_out/bias": (16,), **FFN}),
     "none": (
         {"layer_types": ["ffn"], "ffn": "swiglu", "d_ff": 24,
          "positions": "none"},
@@ -130,6 +152,18 @@ def _structure(tree):
 
 def test_a_trunk_for_every_operator():
     assert set(TRUNKS) == set(layers.OPERATORS)
+
+
+def test_only_an_operator_with_a_loss_of_its_own_gives_the_policy_one():
+    """``Policy.own_loss`` follows from the operators' ``OWN_LOSS``, and a
+    trunk that has one has ``evaluate_stats``, where its rows come from."""
+    for op, (arch, _leaves) in TRUNKS.items():
+        policy = build_policy({**BASE, **arch})
+        own = getattr(layers.OPERATORS[op], "OWN_LOSS", None)
+        assert policy.own_loss == own, op
+        assert (policy.evaluate_stats is not None) == (own is not None), op
+    assert [op for op in layers.OPERATORS if hasattr(
+        layers.OPERATORS[op], "OWN_LOSS")] == ["sparse_attention"]
 
 
 @pytest.mark.parametrize("op", sorted(TRUNKS))
