@@ -16,7 +16,10 @@ the indexer through the set. It learns from a loss of its own
 ``p^`` the heads' mean attention over the set, detached — sown as
 ``own_loss_rows`` where the caller collects ``intermediates``, beside
 ``index_kept`` (the pairs kept, per sequence). ``index_chunk`` is the tile
-the scores are computed in and no part of the model.
+the scores are computed in and no part of the model. The attention over the
+set runs as Pallas kernels on a TPU at shapes that tile and as plain XLA
+everywhere else (``ops/sparse_attn.backend``: platform and shape alone);
+:data:`KERNELS` records which, a full-mode shape at a time.
 
 Three modes, one parameter tree:
 
@@ -58,8 +61,11 @@ def _shape(q, k, v, qi, ki, w, topk, chunk, loss):
            int(topk), q.dtype.name)
     causal = n_rows * (n_rows + 1) // 2
     kept = sparse_attn.kept_pairs(n_rows, topk)
-    tile, _ = sparse_attn.stages(n_rows, chunk)
-    return key, "bisect_select+masked_xla", (
+    tile, per_stage = sparse_attn.stages(n_rows, chunk)
+    # every stage's keys are whole multiples of the first's: one answer
+    attention = sparse_attn.backend(tile, per_stage, int(q.shape[2]),
+                                    int(k.shape[2]), key[1])
+    return key, f"bisect_select+{attention}", (
         f"T={n_rows} heads {q.shape[2]}/{k.shape[2]} head_dim={key[1]} "
         f"index {key[2]}x{key[3]} topk={topk} tile={tile} {key[5]}: "
         f"computes {100 * sparse_attn.computed_pairs(n_rows, chunk) / causal:.1f}% "
@@ -67,7 +73,8 @@ def _shape(q, k, v, qi, ki, w, topk, chunk, loss):
 
 
 # ``Policy.index_backends``: ``{(T, head_dim, index heads, index head_dim,
-# topk, dtype): selection + attention backend}`` of the full mode's shapes
+# topk, dtype): "bisect_select+masked_pallas" | "bisect_select+masked_xla"}``
+# of the full mode's shapes: the selection, and what ran the attention over it
 KERNELS = (kernel("index", sparse_attn.sparse_attention, _shape),)
 
 

@@ -1,6 +1,6 @@
 """Attention over a set of keys that a learned indexer picks for each query
-(DeepSeek sparse attention): the indexer's scores, the exact selection and
-the softmax attention over the selected set, in plain XLA.
+(DeepSeek sparse attention): the indexer's scores and the exact selection in
+plain XLA, the softmax attention over the selected set in one of two forms.
 
 For a query ``t`` and the keys ``s <= t``:
 
@@ -35,8 +35,29 @@ and backward, in every layer: the compiled update was 552 MB serialized for
 run compiled anew: PERF.md section 6, PR 47). A stage that ends at or before ``topk`` selects nothing:
 every causal key is kept. The tile is no part of the function.
 
-The three parts carry the names ``relayrl_index``, ``relayrl_sparse_attn``
-and ``relayrl_loss`` onto the device (``ops/scopes.py``).
+**Two forms of the attention over the selected set, picked by what the code
+can observe** (:func:`backend`; no arch key, no environment variable, no
+switch):
+
+* ``masked_pallas`` — on a TPU, for shapes that tile (heads of whole lane
+  tiles, a tile of queries that is a multiple of 128, the keys in whole
+  blocks of 128: :func:`relayrl_tpu.ops.sparse_attn_pallas.fits`): the Pallas
+  kernels of :mod:`relayrl_tpu.ops.sparse_attn_pallas`, one call a tile, the
+  selection an int8 mask operand, a tile's scores and probabilities in VMEM
+  and never in HBM, the key blocks past the tile's last query skipped from
+  its position, a hand-written backward (``jax.custom_vjp``).
+  ``keye-vl2-policy.update`` runs them (PERF.md section 6, PR 48: 1,503 ms an
+  update of masked-dense plain XLA at 2.1% of its roofline before them).
+* ``masked_xla`` (:func:`masked_attention`) — everywhere else (CPU actor
+  hosts, CI, the cached step's and the readout's few rows, a tile that does
+  not tile) and the reference the kernels' tests hold them to: plain XLA,
+  masked-dense, a ``[Hkv, H / Hkv, Tq, Tk]`` float32 score tile through HBM,
+  backward by autodiff.
+
+The indexer's scores, the selection and the loss are plain XLA in both. The
+three parts carry the names ``relayrl_index``, ``relayrl_sparse_attn`` and
+``relayrl_loss`` onto the device (``ops/scopes.py``), the kernels under the
+second and under no deeper ``relayrl_`` name.
 """
 
 from __future__ import annotations
@@ -50,6 +71,9 @@ from relayrl_tpu.ops.attention import _NEG_INF
 from relayrl_tpu.ops.scopes import INDEX, LOSS, SPARSE_ATTN
 
 STAGES = 4  # of a sequence: a tile computes the keys up to its stage's end
+PALLAS, XLA = "masked_pallas", "masked_xla"
+# the name a tile's checkpoint keeps the kernels' log-sum-exp under
+LSE_NAME = "sparse_attn_lse"
 
 
 def index_scores(qi, ki, w):
@@ -115,6 +139,41 @@ def masked_attention(q, k, v, keep):
     return out.reshape(tq, n_heads, width), jnp.mean(p, axis=(0, 1))
 
 
+def backend(tq: int, tk: int, n_heads: int, n_kv: int, width: int) -> str:
+    """``"masked_pallas"`` or ``"masked_xla"``: what :func:`attention` runs
+    ``tq`` queries of ``n_heads`` heads of ``width`` over ``tk`` keys of
+    ``n_kv`` heads as on this process's platform. The kernels on a TPU where
+    the shapes tile (``sparse_attn_pallas.fits``: heads of whole lane tiles,
+    a tile of queries and the keys in whole blocks), plain XLA everywhere
+    else — CPU actor hosts, CI, the cached step's and the readout's few
+    rows, a tile that does not divide. Platform and shape decide, nothing
+    else: no arch key, no environment variable."""
+    if jax.default_backend() != "tpu":
+        return XLA
+    from relayrl_tpu.ops import sparse_attn_pallas
+
+    return PALLAS if sparse_attn_pallas.fits(tq, tk, n_heads, n_kv,
+                                             width) else XLA
+
+
+def attention(q, k, v, keep, pos, want_p_hat: bool = True,
+              defer: bool = False):
+    """:func:`masked_attention` of queries at the positions ``pos [Tq]``,
+    as :func:`backend` says -> ``(out, p^, owed)``; ``p^`` is None unless
+    ``want_p_hat``. ``keep`` names no key after its query's position and at
+    least one key a query. ``defer``: where the kernels run, the caller
+    settles their backward's ``delta`` over the ``out`` it assembles
+    (``owed [Tq, H]``: ``sparse_attn_pallas.settle``); ``owed`` is None
+    wherever nothing is owed."""
+    if backend(q.shape[0], k.shape[0], q.shape[1], k.shape[1],
+               q.shape[2]) == PALLAS:
+        from relayrl_tpu.ops.sparse_attn_pallas import masked_attention_pallas
+
+        return masked_attention_pallas(q, k, v, keep, pos, want_p_hat, defer)
+    out, p_hat = masked_attention(q, k, v, keep)
+    return out, p_hat if want_p_hat else None, None
+
+
 def index_kl(p_hat, scores, keep):
     """``KL(p^[t, .] || softmax over the kept keys of scores[t, .])`` a
     row, ``[Tq]`` float32 (``p^`` sums to one over the kept keys and is
@@ -125,14 +184,10 @@ def index_kl(p_hat, scores, keep):
                    - p_hat * jnp.where(keep, log_pi, 0.0), axis=-1)
 
 
-def sparse_rows(q, qi, w, pos, k, v, ki, topk: int, select: bool = True,
-                loss: bool = True):
-    """Queries at positions ``pos [Tq]`` against the key rows ``0 .. Tk -
-    1`` -> ``(out [Tq, H, D], kl [Tq], kept [Tq])``: the attention over each
-    query's selected keys, its row of the indexer's loss (zeros unless
-    ``loss``) and how many keys it kept. ``select`` False: the caller knows
-    that no row sees more than ``topk`` keys, and every causal key is
-    kept."""
+def _rows(q, qi, w, pos, k, v, ki, topk: int, select: bool, loss: bool,
+          defer: bool):
+    """:func:`sparse_rows` and what its attention owes (:func:`attention`'s
+    ``owed``; None unless ``defer`` and the kernels ran)."""
     seen = pos[:, None] >= jnp.arange(k.shape[0])[None, :]
     keep, scores = seen, None
     if select or loss:
@@ -141,11 +196,22 @@ def sparse_rows(q, qi, w, pos, k, v, ki, topk: int, select: bool = True,
             if select:
                 keep = top_k_mask(jax.lax.stop_gradient(scores), seen, topk)
     with jax.named_scope(SPARSE_ATTN):
-        out, p_hat = masked_attention(q, k, v, keep)
+        out, p_hat, owed = attention(q, k, v, keep, pos, loss, defer)
     with jax.named_scope(LOSS):
         kl = (index_kl(jax.lax.stop_gradient(p_hat), scores, keep) if loss
               else jnp.zeros(q.shape[0], jnp.float32))
-        return out, kl, jnp.sum(keep, axis=-1, dtype=jnp.int32)
+        return out, kl, jnp.sum(keep, axis=-1, dtype=jnp.int32), owed
+
+
+def sparse_rows(q, qi, w, pos, k, v, ki, topk: int, select: bool = True,
+                loss: bool = True):
+    """Queries at positions ``pos [Tq]`` against the key rows ``0 .. Tk -
+    1`` -> ``(out [Tq, H, D], kl [Tq], kept [Tq])``: the attention over each
+    query's selected keys, its row of the indexer's loss (zeros unless
+    ``loss``) and how many keys it kept. ``select`` False: the caller knows
+    that no row sees more than ``topk`` keys, and every causal key is
+    kept."""
+    return _rows(q, qi, w, pos, k, v, ki, topk, select, loss, False)[:3]
 
 
 def stages(n_rows: int, chunk: int) -> tuple[int, int]:
@@ -180,8 +246,12 @@ def _sequence(q, k, v, qi, ki, w, topk, chunk, loss):
     outs = []
     for start in range(0, n_rows, per_stage):
         end = start + per_stage
-        rows = jax.checkpoint(functools.partial(
-            sparse_rows, topk=topk, select=end > topk, loss=loss))
+        # of a tile's forward its backward wants the kernels' log-sum-exp
+        # alone (``sparse_attn_pallas.settle``); the plain form keeps nothing
+        rows = jax.checkpoint(
+            functools.partial(_rows, topk=topk, select=end > topk, loss=loss,
+                              defer=True),
+            policy=jax.checkpoint_policies.save_only_these_names(LSE_NAME))
 
         def one_tile(args, end=end, rows=rows):
             return rows(*args, k[:end], v[:end], ki[:end])
@@ -192,7 +262,13 @@ def _sequence(q, k, v, qi, ki, w, topk, chunk, loss):
         outs.append(jax.tree_util.tree_map(
             lambda a: a.reshape(per_stage, *a.shape[2:]),
             jax.lax.map(one_tile, tiles)))
-    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
+    out, kl, kept, owed = jax.tree_util.tree_map(
+        lambda *a: jnp.concatenate(a), *outs)
+    if owed is not None:
+        from relayrl_tpu.ops.sparse_attn_pallas import settle
+
+        out = settle(out, owed)
+    return out, kl, kept
 
 
 def sparse_attention(q, k, v, qi, ki, w, topk: int, chunk: int,

@@ -389,6 +389,60 @@ def test_the_rule_kernels_count_for_the_rules_name(monkeypatch):
     assert bare.as_text() == named.as_text()
 
 
+def _lower_sparse_attention_kernels():
+    """The sparse attention's Pallas kernels (what a ``sparse_attention``
+    layer's tiles run at shapes that tile, on a TPU), lowered through the
+    interpreter: forward, ``p^`` and the ``custom_vjp``'s backward."""
+    from relayrl_tpu.ops import sparse_attn_pallas
+
+    for cached in (sparse_attn_pallas._build, sparse_attn_pallas._make_rule):
+        cached.cache_clear()        # a call is named where it is built
+    S = jax.ShapeDtypeStruct
+    args = [S((128, 4, 128), jnp.float32), S((256, 2, 128), jnp.float32),
+            S((256, 2, 128), jnp.float32)]
+    pos = 128 + jnp.arange(128)
+    keep = pos[:, None] >= jnp.arange(256)[None, :]
+
+    def loss(q, k, v):
+        with jax.named_scope(scopes.SPARSE_ATTN):   # as ``sparse_rows`` does
+            out, p_hat, _ = sparse_attn_pallas.masked_attention_pallas(
+                q, k, v, keep, pos, True, interpret=True)
+        return jnp.sum(out) + jnp.sum(p_hat)
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*args)
+
+
+def test_the_sparse_attention_kernels_count_for_the_scopes_name(monkeypatch):
+    """Every kernel call's innermost ``relayrl_`` name is
+    ``relayrl_sparse_attn`` — ``sparse_attn_ms`` reads the exact scope, so
+    the kernels' own names carry no such prefix (and nothing of
+    ``relayrl_flash_``, which the flash readers match anywhere) — in the
+    forward and in the backward rule, which opens the scope itself; and the
+    names are metadata."""
+    from relayrl_tpu.ops import sparse_attn_pallas
+
+    named = _lower_sparse_attention_kernels()
+    paths = set(re.findall(r'loc\("(jit\([^"]*)"',
+                           named.as_text(debug_info=True)))
+    for kernel, backward in ((sparse_attn_pallas.FWD_NAME, False),
+                             (sparse_attn_pallas.PHAT_NAME, False),
+                             (sparse_attn_pallas.DQ_NAME, True),
+                             (sparse_attn_pallas.DKV_NAME, True)):
+        assert not kernel.startswith("relayrl_")
+        mine = [p for p in paths if re.search(rf"/{kernel}(/|$)", p)]
+        assert mine, kernel
+        for path in mine:
+            assert set(re.findall(r"relayrl_\w+", path)) == {
+                scopes.SPARSE_ATTN}, path
+            assert ("transpose(" in path) == backward, path
+    assert not [p for p in paths if "relayrl_flash_" in p]
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _lower_sparse_attention_kernels()
+    assert scopes.SPARSE_ATTN not in bare.as_text(debug_info=True)
+    assert bare.as_text() == named.as_text()
+
+
 def test_the_doc_names_every_scope():
     """``docs/observability.md``, "Device names": every part and every
     kernel name of the one list, each beside what reads it."""

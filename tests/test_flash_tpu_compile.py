@@ -423,6 +423,57 @@ def test_the_mixers_convolution_compiles_for_v5e(one_chip, columns, bias,
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * rows
 
 
+def test_the_sparse_attention_kernels_compile_for_v5e(one_chip):
+    """The attention over the selected keys (``ops/sparse_attn_pallas.py``)
+    at ``keye-vl2-policy.update``'s last tile — 512 queries of 32 heads of
+    128 over 16,384 keys of 4, bfloat16 —, forward, ``p^`` and every
+    gradient: four Mosaic calls, ``sparse_attn_fwd`` and
+    ``sparse_attn_phat`` forward, ``sparse_attn_dq`` and ``sparse_attn_dkv``
+    in the backward, each under ``relayrl_sparse_attn`` and under no other
+    ``relayrl_`` name (the benchmark's ``sparse_attn_ms`` reads the exact
+    scope; a name holding ``relayrl_flash_`` would be read as flash), and
+    nothing of a score tile's size in HBM but ``p^`` itself: one float32
+    ``[512, 16384]`` (33.6 MB) beside the operands."""
+    from relayrl_tpu.ops import sparse_attn_pallas as kernels
+
+    tq, tk, heads, kv, width = 512, 16_384, 32, 4, 128
+    assert kernels.fits(tq, tk, heads, kv, width)
+    assert kernels.key_block(tk) == 512
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+
+    def value_and_grads(q, k, v, keep, pos):
+        def loss(q, k, v):
+            with jax.named_scope(scopes.SPARSE_ATTN):   # ``sparse_rows``'
+                out, p_hat, _ = kernels.masked_attention_pallas(q, k, v, keep,
+                                                             pos, True)
+            return jnp.sum(out.astype(jnp.float32)), p_hat
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+
+    compiled = jax.jit(value_and_grads).lower(
+        S((tq, heads, width), jnp.bfloat16), S((tk, kv, width), jnp.bfloat16),
+        S((tk, kv, width), jnp.bfloat16), S((tq, tk), jnp.bool_),
+        S((tq,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'(%[\w.\-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) == sorted(
+        "%" + name for name in (kernels.FWD_NAME, kernels.PHAT_NAME,
+                                kernels.DQ_NAME, kernels.DKV_NAME))
+    for name, path in calls:
+        assert set(re.findall(r"relayrl_\w+", path)) == {
+            scopes.SPARSE_ATTN}, path
+        assert ("transpose(" in path) == (
+            kernels.DQ_NAME in name or kernels.DKV_NAME in name), path
+    assert "relayrl_flash" not in text
+    assert not re.findall(r"\bwhile\(", text)
+    # the int8 mask, the scaled and turned q / do / out, delta: well under a
+    # second score tile
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * tq * tk * 4
+
+
 def test_the_whole_rotary_is_todays_function_bit_for_bit():
     """``apply_rope`` at a share of 1.0 (the default) is the function every
     accepted configuration has run: the same bits as its lines written out
