@@ -156,11 +156,12 @@ class Policy:
     # layers, None for other families.
     conv_backends: Mapping[tuple, str] | None = None
     # Sequence policies with sparse-attention layers: ``{(T, head_dim, index
-    # heads, index head_dim, topk, dtype): "bisect_select+masked_pallas" |
-    # "bisect_select+masked_xla"}`` for every full-mode shape traced so far
-    # (models/layers/sparse_attention.py) — whether ``ops/sparse_attn.py``
-    # ran the attention over the selected keys as the Pallas kernels (a TPU,
-    # shapes that tile) or as plain XLA. Empty for a trunk without such
+    # heads, index head_dim, topk, dtype): "select_pallas+masked_pallas" |
+    # "bisect_select+masked_xla" | ...}`` for every full-mode shape traced so
+    # far (models/layers/sparse_attention.py) — whether ``ops/sparse_attn.py``
+    # ran the indexer's scores and selection (before the ``+``) and the
+    # attention over the selected keys (after it) as the Pallas kernels (a
+    # TPU, shapes that tile) or as plain XLA. Empty for a trunk without such
     # layers, None for other families.
     index_backends: Mapping[tuple, str] | None = None
     # MoE and sparse-attention trunks: ``evaluate_stats(params, obs, act,

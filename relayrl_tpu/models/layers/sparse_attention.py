@@ -17,9 +17,10 @@ the indexer through the set. It learns from a loss of its own
 ``own_loss_rows`` where the caller collects ``intermediates``, beside
 ``index_kept`` (the pairs kept, per sequence). ``index_chunk`` is the tile
 the scores are computed in and no part of the model. The attention over the
-set runs as Pallas kernels on a TPU at shapes that tile and as plain XLA
-everywhere else (``ops/sparse_attn.backend``: platform and shape alone);
-:data:`KERNELS` records which, a full-mode shape at a time.
+set, and the indexer's scores and selection in front of it, run as Pallas
+kernels on a TPU at shapes that tile and as plain XLA everywhere else
+(``ops/sparse_attn.backend`` and ``index_backend``: platform and shape
+alone); :data:`KERNELS` records which, a full-mode shape at a time.
 
 Three modes, one parameter tree:
 
@@ -65,7 +66,8 @@ def _shape(q, k, v, qi, ki, w, topk, chunk, loss):
     # every stage's keys are whole multiples of the first's: one answer
     attention = sparse_attn.backend(tile, per_stage, int(q.shape[2]),
                                     int(k.shape[2]), key[1])
-    return key, f"bisect_select+{attention}", (
+    selection = sparse_attn.index_backend(tile, per_stage, key[2], key[3])
+    return key, f"{selection}+{attention}", (
         f"T={n_rows} heads {q.shape[2]}/{k.shape[2]} head_dim={key[1]} "
         f"index {key[2]}x{key[3]} topk={topk} tile={tile} {key[5]}: "
         f"computes {100 * sparse_attn.computed_pairs(n_rows, chunk) / causal:.1f}% "
@@ -73,8 +75,9 @@ def _shape(q, k, v, qi, ki, w, topk, chunk, loss):
 
 
 # ``Policy.index_backends``: ``{(T, head_dim, index heads, index head_dim,
-# topk, dtype): "bisect_select+masked_pallas" | "bisect_select+masked_xla"}``
-# of the full mode's shapes: the selection, and what ran the attention over it
+# topk, dtype): "select_pallas+masked_pallas" | "bisect_select+masked_xla" |
+# ...}`` of the full mode's shapes: what ran the indexer's scores and
+# selection (``ops/sparse_attn.index_backend``), and what the attention over it
 KERNELS = (kernel("index", sparse_attn.sparse_attention, _shape),)
 
 

@@ -1,6 +1,6 @@
 """Attention over a set of keys that a learned indexer picks for each query
-(DeepSeek sparse attention): the indexer's scores and the exact selection in
-plain XLA, the softmax attention over the selected set in one of two forms.
+(DeepSeek sparse attention): the indexer's scores with the exact selection,
+and the softmax attention over the selected set, each in one of two forms.
 
 For a query ``t`` and the keys ``s <= t``:
 
@@ -25,7 +25,8 @@ For a query ``t`` and the keys ``s <= t``:
 queries, each a ``jax.checkpoint`` (the backward makes a tile's scores,
 selection and probabilities again; nothing ``[T, T]`` is ever kept — keeping
 the selection alone, a bool a computed pair, read 1.16 GB more for one
-threshold search less, ``rehearse_compile``, PR 47), in
+threshold search less, ``rehearse_compile``, PR 47; the kernels keep each
+row's threshold instead, 8 B a query), in
 stages of a quarter of the sequence so that a tile meets the keys up to its
 stage's end and not the whole row: 62.5% of the square at 4 stages, where
 the causal half is 50% (8 stages compute 56% and read 7,820 samples/s at the
@@ -54,10 +55,33 @@ switch):
   masked-dense, a ``[Hkv, H / Hkv, Tq, Tk]`` float32 score tile through HBM,
   backward by autodiff.
 
-The indexer's scores, the selection and the loss are plain XLA in both. The
+**Two forms of the indexer's scores and selection, picked the same way**
+(:func:`index_backend`):
+
+* ``select_pallas`` — on a TPU, for shapes that tile (whole query blocks, the
+  keys in whole blocks, index heads of half a lane tile or more:
+  :func:`relayrl_tpu.ops.index_pallas.fits`): the kernels of
+  :mod:`relayrl_tpu.ops.index_pallas`. A block of queries' scores stay in VMEM
+  from the matmuls that make them to the selection that reads them:
+  ``index_kth`` searches the rows' thresholds (the same 32-step bisection over
+  :func:`_ordered_bits`' order, each step a compare and count over VMEM
+  scratch), ``index_select`` makes the scores again, compares once, settles
+  the ties in index order and writes ``keep`` as the int8 tile the attention's
+  kernels read (and the scores where the loss wants them), ``index_bwd`` is
+  the scores' backward. A tile's checkpoint keeps the thresholds by name
+  (:data:`KTH_NAME`, 128 KB a layer), so the search runs once an update and
+  the recompute selects in one pass. ``keye-vl2-policy.update`` runs them
+  (PERF.md section 6, PR 49: 418.5 ms an update of plain XLA at 3.0% of its
+  roofline before them).
+* ``bisect_select`` (:func:`index_scores` + :func:`top_k_mask`) — everywhere
+  else, and the reference the kernels' tests hold them to: plain XLA, the 16
+  heads' terms and 32 passes over a tile's bits through HBM.
+
+The two rules are each their own: a shape may take one form's kernels and
+the other's plain XLA. The loss (:func:`index_kl`) is plain XLA in both. The
 three parts carry the names ``relayrl_index``, ``relayrl_sparse_attn`` and
 ``relayrl_loss`` onto the device (``ops/scopes.py``), the kernels under the
-second and under no deeper ``relayrl_`` name.
+first two and under no deeper ``relayrl_`` name.
 """
 
 from __future__ import annotations
@@ -74,6 +98,9 @@ STAGES = 4  # of a sequence: a tile computes the keys up to its stage's end
 PALLAS, XLA = "masked_pallas", "masked_xla"
 # the name a tile's checkpoint keeps the kernels' log-sum-exp under
 LSE_NAME = "sparse_attn_lse"
+# ... and the indexer's kernels' thresholds (``ops/index_pallas.py``)
+KTH_NAME = "index_kth_room"
+SELECT_PALLAS, SELECT_XLA = "select_pallas", "bisect_select"
 
 
 def index_scores(qi, ki, w):
@@ -156,6 +183,43 @@ def backend(tq: int, tk: int, n_heads: int, n_kv: int, width: int) -> str:
                                              width) else XLA
 
 
+def index_backend(tq: int, tk: int, n_heads: int, width: int) -> str:
+    """``"select_pallas"`` or ``"bisect_select"``: what :func:`indexer` runs
+    ``tq`` queries of ``n_heads`` index heads of ``width`` over ``tk`` keys as
+    on this process's platform — :func:`backend`'s rule for the indexer: the
+    kernels of :mod:`relayrl_tpu.ops.index_pallas` on a TPU where the shapes
+    tile (``index_pallas.fits``), :func:`index_scores` and
+    :func:`top_k_mask` everywhere else. Platform and shape decide, nothing
+    else."""
+    if jax.default_backend() != "tpu":
+        return SELECT_XLA
+    from relayrl_tpu.ops import index_pallas
+
+    return SELECT_PALLAS if index_pallas.fits(tq, tk, n_heads,
+                                              width) else SELECT_XLA
+
+
+def indexer(qi, ki, w, pos, topk: int, choose: bool, want_scores: bool):
+    """The indexer of queries at the positions ``pos [Tq]``, as
+    :func:`index_backend` says -> ``(keep [Tq, Tk] bool or int8, scores [Tq,
+    Tk] float32 | None)``: each query's seen keys, of them the ``topk`` of
+    largest index score where ``choose``; the scores where something reads
+    them (``choose`` or ``want_scores``)."""
+    seen = pos[:, None] >= jnp.arange(ki.shape[0])[None, :]
+    if not (choose or want_scores):
+        return seen, None
+    with jax.named_scope(INDEX):
+        if index_backend(qi.shape[0], ki.shape[0], qi.shape[1],
+                         qi.shape[2]) == SELECT_PALLAS:
+            from relayrl_tpu.ops.index_pallas import index_select
+
+            return index_select(qi, ki, w, pos, topk, choose, want_scores)
+        scores = index_scores(qi, ki, w)
+        if not choose:
+            return seen, scores
+        return top_k_mask(jax.lax.stop_gradient(scores), seen, topk), scores
+
+
 def attention(q, k, v, keep, pos, want_p_hat: bool = True,
               defer: bool = False):
     """:func:`masked_attention` of queries at the positions ``pos [Tq]``,
@@ -170,7 +234,7 @@ def attention(q, k, v, keep, pos, want_p_hat: bool = True,
         from relayrl_tpu.ops.sparse_attn_pallas import masked_attention_pallas
 
         return masked_attention_pallas(q, k, v, keep, pos, want_p_hat, defer)
-    out, p_hat = masked_attention(q, k, v, keep)
+    out, p_hat = masked_attention(q, k, v, keep.astype(bool))
     return out, p_hat if want_p_hat else None, None
 
 
@@ -188,17 +252,12 @@ def _rows(q, qi, w, pos, k, v, ki, topk: int, select: bool, loss: bool,
           defer: bool):
     """:func:`sparse_rows` and what its attention owes (:func:`attention`'s
     ``owed``; None unless ``defer`` and the kernels ran)."""
-    seen = pos[:, None] >= jnp.arange(k.shape[0])[None, :]
-    keep, scores = seen, None
-    if select or loss:
-        with jax.named_scope(INDEX):
-            scores = index_scores(qi, ki, w)
-            if select:
-                keep = top_k_mask(jax.lax.stop_gradient(scores), seen, topk)
+    keep, scores = indexer(qi, ki, w, pos, topk, select, loss)
     with jax.named_scope(SPARSE_ATTN):
         out, p_hat, owed = attention(q, k, v, keep, pos, loss, defer)
     with jax.named_scope(LOSS):
-        kl = (index_kl(jax.lax.stop_gradient(p_hat), scores, keep) if loss
+        kl = (index_kl(jax.lax.stop_gradient(p_hat), scores,
+                       keep.astype(bool)) if loss
               else jnp.zeros(q.shape[0], jnp.float32))
         return out, kl, jnp.sum(keep, axis=-1, dtype=jnp.int32), owed
 
@@ -251,7 +310,8 @@ def _sequence(q, k, v, qi, ki, w, topk, chunk, loss):
         rows = jax.checkpoint(
             functools.partial(_rows, topk=topk, select=end > topk, loss=loss,
                               defer=True),
-            policy=jax.checkpoint_policies.save_only_these_names(LSE_NAME))
+            policy=jax.checkpoint_policies.save_only_these_names(
+                LSE_NAME, KTH_NAME))
 
         def one_tile(args, end=end, rows=rows):
             return rows(*args, k[:end], v[:end], ki[:end])

@@ -9,6 +9,7 @@ process may load the TPU's library, and every xdist worker imports every
 test file. Keep these tests in this one file.
 """
 
+import functools
 import re
 
 import jax
@@ -472,6 +473,59 @@ def test_the_sparse_attention_kernels_compile_for_v5e(one_chip):
     # the int8 mask, the scaled and turned q / do / out, delta: well under a
     # second score tile
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * tq * tk * 4
+
+
+@pytest.mark.parametrize("tk", [16_384, 4_096])
+def test_the_indexers_kernels_compile_for_v5e(one_chip, tk):
+    """The indexer's scores, selection and backward (``ops/index_pallas.py``)
+    at ``keye-vl2-policy.update``'s last and first stages' tiles — 512
+    queries of 16 index heads of 64 over ``tk`` keys, bfloat16 —: four
+    Mosaic calls, ``index_kth`` and ``index_select`` forward, ``index_select``
+    again under a checkpoint that keeps the thresholds (and NO second
+    ``index_kth``), ``index_bwd`` in the backward, each under
+    ``relayrl_index`` and under no other ``relayrl_`` name (the benchmark's
+    ``index_ms`` reads the exact scope), and nothing of a score tile's size
+    in HBM but what the callers read: ``keep`` (int8), the scores and their
+    cotangent."""
+    from relayrl_tpu.ops import index_pallas as kernels
+    from relayrl_tpu.ops import sparse_attn
+
+    tq, heads, width, topk = 512, 16, 64, 2_048
+    assert kernels.fits(tq, tk, heads, width)
+    assert kernels.key_block(tk) == 512
+    assert kernels.query_block(tq, tk, heads, width) == 512
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+
+    def grads(qi, ki, w, pos, weight):
+        @functools.partial(
+            jax.checkpoint,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                sparse_attn.LSE_NAME, sparse_attn.KTH_NAME))
+        def tile(qi, ki, w):
+            with jax.named_scope(scopes.INDEX):     # ``sparse_attn.indexer``'s
+                keep, scores = kernels.index_select(qi, ki, w, pos, topk)
+            return jnp.sum(jnp.where(keep != 0, scores * weight, 0.0))
+
+        return jax.value_and_grad(tile, argnums=(0, 1, 2))(qi, ki, w)
+
+    compiled = jax.jit(grads).lower(
+        S((tq, heads, width), jnp.bfloat16), S((tk, width), jnp.bfloat16),
+        S((tq, heads), jnp.bfloat16), S((tq,), jnp.int32),
+        S((tq, tk), jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'(%[\w.\-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) == sorted(
+        "%" + name for name in (kernels.SEARCH_NAME, kernels.SELECT_NAME,
+                                kernels.SELECT_NAME, kernels.BWD_NAME))
+    for name, path in calls:
+        assert set(re.findall(r"relayrl_\w+", path)) == {scopes.INDEX}, path
+    assert "relayrl_flash" not in text and "relayrl_sparse" not in text
+    assert not re.findall(r"\bwhile\(", text)
+    # the scores, their cotangent and the weight, keep, and the operands as
+    # the kernels take them: no third float32 tile
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6 * tq * tk * 4
 
 
 def test_the_whole_rotary_is_todays_function_bit_for_bit():

@@ -234,7 +234,7 @@ class TestTheRule:
         assert key == (16_384, 128, 16, 64, 2_048, "bfloat16")
         assert ran == "bisect_select+masked_xla"
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        assert layer._shape(*args)[1] == "bisect_select+masked_pallas"
+        assert layer._shape(*args)[1] == "select_pallas+masked_pallas"
         # a sequence of one row (``init``'s), a tile that does not divide
         short = tuple(S((1, 1) + a.shape[2:], a.dtype) for a in args[:6])
         assert layer._shape(*short, 2_048, 512, True)[1] == (
