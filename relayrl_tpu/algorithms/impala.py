@@ -214,6 +214,19 @@ class IMPALA(OnPolicyAlgorithm):
             }
             self._fence_notes = ("moe_load_max", "moe_held_slots",
                                  "moe_row_passes")
+        loop_steps = int(self.policy.arch.get("loop_steps", 1))
+        if loop_steps > 1:
+            # a looped trunk: a sample costs loop_steps passes, set once so
+            # that a reader of samples/s can tell it from an un-looped one
+            from relayrl_tpu import telemetry
+
+            reg = telemetry.get_registry()
+            reg.gauge("relayrl_loop_steps",
+                      "passes a looped trunk makes over its one parameter "
+                      "tree a forward (the arch's loop_steps)").set(loop_steps)
+            reg.gauge("relayrl_layer_applications",
+                      "block applications a forward: loop_steps x n_layers"
+                      ).set(loop_steps * int(self.policy.arch.get("n_layers", 2)))
         # a trunk with a loss of its own: the loss as the update reports it
         # and the share of the causal pairs its selections kept
         self._own_loss_keys = ((self.policy.own_loss, "index_kept_pct")

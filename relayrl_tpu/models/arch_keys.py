@@ -22,9 +22,15 @@ TRUNK_KEYS = ("d_model", "n_layers", "n_heads", "mlp_ratio", "max_seq_len",
 # ``layers.LAYER_KINDS`` a layer; ``moe_dense_layers`` leading layers keep
 # the dense FFN in a MoE trunk; ``sliding_window`` of the windowed layers)
 # and where the positions come from (``positions``: "learned" | "rope" |
-# "none"; under "rope", ``rope_layers`` says layer by layer which rotate).
+# "none"; under "rope", ``rope_layers`` says layer by layer which rotate);
+# how often the stack runs (``loop_steps`` passes over ONE parameter tree,
+# the final norm's output of a pass the next pass's input; 1: once) and
+# whether the learner's forward keeps of a block application its input and
+# the flash kernel's output alone and makes the rest again in its backward
+# (``block_checkpoint``).
 CORE_KEYS = ("layer_types", "moe_dense_layers", "sliding_window",
-             "positions", "rope_theta", "rope_layers")
+             "positions", "rope_theta", "rope_layers",
+             "loop_steps", "block_checkpoint")
 
 # What every layer shares (``TransformerBlock``'s fields of the same names).
 # With none of them given it is the GPT-2 shaped block: LayerNorm at flax's
@@ -33,6 +39,7 @@ BLOCK_KEYS: Mapping[str, Any] = {
     "norm": "layer",                # | "rms"
     "norm_eps": None,               # None: flax's 1e-6
     "norm_zero_centred": False,     # RMSNorm weights as offsets from one
+    "norm_sandwich": False,         # a 2nd norm on each half's OUTPUT
     "use_bias": True,
     "ffn": "gelu",                  # | "relu2" | "swiglu" | "reglu"
     "d_ff": None,                   # FFN width; None: mlp_ratio * d_model

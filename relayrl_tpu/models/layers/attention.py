@@ -47,6 +47,7 @@ from relayrl_tpu.models.layers.block import (
     block_dense,
     block_ffn,
     block_norm,
+    block_residual,
 )
 from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
 from relayrl_tpu.ops.scopes import OP_PROJ
@@ -327,8 +328,8 @@ def apply(block, x, cache, t, readout_idx, n_valid):
                 attn = _gated(attn, jax.lax.dynamic_slice_in_dim(
                     gate, readout_idx, 1, axis=1))
             row_in = jax.lax.dynamic_slice_in_dim(x, readout_idx, 1, axis=1)
-            x = row_in + block_dense(block, d, "attn_out")(attn).astype(
-                x.dtype)
+            x = block_residual(block, row_in, block_dense(
+                block, d, "attn_out")(attn), "ln_attn_out")
         return block_ffn(block, x, row_in)
     if rope:
         with jax.named_scope(OP_PROJ):
@@ -355,7 +356,9 @@ def apply(block, x, cache, t, readout_idx, n_valid):
         attn = attn.reshape(B, T, width)
         if gate is not None:
             attn = _gated(attn, gate)
-        x = x + block_dense(block, d, "attn_out")(attn).astype(x.dtype)
+        x = block_residual(block, x,
+                           block_dense(block, d, "attn_out")(attn),
+                           "ln_attn_out")
     out = block_ffn(block, x, layer_in)
     return out if cache is None else (out, new_cache)
 

@@ -63,6 +63,17 @@ def block_dense(block, features: int, name: str):
                     use_bias=block.use_bias)
 
 
+def block_residual(block, x, h, name: str):
+    """A half's residual add, ``x + h`` in ``x``'s dtype — under the arch's
+    ``norm_sandwich`` ``x + norm(h)``: a second norm, ``name``, on the
+    half's OUTPUT (Ouro's ``input_layernorm_2`` /
+    ``post_attention_layernorm_2``). The caller opens the scope."""
+    h = h.astype(x.dtype)
+    if block.norm_sandwich:
+        h = block_norm(block, name)(h)
+    return x + h
+
+
 def block_ffn(block, x, layer_in):
     """``x + FFN(norm(x))`` in ``block``'s param scope: the arch's dense
     FFN or the MoE layer. ``layer_in``: the rows of the layer's own input
@@ -94,7 +105,7 @@ def block_ffn(block, x, layer_in):
                        h, layer_in if block.moe_router_input == "layer"
                        else None)
         with jax.named_scope(part):
-            return x + h.astype(x.dtype)
+            return block_residual(block, x, h, "ln_mlp_out")
     with jax.named_scope(part):
         h = h.astype(block.compute_dtype)
         up = block_dense(block, width, "mlp_up")(h)
@@ -104,7 +115,7 @@ def block_ffn(block, x, layer_in):
         else:
             h = UNGATED_FFN[block.ffn](up)
         h = block_dense(block, block.d_model, "mlp_down")(h)
-        return x + h.astype(x.dtype)
+        return block_residual(block, x, h, "ln_mlp_out")
 
 
 # -- the operator "none" (``layers``' interface) ----------------------------

@@ -24,7 +24,11 @@ from typing import Callable, Sequence
 
 import jax
 
-from relayrl_tpu.models.layers.block import block_ffn, block_norm
+from relayrl_tpu.models.layers.block import (
+    block_ffn,
+    block_norm,
+    block_residual,
+)
 from relayrl_tpu.ops import conv as conv_ops
 from relayrl_tpu.ops.scopes import OP_PROJ
 
@@ -83,7 +87,7 @@ def mixer_apply(build: Callable, kept: Sequence[str]) -> Callable:
         else:
             y, padded, state = mix(h, weights, *cache, n_valid)
         with jax.named_scope(OP_PROJ):
-            x_out = x + y.astype(x.dtype)
+            x_out = block_residual(block, x, y, "ln_attn_out")
         out = block_ffn(block, x_out, x)
         if cache is None:
             return out

@@ -20,6 +20,7 @@ from relayrl_tpu.models.layers.block import (
     block_dense,
     block_ffn,
     block_norm,
+    block_residual,
 )
 from relayrl_tpu.ops.scopes import OP_PROJ, SHORT_CONV_NAME
 
@@ -66,7 +67,9 @@ def apply(block, x, cache, t, readout_idx, n_valid):
 
     def out_proj(x, y):
         with jax.named_scope(OP_PROJ):
-            return x + block_dense(block, d, "conv_out")(y).astype(x.dtype)
+            return block_residual(block, x,
+                                  block_dense(block, d, "conv_out")(y),
+                                  "ln_attn_out")
 
     if readout_idx is not None:
         # the one row needs its own and the conv_taps - 1 rows before it;

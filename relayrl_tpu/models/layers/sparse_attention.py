@@ -45,6 +45,7 @@ from relayrl_tpu.models.layers.block import (
     block_dense,
     block_ffn,
     block_norm,
+    block_residual,
     norm,
 )
 from relayrl_tpu.models.layers.recurrent import kernel
@@ -150,7 +151,9 @@ def apply(block, x, cache, t, readout_idx, n_valid):
             q, qi, w, pos, *keys, topk=topk, loss=False))(q, qi, w, *keys)
     with jax.named_scope(OP_PROJ):
         attn = attn.reshape(B, -1, n_heads * head_dim)
-        x = x + block_dense(block, d, "attn_out")(attn).astype(x.dtype)
+        x = block_residual(block, x,
+                           block_dense(block, d, "attn_out")(attn),
+                           "ln_attn_out")
     out = block_ffn(block, x, layer_in)
     return out if cache is None else (out, cache)
 

@@ -38,6 +38,7 @@ runs for the readout row alone where its operator can); ``step_cached`` /
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Mapping
 
@@ -63,7 +64,7 @@ from relayrl_tpu.models.mlp import (
     _categorical_logp,
     _compute_dtype,
 )
-from relayrl_tpu.ops.scopes import EMBED, HEADS
+from relayrl_tpu.ops.scopes import EMBED, HEADS, LOOP_PASS
 
 
 class TransformerBlock(nn.Module):
@@ -92,6 +93,7 @@ class TransformerBlock(nn.Module):
     norm: str = BLOCK_KEYS["norm"]
     norm_eps: float | None = BLOCK_KEYS["norm_eps"]
     norm_zero_centred: bool = BLOCK_KEYS["norm_zero_centred"]
+    norm_sandwich: bool = BLOCK_KEYS["norm_sandwich"]
     use_bias: bool = BLOCK_KEYS["use_bias"]
     ffn: str = BLOCK_KEYS["ffn"]
     d_ff: int | None = BLOCK_KEYS["d_ff"]
@@ -135,12 +137,14 @@ def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
 
 def _readout_heads(x, mask, act_dim: int, d_model: int, has_critic: bool,
                    norm: str = "layer", norm_eps=None,
-                   norm_zero_centred: bool = False):
+                   norm_zero_centred: bool = False, normed: bool = False):
     """Final norm (the arch's kind and epsilon, as the blocks') + pi/vf
     heads in the caller's scope (shared with _PPReadout; the vf optimizer
-    partition keys off these exact `vf*` names)."""
+    partition keys off these exact `vf*` names). ``normed``: the rows come
+    from the final norm already (a looped trunk's last pass)."""
     with jax.named_scope(HEADS):
-        x = _norm(norm, norm_eps, "ln_final", norm_zero_centred)(x)
+        if not normed:
+            x = _norm(norm, norm_eps, "ln_final", norm_zero_centred)(x)
         logits = nn.Dense(act_dim, dtype=jnp.float32, name="pi_head")(x)
         if mask is not None:
             logits = jnp.where(mask > 0, logits, _MASK_FILL)
@@ -188,6 +192,10 @@ class TransformerCore(nn.Module):
     # "learned": a table added to the embedding; "rope": the blocks rotate;
     # "none": no positional signal at all.
     positions: str = "learned"
+    # How often the stack runs over its one parameter tree, and whether the
+    # full mode checkpoints each block application (arch_keys.CORE_KEYS).
+    loop_steps: int = 1
+    block_checkpoint: bool = False
 
     def layer_parts(self, i: int) -> tuple[str, bool]:
         """Layer ``i``'s (operator, whether an FFN follows it)."""
@@ -215,78 +223,137 @@ class TransformerCore(nn.Module):
     def __call__(self, obs, mask=None, cache=None, t=None, readout_t=None,
                  n_valid=None):
         """Full mode: obs ``[B, T, D]`` -> (logits, v). Decode mode
-        (``cache`` = tuple of per-layer states, each its operator's;
-        ``t`` = position; ``n_valid``: prefill's count of real rows):
-        obs is ``[B, 1, D]``; returns ``((logits, v), new_cache)`` for the
-        single position. Readout mode (``readout_t`` = dynamic row index):
-        obs is a full window ``[B, W, D]`` but only position ``readout_t``
-        is decoded — layers ``0..L-2`` run over every row (deeper layers
-        attend all earlier positions' hidden states, so those are live),
-        the final layer runs row-only (its other rows feed nothing), and
-        the heads see the one row; returns ``(logits[B, A], v[B])``. Init
-        always traces the full path, so all modes share one param tree."""
+        (``cache`` = tuple of states, one a pass and layer, pass-major, each
+        its operator's; ``t`` = position; ``n_valid``: prefill's count of
+        real rows): obs is ``[B, 1, D]``; returns ``((logits, v),
+        new_cache)`` for the single position. Readout mode (``readout_t`` =
+        dynamic row index): obs is a full window ``[B, W, D]`` but only
+        position ``readout_t`` is decoded — every layer but the last pass's
+        last runs over every row (deeper layers attend all earlier
+        positions' hidden states, so those are live), that one runs row-only
+        (its other rows feed nothing), and the heads see the one row;
+        returns ``(logits[B, A], v[B])``. Init always traces the full path,
+        so all modes share one param tree.
+
+        ``loop_steps`` > 1: the ``n_layers`` blocks run that many times over
+        the SAME parameters, ``ln_final`` after every pass (its output is
+        the next pass's input, and the heads read the last pass's without a
+        second norm), positions the same at every pass; the full mode runs
+        the passes as ONE body of a scan. ``block_checkpoint``: the full
+        mode keeps a block application's input (and the flash forward's
+        output, by name) for the backward and makes the rest again there
+        (``nn.remat``)."""
         decode = cache is not None
         kw = self.block_kw
+        S, L = self.loop_steps, self.n_layers
 
         for what, per_layer in (("layer_types", self.layer_types),
                                 ("rope_layers", self.rope_layers)):
-            if per_layer and len(per_layer) != self.n_layers:
+            if per_layer and len(per_layer) != L:
                 raise ValueError(f"{what} names {len(per_layer)} layers, "
-                                 f"n_layers is {self.n_layers}")
+                                 f"n_layers is {L}")
+        if S < 1:
+            raise ValueError(f"loop_steps is {S}: at least one pass")
+        if decode and len(cache) != S * L:
+            raise ValueError(f"the cache holds {len(cache)} states; "
+                             f"{S} passes of {L} layers keep {S * L}")
+        idx = None
+        if readout_t is not None:
+            idx = jnp.asarray(readout_t, jnp.int32)
+        # the learner's forward: every pass alike, and the one mode that is
+        # differentiated
+        full = not decode and idx is None
+        block_cls = TransformerBlock
+        if self.block_checkpoint and full:
+            # beside a block application's input, the flash forward's
+            # output and log-sum-exp: the backward then runs no forward
+            # kernel a second time (PERF.md section 6, PR 50)
+            from relayrl_tpu.ops import flash
+
+            block_cls = nn.remat(
+                TransformerBlock,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    flash.OUT_NAME, flash.LSE_NAME))
 
         def block_at(i: int) -> TransformerBlock:
             op, has_ffn = self.layer_parts(i)
             cfg = self.op_cfg[op]
             if self.rope_layers and not self.rope_layers[i]:
                 cfg = cfg.copy({"rope_theta": None})  # no positions at all
-            return TransformerBlock(
+            return block_cls(
                 self.d_model, self.mlp_ratio, self.compute_dtype, op=op,
                 cfg=cfg, fns=self.fns, has_ffn=has_ffn,
                 window=self.layer_window(i),
                 moe_experts=self.layer_experts(i), moe_top_k=self.moe_top_k,
                 name=f"block_{i}", **kw)
 
-        def heads(x, mask):
-            return _readout_heads(x, mask, self.act_dim, self.d_model,
-                                  self.has_critic, kw["norm"],
-                                  kw["norm_eps"], kw["norm_zero_centred"])
+        def final_norm():
+            return _norm(kw["norm"], kw["norm_eps"], "ln_final",
+                         kw["norm_zero_centred"])
+
+        def whole_pass(_core, x, _):
+            """One pass over every row, the scan's body: the L blocks, then
+            the final norm. ``_core`` is this module as the scan lifts it,
+            the parent of what is made here."""
+            with jax.named_scope(LOOP_PASS):
+                for i in range(L):
+                    x = block_at(i)(x)
+                with jax.named_scope(HEADS):
+                    return final_norm()(x), None
 
         x = _embed_obs(
             self, obs, self.d_model, self.max_seq_len,
             start=t if decode else 0,
             learned_positions=self.positions == "learned")
-        if readout_t is not None:
-            idx = jnp.asarray(readout_t, jnp.int32)
-            for i in range(self.n_layers - 1):
-                x = block_at(i)(x)
-            final = block_at(self.n_layers - 1)
-            if (final.moe_experts > 0 and final.op == "attention"
-                    or not layers.OPERATORS[final.op].ROW_READOUT):
-                # The MoE final attention block keeps its full-window pass
-                # (routing is per token, so the sliced row is what a
-                # row-only pass would give; the shortcut is simply not
-                # taken here), as does an operator whose row needs every
-                # row before it.
-                x = jax.lax.dynamic_slice_in_dim(final(x), idx, 1, axis=1)
-            else:
-                x = final(x, readout_idx=idx)
-            mask_row = None
-            if mask is not None:
-                mask_row = jax.lax.dynamic_slice_in_dim(mask, idx, 1,
-                                                        axis=1)
-            logits, v = heads(x, mask_row)
-            return logits[:, 0], v[:, 0]
         new_cache = []
-        for i in range(self.n_layers):
-            block = block_at(i)
-            if decode:
-                x, layer_cache = block(x, cache=cache[i], t=t,
-                                       n_valid=n_valid)
-                new_cache.append(layer_cache)
-            else:
-                x = block(x)
-        out = heads(x, mask)
-        return (out, tuple(new_cache)) if decode else out
+        if S > 1 and full:
+            # ONE body in the program, run S times over the broadcast
+            # parameters: 32 block applications written out are 0.8 GB of
+            # executable at the benchmark's looped configuration, which no
+            # compile cache keeps and the chip holds beside its state
+            x, _ = nn.scan(whole_pass, variable_broadcast="params",
+                           split_rngs={"params": False}, length=S)(
+                               self, x, None)
+        else:
+            # one tree: every pass calls the same L modules; a cached pass
+            # has its own states, the last pass of a readout its one row
+            blocks = [block_at(i) for i in range(L)]
+            end_of_pass = final_norm() if S > 1 else None
+            # A final attention block with experts keeps its full-window
+            # pass (routing is per token, so the sliced row is what a
+            # row-only pass would give; the shortcut is simply not taken),
+            # as does an operator whose row needs every row before it.
+            last = blocks[-1]
+            row_only = (layers.OPERATORS[last.op].ROW_READOUT and not (
+                last.moe_experts > 0 and last.op == "attention"))
+            for s in range(S):
+                with (jax.named_scope(LOOP_PASS) if S > 1
+                      else contextlib.nullcontext()):
+                    for i, block in enumerate(blocks):
+                        if decode:
+                            x, layer_cache = block(
+                                x, cache=cache[s * L + i], t=t,
+                                n_valid=n_valid)
+                            new_cache.append(layer_cache)
+                        elif idx is None or (s, i) != (S - 1, L - 1):
+                            x = block(x)
+                        elif row_only:
+                            x = block(x, readout_idx=idx)
+                        else:
+                            x = jax.lax.dynamic_slice_in_dim(block(x), idx,
+                                                             1, axis=1)
+                    if S > 1:
+                        with jax.named_scope(HEADS):
+                            x = end_of_pass(x)
+        if idx is not None and mask is not None:
+            mask = jax.lax.dynamic_slice_in_dim(mask, idx, 1, axis=1)
+        logits, v = _readout_heads(
+            x, mask, self.act_dim, self.d_model, self.has_critic,
+            kw["norm"], kw["norm_eps"], kw["norm_zero_centred"],
+            normed=S > 1)
+        if idx is not None:
+            return logits[:, 0], v[:, 0]
+        return ((logits, v), tuple(new_cache)) if decode else (logits, v)
 
 
 def _as_btd(obs, mask):
@@ -437,6 +504,8 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         sliding_window=arch.get("sliding_window"),
         rope_layers=tuple(bool(r) for r in arch.get("rope_layers", ())),
         positions=arch.get("positions", "learned"),
+        loop_steps=int(arch.get("loop_steps", 1)),
+        block_checkpoint=bool(arch.get("block_checkpoint", False)),
     )
 
 
@@ -449,7 +518,9 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
 
     def init_cache(length: int, batch_size: int = 1):
-        """Zeroed per-layer states for incremental decoding, each its
+        """Zeroed states for incremental decoding, one a pass and layer
+        (``loop_steps * n_layers``, pass-major: a looped trunk's pass ``s``,
+        layer ``l`` attends what pass ``s``, layer ``l`` wrote), each its
         operator's (``layers``): a (k, v) pair or a ring of rows, a
         convolution's last rows, a mixer's rows and float32 state — whose
         size does not grow with ``length`` —, nothing for an FFN alone."""
@@ -459,7 +530,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                 core.op_cfg[op], core.d_model, batch_size, int(length),
                 core.compute_dtype, core.layer_window(i))
 
-        return tuple(state(i) for i in range(core.n_layers))
+        return tuple(state(i) for _ in range(core.loop_steps)
+                     for i in range(core.n_layers))
 
     def step_cached(params, rng, cache, obs, t, mask=None):
         """One O(W) decode step: writes position ``t`` into the cache and
@@ -514,6 +586,11 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                 if hasattr(op, "OWN_LOSS")), None)
     own_loss = own and own.OWN_LOSS
     evaluate_stats = None
+    if core.loop_steps > 1 and (moe_experts > 0 or own_loss):
+        raise ValueError(
+            "loop_steps > 1 over a trunk with experts or a loss of its own: "
+            "what such a layer counts for the update (the expert load, the "
+            "loss rows) is sown a layer, not a pass and layer")
     if moe_experts > 0 or own_loss:
         def evaluate_stats(params, obs, act, mask=None):
             """``evaluate`` plus what the same forward counted: the expert

@@ -178,6 +178,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -188,6 +189,12 @@ from relayrl_tpu.ops.scopes import (  # noqa: F401  (re-exported)
     OP_PROJ,
     WINDOW_SUFFIX,
 )
+
+# what a caller's checkpoint may keep of the forward, by name: the output and
+# the rows' log-sum-exp, so that its backward runs no forward kernel again
+# (no ``relayrl_flash_`` prefix: that one finds the kernels)
+OUT_NAME = "flash_fwd_out"
+LSE_NAME = "flash_fwd_lse"
 
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
@@ -1028,6 +1035,8 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
 
         def fwd(qr, kr, vr):
             out, lse_row = run_fwd(qr, kr, vr)
+            out = checkpoint_name(out, OUT_NAME)
+            lse_row = checkpoint_name(lse_row, LSE_NAME)
             return out, (qr, kr, vr, out, lse_row)
 
         def bwd(res, dor):

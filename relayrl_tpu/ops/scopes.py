@@ -20,6 +20,13 @@ flash kernels' glue open theirs in both rules.
 **Kernels** (:data:`KERNEL_SCOPES`) keep their own innermost names inside
 whatever part calls them: a Mosaic call is named after the innermost scope,
 and the device trace's readers match those names.
+
+**A pass** (:data:`LOOP_PASS`) is the one scope opened OUTSIDE the parts: a
+looped trunk (``loop_steps`` > 1) opens ``relayrl_loop_pass`` round a pass of
+its stack (the learner's passes are ONE body of a scan, so the name carries
+no number), the parts stay siblings of each other inside it, and a reader
+that takes an operation's innermost ``relayrl_`` name still finds the part.
+No other program opens it, so no other program's ``op_name`` moves.
 """
 
 from __future__ import annotations
@@ -43,6 +50,9 @@ CONV = "relayrl_conv"                # cnn: the conv stack and its dense layer
 DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, INDEX, SPARSE_ATTN,
                  FFN, MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE, HEADS, OBS_PREP,
                  CONV)
+
+# -- a looped trunk's pass, outside the parts ---------------------------------
+LOOP_PASS = "relayrl_loop_pass"      # models/transformer.py's loop
 
 # -- kernels and the operators that keep a name of their own -----------------
 SHORT_CONV_NAME = "relayrl_short_conv"   # models/layers/short_conv.py

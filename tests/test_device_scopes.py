@@ -119,6 +119,13 @@ FAMILIES = {
                 "moe_d_ff": 12, "norm": "rms", "positions": "rope",
                 "qk_norm": "head", "use_bias": False, "ffn": "swiglu"},
                TRUNK + MOE + (INDEX, SPARSE_ATTN)),
+    # the stack run three times over one tree under the block checkpoint,
+    # sandwich norms (ouro-policy): a pass scope OUTSIDE the parts
+    "looped": ({**SEQ, "kind": "transformer_discrete", "n_layers": 2,
+                "loop_steps": 3, "block_checkpoint": True,
+                "norm_sandwich": True, "norm": "rms", "positions": "rope",
+                "use_bias": False, "ffn": "swiglu"},
+               TRUNK + (FFN,)),
     # the pixel learner (nature-cnn)
     "cnn": ({"kind": "cnn_discrete", "obs_shape": [36, 36, 2],
              "obs_dim": 36 * 36 * 2, "act_dim": 3},
@@ -187,7 +194,7 @@ def paths(lowered, compiled):
     def of(family):
         found = set(re.findall(r'loc\("(jit\([^"]*)"',
                                lowered(family).as_text(debug_info=True)))
-        if family == "sparse":
+        if family in ("sparse", "looped"):
             found |= set(re.findall(r'op_name="([^"]*)"', compiled(family)))
         return sorted(found)
 
@@ -245,6 +252,75 @@ def test_parts_are_siblings(paths, family):
         assert len(_part_names(path)) <= 1, path
         for name in _part_names(path):
             assert len(re.findall(rf"{name}(?!\w)", path)) == 1, path
+
+
+def test_a_pass_is_named_outside_the_parts(paths):
+    """The one scope that is no sibling: ``relayrl_loop_pass`` is the
+    outermost ``relayrl_`` name of every operation of a pass (the learner's
+    passes are one body of a scan, so the name has no number), the part
+    stays the innermost (what ``scope_table`` counts an operation for), and
+    a trunk that runs its stack once opens none."""
+    looped = [p for p in paths("looped") if scopes.LOOP_PASS in p]
+    for path in looped:
+        names = re.findall(r"relayrl_\w+", path)
+        assert names[0] == scopes.LOOP_PASS, path
+    blocks = [p for p in looped if re.search(r"/block_\d", p)]
+    assert {scope for p in blocks for scope in _part_names(p)} == {
+        OP_PROJ, FFN}
+    # the blocks, the final norm between passes, and the loop's own glue
+    # (the scan's carry, the checkpoint's call, the sum of a tied weight's
+    # gradients): nothing else is under a pass without a part
+    rest = [p for p in looped if not re.search(r"/block_\d", p)]
+    assert any(_part_names(p) == {HEADS} for p in rest)
+    assert not [p for p in rest if _part_names(p) - {HEADS}]
+    # every block operation of the update is inside the pass, forward and
+    # transposed: one body, run loop_steps times
+    assert not [p for p in paths("looped") if re.search(r"/block_\d", p)
+                and scopes.LOOP_PASS not in p]
+    assert [p for p in looped if "transpose(" in p]
+    for family in FAMILIES:
+        if family != "looped":
+            assert not [p for p in paths(family) if scopes.LOOP_PASS in p]
+
+
+def test_the_learners_passes_are_one_body(compiled):
+    """32 block applications written out are 0.8 GB of executable at the
+    benchmark's looped configuration: the update holds each block's
+    matmuls ONCE forward (and once transposed), whatever ``loop_steps``."""
+    text = compiled("looped")
+    up = re.findall(r'op_name="[^"]*block_0/relayrl_ffn/mlp_up/dot_general"',
+                    text)
+    forward = [p for p in up if "transpose(" not in p]
+    assert len({p for p in forward if "rematted" not in p}) == 1
+    assert scopes.LOOP_PASS in forward[0] and "while" in forward[0]
+
+
+def test_the_checkpoints_second_forward_is_told_apart(paths):
+    """What ``loop_recompute_pct`` reads: ``jax.checkpoint`` names the
+    forward it runs again in the backward ``rematted_computation``; the
+    first forward's paths hold no ``checkpoint`` and the backward proper's
+    hold it without that name. All three carry the pass and the part."""
+    from benchmark import loop_trace
+
+    assert loop_trace.RECOMPUTED == "rematted_computation"
+    assert loop_trace.PASS == scopes.LOOP_PASS
+    in_blocks = [p for p in paths("looped") if re.search(r"/block_\d", p)]
+    again = [p for p in in_blocks if loop_trace.RECOMPUTED in p]
+    first = [p for p in in_blocks if "checkpoint" not in p]
+    back = [p for p in in_blocks
+            if "checkpoint/" in p and loop_trace.RECOMPUTED not in p]
+    assert again and first and back
+    # (a loop body's operations are named from the body's own start in the
+    # compiled text: the whole path is the lowered text's)
+    whole = [p for p in again + back if p.startswith("jit(")]
+    assert whole and all("transpose(" in p for p in whole)
+    assert not [p for p in first if "transpose(" in p]
+    for kind in (again, first, back):
+        assert {scope for p in kind for scope in _part_names(p)} >= {
+            OP_PROJ, FFN}
+        assert all(loop_trace.PASS in p for p in kind)
+    # no other family's trunk is checkpointed a block
+    assert not [p for p in paths("gpt2") if loop_trace.RECOMPUTED in p]
 
 
 def test_custom_vjp_backwards_carry_their_parts(paths, compiled):

@@ -67,6 +67,14 @@ CACHED_ARCHS = {
                            "layer_types": ["full_attention",
                                            "sliding_attention"],
                            "sliding_window": 3},
+    # the stack run 3 times over one tree: a (k, v) pair a pass and layer,
+    # sandwich norms, the final norm between passes
+    "looped": {**_MODERN, "qk_norm": False, "loop_steps": 3,
+               "norm_sandwich": True},
+    # ... and with two kinds of state a pass: a conv layer's rows and an
+    # attention layer's pair, three times each
+    "looped_conv": {**_MODERN, "qk_norm": False, "loop_steps": 3,
+                    "layer_types": ["conv", "full_attention"]},
 }
 
 
@@ -100,7 +108,8 @@ class TestStepCachedNumerics:
     @pytest.mark.parametrize("name", ["rope", "olmoe_block", "lfm2_trunk",
                                       "conv_last_dense",
                                       "smallthinker_trunk",
-                                      "sliding_last_dense"])
+                                      "sliding_last_dense", "looped",
+                                      "looped_conv"])
     def test_prefilled_cache_continues_as_the_stepped_one(self, name):
         # prefill rotates W keys at positions 0..W-1 in one dispatch; the
         # steps after it must read them as if they had been written one by

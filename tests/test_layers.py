@@ -57,10 +57,10 @@ def test_the_passthrough_keys_are_the_declarations():
                    for k in keys])
     assert list(arch_keys.DECLARED) == declared
     assert base.ARCH_PASSTHROUGH_KEYS == arch_keys.TRUNK_KEYS + tuple(declared)
-    # no key declared twice, and the 55 the configurations' references ask
+    # no key declared twice, and the 58 the configurations' references ask
     # base for (benchmark/reference/*.py) are all there
     assert len(set(base.ARCH_PASSTHROUGH_KEYS)) == len(
-        base.ARCH_PASSTHROUGH_KEYS) == 55
+        base.ARCH_PASSTHROUGH_KEYS) == 58
 
 
 @pytest.mark.parametrize("key", arch_keys.DECLARED)
@@ -191,3 +191,90 @@ def test_an_operators_state_is_what_a_prefill_returns(op):
         policy.step_cached, params, jax.random.PRNGKey(1), cache,
         jnp.zeros((2, 6)), jnp.int32(3), jnp.ones((2, 3), bool))
     assert _structure(stepped) == _structure(cache)
+
+
+# -- the looped trunk's keys: loop_steps, norm_sandwich, block_checkpoint -----
+
+LOOP_DEFAULTS = {"loop_steps": 1, "norm_sandwich": False,
+                 "block_checkpoint": False}
+PRESETS = {
+    "dense": {**BASE, "n_layers": 2, "norm": "layer", "has_critic": True},
+    "expert": {**BASE, "kind": "transformer_moe_discrete", "n_layers": 2,
+               "moe_experts": 4, "moe_top_k": 2, "moe_dense_layers": 1,
+               "positions": "rope", "use_bias": False, "ffn": "swiglu",
+               "has_critic": True},
+}
+
+
+def _lowered_grad(arch):
+    policy = build_policy(arch)
+    params = jax.eval_shape(policy.init_params, jax.random.PRNGKey(0))
+    obs = jax.ShapeDtypeStruct((2, 8, 6), jnp.float32)
+    act = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+
+    def loss(p, o, a):
+        logp, ent, v = policy.evaluate(p, o, a)
+        return jnp.sum(logp) + jnp.sum(ent) + jnp.sum(v)
+
+    return jax.jit(jax.value_and_grad(loss)).lower(params, obs, act).as_text()
+
+
+def test_the_loop_keys_defaults_are_the_declared_ones():
+    assert {k: v for k, v in arch_keys.BLOCK_KEYS.items()
+            if k in LOOP_DEFAULTS} == {"norm_sandwich": False}
+    assert set(LOOP_DEFAULTS) - {"norm_sandwich"} <= set(arch_keys.CORE_KEYS)
+    core = transformer._make_core({**PRESETS["dense"]})
+    assert (core.loop_steps, core.block_checkpoint) == (1, False)
+    assert core.block_kw["norm_sandwich"] is False
+
+
+@pytest.mark.parametrize("preset", sorted(PRESETS))
+def test_the_loop_keys_at_their_defaults_leave_the_program_as_it_was(preset):
+    """An arch that spells the three keys out at their defaults lowers,
+    forward and backward, to the text of an arch without them."""
+    arch = PRESETS[preset]
+    assert _lowered_grad({**arch, **LOOP_DEFAULTS}) == _lowered_grad(arch)
+
+
+@pytest.mark.parametrize("key,value", [
+    ("loop_steps", 2), ("norm_sandwich", True), ("block_checkpoint", True)])
+def test_a_loop_key_off_its_default_is_another_program(key, value):
+    arch = PRESETS["dense"]
+    assert _lowered_grad({**arch, key: value}) != _lowered_grad(arch)
+
+
+@pytest.mark.parametrize("op", sorted(TRUNKS))
+def test_sandwich_norms_are_every_operators(op):
+    """``norm_sandwich`` is the block's, not the attention's: each half of
+    a layer gains a norm on its output and nothing else, whatever the
+    operator; a loop adds no parameter at all (an operator that brings a
+    loss of its own is not looped: below)."""
+    keys, leaves = TRUNKS[op]
+    loop = {} if op == "sparse_attention" else {"loop_steps": 3}
+    plain = jax.eval_shape(
+        build_policy({**BASE, **keys}).init_params, jax.random.PRNGKey(0))
+    looped = jax.eval_shape(
+        build_policy({**BASE, **keys, "norm_sandwich": True,
+                      **loop}).init_params, jax.random.PRNGKey(0))
+    gained = set(_structure(looped["params"]["block_0"])) - set(
+        _structure(plain["params"]["block_0"]))
+    assert gained == ({"ln_attn_out/scale"} if op != "none" else set()) | (
+        {"ln_mlp_out/scale"} if "ln_mlp/scale" in leaves else set())
+    assert set(looped["params"]) == set(plain["params"])
+
+
+@pytest.mark.parametrize("what", ["experts", "own_loss"])
+def test_a_loop_over_layers_that_count_for_the_update_is_refused(what):
+    """The expert load and a sparse-attention layer's loss rows are sown a
+    layer; a pass and layer is not built."""
+    arch = ({**PRESETS["expert"]} if what == "experts"
+            else {**BASE, **TRUNKS["sparse_attention"][0]})
+    build_policy(arch)
+    with pytest.raises(ValueError, match="loop_steps > 1 over a trunk"):
+        build_policy({**arch, "loop_steps": 2})
+
+
+def test_a_loop_of_no_pass_is_refused():
+    with pytest.raises(ValueError, match="at least one pass"):
+        build_policy({**BASE, "loop_steps": 0}).init_params(
+            jax.random.PRNGKey(0))
