@@ -46,7 +46,8 @@ switch):
   kernels of :mod:`relayrl_tpu.ops.sparse_attn_pallas`, one call a tile, the
   selection an int8 mask operand, a tile's scores and probabilities in VMEM
   and never in HBM, the key blocks past the tile's last query skipped from
-  its position, a hand-written backward (``jax.custom_vjp``).
+  its position, a hand-written backward (``jax.custom_vjp``: one call a
+  tile, ``sparse_attn_bwd``, all three gradients from one score tile).
   ``keye-vl2-policy.update`` runs them (PERF.md section 6, PR 48: 1,503 ms an
   update of masked-dense plain XLA at 2.1% of its roofline before them).
 * ``masked_xla`` (:func:`masked_attention`) — everywhere else (CPU actor

@@ -47,17 +47,23 @@ selection kept nothing is computed like any other.
   innermost, and the heads are summed into the output block while it stays in
   VMEM. Only where the caller wants the indexers' loss; detached (its inputs
   are cut before the call), it has no backward.
-* ``sparse_attn_dq``: ``p`` made again from the log-sum-exp, ``ds = p (do
-  v^T - delta)``, ``dq += ds k`` in VMEM scratch over the key blocks.
-* ``sparse_attn_dkv``: the same in turned space, so that ``dv = p^T do`` and
-  ``dk = ds^T q`` are plain matmuls, summed over the group's heads in the
-  step; a tile's ``dk`` / ``dv`` are complete when its call ends, and the
-  tiles' add up through the caller's loop.
+* ``sparse_attn_bwd``: the whole backward of a tile, in turned space like
+  the forward: a head's ``p^T`` made again from the log-sum-exp and ``ds^T =
+  p^T (v do^T - delta)`` ONCE a head and key block, and all three gradients
+  from them as plain matmuls — ``dv = p^T do`` and ``dk = ds^T q``, summed
+  over the group's heads in the step (a tile's ``dk`` / ``dv`` are complete
+  when its call ends, and the tiles' add up through the caller's loop), and
+  ``dq^T += k^T ds^T`` into a float32 ``[D, Tq]`` a head in VMEM scratch
+  over the key blocks, the key block turned once a step for the group's
+  heads; writes ``dq`` as ``[H, D, Tq]`` at the last step (plain XLA turns
+  it back, as it turns ``out``). Five matmuls a head and block where a dq
+  and a dk / dv kernel made seven and the scores, the select and the
+  exponentials twice (PERF.md section 6, PR 51).
 
 The per-query float32 scalars (log-sum-exp, delta) travel as ``[Hkv, H /
 Hkv, Tq]`` rows for the kernels whose queries lie along the lanes (forward,
-dk / dv) and as ``[Hkv, Tq, H / Hkv]`` columns for those whose queries lie
-down the sublanes (``p^``, dq); plain XLA turns the 64 KB between them.
+backward) and as ``[Hkv, Tq, H / Hkv]`` columns for ``p^``, whose queries
+lie down the sublanes; plain XLA turns its 64 KB.
 
 **Every query keeps at least one key** (``top_k_mask`` keeps ``min(t + 1,
 topk) >= 1``): a row whose first blocks hold no kept key carries a running
@@ -72,8 +78,8 @@ policy, and ``delta = rowsum(do * out)`` — for which the rule does NOT keep
 :func:`settle` fills with ``delta`` from the ``out`` the caller assembled.
 ``ops/sparse_attn._sequence`` settles once a sequence, outside the tiles'
 loop, so a tile's ``jax.checkpoint`` keeps 64 KB and the backward runs
-``sparse_attn_phat`` + ``sparse_attn_dq`` + ``sparse_attn_dkv`` and never
-the forward kernel a second time; :func:`masked_attention_pallas` alone
+``sparse_attn_phat`` + ``sparse_attn_bwd`` and never the forward kernel a
+second time; :func:`masked_attention_pallas` alone
 settles its own call. ``keep`` and ``live`` are integers and get no
 cotangent; the log-sum-exp's cotangent is dropped (nothing differentiates
 it: ``p^`` is detached).
@@ -101,7 +107,7 @@ from relayrl_tpu.ops.scopes import SPARSE_ATTN
 from relayrl_tpu.ops.sparse_attn import LSE_NAME
 
 FWD_NAME, PHAT_NAME = "sparse_attn_fwd", "sparse_attn_phat"
-DQ_NAME, DKV_NAME = "sparse_attn_dq", "sparse_attn_dkv"
+BWD_NAME = "sparse_attn_bwd"
 
 _LOG2E = math.log2(math.e)
 _VMEM_LIMIT = 64 * 1024 * 1024
@@ -125,6 +131,13 @@ def _kept(keep_ref, turned: bool = False):
     return (mask.T if turned else mask) > 0.0
 
 
+def _turn(x):
+    """A step's ``[block, D]`` block of keys or values as ``[D, block]``,
+    once for all the heads of its group (in float32: a 32-bit tile is what
+    the transpose unit turns)."""
+    return x.astype(_F32).T.astype(x.dtype)
+
+
 def _of_head(x, h, axis: int):
     """Head ``h``'s column of ``x [Tq, heads]`` (``axis`` 1) or row of ``x
     [heads, Tq]`` (``axis`` 0), for an ``h`` that a loop counts."""
@@ -136,10 +149,10 @@ def _each_head(rep: int, body, carry=None):
     """``body(h, carry)`` for the ``rep`` heads of a step's group: a loop
     on the device, not ``rep`` copies of the body in the kernel's code. A
     head's score tile is 256 vector registers an operation: unrolled, a
-    kernel is 0.4 to 1.0 MB of code for 0.17 to 0.29 and an update holds 80
+    kernel is 0.4 to 1.3 MB of code for 0.17 to 0.33 and an update holds 64
     of them, in an executable whose size the chip machine's compile cache
-    bounds (ROADMAP 1.13 (e)); the loop costs the kernels 12 to 15% of their
-    time (PERF.md section 6, PR 48)."""
+    bounds (ROADMAP 1.13 (e)); the loop costs the kernels 12 to 16% of their
+    time (PERF.md section 6, PR 48 and PR 51)."""
     return jax.lax.fori_loop(0, rep, body, carry)
 
 
@@ -165,7 +178,7 @@ def _fwd_kernel(live_ref, q_ref, k_ref, v_ref, keep_ref, out_ref, lse_ref,
     @pl.when(j < live_ref[0])
     def _block():
         kept, k = _kept(keep_ref, turned=True), k_ref[...]
-        v_t = v_ref[...].astype(_F32).T.astype(v_ref.dtype)     # [D, block]
+        v_t = _turn(v_ref[...])
 
         def head(h, _):
             s = jnp.where(kept, _mm(k, q_ref[h], _NT), _NEG_INF)
@@ -218,9 +231,13 @@ def _phat_kernel(live_ref, q_ref, k_ref, keep_ref, lse_ref, p_ref, *,
         p_ref[...] *= 1.0 / n_heads
 
 
-def _dq_kernel(live_ref, q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
-               delta_ref, dq_ref, acc_ref, *, rep: int):
-    """``sparse_attn_dq``: one key block's share of a group's ``dq``."""
+def _bwd_kernel(live_ref, q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
+                delta_ref, dq_ref, dk_ref, dv_ref, acc_ref, *, rep: int):
+    """``sparse_attn_bwd``: one key block's ``dk`` and ``dv`` of this tile,
+    summed over the group's heads, and its share of the group's ``dq``;
+    scores turned, ``[keys, queries]``, made once for the three of them, and
+    ``dq`` accumulated turned, ``[D, queries]`` a head, as the forward
+    accumulates ``out``."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -229,43 +246,17 @@ def _dq_kernel(live_ref, q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
 
     @pl.when(j < live_ref[0])
     def _block():
-        kept, k, v = _kept(keep_ref), k_ref[...], v_ref[...]
-        lse, delta = lse_ref[0], delta_ref[0]
-
-        def head(h, _):
-            s = jnp.where(kept, _mm(q_ref[h], k, _NT), _NEG_INF)
-            p = jnp.exp2(s - _of_head(lse, h, 1))
-            ds = p * (_mm(do_ref[h], v, _NT) - _of_head(delta, h, 1))
-            acc_ref[h] += _mm(ds.astype(k.dtype), k)
-
-        _each_head(rep, head)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _end():
-        # q came in scaled by log2(e) / sqrt(D); d(scores) / d(q) is the
-        # second factor alone
-        dq_ref[...] = (acc_ref[...] * acc_ref.shape[-1] ** -0.5).astype(
-            dq_ref.dtype)
-
-
-def _dkv_kernel(live_ref, q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
-                delta_ref, dk_ref, dv_ref, *, rep: int):
-    """``sparse_attn_dkv``: one key block's ``dk`` and ``dv`` of this tile,
-    summed over the group's heads; scores turned, ``[keys, queries]``."""
-    j = pl.program_id(1)
-
-    @pl.when(j < live_ref[0])
-    def _block():
         kept, k, v = _kept(keep_ref, turned=True), k_ref[...], v_ref[...]
-        lse, delta = lse_ref[0], delta_ref[0]
+        k_t, lse, delta = _turn(k), lse_ref[0], delta_ref[0]
 
         def head(h, sums):
             q, do = q_ref[h], do_ref[h]
             s = jnp.where(kept, _mm(k, q, _NT), _NEG_INF)
             p = jnp.exp2(s - _of_head(lse, h, 0))
-            ds = p * (_mm(v, do, _NT) - _of_head(delta, h, 0))
-            return (sums[0] + _mm(ds.astype(q.dtype), q),
-                    sums[1] + _mm(p.astype(do.dtype), do))
+            ds = (p * (_mm(v, do, _NT) - _of_head(delta, h, 0))).astype(
+                q.dtype)
+            acc_ref[h] += _mm(k_t, ds)
+            return sums[0] + _mm(ds, q), sums[1] + _mm(p.astype(do.dtype), do)
 
         dk, dv = _each_head(rep, head, (jnp.zeros(dk_ref.shape, _F32),
                                         jnp.zeros(dv_ref.shape, _F32)))
@@ -277,6 +268,13 @@ def _dkv_kernel(live_ref, q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
     def _above():
         dk_ref[...] = jnp.zeros(dk_ref.shape, dk_ref.dtype)
         dv_ref[...] = jnp.zeros(dv_ref.shape, dv_ref.dtype)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _end():
+        # q came in scaled by log2(e) / sqrt(D); d(scores) / d(q) is the
+        # second factor alone
+        dq_ref[...] = (acc_ref[...] * acc_ref.shape[1] ** -0.5).astype(
+            dq_ref.dtype)
 
 
 def key_block(tk: int) -> int | None:
@@ -314,18 +312,19 @@ def _build(kind: str, shape: tuple, dtype_name: str, interpret: bool):
         return pl.BlockSpec(block_shape, index_map)
 
     heads = spec((rep, tq, width), lambda g, j, at: (g, 0, 0))
+    heads_t = spec((rep, width, tq), lambda g, j, at: (g, 0, 0))
     keys = spec((block, width), lambda g, j, at: (at, g))
     mask = spec((tq, block), lambda g, j, at: (0, at))
     cols = spec((1, tq, rep), lambda g, j, at: (g, 0, 0))
     rows = spec((1, rep, tq), lambda g, j, at: (g, 0, 0))
     S = jax.ShapeDtypeStruct
-    heads_s, keys_s = S((n_heads, tq, width), cd), S((tk, n_kv * width), cd)
+    heads_t_s, keys_s = S((n_heads, width, tq), cd), S((tk, n_kv * width), cd)
     scratch = []
     if kind == FWD_NAME:
         kernel = functools.partial(_fwd_kernel, rep=rep)
         in_specs = [heads, keys, keys, mask]
-        out_specs = [spec((rep, width, tq), lambda g, j, at: (g, 0, 0)), rows]
-        out_shape = [S((n_heads, width, tq), cd), S((n_kv, rep, tq), _F32)]
+        out_specs = [heads_t, rows]
+        out_shape = [heads_t_s, S((n_kv, rep, tq), _F32)]
         scratch = [pltpu.VMEM((rep, 8, tq), _F32),
                    pltpu.VMEM((rep, 8, tq), _F32),
                    pltpu.VMEM((rep, width, tq), _F32)]
@@ -334,16 +333,13 @@ def _build(kind: str, shape: tuple, dtype_name: str, interpret: bool):
         in_specs = [heads, keys, mask, cols]
         out_specs = [spec((tq, block), lambda g, j, at: (0, j))]
         out_shape = [S((tq, tk), _F32)]
-    elif kind == DQ_NAME:
-        kernel = functools.partial(_dq_kernel, rep=rep)
-        in_specs = [heads, keys, keys, mask, heads, cols, cols]
-        out_specs, out_shape = [heads], [heads_s]
-        scratch = [pltpu.VMEM((rep, tq, width), _F32)]
     else:
-        kernel = functools.partial(_dkv_kernel, rep=rep)
+        kernel = functools.partial(_bwd_kernel, rep=rep)
         in_specs = [heads, keys, keys, mask, heads, rows, rows]
         written = spec((block, width), lambda g, j, at: (j, g))
-        out_specs, out_shape = [written, written], [keys_s, keys_s]
+        out_specs = [heads_t, written, written]
+        out_shape = [heads_t_s, keys_s, keys_s]
+        scratch = [pltpu.VMEM((rep, width, tq), _F32)]
     grid = (tk // block, n_kv) if turned else (n_kv, tk // block)
     call = pl.pallas_call(
         kernel, name=kind,
@@ -402,11 +398,9 @@ def _make_rule(shape: tuple, dtype_name: str, interpret: bool):
         do, _, delta = cotangents
         with jax.named_scope(SPARSE_ATTN):
             delta = delta.T.reshape(n_kv, rep, tq)
-            qs, do = scaled(q), do.swapaxes(0, 1)
-            dq, = build(DQ_NAME)(live, qs, k, v, keep, do,
-                                 lse.swapaxes(1, 2), delta.swapaxes(1, 2))
-            dk, dv = build(DKV_NAME)(live, qs, k, v, keep, do, lse, delta)
-            return dq.swapaxes(0, 1), dk, dv, None, None
+            dq, dk, dv = build(BWD_NAME)(live, scaled(q), k, v, keep,
+                                         do.swapaxes(0, 1), lse, delta)
+            return dq.transpose(2, 0, 1), dk, dv, None, None
 
     rule.defvjp(fwd, bwd)
 

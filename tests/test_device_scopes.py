@@ -502,8 +502,7 @@ def test_the_sparse_attention_kernels_count_for_the_scopes_name(monkeypatch):
                            named.as_text(debug_info=True)))
     for kernel, backward in ((sparse_attn_pallas.FWD_NAME, False),
                              (sparse_attn_pallas.PHAT_NAME, False),
-                             (sparse_attn_pallas.DQ_NAME, True),
-                             (sparse_attn_pallas.DKV_NAME, True)):
+                             (sparse_attn_pallas.BWD_NAME, True)):
         assert not kernel.startswith("relayrl_")
         mine = [p for p in paths if re.search(rf"/{kernel}(/|$)", p)]
         assert mine, kernel

@@ -428,11 +428,12 @@ def test_the_sparse_attention_kernels_compile_for_v5e(one_chip):
     """The attention over the selected keys (``ops/sparse_attn_pallas.py``)
     at ``keye-vl2-policy.update``'s last tile — 512 queries of 32 heads of
     128 over 16,384 keys of 4, bfloat16 —, forward, ``p^`` and every
-    gradient: four Mosaic calls, ``sparse_attn_fwd`` and
-    ``sparse_attn_phat`` forward, ``sparse_attn_dq`` and ``sparse_attn_dkv``
-    in the backward, each under ``relayrl_sparse_attn`` and under no other
-    ``relayrl_`` name (the benchmark's ``sparse_attn_ms`` reads the exact
-    scope; a name holding ``relayrl_flash_`` would be read as flash), and
+    gradient: three Mosaic calls, ``sparse_attn_fwd`` and
+    ``sparse_attn_phat`` forward, ``sparse_attn_bwd`` (``dq``, ``dk`` and
+    ``dv`` from one score tile) in the backward, each under
+    ``relayrl_sparse_attn`` and under no other ``relayrl_`` name (the
+    benchmark's ``sparse_attn_ms`` reads the exact scope; a name holding
+    ``relayrl_flash_`` would be read as flash), and
     nothing of a score tile's size in HBM but ``p^`` itself: one float32
     ``[512, 16384]`` (33.6 MB) beside the operands."""
     from relayrl_tpu.ops import sparse_attn_pallas as kernels
@@ -462,12 +463,11 @@ def test_the_sparse_attention_kernels_compile_for_v5e(one_chip):
                        r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
     assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) == sorted(
         "%" + name for name in (kernels.FWD_NAME, kernels.PHAT_NAME,
-                                kernels.DQ_NAME, kernels.DKV_NAME))
+                                kernels.BWD_NAME))
     for name, path in calls:
         assert set(re.findall(r"relayrl_\w+", path)) == {
             scopes.SPARSE_ATTN}, path
-        assert ("transpose(" in path) == (
-            kernels.DQ_NAME in name or kernels.DKV_NAME in name), path
+        assert ("transpose(" in path) == (kernels.BWD_NAME in name), path
     assert "relayrl_flash" not in text
     assert not re.findall(r"\bwhile\(", text)
     # the int8 mask, the scaled and turned q / do / out, delta: well under a

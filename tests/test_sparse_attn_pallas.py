@@ -53,7 +53,7 @@ def _kernels(q, k, v, keep, pos, want_p_hat=True):
 
 
 POSITIONS = [0, 128, 256]       # the first tile, one in the middle, the last
-HEADS = [(32, 4), (4, 2)]
+HEADS = [(32, 4), (4, 2), (2, 2)]    # groups of 8, of 2 and of ONE head
 
 
 class TestForward:
@@ -137,6 +137,12 @@ class TestBackward:
         # the keys past the tile's last query get exact zeros
         assert float(jnp.abs(got[1][first + N_Q:]).max(initial=0.0)) == 0.0
         assert float(jnp.abs(got[2][first + N_Q:]).max(initial=0.0)) == 0.0
+        if first == 0:
+            # ``live`` 1 of 3 key blocks: ``dq`` leaves its accumulator at
+            # the LAST grid step, two steps after the last one that computed
+            assert bool(jnp.all(jnp.any(got[0] != 0.0, axis=(1, 2))))
+            assert bool(jnp.any(got[1][:N_Q] != 0.0))
+            assert bool(jnp.any(got[2][:N_Q] != 0.0))
 
     @pytest.mark.parametrize("heads,kv", HEADS)
     def test_through_an_empty_block(self, heads, kv):
