@@ -23,7 +23,7 @@ import threading
 import time
 from typing import Any, Callable, Mapping, Sequence
 
-from relayrl_tpu.telemetry.spans import span
+from relayrl_tpu.telemetry.spans import span, watch_gc
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import ModelBundle
 
@@ -52,6 +52,9 @@ def build_algorithm(name: str, **kwargs) -> "AlgorithmBase":
             f"its own ({own_loss}: models/base.Policy.own_loss) that this "
             f"algorithm's update would drop — its indexers would never "
             f"train; IMPALA's update adds it")
+    # A process that updates names its full collections (rl:gc): a stall
+    # of the dispatching thread no other span can account for.
+    watch_gc()
     return algo
 
 
@@ -119,16 +122,22 @@ class AlgorithmBase(abc.ABC):
     # The dispatching thread and its CPU clock at its previous dispatch
     # (``_dispatch_span``).
     _cpu_mark = None
+    # ``start_ns -> {argument: value}``: what the algorithm's owner adds to
+    # a ``host:dispatch`` span (the training server: the batch's data age).
+    _dispatch_note = None
 
     @contextlib.contextmanager
     def _dispatch_span(self, updates: int = 1):
         """The once-per-dispatch ``host:dispatch`` span. Its arguments are
         what only the program knows at this instant: the ``version`` the
         dispatch produces, ``mono_ns`` (its own start stamp: the shift
-        from CLOCK_MONOTONIC to the profiler's clock) and ``cycle_cpu_ns``,
+        from CLOCK_MONOTONIC to the profiler's clock), ``cycle_cpu_ns``,
         this thread's CPU time since its previous dispatch — against the
         wall time between the two it says how long the thread was off the
-        CPU (blocked, or runnable and not running)."""
+        CPU (blocked, or runnable and not running) — and, under a training
+        server fed by actors that say when an unroll was born,
+        ``data_age_us`` / ``data_age_max_us`` of the batch
+        (``_dispatch_note``)."""
         cpu_ns = time.thread_time_ns()
         ident = threading.get_ident()
         mark = self._cpu_mark
@@ -137,7 +146,8 @@ class AlgorithmBase(abc.ABC):
         with span("host:dispatch",
                   version=self._dispatched_updates + updates,
                   cycle_cpu_ns=cycle) as sp:
-            sp.note(mono_ns=sp.t0_ns)
+            note = self._dispatch_note
+            sp.note(mono_ns=sp.t0_ns, **(note(sp.t0_ns) if note else {}))
             yield
 
     def _drop_nonfinite(self) -> None:

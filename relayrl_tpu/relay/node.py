@@ -582,13 +582,9 @@ class RelayNode:
         if self._fleet_buf is not None and is_snapshot_frame(payload):
             self._ingest_subtree_snapshot(payload)
             return
-        from relayrl_tpu.transport.base import (
-            split_agent_seq,
-            split_agent_trace,
-        )
+        from relayrl_tpu.transport.base import split_agent_tags
 
-        clean_id, _seq = split_agent_seq(tagged_id)
-        clean_id, _trace = split_agent_trace(clean_id)
+        clean_id = split_agent_tags(tagged_id)[0]
         with self._subtree_lock:
             if len(self._subtree_agents) < 65536:
                 self._subtree_agents.add(clean_id)

@@ -24,6 +24,7 @@ import numpy as np
 
 from relayrl_tpu.config import ConfigLoader
 from relayrl_tpu.runtime.policy_actor import PolicyActor
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.transport import make_agent_transport
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import ModelBundle
@@ -41,8 +42,12 @@ def _deliver_model(actor_host, transport, client_model_path: str, tag: str,
     actor-host kinds."""
     from relayrl_tpu.transport.modelwire import WireBaseMismatch
 
+    # The actor's half of model lag — the subscriber thread's hand-over of
+    # the frame to installed — is the span round the call (the host's own
+    # spans sit inside it: rl:actor.model_decode, rl:actor.swap).
     try:
-        installed = actor_host.swap_from_wire(version, blob)
+        with span("rl:actor.model_install", version=int(version)) as sp:
+            installed = actor_host.swap_from_wire(version, blob)
     except WireBaseMismatch as e:
         from relayrl_tpu import telemetry
 
@@ -57,49 +62,70 @@ def _deliver_model(actor_host, transport, client_model_path: str, tag: str,
         print(f"[{tag}] rejected model update: {e!r}", flush=True)
         return
     if installed is not None:
+        ledger = getattr(actor_host, "ledger", None)
+        if ledger is not None:
+            ledger.timings["model_install_s"] += sp.seconds
+            ledger.counts["installs"] += 1
         try:
             installed.save(client_model_path)
         except OSError:
             pass
 
 
-def _trace_emit(agent_id: str, born_ns: int, enc0_ns: int, enc1_ns: int,
-                version: int):
-    """Distributed-tracing emission hook shared by Agent and VectorAgent
-    (telemetry/trace.py): sample a trajectory trace context and record
-    the actor-side ``env`` (production) and ``encode`` (serialize) hop
-    spans. Returns the context (riding the wire as the ``#t`` id tag)
-    or None — one tracer read per *trajectory*, never per step."""
+def ship_unroll(owner, agent_id: str, payload: bytes, origin, version: int,
+                ledger=None) -> None:
+    """The one send of a serialized unroll (Agent, VectorAgent, the thin
+    client): through the owner's spool, or straight to its transport where
+    ``actor.spool_entries`` is 0.
+
+    ``origin`` is ``(born_ns, rl:actor.encode span)`` of the unroll, or
+    None for a payload a dataflow stage re-injects long after its
+    production. The envelope id always carries the shipper's report
+    (``#r``: the born stamp and version, and ``ledger``'s deltas where it
+    keeps one — telemetry/actor_ledger.py); a sampled trajectory also draws
+    a trace context (``#t``) and records its actor-side hops from the
+    stamps the spans already took: ``env`` (born → encode start),
+    ``encode`` and ``send`` — one tracer read per *trajectory*, never per
+    step."""
+    from relayrl_tpu.telemetry.actor_ledger import encode_report
     from relayrl_tpu.telemetry import trace as trace_mod
 
+    born_ns, encode = origin if origin is not None else (0, None)
     tracer = trace_mod.get_tracer()
-    if not tracer.enabled or not born_ns:
-        return None
-    ctx = tracer.sample_traj(born_ns, version)
-    if ctx is None:
-        return None
-    import time
+    ctx = tracer.sample_traj(born_ns, version) if born_ns else None
+    if ctx is not None:
+        tracer.span("traj", ctx.trace_id, "env", born_ns, encode.t0_ns,
+                    agent=agent_id, version=int(version))
+        encode.hop("traj", ctx.trace_id, "encode", agent=agent_id)
+    # One born stamp on the wire: a trace context carries it already.
+    report = (encode_report if ledger is None else ledger.report)(
+        0 if ctx is not None else born_ns, version)
+    trace = None if ctx is None else ctx.encode()
+    with span("rl:actor.send", None if ledger is None else ledger.timings,
+              "send_s") as sp:
+        if ctx is not None:
+            sp.hop("traj", ctx.trace_id, "send", agent=agent_id)
+        if owner.spool is not None:
+            owner.spool.send(payload, agent_id, trace=trace, report=report)
+            return
+        # actor.spool_entries == 0: the pre-recovery direct path
+        from relayrl_tpu.transport.base import (
+            IngestNack,
+            tag_agent_report,
+            tag_agent_trace,
+        )
 
-    now = time.monotonic_ns()
-    enc0 = enc0_ns if born_ns <= enc0_ns <= now else now
-    enc1 = max(enc0, min(enc1_ns, now)) if enc1_ns else enc0
-    tracer.span("traj", ctx.trace_id, "env", born_ns, enc0,
-                agent=agent_id, version=int(version))
-    if enc1 > enc0:
-        tracer.span("traj", ctx.trace_id, "encode", enc0, enc1,
-                    agent=agent_id)
-    return ctx
-
-
-def _trace_send_span(ctx, agent_id: str, t0_ns: int) -> None:
-    if ctx is None:
-        return
-    import time
-
-    from relayrl_tpu.telemetry import trace as trace_mod
-
-    trace_mod.get_tracer().span("traj", ctx.trace_id, "send", t0_ns,
-                                time.monotonic_ns(), agent=agent_id)
+        wire_id = tag_agent_report(agent_id, report)
+        if trace is not None:
+            wire_id = tag_agent_trace(wire_id, trace)
+        try:
+            owner.transport.send_trajectory(payload, agent_id=wire_id)
+        except IngestNack:
+            # The server answered with a guardrail verdict
+            # (quarantine/overload). Spool-less there is nothing to
+            # retain or replay — drop, never crash the env loop (the
+            # spooled path routes this through spool._attempt).
+            pass
 
 
 def _bind_spool_impl(owner, name: str) -> None:
@@ -274,35 +300,12 @@ class Agent:
                        version=version, side="agent")
 
     def _send_traj(self, payload: bytes) -> None:
-        # Runs inside Trajectory.flush, so the trajectory's born/encode
-        # stamps describe exactly the chunk in `payload`.
+        # Runs inside Trajectory.flush, so the trajectory's born stamp
+        # and encode span describe exactly the chunk in `payload`.
         traj = self.actor.trajectory
-        ctx = _trace_emit(self.transport.identity, traj.born_ns,
-                          traj.encode_t0_ns, traj.encode_t1_ns,
-                          self.actor.version)
-        t0 = 0
-        if ctx is not None:
-            import time
-
-            t0 = time.monotonic_ns()
-        if self.spool is not None:
-            self.spool.send(payload, self.transport.identity,
-                            trace=None if ctx is None else ctx.encode())
-        else:  # actor.spool_entries == 0: the pre-recovery direct path
-            from relayrl_tpu.transport.base import IngestNack, tag_agent_trace
-
-            try:
-                self.transport.send_trajectory(
-                    payload,
-                    agent_id=(None if ctx is None else tag_agent_trace(
-                        self.transport.identity, ctx.encode())))
-            except IngestNack:
-                # The server answered with a guardrail verdict
-                # (quarantine/overload). Spool-less there is nothing to
-                # retain or replay — drop, never crash the env loop
-                # (the spooled path routes this through spool._attempt).
-                pass
-        _trace_send_span(ctx, self.transport.identity, t0)
+        ship_unroll(self, self.transport.identity, payload,
+                    (traj.born_ns, traj.encode_span), self.actor.version,
+                    self.actor.ledger)
 
     def _bind_spool(self) -> None:
         name = self._addr_overrides.get("identity") or "agent"
@@ -596,36 +599,16 @@ class VectorAgent:
         self.active = False
 
     def _send_lane(self, lane: int, payload: bytes) -> None:
-        # Emission stamps read BEFORE the interceptor (it may withhold
-        # and re-inject much later, when the host's stamps describe a
-        # different episode — re-injected payloads trace through the
-        # RLHF plane's own stage spans instead).
-        stamps = self._emit_stamps(lane)
+        # The unroll's origin is read BEFORE the interceptor (it may
+        # withhold and re-inject much later, when the host's stamps
+        # describe a different episode — re-injected payloads trace
+        # through the RLHF plane's own stage spans instead).
+        origin = self.host.shipping(lane)
         if self._send_interceptor is not None:
             payload = self._send_interceptor(lane, payload)
             if payload is None:
                 return  # the stage owns it now; emit_lane re-injects
-        self.emit_lane(lane, payload, _stamps=stamps)
-
-    def _emit_stamps(self, lane: int):
-        """(born_ns, encode_t0_ns, encode_t1_ns) for the payload being
-        emitted right now, or None when tracing is off: anakin columnar
-        hosts stamp ``_last_emit_stamps`` per frame; the per-record
-        tiers read the lane trajectory's chunk stamps (we are inside
-        its flush)."""
-        from relayrl_tpu.telemetry import trace as trace_mod
-
-        if not trace_mod.get_tracer().enabled:
-            return None
-        host = self.host
-        stamps = getattr(host, "_last_emit_stamps", None)
-        if stamps is not None:
-            return stamps
-        trajs = getattr(host, "trajectories", None)
-        if trajs is None:
-            return None
-        traj = trajs[lane]
-        return (traj.born_ns, traj.encode_t0_ns, traj.encode_t1_ns)
+        self.emit_lane(lane, payload, _stamps=origin)
 
     def emit_lane(self, lane: int, payload: bytes, _stamps=None) -> None:
         """Ship one lane's serialized episode through the normal
@@ -634,32 +617,10 @@ class VectorAgent:
         after assigning the terminal reward). Spool sequence numbers are
         assigned HERE, so withheld episodes only enter the at-least-once
         window once they are final — a replay after a crash redelivers
-        the scored bytes, never the unscored ones."""
-        ctx = None
-        t0 = 0
-        if _stamps is not None:
-            born_ns, enc0, enc1 = _stamps
-            ctx = _trace_emit(self.agent_ids[lane], born_ns, enc0, enc1,
-                              self.host.version)
-            if ctx is not None:
-                import time
-
-                t0 = time.monotonic_ns()
-        if self.spool is not None:
-            self.spool.send(payload, self.agent_ids[lane],
-                            trace=None if ctx is None else ctx.encode())
-        else:
-            from relayrl_tpu.transport.base import IngestNack, tag_agent_trace
-
-            try:
-                self.transport.send_trajectory(
-                    payload,
-                    agent_id=(self.agent_ids[lane] if ctx is None
-                              else tag_agent_trace(self.agent_ids[lane],
-                                                   ctx.encode())))
-            except IngestNack:
-                pass  # guardrail verdict, spool-less: drop (see Agent)
-        _trace_send_span(ctx, self.agent_ids[lane], t0)
+        the scored bytes, never the unscored ones. ``_stamps`` is
+        ``_send_lane``'s: the host's ``shipping(lane)``."""
+        ship_unroll(self, self.agent_ids[lane], payload, _stamps,
+                    self.host.version, getattr(self.host, "ledger", None))
 
     def _on_model(self, version: int, bundle_bytes: bytes) -> None:
         # ONE receipt serves all lanes: a single wire-aware swap

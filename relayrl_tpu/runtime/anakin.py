@@ -52,6 +52,7 @@ from relayrl_tpu.runtime.policy_actor import (
     resolve_actor_context,
     window_advance,
 )
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.columnar import (
     DecodedTrajectory,
@@ -278,12 +279,11 @@ class AnakinActorHost:
         self.emit_coalesce = max(1, int(emit_coalesce_frames))
         self._coalesce_buf: list[list[bytes]] = [
             [] for _ in range(self.num_envs)]
-        # Tracing stamps (telemetry/trace.py): the window production
-        # stamp (rollout dispatch start) plus the last frame's encode
-        # bracket, read by VectorAgent._emit_stamps when it mints a
-        # trajectory trace context for an emitted columnar segment.
+        # The window production stamp (rollout dispatch start) and, with
+        # it, the last columnar frame's origin — what ``shipping`` hands
+        # the agent's send hook for an emitted segment.
         self._window_born_ns = 0
-        self._last_emit_stamps: tuple[int, int, int] | None = None
+        self._frame_origin = None
         self.trajectories = [
             Trajectory(
                 max_length=max_traj_length,
@@ -615,15 +615,9 @@ class AnakinActorHost:
                      "r": r, "t": t_col, "u": u_col, "x": x_col},
             aux={k: self._cat(chunks) for k, chunks in p["aux"].items()},
             final_obs=final if time_limited else None)
-        from relayrl_tpu.telemetry import trace as trace_mod
-
-        if trace_mod.get_tracer().enabled:
-            enc0 = time.monotonic_ns()
+        with span("rl:actor.encode") as enc:
             frame = encode_columnar_frame(dt)
-            self._last_emit_stamps = (self._window_born_ns or enc0,
-                                      enc0, time.monotonic_ns())
-        else:
-            frame = encode_columnar_frame(dt)
+        self._frame_origin = (self._window_born_ns or enc.t0_ns, enc)
         self._m_frames.inc()
         self._m_frame_bytes.inc(len(frame))
         if self.emit_coalesce > 1:
@@ -708,6 +702,16 @@ class AnakinActorHost:
                 self._ep_ret[lane] += float(
                     np.sum(rew[lane, start:], dtype=np.float64))
         return episodes
+
+    def shipping(self, lane: int):
+        """``(born_ns, rl:actor.encode span)`` of what lane ``lane`` is
+        handing to ``on_send`` right now: the columnar frame just encoded
+        (born with its window), or the per-record fallback's trajectory
+        chunk."""
+        if self.columnar_wire:
+            return self._frame_origin
+        traj = self.trajectories[lane]
+        return traj.born_ns, traj.encode_span
 
     # -- model hot-swap (one gate, all lanes, whole windows) --
     def maybe_swap(self, bundle: ModelBundle) -> bool:

@@ -1077,33 +1077,14 @@ class RemoteActorClient:
         _handle_reconnect_impl(self, [self.transport.identity])
 
     def _send_traj(self, payload: bytes) -> None:
-        # Trajectory tracing parity with Agent._send_traj: the thin
-        # client's episodes draw trace contexts too (env hop = the
-        # round-trip-served production window).
-        from relayrl_tpu.runtime.agent import _trace_emit, _trace_send_span
+        # The thin client ships as Agent._send_traj does, with no ledger
+        # of its own: its report says when the episode was born (env hop
+        # = the round-trip-served production window).
+        from relayrl_tpu.runtime.agent import ship_unroll
 
         traj = self.trajectory
-        ctx = _trace_emit(self.transport.identity, traj.born_ns,
-                          traj.encode_t0_ns, traj.encode_t1_ns,
-                          self.version)
-        t0 = 0
-        if ctx is not None:
-            t0 = time.monotonic_ns()
-        if self.spool is not None:
-            self.spool.send(payload, self.transport.identity,
-                            trace=None if ctx is None else ctx.encode())
-            _trace_send_span(ctx, self.transport.identity, t0)
-        else:
-            from relayrl_tpu.transport.base import IngestNack, tag_agent_trace
-
-            try:
-                self.transport.send_trajectory(
-                    payload,
-                    agent_id=(None if ctx is None else tag_agent_trace(
-                        self.transport.identity, ctx.encode())))
-                _trace_send_span(ctx, self.transport.identity, t0)
-            except IngestNack:
-                pass  # guardrail verdict, spool-less: drop (see Agent)
+        ship_unroll(self, self.transport.identity, payload,
+                    (traj.born_ns, traj.encode_span), self.version)
 
     # -- action API (PolicyActor-shaped) --
     def request_for_action(self, obs, mask=None,

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from relayrl_tpu.models import build_policy, validate_policy
+from relayrl_tpu.telemetry.actor_ledger import ActorLedger
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import (
     ModelBundle,
@@ -93,16 +95,17 @@ def apply_bundle_swap(actor, bundle: "ModelBundle") -> bool:
     """Shared hot-swap gate: version check, arch-ABI guard, params
     install under the actor's lock. PolicyActor and VectorActorHost
     delegate here (same attribute contract: ``version``, ``arch``,
-    ``params``, ``_explore_kwargs``, ``_lock``) so the swap semantics —
-    including the exploration-knob refresh that must NOT rebuild the
-    policy — exist exactly once. Being the one gate also makes it the
-    one swap-latency instrumentation point: the histogram measures the
-    lock wait + install (what a slow batched step in flight costs every
-    model delivery), and each installed version lands in the event
-    journal."""
-    import time
-
+    ``params``, ``_explore_kwargs``, ``_lock``, and ``timings`` where
+    the host keeps a ledger) so the swap semantics — including the
+    exploration-knob refresh that must NOT rebuild the policy — exist
+    exactly once. Being the one gate also makes it the one swap-latency
+    instrumentation point: ``rl:actor.swap`` measures the lock wait +
+    install (what a slow batched step in flight costs every model
+    delivery) once, for the host's ``swap_s``, the histogram and the
+    sampled ``model`` / ``swap`` hop, and each installed version lands
+    in the event journal."""
     from relayrl_tpu import telemetry
+    from relayrl_tpu.telemetry import trace as trace_mod
 
     if bundle.version <= actor.version:
         return False
@@ -110,31 +113,28 @@ def apply_bundle_swap(actor, bundle: "ModelBundle") -> bool:
         raise ValueError(
             f"model arch changed {actor.arch} -> {bundle.arch}; "
             "actor refuses hot-swap (param-ABI guard)")
-    from relayrl_tpu.telemetry import trace as trace_mod
-
-    tracer = trace_mod.get_tracer()
-    t0_ns = time.monotonic_ns() if tracer.enabled else 0
-    t0 = time.monotonic()
-    with actor._lock:
-        if dict(bundle.arch) != actor.arch:
-            # Exploration knobs (epsilon/act_noise) changed: they are
-            # traced step arguments, so only the scalar values refresh —
-            # no policy rebuild, no retrace.
-            actor.arch = dict(bundle.arch)
-            actor._explore_kwargs = exploration_kwargs(actor.arch)
-        actor.params = bundle.params
-        actor.version = bundle.version
-    telemetry.get_registry().histogram(
-        "relayrl_actor_swap_seconds",
-        "model hot-swap: lock wait + params install").observe(
-            time.monotonic() - t0)
-    if tracer.enabled and tracer.sample_version(bundle.version):
-        # The downstream trace's terminal hop: this actor host applied
-        # the sampled version (actor field distinguishes hosts sharing
-        # one process — the in-process drill's topology).
-        tracer.span("model", trace_mod.model_trace_id(bundle.version),
-                    "swap", t0_ns, time.monotonic_ns(),
-                    version=int(bundle.version), actor=f"{id(actor):x}")
+    with span("rl:actor.swap", getattr(actor, "timings", None), "swap_s",
+              metric=telemetry.get_registry().histogram(
+                  "relayrl_actor_swap_seconds",
+                  "model hot-swap: lock wait + params install"),
+              version=int(bundle.version)) as sp:
+        if trace_mod.get_tracer().sample_version(bundle.version):
+            # The downstream trace's terminal hop: this actor host
+            # applied the sampled version (actor field distinguishes
+            # hosts sharing one process — the in-process drill's
+            # topology).
+            sp.hop("model", trace_mod.model_trace_id(bundle.version),
+                   "swap", version=int(bundle.version),
+                   actor=f"{id(actor):x}")
+        with actor._lock:
+            if dict(bundle.arch) != actor.arch:
+                # Exploration knobs (epsilon/act_noise) changed: they are
+                # traced step arguments, so only the scalar values
+                # refresh — no policy rebuild, no retrace.
+                actor.arch = dict(bundle.arch)
+                actor._explore_kwargs = exploration_kwargs(actor.arch)
+            actor.params = bundle.params
+            actor.version = bundle.version
     telemetry.emit("model_swap", version=bundle.version)
     return True
 
@@ -157,6 +157,10 @@ def apply_wire_swap(actor, version: int, blob: bytes):
     v1 path: legacy decode, plus a decoder reseed so a mixed-version
     fleet (v1 server, v2-capable actor) keeps the wire state coherent.
 
+    Everything before the gate — sniff, decode, copy, ``device_put`` — is
+    ``rl:actor.model_decode`` (the host's ``model_decode_s``): work of the
+    subscriber thread on the core that steps the lanes.
+
     Raises :class:`~relayrl_tpu.transport.modelwire.WireBaseMismatch`
     (once per divergence) so the transport owner can trigger a resync —
     gRPC re-polls with ``ver=-1``; broadcast planes wait out the
@@ -164,23 +168,35 @@ def apply_wire_swap(actor, version: int, blob: bytes):
     """
     from relayrl_tpu.transport import modelwire
 
-    if not modelwire.is_wire_frame(blob):
-        bundle = ModelBundle.from_bytes(blob,
-                                        params_template=ModelBundle.RAW_TREE)
-        bundle.version = version
-        if not apply_bundle_swap(actor, bundle):
-            return None
-        if actor._wire_decoder is not None:
-            actor._wire_decoder.seed(bundle.version, bundle.arch,
-                                     bundle.params)
-        return bundle
+    with span("rl:actor.model_decode", getattr(actor, "timings", None),
+              "model_decode_s", bytes=len(blob)):
+        v1 = not modelwire.is_wire_frame(blob)
+        if v1:
+            bundle = ModelBundle.from_bytes(
+                blob, params_template=ModelBundle.RAW_TREE)
+            bundle.version = version
+        else:
+            bundle = _decode_wire_frame(actor, blob)
+    if bundle is None or not apply_bundle_swap(actor, bundle):
+        return None
+    if v1 and actor._wire_decoder is not None:
+        actor._wire_decoder.seed(bundle.version, bundle.arch, bundle.params)
+    return bundle
+
+
+def _decode_wire_frame(actor, blob: bytes) -> "ModelBundle | None":
+    """A wire-v2 frame through the actor's decoder into a bundle that owns
+    its memory; None for a stale duplicate, or while awaiting a keyframe
+    after a resync."""
+    from relayrl_tpu.transport import modelwire
+
     dec = actor._wire_decoder
     if dec is None:
         dec = actor._wire_decoder = modelwire.ModelWireDecoder()
         dec.seed(actor.version, actor.arch, jax.device_get(actor.params))
     out = dec.decode(blob)
     if out is None:
-        return None  # stale duplicate, or awaiting a keyframe after resync
+        return None
     ver, arch, host_tree = out
     # The decoder's buffers are its LIVE delta targets — the next frame
     # mutates them in place — so the install must own its memory:
@@ -193,8 +209,7 @@ def apply_wire_swap(actor, version: int, blob: bytes):
     params = jax.tree.map(np.array, host_tree)
     if jax.default_backend() != "cpu":
         params = jax.device_put(params)
-    bundle = ModelBundle(version=ver, arch=arch, params=params)
-    return bundle if apply_bundle_swap(actor, bundle) else None
+    return ModelBundle(version=ver, arch=arch, params=params)
 
 
 def normalize_obs(obs) -> np.ndarray:
@@ -332,7 +347,13 @@ class PolicyActor:
         # created lazily on the first v2 frame (apply_wire_swap) so
         # in-process actors that never touch the network pay nothing.
         self._wire_decoder = None
-        self.trajectory = Trajectory(max_length=max_traj_length, on_send=on_send)
+        # Where this process's time goes, always on, reported to the
+        # learner on every trajectory shipped (telemetry/actor_ledger.py).
+        self.ledger = ActorLedger()
+        self.timings = self.ledger.timings
+        self.counts = self.ledger.counts
+        self.trajectory = Trajectory(max_length=max_traj_length,
+                                     on_send=on_send, timings=self.timings)
         from relayrl_tpu import telemetry
 
         self._m_steps = telemetry.get_registry().counter(
@@ -362,43 +383,49 @@ class PolicyActor:
         # rule (see normalize_obs: an unconditional float32 cast here
         # silently made every "byte-sized" pixel payload 112,989 B/step
         # instead of 28,226).
-        obs = normalize_obs(obs)
-        mask_arr = None if mask is None else np.asarray(mask, dtype=np.float32)
-        with self._lock:
-            if reward and self.trajectory.get_actions():
-                self.trajectory.get_actions()[-1].update_reward(float(reward))
-            # The RNG split rides inside each jitted step (_fuse_rng):
-            # every branch returns next_rng as its last output.
-            if self._window_fn is not None:
-                rolled = self._push_window(obs)
-                t = self._window_len - 1
-                if self._cached_fn is not None and not rolled:
-                    if (self._cache is None
-                            or self._cache_version != self.version):
-                        self._rebuild_cache(t)
-                    act, aux, self._cache, self._rng = self._cached_fn(
-                        self.params, self._rng, self._cache, obs, t,
-                        mask_arr)
-                else:
-                    self._cache = None  # rolling: positions shifted
-                    act, aux, self._rng = self._window_fn(
-                        self.params, self._rng, self._window,
-                        self._window_len, mask_arr)
-            else:
-                act, aux, self._rng = self._step_fn(
-                    self.params, self._rng, obs, mask_arr,
-                    **self._explore_kwargs)
-            record = ActionRecord(
-                obs=obs,
-                act=np.asarray(act),
-                mask=mask_arr,
-                rew=0.0,  # filled by the NEXT request / terminal marker
-                data={k: np.asarray(v) for k, v in aux.items()},
-                done=False,
-            )
-            self.trajectory.add_action(record, send_if_done=True)
+        with self.ledger.step(1):
+            obs = normalize_obs(obs)
+            mask_arr = (None if mask is None
+                        else np.asarray(mask, dtype=np.float32))
+            with self._lock:
+                if reward and self.trajectory.get_actions():
+                    self.trajectory.get_actions()[-1].update_reward(
+                        float(reward))
+                with span("rl:actor.infer", self.timings, "infer_s"):
+                    act, aux = self._infer(obs, mask_arr)
+                    act = np.asarray(act)
+                    data = {k: np.asarray(v) for k, v in aux.items()}
+                with self.ledger.record():
+                    record = ActionRecord(
+                        obs=obs, act=act, mask=mask_arr,
+                        rew=0.0,  # filled by the NEXT request / terminal
+                        data=data, done=False)
+                    self.trajectory.add_action(record, send_if_done=True)
         self._m_steps.inc()
         return record
+
+    def _infer(self, obs, mask_arr):
+        """One jitted sampling step (lock held): ``(act, aux)`` still on
+        the device. The RNG split rides inside each jitted step
+        (_fuse_rng): every branch returns next_rng as its last output."""
+        if self._window_fn is None:
+            act, aux, self._rng = self._step_fn(
+                self.params, self._rng, obs, mask_arr,
+                **self._explore_kwargs)
+            return act, aux
+        rolled = self._push_window(obs)
+        t = self._window_len - 1
+        if self._cached_fn is not None and not rolled:
+            if self._cache is None or self._cache_version != self.version:
+                self._rebuild_cache(t)
+            act, aux, self._cache, self._rng = self._cached_fn(
+                self.params, self._rng, self._cache, obs, t, mask_arr)
+        else:
+            self._cache = None  # rolling: positions shifted
+            act, aux, self._rng = self._window_fn(
+                self.params, self._rng, self._window, self._window_len,
+                mask_arr)
+        return act, aux
 
     def flag_last_action(
         self,
@@ -425,25 +452,28 @@ class PolicyActor:
         """
         if terminated:
             truncated = False
-        with self._lock:
+        # A step of the program's like any other (no lane stepped): the
+        # marker's flush must not read as the caller's environment.
+        with self.ledger.step(0), self._lock:
             if self._window is not None:
                 # Episode boundary: the next episode must not attend this
                 # one's observations.
                 self._window[:] = 0.0
                 self._window_len = 0
                 self._cache = None
-            record = ActionRecord(
-                obs=(None if final_obs is None
-                     else np.asarray(final_obs, np.float32)),
-                mask=(None if final_mask is None
-                      else np.asarray(final_mask, np.float32)),
-                rew=float(reward), done=True, truncated=bool(truncated))
-            self.trajectory.add_action(record, send_if_done=True)
+            with self.ledger.record():
+                record = ActionRecord(
+                    obs=(None if final_obs is None
+                         else np.asarray(final_obs, np.float32)),
+                    mask=(None if final_mask is None
+                          else np.asarray(final_mask, np.float32)),
+                    rew=float(reward), done=True, truncated=bool(truncated))
+                self.trajectory.add_action(record, send_if_done=True)
 
     def record_action(self, action: ActionRecord) -> None:
         """Append an externally-chosen action (the reference declares this
         but left it ``todo!()`` — agent_zmq.rs:585-596)."""
-        with self._lock:
+        with self.ledger.step(1), self._lock, self.ledger.record():
             self.trajectory.add_action(action, send_if_done=True)
 
     # -- model hot-swap --

@@ -33,14 +33,15 @@ from typing import Any, Mapping
 
 from relayrl_tpu.algorithms import build_algorithm, registered_algorithms
 from relayrl_tpu.config import ConfigLoader
+from relayrl_tpu.telemetry import actor_ledger
 from relayrl_tpu.telemetry.aggregate import is_snapshot_frame
-from relayrl_tpu.telemetry.spans import span
+from relayrl_tpu.telemetry.spans import span, watch_gc
+from relayrl_tpu.telemetry.trace import SKEW_GUARD_NS, TrajCtx
 from relayrl_tpu.transport import make_server_transport
-from relayrl_tpu.telemetry.trace import split_ctx as _split_trace_ctx
 from relayrl_tpu.transport.base import (
     BATCH_KIND_ENVELOPES,
     batch_kind,
-    split_agent_seq,
+    split_agent_tags,
     split_batch,
     swallow_decode_error,
     unpack_trajectory_envelope,
@@ -103,8 +104,9 @@ class _TracedRecords(list):
 
 
 def _attach_trace_ctx(item, ctx):
-    """Hang a sampled trajectory's trace context on the decoded item so
-    the learner thread can attribute the consuming update dispatch."""
+    """Hang a trajectory's origin (born stamp and version; a sampled one's
+    trace id too) on the decoded item so the learner thread can attribute
+    the consuming update dispatch."""
     if isinstance(item, DecodedTrajectory):
         item.trace_ctx = ctx
         return item
@@ -197,6 +199,23 @@ class TrainingServer:
             "observed for trajectories that carry bver, or a sampled "
             "trace context's born_version (same evidence)",
             buckets=LAG_BUCKETS)
+        from relayrl_tpu.telemetry.core import AGE_BUCKETS
+
+        self._m_data_age = reg.histogram(
+            "relayrl_trace_data_age_seconds",
+            "end-to-end data age of every trajectory that says when it "
+            "was born (the actors' report tag, a sampled trace context): "
+            "env-step/window production to the start of the update "
+            "dispatch that consumed it (same-host monotonic pairs; "
+            "skew-guarded)",
+            buckets=AGE_BUCKETS)
+        self._m_data_lag = reg.histogram(
+            "relayrl_trace_data_age_versions",
+            "data age in model versions: the version the consuming "
+            "update trains from minus the version the trajectory was "
+            "generated under (the born-stamp twin of "
+            "relayrl_rlhf_train_lag_versions)",
+            buckets=(0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0))
         self._m_ckpt_failures = reg.counter(
             "relayrl_server_checkpoint_failures_total",
             "periodic/final checkpoint saves that raised")
@@ -307,6 +326,9 @@ class TrainingServer:
             buf_size=buf_size,
             **hp,
         )
+        # host:dispatch asks the server what it alone knows of the batch
+        # being dispatched: when its trajectories were born.
+        self.algorithm._dispatch_note = self._note_data_age
         if self.guardrails is not None:
             # Installs the device-side health probes (observers — params
             # stay bit-identical to guardrails-off) and aligns the
@@ -576,12 +598,14 @@ class TrainingServer:
         from collections import deque
 
         self._pending_logs: deque = deque()
-        # Sampled trajectory contexts staged-but-not-yet-consumed: the
-        # next update dispatch closes them out with an "update" span +
-        # the data-age observation (learner thread only). Bounded as a
-        # belt — contexts only enter while the tracer is live, but a
-        # plugin algorithm that never updates must not hoard them.
+        # Origins (born stamp, version; a sampled trajectory's trace id)
+        # staged-but-not-yet-consumed: the next update dispatch reads
+        # their data age into its ``host:dispatch`` span and closes the
+        # sampled ones out with an "update" hop (learner thread only).
+        # Bounded as a belt: a plugin algorithm that never updates must
+        # not hoard them.
         self._trace_pending: deque = deque(maxlen=8192)
+        self._dispatched_origins: list = []
         self._timings_lock = threading.Lock()
         # "dropped" counts transport/queue-level losses; the ingest
         # finite-value guard's count is mirrored from the algorithm after
@@ -591,9 +615,12 @@ class TrainingServer:
         # loops below survive: a learner that ingests forever and never
         # updates (a kernel the compiler refuses, a device OOM) shows here
         # instead of only in scrolled-away log lines.
+        # actor_<count>: the actor tier's own counts, summed from the
+        # reports its trajectories carry (telemetry/actor_ledger.py).
         self.stats = {"trajectories": 0, "updates": 0, "dropped": 0,
                       "dropped_nonfinite": 0, "learner_errors": 0,
-                      "publish_errors": 0, "warmup_failed": 0}
+                      "publish_errors": 0, "warmup_failed": 0,
+                      **{f"actor_{k}": 0 for k in actor_ledger.COUNTS}}
         # Which trajectory decoder the staging threads resolved:
         # "native" (the C++ codec in native/) or "python".
         self.ingest_decoder: str | None = None
@@ -609,16 +636,21 @@ class TrainingServer:
         #                 because async dispatch makes a single "learn"
         #                 bucket meaningless (jaxlint JAX06)
         #   publish_s     publisher thread inside gather/serialize/send
-        #   learn_s       legacy total: learner thread inside trajectory
-        #                 processing (dispatch + deferred logs + fences
-        #                 that land there); superseded by the split above
         #   learner_idle_s learner thread blocked on an empty queue
         #   warmup_s      learner thread pre-compiling update shapes
+        #   gc_s          any thread inside a full collection (rl:gc)
+        #   actor_<key>   the actor PROCESSES' own ledgers, summed over
+        #                 them: each admitted trajectory's report carries
+        #                 its host's deltas (telemetry/actor_ledger.py has
+        #                 the keys; shares are of actor_wall_s)
         # Every total is fed by the span round its site (telemetry/spans.py;
         # docs/observability.md has the table).
-        self.timings = {"decode_s": 0.0, "learn_s": 0.0, "dispatch_s": 0.0,
+        self.timings = {"decode_s": 0.0, "dispatch_s": 0.0,
                         "device_wait_s": 0.0, "publish_s": 0.0,
-                        "learner_idle_s": 0.0, "warmup_s": 0.0}
+                        "learner_idle_s": 0.0, "warmup_s": 0.0,
+                        "gc_s": 0.0,
+                        **{f"actor_{k}": 0.0 for k in actor_ledger.TIMINGS}}
+        watch_gc(self)
         self._warmup_done = threading.Event()
 
         self._tb = None
@@ -778,19 +810,38 @@ class TrainingServer:
             telemetry.emit("duplicate_drop", n=due)
 
     def _admit_seq(self, agent_id: str):
-        """Split the sequence AND trace tags off an envelope id and
-        consult the dedup ledger: ``(clean_agent_id, seq, ctx, admit)``.
-        Both tags strip unconditionally — like the seq tag, a trace
-        context must never leak into attribution/quarantine keys even
-        when this process records no spans. Untagged ids (raw transport
-        users, pre-spool fleets) admit with seq/ctx None."""
-        clean_id, seq = split_agent_seq(agent_id)
-        clean_id, ctx = _split_trace_ctx(clean_id)
-        if seq is None or self._ingest_ledger is None:
-            return clean_id, seq, ctx, True
-        if not self._ingest_ledger.accept(clean_id, seq):
+        """Split the sequence, trace AND report tags off an envelope id
+        and consult the dedup ledger: ``(clean_agent_id, seq, ctx,
+        admit)``. Every tag strips unconditionally — like the seq tag, a
+        trace context or an actor's report must never leak into
+        attribution/quarantine keys even when this process records no
+        spans. ``ctx`` is the trajectory's origin: the sampled trace
+        context where one rides, else the report's born stamp and version
+        under no trace id, else None. Untagged ids (raw transport users,
+        older actors, pre-spool fleets) admit with seq/ctx None.
+
+        The one place an actor's report lands: an ADMITTED envelope adds
+        its deltas to ``timings["actor_<key>"]`` / ``stats["actor_<count>"]``;
+        a spool replay re-sends the tagged id and the dedup verdict keeps
+        it from counting twice. (An envelope shed after admission is
+        retracted and counts again when its replay lands.)"""
+        clean_id, seq, trace_text, report_text = split_agent_tags(agent_id)
+        ctx = None if trace_text is None else TrajCtx.decode(trace_text)
+        if (seq is not None and self._ingest_ledger is not None
+                and not self._ingest_ledger.accept(clean_id, seq)):
             self._count_duplicate()
             return clean_id, seq, ctx, False
+        report = (None if report_text is None
+                  else actor_ledger.decode_report(report_text))
+        if report is not None:
+            if ctx is None and report.born_ns:
+                ctx = TrajCtx(None, report.born_ns, report.born_version)
+            if report.timings:
+                with self._timings_lock:
+                    for key, seconds in report.timings.items():
+                        self.timings[f"actor_{key}"] += seconds
+                    for key, n in report.counts.items():
+                        self.stats[f"actor_{key}"] += n
         return clean_id, seq, ctx, True
 
     def _on_trajectory(self, agent_id: str, payload: bytes) -> None:
@@ -821,13 +872,9 @@ class TrainingServer:
         from relayrl_tpu.transport.base import (
             NACK_OVERLOADED,
             NACK_QUARANTINED,
-            split_agent_seq,
         )
 
-        from relayrl_tpu.transport.base import split_agent_trace
-
-        agent_id, _ = split_agent_seq(tagged_id)
-        agent_id, _ = split_agent_trace(agent_id)
+        agent_id = split_agent_tags(tagged_id)[0]
         if self._halted:
             # NOT counted as a halted drop: an overload nack is retained
             # by the sender's spool and replayed — counting each replay
@@ -896,16 +943,12 @@ class TrainingServer:
         # Trace hops (telemetry/trace.py): clock reads gate on a live
         # tracer, span recording on the envelope actually carrying a
         # sampled context — the untraced hot path pays one attribute
-        # check plus (tracer live) one monotonic_ns.
+        # check plus (tracer live) one monotonic_ns. The origin itself
+        # flows always: every update dispatch drains what it consumed.
         tracer = self._get_tracer()
         t_arr = time.monotonic_ns() if tracer.enabled else 0
         agent_id, seq, ctx, admit = self._admit_seq(agent_id)
-        if not tracer.enabled:
-            # The tag is stripped regardless; the context only FLOWS when
-            # this process traces (a mixed fleet — traced actors, trace-
-            # off server — must not accumulate contexts it never drains).
-            ctx = None
-        elif ctx is not None:
+        if tracer.enabled and ctx is not None and ctx.trace_id:
             t_ded = time.monotonic_ns()
             tracer.span("traj", ctx.trace_id, "ingest", t_arr, t_arr,
                         agent=agent_id, seq=seq)
@@ -976,17 +1019,15 @@ class TrainingServer:
         admitted = []
         for item in batch:
             clean_id, seq, ctx, admit = self._admit_seq(item.agent_id)
-            if ctx is not None and not tracer.enabled:
-                ctx = None  # see _ingest_one: never flow undrained ctxs
-            if ctx is not None:
+            if tracer.enabled and ctx is not None and ctx.trace_id:
                 # The native C++ core already decoded this payload; the
                 # ingest/dedup hops collapse to the drain's arrival.
                 tracer.span("traj", ctx.trace_id, "ingest", t_arr, t_arr,
                             agent=clean_id, seq=seq)
                 tracer.span("traj", ctx.trace_id, "dedup", t_arr,
                             time.monotonic_ns(), admitted=bool(admit))
-                if admit:
-                    item.trace_ctx = ctx
+            if ctx is not None and admit:
+                item.trace_ctx = ctx
             if not admit:
                 continue
             if clean_id != item.agent_id:
@@ -1217,10 +1258,12 @@ class TrainingServer:
                     else:
                         item = guard.validate(agent_id, item)
                 if ctx is not None and item is not None:
-                    # staging hop (decode + validate) + context handoff:
+                    # staging hop (decode + validate) + origin handoff:
                     # the learner attributes the consuming update at
                     # dispatch.
-                    sp.hop("traj", ctx.trace_id, "staging", agent=agent_id)
+                    if ctx.trace_id:
+                        sp.hop("traj", ctx.trace_id, "staging",
+                               agent=agent_id)
                     item = _attach_trace_ctx(item, ctx)
             with self._timings_lock:  # N decode workers share the ledger
                 self.timings["decode_s"] += sp.seconds
@@ -1490,8 +1533,8 @@ class TrainingServer:
                      and isinstance(item[0], DecodedTrajectory) else [item])
             queued_ns = wait.t1_ns - self._decoded.got_put_ns
             try:
-                with span("rl:learner.item", self.timings, "learn_s",
-                          queued_us=queued_ns // 1000, n=len(batch)):
+                with span("rl:learner.item", queued_us=queued_ns // 1000,
+                          n=len(batch)):
                     for one in batch:
                         self._process_one(one)
             finally:
@@ -1541,32 +1584,60 @@ class TrainingServer:
             # touch the ingest path's health.
             pass
 
+    def _note_data_age(self, t0_ns: int) -> dict:
+        """The data age of the batch an update dispatch consumes, computed
+        once for every consumer (learner thread, called by the algorithm
+        at the start of its ``host:dispatch`` span with that span's start
+        stamp): the born stamps of the trajectories accumulated since the
+        previous dispatch give the span's ``data_age_us`` (mean) and
+        ``data_age_max_us`` arguments — what the benchmark's
+        ``data_age_ms`` reads — and feed the two data-age histograms.
+        Same-host skew-guarded: a cross-host born stamp is dropped, not
+        observed."""
+        fresh = list(self._trace_pending)
+        self._trace_pending.clear()
+        self._dispatched_origins += fresh
+        consume_ver = self.algorithm.dispatched_version
+        ages = []
+        for ctx in fresh:
+            age_ns = t0_ns - ctx.born_ns
+            if 0 <= age_ns < SKEW_GUARD_NS:
+                ages.append(age_ns)
+                self._m_data_age.observe(age_ns * 1e-9)
+                if ctx.born_version >= 0:
+                    self._m_data_lag.observe(
+                        float(max(0, consume_ver - ctx.born_version)))
+        if not ages:
+            return {}
+        return {"data_age_us": sum(ages) // len(ages) // 1000,
+                "data_age_max_us": max(ages) // 1000}
+
     def _trace_dispatch(self, tracer, algo, t0_ns: int, t1_ns: int,
                         consume_ver: int) -> None:
-        """Close out the tracing bookkeeping of one update dispatch
-        (learner thread): the downstream ``dispatch`` hop for sampled
-        versions, and for every sampled trajectory context consumed
-        since the previous dispatch, the upstream ``update`` hop plus
-        the end-to-end data-age / version-lag observations (same-host
-        skew-guarded — a cross-host born stamp is dropped, not
-        observed). The stamps are the ``rl:learner.dispatch`` span's."""
-        from relayrl_tpu.telemetry.trace import SKEW_GUARD_NS, model_trace_id
+        """Close out the bookkeeping of one update dispatch (learner
+        thread): forget the origins it consumed and, where the tracer is
+        live, record the downstream ``dispatch`` hop for a sampled
+        version and the upstream ``update`` hop for every sampled
+        trajectory among them. The stamps are the ``rl:learner.dispatch``
+        span's."""
+        from relayrl_tpu.telemetry.trace import model_trace_id
 
-        ver = algo.dispatched_version
-        if tracer.sample_version(ver):
-            tracer.span("model", model_trace_id(ver), "dispatch",
-                        t0_ns, t1_ns, version=int(ver))
-        while self._trace_pending:
-            ctx = self._trace_pending.popleft()
-            # version = the version the batch trained FROM (matching the
-            # train_version_lag convention), not the freshly-minted one.
-            tracer.span("traj", ctx.trace_id, "update", t0_ns, t1_ns,
-                        version=int(consume_ver))
-            age_ns = t1_ns - ctx.born_ns
-            if 0 <= age_ns < SKEW_GUARD_NS:
-                lag = (int(consume_ver) - ctx.born_version
-                       if ctx.born_version >= 0 else None)
-                tracer.observe_data_age(age_ns / 1e9, lag)
+        origins = self._dispatched_origins
+        origins += self._trace_pending  # an algorithm that notes no data age
+        self._trace_pending.clear()
+        if tracer.enabled:
+            ver = algo.dispatched_version
+            if tracer.sample_version(ver):
+                tracer.span("model", model_trace_id(ver), "dispatch",
+                            t0_ns, t1_ns, version=int(ver))
+            for ctx in origins:
+                if ctx.trace_id:
+                    # version = the version the batch trained FROM
+                    # (matching the train_version_lag convention), not
+                    # the freshly-minted one.
+                    tracer.span("traj", ctx.trace_id, "update", t0_ns,
+                                t1_ns, version=int(consume_ver))
+        origins.clear()
 
     def _learner_error(self, what: str, e: Exception) -> None:
         """One bad batch must not kill the learner loop — but it is
@@ -1610,7 +1681,8 @@ class TrainingServer:
         ctx = getattr(item, "trace_ctx", None)
         if ctx is not None:
             self._trace_pending.append(ctx)
-        self._observe_behavior_lag(item, algo, ctx)
+        self._observe_behavior_lag(
+            item, algo, ctx if ctx is not None and ctx.trace_id else None)
         tracer = self._get_tracer()
         # The version this batch trains FROM (pre-dispatch) — the
         # convention _observe_behavior_lag's histogram uses, so the
@@ -1656,7 +1728,7 @@ class TrainingServer:
                 self._pending_logs.append(
                     (algo.inflight.dispatch_count, payload,
                      algo._last_metrics))
-        if tracer.enabled and updated:
+        if updated:
             self._trace_dispatch(tracer, algo, sp.t0_ns, sp.t1_ns,
                                  consume_ver)
         if updated:

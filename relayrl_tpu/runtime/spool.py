@@ -132,24 +132,31 @@ class TrajectorySpool:
             return len(self._entries)
 
     def send(self, payload: bytes, agent_id: str,
-             trace: str | None = None) -> int:
+             trace: str | None = None, report: str | None = None) -> int:
         """Assign the next seq for ``agent_id``, retain, and attempt
         delivery (unless the breaker is open). Returns the seq. Never
         raises on wire failure — the entry is already retained and the
         breaker/replay machinery owns recovery.
 
-        ``trace`` (telemetry/trace.py, a sampled trajectory's encoded
-        context) rides the wire id as a ``#t`` tag BETWEEN the agent id
-        and the ``#s`` seq tag — the seq SPACE stays keyed by the clean
-        agent id (a per-trajectory tag in the key would reset every
-        trajectory to seq 1 and dedup the fleet into silence), while
-        the retained entry keeps the tagged id so a replay re-ships the
-        context verbatim."""
-        wire_id = agent_id
-        if trace is not None:
-            from relayrl_tpu.transport.base import tag_agent_trace
+        ``report`` (telemetry/actor_ledger.py, the shipper's time ledger
+        and the unroll's born stamp) and ``trace`` (telemetry/trace.py, a
+        sampled trajectory's encoded context) ride the wire id as ``#r``
+        and ``#t`` tags BETWEEN the agent id and the ``#s`` seq tag — the
+        seq SPACE stays keyed by the clean agent id (a per-trajectory
+        tag in the key would reset every trajectory to seq 1 and dedup
+        the fleet into silence), while the retained entry keeps the
+        tagged id so a replay re-ships both verbatim (the server's dedup
+        verdict keeps a replayed report from counting twice)."""
+        from relayrl_tpu.transport.base import (
+            tag_agent_report,
+            tag_agent_trace,
+        )
 
-            wire_id = tag_agent_trace(agent_id, trace)
+        wire_id = agent_id
+        if report is not None:
+            wire_id = tag_agent_report(wire_id, report)
+        if trace is not None:
+            wire_id = tag_agent_trace(wire_id, trace)
         with self._lock:
             seq = self._next_seq.get(agent_id, 0) + 1
             self._next_seq[agent_id] = seq
@@ -428,12 +435,12 @@ class TrajectorySpool:
             _, _, old = self._entries.pop(0)
             self._bytes -= len(old)
         if seq:
-            # Stored wire ids may carry a per-trajectory trace tag; the
-            # seq space is keyed by the CLEAN id (see send), so restore
-            # the counter under the same key.
-            from relayrl_tpu.transport.base import split_agent_trace
+            # Stored wire ids carry per-trajectory tags; the seq space
+            # is keyed by the CLEAN id (see send), so restore the
+            # counter under the same key.
+            from relayrl_tpu.transport.base import split_agent_tags
 
-            clean_id, _ = split_agent_trace(agent_id)
+            clean_id = split_agent_tags(agent_id)[0]
             if seq > self._next_seq.get(clean_id, 0):
                 self._next_seq[clean_id] = seq
 

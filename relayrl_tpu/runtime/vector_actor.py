@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 from relayrl_tpu.models import build_policy, validate_policy
+from relayrl_tpu.telemetry.actor_ledger import ActorLedger
 from relayrl_tpu.runtime.policy_actor import (
     apply_bundle_swap,
     apply_wire_swap,
@@ -45,6 +46,7 @@ from relayrl_tpu.runtime.policy_actor import (
     push_window,
     resolve_actor_context,
 )
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import ModelBundle, exploration_kwargs
 from relayrl_tpu.types.trajectory import Trajectory
@@ -106,12 +108,18 @@ class VectorActorHost:
         else:
             self._keys = jax.random.split(
                 jax.random.PRNGKey(seed), self.num_envs)
+        # Where this process's time goes, always on, reported to the
+        # learner on every trajectory shipped (telemetry/actor_ledger.py).
+        self.ledger = ActorLedger()
+        self.timings = self.ledger.timings
+        self.counts = self.ledger.counts
         self.trajectories = [
             Trajectory(
                 max_length=max_traj_length,
                 on_send=(None if on_send is None
                          else (lambda payload, _lane=lane:
-                               on_send(_lane, payload))))
+                               on_send(_lane, payload))),
+                timings=self.timings)
             for lane in range(self.num_envs)
         ]
         from relayrl_tpu import telemetry
@@ -140,6 +148,13 @@ class VectorActorHost:
         — ``ActionRecord.rew`` always means "reward earned BY this
         action"). ``masks`` is None or stacked ``[N, act_dim]``.
         """
+        with self.ledger.step(self.num_envs):
+            records = self._step(obs, masks, rewards)
+        self._m_steps.inc(self.num_envs)
+        self._m_dispatches.inc()
+        return records
+
+    def _step(self, obs, masks, rewards) -> list[ActionRecord]:
         obs = np.asarray(obs)
         if obs.shape[0] != self.num_envs:
             raise ValueError(
@@ -159,40 +174,42 @@ class VectorActorHost:
             # ONE params read under the lock for the whole batch: every
             # lane acts on the same model version by construction
             # (maybe_swap's atomicity across lanes).
-            if self._batched_window_fn is not None:
-                self._push_windows(obs)
-                # step_window takes the per-lane count of REAL rows (it
-                # reads out at t-1 itself) — same convention as
-                # PolicyActor passing _window_len, asserted bit-identical
-                # by the window parity test.
-                acts, aux, self._keys = self._batched_window_fn(
-                    self.params, self._keys, self._windows,
-                    self._window_lens, masks_arr)
-            else:
-                acts, aux, self._keys = self._batched_fn(
-                    self.params, self._keys, obs, masks_arr,
-                    self._explore_kwargs)
-            acts_np = np.asarray(acts)
-            aux_np = {k: np.asarray(v) for k, v in aux.items()}
+            with span("rl:actor.infer", self.timings, "infer_s"):
+                if self._batched_window_fn is not None:
+                    self._push_windows(obs)
+                    # step_window takes the per-lane count of REAL rows
+                    # (it reads out at t-1 itself) — same convention as
+                    # PolicyActor passing _window_len, asserted
+                    # bit-identical by the window parity test.
+                    acts, aux, self._keys = self._batched_window_fn(
+                        self.params, self._keys, self._windows,
+                        self._window_lens, masks_arr)
+                else:
+                    acts, aux, self._keys = self._batched_fn(
+                        self.params, self._keys, obs, masks_arr,
+                        self._explore_kwargs)
+                acts_np = np.asarray(acts)
+                aux_np = {k: np.asarray(v) for k, v in aux.items()}
             records = []
-            for lane in range(self.num_envs):
-                record = ActionRecord(
-                    obs=obs[lane],
-                    act=acts_np[lane],
-                    mask=None if masks_arr is None else masks_arr[lane],
-                    rew=0.0,  # filled by the lane's NEXT request / terminal
-                    # np.asarray: indexing a stacked [N] aux column yields
-                    # a numpy SCALAR, which the wire codec would encode as
-                    # a float64 — the 0-d ndarray keeps dtype (and bytes)
-                    # identical to the single-actor path.
-                    data={k: np.asarray(v[lane])
-                          for k, v in aux_np.items()},
-                    done=False,
-                )
-                self.trajectories[lane].add_action(record, send_if_done=True)
-                records.append(record)
-        self._m_steps.inc(self.num_envs)
-        self._m_dispatches.inc()
+            with self.ledger.record():
+                for lane in range(self.num_envs):
+                    record = ActionRecord(
+                        obs=obs[lane],
+                        act=acts_np[lane],
+                        mask=None if masks_arr is None else masks_arr[lane],
+                        rew=0.0,  # filled by the lane's NEXT request
+                        # np.asarray: indexing a stacked [N] aux column
+                        # yields a numpy SCALAR, which the wire codec
+                        # would encode as a float64 — the 0-d ndarray
+                        # keeps dtype (and bytes) identical to the
+                        # single-actor path.
+                        data={k: np.asarray(v[lane])
+                              for k, v in aux_np.items()},
+                        done=False,
+                    )
+                    self.trajectories[lane].add_action(record,
+                                                       send_if_done=True)
+                    records.append(record)
         return records
 
     def flag_last_action(self, lane: int, reward: float = 0.0,
@@ -206,19 +223,30 @@ class VectorActorHost:
         truncated precedence and the bootstrap ``final_obs``."""
         if terminated:
             truncated = False
-        with self._lock:
+        # A step of the program's like any other (no lane stepped): the
+        # marker's flush must not read as the caller's environment.
+        with self.ledger.step(0), self._lock:
             if self._windows is not None:
                 # Episode boundary for this lane only: its next episode
                 # must not attend this one's observations.
                 self._windows[lane, :, :] = 0.0
                 self._window_lens[lane] = 0
-            record = ActionRecord(
-                obs=(None if final_obs is None
-                     else np.asarray(final_obs, np.float32)),
-                mask=(None if final_mask is None
-                      else np.asarray(final_mask, np.float32)),
-                rew=float(reward), done=True, truncated=bool(truncated))
-            self.trajectories[lane].add_action(record, send_if_done=True)
+            with self.ledger.record():
+                record = ActionRecord(
+                    obs=(None if final_obs is None
+                         else np.asarray(final_obs, np.float32)),
+                    mask=(None if final_mask is None
+                          else np.asarray(final_mask, np.float32)),
+                    rew=float(reward), done=True, truncated=bool(truncated))
+                self.trajectories[lane].add_action(record,
+                                                   send_if_done=True)
+
+    def shipping(self, lane: int):
+        """``(born_ns, rl:actor.encode span)`` of the unroll lane ``lane``
+        is handing to ``on_send`` right now (the send hook runs inside its
+        trajectory's flush)."""
+        traj = self.trajectories[lane]
+        return traj.born_ns, traj.encode_span
 
     # -- model hot-swap (one gate, all lanes) --
     def maybe_swap(self, bundle: ModelBundle) -> bool:

@@ -1,4 +1,5 @@
-"""The one span primitive of the learner process.
+"""The one span primitive of every process of the program: the learner, its
+staging and publisher threads, and the actor processes.
 
 ``with span(name, ...) as sp:`` reads ``time.monotonic_ns()`` once on entry
 and once on exit and feeds every consumer of that interval from the one
@@ -13,10 +14,16 @@ pair of stamps:
    off no annotation object is built;
 3. **the sampled causal trace** — ``sp.hop(kind, trace_id, hop, **fields)``
    records a :mod:`relayrl_tpu.telemetry.trace` hop span with the same two
-   stamps when the block exits (callers gate on the tracer's sampling).
+   stamps when the block exits, or at once if it already has (an actor draws
+   a trajectory's trace context after the unroll is encoded); callers gate
+   on the tracer's sampling.
 
 Nothing switches it: sink 2 follows the profiler, sink 3
-``telemetry.trace_sample_rate``.
+``telemetry.trace_sample_rate``. In a process that never loaded jax (a thin
+client) no profiler session can run: sink 2 is off and jax stays unloaded.
+
+:func:`watch_gc` puts the interpreter's full collections on the same
+primitive (``rl:gc``): the one stall of the host no other span can name.
 
 Names. ``host:<phase>`` are the learner thread's sequential phases (at most
 one open at a time on that thread; the benchmark's reducer attributes device
@@ -31,7 +38,10 @@ shift that places ring spans and journal events on the profiler's clock.
 
 from __future__ import annotations
 
+import gc
+import sys
 import time
+import weakref
 
 _monotonic_ns = time.monotonic_ns
 _annotation = None  # jax.profiler.TraceAnnotation, resolved on first use
@@ -39,10 +49,12 @@ _annotation = None  # jax.profiler.TraceAnnotation, resolved on first use
 
 def _profiling() -> bool:
     """Whether a profiler session is recording. Resolves the annotation
-    class on the first call (not at import: telemetry is imported by
-    processes that never load jax), then rebinds itself to the class's own
-    check."""
+    class on the first call in a process that has loaded jax (not at
+    import, and never by importing it: telemetry is imported by processes
+    that never load jax), then rebinds itself to the class's own check."""
     global _annotation, _profiling
+    if "jax" not in sys.modules:
+        return False
     from jax.profiler import TraceAnnotation
 
     _annotation = TraceAnnotation
@@ -64,6 +76,7 @@ class span:
         self.key = key
         self.metric = metric
         self.args = args
+        self.t1_ns = 0
         self._ann = None
         self._hops = None
 
@@ -83,12 +96,15 @@ class span:
         if self.metric is not None:
             self.metric.observe((t1 - self.t0_ns) * 1e-9)
         if self._hops is not None:
-            from relayrl_tpu.telemetry.trace import get_tracer
-
-            tracer = get_tracer()
-            for kind, trace_id, hop, fields in self._hops:
-                tracer.span(kind, trace_id, hop, self.t0_ns, t1, **fields)
+            for hop in self._hops:
+                self._record_hop(*hop)
         return False
+
+    def _record_hop(self, kind, trace_id, hop, fields) -> None:
+        from relayrl_tpu.telemetry.trace import get_tracer
+
+        get_tracer().span(kind, trace_id, hop, self.t0_ns, self.t1_ns,
+                          **fields)
 
     @property
     def seconds(self) -> float:
@@ -107,10 +123,50 @@ class span:
             self._ann.set_metadata(**args)
 
     def hop(self, kind: str, trace_id: str, hop: str, **fields) -> None:
-        """Also record this interval as a sampled causal hop span."""
+        """Also record this interval as a sampled causal hop span: when the
+        block exits, or now if it has."""
+        if self.t1_ns:
+            self._record_hop(kind, trace_id, hop, fields)
+            return
         if self._hops is None:
             self._hops = []
         self._hops.append((kind, trace_id, hop, fields))
 
 
-__all__ = ["span"]
+# -- full collections --------------------------------------------------------
+
+_gc_watchers: "weakref.WeakSet" = weakref.WeakSet()
+_gc_span: span | None = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``rl:gc`` span a generation-2 collection
+    (start and stop run on the collecting thread, and collections do not
+    nest). Takes no lock — a collection can start under any — and is the
+    only writer of ``gc_s``."""
+    global _gc_span
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_span = span("rl:gc", generation=2)
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        sp, _gc_span = _gc_span, None
+        sp.note(collected=info["collected"])
+        sp.__exit__(None, None, None)
+        for owner in list(_gc_watchers):
+            owner.timings["gc_s"] += sp.seconds
+
+
+def watch_gc(owner=None) -> None:
+    """Install the process's one collection hook (idempotent) and, for an
+    ``owner`` that keeps a ledger, feed its ``owner.timings["gc_s"]`` for as
+    long as it lives. Whoever builds a ledger calls this: the training
+    server, the actor hosts, ``build_algorithm``."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    if owner is not None:
+        _gc_watchers.add(owner)
+
+
+__all__ = ["span", "watch_gc"]

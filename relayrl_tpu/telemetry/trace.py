@@ -14,8 +14,9 @@ Two trace kinds, both sampled at ``telemetry.trace_sample_rate``:
   window production through columnar encode, spool/send, (relay
   batch-forward,) server ingest, dedup, staging decode, and the update
   dispatch that consumed it. The trace context rides the wire as a
-  suffix on the envelope agent id — ``<agent>#t<ctx>#s<seq>`` — beside
-  the spool's ``#s`` seq tag, so zmq/grpc/native and relay hops all
+  suffix on the envelope agent id — ``<agent>#r<report>#t<ctx>#s<seq>``
+  — beside the spool's ``#s`` seq tag and the actors' always-on ``#r``
+  report (telemetry/actor_ledger.py), so zmq/grpc/native and relay hops all
   carry it without a new wire version (the native C++ core carries
   envelope ids verbatim; RLD1 frames and RLB1 containers are untouched).
 * ``model`` — one sampled model version, traced **downstream** from
@@ -43,7 +44,9 @@ which reduces sampled traces to per-hop latency attribution plus the two
 numbers the metrics plane cannot produce: end-to-end **data age**
 (env-step → consumed-by-update) and **model age** (dispatch →
 applied-at-actor) distributions. The same ages are observed live into
-``relayrl_trace_data_age_seconds`` / ``relayrl_trace_model_age_seconds``
+``relayrl_trace_data_age_seconds`` (by the training server, for EVERY
+trajectory that says when it was born — the report tag carries the stamp
+whether or not a trace was drawn) / ``relayrl_trace_model_age_seconds``
 (surfaced by ``telemetry.top`` and pooled by the fleet drills).
 
 Clock discipline: every stamp is CLOCK_MONOTONIC ``monotonic_ns()`` —
@@ -92,11 +95,14 @@ class TrajCtx:
     """The trajectory trace context that rides the wire: a trace id plus
     the origin stamps the server needs to compute data age (born_ns,
     CLOCK_MONOTONIC at env-step/window production) and version lag
-    (born_version, the params version the data was generated under)."""
+    (born_version, the params version the data was generated under). The
+    server keeps the origin of an unsampled trajectory in the same shape
+    under ``trace_id`` None (the stamps then came with its report tag)."""
 
     __slots__ = ("trace_id", "born_ns", "born_version")
 
-    def __init__(self, trace_id: str, born_ns: int, born_version: int):
+    def __init__(self, trace_id: str | None, born_ns: int,
+                 born_version: int):
         self.trace_id = trace_id
         self.born_ns = int(born_ns)
         self.born_version = int(born_version)
@@ -150,7 +156,7 @@ class SpanRecorder:
 
 class Tracer:
     """The live tracing surface: sampling decisions, span recording,
-    and the data-age/model-age histograms. One per process, installed by
+    and the model-age histogram. One per process, installed by
     :func:`configure` (telemetry's ``configure_from_config`` does it when
     ``telemetry.trace_sample_rate > 0``)."""
 
@@ -178,12 +184,6 @@ class Tracer:
         self._m_sampled = reg.counter(
             "relayrl_trace_sampled_total",
             "trajectories that drew a trace context at emission")
-        self._m_data_age = reg.histogram(
-            "relayrl_trace_data_age_seconds",
-            "end-to-end data age of sampled trajectories: env-step/window "
-            "production to the update dispatch that consumed them "
-            "(same-host monotonic pairs; skew-guarded)",
-            buckets=AGE_BUCKETS)
         self._m_model_age = reg.histogram(
             "relayrl_trace_model_age_seconds",
             "model age at the actor: publish stamp to swap-applied "
@@ -191,13 +191,6 @@ class Tracer:
             "the server-side dispatch→publish spans for the full "
             "dispatch→applied distribution",
             buckets=AGE_BUCKETS)
-        self._m_data_lag = reg.histogram(
-            "relayrl_trace_data_age_versions",
-            "data age in model versions: consuming update's dispatched "
-            "version minus the version the trajectory was generated "
-            "under (the trace-context twin of "
-            "relayrl_rlhf_train_lag_versions)",
-            buckets=(0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0))
 
     # -- sampling --
     def _draw(self) -> int | None:
@@ -266,12 +259,6 @@ class Tracer:
 
             telemetry.emit("trace_span", **rec)
 
-    def observe_data_age(self, age_s: float,
-                         lag_versions: int | None = None) -> None:
-        self._m_data_age.observe(age_s)
-        if lag_versions is not None and lag_versions >= 0:
-            self._m_data_lag.observe(float(lag_versions))
-
     def observe_model_age(self, age_s: float) -> None:
         self._m_model_age.observe(age_s)
 
@@ -298,9 +285,6 @@ class NullTracer:
         return False
 
     def span(self, *args, **fields) -> None:
-        pass
-
-    def observe_data_age(self, age_s, lag_versions=None) -> None:
         pass
 
     def observe_model_age(self, age_s) -> None:

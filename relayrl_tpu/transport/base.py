@@ -25,6 +25,7 @@ stays per-connection (one receipt fans into every logical lane host-side).
 from __future__ import annotations
 
 import abc
+import re
 import threading
 from collections import deque
 from typing import Callable
@@ -182,8 +183,8 @@ def tag_agent_trace(agent_id: str, ctx_text: str) -> str:
 
 def split_agent_trace(agent_id: str) -> tuple[str, str | None]:
     """``"a#tdead.beef.2" -> ("a", "dead.beef.2")``; ids without a
-    valid trace tag -> ``(agent_id, None)``. Call AFTER
-    :func:`split_agent_seq` (the seq tag is outermost on the wire)."""
+    valid trace tag as their last tag -> ``(agent_id, None)``
+    (:func:`split_agent_tags` takes every tag off in one call)."""
     base, sep, tail = agent_id.rpartition(_TRACE_TAG)
     if not sep:
         return agent_id, None
@@ -192,6 +193,61 @@ def split_agent_trace(agent_id: str) -> tuple[str, str | None]:
             p and all(c in _CTX_HEX for c in p) for p in parts):
         return agent_id, None
     return base, tail
+
+
+# -- actor report tags (the actor tier's time ledger, telemetry/actor_ledger.py) --
+#
+# Every trajectory an actor host ships carries, on the same envelope-id
+# channel and for the same reason (the id is the one field every backend,
+# container, relay and the native core carry verbatim), the host's time
+# ledger as DELTAS since its previous shipment plus the unroll's born stamp
+# and version: ``<agent>#r<report>#t<ctx>#s<seq>``, the ``#t`` tag only on a
+# sampled trajectory, the ``#s`` tag only through a spool. The payload is
+# dot-separated lowercase-hex integers, a format version first
+# (``telemetry.actor_ledger`` owns the field order); like the trace tag it is
+# validated strictly on split. :func:`split_agent_tags` strips the three
+# tags in whatever order they were applied — the ONE split of every site
+# that attributes an envelope (server admission and ingest check, the relay,
+# the spool's restore): a tag that leaked into an attribution, quarantine or
+# dedup key would make every trajectory its own agent.
+_REPORT_TAG = "#r"
+_REPORT_TEXT = re.compile(r"[0-9a-f]+(?:\.[0-9a-f]+){2,}")
+
+
+def tag_agent_report(agent_id: str, report_text: str) -> str:
+    return f"{agent_id}{_REPORT_TAG}{report_text}"
+
+
+def split_agent_report(agent_id: str) -> tuple[str, str | None]:
+    """``"a#r1.7b.5" -> ("a", "1.7b.5")``; ids without a valid report tag
+    as their last tag -> ``(agent_id, None)``."""
+    base, sep, tail = agent_id.rpartition(_REPORT_TAG)
+    if not sep or _REPORT_TEXT.fullmatch(tail) is None:
+        return agent_id, None
+    return base, tail
+
+
+def split_agent_tags(
+        agent_id: str) -> tuple[str, int | None, str | None, str | None]:
+    """Strip every tag off an envelope id, in any order of application:
+    ``(clean_agent_id, seq, trace_ctx_text, report_text)``, None for a tag
+    that is not there. Untagged ids (raw transport users, older actors)
+    pass through untouched."""
+    seq = trace = report = None
+    while True:
+        if seq is None:
+            agent_id, seq = split_agent_seq(agent_id)
+            if seq is not None:
+                continue
+        if trace is None:
+            agent_id, trace = split_agent_trace(agent_id)
+            if trace is not None:
+                continue
+        if report is None:
+            agent_id, report = split_agent_report(agent_id)
+            if report is not None:
+                continue
+        return agent_id, seq, trace, report
 
 
 def pack_model_frame(version: int, bundle_bytes: bytes,

@@ -21,10 +21,12 @@ Deliberate departures from the reference (documented per SURVEY.md §7.5):
 
 from __future__ import annotations
 
+import time
 from typing import Callable, Iterable
 
 import msgpack
 
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord, _ext_hook
 
 WIRE_VERSION = 1
@@ -37,20 +39,23 @@ class Trajectory:
         self,
         max_length: int = 1000,
         on_send: Callable[[bytes], None] | None = None,
+        timings: dict | None = None,
     ):
         if max_length <= 0:
             raise ValueError("max_length must be positive")
         self.max_length = int(max_length)
         self._on_send = on_send
         self._actions: list[ActionRecord] = []
-        # Tracing stamps (telemetry/trace.py): born_ns marks the first
-        # step of the chunk currently buffering, encode_t0/t1_ns bracket
-        # the last flush's serialize. Read by the owning agent's send
-        # hook when it mints a trajectory trace context; one clock read
-        # per chunk/flush, never per step beyond the emptiness check.
+        # The owning actor host's time ledger (telemetry/actor_ledger.py):
+        # flush feeds its ``encode_s``. None: nobody keeps one.
+        self._timings = timings
+        # What the owning agent's send hook reads of the chunk it is
+        # handed (it runs inside flush): born_ns, the stamp of the
+        # chunk's first step — one clock read a chunk, never per step
+        # beyond the emptiness check — and the flush's
+        # ``rl:actor.encode`` span.
         self.born_ns = 0
-        self.encode_t0_ns = 0
-        self.encode_t1_ns = 0
+        self.encode_span: span | None = None
 
     # -- reference API parity (trajectory.rs:95-203) --
     @property
@@ -83,8 +88,6 @@ class Trajectory:
         if not is_marker and len(self._actions) >= self.max_length:
             self._flush_or_evict_at_capacity(send_if_done)
         if not self._actions:
-            import time
-
             self.born_ns = time.monotonic_ns()
         self._actions.append(action)
         if action.done and send_if_done and self._on_send is not None:
@@ -114,8 +117,6 @@ class Trajectory:
         the number of transport flushes performed."""
         acts = self._actions
         if not acts and records:
-            import time
-
             self.born_ns = time.monotonic_ns()
         flushes = 0
         i, n = 0, len(records)
@@ -150,11 +151,9 @@ class Trajectory:
         """
         if not self._actions or self._on_send is None:
             return
-        import time
-
-        self.encode_t0_ns = time.monotonic_ns()
-        buf = self.to_bytes()
-        self.encode_t1_ns = time.monotonic_ns()
+        with span("rl:actor.encode", self._timings, "encode_s") as sp:
+            buf = self.to_bytes()
+        self.encode_span = sp
         self._on_send(buf)
         self._actions.clear()
 

@@ -11,6 +11,31 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
+def xplane_events(trace_dir) -> dict:
+    """{name: [(line index, start_ns, duration_ns, stats)]} of the program's
+    spans (``host:`` / ``rl:``) on the host planes of the newest xplane
+    under ``trace_dir``."""
+    import glob
+    import os
+
+    import jax
+
+    path = max(glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                      "*", "*.xplane.pb")),
+               key=os.path.getmtime)
+    events: dict = {}
+    n = 0
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            n += 1
+            for ev in line.events:
+                if ev.name.startswith(("host:", "rl:")):
+                    events.setdefault(ev.name, []).append(
+                        (n, ev.start_ns, ev.duration_ns, dict(ev.stats)))
+    return events
+
 
 def zmq_addr_pair() -> tuple[dict, dict]:
     """``(server_addrs, agent_addrs)`` for one zmq plane on fresh ephemeral

@@ -114,16 +114,16 @@ def _leaf_arrival_ids(agent_id: str, payload: bytes) -> list[str]:
     from relayrl_tpu.transport.base import (
         BATCH_KIND_ENVELOPES,
         batch_kind,
-        split_agent_seq,
-        split_agent_trace,
+        split_agent_tags,
         split_batch,
         unpack_trajectory_envelope,
     )
 
     def clean(tagged: str) -> str:
-        # Wire ids carry the seq tag and (tracing on) the trace-context
-        # tag; attribution strips both, like the server's ingest funnel.
-        return split_agent_trace(split_agent_seq(tagged)[0])[0]
+        # Wire ids carry the seq tag, the actor's report tag and (tracing
+        # on) the trace-context tag; attribution strips all three, like
+        # the server's ingest funnel.
+        return split_agent_tags(tagged)[0]
 
     if batch_kind(payload) != BATCH_KIND_ENVELOPES:
         return [clean(agent_id)]
@@ -296,7 +296,7 @@ def run_soak(n_actors: int = 64, agents_per_proc: int = 8,
     from relayrl_tpu import telemetry
     from relayrl_tpu.runtime.server import TrainingServer
     from relayrl_tpu.telemetry.aggregate import snapshot_metric
-    from relayrl_tpu.transport.base import split_agent_seq, split_agent_trace
+    from relayrl_tpu.transport.base import split_agent_tags
 
     if relays and serving:
         raise ValueError("relay-tree soaks run the actor tiers, not serving")
@@ -348,8 +348,7 @@ def run_soak(n_actors: int = 64, agents_per_proc: int = 8,
 
         def counting_decoded(batch):
             seen_traj_agents.update(
-                split_agent_trace(split_agent_seq(t.agent_id)[0])[0]
-                for t in batch)
+                split_agent_tags(t.agent_id)[0] for t in batch)
             orig_decoded(batch)
 
         server.transport.on_trajectory_decoded = counting_decoded

@@ -2,16 +2,19 @@
 totals, the profiler's time line, the sampled causal trace, and the stable
 device names (the three flash kernels, the jitted update)."""
 
-import glob
-import os
+import gc
 import re
+import subprocess
+import sys
 import threading
 import time
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _util import xplane_events
 
 from relayrl_tpu.telemetry import spans as spans_mod
 from relayrl_tpu.telemetry import trace as trace_mod
@@ -28,10 +31,16 @@ LEARNER_NAMES = (
     "rl:batch.stack", "rl:dispatch.enqueue", "rl:dispatch.fence",
     "rl:ingest.decode", "rl:publish", "rl:publish.gather",
     "rl:publish.encode", "rl:publish.send")
-TIMINGS = ("decode_s", "learn_s", "dispatch_s", "device_wait_s",
-           "publish_s", "learner_idle_s", "warmup_s")
+ACTOR_TIMINGS = ("step_s", "infer_s", "record_s", "encode_s", "send_s",
+                 "env_s", "cpu_s", "wall_s", "model_decode_s", "swap_s",
+                 "model_install_s", "gc_s")
+ACTOR_COUNTS = ("steps", "installs")
+TIMINGS = ("decode_s", "dispatch_s", "device_wait_s", "publish_s",
+           "learner_idle_s", "warmup_s", "gc_s",
+           *(f"actor_{k}" for k in ACTOR_TIMINGS))
 STATS = ("trajectories", "updates", "dropped", "dropped_nonfinite",
-         "learner_errors", "publish_errors", "warmup_failed")
+         "learner_errors", "publish_errors", "warmup_failed",
+         *(f"actor_{k}" for k in ACTOR_COUNTS))
 
 
 def _episode(n, seed=0):
@@ -97,26 +106,6 @@ def _run_updates(server, n_updates):
         else:
             server._decoded.put(ep)
     assert server.drain(timeout=120)
-
-
-def _xplane_events(trace_dir):
-    """{name: [(line index, start_ns, duration_ns, stats)]} of the host
-    planes of the newest xplane under ``trace_dir``."""
-    path = max(glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
-                                      "*", "*.xplane.pb")),
-               key=os.path.getmtime)
-    events = {}
-    n = 0
-    for plane in jax.profiler.ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            n += 1
-            for ev in line.events:
-                if ev.name.startswith(("host:", "rl:")):
-                    events.setdefault(ev.name, []).append(
-                        (n, ev.start_ns, ev.duration_ns, dict(ev.stats)))
-    return events
 
 
 class TestPrimitive:
@@ -194,7 +183,7 @@ class TestPrimitive:
                 jax.block_until_ready(jnp.ones(8) * 2)
         finally:
             jax.profiler.stop_trace()
-        (_line, _start, dur, stats), = _xplane_events(tmp_path)[name]
+        (_line, _start, dur, stats), = xplane_events(tmp_path)[name]
         assert dur > 0
         assert stats == {**args, "mono_ns": sp.t0_ns}
 
@@ -218,7 +207,7 @@ class TestBatchSpans:
             batch = buf.drain()
         finally:
             jax.profiler.stop_trace()
-        events = _xplane_events(tmp_path)
+        events = xplane_events(tmp_path)
         assert len(events["rl:batch.pad"]) == 3
         (_line, _start, _dur, stats), = events["rl:batch.stack"]
         assert stats == {
@@ -243,6 +232,17 @@ class TestTracerSink:
         assert rec["hop"] == "encode" and rec["bytes"] == 10
         assert ledger["x_s"] == (rec["t1_ns"] - rec["t0_ns"]) * 1e-9
 
+    def test_hop_after_exit_records_at_once_with_the_spans_stamps(self):
+        """An actor draws a trajectory's trace context after the unroll is
+        encoded: the hop still carries the encode span's own stamps."""
+        with span("rl:test.done") as sp:
+            pass
+        assert trace_mod.snapshot_spans() == []
+        sp.hop("traj", "t-1", "encode", agent="a")
+        rec, = trace_mod.snapshot_spans()
+        assert (rec["t0_ns"], rec["t1_ns"]) == (sp.t0_ns, sp.t1_ns)
+        assert rec["hop"] == "encode" and rec["agent"] == "a"
+
     def test_fence_total_and_ring_span_are_one_interval(self):
         from relayrl_tpu.runtime.pipeline import InflightWindow
 
@@ -251,6 +251,253 @@ class TestTracerSink:
         rec, = [s for s in trace_mod.snapshot_spans() if s["hop"] == "fence"]
         assert rec["version"] == 4 and win.fenced_count == 1
         assert win.device_wait_s == (rec["t1_ns"] - rec["t0_ns"]) * 1e-9
+
+
+ACTOR_NAMES = ("rl:actor.step", "rl:actor.infer", "rl:actor.record",
+               "rl:actor.encode", "rl:actor.send", "rl:actor.model_install",
+               "rl:actor.model_decode", "rl:actor.swap")
+
+
+class FakeClock:
+    """Stands in for the span primitive's clock: a microsecond a stamp, and
+    ``jump`` for what a patched function is to have cost."""
+
+    def __init__(self):
+        self.ns = 1_000_000
+
+    def __call__(self):
+        self.ns += 1_000
+        return self.ns
+
+    def jump(self, seconds):
+        self.ns += int(seconds * 1e9)
+
+
+class ActorRig:
+    """An actor host of either kind over a stub transport: the host's send
+    hook ships through ``agent.ship_unroll`` as ``Agent`` / ``VectorAgent``
+    do, with no spool, so every span of the actor tier runs."""
+
+    LANES = 3
+
+    def __init__(self, tmp_cwd, kind, max_traj_length=4):
+        from relayrl_tpu.algorithms import build_algorithm
+        from relayrl_tpu.runtime.agent import ship_unroll
+        from relayrl_tpu.runtime.policy_actor import PolicyActor
+        from relayrl_tpu.runtime.vector_actor import VectorActorHost
+
+        self.bundle = build_algorithm(
+            "REINFORCE", obs_dim=OBS_DIM, act_dim=ACT_DIM, hidden_sizes=[8],
+            seed_salt=0,
+            logger_kwargs={"output_dir": str(tmp_cwd / "logs")}).bundle()
+        self.sent = []
+        self.transport = types.SimpleNamespace(
+            identity="rig", request_resync=lambda held: None,
+            send_trajectory=lambda payload, agent_id=None:
+                self.sent.append((agent_id, payload)))
+        self.spool = None
+        self.model_path = str(tmp_cwd / "client.rlx")
+        if kind == "vector":
+            self.lanes = self.LANES
+            self.host = host = VectorActorHost(
+                self.bundle, num_envs=self.lanes,
+                max_traj_length=max_traj_length,
+                on_send=lambda lane, payload: ship_unroll(
+                    self, f"rig.lane{lane}", payload, host.shipping(lane),
+                    host.version, host.ledger))
+            self.step = lambda: host.request_for_actions(
+                np.zeros((self.lanes, OBS_DIM), np.float32))
+            self.finish = lambda: host.flag_last_action(0, 1.0)
+        else:
+            self.lanes = 1
+            self.host = host = PolicyActor(
+                self.bundle, max_traj_length=max_traj_length,
+                on_send=lambda payload: ship_unroll(
+                    self, "rig", payload,
+                    (host.trajectory.born_ns, host.trajectory.encode_span),
+                    host.version, host.ledger))
+            self.step = lambda: host.request_for_action(
+                np.zeros(OBS_DIM, np.float32))
+            self.finish = lambda: host.flag_last_action(1.0)
+
+    def deliver_next_model(self):
+        from relayrl_tpu.runtime.agent import _deliver_model
+        from relayrl_tpu.types.model_bundle import ModelBundle
+
+        newer = ModelBundle(version=self.host.version + 1,
+                            arch=self.bundle.arch, params=self.bundle.params)
+        _deliver_model(self.host, self.transport, self.model_path, "rig",
+                       newer.version, newer.to_bytes())
+
+
+@pytest.mark.parametrize("kind", ["vector", "policy"])
+class TestActorSpans:
+    def test_a_stepped_host_fills_every_key_of_its_ledger(self, tmp_cwd,
+                                                          kind):
+        from relayrl_tpu.telemetry.actor_ledger import COUNTS, TIMINGS
+
+        rig = ActorRig(tmp_cwd, kind)
+        t, counts = rig.host.timings, rig.host.counts
+        assert tuple(t) == TIMINGS == ACTOR_TIMINGS
+        assert tuple(counts) == COUNTS == ACTOR_COUNTS
+        assert not any(t.values()) and not any(counts.values())
+        for _ in range(9):     # unrolls of 4: two capacity flushes a lane
+            rig.step()
+        rig.finish()           # a terminal marker ships outside a request
+        rig.deliver_next_model()
+        gc.collect()
+        for key in TIMINGS:
+            assert t[key] > 0, key
+        assert counts == {"steps": 9 * rig.lanes, "installs": 1}
+        assert len(rig.sent) == 2 * rig.lanes + 1
+        # the ledger's own span of time, and what a step nests
+        assert t["step_s"] + t["env_s"] == pytest.approx(t["wall_s"],
+                                                         rel=1e-9)
+        assert (t["infer_s"] + t["record_s"] + t["encode_s"] + t["send_s"]
+                <= t["step_s"])
+        assert t["model_decode_s"] + t["swap_s"] <= t["model_install_s"]
+
+    def test_wall_is_step_plus_env_to_the_stamp_and_record_is_self_time(
+            self, tmp_cwd, kind, monkeypatch):
+        """On a clock the test owns: an encode and a send made to cost a
+        thousand seconds each land in ``encode_s`` / ``send_s`` and in the
+        step that nests them, never in ``record_s``; a pause between two
+        steps is the environment's."""
+        from relayrl_tpu.types.trajectory import Trajectory
+
+        clock = FakeClock()
+        monkeypatch.setattr(spans_mod, "_monotonic_ns", clock)
+        rig = ActorRig(tmp_cwd, kind)
+        real_to_bytes = Trajectory.to_bytes
+
+        def slow_to_bytes(traj):
+            clock.jump(1000.0)
+            return real_to_bytes(traj)
+
+        def slow_send(payload, agent_id=None):
+            clock.jump(1000.0)
+            rig.sent.append((agent_id, payload))
+
+        monkeypatch.setattr(Trajectory, "to_bytes", slow_to_bytes)
+        rig.transport.send_trajectory = slow_send
+        for i in range(9):
+            rig.step()
+            clock.jump(10.0)   # the caller's environment
+        t = rig.host.timings
+        flushes = 2 * rig.lanes
+        assert len(rig.sent) == flushes
+        assert 1000.0 * flushes <= t["encode_s"] < 1000.0 * flushes + 1
+        assert 1000.0 * flushes <= t["send_s"] < 1000.0 * flushes + 1
+        assert 0 < t["record_s"] < 1          # stamps only: microseconds
+        assert 0 < t["infer_s"] < 1
+        assert 80.0 <= t["env_s"] < 81        # 8 pauses between 9 steps
+        assert t["step_s"] >= t["encode_s"] + t["send_s"]
+        assert t["step_s"] + t["env_s"] == pytest.approx(t["wall_s"],
+                                                         rel=1e-12)
+        assert (t["infer_s"] + t["record_s"] + t["encode_s"] + t["send_s"]
+                <= t["step_s"])
+
+    def test_report_carries_deltas_once(self, tmp_cwd, kind):
+        """Every shipment's ``#r`` tag holds the ledger's growth since the
+        previous one: the reports of a run sum to the ledger, to the
+        microsecond a report rounds to."""
+        from relayrl_tpu.telemetry.actor_ledger import decode_report
+        from relayrl_tpu.transport.base import split_agent_tags
+
+        rig = ActorRig(tmp_cwd, kind)
+        for _ in range(9):
+            rig.step()
+        rig.finish()
+        reports = []
+        for wire_id, _payload in rig.sent:
+            clean, seq, trace, text = split_agent_tags(wire_id)
+            assert clean.startswith("rig") and "#" not in clean
+            assert seq is None and trace is None
+            reports.append(decode_report(text))
+        assert all(r.born_ns > 0 and r.born_version == rig.host.version
+                   for r in reports)
+        assert sum(r.counts["steps"] for r in reports) == 9 * rig.lanes
+        final = decode_report(rig.host.ledger.report(0, 0))
+        for key in ("infer_s", "encode_s", "env_s"):
+            total = sum(r.timings[key] for r in reports) + final.timings[key]
+            assert total == pytest.approx(rig.host.timings[key], abs=2e-6)
+
+    def test_profiler_on_names_and_arguments(self, tmp_cwd, tmp_path, kind):
+        rig = ActorRig(tmp_cwd, kind)
+        rig.step()             # compile outside the trace
+        jax.profiler.start_trace(str(tmp_path / "trace"))
+        try:
+            for _ in range(8):
+                rig.step()
+            rig.deliver_next_model()
+            gc.collect()
+        finally:
+            jax.profiler.stop_trace()
+        events = xplane_events(tmp_path / "trace")
+        for name in ACTOR_NAMES:
+            assert name in events, (name, sorted(events))
+        assert len(events["rl:actor.step"]) == 8
+        assert len(events["rl:actor.infer"]) == 8
+        assert len(events["rl:actor.encode"]) == 2 * rig.lanes
+        assert len(events["rl:actor.send"]) == 2 * rig.lanes
+        (_l, _s, _d, swap), = events["rl:actor.swap"]
+        assert swap == {"version": rig.host.version}
+        (_l, _s, _d, decode), = events["rl:actor.model_decode"]
+        assert decode["bytes"] > 0
+        collections = [st for *_x, st in events["rl:gc"]]
+        assert collections and all(
+            st["generation"] == 2 and st["collected"] >= 0
+            for st in collections)
+        # nesting: a step holds its infer, record, and the flush's two
+        step_line = {e[0] for e in events["rl:actor.step"]}
+        for name in ("rl:actor.infer", "rl:actor.record", "rl:actor.encode",
+                     "rl:actor.send"):
+            assert {e[0] for e in events[name]} == step_line, name
+
+
+class TestGcAndThinClients:
+    def test_gc_hook_names_full_collections_only(self):
+        class Owner:
+            def __init__(self, timings):
+                self.timings = timings
+
+        timings = {"gc_s": 0.0}
+        owner = Owner(timings)
+        spans_mod.watch_gc(owner)
+        spans_mod.watch_gc()   # idempotent: one hook a process
+        assert gc.callbacks.count(spans_mod._on_gc) == 1
+        gc.collect(0)
+        gc.collect(1)
+        assert timings["gc_s"] == 0.0
+        gc.collect()
+        first = timings["gc_s"]
+        assert first > 0
+        del owner              # a ledger is fed for as long as its owner lives
+        gc.collect()
+        assert timings["gc_s"] == first
+
+    def test_a_process_without_jax_builds_no_annotation_and_imports_none(
+            self):
+        code = (
+            "import sys\n"
+            "from relayrl_tpu.telemetry import spans\n"
+            "from relayrl_tpu.telemetry.actor_ledger import ActorLedger\n"
+            "ledger = ActorLedger()\n"
+            "with ledger.step(2):\n"
+            "    with spans.span('rl:actor.infer', ledger.timings,\n"
+            "                    'infer_s', version=3) as sp:\n"
+            "        sp.note(bytes=1)\n"
+            "    with ledger.record():\n"
+            "        pass\n"
+            "assert sp._ann is None and spans._annotation is None\n"
+            "assert ledger.counts['steps'] == 2\n"
+            "assert ledger.timings['infer_s'] > 0\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n"
+            "print('JAXLESS_OK')\n")
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert "JAXLESS_OK" in out.stdout
 
 
 class TestLearnerSpans:
@@ -263,11 +510,12 @@ class TestLearnerSpans:
         # ledgers hold the keys they held and no other
         assert sorted(t) == sorted(TIMINGS) and sorted(st) == sorted(STATS)
         assert not hasattr(server.algorithm, "timings")
-        for key in ("learn_s", "dispatch_s", "learner_idle_s", "warmup_s",
-                    "decode_s", "publish_s"):
+        for key in ("dispatch_s", "learner_idle_s", "warmup_s", "decode_s",
+                    "publish_s"):
             assert t[key] > 0, key
-        # the nesting the totals stand for
-        assert t["dispatch_s"] <= t["learn_s"]
+        # no actor reported: raw payloads and queue items carry no tag
+        assert not any(t[k] for k in t if k.startswith("actor_"))
+        assert not any(st[k] for k in st if k.startswith("actor_"))
         assert t["device_wait_s"] == server.algorithm.inflight.device_wait_s
         assert stub.published
 
@@ -278,7 +526,7 @@ class TestLearnerSpans:
             _run_updates(server, 2)
         finally:
             jax.profiler.stop_trace()
-        events = _xplane_events(tmp_path / "trace")
+        events = xplane_events(tmp_path / "trace")
         for name in LEARNER_NAMES:
             assert name in events, (name, sorted(events))
         dispatches = events["host:dispatch"]

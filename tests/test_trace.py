@@ -450,6 +450,249 @@ def test_trace_tag_survives_native_columnar_raw_fallback():
     assert clean == "lane.7" and got.born_ns == 456
 
 
+# -- the actors' report tag (ISSUE 52) --------------------------------------
+
+REPORT_BORN_NS, REPORT_VERSION = 0x5EED, 9
+
+
+def _report_text(born_ns=REPORT_BORN_NS):
+    """A report whose deltas are told apart by key: 6 steps, 1 install,
+    timing ``i`` worth ``i + 1`` milliseconds."""
+    from relayrl_tpu.telemetry.actor_ledger import TIMINGS, encode_report
+
+    return encode_report(born_ns, REPORT_VERSION,
+                         [6, 1] + [1000 * (i + 1)
+                                   for i in range(len(TIMINGS))])
+
+
+def _tag_orders():
+    """Every order the spool and the tracer can apply the three tags in
+    (the spool's own is report, trace, seq), with and without each."""
+    import itertools
+
+    for n in (1, 2, 3):
+        for order in itertools.permutations("rts", n):
+            if "r" in order:
+                yield "".join(order)
+
+
+def _tagged(agent, order, seq, ctx):
+    from relayrl_tpu.transport.base import tag_agent_report
+
+    wire = agent
+    for tag in order:
+        if tag == "r":
+            # one born stamp on the wire: beside a trace context the
+            # report carries none
+            wire = tag_agent_report(
+                wire, _report_text(0 if "t" in order else REPORT_BORN_NS))
+        elif tag == "t":
+            wire = tag_agent_trace(wire, ctx.encode())
+        else:
+            wire = tag_agent_seq(wire, seq)
+    return wire
+
+
+class _NullTransport:
+    on_trajectory = on_trajectory_decoded = None
+    get_model = on_register = on_unregister = None
+
+    def start(self):
+        pass
+
+    def stop(self):
+        pass
+
+    def publish_model(self, version, raw):
+        pass
+
+
+@pytest.fixture
+def quiet_server(tmp_path, monkeypatch):
+    """A TrainingServer that is never started: what its ingest funnel
+    admits stays in its queues for the test to read."""
+    import relayrl_tpu.runtime.server as srv_mod
+
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(srv_mod, "make_server_transport",
+                        lambda *a, **k: _NullTransport())
+    server = srv_mod.TrainingServer(
+        "REINFORCE", obs_dim=4, act_dim=2, env_dir=str(tmp_path),
+        hyperparams={"traj_per_epoch": 2, "hidden_sizes": [8],
+                     "seed_salt": 0}, start=False)
+    yield server
+    server.disable_server()
+
+
+def _native_or_skip():
+    from relayrl_tpu.types.columnar import native_codec_available
+
+    if not native_codec_available():
+        pytest.skip("native codec not built")
+
+
+def _columnar_frame():
+    import numpy as np
+
+    from relayrl_tpu.types.columnar import (
+        DecodedTrajectory,
+        encode_columnar_frame,
+    )
+
+    return encode_columnar_frame(DecodedTrajectory(
+        agent_id="", n_steps=2, n_records=3, marker_truncated=False,
+        columns={"o": np.zeros((2, 4), np.float32),
+                 "a": np.zeros(2, np.int64), "r": np.ones(2, np.float32),
+                 "t": np.array([0, 1], np.uint8),
+                 "u": np.array([1, 0], np.uint8),
+                 "x": np.zeros(2, np.uint8)},
+        aux={}))
+
+
+def _deliver(server, carriage, wire_id):
+    """One envelope through ``carriage``'s receive path into the server's
+    funnel; returns ``(clean id, origin)`` of what was admitted, or None."""
+    import numpy as np
+
+    from relayrl_tpu.transport.base import (
+        pack_trajectory_envelope,
+        unpack_trajectory_envelope,
+    )
+    from relayrl_tpu.types.action import ActionRecord
+    from relayrl_tpu.types.columnar import NativeDecoder, RawTrajectory
+    from relayrl_tpu.types.trajectory import serialize_actions
+
+    records = serialize_actions([ActionRecord(
+        obs=np.zeros(4, np.float32), act=np.int64(0), rew=1.0, done=True)])
+    if carriage in ("zmq", "grpc"):
+        # both unpack the shared envelope in Python; grpc asks for an
+        # admission verdict first
+        got_id, payload = unpack_trajectory_envelope(
+            pack_trajectory_envelope(wire_id, records))
+        if carriage == "grpc":
+            assert server._check_ingest(got_id) is None
+        server._on_trajectory(got_id, payload)
+    elif carriage == "native":
+        # the C++ core decodes the envelope and hands a decoded batch over
+        out = NativeDecoder().decode(
+            pack_trajectory_envelope(wire_id, _columnar_frame()),
+            has_envelope=True)
+        assert out.agent_id == wire_id
+        if isinstance(out, RawTrajectory):  # as native_bindings routes it
+            from relayrl_tpu.types.columnar import parse_frame
+
+            out = parse_frame(out.payload, agent_id=out.agent_id)
+        server._on_trajectory_decoded([out])
+    else:  # native_raw: what the columnar schema cannot hold, id verbatim
+        out = NativeDecoder().decode(
+            pack_trajectory_envelope(wire_id, b"\x00not-a-trajectory"),
+            has_envelope=True)
+        assert isinstance(out, RawTrajectory) and out.agent_id == wire_id
+        server._on_trajectory(out.agent_id, records)
+    if carriage == "native":
+        if server._decoded.empty():
+            return None
+        item = server._decoded.get_nowait()
+        item = item[0] if isinstance(item, list) else item
+        return item.agent_id, getattr(item, "trace_ctx", None)
+    if server._ingest.empty():
+        return None
+    agent_id, _seq, ctx, _payload = server._ingest.get_nowait()
+    return agent_id, ctx
+
+
+@pytest.mark.parametrize("carriage", ["zmq", "grpc", "native", "native_raw"])
+def test_report_tag_survives_every_carriage_and_counts_once(quiet_server,
+                                                            carriage):
+    """The ``#r`` tag beside ``#t`` and ``#s`` in every order of
+    application, through each transport's receive path: stripped from the
+    attribution and dedup keys, its deltas added to the server's ledgers
+    for an ADMITTED envelope only, the born stamp kept on what is admitted;
+    an untagged id admits as before."""
+    from relayrl_tpu.telemetry.actor_ledger import TIMINGS
+
+    if carriage.startswith("native"):
+        _native_or_skip()
+    server = quiet_server
+    ctx = trace.TrajCtx("ab-7", 0x7777, 4)
+    admitted = 0
+    for seq, order in enumerate(_tag_orders(), start=1):
+        wire = _tagged("fleet.lane2", order, seq, ctx)
+        clean, origin = _deliver(server, carriage, wire)
+        admitted += 1
+        assert clean == "fleet.lane2", (order, wire)
+        if "t" in order:
+            assert (origin.trace_id, origin.born_ns) == ("ab-7", 0x7777)
+        else:
+            assert origin.trace_id is None
+            assert (origin.born_ns, origin.born_version) == (
+                REPORT_BORN_NS, REPORT_VERSION)
+        assert server.stats["actor_steps"] == 6 * admitted
+        assert server.stats["actor_installs"] == admitted
+        for i, key in enumerate(TIMINGS):
+            assert server.timings[f"actor_{key}"] == pytest.approx(
+                admitted * (i + 1) * 1e-3), (order, key)
+        if "s" in order:
+            # a spool replay re-sends the tagged id: the dedup ledger's
+            # verdict keeps it out of the queues AND out of the ledgers —
+            # keyed by the clean id, whatever the other tags say
+            for again in (wire, _tagged("fleet.lane2", "rs", seq, ctx)):
+                assert _deliver(server, carriage, again) is None
+            assert server.stats["actor_steps"] == 6 * admitted
+            assert server.timings["actor_step_s"] == pytest.approx(
+                admitted * 1e-3)
+    # a raw transport user, an older actor: no tag, admitted, no origin
+    before = dict(server.timings)
+    clean, origin = _deliver(server, carriage, "plain-agent")
+    assert (clean, origin) == ("plain-agent", None)
+    clean, origin = _deliver(server, carriage,
+                             tag_agent_seq("plain-agent", 1))
+    assert (clean, origin) == ("plain-agent", None)
+    assert server.timings == before
+    # a report this build cannot read is stripped all the same
+    clean, origin = _deliver(server, carriage, "fleet.lane2#r9.1.2.3")
+    assert (clean, origin) == ("fleet.lane2", None)
+    assert server.timings == before
+
+
+def test_report_tag_never_reaches_the_quarantine_key(quiet_server):
+    """An ack-capable transport asks ``_check_ingest`` with the tagged id:
+    the verdict is the clean agent's."""
+    from relayrl_tpu.transport.base import NACK_QUARANTINED
+
+    server = quiet_server
+    book = server.guardrails.quarantine
+    while not book.is_quarantined("fleet.lane2"):
+        book.strike("fleet.lane2", "test")
+    ctx = trace.TrajCtx("ab-8", 1, 1)
+    for order in _tag_orders():
+        verdict = server._check_ingest(_tagged("fleet.lane2", order, 5, ctx))
+        assert verdict is not None and verdict[0] == NACK_QUARANTINED, order
+        assert server._check_ingest(
+            _tagged("fleet.lane3", order, 5, ctx)) is None
+
+
+def test_spool_and_relay_key_by_the_clean_id(tmp_path):
+    from relayrl_tpu.runtime.spool import TrajectorySpool
+    from relayrl_tpu.transport.base import split_agent_tags
+
+    sent = []
+    spool = TrajectorySpool(send_fn=lambda p, i: sent.append(i),
+                            max_entries=16, directory=str(tmp_path),
+                            name="s")
+    ctx = trace.TrajCtx("cc-9", 5, 1)
+    spool.send(b"x", "agent", trace=ctx.encode(), report=_report_text(0))
+    spool.send(b"y", "agent", report=_report_text())
+    assert sent[0] == f"agent#r{_report_text(0)}#t{ctx.encode()}#s1"
+    assert sent[1] == f"agent#r{_report_text()}#s2"
+    assert [split_agent_tags(i)[:2] for i in sent] == [("agent", 1),
+                                                       ("agent", 2)]
+    spool.close()
+    fresh = TrajectorySpool(send_fn=None, max_entries=16,
+                            directory=str(tmp_path), name="s")
+    assert fresh.next_seq("agent") == 3   # not a tagged id's seq space
+
+
 # -- histogram bucket audit (satellite) ------------------------------------
 
 def test_log_bucket_presets():
@@ -556,3 +799,76 @@ def test_live_zmq_end_to_end_trace(tmp_path, capsys):
     hist_mean = lag_hist["sum"] / lag_hist["count"]
     trace_mean = report["trajectories"]["data_age_versions"]["mean"]
     assert abs(trace_mean - hist_mean) <= 0.5
+
+
+def test_live_zmq_actor_report_and_data_age(tmp_path):
+    """No tracer, one actor over LIVE zmq: the steps the actor took arrive
+    in ``server.stats["actor_steps"]`` on the reports its trajectories
+    carry, its ledger's time in ``server.timings``, and every update's
+    ``host:dispatch`` span says how old the batch it consumed was."""
+    import jax
+    from _util import xplane_events
+
+    from relayrl_tpu.envs import make
+    from relayrl_tpu.runtime.agent import Agent, run_gym_loop
+    from relayrl_tpu.runtime.server import TrainingServer
+
+    telemetry.set_registry(Registry(run_id="report"))
+    addrs = {
+        "agent_listener_addr": f"tcp://127.0.0.1:{_free_port()}",
+        "trajectory_addr": f"tcp://127.0.0.1:{_free_port()}",
+        "model_pub_addr": f"tcp://127.0.0.1:{_free_port()}",
+    }
+    server = TrainingServer(
+        "REINFORCE", obs_dim=4, act_dim=2,
+        hyperparams={"traj_per_epoch": 2, "seed_salt": 0},
+        config_path=str(tmp_path / "relayrl_config.json"),
+        env_dir=str(tmp_path), server_type="zmq", **addrs)
+    server.wait_warmup(60)
+    agent = Agent(server_type="zmq", seed=3,
+                  model_path=str(tmp_path / "client.rlx"),
+                  config_path=str(tmp_path / "relayrl_config.json"),
+                  agent_listener_addr=addrs["agent_listener_addr"],
+                  trajectory_addr=addrs["trajectory_addr"],
+                  model_sub_addr=addrs["model_pub_addr"])
+    env = make("CartPole-v1")
+    episodes = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        deadline = time.time() + 60
+        while time.time() < deadline and (server.stats["updates"] < 2
+                                          or agent.model_version < 1):
+            run_gym_loop(agent, env, episodes=2, max_steps=40)
+            episodes += 2
+            time.sleep(0.05)
+        while (time.time() < deadline
+               and server.stats["trajectories"] < episodes):
+            time.sleep(0.05)
+        server.drain(30)
+    finally:
+        jax.profiler.stop_trace()
+    ledger = agent.actor.ledger
+    stats, timings = dict(server.stats), dict(server.timings)
+    installs = ledger.counts["installs"]
+    agent.disable_agent()
+    server.disable_server()
+
+    assert stats["trajectories"] == episodes and stats["updates"] >= 2
+    # every episode ends on a terminal marker, shipped between two steps:
+    # each report holds every step taken before it
+    assert stats["actor_steps"] == ledger.counts["steps"] > 0
+    assert 0 < timings["actor_step_s"] <= ledger.timings["step_s"]
+    assert 0 < timings["actor_infer_s"] <= timings["actor_step_s"]
+    assert timings["actor_wall_s"] == pytest.approx(
+        timings["actor_step_s"] + timings["actor_env_s"], abs=1e-5)
+    assert installs >= 1 and stats["actor_installs"] <= installs
+    snap = telemetry.get_registry().snapshot()
+    age = next(m for m in snap["metrics"]
+               if m["name"] == "relayrl_trace_data_age_seconds")
+    assert age["count"] == episodes
+    events = xplane_events(tmp_path / "trace")
+    dispatches = [st for *_x, st in events["host:dispatch"]]
+    assert len(dispatches) >= 2
+    for st in dispatches:
+        assert 0 < st["data_age_us"] <= st["data_age_max_us"] < 60e6
+
