@@ -511,7 +511,7 @@ def differ(a, b) -> float:
 
 
 def phase_b_kernels() -> None:
-    """The three flash kernels alone at the shapes the benchmark's
+    """The two flash kernels alone at the shapes the benchmark's
     transformer cells run — (B*H, T, D) = (128, 1024, 64), one block a head
     walked in causal strips, (64, 4096, 128), a 4 x 4 grid a head with
     strips on its diagonal, (64, 8192, 64) grouped, 32 q heads over 8
@@ -594,7 +594,7 @@ def phase_b_kernels() -> None:
                     f"{flash.tiling(T)} score area "
                     f"{area:g}% "
                     f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}")
-    say(f"B\": ok — flash fwd / dq / dkv kernels against XLA attention in "
+    say(f"B\": ok — flash fwd / bwd kernels against XLA attention in "
         f"float32: {'; '.join(said)}, {time.monotonic() - t0:.0f}s")
 
 

@@ -1,4 +1,4 @@
-"""Fused flash-attention Pallas TPU kernels (forward + two-pass VJP).
+"""Fused flash-attention Pallas TPU kernels (forward + a one-kernel VJP).
 
 The reference has no attention at all (SURVEY.md §5.7 — its largest model
 is a 2x128 MLP, relayrl_framework/src/native/python/algorithms/REINFORCE/
@@ -44,9 +44,9 @@ happen — index maps are static — but the matmuls are skipped).
 The skip also happens INSIDE a grid step. A block that straddles the
 diagonal (with T = block — one 1024 x 1024 tile a head — that is the
 whole grid) is walked in causal strips, static slices of the refs already
-in VMEM: the forward and dq kernels take ``sub`` query rows at a time
-against the keys at or before them, the dk/dv kernel ``sub`` keys at a
-time against the queries at or after them. What lies above the diagonal
+in VMEM: the forward takes ``sub`` query rows at a time against the keys at
+or before them, the backward ``sub`` keys at a time against the queries at
+or after them. What lies above the diagonal
 beyond a strip's own ``sub x sub`` corner is never computed — no matmul,
 no exp2, no mask — and the mask is built for the strip, not the block.
 ``score_area_pct`` says how much of the T x T score matrix that leaves
@@ -59,12 +59,13 @@ Sliding-window calls (``window``: query ``t`` sees keys ``s`` with ``t -
 window < s <= t``) do not walk the sequence at all: **the innermost grid
 axis is as long as the band**, ``window / block + 1`` steps for a window of
 whole blocks (5 of 16 at T 16384, block 1024, window 4096), and the index
-maps offset it by the outer block (``_band_step``) — forward and dq visit
-K/V blocks ``i - (nband - 1) .. i`` of q block ``i``, dk/dv the q blocks
-``j .. j + nband - 1`` of K/V block ``j`` (for every q head of the group).
+maps offset it by the outer block (``_band_step``) — the forward visits
+K/V blocks ``i - (nband - 1) .. i`` of q block ``i``, the backward the q
+blocks ``j .. j + nband - 1`` of K/V block ``j`` (for every q head of the
+group).
 A block outside the band is therefore neither loaded nor computed, where
 the causal call's blocks above the diagonal are predicated off but still
-loaded. A step that falls before block 0 (past the last, in dk/dv)
+loaded. A step that falls before block 0 (past the last, in the backward)
 computes nothing and names the block its neighbour names, so Pallas skips
 its DMA too. Inside the band a block is the diagonal one (causal strips, as
 above), wholly inside the window (mask-free), or cut by the window's lower
@@ -85,30 +86,47 @@ Measured on the v5e (PR 34, PERF.md section 6): a windowed layer of
 time for 0.44 of its scores; the strip height of the cut block is the
 diagonal's and was not swept on its own.
 
-The backward pass is two more Pallas kernels (the standard two-pass flash
-VJP — no atomics or cross-block communication): a dq pass (grid q-major,
-KV innermost, accumulator in VMEM) and a dk/dv pass (grid kv-major, Q
-innermost), both recomputing p from the saved log-sum-exp residual and
-using the identity ``ds = p * (dp - rowsum(do * o))``. Peak memory stays
-O(T * block). The dk/dv pass works in transposed space — scores as
-``[keys, queries]``, ``k @ q^T`` — so that ``dv = p^T @ do`` and
-``dk = ds^T @ q`` are plain matmuls: the MXU streams the long key axis and
-nothing of score-tile size goes through a transpose.
+The backward pass is ONE more Pallas kernel (``relayrl_flash_bwd``): it
+walks the score tiles once, in transposed space — scores as ``[keys,
+queries]``, ``k @ q^T`` — recomputes ``p^T`` from the saved log-sum-exp,
+makes ``ds^T = p^T * (dp^T - rowsum(do * o))`` and takes all three
+gradients from the tile it holds: ``dv += p^T @ do`` and ``dk += ds^T @ q``
+are plain matmuls (the MXU streams the long key axis and nothing of
+score-tile size goes through a transpose) and ``dq += ds @ k`` contracts
+dimension 0 of ``ds^T`` and ``k``, a turn Mosaic hides under the MXU — five
+matmuls, one ``exp2`` pass and one mask a tile, where a dq pass and a dk/dv
+pass made seven, two and two and walked q / k / v / do twice (PERF.md §6,
+PR 53: 0.68-0.72 of the two kernels' time at every benchmark shape). Grid
+``(k/v steps, q steps of a k/v step's group, K/V blocks, q blocks)``: the
+sums live in float32 VMEM scratch over ALL of T — a q head's ``dq [T,
+lanes]``, summed over the K/V blocks, and the k/v head's ``dk`` / ``dv [T,
+lanes]``, summed over the q blocks and, the q head lying outside the K/V
+blocks, over the group (a group's whole ``dq`` would not fit: 59 MB at 7
+heads of T 16384) — 3 x T x lanes x 4 B, 24 MB at (16384, 128) and at
+(8192, 256), under a ``vmem_limit_bytes`` of 64 MB (``_build_bwd`` refuses a
+T x lanes past 4 Mi); nothing of them is ever a partial in HBM. An output
+block leaves VMEM when its NAME changes, so the index maps name a block
+from the step that completes it until the next one is complete
+(``_dq_complete``: q block ``j`` at its diagonal step, or every q block
+beside the last K/V block where no diagonal runs corner to corner; dk / dv
+beside the group's last q step), and each is written once.
 
-The per-query float32 residuals (LSE out of the forward, delta =
-rowsum(do * o) a head) live in HBM as lane-dense rows
-``[BH, num_q_blocks, 1, block_q]``, one row a head, the heads of a grid
-step adjacent: as ``[BH, T, 1]`` columns the same numbers take 128 x
-their bytes in tiled memory — in every kernel's DMA and in the residuals a
-training step keeps (1.6 GB in ``gpt2m-policy.update``). The dk/dv pass
-reads the rows as they are; the forward and dq kernels, which need them
-down the sublanes, turn a row in VMEM. **delta is the dq kernel's own**:
-it holds the do block already, takes the out block beside it, sums each
-head's lanes of ``do * o`` into the column it subtracts, and writes the
-same numbers out as rows for the dk/dv pass — XLA never sees a
-``[B, T, H, D]``-shaped reduction (in the lane layout it turned the
-float32 product T-minor to make it: 0.78 ms of copy and 2.2 ms of
-convert-multiply an update in ``gpt2m-policy.update``, PERF.md §6 PR 32).
+The per-query float32 residual (the forward's LSE) lives in HBM as
+lane-dense rows ``[BH, num_q_blocks, 1, block_q]``, one row a head, the
+heads of a grid step adjacent: as ``[BH, T, 1]`` columns the same numbers
+take 128 x their bytes in tiled memory — in every kernel's DMA and in the
+residuals a training step keeps (1.6 GB in ``gpt2m-policy.update``). The
+backward reads the rows as they are; the forward, which needs them down
+the sublanes, turns a row in VMEM. **delta = rowsum(do * o) never leaves
+VMEM**: the step at which a q head first holds a q block
+(``_first_visit``: beside K/V block 0, or where the block enters the band)
+takes the out block beside the do block it holds anyway, sums each head's
+lanes of ``do * o`` and keeps the row in scratch for the block's later
+steps, which name the out block they were last given and so load nothing —
+XLA never sees a ``[B, T, H, D]``-shaped reduction (in the lane layout it
+turned the float32 product T-minor to make it: 0.78 ms of copy and 2.2 ms
+of convert-multiply an update in ``gpt2m-policy.update``, PERF.md §6 PR
+32), and no second kernel makes it.
 
 VPU economy (at head_dim 64 the contraction is 128 deep with half of it
 zeros — the other head's lanes — so the two block matmuls do half the
@@ -140,26 +158,25 @@ was not re-swept (a finer grid pays ~0.35 us a step, 16 x the steps).
 Grouped-query attention: ``k`` / ``v`` may carry fewer heads than ``q``
 (``H = G * Hkv``; q head ``j`` reads k/v head ``j // G``). k and v reach
 the kernels as they are — never repeated in HBM, forward or backward — and
-only the index maps change: in the forward and dq grids (one step a q
-head, or a pair) the k/v blocks are those of flat step ``g // G``
-(``_kv_head``); the dk/dv grid has one row a k/v step and its innermost
-axis walks the ``G`` q steps of the group, all their q blocks one after
-another (``_group_step``: step ``i`` is q step ``g * G + i // nq``, q block
-``i % nq``), so that dk and dv are summed over the group in the kernel's
-own accumulators and written once, while the k/v block stays where it is.
-With ``G == 1`` both helpers return their arguments: the index maps, grids
-and kernel bodies are those of plain multi-head attention. Two heads a
+only the index maps change: in the forward grid (one step a q head, or a
+pair) the k/v blocks are those of flat step ``g // G`` (``_kv_head``:
+``g`` itself at ``G == 1``); the backward grid has one row a k/v step, and
+its second axis walks the ``G`` q steps of the group — q step ``g * G + t``
+— each over all the K/V blocks, so that dk and dv are summed over the group
+in the kernel's own accumulators and written once, beside the group's last
+q step. With ``G == 1`` that axis has one step and the grid, index maps
+and kernel body are those of plain multi-head attention. Two heads a
 step and grouped (head_dim 64, ``G`` even): the q pair shares ONE k/v head,
 which sits in one half of a 128-lane k/v block; ``_shared_kv`` copies that
 half into both (a lane roll by 64 in VMEM, once a grid step) so that each
-q head finds it in its own lanes, and the dk/dv kernel keeps one
+q head finds it in its own lanes, and the backward keeps one dk / dv
 accumulator a k/v head of the block, whose two halves — the sums over the
 even and the odd q heads — are added at the end.
 
 The kernels' trace is shared by the repeats of a call (``_make_flash``). A
 ``pallas_call`` traces its body to a jaxpr and lowers it to Mosaic every
 time it is called, two heads a step about doubles a body, and a trunk makes
-the same three calls a layer: the first attention call of a trace runs the
+the same two calls a layer: the first attention call of a trace runs the
 builders' calls bare and every repeat in that trace runs them through one
 inner ``jit`` each, so that a 24-layer trunk traces and lowers two bodies a
 kernel, not 24, and a model with one attention layer never meets the inner
@@ -175,6 +192,7 @@ input dtype.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -183,8 +201,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from relayrl_tpu.ops.scopes import (  # noqa: F401  (re-exported)
-    DKV_NAME,
-    DQ_NAME,
+    BWD_NAME,
     FWD_NAME,
     OP_PROJ,
     WINDOW_SUFFIX,
@@ -199,7 +216,7 @@ LSE_NAME = "flash_fwd_lse"
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
 
-# The three kernels' names in a profiler trace and in the lowered program
+# The two kernels' names in a profiler trace and in the lowered program
 # (``ops/scopes.py`` holds them): each ``pallas_call`` carries its name and
 # sits in a ``jax.named_scope`` of the same name, so a reduction finds it
 # whatever flax scope called it. What this module does round the kernels —
@@ -208,7 +225,7 @@ _LOG2E = 1.4426950408889634
 
 
 # Rows of a causal strip: a grid step on the diagonal is walked ``_SUB_TILE``
-# queries (forward, dq) or keys (dk/dv) at a time. Measured, not swept
+# queries (forward) or keys (backward) at a time. Measured, not swept
 # finer than {128, 256, 512} (PERF.md §6, PR 30). Module-level so that a
 # test can scale the same derivation down to interpreter-sized blocks; not
 # a knob of the program.
@@ -318,17 +335,9 @@ def _kv_head(b, group: int):
     return b if group == 1 else b // group
 
 
-def _group_step(b, i, group: int, nq: int):
-    """dk/dv grid: K/V head ``b``, innermost step ``i`` -> (flat q head,
-    q block). The axis holds ``group * nq`` steps: the q blocks of the
-    group's first q head, then its second's... (A windowed call's axis
-    holds the ``nq = nband`` blocks of the band a head, and the q block
-    returned counts from the band's first: ``_band_step`` places it.)"""
-    return (b, i) if group == 1 else (b * group + i // nq, i % nq)
-
-
 _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
 
 
 def _head_lanes(hps: int, D: int):
@@ -389,7 +398,7 @@ def _scores2(q_ref, k_ref, rows, cols, mask, transposed: bool = False,
              lanes=None):
     """Log2-space scores of query rows ``rows`` against key rows ``cols``
     of the current block pair (``[keys, queries]`` when transposed) — the
-    recompute shared by the forward and both backward kernels. q arrives
+    recompute shared by the forward and the backward kernel. q arrives
     pre-scaled by ``log2(e)/sqrt(D)`` so no per-tile multiply is needed.
     Inputs stay in their storage dtype (bf16 in production): the MXU runs
     bf16 x bf16 -> f32 at full rate, while casting to f32 first would
@@ -416,7 +425,7 @@ def _dispatch(tile, q_start, k_start, causal: bool, block_q: int,
               block_kv: int, sub: int | None, one_block: bool,
               kv_major: bool = False, window: int | None = None,
               in_grid=None):
-    """Shared block-class dispatch for all three kernels: skip blocks
+    """Shared block-class dispatch for both kernels: skip blocks
     strictly above the causal diagonal, run mask-free on ``interior``
     blocks (strictly at-or-below it), and pay the iota/compare/select
     masking only on blocks that straddle the diagonal. ``live`` iff the
@@ -427,7 +436,7 @@ def _dispatch(tile, q_start, k_start, causal: bool, block_q: int,
     ``tile(rows, cols, mask)`` is the kernel's update for query rows
     ``rows`` against key rows ``cols`` of the block pair. A diagonal block
     with a ``sub`` is walked strip by strip (``_strips``); ``kv_major``
-    (the dk/dv pass) takes key strips and wants its masks transposed.
+    (the backward) takes key strips and wants its masks transposed.
     ``one_block``: the grid has one block a head, which is the diagonal one
     — no predicate, and no dead interior body for Mosaic to compile.
 
@@ -490,10 +499,10 @@ def _band_step(outer, inner, nband: int | None, n_blocks: int = 0,
                kv_major: bool = False):
     """A windowed call's innermost grid axis is as long as the band
     (``_band_blocks``), not as the sequence: ``(block, in_grid)`` of step
-    ``inner`` beside outer block ``outer``. Forward and dq (q block
-    outside): K/V blocks ``outer - (nband - 1) .. outer``, in key order;
-    ``kv_major`` (dk/dv, K/V block outside): q blocks ``outer .. outer +
-    nband - 1``. A step that falls before block 0 or past the last block
+    ``inner`` beside outer block ``outer``. Forward (q block outside): K/V
+    blocks ``outer - (nband - 1) .. outer``, in key order; ``kv_major``
+    (the backward, K/V block outside): q blocks ``outer .. outer + nband -
+    1``. A step that falls before block 0 or past the last block
     computes nothing (``in_grid`` false) and names the nearest block of the
     grid, the one its neighbour names, so that nothing new is loaded for
     it. ``nband`` None — no window: the axis is the sequence, every step
@@ -598,8 +607,8 @@ def _row_spec(block_q: int, index_map, hps: int = 1):
     row a head and q block (the two trailing dims are whole, so any block_q
     tiles), the ``hps`` heads of a grid step adjacent. As a ``[BH, T, 1]``
     column the same numbers take 128 x their bytes in tiled HBM (module
-    docstring). The forward and dq kernels, which need them down the
-    sublanes, turn a row in VMEM (``.T``)."""
+    docstring). The forward, which needs them down the sublanes, turns a
+    row in VMEM (``.T``)."""
     return pl.BlockSpec((hps, 1, 1, block_q), index_map)
 
 
@@ -730,113 +739,88 @@ def _from_kernel(x, B: int, H: int, hps: int | None):
     return x.reshape(B, x.shape[1], H, x.shape[2] // H)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
-               delta_ref, *scratch, causal: bool, block_q: int,
-               block_kv: int, sub: int | None, one_block: bool, scale: float,
-               hps: int, D: int, group: int, window: int | None = None,
-               nband: int | None = None):
-    q_start = pl.program_id(1) * block_q
-    kv_block, in_grid = _band_step(pl.program_id(1), pl.program_id(2), nband)
-    k_start = kv_block * block_kv
-    heads = _head_lanes(hps, D)
-    if _shares_kv(hps, group):
-        k_ref, v_ref = _shared_kv(
-            k_ref, v_ref, *scratch[:2],
-            _kv_half(pl.program_id(0), group, hps), D)
-        scratch = scratch[2:]
-
-    def deltas(rows):
-        """delta = rowsum(do * o) a head, as the columns this kernel
-        subtracts; written out as lane-dense rows for the dk/dv pass."""
-        prod = (do_ref[0, rows, :].astype(jnp.float32)
-                * o_ref[0, rows, :].astype(jnp.float32))
-        cols = [jnp.sum(prod if lanes is None else jnp.where(lanes, prod, 0.0),
-                        axis=-1, keepdims=True) for lanes in heads]
-        for h, col in enumerate(cols):
-            delta_ref[h, 0, :, rows] = col.T
-        return cols
-
-    def dq_of(rows, cols, mask, delta):
-        parts = []
-        for h, lanes in enumerate(heads):
-            s = _scores2(q_ref, k_ref, rows, cols, mask, lanes=lanes)
-            p = jnp.exp2(s - lse_ref[h, 0, :, rows].T)    # [rows, cols]
-            dp = jax.lax.dot_general(
-                _head_rows(do_ref, rows, lanes), v_ref[0, cols, :], _NT,
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[h])
-            # every lane of the block; head h's D lanes are ds_h @ k_h
-            parts.append(jax.lax.dot_general(
-                ds.astype(k_ref.dtype), k_ref[0, cols, :], _NN,
-                preferred_element_type=jnp.float32))
-        return _by_head(parts, heads)
-
-    if one_block:  # a tile is its query rows' whole dq: no accumulator
-        def tile(rows, cols, mask):
-            dq_ref[0, rows, :] = (dq_of(rows, cols, mask, deltas(rows))
-                                  * scale).astype(dq_ref.dtype)
-
-        _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
-                  one_block, window=window)
-        return
-
-    acc_ref, delta_col = scratch
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        for h, col in enumerate(deltas(slice(None))):
-            delta_col[h] = col
-
-    def tile(rows, cols, mask):
-        acc_ref[rows] += dq_of(rows, cols, mask,
-                               [delta_col[h, rows] for h in range(hps)])
-
-    _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
-              one_block, window=window, in_grid=in_grid)
-
-    @pl.when(ik == pl.num_programs(2) - 1)
-    def _finalize():
-        # acc holds d/d(q.k) contractions; one [block_q, D] multiply undoes
-        # the score scaling (ds was accumulated in natural space).
-        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+def _first_visit(j, s, nband: int | None, nq: int):
+    """Backward grid, K/V block ``j``, innermost step ``s`` -> ``(first,
+    block)``: whether this is the step at which the q head first holds the
+    step's q block — where its ``dq`` accumulator starts and its ``delta``
+    is made from ``do`` and ``out`` — and the block of ``out`` the step
+    names: the q block on a first visit, and the block the last first visit
+    named on every other step, so that ``out`` is loaded once a q block and
+    not once a step. Without a window every q block is first held beside
+    K/V block 0; in a band (``_band_step``, ``kv_major``) K/V block 0 brings
+    the first ``nband`` q blocks and every later one brings one more, at its
+    last step."""
+    if nband is None:
+        first = j == 0
+        return first, jnp.where(first, s, nq - 1)
+    first = (j == 0) | (s == nband - 1)
+    block = jnp.where(first, j + s, j + nband - 2)
+    return first & (j + s < nq), jnp.minimum(block, nq - 1)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *scratch, causal: bool,
-                block_q: int, block_kv: int, sub: int | None,
-                one_block: bool, hps: int, D: int, group: int = 1,
-                nq: int = 1, window: int | None = None,
+def _dq_complete(j, iq, diagonal: bool, nkv):
+    """Backward grid, K/V block ``j`` beside q block ``iq`` -> ``(done,
+    block)``: whether the q block has its whole ``dq`` once the step has
+    run, and the ``dq`` block the step names. The K/V blocks come in key
+    order, so where the diagonal runs corner to corner (a causal call on
+    equal blocks; every windowed call) q block ``j`` is complete at its
+    diagonal step and the whole row of steps beside K/V block ``j`` names
+    it; any other call completes every q block beside the LAST K/V block,
+    and the rows before it name the block that row's first step names.
+    Either way a name is left only after its block was written — Pallas
+    writes a block out when the name changes."""
+    if diagonal:
+        return iq == j, j
+    last = j == nkv - 1
+    return last, jnp.where(last, iq, 0)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, dk_ref,
+                dv_ref, dq_acc, dk_acc, dv_acc, delta_ref, *scratch,
+                causal: bool, block_q: int, block_kv: int, sub: int | None,
+                one_block: bool, scale: float, hps: int, D: int, group: int,
+                nq: int, diagonal: bool, window: int | None = None,
                 nband: int | None = None):
-    step = pl.program_id(2)  # the group's q steps, nq q blocks each
-    per_head = nband or nq   # ... or the band's nband q blocks each
-    iq, in_grid = _band_step(
-        pl.program_id(1), _group_step(0, step, group, per_head)[1], nband,
-        nq, kv_major=True)
+    # grid: k/v step, q step of its group, K/V block, q block (of the band)
+    t, j, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    iq, in_grid = _band_step(j, step, nband, nq, kv_major=True)
     heads = _head_lanes(hps, D)
     shared = _shares_kv(hps, group)
-    at = lambda cols: cols      # where a tile adds to dk_acc / dv_acc
+    at = lambda cols: (j, cols)      # where a tile adds to dk_acc / dv_acc
     if shared:
         # this step's q heads read k/v head ``half`` of the block, and what
         # they add to dk / dv — each in its own lanes — is that head's: one
         # accumulator a k/v head, its halves summed at the end
-        half = _kv_half(step // per_head, group, hps)
+        half = _kv_half(t, group, hps)
         k_ref, v_ref = _shared_kv(k_ref, v_ref, *scratch, half, D)
-        at = lambda cols: (half, cols)
+        at = lambda cols: (j, half, cols)
 
-    @pl.when(step == 0)
+    @pl.when((t == 0) & (step == 0))
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[j] = jnp.zeros(dk_acc.shape[1:], jnp.float32)
+        dv_acc[j] = jnp.zeros(dv_acc.shape[1:], jnp.float32)
+
+    @pl.when(_first_visit(j, step, nband, nq)[0])
+    def _first():
+        # delta = rowsum(do * o) a head, kept as the lane-dense rows the
+        # tiles subtract in transposed space
+        dq_acc[iq] = jnp.zeros(dq_acc.shape[1:], jnp.float32)
+        prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        for h, lanes in enumerate(heads):
+            delta_ref[h, iq] = jnp.sum(
+                prod if lanes is None else jnp.where(lanes, prod, 0.0),
+                axis=-1, keepdims=True).T
 
     q_start = iq * block_q
-    k_start = pl.program_id(1) * block_kv
+    k_start = j * block_kv
 
     def tile(rows, cols, mask):
         # Transposed space, [keys, queries]: lse and delta are rows. A
         # head's q and do rows are zero in the other heads' lanes, so its
-        # dv and dk land in its own lanes and the heads' sums do not mix.
+        # dv and dk land in its own lanes and the heads' sums do not mix;
+        # ``ds_h @ k`` fills every lane of the block, and head h's D lanes
+        # of it are its dq.
+        dqs = []
         for h, lanes in enumerate(heads):
             s_t = _scores2(q_ref, k_ref, rows, cols, mask, transposed=True,
                            lanes=lanes)
@@ -848,142 +832,159 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dp_t = jax.lax.dot_general(
                 v_ref[0, cols, :], do, _NT,
                 preferred_element_type=jnp.float32)
-            ds_t = p_t * (dp_t - delta_ref[h, 0, :, rows])
+            ds_t = (p_t * (dp_t - delta_ref[h, iq, :, rows])).astype(
+                q_ref.dtype)
             dk_acc[at(cols)] += jax.lax.dot_general(
-                ds_t.astype(q_ref.dtype), _head_rows(q_ref, rows, lanes),
-                _NN, preferred_element_type=jnp.float32)
+                ds_t, _head_rows(q_ref, rows, lanes), _NN,
+                preferred_element_type=jnp.float32)
+            dqs.append(jax.lax.dot_general(
+                ds_t, k_ref[0, cols, :], _TN,
+                preferred_element_type=jnp.float32))
+        dq_acc[iq, rows] += _by_head(dqs, heads)
 
     _dispatch(tile, q_start, k_start, causal, block_q, block_kv, sub,
               one_block, kv_major=True, window=window, in_grid=in_grid)
 
+    @pl.when(_dq_complete(j, iq, diagonal, pl.num_programs(2))[0])
+    def _dq_out():
+        # acc holds d/d(q.k) contractions; one [block_q, D] multiply undoes
+        # the score scaling (ds was accumulated in natural space).
+        dq_ref[0] = (dq_acc[iq] * scale).astype(dq_ref.dtype)
+
     def total(acc):
         if not shared:
-            return acc[:]
+            return acc
         return _by_head([acc[h] + pltpu.roll(acc[h], D, 1)
                          for h in range(hps)], heads)
 
-    @pl.when(step == pl.num_programs(2) - 1)
-    def _finalize():
+    @pl.when((t == group - 1) & (step == pl.num_programs(3) - 1))
+    def _dkv_out():
         # dk contracted ds against the PRE-SCALED q (scale * log2e folded
         # in), while true dk = scale * (ds^T @ q_unscaled) — so divide the
         # extra log2e back out. dv never touches scores: exact as-is.
-        dk_ref[0] = (total(dk_acc) * (1.0 / _LOG2E)).astype(dk_ref.dtype)
-        dv_ref[0] = total(dv_acc).astype(dv_ref.dtype)
+        dk_ref[0] = (total(dk_acc[j]) * (1.0 / _LOG2E)).astype(dk_ref.dtype)
+        dv_ref[0] = total(dv_acc[j]).astype(dv_ref.dtype)
+
+
+# What a backward call may ask of VMEM: the accumulators below, the blocks
+# in flight and the score tiles Mosaic keeps for a step (11 MiB at most as
+# the compiler counts them, ``tests/test_flash_tpu_compile.py``; the
+# precedent is ``ops/sparse_attn_pallas.py``'s; a v5e core has 128 MiB).
+# One number for every shape: XLA keeps buffers of its own in what a kernel
+# leaves, so the limit moves the glue round the kernels, and a limit that
+# followed the accumulators (16 MiB + their bytes) read 3 ms an update
+# better in two cells and 4 ms worse in a third (PERF.md section 6, PR 53).
+_VMEM_LIMIT = 64 * 1024 * 1024
+# ... of which the three float32 accumulators over all of T may take this
+# much: T x lanes <= 4 Mi (16,384 x 256, 32,768 x 128)
+_MAX_ACC_BYTES = 48 * 1024 * 1024
 
 
 @functools.lru_cache(maxsize=None)
 def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                sub: int | None, in_dtype_name: str, interpret: bool,
                group: int = 1, hps: int = 1, window: int | None = None):
-    """Compile-cached backward pallas_calls over ``_build_fwd``'s operand
-    layouts: a dq pass (grid q-major, KV innermost) and a dk/dv pass (grid
-    kv-major, Q innermost) — the standard two-pass flash backward, so
-    neither pass needs atomics or cross-block communication. ``lse``
-    arrives as lane-dense rows (``_row_spec``); the dq pass makes delta =
-    rowsum(do * o) a head from the do and out blocks it holds and hands it
-    to the dk/dv pass as rows of the same kind. ``group`` q heads
-    share a K/V head: dq is per q head, dk/dv per K/V head, summed over the
-    group along the dk/dv grid's innermost axis. ``window``: the
-    innermost axis of either grid is the band (``_band_step``)."""
+    """Compile-cached backward pallas_call over ``_build_fwd``'s operand
+    layouts: ONE kernel that walks the score tiles once, in transposed
+    space, and makes dq, dk and dv from the ``ds^T`` tile it holds. Grid
+    ``(k/v steps, q steps of a k/v step's group, K/V blocks, q blocks)`` —
+    a windowed call's innermost axis is the band
+    (``_band_step``) — with a q head's whole ``dq`` and the k/v head's whole
+    ``dk`` / ``dv`` in float32 VMEM scratch over all of T: ``dq`` is summed
+    over the K/V blocks, ``dk`` / ``dv`` over the q blocks and, the q head
+    lying outside the K/V blocks, over the group. ``lse`` arrives as
+    lane-dense rows (``_row_spec``); delta = rowsum(do * o) is made in the
+    kernel where a q head first holds a q block (``_first_visit``) and never
+    leaves VMEM."""
     dtype = jnp.dtype(in_dtype_name)
     scale = 1.0 / (D ** 0.5)
     one_block = T == block_q == block_kv
     w = hps * D
     nq, nkv = T // block_q, T // block_kv
-    nband = None if window is None else _band_blocks(window, block_kv, nkv)
-    static = dict(causal=causal, block_q=block_q, block_kv=block_kv, sub=sub,
-                  one_block=one_block, hps=hps, D=D, group=group,
-                  window=window, nband=nband)
-    suffix = WINDOW_SUFFIX if window is not None else ""
-    dq_kernel = functools.partial(_dq_kernel, scale=scale, **static)
-    dkv_kernel = functools.partial(_dkv_kernel, nq=nq, **static)
     shared = _shares_kv(hps, group)
-    shared_kv = [pltpu.VMEM((1, block_kv, w), dtype)] * 2 * shared
-    # dk/dv accumulators: one a k/v head of the block where q pairs share
-    acc_shape = (hps,) * shared + (block_kv, w)
-    row_spec_q = _row_spec(block_q, lambda g, i, j: (g, i, 0, 0), hps)
-
-    def q_of(g, j, i):    # dk/dv grid: K/V step g, K/V block j, step i
-        gq, iq = _group_step(g, i, group, nband or nq)
-        return gq, _band_step(j, iq, nband, nq, kv_major=True)[0]
-
-    def q_row(g, j, i):
-        return (*q_of(g, j, i), 0, 0)
-
-    row_spec_kv_inner = _row_spec(block_q, q_row, hps)
+    # a q step's dq; the k/v step's dk and dv, one each a k/v head of the
+    # block where q pairs share
+    kv_acc = (nkv,) + (hps,) * shared + (block_kv, w)
+    acc_shapes = [(nq, block_q, w), kv_acc, kv_acc]
+    acc_bytes = 4 * sum(math.prod(shape) for shape in acc_shapes)
+    if acc_bytes > _MAX_ACC_BYTES:
+        raise ValueError(
+            f"flash backward: the accumulators of {T} rows x {w} lanes do "
+            f"not fit VMEM ({acc_bytes} bytes of {_MAX_ACC_BYTES})")
+    nband = None if window is None else _band_blocks(window, block_kv, nkv)
+    diagonal = causal and block_q == block_kv
+    kernel = functools.partial(
+        _bwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv,
+        sub=sub, one_block=one_block, scale=scale, hps=hps, D=D, group=group,
+        nq=nq, diagonal=diagonal, window=window, nband=nband)
+    name = BWD_NAME + (WINDOW_SUFFIX if window is not None else "")
 
     def call(qr, kr, vr, dor, out, lse):
         nlb, nlb_kv = qr.shape[2] // w, kr.shape[2] // w
-        steps, steps_kv = qr.shape[0] * nlb, kr.shape[0] * nlb_kv
+        steps_kv = kr.shape[0] * nlb_kv
 
-        def q_block(g, i, j):        # dq grid: q step g
-            row, c = _lane_block(g, nlb)
-            return (row, i, c)
+        def q_of(g, t, j, s):    # -> flat q step, q block
+            return (g * group + t,
+                    _band_step(j, s, nband, nq, kv_major=True)[0])
 
-        def kv_block(g, i, j):       # dq grid: q step g, kv block j
-            row, c = _lane_block(_kv_head(g, group), nlb_kv)
-            return (row, _band_step(i, j, nband)[0], c)
-
-        def q_inner(g, j, i):        # dk/dv grid: K/V step g, step i
-            gq, iq = q_of(g, j, i)
+        def q_block(g, t, j, s):
+            gq, iq = q_of(g, t, j, s)
             row, c = _lane_block(gq, nlb)
             return (row, iq, c)
 
-        def kv_outer(g, j, i):
+        def out_block(g, t, j, s):
+            row, c = _lane_block(g * group + t, nlb)
+            return (row, _first_visit(j, s, nband, nq)[1], c)
+
+        def dq_block(g, t, j, s):
+            gq, iq = q_of(g, t, j, s)
+            row, c = _lane_block(gq, nlb)
+            return (row, _dq_complete(j, iq, diagonal, nkv)[1], c)
+
+        def kv_block(g, t, j, s):
             row, c = _lane_block(g, nlb_kv)
             return (row, j, c)
 
-        dq_call = pl.pallas_call(
-            dq_kernel,
-            name=DQ_NAME + suffix,
-            grid=(steps, nq, nband or nkv),
+        def dkv_block(g, t, j, s):
+            # the group's last q step completes dk / dv; the steps before
+            # it name the block its first row names
+            row, c = _lane_block(g, nlb_kv)
+            return (row, j if group == 1 else jnp.where(t == group - 1, j, 0),
+                    c)
+
+        bwd = pl.pallas_call(
+            kernel,
+            name=name,
+            grid=(steps_kv, group, nkv, nband or nq),
             in_specs=[
                 pl.BlockSpec((1, block_q, w), q_block),
                 pl.BlockSpec((1, block_kv, w), kv_block),
                 pl.BlockSpec((1, block_kv, w), kv_block),
                 pl.BlockSpec((1, block_q, w), q_block),
-                pl.BlockSpec((1, block_q, w), q_block),
-                row_spec_q,
-            ],
-            out_specs=[pl.BlockSpec((1, block_q, w), q_block), row_spec_q],
-            out_shape=[jax.ShapeDtypeStruct(qr.shape, dtype),
-                       jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
-            scratch_shapes=shared_kv + ([] if one_block else [
-                pltpu.VMEM((block_q, w), jnp.float32),
-                pltpu.VMEM((hps, block_q, 1), jnp.float32)]),
-            interpret=interpret,
-        )
-        with jax.named_scope(DQ_NAME + suffix):
-            dq, delta = dq_call(qr, kr, vr, dor, out, lse)
-        dkv_call = pl.pallas_call(
-            dkv_kernel,
-            name=DKV_NAME + suffix,
-            grid=(steps_kv, nkv, group * (nband or nq)),
-            in_specs=[
-                pl.BlockSpec((1, block_q, w), q_inner),
-                pl.BlockSpec((1, block_kv, w), kv_outer),
-                pl.BlockSpec((1, block_kv, w), kv_outer),
-                pl.BlockSpec((1, block_q, w), q_inner),
-                row_spec_kv_inner,
-                row_spec_kv_inner,
+                pl.BlockSpec((1, block_q, w), out_block),
+                _row_spec(block_q, lambda *at: (*q_of(*at), 0, 0), hps),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_kv, w), kv_outer),
-                pl.BlockSpec((1, block_kv, w), kv_outer),
+                pl.BlockSpec((1, block_q, w), dq_block),
+                pl.BlockSpec((1, block_kv, w), dkv_block),
+                pl.BlockSpec((1, block_kv, w), dkv_block),
             ],
             out_shape=[
+                jax.ShapeDtypeStruct(qr.shape, dtype),
                 jax.ShapeDtypeStruct(kr.shape, dtype),
                 jax.ShapeDtypeStruct(kr.shape, dtype),
             ],
             scratch_shapes=[
-                pltpu.VMEM(acc_shape, jnp.float32),
-                pltpu.VMEM(acc_shape, jnp.float32),
-            ] + shared_kv,
+                pltpu.VMEM(shape, jnp.float32) for shape in acc_shapes
+            ] + [
+                pltpu.VMEM((hps, nq, 1, block_q), jnp.float32),   # delta
+            ] + [pltpu.VMEM((1, block_kv, w), dtype)] * 2 * shared,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
         )
-        with jax.named_scope(DKV_NAME + suffix):
-            dk, dv = dkv_call(qr, kr, vr, dor, lse, delta)
-        return dq, dk, dv
+        with jax.named_scope(name):
+            return bwd(qr, kr, vr, dor, out, lse)
 
     return call
 
@@ -1004,12 +1005,13 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
     The first call a trace makes runs the builders' calls bare; every
     repeat in that trace runs them through one inner ``jit`` each
     (``_shared``). Every ``pallas_call`` traces its body and lowers it to
-    Mosaic anew, a trunk of L attention layers makes the same three calls
+    Mosaic anew, a trunk of L attention layers makes the same two calls
     L times, and two heads a step about doubles a body
-    (``gpt2m-policy.update``: 72 calls, + 7 s of warm set-up traced a
-    layer); through the inner ``jit`` the repeats share one jaxpr and one
-    lowered function (eagerly — ``init_params`` runs every layer at T = 1
-    — one executable in place of one a layer). The first call stays bare
+    (``gpt2m-policy.update``: 48 calls; the 72 of the three kernels it had
+    cost + 7 s of warm set-up traced a layer); through the inner ``jit``
+    the repeats share one jaxpr and one lowered function (eagerly —
+    ``init_params`` runs every layer at T = 1 — one executable in place of
+    one a layer). The first call stays bare
     so that a model with ONE attention layer traces and lowers exactly
     what it did without this: an inner ``jit`` shares nothing there, and
     it moves the depth of the Python stack at which Mosaic's lowering

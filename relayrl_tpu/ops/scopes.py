@@ -61,8 +61,7 @@ MAMBA_CONV_NAME = "relayrl_mamba_conv"   # models/layers/mamba2.py's
 GDN_NAME = "relayrl_gdn"                 # ops/gdn.py: the gated delta rule
 GDN_CONV_NAME = "relayrl_gdn_conv"       # models/layers/gdn.py's
 FWD_NAME = "relayrl_flash_fwd"           # ops/flash.py, also the calls' name
-DQ_NAME = "relayrl_flash_dq"
-DKV_NAME = "relayrl_flash_dkv"
+BWD_NAME = "relayrl_flash_bwd"           # dq, dk, dv from one score tile
 # A windowed call's kernels carry the same names with this suffix: a reader
 # that matches ``relayrl_flash_fwd`` finds them too, one that wants the band
 # calls alone asks for the suffix.
@@ -74,5 +73,5 @@ GMM_DRHS_NAME = "relayrl_moe_gmm_drhs"
 HELD_EXPERTS_NAME = "held_experts"
 
 KERNEL_SCOPES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME, GDN_NAME,
-                 GDN_CONV_NAME, FWD_NAME, DQ_NAME, DKV_NAME, GMM_FWD_NAME,
+                 GDN_CONV_NAME, FWD_NAME, BWD_NAME, GMM_FWD_NAME,
                  GMM_DLHS_NAME, GMM_DRHS_NAME)

@@ -106,9 +106,10 @@ def test_flash_never_defaults_to_the_interpreter():
     (True, 16, 32), (True, 32, 16), (False, 16, 32), (False, 32, 16),
 ])
 def test_flash_grads_uneven_and_noncausal(causal, bq, bk):
-    # The two-pass Pallas VJP has distinct grid orderings per pass (dq is
-    # q-major, dk/dv is kv-major) and per-pass live-block predicates; cover
-    # uneven blocks and the non-causal branch explicitly.
+    # The backward kernel walks K/V blocks outside q blocks and holds dq over
+    # all of them; where the diagonal does not run corner to corner (uneven
+    # blocks) or there is none (non-causal) a q block's dq is complete only
+    # beside the LAST K/V block: cover both explicitly.
     q, k, v = _qkv()
 
     def loss(fn):
@@ -233,7 +234,7 @@ def test_score_area_pct(T, block, sub, pct):
                                          (64, 32, 8), (32, 32, None)])
 def test_score_area_is_what_the_kernels_visit(monkeypatch, T, block, sub):
     """The area function against the kernel bodies: sum the score tiles
-    each of the three kernels emits at trace time."""
+    each of the two kernels (forward; backward) emits at trace time."""
     _sub_tile(monkeypatch, sub or 1 << 30)
     _clear_kernel_caches()
     visited = []
@@ -253,19 +254,19 @@ def test_score_area_is_what_the_kernels_visit(monkeypatch, T, block, sub):
     if n_blocks > 1:
         # One whole interior tile a kernel: emitted once, run by the
         # n (n - 1) / 2 grid blocks below the diagonal.
-        for _ in range(3):
+        for _ in range(2):
             visited.remove((block, block))
     strips = sum(a * b for a, b in visited)
-    assert strips % 3 == 0, visited  # fwd, dq, dk/dv: the same area
-    area = n_blocks * strips // 3 + (
+    assert strips % 2 == 0, visited  # fwd, bwd: the same area
+    area = n_blocks * strips // 2 + (
         n_blocks * (n_blocks - 1) // 2) * block * block
     assert flash.score_area_pct(T, block, block, sub, True) == (
         100.0 * area / (T * T))
     if sub is not None:  # strips, and nothing taller or wider than needed
         n = block // sub
         assert sorted(visited) == sorted(
-            [(sub, (r + 1) * sub) for r in range(n)] * 2       # fwd, dq
-            + [(sub, (n - c) * sub) for c in range(n)])        # dk/dv^T
+            [(sub, (r + 1) * sub) for r in range(n)]           # fwd
+            + [(sub, (n - c) * sub) for c in range(n)])        # bwd^T
 
 
 def test_policy_records_the_score_area(monkeypatch, capsys):
@@ -350,8 +351,9 @@ def test_grouped_flash_matches_dense(B, T, H, h_kv, block):
 def test_grouped_flash_is_the_plain_kernels_on_each_q_head(T, block):
     """A q head of a group computes what it computes with its k/v head
     given to it alone: the grouped forward and dq are BIT-equal to the
-    group-1 kernels on k/v repeated over the group; dk/dv are those
-    kernels' summed over the group (another order of the same sums)."""
+    group-1 kernels on k/v repeated over the group (a q head's dq has an
+    accumulator of its own, whatever the group); dk/dv are those kernels'
+    summed over the group (another order of the same sums)."""
     q, k, v = _grouped_qkv(2, T, 4, 2)
     rep = lambda a: jnp.repeat(a, 2, axis=2)
     fl = lambda q, k, v: flash_attention(q, k, v, block_q=block,
@@ -367,13 +369,40 @@ def test_grouped_flash_is_the_plain_kernels_on_each_q_head(T, block):
 
 
 def test_group_one_keeps_the_index_maps_it_had():
-    # at group 1 the helpers hand back their arguments: the same index
-    # maps, grids and kernel bodies trace as before k/v could be grouped
-    b, i = object(), object()
+    # at group 1 the forward's helper hands back its argument: the same
+    # index maps, grids and kernel bodies trace as before k/v could be
+    # grouped
+    b = object()
     assert flash._kv_head(b, 1) is b
-    assert flash._group_step(b, i, 1, 7) == (b, i)
     assert flash._kv_head(13, 4) == 3
-    assert flash._group_step(3, 9, 4, 4) == (14, 1)
+
+
+def test_the_backward_grid_names_each_block_once():
+    """The backward's three walks over a q head's blocks, as its index maps
+    and its kernel read them: ``out`` is named at a q block's first visit
+    and stays named between visits (one load a q block), and a ``dq`` block
+    is named from the step that completes it until the next one is."""
+    first = lambda *a: tuple(map(int, flash._first_visit(*a)))
+    done = lambda *a: tuple(map(int, flash._dq_complete(*a)))
+    # no window, 4 q blocks: all first held beside K/V block 0
+    assert [first(0, s, None, 4) for s in range(4)] == [
+        (1, 0), (1, 1), (1, 2), (1, 3)]
+    assert [first(2, s, None, 4) for s in range(4)] == [(0, 3)] * 4
+    # a band of 3 over 5 q blocks: K/V block 0 brings q blocks 0..2, each
+    # later one the block at its last step, and a step past the last q block
+    # is no visit at all
+    assert [first(0, s, 3, 5) for s in range(3)] == [(1, 0), (1, 1), (1, 2)]
+    assert [first(1, s, 3, 5) for s in range(3)] == [(0, 2), (0, 2), (1, 3)]
+    assert [first(2, s, 3, 5) for s in range(3)] == [(0, 3), (0, 3), (1, 4)]
+    assert [first(3, s, 3, 5) for s in range(3)] == [(0, 4), (0, 4), (0, 4)]
+    assert [first(3, 0, 1, 5), first(4, 0, 1, 5)] == [(1, 3), (1, 4)]
+    # the diagonal runs corner to corner: q block j is complete beside K/V
+    # block j; otherwise every q block beside the last K/V block
+    assert [done(2, i, True, 4) for i in range(4)] == [
+        (0, 2), (0, 2), (1, 2), (0, 2)]
+    assert [done(1, i, False, 4) for i in range(3)] == [(0, 0)] * 3
+    assert [done(3, i, False, 4) for i in range(3)] == [
+        (1, 0), (1, 1), (1, 2)]
 
 
 def test_grouped_flash_refuses_heads_that_do_not_group():
@@ -478,6 +507,92 @@ def test_lane_layout_grid_steps_causal_and_not(monkeypatch, H, h_kv, T,
     _check_both_layouts(monkeypatch, q, k, v, causal, block, 5e-5)
 
 
+# -- the backward: dq, dk and dv of one score tile, one kernel a call ---------
+
+@pytest.mark.parametrize("H,h_kv,D,T,bq,bk,window,sub", [
+    (2, 2, 128, 64, 16, 16, None, 8),    # head-major at 128, plain heads
+    (7, 1, 128, 64, 16, 16, None, 8),    # ... 7 q heads a k/v head
+    (8, 1, 256, 32, 16, 16, None, 8),    # head_dim 256, 8 q heads a k/v head
+    (4, 4, 64, 64, 16, 16, None, 8),     # two heads a step: plain pairs
+    (8, 2, 64, 64, 16, 16, None, 8),     # ... 4 q heads share a k/v head
+    (2, 2, 128, 32, 32, 32, None, 8),    # one block a head, in strips
+    (4, 4, 64, 16, 16, 16, None, None),  # ... one tile, two heads a step
+    (2, 2, 128, 64, 32, 16, None, None),  # unequal blocks: dq waits for
+    (2, 1, 128, 64, 16, 32, None, None),  # the last K/V block
+    (7, 1, 128, 64, 16, 16, 32, 8),      # a window of whole blocks
+    (7, 1, 128, 64, 16, 16, 24, 8),      # a window that cuts a block
+    (4, 2, 64, 64, 16, 16, 8, 8),        # a window shorter than a block
+])
+def test_fused_backward_matches_dense(monkeypatch, H, h_kv, D, T, bq, bk,
+                                      window, sub):
+    """dq, dk and dv of the one backward kernel against ``jax.grad`` of the
+    dense form, over the layouts, groups and grids the module claims."""
+    _sub_tile(monkeypatch, sub or 1 << 30)
+    _clear_kernel_caches()
+    assert flash.lane_layout(H, h_kv, D) == (2 if D == 64 else None)
+    q, k, v = _grouped_qkv(1, T, H, h_kv, D, seed=13)
+    fl = lambda q, k, v: flash_attention(q, k, v, block_q=bq, block_kv=bk,
+                                         window=window)
+    dense = functools.partial(dense_attention, window=window)
+    text = str(jax.make_jaxpr(jax.grad(_grad_loss(fl), (0, 1, 2)))(q, k, v))
+    assert text.count("pallas_call") == 2
+    got = jax.grad(_grad_loss(fl), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_grad_loss(dense), argnums=(0, 1, 2))(q, k, v)
+    _clear_kernel_caches()
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_the_accumulators_hold_five_blocks_and_start_from_zero(causal):
+    """Five K/V blocks a head and three q heads a k/v head: a q head's dq is
+    summed over all five beside dk / dv summed over five q blocks of three
+    heads, with only the last q block's cotangent alive (so every K/V block's
+    share of dq lands in ONE accumulator block, and dk / dv of every key
+    come from one q block). A second batch row equal to the first gives the
+    same bits: nothing of a head's sums is left for the next."""
+    q, k, v = _grouped_qkv(1, 80, 3, 1, 16, seed=17)
+    q, k, v = (jnp.concatenate([x, x]) for x in (q, k, v))
+    last = (jnp.arange(80) >= 64)[None, :, None, None]
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            jnp.where(last, jnp.sin(fn(q, k, v)), 0.0))
+
+    fl = lambda q, k, v: flash_attention(q, k, v, causal=causal, block_q=16,
+                                         block_kv=16)
+    dense = functools.partial(dense_attention, causal=causal)
+    got = jax.grad(loss(fl), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(g, w, atol=2e-5, rtol=2e-5,
+                                   err_msg=f"d{name}")
+        np.testing.assert_array_equal(g[0], g[1], err_msg=f"d{name}")
+    dq, dk, _ = got
+    assert not dq[:, :64].any() and bool(dq[:, 64:].any())
+    assert all(bool(dk[:, b * 16:(b + 1) * 16].any())
+               for b in range(5))
+
+
+def test_the_backward_refuses_accumulators_past_vmem():
+    """A q head's dq and a k/v head's dk and dv stay in VMEM over all of T:
+    the builder says so where T x lanes is past what fits, read off the
+    operands' shape; the benchmark's largest (16,384 x 128 and 8,192 x 256)
+    take half of it."""
+    build = lambda T, D: flash._build_bwd(T, D, True, 1024, 1024, 256,
+                                          "bfloat16", False)
+    for T, D in ((16_384, 128), (8_192, 256), (32_768, 128)):
+        assert 3 * T * D * 4 <= flash._MAX_ACC_BYTES
+        build(T, D)
+    with pytest.raises(ValueError, match="do not fit VMEM"):
+        build(65_536, 128)
+    with pytest.raises(ValueError, match="do not fit VMEM"):
+        build(32_768, 256)
+    flash._build_bwd.cache_clear()
+
+
 # -- the kernels' trace: a call's repeats share one, its first stays bare -----
 
 def _flash_policy(monkeypatch, n_layers, T=16):
@@ -494,10 +609,10 @@ def _flash_policy(monkeypatch, n_layers, T=16):
 
 
 def _count_kernel_bodies(monkeypatch):
-    """Calls of the three kernel body functions = traces of a body."""
+    """Calls of the two kernel body functions = traces of a body."""
     calls = {}
     _clear_kernel_caches()
-    for name in ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"):
+    for name in ("_fwd_kernel", "_bwd_kernel"):
         body = getattr(flash, name)
 
         def counted(*args, _body=body, _name=name, **kw):
@@ -543,8 +658,7 @@ def test_a_trunk_traces_a_kernel_body_twice_at_most(monkeypatch, n_layers,
     assert policy.attention_layout[(16, 64, "float32")] == "2 heads a step"
     # init ran the model at T = 1 (forward only, the custom_vjp's forward
     # in a trace of its own a layer), the update at T = 16
-    assert calls == {"_fwd_kernel": 2 * traces, "_dq_kernel": traces,
-                     "_dkv_kernel": traces}
+    assert calls == {"_fwd_kernel": 2 * traces, "_bwd_kernel": traces}
     # one lowered function a builder (forward; backward), called a layer
     assert len([ln for ln in text.splitlines()
                 if "func.func private @call" in ln]) == shared_funcs
@@ -574,7 +688,7 @@ def test_eager_repeats_share_one_program(monkeypatch):
         q, k, v = _grouped_qkv(1, 16, 2, 2, 64)
         jax.grad(_grad_loss(lambda q, k, v: flash_attention(
             q, k, v, block_q=16, block_kv=16)), argnums=(0, 1, 2))(q, k, v)
-        assert calls == {"_fwd_kernel": 1, "_dq_kernel": 1, "_dkv_kernel": 1}
+        assert calls == {"_fwd_kernel": 1, "_bwd_kernel": 1}
         assert "jit(call)" not in kernel_programs()
         calls.clear()
         policy = _flash_policy(monkeypatch, n_layers=4)
@@ -652,8 +766,9 @@ def test_a_windowed_call_carries_names_that_extend_the_kernels_own():
     text = jax.jit(jax.grad(_grad_loss(lambda q, k, v: flash_attention(
         q, k, v, block_q=16, block_kv=16, window=32)),
         argnums=(0, 1, 2))).lower(q, k, v).as_text(debug_info=True)
-    for name in (flash.FWD_NAME, flash.DQ_NAME, flash.DKV_NAME):
+    for name in (flash.FWD_NAME, flash.BWD_NAME):
         assert name + flash.WINDOW_SUFFIX in text, name
+    assert "relayrl_flash_dq" not in text and "relayrl_flash_dkv" not in text
 
 
 def test_a_window_wants_a_causal_call_and_equal_blocks():
@@ -702,7 +817,7 @@ def test_band_score_area_is_what_the_kernels_visit(monkeypatch, T, block,
         q, k, v, block_q=block, block_kv=block, window=window)),
         argnums=(0, 1, 2))(q, k, v)
     _clear_kernel_caches()
-    assert len(visited) % 3 == 0
+    assert len(visited) % 2 == 0    # forward; backward
     n = T // block
     nband = flash._band_blocks(window, block, n)
     both_edges = window < block
@@ -720,6 +835,6 @@ def test_band_score_area_is_what_the_kernels_visit(monkeypatch, T, block,
         100.0 * area / (T * T))
     # what a kernel's body holds: the diagonal's tiles, the cut block's,
     # and an interior tile where the window spans two blocks or more
-    emitted = sum(a * b for a, b in visited) // 3
+    emitted = sum(a * b for a, b in visited) // 2
     assert emitted == diag + cut * (nband > 1) + block * block * (
         window >= 2 * block)

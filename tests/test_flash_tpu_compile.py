@@ -48,6 +48,9 @@ def one_chip():
     # default 1024 blocks in 256-row strips, head-major
     ((2, 8192, 16, 256), 256, 2, None, None),
     ((1, 1024, 8, 256), 256, 1, None, None),    # one block a head at 256
+    # nemotron-twotower-policy.update: 16 q heads a k/v head
+    ((2, 8192, 32, 128), 256, 2, None, None),
+    ((1, 8192, 16, 128), 256, 16, None, None),  # ouro-policy.update: plain
 ])
 def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
                                        layout, window):
@@ -64,13 +67,39 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
                     jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 
+    # the chip's compiler refuses a kernel that asks for more VMEM than its
+    # limit: the backward holds a q head's dq and a k/v head's dk and dv
+    # over all of T beside its blocks and score tiles
     compiled = jax.jit(value_and_grads).lower(x, kv, kv).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
+    lanes = (layout or 1) * shape[3]
+    # float32 over all of T: dq, and dk and dv — one each a k/v head of the
+    # block where a step's two q heads share one
+    shared = flash._shares_kv(layout or 1, shape[2] // kv_heads)
+    acc = (5 if shared else 3) * shape[1] * lanes * 4
+    assert acc <= flash._MAX_ACC_BYTES < flash._VMEM_LIMIT
+    (line,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+               and f"/{flash.BWD_NAME}" in ln and " = " in ln]
+    # (XLA may keep operands of its own in VMEM below the kernel's scope:
+    # the scope's offset, which the used size counts too)
+    config = r'"%s":\[{"memory_space":"1","offset":"(\d+)","size":"(\d+)"'
+    below, asked = map(int, re.search(
+        config % "scoped_memory_configs", line).groups())
+    used = int(re.search(
+        config % "used_scoped_memory_configs", line).group(2)) - below
+    # a step's blocks and tiles fit in what a kernel has by default, so the
+    # limit holds whatever accumulators the builder lets through
+    assert asked == flash._VMEM_LIMIT, asked
+    assert acc < used <= acc + 16 * 2 ** 20, (acc, used)
+    print(f"relayrl_flash_bwd at {shape} window {window}: "
+          f"{used / 2 ** 20:.1f} of {asked / 2 ** 20:.1f} MiB of VMEM")
+    # forward, and ONE backward kernel for dq, dk and dv
+    assert text.count("tpu_custom_call") == 2
     # a windowed call's kernels say so by name, the others' names are bare
-    for name in (flash.FWD_NAME, flash.DQ_NAME, flash.DKV_NAME):
+    for name in (flash.FWD_NAME, flash.BWD_NAME):
         assert name in text
         assert (name + flash.WINDOW_SUFFIX in text) == bool(window)
+    assert "relayrl_flash_dq" not in text and "relayrl_flash_dkv" not in text
     # What the module does round the kernels is the operator's glue, named
     # in the forward and in the backward rule; no kernel sits in a part.
     glue = re.findall(rf'op_name="([^"]*{scopes.OP_PROJ}[^"]*)"', text)
