@@ -77,6 +77,16 @@ OPERATOR_KEYS: Mapping[str, Mapping[str, Any]] = {
         "gdn_key_heads": 4, "gdn_value_heads": 8, "gdn_key_dim": 64,
         "gdn_value_dim": 64, "gdn_conv_taps": 4, "gdn_chunk": 64,
     },
+    # the delta rule under a decay a key lane; keys and values one width
+    "kda": {
+        "kda_heads": 8, "kda_head_dim": 64, "kda_conv_taps": 4,
+        "kda_chunk": 64,
+    },
+    # keys and values expanded from one compressed row a token
+    "latent_attention": {
+        "kv_lora_rank": 64, "qk_nope_head_dim": 32, "qk_rope_head_dim": 16,
+        "v_head_dim": 32,
+    },
     # "attention" (OPERATOR_BASE) with a learned indexer in front of it
     "sparse_attention": {
         "index_heads": 4, "index_head_dim": 32,  # over ONE key head

@@ -39,13 +39,16 @@ from relayrl_tpu.models.layers import (
     attention,
     block,
     gdn,
+    kda,
     mamba2,
+    mla,
     short_conv,
     sparse_attention,
 )
 
 OPERATORS = {"attention": attention, "conv": short_conv, "mamba2": mamba2,
-             "gdn": gdn, "sparse_attention": sparse_attention, "none": block}
+             "gdn": gdn, "kda": kda, "latent_attention": mla,
+             "sparse_attention": sparse_attention, "none": block}
 
 # ``layer_types`` entry -> (the layer's operator, whether an FFN follows)
 LAYER_KINDS = {"full_attention": ("attention", True),
@@ -53,6 +56,8 @@ LAYER_KINDS = {"full_attention": ("attention", True),
                "conv": ("conv", True),
                "mamba2": ("mamba2", False),
                "linear_attention": ("gdn", True),
+               "kda": ("kda", True),
+               "latent_attention": ("latent_attention", True),
                "sparse_attention": ("sparse_attention", True),
                "attention": ("attention", False),
                "ffn": ("none", True)}

@@ -161,7 +161,9 @@ def _mixer(block, shape):
     return weights, mix, taps - 1
 
 
-apply = recurrent.mixer_apply(_mixer, kept=(_GDN_OUT, _GDN_SOLVE))
+# ... and what a block checkpoint round the whole layer keeps with it
+KEPT = (_GDN_OUT, _GDN_SOLVE)
+apply = recurrent.mixer_apply(_mixer, kept=KEPT)
 
 
 def init_cache(cfg, d_model, batch, length, dtype, window):

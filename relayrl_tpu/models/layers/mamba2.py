@@ -129,7 +129,9 @@ def _mixer(block, shape):
     return weights, mix, taps - 1
 
 
-apply = recurrent.mixer_apply(_mixer, kept=(_SSD_OUT,))
+# ... and what a block checkpoint round the whole layer keeps with it
+KEPT = (_SSD_OUT,)
+apply = recurrent.mixer_apply(_mixer, kept=KEPT)
 
 
 def init_cache(cfg, d_model, batch, length, dtype, window):

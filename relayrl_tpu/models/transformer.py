@@ -266,14 +266,18 @@ class TransformerCore(nn.Module):
         block_cls = TransformerBlock
         if self.block_checkpoint and full:
             # beside a block application's input, the flash forward's
-            # output and log-sum-exp: the backward then runs no forward
-            # kernel a second time (PERF.md section 6, PR 50)
+            # output and log-sum-exp and what a mixer's own checkpoint keeps
+            # of its recurrence (``KEPT``): the backward then runs no
+            # forward kernel and no recurrence a second time (PERF.md
+            # section 6, PR 50, PR 55)
             from relayrl_tpu.ops import flash
 
+            kept = tuple(name for op in layers.OPERATORS.values()
+                         for name in getattr(op, "KEPT", ()))
             block_cls = nn.remat(
                 TransformerBlock,
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    flash.OUT_NAME, flash.LSE_NAME))
+                    flash.OUT_NAME, flash.LSE_NAME, *kept))
 
         def block_at(i: int) -> TransformerBlock:
             op, has_ffn = self.layer_parts(i)

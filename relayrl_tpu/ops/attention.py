@@ -10,7 +10,9 @@ the new framework adds as first-class components: a dense softmax attention
 per-block combine step is shared with the ring-attention sequence-parallel
 path in :mod:`relayrl_tpu.parallel.ring`.
 
-Layout convention: ``[batch, time, heads, head_dim]`` (BTHD) everywhere.
+Layout convention: ``[batch, time, heads, head_dim]`` (BTHD) everywhere;
+``v`` may have a head width of its own (latent attention: q and k 192 lanes
+a head, v 128), which is then the result's.
 Scores are computed in float32 regardless of input dtype (bf16 trunks feed
 the MXU; softmax stays f32 for stability).
 
@@ -161,10 +163,11 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         H = k.shape[2]
         q, G = _fold_groups(q, H)
         q_pos = jnp.tile(q_pos, G)
+    Dv = v.shape[-1]            # a value head's own width (latent: 192 / 128)
     k_blocks = k.reshape(B, n_blocks, block_size, H, D)
-    v_blocks = v.reshape(B, n_blocks, block_size, H, D)
+    v_blocks = v.reshape(B, n_blocks, block_size, H, Dv)
 
-    o = jnp.zeros((B, H, G * T, D), jnp.float32)
+    o = jnp.zeros((B, H, G * T, Dv), jnp.float32)
     m = jnp.full((B, H, G * T), _NEG_INF, jnp.float32)
     l = jnp.zeros((B, H, G * T), jnp.float32)
 

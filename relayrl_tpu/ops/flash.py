@@ -203,6 +203,7 @@ from jax.experimental.pallas import tpu as pltpu
 from relayrl_tpu.ops.scopes import (  # noqa: F401  (re-exported)
     BWD_NAME,
     FWD_NAME,
+    LATENT_SUFFIX,
     OP_PROJ,
     WINDOW_SUFFIX,
 )
@@ -619,24 +620,46 @@ def _lane_block(g, nlb: int):
     return (g, 0) if nlb == 1 else (g // nlb, g % nlb)
 
 
+def _value_lanes(hps: int, Dv: int) -> int:
+    """The lanes of a v / out / do / dv block where a value head is ``Dv``
+    wide and a q / k head another width: one head a step (head-major), the
+    block a head's own lanes."""
+    if hps != 1:
+        raise ValueError("values of another width than q and k run "
+                         "head-major, one head a grid step")
+    return Dv
+
+
+def _name_suffix(window: int | None, Dv: int | None) -> str:
+    """What a call's kernels add to the plain names: ``_win`` on a
+    windowed call, ``_mla`` where the values have a width of their own."""
+    return ((WINDOW_SUFFIX if window is not None else "")
+            + (LATENT_SUFFIX if Dv is not None else ""))
+
+
 @functools.lru_cache(maxsize=None)
 def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                sub: int | None, in_dtype_name: str, interpret: bool,
-               group: int = 1, hps: int = 1, window: int | None = None):
+               group: int = 1, hps: int = 1, window: int | None = None,
+               Dv: int | None = None):
     """Compile-cached pallas_call for a forward over ``[rows, T, lanes]``
     operands in blocks ``hps * D`` lanes wide: head-major ``[BH, T, D]``
     (k and v ``[BH / group, T, D]``), or the projections' own
     ``[B, T, H * D]`` with ``hps`` heads a grid step (``lane_layout``).
-    ``window``: the K/V axis of the grid is the band (``_band_step``)."""
+    ``window``: the K/V axis of the grid is the band (``_band_step``).
+    ``Dv``: values (and the output) of another width than q and k (latent
+    attention: 192 / 128), head-major operands only; None: ``D``, and the
+    call is what it was."""
     one_block = T == block_q == block_kv
     w = hps * D
+    wv = w if Dv is None else _value_lanes(hps, Dv)
     nq, nkv = T // block_q, T // block_kv
     nband = None if window is None else _band_blocks(window, block_kv, nkv)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv,
         sub=sub, one_block=one_block, hps=hps, D=D, group=group,
         window=window, nband=nband)
-    name = FWD_NAME + (WINDOW_SUFFIX if window is not None else "")
+    name = FWD_NAME + _name_suffix(window, Dv)
     dtype = jnp.dtype(in_dtype_name)
     # the step's copy of its one k/v head (``_shared_kv``)
     shared_kv = [pltpu.VMEM((1, block_kv, w), dtype)] * 2 * _shares_kv(
@@ -661,19 +684,20 @@ def _build_fwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
             in_specs=[
                 pl.BlockSpec((1, block_q, w), q_block),
                 pl.BlockSpec((1, block_kv, w), kv_block),
-                pl.BlockSpec((1, block_kv, w), kv_block),
+                pl.BlockSpec((1, block_kv, wv), kv_block),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, w), q_block),
+                pl.BlockSpec((1, block_q, wv), q_block),
                 _row_spec(block_q, lambda g, i, j: (g, i, 0, 0), hps),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct(qr.shape, dtype),
+                jax.ShapeDtypeStruct(
+                    qr.shape if Dv is None else qr.shape[:2] + (wv,), dtype),
                 jax.ShapeDtypeStruct((steps * hps, nq, 1, block_q),
                                      jnp.float32),
             ],
             scratch_shapes=shared_kv + ([] if one_block else [
-                pltpu.VMEM((block_q, w), jnp.float32),
+                pltpu.VMEM((block_q, wv), jnp.float32),
                 pltpu.VMEM((hps, block_q, 1), jnp.float32),
                 pltpu.VMEM((hps, block_q, 1), jnp.float32),
             ]),
@@ -883,7 +907,8 @@ _MAX_ACC_BYTES = 48 * 1024 * 1024
 @functools.lru_cache(maxsize=None)
 def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
                sub: int | None, in_dtype_name: str, interpret: bool,
-               group: int = 1, hps: int = 1, window: int | None = None):
+               group: int = 1, hps: int = 1, window: int | None = None,
+               Dv: int | None = None):
     """Compile-cached backward pallas_call over ``_build_fwd``'s operand
     layouts: ONE kernel that walks the score tiles once, in transposed
     space, and makes dq, dk and dv from the ``ds^T`` tile it holds. Grid
@@ -895,17 +920,19 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
     lying outside the K/V blocks, over the group. ``lse`` arrives as
     lane-dense rows (``_row_spec``); delta = rowsum(do * o) is made in the
     kernel where a q head first holds a q block (``_first_visit``) and never
-    leaves VMEM."""
+    leaves VMEM. ``Dv``: as ``_build_fwd``'s — v, do, out and dv are then
+    ``Dv`` lanes a head."""
     dtype = jnp.dtype(in_dtype_name)
     scale = 1.0 / (D ** 0.5)
     one_block = T == block_q == block_kv
     w = hps * D
+    wv = w if Dv is None else _value_lanes(hps, Dv)
     nq, nkv = T // block_q, T // block_kv
     shared = _shares_kv(hps, group)
     # a q step's dq; the k/v step's dk and dv, one each a k/v head of the
     # block where q pairs share
     kv_acc = (nkv,) + (hps,) * shared + (block_kv, w)
-    acc_shapes = [(nq, block_q, w), kv_acc, kv_acc]
+    acc_shapes = [(nq, block_q, w), kv_acc, kv_acc[:-1] + (wv,)]
     acc_bytes = 4 * sum(math.prod(shape) for shape in acc_shapes)
     if acc_bytes > _MAX_ACC_BYTES:
         raise ValueError(
@@ -917,7 +944,7 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
         _bwd_kernel, causal=causal, block_q=block_q, block_kv=block_kv,
         sub=sub, one_block=one_block, scale=scale, hps=hps, D=D, group=group,
         nq=nq, diagonal=diagonal, window=window, nband=nband)
-    name = BWD_NAME + (WINDOW_SUFFIX if window is not None else "")
+    name = BWD_NAME + _name_suffix(window, Dv)
 
     def call(qr, kr, vr, dor, out, lse):
         nlb, nlb_kv = qr.shape[2] // w, kr.shape[2] // w
@@ -959,20 +986,20 @@ def _build_bwd(T: int, D: int, causal: bool, block_q: int, block_kv: int,
             in_specs=[
                 pl.BlockSpec((1, block_q, w), q_block),
                 pl.BlockSpec((1, block_kv, w), kv_block),
-                pl.BlockSpec((1, block_kv, w), kv_block),
-                pl.BlockSpec((1, block_q, w), q_block),
-                pl.BlockSpec((1, block_q, w), out_block),
+                pl.BlockSpec((1, block_kv, wv), kv_block),
+                pl.BlockSpec((1, block_q, wv), q_block),
+                pl.BlockSpec((1, block_q, wv), out_block),
                 _row_spec(block_q, lambda *at: (*q_of(*at), 0, 0), hps),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, w), dq_block),
                 pl.BlockSpec((1, block_kv, w), dkv_block),
-                pl.BlockSpec((1, block_kv, w), dkv_block),
+                pl.BlockSpec((1, block_kv, wv), dkv_block),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct(qr.shape, dtype),
                 jax.ShapeDtypeStruct(kr.shape, dtype),
-                jax.ShapeDtypeStruct(kr.shape, dtype),
+                jax.ShapeDtypeStruct(vr.shape, dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM(shape, jnp.float32) for shape in acc_shapes
@@ -997,7 +1024,7 @@ _shared = functools.lru_cache(maxsize=None)(jax.jit)
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
                 interpret: bool, D: int, group: int, hps: int | None,
-                window: int | None = None):
+                window: int | None = None, Dv: int | None = None):
     """The differentiable call over the kernels' own operands (``hps``
     None: head-major ``[BH, T, D]``; else ``[B, T, H * D]``) — what is
     kept for the backward is kept as the kernels read it.
@@ -1026,7 +1053,7 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
             T = qr.shape[1]
             call = built(_build_fwd(T, D, causal, block_q, block_kv, sub,
                                     qr.dtype.name, interpret, group,
-                                    per_step, window))
+                                    per_step, window, Dv))
             with jax.named_scope(OP_PROJ):
                 qs = _prescale_q(qr, D)
             return call(qs, kr, vr)
@@ -1045,7 +1072,7 @@ def _make_flash(causal: bool, block_q: int, block_kv: int, sub: int | None,
             qr, kr, vr, out, lse_row = res
             call = built(_build_bwd(qr.shape[1], D, causal, block_q,
                                     block_kv, sub, qr.dtype.name, interpret,
-                                    group, per_step, window))
+                                    group, per_step, window, Dv))
             # the kernels recompute log2-space scores
             with jax.named_scope(OP_PROJ):
                 qs = _prescale_q(qr, D)
@@ -1074,7 +1101,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     window: int | None = None) -> jax.Array:
     """Fused attention on ``q [B, T, H, D]``, ``k`` / ``v``
     ``[B, T, Hkv, D]`` (``Hkv`` divides ``H``; q head ``j`` reads k/v head
-    ``j // (H / Hkv)``) via a Pallas TPU kernel.
+    ``j // (H / Hkv)``) via a Pallas TPU kernel. ``v`` may be ``[B, T, Hkv,
+    Dv]`` with a width of its own (latent attention's 192 / 128): the result
+    is then ``[B, T, H, Dv]``, the operands head-major and the kernels named
+    ``relayrl_flash_fwd_mla`` / ``_bwd_mla``; with ``Dv == D`` the call is
+    what it was.
 
     Compiled by Mosaic, so it runs on a TPU backend only; off-TPU callers
     use :func:`relayrl_tpu.ops.attention.blockwise_attention` (what the
@@ -1096,7 +1127,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     pressure forces it (an interior step's score tile is
     block_q x block_kv f32).
     """
-    if k.shape != v.shape or q.shape[2] % k.shape[2]:
+    if k.shape[:3] != v.shape[:3] or q.shape[2] % k.shape[2]:
         raise ValueError(f"q heads {q.shape[2]} do not group over k/v "
                          f"{k.shape} / {v.shape}")
     if window is not None and window >= q.shape[1]:
@@ -1106,8 +1137,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     B, _, H, D = q.shape
     h_kv = k.shape[2]
     hps = lane_layout(H, h_kv, D)
+    own_width = v.shape[3] != D
+    if own_width:
+        hps = None      # a value head's lanes are not a q head's
     flash = _make_flash(causal, block_q, block_kv, sub, bool(interpret), D,
-                        H // h_kv, hps, window)
+                        H // h_kv, hps, window,
+                        *((int(v.shape[3]),) if own_width else ()))
     with jax.named_scope(OP_PROJ):
         operands = [_to_kernel(x, hps) for x in (q, k, v)]
     out = flash(*operands)

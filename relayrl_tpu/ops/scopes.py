@@ -60,12 +60,17 @@ SSD_NAME = "relayrl_ssd"                 # ops/ssd.py: the Mamba-2 scan
 MAMBA_CONV_NAME = "relayrl_mamba_conv"   # models/layers/mamba2.py's
 GDN_NAME = "relayrl_gdn"                 # ops/gdn.py: the gated delta rule
 GDN_CONV_NAME = "relayrl_gdn_conv"       # models/layers/gdn.py's
+KDA_NAME = "relayrl_kda"                 # ops/kda.py: the per-lane-decay rule
+KDA_CONV_NAME = "relayrl_kda_conv"       # models/layers/kda.py's
 FWD_NAME = "relayrl_flash_fwd"           # ops/flash.py, also the calls' name
 BWD_NAME = "relayrl_flash_bwd"           # dq, dk, dv from one score tile
 # A windowed call's kernels carry the same names with this suffix: a reader
 # that matches ``relayrl_flash_fwd`` finds them too, one that wants the band
 # calls alone asks for the suffix.
 WINDOW_SUFFIX = "_win"
+# ... and a call whose values have a width of their own (latent attention:
+# q / k 192 lanes a head, v 128) this one, after the window's if both
+LATENT_SUFFIX = "_mla"
 GMM_FWD_NAME = "relayrl_moe_gmm_fwd"     # ops/grouped_matmul.py
 GMM_DLHS_NAME = "relayrl_moe_gmm_dlhs"
 GMM_DRHS_NAME = "relayrl_moe_gmm_drhs"
@@ -73,5 +78,5 @@ GMM_DRHS_NAME = "relayrl_moe_gmm_drhs"
 HELD_EXPERTS_NAME = "held_experts"
 
 KERNEL_SCOPES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME, GDN_NAME,
-                 GDN_CONV_NAME, FWD_NAME, BWD_NAME, GMM_FWD_NAME,
+                 GDN_CONV_NAME, KDA_NAME, KDA_CONV_NAME, FWD_NAME, BWD_NAME, GMM_FWD_NAME,
                  GMM_DLHS_NAME, GMM_DRHS_NAME)

@@ -36,6 +36,8 @@ from relayrl_tpu.ops.scopes import (
     GDN_NAME,
     HEADS,
     INDEX,
+    KDA_CONV_NAME,
+    KDA_NAME,
     LOSS,
     MAMBA_CONV_NAME,
     MOE_ELEMENTWISE,
@@ -58,7 +60,7 @@ TRUNK = EVERY_UPDATE + (EMBED, OP_PROJ)
 MOE = (MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE)
 # plain XLA operators that keep a name of their own, as the kernels do
 OWN_NAMES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME, GDN_NAME,
-             GDN_CONV_NAME)
+             GDN_CONV_NAME, KDA_NAME, KDA_CONV_NAME)
 # family -> (arch, the scopes its update uses, observation width)
 FAMILIES = {
     # the GPT-2 shaped block (gpt2m-policy)
@@ -109,6 +111,20 @@ FAMILIES = {
                 "positions": "rope", "rope_share": 0.5, "qk_norm": "head",
                 "attn_gate": True, "use_bias": False, "ffn": "swiglu"},
                TRUNK + MOE + (FFN, GDN_NAME, GDN_CONV_NAME)),
+    # a delta rule under a decay a key lane (two chunks) with the dense FFN,
+    # then latent attention (q / k 6 wide, v 4) with sigmoid-routed experts
+    # beside a shared expert (kimi-linear-policy)
+    "latent": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 2,
+                "n_heads": 4, "layer_types": ["kda", "latent_attention"],
+                "kda_heads": 2, "kda_head_dim": 8, "kda_chunk": 4,
+                "kv_lora_rank": 8, "qk_nope_head_dim": 4,
+                "qk_rope_head_dim": 2, "v_head_dim": 4,
+                "moe_dense_layers": 1, "moe_experts": 8, "moe_top_k": 3,
+                "moe_held": [2, 4], "moe_d_ff": 12, "moe_shared_d_ff": 12,
+                "moe_router": "sigmoid", "moe_expert_bias": True,
+                "moe_routed_scaling": 2.446, "norm": "rms",
+                "positions": "none", "use_bias": False, "ffn": "swiglu"},
+               TRUNK + MOE + (FFN, KDA_NAME, KDA_CONV_NAME)),
     # attention over the keys an indexer picks, 2 of up to 8, in two tiles;
     # the indexers' own loss under the loss's name (keye-vl2-policy)
     "sparse": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 2,

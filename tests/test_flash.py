@@ -838,3 +838,69 @@ def test_band_score_area_is_what_the_kernels_visit(monkeypatch, T, block,
     emitted = sum(a * b for a, b in visited) // 2
     assert emitted == diag + cut * (nband > 1) + block * block * (
         window >= 2 * block)
+
+
+# -- values of another width than q and k (latent attention: 192 / 128) -------
+
+def _latent_qkv(B, T, H, D, Dv, seed=11, h_kv=None):
+    rng = np.random.default_rng(seed)
+    mk = lambda h, d: jnp.asarray(rng.standard_normal((B, T, h, d)),
+                                  jnp.float32)
+    return mk(H, D), mk(h_kv or H, D), mk(h_kv or H, Dv)
+
+
+@pytest.mark.parametrize("T,bq,bk,causal,h_kv", [
+    (64, 32, 32, True, None),     # the diagonal corner to corner
+    (64, 64, 64, True, None),     # one block a head: no carried softmax
+    (64, 32, 16, False, 1),       # uneven, not causal, 2 q heads a k/v head
+])
+def test_values_of_their_own_width_match_dense(T, bq, bk, causal, h_kv):
+    """q and k 24 lanes a head, v 16 (192 / 128 in small): the output is
+    ``[B, T, H, 16]``, and dq, dk come back 24 wide, dv 16 — the forward and
+    the one backward kernel against dense attention, and the blockwise form
+    (what "flash" resolves to off a TPU) beside them."""
+    q, k, v = _latent_qkv(2, T, 2, 24, 16, h_kv=h_kv)
+    call = functools.partial(flash_attention, causal=causal, block_q=bq,
+                             block_kv=bk)
+    dense = functools.partial(dense_attention, causal=causal)
+    out = call(q, k, v)
+    assert out.shape == (2, T, 2, 16)
+    np.testing.assert_allclose(out, dense(q, k, v), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        blockwise_attention(q, k, v, 16, causal=causal), dense(q, k, v),
+        atol=2e-5, rtol=2e-5)
+    w = jnp.asarray(np.random.default_rng(12).standard_normal(out.shape),
+                    jnp.float32)
+    grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(
+        q, k, v) for fn in (call, dense, functools.partial(
+            blockwise_attention, block_size=16, causal=causal))]
+    assert [g.shape for g in grads[0]] == [q.shape, k.shape, v.shape]
+    for mine, theirs in zip(grads[0], grads[1]):
+        np.testing.assert_allclose(mine, theirs, atol=5e-5, rtol=5e-5)
+    for mine, theirs in zip(grads[2], grads[1]):
+        np.testing.assert_allclose(mine, theirs, atol=5e-5, rtol=5e-5)
+
+
+def test_a_width_of_its_own_names_its_kernels_and_equal_widths_do_not():
+    """``_mla`` on the kernels of a call whose values have their own width;
+    a call with ``D_qk = D_v`` traces the ``pallas_call``s it traced before:
+    the plain names, every block the q / k width, and the same jaxpr as a
+    call that never heard of the option (the builders' cache keys hold no
+    ``Dv``)."""
+    q, k, v = _latent_qkv(1, 32, 2, 24, 16)
+    grad = lambda *a: jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, block_q=16, block_kv=16)), argnums=(0, 1, 2))(*a)
+    own = str(jax.make_jaxpr(grad)(q, k, v))
+    for name in (flash.FWD_NAME, flash.BWD_NAME):
+        assert name + flash.LATENT_SUFFIX in own
+    flash._build_fwd.cache_clear()
+    flash._build_bwd.cache_clear()
+    equal = str(jax.make_jaxpr(grad)(q, k, k))
+    assert flash.LATENT_SUFFIX not in equal
+    assert flash.FWD_NAME in equal and flash.BWD_NAME in equal
+    for built in (flash._build_fwd, flash._build_bwd):
+        assert built.cache_info().currsize == 1
+        # the call's key: no Dv behind the window's place
+        assert built.cache_parameters()["typed"] is False
+    with pytest.raises(ValueError, match="do not group"):
+        flash_attention(q, k[:, :16], v)

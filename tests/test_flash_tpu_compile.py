@@ -120,6 +120,49 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, sub, kv_heads,
         x.shape, kv.shape, kv.shape]
 
 
+def test_the_latent_attention_kernels_compile_for_v5e(one_chip):
+    """``kimi-linear-policy.update``'s latent-attention layer: 32 heads, q
+    and k 192 lanes a head, v 128, one 16,384-token episode. The same two
+    kernels, head-major, under names that say so (``_mla``): the backward's
+    float32 sums over all of T are dq and dk at 192 lanes and dv at 128, and
+    the gradients come back at the operands' own widths."""
+    B, T, H, D, Dv = 1, 16384, 32, 192, 128
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one_chip)
+    q, k, v = S(B, T, H, D), S(B, T, H, D), S(B, T, H, Dv)
+
+    def value_and_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(
+                flash.flash_attention(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(value_and_grads).lower(q, k, v).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for name in (flash.FWD_NAME, flash.BWD_NAME):
+        assert name + flash.LATENT_SUFFIX in text
+    assert 4 * T * (2 * D + Dv) <= flash._MAX_ACC_BYTES
+    assert [o.shape for o in compiled.out_info[1]] == [
+        q.shape, k.shape, v.shape]
+
+
+def test_equal_widths_lower_the_kernels_that_were_there():
+    """``D_qk = D_v`` (every shape but latent attention's): no ``_mla`` name
+    and no block of another width anywhere in the traced call — the
+    ``pallas_call``s' block shapes are the q / k width's throughout."""
+    q = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash.flash_attention(
+            q, k, v, interpret=True).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, q, q))
+    assert flash.FWD_NAME in jaxpr and flash.BWD_NAME in jaxpr
+    assert flash.LATENT_SUFFIX not in jaxpr
+    own = str(jax.make_jaxpr(lambda q, k, v: flash.flash_attention(
+        q, k, v, interpret=True))(q, q, q[..., :64]))
+    assert flash.FWD_NAME + flash.LATENT_SUFFIX in own
+
+
 def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
     """The held-experts layer (``models/moe.py``) at ``lfm2-policy``'s
     widths, 8 of 64 experts held, a quarter of its tokens: ONE copy of the
@@ -415,6 +458,7 @@ def test_the_plain_rule_compiles_for_v5e(one_chip):
 @pytest.mark.parametrize("columns,bias,scope", [
     (6144, True, scopes.MAMBA_CONV_NAME),    # nemotron-twotower-policy.update
     (8192, False, scopes.GDN_CONV_NAME),     # qwen3next-policy.update
+    (12288, False, scopes.KDA_CONV_NAME),    # kimi-linear-policy.update
 ])
 def test_the_mixers_convolution_compiles_for_v5e(one_chip, columns, bias,
                                                  scope):

@@ -61,6 +61,30 @@ CACHED_ARCHS = {
         "sliding_window": 3, "rope_layers": [0, 1, 1], "moe_experts": 8,
         "moe_top_k": 3, "moe_d_ff": 16, "moe_router_input": "layer",
         "moe_held": [2, 4]},
+    # the Kimi Linear trunk: two kinds of state side by side — a KDA layer's
+    # convolution tails with its [H, K, K] float32 state, and the LATENT rows
+    # (c, k_pe) of the attention layer, expanded through kv_b every step —
+    # the dense FFN first, sigmoid-routed experts beside a shared expert
+    "kimi_linear_trunk": {
+        "kind": "transformer_moe_discrete", "norm": "rms", "norm_eps": 1e-5,
+        "positions": "none", "use_bias": False, "ffn": "swiglu", "d_ff": 48,
+        "n_layers": 3, "n_heads": 4,
+        "layer_types": ["kda", "latent_attention", "kda"],
+        "kda_heads": 2, "kda_head_dim": 8, "kda_chunk": 4,
+        "kv_lora_rank": 8, "qk_nope_head_dim": 4, "qk_rope_head_dim": 2,
+        "v_head_dim": 4, "moe_dense_layers": 1, "moe_experts": 8,
+        "moe_top_k": 3, "moe_d_ff": 16, "moe_router": "sigmoid",
+        "moe_expert_bias": True, "moe_routed_scaling": 2.446,
+        "moe_shared_d_ff": 16, "moe_held": [2, 4]},
+    # latent attention LAST under a dense FFN, behind a layer that rotates
+    # (``rope_layers``: the latent layer sees no positions): the readout-row
+    # mode expands every row's latent and runs one query
+    "latent_last_dense": {**_MODERN, "qk_norm": False, "n_layers": 2,
+                          "n_heads": 4, "rope_layers": [1, 0],
+                          "layer_types": ["full_attention",
+                                          "latent_attention"],
+                          "kv_lora_rank": 8, "qk_nope_head_dim": 4,
+                          "qk_rope_head_dim": 2, "v_head_dim": 4},
     # a windowed layer LAST under a dense FFN: the readout-row mode masks
     # the one row's keys by the window
     "sliding_last_dense": {**_MODERN, "qk_norm": False, "n_layers": 2,
@@ -109,7 +133,7 @@ class TestStepCachedNumerics:
                                       "conv_last_dense",
                                       "smallthinker_trunk",
                                       "sliding_last_dense", "looped",
-                                      "looped_conv"])
+                                      "looped_conv", "kimi_linear_trunk"])
     def test_prefilled_cache_continues_as_the_stepped_one(self, name):
         # prefill rotates W keys at positions 0..W-1 in one dispatch; the
         # steps after it must read them as if they had been written one by

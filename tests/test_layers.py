@@ -57,10 +57,11 @@ def test_the_passthrough_keys_are_the_declarations():
                    for k in keys])
     assert list(arch_keys.DECLARED) == declared
     assert base.ARCH_PASSTHROUGH_KEYS == arch_keys.TRUNK_KEYS + tuple(declared)
-    # no key declared twice, and the 58 the configurations' references ask
-    # base for (benchmark/reference/*.py) are all there
+    # no key declared twice, and the 67 the configurations' references ask
+    # base for (benchmark/reference/*.py) are all there (58 + kda's five and
+    # latent attention's four, PR 55)
     assert len(set(base.ARCH_PASSTHROUGH_KEYS)) == len(
-        base.ARCH_PASSTHROUGH_KEYS) == 58
+        base.ARCH_PASSTHROUGH_KEYS) == 66
 
 
 @pytest.mark.parametrize("key", arch_keys.DECLARED)
@@ -120,6 +121,23 @@ TRUNKS = {
         {"ln_attn/scale": (16,), "gdn_in_qkvz": (16, 96),
          "gdn_in_ba": (16, 8), "gdn_conv_w": (4, 64), "gdn_dt_bias": (4,),
          "gdn_A_log": (4,), "gdn_norm": (8,), "gdn_out": (32, 16), **FFN}),
+    "kda": (
+        {"layer_types": ["kda"], "kda_heads": 4, "kda_head_dim": 8,
+         "kda_chunk": 4, "positions": "none"},
+        {"ln_attn/scale": (16,), "kda_in_qkv": (16, 96),
+         "kda_in_beta": (16, 4), "kda_f_down": (16, 8), "kda_f_up": (8, 32),
+         "kda_g_down": (16, 8), "kda_g_up": (8, 32), "kda_g_bias": (32,),
+         "kda_conv_w": (4, 96), "kda_dt_bias": (32,), "kda_A_log": (4,),
+         "kda_norm": (8,), "kda_out": (32, 16), **FFN}),
+    "latent_attention": (
+        {"layer_types": ["latent_attention"], "positions": "none",
+         "kv_lora_rank": 6, "qk_nope_head_dim": 4, "qk_rope_head_dim": 2,
+         "v_head_dim": 3},
+        {"ln_attn/scale": (16,), "q_proj/kernel": (16, 12),
+         "q_proj/bias": (12,), "kv_a/kernel": (16, 8), "kv_a/bias": (8,),
+         "kv_a_norm/scale": (6,), "kv_b/kernel": (6, 14),
+         "kv_b/bias": (14,), "attn_out/kernel": (6, 16),
+         "attn_out/bias": (16,), **FFN}),
     "sparse_attention": (
         {"layer_types": ["sparse_attention"], "positions": "rope",
          "n_kv_heads": 1, "head_dim": 4, "qk_norm": "head",

@@ -1126,6 +1126,45 @@ class TestReLU2ScalingAndTheSharedExpert:
         np.testing.assert_allclose(routed + once, whole, atol=3e-5,
                                    rtol=1e-5)
 
+    @pytest.mark.parametrize("chips", [4, 16])
+    def test_kimi_linears_shares_add_up_to_the_uncut_reference_layer(
+            self, chips):
+        """SwiGLU experts behind a sigmoid router with a correction bias,
+        top-4 of 16 renormalised x 2.446, one ungated shared expert
+        (``kimi-linear-policy``'s layer, tiny): the chips' routed shares —
+        4 shares of 4 experts as the deployment's 32 of 8, and 16 of one —
+        with the shared expert counted once are what
+        ``benchmark/reference/kimi-linear-policy.py`` computes for the WHOLE
+        layer, every expert held."""
+        from test_lfm2_reference import _by_path
+
+        ref = _by_path("benchmark/reference/kimi-linear-policy.py")
+        kw = dict(k=4, scaling=2.446, ffn="swiglu")
+        params, x = _relu2_params(**kw)
+        moe_p = params["params"]
+        tokens = x.reshape(-1, _D)
+        plain = lambda a: a
+        w = ref._route(moe_p, tokens, 4, 2.446, 0, 16)
+        want = sum(w[:, e:e + 1] * ref._swiglu(
+            tokens, moe_p["moe_w_gate"][e], moe_p["moe_w_up"][e],
+            moe_p["moe_w_down"][e], plain) for e in range(16))
+        want = want + ref._swiglu(
+            tokens, moe_p["moe_shared_gate"]["kernel"],
+            moe_p["moe_shared_up"]["kernel"],
+            moe_p["moe_shared_down"]["kernel"], plain)
+        per = 16 // chips
+        no_shared = lambda p: {"params": {
+            k: v for k, v in p["params"].items() if "shared" not in k}}
+        routed = sum(_relu2_layer((c * per, per), shared=None, **kw).apply(
+            no_shared(_share_of(params, c * per, per)), x)
+            for c in range(chips))
+        once = _relu2_layer((0, per), **kw).apply(
+            _share_of(params, 0, per), x) - _relu2_layer(
+                (0, per), shared=None, **kw).apply(
+                    no_shared(_share_of(params, 0, per)), x)
+        np.testing.assert_allclose((routed + once).reshape(want.shape), want,
+                                   atol=3e-5, rtol=1e-5)
+
     def test_an_unknown_ffn_is_refused_and_the_arch_sets_the_fields(self):
         with pytest.raises(ValueError, match="unknown ffn"):
             _policy_params(ffn="relu3")
