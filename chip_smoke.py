@@ -1081,6 +1081,64 @@ def phase_i() -> None:
         f"4 taps bfloat16: {'; '.join(said)}, {time.monotonic() - t0:.0f}s")
 
 
+def phase_j() -> None:
+    """The delta rule under a decay a key lane at
+    ``kimi-linear-policy.update``'s shape — one 16,384-token episode, 32
+    heads of 128, chunks of 64, bfloat16, from a carried state — through the
+    Pallas kernels (``ops/kda_pallas.py``: ``kda_fwd``, and under a random
+    cotangent of both results ``kda_states`` + ``kda_bwd``) and through the
+    plain form (``ops/kda.kda_xla``) on the same operands: ``o``, the last
+    state and the six cotangents, each within 2^-6 of the plain form's
+    largest entry. Twice: at the decays the policy is seeded with
+    (``exp(A_log)`` uniform over (0, 16) a head: all but a head or two
+    forget a state within a token or two, so a wrong state handed from chunk
+    to chunk would not show), and at decays near 1 (``g`` in [-0.05, 0]),
+    where the state carried in reaches every chunk's output. ``kda()``
+    itself has to pick the kernels here, and says so."""
+    import jax
+    import jax.numpy as jnp
+
+    from relayrl_tpu.ops import kda as rule
+
+    t0 = time.monotonic()
+    b, T, H, K, V, chunk = 1, 16384, 32, 128, 128, 64
+    check(rule.backend(T, H, K, V, chunk) == rule.PALLAS,
+          f"J: kda() would run {rule.backend(T, H, K, V, chunk)} at {H} heads "
+          f"of {K} x {V}, chunk {chunk} on a TPU")
+    keys = jax.random.split(jax.random.PRNGKey(45), 9)
+    lo = jnp.bfloat16
+
+    def unit(key, scale):    # as the mixer's L2 norm leaves q and k
+        a = jax.random.normal(key, (b, T, H, K), jnp.float32)
+        return (a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+                * scale).astype(lo)
+
+    lanes = jax.random.uniform(keys[3], (b, T, H, K), jnp.float32, 1e-3, 1.0)
+    rates = jax.random.uniform(keys[8], (H, 1), jnp.float32, 1e-4, 16.0)
+    names = ("o", "last", "dq", "dk", "dv", "dg", "dbeta", "dstate")
+    said = []
+    for what, g in (("seeded", -rates * lanes), ("near 1", -0.05 * lanes)):
+        args = (unit(keys[0], K ** -0.5), unit(keys[1], 1.0),
+                jax.random.normal(keys[2], (b, T, H, V), lo), g,
+                jax.random.uniform(keys[4], (b, T, H), jnp.float32),
+                jax.random.normal(keys[5], (b, H, K, V), jnp.float32))
+        cotangents = (jax.random.normal(keys[6], (b, T, H, V), lo),
+                      jax.random.normal(keys[7], (b, H, K, V), jnp.float32))
+        errs = dict(zip(names, map(
+            differ, both_ways(rule.kda, args, cotangents, chunk),
+            both_ways(rule.kda_xla, args, cotangents, chunk))))
+        for name, err in errs.items():
+            check(err <= 2.0 ** -6,
+                  f"J: at decays {what} the kernels' {name} differs from the "
+                  f"plain form's by {err:.3g} of its largest entry (limit "
+                  f"2^-6)")
+        said.append(f"decays {what} "
+                    f"{json.dumps({w: round(e, 6) for w, e in errs.items()})}")
+    say(f"J: ok — kda_fwd / kda_states / kda_bwd against the plain form at "
+        f"{(b, T, H, K)} chunk {chunk} bfloat16: {'; '.join(said)}, "
+        f"{time.monotonic() - t0:.0f}s")
+
+
 # --------------------------------------------------------------------------
 
 def main() -> None:
@@ -1149,6 +1207,7 @@ def run(dev: dict, t_start: float) -> None:
     phase_g()
     phase_h()
     phase_i()
+    phase_j()
 
     say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
         f"the persistent cache, {compiles.requests - compiles.hits} compiled "

@@ -147,6 +147,13 @@ class Policy:
     # tile) or plain XLA. Empty for a trunk without such layers, None for
     # other families.
     gdn_backends: Mapping[tuple, str] | None = None
+    # ...and with Kimi Delta Attention layers: ``{(T, heads, key width,
+    # value width, dtype): "kda_pallas" | "kda_xla"}`` for every shape of
+    # the delta rule under a decay a key lane traced so far — whether
+    # ``ops/kda.py`` ran the Pallas kernels (a TPU, shapes that tile) or
+    # plain XLA. Empty for a trunk without such layers, None for other
+    # families.
+    kda_backends: Mapping[tuple, str] | None = None
     # Sequence policies with Mamba-2 or linear-attention layers: ``{(T,
     # columns, taps, continues from a cache's rows, dtype): "conv_pallas" |
     # "conv_xla"}`` for every shape of the mixers' depthwise convolution

@@ -10,8 +10,10 @@ lane (``A_log`` a head, ``dt_bias`` a lane; the low-rank path a head's
 width inside, as the family's code fixes it), both float32; ``q = q / |q| /
 sqrt(K)``, ``k = k / |k|`` a head; ``o`` the rule of
 :mod:`relayrl_tpu.ops.kda` on a ``[K, K]`` matrix state a head, in chunks of
-``kda_chunk``; ``norm_h`` an RMSNorm over each head's width with a plain
-weight, then the gate ``sigmoid(g_up(g_down(norm(x))) + bias)`` (the second
+``kda_chunk`` (as Pallas kernels on a TPU where the shapes tile, as plain XLA
+everywhere else: ``ops.kda.backend``, recorded in ``Policy.kda_backends``);
+``norm_h`` an RMSNorm over each head's width with a plain weight, then the
+gate ``sigmoid(g_up(g_down(norm(x))) + bias)`` (the second
 low-rank path; the bias is the family's, whatever ``use_bias`` says of the
 block's dense layers) and ``out``.
 
@@ -30,13 +32,28 @@ from jax.ad_checkpoint import checkpoint_name
 from relayrl_tpu.models.layers import recurrent
 from relayrl_tpu.models.layers.gdn import _a_log_init, _normed_heads
 from relayrl_tpu.ops import kda as kda_ops
+from relayrl_tpu.ops.kda import SOLVE_NAME as _KDA_SOLVE
 from relayrl_tpu.ops.scopes import KDA_CONV_NAME, OP_PROJ
 
 ROW_READOUT = False
-# what a layer's checkpoint keeps: the rule's output
+# what a layer's checkpoint keeps: the rule's output and, where the rule
+# runs as kernels, the solve's tiles their forward wrote (67 MB a layer) —
+# without those the backward would run the rule's forward a second time
 _KDA_OUT = "relayrl_kda_out"
-# the rule has one form (``ops/kda.py``): called as it is, no record
-KERNELS = (recurrent.CONV_KERNEL,)
+
+
+def _rule_shape(q, k, v, g, beta, chunk, state):
+    key = (int(v.shape[1]), int(v.shape[2]), int(k.shape[3]),
+           int(v.shape[3]), v.dtype.name)
+    return key, kda_ops.backend(*key[:4], chunk), (
+        f"T={key[0]} heads={key[1]} key_dim={key[2]} value_dim={key[3]} "
+        f"chunk={chunk} {key[4]}")
+
+
+# ``Policy.kda_backends``: ``{(T, heads, key width, value width, dtype):
+# "kda_pallas" | "kda_xla"}``
+KERNELS = (recurrent.kernel("kda", kda_ops.kda, _rule_shape),
+           recurrent.CONV_KERNEL)
 
 
 def _mixer(block, shape):
@@ -47,7 +64,7 @@ def _mixer(block, shape):
     width = H * K
     f32 = jnp.float32
     cd = block.compute_dtype
-    conv_fn = block.fns["conv"]
+    rule_fn, conv_fn = block.fns["kda"], block.fns["conv"]
     lecun = nn.initializers.lecun_normal()
     weights = (
         block.param("kda_in_qkv", lecun, (d, 3 * width), f32),
@@ -103,7 +120,7 @@ def _mixer(block, shape):
                                         beta[:, 0], state)
             o = o[:, None]
         else:
-            o, state = kda_ops.kda(q, k, v, g, beta, chunk, state)
+            o, state = rule_fn(q, k, v, g, beta, chunk, state)
         o = checkpoint_name(o.reshape(Bsz, T, width), _KDA_OUT)
         with jax.named_scope(OP_PROJ):
             # the norm over each head's width, plain weight, THEN the gate
@@ -116,7 +133,7 @@ def _mixer(block, shape):
 
 
 # ... and what a block checkpoint round the whole layer keeps with it
-KEPT = (_KDA_OUT,)
+KEPT = (_KDA_OUT, _KDA_SOLVE)
 apply = recurrent.mixer_apply(_mixer, kept=KEPT)
 
 

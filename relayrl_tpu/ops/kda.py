@@ -51,15 +51,31 @@ matmuls' operands are ``v``'s dtype with float32 accumulation.
 ``beta = 0``, zero ``k`` and ``v`` leaves the state as it is), and
 right-padded episodes need nothing: the rule is causal.
 
-**What runs it**: plain XLA on every platform — heads before rows,
-``_HEADS_A_STEP`` heads a step of a ``lax.map``, ONE ``lax.scan`` over the
-chunks, backward by autodiff under ``jax.checkpoint`` (a step's tiles are
-made again from its arguments). There is one form, so nothing picks and
-nothing records a pick; a kernel for the rule (PERF.md section 7) brings its
-``backend()`` and its record with it, as ``gdn_pallas`` brought
-:mod:`.gdn`'s. Everything sits under one named scope, ``relayrl_kda``
-(``ops/scopes.py``), and no deeper ``relayrl_`` name: the benchmark's
-``kda_ms`` / ``kda_roofline`` read the exact scope.
+**Two forms of the same algebra, picked by what the code can observe**
+(:func:`backend`; no arch key, no environment variable, no switch):
+
+* ``kda_pallas`` — on a TPU, for shapes that tile (keys and values of one
+  lane tile a head, four heads a grid step, chunks of 64:
+  :func:`relayrl_tpu.ops.kda_pallas.fits`) and at least one whole chunk of
+  rows: the Pallas kernels of :mod:`relayrl_tpu.ops.kda_pallas` — ``kda_fwd``,
+  and in the backward ``kda_states`` + ``kda_bwd`` under one
+  ``jax.custom_vjp`` —, a grid over (sequence, four heads, chunk) with the
+  chunk axis sequential, a chunk's tiles, its solve and the carried state in
+  VMEM, the operands as the projections leave them (no head transpose), the
+  running sums of ``g`` made inside. ``kimi-linear-policy.update`` runs them
+  (PERF.md section 6, PR 56: 722 ms an update of plain XLA at 2.4 % of its
+  roofline before them).
+* ``kda_xla`` (:func:`kda_xla`) — everywhere else (CPU actor hosts, CI, a
+  shape that does not tile, a prompt shorter than a chunk) and the reference
+  the kernels' tests hold them to: plain XLA, heads before rows,
+  ``_HEADS_A_STEP`` heads a step of a ``lax.map``, ONE ``lax.scan`` over the
+  chunks, backward by autodiff under ``jax.checkpoint`` (a step's tiles are
+  made again from its arguments).
+
+Both sit under one named scope, ``relayrl_kda`` (``ops/scopes.py``), and no
+deeper ``relayrl_`` name: the benchmark's ``kda_ms`` / ``kda_roofline`` read
+the exact scope. ``models/layers/kda.KERNELS`` records which form a policy's
+rules ran as (``Policy.kda_backends``) and prints one ``[kda]`` line a shape.
 
 :func:`kda_step` is the rule's one step, what a cached decode runs.
 """
@@ -73,6 +89,12 @@ import jax.numpy as jnp
 
 from relayrl_tpu.ops.gdn import _inverse_unit_lower
 from relayrl_tpu.ops.scopes import KDA_NAME
+
+# what a rule ran as (``backend``; ``Policy.kda_backends``)
+PALLAS, XLA = "kda_pallas", "kda_xla"
+# what a differentiated kernel forward keeps beside its arguments, by the name
+# a caller's checkpoint policy saves it under (``ops/kda_pallas.py``)
+SOLVE_NAME = "relayrl_kda_solve"
 
 # Rows a sub-chunk: the lane-wise sums cost ``_SUB * K`` products a row and
 # the split products one re-weighted copy of the chunk's keys a sub-chunk
@@ -177,16 +199,10 @@ def _heads(args, chunk: int):
     return o.astype(cd), last
 
 
-def kda(q, k, v, g, beta, chunk: int = 64, state=None):
-    """``q, k [b, T, H, K]`` (as they enter the rule: normalised and scaled
-    by the caller), ``v [b, T, H, V]``, ``g [b, T, H, K]`` (log decay a
-    lane, <= 0) and ``beta [b, T, H]`` float32, ``state [b, H, K, V]``
-    float32 (None: zeros, a sequence's start) -> ``(o [b, T, H, V]`` in
-    ``v``'s dtype, ``last_state [b, H, K, V]`` float32``)``:
-    ``_HEADS_A_STEP`` heads a step of a ``lax.map``."""
-    if q.shape != k.shape or g.shape != k.shape:
-        raise ValueError(f"q {q.shape}, k {k.shape} and g {g.shape} are one "
-                         f"shape: a decay a key lane")
+def kda_xla(q, k, v, g, beta, chunk: int = 64, state=None):
+    """:func:`kda` as plain XLA, ``_HEADS_A_STEP`` heads a step of a
+    ``lax.map``: every backend takes it, and the kernels' tests hold them to
+    it."""
     if chunk % min(_SUB, chunk):
         raise ValueError(f"a chunk of {chunk} is no whole sub-chunks of "
                          f"{_SUB}")
@@ -219,6 +235,38 @@ def kda(q, k, v, g, beta, chunk: int = 64, state=None):
         o = jnp.swapaxes(jnp.moveaxis(o, 0, 1).reshape(b, H, T + pad, V),
                          1, 2)
         return o[:, :T], jnp.moveaxis(last, 0, 1).reshape(b, H, K, V)
+
+
+def backend(T: int, H: int, K: int, V: int, chunk: int) -> str:
+    """``"kda_pallas"`` or ``"kda_xla"``: what :func:`kda` runs a rule of
+    these shapes as on this process's platform. The kernels on a TPU where
+    the shapes tile (``kda_pallas.fits``) and there is a whole chunk of
+    rows, plain XLA everywhere else — CPU actor hosts, CI, a shape that does
+    not tile, a rule shorter than a chunk. Platform and shape decide, nothing
+    else: no arch key, no environment variable."""
+    if jax.default_backend() != "tpu" or T < chunk:
+        return XLA
+    from relayrl_tpu.ops import kda_pallas
+
+    return PALLAS if kda_pallas.fits(H, K, V, chunk) else XLA
+
+
+def kda(q, k, v, g, beta, chunk: int = 64, state=None):
+    """``q, k [b, T, H, K]`` (as they enter the rule: normalised and scaled
+    by the caller), ``v [b, T, H, V]``, ``g [b, T, H, K]`` (log decay a
+    lane, <= 0) and ``beta [b, T, H]`` float32, ``state [b, H, K, V]``
+    float32 (None: zeros, a sequence's start) -> ``(o [b, T, H, V]`` in
+    ``v``'s dtype, ``last_state [b, H, K, V]`` float32``)``, as
+    :func:`backend` says."""
+    if q.shape != k.shape or g.shape != k.shape:
+        raise ValueError(f"q {q.shape}, k {k.shape} and g {g.shape} are one "
+                         f"shape: a decay a key lane")
+    T, H, V = v.shape[1:]
+    if backend(T, H, k.shape[3], V, chunk) == PALLAS:
+        from relayrl_tpu.ops.kda_pallas import kda_pallas
+
+        return kda_pallas(q, k, v, g, beta, chunk, state)
+    return kda_xla(q, k, v, g, beta, chunk, state)
 
 
 def kda_step(q, k, v, g, beta, state):

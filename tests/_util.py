@@ -120,3 +120,17 @@ def band_attention_oracle(q, k, v, window):
     back = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :]
     s = jnp.where((back >= 0) & (back < window), s, -jnp.inf)
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+def kernel_calls(jaxpr) -> list:
+    """``[(kernel name, number of results)]`` of every ``pallas_call`` in a
+    jaxpr, inner jaxprs included."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], len(eqn.outvars)))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += kernel_calls(inner)
+    return found

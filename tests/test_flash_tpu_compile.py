@@ -372,19 +372,14 @@ def test_the_plain_scan_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
-def _rule_at_the_cells_shape(one_chip, fn, wrt):
-    """``fn`` (a form of ``ops/gdn.py``'s rule) at
-    ``qwen3next-policy.update``'s shape — two 8192-token episodes, 32 value
-    heads over 16 key heads of 128, chunks of 64, bfloat16 from a carried
-    state —, a loss that reads both results differentiated with respect to
-    ``wrt`` (none: the two results themselves), compiled for the described
-    chip."""
-    b, t, hk, h, w = 2, 8192, 16, 32, 128
-    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                  sharding=one_chip)
-    args = (S((b, t, hk, w), jnp.bfloat16), S((b, t, hk, w), jnp.bfloat16),
-            S((b, t, h, w), jnp.bfloat16), S((b, t, h), jnp.float32),
-            S((b, t, h), jnp.float32), S((b, h, w, w), jnp.float32))
+def _rule_compiled(one_chip, fn, wrt, operands):
+    """``fn`` (a form of a delta rule: ``ops/gdn.py``'s or ``ops/kda.py``'s)
+    over ``operands`` — ``(shape, dtype)`` of q, k, v, g, beta and the
+    carried state —, chunks of 64, a loss that reads both results
+    differentiated with respect to ``wrt`` (none: the two results
+    themselves), compiled for the described chip."""
+    args = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                 for shape, dtype in operands)
 
     def loss(*a):
         o, last = fn(*a[:5], chunk=64, state=a[5])
@@ -393,6 +388,16 @@ def _rule_at_the_cells_shape(one_chip, fn, wrt):
     step = (jax.value_and_grad(loss, argnums=wrt) if wrt
             else lambda *a: fn(*a[:5], chunk=64, state=a[5]))
     return jax.jit(step).lower(*args).compile()
+
+
+def _rule_at_the_cells_shape(one_chip, fn, wrt):
+    """``qwen3next-policy.update``'s shape: two 8192-token episodes, 32
+    value heads over 16 key heads of 128, bfloat16 from a carried state."""
+    b, t, hk, h, w = 2, 8192, 16, 32, 128
+    lo, f32 = jnp.bfloat16, jnp.float32
+    return _rule_compiled(one_chip, fn, wrt, (
+        ((b, t, hk, w), lo), ((b, t, hk, w), lo), ((b, t, h, w), lo),
+        ((b, t, h), f32), ((b, t, h), f32), ((b, h, w, w), f32)))
 
 
 def _rule_paths(compiled):
@@ -453,6 +458,54 @@ def test_the_plain_rule_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()
     # arguments, cotangents and a step's intermediates: 2.3 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
+def _lane_rule_at_the_cells_shape(one_chip, fn, wrt):
+    """``kimi-linear-policy.update``'s shape: one 16,384-token episode, 32
+    heads of 128, a decay a key lane, bfloat16 from a carried state."""
+    b, t, h, w = 1, 16384, 32, 128
+    lo, f32 = jnp.bfloat16, jnp.float32
+    return _rule_compiled(one_chip, fn, wrt, (
+        ((b, t, h, w), lo),) * 3 + (
+        ((b, t, h, w), f32), ((b, t, h), f32), ((b, h, w, w), f32)))
+
+
+def test_the_lane_decay_rule_compiles_for_v5e(one_chip):
+    """The rule's Pallas kernels (``ops/kda_pallas.py``), forward and every
+    gradient: three Mosaic calls — ``kda_fwd``, and in the backward
+    ``kda_states`` + ``kda_bwd`` — each under ``relayrl_kda`` and under no
+    deeper ``relayrl_`` name (the benchmark's ``kda_ms`` reads the exact
+    scope), no loop left under the scope, and of chunk-shaped arrays only
+    the chunk-start states (0.54 GB) and the solve's tiles (0.13 GB as the
+    chip pads their 64 lanes) in HBM beside the views of this test's
+    head-by-head operands: temporaries under 1.7 GB."""
+    from relayrl_tpu.ops import kda_pallas
+
+    compiled = _lane_rule_at_the_cells_shape(
+        one_chip, kda_pallas.kda_pallas, tuple(range(6)))
+    text = compiled.as_text()
+    calls = re.findall(r'(%[\w.\-]+) = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) == [
+        "%" + kda_pallas.BWD_NAME, "%" + kda_pallas.FWD_NAME,
+        "%" + kda_pallas.STATES_NAME]
+    for name, path in calls:
+        assert re.findall(r"relayrl_\w+", path)[-1] == scopes.KDA_NAME, path
+        assert ("transpose(" in path) == (kda_pallas.FWD_NAME not in name)
+    assert not re.findall(r"\bwhile\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.7e9
+
+
+def test_a_lane_decay_rule_nobody_differentiates_is_one_call(one_chip):
+    """The prefill's call: ``kda_fwd`` alone, no solve's tiles and no
+    chunk-start states written."""
+    from relayrl_tpu.ops import kda_pallas
+
+    compiled = _lane_rule_at_the_cells_shape(one_chip,
+                                             kda_pallas.kda_pallas, ())
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and kda_pallas.FWD_NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
 
 
 @pytest.mark.parametrize("columns,bias,scope", [

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _util import kernel_calls
 
 from relayrl_tpu.ops import gdn as rule
 from relayrl_tpu.ops.gdn import gdn, gdn_step
@@ -427,27 +428,15 @@ def test_kernels_bfloat16_operands_accumulate_in_float32():
             scale * 2.0 ** -6), wrt
 
 
-def _kernel_calls(jaxpr):
-    """``[(kernel name, number of results)]`` of every ``pallas_call`` in a
-    jaxpr, inner jaxprs included."""
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["name"], len(eqn.outvars)))
-        for inner in jax.core.jaxprs_in_params(eqn.params):
-            found += _kernel_calls(inner)
-    return found
-
-
 def test_a_rule_nobody_differentiates_writes_no_states():
     """The prefill's call is ``gdn_fwd`` alone with its two results; a
     differentiated one also writes the solve's tiles, and the chunk-start
     states are made in the backward only."""
     a = _tiled(128)
-    assert _kernel_calls(jax.make_jaxpr(_kernels)(**a).jaxpr) == [
+    assert kernel_calls(jax.make_jaxpr(_kernels)(**a).jaxpr) == [
         ("gdn_fwd", 2)]
     grad = jax.make_jaxpr(jax.grad(lambda a: jnp.sum(_kernels(**a)[0])))(a)
-    assert sorted(_kernel_calls(grad.jaxpr)) == [
+    assert sorted(kernel_calls(grad.jaxpr)) == [
         ("gdn_bwd", 6), ("gdn_fwd", 3), ("gdn_states", 1)]
 
 
@@ -468,7 +457,7 @@ def test_under_the_mixers_checkpoint_the_forward_runs_once():
         kept = jax.checkpoint(
             mixer, policy=jax.checkpoint_policies.save_only_these_names(
                 *names))
-        found = _kernel_calls(jax.make_jaxpr(jax.grad(kept))(a).jaxpr)
+        found = kernel_calls(jax.make_jaxpr(jax.grad(kept))(a).jaxpr)
         return sorted(name for name, _ in found)
 
     assert calls(_GDN_OUT, _GDN_SOLVE) == ["gdn_bwd", "gdn_fwd",

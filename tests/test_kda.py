@@ -54,7 +54,7 @@ def _highest():
                                      (40, 16), (96, 32)])
 def test_chunks_are_the_rule_a_token_at_a_time(T, chunk):
     args = _inputs(T)
-    o, last = kda.kda(*args, chunk)
+    o, last = kda.kda_xla(*args, chunk)
     o_ref, last_ref = _by_step(*args)
     np.testing.assert_allclose(o, o_ref, atol=5e-6)
     np.testing.assert_allclose(last, last_ref, atol=5e-6)
@@ -62,11 +62,11 @@ def test_chunks_are_the_rule_a_token_at_a_time(T, chunk):
 
 def test_a_state_carried_between_calls_is_one_call():
     args = _inputs(100)
-    o, last = kda.kda(*args, 32)
+    o, last = kda.kda_xla(*args, 32)
     head = tuple(a[:, :37] for a in args)
     tail = tuple(a[:, 37:] for a in args)
-    o1, s1 = kda.kda(*head, 32)
-    o2, s2 = kda.kda(*tail, 32, s1)
+    o1, s1 = kda.kda_xla(*head, 32)
+    o2, s2 = kda.kda_xla(*tail, 32, s1)
     np.testing.assert_allclose(jnp.concatenate([o1, o2], 1), o, atol=5e-6)
     np.testing.assert_allclose(s2, last, atol=5e-6)
 
@@ -78,9 +78,9 @@ def test_right_padded_episodes_need_nothing():
     n = 50
     real = (jnp.arange(80) < n)[None, :, None]
     junk = _inputs(80, seed=7)
-    o, last = kda.kda(q, k, v, jnp.where(real[..., None], g, 0.0),
+    o, last = kda.kda_xla(q, k, v, jnp.where(real[..., None], g, 0.0),
                       jnp.where(real, beta, 0.0), 32)
-    o2, _ = kda.kda(*(jnp.where(real[..., None] if a.ndim == 4 else real,
+    o2, _ = kda.kda_xla(*(jnp.where(real[..., None] if a.ndim == 4 else real,
                                 a, b) for a, b in zip(
                                     (q, k, v, g, beta), junk)), 32)
     np.testing.assert_allclose(o[:, :n], o2[:, :n], atol=5e-6)
@@ -97,12 +97,12 @@ def test_small_decays_over_a_whole_chunk_stay_finite_and_equal(alpha):
     g = jnp.full((B, 128, H, K), np.log(alpha), jnp.float32)
     # a few lanes that do not decay at all beside those that vanish
     g = g.at[..., ::5].set(0.0)
-    o, last = kda.kda(q, k, v, g, beta, 64)
+    o, last = kda.kda_xla(q, k, v, g, beta, 64)
     o_ref, last_ref = _by_step(q, k, v, g, beta)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(last).all())
     np.testing.assert_allclose(o, o_ref, atol=5e-6)
     np.testing.assert_allclose(last, last_ref, atol=5e-6)
-    grads = jax.grad(lambda *a: jnp.sum(kda.kda(*a, 64)[0]),
+    grads = jax.grad(lambda *a: jnp.sum(kda.kda_xla(*a, 64)[0]),
                      argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     assert all(bool(jnp.isfinite(a).all()) for a in grads)
 
@@ -114,14 +114,14 @@ def test_with_every_lanes_decay_equal_kda_is_gdn():
     q, k, v, g, beta = _inputs(100)
     g1 = g[..., 0]
     wide = lambda g1: jnp.broadcast_to(g1[..., None], g.shape)
-    o, last = kda.kda(q, k, v, wide(g1), beta, 64)
+    o, last = kda.kda_xla(q, k, v, wide(g1), beta, 64)
     o_gdn, last_gdn = gdn.gdn_xla(q, k, v, g1, beta, 64)
     np.testing.assert_allclose(o, o_gdn, atol=5e-6)
     np.testing.assert_allclose(last, last_gdn, atol=5e-6)
     w = jnp.asarray(np.random.default_rng(3).normal(size=o.shape),
                     jnp.float32)
     ours = jax.grad(lambda q, k, v, g1, beta: jnp.sum(
-        kda.kda(q, k, v, wide(g1), beta, 64)[0] * w),
+        kda.kda_xla(q, k, v, wide(g1), beta, 64)[0] * w),
         argnums=(0, 1, 2, 3, 4))(q, k, v, g1, beta)
     theirs = jax.grad(lambda *a: jnp.sum(gdn.gdn_xla(*a, 64)[0] * w),
                       argnums=(0, 1, 2, 3, 4))(q, k, v, g1, beta)
@@ -146,7 +146,7 @@ def test_all_five_cotangents_and_the_states():
             return jnp.sum(o * w_o) + jnp.sum(last * w_s)
         return f
 
-    ours = jax.grad(loss(lambda *a: kda.kda(*a[:5], 32, a[5])),
+    ours = jax.grad(loss(lambda *a: kda.kda_xla(*a[:5], 32, a[5])),
                     argnums=tuple(range(6)))(*args, s0)
     theirs = jax.grad(loss(lambda *a: _by_step(*a)),
                       argnums=tuple(range(6)))(*args, s0)
@@ -157,9 +157,9 @@ def test_all_five_cotangents_and_the_states():
 
 def test_bfloat16_operands_stay_near_the_float32_rule():
     args = _inputs(96, seed=8)
-    o32, _ = kda.kda(*args, 32)
+    o32, _ = kda.kda_xla(*args, 32)
     q, k, v, g, beta = args
-    o16, last = kda.kda(*(a.astype(jnp.bfloat16) for a in (q, k, v)), g,
+    o16, last = kda.kda_xla(*(a.astype(jnp.bfloat16) for a in (q, k, v)), g,
                         beta, 32)
     assert o16.dtype == jnp.bfloat16 and last.dtype == jnp.float32
     assert float(jnp.abs(o16.astype(jnp.float32) - o32).max()) < 0.02
@@ -201,7 +201,7 @@ def test_heads_that_are_no_multiple_of_a_maps_step(heads):
                                            + (1,) * (a.ndim - 3)),
                                jnp.float32)
                  for a in one)
-    o, last = kda.kda(*args, 16)
+    o, last = kda.kda_xla(*args, 16)
     for h in range(heads):
         head = tuple(jnp.concatenate([a[:, :, h:h + 1]] * H, axis=2)
                      for a in args)

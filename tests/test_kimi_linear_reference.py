@@ -176,8 +176,14 @@ class TestSystemAgainstReference:
         logp_ref, v_ref = reference.forward(params, obs, cfg)
         assert float(over_tokens(jnp.abs(logp - logp_ref).max(-1))) < atol
         assert float(over_tokens(jnp.abs(v - v_ref))) < atol
-        # the rule has one form: no record of a pick, and none of ``gdn``'s
+        # the record of what the rule ran as: plain XLA off a TPU, under a
+        # key of its own shape (``gdn``'s record stays empty)
         assert policy.gdn_backends == {}
+        assert list(policy.kda_backends.values()) == ["kda_xla"]
+        (rows, heads, key_dim, value_dim, _), = policy.kda_backends
+        linear = cfg["linear_attn_config"]
+        assert (rows, heads, key_dim, value_dim) == (
+            T, linear["num_heads"], linear["head_dim"], linear["head_dim"])
 
     def test_the_blockwise_form_agrees(self, reference, cfg):
         """q and k 12 wide, v 8, through the blockwise form ("flash"
