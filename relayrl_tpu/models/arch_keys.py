@@ -56,7 +56,10 @@ MOE_KEYS: Mapping[str, str] = {
     "moe_router": "router", "moe_expert_bias": "expert_bias",
     "moe_held": "held", "moe_routed_scaling": "routed_scaling",
     "moe_shared_d_ff": "shared_d_ff",
-    "moe_shared_expert_gate": "shared_gate"}
+    "moe_shared_expert_gate": "shared_gate",
+    # the routed experts' width where it is narrower than d_model: one
+    # down-projection before the sort, one up-projection after the un-sort
+    "moe_latent": "latent"}
 
 # An operator's own settings (``layers.OPERATORS``' names; a block's
 # ``cfg``). "none", the FFN alone, has none.

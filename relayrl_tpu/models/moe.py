@@ -67,6 +67,20 @@ with ``w_s [d]`` (``moe_shared_expert_gate``, no bias): one scalar a token
 times the shared expert's output (Qwen3-Next's, Qwen2-MoE's), in float32
 under the same part, outside the held share like the expert it gates.
 
+**Experts in a latent** (arch ``moe_latent``: the width; unset, the residual
+stream's own). The routed experts then work in a space narrower than the
+stream: ONE down-projection of every token before the sort (``h = u W_dn``,
+``moe_latent_down``, ``d -> latent``), the experts' stacks ``[E, latent,
+ff]`` / ``[E, ff, latent]``, the weighted sum (and ``moe_routed_scaling``) in
+the latent, ONE up-projection after the un-sort (``moe_latent_up``, ``latent
+-> d``) — neither with a bias, a norm or an activation of its own
+(Nemotron-3-Super's ``moe_latent_size`` 1024 under a stream of 4096). The
+router and the shared expert read the full-width rows. Both projections are
+dense matmuls under the part ``relayrl_moe_latent``; the sort, the row
+buffers and the custom VJP below move ``latent``-wide rows. Every chip of a
+layer computes the down-projection alike and the up-projection is linear, so
+the shares of the chips that divide a layer still add up to the layer.
+
 Dispatch is **sparse** on one device: the
 N·k token-slots are sorted by expert, each expert's rows go through one
 grouped matmul per weight stack (group sizes = a bincount of the chosen
@@ -150,7 +164,8 @@ the gate-collapse failure mode that omission leaves open).
 
 Shapes: tokens flatten to ``[N = B*T, d]``; expert stacks are
 ``moe_w_up`` / ``moe_w_gate [E, d, ff]`` and ``moe_w_down [E, ff, d]``
-(``E`` = the held count where ``moe_held`` is set).
+(``E`` = the held count where ``moe_held`` is set; ``d`` = ``moe_latent``
+where that is set).
 """
 
 from __future__ import annotations
@@ -168,6 +183,7 @@ from relayrl_tpu.ops.scopes import (
     FFN,
     HELD_EXPERTS_NAME,
     MOE_ELEMENTWISE,
+    MOE_LATENT,
     MOE_ROUTE,
     MOE_ROWS,
 )
@@ -529,6 +545,9 @@ class MoEMLP(nn.Module):
     shared_d_ff: int | None = None
     # sigmoid(u w_s) times the shared expert's output (module docstring)
     shared_gate: bool = False
+    # the routed experts' width where it is not the stream's (module
+    # docstring, "Experts in a latent"); None: d_model
+    latent: int | None = None
 
     @nn.compact
     def __call__(self, x, route_x=None):
@@ -546,6 +565,13 @@ class MoEMLP(nn.Module):
         if not (0 <= first and 0 < n_held and first + n_held <= n_exp):
             raise ValueError(f"moe_held {self.held} outside 0..{n_exp}")
         partial = n_held < n_exp
+        if self.latent is not None and (
+                isinstance(self.latent, bool)
+                or not isinstance(self.latent, int) or self.latent < 1):
+            raise ValueError(
+                f"moe_latent {self.latent!r}: the width the routed experts "
+                f"work in, a whole number of at least 1 (unset: d_model)")
+        width = self.latent or d
 
         # the layer's parts carry their names onto the device
         # (ops/scopes.py): the router and the sort, the row gathers, and the
@@ -567,16 +593,24 @@ class MoEMLP(nn.Module):
             if self.routed_scaling != 1.0:
                 top_w = top_w * float(self.routed_scaling)
 
+        # the rows the experts read: the tokens, or their latent
+        rows_in = tokens
+        if self.latent:
+            with jax.named_scope(MOE_LATENT):
+                rows_in = nn.Dense(width, dtype=cd, use_bias=False,
+                                   name="moe_latent_down")(tokens.astype(cd))
+
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         gated = self.ffn in GATED_FFN
         with jax.named_scope(MOE_ELEMENTWISE):  # the stacks' casts
             if gated:
                 w_gate = self.param("moe_w_gate", init,
-                                    (n_held, d, self.d_ff),
+                                    (n_held, width, self.d_ff),
                                     jnp.float32).astype(cd)
-            w_up = self.param("moe_w_up", init, (n_held, d, self.d_ff),
+            w_up = self.param("moe_w_up", init, (n_held, width, self.d_ff),
                               jnp.float32).astype(cd)
-            w_down = self.param("moe_w_down", init, (n_held, self.d_ff, d),
+            w_down = self.param("moe_w_down", init,
+                                (n_held, self.d_ff, width),
                                 jnp.float32).astype(cd)
 
         # token-slots per (held) expert: the grouped matmuls' group sizes
@@ -592,7 +626,7 @@ class MoEMLP(nn.Module):
                 jnp.arange(n)[:, None], top_idx].set(top_w)      # [N, E]
             if partial:
                 weights = weights[:, first:first + n_held]
-            xs = tokens.astype(cd)
+            xs = rows_in.astype(cd)
 
             def up_proj(w):
                 return jnp.einsum("nd,edf->enf", xs, w,
@@ -642,7 +676,7 @@ class MoEMLP(nn.Module):
                 rows = row_buffer(n * k, n_held, n_exp)
                 # (the cast in each branch: where it stands in the program)
                 with jax.named_scope(MOE_ELEMENTWISE):
-                    xs = tokens.astype(cd)
+                    xs = rows_in.astype(cd)
                 with jax.named_scope(MOE_ROUTE):
                     padded = jnp.pad(row_to_slot, (0, -(n * k) % rows))
                 # its parts are named inside, in both of its loops
@@ -655,7 +689,7 @@ class MoEMLP(nn.Module):
                     token_of_row = (row_to_slot % n if choice_major
                                     else row_to_slot // k)
                 with jax.named_scope(MOE_ELEMENTWISE):
-                    xs = tokens.astype(cd)
+                    xs = rows_in.astype(cd)
                 # round the CALLS: a custom_vjp's backward carries the
                 # scopes of its call, not those opened in its forward
                 with jax.named_scope(MOE_ROWS):
@@ -686,6 +720,11 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "expert_slots", jnp.int32(n * k))
         self.sow("intermediates", "row_passes", row_passes)
         self.sow("intermediates", "row_buffer", jnp.int32(rows))
+        if self.latent:
+            with jax.named_scope(MOE_LATENT):
+                y = nn.Dense(d, dtype=cd, use_bias=False,
+                             name="moe_latent_up")(y.astype(cd)).astype(
+                                 jnp.float32)
         if self.shared_d_ff:
             y = y + _shared_ffn(self, tokens.astype(cd), gated, tokens)
         return y.reshape(B, T, d).astype(x.dtype)

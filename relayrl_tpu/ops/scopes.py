@@ -43,13 +43,14 @@ FFN = "relayrl_ffn"                  # a layer's dense FFN, norm and residual
 MOE_ROUTE = "relayrl_moe_route"      # router, top-k, the sort, load counts
 MOE_ROWS = "relayrl_moe_rows"        # tokens -> rows, rows -> tokens
 MOE_ELEMENTWISE = "relayrl_moe_elementwise"  # between and after the matmuls
+MOE_LATENT = "relayrl_moe_latent"    # experts in a latent: down before, up after
 HEADS = "relayrl_heads"              # final norm, pi / vf heads, logp, entropy
 OBS_PREP = "relayrl_obs_prep"        # cnn: cast, scale, relayout on entry
 CONV = "relayrl_conv"                # cnn: the conv stack and its dense layer
 
 DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, INDEX, SPARSE_ATTN,
-                 FFN, MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE, HEADS, OBS_PREP,
-                 CONV)
+                 FFN, MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE, MOE_LATENT, HEADS,
+                 OBS_PREP, CONV)
 
 # -- a looped trunk's pass, outside the parts ---------------------------------
 LOOP_PASS = "relayrl_loop_pass"      # models/transformer.py's loop

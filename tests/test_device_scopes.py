@@ -41,6 +41,7 @@ from relayrl_tpu.ops.scopes import (
     LOSS,
     MAMBA_CONV_NAME,
     MOE_ELEMENTWISE,
+    MOE_LATENT,
     MOE_ROUTE,
     MOE_ROWS,
     OBS_PREP,
@@ -97,6 +98,24 @@ FAMILIES = {
                   "norm": "rms", "positions": "none", "use_bias": False,
                   "ffn": "relu2"},
                  TRUNK + MOE + (FFN, SSD_NAME, MAMBA_CONV_NAME)),
+    # experts in a latent narrower than the stream beside a shared expert
+    # at the stream's width, under the block checkpoint
+    # (nemotron3-super-policy)
+    "latent_experts": ({**SEQ, "kind": "transformer_moe_discrete",
+                        "n_layers": 3, "n_heads": 4, "n_kv_heads": 1,
+                        "head_dim": 8,
+                        "layer_types": ["ffn", "mamba2", "attention"],
+                        "block_checkpoint": True,
+                        "mamba_heads": 4, "mamba_head_dim": 8,
+                        "mamba_state": 8, "mamba_groups": 2,
+                        "mamba_chunk": 4, "moe_experts": 8, "moe_top_k": 3,
+                        "moe_held": [2, 4], "moe_d_ff": 12, "moe_latent": 8,
+                        "moe_router": "sigmoid", "moe_expert_bias": True,
+                        "moe_routed_scaling": 5.0, "moe_shared_d_ff": 24,
+                        "norm": "rms", "positions": "none",
+                        "use_bias": False, "ffn": "relu2"},
+                       TRUNK + MOE + (MOE_LATENT, FFN, SSD_NAME,
+                                      MAMBA_CONV_NAME)),
     # linear-attention layers (a delta rule over two chunks) beside a gated
     # attention layer with a partial rotary and zero-centred norms, a gated
     # shared expert (qwen3next-policy)
@@ -229,7 +248,7 @@ USES = [(family, scope) for family, (_arch, used) in FAMILIES.items()
 def test_one_list_of_names():
     """Every name a ``with`` line can open is a constant of the one module,
     and the lists hold each once."""
-    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 14
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 15
     assert not set(DEVICE_SCOPES) & set(scopes.KERNEL_SCOPES)
     used = {scope for _family, scope in USES}
     assert used == set(DEVICE_SCOPES) | set(OWN_NAMES)
@@ -335,7 +354,7 @@ def test_the_checkpoints_second_forward_is_told_apart(paths):
         assert {scope for p in kind for scope in _part_names(p)} >= {
             OP_PROJ, FFN}
         assert all(loop_trace.PASS in p for p in kind)
-    # no other family's trunk is checkpointed a block
+    # a trunk without the key is not checkpointed a block
     assert not [p for p in paths("gpt2") if loop_trace.RECOMPUTED in p]
 
 

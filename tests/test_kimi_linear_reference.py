@@ -72,8 +72,17 @@ def _system(reference, cfg, precision, seed=0, **over):
                 "act_dim": cfg["act_dim"], "has_critic": True,
                 "precision": precision, **kwargs}
         policy = build_policy(arch)
-        _BUILT[key] = policy, policy.init_params(jax.random.PRNGKey(seed))
+        # (one program: op by op the init costs the suite's clock a minute)
+        _BUILT[key] = policy, jax.jit(policy.init_params)(
+            jax.random.PRNGKey(seed))
     return _BUILT[key]
+
+
+def _outputs(policy, params, obs, act_dim):
+    """``_all_logp_v`` as one program (op by op it costs the suite's clock
+    half a minute a call)."""
+    return jax.jit(lambda p, o: _all_logp_v(policy, p, o, act_dim))(params,
+                                                                    obs)
 
 
 def _obs(cfg, seed=1, batch=2):
@@ -172,7 +181,7 @@ class TestSystemAgainstReference:
                                           over_tokens, atol):
         policy, params = _system(reference, cfg, precision)
         obs = _obs(cfg)
-        logp, v = _all_logp_v(policy, params, obs, cfg["act_dim"])
+        logp, v = _outputs(policy, params, obs, cfg["act_dim"])
         logp_ref, v_ref = reference.forward(params, obs, cfg)
         assert float(over_tokens(jnp.abs(logp - logp_ref).max(-1))) < atol
         assert float(over_tokens(jnp.abs(v - v_ref))) < atol
@@ -191,7 +200,7 @@ class TestSystemAgainstReference:
         policy, params = _system(reference, cfg, "float32",
                                  attention="flash", attention_block=8)
         obs = _obs(cfg)
-        got = _all_logp_v(policy, params, obs, cfg["act_dim"])
+        got = _outputs(policy, params, obs, cfg["act_dim"])
         assert _differs(got, reference.forward(params, obs, cfg)) < 1e-4
         assert policy.attention_backends[(T, 12, "float32")] == "blockwise"
 
@@ -202,7 +211,9 @@ class TestSystemAgainstReference:
             *_all_logp_v(policy, p, obs, cfg["act_dim"]), batch)
         ref_loss = lambda p: _impala_loss(
             *reference.forward(p, obs, cfg), batch)
-        (ls, gs), (lr, gr) = (jax.value_and_grad(f)(params)
+        # (one program a side: op by op the five layers' backward costs the
+        # suite's clock a minute and tests nothing more)
+        (ls, gs), (lr, gr) = (jax.jit(jax.value_and_grad(f))(params)
                               for f in (sys_loss, ref_loss))
         np.testing.assert_allclose(float(ls), float(lr), atol=2e-5)
         flat_ref = dict(jax.tree_util.tree_flatten_with_path(gr)[0])
@@ -219,9 +230,10 @@ class TestSystemAgainstReference:
         policy, params = _system(reference, cfg, "float32")
         window = np.asarray(_obs(cfg, batch=1)[0])
         logp_ref, v_ref = reference.forward(params, window[None], cfg)
+        step_window = jax.jit(policy.step_window)
         for t in (9, T):                # past a chunk's end, the last row
-            act, aux = policy.step_window(params, jax.random.PRNGKey(t),
-                                          jnp.asarray(window), t)
+            act, aux = step_window(params, jax.random.PRNGKey(t),
+                                   jnp.asarray(window), t)
             np.testing.assert_allclose(float(aux["v"]),
                                        float(v_ref[0, t - 1]), atol=3e-5)
             np.testing.assert_allclose(
@@ -240,12 +252,13 @@ class TestSystemAgainstReference:
                 "has_critic": True, "precision": "float32", **kwargs}
         arch["kind"] = arch.pop("model_kind")
         policy = build_policy(arch)
-        params = policy.init_params(jax.random.PRNGKey(0))
+        params = jax.jit(policy.init_params)(jax.random.PRNGKey(0))
         obs = _obs(cfg, batch=1)
-        _, _, v = policy.evaluate(params, obs, jnp.zeros((1, T), jnp.int32))
+        _, _, v = jax.jit(policy.evaluate)(params, obs,
+                                           jnp.zeros((1, T), jnp.int32))
+        step_window = jax.jit(policy.step_window)
         for t in (2, T):
-            _, aux = policy.step_window(params, jax.random.PRNGKey(t),
-                                        obs[0], t)
+            _, aux = step_window(params, jax.random.PRNGKey(t), obs[0], t)
             np.testing.assert_allclose(float(aux["v"]), float(v[0, t - 1]),
                                        atol=3e-5)
 
@@ -309,7 +322,7 @@ class TestSystemAgainstReference:
     def test_a_wrong_reference_is_told_apart(self, reference, cfg, wrong):
         policy, params = _system(reference, cfg, "float32")
         obs = _obs(cfg)
-        got = _all_logp_v(policy, params, obs, cfg["act_dim"])
+        got = _outputs(policy, params, obs, cfg["act_dim"])
         assert _differs(got, reference.forward(params, obs, cfg,
                                                wrong=wrong)) > 1e-3
 
@@ -322,7 +335,7 @@ class TestSystemAgainstReference:
             params = jax.tree_util.tree_map_with_path(
                 lambda path, a: a[1:] if "kda_conv_w" in jax.tree_util.
                 keystr(path) else a, params)
-        got = _all_logp_v(other, params, _obs(cfg), cfg["act_dim"])
+        got = _outputs(other, params, _obs(cfg), cfg["act_dim"])
         assert _differs(got, reference.forward(
             _system(reference, cfg, "float32")[1], _obs(cfg), cfg)) > 1e-3
 

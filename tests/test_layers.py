@@ -57,11 +57,11 @@ def test_the_passthrough_keys_are_the_declarations():
                    for k in keys])
     assert list(arch_keys.DECLARED) == declared
     assert base.ARCH_PASSTHROUGH_KEYS == arch_keys.TRUNK_KEYS + tuple(declared)
-    # no key declared twice, and the 67 the configurations' references ask
+    # no key declared twice, and those the configurations' references ask
     # base for (benchmark/reference/*.py) are all there (58 + kda's five and
-    # latent attention's four, PR 55)
+    # latent attention's four, PR 55; + moe_latent, PR 57)
     assert len(set(base.ARCH_PASSTHROUGH_KEYS)) == len(
-        base.ARCH_PASSTHROUGH_KEYS) == 66
+        base.ARCH_PASSTHROUGH_KEYS) == 67
 
 
 @pytest.mark.parametrize("key", arch_keys.DECLARED)

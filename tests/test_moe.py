@@ -794,12 +794,12 @@ class TestHeldExperts:
                                             n_layers=3, **arch)
             obs = jnp.asarray(np.random.default_rng(2).standard_normal(
                 (2, 8, 6)), jnp.float32)
-            *_, stats = policy.evaluate_stats(params, obs,
-                                              jnp.zeros((2, 8), jnp.int32))
+            *_, stats = jax.jit(policy.evaluate_stats)(
+                params, obs, jnp.zeros((2, 8), jnp.int32))
             update, state_of = _impala_update_of(policy)
             batch = {name: jnp.asarray(a) for name, a in
                      TrajectoryBatch.zeros(2, 8, 6, 3, True).items()}
-            _, metrics = update(state_of(params), {
+            _, metrics = jax.jit(update, donate_argnums=0)(state_of(params), {
                 **batch, "obs": obs, "valid": jnp.ones((2, 8)),
                 "act_mask": jnp.ones((2, 8, 3))})
             assert np.isfinite(float(metrics["LossTotal"]))

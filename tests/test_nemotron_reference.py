@@ -59,13 +59,37 @@ def cfg():
     return cfg
 
 
-def _system(reference, cfg, precision, seed=0, **over):
+def _policy(reference, cfg, precision, **over):
     kwargs = {**reference.program_kwargs(cfg), **over}
     arch = {"kind": kwargs.pop("model_kind"), "obs_dim": cfg["obs_dim"],
             "act_dim": cfg["act_dim"], "has_critic": True,
             "precision": precision, **kwargs}
-    policy = build_policy(arch)
-    return policy, policy.init_params(jax.random.PRNGKey(seed))
+    return build_policy(arch)
+
+
+def _system(reference, cfg, precision, seed=0, **over):
+    policy = _policy(reference, cfg, precision, **over)
+    # (one program, as the steps below: op by op the nine layers cost the
+    # suite's clock minutes and test nothing more)
+    return policy, jax.jit(policy.init_params)(jax.random.PRNGKey(seed))
+
+
+def _outputs(policy, params, obs, act_dim):
+    return jax.jit(lambda p, o: _all_logp_v(policy, p, o, act_dim))(params,
+                                                                    obs)
+
+
+@pytest.fixture(scope="module")
+def system(reference, cfg):
+    """The float32 trunk and its seeded weights, built once."""
+    return _system(reference, cfg, "float32")
+
+
+@pytest.fixture(scope="module")
+def outputs(system, cfg):
+    """... and its log-probabilities and values on the seeded rows."""
+    policy, params = system
+    return _outputs(policy, params, _obs(cfg), cfg["act_dim"])
 
 
 def _obs(cfg, seed=1, batch=2):
@@ -89,10 +113,11 @@ def _differs(a, b):
 
 
 class TestSystemAgainstReference:
-    def test_the_trunk_is_what_the_configuration_says(self, reference, cfg):
+    def test_the_trunk_is_what_the_configuration_says(self, reference, cfg,
+                                                      system):
         kwargs = reference.program_kwargs(cfg)
         assert kwargs["layer_types"] == KINDS
-        _, params = _system(reference, cfg, "float32")
+        _, params = system
         p = params["params"]
         assert "pos_embed" not in p
         m = p["block_0"]                    # M: one part, one norm
@@ -134,19 +159,19 @@ class TestSystemAgainstReference:
                                           over_tokens, atol):
         policy, params = _system(reference, cfg, precision)
         obs = _obs(cfg)
-        logp, v = _all_logp_v(policy, params, obs, cfg["act_dim"])
+        logp, v = _outputs(policy, params, obs, cfg["act_dim"])
         logp_ref, v_ref = reference.forward(params, obs, cfg)
         assert float(over_tokens(jnp.abs(logp - logp_ref).max(-1))) < atol
         assert float(over_tokens(jnp.abs(v - v_ref))) < atol
 
-    def test_impala_loss_and_every_gradient(self, reference, cfg):
-        policy, params = _system(reference, cfg, "float32")
+    def test_impala_loss_and_every_gradient(self, reference, cfg, system):
+        policy, params = system
         obs, batch = _obs(cfg), _batch(cfg)
         sys_loss = lambda p: _impala_loss(
             *_all_logp_v(policy, p, obs, cfg["act_dim"]), batch)
         ref_loss = lambda p: _impala_loss(
             *reference.forward(p, obs, cfg), batch)
-        (ls, gs), (lr, gr) = (jax.value_and_grad(f)(params)
+        (ls, gs), (lr, gr) = (jax.jit(jax.value_and_grad(f))(params)
                               for f in (sys_loss, ref_loss))
         np.testing.assert_allclose(float(ls), float(lr), atol=2e-5)
         flat_ref = dict(jax.tree_util.tree_flatten_with_path(gr)[0])
@@ -158,13 +183,15 @@ class TestSystemAgainstReference:
             assert (float(jnp.abs(g).max()) > 0) != (
                 "moe_expert_bias" in name), name
 
-    def test_the_readout_row_is_the_full_forwards_row(self, reference, cfg):
-        policy, params = _system(reference, cfg, "float32")
+    def test_the_readout_row_is_the_full_forwards_row(self, reference, cfg,
+                                                      system):
+        policy, params = system
         window = np.asarray(_obs(cfg, batch=1)[0])
         logp_ref, v_ref = reference.forward(params, window[None], cfg)
+        step_window = jax.jit(policy.step_window)
         for t in (1, 8, 9, 20, T):      # inside, at and past a chunk's end
-            act, aux = policy.step_window(params, jax.random.PRNGKey(t),
-                                          jnp.asarray(window), t)
+            act, aux = step_window(params, jax.random.PRNGKey(t),
+                                   jnp.asarray(window), t)
             np.testing.assert_allclose(float(aux["v"]),
                                        float(v_ref[0, t - 1]), atol=3e-5)
             np.testing.assert_allclose(
@@ -177,20 +204,21 @@ class TestSystemAgainstReference:
         policy, params = _system(reference, short, "float32")
         window = np.asarray(_obs(cfg, batch=1)[0])
         _, v_ref = reference.forward(params, window[None], short)
+        step_window = jax.jit(policy.step_window)
         for t in (3, 17, T):
-            _, aux = policy.step_window(params, jax.random.PRNGKey(t),
-                                        jnp.asarray(window), t)
+            _, aux = step_window(params, jax.random.PRNGKey(t),
+                                 jnp.asarray(window), t)
             np.testing.assert_allclose(float(aux["v"]),
                                        float(v_ref[0, t - 1]), atol=3e-5)
 
     def test_cached_decode_through_the_state_is_the_full_forward(
-            self, reference, cfg):
+            self, reference, cfg, system):
         """32 steps through the fourth kind of cache — each Mamba-2 layer's
         last three rows of ``xBC`` and its ``[H, P, N]`` state, whose size
         does not grow with the position — beside the attention layer's
         32-row pair and the expert layers' nothing: every step's value and
         log-probability equal the reference's full forward at that row."""
-        policy, params = _system(reference, cfg, "float32")
+        policy, params = system
         window = np.asarray(_obs(cfg, batch=1)[0])
         logp_ref, v_ref = reference.forward(params, window[None], cfg)
         cache = policy.init_cache(T)
@@ -205,8 +233,9 @@ class TestSystemAgainstReference:
             else:
                 assert c == ()
         assert policy.init_cache(4 * T)[0][1].shape == (1, 4, 8, 16)
+        step_cached = jax.jit(policy.step_cached)
         for t in range(T):
-            act, aux, cache = policy.step_cached(
+            act, aux, cache = step_cached(
                 params, jax.random.PRNGKey(t), cache, window[t], t)
             np.testing.assert_allclose(float(aux["v"]), float(v_ref[0, t]),
                                        atol=3e-5, err_msg=f"t={t}")
@@ -216,19 +245,20 @@ class TestSystemAgainstReference:
 
     @pytest.mark.parametrize("t0", [3, 19, T - 1])
     def test_a_prefilled_state_continues_as_the_full_forward(
-            self, reference, cfg, t0):
+            self, reference, cfg, system, t0):
         """Prefill ``t0`` real rows of a zero-padded window, then decode:
         the padding rows enter neither the state nor the convolution's
         rows."""
-        policy, params = _system(reference, cfg, "float32")
+        policy, params = system
         window = np.asarray(_obs(cfg, batch=1)[0])
         _, v_ref = reference.forward(params, window[None], cfg)
         padded = window.copy()
         padded[t0:] = 0.0
-        cache = policy.prefill_cache(params, policy.init_cache(T),
-                                     jnp.asarray(padded), t0)
+        cache = jax.jit(policy.prefill_cache)(
+            params, policy.init_cache(T), jnp.asarray(padded), t0)
+        step_cached = jax.jit(policy.step_cached)
         for t in range(t0, T):
-            _, aux, cache = policy.step_cached(
+            _, aux, cache = step_cached(
                 params, jax.random.PRNGKey(t), cache, window[t], t)
             np.testing.assert_allclose(float(aux["v"]), float(v_ref[0, t]),
                                        atol=3e-5, err_msg=f"t={t}")
@@ -243,17 +273,15 @@ class TestSystemAgainstReference:
         {"rope": True},                 # rotary positions on the attention
         {"top_k": 2},                   # an expert dropped per token
     ])
-    def test_a_wrong_reference_is_told_apart(self, reference, cfg, wrong):
-        policy, params = _system(reference, cfg, "float32")
-        obs = _obs(cfg)
-        got = _all_logp_v(policy, params, obs, cfg["act_dim"])
-        assert _differs(got, reference.forward(params, obs, cfg,
-                                               wrong=wrong)) > 1e-3
+    def test_a_wrong_reference_is_told_apart(self, reference, cfg, system,
+                                             outputs, wrong):
+        assert _differs(outputs, reference.forward(
+            system[1], _obs(cfg), cfg, wrong=wrong)) > 1e-3
 
-    def test_the_chunk_is_no_part_of_the_model(self, reference, cfg):
-        _, params = _system(reference, cfg, "float32")
-        other, _ = _system(reference, cfg, "float32", mamba_chunk=16)
-        got = _all_logp_v(other, params, _obs(cfg), cfg["act_dim"])
+    def test_the_chunk_is_no_part_of_the_model(self, reference, cfg, system):
+        params = system[1]
+        other = _policy(reference, cfg, "float32", mamba_chunk=16)
+        got = _outputs(other, params, _obs(cfg), cfg["act_dim"])
         assert _differs(got, reference.forward(params, _obs(cfg),
                                                cfg)) < 3e-5
 
@@ -262,16 +290,17 @@ class TestSystemAgainstReference:
         {"moe_routed_scaling": 1.0}, {"moe_top_k": 2}, {"moe_held": [3, 4]},
         {"moe_router": "softmax"}, {"norm_eps": 1e-2},
         {"positions": "rope", "rope_theta": 10000.0}])
-    def test_a_different_model_is_told_apart(self, reference, cfg, wrong):
-        _, params = _system(reference, cfg, "float32")
-        other, _ = _system(reference, cfg, "float32", **wrong)
-        got = _all_logp_v(other, params, _obs(cfg), cfg["act_dim"])
+    def test_a_different_model_is_told_apart(self, reference, cfg, system,
+                                             wrong):
+        params = system[1]
+        other = _policy(reference, cfg, "float32", **wrong)
+        got = _outputs(other, params, _obs(cfg), cfg["act_dim"])
         assert _differs(got, reference.forward(params, _obs(cfg),
                                                cfg)) > 1e-3
 
     def test_an_8_bit_trunk_is_further_off_than_bfloat16(self, reference,
-                                                         cfg):
-        _, params = _system(reference, cfg, "float32")
+                                                         cfg, system):
+        params = system[1]
         obs = _obs(cfg)
         exact = reference.forward(params, obs, cfg)
         errs = {}
