@@ -67,9 +67,9 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
     tx = make_impala_tx(lr, max_grad_norm, freeze, params_template)
     # a MoE trunk reports the expert load of the same forward
     # (``moe_load_max`` / ``moe_load_min`` / ``moe_held_slots`` /
-    # ``moe_row_passes``), a sparse-attention trunk the share of the causal
-    # pairs its selections kept (``index_kept_pct``); every other family
-    # reports {}
+    # ``moe_row_passes`` / ``moe_sorted_slots``), a sparse-attention trunk
+    # the share of the causal pairs its selections kept
+    # (``index_kept_pct``); every other family reports {}
     evaluate = policy.evaluate_stats or (
         lambda *args: (*policy.evaluate(*args), {}))
     # a loss the model itself brings (``Policy.own_loss``): the name it is
@@ -121,9 +121,9 @@ def make_impala_update(policy, lr: float, gamma: float, vf_coef: float,
             "LossTotal": total,
             "RhoMean": rho_mean,
             "KL": kl,
-            # moe_load_max / _min / moe_held_slots / moe_row_passes (MoE
-            # trunks), IndexLoss / index_kept_pct (sparse attention): only
-            # where the trunk has such layers
+            # moe_load_max / _min / moe_held_slots / moe_row_passes /
+            # moe_sorted_slots (MoE trunks), IndexLoss / index_kept_pct
+            # (sparse attention): only where the trunk has such layers
             **stats,
         }
         return ImpalaState(params=params, opt_state=opt_state, rng=state.rng,
@@ -211,9 +211,16 @@ class IMPALA(OnPolicyAlgorithm):
                     "newest update, summed over MoE layers (1 a layer "
                     "unless a held-experts layer got more rows than its "
                     "buffer has)"),
+                "moe_sorted_slots": reg.gauge(
+                    "relayrl_moe_sorted_slots",
+                    "slots the sparse dispatch put in expert order, newest "
+                    "update, summed over MoE layers (passes x the row "
+                    "buffer in a held-experts layer that counts its rows, "
+                    "all N*k a layer that sorts them or holds every "
+                    "expert)"),
             }
             self._fence_notes = ("moe_load_max", "moe_held_slots",
-                                 "moe_row_passes")
+                                 "moe_row_passes", "moe_sorted_slots")
         loop_steps = int(self.policy.arch.get("loop_steps", 1))
         if loop_steps > 1:
             # a looped trunk: a sample costs loop_steps passes, set once so

@@ -171,6 +171,16 @@ class Policy:
     # TPU, shapes that tile) or as plain XLA. Empty for a trunk without such
     # layers, None for other families.
     index_backends: Mapping[tuple, str] | None = None
+    # Sequence policies with expert layers: ``{(N*k slots, rows of the
+    # buffers, held, experts, k): "counted" | "sorted" | "plain"}`` for
+    # every shape of the sparse dispatch traced so far
+    # (models/moe.dispatch_form) — a held-experts layer counts its held
+    # choices into expert order where its shapes pay for it
+    # (models/moe.held_form) and sorts all N*k slots with the absent
+    # experts' behind where they do not; a layer that holds every expert
+    # sorts all N*k. Empty for a trunk without experts (and for the dense
+    # dispatch), None for other families.
+    moe_backends: Mapping[tuple, str] | None = None
     # MoE and sparse-attention trunks: ``evaluate_stats(params, obs, act,
     # mask) -> (logp, entropy, v, stats)`` — ``evaluate`` plus what the same
     # forward counted (``moe_load_max`` / ``moe_load_min``:

@@ -97,6 +97,13 @@ def block_ffn(block, x, layer_in):
         if block.moe_router_input not in ("ffn", "layer"):
             raise ValueError(f"unknown moe_router_input "
                              f"{block.moe_router_input!r} (ffn | layer)")
+        # the policy's record of what this layer's shapes run as, asked
+        # here and not from inside the layer: nothing stands between the
+        # layer's call and its kernels that was not there (ROADMAP 1.5)
+        if "moe" in block.fns:
+            block.fns["moe"](h.shape[0] * h.shape[1], block.moe_top_k,
+                             block.moe_experts, block.moe_kw.get("held"),
+                             block.moe_dispatch)
         h = MoEMLP(block.d_model, block.moe_d_ff or width,
                    block.moe_experts, block.moe_top_k, block.compute_dtype,
                    norm_topk_prob=block.moe_norm_topk_prob, ffn=block.ffn,
@@ -120,7 +127,29 @@ def block_ffn(block, x, layer_in):
 
 # -- the operator "none" (``layers``' interface) ----------------------------
 
-KERNELS = ()
+def _moe_record(arch):
+    """The expert layer's entry: nothing to pick — the layer takes its form
+    by its own rule (``models/moe.held_form``) — but the record of it,
+    ``Policy.moe_backends``: what each distinct layer shape's sparse
+    dispatch runs as, one ``[moe]`` line a shape."""
+    resolved: dict[tuple, str] = {}
+
+    def say(*shape):
+        from relayrl_tpu.models.moe import dispatch_form
+
+        said = dispatch_form(*shape)
+        if said is None or resolved.get(said[0]) == said[1]:
+            return      # the dense dispatch walks no slots; or said before
+        key, ran, text = said
+        resolved[key] = ran
+        print(f"[moe] {text} -> {ran} "
+              f"(platform {jax.default_backend()})", flush=True)
+
+    return {"moe": say}, {"moe_backends": resolved}
+
+
+# every layer kind ends in :func:`block_ffn`, which asks ``fns["moe"]``
+KERNELS = (_moe_record,)
 # the FFN is per row: a final layer runs for the readout row alone
 ROW_READOUT = True
 

@@ -93,52 +93,123 @@ permutations are gathers, forward and backward (a permutation's transpose
 is the gather by its inverse), because a TPU scatter-add of [N·k, d] rows
 is slower than the matmuls it serves.
 
-With held experts the layer **works on the rows it holds**. The slots of
-absent experts sort behind the held ones, so the L live rows (the held
-experts' group sizes, summed) come first in the sorted order, and the
-layer's row buffers — the dispatched rows, up and gate in float32, the down
-output — have **R rows, not N·k**: ``R = min(N·k, round_up(margin · N·k ·
-held / E, 512))`` (:func:`row_buffer`), what even routing would send this
-device times a margin (:data:`_ROW_MARGIN`, with the readings it was set
-from), in whole row tiles of the grouped matmuls. The layer walks the
-sorted order in **``ceil(L / R)`` passes**, a loop whose trip count the
-update computes: pass p takes rows ``[p R, (p + 1) R)`` through ONE copy of
-the grouped matmuls with pass-local group sizes and adds its tokens' shares
-to ``y [N, d]`` in float32. One pass while the routing stays under the
-margin — a learner that trains only the experts it holds draws the routing
-towards them, update by update — and as many as it takes beyond: a router
-that sends this device everything takes ``ceil(N·k / R)``. Every live slot
-is computed exactly once whatever the routing, the guarantee above
-unchanged: no capacity, no dropped slot, the same sums. Token → row is an
+With held experts the layer **works on the rows it holds**. Its row
+buffers — the dispatched rows, up and gate in float32, the down output —
+have **R rows, not N·k**: ``R = min(N·k, round_up(margin · N·k · held / E,
+512))`` (:func:`row_buffer`), what even routing would send this device times
+a margin (:data:`_ROW_MARGIN`, with the readings it was set from), in whole
+row tiles of the grouped matmuls. The L live rows (the held experts' group
+sizes, summed) stand in the order by expert and, inside an expert, by token,
+and the layer walks that order in **``ceil(L / R)`` passes**, a loop whose
+trip count the update computes: pass p takes rows ``[p R, (p + 1) R)``
+through ONE copy of the grouped matmuls with pass-local group sizes and adds
+its tokens' shares to ``y [N, d]`` in float32. One pass while the routing
+stays under the margin — a learner that trains only the experts it holds
+draws the routing towards them, update by update — and as many as it takes
+beyond: a router that sends this device everything takes ``ceil(N·k / R)``.
+Every live slot is computed exactly once whatever the routing, the guarantee
+above unchanged: no capacity, no dropped slot, the same sums; a token's held
+choices may lie in two passes, each adding its share. Token → row is an
 R-row gather (``tokens[token_of_row]``; in the backward the output's
 cotangent by the same index, times the row's router weight at R rows).
-Row → token is ONE N·k-row gather from the R-row buffer and a weighted sum
-over k: measured against an R-row scatter-add into ``[N, d]``, which costs
-206–320 ns a row where a gathered row costs 26–31 (PERF.md section 6,
-PR 35). **No arithmetic touches a row the kernels did not write**: what
-they leave in a pass's tail — rows past its live ones — is whatever the
-buffer held, and ``0 x`` that is NaN where that is NaN. A slot without a row
-in this pass (an absent expert's, another pass's) gathers row 0, a live
-row, and is then SELECTED away (``where``), in the forward's combine and in
-the tokens' gradient; the router weights' gradient selects its live rows at
-R rows; the stacks' gradients come from kernels that select their groups'
-rows themselves; and only selected values are accumulated across passes.
-Forward and backward are each a loop of their own under one
-``jax.custom_vjp`` (:func:`_held_experts`): the forward keeps the
-``[N, d]`` tokens, the router weights, the sort's index vectors and the
-stacks; the backward loop makes a pass's R-row buffers again from the
-tokens and runs that pass's transpose, accumulating the stacks' gradients,
-the tokens' and the router weights'. Differentiating THROUGH the loop (or
-through a ``lax.cond`` that picked a buffer size) would make every buffer
-of every pass a residual of the forward, written in full — zero-filled —
-for the passes not taken: the N·k-row buffers this form exists to avoid. A
-pass's experts, and their transpose, are each one inner ``jax.jit``
-(:data:`_shared_experts`, :func:`_shared_experts_vjp`): the held layers of
-a trunk share ONE trace and ONE lowering of the kernels — set-up time, not
-speed: the compiled update inlines the calls, twelve Mosaic calls a layer
-as before. The layer sows ``row_passes`` (the trips its forward loop
-counted as it ran) and ``row_buffer`` (R) beside ``expert_load``; the
-update reports ``moe_row_passes``.
+
+**How the order is made and how the rows come back: two walks, one rule.**
+A layer takes one of them by :func:`held_form`, a function of its static
+shapes ``(N, k, held, E)`` — no arch key, no knob —, and they share the
+buffers, the passes, :data:`_shared_experts` and nothing of the index
+arithmetic. Both make the same order (a stable sort by expert IS the order
+by expert and token), so the same rows lie in the same passes.
+
+* **Sorted** (:func:`_held_experts`; PR 35 / 36). ONE argsort of all N·k
+  slots, choice-major, the absent experts' behind the held ones, and an
+  N·k-element scatter that inverts it; a pass slices its R slots out of the
+  sorted order. Row → token is ONE N·k-row gather from the R-row buffer and a
+  weighted sum over k, a slot without a row in this pass reading row 0 and
+  SELECTED away. PR 35 measured that against an R-row scatter-add into
+  ``[N, d]``: 206–320 ns an added row where a gathered row costs 26–31, at
+  k 4–6 where N·k is 2–4 R — which decided the gather for the cells of
+  then (``lfm2-policy``, ``smallthinker-policy``).
+* **Counted** (:func:`_counted_experts`; PR 58 / 59). Nothing is sorted and
+  the only N·k-sized work is element-wise over ``top_idx``. The compaction
+  (:func:`_compact`, once a forward): which of a token's k choices are held —
+  at most ``h = min(k, held)``, a token's experts are distinct — and per
+  place ``j < h`` that choice's expert, its place among the k and its
+  weight, ``[N, h]`` each; then ONE cumulative count over ``[N, held]``
+  gives each held choice's row: its expert's first row plus the tokens
+  before it that chose that expert. A pass finds its R rows' (expert,
+  token) by compares against those running counts (:func:`_first_past`:
+  two levels of 128 and one R-row gather of a block's counts, 0.06–0.23 ms a
+  call where a binary search's 14 dependent gathers read 1.0–5.7 and all
+  R x N compares 0.19–0.99). Row → token is ONE R-row scatter-add into
+  ``[N, d]``, the rows added at their tokens: 33–140 ns an added row at R
+  rows, not PR 35's 206–320 at N·k. (Two other forms were built and taken
+  out. A segmented sum over the token-ordered rows lost wherever R x d is
+  large: XLA writes each shifted ``[R, d]`` float32 copy out. h N-row
+  gathers ``rows[row(t, j)]`` selected by ``j < count[t]`` and summed won
+  alone only at N·h / R <= 4, and the one cell that took them by that rule,
+  ``keye-vl2-policy`` at N·h = 4 R, read 23,900–23,981 samples/s with them
+  and 24,464–24,552 with the scatter-add, x 1.024 on each of five seeds:
+  PERF.md section 6, PR 59's review round.) A row's weight gradient is
+  added at its token's place (``[N, h]``, an R-element scatter-add) and goes
+  back to the k choices element-wise (:func:`_at_choices`): no N·k
+  ``slot_to_row``.
+
+**The rule** (:func:`held_form`): counted where ``N·k >= 16 R`` — the
+N·k-scale index work is what the layer spends its time on (top-22 of 512
+with 8 held: N·k = 32 R, 124 ms of dispatch round 14 ms of grouped matmuls) —
+or where 8 divides k; sorted elsewhere, and wherever the buffers are N·k
+rows long (one token's: nothing to save, and an eager ``init`` of 24 more
+programs). PR 58 ran every held layer counted: + 11.9 %, + 12.5 % and
++ 12.2 % of ``train_samples_per_s`` in the three cells the rule now names
+(``nemotron3-super-policy``, and at k = 8 ``kimi-linear-policy`` and
+``keye-vl2-policy``), + 0.1 % to + 1.1 % in the four at N·k / R = 2–8 with
+k = 4, 6, 10 — and the counted program's longer text cost the two smallest
+(N·k / R = 2 and 4) 1.7–2.4 s of warm set-up, + 15.9 % and + 7.8 % of
+``setup_s`` as the driver read them: refused. Those four keep the sorted
+walk, lowered text for text as it was (``tests/test_flash_tpu_compile.py``
+pins two of them). The sorted walk before PR 59 ran a held layer's slots
+token-major where 8 divides k (the plain branch's order, below), the slow
+N·k-row gather-and-sum: 15.1–16.7 ms a call at 16,384 tokens and k = 8
+where the choice-major shapes read 3.1–10.1. It is written choice-major
+alone now, and at k = 8 that was measured too, in ``keye-vl2-policy.update``
+(N·k = 4 R): 21,302–21,357 samples/s token-major, 23,412–23,478
+choice-major, 24,464–24,552 counted — so 8 | k stays a condition, on that
+one cell's reading (PERF.md section 6, PR 58 and 59).
+
+**No arithmetic touches a row the kernels did not write**, on either walk:
+what they leave in a pass's tail — rows past its live ones — is whatever the
+buffer held, and ``0 x`` that is NaN where that is NaN. A row past the live
+ones, and a slot or place without a row in this pass (which reads row 0, a
+live row), is SELECTED away (``where``) before it is weighted or added, in
+the forward's combine, in the tokens' gradient and in the router weights';
+the stacks' gradients come from kernels that select their groups' rows
+themselves; and only selected values are accumulated across passes. Forward
+and backward are each a loop of their own under one ``jax.custom_vjp`` a
+walk: the forward keeps the ``[N, d]`` tokens, the router weights, the
+walk's index vectors and the stacks; the backward loop makes a pass's R-row
+buffers again from the tokens and runs that pass's transpose, accumulating
+the stacks' gradients, the tokens' and the router weights'. Differentiating
+THROUGH the loop (or through a ``lax.cond`` that picked a buffer size) would
+make every buffer of every pass a residual of the forward, written in full —
+zero-filled — for the passes not taken: the N·k-row buffers this form exists
+to avoid. A pass's experts, and their transpose, are each one inner
+``jax.jit`` (:data:`_shared_experts`, :func:`_shared_experts_vjp`): the held
+layers of a trunk share ONE trace and ONE lowering of the kernels — set-up
+time, not speed: the compiled update inlines the calls, twelve Mosaic calls
+a layer as before. Set-up is also why nothing stands between the layer's
+call and those kernels that need not (ROADMAP 1.5: a warm ``build`` moves by
+seconds with a frame more above a lowering): the rule is a plain call that
+returns before anything is traced, and the policy's record of what each
+layer shape ran as — one ``[moe]`` line a distinct shape, e.g. ``[moe]
+slots=360448 rows=11264 held=8/512 k=22 -> counted``
+(:func:`dispatch_form`, ``Policy.moe_backends``) — is written by the block
+before it calls the layer (``layers/block.py``), not from inside; the layer
+and the record take the branch from ONE resolution (:func:`layer_form`).
+The layer sows ``row_passes`` (the trips its forward loop counted as it ran),
+``row_buffer`` (R) and ``sorted_slots`` (the slots it put in expert order:
+passes x R counted, N·k sorted and where every expert is held) beside
+``expert_load``; the update reports ``moe_row_passes`` and
+``moe_sorted_slots``.
 
 The **dense** path — every expert on every token, a dense ``[N, E]`` weight
 mask in the combine einsum, E/k times the FLOPs — is what GSPMD partitions:
@@ -172,7 +243,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -325,9 +396,101 @@ def row_buffer(n_slots: int, n_held: int, n_exp: int) -> int:
     return min(n_slots, rows * _ROW_TILE)
 
 
-def _passes(load, rows: int):
-    """Passes that cover the live rows: ``ceil(load.sum() / rows)``."""
-    return (load.sum() + (rows - 1)) // rows
+# slots a buffer row (N*k / R) from which a held layer counts its rows
+_COUNTED_SLOTS_A_ROW = 16
+
+
+def held_form(n: int, k: int, n_held: int, n_exp: int) -> str:
+    """Which of its two walks a held-experts layer of these static shapes
+    takes (module docstring): ``"counted"`` or ``"sorted"``. Sorted where
+    the buffers have a row a slot (``R = N*k``: a decode step, the one token
+    a model's parameters are made at) — counting saves nothing there and is
+    24 more eager programs an ``init``. Else counted
+
+    * where 8 divides k: ``keye-vl2-policy.update`` (k = 8, N*k = 4 R)
+      reads 24,464-24,552 samples/s counted against 23,412-23,478 sorted
+      (and 21,302-21,357 on the token-major sorted walk it had before
+      PR 59) — the one cell behind this condition: at k = 4, 6 and 10 and
+      N*k / R = 2-8 the two walks draw, and ``kimi-linear-policy`` (k = 8)
+      counts by the next condition too;
+    * where ``N*k >= 16 R``: the N*k-scale index work — the argsort, the
+      scatter that inverts it, the N*k-row gather — is what the layer
+      spends its time on (top-22 of 512 with 8 held: N*k = 32 R).
+
+    Elsewhere the two are a draw on the chip (+ 0.1 % to + 1.1 % in the four
+    cells at N*k / R = 2-8) and the counted walk's longer program costs
+    1.7-2.4 s of a warm set-up: sorted (PERF.md section 6, PR 58 and 59).
+    Every reading behind the rule is at N = 16,384 tokens an update: the
+    counted walk's search (:func:`_first_past`) compares R x (held * N / 128
+    + 128) a pass, which with R growing as N is quadratic in the tokens
+    (0.06-0.23 ms a call here), where the sort is N*k log N*k — an update of
+    64k tokens or more reads the rule again before it trusts it."""
+    rows = row_buffer(n * k, n_held, n_exp)
+    if rows == n * k:
+        return "sorted"
+    counted = k % 8 == 0 or n * k >= _COUNTED_SLOTS_A_ROW * rows
+    return "counted" if counted else "sorted"
+
+
+class _Form(NamedTuple):
+    """What a layer runs as (:func:`layer_form`): ``form`` — ``"dense"``,
+    ``"plain"`` (the sparse dispatch, every expert held: one sort of all
+    N*k slots), ``"sorted"`` or ``"counted"`` (held experts:
+    :func:`held_form`); ``k`` of the experts there are; the experts held,
+    ``first`` and ``n_held``; ``rows`` of the sparse dispatch's buffers (0
+    dense)."""
+    form: str
+    k: int
+    first: int
+    n_held: int
+    rows: int
+
+
+def layer_form(n: int, top_k: int, n_exp: int, held, dispatch) -> _Form:
+    """A layer's branch from its static shapes and the ambient mesh, ONE
+    resolution for the layer itself (:meth:`MoEMLP.__call__`) and for the
+    policy's record of it (:func:`dispatch_form`). The arguments are a
+    block's own: N tokens, arch ``moe_top_k``, ``moe_experts``, ``moe_held``
+    (None: all) and ``moe_dispatch`` (None: by the mesh)."""
+    k = max(1, min(top_k, n_exp))
+    first, n_held = held or (0, n_exp)
+    if not (0 <= first and 0 < n_held and first + n_held <= n_exp):
+        raise ValueError(f"moe_held {held} outside 0..{n_exp}")
+    dispatch = _dispatch_of(dispatch)
+    if dispatch == "dense":
+        return _Form("dense", k, first, n_held, 0)
+    if dispatch != "sparse":
+        raise ValueError(f"unknown moe_dispatch {dispatch!r}")
+    if _mesh_ep() > 1:
+        raise ValueError(
+            f"moe_dispatch 'sparse' under a mesh with ep={_mesh_ep()}: the "
+            f"sparse dispatch is a single-device program (GSPMD does not "
+            f"partition its Pallas calls over ep); leave moe_dispatch unset "
+            f"and the layer takes the dense path, which GSPMD does "
+            f"partition")
+    if n_held == n_exp:
+        return _Form("plain", k, first, n_held, n * k)
+    return _Form(held_form(n, k, n_held, n_exp), k, first, n_held,
+                 row_buffer(n * k, n_held, n_exp))
+
+
+def dispatch_form(n: int, top_k: int, n_exp: int, held,
+                  dispatch) -> tuple | None:
+    """:func:`layer_form` as the policy records it (``Policy.moe_backends``,
+    one ``[moe]`` line a distinct shape): ``(the record's key, the form, the
+    shapes as the line says them)``; None for the dense dispatch, which
+    walks no slots. ``layers/block.block_ffn`` asks before it calls the
+    layer."""
+    form, k, _, n_held, rows = layer_form(n, top_k, n_exp, held, dispatch)
+    if form == "dense":
+        return None
+    return ((n * k, rows, n_held, n_exp, k), form,
+            f"slots={n * k} rows={rows} held={n_held}/{n_exp} k={k}")
+
+
+def _passes(live, rows: int):
+    """Passes that cover ``live`` rows: ``ceil(live / rows)``."""
+    return (live + (rows - 1)) // rows
 
 
 def _pass_of(p, rows, load, row_to_slot, slot_to_row):
@@ -345,14 +508,13 @@ def _pass_of(p, rows, load, row_to_slot, slot_to_row):
             jnp.where(has_row, row_of_slot, 0), has_row)
 
 
-def _rows_of(tokens, slots, k, choice_major):
-    """``tokens [N, .]`` gathered to the rows of ``slots`` (R rows)."""
-    token_of_row = slots % tokens.shape[0] if choice_major else slots // k
-    return tokens.at[token_of_row].get(mode="promise_in_bounds")
+def _rows_of(tokens, slots):
+    """``tokens [N, .]`` gathered to the rows of ``slots`` (R rows; slot =
+    choice * N + token: the sorted walk's slots run choice-major)."""
+    return tokens.at[slots % tokens.shape[0]].get(mode="promise_in_bounds")
 
 
-def _rows_to_tokens(rows, row_of_slot, has_row, n, choice_major,
-                    slot_weights=None):
+def _rows_to_tokens(rows, row_of_slot, has_row, n, slot_weights=None):
     """Each token's sum over its k slots of the slot's row (times
     ``slot_weights`` where given), float32 ``[N, d]``: ONE N*k-row gather
     and a sum over k. A slot without a row in this pass reads row 0 and is
@@ -361,10 +523,9 @@ def _rows_to_tokens(rows, row_of_slot, has_row, n, choice_major,
     choice: as a reduction XLA first writes the gathered rows again in
     float32."""
     got = rows.at[row_of_slot].get(mode="promise_in_bounds")
-    axis = 0 if choice_major else 1
 
     def choice(a, j):  # slot-ordered [N*k, .] -> choice j's [N, .]
-        return jnp.take(_slots_3d(a, n, choice_major), j, axis=axis)
+        return jnp.take(_slots_3d(a, n, True), j, axis=0)
 
     def share(j):
         row = choice(got, j).astype(jnp.float32)
@@ -394,25 +555,22 @@ def _shared_experts_vjp(ffn, xs, stacks, group_sizes, d_out):
     return (out, *transpose(d_out))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_experts(ffn, rows, choice_major, tokens, top_w, stacks, load,
-                  row_to_slot, slot_to_row):
-    """The held-experts layer on the rows it holds (module docstring):
-    ``(y [N, d] float32, the passes the loop ran)``. ``row_to_slot``: the
-    slots sorted by held expert, absent experts' behind, padded to a whole
-    number of ``rows``-row passes; ``slot_to_row``: its inverse."""
-    return _held_fwd(ffn, rows, choice_major, tokens, top_w, stacks, load,
-                     row_to_slot, slot_to_row)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(ffn, rows, tokens, top_w, stacks, load, row_to_slot,
+                  slot_to_row):
+    """The held-experts layer on the rows it holds, the SORTED walk (module
+    docstring): ``(y [N, d] float32, the passes the loop ran)``.
+    ``row_to_slot``: the choice-major slots sorted by held expert, absent
+    experts' behind, padded to a whole number of ``rows``-row passes;
+    ``slot_to_row``: its inverse."""
+    return _held_fwd(ffn, rows, tokens, top_w, stacks, load, row_to_slot,
+                     slot_to_row)[0]
 
 
-def _flat(top_w, choice_major):
-    return (top_w.T if choice_major else top_w).reshape(-1)
-
-
-def _held_fwd(ffn, rows, choice_major, tokens, top_w, stacks, load,
-              row_to_slot, slot_to_row):
+def _held_fwd(ffn, rows, tokens, top_w, stacks, load, row_to_slot,
+              slot_to_row):
     n, k = top_w.shape
-    top_w_flat = _flat(top_w, choice_major)
+    top_w_flat = top_w.T.reshape(-1)
 
     def one_pass(p, carry):
         y, ran = carry
@@ -420,21 +578,21 @@ def _held_fwd(ffn, rows, choice_major, tokens, top_w, stacks, load,
             slots, _, sizes, row_of_slot, has_row = _pass_of(
                 p, rows, load, row_to_slot, slot_to_row)
         with jax.named_scope(MOE_ROWS):
-            xs = _rows_of(tokens, slots, k, choice_major)
+            xs = _rows_of(tokens, slots)
         with jax.named_scope(MOE_ELEMENTWISE):  # round the kernels' names
             out = _shared_experts(ffn, xs, stacks, sizes)
         with jax.named_scope(MOE_ROWS):
             y = y + _rows_to_tokens(out, row_of_slot, has_row, n,
-                                    choice_major, top_w_flat)
+                                    top_w_flat)
         return y, ran + 1
 
     out = jax.lax.fori_loop(
-        0, _passes(load, rows), one_pass,
+        0, _passes(load.sum(), rows), one_pass,
         (jnp.zeros(tokens.shape, jnp.float32), jnp.int32(0)))
     return out, (tokens, top_w, stacks, load, row_to_slot, slot_to_row)
 
 
-def _held_bwd(ffn, rows, choice_major, res, g):
+def _held_bwd(ffn, rows, res, g):
     """One loop of its own: pass p's R-row buffers again from the [N, d]
     tokens, then that pass's transpose. Differentiating THROUGH a loop (or
     a ``cond``) would make every buffer of every pass a residual of the
@@ -442,7 +600,7 @@ def _held_bwd(ffn, rows, choice_major, res, g):
     tokens, top_w, stacks, load, row_to_slot, slot_to_row = res
     g_y, _ = g  # the trip count is an integer: nothing comes back for it
     n, k = top_w.shape
-    top_w_flat = _flat(top_w, choice_major)
+    top_w_flat = top_w.T.reshape(-1)
 
     def one_pass(p, carry):
         d_tokens, d_weights, d_stacks = carry
@@ -450,9 +608,9 @@ def _held_bwd(ffn, rows, choice_major, res, g):
             slots, live, sizes, row_of_slot, has_row = _pass_of(
                 p, rows, load, row_to_slot, slot_to_row)
         with jax.named_scope(MOE_ROWS):
-            g_rows = _rows_of(g_y, slots, k, choice_major)
+            g_rows = _rows_of(g_y, slots)
             weights = top_w_flat.at[slots].get(mode="promise_in_bounds")
-            xs = _rows_of(tokens, slots, k, choice_major)
+            xs = _rows_of(tokens, slots)
         with jax.named_scope(MOE_ELEMENTWISE):  # round the kernels' names
             d_out = (g_rows * weights[:, None]).astype(tokens.dtype)
             out, d_xs, d_pass = _shared_experts_vjp(ffn, xs, stacks, sizes,
@@ -462,7 +620,7 @@ def _held_bwd(ffn, rows, choice_major, res, g):
                             (out.astype(jnp.float32) * g_rows).sum(-1), 0)
         with jax.named_scope(MOE_ROWS):
             d_tokens = d_tokens + _rows_to_tokens(d_xs, row_of_slot, has_row,
-                                                  n, choice_major)
+                                                  n)
         with jax.named_scope(MOE_ELEMENTWISE):
             return (d_tokens,
                     jax.lax.dynamic_update_slice(d_weights, d_w,
@@ -470,21 +628,216 @@ def _held_bwd(ffn, rows, choice_major, res, g):
                     jax.tree_util.tree_map(jnp.add, d_stacks, d_pass))
 
     d_tokens, d_weights, d_stacks = jax.lax.fori_loop(
-        0, _passes(load, rows), one_pass,
+        0, _passes(load.sum(), rows), one_pass,
         (jnp.zeros(tokens.shape, jnp.float32),
          jnp.zeros(row_to_slot.shape, jnp.float32),
          jax.tree_util.tree_map(jnp.zeros_like, stacks)))
     with jax.named_scope(MOE_ROWS):
         # each slot's weight gradient is its row's (0 for a slot without one)
         d_top_w = d_weights.at[slot_to_row].get(mode="promise_in_bounds")
-        d_top_w = (d_top_w.reshape(k, n).T if choice_major
-                   else d_top_w.reshape(n, k))
+        d_top_w = d_top_w.reshape(k, n).T
     with jax.named_scope(MOE_ELEMENTWISE):
         return (d_tokens.astype(tokens.dtype), d_top_w.astype(top_w.dtype),
                 d_stacks, None, None, None)
 
 
 _held_experts.defvjp(_held_fwd, _held_bwd)
+
+
+# -- the same layer, its rows COUNTED into expert order: nothing is sorted ----
+
+class _Held(NamedTuple):
+    """A forward's held choices (:func:`_compact`): ``count [N]``, a token's
+    held choices, at most ``h = min(k, held)`` (its experts are distinct);
+    ``[N, h]`` each, in the order of the k choices, the j-th held choice's
+    local ``expert`` (-1 at the places past ``count``), its place among the
+    k (``choice``) and its router ``weight``; ``running [held * N]``,
+    expert-major: the rows, in the order by expert and, inside an expert, by
+    token, up to and with (expert e, token t) — row r belongs to the first
+    (e, t) whose count passes it; ``load [held]``."""
+    count: Any
+    expert: Any
+    choice: Any
+    weight: Any
+    running: Any
+    load: Any
+
+
+def _compact(top_idx, top_w, held) -> _Held:
+    """The held choices, element-wise over ``top_idx [N, k]`` (the only
+    N*k-sized work of a held layer) and then by COUNTING — no sort: a held
+    choice's row in the expert order is its expert's first row plus the
+    tokens before this one that chose that expert."""
+    first, n_held = held
+    k = top_idx.shape[1]
+    h = min(k, n_held)
+    # choice-major [k, N]: the tokens in the lanes
+    local = top_idx.T - first
+    is_held = (local >= 0) & (local < n_held)
+    seen = jnp.cumsum(is_held, axis=0, dtype=jnp.int32)  # up to and with c
+    # [h, k, N]: choice c is the token's j-th held one (one c a place, or
+    # none); written out as a loop over c and j it is k * h selects a table
+    # and doubles the time an update takes to lower (PERF.md section 6)
+    at = is_held & (seen == jnp.arange(1, h + 1)[:, None, None])
+
+    def placed(per_choice, empty=0):  # [k, .] -> [N, h]
+        return (jnp.where(at, per_choice - empty, 0).sum(1) + empty).T
+
+    expert = placed(local, -1)
+    # [N, held]: the tokens up to and with t that chose expert e ...
+    chose = expert[..., None] == jnp.arange(n_held)
+    upto = jnp.cumsum(chose.any(1), axis=0, dtype=jnp.int32)
+    load = upto[-1]
+    upto = upto + (jnp.cumsum(load) - load)      # ... and the experts < e
+    return _Held(seen[-1], expert, placed(jnp.arange(k)[:, None]),
+                 placed(top_w.T), upto.T.reshape(-1), load)
+
+
+def _at_choices(per_held, count, choice, k: int):
+    """``per_held [N, h]`` back at the k choices: ``[N, k]`` holding
+    ``per_held[t, j]`` at ``choice[t, j]`` for ``j < count[t]`` and 0 at
+    the choices that are not held."""
+    here = ((jnp.arange(choice.shape[1]) < count[:, None])[..., None]
+            & (choice[..., None] == jnp.arange(k)))              # [N, h, k]
+    return jnp.where(here, per_held[..., None], 0).sum(1)
+
+
+# entries a block of the search by compares (:func:`_first_past`)
+_SEARCH_BLOCK = 128
+
+
+def _first_past(running, at):
+    """For each of ``at [R]`` the first index whose ``running`` count (non-
+    decreasing) is larger — ``len(running)`` where none is. By compares, in
+    two levels — the block of :data:`_SEARCH_BLOCK` entries, then the entry
+    in it: R x (len / 128 + 128) compares and ONE R-row gather of a block's
+    counts, where a binary search is ``log2 len`` dependent R-element
+    gathers, 6-10 x the time (PERF.md section 6, PR 58)."""
+    n = running.shape[0]
+    blocks = -(-n // _SEARCH_BLOCK)
+    counts = jnp.pad(running, (0, blocks * _SEARCH_BLOCK - n),
+                     mode="edge").reshape(blocks, _SEARCH_BLOCK)
+    block = (counts[:, -1][None, :] <= at[:, None]).sum(1, dtype=jnp.int32)
+    mine = counts.at[jnp.minimum(block, blocks - 1)].get(
+        mode="promise_in_bounds")                                # [R, 128]
+    found = block * _SEARCH_BLOCK + (mine <= at[:, None]).sum(
+        1, dtype=jnp.int32)
+    return jnp.minimum(found, n)
+
+
+class _Pass(NamedTuple):
+    """A counted pass's R rows (:func:`_counted_pass`): each row's ``token``,
+    its ``place`` among that token's held choices, its router ``weight`` and
+    whether it is ``live`` (a row past the live ones points at token 0,
+    place 0, weight 0); ``sizes [held]``, the pass-local groups."""
+    token: Any
+    place: Any
+    weight: Any
+    live: Any
+    sizes: Any
+
+
+def _counted_pass(p, rows: int, held: _Held) -> _Pass:
+    """Pass ``p``'s share of the expert order, rows ``[p R, (p + 1) R)``."""
+    n, h = held.expert.shape
+    row = p * rows + jnp.arange(rows, dtype=jnp.int32)
+    live = row < held.running[-1]
+    at = _first_past(held.running, row)             # expert * N + token
+    token = jnp.where(live, at % n, 0)
+    mine = held.expert.at[token].get(
+        mode="promise_in_bounds") == (at // n)[:, None]
+    weight = held.weight.at[token].get(mode="promise_in_bounds")
+    ends = jnp.clip(jnp.cumsum(held.load) - p * rows, 0, rows)
+    return _Pass(token, jnp.where(mine, jnp.arange(h), 0).sum(1),
+                 jnp.where(mine, weight, 0).sum(1), live,
+                 jnp.diff(ends, prepend=0))
+
+
+def _counted_to_tokens(y, rows, ix: _Pass, weighted):
+    """``y [N, d]`` float32 plus each token's sum over its live rows of this
+    pass (times their router weights where ``weighted``): the R rows added
+    at their tokens. A row past the live ones — whatever the kernels left
+    there — is SELECTED away, never multiplied by a zero weight: ``0 x`` is
+    NaN where that is NaN."""
+    x = rows.astype(jnp.float32)
+    if weighted:
+        x = x * ix.weight[:, None]
+    return y.at[ix.token].add(jnp.where(ix.live[:, None], x, 0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _counted_experts(ffn, rows, held, tokens, top_w, top_idx, stacks):
+    """The held-experts layer on the rows it holds, the COUNTED walk
+    (module docstring): ``(y [N, d] float32, the passes the loop ran)``.
+    ``held``: ``(first, count)`` of the experts ``top_idx`` chooses among."""
+    return _counted_fwd(ffn, rows, held, tokens, top_w, top_idx, stacks)[0]
+
+
+def _counted_fwd(ffn, rows, held, tokens, top_w, top_idx, stacks):
+    with jax.named_scope(MOE_ROUTE):
+        mine = _compact(top_idx, top_w, held)
+
+    def one_pass(p, carry):
+        y, ran = carry
+        with jax.named_scope(MOE_ROUTE):
+            ix = _counted_pass(p, rows, mine)
+        with jax.named_scope(MOE_ROWS):
+            xs = tokens.at[ix.token].get(mode="promise_in_bounds")
+        with jax.named_scope(MOE_ELEMENTWISE):  # round the kernels' names
+            out = _shared_experts(ffn, xs, stacks, ix.sizes)
+        with jax.named_scope(MOE_ROWS):
+            y = _counted_to_tokens(y, out, ix, True)
+        return y, ran + 1
+
+    out = jax.lax.fori_loop(
+        0, _passes(mine.running[-1], rows), one_pass,
+        (jnp.zeros(tokens.shape, jnp.float32), jnp.int32(0)))
+    return out, (tokens, stacks, top_idx, mine)
+
+
+def _counted_bwd(ffn, rows, held, res, g):
+    """One loop of its own: pass p's R-row buffers again from the [N, d]
+    tokens, then that pass's transpose. Differentiating THROUGH a loop (or
+    a ``cond``) would make every buffer of every pass a residual of the
+    forward, zero-filled where a pass did not run."""
+    tokens, stacks, top_idx, mine = res
+    g_y, _ = g  # the trip count is an integer: nothing comes back for it
+
+    def one_pass(p, carry):
+        d_tokens, d_weight, d_stacks = carry
+        with jax.named_scope(MOE_ROUTE):
+            ix = _counted_pass(p, rows, mine)
+        with jax.named_scope(MOE_ROWS):
+            g_rows = g_y.at[ix.token].get(mode="promise_in_bounds")
+            xs = tokens.at[ix.token].get(mode="promise_in_bounds")
+        with jax.named_scope(MOE_ELEMENTWISE):  # round the kernels' names
+            d_out = (g_rows * ix.weight[:, None]).astype(tokens.dtype)
+            out, d_xs, d_pass = _shared_experts_vjp(ffn, xs, stacks,
+                                                    ix.sizes, d_out)
+            # the tail's rows were never written: selected away, not masked
+            d_w = jnp.where(ix.live,
+                            (out.astype(jnp.float32) * g_rows).sum(-1), 0)
+        with jax.named_scope(MOE_ROWS):
+            d_tokens = _counted_to_tokens(d_tokens, d_xs, ix, False)
+            # a row's weight gradient, at its token's place for it
+            d_weight = d_weight.at[ix.token, ix.place].add(d_w)
+        with jax.named_scope(MOE_ELEMENTWISE):
+            return (d_tokens, d_weight,
+                    jax.tree_util.tree_map(jnp.add, d_stacks, d_pass))
+
+    d_tokens, d_weight, d_stacks = jax.lax.fori_loop(
+        0, _passes(mine.running[-1], rows), one_pass,
+        (jnp.zeros(tokens.shape, jnp.float32),
+         jnp.zeros(mine.weight.shape, jnp.float32),
+         jax.tree_util.tree_map(jnp.zeros_like, stacks)))
+    with jax.named_scope(MOE_ELEMENTWISE):
+        d_top_w = _at_choices(d_weight, mine.count, mine.choice,
+                              top_idx.shape[1])
+        return (d_tokens.astype(tokens.dtype),
+                d_top_w.astype(mine.weight.dtype), None, d_stacks)
+
+
+_counted_experts.defvjp(_counted_fwd, _counted_bwd)
 
 
 def _mesh_ep() -> int:
@@ -497,6 +850,12 @@ def _mesh_ep() -> int:
     context = sys.modules.get("relayrl_tpu.parallel.context")
     mesh = context.current_mesh() if context else None
     return 1 if mesh is None else int(mesh.shape.get("ep", 1))
+
+
+def _dispatch_of(dispatch: str | None) -> str:
+    """Arch ``moe_dispatch``, or the pick by the ambient mesh's ``ep``
+    axis (module docstring): ``"sparse"`` | ``"dense"``."""
+    return dispatch or ("dense" if _mesh_ep() > 1 else "sparse")
 
 
 def _shared_ffn(layer: "MoEMLP", xs, gated: bool, tokens):
@@ -557,13 +916,12 @@ class MoEMLP(nn.Module):
         B, T, d = x.shape
         n = B * T
         n_exp = self.n_experts
-        k = max(1, min(self.top_k, n_exp))
         cd = self.compute_dtype
         tokens = x.reshape(n, d)
 
-        first, n_held = self.held or (0, n_exp)
-        if not (0 <= first and 0 < n_held and first + n_held <= n_exp):
-            raise ValueError(f"moe_held {self.held} outside 0..{n_exp}")
+        # the branch taken below, as the policy's record says it
+        form, k, first, n_held, rows = layer_form(
+            n, self.top_k, n_exp, self.held, self.dispatch)
         partial = n_held < n_exp
         if self.latent is not None and (
                 isinstance(self.latent, bool)
@@ -620,8 +978,7 @@ class MoEMLP(nn.Module):
             load = (top_idx[..., None] == first + jnp.arange(n_held)).sum(
                 axis=(0, 1), dtype=jnp.int32)
 
-        dispatch = self.dispatch or ("dense" if _mesh_ep() > 1 else "sparse")
-        if dispatch == "dense":
+        if form == "dense":
             weights = jnp.zeros((n, n_exp), jnp.float32).at[
                 jnp.arange(n)[:, None], top_idx].set(top_w)      # [N, E]
             if partial:
@@ -637,89 +994,99 @@ class MoEMLP(nn.Module):
             out = jnp.einsum("enf,efd->end", h.astype(cd), w_down,
                              preferred_element_type=jnp.float32)
             y = jnp.einsum("ne,end->nd", weights, out)       # psum over ep
-            rows, row_passes = 0, jnp.int32(0)       # no row buffers here
-        elif dispatch == "sparse":
-            if _mesh_ep() > 1:
-                raise ValueError(
-                    f"moe_dispatch 'sparse' under a mesh with ep="
-                    f"{_mesh_ep()}: the sparse dispatch is a single-device "
-                    f"program (GSPMD does not partition its Pallas calls "
-                    f"over ep); leave moe_dispatch unset and the layer "
-                    f"takes the dense path, which GSPMD does partition")
-            # slot s = token s // k, choice s % k; rows = slots by expert.
-            # [N*k, d] rows split as [N, k, d] for the combine, which a TPU
-            # tiles (8, 128) over (k, d): free at k = 8, a relayout into
-            # padded tiles at k = 4 (25 ms an update in lfm2-policy.update,
-            # PERF.md section 6). So where 8 does not divide k the slots run
-            # choice-major, slot s = choice s // N, token s % N, and the
-            # split is [k, N, d]. Both orders are measured: choice-major at
-            # k = 8 costs olmoe-policy.update 1.4% (1.7 ms an update, one
-            # broadcast-select fusion half as long again: PERF.md section 6).
-            choice_major = k % 8 != 0
-            stacks = (w_up, w_gate if gated else None, w_down)
-            with jax.named_scope(MOE_ROUTE):
-                expert_of_slot = (top_idx.T if choice_major else top_idx
-                                  ).reshape(n * k)
-                if partial:
-                    # the slots of absent experts sort behind the held
-                    # ones: a tail no pass reaches (module docstring, "Held
-                    # experts")
-                    local = expert_of_slot - first
-                    expert_of_slot = jnp.where(
-                        (local >= 0) & (local < n_held), local, n_held)
-                row_to_slot = jnp.argsort(expert_of_slot, stable=True)
-                slot_to_row = jnp.zeros_like(row_to_slot).at[
-                    row_to_slot].set(
-                        jnp.arange(n * k, dtype=row_to_slot.dtype),
-                        unique_indices=True)
-            if partial:
-                rows = row_buffer(n * k, n_held, n_exp)
-                # (the cast in each branch: where it stands in the program)
-                with jax.named_scope(MOE_ELEMENTWISE):
-                    xs = rows_in.astype(cd)
-                with jax.named_scope(MOE_ROUTE):
-                    padded = jnp.pad(row_to_slot, (0, -(n * k) % rows))
-                # its parts are named inside, in both of its loops
-                y, row_passes = _held_experts(
-                    self.ffn, rows, choice_major, xs, top_w, stacks, load,
-                    padded, slot_to_row)
-            else:
-                rows, row_passes = n * k, jnp.int32(1)
-                with jax.named_scope(MOE_ROUTE):
-                    token_of_row = (row_to_slot % n if choice_major
-                                    else row_to_slot // k)
-                with jax.named_scope(MOE_ELEMENTWISE):
-                    xs = rows_in.astype(cd)
-                # round the CALLS: a custom_vjp's backward carries the
-                # scopes of its call, not those opened in its forward
-                with jax.named_scope(MOE_ROWS):
-                    xs = _dispatch_rows(xs, token_of_row, slot_to_row,
-                                        choice_major)             # [N*k, d]
-                # what is not a kernel between the dispatch and its way
-                # back (the kernels keep their own innermost names)
-                with jax.named_scope(MOE_ELEMENTWISE):
-                    out = _experts(self.ffn, xs, stacks, load)    # [N*k, d]
-                with jax.named_scope(MOE_ROWS):
-                    out = _unsort_rows(out, slot_to_row, row_to_slot)
-                with jax.named_scope(MOE_ELEMENTWISE):
-                    out = _slots_3d(out, n, choice_major).astype(
-                        jnp.float32)
-                    y = (jnp.einsum("kn,knd->nd", top_w.T, out)
-                         if choice_major
-                         else jnp.einsum("nk,nkd->nd", top_w, out))
+            row_passes = jnp.int32(0)                # no row buffers here
+            sorted_slots = jnp.int32(0)              # and no expert order
         else:
-            raise ValueError(f"unknown moe_dispatch {dispatch!r}")
+            stacks = (w_up, w_gate if gated else None, w_down)
+            if form == "counted":
+                with jax.named_scope(MOE_ELEMENTWISE):
+                    xs = rows_in.astype(cd)
+                # its parts are named inside, in both of its loops
+                y, row_passes = _counted_experts(
+                    self.ffn, rows, (first, n_held), xs, top_w, top_idx,
+                    stacks)
+                sorted_slots = row_passes * rows
+            else:
+                # slot s = token s // k, choice s % k; rows = slots by
+                # expert. [N*k, d] rows split as [N, k, d] for the combine,
+                # which a TPU tiles (8, 128) over (k, d): free at k = 8, a
+                # relayout into padded tiles at k = 4 (25 ms an update in
+                # lfm2-policy.update, PERF.md section 6). So where 8 does
+                # not divide k the slots run choice-major, slot s = choice
+                # s // N, token s % N, and the split is [k, N, d]. Both
+                # orders are measured: choice-major at k = 8 costs
+                # olmoe-policy.update 1.4% (1.7 ms an update, one
+                # broadcast-select fusion half as long again: PERF.md
+                # section 6). A held layer's sorted walk is written
+                # choice-major alone: where 8 divides k it counts (or, its
+                # buffers N*k rows long, walks one token's slots).
+                choice_major = partial or k % 8 != 0
+                sorted_slots = jnp.int32(n * k)
+                with jax.named_scope(MOE_ROUTE):
+                    expert_of_slot = (top_idx.T if choice_major else top_idx
+                                      ).reshape(n * k)
+                    if partial:
+                        # the slots of absent experts sort behind the held
+                        # ones: a tail no pass reaches (module docstring,
+                        # "Held experts")
+                        local = expert_of_slot - first
+                        expert_of_slot = jnp.where(
+                            (local >= 0) & (local < n_held), local, n_held)
+                    row_to_slot = jnp.argsort(expert_of_slot, stable=True)
+                    slot_to_row = jnp.zeros_like(row_to_slot).at[
+                        row_to_slot].set(
+                            jnp.arange(n * k, dtype=row_to_slot.dtype),
+                            unique_indices=True)
+                if partial:
+                    # (the cast in each branch: where it stands in the
+                    # program)
+                    with jax.named_scope(MOE_ELEMENTWISE):
+                        xs = rows_in.astype(cd)
+                    with jax.named_scope(MOE_ROUTE):
+                        padded = jnp.pad(row_to_slot, (0, -(n * k) % rows))
+                    # its parts are named inside, in both of its loops
+                    y, row_passes = _held_experts(
+                        self.ffn, rows, xs, top_w, stacks, load, padded,
+                        slot_to_row)
+                else:
+                    row_passes = jnp.int32(1)
+                    with jax.named_scope(MOE_ROUTE):
+                        token_of_row = (row_to_slot % n if choice_major
+                                        else row_to_slot // k)
+                    with jax.named_scope(MOE_ELEMENTWISE):
+                        xs = rows_in.astype(cd)
+                    # round the CALLS: a custom_vjp's backward carries the
+                    # scopes of its call, not those opened in its forward
+                    with jax.named_scope(MOE_ROWS):
+                        xs = _dispatch_rows(xs, token_of_row, slot_to_row,
+                                            choice_major)         # [N*k, d]
+                    # what is not a kernel between the dispatch and its way
+                    # back (the kernels keep their own innermost names)
+                    with jax.named_scope(MOE_ELEMENTWISE):
+                        out = _experts(self.ffn, xs, stacks, load)
+                    with jax.named_scope(MOE_ROWS):
+                        out = _unsort_rows(out, slot_to_row, row_to_slot)
+                    with jax.named_scope(MOE_ELEMENTWISE):
+                        out = _slots_3d(out, n, choice_major).astype(
+                            jnp.float32)
+                        y = (jnp.einsum("kn,knd->nd", top_w.T, out)
+                             if choice_major
+                             else jnp.einsum("nk,nkd->nd", top_w, out))
 
         # Monitoring hook: token-slots per held expert (sums to N*k where
         # every expert is held), and how the sparse dispatch walked them:
-        # the rows of its buffers and the passes it took over them (1 over
-        # N*k rows where every expert is held). Inert unless applied with
+        # the rows of its buffers, the passes it took over them (1 over
+        # N*k rows where every expert is held) and the slots it put in
+        # expert order (passes x R where a held layer counts, N*k where one
+        # sort orders them all). Inert unless applied with
         # mutable=["intermediates"] — the update's moe_load_max/min,
-        # moe_held_slots, moe_row_passes and expert_utilization() read it.
+        # moe_held_slots, moe_row_passes, moe_sorted_slots and
+        # expert_utilization() read it.
         self.sow("intermediates", "expert_load", load)
         self.sow("intermediates", "expert_slots", jnp.int32(n * k))
         self.sow("intermediates", "row_passes", row_passes)
         self.sow("intermediates", "row_buffer", jnp.int32(rows))
+        self.sow("intermediates", "sorted_slots", sorted_slots)
         if self.latent:
             with jax.named_scope(MOE_LATENT):
                 y = nn.Dense(d, dtype=cd, use_bias=False,
@@ -752,21 +1119,26 @@ def _shares(intermediates) -> dict:
 
 def load_extremes(intermediates) -> dict:
     """``{"moe_load_max", "moe_load_min", "moe_held_slots",
-    "moe_row_passes"}``: the fullest and the emptiest held expert's share
-    of all the token-slots, over every MoE layer of one applied forward
-    (nothing is recomputed); the token-slots routed to held experts and the
-    passes the sparse dispatch took over its row buffers, each summed over
-    those layers. 1/E each at even load; max -> 1/k is the gate collapsing;
-    held slots = N*k a layer where every expert is held; passes = 1 a
-    layer unless a held-experts layer's router sent it more rows than its
-    buffer has."""
+    "moe_row_passes", "moe_sorted_slots"}``: the fullest and the emptiest
+    held expert's share of all the token-slots, over every MoE layer of one
+    applied forward (nothing is recomputed); the token-slots routed to held
+    experts, the passes the sparse dispatch took over its row buffers and
+    the slots it put in expert order, each summed over those layers. 1/E
+    each at even load; max -> 1/k is the gate collapsing; held slots = N*k a
+    layer where every expert is held; passes = 1 a layer unless a held-experts
+    layer's router sent it more rows than its buffer has; sorted slots =
+    passes x the buffer's rows in a held-experts layer that counts its rows
+    (:func:`held_form`), N*k in one that sorts them and where every expert
+    is held."""
+    sown = _sown(intermediates).values()
     shares = jnp.stack(list(_shares(intermediates).values()))
     held = sum(load.sum() for load, _ in _loads(intermediates).values())
-    passes = sum(moe["row_passes"][0]
-                 for moe in _sown(intermediates).values())
+    passes = sum(moe["row_passes"][0] for moe in sown)
+    ordered = sum(moe["sorted_slots"][0] for moe in sown)
     return {"moe_load_max": shares.max(), "moe_load_min": shares.min(),
             "moe_held_slots": held,
-            "moe_row_passes": jnp.asarray(passes, jnp.float32)}
+            "moe_row_passes": jnp.asarray(passes, jnp.float32),
+            "moe_sorted_slots": jnp.asarray(ordered, jnp.float32)}
 
 
 def expert_utilization(arch, params, obs, mask=None) -> dict:

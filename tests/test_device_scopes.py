@@ -14,6 +14,7 @@ they compile, in ``tests/test_flash_tpu_compile.py``.
 import contextlib
 import re
 from pathlib import Path
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ from relayrl_tpu.algorithms.impala import (
     make_impala_update,
 )
 from relayrl_tpu.data.batching import TrajectoryBatch
-from relayrl_tpu.models import build_policy
+from relayrl_tpu.models import build_policy, moe
 from relayrl_tpu.ops import scopes
 from relayrl_tpu.ops.scopes import (
     CONV,
@@ -132,25 +133,27 @@ FAMILIES = {
                TRUNK + MOE + (FFN, GDN_NAME, GDN_CONV_NAME)),
     # a delta rule under a decay a key lane (two chunks) with the dense FFN,
     # then latent attention (q / k 6 wide, v 4) with sigmoid-routed experts
-    # beside a shared expert (kimi-linear-policy)
+    # beside a shared expert, top-8: the held layer counts its rows
+    # (kimi-linear-policy)
     "latent": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 2,
                 "n_heads": 4, "layer_types": ["kda", "latent_attention"],
                 "kda_heads": 2, "kda_head_dim": 8, "kda_chunk": 4,
                 "kv_lora_rank": 8, "qk_nope_head_dim": 4,
                 "qk_rope_head_dim": 2, "v_head_dim": 4,
-                "moe_dense_layers": 1, "moe_experts": 8, "moe_top_k": 3,
+                "moe_dense_layers": 1, "moe_experts": 16, "moe_top_k": 8,
                 "moe_held": [2, 4], "moe_d_ff": 12, "moe_shared_d_ff": 12,
                 "moe_router": "sigmoid", "moe_expert_bias": True,
                 "moe_routed_scaling": 2.446, "norm": "rms",
                 "positions": "none", "use_bias": False, "ffn": "swiglu"},
                TRUNK + MOE + (FFN, KDA_NAME, KDA_CONV_NAME)),
     # attention over the keys an indexer picks, 2 of up to 8, in two tiles;
-    # the indexers' own loss under the loss's name (keye-vl2-policy)
+    # the indexers' own loss under the loss's name; top-8 experts, the held
+    # layer counting its rows (keye-vl2-policy)
     "sparse": ({**SEQ, "kind": "transformer_moe_discrete", "n_layers": 2,
                 "n_heads": 4, "n_kv_heads": 2, "head_dim": 8,
                 "layer_types": ["sparse_attention"] * 2, "index_heads": 2,
                 "index_head_dim": 8, "index_topk": 2, "index_chunk": 4,
-                "moe_experts": 8, "moe_top_k": 3, "moe_held": [2, 4],
+                "moe_experts": 16, "moe_top_k": 8, "moe_held": [2, 4],
                 "moe_d_ff": 12, "norm": "rms", "positions": "rope",
                 "qk_norm": "head", "use_bias": False, "ffn": "swiglu"},
                TRUNK + MOE + (INDEX, SPARSE_ATTN)),
@@ -193,7 +196,11 @@ def _lower(family: str):
                                 max_grad_norm=1.0)
     batch = TrajectoryBatch.zeros(2, 8, arch["obs_dim"], arch["act_dim"],
                                   True)
-    return jax.jit(update, donate_argnums=0).lower(state, batch)
+    # 16 tokens: under the kernels' row tile of 512 a held layer's buffers
+    # would be N k rows long and every such layer would sort; a tile of 8
+    # gives them the cells' proportions (R < N k), so that top-8 counts
+    with mock.patch.object(moe, "_ROW_TILE", 8):
+        return jax.jit(update, donate_argnums=0).lower(state, batch)
 
 
 def _once(make):
@@ -370,6 +377,12 @@ def test_custom_vjp_backwards_carry_their_parts(paths, compiled):
     for scope, what in ((MOE_ROUTE, "cumsum"), (MOE_ROWS, "gather"),
                         (MOE_ELEMENTWISE, "dynamic_update_slice")):
         assert backward("moe_held", scope, what), (scope, what)
+    # ... the walk that sorts; and the one that counts (top-8): a pass's
+    # rows found by compares, gathered, their weight gradients added
+    for scope, what in ((MOE_ROUTE, "cumsum"), (MOE_ROUTE, "le"),
+                        (MOE_ROWS, "gather"), (MOE_ROWS, "scatter-add"),
+                        (MOE_ELEMENTWISE, "_where")):
+        assert backward("latent", scope, what), (scope, what)
     # ... and a held pass's experts sit inside the element-wise part, the
     # vjp's own wrapper absorbed by the name made for it
     assert re.search(rf'op_name="[^"]*{MOE_ELEMENTWISE}/[^"]*'

@@ -163,6 +163,28 @@ def test_equal_widths_lower_the_kernels_that_were_there():
     assert flash.FWD_NAME + flash.LATENT_SUFFIX in own
 
 
+def _on(chip, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree)
+
+
+def _layer_and_every_gradient(one_chip, layer, *rows_in):
+    """``jit(value_and_grad)`` of an expert layer's summed output, with
+    respect to its parameters and the rows it reads, lowered for the
+    described chip (the caller answers "tpu" for the backend)."""
+    from relayrl_tpu.models import moe
+
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), *rows_in)
+    try:
+        return jax.jit(jax.value_and_grad(
+            lambda p, *a: jnp.sum(layer.apply(p, *a).astype(jnp.float32)),
+            (0, 1))).lower(_on(one_chip, params), *_on(one_chip, rows_in))
+    finally:  # traces made under the answer "tpu" stay in this test
+        moe._shared_experts.clear_cache()
+        moe._shared_experts_vjp.clear_cache()
+
+
 def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
     """The held-experts layer (``models/moe.py``) at ``lfm2-policy``'s
     widths, 8 of 64 experts held, a quarter of its tokens: ONE copy of the
@@ -185,22 +207,9 @@ def test_held_experts_layer_compiles_for_v5e(one_chip, monkeypatch):
                        held=(0, 8))
     rows = moe.row_buffer(n * k, 8, 64)
     assert rows == 4096
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    x = jnp.zeros((1, n, d), jnp.bfloat16)
-    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
-    try:
-        compiled = jax.jit(jax.value_and_grad(
-            lambda p, x: jnp.sum(layer.apply(p, x).astype(jnp.float32)),
-            (0, 1))).lower(on_chip(params), on_chip(x)).compile()
-    finally:  # traces made under the answer "tpu" stay in this test
-        moe._shared_experts.clear_cache()
-        moe._shared_experts_vjp.clear_cache()
-    text = compiled.as_text()
+    text = _layer_and_every_gradient(
+        one_chip, layer, jnp.zeros((1, n, d), jnp.bfloat16)
+    ).compile().as_text()
     assert text.count("tpu_custom_call") == 12
     names = collections.Counter(
         re.sub(r"[._]\d+$", "", name)
@@ -257,23 +266,10 @@ def test_held_experts_of_a_width_no_128_divides_compile_for_v5e(
     layer = moe.MoEMLP(d, ff, 128, k, jnp.bfloat16, ffn="relu2",
                        use_bias=False, router="sigmoid", expert_bias=True,
                        held=(0, 8), routed_scaling=2.5, shared_d_ff=3712)
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
     x = jnp.zeros((1, n, d), jnp.bfloat16)
     params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
     assert params["params"]["moe_w_up"].shape == (8, d, ff)
-    try:
-        compiled = jax.jit(jax.value_and_grad(
-            lambda p, x: jnp.sum(layer.apply(p, x).astype(jnp.float32)),
-            (0, 1))).lower(on_chip(params), on_chip(x)).compile()
-    finally:  # traces made under the answer "tpu" stay in this test
-        moe._shared_experts.clear_cache()
-        moe._shared_experts_vjp.clear_cache()
-    text = compiled.as_text()
+    text = _layer_and_every_gradient(one_chip, layer, x).compile().as_text()
     assert "ragged" not in text
     assert text.count("tpu_custom_call") == 8
     names = collections.Counter(
@@ -286,6 +282,144 @@ def test_held_experts_of_a_width_no_128_divides_compile_for_v5e(
     # the shared expert is dense matmuls under the dense FFN's part
     assert re.search(rf'op_name="[^"]*/{scopes.FFN}/[^"]*dot_general',
                      text)
+
+
+def _sorts_by_keys(text, rows):
+    """``(the router's, the N k-key ones outside the loops, the others')``
+    key counts of a compiled layer's sorts."""
+    router, whole, mine = [], [], []
+    for line in text.splitlines():
+        if " sort(" not in line:
+            continue
+        shape = re.search(r"= \(\w+\[([\d,]+)\]", line).group(1).split(",")
+        keys = int(shape[int(re.search(r"dimensions=\{(\d)\}",
+                                       line).group(1))])
+        if "top_k" in line:
+            router.append(keys)
+        elif keys > rows:
+            assert "while" not in line, line
+            whole.append(keys)
+        else:
+            mine.append(keys)
+    return router, whole, mine
+
+
+def test_a_held_layer_at_top_22_of_512_counts_the_rows_it_holds_on_v5e(
+        one_chip, monkeypatch):
+    """``nemotron3-super-policy``'s expert layer at the cell's shape —
+    16,384 tokens, top-22 of 512 sigmoid-routed ``relu^2`` experts in a
+    latent of 1024, 8 held, the shared expert beside them — forward and
+    every gradient: 360,448 slots, buffers of 11,264 rows, N k = 32 R, so
+    the layer COUNTS its rows (``moe.held_form``) and adds them back at
+    their tokens. It sorts nothing: the sorts left are the index sorts
+    of R keys XLA puts before the R-row scatter-adds, and the router's own —
+    ``top_k`` (each token's 512 scores) and the transpose of its
+    ``take_along_axis`` (N k keys), the only operations over all N k slots;
+    nothing has N k rows of the latent's width."""
+    from relayrl_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, k, latent = 16384, 4096, 22, 1024
+    rows = moe.row_buffer(n * k, 8, 512)
+    assert (n * k, rows) == (360448, 11264)
+    assert moe.dispatch_form(n, k, 512, (0, 8), None)[1:] == (
+        "counted", "slots=360448 rows=11264 held=8/512 k=22")
+    layer = moe.MoEMLP(d, 2688, 512, k, jnp.bfloat16, ffn="relu2",
+                       use_bias=False, router="sigmoid", expert_bias=True,
+                       held=(0, 8), routed_scaling=5.0, shared_d_ff=5376,
+                       latent=latent)
+    text = _layer_and_every_gradient(
+        one_chip, layer, jnp.zeros((2, n // 2, d), jnp.bfloat16)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 8   # two stacks: 4 + 2 + 2
+    router, whole, mine = _sorts_by_keys(text, rows)
+    assert set(router) == {512} and set(whole) <= {n * k}
+    # the index sorts of the R-row scatter-adds (y, d tokens)
+    assert mine and set(mine) == {rows}
+    assert f"bf16[{rows},{latent}]" in text
+    # nothing of the latent's width has N k rows, in either slot order
+    for lead in (f"{n * k}", f"{k},{n}", f"{n},{k}"):
+        assert not re.findall(rf"\w+\[{lead},{latent}\]", text), lead
+
+
+def test_a_held_layer_at_top_8_counts_the_rows_it_holds_on_v5e(
+        one_chip, monkeypatch):
+    """``keye-vl2-policy``'s expert layer at the cell's shape — 16,384
+    tokens, top-8 of 128 SwiGLU experts, 16 held: N k is only 4 R, but 8
+    divides k, so the layer counts its rows. Forward and every gradient
+    compile for the chip: twelve Mosaic calls, no sort of the layer's own
+    but the R-row scatter-adds' index sorts, nothing N k rows long at the
+    model's width."""
+    from relayrl_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d, k = 16384, 2048, 8
+    rows = moe.row_buffer(n * k, 16, 128)
+    assert rows == 32768
+    assert moe.dispatch_form(n, k, 128, (0, 16), None)[1] == "counted"
+    layer = moe.MoEMLP(d, 768, 128, k, jnp.bfloat16, ffn="swiglu",
+                       use_bias=False, held=(0, 16))
+    text = _layer_and_every_gradient(
+        one_chip, layer, jnp.zeros((1, n, d), jnp.bfloat16)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 12
+    router, whole, mine = _sorts_by_keys(text, rows)
+    # the R-row scatter-adds (y, d tokens, the weights' gradients) sort
+    # their R indices
+    assert set(router) == {128} and mine and set(mine) == {rows}
+    assert set(whole) <= {n * k}
+    assert f"bf16[{rows},{d}]" in text
+    for lead in (f"{n * k}", f"{k},{n}", f"{n},{k}"):
+        assert not re.findall(rf"\w+\[{lead},{d}\]", text), lead
+
+
+# sha256 of the lowered layer and every gradient (Mosaic bodies left out:
+# they hold line numbers) as the parent of PR 59 lowered it, before
+# ``models/moe.py`` was given its second held walk
+_SORTED_WALK_AS_IT_WAS = {
+    "smallthinker-policy":
+        "12598113ac97db234f672e2fe0c3313512332e0120e7e01b4a60091bbe4a6fcd",
+    "lfm2-policy":
+        "39bfe868664a9a4ac9ec3d5132585acd2ba8adbab0b874a3ebc8b71329acafc6",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_SORTED_WALK_AS_IT_WAS))
+def test_a_held_layer_that_sorts_lowers_the_program_it_was(one_chip,
+                                                           monkeypatch,
+                                                           cell):
+    """The two cells with the fewest slots a held row (N k / R = 2 and 4:
+    ``smallthinker-policy``, ``lfm2-policy``) keep the sorted walk, and
+    keep it text for text: the layer at the cell's shape, forward and every
+    gradient, lowers to the StableHLO it lowered to before the counted walk
+    existed (PR 58 was refused on the warm set-up of the first; a set-up
+    moves with the program's text and with the frames above a lowering —
+    ROADMAP 1.5 — so neither may move here). An edit that changes the
+    sorted walk's program on purpose pins the hash it then reads: the
+    failed assertion shows it."""
+    import hashlib
+
+    from relayrl_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if cell == "smallthinker-policy":  # its router reads the layer's input
+        layer = moe.MoEMLP(2560, 768, 64, 6, jnp.bfloat16, ffn="reglu",
+                           use_bias=False, held=(0, 16))
+        x = jnp.zeros((1, 16384, 2560), jnp.bfloat16)
+        rows_in = (x, x)
+    else:
+        layer = moe.MoEMLP(2048, 1536, 64, 4, jnp.bfloat16, ffn="swiglu",
+                           use_bias=False, router="sigmoid",
+                           expert_bias=True, held=(0, 8))
+        rows_in = (jnp.zeros((2, 8192, 2048), jnp.bfloat16),)
+    assert moe.held_form(16384, layer.top_k, layer.held[1], 64) == "sorted"
+    text = _layer_and_every_gradient(one_chip, layer, *rows_in).as_text()
+    assert text.count("tpu_custom_call") == 12
+    bare = re.sub(r'\\22body\\22: \\22[^\\]*\\22',
+                  r'\\22body\\22: \\22<mosaic>\\22', text)
+    assert bare != text
+    assert hashlib.sha256(bare.encode()).hexdigest() == (
+        _SORTED_WALK_AS_IT_WAS[cell])
 
 
 def _scan_at_the_cells_shape(one_chip, fn, wrt):

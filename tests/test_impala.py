@@ -229,12 +229,16 @@ def test_impala_updates_the_olmoe_block(tmp_path, precision):
                     for block in algo.state.params["params"].values())
         assert metrics["moe_row_passes"] == n_moe > 0
         assert "moe_row_passes" in algo._fence_notes
+        # ...and one sort of all its slots: 2 x 16 tokens, top-2
+        assert metrics["moe_sorted_slots"] == n_moe * 2 * 16 * 2
+        assert "moe_sorted_slots" in algo._fence_notes
         snap = {m["name"]: m["value"]
                 for m in telemetry.get_registry().snapshot()["metrics"]
                 if m["kind"] == "gauge"}
         assert snap["relayrl_moe_load_max"] == pytest.approx(
             metrics["moe_load_max"])
         assert snap["relayrl_moe_row_passes"] == n_moe
+        assert snap["relayrl_moe_sorted_slots"] == n_moe * 64
         after = algo.state.params["params"]
         for stack in ("moe_w_gate", "moe_w_up", "moe_w_down"):
             moved = np.abs(np.asarray(after["block_1"]["moe"][stack])
