@@ -601,10 +601,10 @@ class Client {
   // ---- async subscription mode ----
   // A C++ reader thread owns the socket: every ModelPush is timestamped
   // with CLOCK_MONOTONIC at parse completion (comparable across processes
-  // on one host — the GIL-free receipt evidence the soak benches need,
-  // VERDICT r2 weak #1), queued for rl_sub_next, and logged in the
-  // receipt ledger. The reader also owns keepalive pings and reconnects,
-  // so Python never touches this socket again after start.
+  // on one host — the GIL-free receipt stamp rl_sub_next hands back with
+  // each frame) and queued for rl_sub_next. The reader also owns keepalive
+  // pings and reconnects, so Python never touches this socket again after
+  // start.
   void start_async(int heartbeat_ms) {
     if (reader_.joinable()) return;
     heartbeat_ms_ = heartbeat_ms;
@@ -633,19 +633,6 @@ class Client {
     q_bytes_ -= f.payload.size();
     q_frames_.pop_front();
     return static_cast<long>(n);
-  }
-
-  // Drain up to `max` receipt records (version, CLOCK_MONOTONIC ns).
-  long drain_receipts(uint64_t* versions, int64_t* ts_ns, long max) {
-    std::lock_guard<std::mutex> lk(q_mu_);
-    long n = 0;
-    while (n < max && !receipts_.empty()) {
-      versions[n] = receipts_.front().version;
-      ts_ns[n] = receipts_.front().mono_ns;
-      receipts_.pop_front();
-      ++n;
-    }
-    return n;
   }
 
   void set_timeout(int timeout_ms) {
@@ -681,12 +668,8 @@ class Client {
           clock_gettime(CLOCK_MONOTONIC, &ts);
           int64_t ns = static_cast<int64_t>(ts.tv_sec) * 1000000000ll +
                        ts.tv_nsec;
-          uint64_t ver;
-          memcpy(&ver, f.payload.data(), 8);
           {
             std::lock_guard<std::mutex> lk(q_mu_);
-            receipts_.push_back({ver, ns});
-            if (receipts_.size() > 65536) receipts_.pop_front();
             q_bytes_ += f.payload.size();
             q_frames_.push_back({std::move(f), ns});
             // Cap the payload queue so a slow Python drain can't hoard
@@ -757,10 +740,6 @@ class Client {
     return true;
   }
 
-  struct Receipt {
-    uint64_t version;
-    int64_t mono_ns;
-  };
   struct QueuedFrame {
     Frame frame;
     int64_t rx_ns;
@@ -781,7 +760,6 @@ class Client {
   std::condition_variable q_cv_;
   std::deque<QueuedFrame> q_frames_;
   size_t q_bytes_ = 0;  // payload bytes queued (the eviction budget)
-  std::deque<Receipt> receipts_;
 };
 
 }  // namespace
@@ -943,7 +921,7 @@ int rl_sub_ping(void* h) {
   return c->send_frame(kFramePing, nullptr, 0) ? 0 : (c->reconnect() ? 1 : -1);
 }
 
-// ---- async subscription mode (C++ reader thread + receipt ledger) ----
+// ---- async subscription mode (C++ reader thread) ----
 int rl_sub_start_async(void* h, int heartbeat_ms) {
   static_cast<Client*>(h)->start_async(heartbeat_ms);
   return 0;
@@ -957,14 +935,6 @@ long rl_sub_next(void* h, int timeout_ms, uint64_t* version,
                  int64_t* rx_mono_ns, uint8_t* buf, size_t cap) {
   return static_cast<Client*>(h)->next_model(timeout_ms, version, rx_mono_ns,
                                              buf, cap);
-}
-
-// Drain up to `max` receipt records (every ModelPush ever parsed by the
-// async reader, including ones whose payloads were superseded before
-// Python drained them). The soak benches pair these against the
-// publisher's time.monotonic_ns() — same host, same clock.
-long rl_sub_receipts(void* h, uint64_t* versions, int64_t* ts_ns, long max) {
-  return static_cast<Client*>(h)->drain_receipts(versions, ts_ns, max);
 }
 
 long rl_sub_poll(void* h, int timeout_ms, uint64_t* version, uint8_t* buf,

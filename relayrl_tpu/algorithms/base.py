@@ -23,6 +23,7 @@ import threading
 import time
 from typing import Any, Callable, Mapping, Sequence
 
+from relayrl_tpu.algorithms.dispatch import InflightWindow, PublishSnapshot
 from relayrl_tpu.telemetry.spans import span, watch_gc
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.model_bundle import ModelBundle
@@ -107,7 +108,7 @@ class AlgorithmBase(abc.ABC):
     # dispatch paths pay one identity check.
     _guard_probes = None
 
-    # Bounded async-dispatch window (runtime/pipeline.InflightWindow);
+    # Bounded async-dispatch window (algorithms/dispatch.InflightWindow);
     # class defaults so pre-existing subclasses/tests that never touch
     # the pipeline keep working. max_inflight_updates=0 restores the
     # fully synchronous fence-every-dispatch behavior.
@@ -231,7 +232,7 @@ class AlgorithmBase(abc.ABC):
         return jax.process_count() > 1
 
     @property
-    def inflight(self) -> "InflightWindow":
+    def inflight(self) -> InflightWindow:
         """The dispatched-but-unfenced update window, created lazily so
         algorithms built before any training pay nothing. One per
         instance: every family's ``train_on_batch`` pushes its update's
@@ -239,8 +240,6 @@ class AlgorithmBase(abc.ABC):
         of the device and (b) is the fence ledger the server's
         ``drain()`` and the staging-buffer reuse proof rely on."""
         if self._inflight is None:
-            from relayrl_tpu.runtime.pipeline import InflightWindow
-
             self._inflight = InflightWindow(self.max_inflight_updates)
         return self._inflight
 
@@ -361,8 +360,6 @@ class AlgorithmBase(abc.ABC):
         """
         import jax
         import jax.numpy as jnp
-
-        from relayrl_tpu.runtime.pipeline import PublishSnapshot
 
         gather = getattr(self, "_gather_params", None)
         if gather is not None:

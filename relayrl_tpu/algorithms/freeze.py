@@ -3,7 +3,8 @@ config for fine-tune-style runs that train the heads and upper blocks
 over a frozen trunk.
 
 ``learner.freeze`` is a regex (or list of regexes) matched against
-"/"-joined parameter leaf paths (e.g. ``params/block_0/qkv/kernel``).
+"/"-joined parameter leaf paths (e.g. ``params/block_0/qkv/kernel``);
+the config loader validates it (``config/loader.normalize_freeze_spec``).
 Matching leaves are partitioned to ``optax.set_to_zero()`` via
 ``optax.multi_transform`` — NOT ``optax.masked``, which passes raw
 gradients through for unmasked leaves and silently moves the "frozen"
@@ -26,31 +27,6 @@ import re
 from typing import Any, Sequence
 
 import jax
-
-
-def normalize_freeze_spec(spec) -> tuple[str, ...]:
-    """Config value -> tuple of regex source strings. Accepts None/""
-    (no freezing), one string, or a list of strings; anything that does
-    not compile is rejected HERE (the loader calls this at load time —
-    the unknown-key warning convention's validate-early cousin) so a
-    typo'd pattern fails the config read, not the Nth training step."""
-    if spec is None or spec == "" or spec == []:
-        return ()
-    patterns = [spec] if isinstance(spec, str) else list(spec)
-    out = []
-    for p in patterns:
-        if not isinstance(p, str) or not p:
-            raise ValueError(
-                f"learner.freeze entries must be non-empty regex strings; "
-                f"got {p!r}")
-        try:
-            re.compile(p)
-        except re.error as e:
-            raise ValueError(
-                f"learner.freeze pattern {p!r} is not a valid regex: {e}"
-            ) from e
-        out.append(p)
-    return tuple(out)
 
 
 def leaf_path(path) -> str:

@@ -25,6 +25,7 @@ import numpy as np
 import optax
 
 from relayrl_tpu.algorithms.base import AlgorithmBase, anchor_path
+from relayrl_tpu.algorithms.dispatch import LazyMetrics
 from relayrl_tpu.config import ConfigLoader
 from relayrl_tpu.data.step_buffer import StepReplayBuffer
 from relayrl_tpu.telemetry.spans import span
@@ -215,8 +216,6 @@ class OffPolicyAlgorithm(AlgorithmBase):
         # [K, B, ...] and multi-host updates are one-batch collectives,
         # so fusion is single-host only.
         while k > 1 and self._place is None and n - i >= k:
-            from relayrl_tpu.runtime.pipeline import LazyMetrics
-
             chunk = host_batches[i:i + k]
             # Device-prefetched batches stack ON DEVICE (async dispatch):
             # np.stack on a just-uploaded jax.Array would block on the
@@ -255,8 +254,6 @@ class OffPolicyAlgorithm(AlgorithmBase):
         bounds outstanding updates). Multi-host: every process calls
         this with the same (broadcast) batch — the replay buffer itself
         stays coordinator-side."""
-        from relayrl_tpu.runtime.pipeline import LazyMetrics
-
         self._sync_version_mirror()
         with self._dispatch_span():
             probe_base = self._guard_pre_update()

@@ -20,7 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from relayrl_tpu.algorithms.base import AlgorithmBase, anchor_path
+from relayrl_tpu.algorithms.dispatch import LazyMetrics
 from relayrl_tpu.config import ConfigLoader
+from relayrl_tpu.config.loader import normalize_freeze_spec
 from relayrl_tpu.data import EpochBuffer
 from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.types.action import ActionRecord
@@ -125,10 +127,7 @@ class OnPolicyAlgorithm(AlgorithmBase):
         extras and is what the wire-v2 frozen-leaf savings claim is
         audited against. Shared by the whole family so the mask
         semantics cannot drift between REINFORCE/PPO/IMPALA."""
-        from relayrl_tpu.algorithms.freeze import (
-            freeze_info,
-            normalize_freeze_spec,
-        )
+        from relayrl_tpu.algorithms.freeze import freeze_info
 
         patterns = normalize_freeze_spec(
             params.get("freeze", learner.get("freeze")))
@@ -195,13 +194,11 @@ class OnPolicyAlgorithm(AlgorithmBase):
     def train_on_batch(self, host_batch: Mapping[str, Any]) -> Mapping[str, float]:
         """One jitted update on an assembled batch dict (host or device
         arrays), dispatched asynchronously: metrics come back as a
-        :class:`~relayrl_tpu.runtime.pipeline.LazyMetrics` that fences
+        :class:`~relayrl_tpu.algorithms.dispatch.LazyMetrics` that fences
         only when read (``log_epoch``/``stats``), and the in-flight
         window bounds how far dispatch runs ahead of the device.
         Multi-host: every process must call this with the same batch
         (see the server's broadcast loop)."""
-        from relayrl_tpu.runtime.pipeline import LazyMetrics
-
         self._sync_version_mirror()
         with self._dispatch_span():
             # Health-probe base copy BEFORE the donating update (guardrails

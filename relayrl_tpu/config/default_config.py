@@ -496,7 +496,7 @@ DEFAULT_CONFIG: dict = {
         # Terminal-boundary scorer: "programmatic" (all-integer
         # successor-pattern count — the CI scorer) or "reward_model"
         # (frozen randomly-initialized transformer critic holding its
-        # OWN params — rlhf/scorers.py; rm_* size it, rm_seed fixes it
+        # OWN params — envs/scorers.py; rm_* size it, rm_seed fixes it
         # so the score stage and any self-contained env agree).
         "scorer": "programmatic",
         "rm_d_model": 32,
@@ -652,9 +652,6 @@ DEFAULT_CONFIG: dict = {
         # write) on a dedicated latest-wins thread; false publishes
         # synchronously on the learner thread.
         "async_publish": True,
-        # jax.device_put assembled batches at dispatch time so the H2D
-        # copy overlaps in-flight device compute.
-        "device_prefetch": True,
         # Ingest decode workers feeding the learner thread (the native
         # decoder drops the GIL, so extra workers scale on real cores).
         "ingest_staging_threads": 1,

@@ -22,6 +22,7 @@ from __future__ import annotations
 import copy
 import json
 import os
+import re
 from pathlib import Path
 from typing import Any, Mapping
 
@@ -46,6 +47,31 @@ def _closest(key: str, candidates) -> str | None:
     matches = difflib.get_close_matches(key, [str(c) for c in candidates],
                                         n=1, cutoff=0.6)
     return matches[0] if matches else None
+
+
+def normalize_freeze_spec(spec) -> tuple[str, ...]:
+    """Config value -> tuple of regex source strings. Accepts None/""
+    (no freezing), one string, or a list of strings; anything that does
+    not compile is rejected HERE (the loader calls this at load time —
+    the unknown-key warning convention's validate-early cousin) so a
+    typo'd pattern fails the config read, not the Nth training step."""
+    if spec is None or spec == "" or spec == []:
+        return ()
+    patterns = [spec] if isinstance(spec, str) else list(spec)
+    out = []
+    for p in patterns:
+        if not isinstance(p, str) or not p:
+            raise ValueError(
+                f"learner.freeze entries must be non-empty regex strings; "
+                f"got {p!r}")
+        try:
+            re.compile(p)
+        except re.error as e:
+            raise ValueError(
+                f"learner.freeze pattern {p!r} is not a valid regex: {e}"
+            ) from e
+        out.append(p)
+    return tuple(out)
 
 
 class Endpoint:
@@ -261,8 +287,6 @@ class ConfigLoader:
         # with a warning rather than crashing server construction.
         freeze = params.get("freeze")
         if freeze is not None:
-            from relayrl_tpu.algorithms.freeze import normalize_freeze_spec
-
             try:
                 params["freeze"] = list(normalize_freeze_spec(freeze)) or None
             except ValueError as e:

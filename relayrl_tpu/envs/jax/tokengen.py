@@ -4,12 +4,12 @@ All-integer dynamics (prompt sampling, token buffer writes, flags), so
 the parity goldens hold this env to FULL bitwise equality against the
 numpy twin on observation/flags/counters from injected states. The
 reward is paid by the pluggable scorer at the terminal step; the
-built-in scorers (relayrl_tpu/rlhf/scorers.py) expose one jitted
+built-in scorers (relayrl_tpu/envs/scorers.py) expose one jitted
 implementation to both planes, so the scored reward is bit-equal too.
 
 ``scorer.score_jax(tokens, prompt_len, gen_len)`` must be traceable
 (pure function of the int32 token buffer; ``prompt_len`` arrives as a
-static Python int). A :class:`~relayrl_tpu.rlhf.scorers.
+static Python int). A :class:`~relayrl_tpu.envs.scorers.
 RewardModelScorer` closes over its frozen transformer params — static
 per-instance configuration under the JaxEnv contract, exactly like
 physics constants — so the whole episode, scoring included, fuses into

@@ -26,7 +26,7 @@ Dynamics are all-integer (prompt sampling, buffer writes, flags), so
 the pure-JAX twin (``envs/jax/tokengen.py``) holds FULL bitwise parity
 on observation/flags/counters; the reward is bit-equal too whenever the
 two planes share the scorer implementation (the built-in scorers expose
-one jitted implementation to both — relayrl_tpu/rlhf/scorers.py).
+one jitted implementation to both — relayrl_tpu/envs/scorers.py).
 """
 
 from __future__ import annotations
@@ -46,9 +46,8 @@ def _resolve_scorer(scorer):
     if scorer is None:
         return None
     if isinstance(scorer, str):
-        # Lazy so `import relayrl_tpu.envs` stays light; the names live
-        # beside the scheduler that consumes them.
-        from relayrl_tpu.rlhf.scorers import make_scorer
+        # Lazy so `import relayrl_tpu.envs` stays jax-free.
+        from relayrl_tpu.envs.scorers import make_scorer
 
         return make_scorer(scorer)
     if (callable(getattr(scorer, "score_np", None))

@@ -18,7 +18,7 @@ All three come back as **unresolved device scalars** merged into the
 update's metrics dict: they ride the same in-flight window as the
 metrics (same XLA stream ⇒ "probe ready" implies "update done") and are
 resolved lazily at the fence, exactly like
-:class:`~relayrl_tpu.runtime.pipeline.LazyMetrics` — zero host sync on
+:class:`~relayrl_tpu.algorithms.dispatch.LazyMetrics` — zero host sync on
 the dispatch hot path (jaxlint JAX02/JAX06 clean by construction).
 
 The :class:`DivergenceWatchdog` consumes resolved probes plus two host

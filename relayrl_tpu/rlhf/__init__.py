@@ -4,7 +4,7 @@ organize LLM-scale RL exactly this way).
 
 Pieces:
 
-* :mod:`relayrl_tpu.rlhf.scorers`   — the pluggable terminal-boundary
+* :mod:`relayrl_tpu.envs.scorers`   — the pluggable terminal-boundary
   scorer interface with two built-ins (programmatic CI scorer, frozen
   transformer reward model);
 * :mod:`relayrl_tpu.rlhf.scheduler` — the dataflow scheduler wiring
@@ -20,7 +20,7 @@ masks in ``algorithms/freeze.py`` (the ``learner.freeze`` knob), and
 the live dataflow test in ``tests/test_rlhf.py::TestLivePlane``.
 """
 
-from relayrl_tpu.rlhf.scorers import (  # noqa: F401
+from relayrl_tpu.envs.scorers import (  # noqa: F401
     SCORERS,
     ProgrammaticScorer,
     RewardModelScorer,

@@ -56,6 +56,8 @@ from typing import Callable
 
 import numpy as np
 
+from relayrl_tpu.envs.scorers import make_scorer
+from relayrl_tpu.telemetry.core import LAG_BUCKETS
 from relayrl_tpu.types.columnar import (
     DecodedTrajectory,
     encode_columnar_frame,
@@ -66,9 +68,6 @@ from relayrl_tpu.types.trajectory import (
     deserialize_actions,
     serialize_actions,
 )
-
-#: Version-lag buckets: unit-ish resolution near on-policy, coarse tail.
-LAG_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
 
 
 def extract_generation(records, prompt_len: int):
@@ -615,8 +614,6 @@ class RlhfScheduler:
                            else GenerationStage(host, self.venv, seed=seed))
 
     def _make_scorer(self, p: dict):
-        from relayrl_tpu.rlhf.scorers import make_scorer
-
         if p["scorer"] == "reward_model":
             return make_scorer(
                 "reward_model", vocab_size=p["vocab_size"],

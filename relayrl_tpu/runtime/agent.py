@@ -109,11 +109,8 @@ def ship_unroll(owner, agent_id: str, payload: bytes, origin, version: int,
             owner.spool.send(payload, agent_id, trace=trace, report=report)
             return
         # actor.spool_entries == 0: the pre-recovery direct path
-        from relayrl_tpu.transport.base import (
-            IngestNack,
-            tag_agent_report,
-            tag_agent_trace,
-        )
+        from relayrl_tpu.telemetry.trace import tag_agent_trace
+        from relayrl_tpu.transport.base import IngestNack, tag_agent_report
 
         wire_id = tag_agent_report(agent_id, report)
         if trace is not None:

@@ -35,6 +35,7 @@ from relayrl_tpu.algorithms import build_algorithm, registered_algorithms
 from relayrl_tpu.config import ConfigLoader
 from relayrl_tpu.telemetry import actor_ledger
 from relayrl_tpu.telemetry.aggregate import is_snapshot_frame
+from relayrl_tpu.telemetry.core import LAG_BUCKETS
 from relayrl_tpu.telemetry.spans import span, watch_gc
 from relayrl_tpu.telemetry.trace import SKEW_GUARD_NS, TrajCtx
 from relayrl_tpu.transport import make_server_transport
@@ -186,11 +187,6 @@ class TrainingServer:
             "relayrl_server_duplicate_trajectories_total",
             "sequence-tagged trajectories dropped by idempotent ingest "
             "(replays, retry storms, duplicate-injection faults)")
-        # Same bucket grid as the scheduler's emit-side lag histogram —
-        # the two distributions are read side by side (telemetry.top), so
-        # the grids must never drift apart.
-        from relayrl_tpu.rlhf.scheduler import LAG_BUCKETS
-
         self._m_rlhf_train_lag = reg.histogram(
             "relayrl_rlhf_train_lag_versions",
             "behavior version (data['bver'], stamped at generation) vs "
@@ -583,10 +579,8 @@ class TrainingServer:
         # logs defer until their update's fence. Knobs (docs/operations):
         #   learner.max_inflight_updates  (algorithm-side; 0 = sync)
         #   learner.async_publish         false = publish on learner thread
-        #   learner.device_prefetch       false = H2D inside the dispatch
         #   learner.ingest_staging_threads  decode workers (default 1)
         self._async_publish = bool(learner_cfg.get("async_publish", True))
-        self._prefetch = bool(learner_cfg.get("device_prefetch", True))
         self._staging_count = max(
             1, int(learner_cfg.get("ingest_staging_threads", 1)))
         self._publisher = None
@@ -1391,12 +1385,11 @@ class TrainingServer:
             with span("rl:learner.dispatch", self.timings, "dispatch_s",
                       metric=self._m_dispatch):
                 try:
-                    if self._prefetch:
-                        # Eager sharded H2D (device_put with NamedSharding
-                        # via the mesh-aware _place): the transfer enqueues
-                        # now and overlaps the in-flight updates instead of
-                        # running inside the dispatch below.
-                        batch = algo.stage_batch(batch)
+                    # Eager sharded H2D (device_put with NamedSharding
+                    # via the mesh-aware _place): the transfer enqueues
+                    # now and overlaps the in-flight updates instead of
+                    # running inside the dispatch below.
+                    batch = algo.stage_batch(batch)
                     # Dispatch-only: the sharded update enters the in-flight
                     # window unfenced (its collectives live inside the XLA
                     # program, so nothing here blocks the host).
@@ -1700,11 +1693,10 @@ class TrainingServer:
                 updated = got is not None
                 if updated:
                     batches = got if isinstance(got, list) else [got]
-                    if self._prefetch:
-                        # Eager H2D: enqueued now, the transfer overlaps
-                        # the in-flight updates instead of running after
-                        # the window fence below.
-                        batches = [algo.stage_batch(b) for b in batches]
+                    # Eager H2D: enqueued now, the transfer overlaps the
+                    # in-flight updates instead of running after the window
+                    # fence below.
+                    batches = [algo.stage_batch(b) for b in batches]
                     if isinstance(got, list):
                         algo.train_on_batches(batches)
                     else:

@@ -147,10 +147,8 @@ class TrajectorySpool:
         the fleet into silence), while the retained entry keeps the
         tagged id so a replay re-ships both verbatim (the server's dedup
         verdict keeps a replayed report from counting twice)."""
-        from relayrl_tpu.transport.base import (
-            tag_agent_report,
-            tag_agent_trace,
-        )
+        from relayrl_tpu.telemetry.trace import tag_agent_trace
+        from relayrl_tpu.transport.base import tag_agent_report
 
         wire_id = agent_id
         if report is not None:

@@ -18,7 +18,7 @@ Design constraints (ISSUE 4 tentpole, part 1):
 * **JAX-aware: never fence a dispatch.** :meth:`Gauge.set` stores
   whatever it is given — a host float or an in-flight device scalar —
   and resolves to a host float only inside :meth:`Registry.snapshot`
-  (the same deferral as ``runtime/pipeline.LazyMetrics``: the fence
+  (the same deferral as ``algorithms/dispatch.LazyMetrics``: the fence
   happens where the value is *read*, at export time, never on the
   thread that dispatched it). Histograms take host floats only (their
   bucketing is a comparison, which on a device value would be a sync);
@@ -68,6 +68,12 @@ def log_buckets(lo: float, hi: float, per_decade: int = 3) -> tuple[float, ...]:
 # requests queued behind an overload — where the old 10 s top bucket
 # pinned every tail sample in +Inf.
 LATENCY_BUCKETS_WIDE = log_buckets(1e-4, 60.0, per_decade=3)
+
+# Version-lag grid: unit-ish resolution near on-policy, coarse tail. One
+# grid for the scheduler's emit-side lag histogram and the server's
+# train-side one — the two distributions are read side by side
+# (telemetry.top), so the grids must never drift apart.
+LAG_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
 
 # End-to-end age grid (distributed tracing): 1 ms .. 600 s. Data age
 # (env-step → consumed-by-update) and model age (publish → applied)
@@ -432,5 +438,5 @@ class NullRegistry:
 __all__ = [
     "Counter", "Gauge", "GaugeFn", "Histogram", "Registry", "NullRegistry",
     "NULL_METRIC", "DEFAULT_TIME_BUCKETS", "LATENCY_BUCKETS_WIDE",
-    "AGE_BUCKETS", "log_buckets",
+    "LAG_BUCKETS", "AGE_BUCKETS", "log_buckets",
 ]

@@ -9,9 +9,9 @@ each write is flushed whole).
 
 Every event carries the registry's ``run_id``, a wall-clock ``t_unix``
 (human correlation) and a ``mono_ns`` CLOCK_MONOTONIC stamp — the same
-clock the transports stamp model receipts with, so journal
-events pair against wire receipts across processes on one host
-(``AgentTransport.drain_receipts``, transport/base.py).
+clock the transports stamp model receipts with (``rx_ns``), so
+journal events pair against the ``receipt`` hop across processes on one
+host.
 
 Event volume is run-event scale (tens per second at most: publishes,
 registrations, checkpoints); the one potentially hot type — ``drop`` —

@@ -67,11 +67,6 @@ import threading
 import time
 from collections import deque
 
-from relayrl_tpu.transport.base import (  # noqa: F401 (re-exported)
-    split_agent_trace,
-    tag_agent_trace,
-)
-
 # Upstream (trajectory) hops in causal order; the analyzer sorts by this.
 TRAJ_HOPS = ("env", "encode", "send", "relay", "ingest", "dedup",
              "staging", "update")
@@ -171,7 +166,7 @@ class Tracer:
         self.recorder = SpanRecorder(ring)
         self.proc = proc or f"pid{os.getpid()}"
         # Wire-safe trace-id prefix: the ctx tag's validator admits
-        # lowercase hex + '-' only (transport.base.split_agent_trace).
+        # lowercase hex + '-' only (split_agent_trace).
         self._id_prefix = f"{os.getpid():x}"
         self.journal = bool(journal)
         self._sample_lock = threading.Lock()
@@ -357,6 +352,40 @@ def record_model_receipt(version: int, rx_ns: int, pub_ns: int | None,
                 backend=backend, version=int(version))
     if pub_ns is not None and 0 <= done - pub_ns < SKEW_GUARD_NS:
         tr.observe_model_age((done - pub_ns) / 1e9)
+
+
+# -- the context's wire form: the ``#t`` tag on an envelope id --
+#
+# A sampled trajectory's trace context rides the SAME envelope-id channel
+# as the transport's seq tag, immediately before it:
+# ``<agent>#t<ctx>#s<seq>``. The ctx payload is three dot-separated
+# lowercase-hex fields (trace id, born_ns, born_version — TrajCtx),
+# validated strictly on split so an agent id that happens to contain
+# ``#t`` cannot be misparsed. Coalescing with the id (instead of a new
+# envelope key) is what makes the context survive the native C++ columnar
+# raw-fallback path verbatim — codec.cc drops unknown envelope KEYS but
+# carries the id untouched, the seq-tag lesson from PR 6 (locked by an
+# explicit passthrough test in tests/test_trace.py).
+_TRACE_TAG = "#t"
+_CTX_HEX = set("0123456789abcdef-")
+
+
+def tag_agent_trace(agent_id: str, ctx_text: str) -> str:
+    return f"{agent_id}{_TRACE_TAG}{ctx_text}"
+
+
+def split_agent_trace(agent_id: str) -> tuple[str, str | None]:
+    """``"a#tdead.beef.2" -> ("a", "dead.beef.2")``; ids without a
+    valid trace tag as their last tag -> ``(agent_id, None)``
+    (:func:`split_agent_tags` takes every tag off in one call)."""
+    base, sep, tail = agent_id.rpartition(_TRACE_TAG)
+    if not sep:
+        return agent_id, None
+    parts = tail.split(".")
+    if len(parts) != 3 or not all(
+            p and all(c in _CTX_HEX for c in p) for p in parts):
+        return agent_id, None
+    return base, tail
 
 
 def split_ctx(agent_id: str) -> tuple[str, TrajCtx | None]:

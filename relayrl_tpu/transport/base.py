@@ -27,10 +27,13 @@ from __future__ import annotations
 import abc
 import re
 import threading
-from collections import deque
 from typing import Callable
 
 import msgpack
+
+from relayrl_tpu import telemetry
+from relayrl_tpu.telemetry.core import LATENCY_BUCKETS_WIDE
+from relayrl_tpu.telemetry.trace import split_agent_trace
 
 # -- command frames (ref: GET_MODEL/MODEL_SET/ID_LOGGED strings,
 #    training_zmq.rs:747-829) --
@@ -161,48 +164,15 @@ def split_agent_seq(agent_id: str) -> tuple[str, int | None]:
     return agent_id, None
 
 
-# -- trace-context tags (distributed tracing, telemetry/trace.py) --
-#
-# A sampled trajectory's trace context rides the SAME envelope-id channel
-# as the seq tag, immediately before it: ``<agent>#t<ctx>#s<seq>``. The
-# ctx payload is three dot-separated lowercase-hex fields (trace id,
-# born_ns, born_version — telemetry.trace.TrajCtx), validated strictly
-# on split so an agent id that happens to contain ``#t`` cannot be
-# misparsed. Coalescing with the id (instead of a new envelope key)
-# is what makes the context survive the native C++ columnar raw-fallback
-# path verbatim — codec.cc drops unknown envelope KEYS but carries the
-# id untouched, the seq-tag lesson from PR 6 (locked by an explicit
-# passthrough test in tests/test_trace.py).
-_TRACE_TAG = "#t"
-_CTX_HEX = set("0123456789abcdef-")
-
-
-def tag_agent_trace(agent_id: str, ctx_text: str) -> str:
-    return f"{agent_id}{_TRACE_TAG}{ctx_text}"
-
-
-def split_agent_trace(agent_id: str) -> tuple[str, str | None]:
-    """``"a#tdead.beef.2" -> ("a", "dead.beef.2")``; ids without a
-    valid trace tag as their last tag -> ``(agent_id, None)``
-    (:func:`split_agent_tags` takes every tag off in one call)."""
-    base, sep, tail = agent_id.rpartition(_TRACE_TAG)
-    if not sep:
-        return agent_id, None
-    parts = tail.split(".")
-    if len(parts) != 3 or not all(
-            p and all(c in _CTX_HEX for c in p) for p in parts):
-        return agent_id, None
-    return base, tail
-
-
 # -- actor report tags (the actor tier's time ledger, telemetry/actor_ledger.py) --
 #
 # Every trajectory an actor host ships carries, on the same envelope-id
 # channel and for the same reason (the id is the one field every backend,
 # container, relay and the native core carry verbatim), the host's time
 # ledger as DELTAS since its previous shipment plus the unroll's born stamp
-# and version: ``<agent>#r<report>#t<ctx>#s<seq>``, the ``#t`` tag only on a
-# sampled trajectory, the ``#s`` tag only through a spool. The payload is
+# and version: ``<agent>#r<report>#t<ctx>#s<seq>``, the ``#t`` tag (the trace
+# context's, ``telemetry/trace.py``) only on a sampled trajectory, the ``#s``
+# tag only through a spool. The payload is
 # dot-separated lowercase-hex integers, a format version first
 # (``telemetry.actor_ledger`` owns the field order); like the trace tag it is
 # validated strictly on split. :func:`split_agent_tags` strips the three
@@ -359,8 +329,6 @@ def swallow_decode_error(backend: str, site: str, exc: Exception) -> None:
     """
     if not isinstance(exc, TRANSIENT_DECODE_ERRORS):
         raise exc
-    from relayrl_tpu import telemetry
-
     telemetry.get_registry().counter(
         "relayrl_transport_swallowed_errors_total",
         "malformed frames dropped by receive loops",
@@ -377,30 +345,6 @@ def swallow_decode_error(backend: str, site: str, exc: Exception) -> None:
               f"occurrences logged only to the counter", flush=True)
 
 
-class ReceiptLedger:
-    """Pre-decode model-receipt ledger: ``(version, rx_mono_ns)`` pairs
-    stamped the moment a frame leaves the socket, drained destructively.
-    The Python mirror of the native C++ reader's ledger
-    (``rl_sub_receipts``), shared by the zmq and grpc agent transports
-    so the stamping semantics and bounds can never drift between
-    backends (stamped post-decode, a busy SUB thread loses receipts)."""
-
-    def __init__(self, maxlen: int = 65536):
-        self._receipts: deque[tuple[int, int]] = deque(maxlen=maxlen)
-        self._lock = threading.Lock()
-
-    def append(self, version: int, rx_ns: int) -> None:
-        with self._lock:
-            self._receipts.append((version, rx_ns))
-
-    def drain(self, max_n: int = 65536) -> list[tuple[int, int]]:
-        with self._lock:
-            out: list[tuple[int, int]] = []
-            while self._receipts and len(out) < max_n:
-                out.append(self._receipts.popleft())
-            return out
-
-
 def register_subscriber_gauge(backend: str, fn, bind: str = "") -> None:
     """Install the ``relayrl_transport_subscribers`` pull-gauge for one
     server transport (ISSUE 11 satellite: the fan-out observability
@@ -413,8 +357,6 @@ def register_subscriber_gauge(backend: str, fn, bind: str = "") -> None:
     instances — a process hosting two same-backend server transports
     (an in-process relay next to a root) must not clobber one gauge
     with the other's table."""
-    from relayrl_tpu import telemetry
-
     labels = {"backend": backend}
     if bind:
         labels["bind"] = bind
@@ -432,8 +374,6 @@ def server_wire_metrics(backend: str,
     ``publish_total``(/``publish_bytes``) for model broadcasts.
     ``include_publish_bytes=False`` for pull-based planes (grpc long
     polls) where no broadcast bytes exist to count."""
-    from relayrl_tpu import telemetry
-
     reg = telemetry.get_registry()
     labels = {"backend": backend}
     metrics = {
@@ -454,12 +394,6 @@ def server_wire_metrics(backend: str,
     return metrics
 
 
-def _wide_buckets():
-    from relayrl_tpu.telemetry.core import LATENCY_BUCKETS_WIDE
-
-    return LATENCY_BUCKETS_WIDE
-
-
 def agent_wire_metrics(backend: str) -> dict:
     """The shared agent-side transport instrument set, one registry
     lookup per connection (all metrics are process-aggregated across
@@ -477,8 +411,6 @@ def agent_wire_metrics(backend: str) -> dict:
       carries the publisher's monotonic stamp (same-host pairs only)
     * ``reconnects``                   — transport heals/redials
     """
-    from relayrl_tpu import telemetry
-
     reg = telemetry.get_registry()
     labels = {"backend": backend}
     return {
@@ -497,7 +429,7 @@ def agent_wire_metrics(backend: str) -> dict:
         "send_seconds": reg.histogram(
             "relayrl_transport_send_seconds",
             "one trajectory send on the caller thread", labels,
-            buckets=_wide_buckets()),
+            buckets=LATENCY_BUCKETS_WIDE),
         "model_recv_total": reg.counter(
             "relayrl_transport_model_recv_total",
             "model frames received on the subscription", labels),
@@ -507,7 +439,7 @@ def agent_wire_metrics(backend: str) -> dict:
         "model_deliver_seconds": reg.histogram(
             "relayrl_transport_model_deliver_seconds",
             "receipt stamp to on_model return (decode+swap+persist)",
-            labels, buckets=_wide_buckets()),
+            labels, buckets=LATENCY_BUCKETS_WIDE),
         "receipt_latency_seconds": reg.histogram(
             "relayrl_transport_receipt_latency_seconds",
             "publish stamp to receipt stamp, same-host monotonic pairs",
@@ -614,13 +546,7 @@ class ServerTransport(abc.ABC):
 
 
 class AgentTransport(abc.ABC):
-    """Agent-side: handshake, trajectory send, model-update subscription.
-
-    Backends that stamp model receipts pre-decode additionally expose
-    ``drain_receipts() -> [(version, rx_mono_ns), ...]`` — the native
-    C++ ledger's surface, mirrored in Python by the zmq/grpc listeners
-    so fan-out accounting is backend-uniform.
-    """
+    """Agent-side: handshake, trajectory send, model-update subscription."""
 
     def __init__(self):
         self.on_model: Callable[[int, bytes], None] = lambda *_: None

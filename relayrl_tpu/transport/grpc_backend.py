@@ -38,7 +38,6 @@ from relayrl_tpu.transport.base import (
     NACK_QUARANTINED,
     AgentTransport,
     IngestNack,
-    ReceiptLedger,
     ServerTransport,
     agent_wire_metrics,
     server_wire_metrics,
@@ -363,10 +362,6 @@ class GrpcAgentTransport(AgentTransport):
         # failed retry — a 60s server restart is ONE reconnect, not 60.
         self._poll_broken = False
         self._poll_fail_streak = 0
-        # Pre-decode receipt ledger (base.ReceiptLedger), same surface
-        # as the native C++ and zmq ledgers — soak fan-out accounting is
-        # backend-uniform.
-        self._ledger = ReceiptLedger()
 
     def _make_channel(self) -> None:
         """(Re)build the channel + stubs. Reconnect backoff is bounded by
@@ -434,7 +429,6 @@ class GrpcAgentTransport(AgentTransport):
         if resp.get("code") == 1 and "model" in resp:
             self._known_version = int(resp["ver"])
             if record:  # subscription deliveries only, not handshakes
-                self._ledger.append(int(resp["ver"]), rx_ns)
                 self._m["model_recv_total"].inc()
                 self._m["model_recv_bytes"].inc(len(raw))
             return int(resp["ver"]), resp["model"], rx_ns
@@ -583,11 +577,6 @@ class GrpcAgentTransport(AgentTransport):
                 )
 
                 record_model_receipt(version, rx_ns, None, "grpc")
-
-    def drain_receipts(self, max_n: int = 65536) -> list[tuple[int, int]]:
-        """Drain the pre-decode receipt ledger (same surface as the
-        native C++ and zmq ledgers)."""
-        return self._ledger.drain(max_n)
 
     def request_resync(self, held_version: int = -1) -> None:
         """Model-wire v2 resync: forget the held version so the next

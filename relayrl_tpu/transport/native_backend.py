@@ -11,30 +11,7 @@ from __future__ import annotations
 
 import os
 
-_LIB_NAMES = ("librelayrl_native.so",)
-
-
-def _find_library() -> str | None:
-    # Wheel install: the .so ships inside the package (setup.py builds
-    # it into relayrl_tpu/_native/ — reference parity with its
-    # maturin-bundled native artifact). Checked first so an installed
-    # user never silently downgrades; source checkouts fall through to
-    # the make -C native output.
-    try:
-        from relayrl_tpu._native import bundled_library_path
-
-        bundled = bundled_library_path()
-        if bundled is not None:
-            return bundled
-    except ImportError:
-        pass
-    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    for name in _LIB_NAMES:
-        for cand in (os.path.join(here, "native", name),
-                     os.path.join(here, name)):
-            if os.path.isfile(cand):
-                return cand
-    return None
+from relayrl_tpu._native import find_library
 
 
 def _try_build() -> None:
@@ -56,18 +33,18 @@ def _try_build() -> None:
 
 
 def native_available(build: bool = True) -> bool:
-    if _find_library() is not None:
+    if find_library() is not None:
         return True
     if build:
         _try_build()
-    return _find_library() is not None
+    return find_library() is not None
 
 
 def _require_lib() -> str:
-    path = _find_library()
+    path = find_library()
     if path is None:
         _try_build()
-        path = _find_library()
+        path = find_library()
     if path is None:
         raise RuntimeError(
             "native transport library not built and auto-build failed; run "

@@ -87,10 +87,6 @@ def _load(lib_path: str) -> ctypes.CDLL:
     lib.rl_sub_next.argtypes = [
         ctypes.c_void_p, ctypes.c_int, ctypes.POINTER(ctypes.c_uint64),
         ctypes.POINTER(ctypes.c_int64), u8p, ctypes.c_size_t]
-    lib.rl_sub_receipts.restype = ctypes.c_long
-    lib.rl_sub_receipts.argtypes = [
-        ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64),
-        ctypes.POINTER(ctypes.c_int64), ctypes.c_long]
     # native gRPC/HTTP-2 server (grpc_server.cc): same embedder surface
     lib.rl_grpc_server_create.restype = ctypes.c_void_p
     lib.rl_grpc_server_create.argtypes = [ctypes.c_char_p, ctypes.c_uint16]
@@ -553,7 +549,7 @@ class NativeAgentTransportImpl(AgentTransport):
                              else float(heartbeat_s))
         # Async mode: a C++ reader thread owns the socket — it parses and
         # CLOCK_MONOTONIC-timestamps every ModelPush the moment it arrives
-        # (GIL-free; the receipt ledger behind drain_receipts()),
+        # (GIL-free; the stamp rl_sub_next hands back with each frame),
         # owns the sub-channel keepalive, and reconnects. The
         # Python thread below only drains the decoded queue.
         self._lib.rl_sub_start_async(self._sub, int(self._heartbeat_s * 1000))
@@ -561,17 +557,6 @@ class NativeAgentTransportImpl(AgentTransport):
         self._listener = threading.Thread(target=self._sub_loop,
                                           name="native-model-sub", daemon=True)
         self._listener.start()
-
-    def drain_receipts(self, max_n: int = 65536) -> list[tuple[int, int]]:
-        """Drain the C++ receipt ledger: ``[(version, rx_mono_ns), ...]``,
-        stamped at frame parse in the native reader thread — comparable
-        against ``time.monotonic_ns()`` of any process on this host."""
-        if self._sub is None:
-            return []
-        vers = (ctypes.c_uint64 * max_n)()
-        ts = (ctypes.c_int64 * max_n)()
-        n = self._lib.rl_sub_receipts(self._sub, vers, ts, max_n)
-        return [(int(vers[i]), int(ts[i])) for i in range(int(n))]
 
     def _sub_loop(self) -> None:
         from relayrl_tpu.transport.modelwire import ChunkReassembler

@@ -38,7 +38,6 @@ from relayrl_tpu.transport.base import (
     REPLY_ERROR,
     REPLY_ID_LOGGED,
     REPLY_MODEL,
-    ReceiptLedger,
     ServerTransport,
     agent_wire_metrics,
     pack_model_frame,
@@ -305,15 +304,9 @@ class ZmqAgentTransport(AgentTransport):
         self._retry = RetryPolicy.from_dict(retry)
         self._fault_send = faults.site("agent.send")
         self._fault_model = faults.site("agent.model")
-        # Pre-decode receipt ledger (base.ReceiptLedger — the native C++
-        # ledger's Python mirror): (version, rx_mono_ns) stamped the
-        # moment recv returns, BEFORE the frame is decoded or the swap
-        # runs — so fan-out accounting measures the wire, not the Python
-        # decode backlog behind it (stamped after, a busy fleet loses them).
-        self._ledger = ReceiptLedger()
         # Chunked model frames (server transport.chunk_bytes) reassemble
-        # here before the ledger stamp / on_model, so one publish is one
-        # receipt no matter how many wire messages carried it.
+        # here before on_model, so one publish is one receipt no matter
+        # how many wire messages carried it.
         from relayrl_tpu.transport.modelwire import ChunkReassembler
 
         self._reasm = ChunkReassembler()
@@ -484,7 +477,6 @@ class ZmqAgentTransport(AgentTransport):
         bundle = self._reasm.feed(bundle)
         if bundle is None:
             return  # mid-chunk: the receipt stamps on the last part
-        self._ledger.append(version, rx_ns)
         self._m["model_recv_total"].inc()
         if pub_ns is not None and 0 <= rx_ns - pub_ns < int(300e9):
             # Same-host monotonic pair only. CLOCK_MONOTONIC is
@@ -527,13 +519,6 @@ class ZmqAgentTransport(AgentTransport):
                     self._notify_reconnect()
         except (zmq.ZMQError, KeyError, OSError):
             pass  # monitor died (socket rebuilt): detection degrades
-
-    def drain_receipts(self, max_n: int = 65536) -> list[tuple[int, int]]:
-        """Drain the pre-decode receipt ledger: ``[(version,
-        rx_mono_ns), ...]`` — same surface and semantics as the native
-        C++ ledger (``rl_sub_receipts``), so soak fan-out accounting is
-        backend-uniform."""
-        return self._ledger.drain(max_n)
 
     # Resync-request floor: a decoder stuck awaiting a keyframe raises
     # WireBaseMismatch once, but repeated divergences (chaos drills,

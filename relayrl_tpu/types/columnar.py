@@ -27,6 +27,7 @@ import zlib
 
 import numpy as np
 
+from relayrl_tpu._native import find_library
 from relayrl_tpu.types.action import ActionRecord
 from relayrl_tpu.types.dtypes import DType, from_numpy_dtype, to_numpy_dtype
 from relayrl_tpu.types.tensor import decode_tensor, encode_tensor
@@ -439,9 +440,7 @@ def _load_codec():
         if _codec_checked:
             return _codec_lib
         _codec_checked = True
-        from relayrl_tpu.transport.native_backend import _find_library
-
-        path = _find_library()
+        path = find_library()
         if path is None:
             return None
         try:

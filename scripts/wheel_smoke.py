@@ -38,12 +38,10 @@ def main() -> None:
     assert "site-packages" in pkg, (
         f"smoke must run against an INSTALLED wheel, got {pkg}")
 
-    from relayrl_tpu.transport.native_backend import (
-        _find_library,
-        native_available,
-    )
+    from relayrl_tpu._native import find_library
+    from relayrl_tpu.transport.native_backend import native_available
 
-    lib = _find_library()
+    lib = find_library()
     print("native lib:", lib)
     assert lib is not None, "no native library in the installed wheel"
     assert os.sep + "_native" + os.sep in lib, (

@@ -382,7 +382,7 @@ class TestTokenGenParity:
         float32), and flags bit-equal to the numpy twin from injected
         states, across EOS endings and max_new_tokens endings."""
         from relayrl_tpu.envs import TokenGenEnv
-        from relayrl_tpu.rlhf.scorers import ProgrammaticScorer
+        from relayrl_tpu.envs.scorers import ProgrammaticScorer
 
         scorer = ProgrammaticScorer(vocab_size=6)
         kwargs = dict(vocab_size=6, prompt_len=2, max_new_tokens=5,

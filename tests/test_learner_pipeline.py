@@ -1,4 +1,5 @@
-"""Pipelined learner hot path (runtime/pipeline.py + the server wiring).
+"""Pipelined learner hot path (algorithms/dispatch.py, runtime/pipeline.py
+and the server wiring).
 
 The contract under test is ISSUE 2's acceptance bar: pipelining may not
 change learning semantics — the async-dispatch window, staging-slab
@@ -16,11 +17,8 @@ import numpy as np
 import pytest
 
 from relayrl_tpu.algorithms import build_algorithm
-from relayrl_tpu.runtime.pipeline import (
-    InflightWindow,
-    LazyMetrics,
-    ModelPublisher,
-)
+from relayrl_tpu.algorithms.dispatch import InflightWindow, LazyMetrics
+from relayrl_tpu.runtime.pipeline import ModelPublisher
 from relayrl_tpu.types.action import ActionRecord
 
 OBS_DIM, ACT_DIM = 4, 2

@@ -52,7 +52,7 @@ def _clean_telemetry():
 
 class TestScorers:
     def test_programmatic_counts_successor_chain(self):
-        from relayrl_tpu.rlhf.scorers import ProgrammaticScorer
+        from relayrl_tpu.envs.scorers import ProgrammaticScorer
 
         sc = ProgrammaticScorer(vocab_size=6)
         # prompt [2, 3]; generated [4, 5, 1, 0]: 4=3+1 hit, 5=4+1 hit,
@@ -69,7 +69,7 @@ class TestScorers:
         assert batch[0] == 2.0 and batch[1] == sc.score_np(tokens, 2, 2)
 
     def test_reward_model_frozen_and_deterministic(self):
-        from relayrl_tpu.rlhf.scorers import RewardModelScorer
+        from relayrl_tpu.envs.scorers import RewardModelScorer
 
         a = RewardModelScorer(vocab_size=6, context_len=8, seed=11)
         b = RewardModelScorer(vocab_size=6, context_len=8, seed=11)
@@ -90,7 +90,7 @@ class TestScorers:
             before, jax.tree_util.tree_leaves(a.params)[0])
 
     def test_make_scorer_unknown_name(self):
-        from relayrl_tpu.rlhf.scorers import make_scorer
+        from relayrl_tpu.envs.scorers import make_scorer
 
         with pytest.raises(ValueError, match="programmatic"):
             make_scorer("nope")
@@ -103,7 +103,7 @@ class TestScorers:
         import jax.numpy as jnp
 
         from relayrl_tpu.envs import TokenGenEnv, make_jax
-        from relayrl_tpu.rlhf.scorers import RewardModelScorer
+        from relayrl_tpu.envs.scorers import RewardModelScorer
 
         rm = RewardModelScorer(vocab_size=5, context_len=6, seed=2)
         kwargs = dict(vocab_size=5, prompt_len=2, max_new_tokens=4,
@@ -244,7 +244,7 @@ class TestScoreStage:
         """A partial batch pads with repeated rows (inert) — scores for
         the real rows must equal the single-path scores."""
         from relayrl_tpu.rlhf.scheduler import ScoreStage
-        from relayrl_tpu.rlhf.scorers import ProgrammaticScorer
+        from relayrl_tpu.envs.scorers import ProgrammaticScorer
         from relayrl_tpu.types.trajectory import deserialize_actions
 
         sc = ProgrammaticScorer(vocab_size=6)
@@ -326,7 +326,7 @@ class TestScoreStage:
 
 class TestFreezeMask:
     def test_normalize_spec_validates(self):
-        from relayrl_tpu.algorithms.freeze import normalize_freeze_spec
+        from relayrl_tpu.config.loader import normalize_freeze_spec
 
         assert normalize_freeze_spec(None) == ()
         assert normalize_freeze_spec("") == ()

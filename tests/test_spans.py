@@ -244,7 +244,7 @@ class TestTracerSink:
         assert rec["hop"] == "encode" and rec["agent"] == "a"
 
     def test_fence_total_and_ring_span_are_one_interval(self):
-        from relayrl_tpu.runtime.pipeline import InflightWindow
+        from relayrl_tpu.algorithms.dispatch import InflightWindow
 
         win = InflightWindow(max_in_flight=0)
         win.push(jnp.float32(1.0), version=4)

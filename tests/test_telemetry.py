@@ -521,7 +521,7 @@ class TestLearnerParity:
         (disabled) registry runs its hot path with null metrics."""
         import jax.numpy as jnp
 
-        from relayrl_tpu.runtime.pipeline import InflightWindow
+        from relayrl_tpu.algorithms.dispatch import InflightWindow
 
         win = InflightWindow(max_in_flight=1)
         win.push(jnp.float32(1.0))

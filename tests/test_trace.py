@@ -22,12 +22,8 @@ from relayrl_tpu.telemetry.core import (
     log_buckets,
 )
 from relayrl_tpu.telemetry.events import EventJournal, read_events
-from relayrl_tpu.transport.base import (
-    split_agent_seq,
-    split_agent_trace,
-    tag_agent_seq,
-    tag_agent_trace,
-)
+from relayrl_tpu.telemetry.trace import split_agent_trace, tag_agent_trace
+from relayrl_tpu.transport.base import split_agent_seq, tag_agent_seq
 
 pytestmark = pytest.mark.tracing
 

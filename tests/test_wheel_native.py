@@ -9,8 +9,6 @@ source-tree one so an installed user never silently downgrades.
 
 import os
 
-from relayrl_tpu.transport import native_backend
-
 
 class TestLoaderPreference:
     def test_bundled_library_wins(self, monkeypatch, tmp_path):
@@ -20,13 +18,13 @@ class TestLoaderPreference:
 
         monkeypatch.setattr(native_pkg, "bundled_library_path",
                             lambda: str(fake))
-        assert native_backend._find_library() == str(fake)
+        assert native_pkg.find_library() == str(fake)
 
     def test_source_tree_fallback(self, monkeypatch):
         import relayrl_tpu._native as native_pkg
 
         monkeypatch.setattr(native_pkg, "bundled_library_path", lambda: None)
-        found = native_backend._find_library()
+        found = native_pkg.find_library()
         # In this checkout the make-built lib exists; wherever it is, it
         # must NOT claim to be the bundled one.
         if found is not None:
