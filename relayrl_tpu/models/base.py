@@ -181,6 +181,13 @@ class Policy:
     # sorts all N*k. Empty for a trunk without experts (and for the dense
     # dispatch), None for other families.
     moe_backends: Mapping[tuple, str] | None = None
+    # Sequence policies under ``block_checkpoint``: ``{(operator, "experts" |
+    # "dense" | "none"): {name: bytes}}`` for every distinct kind of layer a
+    # gradient was traced through so far (models/layers/block.py) — what the
+    # checkpoint round that layer keeps by name beside the layer's input,
+    # and the bytes each value holds at the traced shape (one application).
+    # Empty without the key (and before a gradient), None for other families.
+    checkpoint_kept: Mapping[tuple, Mapping[str, int]] | None = None
     # MoE and sparse-attention trunks: ``evaluate_stats(params, obs, act,
     # mask) -> (logp, entropy, v, stats)`` — ``evaluate`` plus what the same
     # forward counted (``moe_load_max`` / ``moe_load_min``:

@@ -42,6 +42,7 @@ from relayrl_tpu.models.layers import (
     kda,
     mamba2,
     mla,
+    recurrent,
     short_conv,
     sparse_attention,
 )

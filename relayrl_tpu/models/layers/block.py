@@ -148,8 +148,48 @@ def _moe_record(arch):
     return {"moe": say}, {"moe_backends": resolved}
 
 
-# every layer kind ends in :func:`block_ffn`, which asks ``fns["moe"]``
-KERNELS = (_moe_record,)
+def _checkpoint_record(arch):
+    """The trunk's checkpoint round a whole layer (``block_checkpoint``):
+    ``fns["checkpoint"](kind, names) -> (policy, said)`` for a kind of layer,
+    ``(operator, "experts" | "dense" | "none")`` — a ``jax.checkpoint``
+    policy, ``save_only_these_names(*names)`` that notes each value it keeps
+    (the trunk asks for ONE a kind and pass: a policy an application would
+    lower each layer's inner functions apart), and ``said()``, which the
+    trunk calls once its layers have been traced: ``Policy.checkpoint_kept[kind] = {name: bytes}`` (a
+    name marks one value a layer; the bytes of one application) and one
+    ``[checkpoint]`` line a distinct kind, the names and the MB they hold at
+    the traced shape. The policy is asked when a gradient is taken through
+    the layer: a forward alone keeps nothing and says nothing."""
+    resolved: dict[tuple, dict] = {}
+
+    def checkpoint(kind, names):
+        listed = jax.checkpoint_policies.save_only_these_names(*names)
+        kept: dict[str, int] = {}
+
+        def policy(prim, *avals, **params):
+            keep = listed(prim, *avals, **params)
+            if keep:
+                kept[params["name"]] = avals[0].size * avals[0].dtype.itemsize
+            return keep
+
+        def said():
+            if not kept or resolved.get(kind) == kept:
+                return      # no gradient through it; or said before
+            resolved[kind] = dict(kept)
+            parts = ", ".join(f"{name} {size / 1e6:.1f}"
+                              for name, size in kept.items())
+            print(f"[checkpoint] {kind[0]}+{kind[1]} keeps its input and "
+                  f"{parts} = {sum(kept.values()) / 1e6:.1f} MB by name "
+                  f"(platform {jax.default_backend()})", flush=True)
+
+        return policy, said
+
+    return {"checkpoint": checkpoint}, {"checkpoint_kept": resolved}
+
+
+# every layer kind ends in :func:`block_ffn`, which asks ``fns["moe"]``; the
+# trunk asks ``fns["checkpoint"]`` round a layer of any kind
+KERNELS = (_moe_record, _checkpoint_record)
 # the FFN is per row: a final layer runs for the readout row alone
 ROW_READOUT = True
 

@@ -121,8 +121,10 @@ def _mixer(block, shape):
     def mix(h, weights, conv_rows, state, n_valid):
         w_qkvz, w_ba, conv_w, dt_bias, a_log, scale, w_out = weights
         with jax.named_scope(OP_PROJ):
-            qkv, z = jnp.split(jnp.dot(h, w_qkvz.astype(cd)),
-                               [2 * kw + vw], axis=-1)
+            qkv, z = jnp.split(
+                checkpoint_name(jnp.dot(h, w_qkvz.astype(cd)),
+                                recurrent.MIXER_IN),
+                [2 * kw + vw], axis=-1)
             b_in, a_in = jnp.split(
                 jnp.dot(h, w_ba.astype(cd), preferred_element_type=f32),
                 2, axis=-1)

@@ -90,7 +90,8 @@ def _mixer(block, shape):
         (w_qkv, w_beta, f_down, f_up, g_down, g_up, g_bias, conv_w, dt_bias,
          a_log, scale, w_out) = weights
         with jax.named_scope(OP_PROJ):
-            qkv = jnp.dot(h, w_qkv.astype(cd))
+            qkv = checkpoint_name(jnp.dot(h, w_qkv.astype(cd)),
+                                  recurrent.MIXER_IN)
             beta = jax.nn.sigmoid(
                 jnp.dot(h, w_beta.astype(cd), preferred_element_type=f32))
             # a decay a key lane: A_log a head, dt_bias a lane

@@ -89,7 +89,8 @@ def _mixer(block, shape):
         w_in, conv_w, conv_b, dt_bias, a_log, skip, scale, w_out = weights
         with jax.named_scope(OP_PROJ):
             z, xbc, dt = jnp.split(
-                jnp.dot(h, w_in.astype(cd)),
+                checkpoint_name(jnp.dot(h, w_in.astype(cd)),
+                                recurrent.MIXER_IN),
                 [inner, 2 * inner + 2 * bc], axis=-1)
             dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
             if n_valid is not None:

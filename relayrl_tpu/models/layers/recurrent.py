@@ -23,6 +23,7 @@ from __future__ import annotations
 from typing import Callable, Sequence
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 
 from relayrl_tpu.models.layers.block import (
     block_ffn,
@@ -57,6 +58,18 @@ def kernel(tag: str, fn: Callable, describe: Callable) -> Callable:
     return resolve
 
 
+# What a checkpoint round the WHOLE layer (the trunk's ``block_checkpoint``)
+# keeps of a mixer beside the mixer's own ``kept``, dearest first by device
+# time a byte: the mixer's output (named in :func:`mixer_apply`) — with it
+# that checkpoint's backward runs no part of the mixer to feed the FFN behind
+# it — and the input projection's product before its split (named in each
+# mixer's ``mix``), the widest matmul of the layer. A mixer's own checkpoint lists neither: to
+# a trunk without ``block_checkpoint`` both are marks that lower to nothing.
+MIXER_OUT = "relayrl_mixer_out"
+MIXER_IN = "relayrl_mixer_in"
+BLOCK_KEPT = (MIXER_OUT, MIXER_IN)
+
+
 def mixer_apply(build: Callable, kept: Sequence[str]) -> Callable:
     """A mixer's ``apply``: ``x + mix(norm(x))`` behind the layer's one
     norm, then the FFN if the layer has one. ``build(block, x.shape) ->
@@ -74,7 +87,19 @@ def mixer_apply(build: Callable, kept: Sequence[str]) -> Callable:
     backward from the normed rows; of them only the ``kept`` names are
     saved — the recurrence's output and, where its backward needs more than
     that, what its forward wrote for it —, so that the backward runs the
-    recurrence's backward alone and never its forward a second time."""
+    recurrence's backward alone and never its forward a second time.
+
+    Under a checkpoint round the whole layer (``block_checkpoint``) with an
+    FFN behind the mixer, the outer backward first runs the mixer again for
+    the FFN's input — from the kept recurrence's output on: the gate, the
+    norm and the output projection, and the input projection too where the
+    gate is a slice of it (the gated delta rule's ``z``) —, then the mixer's
+    own checkpoint runs its inside again. The outer policy is applied inside
+    the nested checkpoint too, and what it saves enters the inner one as an
+    input: with :data:`BLOCK_KEPT` in it (and in it alone) the output named
+    here spares the outer backward the mixer altogether, and the input
+    projection's named product is made once where it was made twice (three
+    times for that ``z``)."""
 
     def apply(block, x, cache, t, readout_idx, n_valid):
         weights, mix, back = build(block, x.shape)
@@ -84,6 +109,10 @@ def mixer_apply(build: Callable, kept: Sequence[str]) -> Callable:
             y, _, _ = jax.checkpoint(
                 mix, policy=jax.checkpoint_policies.save_only_these_names(
                     *kept))(h, weights, None, None, None)
+            if block.has_ffn or block.norm_sandwich:
+                # where something behind the mixer reads it (a residual
+                # add alone does not)
+                y = checkpoint_name(y, MIXER_OUT)
         else:
             y, padded, state = mix(h, weights, *cache, n_valid)
         with jax.named_scope(OP_PROJ):

@@ -248,6 +248,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from relayrl_tpu.models.mlp import GATED_FFN, UNGATED_FFN
 from relayrl_tpu.ops.scopes import (
@@ -259,6 +260,21 @@ from relayrl_tpu.ops.scopes import (
     MOE_ROWS,
 )
 
+# What a checkpoint round the whole layer (the trunk's ``block_checkpoint``)
+# keeps of an expert FFN by name, dearest first by device time a byte: the
+# router's choice — its float32 logits and the experts a sigmoid router
+# chose, so that no backward runs the score matmul or ``lax.top_k`` (a full
+# sort of a token's scores on a TPU) a second time —, the shared expert's first products and
+# the rows of the latent the routed experts read. Marks that lower to
+# nothing under any other policy: nothing in this file lists them.
+ROUTER_LOGITS = "relayrl_moe_logits"
+ROUTER_CHOICE = "relayrl_moe_choice"
+SHARED_UP = "relayrl_moe_shared_up"
+SHARED_GATE = "relayrl_moe_shared_gate"
+LATENT_ROWS = "relayrl_moe_latent_rows"
+BLOCK_KEPT = (ROUTER_LOGITS, ROUTER_CHOICE, SHARED_UP, SHARED_GATE,
+              LATENT_ROWS)
+
 # Spread of the seeded ``moe_expert_bias`` (sigmoid router): against scores
 # whose 4th and 5th lie ~0.02 apart it moves the choice of about every
 # second token, and an expert's load by about a quarter.
@@ -268,11 +284,15 @@ _EXPERT_BIAS_STD = 0.02
 def route(logits, k: int, norm_topk_prob: bool, router: str = "softmax",
           expert_bias=None):
     """Router logits ``[N, E]`` (float32) -> (weights ``[N, k]``, expert
-    indices ``[N, k]``); a token's row depends on that token alone."""
+    indices ``[N, k]``); a token's row depends on that token alone. The
+    sigmoid router's choice carries a name (:data:`BLOCK_KEPT`): a checkpoint
+    that lists it sorts once (a softmax router's weights are made from
+    ``top_k``'s values too, and no trunk under ``block_checkpoint`` has one:
+    nothing is named there)."""
     if router == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         biased = scores if expert_bias is None else scores + expert_bias
-        top_idx = jax.lax.top_k(biased, k)[1]
+        top_idx = checkpoint_name(jax.lax.top_k(biased, k)[1], ROUTER_CHOICE)
         top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
         if norm_topk_prob:
             top_w = top_w / (top_w.sum(axis=-1, keepdims=True) + 1e-6)
@@ -869,8 +889,10 @@ def _shared_ffn(layer: "MoEMLP", xs, gated: bool, tokens):
                         name=name)
 
     with jax.named_scope(FFN):
-        up = dense(layer.shared_d_ff, "moe_shared_up")(xs)
-        gate = (dense(layer.shared_d_ff, "moe_shared_gate")(xs)
+        up = checkpoint_name(dense(layer.shared_d_ff, "moe_shared_up")(xs),
+                             SHARED_UP)
+        gate = (checkpoint_name(
+            dense(layer.shared_d_ff, "moe_shared_gate")(xs), SHARED_GATE)
                 if gated else None)
         h = _activation(layer.ffn, up.astype(jnp.float32),
                         None if gate is None else gate.astype(jnp.float32))
@@ -936,9 +958,10 @@ class MoEMLP(nn.Module):
         # element-wise passes round the grouped matmuls, which keep their own
         with jax.named_scope(MOE_ROUTE):
             routed = tokens if route_x is None else route_x.reshape(n, d)
-            logits = nn.Dense(n_exp, dtype=jnp.float32,
-                              use_bias=self.use_bias,
-                              name="moe_gate")(routed.astype(jnp.float32))
+            logits = checkpoint_name(
+                nn.Dense(n_exp, dtype=jnp.float32, use_bias=self.use_bias,
+                         name="moe_gate")(routed.astype(jnp.float32)),
+                ROUTER_LOGITS)
             bias = None
             if self.expert_bias:
                 # enters the choice only: zero gradient, never moved; seeded
@@ -955,8 +978,10 @@ class MoEMLP(nn.Module):
         rows_in = tokens
         if self.latent:
             with jax.named_scope(MOE_LATENT):
-                rows_in = nn.Dense(width, dtype=cd, use_bias=False,
-                                   name="moe_latent_down")(tokens.astype(cd))
+                rows_in = checkpoint_name(
+                    nn.Dense(width, dtype=cd, use_bias=False,
+                             name="moe_latent_down")(tokens.astype(cd)),
+                    LATENT_ROWS)
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         gated = self.ffn in GATED_FFN

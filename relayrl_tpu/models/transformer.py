@@ -240,9 +240,14 @@ class TransformerCore(nn.Module):
         the next pass's input, and the heads read the last pass's without a
         second norm), positions the same at every pass; the full mode runs
         the passes as ONE body of a scan. ``block_checkpoint``: the full
-        mode keeps a block application's input (and the flash forward's
-        output, by name) for the backward and makes the rest again there
-        (``nn.remat``)."""
+        mode keeps a block application's input for the backward and makes
+        the rest again there (``nn.remat``) — but for what is dearest to
+        make again, which it keeps by name: the flash forward's output, a
+        mixer's recurrence, output and input projection, the router's
+        choice, the shared expert's first products and the latent's rows
+        (the list and its order: where ``kept`` is built below;
+        ``Policy.checkpoint_kept`` and the ``[checkpoint]`` lines say what
+        each kind of layer held, in bytes)."""
         decode = cache is not None
         kw = self.block_kw
         S, L = self.loop_steps, self.n_layers
@@ -263,27 +268,45 @@ class TransformerCore(nn.Module):
         # the learner's forward: every pass alike, and the one mode that is
         # differentiated
         full = not decode and idx is None
-        block_cls = TransformerBlock
+        kept = ()
         if self.block_checkpoint and full:
-            # beside a block application's input, the flash forward's
-            # output and log-sum-exp and what a mixer's own checkpoint keeps
-            # of its recurrence (``KEPT``): the backward then runs no
-            # forward kernel and no recurrence a second time (PERF.md
-            # section 6, PR 50, PR 55)
+            # beside a block application's input, by name: the flash
+            # forward's output and log-sum-exp and what a mixer's own
+            # checkpoint keeps of its recurrence (``KEPT``) — the backward
+            # then runs no forward kernel and no recurrence a second time
+            # (PERF.md section 6, PR 50, PR 55) —, and what is dearest to
+            # make again of the rest, in the order of device time a byte
+            # (PR 61): a mixer's output and its input projection's product
+            # (``recurrent.BLOCK_KEPT``), the router's choice, the shared
+            # expert's first products and the latent's rows
+            # (``moe.BLOCK_KEPT``). One static list: a name no value of a
+            # layer carries keeps nothing there, and the dense FFN's
+            # products have none (32 applications of it do not fit).
+            from relayrl_tpu.models import moe
             from relayrl_tpu.ops import flash
 
-            kept = tuple(name for op in layers.OPERATORS.values()
-                         for name in getattr(op, "KEPT", ()))
-            block_cls = nn.remat(
-                TransformerBlock,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    flash.OUT_NAME, flash.LSE_NAME, *kept))
+            kept = (flash.OUT_NAME, flash.LSE_NAME,
+                    *(name for op in layers.OPERATORS.values()
+                      for name in getattr(op, "KEPT", ())),
+                    *layers.recurrent.BLOCK_KEPT, *moe.BLOCK_KEPT)
+        checked = {}    # kind of layer -> its checkpointed block class
 
         def block_at(i: int) -> TransformerBlock:
             op, has_ffn = self.layer_parts(i)
             cfg = self.op_cfg[op]
             if self.rope_layers and not self.rope_layers[i]:
                 cfg = cfg.copy({"rope_theta": None})  # no positions at all
+            block_cls = TransformerBlock
+            if kept:
+                # a policy a kind of layer, behind the record of what it
+                # keeps (``Policy.checkpoint_kept``, ``[checkpoint]`` lines)
+                kind = (op, "experts" if self.layer_experts(i)
+                        else "dense" if has_ffn else "none")
+                if kind not in checked:
+                    policy, said = self.fns["checkpoint"](kind, kept)
+                    checked[kind] = nn.remat(TransformerBlock,
+                                             policy=policy), said
+                block_cls = checked[kind][0]
             return block_cls(
                 self.d_model, self.mlp_ratio, self.compute_dtype, op=op,
                 cfg=cfg, fns=self.fns, has_ffn=has_ffn,
@@ -349,6 +372,8 @@ class TransformerCore(nn.Module):
                     if S > 1:
                         with jax.named_scope(HEADS):
                             x = end_of_pass(x)
+        for _, said in checked.values():
+            said()
         if idx is not None and mask is not None:
             mask = jax.lax.dynamic_slice_in_dim(mask, idx, 1, axis=1)
         logits, v = _readout_heads(

@@ -134,3 +134,13 @@ def kernel_calls(jaxpr) -> list:
         for inner in jax.core.jaxprs_in_params(eqn.params):
             found += kernel_calls(inner)
     return found
+
+
+def without_symbol_counters(text: str) -> str:
+    """A lowered module's text less the counters the lowering gives its
+    private functions (``@_where_38`` -> ``@_where``): they move with what a
+    process lowered before and with a ``checkpoint_name`` no policy lists,
+    not with what the program is."""
+    import re
+
+    return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
