@@ -9,7 +9,8 @@ has (the widest transformer through ``build_algorithm``, the flash kernels
 alone at the benchmark's shapes against XLA attention, the fused anakin
 rollout, a served batch, the expert layer's grouped-matmul kernels against
 XLA's own, the Mamba-2 scan's, the gated delta rule's and the mixers'
-convolution's kernels against their plain forms). It checks what comes out, fails on the first thing
+convolution's kernels against their plain forms, the rotated latent layer
+against the benchmark's plain reference of it). It checks what comes out, fails on the first thing
 that is wrong (non-zero exit, one ``chip_smoke: FAIL`` line saying why; a
 phase's own exception is never caught), and ends with ONE JSON line:
 
@@ -1139,6 +1140,84 @@ def phase_j() -> None:
         f"{time.monotonic() - t0:.0f}s")
 
 
+def phase_k() -> None:
+    """The rotated latent layer at ``joyai-flash-policy``'s widths — 32 heads,
+    a query rank of 1536, a latent row of 512 + 64, q / k 192 lanes and v
+    128, theta 32e6 on interleaved pairs, a dense SwiGLU FFN of 7168 behind
+    it, bfloat16 — as a one-layer trunk through ``build_policy``, held to
+    the benchmark's plain reference of the same layer
+    (``benchmark/reference/joyai-flash-policy.py``, float32 "highest", the
+    pairs turned in place) on the same seeded tree, T 2,048. Full: every
+    row's value and its action's log-probability through the ``_mla`` flash kernels
+    (``Policy.attention_backends`` has to say ``flash_pallas``). Cached: a
+    prefill of the first 2,040 rows, then eight steps through the ``(c,
+    k_pe)`` cache, ``k_pe`` stored already rotated — each step's value
+    against the reference's row. Limits 0.05: one layer of bfloat16
+    rounding, no routing to flip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from relayrl_tpu.models import build_policy
+
+    t0 = time.monotonic()
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark import harness
+
+    reference = harness.load_reference("joyai-flash-policy")
+    cfg = {**harness.load_cell("joyai-flash-policy.update")["config"],
+           "num_hidden_layers": 1, "positions_as_run": 2048}
+    T, pre = cfg["positions_as_run"], 2040
+    kwargs = {k: v for k, v in reference.program_kwargs(cfg).items()
+              if not k.startswith("moe_") and k != "block_checkpoint"}
+    kwargs["kind"] = "transformer_discrete"
+    del kwargs["model_kind"]
+    policy = build_policy({"obs_dim": cfg["obs_dim"],
+                           "act_dim": cfg["act_dim"], "has_critic": True,
+                           "precision": "bfloat16", **kwargs})
+    params = jax.jit(policy.init_params)(jax.random.PRNGKey(62))
+    rng = np.random.default_rng(62)
+    obs = jnp.asarray(rng.standard_normal((1, T, cfg["obs_dim"])),
+                      jnp.float32)
+    act = jnp.asarray(rng.integers(0, cfg["act_dim"], (1, T)), jnp.int32)
+    logp_ref, v_ref = reference.forward(params, obs, cfg)
+    logp_ref = jnp.take_along_axis(logp_ref, act[..., None], -1)[..., 0]
+    logp, _ent, v = jax.jit(policy.evaluate)(params, obs, act)
+    ran = dict(policy.attention_backends or {}).get((T, 192, "bfloat16"))
+    check(ran == "flash_pallas",
+          f"K: the latent layer's full mode ran {ran!r} at T {T}, q / k 192")
+    d_logp = float(jnp.abs(logp - logp_ref).max())
+    d_v = float(jnp.abs(v - v_ref).max())
+    check(d_logp <= 0.05 and d_v <= 0.05,
+          f"K: full mode differs from the reference's layer by {d_logp:.3g} "
+          f"(log-probabilities) / {d_v:.3g} (values), limit 0.05")
+    # the other pairing is another function of the same tree: told apart
+    halves = reference.forward(params, obs, cfg, wrong={"half_split": True})
+    apart = float(jnp.abs(v - halves[1]).max())
+    check(apart > 2 * d_v,
+          f"K: the half-split reference is as near ({apart:.3g}) as the "
+          f"interleaved one ({d_v:.3g})")
+    padded = np.asarray(obs[0]).copy()
+    padded[pre:] = 0.0
+    cache = policy.prefill_cache(params, policy.init_cache(T),
+                                 jnp.asarray(padded), pre)
+    step = jax.jit(policy.step_cached)
+    d_step = 0.0
+    for t in range(pre, T):
+        _, aux, cache = step(params, jax.random.PRNGKey(t), cache,
+                             obs[0, t], t)
+        d_step = max(d_step, abs(float(aux["v"]) - float(v_ref[0, t])))
+    check(d_step <= 0.05,
+          f"K: cached steps {pre}..{T - 1} differ from the reference's rows "
+          f"by {d_step:.3g} (values), limit 0.05")
+    say(f"K: ok — the rotated latent layer (32 heads, q / k 192, v 128, "
+        f"theta 32e6 interleaved) against the plain reference at T {T} "
+        f"bfloat16: full {d_logp:.4f} / {d_v:.4f} through {ran}, "
+        f"half-split apart by {apart:.4f}, cached steps {d_step:.4f}, "
+        f"{time.monotonic() - t0:.0f}s")
+
+
 # --------------------------------------------------------------------------
 
 def main() -> None:
@@ -1208,6 +1287,7 @@ def run(dev: dict, t_start: float) -> None:
     phase_h()
     phase_i()
     phase_j()
+    phase_k()
 
     say(f"compiles: {compiles.requests} requests, {compiles.hits} served by "
         f"the persistent cache, {compiles.requests - compiles.hits} compiled "

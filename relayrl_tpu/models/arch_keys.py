@@ -89,6 +89,8 @@ OPERATOR_KEYS: Mapping[str, Mapping[str, Any]] = {
     "latent_attention": {
         "kv_lora_rank": 64, "qk_nope_head_dim": 32, "qk_rope_head_dim": 16,
         "v_head_dim": 32,
+        "q_lora_rank": None,        # the query's own low rank; None: q_proj
+        "rope_interleave": False,   # RoPE pairs lanes (2i, 2i + 1)
     },
     # "attention" (OPERATOR_BASE) with a learned indexer in front of it
     "sparse_attention": {

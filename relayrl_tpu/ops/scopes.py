@@ -37,6 +37,7 @@ VTRACE = "relayrl_vtrace"            # ratios, delta, the reverse recursion, pg_
 LOSS = "relayrl_loss"                # the loss sums, a trunk's own, RhoMean, KL
 EMBED = "relayrl_embed"              # obs embedding + learned positions
 OP_PROJ = "relayrl_op_proj"          # a layer's operator less its kernel
+LATENT_ROPE = "relayrl_latent_rope"  # latent attention's two rotations
 INDEX = "relayrl_index"              # an indexer: projections, scores, selection
 SPARSE_ATTN = "relayrl_sparse_attn"  # attention over the selected keys, and p^
 FFN = "relayrl_ffn"                  # a layer's dense FFN, norm and residual
@@ -48,9 +49,9 @@ HEADS = "relayrl_heads"              # final norm, pi / vf heads, logp, entropy
 OBS_PREP = "relayrl_obs_prep"        # cnn: cast, scale, relayout on entry
 CONV = "relayrl_conv"                # cnn: the conv stack and its dense layer
 
-DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, INDEX, SPARSE_ATTN,
-                 FFN, MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE, MOE_LATENT, HEADS,
-                 OBS_PREP, CONV)
+DEVICE_SCOPES = (OPTIMIZER, VTRACE, LOSS, EMBED, OP_PROJ, LATENT_ROPE, INDEX,
+                 SPARSE_ATTN, FFN, MOE_ROUTE, MOE_ROWS, MOE_ELEMENTWISE,
+                 MOE_LATENT, HEADS, OBS_PREP, CONV)
 
 # -- a looped trunk's pass, outside the parts ---------------------------------
 LOOP_PASS = "relayrl_loop_pass"      # models/transformer.py's loop

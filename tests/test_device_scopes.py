@@ -39,6 +39,7 @@ from relayrl_tpu.ops.scopes import (
     INDEX,
     KDA_CONV_NAME,
     KDA_NAME,
+    LATENT_ROPE,
     LOSS,
     MAMBA_CONV_NAME,
     MOE_ELEMENTWISE,
@@ -146,6 +147,23 @@ FAMILIES = {
                 "moe_routed_scaling": 2.446, "norm": "rms",
                 "positions": "none", "use_bias": False, "ffn": "swiglu"},
                TRUNK + MOE + (FFN, KDA_NAME, KDA_CONV_NAME)),
+    # latent attention on every layer, a low-rank query path, the shared key
+    # lanes and the queries' matching lanes rotated in interleaved pairs,
+    # under the block checkpoint (joyai-flash-policy)
+    "latent_rope": ({**SEQ, "kind": "transformer_moe_discrete",
+                     "n_layers": 2, "n_heads": 4,
+                     "layer_types": ["latent_attention"] * 2,
+                     "block_checkpoint": True, "q_lora_rank": 12,
+                     "kv_lora_rank": 8, "qk_nope_head_dim": 4,
+                     "qk_rope_head_dim": 4, "v_head_dim": 4,
+                     "rope_interleave": True, "moe_dense_layers": 1,
+                     "moe_experts": 16, "moe_top_k": 8, "moe_held": [2, 4],
+                     "moe_d_ff": 12, "moe_shared_d_ff": 12,
+                     "moe_router": "sigmoid", "moe_expert_bias": True,
+                     "moe_routed_scaling": 2.5, "norm": "rms",
+                     "positions": "rope", "rope_theta": 100.0,
+                     "use_bias": False, "ffn": "swiglu"},
+                    TRUNK + MOE + (FFN, LATENT_ROPE)),
     # attention over the keys an indexer picks, 2 of up to 8, in two tiles;
     # the indexers' own loss under the loss's name; top-8 experts, the held
     # layer counting its rows (keye-vl2-policy)
@@ -255,7 +273,7 @@ USES = [(family, scope) for family, (_arch, used) in FAMILIES.items()
 def test_one_list_of_names():
     """Every name a ``with`` line can open is a constant of the one module,
     and the lists hold each once."""
-    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 15
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES) == 16
     assert not set(DEVICE_SCOPES) & set(scopes.KERNEL_SCOPES)
     used = {scope for _family, scope in USES}
     assert used == set(DEVICE_SCOPES) | set(OWN_NAMES)

@@ -339,12 +339,40 @@ class TestSystemAgainstReference:
         assert _differs(got, reference.forward(
             _system(reference, cfg, "float32")[1], _obs(cfg), cfg)) > 1e-3
 
-    def test_a_trunk_that_rotates_is_refused(self, reference, cfg):
-        """No lane of the latent layer is rotated (``mla_use_nope``); a
-        trunk of ``positions: "rope"`` is refused, not run unrotated."""
-        with pytest.raises(ValueError, match="rotates no lane"):
-            _system(reference, cfg, "float32", positions="rope",
-                    rope_theta=cfg["rope_theta"])
+    def test_a_trunk_that_rotates_turns_the_shared_lanes(self, reference,
+                                                         cfg):
+        """The model rotates no lane (``mla_use_nope``); the same tree under
+        ``positions: "rope"`` runs the latent layer's rotation: the shared
+        ``k_pe`` lanes and q's matching lanes in half-split pairs, which is
+        the wrong reference ``rope`` — and no longer the model."""
+        policy, params = _system(reference, cfg, "float32", positions="rope",
+                                 rope_theta=cfg["rope_theta"])
+        obs = _obs(cfg)
+        got = _outputs(policy, params, obs, cfg["act_dim"])
+        assert _differs(got, reference.forward(
+            params, obs, cfg, wrong={"rope": True})) < 1e-4
+        assert _differs(got, reference.forward(params, obs, cfg)) > 1e-3
+
+    def test_the_latent_layers_later_keys_default_to_this_program(
+            self, reference, cfg):
+        """``q_lora_rank`` and ``rope_interleave`` (PR 62) at their defaults
+        are the program this trunk ran before them: the keys spelled out
+        lower to the text of the keys left out — one ``q_proj``, no cosine,
+        no part ``relayrl_latent_rope`` — and with nothing rotated the
+        pairing has nothing to pair. (The update's lowered text, tiny and
+        published sizes, held to the parent's by hand: CHANGES.md, PR 62.)"""
+        texts = []
+        for over in ({}, {"q_lora_rank": None, "rope_interleave": False},
+                     {"rope_interleave": True}):
+            policy, params = _system(reference, cfg, "float32", **over)
+            assert "q_proj" in params["params"]["block_3"]
+            texts.append(jax.jit(policy.evaluate).lower(
+                params, _obs(cfg), jnp.zeros((2, T), jnp.int32)).as_text(
+                    debug_info=True))
+        assert texts[0] == texts[1] == texts[2]
+        assert "cosine" not in texts[0]
+        assert "relayrl_latent_rope" not in texts[0]
+        assert "relayrl_op_proj" in texts[0]
 
     # ``benchmark/tests/controls_kimi_linear.py`` is how the controls are
     # read on the chip: each wrong reference planted in the program's place
