@@ -154,6 +154,14 @@ class Policy:
     # plain XLA. Empty for a trunk without such layers, None for other
     # families.
     kda_backends: Mapping[tuple, str] | None = None
+    # Sequence policies with latent-attention layers that rotate (``positions:
+    # "rope"``): ``{("latent_attention", "experts" | "dense"): "columns"}``
+    # for every kind of such layer traced so far (models/layers/mla.py) —
+    # the nope / rope split and the rotation's pairing are taken on the
+    # projections' columns at use, and the rotation walks the rotary lanes
+    # alone. No entry for a latent layer that rotates nothing; None for
+    # other families.
+    latent_rope: Mapping[tuple, str] | None = None
     # Sequence policies with Mamba-2 or linear-attention layers: ``{(T,
     # columns, taps, continues from a cache's rows, dtype): "conv_pallas" |
     # "conv_xla"}`` for every shape of the mixers' depthwise convolution

@@ -407,6 +407,117 @@ def test_custom_vjp_backwards_carry_their_parts(paths, compiled):
                      rf'jvp\({scopes.HELD_EXPERTS_NAME}\)/', compiled("moe_held"))
 
 
+def _under(jaxpr, scope: str):
+    """Every equation of ``jaxpr``, and of the jaxprs its equations hold,
+    traced under ``scope``."""
+    for eqn in jaxpr.eqns:
+        inner = [v for v in eqn.params.values()
+                 if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+        for sub in inner:
+            yield from _under(getattr(sub, "jaxpr", sub), scope)
+        if not inner and scope in str(eqn.source_info.name_stack):
+            yield eqn
+
+
+def _walked(eqns):
+    """``(the largest value an equation reads or writes, in elements; the
+    lane gathers: ``gather`` and strided ``slice`` equations)``."""
+    largest, gathers = 0, []
+    for eqn in eqns:
+        sizes = [v.aval.size for v in (*eqn.invars, *eqn.outvars)
+                 if hasattr(v.aval, "size")]
+        largest = max([largest, *sizes])
+        strided = eqn.primitive.name == "slice" and any(
+            s != 1 for s in eqn.params["strides"] or ())
+        if eqn.primitive.name == "gather" or strided:
+            gathers.append((eqn.primitive.name, max(sizes)))
+    return largest, gathers
+
+
+def test_the_rotation_walks_the_rotary_lanes_alone():
+    """In the update of the tiny rotary latent trunk — forward, the block
+    checkpoint's second forward and the backward — nothing under
+    ``relayrl_latent_rope`` reads or writes a value wider than half of q's
+    rotary lanes (2 x 8 rows of 4 heads of 4 lanes, where q whole is 4 + 4 a
+    head), and no ``gather`` or strided ``slice`` is there: the pairing was
+    taken on the weights' columns. The walk is held to the rotation as it was
+    written before (``test_joyai_flash_reference._row_form``), which fails
+    both."""
+    from test_joyai_flash_reference import _row_form
+
+    arch, _ = FAMILIES["latent_rope"]
+    heads, pe, nope = (arch["n_heads"], arch["qk_rope_head_dim"],
+                       arch["qk_nope_head_dim"])
+    lanes = 2 * 8 * heads * pe
+    policy = build_policy({**arch, "has_critic": True})
+    params = jax.eval_shape(policy.init_params, jax.random.PRNGKey(0))
+    obs = jnp.zeros((2, 8, arch["obs_dim"]), jnp.float32)
+    act = jnp.zeros((2, 8), jnp.int32)
+
+    def loss(p):
+        logp, _ent, v = policy.evaluate(p, obs, act)
+        return jnp.sum(logp) + jnp.sum(v)
+
+    with mock.patch.object(moe, "_ROW_TILE", 8):
+        traced = jax.make_jaxpr(jax.grad(loss))(params)
+    mine = list(_under(traced.jaxpr, LATENT_ROPE))
+    names = {str(eqn.source_info.name_stack) for eqn in mine}
+    # the forward, the checkpoint's second forward, and (a jaxpr names a
+    # transposed equation by its scopes alone) the backward
+    for making in ("jvp(TransformerCore)/", "rematted_computation/", ""):
+        assert f"{making}block_1/{LATENT_ROPE}" in names, making
+    largest, gathers = _walked(mine)
+    assert gathers == []
+    # the first (or the second) of q's rotated pairs, never one array with
+    # the other; k's are a head's
+    assert largest == lanes // 2
+    assert dict(policy.latent_rope) == {
+        ("latent_attention", "dense"): "columns",
+        ("latent_attention", "experts"): "columns"}
+
+    # the same walk over every row's lanes, as it was
+    cfg = {"rope_theta": 100.0, "qk_rope_head_dim": pe,
+           "rope_interleave": True}
+
+    def rows_form(q):
+        with jax.named_scope(LATENT_ROPE):
+            return jnp.sum(_row_form(cfg, q, 0))
+
+    q = jnp.zeros((2, 8, heads, nope + pe), jnp.float32)
+    was = list(_under(jax.make_jaxpr(jax.grad(rows_form))(q).jaxpr,
+                      LATENT_ROPE))
+    largest, gathers = _walked(was)
+    assert largest == q.size == 2 * lanes
+    assert ("gather", lanes) in gathers     # the stride-2 lane pick
+
+
+# sha256 of the tiny Kimi-Linear-shaped update ("latent": a KDA layer, then a
+# latent layer that rotates nothing, held experts) as the parent of PR 63
+# lowered it, less the counters of the lowering's private functions: the
+# latent layer takes its columns apart only where lanes turn
+_NO_LANE_TURNS_AS_IT_WAS = (
+    "edc4d9c62b65c9249a4ea02d10b4e180d5ab6dd3346748b3fcbc1edf3522c2cc")
+
+
+def test_a_latent_layer_that_rotates_nothing_lowers_the_update_it_was(
+        lowered):
+    """``kimi-linear-policy``'s shape: whole projections, the code path as
+    it was, text for text — and no record of a rotation. An edit that
+    changes this family's program on purpose pins the hash it then reads."""
+    import hashlib
+
+    from _util import without_symbol_counters
+
+    text = without_symbol_counters(lowered("latent").as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        _NO_LANE_TURNS_AS_IT_WAS)
+    arch, _ = FAMILIES["latent"]
+    policy = build_policy({**arch, "has_critic": True})
+    jax.eval_shape(policy.init_params, jax.random.PRNGKey(0))
+    assert policy.latent_rope == {}
+    assert policy.attention_backends       # the latent layer was traced
+
+
 @pytest.mark.parametrize("family", FAMILIES)
 def test_compiled_update_is_scoped(compiled, family):
     scoped = total = 0

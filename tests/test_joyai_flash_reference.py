@@ -21,6 +21,7 @@ import pytest
 
 from relayrl_tpu.models import build_policy
 from relayrl_tpu.models.layers import mla
+from relayrl_tpu.models.layers.attention import apply_rope
 # the reference tests share their plumbing: a file loaded by its path, the
 # system's outputs for all actions, IMPALA's loss from either side's
 from test_lfm2_reference import _all_logp_v, _by_path, _impala_loss
@@ -393,9 +394,77 @@ class TestSystemAgainstReference:
             reference.program_kwargs(cfg)
 
 
+def _row_form(cfg, a, start):
+    """The rotation as it was written before the pairing moved to the
+    weights' columns (``mla._rotated`` up to PR 62), kept here as what the
+    column form has to equal: ``a [B, L, heads, w]`` (or the shared ``[B, L,
+    w]``), its LAST ``qk_rope_head_dim`` lanes de-interleaved row by row and
+    turned in half-split pairs, the other lanes as they came."""
+    theta, pe = cfg["rope_theta"], cfg["qk_rope_head_dim"]
+    if theta is None:
+        return a
+    shared = a.ndim == 3
+    if shared:
+        a = a[:, :, None]
+    lanes = a[..., a.shape[-1] - pe:]
+    if cfg["rope_interleave"]:
+        lanes = jnp.concatenate([lanes[..., 0::2], lanes[..., 1::2]], -1)
+    lanes = apply_rope(lanes, start, theta)
+    if a.shape[-1] > pe:
+        lanes = jnp.concatenate([a[..., :a.shape[-1] - pe], lanes], -1)
+    return lanes[:, :, 0] if shared else lanes
+
+
+# the widths of the tiny layer: 4 heads of 8 + 4 (q / k 12 wide, v 8) over a
+# latent row of 12, the residual stream 24 wide
+_D, _H, _RANK, _NOPE, _PE, _VD = 24, 4, 12, 8, 4, 8
+_EPS, _THETA = 1e-6, 100.0
+
+
+def _latent_layer(interleave, q_rank, use_bias=False, theta=_THETA):
+    """One latent layer alone (``TransformerBlock``, no FFN) at the tiny
+    widths -> ``(block, seeded params, rows [2, T, 24], cfg, records)``."""
+    import flax
+
+    from relayrl_tpu.models import layers
+    from relayrl_tpu.models.transformer import TransformerBlock
+
+    fns, records = layers.resolve({"attention": "dense"})
+    cfg = {"n_heads": _H, "rope_theta": theta, "kv_lora_rank": _RANK,
+           "qk_nope_head_dim": _NOPE, "qk_rope_head_dim": _PE,
+           "v_head_dim": _VD, "q_lora_rank": q_rank,
+           "rope_interleave": interleave}
+    block = TransformerBlock(
+        _D, 2, jnp.float32, op="latent_attention",
+        cfg=flax.core.FrozenDict(cfg), fns=fns, has_ffn=False,
+        norm="rms", norm_eps=_EPS, use_bias=use_bias)
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (2, T, _D)), jnp.float32)
+    params = block.init(jax.random.PRNGKey(0), x)
+    return block, params, x, cfg, records
+
+
+def _plain(reference, params, x, interleave, q_rank):
+    """The reference's layer on the same tree. Without a rank of its own the
+    query is ``u W_q``: the reference's ``(u I) W_qb`` with its norm left
+    out."""
+    p = dict(params["params"])
+    if q_rank is None:
+        p["q_a"] = {"kernel": jnp.eye(_D, dtype=jnp.float32)}
+        p["q_b"] = p.pop("q_proj")
+    as_run = {"no_rope": False, "half_split": not interleave,
+              "no_q_norm": q_rank is None, "scale_128": False}
+    with jax.default_matmul_precision("highest"):
+        return reference._latent_attention(
+            p, x, (_H, _RANK, _NOPE, _PE, _VD), _EPS, _THETA, None,
+            as_run)
+
+
 class TestTheRotation:
-    """``mla._rotated`` alone: the last ``qk_rope_head_dim`` lanes of its
-    rows, at their absolute positions."""
+    """``mla._apart`` and ``mla._rotated`` alone: a head's last
+    ``qk_rope_head_dim`` lanes taken apart as the first and the second of
+    each rotated pair — of a weight's columns in the layer, of plain rows
+    here, the function is one — and turned at their absolute positions."""
 
     CFG = {"rope_theta": 100.0, "qk_rope_head_dim": 8,
            "rope_interleave": True}
@@ -404,37 +473,64 @@ class TestTheRotation:
         return jnp.asarray(np.random.default_rng(seed).standard_normal(
             (2, length, heads, width)), jnp.float32)
 
+    def _turned(self, cfg, a, at, stand=0):
+        """``a``'s last 8 lanes a head through the column form's two steps,
+        the halves side by side behind the lanes that stand."""
+        B, L, heads, width = a.shape
+        still, *pair = mla._apart(a.reshape(B, L, heads * width), heads,
+                                  stand, width - stand,
+                                  cfg["rope_interleave"])
+        pair = mla._rotated(cfg, *(h.reshape(B, L, heads, -1) for h in pair),
+                            at)
+        return jnp.concatenate([still.reshape(B, L, heads, stand), *pair],
+                               -1)
+
     @pytest.mark.parametrize("interleave", [True, False])
     @pytest.mark.parametrize("start", [1, 7, 200])
     def test_scores_depend_on_the_distance_alone(self, interleave, start):
         """``R_i q . R_j k`` is a function of ``i - j``: the same rows at
         positions ``start + j`` give the scores they give from 0."""
         cfg = {**self.CFG, "rope_interleave": interleave}
-        q, k = self._rows(0, 8), self._rows(1, 8, heads=1)[:, :, 0]
+        q, k = self._rows(0, 8), self._rows(1, 8, heads=1)
 
         def scores(at):
-            return jnp.einsum("bqhd,bkd->bhqk", mla._rotated(cfg, q, at),
-                              mla._rotated(cfg, k, at))
+            return jnp.einsum("bqhd,bkd->bhqk", self._turned(cfg, q, at),
+                              self._turned(cfg, k, at)[:, :, 0])
 
         np.testing.assert_allclose(scores(start), scores(0), atol=1e-4)
         # and a position is seen: the unrotated rows score otherwise
-        plain = jnp.einsum("bqhd,bkd->bhqk", q, k)
+        plain = jnp.einsum("bqhd,bkd->bhqk", q, k[:, :, 0])
         assert float(jnp.abs(scores(0) - plain).max()) > 1e-2
 
     def test_only_the_last_lanes_turn_and_row_zero_stands(self):
         q = self._rows(2, 20)
-        got = mla._rotated(self.CFG, q, 0)
+        still, first, second = mla._apart(q.reshape(2, 16, 60), 3, 12, 8,
+                                          True)
+        np.testing.assert_array_equal(still.reshape(2, 16, 3, 12),
+                                      q[..., :12])
+        # the pairs (2i, 2i + 1) come apart as (i of the first, i of the
+        # second)
+        np.testing.assert_array_equal(first.reshape(2, 16, 3, 4),
+                                      q[..., 12::2])
+        np.testing.assert_array_equal(second.reshape(2, 16, 3, 4),
+                                      q[..., 13::2])
+        got = self._turned(self.CFG, q, 0, stand=12)
         np.testing.assert_array_equal(got[..., :12], q[..., :12])
-        # position 0 turns nothing: the lanes come back de-interleaved
-        np.testing.assert_allclose(
-            got[:, 0, :, 12:], jnp.concatenate(
-                [q[:, 0, :, 12::2], q[:, 0, :, 13::2]], -1), atol=1e-6)
-        assert float(jnp.abs(got[:, 1:, :, 12:]
-                             - q[:, 1:, :, 12:]).max()) > 1e-2
+        # position 0 turns nothing
+        np.testing.assert_allclose(got[:, 0, :, 12:], jnp.concatenate(
+            [q[:, 0, :, 12::2], q[:, 0, :, 13::2]], -1), atol=1e-6)
+        assert float(jnp.abs(got[:, 1:, :, 12:16]
+                             - q[:, 1:, :, 12::2]).max()) > 1e-2
 
-    def test_a_layer_that_rotates_nothing_gets_its_rows_back(self):
-        q = self._rows(3, 20)
-        assert mla._rotated({**self.CFG, "rope_theta": None}, q, 5) is q
+    def test_a_layer_that_rotates_nothing_keeps_its_projections_whole(self):
+        """No lane turns: ``nn.Dense`` products as before, no columns
+        apart, nothing under the rotation's name."""
+        block, params, x, _, records = _latent_layer(
+            True, 20, theta=None)
+        text = str(jax.make_jaxpr(block.apply)(params, x))
+        assert text.count("dot_general") == 7   # 5 projections, q k^T, p v
+        assert "relayrl_latent_rope" not in text and "cos" not in text
+        assert records["latent_rope"] == {}
 
     def test_the_interleaved_pairs_are_the_published_ones(self, reference):
         """Against the reference's rotation in place: equal up to the ONE
@@ -442,13 +538,226 @@ class TestTheRotation:
         q, k = self._rows(4, 8), self._rows(5, 8, heads=1)
         want = jnp.einsum("bqhd,bkd->bhqk", reference._rope(q, 100.0, True),
                           reference._rope(k, 100.0, True)[:, :, 0])
-        got = jnp.einsum("bqhd,bkd->bhqk", mla._rotated(self.CFG, q, 0),
-                         mla._rotated(self.CFG, k[:, :, 0], 0))
+        got = jnp.einsum("bqhd,bkd->bhqk", self._turned(self.CFG, q, 0),
+                         self._turned(self.CFG, k, 0)[:, :, 0])
         np.testing.assert_allclose(got, want, atol=2e-5)
         halves = jnp.einsum(
             "bqhd,bkd->bhqk", reference._rope(q, 100.0, False),
             reference._rope(k, 100.0, False)[:, :, 0])
         assert float(jnp.abs(want - halves).max()) > 1e-2
+
+    @pytest.mark.parametrize("interleave", [True, False])
+    @pytest.mark.parametrize("start", [0, 5])
+    def test_the_two_steps_are_the_row_form(self, interleave, start):
+        """Lanes apart, then turned: the rows the row form gave, lane for
+        lane — of heads' rows and of the shared ``[B, L, pe]`` alike."""
+        cfg = {**self.CFG, "rope_interleave": interleave}
+        q = self._rows(6, 20)
+        np.testing.assert_allclose(self._turned(cfg, q, start, stand=12),
+                                   _row_form(cfg, q, start), atol=1e-6)
+        k = self._rows(7, 8, heads=1)[:, :, 0]
+        _, *pair = mla._apart(k, 1, 0, 8, interleave)
+        np.testing.assert_allclose(
+            jnp.concatenate(mla._rotated(cfg, *pair, start), -1),
+            _row_form(cfg, k, start), atol=1e-6)
+
+
+class TestTheColumnForm:
+    """One rotary latent layer alone (``TransformerBlock``, no FFN), the
+    pairing and the nope / rope split taken on the projections' columns,
+    against the benchmark's plain float32 layer: full, cached (a prefill,
+    then eight steps) and readout modes, both pairings, with and without a
+    low rank of the query's own."""
+
+    FORMS = [(True, 20), (True, None), (False, 20), (False, None)]
+
+    @pytest.mark.parametrize("interleave,q_rank", FORMS)
+    def test_the_full_mode(self, reference, interleave, q_rank):
+        block, params, x, _, records = _latent_layer(interleave, q_rank)
+        got = jax.jit(block.apply)(params, x)
+        want = _plain(reference, params, x, interleave, q_rank)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        assert records["latent_rope"] == {
+            ("latent_attention", "dense"): "columns"}
+        # and the other pairing is another function of the same tree
+        other = _plain(reference, params, x, not interleave, q_rank)
+        assert float(jnp.abs(got - other).max()) > 1e-3
+
+    @pytest.mark.parametrize("interleave,q_rank", FORMS)
+    def test_the_readout_row(self, reference, interleave, q_rank):
+        block, params, x, _, _ = _latent_layer(interleave, q_rank)
+        want = _plain(reference, params, x, interleave, q_rank)
+        row = jax.jit(lambda p, x, i: block.apply(p, x, readout_idx=i))
+        for idx in (0, 9, T - 1):
+            got = row(params, x, jnp.asarray(idx, jnp.int32))
+            assert got.shape == (2, 1, _D)
+            np.testing.assert_allclose(got[:, 0], want[:, idx], atol=2e-5,
+                                       err_msg=f"row {idx}")
+
+    def _decode(self, block, params, x, cache, t0):
+        step = jax.jit(lambda p, row, cache, t: block.apply(
+            p, row, cache=cache, t=t))
+        rows = []
+        for t in range(t0, T):
+            out, cache = step(params, x[:, t:t + 1], cache, t)
+            rows.append(out[:, 0])
+        return jnp.stack(rows, 1), cache
+
+    @pytest.mark.parametrize("interleave,q_rank", FORMS)
+    def test_a_prefill_then_eight_steps(self, reference, interleave, q_rank):
+        block, params, x, cfg, _ = _latent_layer(interleave, q_rank)
+        want = _plain(reference, params, x, interleave, q_rank)
+        t0 = T - 8
+        cache = mla.init_cache(cfg, _D, 2, T, jnp.float32, None)
+        out, cache = jax.jit(lambda p, rows, cache: block.apply(
+            p, rows, cache=cache, t=0))(params, x[:, :t0], cache)
+        np.testing.assert_allclose(out, want[:, :t0], atol=2e-5)
+        got, _ = self._decode(block, params, x, cache, t0)
+        np.testing.assert_allclose(got, want[:, t0:], atol=2e-5)
+
+    @pytest.mark.parametrize("interleave,q_rank", FORMS)
+    def test_a_cache_the_row_form_wrote_reads_the_same(
+            self, reference, interleave, q_rank):
+        """The latent rows and the shared lanes as the program up to PR 62
+        wrote them — ``kv_a``'s product in the published column order, its
+        last lanes turned by the row form — are the rows the column form
+        writes, in the same order: a cache written before reads the same,
+        and the steps from it are the full forward's rows."""
+        block, params, x, cfg, _ = _latent_layer(interleave, q_rank)
+        p = params["params"]
+        with jax.default_matmul_precision("highest"):
+            u = reference._rms_norm(p["ln_attn"], x, _EPS)
+            c, k_pe = jnp.split(u @ p["kv_a"]["kernel"], [_RANK], axis=-1)
+        t0 = T - 8
+        old = tuple(
+            jnp.zeros_like(rows).at[:, :t0].set(rows[:, :t0])
+            for rows in (c, _row_form(cfg, k_pe, 0)))
+        _, new = jax.jit(lambda p, rows, cache: block.apply(
+            p, rows, cache=cache, t=0))(
+                params, x[:, :t0],
+                mla.init_cache(cfg, _D, 2, T, jnp.float32, None))
+        for a, b in zip(old, new):
+            np.testing.assert_allclose(a, b, atol=1e-5)
+        # (the shared lanes in half-split order, not as published)
+        if interleave:
+            assert float(jnp.abs(
+                new[1][:, 0] - k_pe[:, 0]).max()) > 1e-2
+        want = _plain(reference, params, x, interleave, q_rank)
+        got, _ = self._decode(block, params, x, old, t0)
+        np.testing.assert_allclose(got, want[:, t0:], atol=2e-5)
+
+    @pytest.mark.parametrize("interleave,q_rank", FORMS)
+    def test_the_gradients_come_back_in_the_published_order(
+            self, reference, interleave, q_rank):
+        """``q_b``'s (or ``q_proj``'s) and ``kv_a``'s kernels' gradients
+        and the layer input's against ``jax.grad`` of the reference, which
+        reads the columns as published."""
+        block, params, x, _, _ = _latent_layer(interleave, q_rank)
+        w = jnp.asarray(np.random.default_rng(4).standard_normal(x.shape),
+                        jnp.float32)
+        loss = lambda f: (lambda p, x: jnp.sum(f(p, x) * w))
+        got = jax.jit(jax.grad(loss(block.apply), (0, 1)))(params, x)
+        want = jax.grad(loss(lambda p, x: _plain(
+            reference, p, x, interleave, q_rank)), (0, 1))(params, x)
+        np.testing.assert_allclose(got[1], want[1], atol=1e-4, rtol=1e-4)
+        q_name = "q_b" if q_rank else "q_proj"
+        flat_want = dict(jax.tree_util.tree_flatten_with_path(want[0])[0])
+        for path, g in jax.tree_util.tree_flatten_with_path(got[0])[0]:
+            np.testing.assert_allclose(
+                g, flat_want[path], atol=1e-4, rtol=1e-4,
+                err_msg=jax.tree_util.keystr(path))
+        for name in (q_name, "kv_a"):
+            g = got[0]["params"][name]["kernel"]
+            assert g.shape == params["params"][name]["kernel"].shape
+            assert float(jnp.abs(g).min(0).max()) > 0   # every column's
+
+    @pytest.mark.parametrize("interleave,q_rank", FORMS)
+    @pytest.mark.parametrize("use_bias", [False, True])
+    def test_the_parameter_tree_is_the_one_it_was(self, interleave, q_rank,
+                                                  use_bias):
+        """Names, shapes and SEEDED VALUES of the tree are ``nn.Dense``'s,
+        as before the columns were taken apart: the same layer built where
+        no lane turns (whole projections, the code as it was) seeds the same
+        numbers under the same names."""
+        import flax
+
+        block, params, x, cfg, _ = _latent_layer(interleave, q_rank, use_bias)
+        whole = block.clone(cfg=flax.core.FrozenDict(
+            {**cfg, "rope_theta": None}))
+        was = whole.init(jax.random.PRNGKey(0), x)
+        flat = {jax.tree_util.keystr(k): v for k, v in
+                jax.tree_util.tree_flatten_with_path(params)[0]}
+        flat_was = {jax.tree_util.keystr(k): v for k, v in
+                    jax.tree_util.tree_flatten_with_path(was)[0]}
+        assert list(flat) == list(flat_was)
+        for name, leaf in flat.items():
+            np.testing.assert_array_equal(leaf, flat_was[name], err_msg=name)
+        q = ({"q_a": (_D, 20), "q_a_norm": (20,), "q_b": (20, _H * 12)}
+             if q_rank else {"q_proj": (_D, _H * 12)})
+        shapes = {"ln_attn": (_D,), **q, "kv_a": (_D, _RANK + _PE),
+                  "kv_a_norm": (_RANK,), "kv_b": (_RANK, _H * 16),
+                  "attn_out": (_H * _VD, _D)}
+        p = params["params"]
+        assert set(p) == set(shapes)
+        for name, shape in shapes.items():
+            leaf = "scale" if len(shape) == 1 else "kernel"
+            assert p[name][leaf].shape == shape
+            assert ("bias" in p[name]) == (use_bias and leaf == "kernel")
+
+    @pytest.mark.parametrize("interleave", [True, False])
+    def test_a_bias_is_taken_apart_with_its_columns(self, interleave):
+        """``_ColumnsApart`` against ``nn.Dense`` on one tree, a bias that
+        is not zero: the two products are the whole one's lanes, apart."""
+        from flax import linen as nn
+
+        x = jnp.asarray(np.random.default_rng(5).standard_normal(
+            (2, 5, 7)), jnp.float32)
+        apart = mla._ColumnsApart(3, 4, 6, interleave, jnp.float32, True)
+        whole = nn.Dense(3 * 10, dtype=jnp.float32)
+        tree = whole.init(jax.random.PRNGKey(1), x)
+        np.testing.assert_array_equal(
+            tree["params"]["kernel"],
+            apart.init(jax.random.PRNGKey(1), x)["params"]["kernel"])
+        tree = {"params": {**tree["params"], "bias": jnp.arange(30.0)}}
+        still, first, second = apart.apply(tree, x)
+        want = whole.apply(tree, x).reshape(2, 5, 3, 10)
+        np.testing.assert_allclose(still.reshape(2, 5, 3, 4),
+                                   want[..., :4], atol=1e-5)
+        lanes = want[..., 4:]
+        pair = ((lanes[..., 0::2], lanes[..., 1::2]) if interleave
+                else (lanes[..., :3], lanes[..., 3:]))
+        for got, half in zip((first, second), pair):
+            np.testing.assert_allclose(got.reshape(2, 5, 3, 3), half,
+                                       atol=1e-5)
+
+    def test_the_record_and_its_line(self, reference, cfg, capsys):
+        """``Policy.latent_rope`` says ``"columns"`` for both kinds of a
+        six-layer trunk (one dense layer, five with experts) and the build
+        prints one ``[latent_rope]`` line a kind and shape; a trunk whose
+        latent layer rotates nothing has no entry."""
+        policy, params = _system(reference, cfg, "float32", n_layers=6,
+                                 layer_types=["latent_attention"] * 6)
+        assert sorted(params["params"])[:6] == [
+            f"block_{i}" for i in range(6)]
+        capsys.readouterr()
+        jax.jit(policy.evaluate).lower(
+            params, _obs(cfg), jnp.zeros((2, T), jnp.int32))
+        assert dict(policy.latent_rope) == {
+            ("latent_attention", "dense"): "columns",
+            ("latent_attention", "experts"): "columns"}
+        lines = [line for line in capsys.readouterr().out.splitlines()
+                 if line.startswith("[latent_rope]")]
+        assert len(lines) == 2
+        assert lines[0].startswith(
+            "[latent_rope] latent_attention+dense (from block_0) T=32 "
+            "heads=4 lanes 4 of 12 float32, pairs (2i, 2i + 1) -> columns")
+        assert "(from block_1)" in lines[1]
+        assert "MB of rows, where every row's lanes were" in lines[0]
+        still, _ = _system(reference, cfg, "float32", positions="none")
+        jax.jit(still.evaluate).lower(
+            params, _obs(cfg), jnp.zeros((2, T), jnp.int32))
+        assert dict(still.latent_rope) == {}
+        assert "[latent_rope]" not in capsys.readouterr().out
 
 
 class TestTheSharesAddUp:
