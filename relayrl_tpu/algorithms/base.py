@@ -19,10 +19,12 @@ from __future__ import annotations
 
 import abc
 import contextlib
+import functools
 import threading
 import time
 from typing import Any, Callable, Mapping, Sequence
 
+from relayrl_tpu import telemetry
 from relayrl_tpu.algorithms.dispatch import InflightWindow, PublishSnapshot
 from relayrl_tpu.telemetry.spans import span, watch_gc
 from relayrl_tpu.types.action import ActionRecord
@@ -72,6 +74,30 @@ def anchor_path(path: str, env_dir: str | None) -> str:
     if env_dir and not os.path.isabs(path):
         return os.path.join(env_dir, path)
     return path
+
+
+# ``stage_batch``'s rule for a large array: one of at least this many bytes
+# crosses as its flat bytes and is given its shape on the device. A constant,
+# not configuration (the code reads the array's size), from a sweep on the
+# v5e (PERF.md section 6, PR 64): at 36 MB the two ways land together, at 72 MB
+# and above the flat one lands in half the time or less, and at
+# nature-cnn.update's 289 MB of [512, 20, 28224] uint8 frames it takes the
+# host an eighth of the CPU time.
+_H2D_FLAT_BYTES = 64 << 20
+
+
+@functools.cache
+def _shape_on_device():
+    """The device's half of a flat put: one small program that gives the
+    flat bytes the host array's shape, built on first use (this module
+    loads without jax)."""
+    import jax
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def relayrl_staged_shape(flat, shape):
+        return flat.reshape(shape)
+
+    return relayrl_staged_shape
 
 
 class AlgorithmBase(abc.ABC):
@@ -399,14 +425,51 @@ class AlgorithmBase(abc.ABC):
         inside the (window-fenced) dispatch path. ``_to_device`` passes
         already-placed arrays through untouched, so a staged batch and a
         host batch are interchangeable downstream. Single-host only —
-        mesh placement (``_place``) already owns multihost batches."""
-        import jax
+        mesh placement (``_place``) already owns multihost batches.
 
-        with span("host:stage_batch"):
+        An array of at least ``_H2D_FLAT_BYTES`` crosses as a flat view of
+        its bytes and is given its shape by one small program dispatched
+        here, outside the update. ``device_put`` of an array in its own
+        shape has the HOST turn it into the device's tiled layout first
+        (for ``[512, 20, 28224]`` bytes a transposition: 190 ms of CPU on
+        the transfer's threads, landing 100 ms later); a one-dimensional
+        array crosses as it lies and the device does the turning. What
+        comes back is the same either way — every value one ``jax.Array``
+        of the host array's shape and dtype — and a batch with no such
+        array takes one ``device_put`` of the whole dict.
+
+        **The staging ring's order still frees the slab** (``EpochBuffer.
+        add_episode``): the flat array is a view of the slab, the update's
+        operand is the shaping program's result and that program runs only
+        when the bytes have landed, so "update fenced" still implies "slab
+        read".
+
+        The span carries ``flat`` (arrays put that way; 0 when the dict
+        went as it is) and ``bytes``;
+        ``relayrl_learner_h2d_flat_total`` counts the same arrays."""
+        import jax
+        import numpy as np
+
+        with span("host:stage_batch") as sp:
             place = getattr(self, "_place", None)
             if place is not None:
                 return place(dict(host_batch))
-            return jax.device_put(dict(host_batch))
+            flat = {k: v.reshape(-1) for k, v in host_batch.items()
+                    if isinstance(v, np.ndarray) and v.ndim > 1
+                    and v.nbytes >= _H2D_FLAT_BYTES and v.flags.c_contiguous}
+            staged = jax.device_put({**host_batch, **flat})
+            for k in flat:
+                staged[k] = _shape_on_device()(staged[k],
+                                               host_batch[k].shape)
+            if flat:
+                telemetry.get_registry().counter(
+                    "relayrl_learner_h2d_flat_total",
+                    "arrays stage_batch put as flat bytes and shaped on "
+                    "the device").inc(len(flat))
+            if sp.traced:
+                sp.note(flat=len(flat), bytes=sum(
+                    getattr(v, "nbytes", 0) for v in host_batch.values()))
+            return staged
 
     def _to_device(self, host_batch) -> dict:
         """The single owner of host-batch → device-batch placement

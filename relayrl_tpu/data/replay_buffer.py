@@ -190,9 +190,13 @@ class EpochBuffer:
         fences update ``k - window``) *before* it adds the next episode:
         ``drain k → stage_batch → train_on_batch k → add_episode``. Every
         caller in this repo does (``accumulate`` drains the moment a batch
-        is full). Episodes added while an older batch still waits to be
-        drained go to a fresh slab, not to the ring; a caller that keeps
-        drained batches longer calls :meth:`disable_staging`."""
+        is full). ``stage_batch`` puts a large array as a flat view of
+        the slab and shapes it on the device: the update reads the shaped
+        array, the shaping runs once the bytes have landed, so the
+        update's fence still says the slab was read. Episodes added
+        while an older batch still waits to be drained go to a fresh slab,
+        not to the ring; a caller that keeps drained batches longer calls
+        :meth:`disable_staging`."""
         bucket = pick_bucket(len(actions), self.buckets)
         with span("rl:batch.pad"):
             if isinstance(actions, DecodedTrajectory):

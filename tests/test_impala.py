@@ -390,6 +390,40 @@ class TestUint8Observations:
         assert learner._update._cache_size() == size
         assert learner.version == 1
 
+    @pytest.mark.parametrize("model,obs_dtype,hp", [
+        ("cnn", np.uint8, {}),
+        ("mlp", np.float32, dict(
+            model_kind="transformer_discrete", d_model=16, n_layers=1,
+            n_heads=2, max_seq_len=8))],
+        ids=["pixel", "sequence"])
+    def test_update_on_a_batch_shaped_on_the_device_is_the_same_program(
+            self, tmp_path, monkeypatch, model, obs_dtype, hp):
+        """``stage_batch`` shapes a flat put outside the update, so the
+        update lowered on such a batch has the text of the update lowered
+        on one put as it is, and running both compiles it once."""
+        from relayrl_tpu.algorithms import base
+
+        learner = _build(tmp_path, "IMPALA", model, **hp)
+        if hp:
+            assert learner.arch["kind"] == hp["model_kind"]
+        batch = _full_batch(learner, obs_dtype)
+        whole = learner.stage_batch(batch)
+        monkeypatch.setattr(base, "_H2D_FLAT_BYTES", 1)
+        shaped = learner.stage_batch(batch)
+        assert all(isinstance(v, jax.Array) for v in shaped.values())
+        on_whole, on_shaped = (
+            learner._update.lower(learner.state, staged).as_text()
+            for staged in (whole, shaped))
+        assert on_whole == on_shaped
+        learner.train_on_batch(whole)
+        size = learner._update._cache_size()
+        with _Compiles() as seen:
+            learner.train_on_batch(shaped)
+            learner.inflight.drain()
+        assert (seen.requests, seen.backend) == (0, 0)
+        assert learner._update._cache_size() == size
+        assert learner.version == 2
+
     def test_scale_obs_learner_fed_float32_compiles_once_more(
             self, tmp_path):
         """envs/atari.py's default obs_dtype is float32: such a stream

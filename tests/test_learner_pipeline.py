@@ -249,6 +249,53 @@ class TestStagingBuffers:
             for x, y in zip(la, lb):
                 assert np.asarray(x).tobytes() == np.asarray(y).tobytes(), k
 
+    @pytest.mark.parametrize("algo_name,window", [("IMPALA", 2),
+                                                  ("REINFORCE", 1)])
+    def test_ring_holds_when_the_batch_crosses_as_flat_bytes(
+            self, tmp_cwd, monkeypatch, algo_name, window):
+        """The drill above with ``stage_batch`` made to put every array of
+        two axes as a flat view of the slab, shaped on the device: the
+        update reads the shaped array, and after ``window + 3`` unfenced
+        updates the parameters are byte for byte those of a twin that puts
+        every array as it is."""
+        import jax
+        import jax.numpy as jnp
+
+        from relayrl_tpu.algorithms import base
+
+        def run(flat):
+            monkeypatch.setattr(base, "_H2D_FLAT_BYTES",
+                                1 if flat else 1 << 40)
+            algo = build_algorithm(
+                algo_name, obs_dim=OBS_DIM, act_dim=ACT_DIM,
+                env_dir=str(tmp_cwd / f"flat-{flat}"), traj_per_epoch=3,
+                hidden_sizes=[16], seed_salt=0, bucket_lengths=[64, 256],
+                max_inflight_updates=window)
+            assert algo.buffer._staging.slots == window + 1
+            params = []
+            for ep in _stream(3 * (window + 3)):
+                batch = algo.accumulate(ep)
+                if batch is None:
+                    continue
+                staged = algo.stage_batch(batch)
+                assert all(isinstance(v, jax.Array) and
+                           v.shape == batch[k].shape
+                           for k, v in staged.items())
+                algo.train_on_batch(staged)
+                params.append(jax.tree_util.tree_map(jnp.copy,
+                                                     algo.state.params))
+            assert algo.inflight.dispatch_count == window + 3
+            assert algo.inflight.fenced_count == 3
+            return [jax.device_get(p) for p in params]
+
+        flat, whole = run(True), run(False)
+        assert len(flat) == len(whole) == window + 3
+        for k, (a, b) in enumerate(zip(flat, whole)):
+            la, lb = (jax.tree_util.tree_leaves(t) for t in (a, b))
+            assert len(la) == len(lb)
+            for x, y in zip(la, lb):
+                assert np.asarray(x).tobytes() == np.asarray(y).tobytes(), k
+
     def test_sample_out_gathers_identical_values(self):
         from relayrl_tpu.data import StepReplayBuffer
 
@@ -280,6 +327,143 @@ class TestStagingBuffers:
         buf = EpochBuffer(obs_dim=2, act_dim=2, traj_per_epoch=1,
                           buckets=(256, 64, 64, 1000))
         assert buf.buckets == (64, 256, 1000)  # sorted + deduped once
+
+
+def _host_batch(rows, obs_dtype, horizon=5, width=12, filled=None):
+    """A host batch of ``rows`` rows (the leading ``filled`` rows of it as
+    contiguous views, as a part-filled drain hands them out)."""
+    rng = np.random.default_rng(rows)
+    if obs_dtype == np.uint8:
+        obs = rng.integers(0, 256, (rows, horizon, width), dtype=np.uint8)
+    else:
+        obs = rng.standard_normal((rows, horizon, width)).astype(obs_dtype)
+    batch = {"obs": obs,
+             "act": rng.integers(0, ACT_DIM, (rows, horizon)).astype(np.int32),
+             "rew": rng.random((rows, horizon)).astype(np.float32),
+             "last_val": rng.random(rows).astype(np.float32),
+             "valid": np.ones((rows, horizon), np.float32)}
+    if filled is not None:
+        batch = {k: v[:filled] for k, v in batch.items()}
+    return batch
+
+
+def _not_contiguous(batch):
+    """The same values with ``obs`` laid out column-major: no flat view."""
+    return {**batch, "obs": np.asfortranarray(batch["obs"])}
+
+
+def _one_dimensional(batch):
+    """``obs`` as one long row of its own: nothing to shape."""
+    return {**batch, "obs": batch["obs"].reshape(-1)}
+
+
+# name -> (rows, filled, obs dtype, threshold in bytes of obs, a change to
+# the batch) and the keys that must cross flat
+_FLAT_CASES = {
+    "uint8-at-the-threshold": ((8, None, np.uint8, 1.0, None), {"obs"}),
+    "uint8-over-the-threshold": ((8, None, np.uint8, 0.5, None), {"obs"}),
+    "float32-at-the-threshold": ((8, None, np.float32, 1.0, None), {"obs"}),
+    "part-filled-leading-rows": ((8, 5, np.uint8, 1.0, None), {"obs"}),
+    "float32-part-filled": ((9, 6, np.float32, 1.0, None), {"obs"}),
+    "a-single-row": ((1, None, np.uint8, 1.0, None), {"obs"}),
+    "every-array-of-two-axes": ((8, None, np.float32, 0.0, None),
+                                {"obs", "act", "rew", "valid"}),
+    "uint8-under-the-threshold": ((8, None, np.uint8, 1.01, None), set()),
+    "float32-under-the-threshold": ((8, None, np.float32, 1.01, None), set()),
+    "not-contiguous": ((8, None, np.uint8, 1.0, _not_contiguous), set()),
+    "one-axis-already": ((8, None, np.uint8, 1.0, _one_dimensional), set()),
+}
+
+
+class TestStageBatchFlatPut:
+    """``stage_batch`` puts an array of ``_H2D_FLAT_BYTES`` or more as a
+    flat view of its bytes and shapes it on the device; what it returns is
+    the host batch, value for value, whichever way an array went."""
+
+    @staticmethod
+    def _stage(batch):
+        import types
+
+        from relayrl_tpu.algorithms.base import AlgorithmBase
+
+        return AlgorithmBase.stage_batch(types.SimpleNamespace(), batch)
+
+    @pytest.fixture
+    def registry(self):
+        from relayrl_tpu import telemetry
+
+        telemetry.set_registry(telemetry.Registry(run_id="h2d"))
+        yield telemetry.get_registry()
+        telemetry.reset_for_tests()
+
+    @pytest.mark.parametrize("case", list(_FLAT_CASES), ids=list(_FLAT_CASES))
+    def test_staged_batch_is_the_host_batch(self, monkeypatch, registry,
+                                            case):
+        import jax
+
+        from relayrl_tpu.algorithms import base
+
+        (rows, filled, dtype, at, change), flat = _FLAT_CASES[case]
+        host = _host_batch(rows, dtype, filled=filled)
+        if change is not None:
+            host = change(host)
+        monkeypatch.setattr(base, "_H2D_FLAT_BYTES",
+                            int(np.ceil(at * host["obs"].nbytes)))
+        puts = []
+        real_put = jax.device_put
+        monkeypatch.setattr(
+            jax, "device_put",
+            lambda tree, *a, **k: (puts.append(tree), real_put(tree, *a, **k)
+                                   )[1])
+        staged = self._stage(host)
+
+        (put,) = puts  # every array enqueued by the one call
+        assert type(put) is dict and sorted(put) == sorted(host)
+        for k in host:
+            if k in flat:  # a view of the host array's bytes, in order
+                assert put[k].ndim == 1 and put[k].size == host[k].size, k
+                assert np.shares_memory(put[k], host[k]), k
+            else:
+                assert put[k] is host[k], k
+        assert registry.counter(
+            "relayrl_learner_h2d_flat_total").total() == len(flat)
+        assert sorted(staged) == sorted(host)
+        for k, v in staged.items():
+            assert isinstance(v, jax.Array), k
+            assert v.shape == host[k].shape and v.dtype == host[k].dtype, k
+            assert np.asarray(v).tobytes() == host[k].tobytes(), k
+
+    def test_no_array_at_the_threshold_is_one_put_of_the_dict(
+            self, monkeypatch):
+        """The shipped constant: a batch of the sequence cells' size (and
+        every batch of this suite) takes ``jax.device_put(dict(batch))``,
+        once."""
+        import jax
+
+        host = _host_batch(8, np.float32, horizon=64, width=32)
+        calls = []
+        real_put = jax.device_put
+        monkeypatch.setattr(
+            jax, "device_put",
+            lambda *a, **k: (calls.append((a, k)), real_put(*a, **k))[1])
+        staged = self._stage(host)
+        assert len(calls) == 1
+        (tree,), kwargs = calls[0]
+        assert kwargs == {} and type(tree) is dict and tree is not host
+        assert all(tree[k] is host[k] for k in host) and len(tree) == len(host)
+        assert all(isinstance(v, jax.Array) for v in staged.values())
+
+    def test_the_constant_takes_the_pixel_batch_and_no_sequence_batch(self):
+        """nature-cnn.update's frames are over ``_H2D_FLAT_BYTES``, the
+        largest batch a sequence cell stages (2.56 MB) far under it (no
+        memory is touched: a broadcast view of one byte has the shape and
+        the ``nbytes``)."""
+        from relayrl_tpu.algorithms import base
+
+        frames = np.broadcast_to(np.uint8(0), (512, 20, 28224))
+        assert frames.nbytes >= base._H2D_FLAT_BYTES
+        sequence = np.broadcast_to(np.float32(0), (8, 2048, 39))
+        assert 20 * sequence.nbytes < base._H2D_FLAT_BYTES
 
 
 class TestEquivalence:

@@ -215,6 +215,43 @@ class TestBatchSpans:
             "bytes": sum(v.nbytes for v in batch.as_dict().values()),
             "moved": moved}
 
+    @pytest.mark.parametrize("threshold,flat", [(None, 0), (480, 1), (1, 3)],
+                             ids=["as-it-is", "the-frames", "every-array"])
+    def test_stage_batch_span_counts_its_flat_puts(
+            self, tmp_path, monkeypatch, threshold, flat):
+        """``host:stage_batch`` carries ``flat`` — the arrays it put as
+        flat bytes and shaped on the device, 0 when the dict went as it
+        is — and the batch's ``bytes``; ``relayrl_learner_h2d_flat_total``
+        advances by the same."""
+        import types
+
+        from relayrl_tpu import telemetry
+        from relayrl_tpu.algorithms import base
+
+        rng = np.random.default_rng(0)
+        batch = {"obs": rng.integers(0, 256, (6, 5, 16), dtype=np.uint8),
+                 "act": np.zeros((6, 5), np.int32),
+                 "rew": np.zeros((6, 5), np.float32),
+                 "last_val": np.zeros((6,), np.float32)}
+        if threshold:
+            monkeypatch.setattr(base, "_H2D_FLAT_BYTES", threshold)
+        telemetry.set_registry(telemetry.Registry(run_id="h2d"))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for _ in range(2):
+                staged = base.AlgorithmBase.stage_batch(
+                    types.SimpleNamespace(), batch)
+            total = telemetry.get_registry().counter(
+                "relayrl_learner_h2d_flat_total").total()
+        finally:
+            jax.profiler.stop_trace()
+            telemetry.reset_for_tests()
+        assert all(np.array_equal(staged[k], batch[k]) for k in batch)
+        spans = xplane_events(tmp_path)["host:stage_batch"]
+        assert [stats for *_x, stats in spans] == 2 * [{
+            "flat": flat, "bytes": sum(v.nbytes for v in batch.values())}]
+        assert total == 2 * flat
+
 
 class TestTracerSink:
     @pytest.fixture(autouse=True)
