@@ -504,6 +504,9 @@ def finish_run(run: Run) -> dict:
     line["notes"]["window"] = {"window_s": run.window_s,
                                "updates": run.updates,
                                "samples": run.samples, **run.e2e}
+    if "compared" in run.notes:
+        # each number a driver compared, beside its limit: last in the line
+        line["compared"] = line["notes"].pop("compared")
     return line
 
 

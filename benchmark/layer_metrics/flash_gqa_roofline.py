@@ -4,9 +4,11 @@ operations / peak FLOP/s and bytes / peak bytes/s, from the reference
 file's ``flash_gqa_train_ops_bytes`` (operations of the scores a causal
 call needs, T (T + 1) / 2 a q head, whatever the kernels' tiling executes
 beside them; bytes with k/v at their own head count) — over the device
-time per update of the operations named ``relayrl_flash_fwd`` / ``_dq`` /
-``_dkv`` ONLY (``flash_roofline`` sums every Mosaic call, which here would
-take the expert layer's grouped matmuls for attention)."""
+time per update of the operations named ``relayrl_flash_fwd`` /
+``relayrl_flash_bwd`` ONLY (``program_trace.KERNELS``; ``flash_roofline`` sums
+every Mosaic call, which here would take the expert layer's grouped matmuls
+for attention). The count is of the work, not of an implementation: it stood
+when one backward kernel took the place of dq and dkv (PR 53)."""
 
 from benchmark import program_trace
 

@@ -8,8 +8,8 @@ runs, so they sit in the xplane on the device trace's clock: ``host:<phase>``
 for the learner thread's sequential phases, ``rl:<layer>.<what>`` for what is
 nested or on another thread, arguments as event stats. The device side
 carries the names the program sets: the jitted update's module
-(``jit_<algo>_update``) and ``relayrl_flash_fwd`` / ``_dq`` / ``_dkv`` on the
-three flash kernels. PERF.md section 3 says where each lands.
+(``jit_<algo>_update``) and ``relayrl_flash_fwd`` / ``relayrl_flash_bwd`` on
+the two flash kernels. PERF.md section 3 says where each lands.
 
 Two steps, as in ``trace_reduce``, so that the arithmetic can be checked
 without a chip (``benchmark/tests/test_program_trace.py`` against
@@ -36,7 +36,7 @@ SPAN_PREFIXES = ("host:", "rl:")
 WINDOW = "host:window"
 MODULES_LINE = "XLA Modules"
 UPDATE_MODULE = "_update("     # jit_impala_update(<fingerprint>)
-KERNELS = ("relayrl_flash_fwd", "relayrl_flash_dq", "relayrl_flash_dkv")
+KERNELS = ("relayrl_flash_fwd", "relayrl_flash_bwd")
 # the learner thread's waits that the program names
 BLOCKED = ("host:wait_data", "rl:dispatch.fence")
 

@@ -102,10 +102,10 @@ def test_kernel_is_found_whatever_scope_called_it():
     of = program_trace._kernel_of
     call = ('= (bf16[128,1024,64]{2,1,0}) custom-call(bf16[128,1024,64] '
             '%bitcast.2), custom_call_target="tpu_custom_call"')
-    assert of(_Event("%relayrl_flash_dq.7 " + call)) == "relayrl_flash_dq"
+    assert of(_Event("%relayrl_flash_bwd.7 " + call)) == "relayrl_flash_bwd"
     assert of(_Event("%block_18.5 " + call, tf_op=(
-        "jit(impala_update)/loss/block_18/attn/relayrl_flash_dkv/"
-        "pallas_call"))) == "relayrl_flash_dkv"
+        "jit(impala_update)/loss/block_18/attn/relayrl_flash_bwd/"
+        "pallas_call"))) == "relayrl_flash_bwd"
     assert of(_Event("%encoder.2 " + call, long_name=(
         "jit(ppo_update)/layer_3/relayrl_flash_fwd/pallas_call"),
         flops=12)) == "relayrl_flash_fwd"
