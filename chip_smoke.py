@@ -1220,6 +1220,138 @@ def phase_k() -> None:
 
 # --------------------------------------------------------------------------
 
+def phase_l() -> None:
+    """The fused rollout's cached scan and a model swap's rebuild at
+    ``gpt2m-policy.rollout``'s own size — 64 lanes of a 1,024-row window, 24
+    layers' keys and values in the scan carry (6.44 GB) — where only the
+    chip can say that both programs fit beside each other. Six dispatches
+    under one seeded tree, ``maybe_swap`` to another mid-episode, two more,
+    a swap back, two more: the first dispatch after each swap rebuilds every
+    lane's cache from its ring (``runtime/anakin.make_cache_rebuild``; the
+    second rebuild finds the program compiled, so its time is the
+    rebuild's own). Two lanes' emitted ``logp_a``
+    and ``v`` are held to ``policy.evaluate`` over the lane's observations,
+    at the learner's own shape, under the parameters that were installed
+    when the step was dispatched (limit 0.05 of max(1, range): bfloat16
+    rounding reads under 0.02, PERF.md section 6), and the steps after the
+    swap ALSO against the old parameters, which has to read far over the
+    limit — a cache left as the old parameters wrote it would pass the one
+    and fail the other the wrong way round. Not one of ``run``'s phases
+    (its programs are the benchmark cell's, which every check of a PR
+    runs): ``chiprun -- python -c "import chip_smoke;
+    chip_smoke.phase_l()"``."""
+    import jax
+    import numpy as np
+
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark import harness
+    from benchmark.drivers.rollout import policy_arch
+    from relayrl_tpu import telemetry
+    from relayrl_tpu.models import build_policy
+    from relayrl_tpu.runtime.anakin import AnakinActorHost
+    from relayrl_tpu.types.model_bundle import ModelBundle
+
+    t0 = time.monotonic()
+    spec = harness.load_cell("gpt2m-policy.rollout")
+    cfg, tr = spec["config"], spec["traffic"]
+    arch = policy_arch(cfg, harness.load_reference(
+        spec["config_name"]).program_kwargs(cfg))
+    policy = build_policy(arch)
+    make = jax.jit(policy.init_params)
+    old, new = (jax.block_until_ready(make(jax.random.PRNGKey(s)))
+                for s in (2**31 + 67, 67))
+    lanes, unroll = int(tr["lanes"]), int(tr["unroll_length"])
+    width = int(tr["window_size"])
+    telemetry.set_registry(telemetry.Registry(run_id="chip-smoke-l"))
+    host = AnakinActorHost(
+        ModelBundle(version=0, arch=arch, params=old), tr["env"],
+        num_envs=lanes, unroll_length=unroll, window_size=width,
+        max_traj_length=int(tr["max_traj_length"]), seed=67,
+        **tr["env_kwargs"])
+    windows, produce = [], host._rollout_fn
+
+    def kept(params, explore, carry):
+        carry, window = produce(params, explore, carry)
+        windows.append(jax.device_get(window))
+        return carry, window
+
+    host._rollout_fn = kept
+    stats = jax.devices()[0].memory_stats
+    try:
+        before = [host.rollout()["dispatch_s"] for _ in range(6)]
+        peak_before = stats()["peak_bytes_in_use"]
+        check(host.maybe_swap(ModelBundle(version=1, arch=arch, params=new)),
+              "L: the swap installed nothing")
+        after = [host.rollout()["dispatch_s"] for _ in range(2)]
+        peak_after = stats()["peak_bytes_in_use"]
+        check(host.maybe_swap(ModelBundle(version=2, arch=arch, params=old)),
+              "L: the swap back installed nothing")
+        back = [host.rollout()["dispatch_s"] for _ in range(2)]
+    finally:
+        host.close()
+    counted = {m["name"]: m.get("value") for m in
+               telemetry.get_registry().snapshot()["metrics"]}
+    reads = counted.__getitem__
+
+    cache_bytes = lanes * sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in
+        jax.tree.leaves(jax.eval_shape(lambda: policy.init_cache(width))))
+    check(reads("relayrl_actor_cache_rebuilds_total") == 2,
+          f"L: {reads('relayrl_actor_cache_rebuilds_total')} rebuilds")
+    check(reads("relayrl_actor_cached_steps_total")
+          == reads("relayrl_actor_env_steps_total") == 10 * lanes * unroll,
+          "L: not every step was a cached step")
+    check(reads("relayrl_actor_cache_bytes") == cache_bytes,
+          f"L: the gauge reads {reads('relayrl_actor_cache_bytes')}, "
+          f"{lanes} x init_cache({width}) is {cache_bytes}")
+    check(on_tpu(host._carry), "L: the scan carry is not on tpu devices")
+
+    evaluate = jax.jit(policy.evaluate)
+    swap_at, back_at, worst, control = 6 * unroll, 8 * unroll, 0.0, np.inf
+    for lane in (0, lanes - 1):
+        obs = np.zeros((1, width, int(cfg["obs_dim"])), np.float32)
+        act = np.zeros((1, width), np.int32)
+        got = {k: np.concatenate([w["aux"][k][lane] for w in windows])
+               for k in ("logp_a", "v")}
+        n = len(got["v"])
+        obs[0, :n] = np.concatenate([w["obs"][lane] for w in windows])
+        act[0, :n] = np.concatenate([w["act"][lane] for w in windows])
+        check(np.all(np.isfinite(got["logp_a"]) & np.isfinite(got["v"])),
+              f"L: lane {lane} emitted a non-finite step")
+
+        def distance(params, rows):
+            logp, _ent, v = (np.asarray(x)[0] for x in
+                             evaluate(params, obs, act))
+            return max(
+                float(np.max(np.abs(got["logp_a"][rows] - logp[rows])))
+                / max(1.0, float(np.ptp(logp[rows]))),
+                float(np.max(np.abs(got["v"][rows] - v[rows])))
+                / max(1.0, float(np.max(np.abs(v[rows])))))
+
+        worst = max(worst, distance(old, slice(0, swap_at)),
+                    distance(new, slice(swap_at, back_at)),
+                    distance(old, slice(back_at, n)))
+        control = min(control, distance(old, slice(swap_at, back_at)),
+                      distance(new, slice(back_at, n)))
+    check(worst <= 0.05, f"L: emitted logp_a / v are {worst:.4f} from "
+          f"policy.evaluate under the installed parameters (limit 0.05)")
+    check(control > 0.05, f"L: the steps after a swap are {control:.4f} "
+          f"from the parameters it replaced (the control has to read over "
+          f"0.05)")
+    say(f"L: ok — {lanes} x {width} cached, {cache_bytes / 1e9:.2f} GB of "
+        f"cache in the carry; dispatches before the swap "
+        f"{[round(1e3 * d, 1) for d in before]} ms, the one that rebuilt "
+        f"{1e3 * after[0]:.1f} ms (its program compiles), the next "
+        f"{1e3 * after[1]:.1f} ms; after the swap back "
+        f"{1e3 * back[0]:.1f} ms (the rebuild alone beside a dispatch) and "
+        f"{1e3 * back[1]:.1f} ms; "
+        f"peak_bytes_in_use {peak_before / 1e9:.3f} GB before the swap "
+        f"(two trees of parameters held), {peak_after / 1e9:.3f} GB after "
+        f"the rebuild; distance from evaluate {worst:.4f}, control "
+        f"{control:.4f}, {time.monotonic() - t0:.0f}s")
+
+
 def main() -> None:
     t_start = time.monotonic()
     import jax

@@ -37,6 +37,7 @@ class JaxBandit(JaxEnv):
         self.n_arms = int(n_arms)
         self.mult = int(mult)
         self.shift = int(shift)
+        self.max_episode_steps = 1
         self.observation_space = Box(0, 1, shape=(self.n_contexts,),
                                      dtype=np.int32)
         self.action_space = Discrete(self.n_arms)

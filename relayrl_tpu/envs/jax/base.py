@@ -53,10 +53,16 @@ class JaxEnv:
     """Base class carrying the space metadata; subclasses implement the
     functional ``reset``/``step`` pair. Instances hold only static
     configuration (horizon, physics constants) — never per-episode state —
-    so one instance serves every lane of a fused rollout."""
+    so one instance serves every lane of a fused rollout.
+
+    ``max_episode_steps`` states the most ``step`` calls an episode can
+    take before it reports ``terminated`` or ``truncated`` (None: no limit
+    known). The fused rollout reads it to decide whether a sequence
+    policy's history can outgrow its window (``runtime/anakin.py``)."""
 
     observation_space: Any
     action_space: Any
+    max_episode_steps: int | None = None
 
     @property
     def obs_dim(self) -> int:

@@ -34,6 +34,7 @@ class JaxCartPole(JaxEnv):
         self.observation_space = Box(-np.inf, np.inf, shape=(4,))
         self.action_space = Discrete(2)
         self.max_steps = int(max_steps or CartPoleEnv.MAX_STEPS)
+        self.max_episode_steps = self.max_steps
 
     def reset(self, key):
         state = jax.random.uniform(key, (4,), jnp.float32, -0.05, 0.05)

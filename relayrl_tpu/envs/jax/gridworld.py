@@ -39,6 +39,7 @@ class JaxGridWorld(JaxEnv):
             raise ValueError("size must be >= 2 (start and goal differ)")
         self.size = int(size)
         self.max_steps = int(max_steps)
+        self.max_episode_steps = self.max_steps
         self.observation_space = Box(0, self.size - 1, shape=(2,),
                                      dtype=np.int32)
         self.action_space = Discrete(4)

@@ -36,6 +36,7 @@ class JaxPendulum(JaxEnv):
         self.observation_space = Box(-high, high)
         self.action_space = Box(-c.MAX_TORQUE, c.MAX_TORQUE, shape=(1,))
         self.max_steps = int(max_steps or c.MAX_STEPS)
+        self.max_episode_steps = self.max_steps
 
     def reset(self, key):
         k_theta, k_vel = jax.random.split(key)

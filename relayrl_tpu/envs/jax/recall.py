@@ -36,6 +36,7 @@ class JaxRecall(JaxEnv):
         if horizon < 2:
             raise ValueError("horizon must be >= 2 (cue step + query step)")
         self.horizon = int(horizon)
+        self.max_episode_steps = self.horizon
         self.n_cues = int(n_cues)
         self.noise = float(noise)
         self.observation_space = Box(-np.inf, np.inf,

@@ -49,6 +49,7 @@ class JaxTokenGen(JaxEnv):
         self.vocab_size = int(vocab_size)
         self.prompt_len = int(prompt_len)
         self.max_new_tokens = int(max_new_tokens)
+        self.max_episode_steps = self.max_new_tokens
         self.context_len = self.prompt_len + self.max_new_tokens
         self.scorer = _resolve_scorer(scorer)
         if (self.scorer is not None

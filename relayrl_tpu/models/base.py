@@ -116,6 +116,13 @@ class Policy:
     # after hot-swaps); ``n_valid`` = the window's count of real rows, which
     # a state without positions (a short convolution's) is taken before.
     prefill_cache: Callable | None = None
+    # Whether every state of the cache is rows at their positions, of which
+    # a step at ``t`` reads rows <= ``t`` alone (attention's (k, v)): a new
+    # sequence then starts at ``t`` = 0 over whatever the cache holds and
+    # needs no zeroed one. False where a layer keeps a state without
+    # positions (a recurrence's, a convolution's last rows). The fused
+    # rollout's scan carry holds a cache only where this is True.
+    cache_by_position: bool = False
     # Sequence policies: ``{(T, head_dim, dtype): backend}`` for every
     # attention shape traced so far (models/layers/attention.resolve fills
     # it at trace time) — which implementation a platform-dependent

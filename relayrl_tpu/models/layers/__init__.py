@@ -22,6 +22,10 @@ transformer.py`` asks the table and nothing else of this package:
   state a cached call continues from;
 * ``ROW_READOUT`` — whether a final layer can run for the readout row alone
   (a recurrent mixer's row needs the whole recurrence before it);
+* ``CACHE_BY_POSITION`` (optional, False where absent) — whether the state
+  is rows at their positions and a cached step at ``t`` reads rows <= ``t``
+  alone, so that a new sequence may start over a used state
+  (``Policy.cache_by_position``);
 * ``KERNELS`` — for each kernel entry the operator calls, ``(arch) ->
   ({name: callable}, {Policy field: record})``: the callable a block finds
   in its ``fns`` under ``name``, behind the policy's record of what it ran

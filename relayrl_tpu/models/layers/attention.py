@@ -49,12 +49,20 @@ from relayrl_tpu.models.layers.block import (
     block_norm,
     block_residual,
 )
-from relayrl_tpu.ops.attention import blockwise_attention, dense_attention
+from relayrl_tpu.ops.attention import (
+    blockwise_attention,
+    cached_attention,
+    dense_attention,
+)
+from relayrl_tpu.ops.cache_rows import write_row
 from relayrl_tpu.ops.scopes import OP_PROJ
 
 # with a dense FFN; a final layer with experts keeps its full-window pass
 # (the core's rule)
 ROW_READOUT = True
+# (k, v) rows at their positions; a step at t masks every row after t, and a
+# windowed layer's ring every row whose position is not yet written
+CACHE_BY_POSITION = True
 # the flash kernels' q and k/v block: few large grid steps, where the
 # blockwise path's ``attention_block`` is a memory knob that wants small ones
 FLASH_BLOCK = 1024
@@ -232,10 +240,11 @@ def _gated(attn, gate):
 
 def _ring_cached(q, k, v, cache, t, window: int, n_valid):
     """A windowed layer's two cached modes -> ``(attn, new_cache)``. The
-    cache is a ring: ``(k, v)`` of ``rows = min(window, W)`` rows, position
-    ``p`` in row ``p % rows`` (keys rotated at their absolute positions,
-    where the layer has RoPE, before they go in). Softmax does not care
-    about the order of its keys, so a row's position is all a step needs.
+    cache is a ring: ``(k, v)`` of ``rows = min(window, W)`` flat rows
+    (``init_cache``), position ``p`` in row ``p % rows`` (keys rotated at
+    their absolute positions, where the layer has RoPE, before they go in).
+    Softmax does not care about the order of its keys, so a row's position
+    is all a step needs.
 
     One decode step (``T == 1``, position ``t``): write row ``t % rows``,
     then attend every row under the positions the ring now holds — row
@@ -248,20 +257,25 @@ def _ring_cached(q, k, v, cache, t, window: int, n_valid):
     k_cache, v_cache = cache
     rows, T = k_cache.shape[1], q.shape[1]
     slot = jnp.arange(rows)
+    k_rows, v_rows = _flat_rows(k, k_cache), _flat_rows(v, v_cache)
     if T == 1:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), t % rows, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), t % rows, axis=1)
-        attn = dense_attention(q, k_cache, v_cache, causal=True, q_offset=t,
-                               window=window,
-                               kv_positions=t - jnp.mod(t - slot, rows))
+        k_cache = write_row(k_cache, k_rows, t % rows)
+        v_cache = write_row(v_cache, v_rows, t % rows)
+        attn = cached_attention(q, k_cache, v_cache, k.shape[2], q_offset=t,
+                                window=window,
+                                kv_positions=t - jnp.mod(t - slot, rows))
         return attn, (k_cache, v_cache)
     attn = dense_attention(q, k, v, causal=True, window=window)
     n = T if n_valid is None else n_valid
     newest = jnp.clip((n - 1) - jnp.mod(n - 1 - slot, rows), 0, T - 1)
-    return attn, (jnp.take(k, newest, axis=1).astype(k_cache.dtype),
-                  jnp.take(v, newest, axis=1).astype(v_cache.dtype))
+    return attn, (jnp.take(k_rows, newest, axis=1),
+                  jnp.take(v_rows, newest, axis=1))
+
+
+def _flat_rows(x, cache):
+    """``x [B, T, Hkv, hd]`` as the cache keeps rows: ``[B, T, Hkv * hd]``
+    of its type."""
+    return x.reshape(*x.shape[:2], -1).astype(cache.dtype)
 
 
 def apply(block, x, cache, t, readout_idx, n_valid):
@@ -342,15 +356,14 @@ def apply(block, x, cache, t, readout_idx, n_valid):
                                        n_valid)
     else:
         k_cache, v_cache = cache
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), t, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), t, axis=1)
+        # a decode step's one row goes where it lies, also under vmap
+        k_cache = write_row(k_cache, _flat_rows(k, k_cache), t)
+        v_cache = write_row(v_cache, _flat_rows(v, v_cache), t)
         # Query j sits at absolute position t+j (T=1 per-step decode;
         # T=W prefill rebuilds the whole prefix in one dispatch) —
-        # exactly dense_attention's offset-causal mask, so the cached
-        # path shares the window path's attention code verbatim.
-        attn = dense_attention(q, k_cache, v_cache, causal=True, q_offset=t)
+        # exactly dense_attention's offset-causal mask, over the cache's
+        # flat rows (a prefill goes through dense_attention itself).
+        attn = cached_attention(q, k_cache, v_cache, n_kv, q_offset=t)
         new_cache = (k_cache, v_cache)
     with jax.named_scope(OP_PROJ):
         attn = attn.reshape(B, T, width)
@@ -364,10 +377,12 @@ def apply(block, x, cache, t, readout_idx, n_valid):
 
 
 def init_cache(cfg, d_model, batch, length, dtype, window):
-    """Zeroed ``(k, v)`` ``[B, length, Hkv, hd]``: grouped-query k/v are
-    cached as they are, and the q heads of a group read the same rows; of a
-    windowed layer, a ring of ``min(window, length)`` rows."""
+    """Zeroed ``(k, v)`` ``[B, length, Hkv * hd]``, a position's heads side
+    by side in one flat row (``ops.attention.cached_attention`` says why):
+    grouped-query k/v are cached as they are, and the q heads of a group
+    read the same rows; of a windowed layer, a ring of ``min(window,
+    length)`` rows."""
     kv = (batch, min(window or length, length),
-          cfg["n_kv_heads"] or cfg["n_heads"],
-          cfg["head_dim"] or d_model // cfg["n_heads"])
+          (cfg["n_kv_heads"] or cfg["n_heads"])
+          * (cfg["head_dim"] or d_model // cfg["n_heads"]))
     return jnp.zeros(kv, dtype), jnp.zeros(kv, dtype)

@@ -192,6 +192,7 @@ def _checkpoint_record(arch):
 KERNELS = (_moe_record, _checkpoint_record)
 # the FFN is per row: a final layer runs for the readout row alone
 ROW_READOUT = True
+CACHE_BY_POSITION = True    # no state at all
 
 
 def apply(block, x, cache, t, readout_idx, n_valid):

@@ -643,9 +643,13 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                 params, obs, act, mask)
             return (*out, stats)
 
+    by_position = all(
+        getattr(layers.OPERATORS[core.layer_parts(i)[0]],
+                "CACHE_BY_POSITION", False) for i in range(core.n_layers))
     return dataclasses.replace(policy, init_cache=init_cache,
                                step_cached=step_cached,
                                prefill_cache=prefill_cache,
+                               cache_by_position=by_position,
                                evaluate_stats=evaluate_stats,
                                own_loss=own_loss, **records)
 

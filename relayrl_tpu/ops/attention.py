@@ -108,6 +108,54 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out if G == 1 else _unfold_groups(out, G)
 
 
+def cached_attention(q: jax.Array, k_rows: jax.Array, v_rows: jax.Array,
+                     kv_heads: int, q_offset: int | jax.Array = 0,
+                     window: int | None = None,
+                     kv_positions: jax.Array | None = None) -> jax.Array:
+    """Causal attention of ``q [B, Tq, H, D]`` over a cache's rows, kept
+    flat: ``k_rows [B, L, Hkv * D]``, ``v_rows [B, L, Hkv * Dv]``, a row's
+    heads side by side in its lanes (a head of 64 lanes as a minor axis of
+    its own pads to the TPU's 128-lane tile and doubles the cache; flat,
+    a row is written where it lies and read as it is). ``q_offset``,
+    ``window`` and ``kv_positions`` as :func:`dense_attention` takes them.
+
+    One query row (a decode step) never splits the lanes into heads: each
+    q head is laid into the lanes of its k/v head, zeros elsewhere, so one
+    matmul over whole rows gives every head's scores, and one more every
+    head's values, of which a head keeps its own lanes — the same products
+    summed in float32 as :func:`dense_attention`'s, beside zeros. More
+    rows (a prefill) split the cache into heads once and go through
+    :func:`dense_attention`."""
+    B, Tq, H, D = q.shape
+    L = k_rows.shape[1]
+    Dv = v_rows.shape[-1] // kv_heads
+    if Tq != 1:
+        return dense_attention(
+            q, k_rows.reshape(B, L, kv_heads, D),
+            v_rows.reshape(B, L, kv_heads, Dv), causal=True,
+            q_offset=q_offset, window=window, kv_positions=kv_positions)
+    if H % kv_heads:
+        raise ValueError(f"{H} query heads do not group over {kv_heads} "
+                         f"k/v heads")
+    owner = jnp.arange(H) // (H // kv_heads)        # a q head's k/v head
+    own_lanes = (jnp.arange(kv_heads * D) // D)[:, None] == owner[None, :]
+    q_lanes = jnp.tile(jnp.swapaxes(q[:, 0], 1, 2), (1, kv_heads, 1))
+    q_lanes = jnp.where(own_lanes[None], q_lanes, 0)
+    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    s = jnp.einsum("bkc,bch->bhk", k_rows, q_lanes,
+                   preferred_element_type=jnp.float32) * scale
+    q_pos = jnp.asarray(q_offset)[None]
+    if kv_positions is None:
+        seen = visible(q_pos, jnp.arange(L), window)
+    else:  # a row at a negative position does not exist yet
+        seen = visible(q_pos, kv_positions, window) & (kv_positions >= 0)
+    p = jax.nn.softmax(jnp.where(seen[None], s, _NEG_INF), axis=-1)
+    rows = jnp.einsum("bhk,bkc->bhc", p.astype(v_rows.dtype), v_rows)
+    own = (jnp.arange(kv_heads)[None, :] == owner[:, None])[None, :, :, None]
+    out = jnp.where(own, rows.reshape(B, H, kv_heads, Dv), 0).sum(axis=2)
+    return out[:, None]
+
+
 def attention_block_combine(carry, q, k_blk, v_blk, mask):
     """One online-softmax accumulation step (the flash-attention recurrence).
 

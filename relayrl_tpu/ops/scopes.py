@@ -78,7 +78,8 @@ GMM_DLHS_NAME = "relayrl_moe_gmm_dlhs"
 GMM_DRHS_NAME = "relayrl_moe_gmm_drhs"
 # absorbs the vjp's name transform round a held pass's experts (models/moe.py)
 HELD_EXPERTS_NAME = "held_experts"
+CACHE_WRITE_ROW = "relayrl_cache_write_row"  # ops/cache_rows.py, the call's name
 
 KERNEL_SCOPES = (SHORT_CONV_NAME, SSD_NAME, MAMBA_CONV_NAME, GDN_NAME,
                  GDN_CONV_NAME, KDA_NAME, KDA_CONV_NAME, FWD_NAME, BWD_NAME, GMM_FWD_NAME,
-                 GMM_DLHS_NAME, GMM_DRHS_NAME)
+                 GMM_DLHS_NAME, GMM_DRHS_NAME, CACHE_WRITE_ROW)

@@ -13,10 +13,11 @@ ONE dispatch of
 
 with per-lane PRNG keys split from one seed, in-scan autoreset
 (``envs.jax.base.step_autoreset`` — lanes never leave the device between
-episodes), and the whole carry (keys + env states + observations) donated
-back to the next window. Amortized per env step, the dispatch cost tends
-to zero as ``unroll_length`` grows. Its rate on the chip is not
-measured yet: the fused tier has no benchmark cell (ROADMAP 2.2).
+episodes), and the whole carry (keys + env states + observations; for a
+sequence policy its observation ring and, where no episode can outgrow the
+ring, its decode cache) donated back to the next window. Amortized per env
+step, the dispatch cost tends to zero as ``unroll_length`` grows. Its rate
+on the chip is the benchmark cell ``gpt2m-policy.rollout``'s (PERF.md).
 
 The host side of the engine is an **unstacker**: one ``device_get`` of
 the stacked window, then a replay of the window into the existing
@@ -37,6 +38,7 @@ step of a window is computed by ONE model version by construction.
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 
@@ -73,8 +75,80 @@ def resolve_jax_env(env, **env_kwargs) -> JaxEnv:
     return make_jax(str(env), **env_kwargs)
 
 
+def carry_holds_cache(policy, env: JaxEnv, window_size: int | None) -> bool:
+    """Whether the fused sequence scan steps from a per-lane decode cache
+    in its carry (``policy.step_cached``) or recomputes each step from the
+    observation ring (``policy.step_window``). Decided from what the host
+    can observe, once, when the program is built:
+
+    * the policy brings ``step_cached``, ``init_cache`` and
+      ``prefill_cache``, and every state of its cache is rows at their
+      positions (``Policy.cache_by_position``: an in-scan reset then needs
+      no zeroed cache);
+    * the environment states a limit on an episode's steps
+      (``JaxEnv.max_episode_steps``) and it is no longer than the window:
+      the ring never rolls, so no cached row's position ever shifts.
+
+    Where an episode can outgrow the window the ring program is the right
+    one and stays (the process tier lives by the same rule one step at a
+    time: cached while not rolled, else the window)."""
+    limit = env.max_episode_steps
+    return (window_size is not None and limit is not None
+            and limit <= window_size and policy.cache_by_position
+            and None not in (policy.step_cached, policy.init_cache,
+                             policy.prefill_cache))
+
+
+def init_lane_caches(policy, lanes: int, window_size: int):
+    """``policy.init_cache(window_size)`` a lane, stacked: the zeroed
+    decode states the cached scan's carry starts from."""
+    one = jax.eval_shape(lambda: policy.init_cache(window_size))
+    return jax.tree.map(
+        lambda x: jnp.zeros((lanes, *x.shape), x.dtype), one)
+
+
+# Lanes a pass of the swap's rebuild: ``prefill_cache`` runs dense
+# attention over the whole window (float32 scores ``[heads, W, W]`` a lane
+# and layer), so the lanes go through in groups and the rebuild needs
+# little beside the cache itself: no more than the step program.
+REBUILD_GROUP = 2
+
+
+def make_cache_rebuild(policy, lanes: int):
+    """``fn(params, carry) -> carry``: every lane's cache made again from
+    its observation ring with ``policy.prefill_cache(params, cache, win,
+    n_valid=wlen)`` — what the first dispatch after a model swap runs, so
+    that a step attends keys and values the NEW parameters computed, as
+    ``step_window`` over the ring would. A program of its own, outside the
+    rollout: :data:`REBUILD_GROUP` lanes a pass of a loop whose carry is
+    the (donated) cache, written back in place."""
+    group = math.gcd(lanes, REBUILD_GROUP)
+
+    def lane_prefill(params, cache, win, wlen):
+        return policy.prefill_cache(params, cache, win, n_valid=wlen)
+
+    def rebuild(params, carry):
+        *head, win, wlen, cache = carry
+
+        def one_group(i, cache):
+            def rows(x):
+                return jax.lax.dynamic_slice_in_dim(x, i * group, group)
+
+            made = jax.vmap(lane_prefill, in_axes=(None, 0, 0, 0))(
+                params, jax.tree.map(rows, cache), rows(win), rows(wlen))
+            return jax.tree.map(
+                lambda x, new: jax.lax.dynamic_update_slice_in_dim(
+                    x, new, i * group, 0), cache, made)
+
+        cache = jax.lax.fori_loop(0, lanes // group, one_group, cache)
+        return (*head, win, wlen, cache)
+
+    donate = (1,) if jax.default_backend() != "cpu" else ()
+    return jax.jit(rebuild, donate_argnums=donate)
+
+
 def make_fused_rollout(policy, env: JaxEnv, unroll_length: int,
-                       sequence: bool = False):
+                       sequence: bool = False, cached: bool = False):
     """Build the one-dispatch window producer:
 
     ``fn(params, explore, carry) -> (carry, window)`` where ``carry`` is
@@ -99,21 +173,39 @@ def make_fused_rollout(policy, env: JaxEnv, unroll_length: int,
     one's tail. Shipped obs follow ``normalize_obs``'s wire-dtype rule
     (uint8 stays uint8, everything else float32) because the vector
     tier normalizes BEFORE windowing, and byte parity rides on it.
-    The window recomputes attention from the ring each step — the
-    KV-cache (``step_cached``) stays off the scan path: a cache carry
-    would be ``[W, n_layers, n_heads, ...]`` per lane and its positions
-    shift on every roll, which re-materializes the whole cache anyway.
+
+    ``cached=True`` (with ``sequence``; the host sets it by
+    :func:`carry_holds_cache`, no option of a user's) makes the one
+    difference in that body: the carry holds, after the ring, each lane's
+    decode cache (``policy.init_cache(W)``), and the policy step is
+    ``policy.step_cached`` for the ONE new row at its position — the
+    lane's count of real rows before the push — in place of
+    ``step_window`` over all ``W``. It is the same action at the same
+    key, with ``logp_a`` and ``v`` equal to ``step_window``'s to float
+    rounding (1e-4 at float32; ``tests/test_kv_cache.py`` holds the pair
+    to it), not to the byte. The cache is NOT zeroed at an in-scan
+    autoreset: a cached step at ``t`` attends rows <= ``t`` alone and the
+    new episode overwrites them in order, and a ``jnp.where`` over the
+    whole cache every step would cost more than the step. The ring stays
+    in the carry: it is what a model swap rebuilds the cache from
+    (:func:`make_cache_rebuild`).
     """
     def lane_rollout(params, explore, carry):
         def seq_body(c, _):
-            pkey, ekey, state, obs, win, wlen = c
+            pkey, ekey, state, obs, win, wlen, *cache = c
             pkey, sub = jax.random.split(pkey)
             wire_obs = (obs if obs.dtype == jnp.uint8
                         else jnp.asarray(obs, jnp.float32))
+            at = wlen   # the new row's position, while the ring never rolls
             win, wlen = window_advance(win, wlen, wire_obs)
-            # step_window takes the post-push count of REAL rows (it
-            # reads out at t-1 itself) — same convention as the hosts.
-            act, aux = policy.step_window(params, sub, win, wlen, None)
+            if cached:
+                act, aux, cache[0] = policy.step_cached(
+                    params, sub, cache[0], jnp.asarray(wire_obs, win.dtype),
+                    at, None)
+            else:
+                # step_window takes the post-push count of REAL rows (it
+                # reads out at t-1 itself) — same convention as the hosts.
+                act, aux = policy.step_window(params, sub, win, wlen, None)
             (ekey, state, next_obs, rew, term, trunc,
              final_obs) = step_autoreset(env, ekey, state, act)
             done = jnp.logical_or(term, trunc)
@@ -121,7 +213,7 @@ def make_fused_rollout(policy, env: JaxEnv, unroll_length: int,
             wlen = jnp.where(done, jnp.int32(0), wlen)
             out = {"obs": wire_obs, "act": act, "rew": rew, "term": term,
                    "trunc": trunc, "final_obs": final_obs, "aux": aux}
-            return (pkey, ekey, state, next_obs, win, wlen), out
+            return (pkey, ekey, state, next_obs, win, wlen, *cache), out
 
         def body(c, _):
             pkey, ekey, state, obs = c
@@ -158,7 +250,11 @@ class AnakinActorHost:
     optionally narrows it below the model context (clamped exactly like
     ``actor.window_size`` on the other tiers), and ``record_bver=True``
     stamps each record's producing model version into the aux plane —
-    the per-token behavior evidence the RLHF score stage reads.
+    the per-token behavior evidence the RLHF score stage reads. Where no
+    episode can outgrow the window (:func:`carry_holds_cache`) the carry
+    also holds each lane's decode cache and a step computes one new row;
+    the first dispatch after a model swap then rebuilds the caches from
+    the rings under the new parameters.
     """
 
     def __init__(
@@ -210,12 +306,13 @@ class AnakinActorHost:
         elif getattr(self.policy, "step_cached", None) is not None:
             raise ValueError(
                 "KV-cache-only policies (step_cached without step_window) "
-                "cannot run in the fused scan — the cache carry's "
-                "positions shift on every window roll, so the scan "
-                "recomputes from the rolling window instead; use "
-                "actor.host_mode=\"process\" for the cached single-lane "
-                "path or the serving plane (InferenceService) for "
-                "stateless clients")
+                "cannot run in the fused scan — its carry holds a cache "
+                "only beside the rolling window: where an episode can "
+                "outgrow the window the scan steps from the window "
+                "(step_window), and a model swap rebuilds the cache from "
+                "it; use actor.host_mode=\"process\" for the cached "
+                "single-lane path or the serving plane (InferenceService) "
+                "for stateless clients")
         self.params = bundle.params
         self.version = bundle.version
         self._explore_kwargs = exploration_kwargs(self.arch)
@@ -225,9 +322,16 @@ class AnakinActorHost:
         # aux at unstack. Opt-in — it widens the wire by one int32
         # column, so plain RL rollouts keep their bytes.
         self.record_bver = bool(record_bver)
+        # The cached scan, where no episode can outgrow the window; its
+        # caches hold what ``_cache_version``'s parameters computed.
+        cached = carry_holds_cache(self.policy, self.env, self._window_size)
         self._rollout_fn = make_fused_rollout(
             self.policy, self.env, self.unroll_length,
-            sequence=self._window_size is not None)
+            sequence=self._window_size is not None, cached=cached)
+        self._rebuild_fn = (make_cache_rebuild(self.policy, self.num_envs)
+                            if cached else None)
+        self._cache_version = self.version
+        self._cache_bytes = 0
 
         # Per-lane key derivation matches VectorActorHost (policy keys
         # split from PRNGKey(seed)); env reset/autoreset keys come from an
@@ -255,6 +359,12 @@ class AnakinActorHost:
                 jnp.float32)
             wlen = jnp.zeros(self.num_envs, jnp.int32)
             self._carry = (pol_keys, carry_keys, states, obs, win, wlen)
+            if cached:
+                caches = init_lane_caches(self.policy, self.num_envs,
+                                          self._window_size)
+                self._cache_bytes = sum(
+                    int(x.nbytes) for x in jax.tree.leaves(caches))
+                self._carry += (caches,)
         else:
             self._carry = (pol_keys, carry_keys, states, obs)
 
@@ -329,6 +439,19 @@ class AnakinActorHost:
         self._m_steps = reg.counter(
             "relayrl_actor_env_steps_total",
             "policy steps served (one per env step per lane)")
+        self._m_cached_steps = reg.counter(
+            "relayrl_actor_cached_steps_total",
+            "fused rollout: env steps served from the scan carry's decode "
+            "cache (one new row computed, not the whole window)")
+        self._m_rebuilds = reg.counter(
+            "relayrl_actor_cache_rebuilds_total",
+            "fused rollout: model swaps after which every lane's decode "
+            "cache was rebuilt from its observation window")
+        reg.gauge(
+            "relayrl_actor_cache_bytes",
+            "fused rollout: bytes of decode cache in the scan carry, all "
+            "lanes (0: the scan steps from the observation window)"
+        ).set(self._cache_bytes)
         self._m_dispatches = reg.counter(
             "relayrl_actor_rollout_dispatches_total",
             "fused rollout dispatches (each serves lanes x unroll steps)")
@@ -381,6 +504,11 @@ class AnakinActorHost:
             # model version (maybe_swap's atomicity across lanes AND
             # unroll steps).
             version = self.version
+            if self._rebuild_fn is not None and self._cache_version != version:
+                # the caches hold what the old parameters computed
+                self._carry = self._rebuild_fn(self.params, self._carry)
+                self._cache_version = version
+                self._m_rebuilds.inc()
             self._carry, window = self._rollout_fn(
                 self.params, self._explore_kwargs, self._carry)
         window = jax.block_until_ready(window)
@@ -416,6 +544,8 @@ class AnakinActorHost:
         t2 = time.monotonic()
         steps = self.num_envs * self.unroll_length
         self._m_steps.inc(steps)
+        if self._rebuild_fn is not None:
+            self._m_cached_steps.inc(steps)
         self._m_dispatches.inc()
         self._m_dispatch_s.observe(t1 - t0)
         if not self.async_emit:

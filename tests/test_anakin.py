@@ -461,6 +461,257 @@ class TestFusedSequenceCrossTierParity:
                     assert a.final_obs.tobytes() == b.final_obs.tobytes()
 
 
+def _recall_bundle(seed=0, version=0, n_cues=4, window=8, obs_dim=None):
+    """A two-layer windowed transformer (float32) for ``JaxRecall(n_cues)``
+    (or any env of ``obs_dim``) at a context of ``window`` rows."""
+    from relayrl_tpu.models import build_policy
+    from relayrl_tpu.types.model_bundle import ModelBundle
+
+    arch = {"kind": "transformer_discrete",
+            "obs_dim": obs_dim or n_cues + 2, "act_dim": n_cues,
+            "d_model": 16, "n_layers": 2, "n_heads": 2,
+            "max_seq_len": window}
+    params = build_policy(arch).init_params(jax.random.PRNGKey(seed))
+    return ModelBundle(version=version, arch=arch, params=params)
+
+
+def _counted(name: str):
+    from relayrl_tpu import telemetry
+
+    return next(m["value"]
+                for m in telemetry.get_registry().snapshot()["metrics"]
+                if m["name"] == name)
+
+
+@pytest.fixture
+def registry():
+    from relayrl_tpu import telemetry
+
+    telemetry.reset_for_tests()
+    telemetry.set_registry(telemetry.Registry(run_id="fused-cached"))
+    yield
+    telemetry.reset_for_tests()
+
+
+class TestFusedCachedScan:
+    """Where no episode can outgrow the window the scan's carry holds
+    each lane's decode cache and a step is ``policy.step_cached`` for the
+    one new row (``runtime/anakin.carry_holds_cache``). Held here, at toy
+    size in float32, against the SAME host built on the window program —
+    ``make_fused_rollout(..., cached=False)``, which the host builds when
+    the rule says no; the tests make it say no — as the process tier's
+    pair is held (``tests/test_kv_cache.py``): the same action at the same
+    key, ``logp_a`` and ``v`` to float rounding, not to the byte."""
+
+    LANES, W, CUES = 3, 8, 4
+
+    def _host(self, monkeypatch, cached: bool, unroll: int, sink=None,
+              columnar=True, bundle=None):
+        from relayrl_tpu.runtime import anakin
+
+        with monkeypatch.context() as m:
+            if not cached:
+                m.setattr(anakin, "carry_holds_cache", lambda *a: False)
+            host = anakin.AnakinActorHost(
+                bundle or _recall_bundle(), "Recall-v0",
+                num_envs=self.LANES, unroll_length=unroll, seed=5,
+                columnar_wire=columnar, on_send=sink,
+                horizon=self.W, n_cues=self.CUES)
+        assert len(host._carry) == (7 if cached else 6)
+        return host
+
+    @staticmethod
+    def _keep_windows(host) -> list:
+        """The stacked window of every dispatch, as the host's own
+        ``rollout`` looks its producer up."""
+        windows, produce = [], host._rollout_fn
+
+        def kept(params, explore, carry):
+            carry, window = produce(params, explore, carry)
+            windows.append(jax.device_get(window))
+            return carry, window
+
+        host._rollout_fn = kept
+        return windows
+
+    @staticmethod
+    def _assert_windows_agree(got, want):
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            for k in ("obs", "act", "rew", "term", "trunc", "final_obs"):
+                np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+            for k in ("logp_a", "v"):
+                assert np.all(np.isfinite(a["aux"][k])), k
+                np.testing.assert_allclose(a["aux"][k], b["aux"][k],
+                                           atol=1e-4, rtol=0, err_msg=k)
+
+    @pytest.mark.parametrize("columnar", [True, False],
+                             ids=["columnar", "records"])
+    def test_cached_steps_equal_the_window_programs(self, tmp_cwd,
+                                                    monkeypatch, registry,
+                                                    columnar):
+        """Two dispatches of 20 steps at a horizon of 8: five in-scan
+        resets a lane, none on a dispatch's edge. Step for step the same
+        actions and episode boundaries, ``logp_a`` / ``v`` within 1e-4,
+        and the same number of payloads of the same sizes on the wire."""
+        sent = {True: [], False: []}
+        windows = {}
+        for cached in (True, False):
+            host = self._host(
+                monkeypatch, cached, unroll=20, columnar=columnar,
+                sink=lambda lane, p, _c=cached: sent[_c].append(
+                    (lane, len(p))))
+            windows[cached] = self._keep_windows(host)
+            host.rollout()
+            host.rollout()
+            assert (host._cache_bytes > 0) == cached
+        self._assert_windows_agree(windows[True], windows[False])
+        assert sum(int(w["term"].sum()) for w in windows[True]) == \
+            self.LANES * 5
+        assert sent[True] == sent[False] and sent[True]
+        steps = 2 * self.LANES * 20
+        assert _counted("relayrl_actor_env_steps_total") == 2 * steps
+        # the window-program host counts none
+        assert _counted("relayrl_actor_cached_steps_total") == steps
+
+    def test_a_reset_reads_nothing_of_the_cache_it_starts_over(
+            self, tmp_cwd, monkeypatch):
+        """The cache is not zeroed at an in-scan reset (decision 2 of the
+        change that brought it): a cached step at ``t`` masks every row
+        after ``t``, and the new episode overwrites them in order. After a
+        dispatch that ends every lane's episode, every key row is made NaN
+        and every value row 1e30 (a masked score is replaced, so a NaN
+        there is never read; a masked value row is multiplied by a weight
+        of exactly 0, which a NaN would survive and a finite row does
+        not — rows a lane has written are finite). The next episodes read
+        what an untouched twin reads."""
+        hosts = [self._host(monkeypatch, True, unroll=self.W)
+                 for _ in range(2)]
+        windows = [self._keep_windows(h) for h in hosts]
+        for host in hosts:
+            host.rollout()
+            assert np.all(np.asarray(host._carry[5]) == 0)  # all reset
+        *rest, cache = hosts[0]._carry
+        hosts[0]._carry = (*rest, tuple(
+            (jax.numpy.full_like(k, np.nan), jax.numpy.full_like(v, 1e30))
+            for k, v in cache))
+        for host in hosts:
+            host.rollout()
+        self._assert_windows_agree(windows[0], windows[1])
+
+    def test_a_swap_rebuilds_the_cache_from_the_ring(self, tmp_cwd,
+                                                     monkeypatch, registry):
+        """``maybe_swap`` mid-episode (5 rows of 8 in every ring): the
+        next window equals the window program's after the same swap — the
+        keys and values of the first five rows are the NEW parameters' —
+        and the rebuild is counted once. A stale bundle rebuilds
+        nothing."""
+        windows = {}
+        for cached in (True, False):
+            host = self._host(monkeypatch, cached, unroll=5)
+            windows[cached] = self._keep_windows(host)
+            host.rollout()
+            assert host.maybe_swap(_recall_bundle(seed=1, version=1))
+            host.rollout()
+            assert not host.maybe_swap(_recall_bundle(seed=2, version=1))
+            host.rollout()
+            assert host._cache_version == (1 if cached else 0)
+        self._assert_windows_agree(windows[True], windows[False])
+        assert _counted("relayrl_actor_cache_rebuilds_total") == 1
+        # the swap changed what is emitted: the check can tell
+        stale = self._host(monkeypatch, True, unroll=5)
+        kept = self._keep_windows(stale)
+        stale.rollout()
+        stale.rollout()
+        assert not np.allclose(kept[1]["aux"]["v"],
+                               windows[True][1]["aux"]["v"], atol=1e-4)
+
+    @staticmethod
+    def _rule_cases():
+        from relayrl_tpu.envs.jax import (JaxCartPole, JaxRecall,
+                                          JaxTokenGen)
+
+        class NoStatedLimit(JaxCartPole):
+            def __init__(self):
+                super().__init__(max_steps=6)
+                self.max_episode_steps = None
+
+        return {
+            "cartpole-outgrows-window": (
+                lambda: JaxCartPole(max_steps=18), 4, 2, False),
+            "no-stated-limit": (NoStatedLimit, 4, 2, False),
+            "recall-fits-window": (
+                lambda: JaxRecall(horizon=8, n_cues=4), 6, 4, True),
+            "tokengen-fits-window": (
+                lambda: JaxTokenGen(vocab_size=4, prompt_len=2,
+                                    max_new_tokens=6), 8, 4, True),
+        }
+
+    @pytest.mark.parametrize("case", [
+        "cartpole-outgrows-window", "no-stated-limit",
+        "recall-fits-window", "tokengen-fits-window"])
+    def test_the_rule_reads_the_episode_limit(self, tmp_cwd, registry, case):
+        """Cached iff the environment states a limit on an episode's steps
+        no longer than the window: the window program keeps the six
+        carry leaves it had and a gauge of 0 bytes."""
+        from relayrl_tpu.runtime.anakin import AnakinActorHost
+
+        make_env, obs_dim, act_dim, cached = self._rule_cases()[case]
+        host = AnakinActorHost(
+            _recall_bundle(n_cues=act_dim, obs_dim=obs_dim), make_env(),
+            num_envs=2, unroll_length=12, seed=1)
+        assert host._window_size == 8
+        assert len(host._carry) == (7 if cached else 6)
+        assert (host._rebuild_fn is not None) == cached
+        assert (_counted("relayrl_actor_cache_bytes") > 0) == cached
+        out = host.rollout()
+        assert _counted("relayrl_actor_cached_steps_total") == (
+            out["steps"] if cached else 0)
+
+    def test_a_state_without_positions_keeps_the_window_program(
+            self, tmp_cwd):
+        """A trunk with a layer whose state has no positions (a
+        convolution's last rows) would carry one episode's state into the
+        next at an in-scan reset: the rule keeps it on the window
+        program, whatever the environment states."""
+        from relayrl_tpu.envs.jax import JaxRecall
+        from relayrl_tpu.models import build_policy
+        from relayrl_tpu.runtime.anakin import carry_holds_cache
+
+        arch = dict(_recall_bundle().arch)
+        env = JaxRecall(horizon=8, n_cues=4)
+        assert carry_holds_cache(build_policy(arch), env, 8)
+        assert not carry_holds_cache(build_policy(arch), env, 7)
+        mixed = build_policy({**arch, "layer_types": ["conv",
+                                                      "full_attention"]})
+        assert mixed.step_cached is not None
+        assert not carry_holds_cache(mixed, env, 8)
+
+    def test_the_carry_holds_lanes_times_init_cache_and_the_same_window(
+            self, tmp_cwd, monkeypatch):
+        """A count, no time: the cache in the carry is ``lanes`` times
+        ``policy.init_cache(W)``, byte for byte of shape, and the window a
+        dispatch hands back has the window program's keys, shapes and
+        dtypes."""
+        hosts = {c: self._host(monkeypatch, c, unroll=4)
+                 for c in (True, False)}
+        one = hosts[True].policy.init_cache(self.W)
+        per_lane = sum(x.nbytes for x in jax.tree.leaves(one))
+        assert hosts[True]._cache_bytes == self.LANES * per_lane > 0
+        assert hosts[False]._cache_bytes == 0
+        assert jax.tree.map(lambda x: (self.LANES, *x.shape), one) == \
+            jax.tree.map(lambda x: x.shape, hosts[True]._carry[6])
+        shapes = {c: jax.eval_shape(h._rollout_fn, h.params,
+                                    h._explore_kwargs, h._carry)[1]
+                  for c, h in hosts.items()}
+        assert jax.tree.structure(shapes[True]) == \
+            jax.tree.structure(shapes[False])
+        assert set(shapes[True]) == {"obs", "act", "rew", "term", "trunc",
+                                     "final_obs", "aux"}
+        assert jax.tree.leaves(shapes[True]) == \
+            jax.tree.leaves(shapes[False])
+
+
 class TestConfigKnobs:
     def test_actor_params_anakin(self, tmp_path):
         from relayrl_tpu.config import ConfigLoader

@@ -50,6 +50,82 @@ class TestDenseAttention:
         np.testing.assert_allclose(out[:, 0], v[:, 0], rtol=1e-5)
 
 
+class TestCachedAttention:
+    """``ops.attention.cached_attention``: a cache's flat rows ``[B, L,
+    Hkv * D]`` read without splitting the lanes into heads (one query row)
+    against :func:`dense_attention` over the same rows split into heads."""
+
+    @staticmethod
+    def _rows(kv_heads, length=12, v_width=D, seed=0):
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((B, length, kv_heads, D)),
+                        jnp.float32)
+        v = jnp.asarray(rng.standard_normal((B, length, kv_heads, v_width)),
+                        jnp.float32)
+        return q, k, v
+
+    @pytest.mark.parametrize("kv_heads", [H, 2, 1])
+    @pytest.mark.parametrize("t", [0, 5, 11])
+    def test_one_row_equals_dense_attention(self, kv_heads, t):
+        from relayrl_tpu.ops.attention import cached_attention
+
+        q, k, v = self._rows(kv_heads)
+        want = dense_attention(q, k, v, causal=True, q_offset=t)
+        got = cached_attention(q, k.reshape(B, 12, -1), v.reshape(B, 12, -1),
+                               kv_heads, q_offset=t)
+        assert got.shape == want.shape == (B, 1, H, D)
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+
+    def test_a_ring_s_positions_and_window(self):
+        from relayrl_tpu.ops.attention import cached_attention
+
+        q, k, v = self._rows(2, length=8)
+        # a ring of 8 rows at step 10 under a window of 5: row s holds the
+        # newest position <= 10 congruent to s
+        pos = 10 - jnp.mod(10 - jnp.arange(8), 8)
+        kw = dict(q_offset=10, window=5, kv_positions=pos)
+        want = dense_attention(q, k, v, causal=True, **kw)
+        got = cached_attention(q, k.reshape(B, 8, -1), v.reshape(B, 8, -1),
+                               2, **kw)
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+        empty = jnp.where(jnp.arange(8) > 2, -1, jnp.arange(8))
+        got = cached_attention(q, k.reshape(B, 8, -1), v.reshape(B, 8, -1),
+                               2, q_offset=2, kv_positions=empty)
+        want = dense_attention(q, k[:, :3], v[:, :3], causal=True,
+                               q_offset=2)
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+
+    def test_values_of_a_width_of_their_own_and_a_prefill(self):
+        from relayrl_tpu.ops.attention import cached_attention
+
+        q, k, v = self._rows(2, v_width=8)
+        got = cached_attention(q, k.reshape(B, 12, -1), v.reshape(B, 12, -1),
+                               2, q_offset=7)
+        np.testing.assert_allclose(
+            got, dense_attention(q, k, v, causal=True, q_offset=7),
+            atol=2e-6, rtol=0)
+        # more rows than one: dense_attention itself, over the split rows
+        rng = np.random.default_rng(3)
+        q4 = jnp.asarray(rng.standard_normal((B, 12, H, D)), jnp.float32)
+        got = cached_attention(q4, k.reshape(B, 12, -1),
+                               v.reshape(B, 12, -1), 2)
+        assert (got == dense_attention(q4, k, v, causal=True)).all()
+
+    def test_masked_rows_are_not_read(self):
+        """Rows after ``t`` may hold anything finite (another episode's
+        tail: the fused rollout does not zero a cache at a reset), and a
+        NaN key there is never read."""
+        from relayrl_tpu.ops.attention import cached_attention
+
+        q, k, v = self._rows(2)
+        k_rows, v_rows = k.reshape(B, 12, -1), v.reshape(B, 12, -1)
+        want = cached_attention(q, k_rows, v_rows, 2, q_offset=4)
+        got = cached_attention(q, k_rows.at[:, 5:].set(jnp.nan),
+                               v_rows.at[:, 5:].set(1e30), 2, q_offset=4)
+        assert (got == want).all()
+
+
 class TestBlockwiseAttention:
     @pytest.mark.parametrize("block", [4, 8, 32])
     def test_matches_dense(self, block):

@@ -794,6 +794,34 @@ def test_the_indexers_kernels_compile_for_v5e(one_chip, tk):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.6 * tq * tk * 4
 
 
+@pytest.mark.parametrize("dtype,lanes,rows,width", [
+    # gpt2m-policy.rollout: 64 lanes' (k, v) rows of 16 heads x 64
+    (jnp.bfloat16, 64, 1024, 1024),
+    (jnp.float32, 4, 64, 256),      # a float32 host: tiles of 8 rows
+])
+def test_the_cache_row_writer_compiles_for_v5e(one_chip, dtype, lanes, rows,
+                                               width):
+    """``ops/cache_rows.write_rows_pallas``: one Mosaic call for every
+    lane's new row, its result aliased to the cache it is given."""
+    from relayrl_tpu.ops import cache_rows
+
+    assert cache_rows.tiles((lanes, 1, rows, width), dtype)
+    cache = jax.ShapeDtypeStruct((lanes, rows, width), dtype,
+                                 sharding=one_chip)
+    new = jax.ShapeDtypeStruct((lanes, 1, width), dtype, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(cache_rows.write_rows_pallas,
+                       donate_argnums=0).lower(cache, new, t).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert scopes.CACHE_WRITE_ROW in text
+    stats = compiled.memory_analysis()
+    # in place: the donated cache is the result, nothing of its size beside
+    assert stats.alias_size_in_bytes >= lanes * rows * width * jnp.dtype(
+        dtype).itemsize
+    assert stats.temp_size_in_bytes < 2**20
+
+
 def test_the_whole_rotary_is_todays_function_bit_for_bit():
     """``apply_rope`` at a share of 1.0 (the default) is the function every
     accepted configuration has run: the same bits as its lines written out

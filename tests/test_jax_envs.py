@@ -468,3 +468,65 @@ class TestRegistry:
     def test_make_jax_forwards_kwargs(self):
         env = make_jax("Recall-v0", horizon=16, n_cues=4)
         assert env.horizon == 16 and env.obs_dim == 6
+
+
+# id -> (constructor arguments, the limit the environment then states)
+EPISODE_LIMITS = {
+    "CartPole-v1": ({"max_steps": 12}, 12),
+    "Pendulum-v1": ({"max_steps": 9}, 9),
+    "Recall-v0": ({"horizon": 8, "n_cues": 4}, 8),
+    "GridWorld-v0": ({"size": 4, "max_steps": 11}, 11),
+    "Bandit-v0": ({}, 1),
+    "TokenGen-v0": ({"vocab_size": 6, "max_new_tokens": 5}, 5),
+}
+
+
+class TestEpisodeLimit:
+    def test_every_registered_env_has_a_case(self):
+        assert set(EPISODE_LIMITS) == set(JAX_ENVS)
+
+    def test_the_base_class_states_no_limit(self):
+        from relayrl_tpu.envs.jax import JaxEnv
+
+        assert JaxEnv.max_episode_steps is None
+
+    @pytest.mark.parametrize("env_id", sorted(EPISODE_LIMITS))
+    def test_no_episode_of_a_random_policy_outruns_the_stated_limit(
+            self, env_id):
+        """``max_episode_steps`` is what the fused rollout sizes a
+        sequence policy's history by (``runtime/anakin.carry_holds_cache``):
+        300 autoreset steps of random actions a lane, four lanes, and no
+        run of steps between two episode ends is longer than the limit."""
+        kwargs, limit = EPISODE_LIMITS[env_id]
+        env = make_jax(env_id, **kwargs)
+        assert env.max_episode_steps == limit
+        space = env.action_space
+
+        def act(key):
+            if hasattr(space, "n"):
+                return jax.random.randint(key, (), 0, space.n)
+            return jax.random.uniform(key, space.shape, jnp.float32,
+                                      space.low, space.high)
+
+        def lane(key):
+            k_reset, k_env, k_act = jax.random.split(key, 3)
+            state, _obs = env.reset(k_reset)
+
+            def body(carry, k):
+                ekey, state = carry
+                ekey, state, _o, _r, term, trunc, _f = step_autoreset(
+                    env, ekey, state, act(k))
+                return (ekey, state), jnp.logical_or(term, trunc)
+
+            _, done = jax.lax.scan(body, (k_env, state),
+                                   jax.random.split(k_act, 300))
+            return done
+
+        done = np.asarray(jax.jit(jax.vmap(lane))(
+            jax.random.split(jax.random.PRNGKey(11), 4)))
+        assert done.any()
+        for row in done:
+            ends = np.flatnonzero(row)
+            runs = np.diff(np.concatenate([[-1], ends]))
+            assert runs.max() <= limit
+            assert len(row) - 1 - ends[-1] < limit  # nor the open tail

@@ -165,17 +165,18 @@ class TestStepCachedNumerics:
         assert [getattr(c, "shape", None) for c in cache] == [
             (3, 2, 32), None, (3, 2, 32), (3, 2, 32)]
         k, v = cache[1]
-        assert k.shape == v.shape == (3, 8, 2, 8)   # 2 k/v heads, not 4
+        # flat rows of 2 k/v heads (not 4) x 8 lanes
+        assert k.shape == v.shape == (3, 8, 2 * 8)
 
     def test_a_windowed_layer_keeps_a_ring_of_window_rows(self):
         policy, _ = _policy_params(**CACHED_ARCHS["smallthinker_trunk"])
         shapes = [[a.shape for a in pair] for pair in policy.init_cache(8, 2)]
         # the global layer: all 8 rows; the windowed ones: 3, of 1 k/v head
         # of 16 (not d_model // n_heads)
-        assert shapes == [[(2, 8, 1, 16)] * 2, [(2, 3, 1, 16)] * 2,
-                          [(2, 3, 1, 16)] * 2]
+        assert shapes == [[(2, 8, 16)] * 2, [(2, 3, 16)] * 2,
+                          [(2, 3, 16)] * 2]
         # a cache shorter than the window is the plain cache
-        assert policy.init_cache(2)[1][0].shape == (1, 2, 1, 16)
+        assert policy.init_cache(2)[1][0].shape == (1, 2, 16)
 
     @pytest.mark.parametrize("t0", [2, 3, 7])
     def test_the_ring_takes_the_real_rows_of_a_prefill_only(self, t0):

@@ -229,7 +229,7 @@ class TestSystemAgainstReference:
                 assert state.shape == (1, 4, 8, 16)
                 assert state.dtype == jnp.float32
             elif kind == "attention":
-                assert c[0].shape == (1, T, 1, 8)
+                assert c[0].shape == (1, T, 1 * 8)  # flat rows: heads x lanes
             else:
                 assert c == ()
         assert policy.init_cache(4 * T)[0][1].shape == (1, 4, 8, 16)

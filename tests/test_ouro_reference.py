@@ -298,7 +298,7 @@ class TestSystemAgainstReference:
         logp_ref, v_ref = reference.forward(params, window[None], cfg)
         cache = policy.init_cache(T)
         assert len(cache) == S * L
-        assert all(k.shape == v.shape == (1, T, 4, 8) for k, v in cache)
+        assert all(k.shape == v.shape == (1, T, 4 * 8) for k, v in cache)
         if prefilled:
             padded = np.zeros_like(window)
             padded[:prefilled] = window[:prefilled]

@@ -228,7 +228,7 @@ class TestSystemAgainstReference:
                 assert state.shape == (1, 4, 8, 8)
                 assert state.dtype == jnp.float32
             else:
-                assert c[0].shape == (1, T, 1, 16)
+                assert c[0].shape == (1, T, 1 * 16)  # flat rows: heads x lanes
         assert policy.init_cache(4 * T)[0][1].shape == (1, 4, 8, 8)
         step = jax.jit(policy.step_cached)      # one program, 32 positions
         for t in range(T):
