@@ -163,7 +163,7 @@ class IMPALA(OnPolicyAlgorithm):
             for key in ("conv_spec", "dense", "scale_obs"):
                 if key in params:
                     self.arch[key] = params[key]
-        apply_arch_overrides(self.arch, params)
+        apply_arch_overrides(self.arch, params, learner=True)
         self.policy = build_policy(self.arch)
 
         init_rng, state_rng = jax.random.split(rng)

@@ -196,7 +196,7 @@ class REINFORCE(OnPolicyAlgorithm):
             # actors inherit it through the arch so learner/actor agree.
             "precision": str(learner.get("precision", "float32")),
         }
-        apply_arch_overrides(self.arch, params)
+        apply_arch_overrides(self.arch, params, learner=True)
         self.policy = build_policy(self.arch)
 
         init_rng, state_rng = jax.random.split(rng)

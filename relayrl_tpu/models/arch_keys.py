@@ -27,10 +27,16 @@ TRUNK_KEYS = ("d_model", "n_layers", "n_heads", "mlp_ratio", "max_seq_len",
 # the final norm's output of a pass the next pass's input; 1: once) and
 # whether the learner's forward keeps of a block application its input and
 # the flash kernel's output alone and makes the rest again in its backward
-# (``block_checkpoint``).
+# (``block_checkpoint``); what multiplies the embedded observation
+# (``embed_multiplier``) and divides the policy logits (``logit_divisor``;
+# the value head is no logit and is not divided), 1 where absent: nothing is
+# traced; and whether ``init_params`` returns the parameters as a tier that
+# only steps the policy holds them (``held_params``: the leaves every use
+# casts to the compute type, at that type — ``base.hold_params``).
 CORE_KEYS = ("layer_types", "moe_dense_layers", "sliding_window",
              "positions", "rope_theta", "rope_layers",
-             "loop_steps", "block_checkpoint")
+             "loop_steps", "block_checkpoint",
+             "embed_multiplier", "logit_divisor", "held_params")
 
 # What every layer shares (``TransformerBlock``'s fields of the same names).
 # With none of them given it is the GPT-2 shaped block: LayerNorm at flax's
@@ -40,6 +46,7 @@ BLOCK_KEYS: Mapping[str, Any] = {
     "norm_eps": None,               # None: flax's 1e-6
     "norm_zero_centred": False,     # RMSNorm weights as offsets from one
     "norm_sandwich": False,         # a 2nd norm on each half's OUTPUT
+    "residual_multiplier": 1.0,     # x + m * half(norm(x)), both halves
     "use_bias": True,
     "ffn": "gelu",                  # | "relu2" | "swiglu" | "reglu"
     "d_ff": None,                   # FFN width; None: mlp_ratio * d_model
@@ -70,6 +77,7 @@ OPERATOR_KEYS: Mapping[str, Mapping[str, Any]] = {
         "qk_norm": False,           # | True (the projection) | "head"
         "rope_share": 1.0,          # the share of a head's lanes RoPE turns
         "attn_gate": False,         # q twice as wide, the 2nd half a gate
+        "attn_scale": None,         # of the scores; None: head_dim ** -0.5
     },
     "conv": {"conv_taps": 3},
     "mamba2": {

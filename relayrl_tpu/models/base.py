@@ -52,17 +52,25 @@ def build_policy(arch: Mapping[str, Any]) -> "Policy":
 ARCH_PASSTHROUGH_KEYS = TRUNK_KEYS + DECLARED
 
 
-def apply_arch_overrides(arch: dict, params: Mapping[str, Any]) -> dict:
+def apply_arch_overrides(arch: dict, params: Mapping[str, Any],
+                         learner: bool = False) -> dict:
     """Copy any present ARCH_PASSTHROUGH_KEYS from hyperparams into arch.
 
-    Algorithms call this once, right before ``build_policy(self.arch)``.
-    Sequence-model keys on a non-sequence kind almost always mean a
-    forgotten ``model_kind`` — warn instead of silently training the
-    default MLP with the overrides ignored.
+    Algorithms call this once, right before ``build_policy(self.arch)``,
+    with ``learner=True``: a key that belongs to a tier that only decodes
+    (``held_params``) is refused there. Sequence-model keys on a
+    non-sequence kind almost always mean a forgotten ``model_kind`` — warn
+    instead of silently training the default MLP with the overrides ignored.
     """
     copied = [k for k in ARCH_PASSTHROUGH_KEYS if k in params]
     for key in copied:
         arch[key] = params[key]
+    if learner and arch.get("held_params"):
+        raise ValueError(
+            "held_params is a decode-only tier's key: init_params would "
+            "hand the learner its matmul weights at the compute type, and "
+            "it would train them as master weights. Build the learner's "
+            "policy without it")
     kind = str(arch.get("kind", ""))
     if copied and (kind.startswith("mlp") or kind.startswith("cnn")):
         import warnings
@@ -116,13 +124,17 @@ class Policy:
     # after hot-swaps); ``n_valid`` = the window's count of real rows, which
     # a state without positions (a short convolution's) is taken before.
     prefill_cache: Callable | None = None
-    # Whether every state of the cache is rows at their positions, of which
-    # a step at ``t`` reads rows <= ``t`` alone (attention's (k, v)): a new
-    # sequence then starts at ``t`` = 0 over whatever the cache holds and
-    # needs no zeroed one. False where a layer keeps a state without
-    # positions (a recurrence's, a convolution's last rows). The fused
-    # rollout's scan carry holds a cache only where this is True.
-    cache_by_position: bool = False
+    # Whether a new sequence may start over a used cache: under
+    # ``step_cached(..., restart=True)`` (and ``prefill_cache`` likewise) a
+    # step at ``t`` = 0 reads nothing an earlier sequence left there, for
+    # every layer's state — rows at their positions, of which a step at
+    # ``t`` reads rows <= ``t`` alone (attention's (k, v)), or a state
+    # without positions that position 0 then reads as zeros (a convolution's
+    # last rows, a recurrence's state: ``layers.CACHE_RESTARTS``). Without
+    # the flag the programs are those of a fresh cache. The fused rollout's
+    # scan carry holds a cache only where this is True: an in-scan episode
+    # end clears nothing.
+    cache_restarts: bool = False
     # Sequence policies: ``{(T, head_dim, dtype): backend}`` for every
     # attention shape traced so far (models/layers/attention.resolve fills
     # it at trace time) — which implementation a platform-dependent
@@ -246,6 +258,64 @@ def validate_policy(policy: Policy, params) -> None:
     act_arr = np.asarray(act)
     if act_arr.ndim > 1:
         raise ValueError(f"policy step returned act of rank {act_arr.ndim} for single obs")
+
+
+def held_dtypes(policy: Policy, params):
+    """``params``' tree of the dtype a tier that only DECODES the policy
+    holds each leaf at: the narrower float type where every use of the leaf
+    in the cached step is a cast to it (a matmul weight under a bfloat16
+    compute type), the leaf's own everywhere else (what is used in float32:
+    norm scales, a recurrence's ``A_log`` / ``dt_bias`` / ``D``, a
+    convolution's taps and bias, the observation embedding, the heads).
+    Read off ``policy.step_cached`` as traced — the program such a tier
+    compiles —, so a step's operands are the same numbers either way. Only
+    the step's own equations are read: a leaf that a call takes whole (or a
+    policy without a cached step) stays as published, which is always
+    right, and a leaf already held is one the step no longer casts."""
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), params)
+    as_is = jax.tree.map(lambda x: np.dtype(x.dtype), shapes)
+    if policy.step_cached is None or policy.init_cache is None:
+        return as_is
+    obs = jnp.zeros((policy.input_dim,), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda p: policy.step_cached(
+        p, jax.random.PRNGKey(0), policy.init_cache(1), obs, 0))(shapes).jaxpr
+    leaves, treedef = jax.tree.flatten(shapes)
+    uses = {id(var): [] for var in jaxpr.invars}    # a Literal has no hash
+    for eqn in jaxpr.eqns:
+        for var in eqn.invars:
+            if id(var) in uses:
+                uses[id(var)].append(
+                    np.dtype(eqn.params["new_dtype"])
+                    if eqn.primitive.name == "convert_element_type" else None)
+    for var in jaxpr.outvars:
+        if id(var) in uses:
+            uses[id(var)].append(None)
+
+    def held(var, leaf):
+        to = set(uses[id(var)])
+        if len(to) != 1 or None in to:
+            return np.dtype(leaf.dtype)
+        to, = to
+        narrower = (jnp.issubdtype(to, jnp.floating)
+                    and jnp.issubdtype(leaf.dtype, jnp.floating)
+                    and to.itemsize < leaf.dtype.itemsize)
+        return to if narrower else np.dtype(leaf.dtype)
+
+    return jax.tree.unflatten(treedef, [
+        held(var, leaf) for var, leaf in zip(jaxpr.invars, leaves)])
+
+
+def hold_params(policy: Policy, params, dtypes=None):
+    """``params`` with each leaf at its :func:`held_dtypes` entry
+    (``dtypes``: that tree, where the caller kept it), cast LEAF BY LEAF: a
+    leaf already there is passed on as it is, and the published float32 tree
+    is never whole on the device beside the held one."""
+    if dtypes is None:
+        dtypes = held_dtypes(policy, params)
+    return jax.tree.map(
+        lambda x, to: x if x.dtype == to else jnp.asarray(x).astype(to),
+        params, dtypes)
 
 
 def mlp_sizes(arch: Mapping[str, Any]) -> tuple[int, ...]:

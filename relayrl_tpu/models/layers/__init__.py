@@ -22,10 +22,15 @@ transformer.py`` asks the table and nothing else of this package:
   state a cached call continues from;
 * ``ROW_READOUT`` — whether a final layer can run for the readout row alone
   (a recurrent mixer's row needs the whole recurrence before it);
-* ``CACHE_BY_POSITION`` (optional, False where absent) — whether the state
-  is rows at their positions and a cached step at ``t`` reads rows <= ``t``
-  alone, so that a new sequence may start over a used state
-  (``Policy.cache_by_position``);
+* ``CACHE_RESTARTS`` (optional, absent: it cannot) — how a new sequence may
+  start over a used state, where the caller says the cache is a used one
+  (``restart``): ``"masked"`` — the state is rows at their positions and a
+  step at ``t`` reads rows <= ``t`` alone (attention's ``(k, v)``), nothing
+  to do — or ``"zeroed"`` — a state without positions (a convolution's last
+  rows, a recurrence's state), which the block then reads as zeros at ``t``
+  = 0 (``TransformerBlock.__call__``: one place, every such operator). What
+  the fused rollout's scan carry needs of every layer
+  (``Policy.cache_restarts``);
 * ``KERNELS`` — for each kernel entry the operator calls, ``(arch) ->
   ({name: callable}, {Policy field: record})``: the callable a block finds
   in its ``fns`` under ``name``, behind the policy's record of what it ran
@@ -60,6 +65,7 @@ LAYER_KINDS = {"full_attention": ("attention", True),
                "sliding_attention": ("attention", True),   # ``sliding_window``
                "conv": ("conv", True),
                "mamba2": ("mamba2", False),
+               "mamba": ("mamba2", True),      # ... and an FFN, its own norm
                "linear_attention": ("gdn", True),
                "kda": ("kda", True),
                "latent_attention": ("latent_attention", True),

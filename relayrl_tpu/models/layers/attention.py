@@ -11,8 +11,12 @@ trunk's ``positions: "rope"``, q and k are rotated at their absolute
 positions (after QK-norm; ``rope_share`` of a head's lanes); ``attn_gate``:
 ``q_proj`` is twice as wide, a head's query lanes then its gate lanes, and
 the attention's output is multiplied by ``sigmoid(gate)`` before the output
-projection. The block's ``window``: query ``t`` sees keys ``t - window < s
-<= t`` (None: every key up to its own).
+projection. ``attn_scale``: the scores are ``attn_scale * q . k`` in place
+of ``q . k / sqrt(head_dim)`` (Granite's ``attention_multiplier``); every
+backend scales by the latter, so q is multiplied by the quotient of the two
+once, in the compute type (Granite's 1/64 at head_dim 64: by 0.125, exact).
+The block's ``window``: query ``t`` sees keys ``t - window < s <= t`` (None:
+every key up to its own).
 
 Four backends, the arch's ``attention``: ``"dense"`` (plain softmax, the
 correctness anchor), ``"blockwise"`` (online softmax over k/v blocks of
@@ -62,7 +66,7 @@ from relayrl_tpu.ops.scopes import OP_PROJ
 ROW_READOUT = True
 # (k, v) rows at their positions; a step at t masks every row after t, and a
 # windowed layer's ring every row whose position is not yet written
-CACHE_BY_POSITION = True
+CACHE_RESTARTS = "masked"
 # the flash kernels' q and k/v block: few large grid steps, where the
 # blockwise path's ``attention_block`` is a memory knob that wants small ones
 FLASH_BLOCK = 1024
@@ -326,6 +330,8 @@ def apply(block, x, cache, t, readout_idx, n_valid):
         elif qk_norm not in (True, False):
             raise ValueError(f"unknown qk_norm {qk_norm!r} "
                              f"(false | true | \"head\")")
+        if cfg["attn_scale"] is not None:
+            q = q * jnp.asarray(cfg["attn_scale"] * head_dim ** 0.5, q.dtype)
         rope = theta is not None
         if rope:
             k = apply_rope(k, 0 if t is None else t, theta, share)

@@ -67,10 +67,14 @@ def block_residual(block, x, h, name: str):
     """A half's residual add, ``x + h`` in ``x``'s dtype — under the arch's
     ``norm_sandwich`` ``x + norm(h)``: a second norm, ``name``, on the
     half's OUTPUT (Ouro's ``input_layernorm_2`` /
-    ``post_attention_layernorm_2``). The caller opens the scope."""
+    ``post_attention_layernorm_2``); under ``residual_multiplier`` ``m``,
+    ``x + m * h`` (Granite's 0.22; 1: no product is traced). The caller
+    opens the scope."""
     h = h.astype(x.dtype)
     if block.norm_sandwich:
         h = block_norm(block, name)(h)
+    if block.residual_multiplier != 1.0:
+        h = block.residual_multiplier * h
     return x + h
 
 
@@ -192,7 +196,7 @@ def _checkpoint_record(arch):
 KERNELS = (_moe_record, _checkpoint_record)
 # the FFN is per row: a final layer runs for the readout row alone
 ROW_READOUT = True
-CACHE_BY_POSITION = True    # no state at all
+CACHE_RESTARTS = "masked"    # no state at all
 
 
 def apply(block, x, cache, t, readout_idx, n_valid):

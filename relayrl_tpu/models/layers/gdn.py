@@ -32,6 +32,7 @@ from relayrl_tpu.ops.gdn import SOLVE_NAME as _GDN_SOLVE
 from relayrl_tpu.ops.scopes import GDN_CONV_NAME, OP_PROJ
 
 ROW_READOUT = False
+CACHE_RESTARTS = "zeroed"   # rows and state have no positions
 # what a layer's checkpoint keeps: the rule's output and, where the rule
 # runs as kernels, the solve's tiles their forward wrote (67 MB a layer) —
 # without those the backward would run the rule's forward a second time

@@ -1,5 +1,7 @@
-"""The operator ``"mamba2"``: the Mamba-2 mixer (Nemotron-H's), ``x +
-out(norm_g(y * silu(z)))`` behind the layer's one norm.
+"""The operator ``"mamba2"``: the Mamba-2 mixer, ``x + out(norm_g(y *
+silu(z)))`` behind the layer's one norm — alone (``layer_types`` entry
+``"mamba2"``: Nemotron-H keeps its FFNs in layers of their own) or with the
+FFN behind a norm of its own after it (``"mamba"``: Granite 4.0-H's layer).
 
 ``[z | xBC | dt] = in(norm(x))``; ``xBC = silu(conv(xBC) + b)``, depthwise
 and causal over ``mamba_conv_taps`` taps; ``(x, B, C) = split(xBC)``; ``dt =
@@ -26,6 +28,7 @@ from relayrl_tpu.ops import ssd as ssd_ops
 from relayrl_tpu.ops.scopes import MAMBA_CONV_NAME, OP_PROJ
 
 ROW_READOUT = False
+CACHE_RESTARTS = "zeroed"   # rows and state have no positions
 # the one activation a layer's checkpoint keeps: the scan's output
 _SSD_OUT = "relayrl_ssd_out"
 

@@ -7,7 +7,10 @@ whose size does not grow with the sequence: ``(the convolution's last ``taps
 - 1`` input rows, the recurrence's state in float32)``. One cached row
 continues from it in one step of the recurrence, O(1) in the position;
 several rows (a prefill) run the chunked form from it and leave the state
-after the ``n_valid`` real ones. The readout row of a window needs the whole
+after the ``n_valid`` real ones. Both are a state without positions
+(``layers.CACHE_RESTARTS = "zeroed"``): where the caller says a new sequence
+may start over a used cache, the block reads them as zeros at position 0 and
+nothing here changes. The readout row of a window needs the whole
 recurrence before it: the core runs a final mixer layer in full and slices.
 
 ``ops.ssd.ssd``, ``ops.gdn.gdn`` and ``ops.conv.conv`` pick their

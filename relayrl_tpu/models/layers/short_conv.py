@@ -7,7 +7,8 @@ by construction and sees ``conv_taps - 1`` rows back.
 Its state is the last ``conv_taps - 1`` rows of ``B * u``, ``[B, conv_taps -
 1, d]``: a cached call continues from them, and a prefill leaves the rows
 before ``n_valid``, the window's count of real rows (the state has no
-positions that later steps could overwrite). The readout row of a window
+positions that later steps could overwrite: ``CACHE_RESTARTS = "zeroed"``).
+The readout row of a window
 needs its own and the ``conv_taps - 1`` rows before it and nothing else."""
 
 from __future__ import annotations
@@ -26,6 +27,7 @@ from relayrl_tpu.ops.scopes import OP_PROJ, SHORT_CONV_NAME
 
 KERNELS = ()
 ROW_READOUT = True
+CACHE_RESTARTS = "zeroed"   # the rows have no positions
 
 
 def _short_conv(bcu, w, state=None):

@@ -86,6 +86,8 @@ def apply(block, x, cache, t, readout_idx, n_valid):
     B, T, _ = x.shape
     cfg, d, cd = block.cfg, block.d_model, block.compute_dtype
     n_heads, n_kv = cfg["n_heads"], cfg["n_kv_heads"] or cfg["n_heads"]
+    if cfg["attn_scale"] is not None:
+        raise ValueError("sparse_attention takes no attn_scale")
     head_dim = cfg["head_dim"] or d // n_heads
     hi, di, topk = (cfg["index_heads"], cfg["index_head_dim"],
                     int(cfg["index_topk"]))

@@ -24,7 +24,11 @@ shaped block (LayerNorm, learned positions, biases, GELU FFN of ``mlp_ratio
 * d_model``). ``positions``: ``"learned"`` (a table added to the embedding),
 ``"rope"`` (the attention layers rotate q and k by ``rope_theta`` — all of
 them, or those ``rope_layers`` names; no table) or ``"none"`` (no positional
-signal at all: recurrent layers order the tokens).
+signal at all: recurrent layers order the tokens). Four scalars, each 1 (or
+absent) unless the arch gives it and then nothing is traced for it:
+``embed_multiplier`` on the embedded observation, ``residual_multiplier`` on
+both halves of every layer, ``attn_scale`` in place of ``1 / sqrt(head_dim)``
+and ``logit_divisor`` under the policy logits (Granite 4.0-H's four).
 
 Sequence ABI: ``evaluate(params, obs[B,T,D], act[B,T], mask[B,T,A]) ->
 (logp[B,T], ent[B,T], v[B,T])`` — same shapes the per-step MLP family
@@ -56,7 +60,7 @@ from relayrl_tpu.models.arch_keys import (
     OPERATOR_KEYS,
     settings,
 )
-from relayrl_tpu.models.base import Policy, register_model
+from relayrl_tpu.models.base import Policy, hold_params, register_model
 from relayrl_tpu.models.layers.block import norm as _norm
 from relayrl_tpu.models.mlp import (
     _MASK_FILL,
@@ -64,7 +68,7 @@ from relayrl_tpu.models.mlp import (
     _categorical_logp,
     _compute_dtype,
 )
-from relayrl_tpu.ops.scopes import EMBED, HEADS, LOOP_PASS
+from relayrl_tpu.ops.scopes import EMBED, HEADS, LOOP_PASS, OP_PROJ
 
 
 class TransformerBlock(nn.Module):
@@ -94,6 +98,7 @@ class TransformerBlock(nn.Module):
     norm_eps: float | None = BLOCK_KEYS["norm_eps"]
     norm_zero_centred: bool = BLOCK_KEYS["norm_zero_centred"]
     norm_sandwich: bool = BLOCK_KEYS["norm_sandwich"]
+    residual_multiplier: float = BLOCK_KEYS["residual_multiplier"]
     use_bias: bool = BLOCK_KEYS["use_bias"]
     ffn: str = BLOCK_KEYS["ffn"]
     d_ff: int | None = BLOCK_KEYS["d_ff"]
@@ -106,28 +111,47 @@ class TransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, cache=None, t=None, readout_idx=None,
-                 n_valid=None):
+                 n_valid=None, restart=False):
         """The operator's ``apply`` (``layers``' interface): full mode ``x
         [B, T, d] -> [B, T, d]``; with ``cache`` (this layer's state) and
         ``t`` (the write index; ``n_valid``: a prefill's count of real rows)
         ``(out, new_cache)``; with ``readout_idx`` the one row ``[B, 1,
         d]``. Param names and creation order are identical in every mode
-        (init always runs the full path), so one param tree serves all."""
-        return layers.OPERATORS[self.op].apply(self, x, cache, t,
-                                               readout_idx, n_valid)
+        (init always runs the full path), so one param tree serves all.
+
+        ``restart``: the caller's cache may be a used one, and a call at
+        ``t`` = 0 starts a new sequence over it. Rows at their positions
+        need nothing for that; a state without positions (the operator's
+        ``CACHE_RESTARTS`` is ``"zeroed"``) is read as zeros there — a
+        select that fuses into the read the step makes anyway, no pass over
+        the state of its own. Without it the program is the one a fresh
+        cache always had."""
+        op = layers.OPERATORS[self.op]
+        if restart and getattr(op, "CACHE_RESTARTS", None) == "zeroed":
+            with jax.named_scope(OP_PROJ):
+                first = jnp.asarray(t) == 0
+                cache = jax.tree.map(
+                    lambda a: jnp.where(first, jnp.zeros((), a.dtype), a),
+                    cache)
+        return op.apply(self, x, cache, t, readout_idx, n_valid)
 
 
 def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
-               start=0, learned_positions: bool = True):
+               start=0, learned_positions: bool = True,
+               multiplier: float = 1.0):
     """Obs embedding + positional table, built in the CALLER's param scope
     (layer names land flat: obs_embed / pos_embed) — the single source of
     truth shared by TransformerCore (full AND cached-decode modes, which
     differ only in the ``start`` position) and the pipeline family's
     _PPEmbed. With rotary positions (``learned_positions=False``) the
-    blocks place the tokens and there is no ``pos_embed`` leaf."""
+    blocks place the tokens and there is no ``pos_embed`` leaf.
+    ``multiplier`` (the arch's ``embed_multiplier``) scales the embedded
+    observation, before any table is added."""
     _, T, _ = obs.shape
     with jax.named_scope(EMBED):
         x = nn.Dense(d_model, dtype=jnp.float32, name="obs_embed")(obs)
+        if multiplier != 1.0:
+            x = multiplier * x
         if not learned_positions:
             return x
         pos = parent.param("pos_embed", nn.initializers.normal(0.02),
@@ -137,15 +161,20 @@ def _embed_obs(parent: nn.Module, obs, d_model: int, max_seq_len: int,
 
 def _readout_heads(x, mask, act_dim: int, d_model: int, has_critic: bool,
                    norm: str = "layer", norm_eps=None,
-                   norm_zero_centred: bool = False, normed: bool = False):
+                   norm_zero_centred: bool = False, normed: bool = False,
+                   logit_divisor: float = 1.0):
     """Final norm (the arch's kind and epsilon, as the blocks') + pi/vf
     heads in the caller's scope (shared with _PPReadout; the vf optimizer
     partition keys off these exact `vf*` names). ``normed``: the rows come
-    from the final norm already (a looped trunk's last pass)."""
+    from the final norm already (a looped trunk's last pass).
+    ``logit_divisor`` divides the policy logits (before a mask fills); the
+    value is no logit and is not divided."""
     with jax.named_scope(HEADS):
         if not normed:
             x = _norm(norm, norm_eps, "ln_final", norm_zero_centred)(x)
         logits = nn.Dense(act_dim, dtype=jnp.float32, name="pi_head")(x)
+        if logit_divisor != 1.0:
+            logits = logits / logit_divisor
         if mask is not None:
             logits = jnp.where(mask > 0, logits, _MASK_FILL)
         if has_critic:
@@ -196,6 +225,9 @@ class TransformerCore(nn.Module):
     # full mode checkpoints each block application (arch_keys.CORE_KEYS).
     loop_steps: int = 1
     block_checkpoint: bool = False
+    # What multiplies the embedded observation and divides the policy logits.
+    embed_multiplier: float = 1.0
+    logit_divisor: float = 1.0
 
     def layer_parts(self, i: int) -> tuple[str, bool]:
         """Layer ``i``'s (operator, whether an FFN follows it)."""
@@ -221,12 +253,13 @@ class TransformerCore(nn.Module):
 
     @nn.compact
     def __call__(self, obs, mask=None, cache=None, t=None, readout_t=None,
-                 n_valid=None):
+                 n_valid=None, restart=False):
         """Full mode: obs ``[B, T, D]`` -> (logits, v). Decode mode
         (``cache`` = tuple of states, one a pass and layer, pass-major, each
         its operator's; ``t`` = position; ``n_valid``: prefill's count of
-        real rows): obs is ``[B, 1, D]``; returns ``((logits, v),
-        new_cache)`` for the single position. Readout mode (``readout_t`` =
+        real rows; ``restart``: the cache may be a used one, which every
+        block then reads at ``t`` = 0 as a new sequence's): obs is ``[B, 1,
+        D]``; returns ``((logits, v), new_cache)`` for the single position. Readout mode (``readout_t`` =
         dynamic row index): obs is a full window ``[B, W, D]`` but only
         position ``readout_t`` is decoded — every layer but the last pass's
         last runs over every row (deeper layers attend all earlier
@@ -331,7 +364,8 @@ class TransformerCore(nn.Module):
         x = _embed_obs(
             self, obs, self.d_model, self.max_seq_len,
             start=t if decode else 0,
-            learned_positions=self.positions == "learned")
+            learned_positions=self.positions == "learned",
+            multiplier=self.embed_multiplier)
         new_cache = []
         if S > 1 and full:
             # ONE body in the program, run S times over the broadcast
@@ -360,7 +394,7 @@ class TransformerCore(nn.Module):
                         if decode:
                             x, layer_cache = block(
                                 x, cache=cache[s * L + i], t=t,
-                                n_valid=n_valid)
+                                n_valid=n_valid, restart=restart)
                             new_cache.append(layer_cache)
                         elif idx is None or (s, i) != (S - 1, L - 1):
                             x = block(x)
@@ -379,7 +413,7 @@ class TransformerCore(nn.Module):
         logits, v = _readout_heads(
             x, mask, self.act_dim, self.d_model, self.has_critic,
             kw["norm"], kw["norm_eps"], kw["norm_zero_centred"],
-            normed=S > 1)
+            normed=S > 1, logit_divisor=self.logit_divisor)
         if idx is not None:
             return logits[:, 0], v[:, 0]
         return ((logits, v), tuple(new_cache)) if decode else (logits, v)
@@ -535,16 +569,26 @@ def _make_core(arch: Mapping[str, Any], moe_experts: int = 0,
         positions=arch.get("positions", "learned"),
         loop_steps=int(arch.get("loop_steps", 1)),
         block_checkpoint=bool(arch.get("block_checkpoint", False)),
+        embed_multiplier=float(arch.get("embed_multiplier", 1.0)),
+        logit_divisor=float(arch.get("logit_divisor", 1.0)),
     )
 
 
 def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
     obs_dim = int(arch["obs_dim"])
+    held = bool(arch.get("held_params", False))
     fns, records = layers.resolve(arch)
     core = _make_core(arch, moe_experts, fns)
 
     def init_params(rng):
-        return core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
+        """The parameters as published, float32 — or, under the arch's
+        ``held_params``, as a tier that only decodes the policy holds them
+        (``base.hold_params``: under ``jit`` each cast fuses into the leaf's
+        own initialiser, so the float32 tree is never whole). Such a policy
+        refuses ``evaluate``, and the learner's build refuses the key
+        (``base.apply_arch_overrides(..., learner=True)``)."""
+        params = core.init(rng, jnp.zeros((1, 1, obs_dim), jnp.float32))
+        return hold_params(policy, params) if held else params
 
     def init_cache(length: int, batch_size: int = 1):
         """Zeroed states for incremental decoding, one a pass and layer
@@ -562,10 +606,12 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         return tuple(state(i) for _ in range(core.loop_steps)
                      for i in range(core.n_layers))
 
-    def step_cached(params, rng, cache, obs, t, mask=None):
+    def step_cached(params, rng, cache, obs, t, mask=None, restart=False):
         """One O(W) decode step: writes position ``t`` into the cache and
         samples the action for it. Numerics match ``step_window`` at the
-        same position (tests/test_kv_cache.py)."""
+        same position (tests/test_kv_cache.py). ``restart`` (static): the
+        cache is one an earlier sequence may have used, and a step at ``t``
+        = 0 reads nothing of it (``Policy.cache_restarts``)."""
         obs = jnp.asarray(obs)
         if obs.ndim == 1:                       # [D] -> [1,1,D]
             obs = obs[None, None]
@@ -579,7 +625,8 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
             elif mask_b.ndim == 2:              # [B,A] -> [B,1,A]
                 mask_b = mask_b[:, None]
         (logits, v), new_cache = core.apply(params, obs, mask_b,
-                                            cache=cache, t=t)
+                                            cache=cache, t=t,
+                                            restart=restart)
         logits_t, v_t = logits[:, 0], v[:, 0]
         act = jax.random.categorical(rng, logits_t, axis=-1)
         aux = {"logp_a": _categorical_logp(logits_t, act), "v": v_t}
@@ -588,7 +635,7 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
             aux = {k: a[0] for k, a in aux.items()}
         return act, aux, new_cache
 
-    def prefill_cache(params, cache, window, n_valid=None):
+    def prefill_cache(params, cache, window, n_valid=None, restart=False):
         """Rebuild the whole cache from a padded window in ONE dispatch
         (post-hot-swap path): runs decode mode with T = W queries at
         t=0. Padding rows write garbage K/V beyond the real prefix, which
@@ -597,12 +644,14 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
         (a convolution's rows, a recurrence's) has nothing to overwrite,
         and a windowed layer's ring would lose live rows to padding ones:
         those are taken from the rows before ``n_valid``, the count of real
-        rows (None: the whole window is real)."""
+        rows (None: the whole window is real). ``restart``: ``cache`` is a
+        used one (the fused tier rebuilds in place), whose state without
+        positions the sequence must not continue from."""
         window = jnp.asarray(window)
         if window.ndim == 2:
             window = window[None]
         _, new_cache = core.apply(params, window, None, cache=cache, t=0,
-                                  n_valid=n_valid)
+                                  n_valid=n_valid, restart=restart)
         return new_cache
 
     policy = _policy_from_apply(
@@ -643,15 +692,29 @@ def _build_core_policy(arch: Mapping[str, Any], moe_experts: int = 0) -> Policy:
                 params, obs, act, mask)
             return (*out, stats)
 
-    by_position = all(
+    restarts = all(
         getattr(layers.OPERATORS[core.layer_parts(i)[0]],
-                "CACHE_BY_POSITION", False) for i in range(core.n_layers))
-    return dataclasses.replace(policy, init_cache=init_cache,
-                               step_cached=step_cached,
-                               prefill_cache=prefill_cache,
-                               cache_by_position=by_position,
-                               evaluate_stats=evaluate_stats,
-                               own_loss=own_loss, **records)
+                "CACHE_RESTARTS", None) for i in range(core.n_layers))
+    # (``init_params`` under ``held_params`` reads the cached step off this
+    # name: the finished policy's)
+    policy = dataclasses.replace(policy, init_cache=init_cache,
+                                 step_cached=step_cached,
+                                 prefill_cache=prefill_cache,
+                                 cache_restarts=restarts,
+                                 evaluate_stats=evaluate_stats,
+                                 own_loss=own_loss, **records)
+    if held:
+        def evaluate(*_args, **_kwargs):
+            raise ValueError(
+                "held_params: this policy's parameters are a decode-only "
+                "tier's (matmul weights at the compute type); evaluate is "
+                "the learner's forward, whose master weights are float32 — "
+                "build the learner's policy without the key")
+
+        policy = dataclasses.replace(
+            policy, evaluate=evaluate,
+            evaluate_stats=evaluate_stats and evaluate)
+    return policy
 
 
 @register_model("transformer_discrete")

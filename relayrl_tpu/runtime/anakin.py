@@ -48,6 +48,7 @@ import numpy as np
 
 from relayrl_tpu.envs.jax.base import JaxEnv, step_autoreset
 from relayrl_tpu.models import build_policy, validate_policy
+from relayrl_tpu.models.base import held_dtypes, hold_params
 from relayrl_tpu.runtime.policy_actor import (
     apply_bundle_swap,
     apply_wire_swap,
@@ -82,19 +83,27 @@ def carry_holds_cache(policy, env: JaxEnv, window_size: int | None) -> bool:
     can observe, once, when the program is built:
 
     * the policy brings ``step_cached``, ``init_cache`` and
-      ``prefill_cache``, and every state of its cache is rows at their
-      positions (``Policy.cache_by_position``: an in-scan reset then needs
-      no zeroed cache);
+      ``prefill_cache``, and a new sequence may start over a used cache
+      (``Policy.cache_restarts``): under ``restart=True``, which the scan
+      passes, every layer's cached step at position 0 reads nothing the
+      last episode left — rows at their positions are
+      masked after ``t`` (attention's ``(k, v)``), a state without positions
+      is read as zeros there (a convolution's last rows, a Mamba-2 / delta
+      rule / lane-decay layer's rows and float32 state) — so an in-scan
+      reset clears nothing;
     * the environment states a limit on an episode's steps
       (``JaxEnv.max_episode_steps``) and it is no longer than the window:
       the ring never rolls, so no cached row's position ever shifts.
 
     Where an episode can outgrow the window the ring program is the right
     one and stays (the process tier lives by the same rule one step at a
-    time: cached while not rolled, else the window)."""
+    time: cached while not rolled, else the window). So does a trunk with a
+    layer whose operator does not declare the restart (an indexer's key
+    rows, a latent layer's compressed rows: by position, and not yet held
+    to it under ``vmap(scan)``)."""
     limit = env.max_episode_steps
     return (window_size is not None and limit is not None
-            and limit <= window_size and policy.cache_by_position
+            and limit <= window_size and policy.cache_restarts
             and None not in (policy.step_cached, policy.init_cache,
                              policy.prefill_cache))
 
@@ -125,7 +134,8 @@ def make_cache_rebuild(policy, lanes: int):
     group = math.gcd(lanes, REBUILD_GROUP)
 
     def lane_prefill(params, cache, win, wlen):
-        return policy.prefill_cache(params, cache, win, n_valid=wlen)
+        return policy.prefill_cache(params, cache, win, n_valid=wlen,
+                                    restart=True)
 
     def rebuild(params, carry):
         *head, win, wlen, cache = carry
@@ -184,11 +194,14 @@ def make_fused_rollout(policy, env: JaxEnv, unroll_length: int,
     key, with ``logp_a`` and ``v`` equal to ``step_window``'s to float
     rounding (1e-4 at float32; ``tests/test_kv_cache.py`` holds the pair
     to it), not to the byte. The cache is NOT zeroed at an in-scan
-    autoreset: a cached step at ``t`` attends rows <= ``t`` alone and the
-    new episode overwrites them in order, and a ``jnp.where`` over the
-    whole cache every step would cost more than the step. The ring stays
-    in the carry: it is what a model swap rebuilds the cache from
-    (:func:`make_cache_rebuild`).
+    autoreset, and no ``jnp.where`` walks it (one over the whole cache
+    every step would cost more than the step): the new episode's first
+    step is at ``t`` = 0, where a cached step attends rows <= ``t`` alone
+    (the episode overwrites the rest in order) and reads a state without
+    positions — a recurrence's, a convolution's last rows — as zeros, a
+    select fused into the read that step makes anyway
+    (``step_cached(..., restart=True)``, ``Policy.cache_restarts``). The ring stays in the carry: it is what a
+    model swap rebuilds the cache from (:func:`make_cache_rebuild`).
     """
     def lane_rollout(params, explore, carry):
         def seq_body(c, _):
@@ -201,7 +214,7 @@ def make_fused_rollout(policy, env: JaxEnv, unroll_length: int,
             if cached:
                 act, aux, cache[0] = policy.step_cached(
                     params, sub, cache[0], jnp.asarray(wire_obs, win.dtype),
-                    at, None)
+                    at, None, restart=True)
             else:
                 # step_window takes the post-push count of REAL rows (it
                 # reads out at t-1 itself) — same convention as the hosts.
@@ -255,6 +268,17 @@ class AnakinActorHost:
     also holds each lane's decode cache and a step computes one new row;
     the first dispatch after a model swap then rebuilds the caches from
     the rings under the new parameters.
+
+    ``params`` are held as the program uses them: where the scan decodes
+    from a cache (``carry_holds_cache``), ``models.base.held_dtypes`` reads
+    off the cached step which leaves every use casts to a narrower float
+    type (a matmul weight under a bfloat16 compute type) and every install —
+    the constructor's, a swap's — casts exactly those, leaf by leaf
+    (:meth:`hold`), so a bfloat16 policy costs 2 B a parameter on the device
+    and no cast a dispatch, and a step's operands are the same numbers. A
+    host whose scan steps from the window holds the tree as published. A
+    swap needs the old and the new tree at once: at a size where two held
+    trees and the caches do not fit, the policy is a frozen one.
     """
 
     def __init__(
@@ -313,25 +337,37 @@ class AnakinActorHost:
                 "it; use actor.host_mode=\"process\" for the cached "
                 "single-lane path or the serving plane (InferenceService) "
                 "for stateless clients")
+        from relayrl_tpu import telemetry
+
+        reg = telemetry.get_registry()
+        self._m_param_bytes = reg.gauge(
+            "relayrl_actor_param_bytes",
+            "fused rollout: bytes of parameters the host holds on the "
+            "device (a matmul weight at the compute type)")
+        # The cached scan, where no episode can outgrow the window; its
+        # caches hold what ``_cache_version``'s parameters computed.
+        cached = carry_holds_cache(self.policy, self.env, self._window_size)
+        # What every install casts: read once, off the cached step — the
+        # program this host then compiles. A host whose scan steps from the
+        # window (or a feed-forward policy's) holds the tree as published.
+        self._held_dtypes = (held_dtypes(self.policy, bundle.params)
+                             if cached else None)
+        self._wire_decoder = None  # one decoder, all lanes (see VectorActorHost)
         self.params = bundle.params
         self.version = bundle.version
         self._explore_kwargs = exploration_kwargs(self.arch)
-        self._wire_decoder = None  # one decoder, all lanes (see VectorActorHost)
         # Per-token behavior evidence for the RLHF plane: stamp each
         # record's producing model version (``bver``) into the window's
         # aux at unstack. Opt-in — it widens the wire by one int32
         # column, so plain RL rollouts keep their bytes.
         self.record_bver = bool(record_bver)
-        # The cached scan, where no episode can outgrow the window; its
-        # caches hold what ``_cache_version``'s parameters computed.
-        cached = carry_holds_cache(self.policy, self.env, self._window_size)
         self._rollout_fn = make_fused_rollout(
             self.policy, self.env, self.unroll_length,
             sequence=self._window_size is not None, cached=cached)
         self._rebuild_fn = (make_cache_rebuild(self.policy, self.num_envs)
                             if cached else None)
         self._cache_version = self.version
-        self._cache_bytes = 0
+        self._cache_bytes = self._cache_rows_bytes = 0
 
         # Per-lane key derivation matches VectorActorHost (policy keys
         # split from PRNGKey(seed)); env reset/autoreset keys come from an
@@ -364,6 +400,15 @@ class AnakinActorHost:
                                           self._window_size)
                 self._cache_bytes = sum(
                     int(x.nbytes) for x in jax.tree.leaves(caches))
+                # rows: the leaves that grow with the window (a (k, v)
+                # pair); state: those that do not (a recurrence's, a
+                # convolution's last rows, a ring shorter than the window)
+                twice = jax.eval_shape(
+                    lambda: self.policy.init_cache(2 * self._window_size))
+                self._cache_rows_bytes = sum(
+                    int(x.nbytes) for x, wide in zip(
+                        jax.tree.leaves(caches), jax.tree.leaves(twice))
+                    if wide.shape != x.shape[1:])
                 self._carry += (caches,)
         else:
             self._carry = (pol_keys, carry_keys, states, obs)
@@ -433,9 +478,6 @@ class AnakinActorHost:
         self._emit_thread: threading.Thread | None = None
         self.start_emitter()
 
-        from relayrl_tpu import telemetry
-
-        reg = telemetry.get_registry()
         self._m_steps = reg.counter(
             "relayrl_actor_env_steps_total",
             "policy steps served (one per env step per lane)")
@@ -447,11 +489,21 @@ class AnakinActorHost:
             "relayrl_actor_cache_rebuilds_total",
             "fused rollout: model swaps after which every lane's decode "
             "cache was rebuilt from its observation window")
-        reg.gauge(
-            "relayrl_actor_cache_bytes",
-            "fused rollout: bytes of decode cache in the scan carry, all "
-            "lanes (0: the scan steps from the observation window)"
-        ).set(self._cache_bytes)
+        for kind, held in (
+                ("rows", self._cache_rows_bytes),
+                ("state", self._cache_bytes - self._cache_rows_bytes)):
+            reg.gauge(
+                "relayrl_actor_cache_bytes",
+                "fused rollout: bytes of decode cache in the scan carry, "
+                "all lanes, by kind — rows at their positions (a (k, v) "
+                "pair) | state without positions (a recurrence's, a "
+                "convolution's last rows); 0 and 0: the scan steps from "
+                "the observation window", labels={"kind": kind}).set(held)
+        self._m_resets = reg.counter(
+            "relayrl_actor_state_resets_total",
+            "fused rollout: lane episode ends inside dispatched windows "
+            "(term | trunc): each one a sequence the cached scan starts "
+            "over a used cache")
         self._m_dispatches = reg.counter(
             "relayrl_actor_rollout_dispatches_total",
             "fused rollout dispatches (each serves lanes x unroll steps)")
@@ -486,6 +538,40 @@ class AnakinActorHost:
                 "rolling observation-window rows per lane in the fused "
                 "sequence scan carry (0 rows = feed-forward policy)"
             ).set(self._window_size)
+
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, tree) -> None:
+        """Every install, the constructor's and a swap's (the shared gates
+        assign under the lock): held as the step uses it. A wire-v2 delta's
+        base is the PUBLISHED tree, which the host no longer has where it
+        cast a leaf: a delivery that came as host arrays (the handshake
+        bundle, a v1 bundle) is kept by reference for the decoder's first
+        seed (``policy_actor._decode_wire_frame``), and dropped there."""
+        self._params = self.hold(tree)
+        self._published = tree if (
+            self._held_dtypes is not None and self._wire_decoder is None
+            and all(isinstance(x, np.ndarray)
+                    for x in jax.tree.leaves(tree))) else None
+        self._m_param_bytes.set(sum(
+            int(x.nbytes) for x in jax.tree.leaves(self._params)))
+
+    def hold(self, tree):
+        """``tree`` as this host holds it, LEAF BY LEAF (``rl:actor.
+        param_cast``): where the scan decodes from a cache, a leaf the
+        cached step casts is put on the device and cast on its own, so the
+        published float32 tree is never whole there beside the held one; a
+        leaf already held passes through. The wire gate calls it BEFORE the
+        lock (``policy_actor._decode_wire_frame``), the setter under it."""
+        with span("rl:actor.param_cast"):
+            if self._held_dtypes is not None:
+                tree = hold_params(self.policy, tree, self._held_dtypes)
+            if jax.default_backend() != "cpu":
+                tree = jax.device_put(tree)     # what is left: small leaves
+            return tree
 
     # -- fused action API --
     def rollout(self) -> dict:
@@ -544,6 +630,8 @@ class AnakinActorHost:
         t2 = time.monotonic()
         steps = self.num_envs * self.unroll_length
         self._m_steps.inc(steps)
+        self._m_resets.inc(int(np.count_nonzero(
+            np.logical_or(host_window["term"], host_window["trunc"]))))
         if self._rebuild_fn is not None:
             self._m_cached_steps.inc(steps)
         self._m_dispatches.inc()

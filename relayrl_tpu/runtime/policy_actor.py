@@ -193,7 +193,17 @@ def _decode_wire_frame(actor, blob: bytes) -> "ModelBundle | None":
     dec = actor._wire_decoder
     if dec is None:
         dec = actor._wire_decoder = modelwire.ModelWireDecoder()
-        dec.seed(actor.version, actor.arch, jax.device_get(actor.params))
+        # the delta base is the tree as PUBLISHED: a host that holds its
+        # parameters in another form (the fused tier: matmul weights at the
+        # compute type) kept the delivery it installed from, where that came
+        # as host arrays; where it did not, the held tree's manifest is not
+        # the publisher's and the first delta asks for a keyframe, once
+        published = getattr(actor, "_published", None)
+        dec.seed(actor.version, actor.arch,
+                 jax.device_get(actor.params) if published is None
+                 else published)
+        if published is not None:
+            actor._published = None
     out = dec.decode(blob)
     if out is None:
         return None
@@ -207,7 +217,11 @@ def _decode_wire_frame(actor, blob: bytes) -> "ModelBundle | None":
     # install directly — same placement semantics as the v1 path, and a
     # device_put dispatch per leaf would cost more than the memcpy.
     params = jax.tree.map(np.array, host_tree)
-    if jax.default_backend() != "cpu":
+    hold = getattr(actor, "hold", None)
+    if hold is not None:
+        # the fused tier: leaf by leaf — put, cast — here, before the gate
+        params = hold(params)
+    elif jax.default_backend() != "cpu":
         params = jax.device_put(params)
     return ModelBundle(version=ver, arch=arch, params=params)
 

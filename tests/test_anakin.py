@@ -475,12 +475,15 @@ def _recall_bundle(seed=0, version=0, n_cues=4, window=8, obs_dim=None):
     return ModelBundle(version=version, arch=arch, params=params)
 
 
-def _counted(name: str):
+def _counted(name: str, **labels):
+    """A metric's value, summed over its label sets that match ``labels``
+    (``relayrl_actor_cache_bytes`` has one a kind)."""
     from relayrl_tpu import telemetry
 
-    return next(m["value"]
-                for m in telemetry.get_registry().snapshot()["metrics"]
-                if m["name"] == name)
+    return sum(m["value"]
+               for m in telemetry.get_registry().snapshot()["metrics"]
+               if m["name"] == name and all(
+                   m["labels"].get(k) == v for k, v in labels.items()))
 
 
 @pytest.fixture
@@ -535,7 +538,7 @@ class TestFusedCachedScan:
         return windows
 
     @staticmethod
-    def _assert_windows_agree(got, want):
+    def _assert_windows_agree(got, want, atol=1e-4):
         assert len(got) == len(want)
         for a, b in zip(got, want):
             for k in ("obs", "act", "rew", "term", "trunc", "final_obs"):
@@ -543,7 +546,7 @@ class TestFusedCachedScan:
             for k in ("logp_a", "v"):
                 assert np.all(np.isfinite(a["aux"][k])), k
                 np.testing.assert_allclose(a["aux"][k], b["aux"][k],
-                                           atol=1e-4, rtol=0, err_msg=k)
+                                           atol=atol, rtol=0, err_msg=k)
 
     @pytest.mark.parametrize("columnar", [True, False],
                              ids=["columnar", "records"])
@@ -668,12 +671,15 @@ class TestFusedCachedScan:
         assert _counted("relayrl_actor_cached_steps_total") == (
             out["steps"] if cached else 0)
 
-    def test_a_state_without_positions_keeps_the_window_program(
-            self, tmp_cwd):
+    def test_a_state_without_positions_rides_the_carry_too(self, tmp_cwd):
         """A trunk with a layer whose state has no positions (a
-        convolution's last rows) would carry one episode's state into the
-        next at an in-scan reset: the rule keeps it on the window
-        program, whatever the environment states."""
+        convolution's last rows) reads that state as zeros at position 0,
+        so a new episode may start over a used cache and the rule takes it
+        (``Policy.cache_restarts``; ``tests/test_granite_rollout.py`` holds
+        such trunks to the window program across resets). A trunk with a
+        layer whose operator does not declare the restart — a latent
+        layer's compressed rows — keeps the window program, whatever the
+        environment states."""
         from relayrl_tpu.envs.jax import JaxRecall
         from relayrl_tpu.models import build_policy
         from relayrl_tpu.runtime.anakin import carry_holds_cache
@@ -684,8 +690,14 @@ class TestFusedCachedScan:
         assert not carry_holds_cache(build_policy(arch), env, 7)
         mixed = build_policy({**arch, "layer_types": ["conv",
                                                       "full_attention"]})
-        assert mixed.step_cached is not None
-        assert not carry_holds_cache(mixed, env, 8)
+        assert mixed.step_cached is not None and mixed.cache_restarts
+        assert carry_holds_cache(mixed, env, 8)
+        assert not carry_holds_cache(mixed, env, 7)
+        latent = build_policy({**arch, "layer_types": ["latent_attention",
+                                                       "full_attention"]})
+        assert latent.step_cached is not None
+        assert not latent.cache_restarts
+        assert not carry_holds_cache(latent, env, 8)
 
     def test_the_carry_holds_lanes_times_init_cache_and_the_same_window(
             self, tmp_cwd, monkeypatch):

@@ -845,13 +845,16 @@ class TestShapeArithmetic:
         with open(os.path.join(REPO, "BENCHMARK.json")) as f:
             bench = json.load(f)
         cell = "joyai-flash-policy.update"
-        assert bench["workloads"][-1] == {
+        # found by name, not by place: later PRs append cells, configurations
+        # and metrics after this one's (PERF.md section 7, since PR 65 (c))
+        entry = {w["name"]: w for w in bench["workloads"]}[cell]
+        assert entry == {
             "name": cell, "config": "joyai-flash-policy",
             "traffic": "impala-seq16k-trace8-batch", "chips": 1,
-            "why": bench["workloads"][-1]["why"]}
-        assert bench["configs"][-1]["reduced"] == [
-            "num_hidden_layers", "n_routed_experts"]
-        assert bench["end_to_end"][0]["workloads"][-1] == cell
+            "why": entry["why"]}
+        config = {c["name"]: c for c in bench["configs"]}[entry["config"]]
+        assert config["reduced"] == ["num_hidden_layers", "n_routed_experts"]
+        assert cell in bench["end_to_end"][0]["workloads"]
         mine = [m["name"] for m in bench["per_layer"]
                 if cell in m.get("workloads", ())]
         kimi = [m["name"] for m in bench["per_layer"]
@@ -859,7 +862,8 @@ class TestShapeArithmetic:
         assert mine == [n for n in kimi if not n.startswith("kda_")] + [
             "latent_rope_ms"]
         assert len(mine) == 31
-        assert bench["per_layer"][-1] == {
+        assert {m["name"]: m for m in bench["per_layer"]}[
+                "latent_rope_ms"] == {
             "name": "latent_rope_ms", "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "trunk",
             "moves": "train_samples_per_s", "workloads": [cell]}

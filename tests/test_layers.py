@@ -60,9 +60,10 @@ def test_the_passthrough_keys_are_the_declarations():
     # no key declared twice, and those the configurations' references ask
     # base for (benchmark/reference/*.py) are all there (58 + kda's five and
     # latent attention's four, PR 55; + moe_latent, PR 57; + latent
-    # attention's q_lora_rank and rope_interleave, PR 62)
+    # attention's q_lora_rank and rope_interleave, PR 62; + Granite's four
+    # scalars and held_params, PR 68)
     assert len(set(base.ARCH_PASSTHROUGH_KEYS)) == len(
-        base.ARCH_PASSTHROUGH_KEYS) == 69
+        base.ARCH_PASSTHROUGH_KEYS) == 74
 
 
 @pytest.mark.parametrize("key", arch_keys.DECLARED)
