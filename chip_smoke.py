@@ -1226,13 +1226,16 @@ def phase_l() -> None:
     layers' keys and values in the scan carry (6.44 GB) — where only the
     chip can say that both programs fit beside each other. Six dispatches
     under one seeded tree, ``maybe_swap`` to another mid-episode, two more,
-    a swap back, two more: the first dispatch after each swap rebuilds every
+    a swap back, two more: the first LAUNCH after each swap rebuilds every
     lane's cache from its ring (``runtime/anakin.make_cache_rebuild``; the
     second rebuild finds the program compiled, so its time is the
-    rebuild's own). Two lanes' emitted ``logp_a``
+    rebuild's own). The host keeps one window in flight, so the first
+    ``rollout()`` after a swap still returns a window of the parameters
+    before it and the new ones' first window is the SECOND after the swap.
+    Two lanes' emitted ``logp_a``
     and ``v`` are held to ``policy.evaluate`` over the lane's observations,
     at the learner's own shape, under the parameters that were installed
-    when the step was dispatched (limit 0.05 of max(1, range): bfloat16
+    when the step was LAUNCHED (limit 0.05 of max(1, range): bfloat16
     rounding reads under 0.02, PERF.md section 6), and the steps after the
     swap ALSO against the old parameters, which has to read far over the
     limit — a cache left as the old parameters wrote it would pass the one
@@ -1269,14 +1272,15 @@ def phase_l() -> None:
         num_envs=lanes, unroll_length=unroll, window_size=width,
         max_traj_length=int(tr["max_traj_length"]), seed=67,
         **tr["env_kwargs"])
-    windows, produce = [], host._rollout_fn
+    # every window as the host's emit is handed it, in the order emitted:
+    # the constructor's first, never the one a last call leaves in flight
+    windows, emit = [], host._emit_columnar
 
-    def kept(params, explore, carry):
-        carry, window = produce(params, explore, carry)
-        windows.append(jax.device_get(window))
-        return carry, window
+    def kept(window):
+        windows.append(window)
+        return emit(window)
 
-    host._rollout_fn = kept
+    host._emit_columnar = kept
     stats = jax.devices()[0].memory_stats
     try:
         before = [host.rollout()["dispatch_s"] for _ in range(6)]
@@ -1290,8 +1294,10 @@ def phase_l() -> None:
         back = [host.rollout()["dispatch_s"] for _ in range(2)]
     finally:
         host.close()
-    counted = {m["name"]: m.get("value") for m in
-               telemetry.get_registry().snapshot()["metrics"]}
+    counted: dict = {}     # summed over a name's label sets (the cache
+    for m in telemetry.get_registry().snapshot()["metrics"]:   # gauge's kinds)
+        if "value" in m:
+            counted[m["name"]] = counted.get(m["name"], 0) + m["value"]
     reads = counted.__getitem__
 
     cache_bytes = lanes * sum(
@@ -1307,8 +1313,12 @@ def phase_l() -> None:
           f"{lanes} x init_cache({width}) is {cache_bytes}")
     check(on_tpu(host._carry), "L: the scan carry is not on tpu devices")
 
+    check(reads("relayrl_actor_windows_in_flight") == 1,
+          "L: close() did not leave the one window in flight where it was")
     evaluate = jax.jit(policy.evaluate)
-    swap_at, back_at, worst, control = 6 * unroll, 8 * unroll, 0.0, np.inf
+    # one window is in flight ahead of the host: a swap after call k first
+    # shows in the window call k+1 LAUNCHES, which call k+2 returns
+    swap_at, back_at, worst, control = 7 * unroll, 9 * unroll, 0.0, np.inf
     for lane in (0, lanes - 1):
         obs = np.zeros((1, width, int(cfg["obs_dim"])), np.float32)
         act = np.zeros((1, width), np.int32)
@@ -1341,7 +1351,9 @@ def phase_l() -> None:
           f"0.05)")
     say(f"L: ok — {lanes} x {width} cached, {cache_bytes / 1e9:.2f} GB of "
         f"cache in the carry; dispatches before the swap "
-        f"{[round(1e3 * d, 1) for d in before]} ms, the one that rebuilt "
+        f"{[round(1e3 * d, 1) for d in before]} ms (host clock, a call's "
+        f"launch until the window it returns is ready: what is LEFT of a "
+        f"window's device time), the one that launched the rebuild "
         f"{1e3 * after[0]:.1f} ms (its program compiles), the next "
         f"{1e3 * after[1]:.1f} ms; after the swap back "
         f"{1e3 * back[0]:.1f} ms (the rebuild alone beside a dispatch) and "

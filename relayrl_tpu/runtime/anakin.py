@@ -34,6 +34,16 @@ Model hot-swap shares the exact gates of the other two actor hosts
 (``apply_bundle_swap`` / ``apply_wire_swap`` — same attribute contract),
 and the fused step reads ``params`` once per window under the lock: every
 step of a window is computed by ONE model version by construction.
+
+The host keeps ONE WINDOW IN FLIGHT ahead of itself: the constructor
+launches the first, and :meth:`AnakinActorHost.rollout` launches window
+k+1 before it fetches and emits window k, so the device computes the next
+window while the host reads the last. A window carries the version and the
+production stamp its LAUNCH read, so a swap takes effect one window later
+than its call: lag the records state (``bver``, ``born_ns``), never a wrong
+stamp. No window launched is ever dropped: it stays in flight, through
+``close()`` and through a wait that raised, until a ``rollout()`` returns
+it.
 """
 
 from __future__ import annotations
@@ -41,6 +51,8 @@ from __future__ import annotations
 import math
 import threading
 import time
+from collections import deque
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +75,16 @@ from relayrl_tpu.types.columnar import (
 )
 from relayrl_tpu.types.model_bundle import ModelBundle, exploration_kwargs
 from relayrl_tpu.types.trajectory import Trajectory
+
+
+class _Launched(NamedTuple):
+    """A window the device holds or is computing, not yet fetched, with
+    what its launch read under the lock (``born_ns`` None: the
+    constructor's, stamped by the ``rollout()`` that takes it)."""
+
+    window: dict
+    version: int
+    born_ns: int | None
 
 
 def resolve_jax_env(env, **env_kwargs) -> JaxEnv:
@@ -279,6 +301,18 @@ class AnakinActorHost:
     host whose scan steps from the window holds the tree as published. A
     swap needs the old and the new tree at once: at a size where two held
     trees and the caches do not fit, the policy is a frozen one.
+
+    One window is always in flight ahead of the host (the constructor
+    launches the first): :meth:`rollout` queues window k+1 behind window k
+    on the device and only then fetches and emits window k. A stated
+    property follows: a model swap takes effect at the next LAUNCH, which is
+    one window later than the swap's call — the window already in flight was
+    computed by the parameters it was launched with, and is stamped with
+    their version (``bver``) and its launch's ``born_ns`` (the constructor's
+    window with the first ``rollout()``'s: a host may be built long before
+    it rolls). The window in flight outlives :meth:`close`: the first
+    ``rollout()`` of a host enabled again returns it, so every lane's
+    stream runs on without a gap. :meth:`rollout` is one thread's to call.
     """
 
     def __init__(
@@ -485,6 +519,17 @@ class AnakinActorHost:
             "relayrl_actor_cached_steps_total",
             "fused rollout: env steps served from the scan carry's decode "
             "cache (one new row computed, not the whole window)")
+        self._m_in_flight = reg.gauge(
+            "relayrl_actor_windows_in_flight",
+            "fused rollout: windows launched and not yet fetched (2 while "
+            "rollout() waits for the older, 1 when it returns and through "
+            "close())")
+        self._m_launch_gap_s = reg.histogram(
+            "relayrl_actor_rollout_launch_gap_seconds",
+            "fused rollout: a rollout() that found the window in flight "
+            "already finished (the device had nothing queued), its host "
+            "time until the next launch returned — a lower bound of the "
+            "device's idle time; 0 when the window was still running")
         self._m_rebuilds = reg.counter(
             "relayrl_actor_cache_rebuilds_total",
             "fused rollout: model swaps after which every lane's decode "
@@ -509,7 +554,9 @@ class AnakinActorHost:
             "fused rollout dispatches (each serves lanes x unroll steps)")
         self._m_dispatch_s = reg.histogram(
             "relayrl_actor_rollout_dispatch_seconds",
-            "fused rollout: device compute per [lanes, unroll] window")
+            "fused rollout: a rollout() from its launch of the next window "
+            "until the window it returns is ready (what is left of a "
+            "window's device time once the host's work ran beside it)")
         self._m_unstack_s = reg.histogram(
             "relayrl_actor_rollout_unstack_seconds",
             "fused rollout: host unstack of one window into trajectories")
@@ -538,6 +585,10 @@ class AnakinActorHost:
                 "rolling observation-window rows per lane in the fused "
                 "sequence scan carry (0 rows = feed-forward policy)"
             ).set(self._window_size)
+        # The pipe: windows launched and not yet fetched, oldest first;
+        # the rolling thread's alone after the first, launched here.
+        self._in_flight: deque[_Launched] = deque()
+        self._launch(stamp=False)
 
     @property
     def params(self):
@@ -574,21 +625,18 @@ class AnakinActorHost:
             return tree
 
     # -- fused action API --
-    def rollout(self) -> dict:
-        """ONE device dispatch producing ``lanes × unroll`` env steps,
-        then the host unstack into the per-lane trajectory streams.
-
-        Returns ``{"steps", "episodes", "dispatch_s", "unstack_s"}`` for
-        the calling driver's accounting; completed episode returns
-        accumulate on :attr:`episode_returns` per lane.
-        """
-        t0 = time.monotonic()
-        born_ns = time.monotonic_ns()
+    def _launch(self, stamp: bool = True) -> None:
+        """Queue the next window on the device, behind whatever it is
+        running: the dispatch returns futures at once, and the window's
+        input is the carry the last launch returned. Where a swap landed
+        since the last launch, the caches are rebuilt first, in the same
+        order on the device."""
+        born_ns = time.monotonic_ns() if stamp else None
         with self._lock:
             # ONE params/explore read under the lock for the whole
             # window: every step of this window is computed by a single
             # model version (maybe_swap's atomicity across lanes AND
-            # unroll steps).
+            # unroll steps), and that version rides with the window.
             version = self.version
             if self._rebuild_fn is not None and self._cache_version != version:
                 # the caches hold what the old parameters computed
@@ -597,16 +645,52 @@ class AnakinActorHost:
                 self._m_rebuilds.inc()
             self._carry, window = self._rollout_fn(
                 self.params, self._explore_kwargs, self._carry)
-        window = jax.block_until_ready(window)
+            self._in_flight.append(_Launched(window, version, born_ns))
+        self._m_in_flight.set(len(self._in_flight))
+
+    def rollout(self) -> dict:
+        """ONE finished window of ``lanes × unroll`` env steps a call,
+        unstacked into the per-lane trajectory streams — after the NEXT
+        window's launch: the device runs window k+1 while the host waits
+        for, fetches and emits window k.
+
+        The window returned was launched by the call before (the first by
+        the constructor) and carries what that launch read: its model
+        version (the ``bver`` fill) and its ``born_ns``. A swap between two
+        calls therefore first shows in the window the second call LAUNCHES,
+        which the call after returns. A wait that raises leaves both windows
+        in flight: the next call launches none and returns the same one.
+
+        Returns ``{"steps", "episodes", "dispatch_s", "unstack_s"}`` for
+        the calling driver's accounting (``dispatch_s``: the host's clock
+        from this call's launch until the window it returns is ready — the
+        same span of this function as ever, now what is LEFT of a window
+        once the host's own work ran beside it); completed episode returns
+        accumulate on :attr:`episode_returns` per lane.
+        """
+        t0 = time.monotonic()
+        launched = self._in_flight[0]
+        born_ns = (time.monotonic_ns() if launched.born_ns is None
+                   else launched.born_ns)
+        if len(self._in_flight) < 2:    # two where the last wait raised
+            # one computation's outputs are ready together: ask one
+            ran_dry = jax.tree.leaves(launched.window)[0].is_ready()
+            self._launch()
+            self._m_launch_gap_s.observe(
+                time.monotonic() - t0 if ran_dry else 0.0)
+        window = jax.block_until_ready(launched.window)
         t1 = time.monotonic()
         host_window = jax.device_get(window)
+        self._in_flight.popleft()
+        self._m_in_flight.set(len(self._in_flight))
         if self.record_bver:
             # The whole window is one model version by construction
             # (params read once under the lock), so the stamp is a fill.
             host_window = dict(host_window)
             host_window["aux"] = dict(host_window["aux"])
             host_window["aux"]["bver"] = np.full(
-                (self.num_envs, self.unroll_length), version, np.int32)
+                (self.num_envs, self.unroll_length), launched.version,
+                np.int32)
         if self.async_emit:
             if self._emit_error is not None:
                 err, self._emit_error = self._emit_error, None
@@ -689,8 +773,9 @@ class AnakinActorHost:
     def flush_emits(self, timeout_s: float = 30.0) -> bool:
         """Drain the async emitter's hand-off queue (no-op when
         ``async_emit`` is off): drivers call this before reading
-        ``episode_returns`` or tearing down, so every dispatched window
-        has reached the wire. True when fully drained in time. A
+        ``episode_returns`` or tearing down, so every window ``rollout``
+        returned has reached the wire (the one in flight is no caller's
+        yet). True when fully drained in time. A
         pending emit failure re-raises HERE too, not only on the next
         rollout — otherwise an error on the FINAL window (no next
         rollout coming) would silently lose it at teardown, where the
@@ -714,7 +799,9 @@ class AnakinActorHost:
 
     def close(self, timeout_s: float = 30.0) -> None:
         """Stop the emitter thread after draining its queue (hosts
-        without ``async_emit`` have nothing to do)."""
+        without ``async_emit`` have nothing to do). The window in flight
+        stays where it is: it is the next ``rollout()``'s, should the host
+        be rolled again (the agent's disable/enable cycle)."""
         if self._emit_thread is None:
             return
         self.flush_emits(timeout_s)
@@ -933,9 +1020,11 @@ class AnakinActorHost:
 
     # -- model hot-swap (one gate, all lanes, whole windows) --
     def maybe_swap(self, bundle: ModelBundle) -> bool:
-        """Install a newer model for every lane atomically; a window in
-        flight finishes on the old version, the next reads the new one
-        (shared gate with PolicyActor/VectorActorHost)."""
+        """Install a newer model for every lane atomically; the window in
+        flight finishes on the version it was launched with, the next
+        LAUNCH reads the new one — so the next ``rollout()`` still returns
+        a window of the old version, stamped as such (shared gate with
+        PolicyActor/VectorActorHost)."""
         return apply_bundle_swap(self, bundle)
 
     def swap_from_bytes(self, buf: bytes) -> bool:

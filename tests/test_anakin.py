@@ -525,8 +525,10 @@ class TestFusedCachedScan:
 
     @staticmethod
     def _keep_windows(host) -> list:
-        """The stacked window of every dispatch, as the host's own
-        ``rollout`` looks its producer up."""
+        """The stacked window of every LAUNCH from here on, as the host
+        looks its producer up: the constructor's window is in flight
+        already and is not among them, and the last one kept is the one a
+        last ``rollout()`` left in flight."""
         windows, produce = [], host._rollout_fn
 
         def kept(params, explore, carry):
@@ -593,6 +595,7 @@ class TestFusedCachedScan:
         windows = [self._keep_windows(h) for h in hosts]
         for host in hosts:
             host.rollout()
+            # the carry the window in flight returns: its episodes ended too
             assert np.all(np.asarray(host._carry[5]) == 0)  # all reset
         *rest, cache = hosts[0]._carry
         hosts[0]._carry = (*rest, tuple(
@@ -604,30 +607,32 @@ class TestFusedCachedScan:
 
     def test_a_swap_rebuilds_the_cache_from_the_ring(self, tmp_cwd,
                                                      monkeypatch, registry):
-        """``maybe_swap`` mid-episode (5 rows of 8 in every ring): the
-        next window equals the window program's after the same swap — the
-        keys and values of the first five rows are the NEW parameters' —
-        and the rebuild is counted once. A stale bundle rebuilds
-        nothing."""
+        """``maybe_swap`` mid-episode, with the constructor's window in
+        flight (5 rows of 8 in every ring, the carry that window returns):
+        the next window LAUNCHED equals the window program's after the same
+        swap — the keys and values of the first five rows are the NEW
+        parameters' — and the rebuild is counted once. A stale bundle
+        rebuilds nothing."""
         windows = {}
         for cached in (True, False):
             host = self._host(monkeypatch, cached, unroll=5)
             windows[cached] = self._keep_windows(host)
-            host.rollout()
+            assert np.all(np.asarray(host._carry[5]) == 5)
             assert host.maybe_swap(_recall_bundle(seed=1, version=1))
-            host.rollout()
+            assert host._cache_version == 0     # no launch since the swap
+            host.rollout()      # launches the first window of version 1
+            assert host._cache_version == (1 if cached else 0)
             assert not host.maybe_swap(_recall_bundle(seed=2, version=1))
             host.rollout()
-            assert host._cache_version == (1 if cached else 0)
+            host.rollout()
         self._assert_windows_agree(windows[True], windows[False])
         assert _counted("relayrl_actor_cache_rebuilds_total") == 1
         # the swap changed what is emitted: the check can tell
         stale = self._host(monkeypatch, True, unroll=5)
         kept = self._keep_windows(stale)
         stale.rollout()
-        stale.rollout()
-        assert not np.allclose(kept[1]["aux"]["v"],
-                               windows[True][1]["aux"]["v"], atol=1e-4)
+        assert not np.allclose(kept[0]["aux"]["v"],
+                               windows[True][0]["aux"]["v"], atol=1e-4)
 
     @staticmethod
     def _rule_cases():
@@ -722,6 +727,329 @@ class TestFusedCachedScan:
                                      "final_obs", "aux"}
         assert jax.tree.leaves(shapes[True]) == \
             jax.tree.leaves(shapes[False])
+
+
+class TestWindowInFlight:
+    """The host keeps one window in flight ahead of itself
+    (``AnakinActorHost.rollout``): window k+1 is launched before window k
+    is waited for, fetched and emitted. Held here by the ORDER of the
+    host's own calls, by what a window carries from its launch across a
+    swap, by the account of launched steps at ``close()``, and by where
+    the cache rebuild runs — no time is read."""
+
+    STEPS = 2 * 4   # lanes x unroll of every host below
+
+    @staticmethod
+    def _host(kind: str, monkeypatch, **kw):
+        from relayrl_tpu.runtime import anakin
+
+        if kind == "mlp":
+            return anakin.AnakinActorHost(
+                kw.pop("bundle", None) or _bundle(), "CartPole-v1",
+                num_envs=2, unroll_length=4, seed=3, **kw)
+        with monkeypatch.context() as m:
+            if kind == "window":
+                m.setattr(anakin, "carry_holds_cache", lambda *a: False)
+            host = anakin.AnakinActorHost(
+                kw.pop("bundle", None) or _recall_bundle(), "Recall-v0",
+                num_envs=2, unroll_length=4, seed=3, horizon=8, n_cues=4,
+                **kw)
+        assert (host._rebuild_fn is not None) == (kind == "cached")
+        return host
+
+    @staticmethod
+    def _log_launches(host, events: list | None = None) -> list:
+        """What each launch from here on was passed and handed back; with
+        ``events``, ``("launch", id of the window)`` into it a launch."""
+        launches, produce = [], host._rollout_fn
+        events = [] if events is None else events
+
+        def logged(params, explore, carry):
+            new, window = produce(params, explore, carry)
+            launches.append({"params": params, "carry_in": carry,
+                             "carry_out": new, "window": window,
+                             "done_ns": time.monotonic_ns()})
+            events.append(("launch", id(window)))
+            return new, window
+
+        host._rollout_fn = logged
+        return launches
+
+    @staticmethod
+    def _keep_emitted(host, monkeypatch) -> list:
+        """Every window the host emits, as its emit was handed it, with
+        the production stamp the host held then."""
+        emitted = []
+        for name in ("_emit_columnar", "_unstack"):
+            emit = getattr(host, name)
+
+            def kept(w, _emit=emit):
+                emitted.append({"window": w, "born_ns": host._window_born_ns})
+                return _emit(w)
+
+            monkeypatch.setattr(host, name, kept)
+        return emitted
+
+    @pytest.mark.parametrize("kind", ["mlp", "window", "cached"])
+    def test_the_next_window_is_launched_before_the_last_is_fetched(
+            self, tmp_cwd, monkeypatch, kind):
+        """The constructor launches window 0. Call k of ``rollout()``
+        launches window k+1, THEN waits for window k and fetches it: the
+        window fetched is never the newest launched. One path over the
+        feed-forward step, the window program and the cached one."""
+        host = self._host(kind, monkeypatch)
+        first = host._in_flight[0].window
+        assert len(host._in_flight) == 1
+        events: list = []
+        launches = self._log_launches(host, events)
+        for kind, name in (("wait", "block_until_ready"),
+                           ("get", "device_get")):
+            def logged(x, _kind=kind, _fn=getattr(jax, name)):
+                events.append((_kind, id(x)))
+                return _fn(x)
+
+            monkeypatch.setattr(jax, name, logged)
+        for _ in range(3):
+            host.rollout()
+            assert len(host._in_flight) == 1
+        monkeypatch.undo()
+        launched = [first] + [rec["window"] for rec in launches]
+        assert len(launched) == 4
+        want = []
+        for k in range(3):
+            want += [("launch", id(launched[k + 1])),
+                     ("wait", id(launched[k])), ("get", id(launched[k]))]
+        assert events == want
+        assert host._in_flight[0].window is launched[3]
+        # every launch consumed the carry the launch before it returned
+        for before, after in zip(launches, launches[1:]):
+            assert after["carry_in"] is before["carry_out"]
+        host.close()
+
+    @pytest.mark.parametrize("wire", ["columnar", "records", "async"])
+    def test_a_window_carries_the_version_and_stamp_of_its_launch(
+            self, tmp_cwd, monkeypatch, wire):
+        """``swap_from_wire`` between two ``rollout()`` calls: the call
+        after the swap still returns a window of the OLD version, stamped
+        as such (it was in flight when the swap landed); the new version
+        first shows one window later, in the window that call launched.
+        Every emitted window's ``bver`` is the version whose parameters
+        its launch was passed, and its ``born_ns`` lies inside its own
+        launch."""
+        from relayrl_tpu.transport.modelwire import ModelWireEncoder
+
+        built_ns = time.monotonic_ns()
+        host = self._host(
+            "cached", monkeypatch, bundle=_recall_bundle(version=3),
+            record_bver=True, columnar_wire=wire != "records",
+            async_emit=wire == "async")
+        launches = self._log_launches(host)
+        emitted = self._keep_emitted(host, monkeypatch)
+        by_version = {3: host.params}
+        first_call_ns = time.monotonic_ns()
+        host.rollout()
+        newer = _recall_bundle(seed=1, version=7)
+        frame, _info = ModelWireEncoder().encode(
+            7, newer.arch, jax.tree.map(np.asarray, newer.params))
+        assert host.swap_from_wire(7, frame) is not None
+        by_version[7] = host.params
+        assert host.version == 7 and by_version[7] is not by_version[3]
+        for _ in range(3):
+            host.rollout()
+        assert host.flush_emits()
+        bver = [np.unique(e["window"]["aux"]["bver"]).tolist()
+                for e in emitted]
+        assert bver == [[3], [3], [7], [7]]
+        # windows 1.. were launched under the log: what each was passed
+        for rec, e, version in zip(launches, emitted[1:], [3, 7, 7]):
+            assert rec["params"] is by_version[version]
+            assert np.unique(e["window"]["aux"]["bver"]).tolist() == [version]
+        assert launches[3]["params"] is by_version[7]   # the one in flight
+        # the stamp is the launch's: after the launch before, before its own
+        # (the constructor's window: the first call's, which takes it)
+        done = [built_ns] + [rec["done_ns"] for rec in launches]
+        born = [e["born_ns"] for e in emitted]
+        assert built_ns < first_call_ns <= born[0] < done[1]
+        for k in (1, 2, 3):
+            assert done[k - 1] <= born[k] < done[k]
+        host.close()
+
+    @staticmethod
+    def _short_episodes(sent: list, **kw):
+        """Two lanes of CartPole cut at 5 steps under windows of 4: an
+        episode ends inside every window from the second on, and every
+        payload the host ships lands in ``sent``."""
+        from relayrl_tpu.envs.jax import JaxCartPole
+        from relayrl_tpu.runtime.anakin import AnakinActorHost
+
+        return AnakinActorHost(
+            _bundle(), JaxCartPole(max_steps=5), num_envs=2, unroll_length=4,
+            seed=3, on_send=lambda lane, p: sent.append((lane, bytes(p))),
+            **kw)
+
+    @pytest.mark.parametrize("wire", ["columnar", "records", "async"])
+    def test_the_window_in_flight_outlives_close_and_no_frame_has_a_gap(
+            self, tmp_cwd, monkeypatch, registry, wire):
+        """``close()`` leaves the window in flight where it is, and the
+        first ``rollout()`` of a host enabled again returns it: across the
+        agent's disable/enable cycle the FRAMES that reach the wire, decoded,
+        are those of a twin that never closed — no step missing, every
+        episode's end in its place, the same returns — and at every close
+        emitted + in flight = launched."""
+        from relayrl_tpu.types.columnar import parse_frame
+        from relayrl_tpu.types.trajectory import deserialize_actions
+
+        def gauge():
+            return _counted("relayrl_actor_windows_in_flight")
+
+        def account(host, launched):
+            assert len(host._in_flight) == gauge() == 1
+            assert (_counted("relayrl_actor_env_steps_total") + self.STEPS
+                    == launched * self.STEPS)
+
+        kw = {"columnar_wire": wire != "records",
+              "async_emit": wire == "async"}
+        twin_sent: list = []
+        twin = self._short_episodes(twin_sent, **kw)
+        for _ in range(6):
+            twin.rollout()
+        assert twin.flush_emits()
+        twin.close()
+        from relayrl_tpu import telemetry
+        telemetry.set_registry(telemetry.Registry(run_id="in-flight"))
+
+        sent: list = []
+        host = self._short_episodes(sent, **kw)
+        assert gauge() == 1
+        launches = self._log_launches(host)
+        for _ in range(3):
+            host.rollout()
+            assert gauge() == 1
+        host.close()                    # windows 0-2 emitted, 3 in flight
+        held = host._in_flight[0]
+        account(host, launched=4)
+        host.close()                    # and again: nothing to do
+        account(host, launched=4)
+        host.start_emitter()            # the agent's enable
+        host.rollout()
+        assert launches[2]["window"] is held.window     # the one returned
+        for _ in range(2):
+            host.rollout()
+        assert host.flush_emits()
+        host.close()
+        assert len(launches) + 1 == 7
+        account(host, launched=7)
+
+        def decoded(payloads):
+            if wire == "records":
+                return [(lane, [(r.obs, r.act, r.rew, r.done, r.truncated)
+                                for r in deserialize_actions(p)])
+                        for lane, p in payloads]
+            frames = [(lane, parse_frame(p)) for lane, p in payloads]
+            return [(lane, f.n_steps, f.n_records, f.marker_truncated,
+                     f.columns, f.aux, f.final_obs) for lane, f in frames]
+
+        assert sent == twin_sent and len(sent) >= 6
+        jax.tree.map(np.testing.assert_array_equal,
+                     decoded(sent), decoded(twin_sent))
+        assert host.episode_returns == twin.episode_returns
+        assert sum(map(len, host.episode_returns)) >= 6
+
+    def test_a_wait_that_raises_leaves_both_windows_in_flight(
+            self, tmp_cwd, monkeypatch, registry):
+        """The wait for a window raises (an interrupt, a device error): the
+        call raises, nothing was dropped — both windows stay in flight —
+        and the next call launches none and returns the window the last one
+        waited for, so the stream is a twin's that never failed."""
+        twin_sent: list = []
+        twin = self._short_episodes(twin_sent)
+        for _ in range(3):
+            twin.rollout()
+        sent: list = []
+        host = self._short_episodes(sent)
+        launches = self._log_launches(host)
+        host.rollout()
+        emitted = _counted("relayrl_actor_env_steps_total")
+        wait = jax.block_until_ready
+
+        def fails_once(x):
+            monkeypatch.setattr(jax, "block_until_ready", wait)
+            raise KeyboardInterrupt
+
+        monkeypatch.setattr(jax, "block_until_ready", fails_once)
+        with pytest.raises(KeyboardInterrupt):
+            host.rollout()
+        assert len(host._in_flight) == 2 == len(launches)
+        assert _counted("relayrl_actor_windows_in_flight") == 2
+        assert _counted("relayrl_actor_env_steps_total") == emitted
+        waited_for = host._in_flight[0]
+        host.rollout()
+        assert len(launches) == 2
+        assert [w is waited_for for w in host._in_flight] == [False]
+        host.rollout()
+        assert len(launches) == 3 and len(host._in_flight) == 1
+        assert sent == twin_sent and sent
+
+    def test_a_launch_behind_a_finished_window_observes_its_gap(
+            self, tmp_cwd, monkeypatch, registry):
+        """One observation a launch of ``rollout()``: above 0 where the
+        window in flight had already finished when the call came (the
+        device had nothing queued), which a wait before the call makes
+        certain here."""
+        from relayrl_tpu import telemetry
+
+        def gap():
+            return next(
+                m for m in telemetry.get_registry().snapshot()["metrics"]
+                if m["name"] == "relayrl_actor_rollout_launch_gap_seconds")
+
+        host = self._host("mlp", monkeypatch)
+        assert gap()["count"] == 0
+        jax.block_until_ready(host._in_flight[0].window)
+        host.rollout()
+        first = gap()
+        assert first["count"] == 1 and first["sum"] > 0
+        host.rollout()
+        assert gap()["count"] == 2
+        host.close()
+
+    def test_the_rebuild_runs_once_a_swap_ahead_of_the_first_new_launch(
+            self, tmp_cwd, monkeypatch, registry):
+        """After a swap the caches are rebuilt once, at the next LAUNCH and
+        under the lock with it: on the newest carry — what the window in
+        flight returns — and under the new parameters, which the launch
+        right behind it is passed. No launch before it saw them; no launch
+        after it rebuilds again."""
+        host = self._host("cached", monkeypatch)
+        events: list = []
+        launches = self._log_launches(host, events)
+        rebuilds, rebuild = [], host._rebuild_fn
+
+        def logged(params, carry):
+            assert host._lock.locked()
+            new = rebuild(params, carry)
+            rebuilds.append({"params": params, "carry_in": carry,
+                             "carry_out": new})
+            events.append(("rebuild", None))
+            return new
+
+        host._rebuild_fn = logged
+        host.rollout()
+        old = host.params
+        assert host.maybe_swap(_recall_bundle(seed=1, version=1))
+        assert not rebuilds                      # a swap launches nothing
+        for _ in range(3):
+            host.rollout()
+        assert [kind for kind, _ in events] == [
+            "launch", "rebuild", "launch", "launch", "launch"]
+        assert len(rebuilds) == 1
+        assert _counted("relayrl_actor_cache_rebuilds_total") == 1
+        assert launches[0]["params"] is old
+        assert rebuilds[0]["params"] is host.params is not old
+        assert rebuilds[0]["carry_in"] is launches[0]["carry_out"]
+        assert launches[1]["carry_in"] is rebuilds[0]["carry_out"]
+        assert all(rec["params"] is host.params for rec in launches[1:])
+        host.close()
 
 
 class TestConfigKnobs:

@@ -306,15 +306,16 @@ class TestFusedScanOverAStateWithoutPositions:
 
     def test_a_swap_rebuilds_state_and_rows_to_what_prefill_gives(
             self, tmp_cwd, monkeypatch, registry, arch):
-        """``maybe_swap`` mid-episode (5 rows of 8 in every ring): after
-        the next dispatch's rebuild every lane's state and rows are what
+        """``maybe_swap`` mid-episode, with the constructor's window in
+        flight (5 rows of 8 in every ring, the carry that window returns):
+        after the next launch's rebuild every lane's state and rows are what
         ``prefill_cache`` makes of the lane's ring under the NEW parameters,
-        and the windows equal the window program's after the same swap."""
+        and the windows launched after the swap equal the window program's
+        after the same swap."""
         windows = {}
         for cached in (True, False):
             host = self._host(monkeypatch, arch, cached, unroll=5)
             windows[cached] = _keep_windows(host)
-            host.rollout()
             assert host.maybe_swap(self._bundle(arch, seed=1, version=1))
             if cached:
                 rebuilt = host._rebuild_fn(host.params, host._carry)
@@ -328,6 +329,7 @@ class TestFusedScanOverAStateWithoutPositions:
                                     jax.tree.leaves(want)):
                         np.testing.assert_allclose(a, b, atol=1e-5, rtol=0)
                 assert int(wlen[0]) == 5
+            host.rollout()
             host.rollout()
         _assert_windows_agree(windows[True], windows[False])
         assert _counted("relayrl_actor_cache_rebuilds_total") == 1
