@@ -37,6 +37,7 @@ from relayrl_tpu.telemetry import actor_ledger
 from relayrl_tpu.telemetry.aggregate import is_snapshot_frame
 from relayrl_tpu.telemetry.core import LAG_BUCKETS
 from relayrl_tpu.telemetry.spans import span, watch_gc
+from relayrl_tpu.telemetry.thread_clock import ThreadLedger
 from relayrl_tpu.telemetry.trace import SKEW_GUARD_NS, TrajCtx
 from relayrl_tpu.transport import make_server_transport
 from relayrl_tpu.transport.base import (
@@ -49,6 +50,10 @@ from relayrl_tpu.transport.base import (
 )
 from relayrl_tpu.types.columnar import DecodedTrajectory
 from relayrl_tpu.types.trajectory import deserialize_actions
+
+# the learner process's threads whose CPU time the per-thread ledger keeps
+# apart (telemetry/thread_clock.py): ``timings["cpu_<role>_s"]``
+_CLOCKED_ROLES = ("learner", "staging", "ingest", "publish")
 
 
 class _EventCoalescer:
@@ -324,7 +329,7 @@ class TrainingServer:
         )
         # host:dispatch asks the server what it alone knows of the batch
         # being dispatched: when its trajectories were born.
-        self.algorithm._dispatch_note = self._note_data_age
+        self.algorithm._dispatch_note = self._on_dispatch
         if self.guardrails is not None:
             # Installs the device-side health probes (observers — params
             # stay bit-identical to guardrails-off) and aligns the
@@ -633,6 +638,19 @@ class TrainingServer:
         #   learner_idle_s learner thread blocked on an empty queue
         #   warmup_s      learner thread pre-compiling update shapes
         #   gc_s          any thread inside a full collection (rl:gc)
+        #   admit_s       the transport's receive thread inside
+        #                 on_trajectory (rl:ingest.admit: tag split, dedup,
+        #                 guardrails, the report's merge, the queue put).
+        #                 Written unlocked by its one writer; a transport
+        #                 that calls from a pool of threads (grpc) may lose
+        #                 an increment to a race, never corrupt the total
+        #   cpu_<role>_s  on-CPU time of the learner | staging | ingest |
+        #                 publish thread(s), runq_<role>_s the learner's
+        #                 and the staging threads' time runnable and not
+        #                 running (absent where the kernel keeps no
+        #                 schedstat), cpu_process_s every thread's:
+        #                 absolute, rewritten by the learner thread once an
+        #                 update dispatch (telemetry/thread_clock.py)
         #   actor_<key>   the actor PROCESSES' own ledgers, summed over
         #                 them: each admitted trajectory's report carries
         #                 its host's deltas (telemetry/actor_ledger.py has
@@ -642,8 +660,12 @@ class TrainingServer:
         self.timings = {"decode_s": 0.0, "dispatch_s": 0.0,
                         "device_wait_s": 0.0, "publish_s": 0.0,
                         "learner_idle_s": 0.0, "warmup_s": 0.0,
-                        "gc_s": 0.0,
+                        "gc_s": 0.0, "admit_s": 0.0,
+                        **{f"cpu_{role}_s": 0.0 for role in
+                           (*_CLOCKED_ROLES, "process")},
                         **{f"actor_{k}": 0.0 for k in actor_ledger.TIMINGS}}
+        self._thread_ledger = ThreadLedger(
+            _CLOCKED_ROLES, runq_roles=("learner", "staging"))
         watch_gc(self)
         self._warmup_done = threading.Event()
 
@@ -838,17 +860,25 @@ class TrainingServer:
                         self.stats[f"actor_{key}"] += n
         return clean_id, seq, ctx, True
 
+    def _watch_ingest_thread(self) -> None:
+        """The calling (transport) thread joins the per-thread ledger's
+        ``ingest`` role, whichever transport it is (one thread for zmq and
+        the native poll loop, a pool's for grpc; watching twice is free)."""
+        self._thread_ledger.watch("ingest", threading.current_thread())
+
     def _on_trajectory(self, agent_id: str, payload: bytes) -> None:
-        if self._fault_ingest is not None:
-            # chaos plane: drop/delay/duplicate/corrupt AFTER the wire —
-            # the frame arrived but the server mishandles it (actor
-            # replay + dedup must make the loop whole again).
-            for delay_s, part in self._fault_ingest.inject(payload):
-                if delay_s > 0:
-                    time.sleep(delay_s)
-                self._ingest_one(agent_id, part)
-            return
-        self._ingest_one(agent_id, payload)
+        self._watch_ingest_thread()
+        with span("rl:ingest.admit", self.timings, "admit_s"):
+            if self._fault_ingest is not None:
+                # chaos plane: drop/delay/duplicate/corrupt AFTER the wire
+                # — the frame arrived but the server mishandles it (actor
+                # replay + dedup must make the loop whole again).
+                for delay_s, part in self._fault_ingest.inject(payload):
+                    if delay_s > 0:
+                        time.sleep(delay_s)
+                    self._ingest_one(agent_id, part)
+                return
+            self._ingest_one(agent_id, payload)
 
     def _check_ingest(self, tagged_id: str):
         """Guardrail admission verdict for ack-capable transports (the
@@ -1002,6 +1032,11 @@ class TrainingServer:
             adm.note_dequeued(victim_id)
 
     def _on_trajectory_decoded(self, batch) -> None:
+        self._watch_ingest_thread()
+        with span("rl:ingest.admit", self.timings, "admit_s"):
+            self._admit_decoded(batch)
+
+    def _admit_decoded(self, batch) -> None:
         """Pre-decoded columnar trajectory batch from the native drain —
         skips the staging thread entirely (one queue entry per drain).
         Sequence tags ride the decoded items' agent ids through the C++
@@ -1214,7 +1249,9 @@ class TrainingServer:
                         # off-GIL msgpack -> columns; falls back to the
                         # Python decoder only for payloads the columnar
                         # schema can't represent
-                        item = decoder.decode(payload, agent_id=agent_id)
+                        with span("rl:ingest.decode_native"):
+                            item = decoder.decode(payload,
+                                                  agent_id=agent_id)
                         if isinstance(item, RawTrajectory):
                             raw = item.payload
                             if item.is_envelope:
@@ -1576,6 +1613,14 @@ class TrainingServer:
             # Lag evidence is diagnostics; malformed aux must never
             # touch the ingest path's health.
             pass
+
+    def _on_dispatch(self, t0_ns: int) -> dict:
+        """What the server does once an update dispatch, on the learner
+        thread, when the algorithm opens its ``host:dispatch`` span:
+        refresh the per-thread CPU ledger and answer the span's data-age
+        arguments."""
+        self._thread_ledger.refresh(self.timings)
+        return self._note_data_age(t0_ns)
 
     def _note_data_age(self, t0_ns: int) -> dict:
         """The data age of the batch an update dispatch consumes, computed
@@ -2364,6 +2409,7 @@ class TrainingServer:
                 for i in range(self._staging_count)]
             for t in self._staging_threads:
                 t.start()
+                self._thread_ledger.watch("staging", t)
         if self.inference is not None:
             self.inference.start()
         # The publisher thread exists wherever there is a transport to
@@ -2376,6 +2422,7 @@ class TrainingServer:
             from relayrl_tpu.runtime.pipeline import ModelPublisher
 
             self._publisher = ModelPublisher(self._publish_snapshot)
+            self._thread_ledger.watch("publish", self._publisher._thread)
         self._mh_ready = []
         self._mh_busy = False
         if multi_host:
@@ -2387,6 +2434,7 @@ class TrainingServer:
                     else self._learner_loop),
             name="learner", daemon=True)
         self._learner_thread.start()
+        self._thread_ledger.watch("learner", self._learner_thread)
         if self._fleet is not None:
             self._fleet_stop.clear()
             self._fleet_thread = threading.Thread(

@@ -10,8 +10,21 @@ pair of stamps:
    histogram); callers that keep their total elsewhere read ``sp.seconds``;
 2. **the profiler's time line** — while a ``jax.profiler`` session runs, a
    ``TraceAnnotation(name, **args)``, so the span sits in the xplane on the
-   device trace's clock with its arguments as event stats. With the profiler
-   off no annotation object is built;
+   device trace's clock with its arguments as event stats, and with one
+   argument of its own: ``cpu_ns``, the thread's CPU time inside the span
+   (``time.thread_time_ns`` on entry and on exit; ``cpu_wall_ns`` beside it
+   is the wall time between the same two reads — the annotation's own
+   duration also holds both reads and the annotation's making, 11 us or more
+   of a span that may last 80), so that ``cpu_wall_ns - cpu_ns`` of a span
+   whose body does no I/O is the time its thread stood runnable or blocked.
+   A NAME is stamped at most once in ``CPU_STAMP_EVERY_NS``: on the
+   v5e machine's host one read of that clock is a 6 us system call under
+   the interpreter's lock and the clock steps by 10 ms, so a pair on each of
+   the 5,300 spans of a loop update cost a fifth of the traced window's rate
+   and said no more than a sample of them does (PERF.md section 6, PR 70);
+   a reader takes a name's ``cpu_ns`` over ``cpu_wall_ns`` from the spans
+   that carry them (``benchmark/thread_account.py``). With the profiler off no
+   annotation object is built and no CPU clock is read;
 3. **the sampled causal trace** — ``sp.hop(kind, trace_id, hop, **fields)``
    records a :mod:`relayrl_tpu.telemetry.trace` hop span with the same two
    stamps when the block exits, or at once if it already has (an actor draws
@@ -45,6 +58,10 @@ import weakref
 
 _monotonic_ns = time.monotonic_ns
 _annotation = None  # jax.profiler.TraceAnnotation, resolved on first use
+# a span name's CPU stamps are at least this far apart (traced spans only):
+# at most 0.2% of a thread's time a name at 6 us a clock read
+CPU_STAMP_EVERY_NS = 5_000_000
+_cpu_stamped: dict[str, int] = {}  # name -> start of its last stamped span
 
 
 def _profiling() -> bool:
@@ -67,7 +84,7 @@ class span:
     are the stamps, ``seconds`` their distance (after exit)."""
 
     __slots__ = ("name", "into", "key", "metric", "args", "t0_ns", "t1_ns",
-                 "_ann", "_hops")
+                 "_ann", "_cpu0_ns", "_hops")
 
     def __init__(self, name: str, into=None, key: str | None = None,
                  metric=None, **args):
@@ -84,12 +101,25 @@ class span:
         if _profiling():
             self._ann = _annotation(self.name, **self.args)
             self._ann.__enter__()
+            now = _monotonic_ns()
+            if now - _cpu_stamped.get(self.name, 0) >= CPU_STAMP_EVERY_NS:
+                _cpu_stamped[self.name] = now
+                self._cpu0_ns = (now, time.thread_time_ns())
+            else:
+                self._cpu0_ns = None
         self.t0_ns = _monotonic_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = self.t1_ns = _monotonic_ns()
         if self._ann is not None:
+            if self._cpu0_ns is not None:
+                # the wall time of the same bracket: from before the first
+                # clock read to before the second, so each number holds
+                # one read's worth of the two
+                wall0, cpu0 = self._cpu0_ns
+                self._ann.set_metadata(cpu_ns=time.thread_time_ns() - cpu0,
+                                       cpu_wall_ns=t1 - wall0)
             self._ann.__exit__(exc_type, exc, tb)
         if self.into is not None:
             self.into[self.key] += (t1 - self.t0_ns) * 1e-9

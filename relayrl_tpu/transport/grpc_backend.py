@@ -33,6 +33,7 @@ from concurrent import futures
 import grpc
 import msgpack
 
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.transport.base import (
     NACK_OVERLOADED,
     NACK_QUARANTINED,
@@ -60,14 +61,18 @@ class _Servicer:
     def send_actions(self, request: bytes, context) -> bytes:
         self._owner._m["recv_total"].inc()
         self._owner._m["recv_bytes"].inc(len(request))
-        try:
-            agent_id, payload = unpack_trajectory_envelope(request)
-        except Exception as e:
-            # data-shaped decode errors drop with a counter; programming
-            # errors re-raise (grpc surfaces them to the caller as an
-            # RPC error instead of a silent code-0 ack).
-            swallow_decode_error("grpc", "trajectory_ingest", e)
-            return msgpack.packb({"code": 0, "error": "malformed envelope"})
+        # grpc's own threads received the frame: the handler's share of
+        # the receive is the envelope's unpack
+        with span("rl:ingest.recv", bytes=len(request)):
+            try:
+                agent_id, payload = unpack_trajectory_envelope(request)
+            except Exception as e:
+                # data-shaped decode errors drop with a counter;
+                # programming errors re-raise (grpc surfaces them to the
+                # caller as an RPC error instead of a silent code-0 ack).
+                swallow_decode_error("grpc", "trajectory_ingest", e)
+                return msgpack.packb({"code": 0,
+                                      "error": "malformed envelope"})
         verdict = None
         if self._owner.check_ingest is not None:
             # Guardrail admission (quarantine / overload-nack): this

@@ -12,6 +12,7 @@ import ctypes
 import threading
 import time
 
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.transport.base import (
     AgentTransport,
     ServerTransport,
@@ -288,17 +289,20 @@ class NativeServerTransportImpl(ServerTransport):
                 cap = max(int(n) * 2, cap * 2)
                 buf = (ctypes.c_uint8 * cap)()
                 continue
-            try:
-                items = parse_drain(ctypes.string_at(buf, int(n)))
-            except Exception as e:
-                # A C++/Python RLD1 layout disagreement loses the whole
-                # already-dequeued batch — make that observable, never
-                # silent (and never crash ingest).
-                self.drain_parse_failures += 1
-                print(f"[NativeTransport] drain buffer unparseable "
-                      f"({e!r}) — a decoded batch was LOST "
-                      f"(#{self.drain_parse_failures})", flush=True)
-                continue
+            # the poll's wait is this thread's idle; the drained bytes'
+            # copy-out and parse are its receive
+            with span("rl:ingest.recv", bytes=int(n)):
+                try:
+                    items = parse_drain(ctypes.string_at(buf, int(n)))
+                except Exception as e:
+                    # A C++/Python RLD1 layout disagreement loses the whole
+                    # already-dequeued batch — make that observable, never
+                    # silent (and never crash ingest).
+                    self.drain_parse_failures += 1
+                    print(f"[NativeTransport] drain buffer unparseable "
+                          f"({e!r}) — a decoded batch was LOST "
+                          f"(#{self.drain_parse_failures})", flush=True)
+                    continue
             # One decoded-batch callback per drain (not per trajectory):
             # at fleet rate the per-item queue handoff was measurable.
             batch = []
@@ -364,11 +368,13 @@ class NativeServerTransportImpl(ServerTransport):
                 continue
             payload = ctypes.string_at(buf, int(n))
             if ev_type.value == _EV_TRAJECTORY:
-                try:
-                    agent_id, traj = unpack_trajectory_envelope(payload)
-                except Exception as e:
-                    swallow_decode_error("native", "trajectory_ingest", e)
-                    continue
+                with span("rl:ingest.recv", bytes=int(n)):
+                    try:
+                        agent_id, traj = unpack_trajectory_envelope(payload)
+                    except Exception as e:
+                        swallow_decode_error("native", "trajectory_ingest",
+                                             e)
+                        continue
                 self.on_trajectory(agent_id, traj)
             elif ev_type.value == _EV_REGISTER:
                 agent_id = payload.decode(errors="replace")

@@ -29,6 +29,7 @@ import time
 
 import zmq
 
+from relayrl_tpu.telemetry.spans import span
 from relayrl_tpu.transport.base import (
     AgentTransport,
     CMD_GET_MODEL,
@@ -243,17 +244,21 @@ class ZmqServerTransport(ServerTransport):
             while not self._stop.is_set():
                 if not dict(poller.poll(_POLL_MS)):
                     continue
-                buf = sock.recv()
-                self._m["recv_total"].inc()
-                self._m["recv_bytes"].inc(len(buf))
-                try:
-                    agent_id, payload = unpack_trajectory_envelope(buf)
-                except Exception as e:
-                    # Malformed frame: drop WITH a trace (counter + one
-                    # log line); non-data errors re-raise — see
-                    # base.swallow_decode_error.
-                    swallow_decode_error("zmq", "trajectory_ingest", e)
-                    continue
+                # the poll above is this thread's idle; from the frame's
+                # receive to its unpacked envelope is its work
+                with span("rl:ingest.recv") as sp:
+                    buf = sock.recv()
+                    sp.note(bytes=len(buf))
+                    self._m["recv_total"].inc()
+                    self._m["recv_bytes"].inc(len(buf))
+                    try:
+                        agent_id, payload = unpack_trajectory_envelope(buf)
+                    except Exception as e:
+                        # Malformed frame: drop WITH a trace (counter + one
+                        # log line); non-data errors re-raise — see
+                        # base.swallow_decode_error.
+                        swallow_decode_error("zmq", "trajectory_ingest", e)
+                        continue
                 self.on_trajectory(agent_id, payload)
         finally:
             sock.close(linger=0)

@@ -37,6 +37,15 @@ def xplane_events(trace_dir) -> dict:
     return events
 
 
+def burn_cpu(seconds: float) -> None:
+    """Keep the calling thread on a CPU for ``seconds`` of ITS CPU time."""
+    import time
+
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        sum(range(1000))
+
+
 def zmq_addr_pair() -> tuple[dict, dict]:
     """``(server_addrs, agent_addrs)`` for one zmq plane on fresh ephemeral
     ports: the server binds ``model_pub_addr``, agents (and relays, as

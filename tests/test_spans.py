@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _util import xplane_events
+from _util import burn_cpu, xplane_events
 
 from relayrl_tpu.telemetry import spans as spans_mod
 from relayrl_tpu.telemetry import trace as trace_mod
@@ -29,14 +29,18 @@ LEARNER_NAMES = (
     "host:publish_submit", "host:epoch_log",
     "rl:learner.item", "rl:learner.dispatch", "rl:batch.pad",
     "rl:batch.stack", "rl:dispatch.enqueue", "rl:dispatch.fence",
-    "rl:ingest.decode", "rl:publish", "rl:publish.gather",
-    "rl:publish.encode", "rl:publish.send")
+    "rl:ingest.decode", "rl:ingest.admit", "rl:publish",
+    "rl:publish.gather", "rl:publish.encode", "rl:publish.send")
+# a span's CPU time against its duration: two clocks, read one after the other
+CLOCK_SLACK_NS = 200_000
+THREAD_ROLES = ("learner", "staging", "ingest", "publish")
 ACTOR_TIMINGS = ("step_s", "infer_s", "record_s", "encode_s", "send_s",
                  "env_s", "cpu_s", "wall_s", "model_decode_s", "swap_s",
                  "model_install_s", "gc_s")
 ACTOR_COUNTS = ("steps", "installs")
 TIMINGS = ("decode_s", "dispatch_s", "device_wait_s", "publish_s",
-           "learner_idle_s", "warmup_s", "gc_s",
+           "learner_idle_s", "warmup_s", "gc_s", "admit_s",
+           *(f"cpu_{role}_s" for role in (*THREAD_ROLES, "process")),
            *(f"actor_{k}" for k in ACTOR_TIMINGS))
 STATS = ("trajectories", "updates", "dropped", "dropped_nonfinite",
          "learner_errors", "publish_errors", "warmup_failed",
@@ -185,7 +189,92 @@ class TestPrimitive:
             jax.profiler.stop_trace()
         (_line, _start, dur, stats), = xplane_events(tmp_path)[name]
         assert dur > 0
+        assert 0 <= stats.pop("cpu_ns") <= dur + CLOCK_SLACK_NS
+        assert 0 < stats.pop("cpu_wall_ns") <= dur
         assert stats == {**args, "mono_ns": sp.t0_ns}
+
+
+class TestCpuTime:
+    """A traced span carries its thread's CPU time, ``cpu_ns`` — every span
+    of a name but no two within ``CPU_STAMP_EVERY_NS`` of each other; with no
+    profiler no CPU clock is read."""
+
+    @pytest.fixture(autouse=True)
+    def _no_stamp_yet(self, monkeypatch):
+        monkeypatch.setattr(spans_mod, "_cpu_stamped", {})
+
+    @pytest.mark.parametrize("body,least,most", [
+        ("busy", 0.5, 1.0), ("sleeping", 0.0, 0.1)])
+    def test_traced_span_carries_its_threads_cpu_time(self, tmp_path, body,
+                                                      least, most):
+        work = {"busy": lambda: burn_cpu(0.05),
+                "sleeping": lambda: time.sleep(0.1)}[body]
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with span("rl:test.cpu"):
+                work()
+        finally:
+            jax.profiler.stop_trace()
+        (_line, _start, dur, stats), = xplane_events(tmp_path)["rl:test.cpu"]
+        assert 0 <= stats["cpu_ns"] <= dur + CLOCK_SLACK_NS
+        assert least * dur <= stats["cpu_ns"] <= most * dur + CLOCK_SLACK_NS
+        # the wall time between the same two reads: inside the annotation
+        assert 0.9 * dur <= stats["cpu_wall_ns"] <= dur
+
+    def test_a_child_spans_cpu_time_is_inside_its_parents(self, tmp_path):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with span("rl:test.outer"):
+                burn_cpu(0.01)
+                with span("rl:test.inner"):
+                    burn_cpu(0.02)
+        finally:
+            jax.profiler.stop_trace()
+        events = xplane_events(tmp_path)
+        (*_o, outer), = events["rl:test.outer"]
+        (*_i, inner), = events["rl:test.inner"]
+        assert 0.02e9 <= inner["cpu_ns"] <= outer["cpu_ns"] - 0.01e9
+
+    def test_a_names_stamps_are_spaced_and_names_do_not_share(self,
+                                                              tmp_path):
+        """A burst of one name inside the spacing: its first span alone is
+        stamped; another name beside it has a clock of its own; after the
+        spacing the name is stamped again."""
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            t0 = time.monotonic_ns()
+            for _ in range(20):
+                with span("rl:test.burst"):
+                    pass
+            with span("rl:test.other"):
+                pass
+            burst_ns = time.monotonic_ns() - t0
+            time.sleep(spans_mod.CPU_STAMP_EVERY_NS * 1.2e-9)
+            with span("rl:test.burst"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        assert burst_ns < spans_mod.CPU_STAMP_EVERY_NS
+        events = xplane_events(tmp_path)
+        burst = sorted(events["rl:test.burst"], key=lambda e: e[1])
+        assert ["cpu_ns" in st for *_x, st in burst] == (
+            [True] + 19 * [False] + [True])
+        (*_o, other), = events["rl:test.other"]
+        assert "cpu_ns" in other
+
+    def test_profiler_off_reads_no_cpu_clock(self, monkeypatch):
+        def never():
+            raise AssertionError("CPU clock read with the profiler off")
+
+        with span("host:test"):     # resolves the profiler's check
+            pass
+        assert not jax.profiler.TraceAnnotation.is_enabled()
+        monkeypatch.setattr(time, "thread_time_ns", never)
+        ledger = {"a_s": 0.0}
+        with span("rl:test.a", ledger, "a_s") as sp:
+            pass
+        assert sp._ann is None and ledger["a_s"] == sp.seconds
+        assert not hasattr(sp, "_cpu0_ns")
 
 
 class TestBatchSpans:
@@ -209,7 +298,9 @@ class TestBatchSpans:
             jax.profiler.stop_trace()
         events = xplane_events(tmp_path)
         assert len(events["rl:batch.pad"]) == 3
-        (_line, _start, _dur, stats), = events["rl:batch.stack"]
+        (_line, _start, dur, stats), = events["rl:batch.stack"]
+        assert 0 <= stats.pop("cpu_ns") <= dur + CLOCK_SLACK_NS
+        assert 0 < stats.pop("cpu_wall_ns") <= dur
         assert stats == {
             "valid": sum(lens), "padded": 3 * batch.horizon,
             "bytes": sum(v.nbytes for v in batch.as_dict().values()),
@@ -248,7 +339,9 @@ class TestBatchSpans:
             telemetry.reset_for_tests()
         assert all(np.array_equal(staged[k], batch[k]) for k in batch)
         spans = xplane_events(tmp_path)["host:stage_batch"]
-        assert [stats for *_x, stats in spans] == 2 * [{
+        assert [{k: v for k, v in stats.items()
+                 if k not in ("cpu_ns", "cpu_wall_ns")}
+                for *_x, stats in spans] == 2 * [{
             "flat": flat, "bytes": sum(v.nbytes for v in batch.values())}]
         assert total == 2 * flat
 
@@ -478,7 +571,8 @@ class TestActorSpans:
         assert len(events["rl:actor.encode"]) == 2 * rig.lanes
         assert len(events["rl:actor.send"]) == 2 * rig.lanes
         (_l, _s, _d, swap), = events["rl:actor.swap"]
-        assert swap == {"version": rig.host.version}
+        assert {k: v for k, v in swap.items() if k not in (
+            "cpu_ns", "cpu_wall_ns")} == {"version": rig.host.version}
         (_l, _s, _d, decode), = events["rl:actor.model_decode"]
         assert decode["bytes"] > 0
         collections = [st for *_x, st in events["rl:gc"]]
@@ -541,20 +635,68 @@ class TestLearnerSpans:
     def test_profiler_off_timings_read_as_before(self, impala_server):
         server, stub = impala_server
         _run_updates(server, 2)
-        t, st = server.timings, server.stats
+        # (the run-queue halves are there only where the kernel keeps them)
+        t = {k: v for k, v in server.timings.items()
+             if not k.startswith("runq_")}
+        st = server.stats
         assert st["updates"] == 2 and st["trajectories"] == 6
         # one sink for the finer spans, the profiler's: the always-on
         # ledgers hold the keys they held and no other
         assert sorted(t) == sorted(TIMINGS) and sorted(st) == sorted(STATS)
         assert not hasattr(server.algorithm, "timings")
         for key in ("dispatch_s", "learner_idle_s", "warmup_s", "decode_s",
-                    "publish_s"):
+                    "publish_s", "admit_s"):
             assert t[key] > 0, key
         # no actor reported: raw payloads and queue items carry no tag
         assert not any(t[k] for k in t if k.startswith("actor_"))
         assert not any(st[k] for k in st if k.startswith("actor_"))
         assert t["device_wait_s"] == server.algorithm.inflight.device_wait_s
         assert stub.published
+
+    @pytest.mark.parametrize("schedstat", [False, True])
+    def test_thread_ledger_is_refreshed_once_a_dispatch(
+            self, impala_server, tmp_path, monkeypatch, schedstat):
+        """After one dispatch every ``cpu_<role>_s`` key holds a thread's
+        clock; the totals only grow; the four named threads sum to no more
+        than the process; the run-queue keys exist where ``schedstat`` does
+        (here a planted one that echoes field 1 as a tenth in field 2)."""
+        from relayrl_tpu.telemetry import thread_clock
+
+        server, _stub = impala_server
+        monkeypatch.setattr(thread_clock, "TASK_DIR", str(tmp_path))
+        if schedstat:
+            clock = thread_clock.read_ns
+
+            def read_ns(thread, schedstat):
+                got = clock(thread, schedstat)
+                return got and (got[0], got[0] // 10)
+
+            monkeypatch.setattr(thread_clock, "read_ns", read_ns)
+        server._thread_ledger = thread_clock.ThreadLedger(
+            THREAD_ROLES, runq_roles=("learner", "staging"))
+        keys = [f"cpu_{role}_s" for role in (*THREAD_ROLES, "process")]
+        runq = ["runq_learner_s", "runq_staging_s"] if schedstat else []
+        assert all(server.timings[k] == 0.0 for k in keys)
+        _run_updates(server, 1)
+        first = dict(server.timings)
+        assert all(first[k] > 0 for k in keys + runq), first
+        assert [k for k in first if k.startswith("runq_")] == runq
+        _run_updates(server, 1)
+        second = dict(server.timings)
+        assert all(second[k] >= first[k] for k in keys + runq)
+        assert second["cpu_learner_s"] > first["cpu_learner_s"]
+        assert second["cpu_process_s"] > first["cpu_process_s"]
+        for t in (first, second):
+            assert sum(t[f"cpu_{role}_s"] for role in THREAD_ROLES) <= (
+                t["cpu_process_s"] + 0.05)
+            if schedstat:
+                assert t["runq_learner_s"] == pytest.approx(
+                    t["cpu_learner_s"] / 10, rel=1e-6)
+        # the threads end with the server; the next refresh raises nothing
+        # and no total falls
+        server.disable_server()
+        server._thread_ledger.refresh(server.timings)
+        assert all(server.timings[k] >= second[k] for k in keys)
 
     def test_profiler_on_names_and_arguments(self, impala_server, tmp_path):
         server, _stub = impala_server
@@ -579,7 +721,8 @@ class TestLearnerSpans:
             assert stats["bytes"] > 3 * 64 * OBS_DIM * 4
             # one bucket, one obs dtype: no row was copied a second time
             assert stats["moved"] == 0
-            assert sorted(stats) == ["bytes", "moved", "padded", "valid"]
+            assert sorted(stats) == ["bytes", "cpu_ns", "cpu_wall_ns",
+                                     "moved", "padded", "valid"]
         assert len(events["rl:batch.pad"]) == 6
         items = events["rl:learner.item"]
         assert all(s["n"] == 1 and s["queued_us"] >= 0
@@ -596,6 +739,80 @@ class TestLearnerSpans:
         # one profiler clock: mono_ns shifts CLOCK_MONOTONIC onto it
         shifts = [start - s["mono_ns"] for _l, start, _d, s in dispatches]
         assert abs(shifts[0] - shifts[1]) < 5e6
+
+
+class TestReceiveThreadSpans:
+    def test_live_zmq_names_the_receive_thread(self, tmp_path):
+        """Three trajectories pushed at a live zmq server: the PULL thread
+        shows ``rl:ingest.recv`` (with the frame's size) and
+        ``rl:ingest.admit`` on one line of its own, the staging thread's
+        ``rl:ingest.decode`` holds the native call as a child, and
+        ``timings["admit_s"]`` grew."""
+        import zmq
+        from _util import zmq_addr_pair
+
+        from relayrl_tpu.runtime.server import TrainingServer
+        from relayrl_tpu.transport.base import pack_trajectory_envelope
+
+        addrs, _agent = zmq_addr_pair()
+        server = TrainingServer(
+            "REINFORCE", obs_dim=OBS_DIM, act_dim=ACT_DIM,
+            hyperparams={"traj_per_epoch": 3, "seed_salt": 0},
+            config_path=str(tmp_path / "relayrl_config.json"),
+            env_dir=str(tmp_path), server_type="zmq", **addrs)
+        push = zmq.Context.instance().socket(zmq.PUSH)
+        frames = [pack_trajectory_envelope(
+            f"agent-{i}", serialize_actions(_episode(5 + i, seed=i)))
+            for i in range(3)]
+        try:
+            assert server.wait_warmup(120)
+            assert server.timings["admit_s"] == 0.0
+            push.connect(addrs["trajectory_addr"])
+            jax.profiler.start_trace(str(tmp_path / "trace"))
+            try:
+                for frame in frames:
+                    push.send(frame)
+                deadline = time.time() + 60
+                while (time.time() < deadline
+                       and server.stats["trajectories"] < 3):
+                    time.sleep(0.02)
+                assert server.drain(timeout=60)
+            finally:
+                jax.profiler.stop_trace()
+            timings, decoder = dict(server.timings), server.ingest_decoder
+        finally:
+            push.close(linger=0)
+            server.disable_server()
+        assert server.stats["updates"] == 1
+        assert 0 < timings["admit_s"] < 60
+        events = xplane_events(tmp_path / "trace")
+        recvs, admits = events["rl:ingest.recv"], events["rl:ingest.admit"]
+        assert sorted(st["bytes"] for *_x, st in recvs) == sorted(
+            len(f) for f in frames)
+        assert len(admits) == 3
+        line_of = {name: {e[0] for e in events[name]} for name in events}
+        assert len(line_of["rl:ingest.recv"]) == 1
+        assert line_of["rl:ingest.recv"] == line_of["rl:ingest.admit"]
+        assert line_of["rl:ingest.recv"].isdisjoint(
+            line_of["rl:ingest.decode"] | line_of["host:dispatch"])
+        # a frame's admission follows its receive on that thread
+        for (_l, r0, rd, _s), (_l2, a0, _ad, _s2) in zip(sorted(recvs),
+                                                        sorted(admits)):
+            assert r0 + rd <= a0
+        decodes = sorted(events["rl:ingest.decode"])
+        if decoder != "native":
+            assert "rl:ingest.decode_native" not in events
+            return
+        natives = sorted(events["rl:ingest.decode_native"])
+        assert line_of["rl:ingest.decode_native"] == line_of[
+            "rl:ingest.decode"]
+        assert len(natives) == len(decodes) == 3
+        for (_l, d0, dd, dst), (_l2, n0, nd, nst) in zip(decodes, natives):
+            assert d0 <= n0 and n0 + nd <= d0 + dd
+        # the first of each name is stamped, whatever came 5 ms after it too
+        assert 0 <= natives[0][3]["cpu_ns"] <= decodes[0][3]["cpu_ns"] + (
+            CLOCK_SLACK_NS)
+        assert "cpu_ns" in sorted(recvs)[0][3]
 
 
 class TestDeviceNames:
