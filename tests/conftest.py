@@ -17,6 +17,14 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
+# What tier-1 pays for on XLA:CPU is compiling thousands of toy programs, not
+# running them: LLVM's optimisation passes were a quarter of the suite's
+# CPU-seconds (PR 71: six kernel and trunk files, 693 -> 510 s of user CPU,
+# every case passing) and speed up nothing a test waits for. The HLO passes
+# (fusion, layout) run as ever, and a test compares two programs built alike.
+# Subprocesses (drill servers, spawned actors) inherit the variable.
+if "xla_backend_optimization_level" not in flags:
+    os.environ["XLA_FLAGS"] += " --xla_backend_optimization_level=0"
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
 # NOTE: do NOT point JAX_COMPILATION_CACHE_DIR at a persistent cache
@@ -36,6 +44,28 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
+
+_NATIVE_SO = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "native", "librelayrl_native.so")
+
+
+def _native_library_line() -> str:
+    if os.path.exists(_NATIVE_SO):
+        return "native/librelayrl_native.so: present"
+    return ("native/librelayrl_native.so: ABSENT, the 41 tests of the native "
+            "decoder and transport skip (`make -C native` builds it)")
+
+
+def pytest_report_header(config):
+    """`.gitignore` hides the library, and a checkout without it counts 41
+    passes fewer with no failure to show for it: say so before the dots."""
+    return _native_library_line()
+
+
+def pytest_terminal_summary(terminalreporter):
+    # -q prints no header: an absent library is said once more at the end
+    if terminalreporter.verbosity < 0 and not os.path.exists(_NATIVE_SO):
+        terminalreporter.write_line(_native_library_line())
 
 
 @pytest.fixture

@@ -5,6 +5,8 @@ the gradient's program makes the dear values with the names listed and
 without, that the gradient is the unchecked trunk's, what the policy's record
 says, and that the names are nothing to a program that lists none of them."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -147,7 +149,17 @@ def test_a_mixers_output_feeds_the_ffn_without_the_mixer(monkeypatch):
 CLOSE = {"float32": (1e-6, 1e-5, 1e-7), "bfloat16": (5e-3, 0.0, 5e-2)}
 
 
-@pytest.mark.parametrize("precision", sorted(CLOSE))
+@functools.cache
+def _seeded(arch):
+    """One tree an arch: the seed fixes it, whatever the trunk computes in
+    and whether it checkpoints (bit for bit: the leaves are float32)."""
+    return build_policy(ARCHS[arch]).init_params(jax.random.PRNGKey(0))
+
+
+# (slow: bfloat16 is the same statement under a looser bound; tier-1 keeps
+# each arch's float32 sibling, held to 1e-6 of the loss)
+@pytest.mark.parametrize("precision", [
+    pytest.param("bfloat16", marks=pytest.mark.slow), "float32"])
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_the_gradient_is_the_unchecked_trunks(arch, precision):
     """What is kept is the value the forward made: the loss and every
@@ -157,8 +169,8 @@ def test_the_gradient_is_the_unchecked_trunks(arch, precision):
     for checked in (False, True):
         policy = build_policy({**ARCHS[arch], "precision": precision,
                                "block_checkpoint": checked})
-        params = policy.init_params(jax.random.PRNGKey(0))
-        got[checked] = jax.jit(jax.value_and_grad(_loss(policy)))(params)
+        got[checked] = jax.jit(jax.value_and_grad(_loss(policy)))(
+            _seeded(arch))
     loss_rel, rtol, atol_of_max = CLOSE[precision]
     assert float(got[True][0]) == pytest.approx(float(got[False][0]),
                                                 rel=loss_rel)

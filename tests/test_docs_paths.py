@@ -70,3 +70,22 @@ def test_paths_named_in_document_exist(doc):
     dead = [t for t in tokens if not _resolves(t)]
     assert not dead, f"{doc} names paths that do not exist: {dead}"
 
+
+# Where a bare ``test_x.py`` may live; a name with its directory has to be
+# there.
+TEST_DIRS = ("tests", "tests/drills", "benchmark/tests")
+TEST_NAMERS = ["pytest.ini", ".claude/skills/verify/SKILL.md"] + DOCS
+_TEST_FILE = re.compile(r"((?:[\w.]+/)*)(test_[\w*]+\.py)")
+
+
+@pytest.mark.parametrize("source", TEST_NAMERS)
+def test_test_files_named_in_document_exist(source):
+    """A marker's description, a ``-m`` recipe or a document that names a
+    test file, with or without its directory, in backticks or not: the file
+    is there (a split or a rename of a test file shows here)."""
+    dead = []
+    for where, name in set(_TEST_FILE.findall((REPO / source).read_text())):
+        dirs = (where.rstrip("/"),) if where else TEST_DIRS
+        if not any(glob.glob(str(REPO / d / name)) for d in dirs):
+            dead.append(where + name)
+    assert not dead, f"{source} names test files that do not exist: {dead}"

@@ -62,17 +62,36 @@ def cfg():
 _BUILT: dict = {}   # a policy and its seeded parameters, built once
 
 
+def _program(reference, cfg, precision, **over):
+    """The policy alone: a case that runs another program on the module's
+    one tree seeds no tree of its own."""
+    kwargs = {**reference.program_kwargs(cfg), **over}
+    arch = {"kind": kwargs.pop("model_kind"), "obs_dim": cfg["obs_dim"],
+            "act_dim": cfg["act_dim"], "has_critic": True,
+            "precision": precision, **kwargs}
+    return build_policy(arch)
+
+
 def _system(reference, cfg, precision, seed=0, **over):
     key = (precision, seed, repr(sorted(over.items())))
     if key not in _BUILT:
-        kwargs = {**reference.program_kwargs(cfg), **over}
-        arch = {"kind": kwargs.pop("model_kind"), "obs_dim": cfg["obs_dim"],
-                "act_dim": cfg["act_dim"], "has_critic": True,
-                "precision": precision, **kwargs}
-        policy = build_policy(arch)
+        policy = _program(reference, cfg, precision, **over)
         _BUILT[key] = policy, jax.jit(policy.init_params)(
             jax.random.PRNGKey(seed))
     return _BUILT[key]
+
+
+@pytest.fixture(scope="module")
+def got(reference, cfg):
+    """The float32 system's outputs on ``_obs(cfg)``, computed once."""
+    return _outputs(*_system(reference, cfg, "float32"), _obs(cfg), cfg)
+
+
+@pytest.fixture(scope="module")
+def want(reference, cfg):
+    """The reference's, from the same tree and rows."""
+    _, params = _system(reference, cfg, "float32")
+    return reference.forward(params, _obs(cfg), cfg)
 
 
 def _obs(cfg, seed=1, batch=2, rows=T):
@@ -153,12 +172,15 @@ class TestSystemAgainstReference:
     # bulk of the tokens is compared: their median.
     @pytest.mark.parametrize("precision,over_tokens,atol", [
         ("float32", jnp.max, 1e-4), ("bfloat16", jnp.median, 0.06)])
-    def test_log_probabilities_and_values(self, reference, cfg, precision,
-                                          over_tokens, atol):
-        policy, params = _system(reference, cfg, precision)
-        obs = _obs(cfg)
-        logp, v = _outputs(policy, params, obs, cfg)
-        logp_ref, v_ref = reference.forward(params, obs, cfg)
+    def test_log_probabilities_and_values(self, reference, cfg, got, want,
+                                          precision, over_tokens, atol):
+        if precision == "float32":
+            (logp, v), (logp_ref, v_ref) = got, want
+        else:
+            policy, params = _system(reference, cfg, precision)
+            obs = _obs(cfg)
+            logp, v = _outputs(policy, params, obs, cfg)
+            logp_ref, v_ref = reference.forward(params, obs, cfg)
         assert float(over_tokens(jnp.abs(logp - logp_ref).max(-1))) < atol
         assert float(over_tokens(jnp.abs(v - v_ref))) < atol
 
@@ -194,13 +216,13 @@ class TestSystemAgainstReference:
             *reference.forward(p, obs, cfg), batch)
         ref_index = lambda p: reference.index_loss(p, obs, cfg,
                                                    batch["valid"])
-        (li, gi), (lx, gx) = (jax.value_and_grad(f)(params)
+        (li, gi), (lx, gx) = (jax.jit(jax.value_and_grad(f))(params)
                               for f in (ref_impala, ref_index))
         # the system's: one plain SGD step of the real update shows its
         # gradient (lr 1, no clipping to speak of), its metrics the loss
         sys_loss = lambda p: _impala_loss(
             *_all_logp_v(policy, p, obs, cfg["act_dim"]), batch)
-        gs_impala = jax.grad(sys_loss)(params)
+        gs_impala = jax.jit(jax.grad(sys_loss))(params)
         tx = make_impala_tx(1e-3, 1e9)
         update = make_impala_update(policy, 1e-3, 0.99, 0.5, 0.01, 1.0, 1.0,
                                     1e9)
@@ -250,8 +272,9 @@ class TestSystemAgainstReference:
                                               jnp.zeros((2, T), jnp.int32))
             return stats["own_loss_rows"].mean()
 
-        gs = jax.grad(sys_index)(params)
-        gr = jax.grad(lambda p: reference.index_loss(p, obs, cfg))(params)
+        gs = jax.jit(jax.grad(sys_index))(params)
+        gr = jax.jit(jax.grad(
+            lambda p: reference.index_loss(p, obs, cfg)))(params)
         flat_ref = dict(jax.tree_util.tree_flatten_with_path(gr)[0])
         for path, g in jax.tree_util.tree_flatten_with_path(gs)[0]:
             np.testing.assert_allclose(
@@ -353,23 +376,22 @@ class TestSystemAgainstReference:
         {"qk_norm": False},             # q and k not normed
         {"top_k": 2},                   # an expert dropped per token
     ])
-    def test_a_wrong_reference_is_told_apart(self, reference, cfg, wrong):
-        policy, params = _system(reference, cfg, "float32")
-        obs = _obs(cfg)
-        got = _outputs(policy, params, obs, cfg)
-        assert _differs(got, reference.forward(params, obs, cfg,
+    def test_a_wrong_reference_is_told_apart(self, reference, cfg, got,
+                                             wrong):
+        _, params = _system(reference, cfg, "float32")
+        assert _differs(got, reference.forward(params, _obs(cfg), cfg,
                                                wrong=wrong)) > 1e-3
 
     @pytest.mark.parametrize("chunk", [4, 16, 32, 5])
-    def test_the_tile_is_no_part_of_the_model(self, reference, cfg, chunk):
+    def test_the_tile_is_no_part_of_the_model(self, reference, cfg, want,
+                                              chunk):
         """``q_chunk_size`` / ``kv_chunk_size`` are read as the tile the
         scores are computed in: another tile (one that does not divide the
         sequence: one tile) gives the same outputs and the same loss."""
         _, params = _system(reference, cfg, "float32")
-        other, _ = _system(reference, cfg, "float32", index_chunk=chunk)
+        other = _program(reference, cfg, "float32", index_chunk=chunk)
         obs = _obs(cfg)
-        got = _outputs(other, params, obs, cfg)
-        assert _differs(got, reference.forward(params, obs, cfg)) < 1e-4
+        assert _differs(_outputs(other, params, obs, cfg), want) < 1e-4
         np.testing.assert_allclose(
             float(_stats(other, params, obs)["own_loss_rows"].mean()),
             float(reference.index_loss(params, obs, cfg)), atol=2e-6)
@@ -378,18 +400,17 @@ class TestSystemAgainstReference:
         {"index_topk": 4}, {"moe_top_k": 2}, {"moe_held": [3, 4]},
         {"moe_norm_topk_prob": False}, {"norm_eps": 1e-2},
         {"qk_norm": False}, {"rope_theta": 10000.0}])
-    def test_a_different_model_is_told_apart(self, reference, cfg, wrong):
+    def test_a_different_model_is_told_apart(self, reference, cfg, want,
+                                             wrong):
         _, params = _system(reference, cfg, "float32")
-        other, _ = _system(reference, cfg, "float32", **wrong)
-        got = _outputs(other, params, _obs(cfg), cfg)
-        assert _differs(got, reference.forward(params, _obs(cfg),
-                                               cfg)) > 1e-3
+        other = _program(reference, cfg, "float32", **wrong)
+        assert _differs(_outputs(other, params, _obs(cfg), cfg),
+                        want) > 1e-3
 
     def test_an_8_bit_trunk_is_further_off_than_bfloat16(self, reference,
-                                                         cfg):
+                                                         cfg, want):
         _, params = _system(reference, cfg, "float32")
-        obs = _obs(cfg)
-        exact = reference.forward(params, obs, cfg)
+        obs, exact = _obs(cfg), want
         errs = {}
         for name, dtype in (("bf16", jnp.bfloat16),
                             ("fp8", jnp.float8_e5m2)):
@@ -482,7 +503,9 @@ class TestTheSharesAddUp:
                       norm_topk_prob=True, ffn="swiglu", use_bias=False,
                       held=held)
 
-    @pytest.mark.parametrize("seed", [0, 1])
+    # (slow: a second draw of the same statement; tier-1 keeps seed 0)
+    @pytest.mark.parametrize("seed", [
+        0, pytest.param(1, marks=pytest.mark.slow)])
     def test_against_the_uncut_reference(self, reference, seed):
         rng = np.random.default_rng(seed)
         u = jnp.asarray(rng.standard_normal((2, 24, self.D)), jnp.float32)

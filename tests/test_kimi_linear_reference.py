@@ -64,14 +64,20 @@ def cfg():
 _BUILT = {}  # one policy (and its compiled functions) a distinct arch
 
 
+def _program(reference, cfg, precision, **over):
+    """The policy alone: a case that runs another program on the module's
+    one tree seeds no tree of its own."""
+    kwargs = {**reference.program_kwargs(cfg), **over}
+    arch = {"kind": kwargs.pop("model_kind"), "obs_dim": cfg["obs_dim"],
+            "act_dim": cfg["act_dim"], "has_critic": True,
+            "precision": precision, **kwargs}
+    return build_policy(arch)
+
+
 def _system(reference, cfg, precision, seed=0, **over):
     key = (precision, seed, json.dumps(over, sort_keys=True))
     if key not in _BUILT:
-        kwargs = {**reference.program_kwargs(cfg), **over}
-        arch = {"kind": kwargs.pop("model_kind"), "obs_dim": cfg["obs_dim"],
-                "act_dim": cfg["act_dim"], "has_critic": True,
-                "precision": precision, **kwargs}
-        policy = build_policy(arch)
+        policy = _program(reference, cfg, precision, **over)
         # (one program: op by op the init costs the suite's clock a minute)
         _BUILT[key] = policy, jax.jit(policy.init_params)(
             jax.random.PRNGKey(seed))
@@ -83,6 +89,19 @@ def _outputs(policy, params, obs, act_dim):
     half a minute a call)."""
     return jax.jit(lambda p, o: _all_logp_v(policy, p, o, act_dim))(params,
                                                                     obs)
+
+@pytest.fixture(scope="module")
+def got(reference, cfg):
+    """The float32 system's outputs on ``_obs(cfg)``, computed once."""
+    return _outputs(*_system(reference, cfg, "float32"), _obs(cfg),
+                    cfg["act_dim"])
+
+
+@pytest.fixture(scope="module")
+def want(reference, cfg):
+    """The reference's, from the same tree and rows."""
+    _, params = _system(reference, cfg, "float32")
+    return reference.forward(params, _obs(cfg), cfg)
 
 
 def _obs(cfg, seed=1, batch=2):
@@ -177,12 +196,14 @@ class TestSystemAgainstReference:
     # the bulk of the tokens is compared: their median.
     @pytest.mark.parametrize("precision,over_tokens,atol", [
         ("float32", jnp.max, 1e-4), ("bfloat16", jnp.median, 0.06)])
-    def test_log_probabilities_and_values(self, reference, cfg, precision,
-                                          over_tokens, atol):
+    def test_log_probabilities_and_values(self, reference, cfg, got, want,
+                                          precision, over_tokens, atol):
         policy, params = _system(reference, cfg, precision)
-        obs = _obs(cfg)
-        logp, v = _outputs(policy, params, obs, cfg["act_dim"])
-        logp_ref, v_ref = reference.forward(params, obs, cfg)
+        if precision != "float32":
+            obs = _obs(cfg)
+            got = _outputs(policy, params, obs, cfg["act_dim"])
+            want = reference.forward(params, obs, cfg)
+        (logp, v), (logp_ref, v_ref) = got, want
         assert float(over_tokens(jnp.abs(logp - logp_ref).max(-1))) < atol
         assert float(over_tokens(jnp.abs(v - v_ref))) < atol
         # the record of what the rule ran as: plain XLA off a TPU, under a
@@ -319,25 +340,24 @@ class TestSystemAgainstReference:
         {"no_latent_norm": True},       # W_kvb c without the RMSNorm
         {"bf16": True},                 # bfloat16 throughout, state included
     ])
-    def test_a_wrong_reference_is_told_apart(self, reference, cfg, wrong):
-        policy, params = _system(reference, cfg, "float32")
-        obs = _obs(cfg)
-        got = _outputs(policy, params, obs, cfg["act_dim"])
-        assert _differs(got, reference.forward(params, obs, cfg,
+    def test_a_wrong_reference_is_told_apart(self, reference, cfg, got,
+                                             wrong):
+        _, params = _system(reference, cfg, "float32")
+        assert _differs(got, reference.forward(params, _obs(cfg), cfg,
                                                wrong=wrong)) > 1e-3
 
     @pytest.mark.parametrize("wrong", [
         {"moe_routed_scaling": 1.0}, {"kda_conv_taps": 3}])
-    def test_a_different_model_is_told_apart(self, reference, cfg, wrong):
+    def test_a_different_model_is_told_apart(self, reference, cfg, want,
+                                             wrong):
         _, params = _system(reference, cfg, "float32")
-        other, _ = _system(reference, cfg, "float32", **wrong)
+        other = _program(reference, cfg, "float32", **wrong)
         if "kda_conv_taps" in wrong:    # one tap fewer: its own tree
             params = jax.tree_util.tree_map_with_path(
                 lambda path, a: a[1:] if "kda_conv_w" in jax.tree_util.
                 keystr(path) else a, params)
         got = _outputs(other, params, _obs(cfg), cfg["act_dim"])
-        assert _differs(got, reference.forward(
-            _system(reference, cfg, "float32")[1], _obs(cfg), cfg)) > 1e-3
+        assert _differs(got, want) > 1e-3
 
     def test_a_trunk_that_rotates_turns_the_shared_lanes(self, reference,
                                                          cfg):

@@ -6,6 +6,9 @@ hot-swaps mid-episode (replay rebuild), and hand off to the window path
 once the episode outgrows the context window.
 """
 
+import functools
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,10 +110,24 @@ def _policy_params(seed=0, **arch):
     return policy, policy.init_params(jax.random.PRNGKey(seed))
 
 
+@functools.cache
+def _trunk(name):
+    """``(policy, params)`` of ``CACHED_ARCHS[name]``, built once for the
+    module, the policy's three steps each ONE compiled program (the position
+    traced, as the runtime calls them): stepped eagerly, every position of
+    every case traced the trunk again."""
+    policy, params = _policy_params(**CACHED_ARCHS[name])
+    return types.SimpleNamespace(
+        init_cache=policy.init_cache, evaluate=jax.jit(policy.evaluate),
+        step_window=jax.jit(policy.step_window),
+        step_cached=jax.jit(policy.step_cached),
+        prefill_cache=jax.jit(policy.prefill_cache)), params
+
+
 class TestStepCachedNumerics:
     @pytest.mark.parametrize("name", sorted(CACHED_ARCHS))
     def test_matches_step_window(self, name):
-        policy, params = _policy_params(**CACHED_ARCHS[name])
+        policy, params = _trunk(name)
         rng = np.random.default_rng(0)
         W = 8
         cache = policy.init_cache(W)
@@ -138,7 +155,7 @@ class TestStepCachedNumerics:
         # prefill rotates W keys at positions 0..W-1 in one dispatch; the
         # steps after it must read them as if they had been written one by
         # one
-        policy, params = _policy_params(**CACHED_ARCHS[name])
+        policy, params = _trunk(name)
         rng = np.random.default_rng(5)
         W, t0 = 8, 5
         window = np.zeros((W, 6), np.float32)
@@ -182,7 +199,7 @@ class TestStepCachedNumerics:
     def test_the_ring_takes_the_real_rows_of_a_prefill_only(self, t0):
         # before, at and past the ring's first wrap; the padding rows after
         # t0 must not displace the real ones
-        policy, params = _policy_params(**CACHED_ARCHS["smallthinker_trunk"])
+        policy, params = _trunk("smallthinker_trunk")
         W = 8
         window = np.zeros((W, 6), np.float32)
         window[:t0] = np.random.default_rng(5).standard_normal((t0, 6))
@@ -206,7 +223,7 @@ class TestStepCachedNumerics:
                                       "sliding_last_dense"])
     def test_full_forward_equals_the_stepped_rows(self, name):
         # evaluate() over the whole sequence against step_cached row by row
-        policy, params = _policy_params(**CACHED_ARCHS[name])
+        policy, params = _trunk(name)
         rng = np.random.default_rng(11)
         W = 8
         window = rng.standard_normal((W, 6)).astype(np.float32)

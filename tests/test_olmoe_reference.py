@@ -51,13 +51,39 @@ def cfg():
     return cfg
 
 
-def _system(reference, cfg, precision, seed=0, **over):
+def _program(reference, cfg, precision, **over):
+    """The policy alone: a case that runs another program on the module's
+    one tree seeds no tree of its own."""
     kwargs = {**reference.program_kwargs(cfg), **over}
     arch = {"kind": kwargs.pop("model_kind"), "obs_dim": cfg["obs_dim"],
             "act_dim": cfg["act_dim"], "has_critic": True,
             "precision": precision, **kwargs}
-    policy = build_policy(arch)
-    return policy, policy.init_params(jax.random.PRNGKey(seed))
+    return build_policy(arch)
+
+
+_BUILT: dict = {}   # a policy and its seeded parameters, built once
+
+
+def _system(reference, cfg, precision, seed=0, **over):
+    key = (precision, seed, repr(sorted(over.items())))
+    if key not in _BUILT:
+        policy = _program(reference, cfg, precision, **over)
+        _BUILT[key] = policy, policy.init_params(jax.random.PRNGKey(seed))
+    return _BUILT[key]
+
+
+@pytest.fixture(scope="module")
+def got(reference, cfg):
+    """The float32 system's outputs on ``_obs(cfg)``, computed once."""
+    return _all_logp_v(*_system(reference, cfg, "float32"), _obs(cfg),
+                       cfg["act_dim"])
+
+
+@pytest.fixture(scope="module")
+def want(reference, cfg):
+    """The reference's, from the same tree and rows."""
+    _, params = _system(reference, cfg, "float32")
+    return reference.forward(params, _obs(cfg), cfg)
 
 
 def _all_logp_v(policy, params, obs, act_dim):
@@ -84,19 +110,21 @@ class TestSystemAgainstReference:
     # rounded to float8 (3 bits) must fall outside it.
     @pytest.mark.parametrize("precision,atol", [("float32", 1e-5),
                                                 ("bfloat16", 0.04)])
-    def test_log_probabilities_and_values(self, reference, cfg, precision,
-                                          atol):
-        policy, params = _system(reference, cfg, precision)
-        obs = _obs(cfg)
-        logp, v = _all_logp_v(policy, params, obs, cfg["act_dim"])
-        logp_ref, v_ref = reference.forward(params, obs, cfg)
+    def test_log_probabilities_and_values(self, reference, cfg, got, want,
+                                          precision, atol):
+        if precision != "float32":
+            policy, params = _system(reference, cfg, precision)
+            obs = _obs(cfg)
+            got = _all_logp_v(policy, params, obs, cfg["act_dim"])
+            want = reference.forward(params, obs, cfg)
+        (logp, v), (logp_ref, v_ref) = got, want
         assert float(jnp.abs(logp - logp_ref).max()) < atol
         assert float(jnp.abs(v - v_ref).max()) < atol
 
-    def test_an_8_bit_trunk_fails_the_bfloat16_bound(self, reference, cfg):
+    def test_an_8_bit_trunk_fails_the_bfloat16_bound(self, reference, cfg,
+                                                     want):
         _, params = _system(reference, cfg, "float32")
-        obs = _obs(cfg)
-        exact = reference.forward(params, obs, cfg)
+        obs, exact = _obs(cfg), want
         errs = {}
         for name, dtype in (("bf16", jnp.bfloat16),
                             ("fp8", jnp.float8_e4m3fn)):
@@ -109,13 +137,13 @@ class TestSystemAgainstReference:
         {"moe_norm_topk_prob": True},      # renormalised top-k weights
         {"moe_top_k": 1},                  # an expert dropped per token
         {"rope_theta": 100.0}, {"norm_eps": 1e-2}])
-    def test_a_different_model_is_told_apart(self, reference, cfg, wrong):
+    def test_a_different_model_is_told_apart(self, reference, cfg, want,
+                                             wrong):
         # the float32 comparison is tight enough to catch each departure
         _, params = _system(reference, cfg, "float32")
-        other, _ = _system(reference, cfg, "float32", **wrong)
-        obs = _obs(cfg)
-        logp, v = _all_logp_v(other, params, obs, cfg["act_dim"])
-        logp_ref, v_ref = reference.forward(params, obs, cfg)
+        other = _program(reference, cfg, "float32", **wrong)
+        logp, v = _all_logp_v(other, params, _obs(cfg), cfg["act_dim"])
+        logp_ref, v_ref = want
         assert float(jnp.abs(logp - logp_ref).max()) > 1e-3
 
     def test_reference_imports_nothing_of_the_models(self):

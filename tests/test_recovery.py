@@ -356,7 +356,8 @@ def test_learner_sigkill_resume_zero_loss_zero_dup(transport, tmp_path,
                 break
             time.sleep(0.1)
         status = _read_status(scratch)
-        assert status and status["version"] >= 2, "no training before kill"
+        assert status and status["version"] >= 2, (
+            f"no training before the kill in 120 s: {status}")
         v_before = status["version"]
         agent_v_before = agent.model_version
 
@@ -375,17 +376,23 @@ def test_learner_sigkill_resume_zero_loss_zero_dup(transport, tmp_path,
         # (breaker probe / socket monitor / heartbeat redial) and the
         # fleet must train PAST the pre-kill version (continuity).
         proc = _spawn_server(scratch, transport, server_addrs, resume=True)
-        _wait_status(scratch, proc, lambda s: True, 120, "server restart")
+        # the dead server's status file is still there: the event is the
+        # NEW process's first write of it (restored, warmed up, serving)
+        _wait_status(scratch, proc, lambda s: s["pid"] == proc.pid, 180,
+                     "the restarted server's own status")
         deadline = time.monotonic() + 180
         while time.monotonic() < deadline:
             _drive_episodes(agent, rng, 2)
+            assert proc.poll() is None, (
+                f"restarted server died (rc={proc.returncode}):\n"
+                f"{proc.communicate()[0][-3000:]}")
             status = _read_status(scratch)
             if (status and status["version"] > v_before
                     and agent.model_version > agent_v_before):
                 break
             time.sleep(0.1)
-        assert status["version"] > v_before, (
-            f"server never trained past the crash: {status['version']} "
+        assert status and status["version"] > v_before, (
+            f"server never trained past the crash in 180 s: {status} "
             f"<= {v_before}")
         assert agent.model_version > agent_v_before, (
             "actor never resynced to the post-crash model line")

@@ -628,6 +628,28 @@ _TRANSFORMER_HP = {
 }
 
 
+def _every_sent_episode_accepted(server, sched, lanes, timeout_s=120):
+    """Wait for what the assertions after it read, not for a clock: the
+    learner has trained twice and every episode the lanes sent has reached
+    the ledger (``drain`` cannot see bytes still in a socket's buffer, so an
+    accounting read straight after it is a race under a busy host). Returns
+    the accounting; past the deadline it fails saying which was short."""
+    sent = sched.agent.spool.sent_counts()
+    deadline = time.monotonic() + timeout_s
+    while True:
+        agents = server.ingest_accounting()["agents"]
+        landed = (len(agents) == lanes and all(
+            row["max_seq"] == sent.get(lane) for lane, row in agents.items()))
+        if landed and server.stats["updates"] >= 2:
+            break
+        assert time.monotonic() < deadline, (
+            f"after {timeout_s} s: updates={server.stats['updates']} (want "
+            f">= 2), sent={sent}, ledger={agents}")
+        time.sleep(0.05)
+    assert server.drain(timeout=60), "the learner's queues never emptied"
+    return server.ingest_accounting()
+
+
 class TestLivePlane:
     def test_generate_score_update_over_live_zmq(self, tmp_cwd):
         """The dataflow against a real TrainingServer: a transformer
@@ -651,16 +673,10 @@ class TestLivePlane:
                                   identity="rlhf-live",
                                   handshake_timeout_s=60, **agent_addrs)
             stats = sched.run(episodes=64, deadline_s=120)
-            assert stats["episodes_scored"] >= 64
+            assert stats["episodes_scored"] >= 64, (
+                f"{stats['episodes_scored']} of 64 episodes scored in 120 s")
             sched.flush()
-            deadline = time.monotonic() + 60
-            while (server.stats["updates"] < 2
-                   and time.monotonic() < deadline):
-                time.sleep(0.1)
-            assert server.stats["updates"] >= 2, "learner never trained"
-            server.drain(timeout=60)
-            acct = server.ingest_accounting()
-            assert len(acct["agents"]) == 4
+            acct = _every_sent_episode_accepted(server, sched, lanes=4)
             sent = sched.agent.spool.sent_counts()
             for lane_id, row in acct["agents"].items():
                 assert row["accepted"] == row["max_seq"] == sent[lane_id]
@@ -711,14 +727,7 @@ class TestLivePlane:
             # lanes x unroll tokens per round, counted by the stage
             assert stats["tokens_generated"] >= 128
             sched.flush()
-            deadline = time.monotonic() + 60
-            while (server.stats["updates"] < 2
-                   and time.monotonic() < deadline):
-                time.sleep(0.1)
-            assert server.stats["updates"] >= 2, "learner never trained"
-            server.drain(timeout=60)
-            acct = server.ingest_accounting()
-            assert len(acct["agents"]) == 4
+            acct = _every_sent_episode_accepted(server, sched, lanes=4)
             sent = sched.agent.spool.sent_counts()
             for lane_id, row in acct["agents"].items():
                 assert row["accepted"] == row["max_seq"] == sent[lane_id]
